@@ -344,10 +344,9 @@ def make_ici_transfer(mesh, axis: str, src: int, dst: int):
     send/recv between aDAG actors). Other shards pass through unchanged.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    @partial(shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     def _hop(x):
         moved = jax.lax.ppermute(x, axis, perm=[(src, dst)])
         idx = jax.lax.axis_index(axis)

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_tpu.ops.flash_attention import mha
+from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.fused import (
     fused_rmsnorm,
     lm_head_cross_entropy,
@@ -155,7 +155,7 @@ def _rope(x, positions, theta: float):
 
 
 def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
-               seq_size: int):
+               seq_size: int, mesh=None):
     if cfg.attention_impl == "ring" and seq_axis is not None:
         # Inside shard_map over the sequence axis: exact ring attention.
         rep = cfg.n_heads // k.shape[2]
@@ -165,13 +165,26 @@ def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
         return ring_attention(
             q, k, v, axis_name=seq_axis, axis_size=seq_size, causal=True
         )
-    return mha(q, k, v, causal=True, impl=(
+    impl = resolve_impl(
         cfg.attention_impl if cfg.attention_impl in ("pallas", "xla") else "auto"
-    ))
+    )
+    attn = partial(mha, causal=True, impl=impl)
+    if impl == "pallas" and mesh is not None and mesh.size > 1:
+        # XLA cannot partition a Mosaic kernel ("wrap the call in a
+        # shard_map"), so map it ourselves over the axes attention is
+        # independent along: batch (data/fsdp) and heads (tensor).
+        spec = mesh_lib.default_transformer_rules(mesh).spec(
+            ("batch", None, "heads", None)
+        )
+        attn = jax.shard_map(
+            attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
+        )
+    return attn(q, k, v)
 
 
 def _block(x, blk, positions, cfg: TransformerConfig,
-           seq_axis: Optional[str], seq_size: int):
+           seq_axis: Optional[str], seq_size: int, mesh=None):
     B, T, d = x.shape
     h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     dt = cfg.dtype
@@ -182,7 +195,7 @@ def _block(x, blk, positions, cfg: TransformerConfig,
     v = (y @ blk["wv"].astype(dt)).reshape(B, T, hk, dh)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
-    o = _attention(q, k, v, cfg, seq_axis, seq_size)
+    o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh)
     x = x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
 
     y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
@@ -194,18 +207,22 @@ def _block(x, blk, positions, cfg: TransformerConfig,
 
 def transformer_hidden(params, tokens, cfg: TransformerConfig,
                        positions=None, seq_axis: Optional[str] = None,
-                       seq_size: int = 1):
+                       seq_size: int = 1, mesh=None):
     """Forward through the blocks: [B, T] tokens -> [B, T, d] normed hidden.
 
     When called under shard_map with the sequence sharded, pass seq_axis and
     positions holding GLOBAL positions so RoPE and causal masks are correct.
+    When called under a jit that shards over `mesh`, pass the mesh: the
+    Pallas attention kernel is mapped over its batch and head axes.
     """
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     x = params["embed"].astype(cfg.dtype)[tokens]
 
-    blk_fn = partial(_block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size)
+    blk_fn = partial(
+        _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size, mesh=mesh
+    )
     if cfg.remat:
         blk_fn = jax.checkpoint(blk_fn, static_argnums=())
 
@@ -222,11 +239,11 @@ def _unembed(params, cfg: TransformerConfig):
 
 def transformer_apply(params, tokens, cfg: TransformerConfig,
                       positions=None, seq_axis: Optional[str] = None,
-                      seq_size: int = 1):
+                      seq_size: int = 1, mesh=None):
     """Forward: [B, T] int32 tokens -> [B, T, vocab] logits (f32)."""
     x = transformer_hidden(
         params, tokens, cfg, positions=positions, seq_axis=seq_axis,
-        seq_size=seq_size,
+        seq_size=seq_size, mesh=mesh,
     )
     return (x @ _unembed(params, cfg).astype(cfg.dtype)).astype(jnp.float32)
 
@@ -270,19 +287,36 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
     tok_sharding = NamedSharding(mesh, P(batch_axes, seq_ax))
     repl = NamedSharding(mesh, P())
 
+    # The state's layout is pinned on both sides of the step. Left to the
+    # compiler, a step's output sharding can differ from its input's (it
+    # spread the replicated norm moments over fsdp), and the next call then
+    # compiles a second program unseen.
+    opt_shard = optax.tree_map_params(
+        optimizer,
+        lambda _, sharding: sharding,
+        jax.eval_shape(
+            lambda: optimizer.init(
+                transformer_init(jax.random.PRNGKey(0), cfg)
+            )
+        ),
+        p_shard,
+        transform_non_params=lambda _: repl,
+    )
+    state_shard = {"params": p_shard, "opt": opt_shard, "step": repl}
+
     def init_state(rng):
         params = transformer_init(rng, cfg)
         params = jax.tree.map(
             lambda x, s: jax.device_put(x, s), params, p_shard
         )
-        opt = optimizer.init(params)
+        opt = jax.jit(optimizer.init, out_shardings=opt_shard)(params)
         return {"params": params, "opt": opt,
-                "step": jnp.zeros((), jnp.int32)}
+                "step": jax.device_put(jnp.zeros((), jnp.int32), repl)}
 
     def loss_fn(params, batch):
-        return transformer_loss(params, batch, cfg)
+        return transformer_loss(params, batch, cfg, mesh=mesh)
 
-    @partial(jax.jit, donate_argnums=(0,))
+    @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))
     def step(state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch)
         updates, opt = optimizer.update(
@@ -296,7 +330,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         )
 
     return init_state, step, {"tokens": tok_sharding, "replicated": repl,
-                              "params": p_shard}
+                              "params": p_shard, "state": state_shard}
 
 
 def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):

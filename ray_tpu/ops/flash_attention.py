@@ -5,9 +5,9 @@ q-block, k-block) with the k dimension innermost; running max/denominator and
 the output accumulator live in VMEM scratch that persists across the k steps
 and is flushed on the last one. f32 accumulation, bf16-friendly inputs.
 
-Dispatch: `mha` picks this kernel on TPU, falls back to an XLA einsum
-implementation elsewhere (tests run the kernel in interpret mode on tiny
-shapes via `flash_attention(..., interpret=True)`).
+Dispatch: `mha(impl="auto")` picks this kernel when JAX reports a TPU and an
+XLA einsum implementation on any other platform (tests run the kernel in
+interpret mode on tiny shapes via `flash_attention(..., interpret=True)`).
 
 Backward pass uses recompute (custom_vjp re-derives the tile softmax),
 trading FLOPs for the O(T^2) memory XLA would otherwise materialize.
@@ -23,13 +23,6 @@ import jax
 import jax.numpy as jnp
 
 _BIG_NEG = -1e30
-
-
-def _compiler_params(pltpu, **kw):
-    """pltpu.TPUCompilerParams was renamed CompilerParams across jax minor
-    releases; build whichever this jax ships."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
 
 
 def _attn_fwd_kernel(
@@ -183,8 +176,8 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(q, k, v)
@@ -401,8 +394,8 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -447,8 +440,8 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -495,11 +488,13 @@ def flash_attention(
     return of.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def resolve_impl(impl: str) -> str:
+    """'auto' -> 'pallas' on TPU, 'xla' elsewhere, by the platform JAX
+    reports. A backend that fails to start raises here: answering 'xla'
+    instead would train on the einsum path and call it the flash step."""
+    if impl == "auto":
+        return "pallas" if jax.devices()[0].platform == "tpu" else "xla"
+    return impl
 
 
 def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
@@ -508,8 +503,7 @@ def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
 
     impl: 'auto' (pallas on TPU, XLA elsewhere) | 'pallas' | 'xla'.
     """
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "xla"
+    impl = resolve_impl(impl)
     if impl == "pallas":
         return flash_attention(q, k, v, causal=causal, scale=scale)
     B, T, H, D = q.shape

@@ -22,29 +22,6 @@ from typing import Optional
 _BIG_NEG = -1e30
 
 
-def _shard_map():
-    """jax.shard_map graduated from jax.experimental between minor releases;
-    resolve whichever this jax ships."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm
-
-
-def _pvary(x, axes):
-    """jax.lax.pvary only exists on jax versions with varying-axes type
-    checking; older releases don't track varying axes, so identity is
-    exactly equivalent there."""
-    import jax
-
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axes) if fn is not None else x
-
-
 def ring_attention(q, k, v, axis_name: str, axis_size: int, causal: bool = False,
                    scale: Optional[float] = None, pvary_axes=None):
     """Exact attention across a ring. Call inside shard_map.
@@ -71,9 +48,13 @@ def ring_attention(q, k, v, axis_name: str, axis_size: int, causal: bool = False
     # Mark the accumulators as varying over every manual mesh axis so the
     # scan carry type is stable under shard_map's varying-axes checks.
     axes = tuple(pvary_axes) if pvary_axes else (axis_name,)
-    o0 = _pvary(jnp.zeros((B, H, T, D), dtype=jnp.float32), axes)
-    m0 = _pvary(jnp.full((B, H, T), _BIG_NEG, dtype=jnp.float32), axes)
-    l0 = _pvary(jnp.zeros((B, H, T), dtype=jnp.float32), axes)
+
+    def varying(x):
+        return jax.lax.pcast(x, axes, to="varying")
+
+    o0 = varying(jnp.zeros((B, H, T, D), dtype=jnp.float32))
+    m0 = varying(jnp.full((B, H, T), _BIG_NEG, dtype=jnp.float32))
+    l0 = varying(jnp.zeros((B, H, T), dtype=jnp.float32))
 
     def step(carry, s):
         o, m, l, k_cur, v_cur = carry
@@ -112,6 +93,7 @@ def ring_attention_sharded(q, k, v, mesh, causal: bool = False,
                            head_axis: str = "tensor"):
     """Global-view wrapper: q/k/v are [B, T, H, D] jax.Arrays; sequence is
     sharded over `seq_axis`, heads optionally over `head_axis`."""
+    import jax
     from jax.sharding import PartitionSpec as P
 
     present = set(mesh.axis_names)
@@ -138,15 +120,10 @@ def ring_attention_sharded(q, k, v, mesh, causal: bool = False,
         causal=causal,
         pvary_axes=tuple(manual_axes),
     )
-    sm = _shard_map()
-    try:
-        mapped = sm(
-            fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False,
-        )
-    except TypeError:  # newer jax: check_rep retired with the pvary typing
-        mapped = sm(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    return mapped(q, k, v)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 def ring_attention_on_group(q, k, v, causal: bool = False,

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
-from ray_tpu.parallel.ring_attention import _shard_map, full_attention
+from ray_tpu.parallel.ring_attention import full_attention
 
 
 def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
@@ -51,6 +51,7 @@ def ulysses_attention_sharded(q, k, v, mesh, causal: bool = False,
                               batch_axes=("data", "fsdp")):
     import functools
 
+    import jax
     from jax.sharding import PartitionSpec as P
 
     present = set(mesh.axis_names)
@@ -59,12 +60,7 @@ def ulysses_attention_sharded(q, k, v, mesh, causal: bool = False,
     b_ax = tuple(a for a in batch_axes if a in present) or None
     spec = P(b_ax, seq_axis, None, None)
     fn = functools.partial(ulysses_attention, axis_name=seq_axis, causal=causal)
-    sm = _shard_map()
-    try:
-        mapped = sm(
-            fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False,
-        )
-    except TypeError:  # newer jax: check_rep retired
-        mapped = sm(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    return mapped(q, k, v)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)

@@ -109,6 +109,16 @@ class BackendExecutor:
         self.worker_group: Optional[WorkerGroup] = None
 
     def start(self) -> None:
+        tpus = self._scaling.total_resources.get("TPU")
+        if tpus and not ray_tpu.cluster_resources().get("TPU"):
+            # Not a TrainingFailedError: re-ganging cannot grow a chip, and
+            # the placement group would only wait out its 120 s horizon.
+            raise RuntimeError(
+                f"the train gang asks for TPU {tpus:g} but no node of "
+                "this cluster advertises a TPU: chip detection "
+                "(TPU_VISIBLE_CHIPS, /dev/accel*, /dev/vfio) found none and "
+                "init() was given none"
+            )
         self.worker_group = WorkerGroup(
             self._scaling.num_workers,
             self._scaling.as_placement_group_bundles(),

@@ -88,17 +88,6 @@ def _observe(op: str, group: str, nbytes: int, dt: float) -> None:
         )
 
 
-def _shard_map():
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm
-
-
 class MeshCollectives:
     """Compiled group ops over one mesh axis.
 
@@ -147,12 +136,12 @@ class MeshCollectives:
         from jax.sharding import PartitionSpec as P
 
         return jax.jit(
-            _shard_map()(
+            jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=P(self.axis),
                 out_specs=P(*out_parts),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -508,12 +497,12 @@ class MeshCollectives:
             )
             spec = P(None, self.axis, None, None)
             return jax.jit(
-                _shard_map()(
+                jax.shard_map(
                     fn,
                     mesh=self.mesh,
                     in_specs=(spec, spec, spec),
                     out_specs=spec,
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
 
@@ -550,12 +539,12 @@ class MeshCollectives:
             )
             spec = P(None, self.axis, None, None)
             return jax.jit(
-                _shard_map()(
+                jax.shard_map(
                     fn,
                     mesh=self.mesh,
                     in_specs=(spec, spec, spec),
                     out_specs=spec,
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
 

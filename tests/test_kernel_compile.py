@@ -1,0 +1,129 @@
+"""The main path's kernels, compiled for the chip without the chip.
+
+The TPU's compiler is installed with libtpu and compiles for a described
+v5e topology that is not attached. It raises what the chip's compiler would
+raise (tile alignment, fast-memory limits, a kernel that cannot be
+partitioned), which interpret mode cannot show. Nothing runs, so these tests
+say nothing about results or times. Shapes are the ones chip_smoke.py
+trains: GPT-2-small widths, batch 32, seq 1024, bf16.
+"""
+
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import TransformerConfig, make_train_step
+from ray_tpu.ops.fused import lm_head_cross_entropy
+from ray_tpu.parallel import make_mesh
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+
+BH, T, D = 32 * 12, 1024, 64
+KERNEL = dict(causal=True, scale=D ** -0.5, block_q=128, block_k=128,
+              interpret=False)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Four described v5e devices, with the compile cache off around the
+    tests: an entry compiled for a described device is written but cannot be
+    read back without a chip, and the next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _shapes(device):
+    one = SingleDeviceSharding(device)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    qkv = sd((BH, T, D), jnp.bfloat16)
+    row = sd((BH, T, 8), jnp.float32)  # lse / delta, sublane-replicated
+    return qkv, row
+
+
+def _flash_case(name):
+    if name == "fwd":
+        return lambda q, k, v, do, lse, delta: fa._flash_fwd(q, k, v, **KERNEL)
+    if name == "fwd_lse":
+        return lambda q, k, v, do, lse, delta: fa._flash_fwd(
+            q, k, v, with_lse=True, **KERNEL)
+    if name == "bwd_dq":
+        return lambda *a: fa._flash_bwd_dq(*a, **KERNEL)
+    return lambda *a: fa._flash_bwd_dkv(*a, **KERNEL)
+
+
+@pytest.mark.parametrize("name", ["fwd", "fwd_lse", "bwd_dq", "bwd_dkv"])
+def test_flash_kernel_compiles_for_v5e(v5e, name):
+    qkv, row = _shapes(v5e[0])
+    compiled = jax.jit(_flash_case(name)).lower(
+        qkv, qkv, qkv, qkv, row, row
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_lm_head_cross_entropy_compiles_for_v5e(v5e):
+    one = SingleDeviceSharding(v5e[0])
+    hidden = jax.ShapeDtypeStruct((32, 1024, 768), jnp.bfloat16, sharding=one)
+    unembed = jax.ShapeDtypeStruct((768, 50304), jnp.float32, sharding=one)
+    targets = jax.ShapeDtypeStruct((32, 1024), jnp.int32, sharding=one)
+
+    def loss_and_grads(h, w, t):
+        return jax.value_and_grad(
+            lambda h, w: lm_head_cross_entropy(h, w, t)[0], argnums=(0, 1)
+        )(h, w)
+
+    compiled = jax.jit(loss_and_grads).lower(hidden, unembed, targets).compile()
+    # The chunked CE exists so that [B*T, V] f32 logits (6.6 GB) never are.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_train_step_compiles_for_four_v5e(v5e):
+    """The whole train step at full width on the 2x2 mesh chip_smoke.py
+    --chips 4 uses (depth cut to 2: the layers are one scan, so depth changes
+    neither the program nor what the compiler may refuse). XLA cannot
+    partition the Mosaic kernel, so this is the test that the model maps it
+    over the batch axes itself, and that the state's layout is a fixed point
+    of the step."""
+    import optax
+
+    cfg = TransformerConfig(
+        vocab_size=50304, d_model=768, n_layers=2, n_heads=12,
+        max_seq_len=1024, dtype=jnp.bfloat16, remat=True,
+        attention_impl="pallas",  # 'auto' would ask the CPU backend
+    )
+    mesh = make_mesh({"data": 2, "fsdp": 2}, devices=v5e)
+    init_state, step, shardings = make_train_step(cfg, mesh, optax.adamw(1e-3))
+    # A described device cannot hold an array: hand the step shapes.
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(init_state, jax.random.PRNGKey(0)),
+        shardings["state"],
+    )
+    tokens = jax.ShapeDtypeStruct((32, 1024), jnp.int32,
+                                  sharding=shardings["tokens"])
+    compiled = step.lower(state, {"tokens": tokens, "targets": tokens}).compile()
+    # forward-with-lse, dq and dkv; plus the remat's forward again
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    out_state = compiled.output_shardings[0]
+    assert jax.tree.leaves(out_state) == jax.tree.leaves(shardings["state"])

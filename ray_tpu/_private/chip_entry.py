@@ -11,6 +11,15 @@ settles before it starts workers. This module imports nothing from JAX.
   key, so it is fixed — never made from `tempfile`, a pid or the clock — and
   a second run on the same machine finds what the first compiled.
 
+The cache's key leaves the program's metadata out (JAX's default), so an
+executable loaded from it carries the name stacks of whichever revision
+compiled it first: a profiler trace of a step whose instructions did not
+change shows that revision's `jax.named_scope`s, or none
+(docs/observability.md, "Device scopes"). Whoever profiles sets
+`JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY=1` for that run, and pays one
+compilation; it is not set here, because every moved source line would then
+compile every program again.
+
 **One process for each chip.** A process that has initialised a JAX backend
 holds the chip, and a worker that needs it then fails or hangs. The parent
 of a train worker therefore stays off JAX, and says so with

@@ -117,6 +117,10 @@ def resnet_init(rng, cfg: ResNetConfig) -> Dict[str, Any]:
     return params
 
 
+# The scopes (`conv`, `bn`, and `stem`, `stage1`..`stage4`, `head` in
+# resnet_apply) name the step's device work in a profiler trace
+# (docs/observability.md, "Device scopes"); they are metadata only.
+@jax.named_scope("conv")
 def _conv(x, w, stride=1, dtype=jnp.bfloat16):
     kh = w.shape[0]
     pad = kh // 2
@@ -128,6 +132,7 @@ def _conv(x, w, stride=1, dtype=jnp.bfloat16):
     )
 
 
+@jax.named_scope("bn")
 def _bn(x, bn, train: bool, momentum=0.9, eps=1e-5):
     """Returns (y, new_stats). In train mode uses batch stats (the psum over
     data axes happens automatically because XLA sees the full sharded batch
@@ -163,55 +168,71 @@ def resnet_apply(params, images, cfg: ResNetConfig, train: bool = False):
     """
     dt = cfg.dtype
     new_params = {k: v for k, v in params.items() if k != "blocks"}
-    if cfg.space_to_depth:
-        b, h, w, c = images.shape
-        x = images.reshape(b, h // 2, 2, w // 2, 2, c)
-        x = x.transpose(0, 1, 3, 2, 4, 5).reshape(b, h // 2, w // 2, 4 * c)
-        # 4x4/s1 with (1, 2) padding keeps the 7x7/s2 stem's output shape.
-        x = jax.lax.conv_general_dilated(
-            x.astype(dt),
-            params["stem_conv"].astype(dt),
-            window_strides=(1, 1),
-            padding=[(1, 2), (1, 2)],
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-    else:
-        x = _conv(images, params["stem_conv"], stride=2, dtype=dt)
-    x, new_params["stem_bn"] = _bn(x, params["stem_bn"], train)
-    x = jax.nn.relu(x)
-    x = jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-        [(0, 0), (1, 1), (1, 1), (0, 0)],
-    )
-    new_blocks = []
-    for blk, (stride, _, _, _) in zip(params["blocks"], block_layout(cfg)):
-        nblk: Dict[str, Any] = {}
-        shortcut = x
-        if "proj_conv" in blk:
-            shortcut = _conv(x, blk["proj_conv"], stride=stride, dtype=dt)
-            shortcut, nblk["proj_bn"] = _bn(shortcut, blk["proj_bn"], train)
-            nblk["proj_conv"] = blk["proj_conv"]
-        if cfg.bottleneck:
-            y = _conv(x, blk["conv1"], 1, dt)
-            y, nblk["bn1"] = _bn(y, blk["bn1"], train)
-            y = jax.nn.relu(y)
-            y = _conv(y, blk["conv2"], stride, dt)
-            y, nblk["bn2"] = _bn(y, blk["bn2"], train)
-            y = jax.nn.relu(y)
-            y = _conv(y, blk["conv3"], 1, dt)
-            y, nblk["bn3"] = _bn(y, blk["bn3"], train)
+    with jax.named_scope("stem"):
+        if cfg.space_to_depth:
+            b, h, w, c = images.shape
+            x = images.reshape(b, h // 2, 2, w // 2, 2, c)
+            x = x.transpose(0, 1, 3, 2, 4, 5).reshape(
+                b, h // 2, w // 2, 4 * c)
+            # 4x4/s1 with (1, 2) padding keeps the 7x7/s2 stem's output shape.
+            with jax.named_scope("conv"):
+                x = jax.lax.conv_general_dilated(
+                    x.astype(dt),
+                    params["stem_conv"].astype(dt),
+                    window_strides=(1, 1),
+                    padding=[(1, 2), (1, 2)],
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                )
         else:
-            y = _conv(x, blk["conv1"], stride, dt)
-            y, nblk["bn1"] = _bn(y, blk["bn1"], train)
-            y = jax.nn.relu(y)
-            y = _conv(y, blk["conv2"], 1, dt)
-            y, nblk["bn2"] = _bn(y, blk["bn2"], train)
-        for k in ("conv1", "conv2", "conv3"):
-            if k in blk:
-                nblk[k] = blk[k]
-        x = jax.nn.relu(y + shortcut)
+            x = _conv(images, params["stem_conv"], stride=2, dtype=dt)
+        x, new_params["stem_bn"] = _bn(x, params["stem_bn"], train)
+        x = jax.nn.relu(x)
+        x = jax.lax.reduce_window(
+            x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
+            [(0, 0), (1, 1), (1, 1), (0, 0)],
+        )
+    new_blocks = []
+    stage_of_block = [
+        f"stage{i + 1}" for i, n in enumerate(cfg.stages) for _ in range(n)
+    ]
+    for blk, (stride, _, _, _), stage in zip(
+        params["blocks"], block_layout(cfg), stage_of_block
+    ):
+        with jax.named_scope(stage):
+            x, nblk = _block(x, blk, stride, cfg, train)
         new_blocks.append(nblk)
     new_params["blocks"] = new_blocks
-    x = x.mean(axis=(1, 2)).astype(jnp.float32)  # global average pool
-    logits = x @ params["fc_w"] + params["fc_b"]
+    with jax.named_scope("head"):
+        x = x.mean(axis=(1, 2)).astype(jnp.float32)  # global average pool
+        logits = x @ params["fc_w"] + params["fc_b"]
     return logits, new_params
+
+
+def _block(x, blk, stride, cfg: ResNetConfig, train: bool):
+    """One residual block: (y, the block's params with new BN stats)."""
+    dt = cfg.dtype
+    nblk: Dict[str, Any] = {}
+    shortcut = x
+    if "proj_conv" in blk:
+        shortcut = _conv(x, blk["proj_conv"], stride=stride, dtype=dt)
+        shortcut, nblk["proj_bn"] = _bn(shortcut, blk["proj_bn"], train)
+        nblk["proj_conv"] = blk["proj_conv"]
+    if cfg.bottleneck:
+        y = _conv(x, blk["conv1"], 1, dt)
+        y, nblk["bn1"] = _bn(y, blk["bn1"], train)
+        y = jax.nn.relu(y)
+        y = _conv(y, blk["conv2"], stride, dt)
+        y, nblk["bn2"] = _bn(y, blk["bn2"], train)
+        y = jax.nn.relu(y)
+        y = _conv(y, blk["conv3"], 1, dt)
+        y, nblk["bn3"] = _bn(y, blk["bn3"], train)
+    else:
+        y = _conv(x, blk["conv1"], stride, dt)
+        y, nblk["bn1"] = _bn(y, blk["bn1"], train)
+        y = jax.nn.relu(y)
+        y = _conv(y, blk["conv2"], 1, dt)
+        y, nblk["bn2"] = _bn(y, blk["bn2"], train)
+    for k in ("conv1", "conv2", "conv3"):
+        if k in blk:
+            nblk[k] = blk[k]
+    return jax.nn.relu(y + shortcut), nblk

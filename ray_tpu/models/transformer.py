@@ -189,19 +189,25 @@ def _block(x, blk, positions, cfg: TransformerConfig,
     h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     dt = cfg.dtype
 
-    y = fused_rmsnorm(x, blk["attn_norm"], eps=cfg.norm_eps)
-    q = (y @ blk["wq"].astype(dt)).reshape(B, T, h, dh)
-    k = (y @ blk["wk"].astype(dt)).reshape(B, T, hk, dh)
-    v = (y @ blk["wv"].astype(dt)).reshape(B, T, hk, dh)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh)
-    x = x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
+    # The scopes name the step's device work in a profiler trace
+    # (docs/observability.md, "Device scopes"); they are metadata only.
+    with jax.named_scope("attn_qkv"):
+        y = fused_rmsnorm(x, blk["attn_norm"], eps=cfg.norm_eps)
+        q = (y @ blk["wq"].astype(dt)).reshape(B, T, h, dh)
+        k = (y @ blk["wk"].astype(dt)).reshape(B, T, hk, dh)
+        v = (y @ blk["wv"].astype(dt)).reshape(B, T, hk, dh)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh)
+    with jax.named_scope("attn_out"):
+        x = x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
 
-    y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
-    gate = jax.nn.silu(y @ blk["w_gate"].astype(dt))
-    up = y @ blk["w_up"].astype(dt)
-    x = x + (gate * up) @ blk["w_down"].astype(dt)
+    with jax.named_scope("mlp"):
+        y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
+        gate = jax.nn.silu(y @ blk["w_gate"].astype(dt))
+        up = y @ blk["w_up"].astype(dt)
+        x = x + (gate * up) @ blk["w_down"].astype(dt)
     return x
 
 
@@ -218,7 +224,8 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig,
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
 
     blk_fn = partial(
         _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size, mesh=mesh
@@ -230,7 +237,8 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig,
         return blk_fn(x, blk, positions), None
 
     x, _ = jax.lax.scan(scan_body, x, params["blocks"])
-    return fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
+    with jax.named_scope("final_norm"):
+        return fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
 
 
 def _unembed(params, cfg: TransformerConfig):
@@ -259,7 +267,9 @@ def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
     else:
         tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     hidden = transformer_hidden(params, tokens, cfg, **kw)
-    loss, _ = lm_head_cross_entropy(hidden, _unembed(params, cfg), targets)
+    with jax.named_scope("lm_head_ce"):
+        loss, _ = lm_head_cross_entropy(
+            hidden, _unembed(params, cfg), targets)
     return loss
 
 
@@ -319,11 +329,12 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
     @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))
     def step(state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch)
-        updates, opt = optimizer.update(
-            grads, state["opt"], state["params"]
-        )
-        params = optax.apply_updates(state["params"], updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt = optimizer.update(
+                grads, state["opt"], state["params"]
+            )
+            params = optax.apply_updates(state["params"], updates)
+            gnorm = optax.global_norm(grads)
         return (
             {"params": params, "opt": opt, "step": state["step"] + 1},
             {"loss": loss, "grad_norm": gnorm},
@@ -372,7 +383,14 @@ def hardware_flops_per_token(
       the backward: +1 block fwd per layer.
 
     hardware-MFU = hardware_flops_per_token * tokens/s / peak must come out
-    below 1.0 — the sanity bound useful-MFU alone cannot provide.
+    below 1.0 — the sanity bound useful-MFU alone cannot provide. It is an
+    analytic figure: it counts what the step asks for, not what the chip
+    does, and a value near 1 says nothing about any kernel (the 0.97 once
+    quoted for the GPT-2-width step stood beside a flash kernel far from its
+    roofline). What is measured on the chip: `model_mfu.tokens`, and per
+    kernel `flash_fwd_roofline.tokens`, `flash_bwd_dq_roofline.tokens`,
+    `flash_bwd_dkv_roofline.tokens`, with `recompute_time_share.tokens` for
+    what remat's second forward costs (BENCHMARK.json, PERF.md section 3).
     """
     if remat is None:
         remat = cfg.remat

@@ -11,6 +11,11 @@ interpret mode on tiny shapes via `flash_attention(..., interpret=True)`).
 
 Backward pass uses recompute (custom_vjp re-derives the tile softmax),
 trading FLOPs for the O(T^2) memory XLA would otherwise materialize.
+
+The three `pallas_call`s are named `flash_fwd` (with or without the lse
+output), `flash_bwd_dq` and `flash_bwd_dkv`: the names a profiler trace and
+the compiled HLO show, and the ones the benchmark's per-kernel roofline
+metrics read (docs/observability.md, "Device scopes").
 """
 
 from __future__ import annotations
@@ -180,6 +185,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -398,6 +404,7 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
 
@@ -444,6 +451,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
 

@@ -1,0 +1,134 @@
+"""The names the program gives its device work: every `jax.named_scope` of
+the two model families and the three kernel names stand in the lowered
+step's name stacks, and every name a benchmark metric matches is one of
+them, so that a rename in the program fails here and not in a metric. CPU
+only: the steps are tiny and the kernels run in interpret mode."""
+
+import glob
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import TransformerConfig, make_train_step
+from ray_tpu.models.resnet import ResNetConfig, resnet_apply, resnet_init
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.parallel import make_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+TRANSFORMER_SCOPES = {"embed", "attn_qkv", "attention", "attn_out", "mlp",
+                      "final_norm", "lm_head_ce", "optimizer"}
+RESNET_SCOPES = {"stem", "stage1", "stage2", "stage3", "stage4", "head",
+                 "conv", "bn"}
+KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+
+
+def name_stacks(lowered):
+    """Every name stack of the lowered module's locations."""
+    return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+
+
+def components(stacks):
+    return {part for stack in stacks for part in re.split(r"[/()]", stack)}
+
+
+def lowered_transformer_step():
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=64, max_seq_len=16, remat=True, attention_impl="xla",
+        tied_embeddings=False)
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    init_state, step, _ = make_train_step(cfg, mesh)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    return step.lower(state, {"tokens": tokens, "targets": tokens})
+
+
+def lowered_resnet_step():
+    cfg = ResNetConfig(depth=18, num_classes=10, width=8)
+    params = jax.eval_shape(lambda: resnet_init(jax.random.PRNGKey(0), cfg))
+
+    def loss(params, images):
+        logits, new = resnet_apply(params, images, cfg, train=True)
+        return logits.sum(), new
+
+    images = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32)
+    return jax.jit(jax.grad(loss, has_aux=True)).lower(params, images)
+
+
+def lowered_flash_kernels():
+    q = jax.ShapeDtypeStruct((1, 128, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+
+
+FAMILIES = {
+    "transformer": (lowered_transformer_step, TRANSFORMER_SCOPES),
+    "resnet": (lowered_resnet_step, RESNET_SCOPES),
+}
+
+
+@pytest.fixture(scope="module")
+def stacks():
+    return {name: name_stacks(lower()) for name, (lower, _) in FAMILIES.items()}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_lowered_step_holds_every_scope(stacks, family):
+    want = FAMILIES[family][1]
+    assert want <= components(stacks[family])
+    # forward, backward and recompute need no scope of the program: JAX's
+    # own name stack wraps a scope's operations
+    assert any("transpose(jvp(" in s for s in stacks[family])
+    if family == "transformer":
+        assert any("rematted_computation" in s and "mlp" in s
+                   for s in stacks[family])
+        # the whole of _attention lies under its scope, wrappers included
+        assert any(re.search(r"attention/.*transpose", s) for s in stacks[family])
+    else:
+        assert any(re.search(r"stage2\)*/bn/", s) for s in stacks[family])
+        assert any(re.search(r"stem\)*/conv/conv_general_dilated", s)
+                   for s in stacks[family])
+
+
+def test_the_kernels_carry_their_names_in_interpret_mode():
+    found = components(name_stacks(lowered_flash_kernels()))
+    assert KERNELS <= found
+
+
+def test_resnet_stem_space_to_depth_has_its_conv_scope():
+    cfg = ResNetConfig(depth=18, num_classes=10, width=8, space_to_depth=True)
+    params = jax.eval_shape(lambda: resnet_init(jax.random.PRNGKey(0), cfg))
+    images = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32)
+    lowered = jax.jit(lambda p, x: resnet_apply(p, x, cfg)[0]).lower(params, images)
+    assert any(s.endswith("stem/conv/conv_general_dilated")
+               for s in name_stacks(lowered))
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
+    from chipbench import scopes
+
+    program = TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS
+    assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
+    assert set(scopes.KERNELS) == KERNELS
+    suffix = ".tokens.json" if family == "transformer" else ".images.json"
+    in_family = components(stacks[family]) | KERNELS
+    named = 0
+    for path in glob.glob(os.path.join(ROOT, "chipbench", "metrics", "*.json")):
+        with open(path) as f:
+            params = json.load(f).get("params", {})
+        for key in ("scope", "kernel"):
+            if key in params:
+                assert params[key] in program, path
+                if path.endswith(suffix):
+                    assert params[key] in in_family, path
+                    named += 1
+    assert named >= 1

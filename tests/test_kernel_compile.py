@@ -127,3 +127,23 @@ def test_train_step_compiles_for_four_v5e(v5e):
     assert compiled.as_text().count("tpu_custom_call") >= 3
     out_state = compiled.output_shardings[0]
     assert jax.tree.leaves(out_state) == jax.tree.leaves(shardings["state"])
+
+
+@pytest.mark.parametrize("case,kernel", [
+    ("fwd", "flash_fwd"), ("fwd_lse", "flash_fwd"),
+    ("bwd_dq", "flash_bwd_dq"), ("bwd_dkv", "flash_bwd_dkv"),
+])
+def test_flash_kernel_is_named_in_the_text_compiled_for_v5e(v5e, case, kernel):
+    """The chip's compiler names a Mosaic custom call after the
+    `pallas_call`'s `name=`: that instruction name is what a profiler trace
+    shows and what the benchmark's per-kernel metrics match."""
+    import re
+
+    qkv, row = _shapes(v5e[0])
+    text = jax.jit(_flash_case(case)).lower(
+        qkv, qkv, qkv, qkv, row, row
+    ).compile().as_text()
+    call = re.search(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert re.fullmatch(re.escape(kernel) + r"(\.\d+)?", call.group(1))
+    assert f"/{kernel}/pallas_call" in call.group(0)  # and its name stack

@@ -3,44 +3,258 @@
 Online-softmax attention tiled for the MXU: the grid walks (batch*heads,
 q-block, k-block) with the k dimension innermost; running max/denominator and
 the output accumulator live in VMEM scratch that persists across the k steps
-and is flushed on the last one. f32 accumulation, bf16-friendly inputs.
+and is flushed on the last one.
 
 Dispatch: `mha(impl="auto")` picks this kernel when JAX reports a TPU and an
 XLA einsum implementation on any other platform (tests run the kernel in
 interpret mode on tiny shapes via `flash_attention(..., interpret=True)`).
 
-Backward pass uses recompute (custom_vjp re-derives the tile softmax),
-trading FLOPs for the O(T^2) memory XLA would otherwise materialize.
+Backward pass uses recompute (custom_vjp re-derives the tile softmax from
+q, k and the saved lse), trading FLOPs for the O(T^2) memory XLA would
+otherwise materialize.
 
 The three `pallas_call`s are named `flash_fwd` (with or without the lse
 output), `flash_bwd_dq` and `flash_bwd_dkv`: the names a profiler trace and
 the compiled HLO show, and the ones the benchmark's per-kernel roofline
 metrics read (docs/observability.md, "Device scopes").
+
+The tile program, the same in all three kernels:
+
+- Tiles come from the shape. `flash_tiles(kernel, T, S, D, dtype)` returns
+  `block_q` and `block_k` for one kernel: multiples of 128 that do not exceed
+  the sequence (the sequence itself when it is shorter than 128), chosen to
+  make the sum of two costs least: what every grid step costs whatever it
+  holds, and the work of the tiles that have a body, of which the part above
+  the causal diagonal is wasted. It also returns the estimate of the VMEM the
+  tile needs and the `vmem_limit_bytes` handed to Mosaic (the default 16 MiB
+  where that is enough), the grid steps a (batch, head) row makes and the
+  share of them that have a body. `flash_attention(block_q=, block_k=)`
+  force a tile, for tests; `None` is the shape's choice.
+- Operands reach the MXU in the input's dtype. q, k, v and do go to
+  `dot_general` as loaded, p and ds are cast to that dtype for the second
+  matmuls, and every dot accumulates in f32. m, l, lse, delta, the
+  accumulators and every exp are f32. An f32 input is fed as f32: the kernel
+  never rounds below what it was given.
+- A tile is masked only where the mask decides something: where the causal
+  diagonal crosses it, or where it hangs over the end of a sequence (there
+  the padded rows, which may hold NaN, are also zeroed before they reach a
+  dot). A tile wholly under the diagonal and wholly inside the sequences
+  takes a body with no iota, compare or select. A tile wholly above the
+  diagonal has no body, and its grid step fetches nothing: the index maps
+  clamp the walked index to the nearest tile of that row (column) that has
+  one, so the step names the block already in VMEM.
+- Outputs leave in the input's dtype: o, and dq, dk, dv, which the flush
+  rounds once from the f32 accumulator. lse and delta are f32 `[BH, T, 8]`.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 _BIG_NEG = -1e30
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _dot(a, b, dims):
+    """One MXU matmul: the operands as they are, accumulated in f32.
+    Operands narrower than f32 take a single pass whatever
+    `jax_default_matmul_precision` says: their products are exact in f32,
+    and Mosaic refuses "highest" for them. f32 operands follow the
+    config."""
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(
+        a, b, dims, precision=precision, preferred_element_type=jnp.float32
+    )
+
+
+# ------------------------------------------------------------------- tiles
+
+_LANES = 128
+_DEFAULT_VMEM = 16 << 20  # Mosaic's scoped limit when none is given
+_MAX_VMEM = 96 << 20  # of the v5e's 128 MiB
+_MAX_BLOCK = 1024
+# [bq, bk] f32 tiles a body holds at once (s/p, and dp, ds and a transposed
+# copy in the backward), and [bq, bk] copies in the input's dtype (p; p, ds).
+_LIVE_TILES = {"flash_fwd": (2, 1), "flash_bwd_dq": (4, 1),
+               "flash_bwd_dkv": (4, 2)}
+
+
+class FlashTiles(NamedTuple):
+    """One kernel's tile for one shape, and what follows from it."""
+    block_q: int
+    block_k: int
+    grid_steps: int  # of one (batch, head) row: q tiles x k tiles
+    active_share: float  # share of those steps whose tile has a body
+    vmem_bytes: int  # estimate of what the tile needs
+    vmem_limit_bytes: int  # what Mosaic is told it may use
+
+
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _active_tiles(T, S, block_q, block_k, causal) -> int:
+    """Tiles with a body: all of them, or under causal those that hold a
+    (query, key) pair with key <= query."""
+    num_q, num_k = _cdiv(T, block_q), _cdiv(S, block_k)
+    if not causal:
+        return num_q * num_k
+    return sum(
+        min(num_k, _cdiv((qi + 1) * block_q, block_k)) for qi in range(num_q)
+    )
+
+
+def _vmem_bytes(kernel, block_q, block_k, D, itemsize) -> int:
+    """Blocks in flight (double-buffered), scratch and the body's live
+    [bq, bk] tiles. A VMEM row is 128 lanes wide whatever D is."""
+    width = _cdiv(D, _LANES) * _LANES
+    q_like, k_like = block_q * width, block_k * width  # elements
+    row = block_q * _LANES * 4  # an lse/delta block, or m or l
+    if kernel == "flash_fwd":
+        blocks = (2 * q_like + 2 * k_like) * itemsize + row
+        scratch = q_like * 4 + 2 * row
+    elif kernel == "flash_bwd_dq":
+        blocks = (3 * q_like + 2 * k_like) * itemsize + 2 * row
+        scratch = q_like * 4
+    else:
+        blocks = (2 * q_like + 4 * k_like) * itemsize + 2 * row
+        scratch = 2 * k_like * 4
+    f32_tiles, dtype_tiles = _LIVE_TILES[kernel]
+    live = block_q * block_k * (4 * f32_tiles + itemsize * dtype_tiles)
+    return 2 * blocks + scratch + live
+
+
+def _block_candidates(seq: int):
+    if seq < _LANES:
+        return [seq]
+    return list(range(_LANES, min(seq, _MAX_BLOCK) + 1, _LANES))
+
+
+# What a tile costs, in microseconds, as the sweep on one v5e found it
+# ({128, 256, 512, 1024}^2 at BH 128, T 4096, D 128 and at BH 384, T 1024,
+# D 64, bf16, causal; PERF.md section 6, PR 26): a grid step whatever it
+# holds; 1,024 query rows of a tile that has a body (the per-row work that
+# does not grow with the tile's width: cross-lane max and sum, the rescale
+# of m, l and the accumulator); 2**20 (query, key) pairs of such a tile
+# (the matmuls, and the exp and its neighbours over [bq, bk]).
+_COST_US = {
+    "flash_fwd": (0.20, 2.75, 2.25),
+    "flash_bwd_dq": (0.29, 0.0, 5.8),
+    "flash_bwd_dkv": (0.29, 0.7, 5.35),
+}
+
+
+def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
+                causal: bool = True, block_q: Optional[int] = None,
+                block_k: Optional[int] = None) -> FlashTiles:
+    """The tile of `kernel` (`flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv`)
+    for q of [*, T, D] and k, v of [*, S, D] in `dtype`. Pure: the shape
+    decides, nothing is asked of a device. Among the tiles that fit VMEM it
+    takes the one whose grid costs least by `_COST_US`: small tiles pay in
+    grid steps, large ones in pairs above the causal diagonal that a
+    diagonal tile computes and masks. A forced `block_q` or `block_k` is
+    taken as given (cut to the sequence) and the other is chosen."""
+    itemsize = jnp.dtype(dtype).itemsize
+    step_us, rows_us, pairs_us = _COST_US[kernel]
+
+    def plan(bq, bk):
+        steps = _cdiv(T, bq) * _cdiv(S, bk)
+        active = _active_tiles(T, S, bq, bk, causal)
+        vmem = _vmem_bytes(kernel, bq, bk, D, itemsize)
+        cost = steps * step_us + active * (
+            rows_us * bq / 1024 + pairs_us * bq * bk / 2 ** 20)
+        return cost, FlashTiles(
+            bq, bk, steps, active / steps, vmem, max(_DEFAULT_VMEM, 2 * vmem))
+
+    qs = [min(block_q, T)] if block_q else _block_candidates(T)
+    ks = [min(block_k, S)] if block_k else _block_candidates(S)
+    plans = [plan(bq, bk) for bq in qs for bk in ks]
+    fitting = [p for p in plans if p[1].vmem_limit_bytes <= _MAX_VMEM]
+    return min(fitting or plans[:1], key=lambda p: p[0])[1]
+
+
+# ----------------------------------------------------------------- kernels
+
+def _tile_kind(qi, ki, *, block_q, block_k, num_q, num_k, causal,
+               seq_q, seq_k):
+    """(has_body, needs_mask) of grid tile (qi, ki): Python bools where the
+    shape settles it, traced scalars where the grid position does.
+    `seq_q=None` says padded q rows need no care (the forward: a row's
+    output depends on that row alone, and padded rows are never written)."""
+    has_body, needs_mask = True, False
+    if causal:
+        # some key of the tile is at or before some query of it
+        has_body = (qi + 1) * block_q > ki * block_k
+        # and some key of it is after some query of it
+        needs_mask = (ki + 1) * block_k - 1 > qi * block_q
+    if seq_k % block_k:
+        needs_mask = jnp.logical_or(needs_mask, ki == num_k - 1)
+    if seq_q is not None and seq_q % block_q:
+        needs_mask = jnp.logical_or(needs_mask, qi == num_q - 1)
+    return has_body, needs_mask
+
+
+def _run_tile(body, has_body, needs_mask):
+    """Run `body(masked)` for this grid step: not at all, masked or bare."""
+    from jax.experimental import pallas as pl
+
+    if needs_mask is False:
+        if has_body is True:
+            body(False)
+        else:
+            pl.when(has_body)(lambda: body(False))
+        return
+    pl.when(jnp.logical_and(has_body, needs_mask))(lambda: body(True))
+    pl.when(jnp.logical_and(has_body, jnp.logical_not(needs_mask)))(
+        lambda: body(False))
+
+
+def _tile_mask(qi, ki, *, block_q, block_k, causal, seq_q, seq_k):
+    """[bq, bk] bool: the pair is inside both sequences and, under causal,
+    the key is not after the query. Only the terms the shape leaves open."""
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0
+    )
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1
+    )
+    terms = []
+    if seq_k % block_k:
+        terms.append(k_pos < seq_k)  # padding keys past the true length
+    if seq_q is not None and seq_q % block_q:
+        terms.append(q_pos < seq_q)
+    if causal:
+        terms.append(q_pos >= k_pos)
+    return functools.reduce(jnp.logical_and, terms)
+
+
+def _rows_valid(i, block, seq):
+    """[block, 1] bool: which rows of tile `i` lie inside the sequence."""
+    row = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+    return row < seq
 
 
 def _attn_fwd_kernel(
     q_ref, k_ref, v_ref,  # inputs
     o_ref,  # output
     acc_ref, m_ref, l_ref,  # VMEM scratch, persistent over the k grid dim
-    *, block_q: int, block_k: int, num_k: int, scale: float, causal: bool,
-    seq_q: int, seq_k: int,
+    *, block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
+    causal: bool, seq_k: int,
 ):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    # seq_q=None: padded q rows need no care here (see _tile_kind)
+    shape = dict(block_q=block_q, block_k=block_k, causal=causal,
+                 seq_q=None, seq_k=seq_k)
 
     @pl.when(ki == 0)
     def _init():
@@ -48,24 +262,14 @@ def _attn_fwd_kernel(
         m_ref[...] = jnp.full_like(m_ref, _BIG_NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)  # [bq, D]
-        k = k_ref[0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0].astype(jnp.float32)  # [bk, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [bq, bk]
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = k_pos < seq_k  # padding keys past the true length
-        if causal:
-            mask &= q_pos >= k_pos
-        s = jnp.where(mask, s, _BIG_NEG)
+    def _body(masked):
+        q = q_ref[0]  # [bq, D]
+        k = k_ref[0]  # [bk, D]
+        v = v_ref[0]  # [bk, D]
+        s = _dot(q, k, _NT) * scale  # [bq, bk]
+        if masked:
+            mask = _tile_mask(qi, ki, **shape)
+            s = jnp.where(mask, s, _BIG_NEG)
 
         m_prev = m_ref[...]  # [bq, 128] (lane-replicated)
         l_prev = l_ref[...]
@@ -73,30 +277,20 @@ def _attn_fwd_kernel(
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
         alpha = jnp.exp(m_prev - m_new)  # [bq, 128]
         p = jnp.exp(s - m_new[:, :1])  # [bq, bk]
-        p = jnp.where(mask, p, 0.0)
+        if masked:
+            p = jnp.where(mask, p, 0.0)
         l_ref[...] = l_prev * alpha + jnp.broadcast_to(
             p.sum(axis=-1, keepdims=True), l_prev.shape
         )
         m_ref[...] = m_new
-        if seq_k % block_k:
+        if masked and seq_k % block_k:
             # Padded K/V rows may be NaN-filled; p is 0 there but 0*NaN=NaN.
-            krow = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, 1), 0
-            )
-            v = jnp.where(krow < seq_k, v, 0.0)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, D]
+            v = jnp.where(_rows_valid(ki, block_k, seq_k), v, 0.0)
+        pv = _dot(p.astype(v.dtype), v, _NN)  # [bq, D]
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
 
-    if causal:
-        # Blocks strictly above the diagonal contribute nothing; skip them.
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _body()
-    else:
-        _body()
+    # Tiles strictly above the diagonal contribute nothing and have no body.
+    _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
     @pl.when(ki == num_k - 1)
     def _flush():
@@ -108,8 +302,7 @@ def _attn_fwd_kernel_lse(
     q_ref, k_ref, v_ref,
     o_ref, lse_ref,
     acc_ref, m_ref, l_ref,
-    *, block_q: int, block_k: int, num_k: int, scale: float, causal: bool,
-    seq_q: int, seq_k: int,
+    *, num_k: int, **tile,
 ):
     """Forward that additionally writes LSE = m + log(l) per q row — the
     residual the tiled backward needs to re-derive tile softmax without
@@ -118,8 +311,7 @@ def _attn_fwd_kernel_lse(
 
     _attn_fwd_kernel(
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-        block_q=block_q, block_k=block_k, num_k=num_k, scale=scale,
-        causal=causal, seq_q=seq_q, seq_k=seq_k,
+        num_k=num_k, **tile,
     )
     ki = pl.program_id(2)
 
@@ -133,6 +325,51 @@ def _attn_fwd_kernel_lse(
         lse_ref[0] = lse[:, :8]
 
 
+def _last_k_with_body(qi, block_q, block_k):
+    """Index of the last k tile that has a body in q row `qi` (causal)."""
+    return jax.lax.div((qi + 1) * block_q - 1, block_k)
+
+
+def _first_q_with_body(ki, block_q, block_k, num_q):
+    """Index of the first q tile that has a body in k column `ki` (causal),
+    kept inside the array for a column that has none."""
+    return jnp.minimum(jax.lax.div(ki * block_k, block_q), num_q - 1)
+
+
+def _compiler_params(tiles: FlashTiles):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=tiles.vmem_limit_bytes,
+    )
+
+
+def _grid(kernel, q, k, causal, block_q, block_k):
+    """(tiles, q tiles, k tiles) of `kernel` for q of [BH, T, D] and k of
+    [BH, S, D]; `block_q`, `block_k` force a tile or are None."""
+    T, S = q.shape[1], k.shape[1]
+    tiles = flash_tiles(kernel, T, S, q.shape[2], q.dtype, causal=causal,
+                        block_q=block_q, block_k=block_k)
+    return tiles, _cdiv(T, tiles.block_q), _cdiv(S, tiles.block_k)
+
+
+def _q_block(bh, qi, ki):
+    return (bh, qi, 0)
+
+
+def _k_block_under_q(causal, block_q, block_k):
+    """Index map of K and V where k is walked innermost (forward, dq): under
+    causal a step past the row's last tile with a body names that tile, the
+    block already in VMEM, and fetches nothing."""
+    def k_block(bh, qi, ki):
+        if causal:
+            ki = jnp.minimum(ki, _last_k_with_body(qi, block_q, block_k))
+        return (bh, ki, 0)
+
+    return k_block
+
+
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
                with_lse: bool = False):
     from jax.experimental import pallas as pl
@@ -140,39 +377,36 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
 
     BH, T, D = q.shape
     S = k.shape[1]
-    block_q = min(block_q, T)
-    block_k = min(block_k, S)
-    num_q = pl.cdiv(T, block_q)
-    num_k = pl.cdiv(S, block_k)
+    tiles, num_q, num_k = _grid("flash_fwd", q, k, causal, block_q, block_k)
+    block_q, block_k = tiles.block_q, tiles.block_k
 
     kernel = functools.partial(
         _attn_fwd_kernel_lse if with_lse else _attn_fwd_kernel,
         block_q=block_q,
         block_k=block_k,
+        num_q=num_q,
         num_k=num_k,
         scale=scale,
         causal=causal,
-        seq_q=T,
         seq_k=S,
     )
+
+    k_block = _k_block_under_q(causal, block_q, block_k)
     out_shape = jax.ShapeDtypeStruct((BH, T, D), q.dtype)
-    out_specs = pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0))
+    out_specs = pl.BlockSpec((1, block_q, D), _q_block)
     if with_lse:
         out_shape = [
             out_shape,
             jax.ShapeDtypeStruct((BH, T, 8), jnp.float32),
         ]
-        out_specs = [
-            out_specs,
-            pl.BlockSpec((1, block_q, 8), lambda bh, qi, ki: (bh, qi, 0)),
-        ]
+        out_specs = [out_specs, pl.BlockSpec((1, block_q, 8), _q_block)]
     return pl.pallas_call(
         kernel,
         grid=(BH, num_q, num_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_q, D), _q_block),
+            pl.BlockSpec((1, block_k, D), k_block),
+            pl.BlockSpec((1, block_k, D), k_block),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -181,9 +415,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(tiles),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
@@ -226,98 +458,78 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, do):
         q, k, v, do, lse, delta, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return dq, dk, dv
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
-              block_q, block_k, scale, causal, seq_q, seq_k):
-    """Shared per-tile computation of both backward kernels: load + sanitize
-    padded rows + re-derive the softmax tile. Returns (q, k, v, do, p, ds).
+              masked, block_q, block_k, scale, causal, seq_q, seq_k):
+    """Shared per-tile computation of both backward kernels: load, sanitize
+    padded rows (masked tiles only: no other tile has any), re-derive the
+    softmax tile. Returns (q, k, do, p, ds), p and ds in the input's dtype.
 
     Sanitizing at load matters: pallas pads partial blocks with arbitrary
     (possibly NaN) data, and a NaN anywhere in a dot input poisons the whole
     contraction even where the weight is 0."""
-    q = q_ref[0].astype(jnp.float32)  # [bq, D]
-    k = k_ref[0].astype(jnp.float32)  # [bk, D]
-    v = v_ref[0].astype(jnp.float32)  # [bk, D]
-    do = do_ref[0].astype(jnp.float32)  # [bq, D]
+    q = q_ref[0]  # [bq, D]
+    k = k_ref[0]  # [bk, D]
+    v = v_ref[0]  # [bk, D]
+    do = do_ref[0]  # [bq, D]
     lse = lse_ref[0][:, :1]  # [bq, 1] (lane-replicated input)
     delta = delta_ref[0][:, :1]  # [bq, 1]
-    if seq_q % block_q:
-        qrow = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0
-        )
-        qvalid = qrow < seq_q
+    if masked and seq_q % block_q:
+        qvalid = _rows_valid(qi, block_q, seq_q)
         q = jnp.where(qvalid, q, 0.0)
         do = jnp.where(qvalid, do, 0.0)
         lse = jnp.where(qvalid, lse, 0.0)
         delta = jnp.where(qvalid, delta, 0.0)
-    if seq_k % block_k:
-        krow = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0
-        )
-        kvalid = krow < seq_k
+    if masked and seq_k % block_k:
+        kvalid = _rows_valid(ki, block_k, seq_k)
         k = jnp.where(kvalid, k, 0.0)
         v = jnp.where(kvalid, v, 0.0)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [bq, bk]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    mask = (k_pos < seq_k) & (q_pos < seq_q)
-    if causal:
-        mask &= q_pos >= k_pos
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # [bq, bk]
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [bq, bk]
-    # Explicit where: p=0 times a NaN dp entry would still poison the dot.
-    ds = jnp.where(mask, p * (dp - delta) * scale, 0.0)  # [bq, bk]
-    return q, k, v, do, p, ds
+    s = _dot(q, k, _NT) * scale  # [bq, bk]
+    p = jnp.exp(s - lse)  # [bq, bk]
+    dp = _dot(do, v, _NT)  # [bq, bk]
+    ds = p * (dp - delta) * scale  # [bq, bk]
+    if masked:
+        mask = _tile_mask(
+            qi, ki, block_q=block_q, block_k=block_k, causal=causal,
+            seq_q=seq_q, seq_k=seq_k,
+        )
+        p = jnp.where(mask, p, 0.0)
+        # Explicit where: p=0 times a NaN dp entry would still poison the dot.
+        ds = jnp.where(mask, ds, 0.0)
+    return q, k, do, p.astype(q.dtype), ds.astype(q.dtype)
 
 
 def _attn_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref,
     acc_ref,
-    *, block_q: int, block_k: int, num_k: int, scale: float, causal: bool,
-    seq_q: int, seq_k: int,
+    *, block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
+    causal: bool, seq_q: int, seq_k: int,
 ):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    shape = dict(block_q=block_q, block_k=block_k, causal=causal,
+                 seq_q=seq_q, seq_k=seq_k)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _body():
-        _, k, _, _, _, ds = _bwd_tile(
+    def _body(masked):
+        _, k, _, _, ds = _bwd_tile(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-            block_q=block_q, block_k=block_k, scale=scale, causal=causal,
-            seq_q=seq_q, seq_k=seq_k,
+            masked=masked, scale=scale, **shape,
         )
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, D]
+        acc_ref[...] += _dot(ds, k, _NN)  # [bq, D]
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _body()
-    else:
-        _body()
+    _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
     @pl.when(ki == num_k - 1)
     def _flush():
@@ -328,41 +540,31 @@ def _attn_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref,
-    *, block_q: int, block_k: int, num_q: int, scale: float, causal: bool,
-    seq_q: int, seq_k: int,
+    *, block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
+    causal: bool, seq_q: int, seq_k: int,
 ):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
     qi = pl.program_id(2)
+    shape = dict(block_q=block_q, block_k=block_k, causal=causal,
+                 seq_q=seq_q, seq_k=seq_k)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    def _body():
-        q, _, _, do, p, ds = _bwd_tile(
+    def _body(masked):
+        q, _, do, p, ds = _bwd_tile(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-            block_q=block_q, block_k=block_k, scale=scale, causal=causal,
-            seq_q=seq_q, seq_k=seq_k,
+            masked=masked, scale=scale, **shape,
         )
-        dv_acc_ref[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bk, D]
-        dk_acc_ref[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bk, D]
+        dv_acc_ref[...] += _dot(p, do, _TN)  # [bk, D]
+        dk_acc_ref[...] += _dot(ds, q, _TN)  # [bk, D]
 
-    if causal:
-        # Only q blocks at/below the diagonal see this k block.
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _body()
-    else:
-        _body()
+    # Only q tiles at/below the diagonal see this k tile.
+    _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
     @pl.when(qi == num_q - 1)
     def _flush():
@@ -377,32 +579,30 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
 
     BH, T, D = q.shape
     S = k.shape[1]
-    block_q = min(block_q, T)
-    block_k = min(block_k, S)
-    num_q = pl.cdiv(T, block_q)
-    num_k = pl.cdiv(S, block_k)
+    tiles, num_q, num_k = _grid("flash_bwd_dq", q, k, causal, block_q, block_k)
+    block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dq_kernel,
-        block_q=block_q, block_k=block_k, num_k=num_k, scale=scale,
-        causal=causal, seq_q=T, seq_k=S,
+        block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
+        scale=scale, causal=causal, seq_q=T, seq_k=S,
     )
+
+    k_block = _k_block_under_q(causal, block_q, block_k)
     return pl.pallas_call(
         kernel,
         grid=(BH, num_q, num_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, D), _q_block),
+            pl.BlockSpec((1, block_k, D), k_block),
+            pl.BlockSpec((1, block_k, D), k_block),
+            pl.BlockSpec((1, block_q, D), _q_block),
+            pl.BlockSpec((1, block_q, 8), _q_block),
+            pl.BlockSpec((1, block_q, 8), _q_block),
         ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_q, D), _q_block),
+        out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(tiles),
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
@@ -415,48 +615,57 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
 
     BH, T, D = q.shape
     S = k.shape[1]
-    block_q = min(block_q, T)
-    block_k = min(block_k, S)
-    num_q = pl.cdiv(T, block_q)
-    num_k = pl.cdiv(S, block_k)
+    tiles, num_q, num_k = _grid(
+        "flash_bwd_dkv", q, k, causal, block_q, block_k)
+    block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dkv_kernel,
-        block_q=block_q, block_k=block_k, num_q=num_q, scale=scale,
-        causal=causal, seq_q=T, seq_k=S,
+        block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
+        scale=scale, causal=causal, seq_q=T, seq_k=S,
     )
+
+    def q_block(bh, ki, qi):
+        if causal:
+            qi = jnp.maximum(
+                qi, _first_q_with_body(ki, block_q, block_k, num_q))
+        return (bh, qi, 0)
+
+    def k_block(bh, ki, qi):
+        return (bh, ki, 0)
+
     return pl.pallas_call(
         kernel,
         grid=(BH, num_k, num_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda bh, ki, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, D), q_block),
+            pl.BlockSpec((1, block_k, D), k_block),
+            pl.BlockSpec((1, block_k, D), k_block),
+            pl.BlockSpec((1, block_q, D), q_block),
+            pl.BlockSpec((1, block_q, 8), q_block),
+            pl.BlockSpec((1, block_q, 8), q_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, D), k_block),
+            pl.BlockSpec((1, block_k, D), k_block),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
+            jax.ShapeDtypeStruct((BH, S, D), k.dtype),
+            jax.ShapeDtypeStruct((BH, S, D), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(tiles),
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
 
 def _xla_attention_bhtd(q, k, v, *, causal, scale):
-    """Reference path on [BH, T, D] used for backward + non-TPU fallback."""
+    """Plain attention on [BH, T, D]: what `mha(impl="xla")` runs, the
+    path of every platform but the TPU and the reference the kernels are
+    tested against."""
     s = jnp.einsum(
         "btd,bsd->bts", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
@@ -475,11 +684,14 @@ def flash_attention(
     *,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ):
-    """Flash attention on [B, T, H, D] inputs (grouped-query: H_kv may divide H)."""
+    """Flash attention on [B, T, H, D] inputs (grouped-query: H_kv may divide H).
+
+    `block_q` and `block_k` force every kernel's tile; `None` lets
+    `flash_tiles` choose each kernel's from the shape."""
     B, T, H, D = q.shape
     Hk = k.shape[2]
     if scale is None:

@@ -51,26 +51,26 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-def _shapes(device):
+def _shapes(device, bh=BH, t=T, d=D):
     one = SingleDeviceSharding(device)
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    qkv = sd((BH, T, D), jnp.bfloat16)
-    row = sd((BH, T, 8), jnp.float32)  # lse / delta, sublane-replicated
+    qkv = sd((bh, t, d), jnp.bfloat16)
+    row = sd((bh, t, 8), jnp.float32)  # lse / delta, sublane-replicated
     return qkv, row
 
 
-def _flash_case(name):
+def _flash_case(name, kernel=KERNEL):
     if name == "fwd":
-        return lambda q, k, v, do, lse, delta: fa._flash_fwd(q, k, v, **KERNEL)
+        return lambda q, k, v, do, lse, delta: fa._flash_fwd(q, k, v, **kernel)
     if name == "fwd_lse":
         return lambda q, k, v, do, lse, delta: fa._flash_fwd(
-            q, k, v, with_lse=True, **KERNEL)
+            q, k, v, with_lse=True, **kernel)
     if name == "bwd_dq":
-        return lambda *a: fa._flash_bwd_dq(*a, **KERNEL)
-    return lambda *a: fa._flash_bwd_dkv(*a, **KERNEL)
+        return lambda *a: fa._flash_bwd_dq(*a, **kernel)
+    return lambda *a: fa._flash_bwd_dkv(*a, **kernel)
 
 
 @pytest.mark.parametrize("name", ["fwd", "fwd_lse", "bwd_dq", "bwd_dkv"])
@@ -79,6 +79,39 @@ def test_flash_kernel_compiles_for_v5e(v5e, name):
     compiled = jax.jit(_flash_case(name)).lower(
         qkv, qkv, qkv, qkv, row, row
     ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("name", ["fwd", "fwd_lse", "bwd_dq", "bwd_dkv"])
+@pytest.mark.parametrize("bh,t,d", [
+    (128, 4096, 128),  # mistral7b.tokens4k (mistral7b.fsdp4: 64 a chip)
+    (BH, T, D),        # chip_smoke.py
+])
+def test_flash_kernel_compiles_at_the_shapes_own_tiles(v5e, name, bh, t, d):
+    """`block_q=None`: the tile `flash_tiles` picks for the shape, with the
+    VMEM limit it derives. A tile that does not fit fails here, on a CPU."""
+    kernel_name = {"fwd": "flash_fwd", "fwd_lse": "flash_fwd",
+                   "bwd_dq": "flash_bwd_dq", "bwd_dkv": "flash_bwd_dkv"}[name]
+    tiles = fa.flash_tiles(kernel_name, t, t, d, jnp.bfloat16)
+    assert tiles.block_q > 128 and tiles.block_k > 128  # not the old tile
+    qkv, row = _shapes(v5e[0], bh, t, d)
+    chosen = dict(KERNEL, scale=d ** -0.5, block_q=None, block_k=None)
+    compiled = jax.jit(_flash_case(name, chosen)).lower(
+        qkv, qkv, qkv, qkv, row, row
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("name", ["fwd_lse", "bwd_dq", "bwd_dkv"])
+def test_flash_kernel_compiles_under_highest_matmul_precision(v5e, name):
+    """bf16 operands go to the MXU as they are, and Mosaic refuses
+    "highest" for them ("Bad lhs type"): the kernels pin one pass for
+    narrow operands, so a process that sets the config keeps its kernel."""
+    qkv, row = _shapes(v5e[0])
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(_flash_case(name)).lower(
+            qkv, qkv, qkv, qkv, row, row
+        ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 

@@ -14,10 +14,17 @@ import jax  # noqa: E402
 jax.config.update("jax_default_matmul_precision", "highest")
 import jax.numpy as jnp  # noqa: E402
 
+import importlib  # noqa: E402
+
+import chip_smoke  # noqa: E402
 from ray_tpu.ops.flash_attention import (  # noqa: E402
     _xla_attention_bhtd,
     flash_attention,
+    flash_tiles,
+    mha,
 )
+# the module: `ray_tpu.ops.flash_attention` is the function of that name
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
 from ray_tpu.ops.fused import (  # noqa: E402
     lm_head_cross_entropy,
     softmax_cross_entropy,
@@ -54,6 +61,195 @@ def test_flash_backward_matches_xla(causal, seq):
     gg = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gg):
         np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+# The tile program. Tiles are forced small, so that one grid holds every
+# kind of tile at once: wholly under the diagonal (no mask), crossed by it,
+# wholly above it (no body, and the index maps clamp), and hanging over the
+# end of a sequence that no block divides.
+TILE_CASES = [
+    # T, S, block_q, block_k, causal
+    (320, 320, 64, 128, True),   # block_q < block_k, ragged k
+    (200, 200, 128, 64, True),   # block_q > block_k, ragged q and k
+    (200, 136, 64, 64, True),    # T > S: the last q rows see every key
+    (136, 200, 64, 64, True),    # T < S: whole k columns have no body
+    (200, 136, 128, 64, False),  # no diagonal: only the edges are masked
+    (256, 256, 64, 128, False),  # nothing is masked at all
+    (256, 256, 128, 128, True),  # interior, diagonal and skipped, no edge
+]
+
+
+def _tile_program_case(T, S, block_q, block_k, causal, dtype, heads=(2, 2)):
+    """out, dq, dk, dv of the kernel (interpret mode) and of mha(impl="xla")
+    under one random cotangent."""
+    H, Hk = heads
+    ks = jax.random.split(jax.random.PRNGKey(T + S), 4)
+    q = jax.random.normal(ks[0], (1, T, H, 32), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (1, S, Hk, 32), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (1, S, Hk, 32), jnp.float32).astype(dtype)
+    w = jax.random.normal(ks[3], (1, T, H, 32), jnp.float32)
+
+    def run(attn):
+        def scalar(q, k, v):
+            out = attn(q, k, v)
+            return (out.astype(jnp.float32) * w).sum(), out
+
+        (_, out), grads = jax.value_and_grad(
+            scalar, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out, *grads)
+
+    got = run(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=True))
+    want = run(lambda q, k, v: mha(q, k, v, causal=causal, impl="xla"))
+    return got, want, (q, k, v)
+
+
+def _rel_err(a, b):
+    """max|a-b| / max|b|, as chip_smoke.check_flash_against_xla has it."""
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.abs(a - b).max() / jnp.abs(b).max())
+
+
+@pytest.mark.parametrize("T,S,block_q,block_k,causal", TILE_CASES)
+def test_flash_tile_program_f32_matches_xla(T, S, block_q, block_k, causal):
+    """f32 in, f32 all the way: today's f32 tolerances, which a kernel that
+    rounded its operands or p to bf16 (2e-3 or worse) would not meet."""
+    got, want, _ = _tile_program_case(
+        T, S, block_q, block_k, causal, jnp.float32)
+    np.testing.assert_allclose(got[0], want[0], atol=3e-5)
+    for a, b in zip(got[1:], want[1:]):
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+@pytest.mark.parametrize("T,S,block_q,block_k,causal", TILE_CASES)
+def test_flash_tile_program_bf16_matches_xla(T, S, block_q, block_k, causal):
+    """bf16 operands on the MXU, p and ds rounded to bf16 for the second
+    matmuls: inside the chip smoke's KERNEL_TOLERANCE, and the gradients
+    come back in the input's dtype."""
+    got, want, _ = _tile_program_case(
+        T, S, block_q, block_k, causal, jnp.bfloat16)
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        assert _rel_err(a, b) <= chip_smoke.KERNEL_TOLERANCE
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_tile_program_gqa(dtype):
+    """Four query heads a key head, tiles forced small."""
+    got, want, (q, k, v) = _tile_program_case(
+        200, 200, 64, 128, True, dtype, heads=(4, 1))
+    for a, b, x in zip(got[1:], want[1:], (q, k, v)):
+        assert a.shape == x.shape and a.dtype == x.dtype
+    tol = 2e-4 if dtype == jnp.float32 else chip_smoke.KERNEL_TOLERANCE
+    for a, b in zip(got, want):
+        assert _rel_err(a, b) <= tol
+
+
+def test_flash_default_tiles_are_the_shapes_choice():
+    """`block_q=None` is `flash_tiles`' answer: forcing that answer changes
+    no bit of the forward or of the gradients."""
+    T, D = 192, 32
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.random.normal(kk, (1, T, 2, D), jnp.float32) for kk in ks)
+
+    def grads(**blocks):
+        return jax.value_and_grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=True, **blocks
+        ).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    tiles = {flash_tiles(kern, T, T, D, jnp.float32)[:2]
+             for kern in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert tiles == {(128, 128)}  # one answer here, so it can be forced
+    for a, b in zip(jax.tree.leaves(grads()),
+                    jax.tree.leaves(grads(block_q=128, block_k=128))):
+        np.testing.assert_array_equal(a, b)
+
+
+# The tile function: pure arithmetic on shapes, no device.
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_tiles_are_legal_for_any_shape(kernel, dtype):
+    for T, S, D in [(4096, 4096, 128), (1024, 1024, 64), (96, 96, 64),
+                    (192, 192, 64), (1000, 520, 64), (128, 4096, 128),
+                    (32768, 32768, 128), (8192, 8192, 256), (64, 300, 32)]:
+        for causal in (True, False):
+            t = flash_tiles(kernel, T, S, D, dtype, causal=causal)
+            for block, seq in ((t.block_q, T), (t.block_k, S)):
+                assert block <= seq
+                assert block % 128 == 0 or block == seq
+            assert t.vmem_bytes < t.vmem_limit_bytes <= 96 << 20
+            assert t.grid_steps == (
+                -(-T // t.block_q) * -(-S // t.block_k))
+            assert 0 < t.active_share <= 1
+            assert causal or t.active_share == 1
+
+
+@pytest.mark.parametrize("kernel,T,D,tile,steps,active", [
+    # the two Mistral cells: BH 128 (64 a chip), T 4096, D 128
+    ("flash_fwd", 4096, 128, (1024, 1024), 16, 10 / 16),
+    ("flash_bwd_dq", 4096, 128, (1024, 1024), 16, 10 / 16),
+    ("flash_bwd_dkv", 4096, 128, (1024, 1024), 16, 10 / 16),
+    # chip_smoke's GPT-2-small: T 1024, D 64
+    ("flash_fwd", 1024, 64, (1024, 1024), 1, 1.0),
+    ("flash_bwd_dq", 1024, 64, (512, 512), 4, 3 / 4),
+    ("flash_bwd_dkv", 1024, 64, (512, 512), 4, 3 / 4),
+])
+def test_flash_tiles_of_the_measured_shapes(kernel, T, D, tile, steps, active):
+    t = flash_tiles(kernel, T, T, D, jnp.bfloat16)
+    assert (t.block_q, t.block_k) == tile
+    assert (t.grid_steps, t.active_share) == (steps, active)
+    # what the kernel was before: 128 x 128, 1,024 steps a row at T 4096
+    old = flash_tiles(kernel, T, T, D, jnp.bfloat16, block_q=128, block_k=128)
+    n = T // 128
+    assert old.grid_steps == n * n
+    assert old.active_share == (n * (n + 1) // 2) / (n * n)
+    assert old.vmem_limit_bytes == 16 << 20  # the default is enough there
+
+
+def test_flash_tiles_forced_blocks_are_cut_to_the_sequence():
+    t = flash_tiles("flash_fwd", 96, 200, 64, jnp.float32,
+                    block_q=128, block_k=64)
+    assert (t.block_q, t.block_k) == (96, 64)
+    t = flash_tiles("flash_bwd_dkv", 4096, 4096, 128, jnp.bfloat16,
+                    block_q=256)
+    assert t.block_q == 256 and t.block_k % 128 == 0
+
+
+@pytest.mark.parametrize("T,S,block_q,block_k", [
+    (320, 320, 64, 128), (200, 136, 128, 64), (136, 200, 64, 64),
+    (4096, 4096, 512, 1024), (1024, 1024, 1024, 256),
+])
+def test_flash_causal_tile_bookkeeping_matches_the_mask(T, S, block_q, block_k):
+    """Which tiles have a body, which need the mask, how many there are and
+    where the index maps' clamps point: all against the mask itself."""
+    allowed = np.arange(T)[:, None] >= np.arange(S)[None, :]
+    num_q, num_k = -(-T // block_q), -(-S // block_k)
+    tile = lambda qi, ki: allowed[qi * block_q:(qi + 1) * block_q,
+                                  ki * block_k:(ki + 1) * block_k]
+    body = np.array([[tile(qi, ki).any() for ki in range(num_k)]
+                     for qi in range(num_q)])
+    assert fa._active_tiles(T, S, block_q, block_k, True) == body.sum()
+    assert fa._active_tiles(T, S, block_q, block_k, False) == body.size
+    for qi in range(num_q):
+        for ki in range(num_k):
+            has_body, needs_mask = fa._tile_kind(
+                qi, ki, block_q=block_q, block_k=block_k, num_q=num_q,
+                num_k=num_k, causal=True, seq_q=T, seq_k=S)
+            assert bool(has_body) == body[qi, ki]
+            full = tile(qi, ki).shape == (block_q, block_k)
+            if body[qi, ki]:  # a bare body only where the mask is all true
+                assert bool(needs_mask) == (not (full and tile(qi, ki).all()))
+        last = min(int(fa._last_k_with_body(qi, block_q, block_k)), num_k - 1)
+        assert last == np.flatnonzero(body[qi]).max()
+    for ki in range(num_k):
+        first = int(fa._first_q_with_body(ki, block_q, block_k, num_q))
+        rows = np.flatnonzero(body[:, ki])
+        assert first == (rows.min() if rows.size else num_q - 1)
 
 
 def test_lm_head_ce_matches_dense():

@@ -12,6 +12,7 @@ from ray_tpu.models.transformer import (
     transformer_apply,
     transformer_init,
     transformer_loss,
+    transformer_loss_and_readings,
     make_train_step,
     param_shardings,
 )
@@ -22,6 +23,7 @@ __all__ = [
     "transformer_init",
     "transformer_apply",
     "transformer_loss",
+    "transformer_loss_and_readings",
     "make_train_step",
     "param_shardings",
     "ResNetConfig",

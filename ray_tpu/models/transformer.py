@@ -5,6 +5,13 @@ attention, SwiGLU MLP, bf16 compute / f32 master weights. Layers are stacked
 into one pytree and iterated with `lax.scan`, so compile time is O(1) in
 depth and XLA pipelines the weight prefetch.
 
+The block's feed-forward is dense SwiGLU or, with `n_experts`, a dropless
+routed one (`ops/moe.py`): a float32 softmax router, the `experts_per_token`
+largest probabilities as weights, every slot computed by a grouped matmul,
+and the router's load-balancing and z losses added to the loss. `qk_norm`
+puts an RMSNorm with a learned scale on the whole q and k projections before
+the heads are split and rotated. Both are OLMoE's (arXiv:2409.02060).
+
 Parallelism (ray_tpu.parallel.mesh axes):
   data/fsdp — batch split; fsdp additionally shards params (ZeRO-3 style)
   tensor    — heads + mlp hidden + vocab split (Megatron layout)
@@ -26,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.fused import (
     fused_rmsnorm,
@@ -48,9 +56,17 @@ class TransformerConfig:
     rope_theta: float = 10_000.0
     dtype: Any = jnp.bfloat16  # compute/activation dtype
     remat: bool = False  # jax.checkpoint each block
-    attention_impl: str = "auto"  # auto | pallas | xla | ring
+    # auto | pallas | xla | ring; a routed feed-forward's grouped matmul
+    # takes its kernels where attention does
+    attention_impl: str = "auto"
     norm_eps: float = 1e-6
     tied_embeddings: bool = True
+    n_experts: int = 0  # 0 => dense SwiGLU; else d_ff is one expert's width
+    experts_per_token: int = 1
+    norm_topk_prob: bool = False  # divide the chosen weights by their sum
+    qk_norm: bool = False  # RMSNorm on the q and k projections
+    router_aux_loss_coef: float = 0.01  # load balancing, mean over layers
+    router_z_loss_coef: float = 0.001  # logsumexp(router logits)^2
 
     @property
     def kv_heads(self) -> int:
@@ -81,6 +97,8 @@ def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
 
     L = cfg.n_layers
     ks = jax.random.split(k_blk, 7)
+    # a routed feed-forward stacks its experts behind the layer axis
+    ff = (L, cfg.n_experts) if cfg.n_experts else (L,)
     blocks = {
         "attn_norm": jnp.ones((L, d), jnp.float32),
         "wq": dense(ks[0], (L, d, h * dh), d),
@@ -88,10 +106,16 @@ def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
         "wv": dense(ks[2], (L, d, hk * dh), d),
         "wo": dense(ks[3], (L, h * dh, d), h * dh),
         "mlp_norm": jnp.ones((L, d), jnp.float32),
-        "w_gate": dense(ks[4], (L, d, f), d),
-        "w_up": dense(ks[5], (L, d, f), d),
-        "w_down": dense(ks[6], (L, f, d), f),
+        "w_gate": dense(ks[4], (*ff, d, f), d),
+        "w_up": dense(ks[5], (*ff, d, f), d),
+        "w_down": dense(ks[6], (*ff, f, d), f),
     }
+    if cfg.n_experts:
+        blocks["router"] = dense(
+            jax.random.fold_in(k_blk, 7), (L, d, cfg.n_experts), d)
+    if cfg.qk_norm:
+        blocks["q_norm"] = jnp.ones((L, h * dh), jnp.float32)
+        blocks["k_norm"] = jnp.ones((L, hk * dh), jnp.float32)
     params = {
         "embed": jax.random.normal(
             k_emb, (cfg.vocab_size, d), jnp.float32
@@ -120,6 +144,13 @@ _LOGICAL_AXES = {
         "w_down": ("layers", "mlp", "embed"),
     },
 }
+_ROUTED_AXES = {
+    "w_gate": ("layers", "experts", "embed", "mlp"),
+    "w_up": ("layers", "experts", "embed", "mlp"),
+    "w_down": ("layers", "experts", "mlp", "embed"),
+    "router": ("layers", "embed", None),
+}
+_QK_NORM_AXES = {"q_norm": ("layers", "heads"), "k_norm": ("layers", "kv")}
 
 
 def param_shardings(mesh, cfg: TransformerConfig):
@@ -135,6 +166,11 @@ def param_shardings(mesh, cfg: TransformerConfig):
     table = dict(_LOGICAL_AXES)
     if cfg.tied_embeddings:
         table.pop("unembed", None)
+    table["blocks"] = {
+        **table["blocks"],
+        **(_ROUTED_AXES if cfg.n_experts else {}),
+        **(_QK_NORM_AXES if cfg.qk_norm else {}),
+    }
     return build(table)
 
 
@@ -165,9 +201,7 @@ def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
         return ring_attention(
             q, k, v, axis_name=seq_axis, axis_size=seq_size, causal=True
         )
-    impl = resolve_impl(
-        cfg.attention_impl if cfg.attention_impl in ("pallas", "xla") else "auto"
-    )
+    impl = _kernel_impl(cfg)
     attn = partial(mha, causal=True, impl=impl)
     if impl == "pallas" and mesh is not None and mesh.size > 1:
         # XLA cannot partition a Mosaic kernel ("wrap the call in a
@@ -183,8 +217,57 @@ def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
     return attn(q, k, v)
 
 
+def _kernel_impl(cfg: TransformerConfig) -> str:
+    """'pallas' or 'xla', for attention and the grouped matmul alike."""
+    return resolve_impl(
+        cfg.attention_impl if cfg.attention_impl in ("pallas", "xla") else "auto"
+    )
+
+
+def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None):
+    """The routed feed-forward on normed activations `y` [B, T, d]: the sum
+    over a token's `experts_per_token` experts of p_e * SwiGLU_e(y), and the
+    layer's router readings {aux_loss, z_loss, expert_load [E],
+    expert_index [B T, k]}. Dropless: `expert_load` sums to B T k."""
+    B, T, d = y.shape
+    impl = _kernel_impl(cfg)
+    if impl == "pallas" and mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "the grouped-matmul kernels are not mapped over a mesh of "
+            f"{mesh.size} devices yet (ROADMAP R1: expert parallelism)"
+        )
+    tokens = y.reshape(B * T, d)
+    with jax.named_scope("moe_router"):
+        # float32 at full precision: with bf16 logits the k-th and the next
+        # expert swap on rounding
+        logits = jnp.dot(
+            tokens.astype(jnp.float32), blk["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        probs, weights, index = moe.route(
+            logits, cfg.experts_per_token, cfg.norm_topk_prob)
+        slots = moe.sort_slots(index, cfg.n_experts)
+        readings = {
+            "aux_loss": moe.load_balancing_loss(probs, slots.group_sizes),
+            "z_loss": moe.router_z_loss(logits),
+            "expert_load": slots.group_sizes,
+            "expert_index": index,
+        }
+    with jax.named_scope("moe_dispatch"):
+        xs = moe.dispatch(tokens, slots.order, slots.inverse)
+    with jax.named_scope("moe_experts"):
+        gmm = partial(moe.grouped_matmul, group_sizes=slots.group_sizes,
+                      impl=impl)
+        hidden = jax.nn.silu(gmm(xs, blk["w_gate"])) * gmm(xs, blk["w_up"])
+        ys = gmm(hidden, blk["w_down"])
+    with jax.named_scope("moe_combine"):
+        out = moe.combine(ys, weights, slots.order, slots.inverse)
+    return out.reshape(B, T, d), readings
+
+
 def _block(x, blk, positions, cfg: TransformerConfig,
            seq_axis: Optional[str], seq_size: int, mesh=None):
+    """One block: (x, the routed feed-forward's readings or None)."""
     B, T, d = x.shape
     h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     dt = cfg.dtype
@@ -193,8 +276,13 @@ def _block(x, blk, positions, cfg: TransformerConfig,
     # (docs/observability.md, "Device scopes"); they are metadata only.
     with jax.named_scope("attn_qkv"):
         y = fused_rmsnorm(x, blk["attn_norm"], eps=cfg.norm_eps)
-        q = (y @ blk["wq"].astype(dt)).reshape(B, T, h, dh)
-        k = (y @ blk["wk"].astype(dt)).reshape(B, T, hk, dh)
+        q, k = y @ blk["wq"].astype(dt), y @ blk["wk"].astype(dt)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = fused_rmsnorm(q, blk["q_norm"], eps=cfg.norm_eps)
+                k = fused_rmsnorm(k, blk["k_norm"], eps=cfg.norm_eps)
+        q = q.reshape(B, T, h, dh)
+        k = k.reshape(B, T, hk, dh)
         v = (y @ blk["wv"].astype(dt)).reshape(B, T, hk, dh)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
@@ -203,24 +291,24 @@ def _block(x, blk, positions, cfg: TransformerConfig,
     with jax.named_scope("attn_out"):
         x = x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
 
+    readings = None
     with jax.named_scope("mlp"):
         y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
-        gate = jax.nn.silu(y @ blk["w_gate"].astype(dt))
-        up = y @ blk["w_up"].astype(dt)
-        x = x + (gate * up) @ blk["w_down"].astype(dt)
-    return x
+        if cfg.n_experts:
+            routed, readings = _routed_ffn(y, blk, cfg, mesh)
+            x = x + routed
+        else:
+            gate = jax.nn.silu(y @ blk["w_gate"].astype(dt))
+            up = y @ blk["w_up"].astype(dt)
+            x = x + (gate * up) @ blk["w_down"].astype(dt)
+    return x, readings
 
 
-def transformer_hidden(params, tokens, cfg: TransformerConfig,
-                       positions=None, seq_axis: Optional[str] = None,
-                       seq_size: int = 1, mesh=None):
-    """Forward through the blocks: [B, T] tokens -> [B, T, d] normed hidden.
-
-    When called under shard_map with the sequence sharded, pass seq_axis and
-    positions holding GLOBAL positions so RoPE and causal masks are correct.
-    When called under a jit that shards over `mesh`, pass the mesh: the
-    Pallas attention kernel is mapped over its batch and head axes.
-    """
+def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
+                         positions=None, seq_axis: Optional[str] = None,
+                         seq_size: int = 1, mesh=None):
+    """`transformer_hidden`, and the routed feed-forwards' readings stacked
+    on a leading layer axis (None for a dense model)."""
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -234,11 +322,24 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig,
         blk_fn = jax.checkpoint(blk_fn, static_argnums=())
 
     def scan_body(x, blk):
-        return blk_fn(x, blk, positions), None
+        return blk_fn(x, blk, positions)
 
-    x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+    x, readings = jax.lax.scan(scan_body, x, params["blocks"])
     with jax.named_scope("final_norm"):
-        return fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
+        x = fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
+    return x, readings
+
+
+def transformer_hidden(params, tokens, cfg: TransformerConfig, **kw):
+    """Forward through the blocks: [B, T] tokens -> [B, T, d] normed hidden.
+
+    Keywords: `positions`, `seq_axis`, `seq_size`, `mesh`. When called under
+    shard_map with the sequence sharded, pass seq_axis and positions holding
+    GLOBAL positions so RoPE and causal masks are correct. When called under
+    a jit that shards over `mesh`, pass the mesh: the Pallas attention
+    kernel is mapped over its batch and head axes.
+    """
+    return _hidden_and_readings(params, tokens, cfg, **kw)[0]
 
 
 def _unembed(params, cfg: TransformerConfig):
@@ -256,30 +357,55 @@ def transformer_apply(params, tokens, cfg: TransformerConfig,
     return (x @ _unembed(params, cfg).astype(cfg.dtype)).astype(jnp.float32)
 
 
-def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
-    """Next-token CE. batch: {'tokens': [B, T+1] or ('tokens','targets')}.
+def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
+    """(loss, readings). Next-token CE; batch: {'tokens': [B, T+1] or
+    ('tokens','targets')}.
 
     Uses the chunked LM-head CE (ops/fused.py lm_head_cross_entropy): the
     [B*T, V] f32 logits are never materialized, which at GPT-2 vocab sizes
-    is the difference between HBM-bound and MXU-bound training steps."""
+    is the difference between HBM-bound and MXU-bound training steps.
+
+    A model with routed experts adds `router_aux_loss_coef` times the
+    load-balancing loss and `router_z_loss_coef` times the router z-loss,
+    each a mean over the layers, and its readings are `aux_loss` and
+    `z_loss` (those means), `expert_load` [L, E] (slots per expert; every
+    row sums to B T k) and `expert_index` [L, B T, k]. A dense model's
+    readings are empty."""
     if "targets" in batch:
         tokens, targets = batch["tokens"], batch["targets"]
     else:
         tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    hidden = transformer_hidden(params, tokens, cfg, **kw)
+    hidden, readings = _hidden_and_readings(params, tokens, cfg, **kw)
     with jax.named_scope("lm_head_ce"):
         loss, _ = lm_head_cross_entropy(
             hidden, _unembed(params, cfg), targets)
-    return loss
+    if readings is None:
+        return loss, {}
+    readings = dict(readings, aux_loss=readings["aux_loss"].mean(),
+                    z_loss=readings["z_loss"].mean())
+    loss = (loss + cfg.router_aux_loss_coef * readings["aux_loss"]
+            + cfg.router_z_loss_coef * readings["z_loss"])
+    return loss, readings
+
+
+def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
+    """The loss of `transformer_loss_and_readings` alone."""
+    return transformer_loss_and_readings(params, batch, cfg, **kw)[0]
 
 
 # -------------------------------------------------------------- train step
+
+# what a routed model's step reports beside loss and grad_norm
+_STEP_READINGS = ("aux_loss", "z_loss", "expert_load")
+
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
     """Build (init_state, step) jitted over the mesh.
 
     state = {'params': f32 sharded, 'opt': optax state, 'step': scalar}
-    step(state, batch) -> (state, metrics); params/opt donated.
+    step(state, batch) -> (state, metrics); params/opt donated. metrics are
+    loss and grad_norm, and with routed experts aux_loss, z_loss and
+    expert_load [L, E].
 
     DP/FSDP/TP come from the in/out shardings (XLA inserts psum /
     all-gather / reduce-scatter over ICI); if the mesh has a 'sequence'
@@ -324,11 +450,14 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
                 "step": jax.device_put(jnp.zeros((), jnp.int32), repl)}
 
     def loss_fn(params, batch):
-        return transformer_loss(params, batch, cfg, mesh=mesh)
+        loss, readings = transformer_loss_and_readings(
+            params, batch, cfg, mesh=mesh)
+        return loss, {k: readings[k] for k in _STEP_READINGS if k in readings}
 
     @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))
     def step(state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch)
+        (loss, readings), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state["params"], batch)
         with jax.named_scope("optimizer"):
             updates, opt = optimizer.update(
                 grads, state["opt"], state["params"]
@@ -337,7 +466,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
             gnorm = optax.global_norm(grads)
         return (
             {"params": params, "opt": opt, "step": state["step"] + 1},
-            {"loss": loss, "grad_norm": gnorm},
+            {"loss": loss, "grad_norm": gnorm, **readings},
         )
 
     return init_state, step, {"tokens": tok_sharding, "replicated": repl,
@@ -349,7 +478,10 @@ def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
     layer, lm-head fwd flops/token)."""
     d, f = cfg.d_model, cfg.ff_dim
     h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    per_layer = 2 * d * (h * dh + 2 * hk * dh) + 2 * h * dh * d + 2 * 3 * d * f
+    ff = 2 * 3 * d * f
+    if cfg.n_experts:  # active parameters: the router and a token's experts
+        ff = 2 * d * cfg.n_experts + cfg.experts_per_token * ff
+    per_layer = 2 * d * (h * dh + 2 * hk * dh) + 2 * h * dh * d + ff
     # Causal attention: token t attends to t+1 keys, so the average query
     # sees (seq_len + 1) / 2 positions; qk^T and pv each cost 2*h*dh flops
     # per (query, key) pair. The flash kernel really skips the masked-out

@@ -9,10 +9,12 @@ MXU-tiled Pallas). Every op has an XLA fallback so the same code runs on CPU
 
 from ray_tpu.ops.flash_attention import flash_attention, mha
 from ray_tpu.ops.fused import fused_rmsnorm, softmax_cross_entropy
+from ray_tpu.ops.moe import grouped_matmul
 
 __all__ = [
     "flash_attention",
     "mha",
+    "grouped_matmul",
     "fused_rmsnorm",
     "softmax_cross_entropy",
 ]

@@ -1,0 +1,512 @@
+"""The pieces of a routed (mixture-of-experts) feed-forward, dropless.
+
+A token's router probabilities pick `k` of `E` experts; the `T x k` slots
+are sorted by expert (a stable sort, so a token's order inside a group is its
+order in the batch), each group of rows goes through its own expert's
+weights, and the results come back to the tokens weighted by the router's
+probabilities. No capacity factor: every slot is computed whatever the
+imbalance, and the group sizes always sum to `T x k`.
+
+    route(logits, k)                 float32 softmax, the k largest
+    sort_slots(index, E)             order, its inverse, rows per expert
+    dispatch(x, order, inverse)      [T, d] -> [T k, d], rows by expert
+    grouped_matmul(x, w, sizes)      rows of group e times w[e]
+    combine(ys, weights, order, inverse)   [T k, d] -> [T, d]
+
+`grouped_matmul` is a matmul whose row groups go to different weights. On a
+TPU it is two Pallas kernels under a `custom_vjp`, after the grouped matmul
+that JAX ships as an example (`jax.experimental.pallas.ops.tpu.megablox`),
+rewritten here because that one is an experimental module with another
+tiling, no names and no f32 weight gradient:
+
+- `moe_gmm`: rows `[M, K]` sorted by group times `[E, K, N]` (or, with
+  `transpose_w`, `[E, N, K]`) gives `[M, N]`. The forward product, and on the
+  transposed weights the gradient of the rows.
+- `moe_tgmm`: per group `x^T dy`, `[E, K, N]`: the gradient of the weights,
+  accumulated in f32 and written in the weights' own dtype, so f32 master
+  weights get an f32 gradient that was never rounded to bf16.
+
+Both walk a static grid of `M / tm + E - 1` row steps. A step is one (row
+tile, group) pair: a row tile that holds the boundary of two groups is
+visited once for each, and the rows of the other group are masked. Which
+tile and which group a step works on is computed outside the kernel from the
+group sizes and handed over as scalar-prefetch arrays, which the index maps
+read. So the work is proportional to the `T x k` rows, not to `E x T`; a group
+of no rows takes no step in `moe_gmm` and one step (that writes zeros) in
+`moe_tgmm`; steps past the last one name the block already in VMEM and
+have no body. Operands reach the MXU in the rows' dtype (bf16 in training)
+and every dot accumulates in f32.
+
+Anywhere but on a TPU `grouped_matmul` is `jax.lax.ragged_dot`, which XLA
+differentiates itself; `impl="auto"` resolves as attention's does.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.flash_attention import (
+    _DEFAULT_VMEM, _LANES, _MAX_VMEM, _NN, _NT, _TN, _cdiv, _dot, resolve_impl)
+
+# ----------------------------------------------------------------- routing
+
+
+def route(logits, k: int, renormalize: bool = False):
+    """(probabilities [T, E], weights [T, k], index [T, k]) of router logits
+    [T, E]: a float32 softmax over the experts and its k largest, in falling
+    order. `renormalize` divides the k weights by their sum
+    (`norm_topk_prob`); otherwise they are the probabilities as they are."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, index = jax.lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    return probs, weights, index
+
+
+class Slots(NamedTuple):
+    """The `T x k` slots (slot `t k + j` is token t's j-th choice) in the
+    order the experts take them."""
+    order: jax.Array        # [T k] slot ids, sorted by expert, stably
+    inverse: jax.Array      # [T k] where slot s stands in that order
+    group_sizes: jax.Array  # [E] rows of each expert; sums to T k
+
+
+def sort_slots(index, n_experts: int) -> Slots:
+    """Sort the slots of `index` [T, k] by expert."""
+    flat = index.reshape(-1).astype(jnp.int32)
+    ids = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    _, order = jax.lax.sort((flat, ids), num_keys=1, is_stable=True)
+    _, inverse = jax.lax.sort((order, ids), num_keys=1)
+    experts = jnp.arange(n_experts, dtype=jnp.int32)
+    sizes = (flat[:, None] == experts[None, :]).sum(axis=0, dtype=jnp.int32)
+    return Slots(order, inverse, sizes)
+
+
+def _by_token(ys, inverse, k):
+    """Rows in expert order back in slot order: [T, k, d]."""
+    return ys[inverse].reshape(-1, k, ys.shape[-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inverse, k):
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inverse, k):
+    return x[order // k], inverse
+
+
+def _dispatch_bwd(k, inverse, dxs):
+    per_token = _by_token(dxs, inverse, k).astype(jnp.float32)
+    return per_token.sum(axis=1).astype(dxs.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def dispatch(x, order, inverse):
+    """Rows of `x` [T, d] copied to their slots in expert order: [T k, d].
+    The gradient gathers by the inverse order and sums a token's k slots,
+    where XLA's own transpose of the gather would scatter-add."""
+    return _dispatch(x, order, inverse, order.shape[0] // x.shape[0])
+
+
+@jax.custom_vjp
+def combine(ys, weights, order, inverse):
+    """Expert outputs `ys` [T k, d] in expert order back to tokens [T, d]:
+    each token's k rows times its k `weights` [T, k] (float32), summed in
+    float32. The gradient of `ys` is a gather by `order`, not a scatter."""
+    rows = _by_token(ys, inverse, weights.shape[1]).astype(jnp.float32)
+    return (rows * weights[..., None]).sum(axis=1).astype(ys.dtype)
+
+
+def _combine_fwd(ys, weights, order, inverse):
+    return combine(ys, weights, order, inverse), (ys, weights, order, inverse)
+
+
+def _combine_bwd(res, dy):
+    ys, weights, order, inverse = res
+    k = weights.shape[1]
+    w_sorted = weights.reshape(-1)[order]
+    dys = (dy[order // k].astype(jnp.float32) * w_sorted[:, None]).astype(ys.dtype)
+    rows = _by_token(ys, inverse, k).astype(jnp.float32)
+    dw = jnp.einsum("td,tkd->tk", dy.astype(jnp.float32), rows)
+    return dys, dw.astype(weights.dtype), None, None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def load_balancing_loss(probs, group_sizes):
+    """`E sum_e f_e P_e`: f_e the share of the slots sent to expert e (no
+    gradient flows through a count), P_e the mean router probability of e.
+    1 when both are uniform."""
+    n_experts = probs.shape[-1]
+    share = group_sizes.astype(jnp.float32) / group_sizes.sum()
+    return n_experts * jnp.sum(share * probs.mean(axis=0))
+
+
+def router_z_loss(logits):
+    """Mean over tokens of `logsumexp(logits)^2`, in float32."""
+    lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
+    return jnp.mean(lse * lse)
+
+
+# ------------------------------------------------------------------- tiles
+
+class GmmTiles(NamedTuple):
+    """One kernel's tile for one shape: rows of a step, and the tiles of the
+    contracted (`moe_gmm`) or first output (`moe_tgmm`) dimension K and of
+    the last dimension N."""
+    tm: int
+    tk: int
+    tn: int
+    vmem_limit_bytes: int
+
+
+def _divisors(size: int):
+    """Tiles of a dimension: the whole of it and the multiples of 128 that
+    divide it."""
+    return sorted({size, *(t for t in range(_LANES, size, _LANES)
+                           if size % t == 0)})
+
+
+def _gmm_vmem(kernel, tm, tk, tn, itemsize, out_itemsize) -> int:
+    """Blocks in flight (double-buffered), the f32 accumulator and the f32
+    result of a step's dot."""
+    if kernel == "moe_gmm":
+        blocks = (tm * tk + tk * tn) * itemsize + tm * tn * out_itemsize
+        acc = 2 * tm * tn * 4
+    else:
+        blocks = (tm * tk + tm * tn) * itemsize + tk * tn * out_itemsize
+        acc = 2 * tk * tn * 4
+    return 2 * blocks + acc
+
+
+_ROW_TILE = 256
+
+
+def gmm_tiles(kernel: str, rows: int, k: int, n: int, experts: int, dtype, *,
+              out_dtype=None, tm: Optional[int] = None) -> GmmTiles:
+    """The tile of `moe_gmm` (rows [rows, k] times [experts, k, n]) or of
+    `moe_tgmm` ([rows, k]^T [rows, n] per group) in `dtype`. Pure: the shape
+    decides, by the rule the sweep on one v5e supports (PERF.md section 6,
+    PR 27; 131,072 rows in 64 groups, weights of [2048, 1024] and
+    [1024, 2048], bf16): K and N whole where VMEM allows, else the largest
+    of their tiles that fit, K before N (a K tile short of the whole K
+    fetches the weights again at every step: 8.4 ms a call against 3.7);
+    256 rows a step, fewer where there are fewer. Called alone the kernels
+    read within 3 % of each other at 128 to 512 rows and up to 20 % slower
+    at 1,024; in the step, 256 rows for both gave 0.63 % more tokens/s than
+    512 (a row tile that holds a group's boundary is computed once for
+    each group, and every group adds one). `experts` does not enter the
+    rule. A forced `tm` is taken as given, for tests."""
+    itemsize = jnp.dtype(dtype).itemsize
+    out_itemsize = jnp.dtype(out_dtype or dtype).itemsize
+    sublanes = 32 // itemsize  # rows of a packed VMEM tile: 8 f32, 16 bf16
+    tm = tm or min(_ROW_TILE, _cdiv(rows, sublanes) * sublanes)
+    plans = [
+        GmmTiles(tm, tk, tn, max(_DEFAULT_VMEM, 2 * _gmm_vmem(
+            kernel, tm, tk, tn, itemsize, out_itemsize)))
+        for tk in _divisors(k) for tn in _divisors(n)]
+    plans.sort(key=lambda t: (t.tk * t.tn, t.tk), reverse=True)
+    fitting = [t for t in plans if t.vmem_limit_bytes <= _MAX_VMEM]
+    return (fitting or plans[-1:])[0]
+
+
+# ---------------------------------------------------------------- metadata
+
+def _row_steps(group_sizes, rows: int, tm: int, visit_empty: bool):
+    """The (row tile, group) pairs the kernels walk, as arrays indexed by
+    the step: `group_ids`, `tile_ids`, and beside them `offsets` [E + 1]
+    (group e holds rows offsets[e] to offsets[e + 1]) and `num_steps` [1].
+    `rows` is a multiple of `tm`. The arrays have the static length
+    `rows / tm + E - 1`, which no set of group sizes passes; steps from
+    `num_steps` on repeat the last real step."""
+    n_groups = group_sizes.shape[0]
+    tiles_m = rows // tm
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = jnp.minimum(starts // tm, tiles_m - 1)
+    last = jnp.where(sizes > 0, (ends - 1) // tm, first)
+    tiles = jnp.where(sizes > 0, last - first + 1, 1 if visit_empty else 0)
+    step_ends = jnp.cumsum(tiles)
+    num_steps = step_ends[-1:]
+    step = jnp.minimum(
+        jnp.arange(tiles_m + n_groups - 1, dtype=jnp.int32), num_steps - 1)
+    group_ids = jnp.minimum(
+        jnp.searchsorted(step_ends, step, side="right").astype(jnp.int32),
+        n_groups - 1)
+    tile_ids = first[group_ids] + step - (step_ends - tiles)[group_ids]
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return group_ids, tile_ids.astype(jnp.int32), offsets, num_steps.astype(jnp.int32)
+
+
+def _rows_in_group(step, group_ids, tile_ids, offsets, tm):
+    """For the tile and the group of `step`: whether all of the tile's rows
+    are the group's, and `mine(width)`, the [tm, width] bool of those that
+    are."""
+    group = group_ids[step]
+    start, end = offsets[group], offsets[group + 1]
+    row0 = tile_ids[step] * tm
+
+    def mine(width):
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (tm, width), 0)
+        return jnp.logical_and(rows >= start, rows < end)
+
+    return jnp.logical_and(start <= row0, row0 + tm <= end), mine
+
+
+def _pad_rows(x, tm):
+    pad = (-x.shape[0]) % tm
+    return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+
+
+def _params(tiles: GmmTiles):
+    """The row steps run in order (a tile's visits are consecutive, a
+    group's accumulator lives across them); only N's tiles are independent."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=tiles.vmem_limit_bytes,
+    )
+
+
+# ----------------------------------------------------------------- kernels
+
+def _gmm_kernel(group_ids, tile_ids, offsets, num_steps,  # scalar prefetch
+                x_ref, w_ref, o_ref, *acc, tm, tn, tiles_k, transpose_w):
+    """`acc` is the f32 accumulator over K's tiles; none when K is whole."""
+    from jax.experimental import pallas as pl
+
+    step, ki = pl.program_id(1), pl.program_id(2)
+
+    def store(value):
+        whole, mine = _rows_in_group(step, group_ids, tile_ids, offsets, tm)
+
+        @pl.when(whole)
+        def _all():
+            o_ref[...] = value.astype(o_ref.dtype)
+
+        @pl.when(jnp.logical_not(whole))
+        def _some():  # the other rows are another group's, or no one's
+            o_ref[...] = jnp.where(
+                mine(tn), value, o_ref[...].astype(jnp.float32)
+            ).astype(o_ref.dtype)
+
+    @pl.when(step < num_steps[0])
+    def _body():
+        product = _dot(x_ref[...], w_ref[...], _NT if transpose_w else _NN)
+        if tiles_k == 1:
+            store(product)
+            return
+
+        acc_ref, = acc
+
+        @pl.when(ki == 0)
+        def _first():
+            acc_ref[...] = product
+
+        @pl.when(ki > 0)
+        def _next():
+            acc_ref[...] += product
+
+        @pl.when(ki == tiles_k - 1)
+        def _flush():
+            store(acc_ref[...])
+
+
+def gmm(x, w, group_sizes, *, transpose_w: bool = False,
+        tiles: Optional[GmmTiles] = None, interpret: bool = False):
+    """`moe_gmm`: `x` [M, K], rows sorted by group, times `w` [E, K, N]
+    (`transpose_w`: [E, N, K]) gives [M, N] in x's dtype. The group sizes
+    sum to M, as a dropless routing's do: a row past their total belongs to
+    no group and is never written."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = x.shape
+    n_groups = w.shape[0]
+    n = w.shape[1] if transpose_w else w.shape[2]
+    if tiles is None:
+        tiles = gmm_tiles("moe_gmm", m, k, n, n_groups, x.dtype)
+    tm, tk, tn = tiles.tm, tiles.tk, tiles.tn
+    tiles_k, tiles_n = k // tk, n // tn
+    xp = _pad_rows(x, tm)
+    meta = _row_steps(group_sizes, xp.shape[0], tm, visit_empty=False)
+
+    def x_block(ni, step, ki, group_ids, tile_ids, offsets, num_steps):
+        return tile_ids[step], ki
+
+    def w_block(ni, step, ki, group_ids, tile_ids, offsets, num_steps):
+        return (group_ids[step], ni, ki) if transpose_w else (
+            group_ids[step], ki, ni)
+
+    def o_block(ni, step, ki, group_ids, tile_ids, offsets, num_steps):
+        return tile_ids[step], ni
+
+    out = pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm, tn=tn, tiles_k=tiles_k,
+                          transpose_w=transpose_w),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(tiles_n, meta[0].shape[0], tiles_k),
+            in_specs=[
+                pl.BlockSpec((tm, tk), x_block),
+                pl.BlockSpec((None, tn, tk) if transpose_w else (None, tk, tn),
+                             w_block),
+            ],
+            out_specs=pl.BlockSpec((tm, tn), o_block),
+            scratch_shapes=(
+                [pltpu.VMEM((tm, tn), jnp.float32)] if tiles_k > 1 else []),
+        ),
+        out_shape=jax.ShapeDtypeStruct((xp.shape[0], n), x.dtype),
+        compiler_params=_params(tiles),
+        interpret=interpret,
+        name="moe_gmm",
+    )(*meta, xp, w)
+    return out[:m]
+
+
+def _tgmm_kernel(group_ids, tile_ids, offsets, num_steps,  # scalar prefetch
+                 x_ref, dy_ref, o_ref, acc_ref, *, tm, tk, tn):
+    from jax.experimental import pallas as pl
+
+    step = pl.program_id(2)
+    last_step = num_steps[0] - 1
+    group = group_ids[step]
+    valid = step <= last_step
+    first = jnp.logical_or(
+        step == 0, group_ids[jnp.maximum(step - 1, 0)] != group)
+    last = jnp.logical_or(
+        step == last_step, group_ids[jnp.minimum(step + 1, last_step)] != group)
+
+    @pl.when(jnp.logical_and(valid, first))
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # rows of another group are zeroed in the narrower operand only: a zero
+    # row on one side is a zero product
+    mask_x = tk <= tn
+    whole, mine = _rows_in_group(step, group_ids, tile_ids, offsets, tm)
+    has_rows = offsets[group + 1] > offsets[group]
+
+    @pl.when(jnp.logical_and(valid, jnp.logical_and(has_rows, whole)))
+    def _all():
+        acc_ref[...] += _dot(x_ref[...], dy_ref[...], _TN)
+
+    @pl.when(jnp.logical_and(valid, jnp.logical_and(
+        has_rows, jnp.logical_not(whole))))
+    def _some():
+        x, dy = x_ref[...], dy_ref[...]
+        if mask_x:
+            x = jnp.where(mine(tk), x, jnp.zeros_like(x))
+        else:
+            dy = jnp.where(mine(tn), dy, jnp.zeros_like(dy))
+        acc_ref[...] += _dot(x, dy, _TN)
+
+    @pl.when(jnp.logical_and(valid, last))
+    def _flush():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def tgmm(x, dy, group_sizes, *, out_dtype=None,
+         tiles: Optional[GmmTiles] = None, interpret: bool = False):
+    """`moe_tgmm`: per group `x[rows]^T dy[rows]` for `x` [M, K] and `dy`
+    [M, N], rows sorted by group: [E, K, N] in `out_dtype` (x's by
+    default), accumulated in f32. A group of no rows gives zeros."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = x.shape
+    n = dy.shape[1]
+    n_groups = group_sizes.shape[0]
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    if tiles is None:
+        tiles = gmm_tiles("moe_tgmm", m, k, n, n_groups, x.dtype,
+                          out_dtype=out_dtype)
+    tm, tk, tn = tiles.tm, tiles.tk, tiles.tn
+    xp, dyp = _pad_rows(x, tm), _pad_rows(dy, tm)
+    meta = _row_steps(group_sizes, xp.shape[0], tm, visit_empty=True)
+
+    def x_block(ni, ki, step, group_ids, tile_ids, offsets, num_steps):
+        return tile_ids[step], ki
+
+    def dy_block(ni, ki, step, group_ids, tile_ids, offsets, num_steps):
+        return tile_ids[step], ni
+
+    def o_block(ni, ki, step, group_ids, tile_ids, offsets, num_steps):
+        return group_ids[step], ki, ni
+
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tm=tm, tk=tk, tn=tn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, k // tk, meta[0].shape[0]),
+            in_specs=[
+                pl.BlockSpec((tm, tk), x_block),
+                pl.BlockSpec((tm, tn), dy_block),
+            ],
+            out_specs=pl.BlockSpec((None, tk, tn), o_block),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_groups, k, n), out_dtype),
+        compiler_params=_params(tiles),
+        interpret=interpret,
+        name="moe_tgmm",
+    )(*meta, xp, dyp)
+
+
+# ---------------------------------------------------------- grouped matmul
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped_matmul(x, w, group_sizes, tm, interpret):
+    return _grouped_matmul_fwd(x, w, group_sizes, tm, interpret)[0]
+
+
+def _grouped_matmul_fwd(x, w, group_sizes, tm, interpret):
+    e, k, n = w.shape
+    tiles = gmm_tiles("moe_gmm", x.shape[0], k, n, e, x.dtype, tm=tm)
+    out = gmm(x, w.astype(x.dtype), group_sizes, tiles=tiles,
+              interpret=interpret)
+    return out, (x, w, group_sizes)
+
+
+def _grouped_matmul_bwd(tm, interpret, res, dy):
+    x, w, group_sizes = res
+    e, k, n = w.shape
+    rows = x.shape[0]
+    dy = dy.astype(x.dtype)
+    dx = gmm(dy, w.astype(x.dtype), group_sizes, transpose_w=True,
+             tiles=gmm_tiles("moe_gmm", rows, n, k, e, x.dtype, tm=tm),
+             interpret=interpret)
+    dw = tgmm(x, dy, group_sizes, out_dtype=w.dtype,
+              tiles=gmm_tiles("moe_tgmm", rows, k, n, e, x.dtype,
+                              out_dtype=w.dtype, tm=tm),
+              interpret=interpret)
+    return dx, dw, None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def grouped_matmul(x, w, group_sizes, *, impl: str = "auto",
+                   interpret: bool = False, block_rows: Optional[int] = None):
+    """Rows of group e of `x` [M, K] (sorted by group, `group_sizes` [E]
+    int32 rows each) times `w[e]` of `w` [E, K, N]: [M, N] in x's dtype.
+
+    `w` may be wider than `x` (f32 master weights under bf16 rows): it is
+    cast to x's dtype for the MXU, and its gradient comes back in its own
+    dtype. impl: 'auto' (the Pallas kernels on a TPU, `jax.lax.ragged_dot`
+    elsewhere) | 'pallas' | 'xla'. `interpret` and `block_rows` (a forced
+    row tile) are for tests of the kernels off the chip."""
+    if resolve_impl(impl) == "pallas" or interpret:
+        return _grouped_matmul(x, w, group_sizes.astype(jnp.int32),
+                               block_rows, interpret)
+    return jax.lax.ragged_dot(x, w.astype(x.dtype), group_sizes.astype(jnp.int32))

@@ -150,6 +150,7 @@ def default_transformer_rules(mesh) -> ShardingRules:
             "batch": ax(DATA, FSDP),
             "embed": ax(FSDP),
             "mlp": ax(TENSOR),
+            "experts": ax(EXPERT),
             "heads": ax(TENSOR),
             "kv": None,
             "vocab": ax(TENSOR),

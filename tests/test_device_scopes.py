@@ -15,6 +15,7 @@ import pytest
 
 from ray_tpu.models import TransformerConfig, make_train_step
 from ray_tpu.models.resnet import ResNetConfig, resnet_apply, resnet_init
+from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel import make_mesh
 
@@ -25,6 +26,10 @@ TRANSFORMER_SCOPES = {"embed", "attn_qkv", "attention", "attn_out", "mlp",
 RESNET_SCOPES = {"stem", "stage1", "stage2", "stage3", "stage4", "head",
                  "conv", "bn"}
 KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+# the routed feed-forward's, inside `mlp`, and QK-norm's, inside `attn_qkv`
+MOE_SCOPES = {"moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+              "qk_norm"}
+MOE_KERNELS = {"moe_gmm", "moe_tgmm"}
 
 
 def name_stacks(lowered):
@@ -36,11 +41,11 @@ def components(stacks):
     return {part for stack in stacks for part in re.split(r"[/()]", stack)}
 
 
-def lowered_transformer_step():
+def lowered_transformer_step(**routed):
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=64, max_seq_len=16, remat=True, attention_impl="xla",
-        tied_embeddings=False)
+        tied_embeddings=False, **routed)
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     init_state, step, _ = make_train_step(cfg, mesh)
     state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
@@ -60,6 +65,22 @@ def lowered_resnet_step():
     return jax.jit(jax.grad(loss, has_aux=True)).lower(params, images)
 
 
+def lowered_moe_step():
+    return lowered_transformer_step(n_experts=4, experts_per_token=2,
+                                    qk_norm=True)
+
+
+def lowered_moe_kernels():
+    x = jax.ShapeDtypeStruct((32, 16), jnp.float32)
+    w = jax.ShapeDtypeStruct((4, 16, 8), jnp.float32)
+
+    def loss(x, w):
+        sizes = jnp.asarray([8, 0, 20, 4], jnp.int32)
+        return moe.grouped_matmul(x, w, sizes, interpret=True).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w)
+
+
 def lowered_flash_kernels():
     q = jax.ShapeDtypeStruct((1, 128, 2, 64), jnp.float32)
 
@@ -71,6 +92,7 @@ def lowered_flash_kernels():
 
 FAMILIES = {
     "transformer": (lowered_transformer_step, TRANSFORMER_SCOPES),
+    "moe_transformer": (lowered_moe_step, TRANSFORMER_SCOPES | MOE_SCOPES),
     "resnet": (lowered_resnet_step, RESNET_SCOPES),
 }
 
@@ -87,20 +109,32 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
     # forward, backward and recompute need no scope of the program: JAX's
     # own name stack wraps a scope's operations
     assert any("transpose(jvp(" in s for s in stacks[family])
-    if family == "transformer":
+    if family != "resnet":
         assert any("rematted_computation" in s and "mlp" in s
                    for s in stacks[family])
         # the whole of _attention lies under its scope, wrappers included
         assert any(re.search(r"attention/.*transpose", s) for s in stacks[family])
+    if family == "transformer":  # the dense step names nothing of the routed
+        assert not (MOE_SCOPES | MOE_KERNELS) & components(stacks[family])
+    elif family == "moe_transformer":
+        # the routed feed-forward stays under `mlp`, QK-norm under `attn_qkv`
+        for inner in sorted(MOE_SCOPES - {"qk_norm"}):
+            assert any(re.search(rf"mlp\)*/{inner}", s) for s in stacks[family])
+        assert any(re.search(r"attn_qkv\)*/qk_norm", s) for s in stacks[family])
+        assert any("rematted_computation/mlp/moe_experts" in s
+                   for s in stacks[family])
     else:
         assert any(re.search(r"stage2\)*/bn/", s) for s in stacks[family])
         assert any(re.search(r"stem\)*/conv/conv_general_dilated", s)
                    for s in stacks[family])
 
 
-def test_the_kernels_carry_their_names_in_interpret_mode():
-    found = components(name_stacks(lowered_flash_kernels()))
-    assert KERNELS <= found
+@pytest.mark.parametrize("lower,names", [
+    (lowered_flash_kernels, KERNELS), (lowered_moe_kernels, MOE_KERNELS)],
+    ids=["flash", "moe"])
+def test_the_kernels_carry_their_names_in_interpret_mode(lower, names):
+    found = components(name_stacks(lower()))
+    assert names <= found
 
 
 def test_resnet_stem_space_to_depth_has_its_conv_scope():
@@ -119,7 +153,7 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
     program = TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     assert set(scopes.KERNELS) == KERNELS
-    suffix = ".tokens.json" if family == "transformer" else ".images.json"
+    suffix = ".images.json" if family == "resnet" else ".tokens.json"
     in_family = components(stacks[family]) | KERNELS
     named = 0
     for path in glob.glob(os.path.join(ROOT, "chipbench", "metrics", "*.json")):

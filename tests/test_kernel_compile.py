@@ -180,3 +180,39 @@ def test_flash_kernel_is_named_in_the_text_compiled_for_v5e(v5e, case, kernel):
         r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*', text)
     assert re.fullmatch(re.escape(kernel) + r"(\.\d+)?", call.group(1))
     assert f"/{kernel}/pallas_call" in call.group(0)  # and its name stack
+
+
+@pytest.mark.parametrize("kernel", ["moe_gmm", "moe_gmm_transposed", "moe_tgmm"])
+def test_grouped_matmul_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
+    """`olmoe.tokens4k`: 131,072 rows in 64 groups, a [2048, 1024] weight a
+    group, at the tile `gmm_tiles` picks; the weights' gradient leaves the
+    kernel as f32. The compiled instruction carries the kernel's name."""
+    import re
+
+    from ray_tpu.ops import moe
+
+    one = SingleDeviceSharding(v5e[0])
+    rows, k, n, experts = 131072, 2048, 1024, 64
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one)
+    dy = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((experts, k, n), jnp.bfloat16, sharding=one)
+    sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one)
+    if kernel == "moe_gmm":
+        lowered = jax.jit(moe.gmm).lower(x, w, sizes)
+    elif kernel == "moe_gmm_transposed":  # the rows' gradient
+        lowered = jax.jit(
+            lambda dy, w, s: moe.gmm(dy, w, s, transpose_w=True)
+        ).lower(dy, w, sizes)
+    else:
+        lowered = jax.jit(
+            lambda x, dy, s: moe.tgmm(x, dy, s, out_dtype=jnp.float32)
+        ).lower(x, dy, sizes)
+    text = lowered.compile().as_text()
+    call = re.search(
+        r'%([\w.-]+) = (\S+) [^\n]*custom_call_target="tpu_custom_call"', text)
+    name = "moe_tgmm" if kernel == "moe_tgmm" else "moe_gmm"
+    assert re.fullmatch(name + r"(\.\d+)?", call.group(1))
+    assert call.group(2).startswith(
+        "f32[64,2048,1024]" if kernel == "moe_tgmm" else
+        "bf16[131072,2048]" if kernel == "moe_gmm_transposed" else
+        "bf16[131072,1024]")

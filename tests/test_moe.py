@@ -1,0 +1,351 @@
+"""The routed feed-forward's pieces (`ray_tpu/ops/moe.py`) and the model that
+uses them, on the CPU: routing, the dropless sort under skew, the grouped
+matmul and its two backward products (the XLA path and the Pallas kernels in
+interpret mode) against a per-expert einsum, dispatch and combine, the two
+auxiliary losses against hand values, QK-norm, and the step's readings."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import TransformerConfig, make_train_step, transformer_init
+from ray_tpu.models.transformer import (
+    flops_per_token, param_shardings, transformer_loss,
+    transformer_loss_and_readings)
+from ray_tpu.ops import moe
+from ray_tpu.ops.fused import fused_rmsnorm
+from ray_tpu.parallel import make_mesh
+
+TINY = dict(vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+            d_ff=128, max_seq_len=64, tied_embeddings=False,
+            n_experts=8, experts_per_token=2, qk_norm=True)
+
+
+def key(i):
+    return jax.random.PRNGKey(i)
+
+
+# ----------------------------------------------------------------- routing
+
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_route_is_top_k_of_the_float32_softmax(k):
+    logits = jax.random.normal(key(k), (50, 16), jnp.bfloat16) * 3
+    probs, weights, index = moe.route(logits, k)
+    want = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w_want, i_want = jax.lax.top_k(want, k)
+    assert probs.dtype == jnp.float32
+    np.testing.assert_array_equal(index, i_want)
+    np.testing.assert_array_equal(weights, w_want)
+    # as they are: the k weights sum to less than 1
+    assert float(weights.sum(-1).max()) < 1.0 or k == 16
+
+
+def test_route_renormalizes_only_when_asked():
+    logits = jax.random.normal(key(0), (20, 8))
+    _, plain, index = moe.route(logits, 2)
+    _, normed, index_n = moe.route(logits, 2, renormalize=True)
+    np.testing.assert_array_equal(index, index_n)
+    np.testing.assert_allclose(normed.sum(-1), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(normed, plain / plain.sum(-1, keepdims=True),
+                               rtol=1e-6)
+
+
+def skewed_index(case, tokens=24, k=2, n_experts=8):
+    if case == "all_to_one":  # every token's first choice is expert 3
+        return jnp.stack([jnp.full((tokens,), 3), jnp.arange(tokens) % 3], 1)
+    if case == "one_empty":  # no token goes to expert 5
+        a = jnp.arange(tokens) % 5
+        return jnp.stack([a, (a + 1) % 5 + jnp.where(a == 4, 2, 0)], 1)
+    return jax.random.randint(key(7), (tokens, k), 0, n_experts)
+
+
+@pytest.mark.parametrize("case", ["all_to_one", "one_empty", "random"])
+def test_sort_is_stable_and_dropless(case):
+    index = skewed_index(case)
+    slots = moe.sort_slots(index, 8)
+    flat = np.asarray(index).reshape(-1)
+    order = np.asarray(slots.order)
+    assert int(slots.group_sizes.sum()) == flat.size  # every slot is kept
+    np.testing.assert_array_equal(slots.group_sizes, np.bincount(flat, minlength=8))
+    np.testing.assert_array_equal(order, np.argsort(flat, kind="stable"))
+    np.testing.assert_array_equal(np.asarray(slots.inverse)[order],
+                                  np.arange(flat.size))
+    if case == "one_empty":
+        assert int(slots.group_sizes[5]) == 0
+
+
+# ---------------------------------------------------------- grouped matmul
+
+def per_expert_einsum(x, w, sizes):
+    """Row m times the weight of the group m falls in."""
+    group = np.repeat(np.arange(len(sizes)), np.asarray(sizes))
+    return jnp.einsum("mk,mkn->mn", x, w[group])
+
+
+GROUPS = {
+    "even": [16, 16, 16, 16],
+    "ragged": [5, 0, 37, 22],      # no multiple of any tile, one empty
+    "all_in_one": [0, 0, 64, 0],
+    "empty_ends": [0, 30, 34, 0],
+}
+PATHS = {
+    "xla": dict(impl="xla"),
+    "kernels_tile8": dict(interpret=True, block_rows=8),
+    "kernels_tile16": dict(interpret=True, block_rows=16),
+    "kernels_own_tile": dict(interpret=True),
+}
+
+
+@pytest.mark.parametrize("path", PATHS)
+@pytest.mark.parametrize("groups", GROUPS)
+def test_grouped_matmul_and_both_gradients(groups, path):
+    sizes = jnp.asarray(GROUPS[groups], jnp.int32)
+    m, k, n = int(sizes.sum()), 32, 48
+    x = jax.random.normal(key(1), (m, k))
+    w = jax.random.normal(key(2), (len(sizes), k, n))
+    cot = jax.random.normal(key(3), (m, n))
+
+    def system(x, w):
+        return moe.grouped_matmul(x, w, sizes, **PATHS[path])
+
+    out, vjp = jax.vjp(system, x, w)
+    want, vjp_want = jax.vjp(lambda x, w: per_expert_einsum(x, w, sizes), x, w)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for got, ref in zip(vjp(cot), vjp_want(cot)):  # rows', then weights'
+        np.testing.assert_allclose(got, ref, atol=5e-5, rtol=5e-5)
+    if "empty" in groups or groups == "ragged":  # an empty group's gradient
+        dw = vjp(cot)[1]
+        empty = [e for e, s in enumerate(GROUPS[groups]) if s == 0]
+        assert float(jnp.abs(dw[jnp.asarray(empty)]).max()) == 0.0
+
+
+def test_kernels_take_bf16_rows_and_return_f32_weight_gradients():
+    sizes = jnp.asarray([20, 44], jnp.int32)
+    x = jax.random.normal(key(1), (64, 32), jnp.bfloat16)
+    w = jax.random.normal(key(2), (2, 32, 16), jnp.float32)  # master weights
+
+    def loss(x, w):
+        return moe.grouped_matmul(
+            x, w, sizes, interpret=True, block_rows=16).astype(jnp.float32).sum()
+
+    dx, dw = jax.grad(loss, (0, 1))(x, w)
+    assert dx.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
+    want = jax.grad(lambda x, w: per_expert_einsum(
+        x.astype(jnp.float32), w, sizes).sum(), 1)(x, w)
+    # f32 accumulation of bf16 products, never rounded to bf16
+    np.testing.assert_allclose(dw, want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("transpose_w", [False, True])
+def test_gmm_multiple_k_and_n_tiles(transpose_w):
+    sizes = jnp.asarray([100, 0, 156], jnp.int32)
+    x = jax.random.normal(key(1), (256, 256))
+    w = jax.random.normal(key(2), (3, 256, 384))
+    tiles = moe.GmmTiles(tm=64, tk=128, tn=128, vmem_limit_bytes=16 << 20)
+    if transpose_w:
+        got = moe.gmm(x, jnp.swapaxes(w, 1, 2), sizes, transpose_w=True,
+                      tiles=tiles, interpret=True)
+    else:
+        got = moe.gmm(x, w, sizes, tiles=tiles, interpret=True)
+    np.testing.assert_allclose(got, per_expert_einsum(x, w, sizes),
+                               atol=1e-4, rtol=1e-4)
+    dy = jax.random.normal(key(3), (256, 384))
+    dw = moe.tgmm(x, dy, sizes, tiles=tiles, interpret=True)
+    group = np.repeat(np.arange(3), np.asarray(sizes))
+    want = jnp.stack([x[group == e].T @ dy[group == e] for e in range(3)])
+    np.testing.assert_allclose(dw, want, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("kernel", ["moe_gmm", "moe_tgmm"])
+def test_gmm_tiles_come_from_the_shape(kernel):
+    tiles = moe.gmm_tiles(kernel, 131072, 2048, 1024, 64, jnp.bfloat16,
+                          out_dtype=jnp.float32 if kernel == "moe_tgmm" else None)
+    # the sweep's rule: K and N whole where VMEM allows, 256 rows a step
+    assert (tiles.tm, tiles.tk, tiles.tn) == (256, 2048, 1024)
+    assert tiles.vmem_limit_bytes <= 96 << 20
+    wide = moe.gmm_tiles(kernel, 131072, 4096, 14336, 8, jnp.bfloat16)
+    assert wide.tk == 4096 and 14336 % wide.tn == 0 and wide.tn % 128 == 0
+    assert wide.vmem_limit_bytes <= 96 << 20 < 2 * moe._gmm_vmem(
+        kernel, 256, 4096, 14336, 2, 2)  # the whole of both does not fit
+    small = moe.gmm_tiles(kernel, 40, 32, 48, 4, jnp.float32)
+    assert (small.tm, small.tk, small.tn) == (40, 32, 48)  # cut to the shape
+    forced = moe.gmm_tiles(kernel, 4096, 256, 256, 8, jnp.bfloat16, tm=64)
+    assert forced.tm == 64
+
+
+# ---------------------------------------------------- dispatch and combine
+
+def test_dispatch_and_combine_round_trip_with_gather_gradients():
+    tokens, k, n_experts, d = 12, 2, 4, 8
+    index = jax.random.randint(key(4), (tokens, k), 0, n_experts)
+    slots = moe.sort_slots(index, n_experts)
+    x = jax.random.normal(key(5), (tokens, d))
+    weights = jax.random.uniform(key(6), (tokens, k))
+
+    def system(x, weights):
+        xs = moe.dispatch(x, slots.order, slots.inverse)
+        return moe.combine(xs * 2.0, weights, slots.order, slots.inverse)
+
+    def plain(x, weights):  # every slot's row is its token's, times 2
+        return (2.0 * x[:, None, :] * weights[..., None]).sum(1)
+
+    np.testing.assert_allclose(system(x, weights), plain(x, weights), rtol=1e-6)
+    got = jax.grad(lambda x, w: (system(x, w) ** 2).sum(), (0, 1))(x, weights)
+    want = jax.grad(lambda x, w: (plain(x, w) ** 2).sum(), (0, 1))(x, weights)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-6)
+
+
+def test_combine_weights_are_not_renormalized():
+    slots = moe.sort_slots(jnp.asarray([[0, 1]]), 2)
+    ys = jnp.ones((2, 4))
+    out = moe.combine(ys, jnp.asarray([[0.3, 0.2]]), slots.order, slots.inverse)
+    np.testing.assert_allclose(out, 0.5 * jnp.ones((1, 4)), rtol=1e-6)
+
+
+# ------------------------------------------------------------ aux losses
+
+def test_load_balancing_loss_hand_values():
+    uniform = jnp.full((6, 4), 0.25)
+    assert float(moe.load_balancing_loss(uniform, jnp.asarray([3, 3, 3, 3]))) \
+        == pytest.approx(1.0)
+    # all slots and all probability on expert 0: E * 1 * 1
+    peaked = jnp.asarray([[1.0, 0.0, 0.0, 0.0]] * 5)
+    assert float(moe.load_balancing_loss(peaked, jnp.asarray([10, 0, 0, 0]))) \
+        == pytest.approx(4.0)
+    # f = (3/4, 1/4, 0, 0), P = (0.5, 0.3, 0.1, 0.1): 4 * (0.375 + 0.075)
+    probs = jnp.asarray([[0.5, 0.3, 0.1, 0.1]] * 2)
+    assert float(moe.load_balancing_loss(probs, jnp.asarray([3, 1, 0, 0]))) \
+        == pytest.approx(1.8)
+
+
+def test_router_z_loss_hand_values():
+    # logsumexp of n zeros is log n
+    assert float(moe.router_z_loss(jnp.zeros((7, 8)))) == pytest.approx(
+        math.log(8) ** 2, rel=1e-6)
+    logits = jnp.asarray([[0.0, 0.0], [math.log(3.0), 0.0]])
+    want = (math.log(2) ** 2 + math.log(4) ** 2) / 2
+    assert float(moe.router_z_loss(logits)) == pytest.approx(want, rel=1e-6)
+
+
+# ------------------------------------------------------------------- model
+
+def tiny(**over):
+    return TransformerConfig(**{**TINY, "dtype": jnp.float32, **over})
+
+
+def tiny_batch(cfg, rows=3, seq=32, seed=1):
+    ids = jax.random.randint(key(seed), (rows, seq + 1), 0, cfg.vocab_size)
+    return {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
+
+
+def test_routed_model_parameter_tree_and_shardings():
+    cfg = tiny()
+    params = transformer_init(key(0), cfg)
+    shapes = {k: v.shape for k, v in params["blocks"].items()}
+    assert shapes["router"] == (2, 64, 8)
+    assert shapes["w_gate"] == shapes["w_up"] == (2, 8, 64, 128)
+    assert shapes["w_down"] == (2, 8, 128, 64)
+    assert shapes["q_norm"] == (2, 64) and shapes["k_norm"] == (2, 32)
+    mesh = make_mesh({"expert": 2, "fsdp": 2}, devices=jax.devices()[:4])
+    shard = param_shardings(mesh, cfg)
+    assert jax.tree.structure(shard) == jax.tree.structure(params)
+    assert shard["blocks"]["w_gate"].spec == (None, "expert", "fsdp", None)
+    assert shard["blocks"]["w_down"].spec == (None, "expert", None, "fsdp")
+
+
+def test_qk_norm_is_an_rmsnorm_of_the_whole_projection():
+    cfg, plain = tiny(), tiny(qk_norm=False)
+    params = transformer_init(key(0), cfg)
+    batch = tiny_batch(cfg)
+    # scales of ones still normalise: the loss differs from the un-normed one
+    bare = {**params, "blocks": {k: v for k, v in params["blocks"].items()
+                                 if k not in ("q_norm", "k_norm")}}
+    with_norm = float(transformer_loss(params, batch, cfg))
+    assert with_norm != pytest.approx(
+        float(transformer_loss(bare, batch, plain)), rel=1e-6)
+    # scaling wq is undone by the norm over the whole 64-wide projection
+    scaled = {**params, "blocks": {**params["blocks"],
+                                   "wq": params["blocks"]["wq"] * 3.0}}
+    assert float(transformer_loss(scaled, batch, cfg)) == pytest.approx(
+        with_norm, rel=1e-4)
+    x = jax.random.normal(key(2), (5, 64))
+    np.testing.assert_allclose(
+        fused_rmsnorm(x, jnp.ones(64), eps=1e-5),
+        x / jnp.sqrt((x * x).mean(-1, keepdims=True) + 1e-5), rtol=1e-5)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_step_readings_and_dropless_load(remat):
+    cfg = tiny(remat=remat)
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    init, step, _ = make_train_step(cfg, mesh)
+    state = init(key(0))
+    batch = tiny_batch(cfg, rows=4, seq=64)
+    state, out = step(state, batch)
+    assert set(out) == {"loss", "grad_norm", "aux_loss", "z_loss", "expert_load"}
+    assert out["expert_load"].shape == (2, 8)
+    # dropless: every layer computes tokens x experts_per_token slots
+    np.testing.assert_array_equal(out["expert_load"].sum(-1), [4 * 64 * 2] * 2)
+    assert float(out["aux_loss"]) >= 1.0 - 1e-5 and float(out["z_loss"]) > 0
+    first = float(out["loss"])
+    for _ in range(3):
+        state, out = step(state, batch)
+    assert float(out["loss"]) < first and math.isfinite(float(out["grad_norm"]))
+
+
+def test_loss_adds_the_weighted_router_losses():
+    cfg = tiny()
+    params = transformer_init(key(0), cfg)
+    batch = tiny_batch(cfg)
+    total, readings = transformer_loss_and_readings(params, batch, cfg)
+    bare, _ = transformer_loss_and_readings(
+        params, batch, tiny(router_aux_loss_coef=0.0, router_z_loss_coef=0.0))
+    assert float(total) == pytest.approx(
+        float(bare) + 0.01 * float(readings["aux_loss"])
+        + 0.001 * float(readings["z_loss"]), rel=1e-6)
+    assert readings["expert_index"].shape == (2, 3 * 32, 2)
+    # the router trains: its gradient is not zero
+    grads = jax.grad(transformer_loss)(params, batch, cfg)
+    assert float(jnp.abs(grads["blocks"]["router"]).max()) > 0
+
+
+def test_kernels_in_interpret_mode_give_the_model_the_xla_path_s_loss(
+        monkeypatch):
+    cfg = tiny(n_layers=1)
+    params = transformer_init(key(0), cfg)
+    batch = tiny_batch(cfg, rows=2, seq=16)
+    want, g_want = jax.value_and_grad(transformer_loss)(params, batch, cfg)
+    real = moe.grouped_matmul
+    monkeypatch.setattr(
+        moe, "grouped_matmul",
+        lambda x, w, group_sizes, impl: real(x, w, group_sizes, interpret=True,
+                                             block_rows=8))
+    got, g_got = jax.value_and_grad(transformer_loss)(params, batch, cfg)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-4)
+
+
+def test_flops_count_active_parameters():
+    cfg = TransformerConfig(
+        vocab_size=50304, d_model=2048, n_layers=1, n_heads=16, n_kv_heads=16,
+        d_ff=1024, n_experts=64, experts_per_token=8, tied_embeddings=False)
+    assert flops_per_token(cfg, 4096) == pytest.approx(1.0719e9, rel=1e-4)
+    # the experts a token does not visit cost nothing
+    more = TransformerConfig(**{**cfg.__dict__, "n_experts": 128})
+    assert flops_per_token(more, 4096) - flops_per_token(cfg, 4096) \
+        == 3 * 2 * 2048 * 64
+
+
+def test_pallas_under_a_mesh_of_several_devices_is_refused():
+    cfg = tiny(attention_impl="pallas")
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    params = transformer_init(key(0), cfg)
+    with pytest.raises(NotImplementedError, match="expert parallelism"):
+        jax.eval_shape(
+            lambda p, b: transformer_loss(p, b, cfg, mesh=mesh),
+            params, tiny_batch(cfg, rows=2))

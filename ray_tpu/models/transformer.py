@@ -514,6 +514,12 @@ def hardware_flops_per_token(
     - per-block remat (cfg.remat) recomputes the whole block forward during
       the backward: +1 block fwd per layer.
 
+    The LM head is counted three times (logits and its two gradients) and
+    not a fourth: `lm_head_cross_entropy` forms both gradients from the
+    logits a chunk's forward holds and recomputes none. (While the head's
+    backward rematerialised each chunk's logits, this figure left that
+    fourth matmul out and read low by `embed`.)
+
     hardware-MFU = hardware_flops_per_token * tokens/s / peak must come out
     below 1.0 — the sanity bound useful-MFU alone cannot provide. It is an
     analytic figure: it counts what the step asks for, not what the chip

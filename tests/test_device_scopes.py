@@ -30,11 +30,16 @@ KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
 MOE_SCOPES = {"moe_router", "moe_dispatch", "moe_experts", "moe_combine",
               "qk_norm"}
 MOE_KERNELS = {"moe_gmm", "moe_tgmm"}
+VOCAB = 96  # the tiny steps' one dimension of this size: it finds the head
+
+
+def stacks_in(text):
+    """Every name stack of a lowered module's locations."""
+    return set(re.findall(r'loc\("([^"]+)"', text))
 
 
 def name_stacks(lowered):
-    """Every name stack of the lowered module's locations."""
-    return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+    return stacks_in(lowered.as_text(debug_info=True))
 
 
 def components(stacks):
@@ -43,7 +48,7 @@ def components(stacks):
 
 def lowered_transformer_step(**routed):
     cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        vocab_size=VOCAB, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=64, max_seq_len=16, remat=True, attention_impl="xla",
         tied_embeddings=False, **routed)
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
@@ -98,8 +103,14 @@ FAMILIES = {
 
 
 @pytest.fixture(scope="module")
-def stacks():
-    return {name: name_stacks(lower()) for name, (lower, _) in FAMILIES.items()}
+def texts():
+    return {name: lower().as_text(debug_info=True)
+            for name, (lower, _) in FAMILIES.items()}
+
+
+@pytest.fixture(scope="module")
+def stacks(texts):
+    return {name: stacks_in(text) for name, text in texts.items()}
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -127,6 +138,28 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
         assert any(re.search(r"stage2\)*/bn/", s) for s in stacks[family])
         assert any(re.search(r"stem\)*/conv/conv_general_dilated", s)
                    for s in stacks[family])
+
+
+@pytest.mark.parametrize("family", ["transformer", "moe_transformer"])
+def test_the_head_makes_its_logits_once(texts, stacks, family):
+    """`lm_head_cross_entropy` forms its gradients in the chunk's forward:
+    nothing of the head is rematerialised, a chunk (the tiny step has one)
+    has three matmuls with the vocabulary dimension (logits, the gradient to
+    the hidden rows, the gradient to the weight) and not a fourth, and they
+    run under the scope `lm_head_ce`, which is where a trace's
+    `lm_head_ce_time_share.tokens` looks for them."""
+    assert not [s for s in stacks[family]
+                if "rematted_computation" in s and "lm_head_ce" in s]
+    text = texts[family]
+    vocab_dot = rf"stablehlo\.dot_general[^\n]*[<x]{VOCAB}x"
+    holders = [f for f in re.split(r"\n(?=\s*func\.func )", text)
+               if re.search(vocab_dot, f)]
+    assert len(holders) == 1  # the scan's body, a function of its own
+    assert len(re.findall(vocab_dot, holders[0])) == 3
+    name = re.match(r"\s*func\.func \w+ @(\w+)", holders[0]).group(1)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = re.findall(rf"call @{name}\([^\n]*loc\((#loc\d+)\)", text)
+    assert calls and all("lm_head_ce" in locs[ref] for ref in calls)
 
 
 @pytest.mark.parametrize("lower,names", [

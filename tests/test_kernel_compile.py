@@ -129,6 +129,10 @@ def test_lm_head_cross_entropy_compiles_for_v5e(v5e):
     compiled = jax.jit(loss_and_grads).lower(hidden, unembed, targets).compile()
     # The chunked CE exists so that [B*T, V] f32 logits (6.6 GB) never are.
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    # Undifferentiated (evaluation): the same scan without the gradient work.
+    evaluated = jax.jit(lm_head_cross_entropy).lower(
+        hidden, unembed, targets).compile()
+    assert evaluated.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_train_step_compiles_for_four_v5e(v5e):

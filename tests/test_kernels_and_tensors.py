@@ -276,6 +276,161 @@ def test_lm_head_ce_matches_dense():
         np.testing.assert_allclose(a, b, atol=1e-4)
 
 
+def _parent_lm_head_ce(hidden, unembed, targets, *, chunk_tokens,
+                       ignore_index=-100):
+    """`lm_head_cross_entropy` as it stood before its `custom_vjp`: autodiff
+    through a scan of checkpointed chunks. Kept as the reference for the
+    roundings of the hand-written gradients (the logits' f32 cotangent cast
+    to the compute dtype before the two gradient matmuls)."""
+    B, T, d = hidden.shape
+    n = B * T
+    h = hidden.reshape(n, d)
+    t = targets.reshape(n)
+    pad = (-n) % chunk_tokens
+    if pad:
+        h = jnp.concatenate([h, jnp.zeros((pad, d), h.dtype)], axis=0)
+        t = jnp.concatenate(
+            [t, jnp.full((pad,), ignore_index, t.dtype)], axis=0)
+    h = h.reshape(-1, chunk_tokens, d)
+    t = t.reshape(-1, chunk_tokens)
+
+    @jax.checkpoint
+    def chunk_loss(hc, tc):
+        logits = (hc @ unembed.astype(hc.dtype)).astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        safe = jnp.where(tc == ignore_index, 0, tc)
+        picked = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
+        mask = (tc != ignore_index).astype(jnp.float32)
+        return ((lse - picked) * mask).sum(), mask.sum()
+
+    def body(carry, xs):
+        ls, ns = chunk_loss(*xs)
+        return (carry[0] + ls, carry[1] + ns), None
+
+    (loss_sum, count), _ = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)), (h, t))
+    return loss_sum / jnp.maximum(count, 1.0)
+
+
+def _ce_inputs(dtype=jnp.float32, V=257, B=2):
+    T, d = 96, 32
+    hidden = jax.random.normal(jax.random.PRNGKey(0), (B, T, d), dtype)
+    unembed = jax.random.normal(jax.random.PRNGKey(1), (d, V), jnp.float32)
+    targets = jax.random.randint(jax.random.PRNGKey(2), (B, T), 0, V)
+    return hidden, unembed, targets
+
+
+def _chunked(h, w, targets, chunk_tokens=80):  # 192 rows: a ragged last chunk
+    return lm_head_cross_entropy(h, w, targets, chunk_tokens=chunk_tokens)[0]
+
+
+def _dense(h, w, targets):
+    return softmax_cross_entropy((h @ w).astype(jnp.float32), targets)[0]
+
+
+def _case_f32_ragged_last_chunk():
+    h, w, t = _ce_inputs()
+    return (lambda h, w: _chunked(h, w, t), lambda h, w: _dense(h, w, t),
+            (h, w), dict(loss_rtol=1e-5, atol=1e-4))
+
+
+def _case_bf16_hidden_against_the_parent():
+    h, w, t = _ce_inputs(jnp.bfloat16)
+    return (lambda h, w: _chunked(h, w, t),
+            lambda h, w: _parent_lm_head_ce(h, w, t, chunk_tokens=80),
+            (h, w), dict(loss_rtol=1e-6, rel=1e-2))
+
+
+def _case_cotangent_not_one():
+    h, w, t = _ce_inputs()
+
+    def around(loss):
+        return lambda h, w: (3.0 * loss(h, w, t)
+                             + (h ** 2).mean() + (w ** 2).mean())
+
+    return around(_chunked), around(_dense), (h, w), dict(
+        loss_rtol=1e-5, atol=3e-4)
+
+
+def _case_tied_embeddings():
+    h, w, t = _ce_inputs()
+    return (lambda h, embed: _chunked(h, embed.T, t),
+            lambda h, embed: _dense(h, embed.T, t),
+            (h, w.T), dict(loss_rtol=1e-5, atol=1e-4))
+
+
+def _case_every_target_ignored():
+    h, w, t = _ce_inputs()
+    t = jnp.full_like(t, -100)
+    return (lambda h, w: _chunked(h, w, t), lambda h, w: 0.0 * _dense(h, w, t),
+            (h, w), dict(loss_rtol=0, atol=0))
+
+
+def _sharded(axes, hidden_spec, unembed_spec):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel import make_mesh
+
+    h, w, t = _ce_inputs(V=256, B=4)
+    n = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:n])
+    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, P(*spec)))
+    loss = lambda h, w: _chunked(h, w, t, chunk_tokens=64)
+    return (loss, loss, (h, w), dict(loss_rtol=1e-6, atol=1e-5),
+            (put(h, hidden_spec), put(w, unembed_spec)))
+
+
+def _case_mesh_fsdp_4():  # Mistral's: tokens over the batch, unembed over d
+    return _sharded({"fsdp": 4}, ("fsdp",), ("fsdp", None))
+
+
+def _case_mesh_tensor_2():  # the vocabulary sharded
+    return _sharded({"tensor": 2}, (), (None, "tensor"))
+
+
+_CE_BACKWARD_CASES = [
+    _case_f32_ragged_last_chunk, _case_bf16_hidden_against_the_parent,
+    _case_cotangent_not_one, _case_tied_embeddings,
+    _case_every_target_ignored, _case_mesh_fsdp_4, _case_mesh_tensor_2,
+]
+
+
+@pytest.mark.parametrize(
+    "case", _CE_BACKWARD_CASES, ids=lambda f: f.__name__[len("_case_"):])
+def test_lm_head_ce_backward(case):
+    """The gradients `lm_head_cross_entropy` forms in its forward pass are
+    those of the reference, under jit. A mesh case hands the jitted function
+    sharded arguments and holds it to its own single-device result."""
+    new, ref, args, tol, *placed = case()
+    grad = lambda f: jax.jit(jax.value_and_grad(f, argnums=(0, 1)))
+    ln, gn = grad(new)(*(placed[0] if placed else args))
+    lr, gr = grad(ref)(*args)
+    np.testing.assert_allclose(ln, lr, rtol=tol["loss_rtol"])
+    for a, b in zip(gn, gr):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all()
+        if "rel" in tol:
+            assert np.linalg.norm(a - b) <= tol["rel"] * np.linalg.norm(b)
+        else:
+            np.testing.assert_allclose(a, b, atol=tol["atol"])
+
+
+def test_lm_head_ce_undifferentiated_holds_no_weight_gradient():
+    """Evaluation runs the scan without the gradient work: the lowered
+    program has no f32 [d, V] array, which under `jax.grad` is the carried
+    gradient to `unembed`. (Lowered, not compiled: the CPU's compiler widens
+    a bf16 matmul's operands to f32 itself.)"""
+    h, w, t = _ce_inputs(jnp.bfloat16)
+    w = w.astype(jnp.bfloat16)
+    carried = f"tensor<{w.shape[0]}x{w.shape[1]}xf32>"
+    loss = lambda h, w: _chunked(h, w, t)
+    text = lambda f: jax.jit(f).lower(h, w).as_text()
+    assert carried in text(jax.grad(loss, argnums=1))
+    assert carried not in text(loss)
+    assert "stablehlo.while" in text(loss)  # still chunk by chunk
+
+
 def test_lm_head_ce_ignore_index():
     hidden = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 16), jnp.float32)
     unembed = jax.random.normal(jax.random.PRNGKey(1), (16, 33), jnp.float32)

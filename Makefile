@@ -17,10 +17,16 @@ lint:
 	$(PYTHON) -m ray_tpu.devtools.exc_flow --mutate swallow_cancel \
 		--expect-violation
 
+# Tier-1 as the driver runs it (/root/TESTS_LAST_RUN.json `commands`): six
+# xdist workers, a file a worker, 1,470 s. The driver also sets
+# ALLOW_MULTIPLE_LIBTPU_LOAD=1 in its own environment, so that the two files
+# that compile for a described v5e may land on different workers; it is
+# not set here, nor anywhere in the repository. (CI's tier-1 step runs the
+# same tests in one process.)
 test:
-	env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q -m 'not slow' \
-		--continue-on-collection-errors -p no:cacheprovider -p no:xdist \
-		-p no:randomly
+	timeout -k 10 1470 env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q \
+		-m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
+		-p xdist -n 6 --dist loadfile -p no:randomly
 
 build:
 	$(PYTHON) setup.py build_ext --inplace

@@ -48,8 +48,8 @@ from typing import Any, Callable, Dict, Iterator, List
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-# The dense decoder at the one full width the repo supports (bench.py's
-# transformer phase). `dtype` is a name so that the parent needs no JAX.
+# The GPT-2-small decoder (12 layers of 768, vocabulary 50304) at batch 32 x
+# sequence 1024. `dtype` is a name so that the parent needs no JAX.
 GPT2_SMALL = {
     "vocab_size": 50304,
     "d_model": 768,

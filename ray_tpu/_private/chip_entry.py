@@ -1,5 +1,6 @@
-"""What an entry point that drives the chip (`chip_smoke.py`, `bench.py`)
-settles before it starts workers. This module imports nothing from JAX.
+"""What an entry point that drives the chip (`chipbench/run.py`,
+`chip_smoke.py`) settles before it starts workers. This module imports
+nothing from JAX.
 
 **The compile cache.** One rule, applied before JAX is imported and before
 `ray_tpu.init()`, so every worker (which inherits the driver's environment,

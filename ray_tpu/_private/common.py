@@ -25,18 +25,15 @@ _CONFIG_DEFAULTS: Dict[str, Any] = {
     "object_store_memory_fraction": 0.3,
     "object_store_memory_min": 64 * 1024 * 1024,
     # Worker lease / pool.
-    "worker_lease_timeout_s": 60.0,
     # Zygote fork / worker process start: how long the raylet waits for the
     # forked pid before declaring the spawn wedged.
     "worker_start_timeout_s": 60.0,
-    "idle_worker_keep_s": 60.0,
     # How long an owner's idle leases park before returning to the raylet.
     # Bursty submitters reuse the full worker set across bursts; other
     # clients (and autoscaler idle scale-down) wait at most this long for
     # the pinned resources (in-flight lease requests force immediate
     # return).
     "worker_lease_idle_keep_s": 0.5,
-    "max_workers_per_node": 64,
     # Health checks (reference cadence: ray_config_def.h:847-853). The GCS
     # actively Pings every ALIVE node each period; `threshold` consecutive
     # misses mark it DEAD (catches wedged-but-connected raylets). period 0
@@ -50,7 +47,6 @@ _CONFIG_DEFAULTS: Dict[str, Any] = {
     "pubsub_max_buffered_msgs": 1000,
     # Task defaults.
     "default_max_task_retries": 3,
-    "actor_default_max_restarts": 0,
     # Lineage reconstruction: how many times a lost task-return object may be
     # recomputed by re-running its producing task (reference:
     # object_recovery_manager.h + task_manager.cc lineage bookkeeping).
@@ -312,11 +308,6 @@ _CONFIG_DEFAULTS: Dict[str, Any] = {
     # cancellation) this long past its wire deadline before the chaos
     # no-call-outlives-deadline invariant flags it.
     "rpc_deadline_grace_s": 0.5,
-    # Event-loop implementation for daemons ("asyncio" | "uvloop").
-    # "uvloop" installs the uvloop policy when the package is importable
-    # and falls back to stock asyncio (with a log line) when it is not —
-    # the A/B lives in `make perf`; see docs/perf.md "Native wire codec".
-    "rpc_event_loop": "asyncio",
     # Worker subprocesses flush deadline_stats deltas (met/shed/enforced/
     # overruns) to the GCS at this cadence, plus once on Exit, so the
     # no-call-outlives-deadline invariant sees overruns inside

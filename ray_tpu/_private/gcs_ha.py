@@ -426,9 +426,6 @@ def _main(argv=None) -> None:
         await stop.wait()
         await standby.stop()
 
-    from ray_tpu._private import rpc
-
-    rpc.install_event_loop()
     asyncio.run(_run())
 
 

@@ -3352,5 +3352,4 @@ async def main() -> None:
 
 if __name__ == "__main__":
     logging.basicConfig(level=logging.INFO)
-    rpc.install_event_loop()
     asyncio.run(main())

@@ -432,28 +432,6 @@ def _cancel_for_timeout(fut: asyncio.Future) -> None:
         fut.cancel()
 
 
-def install_event_loop() -> str:
-    """Install the event-loop policy named by ``config.rpc_event_loop``.
-
-    Returns the name actually in effect. "uvloop" requires the package;
-    when it is not importable (this tree does not vendor it) the stock
-    asyncio policy stays installed and a log line records the fallback, so
-    the knob is safe to flip in config without a hard dependency."""
-    choice = getattr(config, "rpc_event_loop", "asyncio")
-    if choice == "uvloop":
-        try:
-            import uvloop  # type: ignore
-
-            uvloop.install()
-            return "uvloop"
-        except ImportError:
-            logger.info(
-                "rpc_event_loop=uvloop requested but uvloop is not "
-                "installed; using asyncio"
-            )
-    return "asyncio"
-
-
 # ---------------------------------------------------------------------------
 # End-to-end deadlines.
 #

@@ -134,7 +134,6 @@ class SimCluster:
         config.refresh()
         _raise_nofile_limit(4 * self.num_nodes + 512)
 
-        rpc.install_event_loop()
         self._loop = asyncio.new_event_loop()
         self._thread = threading.Thread(
             target=self._loop_main, name="sim-cluster-loop", daemon=True

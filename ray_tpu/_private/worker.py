@@ -44,9 +44,6 @@ class Worker:
     # -- event loop bridge ---------------------------------------------------
 
     def _start_loop(self) -> None:
-        # Honor the rpc_event_loop knob (uvloop when installed; no-op on
-        # the stock config) before the policy mints the driver's loop.
-        rpc.install_event_loop()
         loop = asyncio.new_event_loop()
         started = threading.Event()
 

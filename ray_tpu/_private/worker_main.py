@@ -1166,7 +1166,6 @@ def main() -> None:
         level=logging.INFO,
         format=f"[worker {os.environ.get('RAY_TPU_WORKER_ID', '?')[:8]}] %(message)s",
     )
-    rpc.install_event_loop()
     asyncio.run(amain())
 
 

@@ -1,7 +1,7 @@
 """ResNet (v1.5) in functional JAX — the Train benchmark model family.
 
-North-star workload: ResNet-50 images/sec (reference e2e numbers in
-BASELINE.md rows 'Train ResNet e2e...', doc/source/train/benchmarks.rst).
+Cells that train it (BENCHMARK.json): `resnet50.ingest` and
+`resnet50.resident`, ResNet-50 v1.5 at 256 images of 224x224 a step.
 Convs are NHWC (XLA's preferred TPU layout → MXU-tiled); batch norm carries
 running stats in the state pytree; bf16 compute with f32 params/stats.
 
@@ -34,12 +34,6 @@ class ResNetConfig:
     num_classes: int = 1000
     width: int = 64
     dtype: Any = jnp.bfloat16
-    # TPU stem optimization (MLPerf-style): replace the 7x7/s2 conv on
-    # [H, W, 3] — whose cin=3, stride-2 shape badly underfills the MXU —
-    # with a 2x2 space-to-depth reshape to [H/2, W/2, 12] followed by a
-    # 4x4/s1 conv. Same receptive field and output shape, much better MXU
-    # tiling. Weight shapes differ, so it is opt-in (fresh training only).
-    space_to_depth: bool = False
 
     @property
     def stages(self) -> Sequence[int]:
@@ -83,11 +77,7 @@ def block_layout(cfg: ResNetConfig):
 def resnet_init(rng, cfg: ResNetConfig) -> Dict[str, Any]:
     keys = iter(jax.random.split(rng, 2048))
     params: Dict[str, Any] = {
-        "stem_conv": (
-            _conv_init(next(keys), 4, 4, 12, cfg.width)
-            if cfg.space_to_depth
-            else _conv_init(next(keys), 7, 7, 3, cfg.width)
-        ),
+        "stem_conv": _conv_init(next(keys), 7, 7, 3, cfg.width),
         "stem_bn": _bn_init(cfg.width),
         "blocks": [],
     }
@@ -169,22 +159,7 @@ def resnet_apply(params, images, cfg: ResNetConfig, train: bool = False):
     dt = cfg.dtype
     new_params = {k: v for k, v in params.items() if k != "blocks"}
     with jax.named_scope("stem"):
-        if cfg.space_to_depth:
-            b, h, w, c = images.shape
-            x = images.reshape(b, h // 2, 2, w // 2, 2, c)
-            x = x.transpose(0, 1, 3, 2, 4, 5).reshape(
-                b, h // 2, w // 2, 4 * c)
-            # 4x4/s1 with (1, 2) padding keeps the 7x7/s2 stem's output shape.
-            with jax.named_scope("conv"):
-                x = jax.lax.conv_general_dilated(
-                    x.astype(dt),
-                    params["stem_conv"].astype(dt),
-                    window_strides=(1, 1),
-                    padding=[(1, 2), (1, 2)],
-                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                )
-        else:
-            x = _conv(images, params["stem_conv"], stride=2, dtype=dt)
+        x = _conv(images, params["stem_conv"], stride=2, dtype=dt)
         x, new_params["stem_bn"] = _bn(x, params["stem_bn"], train)
         x = jax.nn.relu(x)
         x = jax.lax.reduce_window(

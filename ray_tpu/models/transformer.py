@@ -18,8 +18,8 @@ Parallelism (ray_tpu.parallel.mesh axes):
   sequence  — context parallelism; attention switches to ring_attention
 
 Capability analog of what the reference reaches only through integrations
-(SURVEY §5 long-context note: reference ships no native SP); here it is
-native. Reference GPT-2 fine-tune workload: BASELINE.json config #5.
+(SURVEY §5: it ships no native SP); here it is native. Cells that train it:
+`mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k` (BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -496,45 +496,7 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
 
     1 forward + backward at 2x forward (the PaLM / scaling-book accounting).
     Recomputation (remat, flash-backward recompute) is deliberately
-    excluded — this is the numerator for useful-MFU. Use
-    hardware_flops_per_token for what the chip actually executes.
+    excluded — this is the numerator for useful-MFU.
     """
     per_layer, attn, embed = _fwd_flops_per_token(cfg, seq_len)
     return 3 * (cfg.n_layers * (per_layer + attn) + embed)
-
-
-def hardware_flops_per_token(
-    cfg: TransformerConfig, seq_len: int, remat: Optional[bool] = None
-) -> float:
-    """Actually-executed train FLOPs/token, including recomputation:
-
-    - the pallas flash-attention backward recomputes the attention forward
-      (recompute custom_vjp in ops/flash_attention.py): +1 attention fwd
-      per layer, always;
-    - per-block remat (cfg.remat) recomputes the whole block forward during
-      the backward: +1 block fwd per layer.
-
-    The LM head is counted three times (logits and its two gradients) and
-    not a fourth: `lm_head_cross_entropy` forms both gradients from the
-    logits a chunk's forward holds and recomputes none. (While the head's
-    backward rematerialised each chunk's logits, this figure left that
-    fourth matmul out and read low by `embed`.)
-
-    hardware-MFU = hardware_flops_per_token * tokens/s / peak must come out
-    below 1.0 — the sanity bound useful-MFU alone cannot provide. It is an
-    analytic figure: it counts what the step asks for, not what the chip
-    does, and a value near 1 says nothing about any kernel (the 0.97 once
-    quoted for the GPT-2-width step stood beside a flash kernel far from its
-    roofline). What is measured on the chip: `model_mfu.tokens`, and per
-    kernel `flash_fwd_roofline.tokens`, `flash_bwd_dq_roofline.tokens`,
-    `flash_bwd_dkv_roofline.tokens`, with `recompute_time_share.tokens` for
-    what remat's second forward costs (BENCHMARK.json, PERF.md section 3).
-    """
-    if remat is None:
-        remat = cfg.remat
-    per_layer, attn, embed = _fwd_flops_per_token(cfg, seq_len)
-    fwd_layer = per_layer + attn
-    extra = cfg.n_layers * attn  # flash bwd recompute
-    if remat:
-        extra += cfg.n_layers * fwd_layer  # block fwd recompute
-    return 3 * (cfg.n_layers * fwd_layer + embed) + extra

@@ -4,7 +4,7 @@ The compute path of the framework is JAX/XLA; these Pallas kernels cover the
 ops where hand-tiling beats XLA's default lowering (attention above all —
 the reference delegates this tier to NCCL-adjacent GPU libraries; here it is
 MXU-tiled Pallas). Every op has an XLA fallback so the same code runs on CPU
-(tests) and TPU (bench) unchanged.
+(tests) and on the TPU (the cells of `chipbench`) unchanged.
 """
 
 from ray_tpu.ops.flash_attention import flash_attention, mha

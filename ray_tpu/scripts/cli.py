@@ -120,9 +120,6 @@ def cmd_start(args) -> None:
             await dash.stop()
         await node.stop()
 
-    from ray_tpu._private import rpc
-
-    rpc.install_event_loop()
     asyncio.run(main())
 
 

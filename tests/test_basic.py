@@ -160,3 +160,28 @@ def test_nested_object_ref_passthrough(ray_start_regular):
 def test_cluster_resources(ray_start_regular):
     res = ray_tpu.cluster_resources()
     assert res.get("CPU") == 4.0
+
+
+def test_every_config_knob_has_a_reader():
+    """A knob that no code under ray_tpu/ reads promises what nothing does
+    (tests that set it then believe in a cap nobody enforces)."""
+    import ast
+    import pathlib
+
+    from ray_tpu._private import common
+
+    read = set()
+    for path in pathlib.Path(ray_tpu.__file__).parent.rglob("*.py"):
+        nodes = list(ast.walk(ast.parse(path.read_text())))
+        # The names this module knows the table's instance by.
+        names = {"config"} if path.samefile(common.__file__) else set()
+        for node in nodes:
+            if (isinstance(node, ast.ImportFrom) and node.module
+                    and node.module.endswith("_private.common")):
+                names |= {a.asname or a.name for a in node.names
+                          if a.name == "config"}
+        read |= {node.attr for node in nodes
+                 if isinstance(node, ast.Attribute)
+                 and isinstance(node.value, ast.Name)
+                 and node.value.id in names}
+    assert sorted(set(common._CONFIG_DEFAULTS) - read) == []

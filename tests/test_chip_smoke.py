@@ -15,7 +15,7 @@ import pytest
 import chip_smoke
 from ray_tpu._private import chip_entry
 from ray_tpu.air import Result, RunConfig, ScalingConfig
-from ray_tpu.train import Checkpoint, TrainingFailedError
+from ray_tpu.train import Checkpoint
 from ray_tpu.train.jax import JaxTrainer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -56,17 +56,38 @@ def test_command_fails_at_once_without_a_chip(argv):
     assert "Traceback" in out.stderr
 
 
-def test_stages_reach_the_worker_device_assertion(
-    ray_start_cpu_mesh_workers, tmp_path
-):
-    """The fixture declares a TPU resource over CPU workers, so ingest, the
+def test_stages_reach_the_worker_device_assertion(tmp_path):
+    """The cluster declares a TPU resource over CPU workers, so ingest, the
     placement group, the lease and the gang all go through; the worker's
-    first act, the platform assertion, is what fails."""
-    with pytest.raises(TrainingFailedError) as err:
-        chip_smoke.run_training(TINY, batch=4, steps=2, chips=1,
-                                storage=str(tmp_path))
-    assert "the train worker sees" in str(err.value)
-    assert "'platform': 'cpu'" in str(err.value)
+    first act, the platform assertion, is what fails. Run apart: the gang's
+    parent must hold no JAX backend (`run_training` asserts it), and this
+    process may long since have initialised the CPU one."""
+    code = (
+        "import json, sys\n"
+        "import chip_smoke, ray_tpu\n"
+        "from ray_tpu.testing import cpu_mesh_worker_env\n"
+        "from ray_tpu.train import TrainingFailedError\n"
+        "ray_tpu.init(num_cpus=8, num_tpus=8,\n"
+        "             worker_env=cpu_mesh_worker_env(8))\n"
+        "try:\n"
+        f"    chip_smoke.run_training({TINY!r}, batch=4, steps=2, chips=1,\n"
+        f"                            storage={str(tmp_path)!r})\n"
+        "except TrainingFailedError as e:\n"
+        "    print(json.dumps({'error': str(e)}))\n"
+        "    code = 7\n"
+        "else:\n"
+        "    code = 0\n"
+        "ray_tpu.shutdown()\n"
+        "raise SystemExit(code)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=300, cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert out.returncode == 7, out.stderr[-2000:]
+    error = json.loads(out.stdout.splitlines()[-1])["error"]
+    assert "the train worker sees" in error
+    assert "'platform': 'cpu'" in error
 
 
 def test_use_tpu_without_a_chip_fails_at_once(ray_start_regular, tmp_path):

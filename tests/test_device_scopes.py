@@ -170,8 +170,8 @@ def test_the_kernels_carry_their_names_in_interpret_mode(lower, names):
     assert names <= found
 
 
-def test_resnet_stem_space_to_depth_has_its_conv_scope():
-    cfg = ResNetConfig(depth=18, num_classes=10, width=8, space_to_depth=True)
+def test_resnet_stem_has_its_conv_scope():
+    cfg = ResNetConfig(depth=18, num_classes=10, width=8)
     params = jax.eval_shape(lambda: resnet_init(jax.random.PRNGKey(0), cfg))
     images = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32)
     lowered = jax.jit(lambda p, x: resnet_apply(p, x, cfg)[0]).lower(params, images)

@@ -16,7 +16,6 @@ pytestmark = pytest.mark.scale
 
 @pytest.fixture
 def big_cluster(shutdown_only, monkeypatch):
-    monkeypatch.setenv("RAY_TPU_MAX_WORKERS_PER_NODE", "300")
     monkeypatch.setenv("RAY_TPU_ACTOR_RESOLVE_TIMEOUT_S", "800")
     ray_tpu.init(num_cpus=256, num_tpus=0)
     yield

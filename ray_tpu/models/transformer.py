@@ -259,9 +259,9 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None):
         gmm = partial(moe.grouped_matmul, group_sizes=slots.group_sizes,
                       impl=impl)
         hidden = jax.nn.silu(gmm(xs, blk["w_gate"])) * gmm(xs, blk["w_up"])
-        ys = gmm(hidden, blk["w_down"])
-    with jax.named_scope("moe_combine"):
-        out = moe.combine(ys, weights, slots.order, slots.inverse)
+    # names its own operations `moe_experts` and `moe_combine`, backward too
+    out = moe.project_and_combine(hidden, blk["w_down"], weights, slots,
+                                  impl=impl)
     return out.reshape(B, T, d), readings
 
 

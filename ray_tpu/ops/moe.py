@@ -11,7 +11,9 @@ imbalance, and the group sizes always sum to `T x k`.
     sort_slots(index, E)             order, its inverse, rows per expert
     dispatch(x, order, inverse)      [T, d] -> [T k, d], rows by expert
     grouped_matmul(x, w, sizes)      rows of group e times w[e]
-    combine(ys, weights, order, inverse)   [T k, d] -> [T, d]
+    combine(ys, weights, inverse)    [T k, d] -> [T, d]
+    project_and_combine(hidden, w_down, weights, slots)
+                                     the last two as one operation
 
 `grouped_matmul` is a matmul whose row groups go to different weights. On a
 TPU it is two Pallas kernels under a `custom_vjp`, after the grouped matmul
@@ -115,30 +117,13 @@ def dispatch(x, order, inverse):
     return _dispatch(x, order, inverse, order.shape[0] // x.shape[0])
 
 
-@jax.custom_vjp
-def combine(ys, weights, order, inverse):
+def combine(ys, weights, inverse):
     """Expert outputs `ys` [T k, d] in expert order back to tokens [T, d]:
     each token's k rows times its k `weights` [T, k] (float32), summed in
-    float32. The gradient of `ys` is a gather by `order`, not a scatter."""
+    float32. The plain forward: a routed model takes it inside
+    `project_and_combine`, whose backward never needs `ys`."""
     rows = _by_token(ys, inverse, weights.shape[1]).astype(jnp.float32)
     return (rows * weights[..., None]).sum(axis=1).astype(ys.dtype)
-
-
-def _combine_fwd(ys, weights, order, inverse):
-    return combine(ys, weights, order, inverse), (ys, weights, order, inverse)
-
-
-def _combine_bwd(res, dy):
-    ys, weights, order, inverse = res
-    k = weights.shape[1]
-    w_sorted = weights.reshape(-1)[order]
-    dys = (dy[order // k].astype(jnp.float32) * w_sorted[:, None]).astype(ys.dtype)
-    rows = _by_token(ys, inverse, k).astype(jnp.float32)
-    dw = jnp.einsum("td,tkd->tk", dy.astype(jnp.float32), rows)
-    return dys, dw.astype(weights.dtype), None, None
-
-
-combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def load_balancing_loss(probs, group_sizes):
@@ -465,35 +450,66 @@ def tgmm(x, dy, group_sizes, *, out_dtype=None,
 
 # ---------------------------------------------------------- grouped matmul
 
+def _product(x, w, group_sizes, kernels, tm, interpret):
+    """Rows of group e of `x` [M, K] times `w[e]` of `w` [E, K, N], cast to
+    x's dtype: `moe_gmm`, or `jax.lax.ragged_dot` without the kernels."""
+    w = w.astype(x.dtype)
+    if not kernels:
+        return jax.lax.ragged_dot(x, w, group_sizes)
+    e, k, n = w.shape
+    return gmm(x, w, group_sizes, interpret=interpret,
+               tiles=gmm_tiles("moe_gmm", x.shape[0], k, n, e, x.dtype, tm=tm))
+
+
+def _rows_gradient(dy, w, group_sizes, kernels, tm, interpret):
+    """`dy` [M, N] times `w[e]`^T: the product's transpose in the rows,
+    [M, K] in dy's dtype. `moe_gmm` on the transposed weights, or
+    `ragged_dot`'s own transpose."""
+    e, k, n = w.shape
+    w = w.astype(dy.dtype)
+    if not kernels:
+        rows = jax.ShapeDtypeStruct((dy.shape[0], k), dy.dtype)
+        return jax.linear_transpose(
+            lambda x: jax.lax.ragged_dot(x, w, group_sizes), rows)(dy)[0]
+    return gmm(dy, w, group_sizes, transpose_w=True, interpret=interpret,
+               tiles=gmm_tiles("moe_gmm", dy.shape[0], n, k, e, dy.dtype, tm=tm))
+
+
+def _weights_gradient(x, dy, w, group_sizes, kernels, tm, interpret):
+    """Per group `x^T dy`: the product's transpose in the weights, in w's
+    own dtype. `moe_tgmm`, or `ragged_dot`'s own transpose."""
+    if not kernels:
+        return jax.linear_transpose(
+            lambda w: jax.lax.ragged_dot(x, w.astype(x.dtype), group_sizes),
+            w)(dy)[0]
+    e, k, n = w.shape
+    return tgmm(x, dy, group_sizes, out_dtype=w.dtype, interpret=interpret,
+                tiles=gmm_tiles("moe_tgmm", x.shape[0], k, n, e, x.dtype,
+                                out_dtype=w.dtype, tm=tm))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _grouped_matmul(x, w, group_sizes, tm, interpret):
-    return _grouped_matmul_fwd(x, w, group_sizes, tm, interpret)[0]
+    return _product(x, w, group_sizes, True, tm, interpret)
 
 
 def _grouped_matmul_fwd(x, w, group_sizes, tm, interpret):
-    e, k, n = w.shape
-    tiles = gmm_tiles("moe_gmm", x.shape[0], k, n, e, x.dtype, tm=tm)
-    out = gmm(x, w.astype(x.dtype), group_sizes, tiles=tiles,
-              interpret=interpret)
-    return out, (x, w, group_sizes)
+    return _product(x, w, group_sizes, True, tm, interpret), (x, w, group_sizes)
 
 
 def _grouped_matmul_bwd(tm, interpret, res, dy):
     x, w, group_sizes = res
-    e, k, n = w.shape
-    rows = x.shape[0]
+    path = (group_sizes, True, tm, interpret)
     dy = dy.astype(x.dtype)
-    dx = gmm(dy, w.astype(x.dtype), group_sizes, transpose_w=True,
-             tiles=gmm_tiles("moe_gmm", rows, n, k, e, x.dtype, tm=tm),
-             interpret=interpret)
-    dw = tgmm(x, dy, group_sizes, out_dtype=w.dtype,
-              tiles=gmm_tiles("moe_tgmm", rows, k, n, e, x.dtype,
-                              out_dtype=w.dtype, tm=tm),
-              interpret=interpret)
-    return dx, dw, None
+    return (_rows_gradient(dy, w, *path), _weights_gradient(x, dy, w, *path),
+            None)
 
 
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def _kernels(impl: str, interpret: bool) -> bool:
+    return resolve_impl(impl) == "pallas" or interpret
 
 
 def grouped_matmul(x, w, group_sizes, *, impl: str = "auto",
@@ -506,7 +522,66 @@ def grouped_matmul(x, w, group_sizes, *, impl: str = "auto",
     dtype. impl: 'auto' (the Pallas kernels on a TPU, `jax.lax.ragged_dot`
     elsewhere) | 'pallas' | 'xla'. `interpret` and `block_rows` (a forced
     row tile) are for tests of the kernels off the chip."""
-    if resolve_impl(impl) == "pallas" or interpret:
-        return _grouped_matmul(x, w, group_sizes.astype(jnp.int32),
-                               block_rows, interpret)
-    return jax.lax.ragged_dot(x, w.astype(x.dtype), group_sizes.astype(jnp.int32))
+    group_sizes = group_sizes.astype(jnp.int32)
+    if _kernels(impl, interpret):
+        return _grouped_matmul(x, w, group_sizes, block_rows, interpret)
+    return _product(x, w, group_sizes, False, None, False)
+
+
+# ------------------------------------- the down projection back to tokens
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _project_and_combine(hidden, w_down, weights, slots, kernels, tm, interpret):
+    with jax.named_scope("moe_experts"):
+        ys = _product(hidden, w_down, slots.group_sizes, kernels, tm, interpret)
+    with jax.named_scope("moe_combine"):
+        return combine(ys, weights, slots.inverse)
+
+
+def _project_and_combine_fwd(hidden, w_down, weights, slots, kernels, tm,
+                             interpret):
+    out = _project_and_combine(hidden, w_down, weights, slots, kernels, tm,
+                               interpret)
+    return out, (hidden, w_down, weights, slots)
+
+
+def _project_and_combine_bwd(kernels, tm, interpret, res, dy):
+    """With `dh_u` the gradient of the rows before their weights: the
+    weight of slot s is `<dy_g[s], hidden[s] w_down[e]> = <dh_u[s],
+    hidden[s]>`, a row sum on the hidden side, and the weights enter the
+    other two gradients on that side too. `ys` is never needed."""
+    hidden, w_down, weights, slots = res
+    path = (slots.group_sizes, kernels, tm, interpret)
+    k = weights.shape[1]
+    hidden32 = hidden.astype(jnp.float32)
+    with jax.named_scope("moe_combine"):
+        dy_g = dy[slots.order // k].astype(hidden.dtype)
+        w_sorted = weights.reshape(-1)[slots.order][:, None]
+    with jax.named_scope("moe_experts"):
+        dh_u = _rows_gradient(dy_g, w_down, *path).astype(jnp.float32)
+        dhidden = (w_sorted * dh_u).astype(hidden.dtype)
+        weighted = (w_sorted * hidden32).astype(hidden.dtype)
+        dw_down = _weights_gradient(weighted, dy_g, w_down, *path)
+    with jax.named_scope("moe_combine"):
+        dw_sorted = (dh_u * hidden32).sum(axis=1)
+        dweights = dw_sorted[slots.inverse].reshape(weights.shape)
+    return dhidden, dw_down, dweights.astype(weights.dtype), None
+
+
+_project_and_combine.defvjp(_project_and_combine_fwd, _project_and_combine_bwd)
+
+
+def project_and_combine(hidden, w_down, weights, slots: Slots, *,
+                        impl: str = "auto", interpret: bool = False,
+                        block_rows: Optional[int] = None):
+    """The experts' down projection and the weighted sum back to tokens:
+    `combine(grouped_matmul(hidden, w_down), weights)`, [T, d] of `hidden`
+    [T k, f] in expert order, `w_down` [E, f, d] and `weights` [T, k]
+    (float32), operation for operation. One differentiable operation so
+    that the backward works on the hidden side (f wide, not d): its
+    residuals are its arguments, never the [T k, d] rows, which a
+    rematerialised block would have to make again and gather again by
+    `inverse` for the gradient of the weights. The other arguments are
+    `grouped_matmul`'s."""
+    return _project_and_combine(hidden, w_down, weights, slots,
+                                _kernels(impl, interpret), block_rows, interpret)

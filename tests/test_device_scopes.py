@@ -86,6 +86,23 @@ def lowered_moe_kernels():
     return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w)
 
 
+def lowered_project_and_combine():
+    """The one operation alone, kernels in interpret mode, under a scope
+    `step` so that what it names itself shows below it."""
+    hidden = jax.ShapeDtypeStruct((32, 16), jnp.float32)
+    w_down = jax.ShapeDtypeStruct((4, 16, 8), jnp.float32)
+    weights = jax.ShapeDtypeStruct((16, 2), jnp.float32)
+
+    def loss(hidden, w_down, weights):
+        slots = moe.sort_slots(jnp.arange(32).reshape(16, 2) % 4, 4)
+        with jax.named_scope("step"):
+            return moe.project_and_combine(
+                hidden, w_down, weights, slots, interpret=True).sum()
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        hidden, w_down, weights)
+
+
 def lowered_flash_kernels():
     q = jax.ShapeDtypeStruct((1, 128, 2, 64), jnp.float32)
 
@@ -134,6 +151,16 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
         assert any(re.search(r"attn_qkv\)*/qk_norm", s) for s in stacks[family])
         assert any("rematted_computation/mlp/moe_experts" in s
                    for s in stacks[family])
+        # `project_and_combine` keeps no [T k, d] rows: nothing of the
+        # combine is made again, and its backward (under `checkpoint`, not
+        # under `rematted_computation`) names itself as its forward does
+        assert not [s for s in stacks[family]
+                    if "rematted_computation" in s and "moe_combine" in s]
+        backward = {s.split("checkpoint/mlp/", 1)[1] for s in stacks[family]
+                    if s.startswith("checkpoint/mlp/")}
+        assert {"moe_combine/gather", "moe_combine/reduce_sum"} <= backward
+        assert any(re.fullmatch(r"moe_experts/.*ragged_dot_general", s)
+                   for s in backward)
     else:
         assert any(re.search(r"stage2\)*/bn/", s) for s in stacks[family])
         assert any(re.search(r"stem\)*/conv/conv_general_dilated", s)
@@ -168,6 +195,24 @@ def test_the_head_makes_its_logits_once(texts, stacks, family):
 def test_the_kernels_carry_their_names_in_interpret_mode(lower, names):
     found = components(name_stacks(lower()))
     assert names <= found
+
+
+def test_project_and_combine_names_its_backward():
+    """Which scope each operation of the backward carries: both kernels
+    under `moe_experts`; the gather of `dy`, the row sum that is the
+    weights' gradient and the gather of its scalars under `moe_combine`."""
+    stacks = name_stacks(lowered_project_and_combine())
+    backward = {s.split("transpose(jvp(step))/", 1)[1] for s in stacks
+                if "transpose(jvp(step))/" in s}
+    for want in ("moe_experts/moe_gmm/pallas_call",
+                 "moe_experts/moe_tgmm/pallas_call", "moe_experts/mul",
+                 "moe_combine/gather", "moe_combine/reduce_sum"):
+        assert want in backward, (want, sorted(backward))
+    forward = {s.split("/jvp(step)/", 1)[1] for s in stacks
+               if "/jvp(step)/" in s}
+    assert "moe_experts/moe_gmm/pallas_call" in forward
+    assert "moe_combine/gather" in forward and "moe_combine/mul" in forward
+    assert not [s for s in forward if "moe_tgmm" in s]
 
 
 def test_resnet_stem_has_its_conv_scope():

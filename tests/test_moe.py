@@ -1,10 +1,13 @@
 """The routed feed-forward's pieces (`ray_tpu/ops/moe.py`) and the model that
 uses them, on the CPU: routing, the dropless sort under skew, the grouped
 matmul and its two backward products (the XLA path and the Pallas kernels in
-interpret mode) against a per-expert einsum, dispatch and combine, the two
-auxiliary losses against hand values, QK-norm, and the step's readings."""
+interpret mode) against a per-expert einsum, dispatch and combine, the down
+projection and the combine as one operation against the two composed, the
+two auxiliary losses against hand values, QK-norm, the step's readings, and
+how many kernels and row gathers the lowered step holds."""
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -187,7 +190,7 @@ def test_dispatch_and_combine_round_trip_with_gather_gradients():
 
     def system(x, weights):
         xs = moe.dispatch(x, slots.order, slots.inverse)
-        return moe.combine(xs * 2.0, weights, slots.order, slots.inverse)
+        return moe.combine(xs * 2.0, weights, slots.inverse)
 
     def plain(x, weights):  # every slot's row is its token's, times 2
         return (2.0 * x[:, None, :] * weights[..., None]).sum(1)
@@ -201,9 +204,69 @@ def test_dispatch_and_combine_round_trip_with_gather_gradients():
 
 def test_combine_weights_are_not_renormalized():
     slots = moe.sort_slots(jnp.asarray([[0, 1]]), 2)
-    ys = jnp.ones((2, 4))
-    out = moe.combine(ys, jnp.asarray([[0.3, 0.2]]), slots.order, slots.inverse)
+    weights = jnp.asarray([[0.3, 0.2]])
+    out = moe.combine(jnp.ones((2, 4)), weights, slots.inverse)
     np.testing.assert_allclose(out, 0.5 * jnp.ones((1, 4)), rtol=1e-6)
+    one = moe.project_and_combine(  # each expert's weight sums its row to 1
+        jnp.ones((2, 3)), jnp.full((2, 3, 4), 1 / 3), weights, slots, impl="xla")
+    np.testing.assert_allclose(one, out, rtol=1e-6)
+
+
+@pytest.mark.parametrize("path", PATHS)
+@pytest.mark.parametrize("case", ["all_to_one", "one_empty", "random"])
+def test_project_and_combine_is_the_two_composed_with_all_three_gradients(
+        case, path):
+    """Value, and the gradients of `hidden`, `w_down` and the router's
+    `weights`, which the one operation takes on the hidden side and the
+    composed pair (XLA's own transposes, a scatter-add) on the rows'."""
+    tokens, k, n_experts, f, d = 24, 2, 8, 32, 48
+    slots = moe.sort_slots(skewed_index(case), n_experts)
+    hidden = jax.random.normal(key(1), (tokens * k, f))
+    w_down = jax.random.normal(key(2), (n_experts, f, d)) / math.sqrt(f)
+    weights = jax.random.uniform(key(3), (tokens, k))
+    cot = jax.random.normal(key(4), (tokens, d))
+
+    def system(hidden, w_down, weights):
+        return moe.project_and_combine(hidden, w_down, weights, slots,
+                                       **PATHS[path])
+
+    def composed(hidden, w_down, weights):
+        ys = moe.grouped_matmul(hidden, w_down, slots.group_sizes, impl="xla")
+        return moe.combine(ys, weights, slots.inverse)
+
+    out, vjp = jax.vjp(system, hidden, w_down, weights)
+    want, vjp_want = jax.vjp(composed, hidden, w_down, weights)
+    np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
+    for got, ref in zip(vjp(cot), vjp_want(cot)):
+        assert got.shape == ref.shape and got.dtype == ref.dtype
+        np.testing.assert_allclose(got, ref, atol=1e-5, rtol=1e-5)
+    if case == "one_empty":  # an expert of no rows gets a gradient of zeros
+        assert float(jnp.abs(vjp(cot)[1][5]).max()) == 0.0
+
+
+def test_project_and_combine_gives_f32_master_weights_an_f32_gradient():
+    tokens, k, n_experts, f, d = 32, 2, 2, 32, 16
+    slots = moe.sort_slots(
+        jax.random.randint(key(4), (tokens, k), 0, n_experts), n_experts)
+    hidden = jax.random.normal(key(1), (tokens * k, f), jnp.bfloat16)
+    w_down = jax.random.normal(key(2), (n_experts, f, d), jnp.float32)
+    weights = jax.random.uniform(key(3), (tokens, k))
+
+    def loss(hidden, w_down, weights):
+        return moe.project_and_combine(
+            hidden, w_down, weights, slots, interpret=True,
+            block_rows=16).astype(jnp.float32).sum()
+
+    dh, dw_down, dweights = jax.grad(loss, (0, 1, 2))(hidden, w_down, weights)
+    assert (dh.dtype, dw_down.dtype, dweights.dtype) == (
+        jnp.bfloat16, jnp.float32, jnp.float32)
+    # f32 accumulation of bf16 products, never rounded to bf16: the weight is
+    # rounded once, onto its bf16 row of `hidden`
+    weighted = (weights.reshape(-1)[slots.order][:, None]
+                * hidden.astype(jnp.float32)).astype(jnp.bfloat16)
+    want = jax.grad(lambda w: per_expert_einsum(
+        weighted.astype(jnp.float32), w, slots.group_sizes).sum())(w_down)
+    np.testing.assert_allclose(dw_down, want, atol=1e-5, rtol=1e-5)
 
 
 # ------------------------------------------------------------ aux losses
@@ -319,15 +382,41 @@ def test_kernels_in_interpret_mode_give_the_model_the_xla_path_s_loss(
     params = transformer_init(key(0), cfg)
     batch = tiny_batch(cfg, rows=2, seq=16)
     want, g_want = jax.value_and_grad(transformer_loss)(params, batch, cfg)
-    real = moe.grouped_matmul
-    monkeypatch.setattr(
-        moe, "grouped_matmul",
-        lambda x, w, group_sizes, impl: real(x, w, group_sizes, interpret=True,
-                                             block_rows=8))
+
+    def interpreted(real):
+        return lambda *args, impl, **kw: real(
+            *args, **kw, interpret=True, block_rows=8)
+
+    for name in ("grouped_matmul", "project_and_combine"):
+        monkeypatch.setattr(moe, name, interpreted(getattr(moe, name)))
     got, g_got = jax.value_and_grad(transformer_loss)(params, batch, cfg)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-4)
+
+
+def test_the_lowered_step_makes_no_expert_rows_again():
+    """The routed step with `remat=True`, lowered for a TPU with the kernels:
+    per layer eight `moe_gmm` (gate, up and down; gate and up made again;
+    three gradients of rows) and three `moe_tgmm`, and five gathers of
+    [T k, d] rows (dispatch, combine, dispatch made again; `dy` by `order`
+    and dispatch's gradient by `inverse`). A ninth and a sixth mean the down
+    projection's rows are a residual again: made twice, gathered twice."""
+    cfg = tiny(n_layers=2, d_model=128, n_heads=2, n_kv_heads=2, d_ff=256,
+               max_seq_len=128, n_experts=4, remat=True,
+               attention_impl="pallas")
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    init, step, _ = make_train_step(cfg, mesh)
+    state = jax.eval_shape(init, key(0))
+    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    text = step.trace(state, {"tokens": tokens, "targets": tokens}).lower(
+        lowering_platforms=("tpu",)).as_text()
+    # the layers are a scan: one body holds a layer's calls
+    assert len(re.findall(r'kernel_name = "moe_gmm"', text)) == 8
+    assert len(re.findall(r'kernel_name = "moe_tgmm"', text)) == 3
+    slot_rows = 2 * 128 * cfg.experts_per_token
+    assert len(re.findall(
+        rf"stablehlo\.gather[^\n]*-> tensor<{slot_rows}x128x", text)) == 5
 
 
 def test_flops_count_active_parameters():

@@ -12,6 +12,21 @@ and the router's load-balancing and z losses added to the loss. `qk_norm`
 puts an RMSNorm with a learned scale on the whole q and k projections before
 the heads are split and rotated. Both are OLMoE's (arXiv:2409.02060).
 
+A stack of unlike layers (`layer_types`, `n_dense_layers`) is a sequence of
+segments: runs of whole periods of one layout, each scanned, so compile time
+is O(periods' lengths). A layer's operator is attention or a gated short
+convolution (`_short_conv`), its feed-forward dense (the leading
+`n_dense_layers`) or routed. The router may score by sigmoid and choose by
+score plus a bias that the step moves toward balance and no gradient
+reaches (`expert_bias`), `qk_norm="head"` norms every head over its own
+width, and `experts_held = (first, n)` makes every routed layer compute `n`
+of the `n_experts` it routes over, as one chip of an expert-parallel
+deployment does. Those are LFM2's (`lfm2_moe`; the bias is
+arXiv:2408.15664's). A model of one kind of layer is one segment whose
+period is one layer, and its parameters are the one stacked tree
+`params["blocks"]`; otherwise `params["blocks"]` is a list of segments, each
+a list with one such tree per layer of its period.
+
 Parallelism (ray_tpu.parallel.mesh axes):
   data/fsdp — batch split; fsdp additionally shards params (ZeRO-3 style)
   tensor    — heads + mlp hidden + vocab split (Megatron layout)
@@ -27,7 +42,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -64,9 +79,22 @@ class TransformerConfig:
     n_experts: int = 0  # 0 => dense SwiGLU; else d_ff is one expert's width
     experts_per_token: int = 1
     norm_topk_prob: bool = False  # divide the chosen weights by their sum
-    qk_norm: bool = False  # RMSNorm on the q and k projections
+    # RMSNorm on q and k: over the whole projection (True, "projection") or
+    # over each head's width with one scale for all heads ("head")
+    qk_norm: Union[bool, str] = False
     router_aux_loss_coef: float = 0.01  # load balancing, mean over layers
     router_z_loss_coef: float = 0.001  # logsumexp(router logits)^2
+    # one of "full_attention" | "conv" per layer; () => attention everywhere
+    layer_types: Tuple[str, ...] = ()
+    conv_taps: int = 3  # the short convolution's reach, this token included
+    n_dense_layers: int = 0  # with n_experts: leading layers with a dense FF
+    d_ff_dense: Optional[int] = None  # their width; None => ff_dim
+    router_score: str = "softmax"  # softmax | sigmoid
+    norm_topk_eps: float = 0.0  # added to the sum norm_topk_prob divides by
+    expert_bias: bool = False  # choose by score + bias; the step moves it
+    expert_bias_update_rate: float = 1e-3
+    # (first, n): compute experts first..first+n-1 of the n_experts routed over
+    experts_held: Optional[Tuple[int, int]] = None
 
     @property
     def kv_heads(self) -> int:
@@ -82,40 +110,122 @@ class TransformerConfig:
             return self.d_ff
         return int(8 * self.d_model / 3 + 127) // 128 * 128  # SwiGLU, 128-mult
 
+    @property
+    def held(self) -> Tuple[int, int]:
+        return tuple(self.experts_held or (0, self.n_experts))
+
+    @property
+    def layers(self) -> Tuple["LayerKind", ...]:
+        """Every layer's kind, first to last."""
+        types = self.layer_types or ("full_attention",) * self.n_layers
+        if len(types) != self.n_layers:
+            raise ValueError(
+                f"layer_types names {len(types)} layers, n_layers is "
+                f"{self.n_layers}")
+        return tuple(
+            LayerKind(op, bool(self.n_experts) and i >= self.n_dense_layers)
+            for i, op in enumerate(types))
+
+    @property
+    def n_routed_layers(self) -> int:
+        return sum(kind.routed for kind in self.layers)
+
+
+class LayerKind(NamedTuple):
+    op: str        # "full_attention" | "conv"
+    routed: bool   # the feed-forward: routed experts, or dense
+
+
+class Segment(NamedTuple):
+    """`periods` repetitions of the layers in `layout`, scanned as one."""
+    layout: Tuple[LayerKind, ...]
+    periods: int
+
+
+def segments(cfg: TransformerConfig) -> List[Segment]:
+    """The stack as runs of whole periods: it is cut where the feed-forward
+    changes kind, and each run is as many repetitions of its shortest period
+    as make it up (one repetition of all of it, if it has none shorter)."""
+    kinds = cfg.layers
+    runs, start = [], 0
+    for i in range(1, len(kinds) + 1):
+        if i == len(kinds) or kinds[i].routed != kinds[start].routed:
+            runs.append(kinds[start:i])
+            start = i
+    out = []
+    for run in runs:
+        n = len(run)
+        period = next(p for p in range(1, n + 1) if n % p == 0 and all(
+            run[i] == run[i % p] for i in range(n)))
+        out.append(Segment(run[:period], n // period))
+    return out
+
 
 # ------------------------------------------------------------------ params
 
-def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
-    """f32 master params. Block params are stacked on a leading layer axis."""
-    k_emb, k_blk, k_out = jax.random.split(rng, 3)
-    d, h, hk, dh, f = (
-        cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.ff_dim,
-    )
+def _dense(key, shape, fan_in):
+    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
 
-    def dense(key, shape, fan_in):
-        return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
 
-    L = cfg.n_layers
+def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
+    """`L` layers of one kind, every leaf stacked on a leading layer axis."""
+    d, h, hk, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
     ks = jax.random.split(k_blk, 7)
-    # a routed feed-forward stacks its experts behind the layer axis
-    ff = (L, cfg.n_experts) if cfg.n_experts else (L,)
-    blocks = {
-        "attn_norm": jnp.ones((L, d), jnp.float32),
-        "wq": dense(ks[0], (L, d, h * dh), d),
-        "wk": dense(ks[1], (L, d, hk * dh), d),
-        "wv": dense(ks[2], (L, d, hk * dh), d),
-        "wo": dense(ks[3], (L, h * dh, d), h * dh),
+    if kind.op == "conv":
+        blocks = {
+            "conv_norm": jnp.ones((L, d), jnp.float32),
+            "conv_in": _dense(ks[0], (L, d, 3 * d), d),
+            "conv_w": _dense(ks[1], (L, cfg.conv_taps, d), cfg.conv_taps),
+            "conv_out": _dense(ks[3], (L, d, d), d),
+        }
+    else:
+        blocks = {
+            "attn_norm": jnp.ones((L, d), jnp.float32),
+            "wq": _dense(ks[0], (L, d, h * dh), d),
+            "wk": _dense(ks[1], (L, d, hk * dh), d),
+            "wv": _dense(ks[2], (L, d, hk * dh), d),
+            "wo": _dense(ks[3], (L, h * dh, d), h * dh),
+        }
+    # a routed feed-forward stacks its (held) experts behind the layer axis
+    ff = (L, cfg.held[1]) if kind.routed else (L,)
+    f = cfg.ff_dim
+    if cfg.n_experts and not kind.routed and cfg.d_ff_dense is not None:
+        f = cfg.d_ff_dense
+    blocks.update({
         "mlp_norm": jnp.ones((L, d), jnp.float32),
-        "w_gate": dense(ks[4], (*ff, d, f), d),
-        "w_up": dense(ks[5], (*ff, d, f), d),
-        "w_down": dense(ks[6], (*ff, f, d), f),
-    }
-    if cfg.n_experts:
-        blocks["router"] = dense(
+        "w_gate": _dense(ks[4], (*ff, d, f), d),
+        "w_up": _dense(ks[5], (*ff, d, f), d),
+        "w_down": _dense(ks[6], (*ff, f, d), f),
+    })
+    if kind.routed:
+        blocks["router"] = _dense(
             jax.random.fold_in(k_blk, 7), (L, d, cfg.n_experts), d)
-    if cfg.qk_norm:
-        blocks["q_norm"] = jnp.ones((L, h * dh), jnp.float32)
-        blocks["k_norm"] = jnp.ones((L, hk * dh), jnp.float32)
+    if cfg.qk_norm and kind.op != "conv":
+        per_head = cfg.qk_norm == "head"
+        blocks["q_norm"] = jnp.ones((L, dh if per_head else h * dh), jnp.float32)
+        blocks["k_norm"] = jnp.ones((L, dh if per_head else hk * dh), jnp.float32)
+    return blocks
+
+
+def _one_kind(segs: List[Segment]) -> bool:
+    return len(segs) == 1 and len(segs[0].layout) == 1
+
+
+def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
+    """f32 master params. Block params are stacked on a leading layer axis:
+    one tree for a model of one kind of layer, else a list of segments, each
+    a list of one tree per layer of its period, stacked over its periods."""
+    k_emb, k_blk, k_out = jax.random.split(rng, 3)
+    d = cfg.d_model
+    segs = segments(cfg)
+    if _one_kind(segs):
+        blocks = _blocks_init(k_blk, cfg, segs[0].layout[0], cfg.n_layers)
+    else:
+        blocks = [
+            [_blocks_init(jax.random.fold_in(jax.random.fold_in(k_blk, si), pi),
+                          cfg, kind, seg.periods)
+             for pi, kind in enumerate(seg.layout)]
+            for si, seg in enumerate(segs)]
     params = {
         "embed": jax.random.normal(
             k_emb, (cfg.vocab_size, d), jnp.float32
@@ -124,8 +234,14 @@ def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
         "final_norm": jnp.ones((d,), jnp.float32),
     }
     if not cfg.tied_embeddings:
-        params["unembed"] = dense(k_out, (d, cfg.vocab_size), d)
+        params["unembed"] = _dense(k_out, (d, cfg.vocab_size), d)
     return params
+
+
+def expert_bias_init(cfg: TransformerConfig):
+    """The routers' selection bias, [routed layers, n_experts] float32:
+    zeros, as training starts. State of the step, not a parameter."""
+    return jnp.zeros((cfg.n_routed_layers, cfg.n_experts), jnp.float32)
 
 
 _LOGICAL_AXES = {
@@ -151,6 +267,31 @@ _ROUTED_AXES = {
     "router": ("layers", "embed", None),
 }
 _QK_NORM_AXES = {"q_norm": ("layers", "heads"), "k_norm": ("layers", "kv")}
+_HEAD_NORM_AXES = {"q_norm": ("layers", None), "k_norm": ("layers", None)}
+# the three streams of `conv_in` are split after the product and the
+# convolution is per channel: neither is cut along the channels
+_CONV_AXES = {
+    "conv_norm": ("layers", None),
+    "conv_in": ("layers", "embed", None),
+    "conv_w": ("layers", None, None),
+    "conv_out": ("layers", None, "embed"),
+}
+_ATTENTION_KEYS = ("attn_norm", "wq", "wk", "wv", "wo")
+
+
+def _block_axes(cfg: TransformerConfig, kind: LayerKind):
+    base = _LOGICAL_AXES["blocks"]
+    if kind.op == "conv":
+        table = {**_CONV_AXES,
+                 **{k: v for k, v in base.items() if k not in _ATTENTION_KEYS}}
+    else:
+        table = dict(base)
+        if cfg.qk_norm:
+            table.update(
+                _HEAD_NORM_AXES if cfg.qk_norm == "head" else _QK_NORM_AXES)
+    if kind.routed:
+        table.update(_ROUTED_AXES)
+    return table
 
 
 def param_shardings(mesh, cfg: TransformerConfig):
@@ -161,16 +302,19 @@ def param_shardings(mesh, cfg: TransformerConfig):
     def build(node):
         if isinstance(node, dict):
             return {k: build(v) for k, v in node.items()}
+        if isinstance(node, list):
+            return [build(v) for v in node]
         return NamedSharding(mesh, rules.spec(node))
 
     table = dict(_LOGICAL_AXES)
     if cfg.tied_embeddings:
         table.pop("unembed", None)
-    table["blocks"] = {
-        **table["blocks"],
-        **(_ROUTED_AXES if cfg.n_experts else {}),
-        **(_QK_NORM_AXES if cfg.qk_norm else {}),
-    }
+    segs = segments(cfg)
+    if _one_kind(segs):
+        table["blocks"] = _block_axes(cfg, segs[0].layout[0])
+    else:
+        table["blocks"] = [[_block_axes(cfg, kind) for kind in seg.layout]
+                           for seg in segs]
     return build(table)
 
 
@@ -224,11 +368,48 @@ def _kernel_impl(cfg: TransformerConfig) -> str:
     )
 
 
-def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None):
+def _attention_layer(x, blk, positions, cfg: TransformerConfig,
+                     seq_axis: Optional[str], seq_size: int, mesh=None):
+    """x + attention(norm(x)): projections, QK-norm, RoPE, the kernel."""
+    B, T, d = x.shape
+    h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    dt = cfg.dtype
+    per_head = cfg.qk_norm == "head"
+
+    def qk_norm(q, k):
+        with jax.named_scope("qk_norm"):
+            return (fused_rmsnorm(q, blk["q_norm"], eps=cfg.norm_eps),
+                    fused_rmsnorm(k, blk["k_norm"], eps=cfg.norm_eps))
+
+    with jax.named_scope("attn_qkv"):
+        y = fused_rmsnorm(x, blk["attn_norm"], eps=cfg.norm_eps)
+        q, k = y @ blk["wq"].astype(dt), y @ blk["wk"].astype(dt)
+        if cfg.qk_norm and not per_head:  # over the whole projection
+            q, k = qk_norm(q, k)
+        q = q.reshape(B, T, h, dh)
+        k = k.reshape(B, T, hk, dh)
+        if per_head:  # every head over its own dh, one scale for all heads
+            q, k = qk_norm(q, k)
+        v = (y @ blk["wv"].astype(dt)).reshape(B, T, hk, dh)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh)
+    with jax.named_scope("attn_out"):
+        return x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
+
+
+def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
     """The routed feed-forward on normed activations `y` [B, T, d]: the sum
     over a token's `experts_per_token` experts of p_e * SwiGLU_e(y), and the
     layer's router readings {aux_loss, z_loss, expert_load [E],
-    expert_index [B T, k]}. Dropless: `expert_load` sums to B T k."""
+    expert_index [B T, k]}. Dropless: `expert_load` sums to B T k.
+
+    A layer that holds a share of the experts (`w_gate` has fewer than the
+    router's width) sums over the held among a token's experts and leaves
+    the others' terms out; its readings gain `held_slots` (the slots whose
+    expert it holds) and `dropped_slots` (those of them it did not compute:
+    0). `bias` [E] is the router's selection bias."""
     B, T, d = y.shape
     impl = _kernel_impl(cfg)
     if impl == "pallas" and mesh is not None and mesh.size > 1:
@@ -245,14 +426,33 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None):
             precision=jax.lax.Precision.HIGHEST,
         )
         probs, weights, index = moe.route(
-            logits, cfg.experts_per_token, cfg.norm_topk_prob)
-        slots = moe.sort_slots(index, cfg.n_experts)
+            logits, cfg.experts_per_token, cfg.norm_topk_prob,
+            score=cfg.router_score, bias=bias, eps=cfg.norm_topk_eps)
+        n_held = blk["w_gate"].shape[0]
+        share = n_held < cfg.n_experts
+        slots = moe.sort_slots(index, cfg.n_experts,
+                               (cfg.held[0], n_held) if share else None)
+        load = (moe.expert_load(index, cfg.n_experts) if share
+                else slots.group_sizes)
         readings = {
-            "aux_loss": moe.load_balancing_loss(probs, slots.group_sizes),
+            "aux_loss": moe.load_balancing_loss(probs, load),
             "z_loss": moe.router_z_loss(logits),
-            "expert_load": slots.group_sizes,
+            "expert_load": load,
             "expert_index": index,
         }
+        if share:
+            held_rows = slots.group_sizes.sum()
+            first = cfg.held[0]
+            readings["held_slots"] = jnp.logical_and(
+                index >= first, index < first + n_held).sum(dtype=jnp.int32)
+            readings["dropped_slots"] = readings["held_slots"] - held_rows
+
+    if share:  # names its own operations as below, a chunk of rows at a time
+        out = moe.experts_of_share(
+            tokens, blk["w_gate"], blk["w_up"], blk["w_down"], weights, slots,
+            impl=impl, chunk=moe.held_chunk(
+                slots.order.shape[0], n_held, cfg.n_experts))
+        return out.reshape(B, T, d), readings
     with jax.named_scope("moe_dispatch"):
         xs = moe.dispatch(tokens, slots.order, slots.inverse)
     with jax.named_scope("moe_experts"):
@@ -265,37 +465,52 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None):
     return out.reshape(B, T, d), readings
 
 
-def _block(x, blk, positions, cfg: TransformerConfig,
+def _short_conv(x, blk, cfg: TransformerConfig):
+    """The gated short convolution on `x` [B, T, d] (LFM2's operator): the
+    normed input projected to three streams B, C, X; `u = B * X`; a causal
+    convolution of `conv_taps` taps per channel, `c_t = sum_i w_i
+    u_{t - taps + 1 + i}` with u zero before the sequence; `(C * c) W_out`.
+    The convolution is `conv_taps` shifted multiply-adds in the compute
+    dtype, which XLA fuses with the gates into one pass over [B, T, d]."""
+    dt = cfg.dtype
+    taps = blk["conv_w"].shape[0]
+    with jax.named_scope("conv_in"):
+        y = fused_rmsnorm(x, blk["conv_norm"], eps=cfg.norm_eps)
+        b, c, xs = jnp.split(y @ blk["conv_in"].astype(dt), 3, axis=-1)
+    with jax.named_scope("conv_gate"):
+        u = b * xs
+        w = blk["conv_w"].astype(dt)
+        padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+        conv = sum(w[i] * jax.lax.dynamic_slice_in_dim(padded, i, u.shape[1], 1)
+                   for i in range(taps))
+        gated = c * conv
+    with jax.named_scope("conv_out"):
+        return gated @ blk["conv_out"].astype(dt)
+
+
+def _block(x, blk, positions, bias, cfg: TransformerConfig,
            seq_axis: Optional[str], seq_size: int, mesh=None):
-    """One block: (x, the routed feed-forward's readings or None)."""
-    B, T, d = x.shape
-    h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    """One block: (x, the routed feed-forward's readings or None). What the
+    block is, its parameters say: a short convolution where it has
+    `conv_in`, a routed feed-forward where it has a `router`."""
     dt = cfg.dtype
 
     # The scopes name the step's device work in a profiler trace
     # (docs/observability.md, "Device scopes"); they are metadata only.
-    with jax.named_scope("attn_qkv"):
-        y = fused_rmsnorm(x, blk["attn_norm"], eps=cfg.norm_eps)
-        q, k = y @ blk["wq"].astype(dt), y @ blk["wk"].astype(dt)
-        if cfg.qk_norm:
-            with jax.named_scope("qk_norm"):
-                q = fused_rmsnorm(q, blk["q_norm"], eps=cfg.norm_eps)
-                k = fused_rmsnorm(k, blk["k_norm"], eps=cfg.norm_eps)
-        q = q.reshape(B, T, h, dh)
-        k = k.reshape(B, T, hk, dh)
-        v = (y @ blk["wv"].astype(dt)).reshape(B, T, hk, dh)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-    with jax.named_scope("attention"):
-        o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh)
-    with jax.named_scope("attn_out"):
-        x = x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
+    if "conv_in" in blk:
+        if seq_axis is not None:
+            raise NotImplementedError(
+                "the short convolution is not mapped over a sequence axis")
+        with jax.named_scope("short_conv"):
+            x = x + _short_conv(x, blk, cfg)
+    else:
+        x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size, mesh)
 
     readings = None
     with jax.named_scope("mlp"):
         y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
-        if cfg.n_experts:
-            routed, readings = _routed_ffn(y, blk, cfg, mesh)
+        if "router" in blk:
+            routed, readings = _routed_ffn(y, blk, cfg, mesh, bias)
             x = x + routed
         else:
             gate = jax.nn.silu(y @ blk["w_gate"].astype(dt))
@@ -304,11 +519,25 @@ def _block(x, blk, positions, cfg: TransformerConfig,
     return x, readings
 
 
+def _layer_axis(trees, stack: bool):
+    """Trees of arrays with a leading layer axis as one such tree: `stack`
+    interleaves them (the layers of a period, scanned over the periods),
+    else they follow one another (segments). One tree is itself."""
+    if len(trees) == 1:
+        return trees[0]
+    if stack:
+        return jax.tree.map(
+            lambda *xs: jnp.stack(xs, axis=1).reshape(-1, *xs[0].shape[1:]),
+            *trees)
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *trees)
+
+
 def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
                          positions=None, seq_axis: Optional[str] = None,
-                         seq_size: int = 1, mesh=None):
+                         seq_size: int = 1, mesh=None, expert_bias=None):
     """`transformer_hidden`, and the routed feed-forwards' readings stacked
-    on a leading layer axis (None for a dense model)."""
+    on a leading layer axis (None for a dense model). `expert_bias`
+    [routed layers, E] is the routers' selection bias."""
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -321,10 +550,37 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
     if cfg.remat:
         blk_fn = jax.checkpoint(blk_fn, static_argnums=())
 
-    def scan_body(x, blk):
-        return blk_fn(x, blk, positions)
+    blocks = params["blocks"]
+    if isinstance(blocks, dict):  # one kind of layer: one segment of it
+        blocks = [[blocks]]
 
-    x, readings = jax.lax.scan(scan_body, x, params["blocks"])
+    def scan_body(x, period):
+        blks, biases = period
+        readings = []
+        for blk, bias in zip(blks, biases):
+            x, reading = blk_fn(x, blk, positions, bias)
+            if reading is not None:
+                readings.append(reading)
+        return x, readings
+
+    readings, routed_before = [], 0
+    for blks in blocks:
+        # this segment's rows of the bias, one [periods, E] per routed layer
+        # of its period
+        biases = [None] * len(blks)
+        routed = [i for i, blk in enumerate(blks) if "router" in blk]
+        if expert_bias is not None and routed:
+            periods = blks[0]["mlp_norm"].shape[0]
+            rows = expert_bias[
+                routed_before:routed_before + periods * len(routed)
+            ].reshape(periods, len(routed), -1)
+            routed_before += periods * len(routed)
+            for j, i in enumerate(routed):
+                biases[i] = rows[:, j]
+        x, of_period = jax.lax.scan(scan_body, x, (blks, biases))
+        if of_period:
+            readings.append(_layer_axis(of_period, stack=True))
+    readings = _layer_axis(readings, stack=False) if readings else None
     with jax.named_scope("final_norm"):
         x = fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
     return x, readings
@@ -333,7 +589,7 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
 def transformer_hidden(params, tokens, cfg: TransformerConfig, **kw):
     """Forward through the blocks: [B, T] tokens -> [B, T, d] normed hidden.
 
-    Keywords: `positions`, `seq_axis`, `seq_size`, `mesh`. When called under
+    Keywords: `positions`, `seq_axis`, `seq_size`, `mesh`, `expert_bias`. When called under
     shard_map with the sequence sharded, pass seq_axis and positions holding
     GLOBAL positions so RoPE and causal masks are correct. When called under
     a jit that shards over `mesh`, pass the mesh: the Pallas attention
@@ -369,8 +625,11 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     load-balancing loss and `router_z_loss_coef` times the router z-loss,
     each a mean over the layers, and its readings are `aux_loss` and
     `z_loss` (those means), `expert_load` [L, E] (slots per expert; every
-    row sums to B T k) and `expert_index` [L, B T, k]. A dense model's
-    readings are empty."""
+    row sums to B T k) and `expert_index` [L, B T, k], L the routed layers;
+    a coefficient of 0 adds nothing. A share of the experts
+    (`experts_held`) also reads `held_slots` and `dropped_slots` [L]. A
+    dense model's readings are empty. `expert_bias=` [L, E] is the routers'
+    selection bias, for a model that has one."""
     if "targets" in batch:
         tokens, targets = batch["tokens"], batch["targets"]
     else:
@@ -383,8 +642,9 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
         return loss, {}
     readings = dict(readings, aux_loss=readings["aux_loss"].mean(),
                     z_loss=readings["z_loss"].mean())
-    loss = (loss + cfg.router_aux_loss_coef * readings["aux_loss"]
-            + cfg.router_z_loss_coef * readings["z_loss"])
+    if cfg.router_aux_loss_coef or cfg.router_z_loss_coef:
+        loss = (loss + cfg.router_aux_loss_coef * readings["aux_loss"]
+                + cfg.router_z_loss_coef * readings["z_loss"])
     return loss, readings
 
 
@@ -396,7 +656,8 @@ def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
 # -------------------------------------------------------------- train step
 
 # what a routed model's step reports beside loss and grad_norm
-_STEP_READINGS = ("aux_loss", "z_loss", "expert_load")
+_STEP_READINGS = ("aux_loss", "z_loss", "expert_load", "held_slots",
+                  "dropped_slots")
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
@@ -405,7 +666,11 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
     state = {'params': f32 sharded, 'opt': optax state, 'step': scalar}
     step(state, batch) -> (state, metrics); params/opt donated. metrics are
     loss and grad_norm, and with routed experts aux_loss, z_loss and
-    expert_load [L, E].
+    expert_load [L, E]; of a share of the experts also held_slots and
+    dropped_slots [L]. With `cfg.expert_bias` the state has 'expert_bias'
+    [L, E] float32, which the optimizer does not own: after the optimizer's
+    update the step moves it by `expert_bias_update_rate` toward the experts
+    that this step's load left short, and reports expert_bias_abs_max.
 
     DP/FSDP/TP come from the in/out shardings (XLA inserts psum /
     all-gather / reduce-scatter over ICI); if the mesh has a 'sequence'
@@ -439,6 +704,8 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         transform_non_params=lambda _: repl,
     )
     state_shard = {"params": p_shard, "opt": opt_shard, "step": repl}
+    if cfg.expert_bias:
+        state_shard["expert_bias"] = repl
 
     def init_state(rng):
         params = transformer_init(rng, cfg)
@@ -446,49 +713,67 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
             lambda x, s: jax.device_put(x, s), params, p_shard
         )
         opt = jax.jit(optimizer.init, out_shardings=opt_shard)(params)
-        return {"params": params, "opt": opt,
-                "step": jax.device_put(jnp.zeros((), jnp.int32), repl)}
+        state = {"params": params, "opt": opt,
+                 "step": jax.device_put(jnp.zeros((), jnp.int32), repl)}
+        if cfg.expert_bias:
+            state["expert_bias"] = jax.device_put(expert_bias_init(cfg), repl)
+        return state
 
-    def loss_fn(params, batch):
+    def loss_fn(params, batch, **bias):
         loss, readings = transformer_loss_and_readings(
-            params, batch, cfg, mesh=mesh)
+            params, batch, cfg, mesh=mesh, **bias)
         return loss, {k: readings[k] for k in _STEP_READINGS if k in readings}
 
     @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))
     def step(state, batch):
+        bias = ({"expert_bias": state["expert_bias"]} if cfg.expert_bias
+                else {})
         (loss, readings), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state["params"], batch)
+            state["params"], batch, **bias)
         with jax.named_scope("optimizer"):
             updates, opt = optimizer.update(
                 grads, state["opt"], state["params"]
             )
             params = optax.apply_updates(state["params"], updates)
             gnorm = optax.global_norm(grads)
-        return (
-            {"params": params, "opt": opt, "step": state["step"] + 1},
-            {"loss": loss, "grad_norm": gnorm, **readings},
-        )
+        state = {"params": params, "opt": opt, "step": state["step"] + 1}
+        if bias:
+            with jax.named_scope("expert_bias"):
+                state["expert_bias"] = moe.update_expert_bias(
+                    bias["expert_bias"], readings["expert_load"],
+                    cfg.expert_bias_update_rate)
+                readings["expert_bias_abs_max"] = jnp.max(
+                    jnp.abs(state["expert_bias"]))
+        return state, {"loss": loss, "grad_norm": gnorm, **readings}
 
     return init_state, step, {"tokens": tok_sharding, "replicated": repl,
                               "params": p_shard, "state": state_shard}
 
 
 def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
-    """(matmul fwd flops/token per layer, causal attn fwd flops/token per
-    layer, lm-head fwd flops/token)."""
+    """(matmul fwd flops/token over the layers, causal attn fwd flops/token
+    over the layers, lm-head fwd flops/token)."""
     d, f = cfg.d_model, cfg.ff_dim
     h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    ff = 2 * 3 * d * f
-    if cfg.n_experts:  # active parameters: the router and a token's experts
-        ff = 2 * d * cfg.n_experts + cfg.experts_per_token * ff
-    per_layer = 2 * d * (h * dh + 2 * hk * dh) + 2 * h * dh * d + ff
+    dense_f = f if cfg.d_ff_dense is None or not cfg.n_experts else cfg.d_ff_dense
+    # active parameters: the router and the held among a token's experts
+    routed = 2 * d * cfg.n_experts + (
+        cfg.experts_per_token * cfg.held[1] / max(cfg.n_experts, 1)
+        * 2 * 3 * d * f)
     # Causal attention: token t attends to t+1 keys, so the average query
     # sees (seq_len + 1) / 2 positions; qk^T and pv each cost 2*h*dh flops
     # per (query, key) pair. The flash kernel really skips the masked-out
     # tiles, so crediting full seq_len here would overcount ~2x.
-    attn = 2 * 2 * h * dh * ((seq_len + 1) / 2)
+    matmul = attn = 0.0
+    for kind in cfg.layers:
+        if kind.op == "conv":
+            matmul += 2 * d * 3 * d + 2 * d * d
+        else:
+            matmul += 2 * d * (h * dh + 2 * hk * dh) + 2 * h * dh * d
+            attn += 2 * 2 * h * dh * ((seq_len + 1) / 2)
+        matmul += routed if kind.routed else 2 * 3 * d * dense_f
     embed = 2 * d * cfg.vocab_size
-    return per_layer, attn, embed
+    return matmul, attn, embed
 
 
 def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
@@ -498,5 +783,5 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     Recomputation (remat, flash-backward recompute) is deliberately
     excluded — this is the numerator for useful-MFU.
     """
-    per_layer, attn, embed = _fwd_flops_per_token(cfg, seq_len)
-    return 3 * (cfg.n_layers * (per_layer + attn) + embed)
+    matmul, attn, embed = _fwd_flops_per_token(cfg, seq_len)
+    return 3 * (matmul + attn + embed)

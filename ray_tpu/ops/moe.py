@@ -7,13 +7,17 @@ weights, and the results come back to the tokens weighted by the router's
 probabilities. No capacity factor: every slot is computed whatever the
 imbalance, and the group sizes always sum to `T x k`.
 
-    route(logits, k)                 float32 softmax, the k largest
+    route(logits, k)                 float32 softmax or sigmoid, the k largest
     sort_slots(index, E)             order, its inverse, rows per expert
+    sort_slots(index, E, (first, n)) the same for a share of the experts
     dispatch(x, order, inverse)      [T, d] -> [T k, d], rows by expert
     grouped_matmul(x, w, sizes)      rows of group e times w[e]
     combine(ys, weights, inverse)    [T k, d] -> [T, d]
+    combine_held(ys, weights, order, rows)   the same from a share's rows
     project_and_combine(hidden, w_down, weights, slots)
                                      the last two as one operation
+    experts_of_share(tokens, w_gate, w_up, w_down, weights, slots)
+                                     the whole layer over a share's rows
 
 `grouped_matmul` is a matmul whose row groups go to different weights. On a
 TPU it is two Pallas kernels under a `custom_vjp`, after the grouped matmul
@@ -41,12 +45,23 @@ and every dot accumulates in f32.
 
 Anywhere but on a TPU `grouped_matmul` is `jax.lax.ragged_dot`, which XLA
 differentiates itself; `impl="auto"` resolves as attention's does.
+
+A layer that holds a share of the experts (`held = (first, n)`, as one chip
+of an expert-parallel deployment does) still routes over all `E`:
+`sort_slots` puts the slots of its `n` experts first and every other slot
+in a tail behind them, `group_sizes` is `[n]` and sums to the held rows, and
+the kernels walk those rows only. Rows past them are never written and hold
+whatever the buffer held, so whoever brings rows back to their tokens is
+given the count of held rows (`rows`) and *selects* with it (a scatter-add
+that drops the others, a gather of scalars that selects): nothing is
+multiplied by a zero. `experts_of_share` is that layer: it walks the held rows in
+buffers of `held_chunk` rows, as many as the step's routing fills.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,16 +72,42 @@ from ray_tpu.ops.flash_attention import (
 # ----------------------------------------------------------------- routing
 
 
-def route(logits, k: int, renormalize: bool = False):
-    """(probabilities [T, E], weights [T, k], index [T, k]) of router logits
-    [T, E]: a float32 softmax over the experts and its k largest, in falling
-    order. `renormalize` divides the k weights by their sum
-    (`norm_topk_prob`); otherwise they are the probabilities as they are."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, index = jax.lax.top_k(probs, k)
+def route(logits, k: int, renormalize: bool = False, *,
+          score: str = "softmax", bias=None, eps: float = 0.0):
+    """(scores [T, E], weights [T, k], index [T, k]) of router logits
+    [T, E]: float32 scores over the experts (`score`: their softmax, or each
+    logit's sigmoid) and the k largest, in falling order. `renormalize`
+    divides the k weights by their sum plus `eps` (`norm_topk_prob`);
+    otherwise they are the scores as they are.
+
+    With a `bias` [E] the k experts are those of the largest `scores +
+    bias`, in that order, and the weights are still their unbiased scores:
+    the bias steers the choice and nothing else (arXiv:2408.15664), and no
+    gradient reaches it."""
+    logits = logits.astype(jnp.float32)
+    probs = (jax.nn.sigmoid(logits) if score == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+    if bias is None:
+        weights, index = jax.lax.top_k(probs, k)
+    else:
+        _, index = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        # the chosen experts' scores, selected: a `take_along_axis` of T k
+        # scalars is a gather that takes 1.3 ms of the chip for 131,072
+        chosen = index[..., None] == jnp.arange(probs.shape[-1], dtype=index.dtype)
+        weights = jnp.where(chosen, probs[:, None, :], 0.0).sum(axis=-1)
     if renormalize:
-        weights = weights / weights.sum(axis=-1, keepdims=True)
+        total = weights.sum(axis=-1, keepdims=True)
+        weights = weights / (total + eps if eps else total)
     return probs, weights, index
+
+
+def update_expert_bias(bias, load, rate: float):
+    """The selection bias after a step: `rate` up for an expert that took
+    fewer slots than the mean of `load` [..., E], `rate` down for one that
+    took more (arXiv:2408.15664, the auxiliary-loss-free balancing rule)."""
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(load.mean(axis=-1, keepdims=True) - load)
 
 
 class Slots(NamedTuple):
@@ -75,46 +116,112 @@ class Slots(NamedTuple):
     order: jax.Array        # [T k] slot ids, sorted by expert, stably
     inverse: jax.Array      # [T k] where slot s stands in that order
     group_sizes: jax.Array  # [E] rows of each expert; sums to T k
+    # of a share of the experts: [n] rows of each held expert, whose slots
+    # come first in `order`; the slots of all the others are its tail
 
 
-def sort_slots(index, n_experts: int) -> Slots:
-    """Sort the slots of `index` [T, k] by expert."""
+def sort_slots(index, n_experts: int,
+               held: Optional[Tuple[int, int]] = None) -> Slots:
+    """Sort the slots of `index` [T, k] by expert; with `held = (first, n)`
+    by held expert, every other expert's slots behind them."""
     flat = index.reshape(-1).astype(jnp.int32)
+    if held is not None and tuple(held) != (0, n_experts):
+        first, n_experts = held
+        local = flat - first
+        flat = jnp.where(
+            jnp.logical_and(local >= 0, local < n_experts), local, n_experts)
     ids = jnp.arange(flat.shape[0], dtype=jnp.int32)
     _, order = jax.lax.sort((flat, ids), num_keys=1, is_stable=True)
     _, inverse = jax.lax.sort((order, ids), num_keys=1)
-    experts = jnp.arange(n_experts, dtype=jnp.int32)
-    sizes = (flat[:, None] == experts[None, :]).sum(axis=0, dtype=jnp.int32)
-    return Slots(order, inverse, sizes)
+    return Slots(order, inverse, _per_group(flat, n_experts))
 
 
-def _by_token(ys, inverse, k):
-    """Rows in expert order back in slot order: [T, k, d]."""
-    return ys[inverse].reshape(-1, k, ys.shape[-1])
+def _per_group(flat, n_groups: int):
+    """How many of the ids `flat` [n] name each of `n_groups` groups."""
+    groups = jnp.arange(n_groups, dtype=jnp.int32)
+    return (flat[:, None] == groups[None, :]).sum(axis=0, dtype=jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inverse, k):
+def expert_load(index, n_experts: int):
+    """Slots of `index` [T, k] per expert, over all `n_experts`: [E] int32."""
+    return _per_group(index.reshape(-1).astype(jnp.int32), n_experts)
+
+
+_HELD_SLACK = 1.25
+
+
+def held_chunk(slots: int, held: int, n_experts: int) -> int:
+    """Rows of the expert-order buffers of a layer that holds `held` of
+    `n_experts` experts, of `slots` slots in all: one and a quarter times
+    its even share, in whole row tiles (over 96 steps of four seeds
+    `lfm2moe.tokens8k`'s held rows stayed within 5 % of even: PERF.md
+    section 6, PR 32). A static length cannot follow the load, so
+    `experts_of_share` walks the held rows a chunk of this length at a
+    time, as many chunks as the step's routing fills: one, near balance."""
+    even = _cdiv(slots * held, n_experts)
+    tiles = max(1, _cdiv(int(_HELD_SLACK * even), _ROW_TILE))
+    return min(slots, tiles * _ROW_TILE)
+
+
+def _by_token(ys, inverse, k, rows=None):
+    """Rows in expert order back in slot order: [T, k, d]. `rows` (a
+    scalar) is the number of rows that were computed: a slot that stands
+    before the buffer or behind those rows is selected away as zeros,
+    whatever a row holds."""
+    if rows is None:
+        picked = ys[inverse]
+    else:
+        picked = jnp.where(
+            jnp.logical_and(inverse >= 0, inverse < rows)[:, None],
+            ys[jnp.clip(inverse, 0, ys.shape[0] - 1)],
+            jnp.zeros((), ys.dtype))
+    return picked.reshape(-1, k, ys.shape[-1])
+
+
+def _to_tokens(ys, order, rows, tokens: int, k: int):
+    """The first `rows` rows of `ys` (in expert order, float32) added up by
+    the token they belong to: [tokens, d] float32. One scatter-add of the
+    buffer's rows, for a share of the experts, where `_by_token` would
+    gather all `tokens x k` slots to select a few of them; a row behind the
+    `rows` is given no token and is dropped, whatever it holds."""
+    n = ys.shape[0]
+    token = jnp.where(jnp.arange(n, dtype=jnp.int32) < rows, order // k, tokens)
+    return jnp.zeros((tokens, ys.shape[1]), jnp.float32).at[token].add(
+        ys, mode="drop")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(x, order, inverse, rows, k):
     return x[order // k]
 
 
-def _dispatch_fwd(x, order, inverse, k):
-    return x[order // k], inverse
+def _dispatch_fwd(x, order, inverse, rows, k):
+    # a share of the experts sums the rows' gradients by `order`
+    return x[order // k], (inverse, None if rows is None else order, rows)
 
 
-def _dispatch_bwd(k, inverse, dxs):
-    per_token = _by_token(dxs, inverse, k).astype(jnp.float32)
-    return per_token.sum(axis=1).astype(dxs.dtype), None, None
+def _dispatch_bwd(k, res, dxs):
+    inverse, order, rows = res
+    if rows is None:
+        per_token = _by_token(dxs, inverse, k).astype(jnp.float32)
+        dx = per_token.sum(axis=1)
+    else:
+        dx = _to_tokens(dxs.astype(jnp.float32), order, rows,
+                        inverse.shape[0] // k, k)
+    return dx.astype(dxs.dtype), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def dispatch(x, order, inverse):
-    """Rows of `x` [T, d] copied to their slots in expert order: [T k, d].
-    The gradient gathers by the inverse order and sums a token's k slots,
-    where XLA's own transpose of the gather would scatter-add."""
-    return _dispatch(x, order, inverse, order.shape[0] // x.shape[0])
+def dispatch(x, order, inverse, rows=None):
+    """Rows of `x` [T, d] copied to their slots in expert order: [T k, d],
+    or as many rows as `order` was cut to. The gradient gathers by the
+    inverse order and sums a token's k slots, where XLA's own transpose of
+    the gather would scatter-add. With `rows` (a share of the experts: the
+    buffer is a fraction of the slots) it does scatter-add, the first `rows`
+    rows alone, and a token none of whose experts is held gets zero."""
+    return _dispatch(x, order, inverse, rows, inverse.shape[0] // x.shape[0])
 
 
 def combine(ys, weights, inverse):
@@ -122,8 +229,18 @@ def combine(ys, weights, inverse):
     each token's k rows times its k `weights` [T, k] (float32), summed in
     float32. The plain forward: a routed model takes it inside
     `project_and_combine`, whose backward never needs `ys`."""
-    rows = _by_token(ys, inverse, weights.shape[1]).astype(jnp.float32)
-    return (rows * weights[..., None]).sum(axis=1).astype(ys.dtype)
+    picked = _by_token(ys, inverse, weights.shape[1]).astype(jnp.float32)
+    return (picked * weights[..., None]).sum(axis=1).astype(ys.dtype)
+
+
+def combine_held(ys, weights, order, rows):
+    """`combine` for a share of the experts: `ys` [n, d] holds `rows` rows
+    that are some held expert's, in the order `order` [n] gives their
+    slots. Each such row times its slot's weight, added to its token in
+    float32; a token none of whose experts is held gets zero."""
+    tokens, k = weights.shape
+    weighted = ys.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
+    return _to_tokens(weighted, order, rows, tokens, k).astype(ys.dtype)
 
 
 def load_balancing_loss(probs, group_sizes):
@@ -360,7 +477,7 @@ def gmm(x, w, group_sizes, *, transpose_w: bool = False,
 
 
 def _tgmm_kernel(group_ids, tile_ids, offsets, num_steps,  # scalar prefetch
-                 x_ref, dy_ref, o_ref, acc_ref, *, tm, tk, tn):
+                 x_ref, dy_ref, o_ref, acc_ref, *, tm, tk, tn, tail):
     from jax.experimental import pallas as pl
 
     step = pl.program_id(2)
@@ -377,7 +494,9 @@ def _tgmm_kernel(group_ids, tile_ids, offsets, num_steps,  # scalar prefetch
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # rows of another group are zeroed in the narrower operand only: a zero
-    # row on one side is a zero product
+    # row on one side is a zero product. Not so for the rows behind the last
+    # group (`tail`: the groups do not fill the rows), which hold whatever
+    # the buffers held, NaN included: there both sides are zeroed.
     mask_x = tk <= tn
     whole, mine = _rows_in_group(step, group_ids, tile_ids, offsets, tm)
     has_rows = offsets[group + 1] > offsets[group]
@@ -390,9 +509,9 @@ def _tgmm_kernel(group_ids, tile_ids, offsets, num_steps,  # scalar prefetch
         has_rows, jnp.logical_not(whole))))
     def _some():
         x, dy = x_ref[...], dy_ref[...]
-        if mask_x:
+        if mask_x or tail:
             x = jnp.where(mine(tk), x, jnp.zeros_like(x))
-        else:
+        if tail or not mask_x:
             dy = jnp.where(mine(tn), dy, jnp.zeros_like(dy))
         acc_ref[...] += _dot(x, dy, _TN)
 
@@ -402,10 +521,13 @@ def _tgmm_kernel(group_ids, tile_ids, offsets, num_steps,  # scalar prefetch
 
 
 def tgmm(x, dy, group_sizes, *, out_dtype=None,
-         tiles: Optional[GmmTiles] = None, interpret: bool = False):
+         tiles: Optional[GmmTiles] = None, interpret: bool = False,
+         tail: bool = False):
     """`moe_tgmm`: per group `x[rows]^T dy[rows]` for `x` [M, K] and `dy`
     [M, N], rows sorted by group: [E, K, N] in `out_dtype` (x's by
-    default), accumulated in f32. A group of no rows gives zeros."""
+    default), accumulated in f32. A group of no rows gives zeros. `tail`
+    says that the groups may end before the rows do, and that what lies
+    behind them is not to be trusted to be finite."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -430,7 +552,7 @@ def tgmm(x, dy, group_sizes, *, out_dtype=None,
         return group_ids[step], ki, ni
 
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tm=tm, tk=tk, tn=tn),
+        functools.partial(_tgmm_kernel, tm=tm, tk=tk, tn=tn, tail=tail),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n // tn, k // tk, meta[0].shape[0]),
@@ -475,34 +597,42 @@ def _rows_gradient(dy, w, group_sizes, kernels, tm, interpret):
                tiles=gmm_tiles("moe_gmm", dy.shape[0], n, k, e, dy.dtype, tm=tm))
 
 
-def _weights_gradient(x, dy, w, group_sizes, kernels, tm, interpret):
+def _weights_gradient(x, dy, w, group_sizes, kernels, tm, interpret,
+                      tail=False):
     """Per group `x^T dy`: the product's transpose in the weights, in w's
-    own dtype. `moe_tgmm`, or `ragged_dot`'s own transpose."""
+    own dtype. `moe_tgmm`, or `ragged_dot`'s own transpose, which multiplies
+    the rows behind the groups (`tail`) by zero: they are zeroed first."""
     if not kernels:
+        if tail:
+            live = (jnp.arange(x.shape[0]) < group_sizes.sum())[:, None]
+            x = jnp.where(live, x, jnp.zeros_like(x))
+            dy = jnp.where(live, dy, jnp.zeros_like(dy))
         return jax.linear_transpose(
             lambda w: jax.lax.ragged_dot(x, w.astype(x.dtype), group_sizes),
             w)(dy)[0]
     e, k, n = w.shape
     return tgmm(x, dy, group_sizes, out_dtype=w.dtype, interpret=interpret,
+                tail=tail,
                 tiles=gmm_tiles("moe_tgmm", x.shape[0], k, n, e, x.dtype,
                                 out_dtype=w.dtype, tm=tm))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _grouped_matmul(x, w, group_sizes, tm, interpret):
-    return _product(x, w, group_sizes, True, tm, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _grouped_matmul(x, w, group_sizes, kernels, tm, interpret, tail):
+    return _product(x, w, group_sizes, kernels, tm, interpret)
 
 
-def _grouped_matmul_fwd(x, w, group_sizes, tm, interpret):
-    return _product(x, w, group_sizes, True, tm, interpret), (x, w, group_sizes)
+def _grouped_matmul_fwd(x, w, group_sizes, kernels, tm, interpret, tail):
+    return (_product(x, w, group_sizes, kernels, tm, interpret),
+            (x, w, group_sizes))
 
 
-def _grouped_matmul_bwd(tm, interpret, res, dy):
+def _grouped_matmul_bwd(kernels, tm, interpret, tail, res, dy):
     x, w, group_sizes = res
-    path = (group_sizes, True, tm, interpret)
+    path = (group_sizes, kernels, tm, interpret)
     dy = dy.astype(x.dtype)
-    return (_rows_gradient(dy, w, *path), _weights_gradient(x, dy, w, *path),
-            None)
+    return (_rows_gradient(dy, w, *path),
+            _weights_gradient(x, dy, w, *path, tail), None)
 
 
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
@@ -513,9 +643,13 @@ def _kernels(impl: str, interpret: bool) -> bool:
 
 
 def grouped_matmul(x, w, group_sizes, *, impl: str = "auto",
-                   interpret: bool = False, block_rows: Optional[int] = None):
+                   interpret: bool = False, block_rows: Optional[int] = None,
+                   tail: bool = False):
     """Rows of group e of `x` [M, K] (sorted by group, `group_sizes` [E]
     int32 rows each) times `w[e]` of `w` [E, K, N]: [M, N] in x's dtype.
+    Where the sizes sum to less than M (`tail`), the rows behind them are
+    no group's: they are not computed, the result there is not defined, and
+    the gradients take nothing from them.
 
     `w` may be wider than `x` (f32 master weights under bf16 rows): it is
     cast to x's dtype for the MXU, and its gradient comes back in its own
@@ -523,26 +657,31 @@ def grouped_matmul(x, w, group_sizes, *, impl: str = "auto",
     elsewhere) | 'pallas' | 'xla'. `interpret` and `block_rows` (a forced
     row tile) are for tests of the kernels off the chip."""
     group_sizes = group_sizes.astype(jnp.int32)
-    if _kernels(impl, interpret):
-        return _grouped_matmul(x, w, group_sizes, block_rows, interpret)
+    kernels = _kernels(impl, interpret)
+    if kernels or tail:  # XLA differentiates a whole `ragged_dot` itself
+        return _grouped_matmul(x, w, group_sizes, kernels, block_rows,
+                               interpret, tail)
     return _product(x, w, group_sizes, False, None, False)
 
 
 # ------------------------------------- the down projection back to tokens
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _project_and_combine(hidden, w_down, weights, slots, kernels, tm, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _project_and_combine(hidden, w_down, weights, slots, rows, kernels, tm,
+                         interpret):
     with jax.named_scope("moe_experts"):
         ys = _product(hidden, w_down, slots.group_sizes, kernels, tm, interpret)
     with jax.named_scope("moe_combine"):
-        return combine(ys, weights, slots.inverse)
+        if rows is None:
+            return combine(ys, weights, slots.inverse)
+        return combine_held(ys, weights, slots.order, rows)
 
 
-def _project_and_combine_fwd(hidden, w_down, weights, slots, kernels, tm,
-                             interpret):
-    out = _project_and_combine(hidden, w_down, weights, slots, kernels, tm,
-                               interpret)
-    return out, (hidden, w_down, weights, slots)
+def _project_and_combine_fwd(hidden, w_down, weights, slots, rows, kernels,
+                             tm, interpret):
+    out = _project_and_combine(hidden, w_down, weights, slots, rows, kernels,
+                               tm, interpret)
+    return out, (hidden, w_down, weights, slots, rows)
 
 
 def _project_and_combine_bwd(kernels, tm, interpret, res, dy):
@@ -550,7 +689,7 @@ def _project_and_combine_bwd(kernels, tm, interpret, res, dy):
     weight of slot s is `<dy_g[s], hidden[s] w_down[e]> = <dh_u[s],
     hidden[s]>`, a row sum on the hidden side, and the weights enter the
     other two gradients on that side too. `ys` is never needed."""
-    hidden, w_down, weights, slots = res
+    hidden, w_down, weights, slots, rows = res
     path = (slots.group_sizes, kernels, tm, interpret)
     k = weights.shape[1]
     hidden32 = hidden.astype(jnp.float32)
@@ -561,11 +700,16 @@ def _project_and_combine_bwd(kernels, tm, interpret, res, dy):
         dh_u = _rows_gradient(dy_g, w_down, *path).astype(jnp.float32)
         dhidden = (w_sorted * dh_u).astype(hidden.dtype)
         weighted = (w_sorted * hidden32).astype(hidden.dtype)
-        dw_down = _weights_gradient(weighted, dy_g, w_down, *path)
+        dw_down = _weights_gradient(weighted, dy_g, w_down, *path,
+                                    rows is not None)
     with jax.named_scope("moe_combine"):
         dw_sorted = (dh_u * hidden32).sum(axis=1)
-        dweights = dw_sorted[slots.inverse].reshape(weights.shape)
-    return dhidden, dw_down, dweights.astype(weights.dtype), None
+        if rows is None:
+            dweights = dw_sorted[slots.inverse].reshape(weights.shape)
+        else:  # hidden's rows behind the held ones are anything at all
+            dweights = _by_token(
+                dw_sorted[:, None], slots.inverse, k, rows)[..., 0]
+    return dhidden, dw_down, dweights.astype(weights.dtype), None, None
 
 
 _project_and_combine.defvjp(_project_and_combine_fwd, _project_and_combine_bwd)
@@ -573,7 +717,7 @@ _project_and_combine.defvjp(_project_and_combine_fwd, _project_and_combine_bwd)
 
 def project_and_combine(hidden, w_down, weights, slots: Slots, *,
                         impl: str = "auto", interpret: bool = False,
-                        block_rows: Optional[int] = None):
+                        block_rows: Optional[int] = None, rows=None):
     """The experts' down projection and the weighted sum back to tokens:
     `combine(grouped_matmul(hidden, w_down), weights)`, [T, d] of `hidden`
     [T k, f] in expert order, `w_down` [E, f, d] and `weights` [T, k]
@@ -581,7 +725,108 @@ def project_and_combine(hidden, w_down, weights, slots: Slots, *,
     that the backward works on the hidden side (f wide, not d): its
     residuals are its arguments, never the [T k, d] rows, which a
     rematerialised block would have to make again and gather again by
-    `inverse` for the gradient of the weights. The other arguments are
-    `grouped_matmul`'s."""
-    return _project_and_combine(hidden, w_down, weights, slots,
+    `inverse` for the gradient of the weights. `rows` is `combine`'s: of a
+    share of the experts, the number of rows that are some held expert's.
+    The other arguments are `grouped_matmul`'s."""
+    return _project_and_combine(hidden, w_down, weights, slots, rows,
                                 _kernels(impl, interpret), block_rows, interpret)
+
+
+# ------------------------------------------- a share of the experts' rows
+
+def _chunk_of(slots: Slots, order, i, chunk: int) -> Slots:
+    """The held rows `i chunk` to `(i + 1) chunk` as slots of their own:
+    their part of `order` (padded to whole chunks), `inverse` counted from
+    the chunk's first row (a slot outside it falls before 0 or behind the
+    chunk's rows), and what each group has of them."""
+    lo = i * chunk
+    ends = jnp.cumsum(slots.group_sizes)
+    starts = ends - slots.group_sizes
+    sizes = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
+    return Slots(jax.lax.dynamic_slice_in_dim(order, lo, chunk),
+                 slots.inverse - lo, sizes)
+
+
+def _experts_on_chunk(i, tokens, w_gate, w_up, w_down, weights, slots, order,
+                      chunk, impl):
+    """One chunk of the held rows through its experts and back to the
+    tokens, [T, d]: today's layer on buffers of `chunk` rows."""
+    part = _chunk_of(slots, order, i, chunk)
+    rows = part.group_sizes.sum()
+    with jax.named_scope("moe_dispatch"):
+        xs = dispatch(tokens, part.order, part.inverse, rows)
+    with jax.named_scope("moe_experts"):
+        gmm = functools.partial(
+            grouped_matmul, group_sizes=part.group_sizes, impl=impl, tail=True)
+        hidden = jax.nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
+    return project_and_combine(hidden, w_down, weights, part, impl=impl,
+                               rows=rows)
+
+
+def _chunks(slots: Slots, chunk: int):
+    """`order` padded to whole chunks, and how many of them hold a row."""
+    order = jnp.pad(slots.order, (0, (-slots.order.shape[0]) % chunk))
+    return order, (slots.group_sizes.sum() + chunk - 1) // chunk
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _experts_of_share(tokens, w_gate, w_up, w_down, weights, slots, chunk,
+                      impl):
+    order, n_chunks = _chunks(slots, chunk)
+
+    def add(i, out):
+        return out + _experts_on_chunk(
+            i, tokens, w_gate, w_up, w_down, weights, slots, order, chunk,
+            impl).astype(jnp.float32)
+
+    out = jax.lax.fori_loop(
+        0, n_chunks, add, jnp.zeros(tokens.shape, jnp.float32))
+    return out.astype(tokens.dtype)
+
+
+def _experts_of_share_fwd(tokens, w_gate, w_up, w_down, weights, slots, chunk,
+                          impl):
+    out = _experts_of_share(tokens, w_gate, w_up, w_down, weights, slots,
+                            chunk, impl)
+    return out, (tokens, w_gate, w_up, w_down, weights, slots)
+
+
+def _experts_of_share_bwd(chunk, impl, res, dout):
+    """A chunk at a time as the forward: the chunk's rows made again and
+    pulled back (the pieces' own backward rules), the gradients summed in
+    float32. A loop whose length the routing sets has no transpose of its
+    own, which is why the layer is one operation."""
+    *args, slots = res
+    order, n_chunks = _chunks(slots, chunk)
+
+    def add(i, grads):
+        _, pull = jax.vjp(
+            lambda *a: _experts_on_chunk(i, *a, slots, order, chunk, impl),
+            *args)
+        return tuple(g + d.astype(jnp.float32)
+                     for g, d in zip(grads, pull(dout)))
+
+    grads = jax.lax.fori_loop(
+        0, n_chunks, add,
+        tuple(jnp.zeros(a.shape, jnp.float32) for a in args))
+    return (*(g.astype(a.dtype) for g, a in zip(grads, args)), None)
+
+
+_experts_of_share.defvjp(_experts_of_share_fwd, _experts_of_share_bwd)
+
+
+def experts_of_share(tokens, w_gate, w_up, w_down, weights, slots: Slots, *,
+                     chunk: int, impl: str = "auto"):
+    """The routed feed-forward of a layer that holds a share of the experts
+    (`sort_slots(index, E, (first, n))`; `w_*` are the n held experts'):
+    for every token the weighted sum over those of its experts that are
+    held, [T, d]; a token none of whose experts is held gets zero.
+
+    The held rows go through dispatch, the grouped matmuls and the combine
+    in buffers of `chunk` rows, as many chunks as the step's routing fills
+    (`held_chunk`: one near balance, `T k / chunk` at most), so the work
+    follows the held rows whatever the load, nothing is dropped, and no
+    buffer is sized for the worst case. One differentiable operation: its
+    backward walks the same chunks."""
+    return _experts_of_share(tokens, w_gate, w_up, w_down, weights, slots,
+                             chunk, impl)

@@ -30,6 +30,9 @@ KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
 MOE_SCOPES = {"moe_router", "moe_dispatch", "moe_experts", "moe_combine",
               "qk_norm"}
 MOE_KERNELS = {"moe_gmm", "moe_tgmm"}
+# the gated short convolution's (`conv_in`, `conv_gate`, `conv_out` inside
+# `short_conv`), and the step's update of the routers' selection bias
+LFM2_SCOPES = {"short_conv", "conv_in", "conv_gate", "conv_out", "expert_bias"}
 VOCAB = 96  # the tiny steps' one dimension of this size: it finds the head
 
 
@@ -47,10 +50,10 @@ def components(stacks):
 
 
 def lowered_transformer_step(**routed):
-    cfg = TransformerConfig(
+    cfg = TransformerConfig(**{**dict(
         vocab_size=VOCAB, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=64, max_seq_len=16, remat=True, attention_impl="xla",
-        tied_embeddings=False, **routed)
+        tied_embeddings=False), **routed})
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     init_state, step, _ = make_train_step(cfg, mesh)
     state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
@@ -73,6 +76,22 @@ def lowered_resnet_step():
 def lowered_moe_step():
     return lowered_transformer_step(n_experts=4, experts_per_token=2,
                                     qk_norm=True)
+
+
+def lowered_lfm2_step():
+    """A stack of unlike layers: a dense conv layer, then attention and conv
+    with routed experts of which a share is held."""
+    row_tile = moe._ROW_TILE
+    moe._ROW_TILE = 8  # chunks of 8 rows of the tiny step's 64 slots
+    try:
+        return lowered_transformer_step(
+            n_layers=3, layer_types=("conv", "full_attention", "conv"),
+            n_dense_layers=1, d_ff_dense=48, n_experts=4, experts_per_token=2,
+            experts_held=(1, 1), router_score="sigmoid", expert_bias=True,
+            norm_topk_prob=True, qk_norm="head", router_aux_loss_coef=0.0,
+            router_z_loss_coef=0.0)
+    finally:
+        moe._ROW_TILE = row_tile
 
 
 def lowered_moe_kernels():
@@ -115,6 +134,8 @@ def lowered_flash_kernels():
 FAMILIES = {
     "transformer": (lowered_transformer_step, TRANSFORMER_SCOPES),
     "moe_transformer": (lowered_moe_step, TRANSFORMER_SCOPES | MOE_SCOPES),
+    "lfm2_moe": (lowered_lfm2_step,
+                 TRANSFORMER_SCOPES | MOE_SCOPES | LFM2_SCOPES),
     "resnet": (lowered_resnet_step, RESNET_SCOPES),
 }
 
@@ -142,8 +163,28 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
                    for s in stacks[family])
         # the whole of _attention lies under its scope, wrappers included
         assert any(re.search(r"attention/.*transpose", s) for s in stacks[family])
+    if family != "lfm2_moe":
+        assert not LFM2_SCOPES & components(stacks[family])
     if family == "transformer":  # the dense step names nothing of the routed
         assert not (MOE_SCOPES | MOE_KERNELS) & components(stacks[family])
+    elif family == "lfm2_moe":
+        # the convolution's three parts inside `short_conv`, in the scan's
+        # body forward, made again, and backward (under `checkpoint`)
+        for inner in ("conv_in", "conv_gate", "conv_out"):
+            for prefix in ("", "checkpoint/rematted_computation/", "checkpoint/"):
+                assert any(s.startswith(f"{prefix}short_conv/{inner}/")
+                           for s in stacks[family]), (inner, prefix)
+        # the routed feed-forward of a share under `mlp`: its pieces inside
+        # the loop over the chunks of held rows, forward and backward
+        for inner in ("moe_dispatch", "moe_experts", "moe_combine"):
+            assert any(s.startswith(f"mlp/while/body/{inner}")
+                       for s in stacks[family]), inner
+            assert any(re.match(rf"checkpoint/mlp/while/body/.*{inner}", s)
+                       for s in stacks[family]), inner
+        assert any(s.startswith("mlp/moe_router/") for s in stacks[family])
+        assert any(re.search(r"attn_qkv\)*/qk_norm", s) for s in stacks[family])
+        # the bias is moved outside the differentiated function
+        assert any(s.endswith("expert_bias/sign") for s in stacks[family])
     elif family == "moe_transformer":
         # the routed feed-forward stays under `mlp`, QK-norm under `attn_qkv`
         for inner in sorted(MOE_SCOPES - {"qk_norm"}):
@@ -228,7 +269,7 @@ def test_resnet_stem_has_its_conv_scope():
 def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
     from chipbench import scopes
 
-    program = TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS
+    program = TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     assert set(scopes.KERNELS) == KERNELS
     suffix = ".images.json" if family == "resnet" else ".tokens.json"
@@ -240,7 +281,8 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
         for key in ("scope", "kernel"):
             if key in params:
                 assert params[key] in program, path
-                if path.endswith(suffix):
+                only_lfm2 = params[key] in LFM2_SCOPES and family != "lfm2_moe"
+                if path.endswith(suffix) and not only_lfm2:
                     assert params[key] in in_family, path
                     named += 1
     assert named >= 1

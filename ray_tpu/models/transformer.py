@@ -27,6 +27,13 @@ period is one layer, and its parameters are the one stacked tree
 `params["blocks"]`; otherwise `params["blocks"]` is a list of segments, each
 a list with one such tree per layer of its period.
 
+With `remat` each block runs under `jax.checkpoint`: its input is kept and
+its values are made again in the backward pass, but for the named ones
+(`checkpoint_name`) that `make_train_step`'s step finds room for on the
+device it is traced for (`saved_activations`: from the widths, the tokens
+and the state a device holds, and the device's memory limit; nothing where
+no limit can be read).
+
 Parallelism (ray_tpu.parallel.mesh axes):
   data/fsdp — batch split; fsdp additionally shards params (ZeRO-3 style)
   tensor    — heads + mlp hidden + vocab split (Megatron layout)
@@ -39,6 +46,7 @@ Capability analog of what the reference reaches only through integrations
 
 from __future__ import annotations
 
+import logging
 import math
 from dataclasses import dataclass, field
 from functools import partial
@@ -46,6 +54,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import moe
@@ -57,6 +66,8 @@ from ray_tpu.ops.fused import (
 )
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel.ring_attention import ring_attention
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass(frozen=True)
@@ -70,7 +81,9 @@ class TransformerConfig:
     max_seq_len: int = 2048
     rope_theta: float = 10_000.0
     dtype: Any = jnp.bfloat16  # compute/activation dtype
-    remat: bool = False  # jax.checkpoint each block
+    # jax.checkpoint each block: its input is the checkpoint; what else a
+    # block keeps the step derives (`saved_activations`)
+    remat: bool = False
     # auto | pallas | xla | ring; a routed feed-forward's grouped matmul
     # takes its kernels where attention does
     attention_impl: str = "auto"
@@ -335,7 +348,7 @@ def _rope(x, positions, theta: float):
 
 
 def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
-               seq_size: int, mesh=None):
+               seq_size: int, mesh=None, keep_ctx: bool = False):
     if cfg.attention_impl == "ring" and seq_axis is not None:
         # Inside shard_map over the sequence axis: exact ring attention.
         rep = cfg.n_heads // k.shape[2]
@@ -346,7 +359,7 @@ def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
             q, k, v, axis_name=seq_axis, axis_size=seq_size, causal=True
         )
     impl = _kernel_impl(cfg)
-    attn = partial(mha, causal=True, impl=impl)
+    attn = partial(mha, causal=True, impl=impl, keep_ctx=keep_ctx)
     if impl == "pallas" and mesh is not None and mesh.size > 1:
         # XLA cannot partition a Mosaic kernel ("wrap the call in a
         # shard_map"), so map it ourselves over the axes attention is
@@ -369,7 +382,8 @@ def _kernel_impl(cfg: TransformerConfig) -> str:
 
 
 def _attention_layer(x, blk, positions, cfg: TransformerConfig,
-                     seq_axis: Optional[str], seq_size: int, mesh=None):
+                     seq_axis: Optional[str], seq_size: int, mesh=None,
+                     keep_ctx: bool = False):
     """x + attention(norm(x)): projections, QK-norm, RoPE, the kernel."""
     B, T, d = x.shape
     h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
@@ -383,20 +397,25 @@ def _attention_layer(x, blk, positions, cfg: TransformerConfig,
 
     with jax.named_scope("attn_qkv"):
         y = fused_rmsnorm(x, blk["attn_norm"], eps=cfg.norm_eps)
-        q, k = y @ blk["wq"].astype(dt), y @ blk["wk"].astype(dt)
+        # named as the products leave the MXU: QK-norm's backward takes
+        # them, and the norm and RoPE after them are elementwise
+        q = checkpoint_name(y @ blk["wq"].astype(dt), "attn_qkv")
+        k = checkpoint_name(y @ blk["wk"].astype(dt), "attn_qkv")
         if cfg.qk_norm and not per_head:  # over the whole projection
             q, k = qk_norm(q, k)
         q = q.reshape(B, T, h, dh)
         k = k.reshape(B, T, hk, dh)
         if per_head:  # every head over its own dh, one scale for all heads
             q, k = qk_norm(q, k)
-        v = (y @ blk["wv"].astype(dt)).reshape(B, T, hk, dh)
+        v = checkpoint_name(y @ blk["wv"].astype(dt), "attn_qkv").reshape(
+            B, T, hk, dh)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
     with jax.named_scope("attention"):
-        o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh)
+        o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh, keep_ctx)
     with jax.named_scope("attn_out"):
-        return x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
+        return checkpoint_name(
+            x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt), "attn_res")
 
 
 def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
@@ -432,6 +451,9 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
         share = n_held < cfg.n_experts
         slots = moe.sort_slots(index, cfg.n_experts,
                                (cfg.held[0], n_held) if share else None)
+        if not share:
+            slots = moe.Slots(
+                *(checkpoint_name(s, "moe_slots") for s in slots))
         load = (moe.expert_load(index, cfg.n_experts) if share
                 else slots.group_sizes)
         readings = {
@@ -458,7 +480,8 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
     with jax.named_scope("moe_experts"):
         gmm = partial(moe.grouped_matmul, group_sizes=slots.group_sizes,
                       impl=impl)
-        hidden = jax.nn.silu(gmm(xs, blk["w_gate"])) * gmm(xs, blk["w_up"])
+        gate = jax.nn.silu(checkpoint_name(gmm(xs, blk["w_gate"]), "moe_gate"))
+        hidden = gate * checkpoint_name(gmm(xs, blk["w_up"]), "moe_up")
     # names its own operations `moe_experts` and `moe_combine`, backward too
     out = moe.project_and_combine(hidden, blk["w_down"], weights, slots,
                                   impl=impl)
@@ -476,7 +499,9 @@ def _short_conv(x, blk, cfg: TransformerConfig):
     taps = blk["conv_w"].shape[0]
     with jax.named_scope("conv_in"):
         y = fused_rmsnorm(x, blk["conv_norm"], eps=cfg.norm_eps)
-        b, c, xs = jnp.split(y @ blk["conv_in"].astype(dt), 3, axis=-1)
+        b, c, xs = jnp.split(
+            checkpoint_name(y @ blk["conv_in"].astype(dt), "conv_in"), 3,
+            axis=-1)
     with jax.named_scope("conv_gate"):
         u = b * xs
         w = blk["conv_w"].astype(dt)
@@ -489,10 +514,12 @@ def _short_conv(x, blk, cfg: TransformerConfig):
 
 
 def _block(x, blk, positions, bias, cfg: TransformerConfig,
-           seq_axis: Optional[str], seq_size: int, mesh=None):
+           seq_axis: Optional[str], seq_size: int, mesh=None,
+           keep_ctx: bool = False):
     """One block: (x, the routed feed-forward's readings or None). What the
     block is, its parameters say: a short convolution where it has
-    `conv_in`, a routed feed-forward where it has a `router`."""
+    `conv_in`, a routed feed-forward where it has a `router`. `keep_ctx`:
+    the attention kernel names its backward's residuals `attn_ctx`."""
     dt = cfg.dtype
 
     # The scopes name the step's device work in a profiler trace
@@ -502,9 +529,10 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig,
             raise NotImplementedError(
                 "the short convolution is not mapped over a sequence axis")
         with jax.named_scope("short_conv"):
-            x = x + _short_conv(x, blk, cfg)
+            x = checkpoint_name(x + _short_conv(x, blk, cfg), "conv_res")
     else:
-        x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size, mesh)
+        x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size, mesh,
+                             keep_ctx)
 
     readings = None
     with jax.named_scope("mlp"):
@@ -513,8 +541,9 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig,
             routed, readings = _routed_ffn(y, blk, cfg, mesh, bias)
             x = x + routed
         else:
-            gate = jax.nn.silu(y @ blk["w_gate"].astype(dt))
-            up = y @ blk["w_up"].astype(dt)
+            gate = jax.nn.silu(
+                checkpoint_name(y @ blk["w_gate"].astype(dt), "mlp_gate"))
+            up = checkpoint_name(y @ blk["w_up"].astype(dt), "mlp_up")
             x = x + (gate * up) @ blk["w_down"].astype(dt)
     return x, readings
 
@@ -534,10 +563,13 @@ def _layer_axis(trees, stack: bool):
 
 def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
                          positions=None, seq_axis: Optional[str] = None,
-                         seq_size: int = 1, mesh=None, expert_bias=None):
+                         seq_size: int = 1, mesh=None, expert_bias=None,
+                         saved_names: Tuple[str, ...] = ()):
     """`transformer_hidden`, and the routed feed-forwards' readings stacked
     on a leading layer axis (None for a dense model). `expert_bias`
-    [routed layers, E] is the routers' selection bias."""
+    [routed layers, E] is the routers' selection bias. Under `cfg.remat` a
+    block's input is its checkpoint; `saved_names` are the activations kept
+    beside it (`saved_activations`), none by default."""
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -545,10 +577,15 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
         x = params["embed"].astype(cfg.dtype)[tokens]
 
     blk_fn = partial(
-        _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size, mesh=mesh
+        _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size, mesh=mesh,
+        keep_ctx="attn_ctx" in saved_names,
     )
     if cfg.remat:
-        blk_fn = jax.checkpoint(blk_fn, static_argnums=())
+        # no policy at all for an empty choice: the step is then the one
+        # that keeps nothing, instruction for instruction
+        policy = (jax.checkpoint_policies.save_only_these_names(*saved_names)
+                  if saved_names else None)
+        blk_fn = jax.checkpoint(blk_fn, policy=policy, static_argnums=())
 
     blocks = params["blocks"]
     if isinstance(blocks, dict):  # one kind of layer: one segment of it
@@ -589,9 +626,10 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
 def transformer_hidden(params, tokens, cfg: TransformerConfig, **kw):
     """Forward through the blocks: [B, T] tokens -> [B, T, d] normed hidden.
 
-    Keywords: `positions`, `seq_axis`, `seq_size`, `mesh`, `expert_bias`. When called under
-    shard_map with the sequence sharded, pass seq_axis and positions holding
-    GLOBAL positions so RoPE and causal masks are correct. When called under
+    Keywords: `positions`, `seq_axis`, `seq_size`, `mesh`, `expert_bias`,
+    `saved_names`. When called under shard_map with the sequence sharded,
+    pass seq_axis and positions holding GLOBAL positions so RoPE and causal
+    masks are correct. When called under
     a jit that shards over `mesh`, pass the mesh: the Pallas attention
     kernel is mapped over its batch and head axes.
     """
@@ -651,6 +689,170 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
 def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
     """The loss of `transformer_loss_and_readings` alone."""
     return transformer_loss_and_readings(params, batch, cfg, **kw)[0]
+
+
+# ---------------------------------------- what a rematerialised block keeps
+
+# The activations a block under `jax.checkpoint` may keep beside its input,
+# by the names the model and the flash kernel's forward rule give them
+# (`checkpoint_name`), in the order they are kept: by the time a byte kept
+# saves. Measured on a v5e (PERF.md section 6, PR 33), ms of step per GB:
+# `attn_ctx` 29 to 64 (the kernel is not run again), the operators'
+# residuals and q, k, v about 15, the feed-forwards' and `conv_in`'s products
+# 10 to 11 (a product of a `d`-wide input costs `2 d` operations a value
+# whatever its width, so these rank by what rides with the matmul: RoPE and
+# the norms with q, k and v, nothing with `up`).
+_SAVE_ORDER = (
+    "attn_ctx",   # the kernel's o [B H, T, dh] and lse as one f32 column
+    "moe_slots",  # the sorted slots: no second sort (integers, small)
+    "attn_res",   # the stream after attention: no second `wo` product
+    "conv_res",   # the stream after the short convolution: no `conv_out`
+    "attn_qkv",   # the q, k, v products, before QK-norm, RoPE and GQA's repeat
+    "conv_in",    # the three streams out of `conv_in`
+    "moe_gate",   # the experts' gate product [slots, f]
+    "moe_up",     # and their up product
+    "mlp_gate",   # the dense feed-forward's gate product, before the silu
+    "mlp_up",     # and its up product
+)
+_SAVE_RESERVE = 1 << 30  # the step stays this far under the device's limit
+_HEAD_CHUNK = 2048  # `lm_head_cross_entropy`'s chunk_tokens
+
+
+def _tile_lanes(width: int) -> int:
+    """`width` as HBM tiles a minor dimension: whole tiles of 128 lanes."""
+    return -(-width // 128) * 128
+
+
+def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
+    """(values a token of one layer of `kind`: {name: width in elements of
+    the compute dtype}, the layer's parameters)."""
+    d, h, hk, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    k, f = cfg.experts_per_token, cfg.ff_dim
+    item = jnp.dtype(cfg.dtype).itemsize
+    if kind.op == "conv":
+        widths = {"conv_res": d, "conv_in": 3 * d}
+        params = 4 * d * d
+    else:
+        widths = {
+            # o and lse as one float32 column
+            "attn_ctx": h * _tile_lanes(dh) + h * 4 // item,
+            "attn_res": d,
+            "attn_qkv": (h + 2 * hk) * dh,
+        }
+        params = d * (h + 2 * hk) * dh + h * dh * d
+    if not kind.routed:
+        f = cfg.d_ff_dense if cfg.n_experts and cfg.d_ff_dense else f
+        widths.update(mlp_gate=f, mlp_up=f)
+        params += 3 * d * f
+    else:
+        params += d * cfg.n_experts + cfg.held[1] * 3 * d * f
+        # a layer that holds a share of the experts is one operation whose
+        # backward makes its rows again chunk by chunk: it has no names
+        if cfg.held[1] == cfg.n_experts:
+            widths.update(moe_slots=2 * k * 4 // item, moe_gate=k * f,
+                          moe_up=k * f)
+    return widths, params
+
+
+def _saved_bytes(cfg: TransformerConfig, tokens: int) -> Dict[str, int]:
+    """Bytes a device holds of each named activation over all the layers
+    that make it, for `tokens` tokens on the device, in `_SAVE_ORDER`."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    total: Dict[str, int] = {}
+    for kind in cfg.layers:
+        for name, width in _layer_widths(cfg, kind)[0].items():
+            total[name] = total.get(name, 0) + tokens * width * item
+    return {name: total[name] for name in _SAVE_ORDER if name in total}
+
+
+def _working_set_bytes(cfg: TransformerConfig, tokens: int,
+                       param_bytes: int) -> int:
+    """What the step that keeps nothing holds on a device beside its state
+    and the gradients when it is fullest, for `tokens` tokens on the device
+    and `param_bytes` of parameters there: the blocks' inputs, and the
+    larger of the head's chunk and one block in its backward.
+
+    A block in its backward is taken as every value of its widest layer at
+    once: the named ones, the two normed inputs, the stream's cotangent,
+    q, k and v as the kernel takes them (heads repeated), lse and delta at
+    a tile's 128 lanes, the feed-forward's hidden product and a routed
+    layer's dispatched rows; with the compute-dtype copy of its weights
+    and, where the parameters are sharded, the same weights gathered whole
+    and their float32 gradient before it is scattered. Against the chip
+    (`bytes_in_use + bytes_reserved` less state and gradients; PERF.md
+    section 6, PR 33), GB: 4.06 for 4.03 at Mistral's widths on one chip
+    and 3.96 for 3.18 on four; 3.32 for 1.77 and 4.64 for 2.23 where every
+    scan is one layer long and the compiler frees an unrolled block's
+    values as it goes. It errs to the full side: a name too few costs a
+    percent, a step that asks for the chip's last GiB is compiled to fit
+    and runs slower than the one that keeps nothing."""
+    d, h = cfg.d_model, cfg.n_heads
+    item = jnp.dtype(cfg.dtype).itemsize
+    whole = 4 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+        lambda: transformer_init(jax.random.PRNGKey(0), cfg))))
+    sharded = param_bytes < whole
+    block = 0
+    for kind in set(cfg.layers):
+        widths, params = _layer_widths(cfg, kind)
+        # the two normed inputs and the stream's cotangent
+        width = sum(widths.values()) + 3 * d
+        if kind.op == "conv":
+            width += 3 * d  # the gate's product, the taps' sum, the gated
+        else:
+            width += (3 * h * _tile_lanes(cfg.head_dim)
+                      + 2 * h * 128 * 4 // item)
+        if kind.routed:
+            width += (cfg.experts_per_token * (d + cfg.ff_dim)
+                      if "moe_gate" in widths else 0)
+        else:
+            width += widths["mlp_gate"]
+        weights = params * item + (params * (item + 4) if sharded else 0)
+        block = max(block, tokens * width * item + weights)
+    unembed = cfg.vocab_size * d * item * param_bytes // whole
+    head = (_HEAD_CHUNK * cfg.vocab_size * (4 + 4 + item) + unembed
+            + tokens * d * item)
+    boundaries = (cfg.n_layers + 1) * tokens * d * item
+    return boundaries + max(block, head)
+
+
+def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
+                      resident_bytes: int, param_bytes: int,
+                      limit_bytes: Optional[int]) -> Dict[str, int]:
+    """{name: bytes on a device} of the activations a rematerialised block
+    keeps beside its input: the names of `_SAVE_ORDER`, in that order, for
+    as long as they fit the device.
+
+    `cfg.remat` says that a block's input is its checkpoint; what is kept
+    beyond it follows from what the step is traced with. The room is
+    `limit_bytes` (the device's `bytes_limit`) less `resident_bytes` (the
+    state on the device), less the gradients (`param_bytes` again: the
+    parameters' bytes on the device), less `_working_set_bytes`, less
+    `_SAVE_RESERVE`. A name's bytes are its width times
+    `tokens_per_device` times the layers that make it. The first name that
+    does not fit ends the choice, so a larger limit only ever adds names.
+    With no limit to read (the CPU, a described topology) or without
+    `remat` nothing is kept, and the step is the one without a policy."""
+    if limit_bytes is None or not cfg.remat:
+        return {}
+    room = (limit_bytes - resident_bytes - param_bytes - _SAVE_RESERVE
+            - _working_set_bytes(cfg, tokens_per_device, param_bytes))
+    chosen: Dict[str, int] = {}
+    for name, size in _saved_bytes(cfg, tokens_per_device).items():
+        if sum(chosen.values()) + size > room:
+            break
+        chosen[name] = size
+    return chosen
+
+
+def _memory_limit(mesh) -> Optional[int]:
+    """`bytes_limit` of the mesh's first device: what its allocator may hand
+    out. None where the device reports none (the CPU, a described
+    topology)."""
+    try:
+        stats = mesh.devices.flat[0].memory_stats()
+    except jax.errors.JaxRuntimeError:  # a described device is not asked
+        return None
+    return (stats or {}).get("bytes_limit")
 
 
 # -------------------------------------------------------------- train step
@@ -719,17 +921,41 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
             state["expert_bias"] = jax.device_put(expert_bias_init(cfg), repl)
         return state
 
-    def loss_fn(params, batch, **bias):
+    def loss_fn(params, batch, **kw):
         loss, readings = transformer_loss_and_readings(
-            params, batch, cfg, mesh=mesh, **bias)
+            params, batch, cfg, mesh=mesh, **kw)
         return loss, {k: readings[k] for k in _STEP_READINGS if k in readings}
+
+    def on_a_device(tree, shardings):
+        """Bytes one device holds of `tree` laid out by `shardings`."""
+        return sum(
+            math.prod(s.shard_shape(x.shape)) * x.dtype.itemsize
+            for x, s in zip(jax.tree.leaves(tree), jax.tree.leaves(shardings)))
+
+    def saved_names(state, batch):
+        """What the rematerialised blocks keep, chosen while the step is
+        traced, from what it is traced with: the state's and the batch's
+        shapes as the mesh lays them out, and the device's memory limit."""
+        limit = _memory_limit(mesh)
+        tokens = math.prod(tok_sharding.shard_shape(batch["tokens"].shape))
+        resident = on_a_device(state, state_shard)
+        params = on_a_device(state["params"], p_shard)
+        saved = saved_activations(cfg, tokens, resident, params, limit)
+        if cfg.remat:
+            logger.info(
+                "train step under remat keeps %s: %d bytes a device beside "
+                "the blocks' inputs (%d tokens a device, state %d bytes, "
+                "bytes_limit %s)", saved or "nothing", sum(saved.values()),
+                tokens, resident, limit)
+        return tuple(saved)
 
     @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))
     def step(state, batch):
         bias = ({"expert_bias": state["expert_bias"]} if cfg.expert_bias
                 else {})
         (loss, readings), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state["params"], batch, **bias)
+            state["params"], batch, saved_names=saved_names(state, batch),
+            **bias)
         with jax.named_scope("optimizer"):
             updates, opt = optimizer.update(
                 grads, state["opt"], state["params"]

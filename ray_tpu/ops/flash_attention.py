@@ -11,7 +11,10 @@ interpret mode on tiny shapes via `flash_attention(..., interpret=True)`).
 
 Backward pass uses recompute (custom_vjp re-derives the tile softmax from
 q, k and the saved lse), trading FLOPs for the O(T^2) memory XLA would
-otherwise materialize.
+otherwise materialize. Its residuals are q, k, v, o and lse; with `keep_ctx`
+the forward rule names o and lse `attn_ctx` (`jax.ad_checkpoint`), so that a
+caller under `jax.checkpoint` whose policy keeps that name does not run the
+forward kernel a second time for them.
 
 The three `pallas_call`s are named `flash_fwd` (with or without the lse
 output), `flash_bwd_dq` and `flash_bwd_dkv`: the names a profiler trace and
@@ -55,6 +58,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _BIG_NEG = -1e30
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -422,24 +426,35 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, keep_ctx):
     return _flash_fwd(
         q, k, v, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                   keep_ctx):
     o, lse = _flash_fwd(
         q, k, v, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret, with_lse=True,
     )
+    if keep_ctx:
+        # Named where the backward takes them, so that a rematerialised
+        # block that keeps `attn_ctx` does not run the kernel again for its
+        # residuals (a name on the layer's output would keep a copy and
+        # still run it); q, k and v are made again from the block's input.
+        # lse is kept as its one column: [BH, T, 8] float32 fills 8 of a
+        # tile's 128 lanes and lies in HBM at 16 times its size.
+        o = checkpoint_name(o, "attn_ctx")
+        lse = checkpoint_name(lse[..., 0], "attn_ctx")
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, do):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx, res,
+                   do):
     """Tiled FlashAttention-2 backward: two pallas kernels (dq; dk/dv), each
     re-deriving its softmax tile from (q, k, lse) — nothing O(T·S) ever
     touches HBM (the previous recompute path materialized full f32 score
@@ -447,6 +462,8 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, do):
     bandwidth-bound)."""
     q, k, v, o, lse = res
     BH, T, _ = q.shape
+    if keep_ctx:
+        lse = jnp.broadcast_to(lse[..., None], (BH, T, 8))
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
     # Same sublane-aligned [BH, T, 8] layout as lse.
     delta = jnp.broadcast_to(delta[..., None], (BH, T, 8))
@@ -687,11 +704,14 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: bool = False,
+    keep_ctx: bool = False,
 ):
     """Flash attention on [B, T, H, D] inputs (grouped-query: H_kv may divide H).
 
     `block_q` and `block_k` force every kernel's tile; `None` lets
-    `flash_tiles` choose each kernel's from the shape."""
+    `flash_tiles` choose each kernel's from the shape. `keep_ctx` names the
+    backward's residuals o and lse `attn_ctx` (`jax.ad_checkpoint`), for a
+    caller under `jax.checkpoint` whose policy keeps that name."""
     B, T, H, D = q.shape
     Hk = k.shape[2]
     if scale is None:
@@ -704,7 +724,8 @@ def flash_attention(
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], D)
-    of = _flash(qf, kf, vf, causal, scale, block_q, block_k, interpret)
+    of = _flash(qf, kf, vf, causal, scale, block_q, block_k, interpret,
+                keep_ctx)
     return of.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
@@ -718,14 +739,16 @@ def resolve_impl(impl: str) -> str:
 
 
 def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
-        impl: str = "auto"):
+        impl: str = "auto", keep_ctx: bool = False):
     """Multi-head attention dispatch on [B, T, H, D].
 
     impl: 'auto' (pallas on TPU, XLA elsewhere) | 'pallas' | 'xla'.
+    `keep_ctx` is `flash_attention`'s; plain attention names its output.
     """
     impl = resolve_impl(impl)
     if impl == "pallas":
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               keep_ctx=keep_ctx)
     B, T, H, D = q.shape
     Hk = k.shape[2]
     if Hk != H:
@@ -738,4 +761,6 @@ def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], D)
     of = _xla_attention_bhtd(qf, kf, vf, causal=causal, scale=scale)
-    return of.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    out = of.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    # kept, it saves the output alone: this backward needs its scores again
+    return checkpoint_name(out, "attn_ctx") if keep_ctx else out

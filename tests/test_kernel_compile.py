@@ -220,3 +220,103 @@ def test_grouped_matmul_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
         "f32[64,2048,1024]" if kernel == "moe_tgmm" else
         "bf16[131072,2048]" if kernel == "moe_gmm_transposed" else
         "bf16[131072,1024]")
+
+
+# ------------------------- the token cells' steps with what remat keeps
+
+HBM_LIMIT = int(15.75 * 2**30)  # a v5e's `bytes_limit`, to the GiB's hundredth
+# after what the rule keeps at that limit: (flash_fwd calls in the text, the
+# parent's; moe_gmm calls, the parent's). One scanned layer kind with
+# attention in every cell; without `attn_ctx` the kernel runs in the second
+# forward too, and `olmoe.tokens4k`'s gate and up products with it.
+TOKEN_CELLS = {
+    "mistral7b.tokens4k": ((1, 2), (0, 0)),
+    "mistral7b.fsdp4": ((1, 2), (0, 0)),
+    "olmoe.tokens4k": ((1, 2), (6, 8)),
+    "lfm2moe.tokens8k": ((1, 2), (32, 32)),  # a share's layer has no names
+}
+
+
+def _token_cell_step(cell_name, devices, monkeypatch):
+    """(lowered step of the cell at its real shapes on described devices,
+    what the rule chose while it was traced)."""
+    from chipbench import loop, spec
+    from ray_tpu.models import transformer as tr
+
+    cell = spec.load_cell(spec.ROOT, cell_name)
+    config, traffic = cell["config"], cell["traffic"]
+    # "auto" asks the platform, which is the CPU here: steered in the test
+    config["attention_impl"] = "pallas"
+    chosen = []
+    rule = tr.saved_activations
+
+    def recording(*args):
+        chosen.append(rule(*args))
+        return chosen[-1]
+
+    monkeypatch.setattr(tr, "saved_activations", recording)
+    family = spec.load_code(spec.ROOT, "loops", config["family"]).build(
+        config, traffic, list(devices[:cell["workload"]["chips"]]))
+    key = jax.eval_shape(lambda: loop.seed_key(0))
+    state = jax.eval_shape(
+        family.init_state, jax.eval_shape(family.init_params, key))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        state, family.state_shardings)
+    batch = family.batch_shapes(int(traffic["batch_rows"]))
+    lowered = family.step.lower(state, batch)
+    monkeypatch.setattr(tr, "saved_activations", rule)
+    return lowered, chosen[0]
+
+
+def _calls(text, kernel):
+    import re
+
+    return len(re.findall(rf"%{kernel}(\.\d+)? = ", text))
+
+
+@pytest.mark.parametrize("cell_name", list(TOKEN_CELLS))
+def test_token_step_with_what_it_keeps_compiles_and_fits(
+        v5e, monkeypatch, cell_name):
+    """The reader patched to a v5e's limit (a described device reports
+    none): the step the chip would run compiles, stays a GB under the limit
+    by the compiler's own count, and runs the flash forward once a layer."""
+    from ray_tpu.models import transformer as tr
+
+    monkeypatch.setattr(tr, "_memory_limit", lambda mesh: HBM_LIMIT)
+    lowered, chosen = _token_cell_step(cell_name, v5e, monkeypatch)
+    assert next(iter(chosen)) == "attn_ctx" and "attn_res" in chosen
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            - memory.alias_size_in_bytes) <= HBM_LIMIT - 10**9
+    text = compiled.as_text()
+    (flash, _), (gmm, _) = TOKEN_CELLS[cell_name]
+    assert _calls(text, "flash_fwd") == flash
+    assert _calls(text, "flash_bwd_dq") == _calls(text, "flash_bwd_dkv") == 1
+    assert _calls(text, "moe_gmm") == gmm
+    if cell_name == "olmoe.tokens4k":
+        assert {"moe_slots", "moe_gate", "moe_up"} <= set(chosen)
+
+
+@pytest.mark.parametrize("cell_name", list(TOKEN_CELLS))
+def test_token_step_without_a_limit_is_the_step_without_names(
+        v5e, monkeypatch, cell_name):
+    """A described device reports no limit: nothing is chosen, no policy is
+    passed, and the step lowers to the text of the program that has no
+    names at all (the parent's, but for metadata)."""
+    import re
+
+    from ray_tpu.models import transformer as tr
+
+    def text_of(lowered):
+        # a function's name ends in a counter of the functions traced, and
+        # a kernel's serialized body holds the locations it was traced at
+        text = re.sub(r"@(\w+?)_\d+\b", r"@\1", lowered.as_text())
+        return re.sub(r'backend_config = "[^"]*"', "", text)
+
+    lowered, chosen = _token_cell_step(cell_name, v5e, monkeypatch)
+    assert chosen == {}
+    monkeypatch.setattr(tr, "checkpoint_name", lambda x, name: x)
+    without_names, _ = _token_cell_step(cell_name, v5e, monkeypatch)
+    assert text_of(without_names) == text_of(lowered)

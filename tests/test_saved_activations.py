@@ -1,0 +1,269 @@
+"""What a rematerialised block keeps (`models/transformer.py`
+`saved_activations`): the rule as a pure function on the token cells' shapes,
+and on the CPU, with the memory reader patched to a limit the CPU does not
+report, tiny models whose step keeps every name: the same loss, gradients
+and parameters as the step that keeps nothing, and no second product of what
+was kept under `rematted_computation`."""
+
+import functools
+import importlib
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import spec
+from ray_tpu.models import TransformerConfig, make_train_step
+from ray_tpu.models import transformer as tr
+from ray_tpu.parallel import make_mesh
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+FLASH = fa.flash_attention
+
+LIMIT = int(15.75 * 2**30)  # what a v5e chip offers a program
+CELLS = ("mistral7b.tokens4k", "mistral7b.fsdp4", "olmoe.tokens4k",
+         "lfm2moe.tokens8k")
+
+
+def cell_shapes(cell_name):
+    """(cfg, tokens a device, state bytes a device, parameter bytes a
+    device) of a token cell: AdamW over f32 weights, sharded over its chips."""
+    cell = spec.load_cell(spec.ROOT, cell_name)
+    config, traffic = cell["config"], cell["traffic"]
+    cfg = spec.load_code(
+        spec.ROOT, "loops", config["family"]).model_config(config)
+    chips = cell["workload"]["chips"]
+    params = jax.eval_shape(
+        lambda: tr.transformer_init(jax.random.PRNGKey(0), cfg))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    tokens = int(traffic["batch_rows"]) * int(traffic["units_per_row"])
+    return cfg, tokens // chips, 12 * n_params // chips, 4 * n_params // chips
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_the_rule_on_a_token_cell_s_shapes(cell_name):
+    cfg, tokens, resident, params = cell_shapes(cell_name)
+    chosen = tr.saved_activations(cfg, tokens, resident, params, LIMIT)
+    every = tr._saved_bytes(cfg, tokens)
+    # names in the rule's own order, each at the bytes its shape gives
+    assert list(chosen) == list(every)[:len(chosen)]
+    assert all(chosen[name] == every[name] for name in chosen)
+    if chosen:
+        assert next(iter(chosen)) == "attn_ctx"
+    room = (LIMIT - resident - params - tr._SAVE_RESERVE
+            - tr._working_set_bytes(cfg, tokens, params))
+    assert sum(chosen.values()) <= max(room, 0)
+    # no limit to read, or no rematerialisation: nothing is kept
+    assert tr.saved_activations(cfg, tokens, resident, params, None) == {}
+    plain = TransformerConfig(**{**cfg.__dict__, "remat": False})
+    assert tr.saved_activations(plain, tokens, resident, params, LIMIT) == {}
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_the_choice_grows_with_the_limit(cell_name):
+    cfg, tokens, resident, params = cell_shapes(cell_name)
+    before = {}
+    for limit in range(8 << 30, 40 << 30, 1 << 28):
+        chosen = tr.saved_activations(cfg, tokens, resident, params, limit)
+        assert list(chosen)[:len(before)] == list(before)
+        before = chosen
+    assert before == tr._saved_bytes(cfg, tokens)  # at 40 GiB: every name
+
+
+def test_a_share_of_the_experts_has_no_names():
+    cfg, tokens, *_ = cell_shapes("lfm2moe.tokens8k")
+    assert not any(name.startswith("moe_")
+                   for name in tr._saved_bytes(cfg, tokens))
+    whole = TransformerConfig(**{**cfg.__dict__, "experts_held": None})
+    assert {"moe_slots", "moe_gate", "moe_up"} <= set(
+        tr._saved_bytes(whole, tokens))
+
+
+def test_bytes_follow_the_shapes():
+    cfg, tokens, *_ = cell_shapes("mistral7b.tokens4k")
+    sizes = tr._saved_bytes(cfg, tokens)
+    # two layers of 16,384 tokens: o [32 heads of 128] bf16 and one f32 lse
+    assert sizes["attn_ctx"] == 2 * 16384 * 32 * (128 * 2 + 4)
+    assert sizes["attn_qkv"] == 2 * 16384 * (32 + 2 * 8) * 128 * 2
+    assert sizes["attn_res"] == 2 * 16384 * 4096 * 2
+    assert sizes["mlp_gate"] == sizes["mlp_up"] == 2 * 16384 * 14336 * 2
+    assert set(sizes) == {"attn_ctx", "attn_res", "attn_qkv", "mlp_gate",
+                          "mlp_up"}
+
+
+# ------------------------------------------------- tiny steps on the CPU
+
+TINY = dict(vocab_size=96, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+            d_ff=64, max_seq_len=16, remat=True, attention_impl="xla",
+            tied_embeddings=False, dtype=jnp.float32)
+MODELS = {
+    "dense": {},
+    "routed": dict(n_experts=4, experts_per_token=2, qk_norm=True),
+    "conv_attention": dict(
+        n_layers=3, layer_types=("conv", "full_attention", "conv"),
+        n_dense_layers=1, d_ff_dense=48, n_experts=4, experts_per_token=2,
+        router_score="sigmoid", norm_topk_prob=True, qk_norm="head",
+        router_aux_loss_coef=0.0, router_z_loss_coef=0.0),
+}
+# what a kept name spares the second forward: (scope, primitive or kernel)
+SPARED = {
+    "dense": [("attention", "flash_fwd"), ("attn_qkv", "dot_general"),
+              ("attn_out", "dot_general"), ("mlp", "dot_general")],
+    "routed": [("attn_qkv", "dot_general"), ("moe_router", "sort"),
+               ("moe_experts", "ragged_dot_general")],
+    "conv_attention": [("conv_in", "dot_general"), ("conv_out", "dot_general"),
+                       ("attn_out", "dot_general"),
+                       ("moe_experts", "ragged_dot_general")],
+}
+
+
+def tiny_step(extra, **config):
+    cfg = TransformerConfig(**{**TINY, **extra, **config})
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    return cfg, make_train_step(cfg, mesh)
+
+
+def tiny_batch():
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0, 96)
+    return {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+
+
+def assert_same(new, old):
+    """Leaf by leaf to float32 rounding: the same operations, fewer times."""
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(old)):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b)
+
+
+def keep_everything(monkeypatch):
+    monkeypatch.setattr(tr, "_memory_limit", lambda mesh: 1 << 40)
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_a_step_that_keeps_every_name_is_the_same_step(monkeypatch, model):
+    _, (init_state, step, _) = tiny_step(MODELS[model])
+    plain_state, plain = step(init_state(jax.random.PRNGKey(0)), tiny_batch())
+    keep_everything(monkeypatch)
+    cfg, (init_state, step, _) = tiny_step(MODELS[model])
+    # the rule did choose, every name this model has
+    kept = tr.saved_activations(cfg, 32, 0, 0, 1 << 40)
+    assert set(kept) == set(tr._saved_bytes(cfg, 32)) and len(kept) >= 5
+    state, out = step(init_state(jax.random.PRNGKey(0)), tiny_batch())
+    for key in ("loss", "grad_norm"):
+        np.testing.assert_allclose(out[key], plain[key], rtol=1e-6)
+    assert_same(state["params"], plain_state["params"])
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_gradients_with_kept_names_are_the_gradients(model):
+    cfg, (init_state, _, _) = tiny_step(MODELS[model])
+    params = init_state(jax.random.PRNGKey(0))["params"]
+    names = tuple(tr._saved_bytes(cfg, 32))
+    loss, grads = jax.value_and_grad(tr.transformer_loss)(
+        params, tiny_batch(), cfg)
+    kept_loss, kept_grads = jax.value_and_grad(tr.transformer_loss)(
+        params, tiny_batch(), cfg, saved_names=names)
+    np.testing.assert_allclose(kept_loss, loss, rtol=1e-6)
+    assert_same(kept_grads, grads)
+
+
+def rematted(lowered):
+    """(scopes, primitive) of every operation of the second forward."""
+    stacks = set(re.findall(
+        r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+    out = set()
+    for stack in stacks:
+        parts = [p for p in re.split(r"[/()]", stack) if p]
+        if "rematted_computation" in parts:
+            out.add((frozenset(parts[:-1]), parts[-1]))
+    return out
+
+
+def lowered_tiny_step(model, monkeypatch):
+    """The model's tiny step, lowered; the dense model's attention through
+    the flash kernels in interpret mode, as the chip runs them (the grouped
+    matmul takes its kernels where attention does, so the routed models
+    stay on `ragged_dot` and plain attention, whose backward makes its
+    scores again whatever is kept)."""
+    config = {}
+    if model == "dense":
+        monkeypatch.setattr(
+            fa, "flash_attention", functools.partial(FLASH, interpret=True))
+        config = dict(attention_impl="pallas", max_seq_len=128)
+    _, (init_state, step, _) = tiny_step(MODELS[model], **config)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, config.get("max_seq_len", 16)), jnp.int32)
+    return step.lower(state, {"tokens": tokens, "targets": tokens})
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_what_is_kept_is_not_made_again(monkeypatch, model):
+    def made_again(lowered):
+        ops = rematted(lowered)
+        return {(scope, prim) for scope, prim in SPARED[model]
+                if any(scope in scopes and (prim == last or prim in scopes)
+                       for scopes, last in ops)}
+
+    # the step that keeps nothing makes every one of them twice
+    assert made_again(lowered_tiny_step(model, monkeypatch)) == set(
+        SPARED[model])
+    keep_everything(monkeypatch)
+    assert made_again(lowered_tiny_step(model, monkeypatch)) == set()
+
+
+def test_names_alone_change_no_instruction(monkeypatch):
+    """With no limit to read the step has the names and no policy: it lowers
+    to the text of the program without the names."""
+    texts = []
+    for named in (True, False):
+        if not named:
+            monkeypatch.setattr(tr, "checkpoint_name", lambda x, name: x)
+        _, (init_state, step, _) = tiny_step(MODELS["conv_attention"])
+        state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+        tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+        text = step.lower(
+            state, {"tokens": tokens, "targets": tokens}).as_text()
+        # a function's name ends in a counter of the functions traced
+        texts.append(re.sub(r"@(\w+?)_\d+\b", r"@\1", text))
+    assert texts[0] == texts[1]
+
+
+def test_the_choice_is_logged_when_the_step_is_traced(monkeypatch, caplog):
+    keep_everything(monkeypatch)
+    _, (init_state, step, _) = tiny_step({})
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    with caplog.at_level("INFO", logger=tr.logger.name):
+        step.lower(state, {"tokens": tokens, "targets": tokens})
+    lines = [r.getMessage() for r in caplog.records if "remat" in r.getMessage()]
+    assert len(lines) == 1
+    for name in ("attn_ctx", "attn_qkv", "attn_res", "mlp_gate", "mlp_up"):
+        assert name in lines[0]
+    assert str(1 << 40) in lines[0]  # the limit it read
+
+
+def test_tokens_on_a_device_follow_the_mesh(monkeypatch):
+    """The step hands the rule the tokens one device holds: the batch over
+    the mesh's batch axes."""
+    seen = {}
+
+    def rule(cfg, tokens, resident, params, limit):
+        seen.update(tokens=tokens, resident=resident, params=params)
+        return {}
+
+    monkeypatch.setattr(tr, "saved_activations", rule)
+    cfg = TransformerConfig(**TINY)
+    mesh = make_mesh({"fsdp": 4}, devices=jax.devices()[:4])
+    init_state, step, _ = make_train_step(cfg, mesh)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((8, 16), jnp.int32)
+    step.lower(state, {"tokens": tokens, "targets": tokens})
+    assert seen["tokens"] == 8 * 16 // 4
+    whole = sum(math.prod(x.shape) * x.dtype.itemsize
+                for x in jax.tree.leaves(state["params"]))
+    # the matrices are cut four ways, the norms' scales are whole
+    assert whole / 4 <= seen["params"] < whole / 3
+    assert 3 * seen["params"] <= seen["resident"] < 3 * seen["params"] + 64

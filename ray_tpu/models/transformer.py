@@ -27,6 +27,19 @@ period is one layer, and its parameters are the one stacked tree
 `params["blocks"]`; otherwise `params["blocks"]` is a list of segments, each
 a list with one such tree per layer of its period.
 
+A third operator is multi-head latent attention (`"latent_attention"` in
+`layer_types`; DeepSeek-V2's, arXiv:2405.04434): keys and values come from
+one low-rank projection of the token (`wkv_a` to a latent of `kv_lora_rank`
+and one rotary key of `qk_rope_head_dim` that every head shares; a norm;
+`wkv_b` up to every head's `qk_nope_head_dim` key columns and `v_head_dim`
+value columns), a head of q and k is its unrotated columns and then the
+rotary ones, and `rope_scaling` blends YaRN's frequencies and enters the
+softmax's scale. This is the decompressed form, which training and prefill
+use; the flash kernels take the two widths as they are. A routed layer may
+have shared experts beside the routed ones (`n_shared_experts`: one dense
+SwiGLU every token goes through, unweighted), and `seq_aux` takes the
+router's balance loss per sequence and sums it over the layers.
+
 With `remat` each block runs under `jax.checkpoint`: its input is kept and
 its values are made again in the backward pass, but for the named ones
 (`checkpoint_name`) that `make_train_step`'s step finds room for on the
@@ -41,7 +54,8 @@ Parallelism (ray_tpu.parallel.mesh axes):
 
 Capability analog of what the reference reaches only through integrations
 (SURVEY §5: it ships no native SP); here it is native. Cells that train it:
-`mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k` (BENCHMARK.json).
+`mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k`,
+`lfm2moe.tokens8k`, `dsv2lite.tokens8k` (BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -97,7 +111,8 @@ class TransformerConfig:
     qk_norm: Union[bool, str] = False
     router_aux_loss_coef: float = 0.01  # load balancing, mean over layers
     router_z_loss_coef: float = 0.001  # logsumexp(router logits)^2
-    # one of "full_attention" | "conv" per layer; () => attention everywhere
+    # one of "full_attention" | "conv" | "latent_attention" per layer;
+    # () => attention everywhere
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3  # the short convolution's reach, this token included
     n_dense_layers: int = 0  # with n_experts: leading layers with a dense FF
@@ -108,6 +123,21 @@ class TransformerConfig:
     expert_bias_update_rate: float = 1e-3
     # (first, n): compute experts first..first+n-1 of the n_experts routed over
     experts_held: Optional[Tuple[int, int]] = None
+    # latent attention, by config.json's own keys: the latent's width, a
+    # head's unrotated and rotary query-key columns, its value columns
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # ((key, value), ...) of config.json's `rope_scaling` (type "yarn"), for
+    # the latent attention's rotary columns; None => plain frequencies
+    rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
+    # with n_experts: one dense SwiGLU of this many experts' width beside
+    # the routed ones, unweighted
+    n_shared_experts: int = 0
+    # the balance loss per sequence, summed over the layers (else over the
+    # batch, mean over the layers)
+    seq_aux: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -145,7 +175,7 @@ class TransformerConfig:
 
 
 class LayerKind(NamedTuple):
-    op: str        # "full_attention" | "conv"
+    op: str        # "full_attention" | "conv" | "latent_attention"
     routed: bool   # the feed-forward: routed experts, or dense
 
 
@@ -191,6 +221,19 @@ def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
             "conv_w": _dense(ks[1], (L, cfg.conv_taps, d), cfg.conv_taps),
             "conv_out": _dense(ks[3], (L, d, d), d),
         }
+    elif kind.op == "latent_attention":
+        r, nope, rope, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                             cfg.qk_rope_head_dim, cfg.v_head_dim)
+        blocks = {
+            "attn_norm": jnp.ones((L, d), jnp.float32),
+            "wq": _dense(ks[0], (L, d, h * (nope + rope)), d),
+            # the latent, then the one rotary key all heads share
+            "wkv_a": _dense(ks[1], (L, d, r + rope), d),
+            "kv_norm": jnp.ones((L, r), jnp.float32),
+            # per head: its unrotated key columns, then its value columns
+            "wkv_b": _dense(ks[2], (L, r, h * (nope + dv)), r),
+            "wo": _dense(ks[3], (L, h * dv, d), h * dv),
+        }
     else:
         blocks = {
             "attn_norm": jnp.ones((L, d), jnp.float32),
@@ -213,7 +256,15 @@ def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
     if kind.routed:
         blocks["router"] = _dense(
             jax.random.fold_in(k_blk, 7), (L, d, cfg.n_experts), d)
-    if cfg.qk_norm and kind.op != "conv":
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            kg, ku, kd = jax.random.split(jax.random.fold_in(k_blk, 8), 3)
+            blocks.update({
+                "ws_gate": _dense(kg, (L, d, fs), d),
+                "ws_up": _dense(ku, (L, d, fs), d),
+                "ws_down": _dense(kd, (L, fs, d), fs),
+            })
+    if cfg.qk_norm and kind.op == "full_attention":
         per_head = cfg.qk_norm == "head"
         blocks["q_norm"] = jnp.ones((L, dh if per_head else h * dh), jnp.float32)
         blocks["k_norm"] = jnp.ones((L, dh if per_head else hk * dh), jnp.float32)
@@ -279,6 +330,21 @@ _ROUTED_AXES = {
     "w_down": ("layers", "experts", "mlp", "embed"),
     "router": ("layers", "embed", None),
 }
+_SHARED_AXES = {
+    "ws_gate": ("layers", "embed", "mlp"),
+    "ws_up": ("layers", "embed", "mlp"),
+    "ws_down": ("layers", "mlp", "embed"),
+}
+# cut along the heads where a product's columns (rows, for `wo`) are the
+# heads'; the down projection to the latent and the shared key is whole
+_LATENT_AXES = {
+    "attn_norm": ("layers", None),
+    "wq": ("layers", "embed", "heads"),
+    "wkv_a": ("layers", "embed", None),
+    "kv_norm": ("layers", None),
+    "wkv_b": ("layers", None, "heads"),
+    "wo": ("layers", "heads", "embed"),
+}
 _QK_NORM_AXES = {"q_norm": ("layers", "heads"), "k_norm": ("layers", "kv")}
 _HEAD_NORM_AXES = {"q_norm": ("layers", None), "k_norm": ("layers", None)}
 # the three streams of `conv_in` are split after the product and the
@@ -290,12 +356,14 @@ _CONV_AXES = {
     "conv_out": ("layers", None, "embed"),
 }
 _ATTENTION_KEYS = ("attn_norm", "wq", "wk", "wv", "wo")
+# the operators whose leaves take the place of full attention's
+_OPERATOR_AXES = {"conv": _CONV_AXES, "latent_attention": _LATENT_AXES}
 
 
 def _block_axes(cfg: TransformerConfig, kind: LayerKind):
     base = _LOGICAL_AXES["blocks"]
-    if kind.op == "conv":
-        table = {**_CONV_AXES,
+    if kind.op in _OPERATOR_AXES:
+        table = {**_OPERATOR_AXES[kind.op],
                  **{k: v for k, v in base.items() if k not in _ATTENTION_KEYS}}
     else:
         table = dict(base)
@@ -304,6 +372,8 @@ def _block_axes(cfg: TransformerConfig, kind: LayerKind):
                 _HEAD_NORM_AXES if cfg.qk_norm == "head" else _QK_NORM_AXES)
     if kind.routed:
         table.update(_ROUTED_AXES)
+        if cfg.n_shared_experts:
+            table.update(_SHARED_AXES)
     return table
 
 
@@ -333,14 +403,67 @@ def param_shardings(mesh, cfg: TransformerConfig):
 
 # ----------------------------------------------------------------- forward
 
-def _rope(x, positions, theta: float):
-    """Rotary embedding on [B, T, H, Dh] with integer positions [B, T]."""
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_frequencies(width: int, theta: float, scaling=None):
+    """(the `width / 2` rotary frequencies, float32; what cos and sin are
+    multiplied by). Plain: `theta ** (-2 i / width)` and 1. With
+    `scaling`, a mapping of config.json's `rope_scaling` of type "yarn"
+    (arXiv:2309.00071), the frequencies that turn more than `beta_fast`
+    times over the original context are kept, those that turn fewer than
+    `beta_slow` times are divided by `factor`, and a linear ramp blends
+    the ones between; cos and sin are scaled by the ratio of
+    `yarn_softmax_scale`'s two factors, `mscale` and `mscale_all_dim`."""
+    half = width // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if not scaling:
+        return freqs, 1.0
+    low, high = yarn_ramp_bounds(width, theta, scaling)
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3),
+        0.0, 1.0)
+    factor = scaling["factor"]
+    blended = freqs / factor * ramp + freqs * (1.0 - ramp)
+    return blended, (_yarn_mscale(factor, scaling.get("mscale", 1.0))
+                     / _yarn_mscale(factor, scaling.get("mscale_all_dim", 0.0)))
+
+
+def yarn_ramp_bounds(width: int, theta: float, scaling) -> Tuple[int, int]:
+    """The first frequency YaRN's ramp moves and the first it moves all the
+    way: the pairs that make `beta_fast` and `beta_slow` turns over the
+    original context, rounded outward and kept inside the `width / 2`."""
+    span = scaling["original_max_position_embeddings"]
+
+    def pair(turns):
+        return width * math.log(span / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    return (max(math.floor(pair(scaling.get("beta_fast", 32))), 0),
+            min(math.ceil(pair(scaling.get("beta_slow", 1))), width - 1))
+
+
+def yarn_softmax_scale(width: int, scaling=None) -> float:
+    """The scale of the scores of heads `width` wide: `width ** -0.5`, times
+    the square of YaRN's `mscale_all_dim` factor where there is scaling."""
+    scale = width ** -0.5
+    if scaling and scaling.get("mscale_all_dim"):
+        scale *= _yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]) ** 2
+    return scale
+
+
+def _rope(x, positions, theta: float, scaling=None):
+    """Rotary embedding on [B, T, H, Dh] with integer positions [B, T], at
+    `rope_frequencies(Dh, theta, scaling)`."""
     B, T, H, Dh = x.shape
     half = Dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs, mscale = rope_frequencies(Dh, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, T, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -348,7 +471,10 @@ def _rope(x, positions, theta: float):
 
 
 def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
-               seq_size: int, mesh=None, keep_ctx: bool = False):
+               seq_size: int, mesh=None, keep_ctx: bool = False,
+               scale: Optional[float] = None):
+    """Causal attention of q, k [B, T, H, D] and v [B, T, H, Dv]; `scale`
+    is the scores', `1 / sqrt(D)` where None."""
     if cfg.attention_impl == "ring" and seq_axis is not None:
         # Inside shard_map over the sequence axis: exact ring attention.
         rep = cfg.n_heads // k.shape[2]
@@ -359,7 +485,7 @@ def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
             q, k, v, axis_name=seq_axis, axis_size=seq_size, causal=True
         )
     impl = _kernel_impl(cfg)
-    attn = partial(mha, causal=True, impl=impl, keep_ctx=keep_ctx)
+    attn = partial(mha, causal=True, impl=impl, keep_ctx=keep_ctx, scale=scale)
     if impl == "pallas" and mesh is not None and mesh.size > 1:
         # XLA cannot partition a Mosaic kernel ("wrap the call in a
         # shard_map"), so map it ourselves over the axes attention is
@@ -418,11 +544,51 @@ def _attention_layer(x, blk, positions, cfg: TransformerConfig,
             x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt), "attn_res")
 
 
+def _latent_attention_layer(x, blk, positions, cfg: TransformerConfig,
+                            mesh=None, keep_ctx: bool = False):
+    """x + latent_attention(norm(x)), decompressed: every head's keys and
+    values are made from the token's latent and the heads attend as in any
+    multi-head attention, with q and k `qk_nope_head_dim + qk_rope_head_dim`
+    wide and v `v_head_dim` wide. Rotary positions turn the last
+    `qk_rope_head_dim` columns of a head of q, and the one key of that width
+    that `wkv_a` makes beside the latent and all heads share."""
+    B, T, d = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = cfg.dtype
+    scaling = dict(cfg.rope_scaling) if cfg.rope_scaling else None
+    with jax.named_scope("attn_qkv"):
+        y = fused_rmsnorm(x, blk["attn_norm"], eps=cfg.norm_eps)
+        q = checkpoint_name(y @ blk["wq"].astype(dt), "attn_qkv").reshape(
+            B, T, h, nope + rope)
+        q = jnp.concatenate(
+            [q[..., :nope],
+             _rope(q[..., nope:], positions, cfg.rope_theta, scaling)], axis=-1)
+    with jax.named_scope("kv_down"):
+        down = checkpoint_name(y @ blk["wkv_a"].astype(dt), "attn_qkv")
+        latent = fused_rmsnorm(down[..., :r], blk["kv_norm"], eps=cfg.norm_eps)
+        k_pe = _rope(down[..., None, r:], positions, cfg.rope_theta, scaling)
+    with jax.named_scope("kv_up"):
+        kv = checkpoint_name(latent @ blk["wkv_b"].astype(dt), "attn_qkv"
+                             ).reshape(B, T, h, nope + dv)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, h, rope))], axis=-1)
+        v = kv[..., nope:]
+    with jax.named_scope("attention"):
+        o = _attention(q, k, v, cfg, None, 1, mesh, keep_ctx,
+                       scale=yarn_softmax_scale(nope + rope, scaling))
+    with jax.named_scope("attn_out"):
+        return checkpoint_name(
+            x + o.reshape(B, T, h * dv) @ blk["wo"].astype(dt), "attn_res")
+
+
 def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
     """The routed feed-forward on normed activations `y` [B, T, d]: the sum
     over a token's `experts_per_token` experts of p_e * SwiGLU_e(y), and the
     layer's router readings {aux_loss, z_loss, expert_load [E],
     expert_index [B T, k]}. Dropless: `expert_load` sums to B T k.
+    `aux_loss` is the balance loss over the batch or, with `seq_aux`, the
+    mean over the B sequences of each one's own.
 
     A layer that holds a share of the experts (`w_gate` has fewer than the
     router's width) sums over the held among a token's experts and leaves
@@ -454,10 +620,17 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
         if not share:
             slots = moe.Slots(
                 *(checkpoint_name(s, "moe_slots") for s in slots))
-        load = (moe.expert_load(index, cfg.n_experts) if share
-                else slots.group_sizes)
+        if cfg.seq_aux:  # every sequence's own counts, over all E experts
+            per_sequence = moe.sequence_load(index, cfg.n_experts, B)
+            load = per_sequence.sum(axis=0) if share else slots.group_sizes
+            aux_loss = moe.sequence_balancing_loss(
+                probs.reshape(B, T, -1), per_sequence)
+        else:
+            load = (moe.expert_load(index, cfg.n_experts) if share
+                    else slots.group_sizes)
+            aux_loss = moe.load_balancing_loss(probs, load)
         readings = {
-            "aux_loss": moe.load_balancing_loss(probs, load),
+            "aux_loss": aux_loss,
             "z_loss": moe.router_z_loss(logits),
             "expert_load": load,
             "expert_index": index,
@@ -473,7 +646,8 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
         out = moe.experts_of_share(
             tokens, blk["w_gate"], blk["w_up"], blk["w_down"], weights, slots,
             impl=impl, chunk=moe.held_chunk(
-                slots.order.shape[0], n_held, cfg.n_experts))
+                slots.order.shape[0], n_held, cfg.n_experts,
+                load_held_even=cfg.expert_bias))
         return out.reshape(B, T, d), readings
     with jax.named_scope("moe_dispatch"):
         xs = moe.dispatch(tokens, slots.order, slots.inverse)
@@ -518,18 +692,25 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig,
            keep_ctx: bool = False):
     """One block: (x, the routed feed-forward's readings or None). What the
     block is, its parameters say: a short convolution where it has
-    `conv_in`, a routed feed-forward where it has a `router`. `keep_ctx`:
-    the attention kernel names its backward's residuals `attn_ctx`."""
+    `conv_in`, latent attention where it has `wkv_a`, a routed feed-forward
+    where it has a `router`, shared experts beside it where it has
+    `ws_gate`. `keep_ctx`: the attention kernel names its backward's
+    residuals `attn_ctx`."""
     dt = cfg.dtype
 
     # The scopes name the step's device work in a profiler trace
     # (docs/observability.md, "Device scopes"); they are metadata only.
-    if "conv_in" in blk:
+    if "conv_in" in blk or "wkv_a" in blk:
         if seq_axis is not None:
             raise NotImplementedError(
-                "the short convolution is not mapped over a sequence axis")
+                "the short convolution and latent attention are not mapped "
+                "over a sequence axis")
+    if "conv_in" in blk:
         with jax.named_scope("short_conv"):
             x = checkpoint_name(x + _short_conv(x, blk, cfg), "conv_res")
+    elif "wkv_a" in blk:
+        with jax.named_scope("latent_attention"):
+            x = _latent_attention_layer(x, blk, positions, cfg, mesh, keep_ctx)
     else:
         x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size, mesh,
                              keep_ctx)
@@ -539,6 +720,13 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig,
         y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
         if "router" in blk:
             routed, readings = _routed_ffn(y, blk, cfg, mesh, bias)
+            if "ws_gate" in blk:  # every token, unweighted, whole on a share
+                with jax.named_scope("moe_shared"):
+                    gate = jax.nn.silu(checkpoint_name(
+                        y @ blk["ws_gate"].astype(dt), "shared_gate"))
+                    up = checkpoint_name(
+                        y @ blk["ws_up"].astype(dt), "shared_up")
+                    routed = routed + (gate * up) @ blk["ws_down"].astype(dt)
             x = x + routed
         else:
             gate = jax.nn.silu(
@@ -661,9 +849,11 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
 
     A model with routed experts adds `router_aux_loss_coef` times the
     load-balancing loss and `router_z_loss_coef` times the router z-loss,
-    each a mean over the layers, and its readings are `aux_loss` and
-    `z_loss` (those means), `expert_load` [L, E] (slots per expert; every
-    row sums to B T k) and `expert_index` [L, B T, k], L the routed layers;
+    each a mean over the layers (with `seq_aux` the balance loss is every
+    sequence's own, summed over the layers), and its readings are `aux_loss`
+    and `z_loss` (those means, or that sum), `expert_load` [L, E] (slots per
+    expert; every row sums to B T k) and `expert_index` [L, B T, k], L the
+    routed layers;
     a coefficient of 0 adds nothing. A share of the experts
     (`experts_held`) also reads `held_slots` and `dropped_slots` [L]. A
     dense model's readings are empty. `expert_bias=` [L, E] is the routers'
@@ -678,7 +868,10 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
             hidden, _unembed(params, cfg), targets)
     if readings is None:
         return loss, {}
-    readings = dict(readings, aux_loss=readings["aux_loss"].mean(),
+    # over the layers: the mean, or with `seq_aux` the sum, as the published
+    # code adds each layer's own
+    over_layers = jnp.sum if cfg.seq_aux else jnp.mean
+    readings = dict(readings, aux_loss=over_layers(readings["aux_loss"]),
                     z_loss=readings["z_loss"].mean())
     if cfg.router_aux_loss_coef or cfg.router_z_loss_coef:
         loss = (loss + cfg.router_aux_loss_coef * readings["aux_loss"]
@@ -703,14 +896,17 @@ def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
 # whatever its width, so these rank by what rides with the matmul: RoPE and
 # the norms with q, k and v, nothing with `up`).
 _SAVE_ORDER = (
-    "attn_ctx",   # the kernel's o [B H, T, dh] and lse as one f32 column
+    "attn_ctx",   # the kernel's o [B H, T, dv] and lse as one f32 column
     "moe_slots",  # the sorted slots: no second sort (integers, small)
     "attn_res",   # the stream after attention: no second `wo` product
     "conv_res",   # the stream after the short convolution: no `conv_out`
     "attn_qkv",   # the q, k, v products, before QK-norm, RoPE and GQA's repeat
+                  # (latent attention: out of `wq`, `wkv_a` and `wkv_b`)
     "conv_in",    # the three streams out of `conv_in`
     "moe_gate",   # the experts' gate product [slots, f]
     "moe_up",     # and their up product
+    "shared_gate",  # the shared experts' gate product, before the silu
+    "shared_up",    # and their up product
     "mlp_gate",   # the dense feed-forward's gate product, before the silu
     "mlp_up",     # and its up product
 )
@@ -732,6 +928,18 @@ def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
     if kind.op == "conv":
         widths = {"conv_res": d, "conv_in": 3 * d}
         params = 4 * d * d
+    elif kind.op == "latent_attention":
+        r, dv = cfg.kv_lora_rank, cfg.v_head_dim
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        up = cfg.qk_nope_head_dim + dv
+        widths = {
+            "attn_ctx": h * _tile_lanes(dv) + h * 4 // item,
+            "attn_res": d,
+            # out of `wq`, `wkv_a` and `wkv_b`
+            "attn_qkv": h * qk + r + cfg.qk_rope_head_dim + h * up,
+        }
+        params = (d * h * qk + d * (r + cfg.qk_rope_head_dim) + r * h * up
+                  + h * dv * d)
     else:
         widths = {
             # o and lse as one float32 column
@@ -751,6 +959,10 @@ def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
         if cfg.held[1] == cfg.n_experts:
             widths.update(moe_slots=2 * k * 4 // item, moe_gate=k * f,
                           moe_up=k * f)
+        if cfg.n_shared_experts:  # dense work on every token, share or not
+            fs = cfg.n_shared_experts * f
+            widths.update(shared_gate=fs, shared_up=fs)
+            params += 3 * d * fs
     return widths, params
 
 
@@ -774,9 +986,10 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
 
     A block in its backward is taken as every value of its widest layer at
     once: the named ones, the two normed inputs, the stream's cotangent,
-    q, k and v as the kernel takes them (heads repeated), lse and delta at
-    a tile's 128 lanes, the feed-forward's hidden product and a routed
-    layer's dispatched rows; with the compute-dtype copy of its weights
+    q, k and v as the kernel takes them (heads repeated; latent attention's
+    q and k at two tiles of lanes), lse and delta at a tile's 128 lanes,
+    the feed-forward's (and the shared experts') hidden product and a
+    routed layer's dispatched rows; with the compute-dtype copy of its weights
     and, where the parameters are sharded, the same weights gathered whole
     and their float32 gradient before it is scattered. Against the chip
     (`bytes_in_use + bytes_reserved` less state and gradients; PERF.md
@@ -798,12 +1011,17 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
         width = sum(widths.values()) + 3 * d
         if kind.op == "conv":
             width += 3 * d  # the gate's product, the taps' sum, the gated
+        elif kind.op == "latent_attention":  # q and k at their own width
+            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            width += (h * (2 * _tile_lanes(qk) + _tile_lanes(cfg.v_head_dim))
+                      + 2 * h * 128 * 4 // item)
         else:
             width += (3 * h * _tile_lanes(cfg.head_dim)
                       + 2 * h * 128 * 4 // item)
         if kind.routed:
             width += (cfg.experts_per_token * (d + cfg.ff_dim)
                       if "moe_gate" in widths else 0)
+            width += widths.get("shared_gate", 0)  # the shared hidden product
         else:
             width += widths["mlp_gate"]
         weights = params * item + (params * (item + 4) if sharded else 0)
@@ -990,10 +1208,18 @@ def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
     # sees (seq_len + 1) / 2 positions; qk^T and pv each cost 2*h*dh flops
     # per (query, key) pair. The flash kernel really skips the masked-out
     # tiles, so crediting full seq_len here would overcount ~2x.
+    routed += 2 * 3 * d * cfg.n_shared_experts * f  # every token, whole
     matmul = attn = 0.0
     for kind in cfg.layers:
         if kind.op == "conv":
             matmul += 2 * d * 3 * d + 2 * d * d
+        elif kind.op == "latent_attention":
+            r, dv = cfg.kv_lora_rank, cfg.v_head_dim
+            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            matmul += 2 * (d * h * qk + d * (r + cfg.qk_rope_head_dim)
+                           + r * h * (cfg.qk_nope_head_dim + dv) + h * dv * d)
+            # scores over q and k's width, the values over v's
+            attn += 2 * h * (qk + dv) * ((seq_len + 1) / 2)
         else:
             matmul += 2 * d * (h * dh + 2 * hk * dh) + 2 * h * dh * d
             attn += 2 * 2 * h * dh * ((seq_len + 1) / 2)

@@ -23,8 +23,9 @@ metrics read (docs/observability.md, "Device scopes").
 
 The tile program, the same in all three kernels:
 
-- Tiles come from the shape. `flash_tiles(kernel, T, S, D, dtype)` returns
-  `block_q` and `block_k` for one kernel: multiples of 128 that do not exceed
+- Tiles come from the shape. `flash_tiles(kernel, T, S, D, dtype)` (with
+  `v_dim=` where v is narrower or wider than q and k) returns `block_q` and
+  `block_k` for one kernel: multiples of 128 that do not exceed
   the sequence (the sequence itself when it is shorter than 128), chosen to
   make the sum of two costs least: what every grid step costs whatever it
   holds, and the work of the tiles that have a body, of which the part above
@@ -48,6 +49,12 @@ The tile program, the same in all three kernels:
   one, so the step names the block already in VMEM.
 - Outputs leave in the input's dtype: o, and dq, dk, dv, which the flush
   rounds once from the f32 accumulator. lse and delta are f32 `[BH, T, 8]`.
+- Two widths. q, k, dq and dk are `D` wide, v, o, do, dv and the forward's
+  accumulator `Dv` wide (latent attention: 128 + 64 rotary against 128).
+  A block's last dimension is the array's whole width, so 192 goes to the
+  MXU as it is, with no zero column in HBM; in VMEM it fills two tiles of
+  128 lanes, which is what `_vmem_bytes` counts. With `Dv == D` every
+  shape, tile and body is the one-width kernel's.
 """
 
 from __future__ import annotations
@@ -115,21 +122,24 @@ def _active_tiles(T, S, block_q, block_k, causal) -> int:
     )
 
 
-def _vmem_bytes(kernel, block_q, block_k, D, itemsize) -> int:
+def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None) -> int:
     """Blocks in flight (double-buffered), scratch and the body's live
-    [bq, bk] tiles. A VMEM row is 128 lanes wide whatever D is."""
-    width = _cdiv(D, _LANES) * _LANES
-    q_like, k_like = block_q * width, block_k * width  # elements
+    [bq, bk] tiles, for q and k `D` wide and v `Dv` wide (`D` where None).
+    A VMEM row is whole tiles of 128 lanes whatever the width is."""
+    qk = _cdiv(D, _LANES) * _LANES
+    vo = qk if Dv is None else _cdiv(Dv, _LANES) * _LANES
+    q_qk, q_vo = block_q * qk, block_q * vo  # elements: q, dq; o, do
+    k_qk, k_vo = block_k * qk, block_k * vo  # k, dk; v, dv
     row = block_q * _LANES * 4  # an lse/delta block, or m or l
     if kernel == "flash_fwd":
-        blocks = (2 * q_like + 2 * k_like) * itemsize + row
-        scratch = q_like * 4 + 2 * row
+        blocks = (q_qk + q_vo + k_qk + k_vo) * itemsize + row
+        scratch = q_vo * 4 + 2 * row
     elif kernel == "flash_bwd_dq":
-        blocks = (3 * q_like + 2 * k_like) * itemsize + 2 * row
-        scratch = q_like * 4
+        blocks = (2 * q_qk + q_vo + k_qk + k_vo) * itemsize + 2 * row
+        scratch = q_qk * 4
     else:
-        blocks = (2 * q_like + 4 * k_like) * itemsize + 2 * row
-        scratch = 2 * k_like * 4
+        blocks = (q_qk + q_vo + 2 * k_qk + 2 * k_vo) * itemsize + 2 * row
+        scratch = (k_qk + k_vo) * 4
     f32_tiles, dtype_tiles = _LIVE_TILES[kernel]
     live = block_q * block_k * (4 * f32_tiles + itemsize * dtype_tiles)
     return 2 * blocks + scratch + live
@@ -155,23 +165,49 @@ _COST_US = {
 }
 
 
+# The matmuls over a tile's pairs, by the width each walks: (over q and k's
+# width, over v's). Forward s = q k^T | o = p v; dq: s, dq = ds k | dp = do
+# v^T; dk/dv: s, dk = ds^T q | dp, dv = p^T do.
+_PAIR_MATMULS = {"flash_fwd": (1, 1), "flash_bwd_dq": (2, 1),
+                 "flash_bwd_dkv": (2, 2)}
+
+
+def _pairs_factor(kernel: str, D: int, Dv: int) -> float:
+    """What a pair costs with q and k `D` wide, over what it costs at `Dv`
+    all round, where `_COST_US` was measured: the MXU takes a width in
+    passes of 128 lanes, so 192 costs two, and one only of a kernel's
+    matmuls walk q and k's width. 1 at equal widths. The sweep at BH 64,
+    T 8192, bf16, causal on one v5e (PERF.md section 6, PR 34) read 1.49,
+    1.70 and 1.52 for (192, 128) over (128, 128) where this gives 1.5,
+    1.67 and 1.5, and the tile it leads to, 1024 x 1024, was the fastest
+    of {256, 512, 1024}^2 in all three kernels."""
+    over_qk, over_v = _PAIR_MATMULS[kernel]
+    passes_qk, passes_v = _cdiv(D, _LANES), _cdiv(Dv, _LANES)
+    return (over_qk * passes_qk + over_v * passes_v) / (
+        (over_qk + over_v) * passes_v)
+
+
 def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
                 causal: bool = True, block_q: Optional[int] = None,
-                block_k: Optional[int] = None) -> FlashTiles:
+                block_k: Optional[int] = None,
+                v_dim: Optional[int] = None) -> FlashTiles:
     """The tile of `kernel` (`flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv`)
-    for q of [*, T, D] and k, v of [*, S, D] in `dtype`. Pure: the shape
-    decides, nothing is asked of a device. Among the tiles that fit VMEM it
-    takes the one whose grid costs least by `_COST_US`: small tiles pay in
-    grid steps, large ones in pairs above the causal diagonal that a
-    diagonal tile computes and masks. A forced `block_q` or `block_k` is
-    taken as given (cut to the sequence) and the other is chosen."""
+    for q of [*, T, D] and k of [*, S, D] and v of [*, S, v_dim] (`D` where
+    None) in `dtype`. Pure: the shape decides, nothing is asked of a
+    device. Among the tiles that fit VMEM it takes the one whose grid costs
+    least by `_COST_US`: small tiles pay in grid steps, large ones in pairs
+    above the causal diagonal that a diagonal tile computes and masks. A
+    forced `block_q` or `block_k` is taken as given (cut to the sequence)
+    and the other is chosen."""
     itemsize = jnp.dtype(dtype).itemsize
+    Dv = D if v_dim is None else v_dim
     step_us, rows_us, pairs_us = _COST_US[kernel]
+    pairs_us = pairs_us * _pairs_factor(kernel, D, Dv)
 
     def plan(bq, bk):
         steps = _cdiv(T, bq) * _cdiv(S, bk)
         active = _active_tiles(T, S, bq, bk, causal)
-        vmem = _vmem_bytes(kernel, bq, bk, D, itemsize)
+        vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv)
         cost = steps * step_us + active * (
             rows_us * bq / 1024 + pairs_us * bq * bk / 2 ** 20)
         return cost, FlashTiles(
@@ -269,7 +305,7 @@ def _attn_fwd_kernel(
     def _body(masked):
         q = q_ref[0]  # [bq, D]
         k = k_ref[0]  # [bk, D]
-        v = v_ref[0]  # [bk, D]
+        v = v_ref[0]  # [bk, Dv]
         s = _dot(q, k, _NT) * scale  # [bq, bk]
         if masked:
             mask = _tile_mask(qi, ki, **shape)
@@ -290,7 +326,7 @@ def _attn_fwd_kernel(
         if masked and seq_k % block_k:
             # Padded K/V rows may be NaN-filled; p is 0 there but 0*NaN=NaN.
             v = jnp.where(_rows_valid(ki, block_k, seq_k), v, 0.0)
-        pv = _dot(p.astype(v.dtype), v, _NN)  # [bq, D]
+        pv = _dot(p.astype(v.dtype), v, _NN)  # [bq, Dv]
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
 
     # Tiles strictly above the diagonal contribute nothing and have no body.
@@ -349,12 +385,13 @@ def _compiler_params(tiles: FlashTiles):
     )
 
 
-def _grid(kernel, q, k, causal, block_q, block_k):
-    """(tiles, q tiles, k tiles) of `kernel` for q of [BH, T, D] and k of
-    [BH, S, D]; `block_q`, `block_k` force a tile or are None."""
+def _grid(kernel, q, k, v, causal, block_q, block_k):
+    """(tiles, q tiles, k tiles) of `kernel` for q of [BH, T, D], k of
+    [BH, S, D] and v of [BH, S, Dv]; `block_q`, `block_k` force a tile or
+    are None."""
     T, S = q.shape[1], k.shape[1]
     tiles = flash_tiles(kernel, T, S, q.shape[2], q.dtype, causal=causal,
-                        block_q=block_q, block_k=block_k)
+                        block_q=block_q, block_k=block_k, v_dim=v.shape[2])
     return tiles, _cdiv(T, tiles.block_q), _cdiv(S, tiles.block_k)
 
 
@@ -380,8 +417,8 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
-    S = k.shape[1]
-    tiles, num_q, num_k = _grid("flash_fwd", q, k, causal, block_q, block_k)
+    S, Dv = k.shape[1], v.shape[2]
+    tiles, num_q, num_k = _grid("flash_fwd", q, k, v, causal, block_q, block_k)
     block_q, block_k = tiles.block_q, tiles.block_k
 
     kernel = functools.partial(
@@ -396,8 +433,8 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
     )
 
     k_block = _k_block_under_q(causal, block_q, block_k)
-    out_shape = jax.ShapeDtypeStruct((BH, T, D), q.dtype)
-    out_specs = pl.BlockSpec((1, block_q, D), _q_block)
+    out_shape = jax.ShapeDtypeStruct((BH, T, Dv), q.dtype)
+    out_specs = pl.BlockSpec((1, block_q, Dv), _q_block)
     if with_lse:
         out_shape = [
             out_shape,
@@ -410,12 +447,12 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, D), _q_block),
             pl.BlockSpec((1, block_k, D), k_block),
-            pl.BlockSpec((1, block_k, D), k_block),
+            pl.BlockSpec((1, block_k, Dv), k_block),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -492,8 +529,8 @@ def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
     contraction even where the weight is 0."""
     q = q_ref[0]  # [bq, D]
     k = k_ref[0]  # [bk, D]
-    v = v_ref[0]  # [bk, D]
-    do = do_ref[0]  # [bq, D]
+    v = v_ref[0]  # [bk, Dv]
+    do = do_ref[0]  # [bq, Dv]
     lse = lse_ref[0][:, :1]  # [bq, 1] (lane-replicated input)
     delta = delta_ref[0][:, :1]  # [bq, 1]
     if masked and seq_q % block_q:
@@ -577,7 +614,7 @@ def _attn_bwd_dkv_kernel(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
             masked=masked, scale=scale, **shape,
         )
-        dv_acc_ref[...] += _dot(p, do, _TN)  # [bk, D]
+        dv_acc_ref[...] += _dot(p, do, _TN)  # [bk, Dv]
         dk_acc_ref[...] += _dot(ds, q, _TN)  # [bk, D]
 
     # Only q tiles at/below the diagonal see this k tile.
@@ -595,8 +632,9 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
-    S = k.shape[1]
-    tiles, num_q, num_k = _grid("flash_bwd_dq", q, k, causal, block_q, block_k)
+    S, Dv = k.shape[1], v.shape[2]
+    tiles, num_q, num_k = _grid(
+        "flash_bwd_dq", q, k, v, causal, block_q, block_k)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dq_kernel,
@@ -611,8 +649,8 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
         in_specs=[
             pl.BlockSpec((1, block_q, D), _q_block),
             pl.BlockSpec((1, block_k, D), k_block),
-            pl.BlockSpec((1, block_k, D), k_block),
-            pl.BlockSpec((1, block_q, D), _q_block),
+            pl.BlockSpec((1, block_k, Dv), k_block),
+            pl.BlockSpec((1, block_q, Dv), _q_block),
             pl.BlockSpec((1, block_q, 8), _q_block),
             pl.BlockSpec((1, block_q, 8), _q_block),
         ],
@@ -631,9 +669,9 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
-    S = k.shape[1]
+    S, Dv = k.shape[1], v.shape[2]
     tiles, num_q, num_k = _grid(
-        "flash_bwd_dkv", q, k, causal, block_q, block_k)
+        "flash_bwd_dkv", q, k, v, causal, block_q, block_k)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dkv_kernel,
@@ -656,22 +694,22 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_block),
             pl.BlockSpec((1, block_k, D), k_block),
-            pl.BlockSpec((1, block_k, D), k_block),
-            pl.BlockSpec((1, block_q, D), q_block),
+            pl.BlockSpec((1, block_k, Dv), k_block),
+            pl.BlockSpec((1, block_q, Dv), q_block),
             pl.BlockSpec((1, block_q, 8), q_block),
             pl.BlockSpec((1, block_q, 8), q_block),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), k_block),
-            pl.BlockSpec((1, block_k, D), k_block),
+            pl.BlockSpec((1, block_k, Dv), k_block),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, S, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=_compiler_params(tiles),
         interpret=interpret,
@@ -706,14 +744,16 @@ def flash_attention(
     interpret: bool = False,
     keep_ctx: bool = False,
 ):
-    """Flash attention on [B, T, H, D] inputs (grouped-query: H_kv may divide H).
+    """Flash attention on q, k of [B, T, H, D] and v of [B, T, H, Dv], which
+    gives [B, T, H, Dv] (grouped-query: H_kv may divide H). `scale` is
+    `1 / sqrt(D)` where the caller has none of its own.
 
     `block_q` and `block_k` force every kernel's tile; `None` lets
     `flash_tiles` choose each kernel's from the shape. `keep_ctx` names the
     backward's residuals o and lse `attn_ctx` (`jax.ad_checkpoint`), for a
     caller under `jax.checkpoint` whose policy keeps that name."""
     B, T, H, D = q.shape
-    Hk = k.shape[2]
+    Hk, Dv = k.shape[2], v.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     if Hk != H:
@@ -723,10 +763,10 @@ def flash_attention(
     # [B, T, H, D] -> [B*H, T, D]
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], D)
+    vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], Dv)
     of = _flash(qf, kf, vf, causal, scale, block_q, block_k, interpret,
                 keep_ctx)
-    return of.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    return of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
 
 
 def resolve_impl(impl: str) -> str:
@@ -740,7 +780,8 @@ def resolve_impl(impl: str) -> str:
 
 def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
         impl: str = "auto", keep_ctx: bool = False):
-    """Multi-head attention dispatch on [B, T, H, D].
+    """Multi-head attention dispatch on q, k of [B, T, H, D] and v of
+    [B, T, H, Dv]: [B, T, H, Dv].
 
     impl: 'auto' (pallas on TPU, XLA elsewhere) | 'pallas' | 'xla'.
     `keep_ctx` is `flash_attention`'s; plain attention names its output.
@@ -750,7 +791,7 @@ def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                keep_ctx=keep_ctx)
     B, T, H, D = q.shape
-    Hk = k.shape[2]
+    Hk, Dv = k.shape[2], v.shape[3]
     if Hk != H:
         rep = H // Hk
         k = jnp.repeat(k, rep, axis=2)
@@ -759,8 +800,8 @@ def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
         scale = 1.0 / math.sqrt(D)
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], D)
+    vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], Dv)
     of = _xla_attention_bhtd(qf, kf, vf, causal=causal, scale=scale)
-    out = of.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    out = of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
     # kept, it saves the output alone: this backward needs its scores again
     return checkpoint_name(out, "attn_ctx") if keep_ctx else out

@@ -12,6 +12,8 @@ imbalance, and the group sizes always sum to `T x k`.
     sort_slots(index, E, (first, n)) the same for a share of the experts
     dispatch(x, order, inverse)      [T, d] -> [T k, d], rows by expert
     grouped_matmul(x, w, sizes)      rows of group e times w[e]
+    load_balancing_loss, sequence_balancing_loss
+                                     over the batch, or per sequence
     combine(ys, weights, inverse)    [T k, d] -> [T, d]
     combine_held(ys, weights, order, rows)   the same from a share's rows
     project_and_combine(hidden, w_down, weights, slots)
@@ -148,18 +150,30 @@ def expert_load(index, n_experts: int):
 
 
 _HELD_SLACK = 1.25
+_HELD_SLACK_BY_LOSS = 1.375
 
 
-def held_chunk(slots: int, held: int, n_experts: int) -> int:
+def held_chunk(slots: int, held: int, n_experts: int,
+               load_held_even: bool = True) -> int:
     """Rows of the expert-order buffers of a layer that holds `held` of
-    `n_experts` experts, of `slots` slots in all: one and a quarter times
-    its even share, in whole row tiles (over 96 steps of four seeds
-    `lfm2moe.tokens8k`'s held rows stayed within 5 % of even: PERF.md
-    section 6, PR 32). A static length cannot follow the load, so
+    `n_experts` experts, of `slots` slots in all: its even share and some
+    slack, in whole row tiles. A static length cannot follow the load, so
     `experts_of_share` walks the held rows a chunk of this length at a
-    time, as many chunks as the step's routing fills: one, near balance."""
+    time, as many chunks as the step's routing fills: one, near balance.
+    Every pass over a chunk's buffers costs what the buffers hold, not the
+    rows in them, so the slack is paid in every step and a second chunk is
+    a whole pass more. Where a selection bias holds the load even
+    (`load_held_even`) the slack is a quarter: over 96 steps of four seeds
+    `lfm2moe.tokens8k`'s held rows stayed within 5 % of even (PERF.md
+    section 6, PR 32). Where only a loss term balances the router it is
+    three eighths: over 640 layer-steps of eight seeds the held rows of
+    `dsv2lite.tokens8k`, in a run's first steps, read 0.73 to 1.32 even
+    shares (standard deviation 0.10; 9 above 1.25, 1 above 1.3125), and a
+    second chunk cost 22 ms a layer of a 1,125 ms step (PERF.md section 6,
+    PR 34)."""
+    slack = _HELD_SLACK if load_held_even else _HELD_SLACK_BY_LOSS
     even = _cdiv(slots * held, n_experts)
-    tiles = max(1, _cdiv(int(_HELD_SLACK * even), _ROW_TILE))
+    tiles = max(1, _cdiv(int(slack * even), _ROW_TILE))
     return min(slots, tiles * _ROW_TILE)
 
 
@@ -250,6 +264,28 @@ def load_balancing_loss(probs, group_sizes):
     n_experts = probs.shape[-1]
     share = group_sizes.astype(jnp.float32) / group_sizes.sum()
     return n_experts * jnp.sum(share * probs.mean(axis=0))
+
+
+def sequence_load(index, n_experts: int, sequences: int):
+    """Slots of `index` [B T, k] per sequence and expert, the tokens in `B`
+    = `sequences` whole sequences one after another: [B, E] int32. Its sum
+    over the sequences is `expert_load`."""
+    flat = index.reshape(sequences, -1).astype(jnp.int32)
+    return jax.vmap(lambda ids: _per_group(ids, n_experts))(flat)
+
+
+def sequence_balancing_loss(probs, load):
+    """`mean_b sum_e f_be P_be` (`seq_aux`, arXiv:2405.04434): for sequence
+    b, f_be the share of its slots sent to expert e times E (no gradient
+    flows through a count), P_be the mean of the router's scores `probs`
+    [B, T, E] over its tokens; `load` [B, E] is `sequence_load`'s. 1 when
+    both are uniform. The batch's `load_balancing_loss` takes both means
+    over all the tokens at once, which lets one sequence's skew cancel
+    another's."""
+    n_experts = probs.shape[-1]
+    load = load.astype(jnp.float32)
+    share = load * n_experts / load.sum(axis=-1, keepdims=True)
+    return jnp.mean(jnp.sum(share * probs.mean(axis=1), axis=-1))
 
 
 def router_z_loss(logits):

@@ -33,6 +33,9 @@ MOE_KERNELS = {"moe_gmm", "moe_tgmm"}
 # the gated short convolution's (`conv_in`, `conv_gate`, `conv_out` inside
 # `short_conv`), and the step's update of the routers' selection bias
 LFM2_SCOPES = {"short_conv", "conv_in", "conv_gate", "conv_out", "expert_bias"}
+# latent attention's parts inside `latent_attention` (beside `attn_qkv`,
+# `attention` and `attn_out`), and the shared experts inside `mlp`
+DSV2_SCOPES = {"latent_attention", "kv_down", "kv_up", "moe_shared"}
 VOCAB = 96  # the tiny steps' one dimension of this size: it finds the head
 
 
@@ -94,6 +97,27 @@ def lowered_lfm2_step():
         moe._ROW_TILE = row_tile
 
 
+def lowered_dsv2_step():
+    """A dense latent-attention layer, then two with routed experts of which
+    a share is held, shared experts beside them, the balance loss per
+    sequence."""
+    row_tile = moe._ROW_TILE
+    moe._ROW_TILE = 8
+    try:
+        return lowered_transformer_step(
+            n_layers=3, n_kv_heads=None, layer_types=("latent_attention",) * 3,
+            n_dense_layers=1, d_ff_dense=48, n_experts=4, experts_per_token=2,
+            experts_held=(1, 1), n_shared_experts=2, seq_aux=True,
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, router_aux_loss_coef=0.001, router_z_loss_coef=0.0,
+            rope_scaling=(("beta_fast", 32), ("beta_slow", 1), ("factor", 40),
+                          ("mscale", 0.707), ("mscale_all_dim", 0.707),
+                          ("original_max_position_embeddings", 8),
+                          ("type", "yarn")))
+    finally:
+        moe._ROW_TILE = row_tile
+
+
 def lowered_moe_kernels():
     x = jax.ShapeDtypeStruct((32, 16), jnp.float32)
     w = jax.ShapeDtypeStruct((4, 16, 8), jnp.float32)
@@ -122,13 +146,18 @@ def lowered_project_and_combine():
         hidden, w_down, weights)
 
 
-def lowered_flash_kernels():
+def lowered_flash_kernels(v_dim=64):
     q = jax.ShapeDtypeStruct((1, 128, 2, 64), jnp.float32)
+    v = jax.ShapeDtypeStruct((1, 128, 2, v_dim), jnp.float32)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=True).sum()
 
-    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, v)
+
+
+def lowered_two_width_kernels():
+    return lowered_flash_kernels(v_dim=32)
 
 
 FAMILIES = {
@@ -136,8 +165,12 @@ FAMILIES = {
     "moe_transformer": (lowered_moe_step, TRANSFORMER_SCOPES | MOE_SCOPES),
     "lfm2_moe": (lowered_lfm2_step,
                  TRANSFORMER_SCOPES | MOE_SCOPES | LFM2_SCOPES),
+    "deepseek_v2": (lowered_dsv2_step,
+                    TRANSFORMER_SCOPES | (MOE_SCOPES - {"qk_norm"}) | DSV2_SCOPES),
     "resnet": (lowered_resnet_step, RESNET_SCOPES),
 }
+# the scopes that one family alone has
+OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES}
 
 
 @pytest.fixture(scope="module")
@@ -163,8 +196,9 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
                    for s in stacks[family])
         # the whole of _attention lies under its scope, wrappers included
         assert any(re.search(r"attention/.*transpose", s) for s in stacks[family])
-    if family != "lfm2_moe":
-        assert not LFM2_SCOPES & components(stacks[family])
+    for other, own in OWN_SCOPES.items():
+        if family != other:
+            assert not own & components(stacks[family])
     if family == "transformer":  # the dense step names nothing of the routed
         assert not (MOE_SCOPES | MOE_KERNELS) & components(stacks[family])
     elif family == "lfm2_moe":
@@ -185,6 +219,20 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
         assert any(re.search(r"attn_qkv\)*/qk_norm", s) for s in stacks[family])
         # the bias is moved outside the differentiated function
         assert any(s.endswith("expert_bias/sign") for s in stacks[family])
+    elif family == "deepseek_v2":
+        # the layer's five parts inside `latent_attention`, in the scan's
+        # body forward, made again, and backward
+        for inner in ("attn_qkv", "kv_down", "kv_up", "attention", "attn_out"):
+            for prefix in ("", "checkpoint/rematted_computation/", "checkpoint/"):
+                assert any(s.startswith(f"{prefix}latent_attention/{inner}/")
+                           for s in stacks[family]), (inner, prefix)
+        # the shared experts under `mlp`, beside the share's loop
+        for prefix in ("", "checkpoint/rematted_computation/", "checkpoint/"):
+            assert any(s.startswith(f"{prefix}mlp/moe_shared/dot_general")
+                       for s in stacks[family]), prefix
+        assert any(s.startswith("mlp/while/body/moe_experts")
+                   for s in stacks[family])
+        assert any(s.startswith("mlp/moe_router/") for s in stacks[family])
     elif family == "moe_transformer":
         # the routed feed-forward stays under `mlp`, QK-norm under `attn_qkv`
         for inner in sorted(MOE_SCOPES - {"qk_norm"}):
@@ -231,8 +279,9 @@ def test_the_head_makes_its_logits_once(texts, stacks, family):
 
 
 @pytest.mark.parametrize("lower,names", [
-    (lowered_flash_kernels, KERNELS), (lowered_moe_kernels, MOE_KERNELS)],
-    ids=["flash", "moe"])
+    (lowered_flash_kernels, KERNELS), (lowered_two_width_kernels, KERNELS),
+    (lowered_moe_kernels, MOE_KERNELS)],
+    ids=["flash", "flash_two_widths", "moe"])
 def test_the_kernels_carry_their_names_in_interpret_mode(lower, names):
     found = components(name_stacks(lower()))
     assert names <= found
@@ -269,7 +318,8 @@ def test_resnet_stem_has_its_conv_scope():
 def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
     from chipbench import scopes
 
-    program = TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
+    program = (TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
+               | DSV2_SCOPES)
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     assert set(scopes.KERNELS) == KERNELS
     suffix = ".images.json" if family == "resnet" else ".tokens.json"
@@ -281,8 +331,9 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
         for key in ("scope", "kernel"):
             if key in params:
                 assert params[key] in program, path
-                only_lfm2 = params[key] in LFM2_SCOPES and family != "lfm2_moe"
-                if path.endswith(suffix) and not only_lfm2:
+                elsewhere = any(params[key] in own and family != other
+                                for other, own in OWN_SCOPES.items())
+                if path.endswith(suffix) and not elsewhere:
                     assert params[key] in in_family, path
                     named += 1
     assert named >= 1

@@ -25,7 +25,7 @@ FLASH = fa.flash_attention
 
 LIMIT = int(15.75 * 2**30)  # what a v5e chip offers a program
 CELLS = ("mistral7b.tokens4k", "mistral7b.fsdp4", "olmoe.tokens4k",
-         "lfm2moe.tokens8k")
+         "lfm2moe.tokens8k", "dsv2lite.tokens8k")
 
 
 def cell_shapes(cell_name):

@@ -1,9 +1,9 @@
 """Blocked (flash) attention as a Pallas TPU kernel.
 
-Online-softmax attention tiled for the MXU: the grid walks (batch*heads,
-q-block, k-block) with the k dimension innermost; running max/denominator and
-the output accumulator live in VMEM scratch that persists across the k steps
-and is flushed on the last one.
+Online-softmax attention tiled for the MXU: the forward's grid walks
+(batch*heads, q-block, k-block) with the k dimension innermost; running
+max/denominator and the output accumulator live in VMEM scratch that
+persists across the k steps and is flushed on the last one.
 
 Dispatch: `mha(impl="auto")` picks this kernel when JAX reports a TPU and an
 XLA einsum implementation on any other platform (tests run the kernel in
@@ -16,12 +16,23 @@ the forward rule names o and lse `attn_ctx` (`jax.ad_checkpoint`), so that a
 caller under `jax.checkpoint` whose policy keeps that name does not run the
 forward kernel a second time for them.
 
-The three `pallas_call`s are named `flash_fwd` (with or without the lse
-output), `flash_bwd_dq` and `flash_bwd_dkv`: the names a profiler trace and
-the compiled HLO show, and the ones the benchmark's per-kernel roofline
-metrics read (docs/observability.md, "Device scopes").
+The backward is one kernel, `flash_bwd_dkv_dq`, wherever it fits: it walks
+(batch*heads, k-block, q-block) with q innermost, makes a tile's s, p, dp and
+ds once and takes dv, dk and dq from them, five matmuls a tile. dk and dv
+are summed over the q steps of a k tile in VMEM scratch; dq is summed over
+k tiles, a whole column of the grid apart, so its f32 sum and its output
+block hold all the q tiles of the (batch, head) row. `flash_bwd_kernels`
+decides from the shape alone whether that fits VMEM (every shape the repo
+runs: 8.4 MB of f32 and a 4.2 MB block at T 8192, D 192) and logs the choice
+once; where it does not, `flash_bwd_dq` (k innermost) and `flash_bwd_dkv` run
+as before, each making the tile for itself, seven matmuls between them.
 
-The tile program, the same in all three kernels:
+The four `pallas_call`s are named `flash_fwd` (with or without the lse
+output), `flash_bwd_dkv_dq`, `flash_bwd_dq` and `flash_bwd_dkv`: the names a
+profiler trace and the compiled HLO show, and the ones the benchmark's
+per-kernel metrics read (docs/observability.md, "Device scopes").
+
+The tile program, the same in all four kernels:
 
 - Tiles come from the shape. `flash_tiles(kernel, T, S, D, dtype)` (with
   `v_dim=` where v is narrower or wider than q and k) returns `block_q` and
@@ -32,8 +43,9 @@ The tile program, the same in all three kernels:
   the causal diagonal is wasted. It also returns the estimate of the VMEM the
   tile needs and the `vmem_limit_bytes` handed to Mosaic (the default 16 MiB
   where that is enough), the grid steps a (batch, head) row makes and the
-  share of them that have a body. `flash_attention(block_q=, block_k=)`
-  force a tile, for tests; `None` is the shape's choice.
+  share of them that have a body, and what the row's grid costs by the
+  sweeps' constants. `flash_attention(block_q=, block_k=)` force a tile,
+  for tests; `None` is the shape's choice.
 - Operands reach the MXU in the input's dtype. q, k, v and do go to
   `dot_general` as loaded, p and ds are cast to that dtype for the second
   matmuls, and every dot accumulates in f32. m, l, lse, delta, the
@@ -60,12 +72,15 @@ The tile program, the same in all three kernels:
 from __future__ import annotations
 
 import functools
+import logging
 import math
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+logger = logging.getLogger(__name__)
 
 _BIG_NEG = -1e30
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -94,7 +109,7 @@ _MAX_BLOCK = 1024
 # [bq, bk] f32 tiles a body holds at once (s/p, and dp, ds and a transposed
 # copy in the backward), and [bq, bk] copies in the input's dtype (p; p, ds).
 _LIVE_TILES = {"flash_fwd": (2, 1), "flash_bwd_dq": (4, 1),
-               "flash_bwd_dkv": (4, 2)}
+               "flash_bwd_dkv": (4, 2), "flash_bwd_dkv_dq": (4, 2)}
 
 
 class FlashTiles(NamedTuple):
@@ -105,6 +120,7 @@ class FlashTiles(NamedTuple):
     active_share: float  # share of those steps whose tile has a body
     vmem_bytes: int  # estimate of what the tile needs
     vmem_limit_bytes: int  # what Mosaic is told it may use
+    cost_us: float  # of a (batch, head) row's grid, by `_COST_US`
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -122,10 +138,13 @@ def _active_tiles(T, S, block_q, block_k, causal) -> int:
     )
 
 
-def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None) -> int:
+def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
+                T=None) -> int:
     """Blocks in flight (double-buffered), scratch and the body's live
     [bq, bk] tiles, for q and k `D` wide and v `Dv` wide (`D` where None).
-    A VMEM row is whole tiles of 128 lanes whatever the width is."""
+    A VMEM row is whole tiles of 128 lanes whatever the width is. The one
+    kernel that makes all three gradients also holds a (batch, head) row's
+    whole dq, `T` rows in whole q tiles: its f32 sum and its block."""
     qk = _cdiv(D, _LANES) * _LANES
     vo = qk if Dv is None else _cdiv(Dv, _LANES) * _LANES
     q_qk, q_vo = block_q * qk, block_q * vo  # elements: q, dq; o, do
@@ -140,6 +159,10 @@ def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None) -> int:
     else:
         blocks = (q_qk + q_vo + 2 * k_qk + 2 * k_vo) * itemsize + 2 * row
         scratch = (k_qk + k_vo) * 4
+        if kernel == "flash_bwd_dkv_dq":
+            dq_row = _cdiv(T, block_q) * q_qk
+            blocks += dq_row * itemsize
+            scratch += dq_row * 4
     f32_tiles, dtype_tiles = _LIVE_TILES[kernel]
     live = block_q * block_k * (4 * f32_tiles + itemsize * dtype_tiles)
     return 2 * blocks + scratch + live
@@ -162,14 +185,20 @@ _COST_US = {
     "flash_fwd": (0.20, 2.75, 2.25),
     "flash_bwd_dq": (0.29, 0.0, 5.8),
     "flash_bwd_dkv": (0.29, 0.7, 5.35),
+    # {512, 1024}^2 with 512 x 1024 and 1024 x 512 at BH 128, T 4096, D 128
+    # (within 2 %), and at BH 64, T 8192, 192 / 128 through `_pairs_factor`
+    # (within 1 %): 8.0 where the two kernels it stands for cost 11.15
+    # (PERF.md section 6, PR 35)
+    "flash_bwd_dkv_dq": (0.29, 0.0, 8.0),
 }
 
 
 # The matmuls over a tile's pairs, by the width each walks: (over q and k's
 # width, over v's). Forward s = q k^T | o = p v; dq: s, dq = ds k | dp = do
-# v^T; dk/dv: s, dk = ds^T q | dp, dv = p^T do.
+# v^T; dk/dv: s, dk = ds^T q | dp, dv = p^T do; all three gradients from one
+# tile: s, dq, dk | dp, dv.
 _PAIR_MATMULS = {"flash_fwd": (1, 1), "flash_bwd_dq": (2, 1),
-                 "flash_bwd_dkv": (2, 2)}
+                 "flash_bwd_dkv": (2, 2), "flash_bwd_dkv_dq": (3, 2)}
 
 
 def _pairs_factor(kernel: str, D: int, Dv: int) -> float:
@@ -191,14 +220,14 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
                 causal: bool = True, block_q: Optional[int] = None,
                 block_k: Optional[int] = None,
                 v_dim: Optional[int] = None) -> FlashTiles:
-    """The tile of `kernel` (`flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv`)
-    for q of [*, T, D] and k of [*, S, D] and v of [*, S, v_dim] (`D` where
-    None) in `dtype`. Pure: the shape decides, nothing is asked of a
-    device. Among the tiles that fit VMEM it takes the one whose grid costs
-    least by `_COST_US`: small tiles pay in grid steps, large ones in pairs
-    above the causal diagonal that a diagonal tile computes and masks. A
-    forced `block_q` or `block_k` is taken as given (cut to the sequence)
-    and the other is chosen."""
+    """The tile of `kernel` (`flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv`,
+    `flash_bwd_dkv_dq`) for q of [*, T, D] and k of [*, S, D] and v of
+    [*, S, v_dim] (`D` where None) in `dtype`. Pure: the shape decides,
+    nothing is asked of a device. Among the tiles that fit VMEM it takes
+    the one whose grid costs least by `_COST_US`: small tiles pay in grid
+    steps, large ones in pairs above the causal diagonal that a diagonal
+    tile computes and masks. A forced `block_q` or `block_k` is taken as
+    given (cut to the sequence) and the other is chosen."""
     itemsize = jnp.dtype(dtype).itemsize
     Dv = D if v_dim is None else v_dim
     step_us, rows_us, pairs_us = _COST_US[kernel]
@@ -207,17 +236,41 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     def plan(bq, bk):
         steps = _cdiv(T, bq) * _cdiv(S, bk)
         active = _active_tiles(T, S, bq, bk, causal)
-        vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv)
+        vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv, T)
         cost = steps * step_us + active * (
             rows_us * bq / 1024 + pairs_us * bq * bk / 2 ** 20)
-        return cost, FlashTiles(
-            bq, bk, steps, active / steps, vmem, max(_DEFAULT_VMEM, 2 * vmem))
+        return FlashTiles(bq, bk, steps, active / steps, vmem,
+                          max(_DEFAULT_VMEM, 2 * vmem), cost)
 
     qs = [min(block_q, T)] if block_q else _block_candidates(T)
     ks = [min(block_k, S)] if block_k else _block_candidates(S)
     plans = [plan(bq, bk) for bq in qs for bk in ks]
-    fitting = [p for p in plans if p[1].vmem_limit_bytes <= _MAX_VMEM]
-    return min(fitting or plans[:1], key=lambda p: p[0])[1]
+    fitting = [p for p in plans if p.vmem_limit_bytes <= _MAX_VMEM]
+    return min(fitting or plans[:1], key=lambda p: p.cost_us)
+
+
+def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
+                      block_q: Optional[int] = None,
+                      block_k: Optional[int] = None,
+                      v_dim: Optional[int] = None) -> Tuple[str, ...]:
+    """The kernels of one backward, for the shapes `flash_tiles` takes:
+    `("flash_bwd_dkv_dq",)`, all three gradients from one pass over the
+    score tiles, or `("flash_bwd_dq", "flash_bwd_dkv")`, which make every
+    tile twice and hold one q tile's dq at a time. Pure, as `flash_tiles`
+    is. The one kernel holds a (batch, head) row's whole dq in VMEM, its
+    f32 sum and its block, `T` rows each: it is taken where that fits
+    beside some tile, and the tile it leaves room for does not cost more in
+    grid steps than the second pass saves: in bf16, causal, T up to about
+    21k at q and k 192 wide and 44k at 128 or 64."""
+    def tiles(kernel):
+        return flash_tiles(kernel, T, S, D, dtype, causal=causal,
+                           block_q=block_q, block_k=block_k, v_dim=v_dim)
+
+    one, two = tiles("flash_bwd_dkv_dq"), ("flash_bwd_dq", "flash_bwd_dkv")
+    if (one.vmem_limit_bytes <= _MAX_VMEM
+            and one.cost_us <= sum(tiles(kernel).cost_us for kernel in two)):
+        return ("flash_bwd_dkv_dq",)
+    return two
 
 
 # ----------------------------------------------------------------- kernels
@@ -376,11 +429,14 @@ def _first_q_with_body(ki, block_q, block_k, num_q):
     return jnp.minimum(jax.lax.div(ki * block_k, block_q), num_q - 1)
 
 
-def _compiler_params(tiles: FlashTiles):
+def _compiler_params(tiles: FlashTiles, inner=("parallel", "arbitrary")):
+    """`inner`: the two grid dimensions inside a (batch, head) row. The
+    last carries a kernel's sums; the one before it too where dq is summed
+    over the k tiles it walks."""
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", *inner),
         vmem_limit_bytes=tiles.vmem_limit_bytes,
     )
 
@@ -490,13 +546,27 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     return o, (q, k, v, o, lse)
 
 
+@functools.lru_cache(maxsize=None)
+def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k):
+    """One line for each backward a process traces, as `saved_activations`
+    has one for what it keeps: which kernels, at which tile and VMEM."""
+    for kernel in kernels:
+        t = flash_tiles(kernel, T, S, D, dtype, causal=causal,
+                        block_q=block_q, block_k=block_k, v_dim=Dv)
+        logger.info(
+            "flash backward at T %d, S %d, D %d, Dv %d, %s: %s, tile %d x "
+            "%d, VMEM %d bytes of a limit of %d", T, S, D, Dv, dtype, kernel,
+            t.block_q, t.block_k, t.vmem_bytes, t.vmem_limit_bytes)
+
+
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx, res,
                    do):
-    """Tiled FlashAttention-2 backward: two pallas kernels (dq; dk/dv), each
-    re-deriving its softmax tile from (q, k, lse) — nothing O(T·S) ever
-    touches HBM (the previous recompute path materialized full f32 score
-    matrices through XLA, which both OOMed large batches and made the step
-    bandwidth-bound)."""
+    """Tiled FlashAttention-2 backward, re-deriving each softmax tile from
+    (q, k, lse) — nothing O(T·S) ever touches HBM (the previous recompute
+    path materialized full f32 score matrices through XLA, which both OOMed
+    large batches and made the step bandwidth-bound). One kernel makes the
+    tile once for dq, dk and dv where `flash_bwd_kernels` says a row's dq
+    fits VMEM; two kernels (dq; dk/dv) make it once each where not."""
     q, k, v, o, lse = res
     BH, T, _ = q.shape
     if keep_ctx:
@@ -504,14 +574,18 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx, res,
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
     # Same sublane-aligned [BH, T, 8] layout as lse.
     delta = jnp.broadcast_to(delta[..., None], (BH, T, 8))
-    dq = _flash_bwd_dq(
-        q, k, v, do, lse, delta, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-    )
-    dk, dv = _flash_bwd_dkv(
-        q, k, v, do, lse, delta, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-    )
+    shape = (T, k.shape[1], q.shape[2])
+    kernels = flash_bwd_kernels(*shape, q.dtype, causal=causal,
+                                block_q=block_q, block_k=block_k,
+                                v_dim=v.shape[2])
+    _log_bwd_kernels(kernels, *shape, v.shape[2], jnp.dtype(q.dtype).name,
+                     causal, block_q, block_k)
+    tile = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+                interpret=interpret)
+    if kernels == ("flash_bwd_dkv_dq",):
+        return _flash_bwd_dkv(q, k, v, do, lse, delta, with_dq=True, **tile)
+    dq = _flash_bwd_dq(q, k, v, do, lse, delta, **tile)
+    dk, dv = _flash_bwd_dkv(q, k, v, do, lse, delta, **tile)
     return dq, dk, dv
 
 
@@ -592,13 +666,24 @@ def _attn_bwd_dq_kernel(
 
 def _attn_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref,
-    dk_acc_ref, dv_acc_ref,
-    *, block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
-    causal: bool, seq_q: int, seq_k: int,
+    *outputs_and_sums,
+    block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
+    causal: bool, seq_q: int, seq_k: int, with_dq: bool = False,
 ):
+    """dk and dv of k tile `ki`, summed over the q tiles the grid walks
+    innermost. `with_dq` (`flash_bwd_dkv_dq`): dq too, from the same p and
+    ds. Its f32 sum `dq_acc_ref` holds every q tile of the (batch, head)
+    row, because the k tiles that add to one q tile's rows are a whole
+    column of the grid apart; tile `qi`'s rows are zeroed in the first
+    column, summed over `ki` in ascending order as `_attn_bwd_dq_kernel`
+    sums them, and rounded once into the row's dq block in the last."""
     from jax.experimental import pallas as pl
 
+    if with_dq:
+        (dk_ref, dv_ref, dq_ref,
+         dk_acc_ref, dv_acc_ref, dq_acc_ref) = outputs_and_sums
+    else:
+        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = outputs_and_sums
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
@@ -609,13 +694,23 @@ def _attn_bwd_dkv_kernel(
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
+    if with_dq:
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_acc_ref[rows, :] = jnp.zeros(
+                (block_q, dq_acc_ref.shape[1]), dq_acc_ref.dtype)
+
     def _body(masked):
-        q, _, do, p, ds = _bwd_tile(
+        q, k, do, p, ds = _bwd_tile(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
             masked=masked, scale=scale, **shape,
         )
         dv_acc_ref[...] += _dot(p, do, _TN)  # [bk, Dv]
         dk_acc_ref[...] += _dot(ds, q, _TN)  # [bk, D]
+        if with_dq:
+            dq_acc_ref[rows, :] += _dot(ds, k, _NN)  # [bq, D]
 
     # Only q tiles at/below the diagonal see this k tile.
     _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
@@ -624,6 +719,11 @@ def _attn_bwd_dkv_kernel(
     def _flush():
         dk_ref[0] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when(ki == num_k - 1)
+        def _flush_dq():
+            dq_ref[0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
@@ -664,19 +764,23 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
 
 
 def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
-                   block_q, block_k, interpret):
+                   block_q, block_k, interpret, with_dq: bool = False):
+    """(dk, dv), or with `with_dq` the kernel `flash_bwd_dkv_dq` and
+    (dq, dk, dv): one more output, whose block is a (batch, head) row's
+    whole dq, fetched nowhere and written back when the row is done, and
+    one more f32 sum of that size."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
     S, Dv = k.shape[1], v.shape[2]
-    tiles, num_q, num_k = _grid(
-        "flash_bwd_dkv", q, k, v, causal, block_q, block_k)
+    name = "flash_bwd_dkv_dq" if with_dq else "flash_bwd_dkv"
+    tiles, num_q, num_k = _grid(name, q, k, v, causal, block_q, block_k)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dkv_kernel,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
-        scale=scale, causal=causal, seq_q=T, seq_k=S,
+        scale=scale, causal=causal, seq_q=T, seq_k=S, with_dq=with_dq,
     )
 
     def q_block(bh, ki, qi):
@@ -688,7 +792,27 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     def k_block(bh, ki, qi):
         return (bh, ki, 0)
 
-    return pl.pallas_call(
+    out_specs = [
+        pl.BlockSpec((1, block_k, D), k_block),
+        pl.BlockSpec((1, block_k, Dv), k_block),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((BH, S, D), k.dtype),
+        jax.ShapeDtypeStruct((BH, S, Dv), v.dtype),
+    ]
+    scratch_shapes = [
+        pltpu.VMEM((block_k, D), jnp.float32),
+        pltpu.VMEM((block_k, Dv), jnp.float32),
+    ]
+    inner = ("parallel", "arbitrary")
+    if with_dq:
+        rows = num_q * block_q  # T in whole q tiles: every step's are there
+        out_specs.append(
+            pl.BlockSpec((1, rows, D), lambda bh, ki, qi: (bh, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((BH, rows, D), q.dtype))
+        scratch_shapes.append(pltpu.VMEM((rows, D), jnp.float32))
+        inner = ("arbitrary", "arbitrary")  # dq is summed over k tiles too
+    out = pl.pallas_call(
         kernel,
         grid=(BH, num_k, num_q),
         in_specs=[
@@ -699,22 +823,17 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
             pl.BlockSpec((1, block_q, 8), q_block),
             pl.BlockSpec((1, block_q, 8), q_block),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), k_block),
-            pl.BlockSpec((1, block_k, Dv), k_block),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, Dv), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, Dv), jnp.float32),
-        ],
-        compiler_params=_compiler_params(tiles),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
+        compiler_params=_compiler_params(tiles, inner),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=name,
     )(q, k, v, do, lse, delta)
+    if not with_dq:
+        return out
+    dk, dv, dq = out
+    return (dq if rows == T else dq[:, :T]), dk, dv
 
 
 def _xla_attention_bhtd(q, k, v, *, causal, scale):

@@ -161,8 +161,10 @@ def test_tiles_take_both_widths():
 
 
 # sha256 of `str(jax.make_jaxpr(...))` of the three kernels under
-# `value_and_grad`, taken on the parent commit (1919b5e, PR 33) with this
-# function: at equal widths the kernels' programs are the parent's
+# `value_and_grad`, taken on PR 33's commit (1919b5e) with this function: at
+# equal widths the forward and the two-kernel backward are that commit's
+# programs. Since PR 35 the backward these shapes take is the one kernel,
+# so the test steers `flash_bwd_kernels` to the two it stands for.
 PARENT_JAXPRS = {
     (320, 2, 128): "1f852492114a85129969ffd5b520d5848bee60a9f99937f0779dddbcf9ff20b0",
     (4096, 1, 128): "6c7e2da1c29c98dcd379f83465f45b14b68d98d364a117e9b2ab797325d8436a",
@@ -171,8 +173,14 @@ PARENT_JAXPRS = {
 
 
 @pytest.mark.parametrize("shape", sorted(PARENT_JAXPRS))
-def test_at_equal_widths_the_kernels_jaxprs_are_the_parent_s(shape):
+def test_at_equal_widths_the_kernels_jaxprs_are_the_parent_s(shape, monkeypatch):
+    import importlib
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
     T, H, D = shape
+    assert fa.flash_bwd_kernels(T, T, D, jnp.bfloat16) == ("flash_bwd_dkv_dq",)
+    monkeypatch.setattr(fa, "flash_bwd_kernels",
+                        lambda *a, **kw: ("flash_bwd_dq", "flash_bwd_dkv"))
     q = jax.ShapeDtypeStruct((1, T, H, D), jnp.bfloat16)
 
     def loss(q, k, v):

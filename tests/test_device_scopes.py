@@ -1,10 +1,11 @@
 """The names the program gives its device work: every `jax.named_scope` of
-the two model families and the three kernel names stand in the lowered
+the two model families and the flash kernels' names stand in the lowered
 step's name stacks, and every name a benchmark metric matches is one of
 them, so that a rename in the program fails here and not in a metric. CPU
 only: the steps are tiny and the kernels run in interpret mode."""
 
 import glob
+import importlib
 import json
 import os
 import re
@@ -25,7 +26,10 @@ TRANSFORMER_SCOPES = {"embed", "attn_qkv", "attention", "attn_out", "mlp",
                       "final_norm", "lm_head_ce", "optimizer"}
 RESNET_SCOPES = {"stem", "stage1", "stage2", "stage3", "stage4", "head",
                  "conv", "bn"}
-KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+# the backward is `flash_bwd_dkv_dq` where a row's dq fits VMEM, and the two
+# kernels it stands for where not (`ops/flash_attention.py`
+# `flash_bwd_kernels`)
+KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_dkv_dq"}
 # the routed feed-forward's, inside `mlp`, and QK-norm's, inside `attn_qkv`
 MOE_SCOPES = {"moe_router", "moe_dispatch", "moe_experts", "moe_combine",
               "qk_norm"}
@@ -147,6 +151,7 @@ def lowered_project_and_combine():
 
 
 def lowered_flash_kernels(v_dim=64):
+    """The forward and the backward these shapes take: the one kernel."""
     q = jax.ShapeDtypeStruct((1, 128, 2, 64), jnp.float32)
     v = jax.ShapeDtypeStruct((1, 128, 2, v_dim), jnp.float32)
 
@@ -158,6 +163,18 @@ def lowered_flash_kernels(v_dim=64):
 
 def lowered_two_width_kernels():
     return lowered_flash_kernels(v_dim=32)
+
+
+def lowered_two_backward_kernels():
+    """The backward of a row whose dq does not fit VMEM: the plan's answer
+    steered here, since no shape a CPU lowers in a test is that long."""
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    plan = fa.flash_bwd_kernels
+    fa.flash_bwd_kernels = lambda *a, **kw: ("flash_bwd_dq", "flash_bwd_dkv")
+    try:
+        return lowered_flash_kernels()
+    finally:
+        fa.flash_bwd_kernels = plan
 
 
 FAMILIES = {
@@ -278,13 +295,19 @@ def test_the_head_makes_its_logits_once(texts, stacks, family):
     assert calls and all("lm_head_ce" in locs[ref] for ref in calls)
 
 
+ONE_BACKWARD = {"flash_fwd", "flash_bwd_dkv_dq"}
+
+
 @pytest.mark.parametrize("lower,names", [
-    (lowered_flash_kernels, KERNELS), (lowered_two_width_kernels, KERNELS),
+    (lowered_flash_kernels, ONE_BACKWARD),
+    (lowered_two_width_kernels, ONE_BACKWARD),
+    (lowered_two_backward_kernels, KERNELS - {"flash_bwd_dkv_dq"}),
     (lowered_moe_kernels, MOE_KERNELS)],
-    ids=["flash", "flash_two_widths", "moe"])
+    ids=["flash", "flash_two_widths", "flash_two_backward_kernels", "moe"])
 def test_the_kernels_carry_their_names_in_interpret_mode(lower, names):
     found = components(name_stacks(lower()))
     assert names <= found
+    assert not (KERNELS - names) & found
 
 
 def test_project_and_combine_names_its_backward():
@@ -321,7 +344,9 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
     program = (TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
                | DSV2_SCOPES)
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
-    assert set(scopes.KERNELS) == KERNELS
+    # the benchmark's list is PR 25's three until a `benchmark` issue adds
+    # the fourth (PERF.md section 7); its time share matches by prefix
+    assert set(scopes.KERNELS) == KERNELS - {"flash_bwd_dkv_dq"}
     suffix = ".images.json" if family == "resnet" else ".tokens.json"
     in_family = components(stacks[family]) | KERNELS
     named = 0

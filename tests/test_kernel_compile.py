@@ -70,10 +70,18 @@ def _flash_case(name, kernel=KERNEL):
             q, k, v, with_lse=True, **kernel)
     if name == "bwd_dq":
         return lambda *a: fa._flash_bwd_dq(*a, **kernel)
-    return lambda *a: fa._flash_bwd_dkv(*a, **kernel)
+    # the whole backward in one kernel is the dk/dv kernel with dq too
+    return lambda *a: fa._flash_bwd_dkv(
+        *a, with_dq=name == "bwd_dkv_dq", **kernel)
 
 
-@pytest.mark.parametrize("name", ["fwd", "fwd_lse", "bwd_dq", "bwd_dkv"])
+CASES = ["fwd", "fwd_lse", "bwd_dq", "bwd_dkv", "bwd_dkv_dq"]
+KERNEL_OF = {"fwd": "flash_fwd", "fwd_lse": "flash_fwd",
+             "bwd_dq": "flash_bwd_dq", "bwd_dkv": "flash_bwd_dkv",
+             "bwd_dkv_dq": "flash_bwd_dkv_dq"}
+
+
+@pytest.mark.parametrize("name", CASES)
 def test_flash_kernel_compiles_for_v5e(v5e, name):
     qkv, row = _shapes(v5e[0])
     compiled = jax.jit(_flash_case(name)).lower(
@@ -82,7 +90,7 @@ def test_flash_kernel_compiles_for_v5e(v5e, name):
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
-@pytest.mark.parametrize("name", ["fwd", "fwd_lse", "bwd_dq", "bwd_dkv"])
+@pytest.mark.parametrize("name", CASES)
 @pytest.mark.parametrize("bh,t,d", [
     (128, 4096, 128),  # mistral7b.tokens4k (mistral7b.fsdp4: 64 a chip)
     (BH, T, D),        # chip_smoke.py
@@ -90,9 +98,7 @@ def test_flash_kernel_compiles_for_v5e(v5e, name):
 def test_flash_kernel_compiles_at_the_shapes_own_tiles(v5e, name, bh, t, d):
     """`block_q=None`: the tile `flash_tiles` picks for the shape, with the
     VMEM limit it derives. A tile that does not fit fails here, on a CPU."""
-    kernel_name = {"fwd": "flash_fwd", "fwd_lse": "flash_fwd",
-                   "bwd_dq": "flash_bwd_dq", "bwd_dkv": "flash_bwd_dkv"}[name]
-    tiles = fa.flash_tiles(kernel_name, t, t, d, jnp.bfloat16)
+    tiles = fa.flash_tiles(KERNEL_OF[name], t, t, d, jnp.bfloat16)
     assert tiles.block_q > 128 and tiles.block_k > 128  # not the old tile
     qkv, row = _shapes(v5e[0], bh, t, d)
     chosen = dict(KERNEL, scale=d ** -0.5, block_q=None, block_k=None)
@@ -102,7 +108,7 @@ def test_flash_kernel_compiles_at_the_shapes_own_tiles(v5e, name, bh, t, d):
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
-@pytest.mark.parametrize("name", ["fwd_lse", "bwd_dq", "bwd_dkv"])
+@pytest.mark.parametrize("name", CASES[1:])
 def test_flash_kernel_compiles_under_highest_matmul_precision(v5e, name):
     """bf16 operands go to the MXU as they are, and Mosaic refuses
     "highest" for them ("Bad lhs type"): the kernels pin one pass for
@@ -113,6 +119,42 @@ def test_flash_kernel_compiles_under_highest_matmul_precision(v5e, name):
             qkv, qkv, qkv, qkv, row, row
         ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("bh,t,d,dv", [
+    (64, 8192, 192, 128),   # dsv2lite.tokens8k: 16 heads of latent attention
+    (128, 8192, 64, 64),    # lfm2moe.tokens8k: 32 heads of 64
+    (128, 4096, 128, 128),  # mistral7b.tokens4k
+    (64, 4096, 128, 128),   # mistral7b.fsdp4 a chip, olmoe.tokens4k
+])
+def test_the_one_backward_kernel_compiles_at_the_cells_shapes(
+        v5e, bh, t, d, dv):
+    """Every token cell's backward is `flash_bwd_dkv_dq` at 1024 x 1024:
+    it compiles with the row's dq resident (8.4 MB of f32 at T 8192, D 192)
+    inside the limit `flash_tiles` derives, as one custom call by its name,
+    and it writes the three gradients in the input's dtype."""
+    import re
+
+    assert fa.flash_bwd_kernels(t, t, d, jnp.bfloat16, v_dim=dv) == (
+        "flash_bwd_dkv_dq",)
+    tiles = fa.flash_tiles("flash_bwd_dkv_dq", t, t, d, jnp.bfloat16, v_dim=dv)
+    assert (tiles.block_q, tiles.block_k) == (1024, 1024)
+    assert tiles.vmem_limit_bytes <= 96 << 20
+    one = SingleDeviceSharding(v5e[0])
+    qk = jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16, sharding=one)
+    vo = jax.ShapeDtypeStruct((bh, t, dv), jnp.bfloat16, sharding=one)
+    _, row = _shapes(v5e[0], bh, t, d)
+    chosen = dict(KERNEL, scale=d ** -0.5, block_q=None, block_k=None)
+    text = jax.jit(_flash_case("bwd_dkv_dq", chosen)).lower(
+        qk, qk, vo, vo, row, row).compile().as_text()
+    calls = re.findall(
+        r'%([\w.-]+) = \((.*?)\) custom-call\([^\n]*"tpu_custom_call"', text)
+    assert len(calls) == 1
+    name, outputs = calls[0]
+    assert re.fullmatch(r"flash_bwd_dkv_dq(\.\d+)?", name)
+    # dk, dv, dq, as the kernel lists them
+    assert re.findall(r"bf16\[[\d,]+\]", outputs) == [
+        f"bf16[{bh},{t},{d}]", f"bf16[{bh},{t},{dv}]", f"bf16[{bh},{t},{d}]"]
 
 
 def test_lm_head_cross_entropy_compiles_for_v5e(v5e):
@@ -160,16 +202,14 @@ def test_train_step_compiles_for_four_v5e(v5e):
     tokens = jax.ShapeDtypeStruct((32, 1024), jnp.int32,
                                   sharding=shardings["tokens"])
     compiled = step.lower(state, {"tokens": tokens, "targets": tokens}).compile()
-    # forward-with-lse, dq and dkv; plus the remat's forward again
+    # forward-with-lse and the backward's one kernel; plus the remat's
+    # forward again
     assert compiled.as_text().count("tpu_custom_call") >= 3
     out_state = compiled.output_shardings[0]
     assert jax.tree.leaves(out_state) == jax.tree.leaves(shardings["state"])
 
 
-@pytest.mark.parametrize("case,kernel", [
-    ("fwd", "flash_fwd"), ("fwd_lse", "flash_fwd"),
-    ("bwd_dq", "flash_bwd_dq"), ("bwd_dkv", "flash_bwd_dkv"),
-])
+@pytest.mark.parametrize("case,kernel", sorted(KERNEL_OF.items()))
 def test_flash_kernel_is_named_in_the_text_compiled_for_v5e(v5e, case, kernel):
     """The chip's compiler names a Mosaic custom call after the
     `pallas_call`'s `name=`: that instruction name is what a profiler trace
@@ -280,7 +320,8 @@ def test_token_step_with_what_it_keeps_compiles_and_fits(
         v5e, monkeypatch, cell_name):
     """The reader patched to a v5e's limit (a described device reports
     none): the step the chip would run compiles, stays a GB under the limit
-    by the compiler's own count, and runs the flash forward once a layer."""
+    by the compiler's own count, and runs the flash forward once a layer
+    and the whole flash backward as one kernel."""
     from ray_tpu.models import transformer as tr
 
     monkeypatch.setattr(tr, "_memory_limit", lambda mesh: HBM_LIMIT)
@@ -293,7 +334,8 @@ def test_token_step_with_what_it_keeps_compiles_and_fits(
     text = compiled.as_text()
     (flash, _), (gmm, _) = TOKEN_CELLS[cell_name]
     assert _calls(text, "flash_fwd") == flash
-    assert _calls(text, "flash_bwd_dq") == _calls(text, "flash_bwd_dkv") == 1
+    assert _calls(text, "flash_bwd_dkv_dq") == 1
+    assert _calls(text, "flash_bwd_dq") == _calls(text, "flash_bwd_dkv") == 0
     assert _calls(text, "moe_gmm") == gmm
     if cell_name == "olmoe.tokens4k":
         assert {"moe_slots", "moe_gate", "moe_up"} <= set(chosen)

@@ -79,15 +79,17 @@ TILE_CASES = [
 ]
 
 
-def _tile_program_case(T, S, block_q, block_k, causal, dtype, heads=(2, 2)):
+def _tile_program_case(T, S, block_q, block_k, causal, dtype, heads=(2, 2),
+                       widths=(32, 32)):
     """out, dq, dk, dv of the kernel (interpret mode) and of mha(impl="xla")
-    under one random cotangent."""
+    under one random cotangent; q and k `widths[0]` wide, v `widths[1]`."""
     H, Hk = heads
+    D, Dv = widths
     ks = jax.random.split(jax.random.PRNGKey(T + S), 4)
-    q = jax.random.normal(ks[0], (1, T, H, 32), jnp.float32).astype(dtype)
-    k = jax.random.normal(ks[1], (1, S, Hk, 32), jnp.float32).astype(dtype)
-    v = jax.random.normal(ks[2], (1, S, Hk, 32), jnp.float32).astype(dtype)
-    w = jax.random.normal(ks[3], (1, T, H, 32), jnp.float32)
+    q = jax.random.normal(ks[0], (1, T, H, D), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (1, S, Hk, D), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (1, S, Hk, Dv), jnp.float32).astype(dtype)
+    w = jax.random.normal(ks[3], (1, T, H, Dv), jnp.float32)
 
     def run(attn):
         def scalar(q, k, v):
@@ -147,6 +149,13 @@ def test_flash_tile_program_gqa(dtype):
         assert _rel_err(a, b) <= tol
 
 
+# The tile function: pure arithmetic on shapes, no device. The last kernel
+# is the whole backward in one, which `flash_bwd_kernels` takes where a
+# (batch, head) row's dq fits VMEM, and the two before it where not.
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_dkv_dq")
+ONE, TWO = KERNELS[3:], KERNELS[1:3]
+
+
 def test_flash_default_tiles_are_the_shapes_choice():
     """`block_q=None` is `flash_tiles`' answer: forcing that answer changes
     no bit of the forward or of the gradients."""
@@ -159,19 +168,14 @@ def test_flash_default_tiles_are_the_shapes_choice():
             q, k, v, causal=True, interpret=True, **blocks
         ).sum(), argnums=(0, 1, 2))(q, k, v)
 
-    tiles = {flash_tiles(kern, T, T, D, jnp.float32)[:2]
-             for kern in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    tiles = {flash_tiles(kern, T, T, D, jnp.float32)[:2] for kern in KERNELS}
     assert tiles == {(128, 128)}  # one answer here, so it can be forced
     for a, b in zip(jax.tree.leaves(grads()),
                     jax.tree.leaves(grads(block_q=128, block_k=128))):
         np.testing.assert_array_equal(a, b)
 
 
-# The tile function: pure arithmetic on shapes, no device.
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-
-
-@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("kernel", KERNELS[:3])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_flash_tiles_are_legal_for_any_shape(kernel, dtype):
     for T, S, D in [(4096, 4096, 128), (1024, 1024, 64), (96, 96, 64),
@@ -194,10 +198,12 @@ def test_flash_tiles_are_legal_for_any_shape(kernel, dtype):
     ("flash_fwd", 4096, 128, (1024, 1024), 16, 10 / 16),
     ("flash_bwd_dq", 4096, 128, (1024, 1024), 16, 10 / 16),
     ("flash_bwd_dkv", 4096, 128, (1024, 1024), 16, 10 / 16),
+    ("flash_bwd_dkv_dq", 4096, 128, (1024, 1024), 16, 10 / 16),
     # chip_smoke's GPT-2-small: T 1024, D 64
     ("flash_fwd", 1024, 64, (1024, 1024), 1, 1.0),
     ("flash_bwd_dq", 1024, 64, (512, 512), 4, 3 / 4),
     ("flash_bwd_dkv", 1024, 64, (512, 512), 4, 3 / 4),
+    ("flash_bwd_dkv_dq", 1024, 64, (512, 512), 4, 3 / 4),
 ])
 def test_flash_tiles_of_the_measured_shapes(kernel, T, D, tile, steps, active):
     t = flash_tiles(kernel, T, T, D, jnp.bfloat16)
@@ -250,6 +256,148 @@ def test_flash_causal_tile_bookkeeping_matches_the_mask(T, S, block_q, block_k):
         first = int(fa._first_q_with_body(ki, block_q, block_k, num_q))
         rows = np.flatnonzero(body[:, ki])
         assert first == (rows.min() if rows.size else num_q - 1)
+
+
+# The whole backward in one kernel (PR 35): dq, dk and dv from one pass over
+# the score tiles. Against the two kernels it stands for, tile for tile:
+# the same p and ds, the same dots, dq summed over k tiles in the same
+# order, so no bit differs.
+ONE_KERNEL_CASES = [
+    # T, S, block_q, block_k, causal, (D, Dv), dtype
+    *[(*case, (32, 32), dtype) for case, dtype in zip(
+        TILE_CASES, [jnp.float32, jnp.bfloat16] * 4)],
+    (200, 200, 64, 128, True, (192, 128), jnp.bfloat16),   # latent attention's
+    (256, 256, 128, 64, True, (192, 128), jnp.float32),    # two widths
+    (320, 320, 128, 128, True, (64, 64), jnp.bfloat16),    # heads of 64
+    (72, 72, None, None, True, (24, 16), jnp.float32),     # one tile, T < 128
+]
+
+
+@pytest.mark.parametrize("T,S,block_q,block_k,causal,widths,dtype",
+                         ONE_KERNEL_CASES)
+def test_flash_one_backward_kernel_is_the_two_bit_for_bit(
+        T, S, block_q, block_k, causal, widths, dtype):
+    D, Dv = widths
+    ks = jax.random.split(jax.random.PRNGKey(T + S + D), 4)
+    q, k, v, do = (
+        jax.random.normal(kk, (2, n, w), jnp.float32).astype(dtype)
+        for kk, n, w in zip(ks, (T, S, S, T), (D, D, Dv, Dv)))
+    tile = dict(causal=causal, scale=D ** -0.5, block_q=block_q,
+                block_k=block_k, interpret=True)
+    o, lse = fa._flash_fwd(q, k, v, with_lse=True, **tile)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    delta = jnp.broadcast_to(delta[..., None], (2, T, 8))
+    args = (q, k, v, do, lse, delta)
+    two = (fa._flash_bwd_dq(*args, **tile), *fa._flash_bwd_dkv(*args, **tile))
+    one = fa._flash_bwd_dkv(*args, with_dq=True, **tile)
+    for got, want, x in zip(one, two, (q, k, v)):
+        assert got.shape == x.shape and got.dtype == x.dtype
+        np.testing.assert_array_equal(got, want)
+
+
+def _pallas_calls(jaxpr):
+    import re
+
+    return set(re.findall(r"name=(flash_\w+)", str(jaxpr)))
+
+
+@pytest.mark.parametrize("T,S,block_q,block_k,causal,widths,heads,dtype", [
+    (200, 200, 64, 128, True, (192, 128), (2, 2), jnp.float32),
+    (256, 256, 128, 64, True, (192, 128), (2, 2), jnp.bfloat16),
+    (200, 136, 128, 64, False, (192, 128), (2, 2), jnp.float32),  # T != S
+    (320, 320, 64, 128, True, (64, 64), (4, 1), jnp.bfloat16),    # GQA 4:1
+    (320, 320, 128, 64, True, (64, 64), (4, 1), jnp.float32),
+])
+def test_flash_one_backward_kernel_matches_xla(
+        T, S, block_q, block_k, causal, widths, heads, dtype):
+    """Through `flash_attention`, which takes the one kernel here: two
+    widths, heads of 64 under grouped queries, `block_q != block_k` both
+    ways, at the tolerances the two kernels were held to."""
+    got, want, (q, k, v) = _tile_program_case(
+        T, S, block_q, block_k, causal, dtype, heads=heads, widths=widths)
+    for a, b, x in zip(got[1:], want[1:], (q, k, v)):
+        assert a.shape == x.shape and a.dtype == x.dtype
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got[0], want[0], atol=3e-5)
+        for a, b in zip(got[1:], want[1:]):
+            np.testing.assert_allclose(a, b, atol=2e-4)
+    else:
+        for a, b in zip(got, want):
+            assert _rel_err(a, b) <= chip_smoke.KERNEL_TOLERANCE
+    grad = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=True).sum(), argnums=(0, 1, 2))
+    assert _pallas_calls(jax.make_jaxpr(grad)(q, k, v)) == {
+        "flash_fwd", "flash_bwd_dkv_dq"}
+
+
+def test_flash_backward_takes_the_two_kernels_where_it_is_told_to(monkeypatch):
+    """The same gradients whichever way `flash_bwd_kernels` answers."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (jax.random.normal(kk, (1, 200, 2, 32), jnp.float32) for kk in ks)
+    grad = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=64, block_k=64, interpret=True).sum(),
+        argnums=(0, 1, 2))
+    one = grad(q, k, v)
+    monkeypatch.setattr(fa, "flash_bwd_kernels", lambda *a, **kw: TWO)
+    assert _pallas_calls(jax.make_jaxpr(grad)(q, k, v)) == {"flash_fwd", *TWO}
+    for a, b in zip(one, grad(q, k, v)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("T,D,Dv,dtype,kernels,tile", [
+    # the token cells: every one takes the one kernel, at its parts' tile
+    (8192, 192, 128, jnp.bfloat16, ONE, (1024, 1024)),  # dsv2lite.tokens8k
+    (8192, 64, 64, jnp.bfloat16, ONE, (1024, 1024)),    # lfm2moe.tokens8k
+    (4096, 128, 128, jnp.bfloat16, ONE, (1024, 1024)),  # mistral7b.*, olmoe.*
+    (1024, 64, 64, jnp.bfloat16, ONE, (512, 512)),      # chip_smoke.py
+    # longer rows leave the tile less room: measured at BH 8, 34.4 ms for
+    # the two kernels' 47.3 (PERF.md section 6, PR 35)
+    (16384, 192, 128, jnp.bfloat16, ONE, (1024, 512)),
+    (32768, 128, 128, jnp.bfloat16, ONE, (768, 768)),
+    # a row's dq (its f32 sum, and its block twice) is 8 T lanes(D) bytes
+    # in bf16: 67 MB at T 32768, D 192, where the limit of 96 MiB allows
+    # an estimate of 48; no tile fits beside it
+    (32768, 192, 128, jnp.bfloat16, TWO, None),
+    (65536, 128, 128, jnp.bfloat16, TWO, None),
+    (32768, 128, 128, jnp.float32, TWO, None),
+    # it fits, beside tiles so small that their grid steps cost more than
+    # the second pass at 1024 x 1024 does
+    (46080, 128, 128, jnp.bfloat16, TWO, (256, 256)),
+])
+def test_flash_backward_kernels_of_a_shape(T, D, Dv, dtype, kernels, tile):
+    assert fa.flash_bwd_kernels(T, T, D, dtype, v_dim=Dv) == kernels
+    one = flash_tiles("flash_bwd_dkv_dq", T, T, D, dtype, v_dim=Dv)
+    fits = one.vmem_limit_bytes <= fa._MAX_VMEM
+    assert fits == (tile is not None)
+    if fits:
+        assert (one.block_q, one.block_k) == tile
+    two = sum(flash_tiles(kernel, T, T, D, dtype, v_dim=Dv).cost_us
+              for kernel in TWO)
+    assert (kernels == ONE) == (fits and one.cost_us <= two)
+
+
+def test_flash_backward_plan_never_passes_the_vmem_limit():
+    """Whatever the shape, every kernel the plan names has a tile that fits,
+    and the forced tiles of the tests decide nothing about the answer."""
+    for T, S, D, Dv in [(4096, 4096, 128, 128), (8192, 8192, 192, 128),
+                        (96, 96, 64, 64), (1000, 520, 64, 32),
+                        (128, 4096, 128, 128), (24576, 24576, 256, 256),
+                        (65536, 65536, 64, 64), (131072, 131072, 192, 128),
+                        (64, 300, 32, 32)]:
+        for dtype in (jnp.bfloat16, jnp.float32):
+            for causal in (True, False):
+                kernels = fa.flash_bwd_kernels(T, S, D, dtype, causal=causal,
+                                               v_dim=Dv)
+                assert kernels in (ONE, TWO)
+                for kernel in kernels:
+                    t = flash_tiles(kernel, T, S, D, dtype, causal=causal,
+                                    v_dim=Dv)
+                    assert t.vmem_bytes < t.vmem_limit_bytes <= fa._MAX_VMEM
+    assert fa.flash_bwd_kernels(200, 136, 32, jnp.float32, block_q=64,
+                                block_k=128) == ONE
+    assert fa._pairs_factor("flash_bwd_dkv_dq", 192, 128) == 1.6  # 8 / 5
+    assert fa._pairs_factor("flash_bwd_dkv_dq", 64, 64) == 1.0
 
 
 def test_lm_head_ce_matches_dense():

@@ -21,6 +21,7 @@ from typing import Dict, Optional, Tuple
 from ray_tpu._private.common import config
 from ray_tpu._private.gcs import GcsServer
 from ray_tpu._private.raylet import Raylet
+from ray_tpu.util import tracing
 
 
 class Node:
@@ -99,7 +100,8 @@ class Node:
                     self.gcs_persist_path() if config.gcs_persistence else None
                 ),
             )
-            self.gcs_addr = await self.gcs_server.start()
+            with tracing.span("init.gcs"):
+                self.gcs_addr = await self.gcs_server.start()
             if self.ha_enabled():
                 await self._arm_standby()
         assert self.gcs_addr is not None
@@ -112,7 +114,8 @@ class Node:
             worker_env=self.worker_env,
             gcs_leader_file=self.gcs_leader_file(),
         )
-        self.raylet_addr = await self.raylet.start()
+        with tracing.span("init.raylet"):
+            self.raylet_addr = await self.raylet.start()
 
     async def stop(self) -> None:
         if self.raylet is not None:

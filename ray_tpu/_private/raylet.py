@@ -1111,7 +1111,9 @@ class Raylet:
         z = self._zygote
         if z is None or z.broken:
             z = self._zygote = _Zygote(self)
-            await z.start(env)
+            # the pool starts with the first worker asked for, not in init()
+            with tracing.span("init.worker_pool"):
+                await z.start(env)
         overrides = {
             k: v
             for k, v in env.items()

@@ -23,6 +23,7 @@ from ray_tpu._private.common import RayTpuError, config
 from ray_tpu._private.core_worker import CoreWorker, ObjectRef
 from ray_tpu._private.ids import JobID, WorkerID
 from ray_tpu._private.node import Node
+from ray_tpu.util import tracing
 
 
 class Worker:
@@ -135,7 +136,7 @@ def init(
             raise RayTpuError("address='auto' but no running cluster found")
     elif address is None and _os.environ.get("RAY_TPU_ADDRESS"):
         address = _os.environ["RAY_TPU_ADDRESS"]
-    with _init_lock:
+    with tracing.span("init"), _init_lock:  # cluster_init_s reads the span
         w = global_worker
         if w.connected:
             if ignore_reinit_error:

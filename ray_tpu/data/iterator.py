@@ -7,7 +7,6 @@ StreamSplitDataIterator — an actor serves blocks to N consumers).
 from __future__ import annotations
 
 import collections
-import time
 from typing import Any, Dict, Iterator, List, Optional
 
 import pyarrow as pa
@@ -16,6 +15,7 @@ import ray_tpu
 from ray_tpu._private import telemetry
 from ray_tpu._private.common import config
 from ray_tpu.data import block as B
+from ray_tpu.util import tracing
 
 # docs/observability.md: component "data".
 _BATCH_ASSEMBLY = telemetry.histogram(
@@ -67,29 +67,31 @@ def batches_from_blocks(
         buf.append(blk)
         buffered += blk.num_rows
         while buffered >= batch_size:
-            t0 = time.perf_counter()
-            need = batch_size
-            parts: List[pa.Table] = []
-            while need:
-                head = buf[0]
-                take = min(head.num_rows - off, need)
-                parts.append(head.slice(off, take))
-                off += take
-                need -= take
-                if off == head.num_rows:
-                    buf.popleft()
-                    off = 0
-            buffered -= batch_size
-            batch = parts[0] if len(parts) == 1 else B.concat_blocks(parts)
-            out = B.block_to_batch(batch, batch_format)
-            hist.observe(time.perf_counter() - t0)
+            assembled = tracing.span("data.batch_assemble")
+            with assembled:
+                need = batch_size
+                parts: List[pa.Table] = []
+                while need:
+                    head = buf[0]
+                    take = min(head.num_rows - off, need)
+                    parts.append(head.slice(off, take))
+                    off += take
+                    need -= take
+                    if off == head.num_rows:
+                        buf.popleft()
+                        off = 0
+                buffered -= batch_size
+                batch = parts[0] if len(parts) == 1 else B.concat_blocks(parts)
+                out = B.block_to_batch(batch, batch_format)
+            hist.observe(assembled.seconds)
             yield out
     if buffered and not drop_last:
-        t0 = time.perf_counter()
-        parts = [buf[0].slice(off)] + list(buf)[1:]
-        batch = parts[0] if len(parts) == 1 else B.concat_blocks(parts)
-        out = B.block_to_batch(batch, batch_format)
-        hist.observe(time.perf_counter() - t0)
+        assembled = tracing.span("data.batch_assemble")
+        with assembled:
+            parts = [buf[0].slice(off)] + list(buf)[1:]
+            batch = parts[0] if len(parts) == 1 else B.concat_blocks(parts)
+            out = B.block_to_batch(batch, batch_format)
+        hist.observe(assembled.seconds)
         yield out
 
 
@@ -104,9 +106,15 @@ def iter_blocks_pipelined(
     if lookahead is None:
         lookahead = config.data_fetch_lookahead
     bytes_cell = _BYTES_FETCHED.cell()
+    ctx = tracing.current_context()  # a pool thread inherits no context
 
     def _fetch(ref):
-        blk = ray_tpu.get(ref)
+        token = tracing.set_context(ctx)
+        try:
+            with tracing.span("data.block_fetch"):
+                blk = ray_tpu.get(ref)
+        finally:
+            tracing.reset_context(token)
         bytes_cell.inc(blk.nbytes)
         return blk
 
@@ -147,16 +155,26 @@ def prefetch_iterator(it: Iterator[Any], n: int) -> Iterator[Any]:
     Overlaps batch assembly (block fetch + slice + format conversion) with
     the consumer's compute — the reference's prefetch_batches semantics
     (python/ray/data/iterator.py iter_batches)."""
+    it = iter(it)
+    _END = object()
+
+    def produce():
+        # one batch made: block fetch, assembly, `_finalize_fn`; the wait
+        # for room in the queue is no part of it
+        with tracing.span("data.batch_produce"):
+            return next(it, _END)
+
     if n <= 0:
-        yield from it
+        while (item := produce()) is not _END:
+            yield item
         return
     import queue
     import threading
 
     q: "queue.Queue" = queue.Queue(maxsize=n)
-    _END = object()
     stop = threading.Event()
     depth = _PREFETCH_DEPTH.cell()
+    ctx = tracing.current_context()  # a new thread inherits no context
 
     def _put(item) -> bool:
         # Bounded put that gives up when the consumer abandoned the
@@ -172,15 +190,14 @@ def prefetch_iterator(it: Iterator[Any], n: int) -> Iterator[Any]:
         return False
 
     def fill():
+        token = tracing.set_context(ctx)
         try:
-            for item in it:
-                if not _put(item):
-                    break
-            else:
-                _put(_END)
+            while _put(item := produce()) and item is not _END:
+                pass
         except BaseException as e:  # surfaced on the consumer side
             _put(e)
         finally:
+            tracing.reset_context(token)
             if stop.is_set():
                 # Run upstream generators' finally-blocks promptly.
                 close = getattr(it, "close", None)
@@ -194,7 +211,8 @@ def prefetch_iterator(it: Iterator[Any], n: int) -> Iterator[Any]:
     t.start()
     try:
         while True:
-            item = q.get()
+            with tracing.span("data.batch_wait"):
+                item = q.get()
             depth.set(q.qsize())
             if item is _END:
                 return
@@ -211,7 +229,9 @@ def _mapped_with_close(fn, it):
     the consumer abandons iteration (plain map objects have no close)."""
     try:
         for item in it:
-            yield fn(item)
+            with tracing.span("data.finalize"):
+                item = fn(item)
+            yield item
     finally:
         close = getattr(it, "close", None)
         if close is not None:
@@ -525,10 +545,11 @@ class DataIterator:
 
         from ray_tpu.data._execution import StreamingExecutor
 
-        ops = cloudpickle.loads(self._local_plan)
-        ex = StreamingExecutor(
-            self._par, preserve_order=config.data_split_preserve_order
-        )
+        with tracing.span("data.epoch_start"):
+            ops = cloudpickle.loads(self._local_plan)
+            ex = StreamingExecutor(
+                self._par, preserve_order=config.data_split_preserve_order
+            )
 
         def refs():
             for bundle in ex.execute(ops):
@@ -555,7 +576,8 @@ class DataIterator:
         if self._coord is None:
             yield from self._local_blocks()
             return
-        epoch = ray_tpu.get(self._coord.start_epoch.remote(self._idx))
+        with tracing.span("data.epoch_start"):
+            epoch = ray_tpu.get(self._coord.start_epoch.remote(self._idx))
         # Direct object-store fetch: zero-copy shm view for local blocks,
         # chunked pull for remote ones — the data plane never flows through
         # the coordinator actor. Pipelined so fetch overlaps assembly.
@@ -583,7 +605,15 @@ class DataIterator:
         )
         if _finalize_fn is not None:
             it = _mapped_with_close(_finalize_fn, it)
-        yield from prefetch_iterator(it, prefetch_batches)
+        batches = prefetch_iterator(it, prefetch_batches)
+        try:
+            with tracing.span("data.pipeline_start"):
+                first = next(batches, None)
+            if first is not None:
+                yield first
+                yield from batches
+        finally:
+            batches.close()
 
     def iter_rows(self) -> Iterator[Any]:
         for blk in self._blocks():

@@ -21,6 +21,7 @@ import ray_tpu
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.train._session import TrialInfo
 from ray_tpu.train._worker_group import WorkerGroup
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -66,8 +67,45 @@ class JaxConfig(BackendConfig):
         return _JaxBackend
 
 
+_compiles_watched = False
+
+
+def _watch_compiles() -> None:
+    """Worker side: one `jax.compile` span a program compiled or loaded from
+    the persistent cache, with the counters `compile.programs`,
+    `compile.cache_hits` and `compile.cache_misses`. `jax.monitoring` tells
+    of a compilation at its end, and of the cache's verdict just before."""
+    global _compiles_watched
+    if _compiles_watched:
+        return
+    _compiles_watched = True
+    import jax.monitoring
+
+    verdicts = {  # the event: (the span's `cache`, the counter)
+        "/jax/compilation_cache/cache_hits": ("hit", "compile.cache_hits"),
+        "/jax/compilation_cache/cache_misses": ("miss", "compile.cache_misses"),
+    }
+    cache = [None]
+
+    def on_event(event: str, **_) -> None:
+        if event in verdicts:
+            cache[0], counter = verdicts[event]
+            tracing.count(counter)
+
+    def on_duration(event: str, seconds: float, fun_name=None, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            tracing.count("compile.programs")
+            tracing.observe("jax.compile", seconds, cache=cache[0] or "none",
+                            function=fun_name)
+            cache[0] = None
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
 class _JaxBackend(Backend):
     def on_start(self, worker_group: WorkerGroup, backend_config: JaxConfig):
+        worker_group.execute("apply", cloudpickle.dumps(_watch_compiles))
         be = backend_config.collective_backend
         if be is None or len(worker_group) <= 1:
             return
@@ -119,13 +157,15 @@ class BackendExecutor:
                 "(TPU_VISIBLE_CHIPS, /dev/accel*, /dev/vfio) found none and "
                 "init() was given none"
             )
-        self.worker_group = WorkerGroup(
-            self._scaling.num_workers,
-            self._scaling.as_placement_group_bundles(),
-            self._scaling.placement_strategy,
-            worker_env=self._worker_env,
-        )
-        self._backend.on_start(self.worker_group, self._backend_config)
+        with tracing.span("train.worker_group_start"):  # to actors ready
+            self.worker_group = WorkerGroup(
+                self._scaling.num_workers,
+                self._scaling.as_placement_group_bundles(),
+                self._scaling.placement_strategy,
+                worker_env=self._worker_env,
+            )
+        with tracing.span("train.backend_start"):
+            self._backend.on_start(self.worker_group, self._backend_config)
 
     def start_training(
         self,
@@ -137,9 +177,8 @@ class BackendExecutor:
         wg = self.worker_group
         assert wg is not None, "start() must run first"
         group = getattr(wg, "_collective_group", None)
-        setup_refs = []
-        for rank, w in enumerate(wg.workers):
-            setup_refs.append(
+        with tracing.span("train.session_setup"):
+            ray_tpu.get([
                 w.setup_session.remote(
                     world_rank=rank,
                     world_size=len(wg),
@@ -152,8 +191,8 @@ class BackendExecutor:
                     loop_config=loop_config,
                     collective_group=group,
                 )
-            )
-        ray_tpu.get(setup_refs)
+                for rank, w in enumerate(wg.workers)
+            ])
         self._backend.on_training_start(wg, self._backend_config)
         blob = cloudpickle.dumps(train_fn)
         self._run_refs = [w.run.remote(blob) for w in wg.workers]

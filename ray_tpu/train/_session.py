@@ -16,7 +16,9 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
+from ray_tpu.train import _runtime
 from ray_tpu.train._checkpoint import Checkpoint, _parse_uri
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -94,6 +96,7 @@ class _TrainSession:
         self.iteration = 0
         self.finished = threading.Event()
         self.error: Optional[BaseException] = None
+        self.runtime = _runtime.RuntimeAccount()
 
     # -- worker-side checkpoint persistence ---------------------------------
 
@@ -122,19 +125,26 @@ class _TrainSession:
     def report(
         self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None
     ) -> None:
-        ckpt_path = None
-        if checkpoint is not None:
-            ckpt_path = self._persist_checkpoint(checkpoint.fs_path)
-            self.latest_checkpoint = Checkpoint(ckpt_path)
-        self.result_queue.put(
-            TrainingResult(
-                metrics=dict(metrics),
-                checkpoint_path=ckpt_path,
-                iteration=self.iteration,
-                world_rank=self.world_rank,
+        with tracing.span("train.report"):
+            ckpt_path = None
+            if checkpoint is not None:
+                with tracing.span("train.checkpoint_persist"):
+                    ckpt_path = self._persist_checkpoint(checkpoint.fs_path)
+                self.latest_checkpoint = Checkpoint(ckpt_path)
+            metrics = dict(metrics)
+            # the worker's account of its own time; a user's key of the
+            # same name wins
+            block = self.runtime.block()
+            metrics.setdefault(_runtime.KEY, block)
+            self.result_queue.put(
+                TrainingResult(
+                    metrics=metrics,
+                    checkpoint_path=ckpt_path,
+                    iteration=self.iteration,
+                    world_rank=self.world_rank,
+                )
             )
-        )
-        self.iteration += 1
+            self.iteration += 1
 
     def get_checkpoint(self) -> Optional[Checkpoint]:
         return self.latest_checkpoint

@@ -20,6 +20,7 @@ from ray_tpu.air.config import (
     RunConfig,
     ScalingConfig,
 )
+from ray_tpu.train import _runtime
 from ray_tpu.train._backend_executor import (
     BackendConfig,
     BackendExecutor,
@@ -27,6 +28,7 @@ from ray_tpu.train._backend_executor import (
 )
 from ray_tpu.train._checkpoint import Checkpoint, _parse_uri
 from ray_tpu.train._session import TrialInfo
+from ray_tpu.util import tracing
 
 
 class _CheckpointManager:
@@ -230,6 +232,11 @@ class DataParallelTrainer(BaseTrainer):
             finally:
                 executor.shutdown()
 
+        # The driver's own spans (`init*`, `train.*`, since this process
+        # began) beside the worker's, in the last result's block.
+        block = history[-1].get(_runtime.KEY) if history else None
+        if isinstance(block, dict) and "since_first_report" in block:
+            block["driver"] = tracing.table()
         best = ckpt_manager.best() or latest_ckpt
         return Result(
             metrics=history[-1] if history else None,

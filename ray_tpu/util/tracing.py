@@ -32,6 +32,13 @@ The GCS diverts both into one bounded ``spans`` ring surfaced through
 SDK dependency: the span model (trace_id / span_id / parent_span_id /
 kind / start / duration) is OTLP-shaped so an exporter can translate 1:1.
 
+The train path's spans (:func:`span`; docs/observability.md, "The train
+path") are the same spans under one more rule: they do not wait for the
+options above. Each adds to a process-local table of seconds by name and,
+once ``jax`` is imported, is a ``jax.profiler.TraceAnnotation``, so that a
+profiler session holds them beside the device's operations; every
+``train.report`` carries the table.
+
 The contextvar itself lives in ``ray_tpu._private.rpc`` (the bottom of
 the import graph — the frame codec must read it, and importing this
 module from rpc would cycle through ``ray_tpu.util``); this module owns
@@ -43,6 +50,7 @@ from __future__ import annotations
 import contextlib
 import os
 import random
+import sys
 import threading
 import time
 import zlib
@@ -56,6 +64,7 @@ from ray_tpu._private import rpc as _rpc
 # with the RPC layer, which stamps it onto outgoing request frames and
 # restores it around incoming handlers.
 _trace_ctx = _rpc._trace_ctx
+_perf_counter = time.perf_counter
 
 # Span-id generation: a module-level PRNG seeded from the OS once. The
 # record path is perf-gated (trace_span_record_ns); os.urandom per span is
@@ -134,6 +143,35 @@ _buf_lock = threading.Lock()
 _flusher_started = False
 
 
+def _emit(
+    name: str,
+    kind: str,
+    trace_id: str,
+    span_id: str,
+    parent: Optional[str],
+    start: float,
+    duration: float,
+    attrs: Dict[str, Any],
+) -> None:
+    """Append one finished span to the ring: the one place a span's
+    dictionary is built."""
+    span = {
+        "state": "SPAN",
+        "name": name,
+        "kind": kind,
+        "span_id": span_id,
+        "parent_span_id": parent,
+        "trace_id": trace_id,
+        "start": start,
+        "duration": duration,
+        "time": start + duration,
+    }
+    if attrs:
+        span.update(attrs)
+    with _buf_lock:
+        _buf.append(span)
+
+
 def record_span(
     name: str,
     kind: str,
@@ -154,103 +192,107 @@ def record_span(
         if ctx is None:
             return None
     span_id = _new_id()
-    span = {
-        "state": "SPAN",
-        "name": name,
-        "kind": kind,
-        "span_id": span_id,
-        "parent_span_id": ctx[1],
-        "trace_id": ctx[0],
-        "start": start,
-        "duration": duration,
-        "time": start + duration,
-    }
-    if attrs:
-        span.update(attrs)
-    with _buf_lock:
-        _buf.append(span)
+    _emit(name, kind, ctx[0], span_id, ctx[1], start, duration, attrs)
     return span_id
 
 
-@contextlib.contextmanager
-def span_scope(name: str, kind: str, ctx: Optional[tuple] = None, **attrs: Any):
+class Span:
+    """One span around a code region: ``__enter__`` starts it and
+    ``__exit__`` finishes it, for every scope function of this module.
+
+    ``trace`` is ``(trace_id, parent_span_id)`` or None. With a trace the
+    span becomes the active context for its duration (nested spans, and RPC
+    calls made inside, parent under it) and goes to the ring when it ends;
+    ``with`` then yields ``(trace_id, span_id)``, and None otherwise.
+
+    ``tabled`` marks a span of the train path (:func:`span`): whatever the
+    tracing options say, its seconds go to this process's table
+    (:func:`table`), and it is a ``jax.profiler.TraceAnnotation`` of the
+    same name when ``jax`` has been imported. The annotation costs a flag
+    test while no profiler session runs; in a session the span lands in the
+    host planes of the same file as the device's operations, on one clock,
+    on its thread's line. ``jax`` is never imported from here: a process
+    that must stay without a backend stays so.
+    """
+
+    __slots__ = ("name", "kind", "attrs", "seconds", "_trace", "_tabled",
+                 "_span_id", "_token", "_wall0", "_t0", "_annotation")
+
+    def __init__(self, name: str, kind: str, trace: Optional[tuple],
+                 attrs: Dict[str, Any], tabled: bool = False):
+        self.name = name
+        self.kind = kind
+        self.attrs = attrs
+        self._trace = trace
+        self._tabled = tabled
+        self._annotation = None
+
+    def __enter__(self) -> Optional[tuple]:
+        trace = self._trace
+        ctx = None
+        if trace is not None:
+            self._span_id = _new_id()
+            ctx = (trace[0], self._span_id)
+            self._token = _trace_ctx.set(ctx)
+            self._wall0 = time.time()
+        if self._tabled:
+            annotation = _TraceAnnotation or _annotation_class()
+            if annotation is not None:
+                self._annotation = annotation = annotation(self.name)
+                annotation.__enter__()
+            self._t0 = _perf_counter()
+        return ctx
+
+    def __exit__(self, *exc) -> None:
+        if self._tabled:
+            t1 = _perf_counter()
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
+            self.seconds = seconds = t1 - self._t0  # for a caller that keeps the span
+            _add(self.name, seconds, t1)
+        trace = self._trace
+        if trace is not None:
+            _trace_ctx.reset(self._token)
+            _emit(self.name, self.kind, trace[0], self._span_id, trace[1],
+                  self._wall0, time.time() - self._wall0, self.attrs)
+
+
+def _joined(ctx: Optional[tuple] = None) -> Optional[tuple]:
+    """The trace a runtime span joins: ``ctx`` or the ambient context, and
+    None when tracing is off or no trace is active."""
+    if not enabled():
+        return None
+    return ctx if ctx is not None else _trace_ctx.get()
+
+
+def _joined_or_rooted(key: str) -> Optional[tuple]:
+    """As :func:`_joined`, but with no trace active a new one begins here,
+    subject to the sampling decision on ``key``."""
+    if not enabled():
+        return None
+    cur = _trace_ctx.get()
+    if cur is not None:
+        return cur
+    return (_new_id(), None) if _sample(key) else None
+
+
+def span_scope(name: str, kind: str, ctx: Optional[tuple] = None,
+               **attrs: Any) -> Span:
     """Span around a runtime code region. Sets the active context to the
     new span for the duration, so nested spans — and RPC calls made inside
     — parent under it. No-op when tracing is off or no trace is active."""
-    if not enabled():
-        yield None
-        return
-    if ctx is None:
-        ctx = _trace_ctx.get()
-    if ctx is None:
-        yield None
-        return
-    span_id = _new_id()
-    token = _trace_ctx.set((ctx[0], span_id))
-    t0 = time.time()
-    try:
-        yield (ctx[0], span_id)
-    finally:
-        _trace_ctx.reset(token)
-        span = {
-            "state": "SPAN",
-            "name": name,
-            "kind": kind,
-            "span_id": span_id,
-            "parent_span_id": ctx[1],
-            "trace_id": ctx[0],
-            "start": t0,
-            "duration": time.time() - t0,
-            "time": time.time(),
-        }
-        if attrs:
-            span.update(attrs)
-        with _buf_lock:
-            _buf.append(span)
+    return Span(name, kind, _joined(ctx), attrs)
 
 
-@contextlib.contextmanager
-def root_scope(name: str, kind: str, key: Optional[str] = None, **attrs: Any):
+def root_scope(name: str, kind: str, key: Optional[str] = None,
+               **attrs: Any) -> Span:
     """Span that CREATES a trace when none is active (subject to the
     sampling decision on ``key``). The serve router wraps each request in
     one of these, so a bare HTTP/handle call — no task ancestry — still
     yields a connected trace. Inside an existing trace it behaves exactly
     like :func:`span_scope`."""
-    if not enabled():
-        yield None
-        return
-    cur = _trace_ctx.get()
-    if cur is None:
-        root_key = key if key is not None else name
-        if not _sample(root_key):
-            yield None
-            return
-        trace_id = _new_id()
-        parent = None
-    else:
-        trace_id, parent = cur
-    span_id = _new_id()
-    token = _trace_ctx.set((trace_id, span_id))
-    t0 = time.time()
-    try:
-        yield (trace_id, span_id)
-    finally:
-        _trace_ctx.reset(token)
-        span = {
-            "state": "SPAN",
-            "name": name,
-            "kind": kind,
-            "span_id": span_id,
-            "parent_span_id": parent,
-            "trace_id": trace_id,
-            "start": t0,
-            "duration": time.time() - t0,
-            "time": time.time(),
-        }
-        if attrs:
-            span.update(attrs)
-        with _buf_lock:
-            _buf.append(span)
+    return Span(name, kind,
+                _joined_or_rooted(key if key is not None else name), attrs)
 
 
 def iter_scope(it: Iterable, name: str, kind: str = "data", **attrs: Any) -> Iterator:
@@ -258,39 +300,134 @@ def iter_scope(it: Iterable, name: str, kind: str = "data", **attrs: Any) -> Ite
     span active while the iterator body runs — so every task a streaming
     executor submits joins a single trace. Creates a root (sampled on
     ``name``) when no trace is active."""
-    if not enabled():
+    with Span(name, kind, _joined_or_rooted(name), attrs):
         yield from it
-        return
-    cur = _trace_ctx.get()
-    if cur is None:
-        if not _sample(name):
-            yield from it
-            return
-        trace_id, parent = _new_id(), None
-    else:
-        trace_id, parent = cur
-    span_id = _new_id()
-    token = _trace_ctx.set((trace_id, span_id))
-    t0 = time.time()
+
+
+def span(name: str, kind: str = "train", **attrs: Any) -> Span:
+    """A span of the train path (docs/observability.md, "The train path"):
+    always in this process's table and, once ``jax`` is imported, in the
+    profiler's trace; in the ring as any runtime span, when tracing is on
+    and a trace is active."""
+    # `_joined()` spelled out: this is the one scope function on a step's path
+    if config.task_trace_spans or config.trace_sample_rate > 0:
+        return Span(name, kind, _trace_ctx.get(), attrs, True)
+    return Span(name, kind, None, attrs, True)
+
+
+def observe(name: str, seconds: float, kind: str = "train",
+            **attrs: Any) -> None:
+    """A span of the train path that is known only once it has ended (a
+    compilation reported by ``jax.monitoring``, a garbage collection): it
+    ended now and took ``seconds``. It reaches the table and the ring, not
+    the profiler's trace, which cannot be told of a span after the fact."""
+    _add(name, seconds, time.perf_counter())
+    trace = _joined()
+    if trace is not None:
+        _emit(name, kind, trace[0], _new_id(), trace[1],
+              time.time() - seconds, seconds, attrs)
+
+
+# ---------------------------------------------------------------------------
+# The table of the train path's spans: seconds by name, kept by every
+# process whatever the tracing options say. Each thread adds to a table of
+# its own (no lock on a span's path); :func:`table` merges them.
+# ---------------------------------------------------------------------------
+
+_local = threading.local()
+# id(table) -> (its thread, {name: [count, seconds, longest, end of the
+# longest on perf_counter's clock, the mark the longest belongs to]}). A dead
+# thread's table is folded into `_retired`, so a job that starts a prefetch
+# thread an epoch keeps one table a live thread.
+_tables: Dict[int, tuple] = {}
+_retired: Dict[str, list] = {}
+_tables_lock = threading.Lock()
+_mark = 0
+_counters: Dict[str, int] = {}
+_TraceAnnotation = None
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` once ``jax`` has been imported by
+    whoever needs it, None before."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _TraceAnnotation = getattr(profiler, "TraceAnnotation", None)
+    return _TraceAnnotation
+
+
+def _add(name: str, seconds: float, end: float) -> None:
     try:
-        yield from it
-    finally:
-        _trace_ctx.reset(token)
-        span = {
-            "state": "SPAN",
-            "name": name,
-            "kind": kind,
-            "span_id": span_id,
-            "parent_span_id": parent,
-            "trace_id": trace_id,
-            "start": t0,
-            "duration": time.time() - t0,
-            "time": time.time(),
-        }
-        if attrs:
-            span.update(attrs)
-        with _buf_lock:
-            _buf.append(span)
+        rows = _local.rows
+    except AttributeError:
+        rows = _local.rows = {}
+        with _tables_lock:
+            _tables[id(rows)] = (threading.current_thread(), rows)
+    row = rows.get(name)
+    if row is None:
+        rows[name] = [1, seconds, seconds, end, _mark]
+        return
+    row[0] += 1
+    row[1] += seconds
+    if row[4] != _mark:
+        row[2], row[3], row[4] = seconds, end, _mark
+    elif seconds > row[2]:
+        row[2], row[3] = seconds, end
+
+
+def _fold(into: Dict[str, list], rows: Dict[str, list]) -> None:
+    for name, (count, seconds, longest, end, mark) in rows.items():
+        have = into.get(name)
+        if have is None:
+            into[name] = [count, seconds, longest, end, mark]
+            continue
+        have[0] += count
+        have[1] += seconds
+        if (mark, longest) > (have[4], have[2]):
+            have[2], have[3], have[4] = longest, end, mark
+
+
+def table(mark: bool = False) -> Dict[str, list]:
+    """``{name: [count, seconds, longest_seconds, time of the longest]}``
+    of the train path's spans in this process, every thread's merged.
+    Count and seconds run from the process's start, so that a reader takes
+    the difference of two readings for a window. The longest cannot be had
+    that way: it is the longest since the last reading with ``mark`` (0
+    where the name saw no span since then), with the wall-clock time at
+    which it ended. One reader of a process marks: the train session, at
+    every report."""
+    global _mark
+    merged: Dict[str, list] = {}
+    with _tables_lock:
+        for key, (thread, rows) in list(_tables.items()):
+            # read `is_alive` first: a thread's last span precedes its end
+            if thread.is_alive():
+                _fold(merged, rows.copy())
+            else:
+                _fold(_retired, rows)
+                del _tables[key]
+        _fold(merged, _retired)
+        current = _mark
+        if mark:
+            _mark += 1
+    to_wall = time.time() - time.perf_counter()
+    return {
+        name: ([count, seconds, longest, end + to_wall] if at == current
+               else [count, seconds, 0.0, 0.0])
+        for name, (count, seconds, longest, end, at) in merged.items()
+    }
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add to a counter of the train path (``compile.programs``, ...)."""
+    with _tables_lock:  # a compilation's pace, not a span's: a lock is cheap
+        _counters[name] = _counters.get(name, 0) + n
+
+
+def counters() -> Dict[str, int]:
+    with _tables_lock:
+        return dict(_counters)
 
 
 def span_flush_delta() -> List[dict]:

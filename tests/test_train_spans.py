@@ -1,0 +1,290 @@
+"""The train path times itself (docs/observability.md, "The train path"):
+the spans of `ray_tpu/util/tracing.py` where the train path's work happens,
+the `ray_tpu_runtime` block every `train.report` carries, and the record a
+slow report interval leaves."""
+
+import subprocess
+import sys
+import time
+
+import pytest
+
+import ray_tpu
+from ray_tpu._private import telemetry
+from ray_tpu.train import _runtime
+from ray_tpu.train._session import _TrainSession
+from ray_tpu.util import tracing
+from ray_tpu.util.state import api as state_api
+
+KEY = _runtime.KEY
+WORKER_SPANS = {
+    "jax.compile", "data.pipeline_start", "data.epoch_start",
+    "data.batch_produce", "data.block_fetch", "data.batch_assemble",
+    "data.finalize", "data.batch_wait", "train.report",
+    "train.checkpoint_persist", "py.gc",
+}
+DRIVER_SPANS = {
+    "init", "init.gcs", "init.raylet", "init.worker_pool",
+    "train.worker_group_start", "train.backend_start", "train.session_setup",
+}
+REPORTS = 4
+
+
+def _make_loop():
+    """Defined inside a function, so that it pickles by value."""
+
+    def _loop(config):
+        import gc
+        import os
+        import tempfile
+
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu import train
+
+        step = jax.jit(lambda x: (x * 2.0).sum())
+        shard = train.get_dataset_shard("train")
+        for _ in range(REPORTS):  # an epoch a report
+            total = 0.0
+            for batch in shard.iter_batches(
+                    batch_size=8, prefetch_batches=2,
+                    _finalize_fn=lambda b: jnp.asarray(b["id"], jnp.float32)):
+                total += float(step(batch))
+            gc.collect()  # a process that holds jax takes milliseconds over it
+            with tempfile.TemporaryDirectory() as d:
+                with open(os.path.join(d, "w"), "w") as f:
+                    f.write("x")
+                train.report({"total": total},
+                             checkpoint=train.Checkpoint.from_directory(d))
+
+    return _loop
+
+
+def _fit(tmp_path):
+    from ray_tpu import data as rd
+    from ray_tpu.air import RunConfig, ScalingConfig
+    from ray_tpu.train.jax import JaxTrainer
+
+    return JaxTrainer(
+        _make_loop(),
+        scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name="spans", storage_path=str(tmp_path)),
+        datasets={"train": rd.range(32, parallelism=4)},
+    ).fit()
+
+
+@pytest.fixture(scope="module")
+def untraced_run(tmp_path_factory):
+    """One tiny JaxTrainer run on the CPU through ray_tpu.data, tracing off."""
+    ray_tpu.init(num_cpus=4, num_tpus=0)
+    try:
+        result = _fit(tmp_path_factory.mktemp("untraced"))
+        time.sleep(1.0)  # a span flusher, were one running, would have fired
+        return result, state_api.list_spans(), tracing.snapshot()
+    finally:
+        ray_tpu.shutdown()
+
+
+def test_every_report_carries_the_block(untraced_run):
+    result = untraced_run[0]
+    assert len(result.metrics_history) == REPORTS
+    for metrics in result.metrics_history:
+        block = metrics[KEY]
+        assert {"total", "since_first_report", "interval", "counters",
+                "rusage"} <= set(block)
+        assert {"ru_nivcsw", "ru_nvcsw", "ru_majflt", "ru_utime",
+                "ru_stime"} <= set(block["rusage"])
+    assert result.metrics[KEY] is result.metrics_history[-1][KEY]
+    assert result.metrics["total"] == sum(range(32)) * 2.0
+
+
+def test_the_block_holds_every_span_of_the_train_path(untraced_run):
+    block = untraced_run[0].metrics[KEY]
+    assert WORKER_SPANS <= set(block["total"])
+    assert DRIVER_SPANS <= set(block["driver"])
+    for table in (block["total"], block["driver"]):
+        for count, seconds, longest, when in table.values():
+            assert count >= 1 and seconds >= longest >= 0.0
+    count, seconds, longest, when = block["driver"]["init"]
+    assert count == 1 and seconds > 0 and abs(when - time.time()) < 600
+    assert block["total"]["data.pipeline_start"][0] == REPORTS
+    assert block["total"]["train.report"][0] == REPORTS - 1  # the last is open
+    assert block["total"]["train.checkpoint_persist"][0] == REPORTS
+    assert block["total"]["data.batch_produce"][0] >= 4 * REPORTS
+    assert block["counters"]["compile.programs"] >= 1
+    assert block["counters"]["compile.programs"] == block["total"]["jax.compile"][0]
+
+
+def test_since_first_report_is_total_less_the_first_reports(untraced_run):
+    history = untraced_run[0].metrics_history
+    first, last = history[0][KEY], history[-1][KEY]
+    assert first["since_first_report"] == {}
+    assert first["interval"] == first["total"]
+    for name, (count, seconds, longest, when) in last["total"].items():
+        began = first["total"].get(name, [0, 0.0])
+        steady = last["since_first_report"].get(name, [0, 0.0, 0.0, 0.0])
+        assert steady[0] == count - began[0]
+        assert steady[1] == pytest.approx(seconds - began[1], abs=1e-9)
+        assert steady[2] <= longest
+    # the compile is set-up's: the steady state holds none
+    assert "jax.compile" not in last["since_first_report"]
+    assert last["interval"]["data.pipeline_start"][0] == 1
+
+
+def test_with_tracing_off_the_ring_receives_nothing(untraced_run):
+    _, listed, local = untraced_run
+    assert listed == [] and local == []
+
+
+def test_with_tracing_on_the_new_spans_reach_list_spans(
+        monkeypatch, shutdown_only, tmp_path):
+    monkeypatch.setenv("RAY_TPU_TASK_TRACE_SPANS", "1")
+    ray_tpu.init(num_cpus=4, num_tpus=0)
+    _fit(tmp_path)
+    want = {"train.report", "train.checkpoint_persist", "data.pipeline_start",
+            "data.batch_wait", "data.batch_produce", "data.block_fetch",
+            "data.batch_assemble", "data.finalize", "jax.compile"}
+    deadline = time.time() + 25
+    while time.time() < deadline:
+        spans = state_api.list_spans()
+        if want <= {s["name"] for s in spans}:
+            break
+        time.sleep(0.25)
+    by_name = {s["name"]: s for s in spans}
+    assert want <= set(by_name), sorted(want - set(by_name))
+    # the prefetch thread's spans join the trace of the loop's thread
+    assert (by_name["data.batch_produce"]["trace_id"]
+            == by_name["train.report"]["trace_id"])
+    assert by_name["jax.compile"]["cache"] in ("hit", "miss", "none")
+
+
+def test_init_leaves_jax_unimported():
+    code = ("import sys, ray_tpu; ray_tpu.init(num_cpus=1, num_tpus=0);"
+            "from ray_tpu.util import tracing;"
+            "assert tracing.table()['init'][0] == 1;"
+            "ray_tpu.shutdown(); sys.exit('jax' in sys.modules)")
+    done = subprocess.run([sys.executable, "-c", code], timeout=120)
+    assert done.returncode == 0
+
+
+def _slow_events():
+    return [fields for _, component, event, fields in
+            telemetry.flight().snapshot()
+            if (component, event) == ("train", "slow_interval")]
+
+
+def test_a_slow_interval_leaves_one_record_that_names_the_span(caplog):
+    telemetry.flight().clear()
+    session = _TrainSession()
+    for i in range(7):
+        time.sleep(0.02)
+        session.report({"i": i})
+    assert _slow_events() == []
+    with tracing.span("data.batch_wait"):
+        time.sleep(0.4)
+    with caplog.at_level("WARNING", logger=_runtime.__name__):
+        session.report({"i": 7})
+    for i in range(3):
+        time.sleep(0.02)
+        session.report({"i": 8 + i})
+    (record,) = _slow_events()
+    assert record["seconds"] > 0.4 > 3 * record["median"]
+    count, seconds, longest, when = record["spans"]["data.batch_wait"]
+    assert count == 1 and longest == seconds >= 0.4
+    assert abs(when - time.time()) < 60
+    assert set(record) == {"seconds", "median", "report", "spans", "gc_s",
+                           "rusage", "compiles"}
+    assert record["report"] == 7 and record["compiles"] == 0
+    lines = [r.getMessage() for r in caplog.records]
+    assert len(lines) == 1 and "slow_interval" in lines[0]
+    assert "data.batch_wait" in lines[0]
+
+
+def test_a_users_own_key_is_kept():
+    session = _TrainSession()
+    session.report({KEY: "mine", "x": 1})
+    assert session.result_queue.get_nowait().metrics == {KEY: "mine", "x": 1}
+    session.report({"x": 2})
+    assert set(session.result_queue.get_nowait().metrics[KEY]) >= {"total"}
+
+
+def test_the_longest_is_the_interval_s_own():
+    """Counts and seconds are differences of two readings; the longest is
+    kept since the reader's last mark, whatever came before."""
+    tracing.table(mark=True)
+    with tracing.span("test.longest"):
+        time.sleep(0.05)
+    assert tracing.table(mark=True)["test.longest"][2] >= 0.05
+    with tracing.span("test.longest"):
+        pass
+    count, seconds, longest, when = tracing.table(mark=True)["test.longest"]
+    assert count == 2 and seconds >= 0.05 > longest > 0.0
+    assert tracing.table()["test.longest"][2:] == [0.0, 0.0]
+
+
+def test_a_dead_thread_s_spans_stay_in_the_table():
+    import threading
+
+    def work():
+        with tracing.span("test.thread"):
+            pass
+
+    for _ in range(3):
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    assert tracing.table()["test.thread"][0] == 3
+    assert tracing.table()["test.thread"][0] == 3  # folded once, not again
+    assert all(thread.is_alive() for thread, _ in tracing._tables.values())
+
+
+def test_a_span_s_dictionary_is_built_in_one_place():
+    import inspect
+
+    source = inspect.getsource(tracing)
+    assert source.count('"parent_span_id":') == 1  # `_emit`, for every scope
+    for scope in (tracing.span_scope("a", "k"), tracing.root_scope("a", "k"),
+                  tracing.span("a")):
+        assert isinstance(scope, tracing.Span)
+
+
+def test_no_span_is_lost_while_the_table_is_read():
+    """More threads than cores add to their own tables, end, and are folded,
+    while a reader merges and marks as fast as it can."""
+    import os
+    import threading
+
+    threads, spans_each = 2 * (os.cpu_count() or 4), 2000
+    before = tracing.table().get("test.stress", [0])[0]
+    done = threading.Event()
+    seen = []
+
+    def work():
+        for _ in range(spans_each):
+            with tracing.span("test.stress"):
+                pass
+        tracing.count("test.stress", 1)
+
+    def read():
+        while not done.is_set():
+            seen.append(tracing.table(mark=True).get("test.stress", [0])[0])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        reader = threading.Thread(target=read)
+        reader.start()
+        workers = [threading.Thread(target=work) for _ in range(threads)]
+        for t in workers:
+            t.start()
+        for t in workers:
+            t.join(timeout=60)
+        done.set()
+        reader.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not reader.is_alive() and not any(t.is_alive() for t in workers)
+    assert tracing.table()["test.stress"][0] - before == threads * spans_each
+    assert tracing.counters()["test.stress"] >= threads
+    assert seen == sorted(seen)  # a reading never goes back

@@ -47,6 +47,12 @@ device it is traced for (`saved_activations`: from the widths, the tokens
 and the state a device holds, and the device's memory limit; nothing where
 no limit can be read).
 
+Every weight of a block's plain matmuls leaves its gradient's matmul as an
+array of its own in the compute dtype, and in a segment of one period also
+reaches its own matmuls as one (`_own_weights`), so that no cast, update of
+the scanned stack or optimizer update is fused into a matmul. The routed
+experts' weights are `ops/moe.py`'s.
+
 Parallelism (ray_tpu.parallel.mesh axes):
   data/fsdp — batch split; fsdp additionally shards params (ZeRO-3 style)
   tensor    — heads + mlp hidden + vocab split (Megatron layout)
@@ -74,12 +80,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.fused import (
+    _own_buffer,
+    _own_cotangent,
     fused_rmsnorm,
     lm_head_cross_entropy,
     softmax_cross_entropy,
 )
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel.ring_attention import ring_attention
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -507,6 +516,76 @@ def _kernel_impl(cfg: TransformerConfig) -> str:
     )
 
 
+# the weights of a block's plain matmuls, every operator's and feed-forward's
+_MATMUL_WEIGHTS = (
+    "wq", "wk", "wv", "wo", "wkv_a", "wkv_b", "conv_in", "conv_out",
+    "w_gate", "w_up", "w_down", "ws_gate", "ws_up", "ws_down",
+)
+_ROUTED_WEIGHTS = ("w_gate", "w_up", "w_down")  # `ops/moe.py` casts its own
+
+
+def own_buffer_weights(blk) -> Tuple[str, ...]:
+    """The leaves of a block that `_own_weights` hands its matmuls: the
+    weights of its plain matmuls. The routed experts' go to `ops/moe.py` as
+    they are, and the norms' vectors and the router are no such matmul."""
+    return tuple(
+        name for name in _MATMUL_WEIGHTS
+        if name in blk and not ("router" in blk and name in _ROUTED_WEIGHTS))
+
+
+def _own_weights(blk, dt, sliced: bool):
+    """`blk` with the weights of its plain matmuls in the compute dtype,
+    each behind a barrier, so that the sites' `blk[name].astype(dt)` finds
+    them made.
+
+    Left to itself XLA fuses what takes a weight's gradient into the matmul
+    that makes it: the cast to float32 and the dynamic update of the scanned
+    stack's gradient, and in a segment of one period (whose loop it
+    unrolls) AdamW's whole update of the weight and its two moments. The
+    TPU compiler then tiles that matmul worse: 37 to 53 % of the MXU's peak
+    with AdamW inside and 69 to 80 % with the update, for 69 to 85 % into a
+    buffer (PERF.md section 6, PR 37; the head's had the same in PR 28).
+    `_own_cotangent` puts the barrier on the gradient alone: the matmul
+    writes a `[d, f]` array in `dt`, and what was fused in runs after it at
+    the memory's pace.
+
+    Where the weight arrives whole (`sliced` false: a segment of one
+    period) `_own_buffer` also makes the cast an array of its own for the
+    forward, the rematerialised and the input-gradient matmuls, which read
+    a float32 `[1, d, f]` through a fused cast at half the pace (12.0 ms for
+    17.9). A slice of a scanned stack is left to the compiler, which makes
+    the slice and the cast one operand of the matmul: there the copy costs
+    6 bytes an element a pass and the chip shows no matmul the faster for
+    it (`mistral7b.tokens4k`: -0.7 %)."""
+    own = _own_cotangent if sliced else _own_buffer
+    return {**blk, **{name: own(blk[name].astype(dt))
+                      for name in own_buffer_weights(blk)}}
+
+
+def _segment_trees(blocks):
+    """`params["blocks"]` as its segments, each one tree per layer of its
+    period: a model of one kind of layer is one segment of it."""
+    return [[blocks]] if isinstance(blocks, dict) else blocks
+
+
+def own_buffers(blocks, dt) -> Tuple[int, int, int]:
+    """(the buffers `_own_weights` makes for the layers of
+    `params["blocks"]`: one for every matmul weight's gradient and one more
+    for every weight of a segment of one period; their bytes in `dt`, whole
+    as a matmul takes them; the widest layer's weights' bytes)."""
+    item = jnp.dtype(dt).itemsize
+    count = total = widest = 0
+    for blk in (blk for blks in _segment_trees(blocks) for blk in blks):
+        names = own_buffer_weights(blk)
+        periods = blk["mlp_norm"].shape[0]
+        layer = item * sum(math.prod(blk[name].shape[1:]) for name in names)
+        each = 2 if periods == 1 else 1
+        count += each * periods * len(names)
+        total += each * periods * layer
+        widest = max(widest, layer)
+    return count, total, widest
+
+
 def _attention_layer(x, blk, positions, cfg: TransformerConfig,
                      seq_axis: Optional[str], seq_size: int, mesh=None,
                      keep_ctx: bool = False):
@@ -689,14 +768,16 @@ def _short_conv(x, blk, cfg: TransformerConfig):
 
 def _block(x, blk, positions, bias, cfg: TransformerConfig,
            seq_axis: Optional[str], seq_size: int, mesh=None,
-           keep_ctx: bool = False):
+           keep_ctx: bool = False, sliced: bool = False):
     """One block: (x, the routed feed-forward's readings or None). What the
     block is, its parameters say: a short convolution where it has
     `conv_in`, latent attention where it has `wkv_a`, a routed feed-forward
     where it has a `router`, shared experts beside it where it has
     `ws_gate`. `keep_ctx`: the attention kernel names its backward's
-    residuals `attn_ctx`."""
+    residuals `attn_ctx`. `sliced`: `blk` is one of several periods of a
+    scanned stack (`_own_weights`)."""
     dt = cfg.dtype
+    blk = _own_weights(blk, dt, sliced)
 
     # The scopes name the step's device work in a profiler trace
     # (docs/observability.md, "Device scopes"); they are metadata only.
@@ -764,45 +845,45 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
 
-    blk_fn = partial(
-        _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size, mesh=mesh,
-        keep_ctx="attn_ctx" in saved_names,
-    )
-    if cfg.remat:
-        # no policy at all for an empty choice: the step is then the one
-        # that keeps nothing, instruction for instruction
-        policy = (jax.checkpoint_policies.save_only_these_names(*saved_names)
-                  if saved_names else None)
-        blk_fn = jax.checkpoint(blk_fn, policy=policy, static_argnums=())
+    # no policy at all for an empty choice: the step is then the one that
+    # keeps nothing, instruction for instruction
+    policy = (jax.checkpoint_policies.save_only_these_names(*saved_names)
+              if saved_names else None)
 
-    blocks = params["blocks"]
-    if isinstance(blocks, dict):  # one kind of layer: one segment of it
-        blocks = [[blocks]]
+    def scan_body(sliced: bool):
+        blk_fn = partial(
+            _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size, mesh=mesh,
+            keep_ctx="attn_ctx" in saved_names, sliced=sliced,
+        )
+        if cfg.remat:
+            blk_fn = jax.checkpoint(blk_fn, policy=policy, static_argnums=())
 
-    def scan_body(x, period):
-        blks, biases = period
-        readings = []
-        for blk, bias in zip(blks, biases):
-            x, reading = blk_fn(x, blk, positions, bias)
-            if reading is not None:
-                readings.append(reading)
-        return x, readings
+        def body(x, period):
+            blks, biases = period
+            readings = []
+            for blk, bias in zip(blks, biases):
+                x, reading = blk_fn(x, blk, positions, bias)
+                if reading is not None:
+                    readings.append(reading)
+            return x, readings
+
+        return body
 
     readings, routed_before = [], 0
-    for blks in blocks:
+    for blks in _segment_trees(params["blocks"]):
+        periods = blks[0]["mlp_norm"].shape[0]
         # this segment's rows of the bias, one [periods, E] per routed layer
         # of its period
         biases = [None] * len(blks)
         routed = [i for i, blk in enumerate(blks) if "router" in blk]
         if expert_bias is not None and routed:
-            periods = blks[0]["mlp_norm"].shape[0]
             rows = expert_bias[
                 routed_before:routed_before + periods * len(routed)
             ].reshape(periods, len(routed), -1)
             routed_before += periods * len(routed)
             for j, i in enumerate(routed):
                 biases[i] = rows[:, j]
-        x, of_period = jax.lax.scan(scan_body, x, (blks, biases))
+        x, of_period = jax.lax.scan(scan_body(periods > 1), x, (blks, biases))
         if of_period:
             readings.append(_layer_axis(of_period, stack=True))
     readings = _layer_axis(readings, stack=False) if readings else None
@@ -1159,12 +1240,21 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         resident = on_a_device(state, state_shard)
         params = on_a_device(state["params"], p_shard)
         saved = saved_activations(cfg, tokens, resident, params, limit)
-        if cfg.remat:
-            logger.info(
-                "train step under remat keeps %s: %d bytes a device beside "
-                "the blocks' inputs (%d tokens a device, state %d bytes, "
-                "bytes_limit %s)", saved or "nothing", sum(saved.values()),
-                tokens, resident, limit)
+        kept = "keeps every activation" if not cfg.remat else (
+            "under remat keeps %s: %d bytes a device beside the blocks' "
+            "inputs (%d tokens a device, state %d bytes, bytes_limit %s)" % (
+                saved or "nothing", sum(saved.values()), tokens, resident,
+                limit))
+        # static, so counted as the step is traced: once a step's program
+        buffers, their_bytes, widest = own_buffers(
+            state["params"]["blocks"], cfg.dtype)
+        tracing.count("train.own_buffers", buffers)
+        tracing.count("train.own_buffer_bytes", their_bytes)
+        logger.info(
+            "train step %s; its blocks' weight matmuls read and write %d "
+            "buffers of their own, %d bytes in %s over %d layers (%d the "
+            "widest layer's weights)", kept, buffers, their_bytes,
+            jnp.dtype(cfg.dtype).name, cfg.n_layers, widest)
         return tuple(saved)
 
     @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))

@@ -96,6 +96,17 @@ def _own_buffer(x):
     return jax.lax.optimization_barrier(x)
 
 
+@jax.custom_vjp
+def _own_cotangent(x):
+    """`x` itself, whose cotangent is an array of its own: the half of
+    `_own_buffer` that a value's gradient sees, for where the value itself
+    is better left to its users' fusions."""
+    return x
+
+
+_own_cotangent.defvjp(lambda x: (x, None), lambda _, g: (_own_buffer(g),))
+
+
 def _chunk_loss_terms(hc, tc, w, ignore_index):
     """One chunk's f32 (logits, logsumexp, one-hot label index, mask,
     summed loss)."""

@@ -362,3 +362,76 @@ def test_token_step_without_a_limit_is_the_step_without_names(
     monkeypatch.setattr(tr, "checkpoint_name", lambda x, name: x)
     without_names, _ = _token_cell_step(cell_name, v5e, monkeypatch)
     assert text_of(without_names) == text_of(lowered)
+
+
+# ------------------- a block's weight matmuls from and to buffers of their own
+
+# the scopes of a block's plain matmuls (docs/observability.md, "Device
+# scopes"); the router's is float32 on purpose and is not `_own_weights`'
+BLOCK_SCOPES = {"mlp", "attn_qkv", "attn_out", "short_conv", "latent_attention"}
+
+
+def _computations(text):
+    """{name: its lines} of a compiled module's text."""
+    import re
+
+    found, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            found[name] = []
+        elif name is not None:
+            found[name].append(line)
+    return found
+
+
+def _block_matmul_fusions(text):
+    """(fusion's name, its result type, its fused computation's lines) of
+    every fusion that holds a convolution under a block's scope."""
+    import re
+
+    computations = _computations(text)
+    for lines in computations.values():
+        for line in lines:
+            fusion = re.match(
+                r"\s*(?:ROOT )?%([\w.-]+) = (.*?) fusion\(.* calls=%([\w.-]+)",
+                line)
+            if not fusion:
+                continue
+            body = computations[fusion.group(3)]
+            scopes = {
+                part for inner in body if " convolution(" in inner
+                for part in re.split(
+                    r"[/()]", re.search(r'op_name="([^"]*)"', inner).group(1))}
+            if scopes & BLOCK_SCOPES and "moe_router" not in scopes:
+                yield fusion.group(1), fusion.group(2), body
+
+
+@pytest.mark.parametrize("cell_name", ["lfm2moe.tokens8k", "mistral7b.tokens4k"])
+def test_no_block_matmul_carries_an_update_of_the_state(
+        v5e, monkeypatch, cell_name):
+    """What PR 37 took out, held out: compiled for a v5e with what the rule
+    keeps there, no fusion of a block's matmul also holds a dynamic update
+    (the weight gradient written into the scanned stack) or writes more
+    than one float32 array of a parameter's shape (AdamW's update of a
+    one-layer segment's weight and moments, fused into its gradient's
+    matmul). `mistral7b.tokens4k` had the first in 7 fusions of a layer,
+    `lfm2moe.tokens8k` the second in 15."""
+    import re
+
+    from ray_tpu.models import transformer as tr
+
+    monkeypatch.setattr(tr, "_memory_limit", lambda mesh: HBM_LIMIT)
+    lowered, _ = _token_cell_step(cell_name, v5e, monkeypatch)
+    state_shapes = {
+        ",".join(map(str, aval.shape))
+        for aval in jax.tree.leaves(lowered.in_avals)
+        if aval.dtype == jnp.float32 and aval.ndim >= 2}
+    fusions = list(_block_matmul_fusions(lowered.compile().as_text()))
+    assert len(fusions) >= 25  # a layer's products, forward and backward
+    for name, result, body in fusions:
+        assert not any(" dynamic-update-slice(" in line for line in body), name
+        written = [dims for dims in re.findall(r"f32\[([\d,]+)\]", result)
+                   if dims in state_shapes]
+        assert len(written) <= 1, (name, result)

@@ -40,6 +40,21 @@ have shared experts beside the routed ones (`n_shared_experts`: one dense
 SwiGLU every token goes through, unweighted), and `seq_aux` takes the
 router's balance loss per sequence and sums it over the layers.
 
+A layer may also be ONE sublayer, `x + f(norm(x))` with `f` an operator or
+a feed-forward alone (`sublayer_types`, one name a layer: `nemotron_h`'s
+`hybrid_override_pattern`); a layer of `layer_types` is two of them, and
+periods are found over whichever the stack is made of. The fourth operator
+is the Mamba-2 mixer (`"mamba2"`; arXiv:2405.21060): one projection to a
+gate, to x, B and C and to a step size a head, a short causal convolution
+over x, B and C, the selective state-space scan in its chunked form
+(`ops/ssd.py`), the gate before a grouped RMSNorm, and the output
+projection. Beside it: plain attention that does not rotate (`rope=False`;
+the mixers carry position) at heads of their own width (`d_head`), the
+ungated feed-forward `W_down relu(W_up x)^2` (`ff_activation="relu2"`) for
+dense, shared and routed experts alike, a shared expert of its own width
+(`d_ff_shared`), and `routed_scaling_factor` on the chosen experts'
+weights. Those are Nemotron-3-Nano's (`nemotron_h`).
+
 With `remat` each block runs under `jax.checkpoint`: its input is kept and
 its values are made again in the backward pass, but for the named ones
 (`checkpoint_name`) that `make_train_step`'s step finds room for on the
@@ -61,7 +76,8 @@ Parallelism (ray_tpu.parallel.mesh axes):
 Capability analog of what the reference reaches only through integrations
 (SURVEY §5: it ships no native SP); here it is native. Cells that train it:
 `mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k`,
-`lfm2moe.tokens8k`, `dsv2lite.tokens8k` (BENCHMARK.json).
+`lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`
+(BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -79,6 +95,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
+from ray_tpu.ops.ssd import ssd
 from ray_tpu.ops.fused import (
     _own_buffer,
     _own_cotangent,
@@ -147,6 +164,31 @@ class TransformerConfig:
     # the balance loss per sequence, summed over the layers (else over the
     # batch, mean over the layers)
     seq_aux: bool = False
+    # one sublayer a layer, `x + f(norm(x))`: an operator ("mamba2" |
+    # "full_attention" | "conv" | "latent_attention") or a feed-forward
+    # ("dense_ff" | "routed_ff") alone; () => `layer_types`, whose every
+    # layer is two sublayers, an operator and then a feed-forward
+    sublayer_types: Tuple[str, ...] = ()
+    d_head: Optional[int] = None  # a head's width; None => d_model / n_heads
+    rope: bool = True  # plain attention rotates q and k by position
+    # "swiglu": silu(gate) * up; "relu2": relu(up)^2, ungated (no `w_gate`):
+    # the dense, the shared and the routed experts' alike
+    ff_activation: str = "swiglu"
+    routed_scaling_factor: float = 1.0  # on the chosen experts' weights
+    d_ff_shared: Optional[int] = None  # None => n_shared_experts * ff_dim
+    # the Mamba-2 mixer (arXiv:2405.21060), by config.json's own keys:
+    # `mamba_num_heads` heads of `mamba_head_dim` channels, a state of
+    # `ssm_state_size` a channel, `n_groups` groups of B and C, `conv_kernel`
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    mamba_conv_taps: int = 4
+    ssd_chunk: int = 128  # tokens a chunk of the scan (`ops/ssd.py`)
+    # dt's initial range and floor: `time_step_min`, `_max`, `_floor`
+    mamba_dt_init: Tuple[float, float, float] = (1e-3, 0.1, 1e-4)
+    # the mixers' output projections start divided by sqrt(n_layers)
+    rescale_prenorm_residual: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -154,7 +196,31 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def shared_dim(self) -> int:
+        return self.d_ff_shared or self.n_shared_experts * self.ff_dim
+
+    @property
+    def gated(self) -> bool:
+        if self.ff_activation not in ("swiglu", "relu2"):
+            raise ValueError(f"ff_activation {self.ff_activation!r}")
+        return self.ff_activation == "swiglu"
+
+    @property
+    def ff_matrices(self) -> int:
+        """A feed-forward's weight matrices: gate, up and down, or two."""
+        return 3 if self.gated else 2
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """The channels the mixer's convolution runs over: x, B and C."""
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def ff_dim(self) -> int:
@@ -169,6 +235,16 @@ class TransformerConfig:
     @property
     def layers(self) -> Tuple["LayerKind", ...]:
         """Every layer's kind, first to last."""
+        if self.sublayer_types:
+            if len(self.sublayer_types) != self.n_layers:
+                raise ValueError(
+                    f"sublayer_types names {len(self.sublayer_types)} layers, "
+                    f"n_layers is {self.n_layers}")
+            return tuple(
+                LayerKind(None, name == "routed_ff", True)
+                if name in ("dense_ff", "routed_ff")
+                else LayerKind(name, False, False)
+                for name in self.sublayer_types)
         types = self.layer_types or ("full_attention",) * self.n_layers
         if len(types) != self.n_layers:
             raise ValueError(
@@ -184,8 +260,11 @@ class TransformerConfig:
 
 
 class LayerKind(NamedTuple):
-    op: str        # "full_attention" | "conv" | "latent_attention"
-    routed: bool   # the feed-forward: routed experts, or dense
+    # "full_attention" | "conv" | "latent_attention" | "mamba2"; None: the
+    # layer is a feed-forward alone
+    op: Optional[str]
+    routed: bool     # the feed-forward: routed experts, or dense
+    ff: bool = True  # False: the layer is an operator alone
 
 
 class Segment(NamedTuple):
@@ -196,14 +275,18 @@ class Segment(NamedTuple):
 
 def segments(cfg: TransformerConfig) -> List[Segment]:
     """The stack as runs of whole periods: it is cut where the feed-forward
-    changes kind, and each run is as many repetitions of its shortest period
+    changes kind (a layer that is an operator alone stays in the run it
+    stands in), and each run is as many repetitions of its shortest period
     as make it up (one repetition of all of it, if it has none shorter)."""
     kinds = cfg.layers
-    runs, start = [], 0
-    for i in range(1, len(kinds) + 1):
-        if i == len(kinds) or kinds[i].routed != kinds[start].routed:
+    runs, start, routed = [], 0, None
+    for i, kind in enumerate(kinds):
+        if kind.ff and routed not in (None, kind.routed):
             runs.append(kinds[start:i])
             start = i
+        if kind.ff:
+            routed = kind.routed
+    runs.append(kinds[start:])
     out = []
     for run in runs:
         n = len(run)
@@ -219,11 +302,43 @@ def _dense(key, shape, fan_in):
     return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
 
 
+def _mamba_init(key, cfg: TransformerConfig, L: int):
+    """`L` Mamba-2 mixers: `A = -exp(A_log)` starts uniform in [-16, -1],
+    `softplus(dt_bias)` log-uniform in `mamba_dt_init`'s range and no less
+    than its floor, the skip `D` at 1 (the published initialiser's)."""
+    d, H = cfg.d_model, cfg.mamba_heads
+    inner, conv = cfg.mamba_inner, cfg.mamba_conv_dim
+    taps = cfg.mamba_conv_taps
+    k_in, k_conv, k_a, k_dt, k_out = jax.random.split(key, 5)
+    dt_min, dt_max, dt_floor = cfg.mamba_dt_init
+    dt = jnp.maximum(dt_floor, jnp.exp(jax.random.uniform(
+        k_dt, (L, H), jnp.float32, math.log(dt_min), math.log(dt_max))))
+    w_out = _dense(k_out, (L, inner, d), inner)
+    if cfg.rescale_prenorm_residual:
+        w_out = w_out / math.sqrt(cfg.n_layers)
+    return {
+        "mixer_norm": jnp.ones((L, d), jnp.float32),
+        # the gate z, then x, B and C (the convolution's channels), then dt
+        "w_in": _dense(k_in, (L, d, inner + conv + H), d),
+        "conv_w": _dense(k_conv, (L, taps, conv), taps),
+        "conv_b": jnp.zeros((L, conv), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus's inverse
+        "A_log": jnp.log(jax.random.uniform(k_a, (L, H), jnp.float32, 1.0, 16.0)),
+        "D": jnp.ones((L, H), jnp.float32),
+        "norm": jnp.ones((L, inner), jnp.float32),
+        "w_out": w_out,
+    }
+
+
 def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
     """`L` layers of one kind, every leaf stacked on a leading layer axis."""
     d, h, hk, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
     ks = jax.random.split(k_blk, 7)
-    if kind.op == "conv":
+    if kind.op is None:
+        blocks = {}
+    elif kind.op == "mamba2":
+        blocks = _mamba_init(ks[0], cfg, L)
+    elif kind.op == "conv":
         blocks = {
             "conv_norm": jnp.ones((L, d), jnp.float32),
             "conv_in": _dense(ks[0], (L, d, 3 * d), d),
@@ -251,14 +366,21 @@ def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
             "wv": _dense(ks[2], (L, d, hk * dh), d),
             "wo": _dense(ks[3], (L, h * dh, d), h * dh),
         }
+    if cfg.qk_norm and kind.op == "full_attention":
+        per_head = cfg.qk_norm == "head"
+        blocks["q_norm"] = jnp.ones((L, dh if per_head else h * dh), jnp.float32)
+        blocks["k_norm"] = jnp.ones((L, dh if per_head else hk * dh), jnp.float32)
+    if not kind.ff:
+        return blocks
     # a routed feed-forward stacks its (held) experts behind the layer axis
     ff = (L, cfg.held[1]) if kind.routed else (L,)
     f = cfg.ff_dim
     if cfg.n_experts and not kind.routed and cfg.d_ff_dense is not None:
         f = cfg.d_ff_dense
+    blocks["mlp_norm"] = jnp.ones((L, d), jnp.float32)
+    if cfg.gated:
+        blocks["w_gate"] = _dense(ks[4], (*ff, d, f), d)
     blocks.update({
-        "mlp_norm": jnp.ones((L, d), jnp.float32),
-        "w_gate": _dense(ks[4], (*ff, d, f), d),
         "w_up": _dense(ks[5], (*ff, d, f), d),
         "w_down": _dense(ks[6], (*ff, f, d), f),
     })
@@ -266,17 +388,14 @@ def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
         blocks["router"] = _dense(
             jax.random.fold_in(k_blk, 7), (L, d, cfg.n_experts), d)
         if cfg.n_shared_experts:
-            fs = cfg.n_shared_experts * f
+            fs = cfg.shared_dim
             kg, ku, kd = jax.random.split(jax.random.fold_in(k_blk, 8), 3)
+            if cfg.gated:
+                blocks["ws_gate"] = _dense(kg, (L, d, fs), d)
             blocks.update({
-                "ws_gate": _dense(kg, (L, d, fs), d),
                 "ws_up": _dense(ku, (L, d, fs), d),
                 "ws_down": _dense(kd, (L, fs, d), fs),
             })
-    if cfg.qk_norm and kind.op == "full_attention":
-        per_head = cfg.qk_norm == "head"
-        blocks["q_norm"] = jnp.ones((L, dh if per_head else h * dh), jnp.float32)
-        blocks["k_norm"] = jnp.ones((L, dh if per_head else hk * dh), jnp.float32)
     return blocks
 
 
@@ -364,9 +483,26 @@ _CONV_AXES = {
     "conv_w": ("layers", None, None),
     "conv_out": ("layers", None, "embed"),
 }
+# cut along the heads where a leaf is the heads'; `w_in`'s columns are
+# three streams that are split after the product, and the convolution runs
+# over x, B and C together: neither is cut
+_MAMBA_AXES = {
+    "mixer_norm": ("layers", None),
+    "w_in": ("layers", "embed", None),
+    "conv_w": ("layers", None, None),
+    "conv_b": ("layers", None),
+    "dt_bias": ("layers", "heads"),
+    "A_log": ("layers", "heads"),
+    "D": ("layers", "heads"),
+    "norm": ("layers", "heads"),
+    "w_out": ("layers", "heads", "embed"),
+}
 _ATTENTION_KEYS = ("attn_norm", "wq", "wk", "wv", "wo")
-# the operators whose leaves take the place of full attention's
-_OPERATOR_AXES = {"conv": _CONV_AXES, "latent_attention": _LATENT_AXES}
+_FF_KEYS = ("mlp_norm", "w_gate", "w_up", "w_down")
+# the operators whose leaves take the place of full attention's; a layer
+# that is a feed-forward alone has none
+_OPERATOR_AXES = {"conv": _CONV_AXES, "latent_attention": _LATENT_AXES,
+                  "mamba2": _MAMBA_AXES, None: {}}
 
 
 def _block_axes(cfg: TransformerConfig, kind: LayerKind):
@@ -383,7 +519,10 @@ def _block_axes(cfg: TransformerConfig, kind: LayerKind):
         table.update(_ROUTED_AXES)
         if cfg.n_shared_experts:
             table.update(_SHARED_AXES)
-    return table
+    absent = () if kind.ff else _FF_KEYS
+    if not cfg.gated:  # an ungated feed-forward has no gate's weights
+        absent += ("w_gate", "ws_gate")
+    return {k: v for k, v in table.items() if k not in absent}
 
 
 def param_shardings(mesh, cfg: TransformerConfig):
@@ -519,7 +658,7 @@ def _kernel_impl(cfg: TransformerConfig) -> str:
 # the weights of a block's plain matmuls, every operator's and feed-forward's
 _MATMUL_WEIGHTS = (
     "wq", "wk", "wv", "wo", "wkv_a", "wkv_b", "conv_in", "conv_out",
-    "w_gate", "w_up", "w_down", "ws_gate", "ws_up", "ws_down",
+    "w_in", "w_out", "w_gate", "w_up", "w_down", "ws_gate", "ws_up", "ws_down",
 )
 _ROUTED_WEIGHTS = ("w_gate", "w_up", "w_down")  # `ops/moe.py` casts its own
 
@@ -562,6 +701,11 @@ def _own_weights(blk, dt, sliced: bool):
                       for name in own_buffer_weights(blk)}}
 
 
+def _periods(blk) -> int:
+    """The length of a block tree's leading layer axis."""
+    return jax.tree.leaves(blk)[0].shape[0]
+
+
 def _segment_trees(blocks):
     """`params["blocks"]` as its segments, each one tree per layer of its
     period: a model of one kind of layer is one segment of it."""
@@ -577,7 +721,7 @@ def own_buffers(blocks, dt) -> Tuple[int, int, int]:
     count = total = widest = 0
     for blk in (blk for blks in _segment_trees(blocks) for blk in blks):
         names = own_buffer_weights(blk)
-        periods = blk["mlp_norm"].shape[0]
+        periods = _periods(blk)
         layer = item * sum(math.prod(blk[name].shape[1:]) for name in names)
         each = 2 if periods == 1 else 1
         count += each * periods * len(names)
@@ -614,8 +758,9 @@ def _attention_layer(x, blk, positions, cfg: TransformerConfig,
             q, k = qk_norm(q, k)
         v = checkpoint_name(y @ blk["wv"].astype(dt), "attn_qkv").reshape(
             B, T, hk, dh)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        if cfg.rope:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
     with jax.named_scope("attention"):
         o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh, keep_ctx)
     with jax.named_scope("attn_out"):
@@ -692,7 +837,9 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
         probs, weights, index = moe.route(
             logits, cfg.experts_per_token, cfg.norm_topk_prob,
             score=cfg.router_score, bias=bias, eps=cfg.norm_topk_eps)
-        n_held = blk["w_gate"].shape[0]
+        if cfg.routed_scaling_factor != 1.0:
+            weights = weights * cfg.routed_scaling_factor
+        n_held = blk["w_down"].shape[0]
         share = n_held < cfg.n_experts
         slots = moe.sort_slots(index, cfg.n_experts,
                                (cfg.held[0], n_held) if share else None)
@@ -723,7 +870,8 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
 
     if share:  # names its own operations as below, a chunk of rows at a time
         out = moe.experts_of_share(
-            tokens, blk["w_gate"], blk["w_up"], blk["w_down"], weights, slots,
+            tokens, blk.get("w_gate"), blk["w_up"], blk["w_down"], weights,
+            slots,
             impl=impl, chunk=moe.held_chunk(
                 slots.order.shape[0], n_held, cfg.n_experts,
                 load_held_even=cfg.expert_bias))
@@ -733,12 +881,26 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
     with jax.named_scope("moe_experts"):
         gmm = partial(moe.grouped_matmul, group_sizes=slots.group_sizes,
                       impl=impl)
-        gate = jax.nn.silu(checkpoint_name(gmm(xs, blk["w_gate"]), "moe_gate"))
-        hidden = gate * checkpoint_name(gmm(xs, blk["w_up"]), "moe_up")
+        if "w_gate" in blk:
+            gate = jax.nn.silu(
+                checkpoint_name(gmm(xs, blk["w_gate"]), "moe_gate"))
+            hidden = gate * checkpoint_name(gmm(xs, blk["w_up"]), "moe_up")
+        else:
+            hidden = moe.relu2(checkpoint_name(gmm(xs, blk["w_up"]), "moe_up"))
     # names its own operations `moe_experts` and `moe_combine`, backward too
     out = moe.project_and_combine(hidden, blk["w_down"], weights, slots,
                                   impl=impl)
     return out.reshape(B, T, d), readings
+
+
+def _causal_taps(u, w):
+    """The causal convolution per channel of `u` [B, T, C] with taps `w`
+    [taps, C], `c_t = sum_i w_i u_{t - taps + 1 + i}`, `u` zero before the
+    sequence: `taps` shifted multiply-adds in u's dtype."""
+    taps = w.shape[0]
+    padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(w[i] * jax.lax.dynamic_slice_in_dim(padded, i, u.shape[1], 1)
+               for i in range(taps))
 
 
 def _short_conv(x, blk, cfg: TransformerConfig):
@@ -749,21 +911,67 @@ def _short_conv(x, blk, cfg: TransformerConfig):
     The convolution is `conv_taps` shifted multiply-adds in the compute
     dtype, which XLA fuses with the gates into one pass over [B, T, d]."""
     dt = cfg.dtype
-    taps = blk["conv_w"].shape[0]
     with jax.named_scope("conv_in"):
         y = fused_rmsnorm(x, blk["conv_norm"], eps=cfg.norm_eps)
         b, c, xs = jnp.split(
             checkpoint_name(y @ blk["conv_in"].astype(dt), "conv_in"), 3,
             axis=-1)
     with jax.named_scope("conv_gate"):
-        u = b * xs
-        w = blk["conv_w"].astype(dt)
-        padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
-        conv = sum(w[i] * jax.lax.dynamic_slice_in_dim(padded, i, u.shape[1], 1)
-                   for i in range(taps))
-        gated = c * conv
+        gated = c * _causal_taps(b * xs, blk["conv_w"].astype(dt))
     with jax.named_scope("conv_out"):
         return gated @ blk["conv_out"].astype(dt)
+
+
+def _mamba_mixer(x, blk, cfg: TransformerConfig):
+    """The Mamba-2 mixer on `x` [B, T, d] (arXiv:2405.21060, as
+    `nemotron_h` holds it): `[z | xBC | dt] = norm(x) W_in`; a causal
+    convolution of `mamba_conv_taps` taps per channel over x, B and C with a
+    bias, then silu; `dt = softplus(dt + dt_bias)` and `A = -exp(A_log)` a
+    head, in float32; the scan (`ops/ssd.py`); the gate before the norm,
+    `GroupRMSNorm(y * silu(z))` over `ssm_groups` groups with one learned
+    scale; `W_out`."""
+    B, T, d = x.shape
+    H, P, G, N = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state)
+    inner, dt_ = cfg.mamba_inner, cfg.dtype
+    with jax.named_scope("mamba_in"):
+        u = fused_rmsnorm(x, blk["mixer_norm"], eps=cfg.norm_eps)
+        z, xbc, dt = jnp.split(
+            checkpoint_name(u @ blk["w_in"].astype(dt_), "mamba_in"),
+            (inner, inner + cfg.mamba_conv_dim), axis=-1)
+    with jax.named_scope("mamba_conv"):
+        xbc = jax.nn.silu(_causal_taps(xbc, blk["conv_w"].astype(dt_))
+                          + blk["conv_b"].astype(dt_))
+        xs, b, c = jnp.split(xbc, (inner, inner + G * N), axis=-1)
+    with jax.named_scope("ssd"):
+        step = jax.nn.softplus(
+            dt.astype(jnp.float32) + blk["dt_bias"].astype(jnp.float32))
+        y = checkpoint_name(ssd(
+            xs.reshape(B, T, H, P), step,
+            -jnp.exp(blk["A_log"].astype(jnp.float32)),
+            b.reshape(B, T, G, N), c.reshape(B, T, G, N), blk["D"],
+            chunk=cfg.ssd_chunk), "ssd_out").reshape(B, T, inner)
+    with jax.named_scope("mamba_norm"):
+        gated = (y * jax.nn.silu(z)).reshape(B, T, G, inner // G)
+        y = fused_rmsnorm(gated, blk["norm"].reshape(G, inner // G),
+                          eps=cfg.norm_eps).reshape(B, T, inner)
+    with jax.named_scope("mamba_out"):
+        return y @ blk["w_out"].astype(dt_)
+
+
+def _feed_forward(y, blk, dt, names, prefix: str = "w"):
+    """A dense feed-forward on normed `y`: `(silu(y W_gate) * y W_up)
+    W_down`, or without a gate's weights `relu(y W_up)^2 W_down`. `names` is
+    the products' `checkpoint_name`s, `prefix` "w" or, for the shared
+    experts' weights, "ws"."""
+    up = prefix + "_up"
+    if prefix + "_gate" in blk:
+        gate = jax.nn.silu(checkpoint_name(
+            y @ blk[prefix + "_gate"].astype(dt), names[0]))
+        hidden = gate * checkpoint_name(y @ blk[up].astype(dt), names[1])
+    else:
+        hidden = moe.relu2(checkpoint_name(y @ blk[up].astype(dt), names[1]))
+    return hidden @ blk[prefix + "_down"].astype(dt)
 
 
 def _block(x, blk, positions, bias, cfg: TransformerConfig,
@@ -771,49 +979,50 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig,
            keep_ctx: bool = False, sliced: bool = False):
     """One block: (x, the routed feed-forward's readings or None). What the
     block is, its parameters say: a short convolution where it has
-    `conv_in`, latent attention where it has `wkv_a`, a routed feed-forward
-    where it has a `router`, shared experts beside it where it has
-    `ws_gate`. `keep_ctx`: the attention kernel names its backward's
-    residuals `attn_ctx`. `sliced`: `blk` is one of several periods of a
-    scanned stack (`_own_weights`)."""
+    `conv_in`, latent attention where it has `wkv_a`, a Mamba-2 mixer where
+    it has `A_log`, attention where it has `wk`, and no operator otherwise;
+    then a feed-forward where it has an `mlp_norm`: routed where it has a
+    `router`, shared experts beside it where it has `ws_up`. `keep_ctx`:
+    the attention kernel names its backward's residuals `attn_ctx`.
+    `sliced`: `blk` is one of several periods of a scanned stack
+    (`_own_weights`)."""
     dt = cfg.dtype
     blk = _own_weights(blk, dt, sliced)
 
     # The scopes name the step's device work in a profiler trace
     # (docs/observability.md, "Device scopes"); they are metadata only.
-    if "conv_in" in blk or "wkv_a" in blk:
+    if "conv_in" in blk or "wkv_a" in blk or "A_log" in blk:
         if seq_axis is not None:
             raise NotImplementedError(
-                "the short convolution and latent attention are not mapped "
-                "over a sequence axis")
+                "the short convolution, latent attention and the Mamba-2 "
+                "mixer are not mapped over a sequence axis")
     if "conv_in" in blk:
         with jax.named_scope("short_conv"):
             x = checkpoint_name(x + _short_conv(x, blk, cfg), "conv_res")
     elif "wkv_a" in blk:
         with jax.named_scope("latent_attention"):
             x = _latent_attention_layer(x, blk, positions, cfg, mesh, keep_ctx)
-    else:
+    elif "A_log" in blk:
+        with jax.named_scope("mamba"):
+            x = x + _mamba_mixer(x, blk, cfg)
+    elif "wk" in blk:
         x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size, mesh,
                              keep_ctx)
 
     readings = None
+    if "mlp_norm" not in blk:
+        return x, readings
     with jax.named_scope("mlp"):
         y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
         if "router" in blk:
             routed, readings = _routed_ffn(y, blk, cfg, mesh, bias)
-            if "ws_gate" in blk:  # every token, unweighted, whole on a share
+            if "ws_up" in blk:  # every token, unweighted, whole on a share
                 with jax.named_scope("moe_shared"):
-                    gate = jax.nn.silu(checkpoint_name(
-                        y @ blk["ws_gate"].astype(dt), "shared_gate"))
-                    up = checkpoint_name(
-                        y @ blk["ws_up"].astype(dt), "shared_up")
-                    routed = routed + (gate * up) @ blk["ws_down"].astype(dt)
+                    routed = routed + _feed_forward(
+                        y, blk, dt, ("shared_gate", "shared_up"), "ws")
             x = x + routed
         else:
-            gate = jax.nn.silu(
-                checkpoint_name(y @ blk["w_gate"].astype(dt), "mlp_gate"))
-            up = checkpoint_name(y @ blk["w_up"].astype(dt), "mlp_up")
-            x = x + (gate * up) @ blk["w_down"].astype(dt)
+            x = x + _feed_forward(y, blk, dt, ("mlp_gate", "mlp_up"))
     return x, readings
 
 
@@ -871,7 +1080,7 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
 
     readings, routed_before = [], 0
     for blks in _segment_trees(params["blocks"]):
-        periods = blks[0]["mlp_norm"].shape[0]
+        periods = _periods(blks[0])
         # this segment's rows of the bias, one [periods, E] per routed layer
         # of its period
         biases = [None] * len(blks)
@@ -984,6 +1193,8 @@ _SAVE_ORDER = (
     "attn_qkv",   # the q, k, v products, before QK-norm, RoPE and GQA's repeat
                   # (latent attention: out of `wq`, `wkv_a` and `wkv_b`)
     "conv_in",    # the three streams out of `conv_in`
+    "mamba_in",   # the mixer's gate, x, B, C and dt out of `w_in`
+    "ssd_out",    # the scan's output, before the gate and the norm
     "moe_gate",   # the experts' gate product [slots, f]
     "moe_up",     # and their up product
     "shared_gate",  # the shared experts' gate product, before the silu
@@ -1006,7 +1217,15 @@ def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
     d, h, hk, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
     k, f = cfg.experts_per_token, cfg.ff_dim
     item = jnp.dtype(cfg.dtype).itemsize
-    if kind.op == "conv":
+    mats = cfg.ff_matrices
+    if kind.op is None:
+        widths, params = {}, 0
+    elif kind.op == "mamba2":
+        inner = cfg.mamba_inner
+        wide = inner + cfg.mamba_conv_dim + cfg.mamba_heads
+        widths = {"mamba_in": wide, "ssd_out": inner}
+        params = d * wide + inner * d
+    elif kind.op == "conv":
         widths = {"conv_res": d, "conv_in": 3 * d}
         params = 4 * d * d
     elif kind.op == "latent_attention":
@@ -1029,21 +1248,24 @@ def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
             "attn_qkv": (h + 2 * hk) * dh,
         }
         params = d * (h + 2 * hk) * dh + h * dh * d
+    if not kind.ff:
+        return widths, params
+    gates = ("gate",) if cfg.gated else ()
     if not kind.routed:
         f = cfg.d_ff_dense if cfg.n_experts and cfg.d_ff_dense else f
-        widths.update(mlp_gate=f, mlp_up=f)
-        params += 3 * d * f
+        widths.update({"mlp_" + name: f for name in (*gates, "up")})
+        params += mats * d * f
     else:
-        params += d * cfg.n_experts + cfg.held[1] * 3 * d * f
+        params += d * cfg.n_experts + cfg.held[1] * mats * d * f
         # a layer that holds a share of the experts is one operation whose
         # backward makes its rows again chunk by chunk: it has no names
         if cfg.held[1] == cfg.n_experts:
-            widths.update(moe_slots=2 * k * 4 // item, moe_gate=k * f,
-                          moe_up=k * f)
+            widths["moe_slots"] = 2 * k * 4 // item
+            widths.update({"moe_" + name: k * f for name in (*gates, "up")})
         if cfg.n_shared_experts:  # dense work on every token, share or not
-            fs = cfg.n_shared_experts * f
-            widths.update(shared_gate=fs, shared_up=fs)
-            params += 3 * d * fs
+            fs = cfg.shared_dim
+            widths.update({"shared_" + name: fs for name in (*gates, "up")})
+            params += mats * d * fs
     return widths, params
 
 
@@ -1088,23 +1310,32 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     block = 0
     for kind in set(cfg.layers):
         widths, params = _layer_widths(cfg, kind)
-        # the two normed inputs and the stream's cotangent
-        width = sum(widths.values()) + 3 * d
-        if kind.op == "conv":
+        # the normed inputs (an operator's, a feed-forward's) and the
+        # stream's cotangent
+        width = sum(widths.values()) + (
+            3 if kind.op is not None and kind.ff else 2) * d
+        if kind.op == "mamba2":
+            # the convolution's sum and its silu, the gated output and the
+            # normed one; and the scan's [H, Q, Q] a chunk, Q values a token
+            # and head: the decays and their gradient in float32, the
+            # masked scores and theirs in the compute dtype and in float32
+            width += 2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner
+            width += cfg.mamba_heads * cfg.ssd_chunk * (4 * 4 + 2 * item) // item
+        elif kind.op == "conv":
             width += 3 * d  # the gate's product, the taps' sum, the gated
         elif kind.op == "latent_attention":  # q and k at their own width
             qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
             width += (h * (2 * _tile_lanes(qk) + _tile_lanes(cfg.v_head_dim))
                       + 2 * h * 128 * 4 // item)
-        else:
+        elif kind.op is not None:
             width += (3 * h * _tile_lanes(cfg.head_dim)
                       + 2 * h * 128 * 4 // item)
         if kind.routed:
             width += (cfg.experts_per_token * (d + cfg.ff_dim)
-                      if "moe_gate" in widths else 0)
-            width += widths.get("shared_gate", 0)  # the shared hidden product
-        else:
-            width += widths["mlp_gate"]
+                      if "moe_up" in widths else 0)
+            width += widths.get("shared_up", 0)  # the shared hidden product
+        elif kind.ff:
+            width += widths["mlp_up"]
         weights = params * item + (params * (item + 4) if sharded else 0)
         block = max(block, tokens * width * item + weights)
     unembed = cfg.vocab_size * d * item * param_bytes // whole
@@ -1290,18 +1521,28 @@ def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
     d, f = cfg.d_model, cfg.ff_dim
     h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     dense_f = f if cfg.d_ff_dense is None or not cfg.n_experts else cfg.d_ff_dense
+    mats = cfg.ff_matrices
     # active parameters: the router and the held among a token's experts
     routed = 2 * d * cfg.n_experts + (
         cfg.experts_per_token * cfg.held[1] / max(cfg.n_experts, 1)
-        * 2 * 3 * d * f)
+        * 2 * mats * d * f)
     # Causal attention: token t attends to t+1 keys, so the average query
     # sees (seq_len + 1) / 2 positions; qk^T and pv each cost 2*h*dh flops
     # per (query, key) pair. The flash kernel really skips the masked-out
     # tiles, so crediting full seq_len here would overcount ~2x.
-    routed += 2 * 3 * d * cfg.n_shared_experts * f  # every token, whole
+    if cfg.n_shared_experts:
+        routed += 2 * mats * d * cfg.shared_dim  # every token, whole
     matmul = attn = 0.0
     for kind in cfg.layers:
-        if kind.op == "conv":
+        if kind.op == "mamba2":
+            inner, H, N = cfg.mamba_inner, cfg.mamba_heads, cfg.ssm_state
+            matmul += 2 * d * (inner + cfg.mamba_conv_dim + H) + 2 * inner * d
+            # the scan as it is computed, whole chunks: the scores of a
+            # chunk, their product with the inputs, the chunk's state and
+            # the state's contribution
+            matmul += (2 * cfg.ssd_chunk * (cfg.ssm_groups * N + inner)
+                       + 2 * 2 * inner * N)
+        elif kind.op == "conv":
             matmul += 2 * d * 3 * d + 2 * d * d
         elif kind.op == "latent_attention":
             r, dv = cfg.kv_lora_rank, cfg.v_head_dim
@@ -1310,10 +1551,11 @@ def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
                            + r * h * (cfg.qk_nope_head_dim + dv) + h * dv * d)
             # scores over q and k's width, the values over v's
             attn += 2 * h * (qk + dv) * ((seq_len + 1) / 2)
-        else:
+        elif kind.op is not None:
             matmul += 2 * d * (h * dh + 2 * hk * dh) + 2 * h * dh * d
             attn += 2 * 2 * h * dh * ((seq_len + 1) / 2)
-        matmul += routed if kind.routed else 2 * 3 * d * dense_f
+        if kind.ff:
+            matmul += routed if kind.routed else 2 * mats * d * dense_f
     embed = 2 * d * cfg.vocab_size
     return matmul, attn, embed
 

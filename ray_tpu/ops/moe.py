@@ -20,6 +20,7 @@ imbalance, and the group sizes always sum to `T x k`.
                                      the last two as one operation
     experts_of_share(tokens, w_gate, w_up, w_down, weights, slots)
                                      the whole layer over a share's rows
+                                     (`w_gate` None: ungated `relu2` experts)
 
 `grouped_matmul` is a matmul whose row groups go to different weights. On a
 TPU it is two Pallas kernels under a `custom_vjp`, after the grouped matmul
@@ -783,10 +784,16 @@ def _chunk_of(slots: Slots, order, i, chunk: int) -> Slots:
                  slots.inverse - lo, sizes)
 
 
+def relu2(x):
+    """`relu(x)^2`: the ungated feed-forward's activation."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def _experts_on_chunk(i, tokens, w_gate, w_up, w_down, weights, slots, order,
                       chunk, impl):
     """One chunk of the held rows through its experts and back to the
-    tokens, [T, d]: today's layer on buffers of `chunk` rows."""
+    tokens, [T, d]: today's layer on buffers of `chunk` rows. Without a
+    `w_gate` the experts are ungated, `relu2` between the two products."""
     part = _chunk_of(slots, order, i, chunk)
     rows = part.group_sizes.sum()
     with jax.named_scope("moe_dispatch"):
@@ -794,7 +801,8 @@ def _experts_on_chunk(i, tokens, w_gate, w_up, w_down, weights, slots, order,
     with jax.named_scope("moe_experts"):
         gmm = functools.partial(
             grouped_matmul, group_sizes=part.group_sizes, impl=impl, tail=True)
-        hidden = jax.nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
+        hidden = (relu2(gmm(xs, w_up)) if w_gate is None
+                  else jax.nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up))
     return project_and_combine(hidden, w_down, weights, part, impl=impl,
                                rows=rows)
 
@@ -839,13 +847,15 @@ def _experts_of_share_bwd(chunk, impl, res, dout):
         _, pull = jax.vjp(
             lambda *a: _experts_on_chunk(i, *a, slots, order, chunk, impl),
             *args)
-        return tuple(g + d.astype(jnp.float32)
-                     for g, d in zip(grads, pull(dout)))
+        return jax.tree.map(
+            lambda g, d: g + d.astype(jnp.float32), grads, pull(dout))
 
+    # an ungated layer's `w_gate` is None: a tree of no leaves, all through
     grads = jax.lax.fori_loop(
         0, n_chunks, add,
-        tuple(jnp.zeros(a.shape, jnp.float32) for a in args))
-    return (*(g.astype(a.dtype) for g, a in zip(grads, args)), None)
+        jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), tuple(args)))
+    return (*jax.tree.map(lambda g, a: g.astype(a.dtype), grads, tuple(args)),
+            None)
 
 
 _experts_of_share.defvjp(_experts_of_share_fwd, _experts_of_share_bwd)
@@ -854,7 +864,8 @@ _experts_of_share.defvjp(_experts_of_share_fwd, _experts_of_share_bwd)
 def experts_of_share(tokens, w_gate, w_up, w_down, weights, slots: Slots, *,
                      chunk: int, impl: str = "auto"):
     """The routed feed-forward of a layer that holds a share of the experts
-    (`sort_slots(index, E, (first, n))`; `w_*` are the n held experts'):
+    (`sort_slots(index, E, (first, n))`; `w_*` are the n held experts';
+    `w_gate` None for ungated experts, `relu(x W_up)^2 W_down`):
     for every token the weighted sum over those of its experts that are
     held, [T, d]; a token none of whose experts is held gets zero.
 

@@ -40,6 +40,9 @@ LFM2_SCOPES = {"short_conv", "conv_in", "conv_gate", "conv_out", "expert_bias"}
 # latent attention's parts inside `latent_attention` (beside `attn_qkv`,
 # `attention` and `attn_out`), and the shared experts inside `mlp`
 DSV2_SCOPES = {"latent_attention", "kv_down", "kv_up", "moe_shared"}
+# the Mamba-2 mixer's parts inside `mamba`, and the scan's inside `ssd`
+NEMOTRON_SCOPES = {"mamba", "mamba_in", "mamba_conv", "ssd", "ssd_chunk",
+                   "ssd_state", "ssd_out", "mamba_norm", "mamba_out"}
 VOCAB = 96  # the tiny steps' one dimension of this size: it finds the head
 
 
@@ -122,6 +125,26 @@ def lowered_dsv2_step():
         moe._ROW_TILE = row_tile
 
 
+def lowered_nemotron_step():
+    """One sublayer a layer: mixers, attention without rotation and routed
+    layers of ungated experts of which a share is held, one shared expert
+    beside them."""
+    row_tile = moe._ROW_TILE
+    moe._ROW_TILE = 8
+    try:
+        return lowered_transformer_step(
+            n_layers=5, d_head=16, rope=False, sublayer_types=(
+                "mamba2", "routed_ff", "mamba2", "full_attention", "routed_ff"),
+            ff_activation="relu2", n_experts=4, experts_per_token=2,
+            experts_held=(1, 1), n_shared_experts=1, d_ff_shared=48,
+            router_score="sigmoid", expert_bias=True, norm_topk_prob=True,
+            routed_scaling_factor=2.5, router_aux_loss_coef=1e-4,
+            router_z_loss_coef=0.0, mamba_heads=4, mamba_head_dim=8,
+            ssm_state=16, ssm_groups=2, ssd_chunk=8)
+    finally:
+        moe._ROW_TILE = row_tile
+
+
 def lowered_moe_kernels():
     x = jax.ShapeDtypeStruct((32, 16), jnp.float32)
     w = jax.ShapeDtypeStruct((4, 16, 8), jnp.float32)
@@ -184,10 +207,16 @@ FAMILIES = {
                  TRANSFORMER_SCOPES | MOE_SCOPES | LFM2_SCOPES),
     "deepseek_v2": (lowered_dsv2_step,
                     TRANSFORMER_SCOPES | (MOE_SCOPES - {"qk_norm"}) | DSV2_SCOPES),
+    "nemotron_h": (lowered_nemotron_step,
+                   TRANSFORMER_SCOPES | (MOE_SCOPES - {"qk_norm"})
+                   | {"moe_shared", "expert_bias"} | NEMOTRON_SCOPES),
     "resnet": (lowered_resnet_step, RESNET_SCOPES),
 }
-# the scopes that one family alone has
-OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES}
+# the scopes that one family alone has, but for those that another family
+# has of them
+OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES,
+              "nemotron_h": NEMOTRON_SCOPES}
+ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"}}
 
 
 @pytest.fixture(scope="module")
@@ -215,7 +244,8 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
         assert any(re.search(r"attention/.*transpose", s) for s in stacks[family])
     for other, own in OWN_SCOPES.items():
         if family != other:
-            assert not own & components(stacks[family])
+            assert not (own - ALSO_HAS.get(family, set())) & components(
+                stacks[family])
     if family == "transformer":  # the dense step names nothing of the routed
         assert not (MOE_SCOPES | MOE_KERNELS) & components(stacks[family])
     elif family == "lfm2_moe":
@@ -250,6 +280,33 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
         assert any(s.startswith("mlp/while/body/moe_experts")
                    for s in stacks[family])
         assert any(s.startswith("mlp/moe_router/") for s in stacks[family])
+    elif family == "nemotron_h":
+        # the mixer's five parts inside `mamba`, forward, made again, and
+        # backward; the scan's three inside `ssd`
+        for inner in ("mamba_in", "mamba_conv", "ssd", "mamba_norm", "mamba_out"):
+            for prefix in ("", "checkpoint/rematted_computation/", "checkpoint/"):
+                if (inner, prefix[11:]) == ("mamba_out", "rematted_computation/"):
+                    continue  # the backward needs its operands, not its product
+                assert any(s.startswith(f"{prefix}mamba/{inner}/")
+                           for s in stacks[family]), (inner, prefix)
+        for inner in ("ssd_chunk", "ssd_state", "ssd_out"):
+            assert any(s.startswith(f"mamba/ssd/{inner}/")
+                       for s in stacks[family]), inner
+            assert any(s.startswith(f"checkpoint/mamba/ssd/{inner}/")
+                       for s in stacks[family]), inner
+        # the recurrence over the chunks' states is a loop inside `ssd_state`
+        assert any(re.match(r"checkpoint/(rematted_computation/)?mamba/ssd/"
+                            r"ssd_state/while/body/", s) for s in stacks[family])
+        # a routed layer alone keeps `mlp` and its pieces; the shared expert
+        # beside the share's loop; attention keeps its three scopes
+        assert any(s.startswith("mlp/moe_router/") for s in stacks[family])
+        assert any(s.startswith("mlp/while/body/moe_experts")
+                   for s in stacks[family])
+        assert any(s.startswith("mlp/moe_shared/dot_general")
+                   for s in stacks[family])
+        assert any(s.endswith("expert_bias/sign") for s in stacks[family])
+        assert any(s.startswith("attn_qkv/") for s in stacks[family])
+        assert any(s.startswith("attn_out/") for s in stacks[family])
     elif family == "moe_transformer":
         # the routed feed-forward stays under `mlp`, QK-norm under `attn_qkv`
         for inner in sorted(MOE_SCOPES - {"qk_norm"}):
@@ -342,7 +399,7 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
     from chipbench import scopes
 
     program = (TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
-               | DSV2_SCOPES)
+               | DSV2_SCOPES | NEMOTRON_SCOPES)
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     # the benchmark's list is PR 25's three until a `benchmark` issue adds
     # the fourth (PERF.md section 7); its time share matches by prefix
@@ -356,8 +413,9 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
         for key in ("scope", "kernel"):
             if key in params:
                 assert params[key] in program, path
-                elsewhere = any(params[key] in own and family != other
-                                for other, own in OWN_SCOPES.items())
+                elsewhere = any(
+                    params[key] in own - ALSO_HAS.get(family, set())
+                    and family != other for other, own in OWN_SCOPES.items())
                 if path.endswith(suffix) and not elsewhere:
                     assert params[key] in in_family, path
                     named += 1

@@ -95,7 +95,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
-from ray_tpu.ops.ssd import ssd
+from ray_tpu.ops.ssd import scan_untiled, ssd
 from ray_tpu.ops.fused import (
     _own_buffer,
     _own_cotangent,
@@ -950,7 +950,8 @@ def _mamba_mixer(x, blk, cfg: TransformerConfig):
             xs.reshape(B, T, H, P), step,
             -jnp.exp(blk["A_log"].astype(jnp.float32)),
             b.reshape(B, T, G, N), c.reshape(B, T, G, N), blk["D"],
-            chunk=cfg.ssd_chunk), "ssd_out").reshape(B, T, inner)
+            chunk=cfg.ssd_chunk, impl=_kernel_impl(cfg)),
+            "ssd_out").reshape(B, T, inner)
     with jax.named_scope("mamba_norm"):
         gated = (y * jax.nn.silu(z)).reshape(B, T, G, inner // G)
         y = fused_rmsnorm(gated, blk["norm"].reshape(G, inner // G),
@@ -1280,6 +1281,25 @@ def _saved_bytes(cfg: TransformerConfig, tokens: int) -> Dict[str, int]:
     return {name: total[name] for name in _SAVE_ORDER if name in total}
 
 
+def _scan_bytes_per_token(cfg: TransformerConfig) -> int:
+    """Bytes a token that a mixer's scan holds in HBM in its backward, by
+    the path `ssd` takes (`ops/ssd.py`). `jax.numpy`: the [H, Q, Q] arrays
+    of a chunk, Q values a token and head: the decays and their gradient in
+    float32, the masked scores and theirs in the compute dtype and in
+    float32. The kernels keep those in VMEM. What they leave is each
+    chunk's entering state, `H P N / Q` float32 values a token, and dt and
+    cum with their cotangents: as columns `[b, G, T, R]` float32, which lie
+    in HBM at a tile's 128 lanes a group (the four the kernels read and
+    write and three that the transposes from and to `[b, T, H]` make),
+    and as rows and plain `[b, T, H]` arrays, two `H` wide all told."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    H, Q, G = cfg.mamba_heads, cfg.ssd_chunk, cfg.ssm_groups
+    if _kernel_impl(cfg) != "pallas" or scan_untiled(
+            Q, cfg.ssm_state, H // G, cfg.mamba_head_dim):
+        return H * Q * (4 * 4 + 2 * item)
+    return 4 * (cfg.mamba_inner * cfg.ssm_state // Q + 7 * G * 128 + 2 * H)
+
+
 def _working_set_bytes(cfg: TransformerConfig, tokens: int,
                        param_bytes: int) -> int:
     """What the step that keeps nothing holds on a device beside its state
@@ -1291,9 +1311,11 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     once: the named ones, the two normed inputs, the stream's cotangent,
     q, k and v as the kernel takes them (heads repeated; latent attention's
     q and k at two tiles of lanes), lse and delta at a tile's 128 lanes,
-    the feed-forward's (and the shared experts') hidden product and a
-    routed layer's dispatched rows; with the compute-dtype copy of its weights
-    and, where the parameters are sharded, the same weights gathered whole
+    the feed-forward's (and the shared experts') hidden product, a
+    routed layer's dispatched rows and what a mixer's scan holds by the
+    path it takes (`_scan_bytes_per_token`); with the compute-dtype copy of
+    its weights and, where the parameters are sharded, the same weights
+    gathered whole
     and their float32 gradient before it is scattered. Against the chip
     (`bytes_in_use + bytes_reserved` less state and gradients; PERF.md
     section 6, PR 33), GB: 4.06 for 4.03 at Mistral's widths on one chip
@@ -1316,11 +1338,9 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
             3 if kind.op is not None and kind.ff else 2) * d
         if kind.op == "mamba2":
             # the convolution's sum and its silu, the gated output and the
-            # normed one; and the scan's [H, Q, Q] a chunk, Q values a token
-            # and head: the decays and their gradient in float32, the
-            # masked scores and theirs in the compute dtype and in float32
+            # normed one
             width += 2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner
-            width += cfg.mamba_heads * cfg.ssd_chunk * (4 * 4 + 2 * item) // item
+            width += _scan_bytes_per_token(cfg) // item
         elif kind.op == "conv":
             width += 3 * d  # the gate's product, the taps' sum, the gated
         elif kind.op == "latent_attention":  # q and k at their own width

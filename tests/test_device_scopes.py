@@ -4,6 +4,7 @@ step's name stacks, and every name a benchmark metric matches is one of
 them, so that a rename in the program fails here and not in a metric. CPU
 only: the steps are tiny and the kernels run in interpret mode."""
 
+import functools
 import glob
 import importlib
 import json
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import TransformerConfig, make_train_step
+from ray_tpu.models import transformer as transformer_module
 from ray_tpu.models.resnet import ResNetConfig, resnet_apply, resnet_init
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import flash_attention
@@ -43,6 +45,9 @@ DSV2_SCOPES = {"latent_attention", "kv_down", "kv_up", "moe_shared"}
 # the Mamba-2 mixer's parts inside `mamba`, and the scan's inside `ssd`
 NEMOTRON_SCOPES = {"mamba", "mamba_in", "mamba_conv", "ssd", "ssd_chunk",
                    "ssd_state", "ssd_out", "mamba_norm", "mamba_out"}
+# the scan's kernels, where its shapes tile and the operators are Pallas's;
+# `ssd_chunk`, `ssd_state`, `ssd_out` are the `jax.numpy` path's
+SSD_KERNELS = {"ssd_fwd", "ssd_bwd"}
 VOCAB = 96  # the tiny steps' one dimension of this size: it finds the head
 
 
@@ -59,7 +64,7 @@ def components(stacks):
     return {part for stack in stacks for part in re.split(r"[/()]", stack)}
 
 
-def lowered_transformer_step(**routed):
+def lowered_transformer_step(tokens=(2, 16), **routed):
     cfg = TransformerConfig(**{**dict(
         vocab_size=VOCAB, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=64, max_seq_len=16, remat=True, attention_impl="xla",
@@ -67,7 +72,7 @@ def lowered_transformer_step(**routed):
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     init_state, step, _ = make_train_step(cfg, mesh)
     state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
-    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    tokens = jax.ShapeDtypeStruct(tokens, jnp.int32)
     return step.lower(state, {"tokens": tokens, "targets": tokens})
 
 
@@ -143,6 +148,21 @@ def lowered_nemotron_step():
             ssm_state=16, ssm_groups=2, ssd_chunk=8)
     finally:
         moe._ROW_TILE = row_tile
+
+
+def lowered_nemotron_kernel_step():
+    """A mixer alone at sizes that tile (a chunk and a state of 128, a group
+    of two heads of 64), the scan's kernels in interpret mode: steered
+    here, since "pallas" does not lower for a CPU."""
+    scan = transformer_module.ssd
+    transformer_module.ssd = functools.partial(scan, interpret=True)
+    try:
+        return lowered_transformer_step(
+            n_layers=1, sublayer_types=("mamba2",), mamba_heads=2,
+            mamba_head_dim=64, ssm_state=128, ssm_groups=1, ssd_chunk=128,
+            max_seq_len=128, tokens=(1, 128))
+    finally:
+        transformer_module.ssd = scan
 
 
 def lowered_moe_kernels():
@@ -365,6 +385,26 @@ def test_the_kernels_carry_their_names_in_interpret_mode(lower, names):
     found = components(name_stacks(lower()))
     assert names <= found
     assert not (KERNELS - names) & found
+
+
+def test_the_scan_s_kernels_sit_under_the_scan_s_scope():
+    """On the kernels' path `ssd_fwd` is under `mamba/ssd` in the forward
+    and in the forward made again, `ssd_bwd` in the backward, so that
+    `ssd_time_share.tokens` and `mamba_time_share.tokens` read them as they
+    are; the `jax.numpy` scopes are that path's alone (the family's step
+    above holds them)."""
+    stacks = name_stacks(lowered_nemotron_kernel_step())
+    for want in ("mamba/ssd/ssd_fwd/pallas_call",
+                 "checkpoint/rematted_computation/mamba/ssd/ssd_fwd/pallas_call",
+                 "checkpoint/mamba/ssd/ssd_bwd/pallas_call"):
+        assert want in stacks, want
+    found = components(stacks)
+    assert SSD_KERNELS <= found
+    assert not {"ssd_chunk", "ssd_state", "ssd_out"} & found
+    # what is left beside the kernels under `ssd`: the softplus, `dt A` and
+    # its running sum, the layouts, dD
+    assert "mamba/ssd/jit(cumsum)" in stacks
+    assert not [s for s in stacks if "ssd_bwd" in s and "rematted" in s]
 
 
 def test_project_and_combine_names_its_backward():

@@ -262,6 +262,101 @@ def test_grouped_matmul_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
         "bf16[131072,1024]")
 
 
+# ------------------------------------------- the Mamba-2 scan's kernels
+
+SCAN = dict(b=2, T=8192, H=64, P=64, G=8, N=128)  # nemotron3nano.tokens8k
+
+
+def _scan_args(device):
+    one = SingleDeviceSharding(device)
+    b, T, H, P, G, N = SCAN.values()
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return (sd((b, T, H, P), jnp.bfloat16), sd((b, T, H), jnp.float32),
+            sd((H,), jnp.float32), sd((b, T, G, N), jnp.bfloat16),
+            sd((b, T, G, N), jnp.bfloat16), sd((H,), jnp.float32))
+
+
+def _custom_calls(text):
+    """[(instruction's name, its result type)] of a compiled text's Mosaic
+    kernels."""
+    import re
+
+    return re.findall(
+        r'%([\w.-]+) = (\(.*?\)|\S+) custom-call\([^\n]*"tpu_custom_call"', text)
+
+
+@pytest.mark.parametrize("kernel", ["ssd_fwd", "ssd_bwd"])
+def test_scan_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
+    """`nemotron3nano.tokens8k`: x `[2, 8192, 64, 64]`, 8 groups, a state
+    of 128, chunks of 128, bf16. The forward alone is one `ssd_fwd` that
+    writes y lane-dense; differentiated, the forward rule's `ssd_fwd` also
+    writes the 64 chunks' entering states in float32 and `ssd_bwd` returns
+    dx, the columns of d dt and d cum, d cum's rows, dB and dC. No array
+    of `[.., 64, 64, 128, 128]` (a sequence's `[n, H, Q, Q]`) is in either
+    program."""
+    import re
+
+    from ray_tpu.ops.ssd import ssd
+
+    def y(*args):
+        return ssd(*args, chunk=128, impl="pallas")
+
+    def grads(*args):
+        return jax.grad(lambda *a: y(*a).astype(jnp.float32).sum(),
+                        argnums=range(6))(*args)
+
+    text = jax.jit(y if kernel == "ssd_fwd" else grads).lower(
+        *_scan_args(v5e[0])).compile().as_text()
+    # outside a step's scopes a differentiated call is named by its whole
+    # stack (`jvp_ssd_fwd_`); in a step `ssd_fwd.3` (`TOKEN_CELLS` below)
+    calls = {re.search(r"ssd_(fwd|bwd)", name).group(0):
+             re.findall(r"(?:bf16|f32)\[[\d,]+\]", out)
+             for name, out in _custom_calls(text)}
+    assert "64,64,128,128]" not in text
+    if kernel == "ssd_fwd":
+        assert calls == {"ssd_fwd": ["bf16[2,8192,4096]"]}
+        return
+    assert set(calls) == {"ssd_fwd", "ssd_bwd"}
+    assert calls["ssd_fwd"] == [
+        "bf16[2,8192,4096]", "f32[2,64,8,128,512]"]
+    assert calls["ssd_bwd"] == [
+        "bf16[2,8192,4096]", "f32[2,8,8192,8]", "f32[2,8,8192,8]",
+        "f32[2,8,8,8192]", "bf16[2,8192,1024]", "bf16[2,8192,1024]"]
+
+
+def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
+    """Arithmetic alone, `nemotron3nano.tokens8k` at 2 x 8192 tokens and a
+    limit of 15.75 GiB. On the kernels' path the scan's part of a block's
+    backward is the entering states and the columns and rows of dt and cum
+    (0.75 GB) where the `jax.numpy` path holds [H, Q, Q] arrays (2.68 GB):
+    the room is 2.14 GB and the rule keeps `mamba_in` (1.35 GB) after
+    attention's three names; `ssd_out` (0.54 GB) no longer fits. With the
+    `jax.numpy` scan the room is 0.20 GB and `attn_ctx` alone is kept."""
+    from chipbench import spec
+    from chipbench.loops import nemotron_h
+    from ray_tpu.models import transformer as tr
+
+    config = spec.load_cell(spec.ROOT, "nemotron3nano.tokens8k")["config"]
+    tokens = 2 * 8192
+
+    def kept(impl):
+        cfg = nemotron_h.model_config(dict(config, attention_impl=impl))
+        params = 4 * 666962944
+        return cfg, tr.saved_activations(
+            cfg, tokens, 3 * params, params, HBM_LIMIT)
+
+    cfg, chosen = kept("pallas")
+    assert tr._scan_bytes_per_token(cfg) * tokens == 746586112
+    assert chosen == {"attn_ctx": 136314880, "attn_res": 88080384,
+                      "attn_qkv": 150994944, "mamba_in": 1350565888}
+    cfg, chosen = kept("xla")
+    assert tr._scan_bytes_per_token(cfg) * tokens == 64 * 128 * 20 * tokens
+    assert chosen == {"attn_ctx": 136314880}
+
+
 # ------------------------- the token cells' steps with what remat keeps
 
 HBM_LIMIT = int(15.75 * 2**30)  # a v5e's `bytes_limit`, to the GiB's hundredth
@@ -274,7 +369,12 @@ TOKEN_CELLS = {
     "mistral7b.fsdp4": ((1, 2), (0, 0)),
     "olmoe.tokens4k": ((1, 2), (6, 8)),
     "lfm2moe.tokens8k": ((1, 2), (32, 32)),  # a share's layer has no names
+    "nemotron3nano.tokens8k": ((1, 2), (20, 20)),  # a share, as above
 }
+# (`ssd_fwd`, `ssd_bwd`) calls in the text: four mixers, each run forward,
+# forward again under remat (their `ssd_out` is not kept, and the backward
+# wants the entering states) and backward
+SCAN_CALLS = {"nemotron3nano.tokens8k": (8, 4)}
 
 
 def _token_cell_step(cell_name, devices, monkeypatch):
@@ -339,6 +439,13 @@ def test_token_step_with_what_it_keeps_compiles_and_fits(
     assert _calls(text, "moe_gmm") == gmm
     if cell_name == "olmoe.tokens4k":
         assert {"moe_slots", "moe_gate", "moe_up"} <= set(chosen)
+    scans = SCAN_CALLS.get(cell_name, (0, 0))
+    assert (_calls(text, "ssd_fwd"), _calls(text, "ssd_bwd")) == scans
+    if any(scans):
+        assert "mamba_in" in chosen and "ssd_out" not in chosen
+        # a sequence's [n, H, Q, Q] of decays or masked scores is nowhere
+        assert "f32[2,64,64,128,128]" not in text
+        assert "bf16[2,64,64,128,128]" not in text
 
 
 @pytest.mark.parametrize("cell_name", list(TOKEN_CELLS))

@@ -55,6 +55,21 @@ dense, shared and routed experts alike, a shared expert of its own width
 (`d_ff_shared`), and `routed_scaling_factor` on the chosen experts'
 weights. Those are Nemotron-3-Nano's (`nemotron_h`).
 
+A fifth operator is attention under a window (`"sliding_attention"` in
+`layer_types`, `sliding_window`): plain attention in which a token sees
+itself and the `sliding_window - 1` before it, through the flash kernels'
+band (`ops/flash_attention.py` `window=`), in one stack with full attention.
+The two differ by operator in more than the mask: the query heads' number
+(`n_heads` for `full_attention`, `n_heads_sliding` under the window, over
+the same `n_kv_heads` of the same width, so `wq`, `wo` and the gate differ
+in shape by layer type and `segments` scans the alike ones together) and
+the rotary recipe (`TransformerConfig.rotary`: theta, the share of a head's
+columns that turn, `partial_rotary_factor`, and for full attention
+`rope_scaling`, YaRN's frequencies with an explicit `attention_factor`,
+through the one `_rope` latent attention uses). `attn_gate` multiplies every
+head's context by one sigmoid gate a head and token, from the normed input
+(`w_gate_attn` `[d, heads]`). Those are Laguna-XS.2's (`laguna`).
+
 With `remat` each block runs under `jax.checkpoint`: its input is kept and
 its values are made again in the backward pass, but for the named ones
 (`checkpoint_name`) that `make_train_step`'s step finds room for on the
@@ -76,8 +91,8 @@ Parallelism (ray_tpu.parallel.mesh axes):
 Capability analog of what the reference reaches only through integrations
 (SURVEY §5: it ships no native SP); here it is native. Cells that train it:
 `mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k`,
-`lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`
-(BENCHMARK.json).
+`lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`,
+`lagunaxs2.tokens8k` (BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -137,8 +152,8 @@ class TransformerConfig:
     qk_norm: Union[bool, str] = False
     router_aux_loss_coef: float = 0.01  # load balancing, mean over layers
     router_z_loss_coef: float = 0.001  # logsumexp(router logits)^2
-    # one of "full_attention" | "conv" | "latent_attention" per layer;
-    # () => attention everywhere
+    # one of "full_attention" | "sliding_attention" | "conv" |
+    # "latent_attention" per layer; () => attention everywhere
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3  # the short convolution's reach, this token included
     n_dense_layers: int = 0  # with n_experts: leading layers with a dense FF
@@ -189,6 +204,20 @@ class TransformerConfig:
     mamba_dt_init: Tuple[float, float, float] = (1e-3, 0.1, 1e-4)
     # the mixers' output projections start divided by sqrt(n_layers)
     rescale_prenorm_residual: bool = False
+    # "sliding_attention": causal attention in which a token sees itself and
+    # the `sliding_window - 1` before it, at a head count of its own (None =>
+    # `n_heads`; `n_kv_heads` and the head's width are shared) and a rotary
+    # recipe of its own: its theta (None => `rope_theta`) over a whole head,
+    # at plain frequencies
+    sliding_window: int = 0
+    n_heads_sliding: Optional[int] = None
+    rope_theta_sliding: Optional[float] = None
+    # the share of a head's columns, the first ones, that full attention
+    # turns, at `rope_theta` and, where there is one, `rope_scaling`
+    partial_rotary_factor: float = 1.0
+    # one sigmoid gate a head and token on plain attention's output, from
+    # the normed input (`w_gate_attn` [d, heads])
+    attn_gate: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -197,6 +226,21 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    def heads(self, op: Optional[str]) -> int:
+        """The query heads of a layer whose operator is `op`."""
+        if op == "sliding_attention":
+            return self.n_heads_sliding or self.n_heads
+        return self.n_heads
+
+    def rotary(self, op: Optional[str]):
+        """(theta, the share of a head that turns, `rope_scaling` as a
+        mapping or None) of plain attention's rotary positions in a layer
+        whose operator is `op`."""
+        if op == "sliding_attention":
+            return self.rope_theta_sliding or self.rope_theta, 1.0, None
+        return (self.rope_theta, self.partial_rotary_factor,
+                dict(self.rope_scaling) if self.rope_scaling else None)
 
     @property
     def shared_dim(self) -> int:
@@ -260,8 +304,8 @@ class TransformerConfig:
 
 
 class LayerKind(NamedTuple):
-    # "full_attention" | "conv" | "latent_attention" | "mamba2"; None: the
-    # layer is a feed-forward alone
+    # "full_attention" | "sliding_attention" | "conv" | "latent_attention" |
+    # "mamba2"; None: the layer is a feed-forward alone
     op: Optional[str]
     routed: bool     # the feed-forward: routed experts, or dense
     ff: bool = True  # False: the layer is an operator alone
@@ -332,7 +376,8 @@ def _mamba_init(key, cfg: TransformerConfig, L: int):
 
 def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
     """`L` layers of one kind, every leaf stacked on a leading layer axis."""
-    d, h, hk, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    d, hk, dh = cfg.d_model, cfg.kv_heads, cfg.head_dim
+    h = cfg.heads(kind.op)
     ks = jax.random.split(k_blk, 7)
     if kind.op is None:
         blocks = {}
@@ -366,7 +411,10 @@ def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
             "wv": _dense(ks[2], (L, d, hk * dh), d),
             "wo": _dense(ks[3], (L, h * dh, d), h * dh),
         }
-    if cfg.qk_norm and kind.op == "full_attention":
+        if cfg.attn_gate:
+            blocks["w_gate_attn"] = _dense(
+                jax.random.fold_in(k_blk, 9), (L, d, h), d)
+    if cfg.qk_norm and kind.op in _PLAIN_ATTENTION:
         per_head = cfg.qk_norm == "head"
         blocks["q_norm"] = jnp.ones((L, dh if per_head else h * dh), jnp.float32)
         blocks["k_norm"] = jnp.ones((L, dh if per_head else hk * dh), jnp.float32)
@@ -498,6 +546,9 @@ _MAMBA_AXES = {
     "w_out": ("layers", "heads", "embed"),
 }
 _ATTENTION_KEYS = ("attn_norm", "wq", "wk", "wv", "wo")
+# plain attention, whole or under a window: the same leaves, the heads'
+# number by the operator (`TransformerConfig.heads`)
+_PLAIN_ATTENTION = ("full_attention", "sliding_attention")
 _FF_KEYS = ("mlp_norm", "w_gate", "w_up", "w_down")
 # the operators whose leaves take the place of full attention's; a layer
 # that is a feed-forward alone has none
@@ -515,6 +566,8 @@ def _block_axes(cfg: TransformerConfig, kind: LayerKind):
         if cfg.qk_norm:
             table.update(
                 _HEAD_NORM_AXES if cfg.qk_norm == "head" else _QK_NORM_AXES)
+        if cfg.attn_gate:  # a column a head
+            table["w_gate_attn"] = ("layers", "embed", "heads")
     if kind.routed:
         table.update(_ROUTED_AXES)
         if cfg.n_shared_experts:
@@ -562,8 +615,9 @@ def rope_frequencies(width: int, theta: float, scaling=None):
     (arXiv:2309.00071), the frequencies that turn more than `beta_fast`
     times over the original context are kept, those that turn fewer than
     `beta_slow` times are divided by `factor`, and a linear ramp blends
-    the ones between; cos and sin are scaled by the ratio of
-    `yarn_softmax_scale`'s two factors, `mscale` and `mscale_all_dim`."""
+    the ones between; cos and sin are scaled by `attention_factor` where
+    the mapping has one, else by the ratio of `yarn_softmax_scale`'s two
+    factors, `mscale` and `mscale_all_dim`."""
     half = width // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     if not scaling:
@@ -574,6 +628,8 @@ def rope_frequencies(width: int, theta: float, scaling=None):
         0.0, 1.0)
     factor = scaling["factor"]
     blended = freqs / factor * ramp + freqs * (1.0 - ramp)
+    if "attention_factor" in scaling:
+        return blended, float(scaling["attention_factor"])
     return blended, (_yarn_mscale(factor, scaling.get("mscale", 1.0))
                      / _yarn_mscale(factor, scaling.get("mscale_all_dim", 0.0)))
 
@@ -618,14 +674,26 @@ def _rope(x, positions, theta: float, scaling=None):
     ).astype(x.dtype)
 
 
+def _rotate(x, positions, theta: float, share: float = 1.0, scaling=None):
+    """`_rope` on the first `share` of the columns of every head of `x`
+    [B, T, H, Dh]; the rest are left as they are."""
+    turned = int(x.shape[-1] * share)
+    if turned == x.shape[-1]:
+        return _rope(x, positions, theta, scaling)
+    return jnp.concatenate(
+        [_rope(x[..., :turned], positions, theta, scaling), x[..., turned:]],
+        axis=-1)
+
+
 def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
                seq_size: int, mesh=None, keep_ctx: bool = False,
-               scale: Optional[float] = None):
+               scale: Optional[float] = None, window: Optional[int] = None):
     """Causal attention of q, k [B, T, H, D] and v [B, T, H, Dv]; `scale`
-    is the scores', `1 / sqrt(D)` where None."""
+    is the scores', `1 / sqrt(D)` where None; under `window` a query sees
+    itself and the `window - 1` keys before it."""
     if cfg.attention_impl == "ring" and seq_axis is not None:
         # Inside shard_map over the sequence axis: exact ring attention.
-        rep = cfg.n_heads // k.shape[2]
+        rep = q.shape[2] // k.shape[2]
         if rep > 1:
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
@@ -633,7 +701,8 @@ def _attention(q, k, v, cfg: TransformerConfig, seq_axis: Optional[str],
             q, k, v, axis_name=seq_axis, axis_size=seq_size, causal=True
         )
     impl = _kernel_impl(cfg)
-    attn = partial(mha, causal=True, impl=impl, keep_ctx=keep_ctx, scale=scale)
+    attn = partial(mha, causal=True, impl=impl, keep_ctx=keep_ctx, scale=scale,
+                   window=window)
     if impl == "pallas" and mesh is not None and mesh.size > 1:
         # XLA cannot partition a Mosaic kernel ("wrap the call in a
         # shard_map"), so map it ourselves over the axes attention is
@@ -657,8 +726,9 @@ def _kernel_impl(cfg: TransformerConfig) -> str:
 
 # the weights of a block's plain matmuls, every operator's and feed-forward's
 _MATMUL_WEIGHTS = (
-    "wq", "wk", "wv", "wo", "wkv_a", "wkv_b", "conv_in", "conv_out",
-    "w_in", "w_out", "w_gate", "w_up", "w_down", "ws_gate", "ws_up", "ws_down",
+    "wq", "wk", "wv", "wo", "w_gate_attn", "wkv_a", "wkv_b", "conv_in",
+    "conv_out", "w_in", "w_out", "w_gate", "w_up", "w_down", "ws_gate",
+    "ws_up", "ws_down",
 )
 _ROUTED_WEIGHTS = ("w_gate", "w_up", "w_down")  # `ops/moe.py` casts its own
 
@@ -732,10 +802,15 @@ def own_buffers(blocks, dt) -> Tuple[int, int, int]:
 
 def _attention_layer(x, blk, positions, cfg: TransformerConfig,
                      seq_axis: Optional[str], seq_size: int, mesh=None,
-                     keep_ctx: bool = False):
-    """x + attention(norm(x)): projections, QK-norm, RoPE, the kernel."""
+                     keep_ctx: bool = False, op: str = "full_attention"):
+    """x + attention(norm(x)): projections, QK-norm, RoPE, the kernel, and
+    with `w_gate_attn` the heads' gates on its output. `op` is
+    "full_attention" or "sliding_attention": the heads' number, the rotary
+    recipe and the window are the operator's."""
     B, T, d = x.shape
-    h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    h, hk, dh = cfg.heads(op), cfg.kv_heads, cfg.head_dim
+    theta, share, scaling = cfg.rotary(op)
+    window = cfg.sliding_window if op == "sliding_attention" else None
     dt = cfg.dtype
     per_head = cfg.qk_norm == "head"
 
@@ -759,10 +834,15 @@ def _attention_layer(x, blk, positions, cfg: TransformerConfig,
         v = checkpoint_name(y @ blk["wv"].astype(dt), "attn_qkv").reshape(
             B, T, hk, dh)
         if cfg.rope:
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q = _rotate(q, positions, theta, share, scaling)
+            k = _rotate(k, positions, theta, share, scaling)
     with jax.named_scope("attention"):
-        o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh, keep_ctx)
+        o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh, keep_ctx,
+                       window=window)
+    if "w_gate_attn" in blk:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(y @ blk["w_gate_attn"].astype(dt))
+            o = o * gate[..., None]
     with jax.named_scope("attn_out"):
         return checkpoint_name(
             x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt), "attn_res")
@@ -977,7 +1057,8 @@ def _feed_forward(y, blk, dt, names, prefix: str = "w"):
 
 def _block(x, blk, positions, bias, cfg: TransformerConfig,
            seq_axis: Optional[str], seq_size: int, mesh=None,
-           keep_ctx: bool = False, sliced: bool = False):
+           keep_ctx: bool = False, sliced: bool = False,
+           sliding: bool = False):
     """One block: (x, the routed feed-forward's readings or None). What the
     block is, its parameters say: a short convolution where it has
     `conv_in`, latent attention where it has `wkv_a`, a Mamba-2 mixer where
@@ -986,7 +1067,8 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig,
     `router`, shared experts beside it where it has `ws_up`. `keep_ctx`:
     the attention kernel names its backward's residuals `attn_ctx`.
     `sliced`: `blk` is one of several periods of a scanned stack
-    (`_own_weights`)."""
+    (`_own_weights`). `sliding`: its attention is under the window, which
+    its parameters' names do not say."""
     dt = cfg.dtype
     blk = _own_weights(blk, dt, sliced)
 
@@ -1006,6 +1088,14 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig,
     elif "A_log" in blk:
         with jax.named_scope("mamba"):
             x = x + _mamba_mixer(x, blk, cfg)
+    elif "wk" in blk and sliding:
+        if seq_axis is not None:
+            raise NotImplementedError(
+                "attention under a window is not mapped over a sequence "
+                "axis: ring attention has no band")
+        with jax.named_scope("sliding_attention"):
+            x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size,
+                                 mesh, keep_ctx, op="sliding_attention")
     elif "wk" in blk:
         x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size, mesh,
                              keep_ctx)
@@ -1060,19 +1150,27 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
     policy = (jax.checkpoint_policies.save_only_these_names(*saved_names)
               if saved_names else None)
 
-    def scan_body(sliced: bool):
-        blk_fn = partial(
-            _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size, mesh=mesh,
-            keep_ctx="attn_ctx" in saved_names, sliced=sliced,
-        )
-        if cfg.remat:
-            blk_fn = jax.checkpoint(blk_fn, policy=policy, static_argnums=())
+    def scan_body(sliced: bool, layout: Tuple[LayerKind, ...]):
+        def block_fn(sliding: bool):
+            blk_fn = partial(
+                _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size,
+                mesh=mesh, keep_ctx="attn_ctx" in saved_names, sliced=sliced,
+                sliding=sliding)
+            if cfg.remat:
+                blk_fn = jax.checkpoint(blk_fn, policy=policy,
+                                        static_argnums=())
+            return blk_fn
+
+        # a layer under the window has the leaves of one that is not: the
+        # layout says which it is
+        under_window = [kind.op == "sliding_attention" for kind in layout]
+        blk_fns = {sliding: block_fn(sliding) for sliding in set(under_window)}
 
         def body(x, period):
             blks, biases = period
             readings = []
-            for blk, bias in zip(blks, biases):
-                x, reading = blk_fn(x, blk, positions, bias)
+            for sliding, blk, bias in zip(under_window, blks, biases):
+                x, reading = blk_fns[sliding](x, blk, positions, bias)
                 if reading is not None:
                     readings.append(reading)
             return x, readings
@@ -1080,7 +1178,7 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
         return body
 
     readings, routed_before = [], 0
-    for blks in _segment_trees(params["blocks"]):
+    for seg, blks in zip(segments(cfg), _segment_trees(params["blocks"])):
         periods = _periods(blks[0])
         # this segment's rows of the bias, one [periods, E] per routed layer
         # of its period
@@ -1093,7 +1191,8 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
             routed_before += periods * len(routed)
             for j, i in enumerate(routed):
                 biases[i] = rows[:, j]
-        x, of_period = jax.lax.scan(scan_body(periods > 1), x, (blks, biases))
+        x, of_period = jax.lax.scan(
+            scan_body(periods > 1, seg.layout), x, (blks, biases))
         if of_period:
             readings.append(_layer_axis(of_period, stack=True))
     readings = _layer_axis(readings, stack=False) if readings else None
@@ -1215,7 +1314,8 @@ def _tile_lanes(width: int) -> int:
 def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
     """(values a token of one layer of `kind`: {name: width in elements of
     the compute dtype}, the layer's parameters)."""
-    d, h, hk, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    d, hk, dh = cfg.d_model, cfg.kv_heads, cfg.head_dim
+    h = cfg.heads(kind.op)
     k, f = cfg.experts_per_token, cfg.ff_dim
     item = jnp.dtype(cfg.dtype).itemsize
     mats = cfg.ff_matrices
@@ -1249,6 +1349,8 @@ def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
             "attn_qkv": (h + 2 * hk) * dh,
         }
         params = d * (h + 2 * hk) * dh + h * dh * d
+        if cfg.attn_gate:
+            params += d * h
     if not kind.ff:
         return widths, params
     gates = ("gate",) if cfg.gated else ()
@@ -1309,8 +1411,9 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
 
     A block in its backward is taken as every value of its widest layer at
     once: the named ones, the two normed inputs, the stream's cotangent,
-    q, k and v as the kernel takes them (heads repeated; latent attention's
-    q and k at two tiles of lanes), lse and delta at a tile's 128 lanes,
+    q, k and v as the kernel takes them (heads repeated, as many as the
+    layer's operator has; latent attention's q and k at two tiles of lanes),
+    lse and delta at a tile's 128 lanes,
     the feed-forward's (and the shared experts') hidden product, a
     routed layer's dispatched rows and what a mixer's scan holds by the
     path it takes (`_scan_bytes_per_token`); with the compute-dtype copy of
@@ -1324,13 +1427,14 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     values as it goes. It errs to the full side: a name too few costs a
     percent, a step that asks for the chip's last GiB is compiled to fit
     and runs slower than the one that keeps nothing."""
-    d, h = cfg.d_model, cfg.n_heads
+    d = cfg.d_model
     item = jnp.dtype(cfg.dtype).itemsize
     whole = 4 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
         lambda: transformer_init(jax.random.PRNGKey(0), cfg))))
     sharded = param_bytes < whole
     block = 0
     for kind in set(cfg.layers):
+        h = cfg.heads(kind.op)
         widths, params = _layer_widths(cfg, kind)
         # the normed inputs (an operator's, a feed-forward's) and the
         # stream's cotangent
@@ -1535,6 +1639,16 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
                               "params": p_shard, "state": state_shard}
 
 
+def keys_per_query(seq_len: int, window: Optional[int] = None) -> float:
+    """The keys a query of a causal sequence of `seq_len` sees, on average:
+    `(seq_len + 1) / 2`, and under a window that is shorter than the
+    sequence the band's pairs over its queries (the first `window` queries
+    see a triangle, every later one `window` keys)."""
+    if not window or window >= seq_len:
+        return (seq_len + 1) / 2
+    return (window * (window + 1) / 2 + (seq_len - window) * window) / seq_len
+
+
 def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
     """(matmul fwd flops/token over the layers, causal attn fwd flops/token
     over the layers, lm-head fwd flops/token)."""
@@ -1572,8 +1686,14 @@ def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
             # scores over q and k's width, the values over v's
             attn += 2 * h * (qk + dv) * ((seq_len + 1) / 2)
         elif kind.op is not None:
-            matmul += 2 * d * (h * dh + 2 * hk * dh) + 2 * h * dh * d
-            attn += 2 * 2 * h * dh * ((seq_len + 1) / 2)
+            heads = cfg.heads(kind.op)
+            matmul += 2 * d * (heads * dh + 2 * hk * dh) + 2 * heads * dh * d
+            if cfg.attn_gate:
+                matmul += 2 * d * heads
+            # a layer under the window: the band's pairs, not the triangle's
+            attn += 2 * 2 * heads * dh * keys_per_query(
+                seq_len, cfg.sliding_window
+                if kind.op == "sliding_attention" else None)
         if kind.ff:
             matmul += routed if kind.routed else 2 * mats * d * dense_f
     embed = 2 * d * cfg.vocab_size

@@ -30,7 +30,15 @@ as before, each making the tile for itself, seven matmuls between them.
 The four `pallas_call`s are named `flash_fwd` (with or without the lse
 output), `flash_bwd_dkv_dq`, `flash_bwd_dq` and `flash_bwd_dkv`: the names a
 profiler trace and the compiled HLO show, and the ones the benchmark's
-per-kernel metrics read (docs/observability.md, "Device scopes").
+per-kernel metrics read (docs/observability.md, "Device scopes"). Under a
+window they are `flash_fwd_window`, `flash_bwd_dkv_dq_window`,
+`flash_bwd_dq_window` and `flash_bwd_dkv_window`, which the same prefixes
+match and a metric of their own can tell apart.
+
+With `window=w` (and `causal`) key `j` counts for query `i` where `j <= i` and
+`i - j < w`: a token sees itself and the `w - 1` before it, a band under the
+diagonal. `window=None`, or a window no shorter than the sequence, is the
+causal kernel: the same names, tiles, index maps and bodies.
 
 The tile program, the same in all four kernels:
 
@@ -40,7 +48,9 @@ The tile program, the same in all four kernels:
   the sequence (the sequence itself when it is shorter than 128), chosen to
   make the sum of two costs least: what every grid step costs whatever it
   holds, and the work of the tiles that have a body, of which the part above
-  the causal diagonal is wasted. It also returns the estimate of the VMEM the
+  the causal diagonal, and under a window the part left of the band, is
+  wasted (`window=`: the tiles with a body are the band's, and those that
+  divide the sequence are weighed). It also returns the estimate of the VMEM the
   tile needs and the `vmem_limit_bytes` handed to Mosaic (the default 16 MiB
   where that is enough), the grid steps a (batch, head) row makes and the
   share of them that have a body, and what the row's grid costs by the
@@ -58,7 +68,11 @@ The tile program, the same in all four kernels:
   takes a body with no iota, compare or select. A tile wholly above the
   diagonal has no body, and its grid step fetches nothing: the index maps
   clamp the walked index to the nearest tile of that row (column) that has
-  one, so the step names the block already in VMEM.
+  one, so the step names the block already in VMEM. Under a window the same
+  holds on the band's other side: a tile wholly left of it (below it, in a
+  column) has no body and the index maps clamp there too, a tile the band's
+  lower edge crosses masks `query - key < window`, and a tile wholly inside
+  the band takes the bare body.
 - Outputs leave in the input's dtype: o, and dq, dk, dv, which the flush
   rounds once from the f32 accumulator. lse and delta are f32 `[BH, T, 8]`.
 - Two widths. q, k, dq and dk are `D` wide, v, o, do, dv and the forward's
@@ -127,14 +141,24 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
-def _active_tiles(T, S, block_q, block_k, causal) -> int:
+def _active_tiles(T, S, block_q, block_k, causal, window=None) -> int:
     """Tiles with a body: all of them, or under causal those that hold a
-    (query, key) pair with key <= query."""
+    (query, key) pair with key <= query, and with a window only those of
+    them that hold one with query - key < window: the band's."""
     num_q, num_k = _cdiv(T, block_q), _cdiv(S, block_k)
     if not causal:
         return num_q * num_k
+    if window is None:
+        return sum(
+            min(num_k, _cdiv((qi + 1) * block_q, block_k))
+            for qi in range(num_q)
+        )
+    # row qi's tiles run from the one that holds the first key its first
+    # query sees to the one that holds its last query's own position
     return sum(
-        min(num_k, _cdiv((qi + 1) * block_q, block_k)) for qi in range(num_q)
+        min(num_k - 1, ((qi + 1) * block_q - 1) // block_k)
+        - max(qi * block_q - window + 1, 0) // block_k + 1
+        for qi in range(num_q)
     )
 
 
@@ -168,10 +192,18 @@ def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
     return 2 * blocks + scratch + live
 
 
-def _block_candidates(seq: int):
+def _block_candidates(seq: int, whole: bool = False):
+    """The tiles a sequence of `seq` may take along one side. `whole` keeps
+    those that divide it, where some do: a tile that hangs over the
+    sequence's end sanitises its padded rows in every masked body, for
+    which `_COST_US` has no term (under a window that read 1.4 to 2.2 ms a
+    backward call at BH 128, T 8192 where 640 and 768 were chosen for
+    512 x 1024: PERF.md section 6, PR 40)."""
     if seq < _LANES:
         return [seq]
-    return list(range(_LANES, min(seq, _MAX_BLOCK) + 1, _LANES))
+    every = list(range(_LANES, min(seq, _MAX_BLOCK) + 1, _LANES))
+    dividing = [b for b in every if seq % b == 0]
+    return dividing if whole and dividing else every
 
 
 # What a tile costs, in microseconds, as the sweep on one v5e found it
@@ -219,7 +251,8 @@ def _pairs_factor(kernel: str, D: int, Dv: int) -> float:
 def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
                 causal: bool = True, block_q: Optional[int] = None,
                 block_k: Optional[int] = None,
-                v_dim: Optional[int] = None) -> FlashTiles:
+                v_dim: Optional[int] = None,
+                window: Optional[int] = None) -> FlashTiles:
     """The tile of `kernel` (`flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv`,
     `flash_bwd_dkv_dq`) for q of [*, T, D] and k of [*, S, D] and v of
     [*, S, v_dim] (`D` where None) in `dtype`. Pure: the shape decides,
@@ -227,7 +260,11 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     the one whose grid costs least by `_COST_US`: small tiles pay in grid
     steps, large ones in pairs above the causal diagonal that a diagonal
     tile computes and masks. A forced `block_q` or `block_k` is taken as
-    given (cut to the sequence) and the other is chosen."""
+    given (cut to the sequence) and the other is chosen. With `window`
+    (causal, `query - key < window`) the tiles with a body are the band's:
+    a large tile then pays in pairs on both sides of the band, and a small
+    one in the grid steps of the tiles the band leaves out, which are
+    walked and hold nothing."""
     itemsize = jnp.dtype(dtype).itemsize
     Dv = D if v_dim is None else v_dim
     step_us, rows_us, pairs_us = _COST_US[kernel]
@@ -235,15 +272,16 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
 
     def plan(bq, bk):
         steps = _cdiv(T, bq) * _cdiv(S, bk)
-        active = _active_tiles(T, S, bq, bk, causal)
+        active = _active_tiles(T, S, bq, bk, causal, window)
         vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv, T)
         cost = steps * step_us + active * (
             rows_us * bq / 1024 + pairs_us * bq * bk / 2 ** 20)
         return FlashTiles(bq, bk, steps, active / steps, vmem,
                           max(_DEFAULT_VMEM, 2 * vmem), cost)
 
-    qs = [min(block_q, T)] if block_q else _block_candidates(T)
-    ks = [min(block_k, S)] if block_k else _block_candidates(S)
+    whole = window is not None
+    qs = [min(block_q, T)] if block_q else _block_candidates(T, whole)
+    ks = [min(block_k, S)] if block_k else _block_candidates(S, whole)
     plans = [plan(bq, bk) for bq in qs for bk in ks]
     fitting = [p for p in plans if p.vmem_limit_bytes <= _MAX_VMEM]
     return min(fitting or plans[:1], key=lambda p: p.cost_us)
@@ -252,7 +290,8 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
 def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
                       block_q: Optional[int] = None,
                       block_k: Optional[int] = None,
-                      v_dim: Optional[int] = None) -> Tuple[str, ...]:
+                      v_dim: Optional[int] = None,
+                      window: Optional[int] = None) -> Tuple[str, ...]:
     """The kernels of one backward, for the shapes `flash_tiles` takes:
     `("flash_bwd_dkv_dq",)`, all three gradients from one pass over the
     score tiles, or `("flash_bwd_dq", "flash_bwd_dkv")`, which make every
@@ -264,7 +303,8 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
     21k at q and k 192 wide and 44k at 128 or 64."""
     def tiles(kernel):
         return flash_tiles(kernel, T, S, D, dtype, causal=causal,
-                           block_q=block_q, block_k=block_k, v_dim=v_dim)
+                           block_q=block_q, block_k=block_k, v_dim=v_dim,
+                           window=window)
 
     one, two = tiles("flash_bwd_dkv_dq"), ("flash_bwd_dq", "flash_bwd_dkv")
     if (one.vmem_limit_bytes <= _MAX_VMEM
@@ -276,17 +316,25 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
 # ----------------------------------------------------------------- kernels
 
 def _tile_kind(qi, ki, *, block_q, block_k, num_q, num_k, causal,
-               seq_q, seq_k):
+               seq_q, seq_k, window=None):
     """(has_body, needs_mask) of grid tile (qi, ki): Python bools where the
     shape settles it, traced scalars where the grid position does.
     `seq_q=None` says padded q rows need no care (the forward: a row's
-    output depends on that row alone, and padded rows are never written)."""
+    output depends on that row alone, and padded rows are never written).
+    `window`: a pair counts where `query - key < window` too."""
     has_body, needs_mask = True, False
     if causal:
         # some key of the tile is at or before some query of it
         has_body = (qi + 1) * block_q > ki * block_k
         # and some key of it is after some query of it
         needs_mask = (ki + 1) * block_k - 1 > qi * block_q
+    if window is not None:
+        # its last key is inside its first query's window: the nearest pair
+        has_body = jnp.logical_and(
+            has_body, qi * block_q - ((ki + 1) * block_k - 1) < window)
+        # and its first key is outside its last query's: the farthest pair
+        needs_mask = jnp.logical_or(
+            needs_mask, (qi + 1) * block_q - 1 - ki * block_k >= window)
     if seq_k % block_k:
         needs_mask = jnp.logical_or(needs_mask, ki == num_k - 1)
     if seq_q is not None and seq_q % block_q:
@@ -309,9 +357,11 @@ def _run_tile(body, has_body, needs_mask):
         lambda: body(False))
 
 
-def _tile_mask(qi, ki, *, block_q, block_k, causal, seq_q, seq_k):
+def _tile_mask(qi, ki, *, block_q, block_k, causal, seq_q, seq_k,
+               window=None):
     """[bq, bk] bool: the pair is inside both sequences and, under causal,
-    the key is not after the query. Only the terms the shape leaves open."""
+    the key is not after the query, nor `window` or more before it. Only
+    the terms the shape leaves open."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
@@ -325,6 +375,8 @@ def _tile_mask(qi, ki, *, block_q, block_k, causal, seq_q, seq_k):
         terms.append(q_pos < seq_q)
     if causal:
         terms.append(q_pos >= k_pos)
+    if window is not None:
+        terms.append(q_pos - k_pos < window)
     return functools.reduce(jnp.logical_and, terms)
 
 
@@ -339,7 +391,7 @@ def _attn_fwd_kernel(
     o_ref,  # output
     acc_ref, m_ref, l_ref,  # VMEM scratch, persistent over the k grid dim
     *, block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
-    causal: bool, seq_k: int,
+    causal: bool, seq_k: int, window: Optional[int] = None,
 ):
     from jax.experimental import pallas as pl
 
@@ -347,7 +399,7 @@ def _attn_fwd_kernel(
     ki = pl.program_id(2)
     # seq_q=None: padded q rows need no care here (see _tile_kind)
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
-                 seq_q=None, seq_k=seq_k)
+                 seq_q=None, seq_k=seq_k, window=window)
 
     @pl.when(ki == 0)
     def _init():
@@ -382,7 +434,8 @@ def _attn_fwd_kernel(
         pv = _dot(p.astype(v.dtype), v, _NN)  # [bq, Dv]
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
 
-    # Tiles strictly above the diagonal contribute nothing and have no body.
+    # Tiles strictly above the diagonal, or wholly left of the band,
+    # contribute nothing and have no body.
     _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
     @pl.when(ki == num_k - 1)
@@ -423,10 +476,24 @@ def _last_k_with_body(qi, block_q, block_k):
     return jax.lax.div((qi + 1) * block_q - 1, block_k)
 
 
+def _first_k_with_body(qi, block_q, block_k, window):
+    """Index of the first k tile that has a body in q row `qi` under a
+    window: the one that holds the first key the row's first query sees."""
+    return jax.lax.div(jnp.maximum(qi * block_q - window + 1, 0), block_k)
+
+
 def _first_q_with_body(ki, block_q, block_k, num_q):
     """Index of the first q tile that has a body in k column `ki` (causal),
     kept inside the array for a column that has none."""
     return jnp.minimum(jax.lax.div(ki * block_k, block_q), num_q - 1)
+
+
+def _last_q_with_body(ki, block_q, block_k, num_q, window):
+    """Index of the last q tile that has a body in k column `ki` under a
+    window: the one that holds the last query that sees the column's last
+    key, kept inside the array."""
+    return jnp.minimum(
+        jax.lax.div((ki + 1) * block_k + window - 2, block_q), num_q - 1)
 
 
 def _compiler_params(tiles: FlashTiles, inner=("parallel", "arbitrary")):
@@ -441,40 +508,53 @@ def _compiler_params(tiles: FlashTiles, inner=("parallel", "arbitrary")):
     )
 
 
-def _grid(kernel, q, k, v, causal, block_q, block_k):
+def _grid(kernel, q, k, v, causal, block_q, block_k, window=None):
     """(tiles, q tiles, k tiles) of `kernel` for q of [BH, T, D], k of
     [BH, S, D] and v of [BH, S, Dv]; `block_q`, `block_k` force a tile or
     are None."""
     T, S = q.shape[1], k.shape[1]
     tiles = flash_tiles(kernel, T, S, q.shape[2], q.dtype, causal=causal,
-                        block_q=block_q, block_k=block_k, v_dim=v.shape[2])
+                        block_q=block_q, block_k=block_k, v_dim=v.shape[2],
+                        window=window)
     return tiles, _cdiv(T, tiles.block_q), _cdiv(S, tiles.block_k)
+
+
+def _kernel_name(kernel: str, window) -> str:
+    """The `pallas_call`'s name: the kernel's, and with a window
+    `<kernel>_window`, which a trace and the compiled HLO tell apart."""
+    return kernel if window is None else kernel + "_window"
 
 
 def _q_block(bh, qi, ki):
     return (bh, qi, 0)
 
 
-def _k_block_under_q(causal, block_q, block_k):
+def _k_block_under_q(causal, block_q, block_k, window=None):
     """Index map of K and V where k is walked innermost (forward, dq): under
     causal a step past the row's last tile with a body names that tile, the
-    block already in VMEM, and fetches nothing."""
+    block already in VMEM, and fetches nothing; under a window a step
+    before the row's first tile with a body names that one, which is then
+    there when the walk reaches it."""
     def k_block(bh, qi, ki):
         if causal:
             ki = jnp.minimum(ki, _last_k_with_body(qi, block_q, block_k))
+        if window is not None:
+            ki = jnp.maximum(
+                ki, _first_k_with_body(qi, block_q, block_k, window))
         return (bh, ki, 0)
 
     return k_block
 
 
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
-               with_lse: bool = False):
+               with_lse: bool = False, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
     S, Dv = k.shape[1], v.shape[2]
-    tiles, num_q, num_k = _grid("flash_fwd", q, k, v, causal, block_q, block_k)
+    tiles, num_q, num_k = _grid("flash_fwd", q, k, v, causal, block_q, block_k,
+                                window)
     block_q, block_k = tiles.block_q, tiles.block_k
 
     kernel = functools.partial(
@@ -486,9 +566,10 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         scale=scale,
         causal=causal,
         seq_k=S,
+        window=window,
     )
 
-    k_block = _k_block_under_q(causal, block_q, block_k)
+    k_block = _k_block_under_q(causal, block_q, block_k, window)
     out_shape = jax.ShapeDtypeStruct((BH, T, Dv), q.dtype)
     out_specs = pl.BlockSpec((1, block_q, Dv), _q_block)
     if with_lse:
@@ -514,24 +595,25 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         ],
         compiler_params=_compiler_params(tiles),
         interpret=interpret,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", window),
     )(q, k, v)
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
 )
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret, keep_ctx):
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, keep_ctx,
+           window):
     return _flash_fwd(
-        q, k, v, causal=causal, scale=scale,
+        q, k, v, causal=causal, scale=scale, window=window,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                   keep_ctx):
+                   keep_ctx, window):
     o, lse = _flash_fwd(
-        q, k, v, causal=causal, scale=scale,
+        q, k, v, causal=causal, scale=scale, window=window,
         block_q=block_q, block_k=block_k, interpret=interpret, with_lse=True,
     )
     if keep_ctx:
@@ -547,20 +629,36 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 @functools.lru_cache(maxsize=None)
-def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k):
+def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
+                     window=None):
     """One line for each backward a process traces, as `saved_activations`
-    has one for what it keeps: which kernels, at which tile and VMEM."""
+    has one for what it keeps: which kernels, at which tile and VMEM. Under
+    a window also the forward's tile, and of every kernel the share of its
+    grid steps that have a body: the rest are walked and hold nothing."""
+    def tiles(kernel):
+        return flash_tiles(kernel, T, S, D, dtype, causal=causal,
+                           block_q=block_q, block_k=block_k, v_dim=Dv,
+                           window=window)
+
     for kernel in kernels:
-        t = flash_tiles(kernel, T, S, D, dtype, causal=causal,
-                        block_q=block_q, block_k=block_k, v_dim=Dv)
+        t = tiles(kernel)
         logger.info(
             "flash backward at T %d, S %d, D %d, Dv %d, %s: %s, tile %d x "
             "%d, VMEM %d bytes of a limit of %d", T, S, D, Dv, dtype, kernel,
             t.block_q, t.block_k, t.vmem_bytes, t.vmem_limit_bytes)
+    if window is None:
+        return
+    for kernel in ("flash_fwd", *kernels):
+        t = tiles(kernel)
+        logger.info(
+            "flash window %d at T %d, D %d, Dv %d, %s: %s, tile %d x %d, "
+            "%d grid steps a row, %.1f %% of them with a body", window, T, D,
+            Dv, dtype, _kernel_name(kernel, window), t.block_q, t.block_k,
+            t.grid_steps, 100 * t.active_share)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx, res,
-                   do):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
+                   window, res, do):
     """Tiled FlashAttention-2 backward, re-deriving each softmax tile from
     (q, k, lse) — nothing O(T·S) ever touches HBM (the previous recompute
     path materialized full f32 score matrices through XLA, which both OOMed
@@ -577,11 +675,11 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx, res,
     shape = (T, k.shape[1], q.shape[2])
     kernels = flash_bwd_kernels(*shape, q.dtype, causal=causal,
                                 block_q=block_q, block_k=block_k,
-                                v_dim=v.shape[2])
+                                v_dim=v.shape[2], window=window)
     _log_bwd_kernels(kernels, *shape, v.shape[2], jnp.dtype(q.dtype).name,
-                     causal, block_q, block_k)
+                     causal, block_q, block_k, window=window)
     tile = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-                interpret=interpret)
+                interpret=interpret, window=window)
     if kernels == ("flash_bwd_dkv_dq",):
         return _flash_bwd_dkv(q, k, v, do, lse, delta, with_dq=True, **tile)
     dq = _flash_bwd_dq(q, k, v, do, lse, delta, **tile)
@@ -593,7 +691,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
-              masked, block_q, block_k, scale, causal, seq_q, seq_k):
+              masked, block_q, block_k, scale, causal, seq_q, seq_k,
+              window=None):
     """Shared per-tile computation of both backward kernels: load, sanitize
     padded rows (masked tiles only: no other tile has any), re-derive the
     softmax tile. Returns (q, k, do, p, ds), p and ds in the input's dtype.
@@ -624,7 +723,7 @@ def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
     if masked:
         mask = _tile_mask(
             qi, ki, block_q=block_q, block_k=block_k, causal=causal,
-            seq_q=seq_q, seq_k=seq_k,
+            seq_q=seq_q, seq_k=seq_k, window=window,
         )
         p = jnp.where(mask, p, 0.0)
         # Explicit where: p=0 times a NaN dp entry would still poison the dot.
@@ -637,14 +736,14 @@ def _attn_bwd_dq_kernel(
     dq_ref,
     acc_ref,
     *, block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
-    causal: bool, seq_q: int, seq_k: int,
+    causal: bool, seq_q: int, seq_k: int, window: Optional[int] = None,
 ):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
-                 seq_q=seq_q, seq_k=seq_k)
+                 seq_q=seq_q, seq_k=seq_k, window=window)
 
     @pl.when(ki == 0)
     def _init():
@@ -669,6 +768,7 @@ def _attn_bwd_dkv_kernel(
     *outputs_and_sums,
     block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
     causal: bool, seq_q: int, seq_k: int, with_dq: bool = False,
+    window: Optional[int] = None,
 ):
     """dk and dv of k tile `ki`, summed over the q tiles the grid walks
     innermost. `with_dq` (`flash_bwd_dkv_dq`): dq too, from the same p and
@@ -687,7 +787,7 @@ def _attn_bwd_dkv_kernel(
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
-                 seq_q=seq_q, seq_k=seq_k)
+                 seq_q=seq_q, seq_k=seq_k, window=window)
 
     @pl.when(qi == 0)
     def _init():
@@ -712,7 +812,8 @@ def _attn_bwd_dkv_kernel(
         if with_dq:
             dq_acc_ref[rows, :] += _dot(ds, k, _NN)  # [bq, D]
 
-    # Only q tiles at/below the diagonal see this k tile.
+    # Only q tiles at/below the diagonal, and inside the band, see this k
+    # tile.
     _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
     @pl.when(qi == num_q - 1)
@@ -727,22 +828,22 @@ def _attn_bwd_dkv_kernel(
 
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
-                  block_q, block_k, interpret):
+                  block_q, block_k, interpret, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
     S, Dv = k.shape[1], v.shape[2]
     tiles, num_q, num_k = _grid(
-        "flash_bwd_dq", q, k, v, causal, block_q, block_k)
+        "flash_bwd_dq", q, k, v, causal, block_q, block_k, window)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dq_kernel,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
-        scale=scale, causal=causal, seq_q=T, seq_k=S,
+        scale=scale, causal=causal, seq_q=T, seq_k=S, window=window,
     )
 
-    k_block = _k_block_under_q(causal, block_q, block_k)
+    k_block = _k_block_under_q(causal, block_q, block_k, window)
     return pl.pallas_call(
         kernel,
         grid=(BH, num_q, num_k),
@@ -759,12 +860,13 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(tiles),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=_kernel_name("flash_bwd_dq", window),
     )(q, k, v, do, lse, delta)
 
 
 def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
-                   block_q, block_k, interpret, with_dq: bool = False):
+                   block_q, block_k, interpret, with_dq: bool = False,
+                   window=None):
     """(dk, dv), or with `with_dq` the kernel `flash_bwd_dkv_dq` and
     (dq, dk, dv): one more output, whose block is a (batch, head) row's
     whole dq, fetched nowhere and written back when the row is done, and
@@ -775,18 +877,23 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     BH, T, D = q.shape
     S, Dv = k.shape[1], v.shape[2]
     name = "flash_bwd_dkv_dq" if with_dq else "flash_bwd_dkv"
-    tiles, num_q, num_k = _grid(name, q, k, v, causal, block_q, block_k)
+    tiles, num_q, num_k = _grid(name, q, k, v, causal, block_q, block_k,
+                                window)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dkv_kernel,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
         scale=scale, causal=causal, seq_q=T, seq_k=S, with_dq=with_dq,
+        window=window,
     )
 
     def q_block(bh, ki, qi):
         if causal:
             qi = jnp.maximum(
                 qi, _first_q_with_body(ki, block_q, block_k, num_q))
+        if window is not None:  # past the band: the column's last tile
+            qi = jnp.minimum(
+                qi, _last_q_with_body(ki, block_q, block_k, num_q, window))
         return (bh, qi, 0)
 
     def k_block(bh, ki, qi):
@@ -828,7 +935,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         scratch_shapes=scratch_shapes,
         compiler_params=_compiler_params(tiles, inner),
         interpret=interpret,
-        name=name,
+        name=_kernel_name(name, window),
     )(q, k, v, do, lse, delta)
     if not with_dq:
         return out
@@ -836,16 +943,19 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     return (dq if rows == T else dq[:, :T]), dk, dv
 
 
-def _xla_attention_bhtd(q, k, v, *, causal, scale):
+def _xla_attention_bhtd(q, k, v, *, causal, scale, window=None):
     """Plain attention on [BH, T, D]: what `mha(impl="xla")` runs, the
     path of every platform but the TPU and the reference the kernels are
-    tested against."""
+    tested against. `window`: with `causal`, `query - key < window` too."""
     s = jnp.einsum(
         "btd,bsd->bts", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
     if causal:
         T, S = q.shape[1], k.shape[1]
         mask = jnp.arange(T)[:, None] >= jnp.arange(S)[None, :]
+        if window is not None:
+            mask = jnp.logical_and(
+                mask, jnp.arange(T)[:, None] - jnp.arange(S)[None, :] < window)
         s = jnp.where(mask[None], s, _BIG_NEG)
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -862,10 +972,14 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: bool = False,
     keep_ctx: bool = False,
+    window: Optional[int] = None,
 ):
     """Flash attention on q, k of [B, T, H, D] and v of [B, T, H, Dv], which
     gives [B, T, H, Dv] (grouped-query: H_kv may divide H). `scale` is
-    `1 / sqrt(D)` where the caller has none of its own.
+    `1 / sqrt(D)` where the caller has none of its own. `window`, with
+    `causal`: key `j` counts for query `i` where `j <= i` and
+    `i - j < window` (a token sees itself and the `window - 1` before it);
+    a window no shorter than the sequence is the causal kernel.
 
     `block_q` and `block_k` force every kernel's tile; `None` lets
     `flash_tiles` choose each kernel's from the shape. `keep_ctx` names the
@@ -873,6 +987,7 @@ def flash_attention(
     caller under `jax.checkpoint` whose policy keeps that name."""
     B, T, H, D = q.shape
     Hk, Dv = k.shape[2], v.shape[3]
+    window = _band(window, causal, k.shape[1])
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     if Hk != H:
@@ -884,8 +999,20 @@ def flash_attention(
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], Dv)
     of = _flash(qf, kf, vf, causal, scale, block_q, block_k, interpret,
-                keep_ctx)
+                keep_ctx, window)
     return of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
+
+
+def _band(window: Optional[int], causal: bool, seq_k: int) -> Optional[int]:
+    """`window` as the kernels take it: None where it hides nothing (no
+    key lies `seq_k` or more before a query)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(
+            f"a window of {window} needs causal attention and at least the "
+            "token itself")
+    return None if window >= seq_k else int(window)
 
 
 def resolve_impl(impl: str) -> str:
@@ -898,17 +1025,20 @@ def resolve_impl(impl: str) -> str:
 
 
 def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
-        impl: str = "auto", keep_ctx: bool = False):
+        impl: str = "auto", keep_ctx: bool = False,
+        window: Optional[int] = None):
     """Multi-head attention dispatch on q, k of [B, T, H, D] and v of
     [B, T, H, Dv]: [B, T, H, Dv].
 
     impl: 'auto' (pallas on TPU, XLA elsewhere) | 'pallas' | 'xla'.
-    `keep_ctx` is `flash_attention`'s; plain attention names its output.
+    `keep_ctx` and `window` are `flash_attention`'s; plain attention names
+    its output.
     """
     impl = resolve_impl(impl)
     if impl == "pallas":
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               keep_ctx=keep_ctx)
+                               keep_ctx=keep_ctx, window=window)
+    window = _band(window, causal, k.shape[1])
     B, T, H, D = q.shape
     Hk, Dv = k.shape[2], v.shape[3]
     if Hk != H:
@@ -920,7 +1050,8 @@ def mha(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], Dv)
-    of = _xla_attention_bhtd(qf, kf, vf, causal=causal, scale=scale)
+    of = _xla_attention_bhtd(qf, kf, vf, causal=causal, scale=scale,
+                             window=window)
     out = of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
     # kept, it saves the output alone: this backward needs its scores again
     return checkpoint_name(out, "attn_ctx") if keep_ctx else out

@@ -1,0 +1,621 @@
+"""Attention under a window beside full attention in one stack, on the CPU
+at a tiny size: the flash kernels' band in interpret mode against a masked
+softmax (forward and all three gradients), the tile program's counts by
+hand, the causal kernels' programs as they were, a layer of each type with
+its head count, gate and rotary recipe and the whole loss against the
+benchmark's plain reference, the shares of a routed layer against the uncut
+layer, the published pattern of 40 layers, and what the new leaves and
+widths mean to `param_shardings`, `_layer_widths` and `saved_activations`
+(`ray_tpu/ops/flash_attention.py`, `ray_tpu/models/transformer.py`)."""
+
+import dataclasses
+import hashlib
+import importlib
+import logging
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import laguna as reference
+from ray_tpu.models import TransformerConfig, make_train_step
+from ray_tpu.models import transformer as model
+from ray_tpu.models.transformer import (
+    param_shardings, saved_activations, segments, transformer_init,
+    transformer_loss_and_readings)
+from ray_tpu.ops import moe
+from ray_tpu.ops.flash_attention import flash_attention, flash_tiles, mha
+from ray_tpu.parallel import make_mesh
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+
+# config.json's `rope_parameters.full_attention`, without theta and the share
+YARN = {"rope_type": "yarn", "factor": 64, "beta_fast": 64, "beta_slow": 1,
+        "original_max_position_embeddings": 4096,
+        "attention_factor": 1.4158883083359672}
+PATTERN = ("full_attention", "sliding_attention", "sliding_attention",
+           "sliding_attention")
+LAGUNA = dict(
+    vocab_size=128, d_model=64, n_layers=5, n_heads=4, n_heads_sliding=8,
+    n_kv_heads=2, d_head=16, d_ff=32, d_ff_dense=96, d_ff_shared=32,
+    max_seq_len=64, norm_eps=1e-6, layer_types=PATTERN + PATTERN[:1],
+    n_dense_layers=1, sliding_window=8, attn_gate=True,
+    rope_theta=500000.0, rope_theta_sliding=10000.0, partial_rotary_factor=0.5,
+    # the ramp lies inside the tiny rotary width at an original context of 16
+    rope_scaling=tuple(sorted(
+        {**YARN, "original_max_position_embeddings": 16}.items())),
+    n_experts=8, experts_per_token=3, experts_held=(2, 2), n_shared_experts=1,
+    router_score="sigmoid", norm_topk_prob=True, routed_scaling_factor=2.5,
+    router_aux_loss_coef=0.001, router_z_loss_coef=0.0,
+    tied_embeddings=False, dtype=jnp.float32,
+)
+
+
+def key(i):
+    return jax.random.PRNGKey(i)
+
+
+def tiny(**over):
+    return TransformerConfig(**{**LAGUNA, **over})
+
+
+def as_reference_config(cfg):
+    return {**dataclasses.asdict(cfg), "dtype": "float32",
+            "rope_scaling": dict(cfg.rope_scaling)}
+
+
+def batch_of(cfg, rows=2, seq=32, seed=1):
+    ids = jax.random.randint(key(seed), (rows, seq + 1), 0, cfg.vocab_size)
+    return {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
+
+
+def masked_softmax(q, k, v, window):
+    """The band as it is written: `(j <= i) & (i - j < window)`."""
+    T, D = q.shape[1], q.shape[3]
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    s = jnp.einsum("bthd,bshd->bhts", q, k) / math.sqrt(D)
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    s = jnp.where((j <= i) & (i - j < window), s, -jnp.inf)
+    return jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, -1), v)
+
+
+# ------------------------------------------------------ the kernels' band
+
+# (T, window, block_q, block_k, D, Dv): the window smaller than a tile, equal
+# to one, no multiple of one, wider than one with unlike tiles, a sequence
+# that is no multiple of 128, v narrower than q and k, one key
+BANDS = [
+    (512, 64, 128, 128, 32, 32), (512, 128, 128, 128, 32, 32),
+    (512, 200, 128, 128, 32, 32), (512, 300, 128, 256, 32, 32),
+    (512, 256, 256, 128, 32, 32), (400, 130, 128, 128, 32, 32),
+    (384, 129, 128, 128, 48, 32), (256, 1, 128, 128, 32, 32),
+    (512, 511, 128, 128, 32, 32), (640, 257, None, None, 32, 32),
+]
+
+
+@pytest.mark.parametrize("two_kernels", [False, True], ids=["one", "two"])
+@pytest.mark.parametrize("shape", BANDS, ids=lambda s: "x".join(map(str, s)))
+def test_windowed_kernels_agree_with_a_masked_softmax(shape, two_kernels,
+                                                      monkeypatch):
+    T, window, bq, bk, D, Dv = shape
+    if two_kernels:
+        monkeypatch.setattr(fa, "flash_bwd_kernels", lambda *a, **kw: (
+            "flash_bwd_dq", "flash_bwd_dkv"))
+    q, k, v, do = (jax.random.normal(key(i), (1, T, heads, width))
+                   for i, (heads, width) in enumerate(
+                       [(4, D), (2, D), (2, Dv), (4, Dv)]))
+    kw = dict(causal=True, window=window, block_q=bq, block_k=bk,
+              interpret=True)
+    with jax.default_matmul_precision("highest"):
+        ours = flash_attention(q, k, v, **kw)
+        theirs = masked_softmax(q, k, v, window)
+        np.testing.assert_allclose(ours, theirs, rtol=2e-5, atol=2e-6)
+        grads = jax.grad(lambda *a: (flash_attention(*a, **kw) * do).sum(),
+                         (0, 1, 2))(q, k, v)
+        wanted = jax.grad(lambda *a: (masked_softmax(*a, window) * do).sum(),
+                          (0, 1, 2))(q, k, v)
+    for got, want in zip(grads, wanted):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [256, 300, 10 ** 6])
+def test_a_window_no_shorter_than_the_sequence_is_the_causal_kernel(window):
+    q, k, v = (jax.random.normal(key(i), (1, 256, 2, 32)) for i in range(3))
+    kw = dict(causal=True, interpret=True)
+
+    def text(**more):
+        return str(jax.make_jaxpr(jax.grad(lambda *a: flash_attention(
+            *a, **kw, **more).sum(), (0, 1, 2)))(q, k, v))
+
+    assert text(window=window) == text()
+    assert "flash_fwd_window" not in text(window=window)
+    assert "flash_fwd_window" in text(window=255)
+    np.testing.assert_array_equal(
+        flash_attention(q, k, v, window=window, **kw),
+        flash_attention(q, k, v, **kw))
+
+
+def test_keep_ctx_names_the_windowed_kernel_s_residuals():
+    q, k, v = (jax.random.normal(key(i), (1, 256, 2, 32)) for i in range(3))
+    kw = dict(causal=True, window=64, interpret=True, block_q=128, block_k=128)
+
+    def loss(keep):
+        return lambda *a: flash_attention(*a, keep_ctx=keep, **kw).sum()
+
+    kept = jax.checkpoint(
+        loss(True),
+        policy=jax.checkpoint_policies.save_only_these_names("attn_ctx"))
+    text = str(jax.make_jaxpr(jax.grad(kept, (0, 1, 2)))(q, k, v))
+    assert text.count("name=attn_ctx") == 2  # o and lse
+    assert text.count("name=flash_fwd_window") == 1  # not run again
+    again = str(jax.make_jaxpr(jax.grad(
+        jax.checkpoint(loss(False)), (0, 1, 2)))(q, k, v))
+    assert again.count("name=flash_fwd_window") == 2
+    with jax.default_matmul_precision("highest"):
+        for got, want in zip(jax.grad(kept, (0, 1, 2))(q, k, v),
+                             jax.grad(loss(False), (0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_the_windowed_calls_have_names_of_their_own():
+    q = jax.ShapeDtypeStruct((1, 512, 2, 32), jnp.float32)
+
+    def names(two, **kw):
+        text = str(jax.make_jaxpr(jax.grad(lambda *a: flash_attention(
+            *a, causal=True, interpret=True, **kw).sum(), (0, 1, 2)))(q, q, q))
+        return set(re.findall(r"name=(flash_\w+)", text))
+
+    assert names(False, window=128) == {
+        "flash_fwd_window", "flash_bwd_dkv_dq_window"}
+    assert names(False) == {"flash_fwd", "flash_bwd_dkv_dq"}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, "flash_bwd_kernels", lambda *a, **kw: (
+            "flash_bwd_dq", "flash_bwd_dkv"))
+        assert names(True, window=128) == {
+            "flash_fwd_window", "flash_bwd_dq_window", "flash_bwd_dkv_window"}
+    # the live metrics' prefixes still find them
+    for prefix in ("^flash_fwd", "^flash_bwd_dkv", "^flash_bwd_dq"):
+        assert any(re.match(prefix, name) for name in names(False, window=128)
+                   | {"flash_bwd_dq_window"})
+
+
+def test_a_window_needs_causal_attention():
+    q = jnp.zeros((1, 128, 1, 32))
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, q, q, window=8, interpret=True)
+    with pytest.raises(ValueError, match="at least the token"):
+        mha(q, q, q, causal=True, window=0, impl="xla")
+
+
+def test_plain_attention_takes_the_same_mask():
+    q, k, v = (jax.random.normal(key(i), (2, 96, heads, 16))
+               for i, heads in enumerate([4, 2, 2]))
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            mha(q, k, v, causal=True, window=17, impl="xla"),
+            masked_softmax(q, k, v, 17), rtol=2e-5, atol=2e-6)
+        np.testing.assert_array_equal(
+            mha(q, k, v, causal=True, window=96, impl="xla"),
+            mha(q, k, v, causal=True, impl="xla"))
+
+
+# ------------------------------------------------------- the tile program
+
+def by_hand(T, window, bq, bk):
+    """Tiles that hold a pair of the band, every pair looked at."""
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    band = (j <= i) & (i - j < window)
+    return sum(
+        bool(band[a:a + bq, b:b + bk].any())
+        for a in range(0, T, bq) for b in range(0, T, bk))
+
+
+@pytest.mark.parametrize("shape,tiles_with_a_body", [
+    # 16 rows: the diagonal tile and the one before it, but for the first
+    ((8192, 512, 512, 512), 31),
+    # 8 rows of 1024: the diagonal tile and half of the one before it
+    ((8192, 512, 1024, 1024), 15),
+    # 16 rows of 512 against k tiles of 1024: an even row's first query
+    # still sees the k tile before, an odd row's sees its own alone
+    ((8192, 512, 512, 1024), 23),
+    ((1024, 200, 128, 128), 8 + 7 + 6),  # 128 < 200 <= 256 + 1: three a row
+    # 130 = 128 + 2: a row's first query sees two keys of the tile before the
+    # last; the ragged fourth row has three tiles as the third has
+    ((400, 130, 128, 128), 1 + 2 + 3 + 3),
+])
+def test_flash_tiles_counts_the_band_s_tiles(shape, tiles_with_a_body):
+    T, window, bq, bk = shape
+    assert by_hand(T, window, bq, bk) == tiles_with_a_body
+    assert fa._active_tiles(T, T, bq, bk, True, window) == tiles_with_a_body
+    for kernel in ("flash_fwd", "flash_bwd_dkv_dq"):
+        tiles = flash_tiles(kernel, T, T, 128, jnp.bfloat16, block_q=bq,
+                            block_k=bk, window=window)
+        steps = -(-T // bq) * -(-T // bk)
+        assert tiles.grid_steps == steps
+        assert tiles.active_share == tiles_with_a_body / steps
+    # without the window the count is the triangle's
+    assert fa._active_tiles(T, T, bq, bk, True) == by_hand(T, T, bq, bk)
+
+
+def test_the_grid_s_bodies_and_fetches_are_the_band_s():
+    """What `_tile_kind` gives a body, and what the clamped index maps
+    name, tile by tile: no step outside the band computes, and none names a
+    block that no body of its row (column) takes."""
+    T, window, bq, bk = 1024, 200, 128, 256
+    num_q, num_k = T // bq, T // bk
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    band = (j <= i) & (i - j < window)
+    k_block = fa._k_block_under_q(True, bq, bk, window)
+    for qi in range(num_q):
+        with_body = []
+        for ki in range(num_k):
+            has_body, needs_mask = fa._tile_kind(
+                qi, ki, block_q=bq, block_k=bk, num_q=num_q, num_k=num_k,
+                causal=True, seq_q=T, seq_k=T, window=window)
+            tile = band[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            assert bool(has_body) == bool(tile.any())
+            if tile.any():
+                assert bool(needs_mask) == (not tile.all())
+                with_body.append(ki)
+        fetched = {int(k_block(0, qi, ki)[1]) for ki in range(num_k)}
+        assert fetched == set(with_body)
+    for ki in range(num_k):  # the dk/dv walk: q innermost
+        with_body = [qi for qi in range(num_q) if band[
+            qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk].any()]
+        first = int(fa._first_q_with_body(ki, bq, bk, num_q))
+        last = int(fa._last_q_with_body(ki, bq, bk, num_q, window))
+        assert (first, last) == (with_body[0], with_body[-1])
+
+
+def test_the_cost_model_chooses_for_the_band():
+    """At the cell's shape the causal kernels take 1024 x 1024; under the
+    window the tiles that divide the sequence are weighed, and a row of
+    512 x 1024 computes 23 tiles where 1024 x 1024 would compute 15 of four
+    times the pairs."""
+    causal = flash_tiles("flash_fwd", 8192, 8192, 128, jnp.bfloat16)
+    assert (causal.block_q, causal.block_k) == (1024, 1024)
+    for kernel in ("flash_fwd", "flash_bwd_dkv_dq"):
+        band = flash_tiles(kernel, 8192, 8192, 128, jnp.bfloat16, window=512)
+        assert 8192 % band.block_q == 0 and 8192 % band.block_k == 0
+        assert (band.block_q, band.block_k) == (512, 1024)
+        assert band.active_share == 23 / 128
+        assert band.cost_us < 0.5 * flash_tiles(
+            kernel, 8192, 8192, 128, jnp.bfloat16).cost_us
+    assert fa.flash_bwd_kernels(8192, 8192, 128, jnp.bfloat16, window=512) == (
+        "flash_bwd_dkv_dq",)
+    # a sequence no tile divides keeps every candidate
+    assert fa._block_candidates(400, whole=True) == [128, 256, 384]
+    assert fa._block_candidates(1024, whole=True) == [128, 256, 512, 1024]
+    assert fa._block_candidates(1024) == list(range(128, 1025, 128))
+
+
+def test_the_backward_logs_the_windowed_tiles_once(caplog):
+    fa._log_bwd_kernels.cache_clear()
+    q = jnp.ones((1, 512, 1, 32))
+    grad = jax.grad(lambda *a: flash_attention(
+        *a, causal=True, window=128, interpret=True).sum(), (0, 1, 2))
+    with caplog.at_level(logging.INFO, logger=fa.logger.name):
+        grad(q, q, q)
+        grad(q * 2, q, q)
+    lines = [r.getMessage() for r in caplog.records
+             if "flash window" in r.getMessage()]
+    assert len(lines) == 2  # the forward's and the one backward kernel's
+    assert "flash_fwd_window" in lines[0] and "% of them with a body" in lines[0]
+    assert "flash_bwd_dkv_dq_window" in lines[1]
+    assert "window 128 at T 512" in lines[1]
+
+
+# the causal kernels' programs as the parent traced them (jax 0.9.0):
+# sha256 of the jaxpr of the forward and backward at q, k [1, 384, 2, 128]
+# and v [1, 384, 2, 64] in bf16, interpret mode, addresses stripped
+PARENT_JAXPRS = {
+    (): "813b6907a7d62fce",
+    (("block_k", 256), ("block_q", 128)): "53467ddc2dcdfd65",
+    (("keep_ctx", True),): "b63b2462e16c5c2f",
+}
+
+
+@pytest.mark.parametrize("options", sorted(PARENT_JAXPRS), ids=str)
+def test_without_a_window_the_kernels_jaxprs_are_the_parent_s(options):
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests are of jaxprs as jax 0.9.0 prints them")
+    x = jax.ShapeDtypeStruct((1, 384, 2, 128), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 384, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True,
+                               **dict(options)).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, x, v))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    assert "window" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+        PARENT_JAXPRS[options])
+
+
+# ------------------------------------------------- the program, the reference
+
+def test_parameter_tree_of_the_cut():
+    cfg = tiny()
+    assert [(s.periods, [(k.op, k.routed) for k in s.layout])
+            for s in segments(cfg)] == [
+        (1, [("full_attention", False)]),
+        (1, [("sliding_attention", True)] * 3 + [("full_attention", True)])]
+    (dense,), period = transformer_init(key(0), cfg)["blocks"]
+    assert dense["wq"].shape == (1, 64, 4 * 16)
+    assert dense["w_gate_attn"].shape == (1, 64, 4)
+    assert dense["w_gate"].shape == (1, 64, 96) and "router" not in dense
+    for blk, heads in zip(period, (8, 8, 8, 4)):
+        assert blk["wq"].shape == (1, 64, heads * 16)
+        assert blk["wo"].shape == (1, heads * 16, 64)
+        assert blk["w_gate_attn"].shape == (1, 64, heads)
+        assert blk["wk"].shape == blk["wv"].shape == (1, 64, 2 * 16)
+        assert blk["w_gate"].shape == (1, 2, 64, 32)
+        assert blk["router"].shape == (1, 64, 8)
+        assert blk["ws_up"].shape == (1, 64, 32)
+    assert "w_gate_attn" in model.own_buffer_weights(dense)
+    # without the new fields nothing of a plain stack's tree changes
+    plain = transformer_init(key(0), TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=2))
+    assert sorted(plain["blocks"]) == [
+        "attn_norm", "mlp_norm", "w_down", "w_gate", "w_up", "wk", "wo", "wq",
+        "wv"]
+
+
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+def test_a_layer_of_each_type_agrees_with_the_reference(kind):
+    """Its head count, its rotary recipe, its mask and its gate."""
+    cfg = tiny()
+    heads = cfg.heads(kind)
+    assert heads == (8 if kind == "sliding_attention" else 4)
+    w = {k: v[0] for k, v in model._blocks_init(
+        key(3), cfg, model.LayerKind(kind, False), 1).items()}
+    x = jax.random.normal(key(4), (2, 40, 64))
+    positions = jnp.broadcast_to(jnp.arange(40), (2, 40))
+    with jax.default_matmul_precision("highest"):
+        ours = model._attention_layer(
+            x, w, positions, cfg, None, 1, op=kind)
+        theirs = reference.attention(x, w, as_reference_config(cfg), kind)
+        np.testing.assert_allclose(ours, theirs, rtol=2e-4, atol=2e-5)
+        # each part of it decides something, in the layers it belongs to
+        config = as_reference_config(cfg)
+        no_factor = {**config["rope_scaling"], "attention_factor": 1.0}
+        for changed, belongs_to in (
+                ({"sliding_window": 9}, "sliding_attention"),
+                ({"rope_theta_sliding": 500000.0}, "sliding_attention"),
+                ({"partial_rotary_factor": 1.0}, "full_attention"),
+                ({"rope_scaling": no_factor}, "full_attention")):
+            other = reference.attention(x, w, {**config, **changed}, kind)
+            moved = float(jnp.abs(other - theirs).max()) > 1e-3
+            assert moved == (kind == belongs_to), (kind, changed)
+        ungated = reference.attention(
+            x, {**w, "w_gate_attn": w["w_gate_attn"] * 0}, config, kind)
+        # a gate of sigmoid(0) halves the context
+        assert float(jnp.abs(ungated - theirs).max()) > 1e-3
+
+
+def test_both_rotary_recipes_by_hand():
+    cfg = tiny(rope_scaling=tuple(sorted(YARN.items())), d_head=128, n_heads=1,
+               n_heads_sliding=1, n_kv_heads=1)
+    theta, share, scaling = cfg.rotary("full_attention")
+    assert (theta, share) == (500000.0, 0.5) and scaling["factor"] == 64
+    assert cfg.rotary("sliding_attention") == (10000.0, 1.0, None)
+    # 64 turned columns, theta 500000, factor 64 over 4096: dim(r) =
+    # 64 ln(4096 / (2 pi r)) / (2 ln 500000) is 5.66 at 64 turns and 15.80 at
+    # one, so the ramp runs from pair 5 to pair 16
+    assert model.yarn_ramp_bounds(64, theta, scaling) == (5, 16)
+    inv_freq, factor = model.rope_frequencies(64, theta, scaling)
+    assert factor == pytest.approx(0.1 * math.log(64) + 1)
+    plain = 500000.0 ** (-np.arange(32) / 32)
+    np.testing.assert_allclose(inv_freq[:6], plain[:6], rtol=1e-6)
+    np.testing.assert_allclose(inv_freq[16:], plain[16:] / 64, rtol=1e-6)
+    ramp = (10 - 5) / 11
+    np.testing.assert_allclose(
+        inv_freq[10], plain[10] / 64 * ramp + plain[10] * (1 - ramp), rtol=1e-6)
+    # the first 64 columns of a head turn, times the factor; the rest pass
+    x = jax.random.normal(key(0), (1, 6, 1, 128))
+    positions = jnp.arange(6)[None]
+    turned = model._rotate(x, positions, theta, share, scaling)
+    np.testing.assert_array_equal(turned[..., 64:], x[..., 64:])
+    np.testing.assert_allclose(turned[0, 0, 0, :64], factor * x[0, 0, 0, :64],
+                               rtol=1e-6)
+    angle = 3 * float(inv_freq[2])
+    np.testing.assert_allclose(
+        turned[0, 3, 0, 2], factor * (x[0, 3, 0, 2] * math.cos(angle)
+                                      - x[0, 3, 0, 34] * math.sin(angle)),
+        rtol=1e-4, atol=1e-5)
+    whole = model._rotate(x, positions, 10000.0)
+    np.testing.assert_array_equal(whole, model._rope(x, positions, 10000.0))
+    tables = reference.rotary_tables(as_reference_config(cfg), "full_attention")
+    np.testing.assert_allclose(reference._rotate(x, *tables), turned,
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_program_agrees_with_the_plain_reference(dtype):
+    cfg = tiny(dtype=dtype)
+    params = transformer_init(key(0), cfg)
+    batch = batch_of(cfg)
+    config = as_reference_config(cfg)
+    with jax.default_matmul_precision("highest"):
+        (ours, readings), grads = jax.value_and_grad(
+            lambda p: transformer_loss_and_readings(p, batch, cfg),
+            has_aux=True)(params)
+        theirs, wanted = jax.value_and_grad(
+            lambda p: reference.loss(p, batch, config,
+                                     readings["expert_index"]))(params)
+    num = sum(float(jnp.sum((a - b) ** 2)) for a, b in zip(
+        jax.tree.leaves(grads), jax.tree.leaves(wanted)))
+    den = sum(float(jnp.sum(b ** 2)) for b in jax.tree.leaves(wanted))
+    if dtype == jnp.float32:
+        assert abs(ours - theirs) / theirs < 1e-6
+        assert math.sqrt(num / den) < 1e-5
+        chosen = reference.forward(params, batch, config)[1]
+        ours_chosen = jax.nn.one_hot(readings["expert_index"], 8).sum(-2) > 0
+        assert bool((chosen == ours_chosen).all())
+    else:
+        assert abs(ours - theirs) / theirs < 2e-3
+        assert math.sqrt(num / den) < 0.12
+    assert readings["expert_load"].shape == (4, 8)
+    assert int(readings["dropped_slots"].sum()) == 0
+    # the mean over the layers of E sum_e f_e P_e, sigmoid scores: about E / 2
+    assert 2.5 < float(readings["aux_loss"]) < 5.5
+
+
+def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
+    """8 experts held 2 a share: attention under the window, its gate and
+    the shared expert are what every share computes alike, and counted once;
+    the four shares' routed parts beside them are the uncut reference's
+    layer."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    cfg = tiny(n_layers=1, layer_types=("sliding_attention",),
+               n_dense_layers=0, experts_held=None)
+    w = jax.tree.map(lambda a: a[0], transformer_init(key(4), cfg)["blocks"])
+    x = jax.random.normal(key(5), (2, 48, 64))
+    positions = jnp.broadcast_to(jnp.arange(48), (2, 48))
+    config = as_reference_config(cfg)
+    names = ("w_gate", "w_up", "w_down")
+    with jax.default_matmul_precision("highest"):
+        after_attention = reference.attention(
+            x, w, config, "sliding_attention")
+        whole, _, _ = reference.routed_feed_forward(after_attention, w, config)
+        none_held = {**w, **{k: w[k][:0] for k in names}}
+        alike = reference.routed_feed_forward(
+            after_attention, none_held, {**config, "experts_held": (0, 0)})[0]
+        parts = []
+        for first in range(0, 8, 2):
+            share_cfg = dataclasses.replace(cfg, experts_held=(first, 2))
+            held = {**w, **{k: w[k][first:first + 2] for k in names}}
+            out, readings = model._block(
+                x, held, positions, None, share_cfg, None, 1, sliding=True)
+            assert int(readings["dropped_slots"]) == 0
+            assert readings["expert_load"].shape == (8,)
+            theirs, _, _ = reference.routed_feed_forward(
+                after_attention, held, {**config, "experts_held": (first, 2)})
+            np.testing.assert_allclose(out, theirs, rtol=2e-4, atol=2e-5)
+            parts.append(out - alike)  # this share's routed part alone
+    np.testing.assert_allclose(alike + sum(parts), whole, rtol=2e-4, atol=5e-5)
+    # the shared expert is no small part of it
+    assert float(jnp.abs(alike - after_attention).mean()) > 0.02
+
+
+def test_the_published_pattern_of_forty_layers_builds_and_steps():
+    """`layer_types`, `num_attention_heads_per_layer` and `mlp_layer_types`
+    as config.json has them, at a tiny width: the cut is a cut of depth."""
+    from chipbench import spec
+
+    published = spec.load_cell(
+        spec.ROOT, "lagunaxs2.tokens8k")["config"]["catalog_config"]
+    kinds = tuple(published["layer_types"])
+    assert len(kinds) == published["num_hidden_layers"] == 40
+    assert kinds == PATTERN * 10
+    cfg = tiny(d_model=32, d_head=8, n_heads=6, n_heads_sliding=8, d_ff=16,
+               d_ff_dense=32, d_ff_shared=16, vocab_size=64, n_layers=40,
+               layer_types=kinds,
+               n_dense_layers=published["mlp_layer_types"].count("dense"))
+    layers = cfg.layers
+    assert sum(k.op == "full_attention" for k in layers) == 10
+    assert sum(k.op == "sliding_attention" for k in layers) == 30
+    assert [k.routed for k in layers] == [
+        kind == "sparse" for kind in published["mlp_layer_types"]]
+    # 48 and 64 heads by layer type, as published, at this test's 6 and 8
+    assert [cfg.heads(k.op) * 8 for k in layers] == (
+        published["num_attention_heads_per_layer"])
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    init, step, _ = make_train_step(cfg, mesh)
+    state = init(key(0))
+    trees = [blk for seg in state["params"]["blocks"] for blk in seg]
+    assert len(trees) == 40
+    assert [blk["wq"].shape[-1] // 8 for blk in trees] == [
+        6 if kind == "full_attention" else 8 for kind in kinds]
+    batch = batch_of(cfg, rows=1, seq=24)
+    state, out = step(state, batch)
+    assert math.isfinite(float(out["loss"])) and float(out["grad_norm"]) > 0
+    assert out["expert_load"].shape == (39, 8)
+
+
+def test_a_window_under_a_sequence_axis_is_refused():
+    cfg = tiny()
+    w = {k: v[0] for k, v in model._blocks_init(
+        key(0), cfg, model.LayerKind("sliding_attention", False), 1).items()}
+    x = jnp.zeros((1, 16, 64))
+    with pytest.raises(NotImplementedError, match="ring attention has no band"):
+        model._block(x, w, jnp.zeros((1, 16), jnp.int32), None, cfg,
+                     "sequence", 2, sliding=True)
+
+
+# ------------------------------------------------------- shardings, remat
+
+def test_param_shardings_of_the_new_leaves():
+    cfg = tiny()
+    mesh = make_mesh({"fsdp": 4, "tensor": 2}, devices=jax.devices()[:8])
+    shard = param_shardings(mesh, cfg)
+    params = jax.eval_shape(lambda: transformer_init(key(0), cfg))
+    assert jax.tree.structure(shard) == jax.tree.structure(params)
+    (dense,), period = shard["blocks"]
+    for blk in (dense, *period):
+        # a column a head: cut along the heads, as wq's columns are
+        assert blk["w_gate_attn"].spec == blk["wq"].spec
+        assert blk["w_gate_attn"].spec[2] == "tensor"
+    plain = param_shardings(mesh, TransformerConfig(n_layers=2))
+    assert "w_gate_attn" not in plain["blocks"]
+
+
+def test_layer_widths_by_hand_for_both_types():
+    from chipbench import spec
+
+    cell = spec.load_cell(spec.ROOT, "lagunaxs2.tokens8k")
+    cfg = spec.load_code(spec.ROOT, "loops", "laguna").model_config(
+        cell["config"])
+    d = 2048
+    full, sliding = (model.LayerKind("full_attention", True),
+                     model.LayerKind("sliding_attention", True))
+    widths, params = model._layer_widths(cfg, sliding)
+    routed = d * 256 + 32 * 3 * d * 512 + 3 * d * 512
+    assert params == 37879808 + routed
+    assert widths == {
+        "attn_ctx": 64 * 128 + 64 * 2,  # o, and lse as one f32 column a head
+        "attn_res": d, "attn_qkv": (64 + 2 * 8) * 128,
+        "shared_gate": 512, "shared_up": 512}
+    widths, params = model._layer_widths(cfg, full)
+    assert params == 29458432 + routed
+    assert widths["attn_ctx"] == 48 * 128 + 48 * 2
+    assert widths["attn_qkv"] == (48 + 2 * 8) * 128
+    widths, params = model._layer_widths(
+        cfg, model.LayerKind("full_attention", False))
+    assert params == 29458432 + 3 * d * 8192
+    assert widths["mlp_gate"] == widths["mlp_up"] == 8192
+    # over the five layers, two sequences of 8192: bytes in bf16
+    saved = model._saved_bytes(cfg, 16384)
+    assert saved["attn_ctx"] == 16384 * 2 * (
+        2 * (48 * 128 + 96) + 3 * (64 * 128 + 128))
+    assert saved["attn_ctx"] / 1e9 == pytest.approx(1.22, abs=0.01)
+    # the widest block in its backward is a sliding one: 64 heads' q, k, v
+    # as the kernel takes them, and their lse and delta at a tile's lanes
+    state = 12 * 691623936
+    working = model._working_set_bytes(cfg, 16384, 4 * 691623936)
+    assert 3.0e9 < working < 4.2e9
+    kept = saved_activations(cfg, 16384, state, 4 * 691623936, int(15.84e9))
+    assert list(kept)[:1] in ([], ["attn_ctx"])
+    assert list(kept) == list(model._SAVE_ORDER[i] for i in sorted(
+        model._SAVE_ORDER.index(name) for name in kept))
+
+
+def test_flops_count_the_band_and_the_heads_by_type():
+    cfg = tiny()
+    assert model.keys_per_query(8192, 512) == pytest.approx(496.03, abs=0.005)
+    assert model.keys_per_query(8192) == 4096.5
+    assert model.keys_per_query(300, 512) == 150.5
+    matmul, attn, head = model._fwd_flops_per_token(cfg, 32)
+    d, dh = 64, 16
+    full = 2 * d * (4 + 2 * 2) * dh + 2 * 4 * dh * d + 2 * d * 4
+    sliding = 2 * d * (8 + 2 * 2) * dh + 2 * 8 * dh * d + 2 * d * 8
+    routed = 2 * d * 8 + (3 * 2 / 8) * 6 * d * 32 + 6 * d * 32
+    assert matmul == 2 * full + 3 * sliding + 6 * d * 96 + 4 * routed
+    band = (8 * 9 / 2 + 24 * 8) / 32
+    assert attn == 2 * 4 * 4 * dh * 16.5 + 3 * 4 * 8 * dh * band
+    assert head == 2 * d * 128

@@ -49,12 +49,13 @@ The tile program, the same in all four kernels:
   make the sum of two costs least: what every grid step costs whatever it
   holds, and the work of the tiles that have a body, of which the part above
   the causal diagonal, and under a window the part left of the band, is
-  wasted (`window=`: the tiles with a body are the band's, and those that
-  divide the sequence are weighed). It also returns the estimate of the VMEM the
-  tile needs and the `vmem_limit_bytes` handed to Mosaic (the default 16 MiB
-  where that is enough), the grid steps a (batch, head) row makes and the
-  share of them that have a body, and what the row's grid costs by the
-  sweeps' constants. `flash_attention(block_q=, block_k=)` force a tile,
+  wasted (`window=`: the tiles with a body are the band's, the grid is the
+  band's too, and the tiles that divide the sequence are weighed). It also
+  returns the estimate of the VMEM the tile needs and the
+  `vmem_limit_bytes` handed to Mosaic (the default 16 MiB where that is
+  enough), the grid steps a (batch, head) row makes and the share of them
+  that have a body, and what the row's grid costs by the sweeps'
+  constants. `flash_attention(block_q=, block_k=)` force a tile,
   for tests; `None` is the shape's choice.
 - Operands reach the MXU in the input's dtype. q, k, v and do go to
   `dot_general` as loaded, p and ds are cast to that dtype for the second
@@ -68,11 +69,20 @@ The tile program, the same in all four kernels:
   takes a body with no iota, compare or select. A tile wholly above the
   diagonal has no body, and its grid step fetches nothing: the index maps
   clamp the walked index to the nearest tile of that row (column) that has
-  one, so the step names the block already in VMEM. Under a window the same
-  holds on the band's other side: a tile wholly left of it (below it, in a
-  column) has no body and the index maps clamp there too, a tile the band's
-  lower edge crosses masks `query - key < window`, and a tile wholly inside
-  the band takes the bare body.
+  one, so the step names the block already in VMEM. Under a window a tile
+  the band's lower edge crosses masks `query - key < window`, and a tile
+  wholly inside the band takes the bare body.
+- Under a window the grid is the band's. A q row's walk over k (forward,
+  dq) is `band_k` steps long, the most key tiles any row's band crosses,
+  and step `j` of row `qi` is key tile `_first_k_with_body(qi) + j`; a key
+  column's walk over q (dk/dv, all three gradients) is `band_q` steps from
+  `_first_q_with_body(ki)`. No tile left of the band or below it is walked.
+  A row (column) whose band crosses fewer tiles, the first rows, the last
+  columns, a ragged end, has trailing steps past its last tile: they have
+  no body, and the index maps clamp them to that tile, so they fetch
+  nothing. The sums are zeroed at the walk's first step and flushed at its
+  last; the one-kernel backward zeroes a q tile's rows of dq at the first
+  key column whose band reaches them and rounds them out at the last.
 - Outputs leave in the input's dtype: o, and dq, dk, dv, which the flush
   rounds once from the f32 accumulator. lse and delta are f32 `[BH, T, 8]`.
 - Two widths. q, k, dq and dk are `D` wide, v, o, do, dv and the forward's
@@ -130,7 +140,7 @@ class FlashTiles(NamedTuple):
     """One kernel's tile for one shape, and what follows from it."""
     block_q: int
     block_k: int
-    grid_steps: int  # of one (batch, head) row: q tiles x k tiles
+    grid_steps: int  # of one (batch, head) row, as the grid is walked
     active_share: float  # share of those steps whose tile has a body
     vmem_bytes: int  # estimate of what the tile needs
     vmem_limit_bytes: int  # what Mosaic is told it may use
@@ -153,13 +163,47 @@ def _active_tiles(T, S, block_q, block_k, causal, window=None) -> int:
             min(num_k, _cdiv((qi + 1) * block_q, block_k))
             for qi in range(num_q)
         )
-    # row qi's tiles run from the one that holds the first key its first
-    # query sees to the one that holds its last query's own position
-    return sum(
-        min(num_k - 1, ((qi + 1) * block_q - 1) // block_k)
-        - max(qi * block_q - window + 1, 0) // block_k + 1
-        for qi in range(num_q)
-    )
+    return sum(last - first + 1 for first, last in _band_rows(
+        T, S, block_q, block_k, window))
+
+
+def _band_rows(T, S, block_q, block_k, window):
+    """(first, last) key tile with a body of every q row under a window:
+    from the tile that holds the first key the row's first query sees to the
+    one that holds its last query's own position (`_first_k_with_body`,
+    `_last_k_with_body`, inside the array). A row past every key it could
+    see (more queries than keys) has `first > last`."""
+    num_q, num_k = _cdiv(T, block_q), _cdiv(S, block_k)
+    return [(max(qi * block_q - window + 1, 0) // block_k,
+             min(num_k - 1, ((qi + 1) * block_q - 1) // block_k))
+            for qi in range(num_q)]
+
+
+def _band_cols(T, S, block_q, block_k, window):
+    """(first, last) q tile with a body of every key column under a window
+    (`_first_q_with_body`, `_last_q_with_body`): from the tile that holds
+    the column's first key's own query to the one that holds the last query
+    that sees its last key."""
+    num_q, num_k = _cdiv(T, block_q), _cdiv(S, block_k)
+    return [(ki * block_k // block_q,
+             min(num_q - 1, ((ki + 1) * block_k + window - 2) // block_q))
+            for ki in range(num_k)]
+
+
+_K_INNERMOST = ("flash_fwd", "flash_bwd_dq")  # the others walk q innermost
+
+
+def _inner_steps(kernel, T, S, block_q, block_k, window=None) -> int:
+    """Steps of `kernel`'s innermost grid dimension: every key tile of a q
+    row (forward, dq) or every q tile of a key column (dk/dv, all three
+    gradients), and under a window the most of them that the band crosses
+    in any row (column)."""
+    k_inner = kernel in _K_INNERMOST
+    if window is None:
+        return _cdiv(S, block_k) if k_inner else _cdiv(T, block_q)
+    spans = (_band_rows if k_inner else _band_cols)(
+        T, S, block_q, block_k, window)
+    return max(1, max(last - first + 1 for first, last in spans))
 
 
 def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
@@ -261,17 +305,19 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     steps, large ones in pairs above the causal diagonal that a diagonal
     tile computes and masks. A forced `block_q` or `block_k` is taken as
     given (cut to the sequence) and the other is chosen. With `window`
-    (causal, `query - key < window`) the tiles with a body are the band's:
-    a large tile then pays in pairs on both sides of the band, and a small
-    one in the grid steps of the tiles the band leaves out, which are
-    walked and hold nothing."""
+    (causal, `query - key < window`) the tiles with a body are the band's
+    and so is the grid (`_inner_steps`): a large tile pays in pairs on both
+    sides of the band, a small one in steps, and one whose band crosses
+    fewer tiles in some rows (columns) than in others in the trailing steps
+    of those."""
     itemsize = jnp.dtype(dtype).itemsize
     Dv = D if v_dim is None else v_dim
     step_us, rows_us, pairs_us = _COST_US[kernel]
     pairs_us = pairs_us * _pairs_factor(kernel, D, Dv)
 
     def plan(bq, bk):
-        steps = _cdiv(T, bq) * _cdiv(S, bk)
+        outer = _cdiv(T, bq) if kernel in _K_INNERMOST else _cdiv(S, bk)
+        steps = outer * _inner_steps(kernel, T, S, bq, bk, window)
         active = _active_tiles(T, S, bq, bk, causal, window)
         vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv, T)
         cost = steps * step_us + active * (
@@ -300,13 +346,19 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
     f32 sum and its block, `T` rows each: it is taken where that fits
     beside some tile, and the tile it leaves room for does not cost more in
     grid steps than the second pass saves: in bf16, causal, T up to about
-    21k at q and k 192 wide and 44k at 128 or 64."""
+    21k at q and k 192 wide and 44k at 128 or 64. Under a window its grid
+    comes to a q tile's dq only through a key column whose band reaches it:
+    where some q tile lies past every key it could see (more queries than
+    keys) the two kernels run, whose dq walks every q row."""
     def tiles(kernel):
         return flash_tiles(kernel, T, S, D, dtype, causal=causal,
                            block_q=block_q, block_k=block_k, v_dim=v_dim,
                            window=window)
 
     one, two = tiles("flash_bwd_dkv_dq"), ("flash_bwd_dq", "flash_bwd_dkv")
+    if window is not None and any(first > last for first, last in _band_rows(
+            T, S, one.block_q, one.block_k, window)):
+        return two
     if (one.vmem_limit_bytes <= _MAX_VMEM
             and one.cost_us <= sum(tiles(kernel).cost_us for kernel in two)):
         return ("flash_bwd_dkv_dq",)
@@ -321,7 +373,9 @@ def _tile_kind(qi, ki, *, block_q, block_k, num_q, num_k, causal,
     shape settles it, traced scalars where the grid position does.
     `seq_q=None` says padded q rows need no care (the forward: a row's
     output depends on that row alone, and padded rows are never written).
-    `window`: a pair counts where `query - key < window` too."""
+    `window`: a pair counts where `query - key < window` too, and the tile
+    is the band grid's logical one, which a short row's (column's) trailing
+    steps carry past the array's last: those have no body."""
     has_body, needs_mask = True, False
     if causal:
         # some key of the tile is at or before some query of it
@@ -335,6 +389,8 @@ def _tile_kind(qi, ki, *, block_q, block_k, num_q, num_k, causal,
         # and its first key is outside its last query's: the farthest pair
         needs_mask = jnp.logical_or(
             needs_mask, (qi + 1) * block_q - 1 - ki * block_k >= window)
+        has_body = jnp.logical_and(
+            has_body, jnp.logical_and(qi < num_q, ki < num_k))
     if seq_k % block_k:
         needs_mask = jnp.logical_or(needs_mask, ki == num_k - 1)
     if seq_q is not None and seq_q % block_q:
@@ -390,18 +446,20 @@ def _attn_fwd_kernel(
     q_ref, k_ref, v_ref,  # inputs
     o_ref,  # output
     acc_ref, m_ref, l_ref,  # VMEM scratch, persistent over the k grid dim
-    *, block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
-    causal: bool, seq_k: int, window: Optional[int] = None,
+    *, block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
+    scale: float, causal: bool, seq_k: int, window: Optional[int] = None,
 ):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)  # of `steps`: the row's walk over k
+    if window is not None:
+        ki = _first_k_with_body(qi, block_q, block_k, window) + step
     # seq_q=None: padded q rows need no care here (see _tile_kind)
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
                  seq_q=None, seq_k=seq_k, window=window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _BIG_NEG)
@@ -434,11 +492,11 @@ def _attn_fwd_kernel(
         pv = _dot(p.astype(v.dtype), v, _NN)  # [bq, Dv]
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
 
-    # Tiles strictly above the diagonal, or wholly left of the band,
-    # contribute nothing and have no body.
+    # Tiles strictly above the diagonal contribute nothing and have no
+    # body; nor has a step past the last tile of a row's band.
     _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == steps - 1)
     def _flush():
         l = l_ref[:, :1]
         o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -448,7 +506,7 @@ def _attn_fwd_kernel_lse(
     q_ref, k_ref, v_ref,
     o_ref, lse_ref,
     acc_ref, m_ref, l_ref,
-    *, num_k: int, **tile,
+    *, steps: int, **tile,
 ):
     """Forward that additionally writes LSE = m + log(l) per q row — the
     residual the tiled backward needs to re-derive tile softmax without
@@ -457,11 +515,11 @@ def _attn_fwd_kernel_lse(
 
     _attn_fwd_kernel(
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-        num_k=num_k, **tile,
+        steps=steps, **tile,
     )
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == steps - 1)
     def _flush_lse():
         # Per-q-row scalars must live on sublanes; the block's minor dim
         # must be 128-divisible OR equal the array dim, so an 8-wide
@@ -509,14 +567,15 @@ def _compiler_params(tiles: FlashTiles, inner=("parallel", "arbitrary")):
 
 
 def _grid(kernel, q, k, v, causal, block_q, block_k, window=None):
-    """(tiles, q tiles, k tiles) of `kernel` for q of [BH, T, D], k of
-    [BH, S, D] and v of [BH, S, Dv]; `block_q`, `block_k` force a tile or
-    are None."""
+    """(tiles, q tiles, k tiles, steps of the innermost grid dimension) of
+    `kernel` for q of [BH, T, D], k of [BH, S, D] and v of [BH, S, Dv];
+    `block_q`, `block_k` force a tile or are None."""
     T, S = q.shape[1], k.shape[1]
     tiles = flash_tiles(kernel, T, S, q.shape[2], q.dtype, causal=causal,
                         block_q=block_q, block_k=block_k, v_dim=v.shape[2],
                         window=window)
-    return tiles, _cdiv(T, tiles.block_q), _cdiv(S, tiles.block_k)
+    return (tiles, _cdiv(T, tiles.block_q), _cdiv(S, tiles.block_k),
+            _inner_steps(kernel, T, S, tiles.block_q, tiles.block_k, window))
 
 
 def _kernel_name(kernel: str, window) -> str:
@@ -529,21 +588,41 @@ def _q_block(bh, qi, ki):
     return (bh, qi, 0)
 
 
-def _k_block_under_q(causal, block_q, block_k, window=None):
+def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None):
     """Index map of K and V where k is walked innermost (forward, dq): under
     causal a step past the row's last tile with a body names that tile, the
-    block already in VMEM, and fetches nothing; under a window a step
-    before the row's first tile with a body names that one, which is then
-    there when the walk reaches it."""
+    block already in VMEM, and fetches nothing. Under a window the walk
+    starts at the row's first tile with a body (the band grid), and its
+    last may be the array's (`num_k`) before it is the diagonal's."""
     def k_block(bh, qi, ki):
+        if window is not None:
+            ki = jnp.minimum(
+                ki + _first_k_with_body(qi, block_q, block_k, window),
+                num_k - 1)
         if causal:
             ki = jnp.minimum(ki, _last_k_with_body(qi, block_q, block_k))
-        if window is not None:
-            ki = jnp.maximum(
-                ki, _first_k_with_body(qi, block_q, block_k, window))
         return (bh, ki, 0)
 
     return k_block
+
+
+def _q_block_under_k(causal, block_q, block_k, num_q, window=None):
+    """Index map of q, do, lse and delta where q is walked innermost (dk/dv,
+    all three gradients): under causal a step before the column's first tile
+    with a body names that tile, which is then there when the walk reaches
+    it. Under a window the walk starts at that tile (the band grid), and a
+    step past the column's last tile with a body names that one."""
+    def q_block(bh, ki, qi):
+        if window is not None:
+            qi = jnp.minimum(
+                qi + _first_q_with_body(ki, block_q, block_k, num_q),
+                _last_q_with_body(ki, block_q, block_k, num_q, window))
+        elif causal:
+            qi = jnp.maximum(
+                qi, _first_q_with_body(ki, block_q, block_k, num_q))
+        return (bh, qi, 0)
+
+    return q_block
 
 
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
@@ -553,8 +632,8 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
 
     BH, T, D = q.shape
     S, Dv = k.shape[1], v.shape[2]
-    tiles, num_q, num_k = _grid("flash_fwd", q, k, v, causal, block_q, block_k,
-                                window)
+    tiles, num_q, num_k, steps = _grid(
+        "flash_fwd", q, k, v, causal, block_q, block_k, window)
     block_q, block_k = tiles.block_q, tiles.block_k
 
     kernel = functools.partial(
@@ -563,13 +642,14 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         block_k=block_k,
         num_q=num_q,
         num_k=num_k,
+        steps=steps,
         scale=scale,
         causal=causal,
         seq_k=S,
         window=window,
     )
 
-    k_block = _k_block_under_q(causal, block_q, block_k, window)
+    k_block = _k_block_under_q(causal, block_q, block_k, window, num_k)
     out_shape = jax.ShapeDtypeStruct((BH, T, Dv), q.dtype)
     out_specs = pl.BlockSpec((1, block_q, Dv), _q_block)
     if with_lse:
@@ -580,7 +660,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         out_specs = [out_specs, pl.BlockSpec((1, block_q, 8), _q_block)]
     return pl.pallas_call(
         kernel,
-        grid=(BH, num_q, num_k),
+        grid=(BH, num_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, D), _q_block),
             pl.BlockSpec((1, block_k, D), k_block),
@@ -633,8 +713,9 @@ def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
                      window=None):
     """One line for each backward a process traces, as `saved_activations`
     has one for what it keeps: which kernels, at which tile and VMEM. Under
-    a window also the forward's tile, and of every kernel the share of its
-    grid steps that have a body: the rest are walked and hold nothing."""
+    a window also the forward's tile, and of every kernel the steps of its
+    grid, the band's, and the share of them that have a body: the rest are
+    the trailing steps of rows (columns) whose band crosses fewer tiles."""
     def tiles(kernel):
         return flash_tiles(kernel, T, S, D, dtype, causal=causal,
                            block_q=block_q, block_k=block_k, v_dim=Dv,
@@ -735,17 +816,20 @@ def _attn_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref,
     acc_ref,
-    *, block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
-    causal: bool, seq_q: int, seq_k: int, window: Optional[int] = None,
+    *, block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
+    scale: float, causal: bool, seq_q: int, seq_k: int,
+    window: Optional[int] = None,
 ):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)  # of `steps`: the row's walk over k
+    if window is not None:
+        ki = _first_k_with_body(qi, block_q, block_k, window) + step
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
                  seq_q=seq_q, seq_k=seq_k, window=window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -758,7 +842,7 @@ def _attn_bwd_dq_kernel(
 
     _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == steps - 1)
     def _flush():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
@@ -766,8 +850,8 @@ def _attn_bwd_dq_kernel(
 def _attn_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     *outputs_and_sums,
-    block_q: int, block_k: int, num_q: int, num_k: int, scale: float,
-    causal: bool, seq_q: int, seq_k: int, with_dq: bool = False,
+    block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
+    scale: float, causal: bool, seq_q: int, seq_k: int, with_dq: bool = False,
     window: Optional[int] = None,
 ):
     """dk and dv of k tile `ki`, summed over the q tiles the grid walks
@@ -775,8 +859,9 @@ def _attn_bwd_dkv_kernel(
     ds. Its f32 sum `dq_acc_ref` holds every q tile of the (batch, head)
     row, because the k tiles that add to one q tile's rows are a whole
     column of the grid apart; tile `qi`'s rows are zeroed in the first
-    column, summed over `ki` in ascending order as `_attn_bwd_dq_kernel`
-    sums them, and rounded once into the row's dq block in the last."""
+    column (under a window: the first whose band reaches them), summed over
+    `ki` in ascending order as `_attn_bwd_dq_kernel` sums them, and rounded
+    once into the row's dq block in the last."""
     from jax.experimental import pallas as pl
 
     if with_dq:
@@ -785,19 +870,25 @@ def _attn_bwd_dkv_kernel(
     else:
         dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = outputs_and_sums
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi = step = pl.program_id(2)  # of `steps`: the column's walk over q
+    if window is not None:
+        qi = _first_q_with_body(ki, block_q, block_k, num_q) + step
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
                  seq_q=seq_q, seq_k=seq_k, window=window)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
     if with_dq:
         rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        first_col = ki == 0  # of those that add to this q tile's dq
+        if window is not None:  # a short column's walk runs past `num_q`
+            first_col = jnp.logical_and(qi < num_q, ki == _first_k_with_body(
+                qi, block_q, block_k, window))
 
-        @pl.when(ki == 0)
+        @pl.when(first_col)
         def _init_dq():
             dq_acc_ref[rows, :] = jnp.zeros(
                 (block_q, dq_acc_ref.shape[1]), dq_acc_ref.dtype)
@@ -816,13 +907,18 @@ def _attn_bwd_dkv_kernel(
     # tile.
     _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(step == steps - 1)
     def _flush():
         dk_ref[0] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
     if with_dq:
-        @pl.when(ki == num_k - 1)
+        last_col = ki == num_k - 1
+        if window is not None:
+            last_col = jnp.logical_and(qi < num_q, ki == jnp.minimum(
+                _last_k_with_body(qi, block_q, block_k), num_k - 1))
+
+        @pl.when(last_col)
         def _flush_dq():
             dq_ref[0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
 
@@ -834,19 +930,20 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
 
     BH, T, D = q.shape
     S, Dv = k.shape[1], v.shape[2]
-    tiles, num_q, num_k = _grid(
+    tiles, num_q, num_k, steps = _grid(
         "flash_bwd_dq", q, k, v, causal, block_q, block_k, window)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dq_kernel,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
-        scale=scale, causal=causal, seq_q=T, seq_k=S, window=window,
+        steps=steps, scale=scale, causal=causal, seq_q=T, seq_k=S,
+        window=window,
     )
 
-    k_block = _k_block_under_q(causal, block_q, block_k, window)
+    k_block = _k_block_under_q(causal, block_q, block_k, window, num_k)
     return pl.pallas_call(
         kernel,
-        grid=(BH, num_q, num_k),
+        grid=(BH, num_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, D), _q_block),
             pl.BlockSpec((1, block_k, D), k_block),
@@ -877,24 +974,17 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     BH, T, D = q.shape
     S, Dv = k.shape[1], v.shape[2]
     name = "flash_bwd_dkv_dq" if with_dq else "flash_bwd_dkv"
-    tiles, num_q, num_k = _grid(name, q, k, v, causal, block_q, block_k,
-                                window)
+    tiles, num_q, num_k, steps = _grid(
+        name, q, k, v, causal, block_q, block_k, window)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dkv_kernel,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
-        scale=scale, causal=causal, seq_q=T, seq_k=S, with_dq=with_dq,
-        window=window,
+        steps=steps, scale=scale, causal=causal, seq_q=T, seq_k=S,
+        with_dq=with_dq, window=window,
     )
 
-    def q_block(bh, ki, qi):
-        if causal:
-            qi = jnp.maximum(
-                qi, _first_q_with_body(ki, block_q, block_k, num_q))
-        if window is not None:  # past the band: the column's last tile
-            qi = jnp.minimum(
-                qi, _last_q_with_body(ki, block_q, block_k, num_q, window))
-        return (bh, qi, 0)
+    q_block = _q_block_under_k(causal, block_q, block_k, num_q, window)
 
     def k_block(bh, ki, qi):
         return (bh, ki, 0)
@@ -921,7 +1011,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         inner = ("arbitrary", "arbitrary")  # dq is summed over k tiles too
     out = pl.pallas_call(
         kernel,
-        grid=(BH, num_k, num_q),
+        grid=(BH, num_k, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_block),
             pl.BlockSpec((1, block_k, D), k_block),

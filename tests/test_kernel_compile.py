@@ -157,6 +157,45 @@ def test_the_one_backward_kernel_compiles_at_the_cells_shapes(
         f"bf16[{bh},{t},{d}]", f"bf16[{bh},{t},{dv}]", f"bf16[{bh},{t},{d}]"]
 
 
+@pytest.mark.timeout(600)  # two kernels, seconds each; room under six workers
+def test_the_windowed_kernels_compile_on_the_band_grid_at_the_cell_s_shape(
+        v5e):
+    """`lagunaxs2.tokens8k`'s sliding layers: BH 128, T 8192, D 128, window
+    512, at the tile the rule picks for the band grid. The forward and the
+    one-kernel backward compile as one custom call each under the names
+    the metrics read, on a grid of (BH, row, tiles the band crosses), with
+    a VMEM limit inside what Mosaic may be given."""
+    import re
+
+    bh, t, d, window = 128, 8192, 128, 512
+    assert fa.flash_bwd_kernels(t, t, d, jnp.bfloat16, window=window) == (
+        "flash_bwd_dkv_dq",)
+    qkv, row = _shapes(v5e[0], bh, t, d)
+    chosen = dict(KERNEL, scale=d ** -0.5, block_q=None, block_k=None,
+                  window=window)
+    for case, kernel in (("fwd_lse", "flash_fwd"),
+                         ("bwd_dkv_dq", "flash_bwd_dkv_dq")):
+        tiles = fa.flash_tiles(kernel, t, t, d, jnp.bfloat16, window=window)
+        assert t % tiles.block_q == 0 and t % tiles.block_k == 0
+        # 18 % on the grid of every tile; the forward's odd rows of 512
+        # cross one key tile of 1024 where the even rows cross two
+        assert tiles.active_share == (
+            23 / 32 if kernel == "flash_fwd" else 31 / 32)
+        fn = _flash_case(case, chosen)
+        (call,) = [eqn for eqn in jax.make_jaxpr(fn)(
+            qkv, qkv, qkv, qkv, row, row).eqns
+            if eqn.primitive.name == "pallas_call"]
+        grid = call.params["grid_mapping"].grid
+        assert grid[0] == bh and grid[1] * grid[2] == tiles.grid_steps
+        assert grid[2] == 2  # the band crosses two tiles of a row (column)
+        limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        assert limit == tiles.vmem_limit_bytes <= fa._MAX_VMEM
+        text = jax.jit(fn).lower(
+            qkv, qkv, qkv, qkv, row, row).compile().as_text()
+        (name,) = [name for name, _ in _custom_calls(text)]
+        assert re.fullmatch(kernel + r"_window(\.\d+)?", name)
+
+
 def test_lm_head_cross_entropy_compiles_for_v5e(v5e):
     one = SingleDeviceSharding(v5e[0])
     hidden = jax.ShapeDtypeStruct((32, 1024, 768), jnp.bfloat16, sharding=one)

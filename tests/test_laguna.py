@@ -9,6 +9,7 @@ widths mean to `param_shardings`, `_layer_widths` and `saved_activations`
 (`ray_tpu/ops/flash_attention.py`, `ray_tpu/models/transformer.py`)."""
 
 import dataclasses
+import functools
 import hashlib
 import importlib
 import logging
@@ -214,77 +215,338 @@ def by_hand(T, window, bq, bk):
         for a in range(0, T, bq) for b in range(0, T, bk))
 
 
-@pytest.mark.parametrize("shape,tiles_with_a_body", [
-    # 16 rows: the diagonal tile and the one before it, but for the first
-    ((8192, 512, 512, 512), 31),
+@pytest.mark.parametrize("shape,tiles_with_a_body,band_k,band_q", [
+    # 16 rows: the diagonal tile and the one before it, but for the first;
+    # a column's own q tile and the one after it
+    ((8192, 512, 512, 512), 31, 2, 2),
     # 8 rows of 1024: the diagonal tile and half of the one before it
-    ((8192, 512, 1024, 1024), 15),
+    ((8192, 512, 1024, 1024), 15, 2, 2),
     # 16 rows of 512 against k tiles of 1024: an even row's first query
-    # still sees the k tile before, an odd row's sees its own alone
-    ((8192, 512, 512, 1024), 23),
-    ((1024, 200, 128, 128), 8 + 7 + 6),  # 128 < 200 <= 256 + 1: three a row
+    # still sees the k tile before, an odd row's sees its own alone; a
+    # column's keys are seen from its own two q tiles and the one after
+    ((8192, 512, 512, 1024), 23, 2, 3),
+    ((1024, 200, 128, 128), 8 + 7 + 6, 3, 3),  # 128 < 200 <= 257: three a row
     # 130 = 128 + 2: a row's first query sees two keys of the tile before the
     # last; the ragged fourth row has three tiles as the third has
-    ((400, 130, 128, 128), 1 + 2 + 3 + 3),
+    ((400, 130, 128, 128), 1 + 2 + 3 + 3, 3, 3),
 ])
-def test_flash_tiles_counts_the_band_s_tiles(shape, tiles_with_a_body):
+def test_flash_tiles_counts_the_band_s_tiles(shape, tiles_with_a_body, band_k,
+                                             band_q):
     T, window, bq, bk = shape
+    num_q, num_k = -(-T // bq), -(-T // bk)
     assert by_hand(T, window, bq, bk) == tiles_with_a_body
     assert fa._active_tiles(T, T, bq, bk, True, window) == tiles_with_a_body
-    for kernel in ("flash_fwd", "flash_bwd_dkv_dq"):
+    # the grid is the band's: a row's walk over k is `band_k` steps, a
+    # column's over q `band_q`, the most tiles any row (column) crosses
+    for kernel, steps in (("flash_fwd", num_q * band_k),
+                          ("flash_bwd_dq", num_q * band_k),
+                          ("flash_bwd_dkv", num_k * band_q),
+                          ("flash_bwd_dkv_dq", num_k * band_q)):
         tiles = flash_tiles(kernel, T, T, 128, jnp.bfloat16, block_q=bq,
                             block_k=bk, window=window)
-        steps = -(-T // bq) * -(-T // bk)
         assert tiles.grid_steps == steps
         assert tiles.active_share == tiles_with_a_body / steps
+        # without the window it is every tile's, as it was
+        assert flash_tiles(kernel, T, T, 128, jnp.bfloat16, block_q=bq,
+                           block_k=bk).grid_steps == num_q * num_k
     # without the window the count is the triangle's
     assert fa._active_tiles(T, T, bq, bk, True) == by_hand(T, T, bq, bk)
 
 
-def test_the_grid_s_bodies_and_fetches_are_the_band_s():
-    """What `_tile_kind` gives a body, and what the clamped index maps
-    name, tile by tile: no step outside the band computes, and none names a
-    block that no body of its row (column) takes."""
-    T, window, bq, bk = 1024, 200, 128, 256
-    num_q, num_k = T // bq, T // bk
-    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
-    band = (j <= i) & (i - j < window)
-    k_block = fa._k_block_under_q(True, bq, bk, window)
-    for qi in range(num_q):
-        with_body = []
-        for ki in range(num_k):
-            has_body, needs_mask = fa._tile_kind(
-                qi, ki, block_q=bq, block_k=bk, num_q=num_q, num_k=num_k,
-                causal=True, seq_q=T, seq_k=T, window=window)
-            tile = band[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
-            assert bool(has_body) == bool(tile.any())
-            if tile.any():
-                assert bool(needs_mask) == (not tile.all())
-                with_body.append(ki)
-        fetched = {int(k_block(0, qi, ki)[1]) for ki in range(num_k)}
-        assert fetched == set(with_body)
+@pytest.mark.parametrize("T,window,bq,bk", [
+    (1024, 200, 128, 256), (1024, 200, 256, 128), (1024, 512, 128, 128),
+    (400, 130, 128, 128), (400, 130, 384, 128), (384, 1, 128, 128)])
+def test_the_grid_s_bodies_and_fetches_are_the_band_s(T, window, bq, bk):
+    """What `_tile_kind` gives a body, and what the index maps name, step
+    by step of the band grid in both walks: every tile the band crosses is
+    visited exactly once, no step outside the band computes, and none names
+    a block that no body of its row (column) takes."""
+    num_q, num_k = -(-T // bq), -(-T // bk)
+    i, j = np.arange(num_q * bq)[:, None], np.arange(num_k * bk)[None, :]
+    band = (j <= i) & (i - j < window)  # by tiles, as `_tile_kind` decides
+
+    def crossed(qi, ki):
+        return band[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+
+    def kind(qi, ki):
+        return fa._tile_kind(
+            qi, ki, block_q=bq, block_k=bk, num_q=num_q, num_k=num_k,
+            causal=True, seq_q=T, seq_k=T, window=window)
+
+    rows = fa._band_rows(T, T, bq, bk, window)
+    band_k = fa._inner_steps("flash_fwd", T, T, bq, bk, window)
+    assert band_k == fa._inner_steps("flash_bwd_dq", T, T, bq, bk, window)
+    assert band_k == max(sum(bool(crossed(qi, ki).any())
+                             for ki in range(num_k)) for qi in range(num_q))
+    k_block = fa._k_block_under_q(True, bq, bk, window, num_k)
+    for qi in range(num_q):  # the forward's and dq's walk: k innermost
+        with_body = [ki for ki in range(num_k) if crossed(qi, ki).any()]
+        assert rows[qi] == (with_body[0], with_body[-1])
+        first = int(fa._first_k_with_body(qi, bq, bk, window))
+        assert first == with_body[0]
+        visited = []
+        for step in range(band_k):
+            has_body, needs_mask = kind(qi, first + step)
+            if bool(has_body):
+                visited.append(first + step)
+                assert bool(needs_mask) == (
+                    not crossed(qi, first + step).all()
+                    or (T % bk and first + step == num_k - 1)
+                    or (T % bq and qi == num_q - 1))
+            fetched = int(k_block(0, qi, step)[1])
+            assert fetched in with_body
+            assert fetched == (first + step if bool(has_body)
+                               else with_body[-1])
+        assert visited == with_body  # each once, in ascending order
+    cols = fa._band_cols(T, T, bq, bk, window)
+    band_q = fa._inner_steps("flash_bwd_dkv_dq", T, T, bq, bk, window)
+    assert band_q == fa._inner_steps("flash_bwd_dkv", T, T, bq, bk, window)
+    assert band_q == max(sum(bool(crossed(qi, ki).any())
+                             for qi in range(num_q)) for ki in range(num_k))
+    q_block = fa._q_block_under_k(True, bq, bk, num_q, window)
     for ki in range(num_k):  # the dk/dv walk: q innermost
-        with_body = [qi for qi in range(num_q) if band[
-            qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk].any()]
+        with_body = [qi for qi in range(num_q) if crossed(qi, ki).any()]
+        assert cols[ki] == (with_body[0], with_body[-1])
         first = int(fa._first_q_with_body(ki, bq, bk, num_q))
         last = int(fa._last_q_with_body(ki, bq, bk, num_q, window))
         assert (first, last) == (with_body[0], with_body[-1])
+        visited = []
+        for step in range(band_q):
+            has_body, _ = kind(first + step, ki)
+            if bool(has_body):
+                visited.append(first + step)
+            fetched = int(q_block(0, ki, step)[1])
+            assert fetched == (first + step if bool(has_body) else last)
+        assert visited == with_body
+
+
+def pallas_calls(fn, *args):
+    """{name: grid} of every `pallas_call` that `fn(*args)` traces."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = (
+                    eqn.params["grid_mapping"].grid,
+                    eqn.params["compiler_params"]["mosaic_tpu"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv", "flash_bwd_dkv_dq"])
+def test_the_grid_handed_to_pallas_call_is_the_band_s(kernel, monkeypatch):
+    """(BH, q tiles, key tiles the band crosses) where k is walked
+    innermost, (BH, key tiles, q tiles the band crosses) where q is; every
+    tile's without a window, as it was."""
+    if kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
+        monkeypatch.setattr(fa, "flash_bwd_kernels", lambda *a, **kw: (
+            "flash_bwd_dq", "flash_bwd_dkv"))
+    q = jax.ShapeDtypeStruct((2, 1024, 3, 32), jnp.float32)
+
+    def grids(**kw):
+        return pallas_calls(jax.grad(lambda *a: flash_attention(
+            *a, causal=True, interpret=True, **kw).sum(), (0, 1, 2)), q, q, q)
+
+    # window 200 at 128 x 256: a row crosses 2 key tiles, a column 4 q tiles
+    band = grids(window=200, block_q=128, block_k=256)
+    inner = 2 if kernel in ("flash_fwd", "flash_bwd_dq") else 4
+    outer = 8 if kernel in ("flash_fwd", "flash_bwd_dq") else 4
+    grid, params = band[kernel + "_window"]
+    assert grid == (6, outer, inner)
+    assert params.dimension_semantics == (
+        "parallel", "arbitrary" if kernel == "flash_bwd_dkv_dq"
+        else "parallel", "arbitrary")
+    tiles = flash_tiles(kernel, 1024, 1024, 32, jnp.float32, block_q=128,
+                        block_k=256, window=200)
+    assert tiles.grid_steps == outer * inner
+    assert params.vmem_limit_bytes == tiles.vmem_limit_bytes
+    causal = grids(block_q=128, block_k=256)
+    assert causal[kernel][0] == (6, outer, 12 - outer)
+    # at the shape's own tile too: one step a row where a tile holds the band
+    own = grids(window=200)[kernel + "_window"][0]
+    assert own[1] * own[2] == flash_tiles(
+        kernel, 1024, 1024, 32, jnp.float32, window=200).grid_steps
+
+
+def walk_the_grid(kernel, T, window, bq, bk, monkeypatch):
+    """The kernel's body run step by step over its grid with `pl.when`
+    recording what fires: {(outer, step): [names]}, and the logical tile of
+    every step that has a body."""
+    from jax.experimental import pallas as pl
+
+    num_q, num_k = -(-T // bq), -(-T // bk)
+    steps = fa._inner_steps(kernel, T, T, bq, bk, window)
+    at, fired = {}, {}
+
+    def when(condition):
+        def record(fn):
+            if bool(condition):
+                fired[at[1], at[2]].append(fn.__name__)
+        return record
+
+    monkeypatch.setattr(pl, "program_id", lambda axis: jnp.int32(at[axis]))
+    monkeypatch.setattr(pl, "when", when)
+    monkeypatch.setattr(pl, "multiple_of", lambda x, m: x)
+    shape = dict(block_q=bq, block_k=bk, num_q=num_q, num_k=num_k,
+                 steps=steps, scale=1.0, causal=True, seq_k=T, window=window)
+    if kernel == "flash_fwd":
+        body = functools.partial(fa._attn_fwd_kernel_lse, *[None] * 8, **shape)
+    elif kernel == "flash_bwd_dq":
+        body = functools.partial(
+            fa._attn_bwd_dq_kernel, *[None] * 8, seq_q=T, **shape)
+    else:
+        with_dq = kernel == "flash_bwd_dkv_dq"
+        body = functools.partial(
+            fa._attn_bwd_dkv_kernel, *[None] * (12 if with_dq else 10),
+            seq_q=T, with_dq=with_dq, **shape)
+    k_inner = kernel in ("flash_fwd", "flash_bwd_dq")
+    for outer in range(num_q if k_inner else num_k):
+        for step in range(steps):
+            at.update({1: outer, 2: step})
+            fired[outer, step] = []
+            body()
+    return fired, steps, num_q, num_k
+
+
+# (T, window, block_q, block_k): the first rows' band is one tile of two, the
+# last columns' likewise; unlike tiles; ragged ends; one key; all but one
+SHORT_ROWS = [(512, 64, 128, 128), (512, 300, 128, 256), (400, 130, 128, 128),
+              (384, 129, 128, 128), (256, 1, 128, 128), (512, 511, 128, 128),
+              (1024, 200, 256, 128)]
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv", "flash_bwd_dkv_dq"])
+@pytest.mark.parametrize("shape", SHORT_ROWS,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_the_sums_are_zeroed_and_flushed_once_a_walk(shape, kernel,
+                                                     monkeypatch):
+    """`_init` fires at a row's (column's) first step and `_flush` at its
+    last, once each, whether or not the band reaches that step; a body runs
+    at every tile the band crosses, once; and in the one-kernel backward a q
+    tile's dq is zeroed before its first body and rounded out after its
+    last, once each."""
+    T, window, bq, bk = shape
+    fired, steps, num_q, num_k = walk_the_grid(
+        kernel, T, window, bq, bk, monkeypatch)
+    k_inner = kernel in ("flash_fwd", "flash_bwd_dq")
+    spans = (fa._band_rows if k_inner else fa._band_cols)(T, T, bq, bk, window)
+    assert steps == max(last - first + 1 for first, last in spans)
+    assert steps < (num_k if k_inner else num_q) or window > T - bk
+    short = 0
+    for outer, (first, last) in enumerate(spans):
+        names = [fired[outer, step] for step in range(steps)]
+        flat = [name for at_step in names for name in at_step]
+        assert flat.count("_init") == 1 and "_init" in names[0]
+        assert flat.count("_flush") == 1 and "_flush" in names[-1]
+        if kernel == "flash_fwd":
+            assert flat.count("_flush_lse") == 1 and flat[-1] == "_flush_lse"
+        bodies = [step for step in range(steps) if "<lambda>" in names[step]]
+        assert bodies == list(range(last - first + 1))
+        assert all(names[step].count("<lambda>") == 1 for step in bodies)
+        short += last - first + 1 < steps
+    assert short or steps == 1  # some walk ends before the grid's does
+    if kernel != "flash_bwd_dkv_dq":
+        return
+    for qi, (first_k, last_k) in enumerate(
+            fa._band_rows(T, T, bq, bk, window)):
+        events = [(ki, name) for ki in range(num_k) for step in range(steps)
+                  for name in fired[ki, step]
+                  if spans[ki][0] + step == qi and name != "_init"
+                  and name != "_flush"]
+        assert events[0] == (first_k, "_init_dq")
+        assert events[-1] == (last_k, "_flush_dq")
+        assert [ki for ki, name in events if name == "<lambda>"] == list(
+            range(first_k, last_k + 1))  # ascending: dq's sum order
+        assert len(events) == last_k - first_k + 3
+    # and no step past a column's band touches a row of dq
+    assert sum(name in ("_init_dq", "_flush_dq") for names in fired.values()
+               for name in names) == 2 * num_q
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_one_kernel_s_dq_is_the_two_kernels_bit_for_bit(dtype):
+    """A band three tiles wide: a q tile's dq is summed over three key
+    columns of the one-kernel grid in the order `flash_bwd_dq` walks them."""
+    T, window = 1024, 200
+    q, k, v, do = (jax.random.normal(key(i), (2, T, 32)).astype(dtype)
+                   for i in range(4))
+    kw = dict(causal=True, scale=32 ** -0.5, block_q=128, block_k=128,
+              interpret=True, window=window)
+    assert fa._band_rows(T, T, 128, 128, window)[-1] == (5, 7)
+    o, lse = fa._flash_fwd(q, k, v, with_lse=True, **kw)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    delta = jnp.broadcast_to(delta[..., None], (2, T, 8))
+    dq, dk, dv = fa._flash_bwd_dkv(q, k, v, do, lse, delta, with_dq=True, **kw)
+    assert dq.dtype == dtype and bool(jnp.isfinite(dq).all())
+    np.testing.assert_array_equal(
+        dq, fa._flash_bwd_dq(q, k, v, do, lse, delta, **kw))
+    for got, want in zip((dk, dv),
+                         fa._flash_bwd_dkv(q, k, v, do, lse, delta, **kw)):
+        np.testing.assert_array_equal(got, want)
+    assert float(jnp.abs(dq.astype(jnp.float32)).max()) > 0.01
+
+
+def test_more_queries_than_keys_take_the_two_kernels():
+    """A q tile past every key it could see is reached by no column of the
+    one-kernel grid; `flash_bwd_dq` walks every q row and gives it zeros."""
+    assert fa._band_rows(512, 128, 128, 128, 64) == [
+        (0, 0), (0, 0), (1, 0), (2, 0)]
+    assert fa.flash_bwd_kernels(512, 128, 32, jnp.float32, window=64) == (
+        "flash_bwd_dq", "flash_bwd_dkv")
+    assert fa.flash_bwd_kernels(512, 512, 32, jnp.float32, window=64) == (
+        "flash_bwd_dkv_dq",)
+    q = jax.random.normal(key(0), (1, 512, 2, 32))
+    k, v = (jax.random.normal(key(i), (1, 128, 2, 32)) for i in (1, 2))
+    kw = dict(causal=True, window=64, interpret=True, block_q=128, block_k=128)
+    with jax.default_matmul_precision("highest"):
+        out = flash_attention(q, k, v, **kw)
+        dq, dk, dv = jax.grad(lambda *a: flash_attention(*a, **kw).sum(),
+                              (0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(
+            out[:, :128], masked_softmax(q[:, :128], k, v, 64),
+            rtol=2e-5, atol=2e-6)
+    # query 191 sees key 128, which is not there: nothing from 191 on
+    assert float(jnp.abs(out[:, 191:]).max()) == 0
+    assert float(jnp.abs(dq[:, 191:]).max()) == 0
+    # (queries 0 and 190 see one key: no gradient through a softmax of 1)
+    assert float(jnp.abs(dq[:, 1:190]).max(axis=(2, 3)).min()) > 0
+    assert bool(jnp.isfinite(dk).all()) and bool(jnp.isfinite(dv).all())
 
 
 def test_the_cost_model_chooses_for_the_band():
     """At the cell's shape the causal kernels take 1024 x 1024; under the
-    window the tiles that divide the sequence are weighed, and a row of
-    512 x 1024 computes 23 tiles where 1024 x 1024 would compute 15 of four
-    times the pairs."""
+    window the tiles that divide the sequence are weighed on the band's
+    grid, where a small tile no longer pays for the steps of the tiles the
+    band leaves out. The backward kernels take 512 x 512, two tiles a row
+    (column) and 31 of 32 steps with a body, which the sweep on one v5e
+    confirmed (PERF.md section 6, PR 41: 11.46 ms a call, the least of
+    {256, 512, 1024}^2). The forward takes 512 x 1024: 23 bodies of 32
+    steps, an odd row's second step past its band. (The sweep has its
+    512 x 512 a tenth faster: every body under a window is a masked one,
+    and `_COST_US` has no term for what the mask costs at 1024 keys.)"""
     causal = flash_tiles("flash_fwd", 8192, 8192, 128, jnp.bfloat16)
     assert (causal.block_q, causal.block_k) == (1024, 1024)
-    for kernel in ("flash_fwd", "flash_bwd_dkv_dq"):
+    for kernel, tile, bodies in (("flash_fwd", (512, 1024), 23),
+                                 ("flash_bwd_dkv_dq", (512, 512), 31),
+                                 ("flash_bwd_dq", (512, 512), 31),
+                                 ("flash_bwd_dkv", (512, 512), 31)):
         band = flash_tiles(kernel, 8192, 8192, 128, jnp.bfloat16, window=512)
         assert 8192 % band.block_q == 0 and 8192 % band.block_k == 0
-        assert (band.block_q, band.block_k) == (512, 1024)
-        assert band.active_share == 23 / 128
-        assert band.cost_us < 0.5 * flash_tiles(
+        assert (band.block_q, band.block_k) == tile
+        assert band.grid_steps == 32  # 128 and 256 on the grid of every tile
+        assert band.active_share == bodies / 32
+        assert band.cost_us < 0.4 * flash_tiles(
             kernel, 8192, 8192, 128, jnp.bfloat16).cost_us
+        # by the constants as they are, at BH 128: ms a call
+        assert band.cost_us * 128 / 1e3 == pytest.approx(
+            {"flash_fwd": 8.18, "flash_bwd_dkv_dq": 9.12,
+             "flash_bwd_dq": 6.94, "flash_bwd_dkv": 7.88}[kernel], abs=0.005)
     assert fa.flash_bwd_kernels(8192, 8192, 128, jnp.bfloat16, window=512) == (
         "flash_bwd_dkv_dq",)
     # a sequence no tile divides keeps every candidate

@@ -100,7 +100,7 @@ from __future__ import annotations
 import logging
 import math
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
@@ -1402,6 +1402,13 @@ def _scan_bytes_per_token(cfg: TransformerConfig) -> int:
     return 4 * (cfg.mamba_inner * cfg.ssm_state // Q + 7 * G * 128 + 2 * H)
 
 
+@lru_cache(maxsize=None)
+def _whole_param_bytes(cfg: TransformerConfig) -> int:
+    """float32 bytes of `cfg`'s parameters, all of them on one device."""
+    return 4 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+        lambda: transformer_init(jax.random.PRNGKey(0), cfg))))
+
+
 def _working_set_bytes(cfg: TransformerConfig, tokens: int,
                        param_bytes: int) -> int:
     """What the step that keeps nothing holds on a device beside its state
@@ -1411,8 +1418,10 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
 
     A block in its backward is taken as every value of its widest layer at
     once: the named ones, the two normed inputs, the stream's cotangent,
-    q, k and v as the kernel takes them (heads repeated, as many as the
-    layer's operator has; latent attention's q and k at two tiles of lanes),
+    q, k and v as the kernel takes them (q at the heads the layer's
+    operator has, k and v at the key-value heads, which the kernels' index
+    maps share among a group; latent attention's k and v at as many heads as
+    q, q and k at two tiles of lanes),
     lse and delta at a tile's 128 lanes,
     the feed-forward's (and the shared experts') hidden product, a
     routed layer's dispatched rows and what a mixer's scan holds by the
@@ -1422,15 +1431,15 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     and their float32 gradient before it is scattered. Against the chip
     (`bytes_in_use + bytes_reserved` less state and gradients; PERF.md
     section 6, PR 33), GB: 4.06 for 4.03 at Mistral's widths on one chip
-    and 3.96 for 3.18 on four; 3.32 for 1.77 and 4.64 for 2.23 where every
+    (PR 42 took 0.201 off both: k and v at their own heads, and the chip's
+    peak fell by 0.201) and 3.96 for 3.18 on four; 3.32 for 1.77 and 4.64 for 2.23 where every
     scan is one layer long and the compiler frees an unrolled block's
     values as it goes. It errs to the full side: a name too few costs a
     percent, a step that asks for the chip's last GiB is compiled to fit
     and runs slower than the one that keeps nothing."""
     d = cfg.d_model
     item = jnp.dtype(cfg.dtype).itemsize
-    whole = 4 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
-        lambda: transformer_init(jax.random.PRNGKey(0), cfg))))
+    whole = _whole_param_bytes(cfg)
     sharded = param_bytes < whole
     block = 0
     for kind in set(cfg.layers):
@@ -1452,7 +1461,7 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
             width += (h * (2 * _tile_lanes(qk) + _tile_lanes(cfg.v_head_dim))
                       + 2 * h * 128 * 4 // item)
         elif kind.op is not None:
-            width += (3 * h * _tile_lanes(cfg.head_dim)
+            width += ((h + 2 * cfg.kv_heads) * _tile_lanes(cfg.head_dim)
                       + 2 * h * 128 * 4 // item)
         if kind.routed:
             width += (cfg.experts_per_token * (d + cfg.ff_dim)

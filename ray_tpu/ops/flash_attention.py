@@ -27,6 +27,22 @@ runs: 8.4 MB of f32 and a 4.2 MB block at T 8192, D 192) and logs the choice
 once; where it does not, `flash_bwd_dq` (k innermost) and `flash_bwd_dkv` run
 as before, each making the tile for itself, seven matmuls between them.
 
+Grouped-query attention makes no copy of k or v. They reach every kernel at
+their own heads, `[B*Hk, S, D]` and `[B*Hk, S, Dv]`, and with heads folded
+batch-major the index maps name the key-value row of query row `bh`:
+`bh // group`, `group = H / Hk`. The forward's and dq's grids, bodies and
+values are those of the kernels fed repeated k and v. dk and dv leave at
+`[B*Hk, S, ...]`, summed over a group's heads in f32 in VMEM and rounded
+once: `flash_bwd_dkv` walks the group's heads inside a key column, grid
+`(B*Hk, key tiles, group * q steps)`, with the key tile's sums it has;
+`flash_bwd_dkv_dq` takes the group's head as a grid dimension of its own,
+`(B*Hk, group, key tiles, q steps)`, so that a head's dq row is complete when
+its key walk ends, and holds the key-value head's whole dk and dv, `S` rows
+each, as it holds the head's dq (8.4 MB of f32 and 4.2 MB of blocks twice
+more at S 8192, D 128 in bf16, whatever the group; `flash_tiles(group=)`
+prices them). With as many key heads as query heads every grid, spec,
+scratch and body is the statement it was.
+
 The four `pallas_call`s are named `flash_fwd` (with or without the lse
 output), `flash_bwd_dkv_dq`, `flash_bwd_dq` and `flash_bwd_dkv`: the names a
 profiler trace and the compiled HLO show, and the ones the benchmark's
@@ -84,7 +100,9 @@ The tile program, the same in all four kernels:
   last; the one-kernel backward zeroes a q tile's rows of dq at the first
   key column whose band reaches them and rounds them out at the last.
 - Outputs leave in the input's dtype: o, and dq, dk, dv, which the flush
-  rounds once from the f32 accumulator. lse and delta are f32 `[BH, T, 8]`.
+  rounds once from the f32 accumulator (dk and dv once a key-value head:
+  the sum over a group's query heads is part of that accumulator). lse and
+  delta are f32 `[BH, T, 8]`.
 - Two widths. q, k, dq and dk are `D` wide, v, o, do, dv and the forward's
   accumulator `Dv` wide (latent attention: 128 + 64 rotary against 128).
   A block's last dimension is the array's whole width, so 192 goes to the
@@ -207,12 +225,14 @@ def _inner_steps(kernel, T, S, block_q, block_k, window=None) -> int:
 
 
 def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
-                T=None) -> int:
+                T=None, S=None, group: int = 1) -> int:
     """Blocks in flight (double-buffered), scratch and the body's live
     [bq, bk] tiles, for q and k `D` wide and v `Dv` wide (`D` where None).
     A VMEM row is whole tiles of 128 lanes whatever the width is. The one
     kernel that makes all three gradients also holds a (batch, head) row's
-    whole dq, `T` rows in whole q tiles: its f32 sum and its block."""
+    whole dq, `T` rows in whole q tiles: its f32 sum and its block; and
+    where `group` query heads share a key-value head, that head's whole dk
+    and dv, `S` rows in whole key tiles, in place of one key tile's."""
     qk = _cdiv(D, _LANES) * _LANES
     vo = qk if Dv is None else _cdiv(Dv, _LANES) * _LANES
     q_qk, q_vo = block_q * qk, block_q * vo  # elements: q, dq; o, do
@@ -231,6 +251,10 @@ def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
             dq_row = _cdiv(T, block_q) * q_qk
             blocks += dq_row * itemsize
             scratch += dq_row * 4
+            if group > 1:  # dk and dv leave and are summed by the row too
+                more = (_cdiv(S, block_k) - 1) * (k_qk + k_vo)
+                blocks += more * itemsize
+                scratch += more * 4
     f32_tiles, dtype_tiles = _LIVE_TILES[kernel]
     live = block_q * block_k * (4 * f32_tiles + itemsize * dtype_tiles)
     return 2 * blocks + scratch + live
@@ -296,11 +320,12 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
                 causal: bool = True, block_q: Optional[int] = None,
                 block_k: Optional[int] = None,
                 v_dim: Optional[int] = None,
-                window: Optional[int] = None) -> FlashTiles:
+                window: Optional[int] = None, group: int = 1) -> FlashTiles:
     """The tile of `kernel` (`flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv`,
     `flash_bwd_dkv_dq`) for q of [*, T, D] and k of [*, S, D] and v of
-    [*, S, v_dim] (`D` where None) in `dtype`. Pure: the shape decides,
-    nothing is asked of a device. Among the tiles that fit VMEM it takes
+    [*, S, v_dim] (`D` where None) in `dtype`, `group` query heads to a
+    key-value head. Pure: the shape decides, nothing is asked of a device.
+    Among the tiles that fit VMEM it takes
     the one whose grid costs least by `_COST_US`: small tiles pay in grid
     steps, large ones in pairs above the causal diagonal that a diagonal
     tile computes and masks. A forced `block_q` or `block_k` is taken as
@@ -319,7 +344,7 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
         outer = _cdiv(T, bq) if kernel in _K_INNERMOST else _cdiv(S, bk)
         steps = outer * _inner_steps(kernel, T, S, bq, bk, window)
         active = _active_tiles(T, S, bq, bk, causal, window)
-        vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv, T)
+        vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv, T, S, group)
         cost = steps * step_us + active * (
             rows_us * bq / 1024 + pairs_us * bq * bk / 2 ** 20)
         return FlashTiles(bq, bk, steps, active / steps, vmem,
@@ -337,7 +362,8 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
                       block_q: Optional[int] = None,
                       block_k: Optional[int] = None,
                       v_dim: Optional[int] = None,
-                      window: Optional[int] = None) -> Tuple[str, ...]:
+                      window: Optional[int] = None,
+                      group: int = 1) -> Tuple[str, ...]:
     """The kernels of one backward, for the shapes `flash_tiles` takes:
     `("flash_bwd_dkv_dq",)`, all three gradients from one pass over the
     score tiles, or `("flash_bwd_dq", "flash_bwd_dkv")`, which make every
@@ -349,11 +375,14 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
     21k at q and k 192 wide and 44k at 128 or 64. Under a window its grid
     comes to a q tile's dq only through a key column whose band reaches it:
     where some q tile lies past every key it could see (more queries than
-    keys) the two kernels run, whose dq walks every q row."""
+    keys) the two kernels run, whose dq walks every q row. With `group`
+    query heads to a key-value head it also holds that head's whole dk and
+    dv, `S` rows each, summed over the group (8.4 MB of f32 and 8.4 MB of
+    blocks more at S 8192, D 128 in bf16, whatever the group)."""
     def tiles(kernel):
         return flash_tiles(kernel, T, S, D, dtype, causal=causal,
                            block_q=block_q, block_k=block_k, v_dim=v_dim,
-                           window=window)
+                           window=window, group=group)
 
     one, two = tiles("flash_bwd_dkv_dq"), ("flash_bwd_dq", "flash_bwd_dkv")
     if window is not None and any(first > last for first, last in _band_rows(
@@ -568,12 +597,12 @@ def _compiler_params(tiles: FlashTiles, inner=("parallel", "arbitrary")):
 
 def _grid(kernel, q, k, v, causal, block_q, block_k, window=None):
     """(tiles, q tiles, k tiles, steps of the innermost grid dimension) of
-    `kernel` for q of [BH, T, D], k of [BH, S, D] and v of [BH, S, Dv];
+    `kernel` for q of [BH, T, D], k of [BHk, S, D] and v of [BHk, S, Dv];
     `block_q`, `block_k` force a tile or are None."""
     T, S = q.shape[1], k.shape[1]
     tiles = flash_tiles(kernel, T, S, q.shape[2], q.dtype, causal=causal,
                         block_q=block_q, block_k=block_k, v_dim=v.shape[2],
-                        window=window)
+                        window=window, group=q.shape[0] // k.shape[0])
     return (tiles, _cdiv(T, tiles.block_q), _cdiv(S, tiles.block_k),
             _inner_steps(kernel, T, S, tiles.block_q, tiles.block_k, window))
 
@@ -588,12 +617,15 @@ def _q_block(bh, qi, ki):
     return (bh, qi, 0)
 
 
-def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None):
+def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None,
+                     group: int = 1):
     """Index map of K and V where k is walked innermost (forward, dq): under
     causal a step past the row's last tile with a body names that tile, the
     block already in VMEM, and fetches nothing. Under a window the walk
     starts at the row's first tile with a body (the band grid), and its
-    last may be the array's (`num_k`) before it is the diagonal's."""
+    last may be the array's (`num_k`) before it is the diagonal's. With
+    `group` query heads to a key-value head, heads folded batch-major, the
+    key-value row of query row `bh` is `bh // group`."""
     def k_block(bh, qi, ki):
         if window is not None:
             ki = jnp.minimum(
@@ -601,7 +633,7 @@ def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None):
                 num_k - 1)
         if causal:
             ki = jnp.minimum(ki, _last_k_with_body(qi, block_q, block_k))
-        return (bh, ki, 0)
+        return (bh if group == 1 else bh // group, ki, 0)
 
     return k_block
 
@@ -649,7 +681,8 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         window=window,
     )
 
-    k_block = _k_block_under_q(causal, block_q, block_k, window, num_k)
+    k_block = _k_block_under_q(causal, block_q, block_k, window, num_k,
+                               group=BH // k.shape[0])
     out_shape = jax.ShapeDtypeStruct((BH, T, Dv), q.dtype)
     out_specs = pl.BlockSpec((1, block_q, Dv), _q_block)
     if with_lse:
@@ -710,23 +743,27 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 @functools.lru_cache(maxsize=None)
 def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
-                     window=None):
+                     window=None, group=1):
     """One line for each backward a process traces, as `saved_activations`
-    has one for what it keeps: which kernels, at which tile and VMEM. Under
+    has one for what it keeps: which kernels, at which tile and VMEM, and
+    how many query heads read a key-value head through the index maps. Under
     a window also the forward's tile, and of every kernel the steps of its
     grid, the band's, and the share of them that have a body: the rest are
     the trailing steps of rows (columns) whose band crosses fewer tiles."""
     def tiles(kernel):
         return flash_tiles(kernel, T, S, D, dtype, causal=causal,
                            block_q=block_q, block_k=block_k, v_dim=Dv,
-                           window=window)
+                           window=window, group=group)
 
+    heads = ("no group" if group == 1 else
+             f"{group} query heads a key-value head by index map")
     for kernel in kernels:
         t = tiles(kernel)
         logger.info(
             "flash backward at T %d, S %d, D %d, Dv %d, %s: %s, tile %d x "
-            "%d, VMEM %d bytes of a limit of %d", T, S, D, Dv, dtype, kernel,
-            t.block_q, t.block_k, t.vmem_bytes, t.vmem_limit_bytes)
+            "%d, VMEM %d bytes of a limit of %d, %s", T, S, D, Dv, dtype,
+            kernel, t.block_q, t.block_k, t.vmem_bytes, t.vmem_limit_bytes,
+            heads)
     if window is None:
         return
     for kernel in ("flash_fwd", *kernels):
@@ -754,11 +791,12 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
     # Same sublane-aligned [BH, T, 8] layout as lse.
     delta = jnp.broadcast_to(delta[..., None], (BH, T, 8))
     shape = (T, k.shape[1], q.shape[2])
+    group = BH // k.shape[0]
     kernels = flash_bwd_kernels(*shape, q.dtype, causal=causal,
                                 block_q=block_q, block_k=block_k,
-                                v_dim=v.shape[2], window=window)
+                                v_dim=v.shape[2], window=window, group=group)
     _log_bwd_kernels(kernels, *shape, v.shape[2], jnp.dtype(q.dtype).name,
-                     causal, block_q, block_k, window=window)
+                     causal, block_q, block_k, window=window, group=group)
     tile = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k,
                 interpret=interpret, window=window)
     if kernels == ("flash_bwd_dkv_dq",):
@@ -852,7 +890,7 @@ def _attn_bwd_dkv_kernel(
     *outputs_and_sums,
     block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
     scale: float, causal: bool, seq_q: int, seq_k: int, with_dq: bool = False,
-    window: Optional[int] = None,
+    window: Optional[int] = None, group: int = 1,
 ):
     """dk and dv of k tile `ki`, summed over the q tiles the grid walks
     innermost. `with_dq` (`flash_bwd_dkv_dq`): dq too, from the same p and
@@ -861,7 +899,17 @@ def _attn_bwd_dkv_kernel(
     column of the grid apart; tile `qi`'s rows are zeroed in the first
     column (under a window: the first whose band reaches them), summed over
     `ki` in ascending order as `_attn_bwd_dq_kernel` sums them, and rounded
-    once into the row's dq block in the last."""
+    once into the row's dq block in the last.
+
+    With `group` query heads to a key-value head dk and dv are summed over
+    the group's heads too, in f32, and rounded once. Without dq the column's
+    walk runs over the group's heads, `group * steps` long, and the sums
+    are a key tile's as they were. With dq the group's head is a grid
+    dimension outside the key tiles (a head's dq row is done when its key
+    walk ends), so the sums and the output blocks hold the key-value head's
+    whole row, as dq's do: tile `ki`'s rows are zeroed at its first step
+    under the group's first head and rounded out at its last under the
+    group's last."""
     from jax.experimental import pallas as pl
 
     if with_dq:
@@ -869,17 +917,29 @@ def _attn_bwd_dkv_kernel(
          dk_acc_ref, dv_acc_ref, dq_acc_ref) = outputs_and_sums
     else:
         dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = outputs_and_sums
-    ki = pl.program_id(1)
-    qi = step = pl.program_id(2)  # of `steps`: the column's walk over q
+    acc, out = ..., 0  # tile `ki` in dk's and dv's sums, and in their blocks
+    if group == 1 or not with_dq:
+        ki = pl.program_id(1)
+        # of `group * steps`: the column's walk over q, a head after another
+        qi = step = walk = pl.program_id(2)
+        if group > 1:
+            qi = step = jax.lax.rem(walk, steps)
+    else:  # grid (key-value row, head of its group, ki, step)
+        head, ki = pl.program_id(1), pl.program_id(2)
+        qi = step = pl.program_id(3)
+        walk = head * steps + step  # of tile `ki`'s sums
+        acc = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        out = (0, acc)
     if window is not None:
         qi = _first_q_with_body(ki, block_q, block_k, num_q) + step
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
                  seq_q=seq_q, seq_k=seq_k, window=window)
 
-    @pl.when(step == 0)
+    @pl.when(walk == 0)
     def _init():
-        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
+        for acc_ref in (dk_acc_ref, dv_acc_ref):
+            acc_ref[acc] = jnp.zeros(
+                (block_k, acc_ref.shape[1]), acc_ref.dtype)
 
     if with_dq:
         rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
@@ -898,8 +958,8 @@ def _attn_bwd_dkv_kernel(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
             masked=masked, scale=scale, **shape,
         )
-        dv_acc_ref[...] += _dot(p, do, _TN)  # [bk, Dv]
-        dk_acc_ref[...] += _dot(ds, q, _TN)  # [bk, D]
+        dv_acc_ref[acc] += _dot(p, do, _TN)  # [bk, Dv]
+        dk_acc_ref[acc] += _dot(ds, q, _TN)  # [bk, D]
         if with_dq:
             dq_acc_ref[rows, :] += _dot(ds, k, _NN)  # [bq, D]
 
@@ -907,10 +967,10 @@ def _attn_bwd_dkv_kernel(
     # tile.
     _run_tile(_body, *_tile_kind(qi, ki, num_q=num_q, num_k=num_k, **shape))
 
-    @pl.when(step == steps - 1)
+    @pl.when(walk == group * steps - 1)
     def _flush():
-        dk_ref[0] = dk_acc_ref[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
+        dk_ref[out] = dk_acc_ref[acc].astype(dk_ref.dtype)
+        dv_ref[out] = dv_acc_ref[acc].astype(dv_ref.dtype)
 
     if with_dq:
         last_col = ki == num_k - 1
@@ -940,7 +1000,8 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
         window=window,
     )
 
-    k_block = _k_block_under_q(causal, block_q, block_k, window, num_k)
+    k_block = _k_block_under_q(causal, block_q, block_k, window, num_k,
+                               group=BH // k.shape[0])
     return pl.pallas_call(
         kernel,
         grid=(BH, num_q, steps),
@@ -967,12 +1028,21 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     """(dk, dv), or with `with_dq` the kernel `flash_bwd_dkv_dq` and
     (dq, dk, dv): one more output, whose block is a (batch, head) row's
     whole dq, fetched nowhere and written back when the row is done, and
-    one more f32 sum of that size."""
+    one more f32 sum of that size.
+
+    k and v of [BHk, S, ...] with `group = BH // BHk` query rows to each:
+    dk and dv leave at [BHk, S, ...], summed over the group in VMEM. The
+    index maps below are written for `(bh, ki, qi)`; `of_grid` reads those
+    from the grid's coordinates: `(BHk, key tiles, group * steps)` without
+    dq, `(BHk, group, key tiles, steps)` with it, where dk's and dv's
+    blocks and sums are the key-value row's whole `S` rows too. A group of
+    one is the grid `(BH, key tiles, steps)` and the maps as they are."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
     S, Dv = k.shape[1], v.shape[2]
+    group = BH // k.shape[0]
     name = "flash_bwd_dkv_dq" if with_dq else "flash_bwd_dkv"
     tiles, num_q, num_k, steps = _grid(
         name, q, k, v, causal, block_q, block_k, window)
@@ -981,7 +1051,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         _attn_bwd_dkv_kernel,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
         steps=steps, scale=scale, causal=causal, seq_q=T, seq_k=S,
-        with_dq=with_dq, window=window,
+        with_dq=with_dq, window=window, group=group,
     )
 
     q_block = _q_block_under_k(causal, block_q, block_k, num_q, window)
@@ -989,29 +1059,55 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     def k_block(bh, ki, qi):
         return (bh, ki, 0)
 
+    def row_block(bh, ki, qi):
+        return (bh, 0, 0)
+
+    grid, of_grid = (BH, num_k, steps), lambda index_map: index_map
+    inner = ("parallel", "arbitrary")
+    dk_block, dk_rows, k_rows = k_block, block_k, S  # a block's, the array's
+    if group > 1 and with_dq:
+        grid = (BH // group, group, num_k, steps)
+        dk_rows = k_rows = num_k * block_k  # S in whole key tiles
+
+        def of_grid(index_map):
+            return lambda bkv, head, ki, qi: index_map(
+                bkv * group + head, ki, qi)
+
+        def k_block(bkv, head, ki, qi):
+            return (bkv, ki, 0)
+
+        def dk_block(bkv, head, ki, qi):
+            return (bkv, 0, 0)
+    elif group > 1:
+        grid = (BH // group, num_k, group * steps)
+
+        def of_grid(index_map):
+            return lambda bkv, ki, walk: index_map(
+                bkv * group + walk // steps, ki, walk % steps)
+
+    q_block = of_grid(q_block)
     out_specs = [
-        pl.BlockSpec((1, block_k, D), k_block),
-        pl.BlockSpec((1, block_k, Dv), k_block),
+        pl.BlockSpec((1, dk_rows, D), dk_block),
+        pl.BlockSpec((1, dk_rows, Dv), dk_block),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-        jax.ShapeDtypeStruct((BH, S, Dv), v.dtype),
+        jax.ShapeDtypeStruct((BH // group, k_rows, D), k.dtype),
+        jax.ShapeDtypeStruct((BH // group, k_rows, Dv), v.dtype),
     ]
     scratch_shapes = [
-        pltpu.VMEM((block_k, D), jnp.float32),
-        pltpu.VMEM((block_k, Dv), jnp.float32),
+        pltpu.VMEM((dk_rows, D), jnp.float32),
+        pltpu.VMEM((dk_rows, Dv), jnp.float32),
     ]
-    inner = ("parallel", "arbitrary")
     if with_dq:
         rows = num_q * block_q  # T in whole q tiles: every step's are there
-        out_specs.append(
-            pl.BlockSpec((1, rows, D), lambda bh, ki, qi: (bh, 0, 0)))
+        out_specs.append(pl.BlockSpec((1, rows, D), of_grid(row_block)))
         out_shape.append(jax.ShapeDtypeStruct((BH, rows, D), q.dtype))
         scratch_shapes.append(pltpu.VMEM((rows, D), jnp.float32))
-        inner = ("arbitrary", "arbitrary")  # dq is summed over k tiles too
+        # dq is summed over k tiles too, dk and dv over a group's heads
+        inner = ("arbitrary",) * (len(grid) - 1)
     out = pl.pallas_call(
         kernel,
-        grid=(BH, num_k, steps),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_block),
             pl.BlockSpec((1, block_k, D), k_block),
@@ -1030,6 +1126,8 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     if not with_dq:
         return out
     dk, dv, dq = out
+    if k_rows != S:
+        dk, dv = dk[:, :S], dv[:, :S]
     return (dq if rows == T else dq[:, :T]), dk, dv
 
 
@@ -1080,14 +1178,14 @@ def flash_attention(
     window = _band(window, causal, k.shape[1])
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    if Hk != H:
-        rep = H // Hk
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    # [B, T, H, D] -> [B*H, T, D]
+    if H % Hk:
+        raise ValueError(
+            f"{H} query heads do not divide among {Hk} key-value heads")
+    # [B, T, H, D] -> [B*H, T, D], heads folded batch-major: k and v stay at
+    # their own heads, and query row `bh` reads key-value row `bh // (H / Hk)`
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], Dv)
+    kf = k.transpose(0, 2, 1, 3).reshape(B * Hk, k.shape[1], D)
+    vf = v.transpose(0, 2, 1, 3).reshape(B * Hk, v.shape[1], Dv)
     of = _flash(qf, kf, vf, causal, scale, block_q, block_k, interpret,
                 keep_ctx, window)
     return of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)

@@ -196,6 +196,43 @@ def test_the_windowed_kernels_compile_on_the_band_grid_at_the_cell_s_shape(
         assert re.fullmatch(kernel + r"_window(\.\d+)?", name)
 
 
+@pytest.mark.timeout(600)  # a kernel, seconds; room under six workers
+@pytest.mark.parametrize("window", [None, 512])
+def test_the_one_backward_kernel_compiles_with_a_group_of_eight(v5e, window):
+    """`lagunaxs2.tokens8k`'s sliding layers without the repeat: 128 query
+    rows over 16 key-value rows, T 8192, D 128. The one-kernel backward
+    takes k and v at their own rows, walks (key-value row, head of its
+    group, key tile, q step), holds the key-value row's dk and dv beside
+    the head's dq inside the limit Mosaic may be given, and hands dk and dv
+    back at 16 rows."""
+    import re
+
+    bh, rows, t, d = 128, 16, 8192, 128
+    shape = dict(window=window, group=bh // rows)
+    assert fa.flash_bwd_kernels(t, t, d, jnp.bfloat16, **shape) == (
+        "flash_bwd_dkv_dq",)
+    tiles = fa.flash_tiles("flash_bwd_dkv_dq", t, t, d, jnp.bfloat16, **shape)
+    q, row = _shapes(v5e[0], bh, t, d)
+    k, _ = _shapes(v5e[0], rows, t, d)
+    fn = _flash_case("bwd_dkv_dq", dict(
+        KERNEL, scale=d ** -0.5, block_q=None, block_k=None, window=window))
+    (call,) = [eqn for eqn in jax.make_jaxpr(fn)(q, k, k, q, row, row).eqns
+               if eqn.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"].grid
+    assert grid[:2] == (rows, bh // rows)
+    assert grid[2] * grid[3] == tiles.grid_steps
+    limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert limit == tiles.vmem_limit_bytes <= fa._MAX_VMEM
+    text = jax.jit(fn).lower(q, k, k, q, row, row).compile().as_text()
+    ((name, outputs),) = re.findall(
+        r'%([\w.-]+) = \((.*?)\) custom-call\([^\n]*"tpu_custom_call"', text)
+    assert re.fullmatch(
+        "flash_bwd_dkv_dq" + ("_window" if window else "") + r"(\.\d+)?", name)
+    # dk, dv, dq, as the kernel lists them
+    assert re.findall(r"bf16\[[\d,]+\]", outputs) == [
+        f"bf16[{rows},{t},{d}]", f"bf16[{rows},{t},{d}]", f"bf16[{bh},{t},{d}]"]
+
+
 def test_lm_head_cross_entropy_compiles_for_v5e(v5e):
     one = SingleDeviceSharding(v5e[0])
     hidden = jax.ShapeDtypeStruct((32, 1024, 768), jnp.bfloat16, sharding=one)

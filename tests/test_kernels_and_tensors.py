@@ -396,6 +396,30 @@ def test_flash_backward_plan_never_passes_the_vmem_limit():
                     assert t.vmem_bytes < t.vmem_limit_bytes <= fa._MAX_VMEM
     assert fa.flash_bwd_kernels(200, 136, 32, jnp.float32, block_q=64,
                                 block_k=128) == ONE
+    # the cells' shapes with a group of query heads to a key-value head:
+    # the one kernel, which then holds that head's whole dk and dv too
+    # (16.8 MB more at T 8192, D 128, whatever the group), at the tile it
+    # takes without a group
+    for T, D in [(8192, 128), (4096, 128), (8192, 64), (4096, 64)]:
+        for window in (None, 512):
+            alone = flash_tiles("flash_bwd_dkv_dq", T, T, D, jnp.bfloat16,
+                                window=window)
+            for group in (4, 6, 8, 16):
+                shape = dict(window=window, group=group)
+                assert fa.flash_bwd_kernels(
+                    T, T, D, jnp.bfloat16, **shape) == ONE
+                t = flash_tiles("flash_bwd_dkv_dq", T, T, D, jnp.bfloat16,
+                                **shape)
+                assert t[:4] == alone[:4] and t.cost_us == alone.cost_us
+                assert t.vmem_bytes < t.vmem_limit_bytes <= fa._MAX_VMEM
+                rows = (T // t.block_k - 1) * t.block_k * 2 * 128
+                assert t.vmem_bytes - alone.vmem_bytes == rows * (2 * 2 + 4)
+                for kernel in ("flash_fwd", *TWO):  # a key tile's, as ever
+                    assert flash_tiles(kernel, T, T, D, jnp.bfloat16,
+                                       **shape) == flash_tiles(
+                        kernel, T, T, D, jnp.bfloat16, window=window)
+    assert flash_tiles("flash_bwd_dkv_dq", 8192, 8192, 128, jnp.bfloat16,
+                       group=6).vmem_limit_bytes == fa._MAX_VMEM
     assert fa._pairs_factor("flash_bwd_dkv_dq", 192, 128) == 1.6  # 8 / 5
     assert fa._pairs_factor("flash_bwd_dkv_dq", 64, 64) == 1.0
 

@@ -321,6 +321,10 @@ def shutdown() -> None:
         w.loop = None
         w._loop_thread = None
         w._owns_loop = False
+        # the spans' table ends with the cluster a driver started: a second
+        # `init` in this process reports itself alone (`cluster_init_s`
+        # reads `init`'s row)
+        tracing.clear_table()
 
 
 def _core() -> CoreWorker:

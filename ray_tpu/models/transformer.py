@@ -63,7 +63,7 @@ The two differ by operator in more than the mask: the query heads' number
 (`n_heads` for `full_attention`, `n_heads_sliding` under the window, over
 the same `n_kv_heads` of the same width, so `wq`, `wo` and the gate differ
 in shape by layer type and `segments` scans the alike ones together) and
-the rotary recipe (`TransformerConfig.rotary`: theta, the share of a head's
+the rotary recipe (`_PlainAttention.rotary`: theta, the share of a head's
 columns that turn, `partial_rotary_factor`, and for full attention
 `rope_scaling`, YaRN's frequencies with an explicit `attention_factor`,
 through the one `_rope` latent attention uses). `attn_gate` multiplies every
@@ -83,6 +83,19 @@ reaches its own matmuls as one (`_own_weights`), so that no cast, update of
 the scanned stack or optimizer update is fused into a matmul. The routed
 experts' weights are `ops/moe.py`'s.
 
+Adding a layer kind. What an operator or a feed-forward is, is stated once:
+a `Sublayer` record in `_OPERATORS` or `_FEED_FORWARDS` (below the forward
+functions) holds its leaves (how they are drawn, their logical axes, which
+are plain matmuls' weights), its forward, the `checkpoint_name`s it makes
+with their widths, its parameters, what its backward holds, and its
+operations. `TransformerConfig.layers`, `_blocks_init`, `param_shardings`,
+`_own_weights`, `_block`, `saved_activations` and `flops_per_token` read
+the tables and name no kind: a new kind is one record, its names in
+`_SAVE_ORDER`, the fields it reads in `TransformerConfig`, and a case in
+`tests/test_layer_kinds.py`, which holds a record's statements to one
+another. The forward functions stay in this module under their names: the
+benchmark's tests patch them here.
+
 Parallelism (ray_tpu.parallel.mesh axes):
   data/fsdp — batch split; fsdp additionally shards params (ZeRO-3 style)
   tensor    — heads + mlp hidden + vocab split (Megatron layout)
@@ -97,6 +110,7 @@ Capability analog of what the reference reaches only through integrations
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import math
 from dataclasses import dataclass, field
@@ -152,8 +166,9 @@ class TransformerConfig:
     qk_norm: Union[bool, str] = False
     router_aux_loss_coef: float = 0.01  # load balancing, mean over layers
     router_z_loss_coef: float = 0.001  # logsumexp(router logits)^2
-    # one of "full_attention" | "sliding_attention" | "conv" |
-    # "latent_attention" per layer; () => attention everywhere
+    # an operator of `_OPERATORS` per layer ("full_attention" |
+    # "sliding_attention" | "conv" | "latent_attention"); () => attention
+    # everywhere
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3  # the short convolution's reach, this token included
     n_dense_layers: int = 0  # with n_experts: leading layers with a dense FF
@@ -227,20 +242,10 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
 
-    def heads(self, op: Optional[str]) -> int:
-        """The query heads of a layer whose operator is `op`."""
-        if op == "sliding_attention":
-            return self.n_heads_sliding or self.n_heads
-        return self.n_heads
-
-    def rotary(self, op: Optional[str]):
-        """(theta, the share of a head that turns, `rope_scaling` as a
-        mapping or None) of plain attention's rotary positions in a layer
-        whose operator is `op`."""
-        if op == "sliding_attention":
-            return self.rope_theta_sliding or self.rope_theta, 1.0, None
-        return (self.rope_theta, self.partial_rotary_factor,
-                dict(self.rope_scaling) if self.rope_scaling else None)
+    def heads(self, op: str) -> int:
+        """The query heads of a layer whose operator is `op`, an attention
+        of `_OPERATORS`."""
+        return _OPERATORS[op].heads(self)
 
     @property
     def shared_dim(self) -> int:
@@ -278,25 +283,32 @@ class TransformerConfig:
 
     @property
     def layers(self) -> Tuple["LayerKind", ...]:
-        """Every layer's kind, first to last."""
+        """Every layer's kind, first to last. A name that the tables do not
+        have is an error: `layer_types` and `sublayer_types` reach this from
+        a deployment's `config.json`."""
         if self.sublayer_types:
-            if len(self.sublayer_types) != self.n_layers:
+            field_, names = "sublayer_types", self.sublayer_types
+            known = {**_OPERATORS, **_FEED_FORWARDS}
+        else:
+            field_, known = "layer_types", _OPERATORS
+            names = self.layer_types or ("full_attention",) * self.n_layers
+        if len(names) != self.n_layers:
+            raise ValueError(
+                f"{field_} names {len(names)} layers, n_layers is "
+                f"{self.n_layers}")
+        for name in names:
+            if name not in known:
                 raise ValueError(
-                    f"sublayer_types names {len(self.sublayer_types)} layers, "
-                    f"n_layers is {self.n_layers}")
+                    f"{field_} names {name!r}, which is none of "
+                    f"{sorted(known)}")
+        if self.sublayer_types:
             return tuple(
                 LayerKind(None, name == "routed_ff", True)
-                if name in ("dense_ff", "routed_ff")
-                else LayerKind(name, False, False)
-                for name in self.sublayer_types)
-        types = self.layer_types or ("full_attention",) * self.n_layers
-        if len(types) != self.n_layers:
-            raise ValueError(
-                f"layer_types names {len(types)} layers, n_layers is "
-                f"{self.n_layers}")
+                if name in _FEED_FORWARDS else LayerKind(name, False, False)
+                for name in names)
         return tuple(
             LayerKind(op, bool(self.n_experts) and i >= self.n_dense_layers)
-            for i, op in enumerate(types))
+            for i, op in enumerate(names))
 
     @property
     def n_routed_layers(self) -> int:
@@ -304,10 +316,10 @@ class TransformerConfig:
 
 
 class LayerKind(NamedTuple):
-    # "full_attention" | "sliding_attention" | "conv" | "latent_attention" |
-    # "mamba2"; None: the layer is a feed-forward alone
-    op: Optional[str]
-    routed: bool     # the feed-forward: routed experts, or dense
+    """A layer's key into the two tables of sublayers (`_sublayers`)."""
+    op: Optional[str]  # of `_OPERATORS`; None: a feed-forward alone
+    # the feed-forward, of `_FEED_FORWARDS`: "routed_ff", or "dense_ff"
+    routed: bool
     ff: bool = True  # False: the layer is an operator alone
 
 
@@ -340,269 +352,7 @@ def segments(cfg: TransformerConfig) -> List[Segment]:
     return out
 
 
-# ------------------------------------------------------------------ params
-
-def _dense(key, shape, fan_in):
-    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
-
-
-def _mamba_init(key, cfg: TransformerConfig, L: int):
-    """`L` Mamba-2 mixers: `A = -exp(A_log)` starts uniform in [-16, -1],
-    `softplus(dt_bias)` log-uniform in `mamba_dt_init`'s range and no less
-    than its floor, the skip `D` at 1 (the published initialiser's)."""
-    d, H = cfg.d_model, cfg.mamba_heads
-    inner, conv = cfg.mamba_inner, cfg.mamba_conv_dim
-    taps = cfg.mamba_conv_taps
-    k_in, k_conv, k_a, k_dt, k_out = jax.random.split(key, 5)
-    dt_min, dt_max, dt_floor = cfg.mamba_dt_init
-    dt = jnp.maximum(dt_floor, jnp.exp(jax.random.uniform(
-        k_dt, (L, H), jnp.float32, math.log(dt_min), math.log(dt_max))))
-    w_out = _dense(k_out, (L, inner, d), inner)
-    if cfg.rescale_prenorm_residual:
-        w_out = w_out / math.sqrt(cfg.n_layers)
-    return {
-        "mixer_norm": jnp.ones((L, d), jnp.float32),
-        # the gate z, then x, B and C (the convolution's channels), then dt
-        "w_in": _dense(k_in, (L, d, inner + conv + H), d),
-        "conv_w": _dense(k_conv, (L, taps, conv), taps),
-        "conv_b": jnp.zeros((L, conv), jnp.float32),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus's inverse
-        "A_log": jnp.log(jax.random.uniform(k_a, (L, H), jnp.float32, 1.0, 16.0)),
-        "D": jnp.ones((L, H), jnp.float32),
-        "norm": jnp.ones((L, inner), jnp.float32),
-        "w_out": w_out,
-    }
-
-
-def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
-    """`L` layers of one kind, every leaf stacked on a leading layer axis."""
-    d, hk, dh = cfg.d_model, cfg.kv_heads, cfg.head_dim
-    h = cfg.heads(kind.op)
-    ks = jax.random.split(k_blk, 7)
-    if kind.op is None:
-        blocks = {}
-    elif kind.op == "mamba2":
-        blocks = _mamba_init(ks[0], cfg, L)
-    elif kind.op == "conv":
-        blocks = {
-            "conv_norm": jnp.ones((L, d), jnp.float32),
-            "conv_in": _dense(ks[0], (L, d, 3 * d), d),
-            "conv_w": _dense(ks[1], (L, cfg.conv_taps, d), cfg.conv_taps),
-            "conv_out": _dense(ks[3], (L, d, d), d),
-        }
-    elif kind.op == "latent_attention":
-        r, nope, rope, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-                             cfg.qk_rope_head_dim, cfg.v_head_dim)
-        blocks = {
-            "attn_norm": jnp.ones((L, d), jnp.float32),
-            "wq": _dense(ks[0], (L, d, h * (nope + rope)), d),
-            # the latent, then the one rotary key all heads share
-            "wkv_a": _dense(ks[1], (L, d, r + rope), d),
-            "kv_norm": jnp.ones((L, r), jnp.float32),
-            # per head: its unrotated key columns, then its value columns
-            "wkv_b": _dense(ks[2], (L, r, h * (nope + dv)), r),
-            "wo": _dense(ks[3], (L, h * dv, d), h * dv),
-        }
-    else:
-        blocks = {
-            "attn_norm": jnp.ones((L, d), jnp.float32),
-            "wq": _dense(ks[0], (L, d, h * dh), d),
-            "wk": _dense(ks[1], (L, d, hk * dh), d),
-            "wv": _dense(ks[2], (L, d, hk * dh), d),
-            "wo": _dense(ks[3], (L, h * dh, d), h * dh),
-        }
-        if cfg.attn_gate:
-            blocks["w_gate_attn"] = _dense(
-                jax.random.fold_in(k_blk, 9), (L, d, h), d)
-    if cfg.qk_norm and kind.op in _PLAIN_ATTENTION:
-        per_head = cfg.qk_norm == "head"
-        blocks["q_norm"] = jnp.ones((L, dh if per_head else h * dh), jnp.float32)
-        blocks["k_norm"] = jnp.ones((L, dh if per_head else hk * dh), jnp.float32)
-    if not kind.ff:
-        return blocks
-    # a routed feed-forward stacks its (held) experts behind the layer axis
-    ff = (L, cfg.held[1]) if kind.routed else (L,)
-    f = cfg.ff_dim
-    if cfg.n_experts and not kind.routed and cfg.d_ff_dense is not None:
-        f = cfg.d_ff_dense
-    blocks["mlp_norm"] = jnp.ones((L, d), jnp.float32)
-    if cfg.gated:
-        blocks["w_gate"] = _dense(ks[4], (*ff, d, f), d)
-    blocks.update({
-        "w_up": _dense(ks[5], (*ff, d, f), d),
-        "w_down": _dense(ks[6], (*ff, f, d), f),
-    })
-    if kind.routed:
-        blocks["router"] = _dense(
-            jax.random.fold_in(k_blk, 7), (L, d, cfg.n_experts), d)
-        if cfg.n_shared_experts:
-            fs = cfg.shared_dim
-            kg, ku, kd = jax.random.split(jax.random.fold_in(k_blk, 8), 3)
-            if cfg.gated:
-                blocks["ws_gate"] = _dense(kg, (L, d, fs), d)
-            blocks.update({
-                "ws_up": _dense(ku, (L, d, fs), d),
-                "ws_down": _dense(kd, (L, fs, d), fs),
-            })
-    return blocks
-
-
-def _one_kind(segs: List[Segment]) -> bool:
-    return len(segs) == 1 and len(segs[0].layout) == 1
-
-
-def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
-    """f32 master params. Block params are stacked on a leading layer axis:
-    one tree for a model of one kind of layer, else a list of segments, each
-    a list of one tree per layer of its period, stacked over its periods."""
-    k_emb, k_blk, k_out = jax.random.split(rng, 3)
-    d = cfg.d_model
-    segs = segments(cfg)
-    if _one_kind(segs):
-        blocks = _blocks_init(k_blk, cfg, segs[0].layout[0], cfg.n_layers)
-    else:
-        blocks = [
-            [_blocks_init(jax.random.fold_in(jax.random.fold_in(k_blk, si), pi),
-                          cfg, kind, seg.periods)
-             for pi, kind in enumerate(seg.layout)]
-            for si, seg in enumerate(segs)]
-    params = {
-        "embed": jax.random.normal(
-            k_emb, (cfg.vocab_size, d), jnp.float32
-        ) * 0.02,
-        "blocks": blocks,
-        "final_norm": jnp.ones((d,), jnp.float32),
-    }
-    if not cfg.tied_embeddings:
-        params["unembed"] = _dense(k_out, (d, cfg.vocab_size), d)
-    return params
-
-
-def expert_bias_init(cfg: TransformerConfig):
-    """The routers' selection bias, [routed layers, n_experts] float32:
-    zeros, as training starts. State of the step, not a parameter."""
-    return jnp.zeros((cfg.n_routed_layers, cfg.n_experts), jnp.float32)
-
-
-_LOGICAL_AXES = {
-    "embed": ("vocab", "embed"),
-    "unembed": ("embed", "vocab"),
-    "final_norm": (None,),
-    "blocks": {
-        "attn_norm": ("layers", None),
-        "wq": ("layers", "embed", "heads"),
-        "wk": ("layers", "embed", "kv"),
-        "wv": ("layers", "embed", "kv"),
-        "wo": ("layers", "heads", "embed"),
-        "mlp_norm": ("layers", None),
-        "w_gate": ("layers", "embed", "mlp"),
-        "w_up": ("layers", "embed", "mlp"),
-        "w_down": ("layers", "mlp", "embed"),
-    },
-}
-_ROUTED_AXES = {
-    "w_gate": ("layers", "experts", "embed", "mlp"),
-    "w_up": ("layers", "experts", "embed", "mlp"),
-    "w_down": ("layers", "experts", "mlp", "embed"),
-    "router": ("layers", "embed", None),
-}
-_SHARED_AXES = {
-    "ws_gate": ("layers", "embed", "mlp"),
-    "ws_up": ("layers", "embed", "mlp"),
-    "ws_down": ("layers", "mlp", "embed"),
-}
-# cut along the heads where a product's columns (rows, for `wo`) are the
-# heads'; the down projection to the latent and the shared key is whole
-_LATENT_AXES = {
-    "attn_norm": ("layers", None),
-    "wq": ("layers", "embed", "heads"),
-    "wkv_a": ("layers", "embed", None),
-    "kv_norm": ("layers", None),
-    "wkv_b": ("layers", None, "heads"),
-    "wo": ("layers", "heads", "embed"),
-}
-_QK_NORM_AXES = {"q_norm": ("layers", "heads"), "k_norm": ("layers", "kv")}
-_HEAD_NORM_AXES = {"q_norm": ("layers", None), "k_norm": ("layers", None)}
-# the three streams of `conv_in` are split after the product and the
-# convolution is per channel: neither is cut along the channels
-_CONV_AXES = {
-    "conv_norm": ("layers", None),
-    "conv_in": ("layers", "embed", None),
-    "conv_w": ("layers", None, None),
-    "conv_out": ("layers", None, "embed"),
-}
-# cut along the heads where a leaf is the heads'; `w_in`'s columns are
-# three streams that are split after the product, and the convolution runs
-# over x, B and C together: neither is cut
-_MAMBA_AXES = {
-    "mixer_norm": ("layers", None),
-    "w_in": ("layers", "embed", None),
-    "conv_w": ("layers", None, None),
-    "conv_b": ("layers", None),
-    "dt_bias": ("layers", "heads"),
-    "A_log": ("layers", "heads"),
-    "D": ("layers", "heads"),
-    "norm": ("layers", "heads"),
-    "w_out": ("layers", "heads", "embed"),
-}
-_ATTENTION_KEYS = ("attn_norm", "wq", "wk", "wv", "wo")
-# plain attention, whole or under a window: the same leaves, the heads'
-# number by the operator (`TransformerConfig.heads`)
-_PLAIN_ATTENTION = ("full_attention", "sliding_attention")
-_FF_KEYS = ("mlp_norm", "w_gate", "w_up", "w_down")
-# the operators whose leaves take the place of full attention's; a layer
-# that is a feed-forward alone has none
-_OPERATOR_AXES = {"conv": _CONV_AXES, "latent_attention": _LATENT_AXES,
-                  "mamba2": _MAMBA_AXES, None: {}}
-
-
-def _block_axes(cfg: TransformerConfig, kind: LayerKind):
-    base = _LOGICAL_AXES["blocks"]
-    if kind.op in _OPERATOR_AXES:
-        table = {**_OPERATOR_AXES[kind.op],
-                 **{k: v for k, v in base.items() if k not in _ATTENTION_KEYS}}
-    else:
-        table = dict(base)
-        if cfg.qk_norm:
-            table.update(
-                _HEAD_NORM_AXES if cfg.qk_norm == "head" else _QK_NORM_AXES)
-        if cfg.attn_gate:  # a column a head
-            table["w_gate_attn"] = ("layers", "embed", "heads")
-    if kind.routed:
-        table.update(_ROUTED_AXES)
-        if cfg.n_shared_experts:
-            table.update(_SHARED_AXES)
-    absent = () if kind.ff else _FF_KEYS
-    if not cfg.gated:  # an ungated feed-forward has no gate's weights
-        absent += ("w_gate", "ws_gate")
-    return {k: v for k, v in table.items() if k not in absent}
-
-
-def param_shardings(mesh, cfg: TransformerConfig):
-    """NamedSharding pytree matching transformer_init's structure, derived
-    from the logical-axis table + default_transformer_rules."""
-    rules = mesh_lib.default_transformer_rules(mesh)
-
-    def build(node):
-        if isinstance(node, dict):
-            return {k: build(v) for k, v in node.items()}
-        if isinstance(node, list):
-            return [build(v) for v in node]
-        return NamedSharding(mesh, rules.spec(node))
-
-    table = dict(_LOGICAL_AXES)
-    if cfg.tied_embeddings:
-        table.pop("unembed", None)
-    segs = segments(cfg)
-    if _one_kind(segs):
-        table["blocks"] = _block_axes(cfg, segs[0].layout[0])
-    else:
-        table["blocks"] = [[_block_axes(cfg, kind) for kind in seg.layout]
-                           for seg in segs]
-    return build(table)
-
-
-# ----------------------------------------------------------------- forward
+# ---------------------------------------------- the sublayers' forwards
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
@@ -724,93 +474,17 @@ def _kernel_impl(cfg: TransformerConfig) -> str:
     )
 
 
-# the weights of a block's plain matmuls, every operator's and feed-forward's
-_MATMUL_WEIGHTS = (
-    "wq", "wk", "wv", "wo", "w_gate_attn", "wkv_a", "wkv_b", "conv_in",
-    "conv_out", "w_in", "w_out", "w_gate", "w_up", "w_down", "ws_gate",
-    "ws_up", "ws_down",
-)
-_ROUTED_WEIGHTS = ("w_gate", "w_up", "w_down")  # `ops/moe.py` casts its own
-
-
-def own_buffer_weights(blk) -> Tuple[str, ...]:
-    """The leaves of a block that `_own_weights` hands its matmuls: the
-    weights of its plain matmuls. The routed experts' go to `ops/moe.py` as
-    they are, and the norms' vectors and the router are no such matmul."""
-    return tuple(
-        name for name in _MATMUL_WEIGHTS
-        if name in blk and not ("router" in blk and name in _ROUTED_WEIGHTS))
-
-
-def _own_weights(blk, dt, sliced: bool):
-    """`blk` with the weights of its plain matmuls in the compute dtype,
-    each behind a barrier, so that the sites' `blk[name].astype(dt)` finds
-    them made.
-
-    Left to itself XLA fuses what takes a weight's gradient into the matmul
-    that makes it: the cast to float32 and the dynamic update of the scanned
-    stack's gradient, and in a segment of one period (whose loop it
-    unrolls) AdamW's whole update of the weight and its two moments. The
-    TPU compiler then tiles that matmul worse: 37 to 53 % of the MXU's peak
-    with AdamW inside and 69 to 80 % with the update, for 69 to 85 % into a
-    buffer (PERF.md section 6, PR 37; the head's had the same in PR 28).
-    `_own_cotangent` puts the barrier on the gradient alone: the matmul
-    writes a `[d, f]` array in `dt`, and what was fused in runs after it at
-    the memory's pace.
-
-    Where the weight arrives whole (`sliced` false: a segment of one
-    period) `_own_buffer` also makes the cast an array of its own for the
-    forward, the rematerialised and the input-gradient matmuls, which read
-    a float32 `[1, d, f]` through a fused cast at half the pace (12.0 ms for
-    17.9). A slice of a scanned stack is left to the compiler, which makes
-    the slice and the cast one operand of the matmul: there the copy costs
-    6 bytes an element a pass and the chip shows no matmul the faster for
-    it (`mistral7b.tokens4k`: -0.7 %)."""
-    own = _own_cotangent if sliced else _own_buffer
-    return {**blk, **{name: own(blk[name].astype(dt))
-                      for name in own_buffer_weights(blk)}}
-
-
-def _periods(blk) -> int:
-    """The length of a block tree's leading layer axis."""
-    return jax.tree.leaves(blk)[0].shape[0]
-
-
-def _segment_trees(blocks):
-    """`params["blocks"]` as its segments, each one tree per layer of its
-    period: a model of one kind of layer is one segment of it."""
-    return [[blocks]] if isinstance(blocks, dict) else blocks
-
-
-def own_buffers(blocks, dt) -> Tuple[int, int, int]:
-    """(the buffers `_own_weights` makes for the layers of
-    `params["blocks"]`: one for every matmul weight's gradient and one more
-    for every weight of a segment of one period; their bytes in `dt`, whole
-    as a matmul takes them; the widest layer's weights' bytes)."""
-    item = jnp.dtype(dt).itemsize
-    count = total = widest = 0
-    for blk in (blk for blks in _segment_trees(blocks) for blk in blks):
-        names = own_buffer_weights(blk)
-        periods = _periods(blk)
-        layer = item * sum(math.prod(blk[name].shape[1:]) for name in names)
-        each = 2 if periods == 1 else 1
-        count += each * periods * len(names)
-        total += each * periods * layer
-        widest = max(widest, layer)
-    return count, total, widest
-
-
 def _attention_layer(x, blk, positions, cfg: TransformerConfig,
                      seq_axis: Optional[str], seq_size: int, mesh=None,
-                     keep_ctx: bool = False, op: str = "full_attention"):
+                     keep_ctx: bool = False, *, op: "_PlainAttention"):
     """x + attention(norm(x)): projections, QK-norm, RoPE, the kernel, and
-    with `w_gate_attn` the heads' gates on its output. `op` is
-    "full_attention" or "sliding_attention": the heads' number, the rotary
-    recipe and the window are the operator's."""
+    with `w_gate_attn` the heads' gates on its output. `op` is the record
+    of "full_attention" or of "sliding_attention": the heads' number, the
+    rotary recipe and the window are the operator's."""
     B, T, d = x.shape
-    h, hk, dh = cfg.heads(op), cfg.kv_heads, cfg.head_dim
-    theta, share, scaling = cfg.rotary(op)
-    window = cfg.sliding_window if op == "sliding_attention" else None
+    h, hk, dh = op.heads(cfg), cfg.kv_heads, cfg.head_dim
+    theta, share, scaling = op.rotary(cfg)
+    window = op.window(cfg)
     dt = cfg.dtype
     per_head = cfg.qk_norm == "head"
 
@@ -1055,65 +729,789 @@ def _feed_forward(y, blk, dt, names, prefix: str = "w"):
     return hidden @ blk[prefix + "_down"].astype(dt)
 
 
-def _block(x, blk, positions, bias, cfg: TransformerConfig,
-           seq_axis: Optional[str], seq_size: int, mesh=None,
-           keep_ctx: bool = False, sliced: bool = False,
-           sliding: bool = False):
-    """One block: (x, the routed feed-forward's readings or None). What the
-    block is, its parameters say: a short convolution where it has
-    `conv_in`, latent attention where it has `wkv_a`, a Mamba-2 mixer where
-    it has `A_log`, attention where it has `wk`, and no operator otherwise;
-    then a feed-forward where it has an `mlp_norm`: routed where it has a
-    `router`, shared experts beside it where it has `ws_up`. `keep_ctx`:
-    the attention kernel names its backward's residuals `attn_ctx`.
-    `sliced`: `blk` is one of several periods of a scanned stack
-    (`_own_weights`). `sliding`: its attention is under the window, which
-    its parameters' names do not say."""
-    dt = cfg.dtype
-    blk = _own_weights(blk, dt, sliced)
+def keys_per_query(seq_len: int, window: Optional[int] = None) -> float:
+    """The keys a query of a causal sequence of `seq_len` sees, on average:
+    `(seq_len + 1) / 2`, and under a window that is shorter than the
+    sequence the band's pairs over its queries (the first `window` queries
+    see a triangle, every later one `window` keys)."""
+    if not window or window >= seq_len:
+        return (seq_len + 1) / 2
+    return (window * (window + 1) / 2 + (seq_len - window) * window) / seq_len
 
-    # The scopes name the step's device work in a profiler trace
-    # (docs/observability.md, "Device scopes"); they are metadata only.
-    if "conv_in" in blk or "wkv_a" in blk or "A_log" in blk:
-        if seq_axis is not None:
-            raise NotImplementedError(
-                "the short convolution, latent attention and the Mamba-2 "
-                "mixer are not mapped over a sequence axis")
-    if "conv_in" in blk:
-        with jax.named_scope("short_conv"):
-            x = checkpoint_name(x + _short_conv(x, blk, cfg), "conv_res")
-    elif "wkv_a" in blk:
-        with jax.named_scope("latent_attention"):
-            x = _latent_attention_layer(x, blk, positions, cfg, mesh, keep_ctx)
-    elif "A_log" in blk:
-        with jax.named_scope("mamba"):
-            x = x + _mamba_mixer(x, blk, cfg)
-    elif "wk" in blk and sliding:
-        if seq_axis is not None:
-            raise NotImplementedError(
+
+def _scan_bytes_per_token(cfg: TransformerConfig) -> int:
+    """Bytes a token that a mixer's scan holds in HBM in its backward, by
+    the path `ssd` takes (`ops/ssd.py`). `jax.numpy`: the [H, Q, Q] arrays
+    of a chunk, Q values a token and head: the decays and their gradient in
+    float32, the masked scores and theirs in the compute dtype and in
+    float32. The kernels keep those in VMEM. What they leave is each
+    chunk's entering state, `H P N / Q` float32 values a token, and dt and
+    cum with their cotangents: as columns `[b, G, T, R]` float32, which lie
+    in HBM at a tile's 128 lanes a group (the four the kernels read and
+    write and three that the transposes from and to `[b, T, H]` make),
+    and as rows and plain `[b, T, H]` arrays, two `H` wide all told."""
+    item = _item(cfg)
+    H, Q, G = cfg.mamba_heads, cfg.ssd_chunk, cfg.ssm_groups
+    if _kernel_impl(cfg) != "pallas" or scan_untiled(
+            Q, cfg.ssm_state, H // G, cfg.mamba_head_dim):
+        return H * Q * (4 * 4 + 2 * item)
+    return 4 * (cfg.mamba_inner * cfg.ssm_state // Q + 7 * G * 128 + 2 * H)
+
+
+# ------------------------------------------------- the kinds of sublayer
+
+def _dense(key, shape, fan_in):
+    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
+
+
+def _tile_lanes(width: int) -> int:
+    """`width` as HBM tiles a minor dimension: whole tiles of 128 lanes."""
+    return -(-width // 128) * 128
+
+
+def _item(cfg: TransformerConfig) -> int:
+    return jnp.dtype(cfg.dtype).itemsize
+
+
+class _Site(NamedTuple):
+    """Where a block runs: what a sublayer's forward takes beside the
+    stream, the block's weights and the configuration."""
+    positions: Any  # [B, T] integers; under a sequence axis the global ones
+    bias: Any = None  # [E]: a routed feed-forward's selection bias
+    seq_axis: Optional[str] = None
+    seq_size: int = 1
+    mesh: Any = None
+    # the attention kernel names its backward's residuals `attn_ctx`
+    keep_ctx: bool = False
+
+
+class Sublayer:
+    """One kind of sublayer, `x -> x + f(norm(x))`: an operator (a record of
+    `_OPERATORS`) or a feed-forward (of `_FEED_FORWARDS`). It states once
+    what `_blocks_init`, `param_shardings`, `_own_weights`, `_block`, the
+    rule of what a rematerialised block keeps (`saved_activations`) and
+    `flops_per_token` read of it; none of them names a kind.
+
+    Its leaves are three statements that `tests/test_layer_kinds.py` holds
+    to one another: `init` makes them, `axes` has exactly their keys, and
+    `matmuls` are among them. Widths are elements of the compute dtype a
+    token; operations are forward ones a token."""
+
+    # the leaves that are weights of plain matmuls, in the order
+    # `_own_weights` takes them
+    matmuls: Tuple[str, ...] = ()
+    # the weights `ops/moe.py` takes as they are: counted in `params`,
+    # never handed a buffer
+    moe_weights: Tuple[str, ...] = ()
+    # the `checkpoint_name`s `forward` may make: every one is in
+    # `_SAVE_ORDER`, or the module does not import
+    names: Tuple[str, ...] = ()
+    # why `forward` cannot be mapped over a sequence axis; None: it can
+    no_sequence_axis: Optional[str] = None
+
+    def init(self, key, cfg: TransformerConfig, L: int) -> Dict[str, Any]:
+        """`L` stacked layers' leaves, float32, from the layer's key. The
+        key is split in seven for every kind alike (the operator's draws are
+        the first four, the feed-forward's the last three) and folded with 7,
+        8 and 9 for the router, the shared experts and the heads' gate: the
+        seeded values are the checkpoint's format."""
+        raise NotImplementedError
+
+    def axes(self, cfg: TransformerConfig) -> Dict[str, Tuple]:
+        """{leaf: its logical axes}, for `default_transformer_rules`."""
+        raise NotImplementedError
+
+    def forward(self, x, blk, cfg: TransformerConfig, site: _Site):
+        """(the stream after the sublayer, residual added; a routed
+        feed-forward's readings, else None), under the sublayer's
+        `jax.named_scope`s: they name the step's device work in a profiler
+        trace (docs/observability.md, "Device scopes") and are metadata
+        only. Optional leaves are found by presence: no `w_gate_attn`, no
+        gate; no `w_gate` or `ws_gate`, `relu2`; no `ws_up`, no shared
+        experts."""
+        raise NotImplementedError
+
+    def widths(self, cfg: TransformerConfig) -> Dict[str, int]:
+        """{name: width} of the `names` a layer under `cfg` makes."""
+        raise NotImplementedError
+
+    def params(self, cfg: TransformerConfig) -> int:
+        """A layer's `matmuls` and `moe_weights`, in elements."""
+        raise NotImplementedError
+
+    def holds(self, cfg: TransformerConfig) -> int:
+        """What its backward holds at once beside the named values and its
+        normed input (`_working_set_bytes`)."""
+        raise NotImplementedError
+
+    def flops(self, cfg: TransformerConfig, seq_len: int):
+        """(matmul operations, causal attention's): of plain matmuls alone,
+        two a parameter."""
+        return 2 * self.params(cfg), 0
+
+
+class _PlainAttention(Sublayer):
+    """Attention over `n_kv_heads` key-value heads `head_dim` wide, whole
+    (`full_attention`) or under `sliding_window` (`sliding_attention`): two
+    records over the same leaves and `_attention_layer`. The query heads'
+    number, the rotary recipe and the window are the record's."""
+
+    matmuls = ("wq", "wk", "wv", "wo", "w_gate_attn")
+    names = ("attn_ctx", "attn_res", "attn_qkv")
+
+    def __init__(self, sliding: bool):
+        self.sliding = sliding
+        if sliding:
+            self.no_sequence_axis = (
                 "attention under a window is not mapped over a sequence "
                 "axis: ring attention has no band")
-        with jax.named_scope("sliding_attention"):
-            x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size,
-                                 mesh, keep_ctx, op="sliding_attention")
-    elif "wk" in blk:
-        x = _attention_layer(x, blk, positions, cfg, seq_axis, seq_size, mesh,
-                             keep_ctx)
 
-    readings = None
-    if "mlp_norm" not in blk:
-        return x, readings
-    with jax.named_scope("mlp"):
-        y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
-        if "router" in blk:
-            routed, readings = _routed_ffn(y, blk, cfg, mesh, bias)
+    def heads(self, cfg) -> int:
+        if self.sliding:
+            return cfg.n_heads_sliding or cfg.n_heads
+        return cfg.n_heads
+
+    def rotary(self, cfg):
+        """(theta, the share of a head's columns that turns, `rope_scaling`
+        as a mapping or None): under the window its own theta over a whole
+        head at plain frequencies."""
+        if self.sliding:
+            return cfg.rope_theta_sliding or cfg.rope_theta, 1.0, None
+        return (cfg.rope_theta, cfg.partial_rotary_factor,
+                dict(cfg.rope_scaling) if cfg.rope_scaling else None)
+
+    def window(self, cfg) -> Optional[int]:
+        return cfg.sliding_window if self.sliding else None
+
+    def init(self, key, cfg, L):
+        d, hk, dh, h = cfg.d_model, cfg.kv_heads, cfg.head_dim, self.heads(cfg)
+        ks = jax.random.split(key, 7)
+        leaves = {
+            "attn_norm": jnp.ones((L, d), jnp.float32),
+            "wq": _dense(ks[0], (L, d, h * dh), d),
+            "wk": _dense(ks[1], (L, d, hk * dh), d),
+            "wv": _dense(ks[2], (L, d, hk * dh), d),
+            "wo": _dense(ks[3], (L, h * dh, d), h * dh),
+        }
+        if cfg.attn_gate:
+            leaves["w_gate_attn"] = _dense(
+                jax.random.fold_in(key, 9), (L, d, h), d)
+        if cfg.qk_norm:
+            per_head = cfg.qk_norm == "head"
+            leaves["q_norm"] = jnp.ones(
+                (L, dh if per_head else h * dh), jnp.float32)
+            leaves["k_norm"] = jnp.ones(
+                (L, dh if per_head else hk * dh), jnp.float32)
+        return leaves
+
+    def axes(self, cfg):
+        table = {
+            "attn_norm": ("layers", None),
+            "wq": ("layers", "embed", "heads"),
+            "wk": ("layers", "embed", "kv"),
+            "wv": ("layers", "embed", "kv"),
+            "wo": ("layers", "heads", "embed"),
+        }
+        if cfg.attn_gate:  # a column a head
+            table["w_gate_attn"] = ("layers", "embed", "heads")
+        if cfg.qk_norm == "head":  # one scale for all heads
+            table.update(q_norm=("layers", None), k_norm=("layers", None))
+        elif cfg.qk_norm:
+            table.update(q_norm=("layers", "heads"), k_norm=("layers", "kv"))
+        return table
+
+    def forward(self, x, blk, cfg, site):
+        # a full layer's scopes stand at the block's top: a trace splits the
+        # two kinds of layer by this scope
+        scope = (jax.named_scope("sliding_attention") if self.sliding
+                 else contextlib.nullcontext())
+        with scope:
+            return _attention_layer(
+                x, blk, site.positions, cfg, site.seq_axis, site.seq_size,
+                site.mesh, site.keep_ctx, op=self), None
+
+    def widths(self, cfg):
+        h, dh = self.heads(cfg), cfg.head_dim
+        return {
+            # o and lse as one float32 column
+            "attn_ctx": h * _tile_lanes(dh) + h * 4 // _item(cfg),
+            "attn_res": cfg.d_model,
+            "attn_qkv": (h + 2 * cfg.kv_heads) * dh,
+        }
+
+    def params(self, cfg):
+        d, hk, dh, h = cfg.d_model, cfg.kv_heads, cfg.head_dim, self.heads(cfg)
+        return (d * (h + 2 * hk) * dh + h * dh * d
+                + (d * h if cfg.attn_gate else 0))
+
+    def holds(self, cfg):
+        """q, k and v as the kernel takes them (q at the operator's heads, k
+        and v at the key-value heads, which the kernels' index maps share
+        among a group), lse and delta at a tile's 128 lanes."""
+        h = self.heads(cfg)
+        return ((h + 2 * cfg.kv_heads) * _tile_lanes(cfg.head_dim)
+                + 2 * h * 128 * 4 // _item(cfg))
+
+    def flops(self, cfg, seq_len):
+        # qk^T and pv each cost 2 h dh operations a (query, key) pair, over
+        # the pairs the causal mask (and the window's band) leaves: the
+        # flash kernel really skips the masked-out tiles, so crediting all
+        # of seq_len would overcount about twofold
+        return 2 * self.params(cfg), (
+            2 * 2 * self.heads(cfg) * cfg.head_dim
+            * keys_per_query(seq_len, self.window(cfg)))
+
+
+class _LatentAttention(Sublayer):
+    """Multi-head latent attention, decompressed
+    (`_latent_attention_layer`)."""
+
+    matmuls = ("wq", "wo", "wkv_a", "wkv_b")
+    names = ("attn_ctx", "attn_res", "attn_qkv")
+    no_sequence_axis = "latent attention is not mapped over a sequence axis"
+
+    def heads(self, cfg) -> int:
+        return cfg.n_heads
+
+    def init(self, key, cfg, L):
+        d, h = cfg.d_model, cfg.n_heads
+        r, nope, rope, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                             cfg.qk_rope_head_dim, cfg.v_head_dim)
+        ks = jax.random.split(key, 7)
+        return {
+            "attn_norm": jnp.ones((L, d), jnp.float32),
+            "wq": _dense(ks[0], (L, d, h * (nope + rope)), d),
+            # the latent, then the one rotary key all heads share
+            "wkv_a": _dense(ks[1], (L, d, r + rope), d),
+            "kv_norm": jnp.ones((L, r), jnp.float32),
+            # per head: its unrotated key columns, then its value columns
+            "wkv_b": _dense(ks[2], (L, r, h * (nope + dv)), r),
+            "wo": _dense(ks[3], (L, h * dv, d), h * dv),
+        }
+
+    def axes(self, cfg):
+        # cut along the heads where a product's columns (rows, for `wo`) are
+        # the heads'; the down projection to the latent and the shared key
+        # is whole
+        return {
+            "attn_norm": ("layers", None),
+            "wq": ("layers", "embed", "heads"),
+            "wkv_a": ("layers", "embed", None),
+            "kv_norm": ("layers", None),
+            "wkv_b": ("layers", None, "heads"),
+            "wo": ("layers", "heads", "embed"),
+        }
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("latent_attention"):
+            return _latent_attention_layer(
+                x, blk, site.positions, cfg, site.mesh, site.keep_ctx), None
+
+    def widths(self, cfg):
+        h, rope = cfg.n_heads, cfg.qk_rope_head_dim
+        return {
+            "attn_ctx": h * _tile_lanes(cfg.v_head_dim) + h * 4 // _item(cfg),
+            "attn_res": cfg.d_model,
+            # out of `wq`, `wkv_a` and `wkv_b`
+            "attn_qkv": (h * (cfg.qk_nope_head_dim + rope)
+                         + cfg.kv_lora_rank + rope
+                         + h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        }
+
+    def params(self, cfg):
+        d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+        nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        return (d * h * (nope + rope) + d * (r + rope) + r * h * (nope + dv)
+                + h * dv * d)
+
+    def holds(self, cfg):
+        """k and v at as many heads as q, q and k at two tiles of lanes; lse
+        and delta at a tile's 128 lanes."""
+        h = cfg.n_heads
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        return (h * (2 * _tile_lanes(qk) + _tile_lanes(cfg.v_head_dim))
+                + 2 * h * 128 * 4 // _item(cfg))
+
+    def flops(self, cfg, seq_len):
+        h = cfg.n_heads
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        # scores over q and k's width, the values over v's
+        return 2 * self.params(cfg), (
+            2 * h * (qk + cfg.v_head_dim) * keys_per_query(seq_len))
+
+
+class _ShortConv(Sublayer):
+    """The gated short convolution (`_short_conv`)."""
+
+    matmuls = ("conv_in", "conv_out")
+    names = ("conv_res", "conv_in")
+    no_sequence_axis = (
+        "the short convolution is not mapped over a sequence axis")
+
+    def init(self, key, cfg, L):
+        d = cfg.d_model
+        ks = jax.random.split(key, 7)
+        return {
+            "conv_norm": jnp.ones((L, d), jnp.float32),
+            "conv_in": _dense(ks[0], (L, d, 3 * d), d),
+            "conv_w": _dense(ks[1], (L, cfg.conv_taps, d), cfg.conv_taps),
+            "conv_out": _dense(ks[3], (L, d, d), d),
+        }
+
+    def axes(self, cfg):
+        # the three streams of `conv_in` are split after the product and the
+        # convolution is per channel: neither is cut along the channels
+        return {
+            "conv_norm": ("layers", None),
+            "conv_in": ("layers", "embed", None),
+            "conv_w": ("layers", None, None),
+            "conv_out": ("layers", None, "embed"),
+        }
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("short_conv"):
+            return checkpoint_name(
+                x + _short_conv(x, blk, cfg), "conv_res"), None
+
+    def widths(self, cfg):
+        return {"conv_res": cfg.d_model, "conv_in": 3 * cfg.d_model}
+
+    def params(self, cfg):
+        return 4 * cfg.d_model * cfg.d_model
+
+    def holds(self, cfg):
+        return 3 * cfg.d_model  # the gate's product, the taps' sum, the gated
+
+
+class _Mamba2(Sublayer):
+    """The Mamba-2 mixer (`_mamba_mixer`)."""
+
+    matmuls = ("w_in", "w_out")
+    names = ("mamba_in", "ssd_out")
+    no_sequence_axis = "the Mamba-2 mixer is not mapped over a sequence axis"
+
+    def init(self, key, cfg, L):
+        """`A = -exp(A_log)` starts uniform in [-16, -1], `softplus(dt_bias)`
+        log-uniform in `mamba_dt_init`'s range and no less than its floor,
+        the skip `D` at 1 (the published initialiser's)."""
+        d, H = cfg.d_model, cfg.mamba_heads
+        inner, conv = cfg.mamba_inner, cfg.mamba_conv_dim
+        taps = cfg.mamba_conv_taps
+        k_in, k_conv, k_a, k_dt, k_out = jax.random.split(
+            jax.random.split(key, 7)[0], 5)
+        dt_min, dt_max, dt_floor = cfg.mamba_dt_init
+        dt = jnp.maximum(dt_floor, jnp.exp(jax.random.uniform(
+            k_dt, (L, H), jnp.float32, math.log(dt_min), math.log(dt_max))))
+        w_out = _dense(k_out, (L, inner, d), inner)
+        if cfg.rescale_prenorm_residual:
+            w_out = w_out / math.sqrt(cfg.n_layers)
+        return {
+            "mixer_norm": jnp.ones((L, d), jnp.float32),
+            # the gate z, then x, B and C (the convolution's channels), then dt
+            "w_in": _dense(k_in, (L, d, inner + conv + H), d),
+            "conv_w": _dense(k_conv, (L, taps, conv), taps),
+            "conv_b": jnp.zeros((L, conv), jnp.float32),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus's inverse
+            "A_log": jnp.log(
+                jax.random.uniform(k_a, (L, H), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((L, H), jnp.float32),
+            "norm": jnp.ones((L, inner), jnp.float32),
+            "w_out": w_out,
+        }
+
+    def axes(self, cfg):
+        # cut along the heads where a leaf is the heads'; `w_in`'s columns
+        # are three streams that are split after the product, and the
+        # convolution runs over x, B and C together: neither is cut
+        return {
+            "mixer_norm": ("layers", None),
+            "w_in": ("layers", "embed", None),
+            "conv_w": ("layers", None, None),
+            "conv_b": ("layers", None),
+            "dt_bias": ("layers", "heads"),
+            "A_log": ("layers", "heads"),
+            "D": ("layers", "heads"),
+            "norm": ("layers", "heads"),
+            "w_out": ("layers", "heads", "embed"),
+        }
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("mamba"):
+            return x + _mamba_mixer(x, blk, cfg), None
+
+    def _wide(self, cfg) -> int:
+        """`w_in`'s columns: the gate, x, B and C, and dt a head."""
+        return cfg.mamba_inner + cfg.mamba_conv_dim + cfg.mamba_heads
+
+    def widths(self, cfg):
+        return {"mamba_in": self._wide(cfg), "ssd_out": cfg.mamba_inner}
+
+    def params(self, cfg):
+        return cfg.d_model * self._wide(cfg) + cfg.mamba_inner * cfg.d_model
+
+    def holds(self, cfg):
+        """The convolution's sum and its silu, the gated output and the
+        normed one, and what the scan holds by the path it takes."""
+        return (2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner
+                + _scan_bytes_per_token(cfg) // _item(cfg))
+
+    def flops(self, cfg, seq_len):
+        inner, N = cfg.mamba_inner, cfg.ssm_state
+        # the scan as it is computed, whole chunks: the scores of a chunk,
+        # their product with the inputs, the chunk's state and the state's
+        # contribution
+        scan = (2 * cfg.ssd_chunk * (cfg.ssm_groups * N + inner)
+                + 2 * 2 * inner * N)
+        return 2 * self.params(cfg) + scan, 0
+
+
+def _ff_gates(cfg) -> Tuple[str, ...]:
+    """The products of a feed-forward that have names, but for `up`."""
+    return ("gate",) if cfg.gated else ()
+
+
+class _DenseFF(Sublayer):
+    """The dense feed-forward (`_feed_forward`): `ff_dim` wide, and
+    `d_ff_dense` where it leads a stack of routed ones."""
+
+    matmuls = ("w_gate", "w_up", "w_down")
+    names = ("mlp_gate", "mlp_up")
+
+    def _width(self, cfg) -> int:
+        if cfg.n_experts and cfg.d_ff_dense is not None:
+            return cfg.d_ff_dense
+        return cfg.ff_dim
+
+    def init(self, key, cfg, L):
+        d, f = cfg.d_model, self._width(cfg)
+        ks = jax.random.split(key, 7)
+        leaves = {"mlp_norm": jnp.ones((L, d), jnp.float32)}
+        if cfg.gated:
+            leaves["w_gate"] = _dense(ks[4], (L, d, f), d)
+        leaves["w_up"] = _dense(ks[5], (L, d, f), d)
+        leaves["w_down"] = _dense(ks[6], (L, f, d), f)
+        return leaves
+
+    def axes(self, cfg):
+        table = {
+            "mlp_norm": ("layers", None),
+            "w_gate": ("layers", "embed", "mlp"),
+            "w_up": ("layers", "embed", "mlp"),
+            "w_down": ("layers", "mlp", "embed"),
+        }
+        if not cfg.gated:  # an ungated feed-forward has no gate's weights
+            table.pop("w_gate")
+        return table
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("mlp"):
+            y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
+            return x + _feed_forward(
+                y, blk, cfg.dtype, ("mlp_gate", "mlp_up")), None
+
+    def widths(self, cfg):
+        return {"mlp_" + name: self._width(cfg)
+                for name in (*_ff_gates(cfg), "up")}
+
+    def params(self, cfg):
+        return cfg.ff_matrices * cfg.d_model * self._width(cfg)
+
+    def holds(self, cfg):
+        return self._width(cfg)  # the hidden product
+
+
+class _RoutedFF(Sublayer):
+    """The routed feed-forward (`_routed_ffn`) over the `held` of
+    `n_experts`, and with `n_shared_experts` one dense feed-forward beside
+    it that every token goes through, unweighted."""
+
+    matmuls = ("ws_gate", "ws_up", "ws_down")
+    moe_weights = ("router", "w_gate", "w_up", "w_down")
+    names = ("moe_slots", "moe_gate", "moe_up", "shared_gate", "shared_up")
+
+    def init(self, key, cfg, L):
+        d, f, held = cfg.d_model, cfg.ff_dim, cfg.held[1]
+        ks = jax.random.split(key, 7)
+        # the (held) experts are stacked behind the layer axis
+        leaves = {"mlp_norm": jnp.ones((L, d), jnp.float32)}
+        if cfg.gated:
+            leaves["w_gate"] = _dense(ks[4], (L, held, d, f), d)
+        leaves["w_up"] = _dense(ks[5], (L, held, d, f), d)
+        leaves["w_down"] = _dense(ks[6], (L, held, f, d), f)
+        leaves["router"] = _dense(
+            jax.random.fold_in(key, 7), (L, d, cfg.n_experts), d)
+        if cfg.n_shared_experts:
+            fs = cfg.shared_dim
+            kg, ku, kd = jax.random.split(jax.random.fold_in(key, 8), 3)
+            if cfg.gated:
+                leaves["ws_gate"] = _dense(kg, (L, d, fs), d)
+            leaves["ws_up"] = _dense(ku, (L, d, fs), d)
+            leaves["ws_down"] = _dense(kd, (L, fs, d), fs)
+        return leaves
+
+    def axes(self, cfg):
+        table = {
+            "mlp_norm": ("layers", None),
+            "w_gate": ("layers", "experts", "embed", "mlp"),
+            "w_up": ("layers", "experts", "embed", "mlp"),
+            "w_down": ("layers", "experts", "mlp", "embed"),
+            "router": ("layers", "embed", None),
+        }
+        if cfg.n_shared_experts:
+            table.update({
+                "ws_gate": ("layers", "embed", "mlp"),
+                "ws_up": ("layers", "embed", "mlp"),
+                "ws_down": ("layers", "mlp", "embed"),
+            })
+        if not cfg.gated:
+            table.pop("w_gate")
+            table.pop("ws_gate", None)
+        return table
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("mlp"):
+            y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
+            routed, readings = _routed_ffn(y, blk, cfg, site.mesh, site.bias)
             if "ws_up" in blk:  # every token, unweighted, whole on a share
                 with jax.named_scope("moe_shared"):
                     routed = routed + _feed_forward(
-                        y, blk, dt, ("shared_gate", "shared_up"), "ws")
-            x = x + routed
-        else:
-            x = x + _feed_forward(y, blk, dt, ("mlp_gate", "mlp_up"))
+                        y, blk, cfg.dtype, ("shared_gate", "shared_up"), "ws")
+            return x + routed, readings
+
+    def widths(self, cfg):
+        k, f = cfg.experts_per_token, cfg.ff_dim
+        widths = {}
+        # a layer that holds a share of the experts is one operation whose
+        # backward makes its rows again chunk by chunk: it has no names
+        if cfg.held[1] == cfg.n_experts:
+            widths["moe_slots"] = 2 * k * 4 // _item(cfg)
+            widths.update(
+                {"moe_" + name: k * f for name in (*_ff_gates(cfg), "up")})
+        if cfg.n_shared_experts:  # dense work on every token, share or not
+            widths.update({"shared_" + name: cfg.shared_dim
+                           for name in (*_ff_gates(cfg), "up")})
+        return widths
+
+    def params(self, cfg):
+        d, mats = cfg.d_model, cfg.ff_matrices
+        return (d * cfg.n_experts + cfg.held[1] * mats * d * cfg.ff_dim
+                + (mats * d * cfg.shared_dim if cfg.n_shared_experts else 0))
+
+    def holds(self, cfg):
+        """The dispatched rows and the experts' hidden product, where the
+        layer holds every expert; the shared experts' hidden product."""
+        rows = (cfg.experts_per_token * (cfg.d_model + cfg.ff_dim)
+                if cfg.held[1] == cfg.n_experts else 0)
+        return rows + (cfg.shared_dim if cfg.n_shared_experts else 0)
+
+    def flops(self, cfg, seq_len):
+        d, mats = cfg.d_model, cfg.ff_matrices
+        # active parameters: the router and the held among a token's experts
+        matmul = 2 * d * cfg.n_experts + (
+            cfg.experts_per_token * cfg.held[1] / max(cfg.n_experts, 1)
+            * 2 * mats * d * cfg.ff_dim)
+        if cfg.n_shared_experts:
+            matmul += 2 * mats * d * cfg.shared_dim  # every token, whole
+        return matmul, 0
+
+
+# The two tables. A `model_config` PR that draws a new kind of layer adds
+# its record here (and its `checkpoint_name`s to `_SAVE_ORDER`): the names
+# are those `layer_types` and `sublayer_types` may hold.
+_OPERATORS: Dict[str, Sublayer] = {
+    "full_attention": _PlainAttention(sliding=False),
+    "sliding_attention": _PlainAttention(sliding=True),
+    "latent_attention": _LatentAttention(),
+    "conv": _ShortConv(),
+    "mamba2": _Mamba2(),
+}
+_FEED_FORWARDS: Dict[str, Sublayer] = {
+    "dense_ff": _DenseFF(),
+    "routed_ff": _RoutedFF(),
+}
+
+
+def _sublayers(kind: LayerKind) -> Tuple[Sublayer, ...]:
+    """The records of a layer of `kind`: its operator's, then its
+    feed-forward's."""
+    operator = () if kind.op is None else (_OPERATORS[kind.op],)
+    if not kind.ff:
+        return operator
+    return (*operator,
+            _FEED_FORWARDS["routed_ff" if kind.routed else "dense_ff"])
+
+
+# ------------------------------------------------------------------ params
+
+def _blocks_init(k_blk, cfg: TransformerConfig, kind: LayerKind, L: int):
+    """`L` layers of one kind, every leaf stacked on a leading layer axis."""
+    return {name: leaf for sub in _sublayers(kind)
+            for name, leaf in sub.init(k_blk, cfg, L).items()}
+
+
+def _one_kind(segs: List[Segment]) -> bool:
+    return len(segs) == 1 and len(segs[0].layout) == 1
+
+
+def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
+    """f32 master params. Block params are stacked on a leading layer axis:
+    one tree for a model of one kind of layer, else a list of segments, each
+    a list of one tree per layer of its period, stacked over its periods."""
+    k_emb, k_blk, k_out = jax.random.split(rng, 3)
+    d = cfg.d_model
+    segs = segments(cfg)
+    if _one_kind(segs):
+        blocks = _blocks_init(k_blk, cfg, segs[0].layout[0], cfg.n_layers)
+    else:
+        blocks = [
+            [_blocks_init(jax.random.fold_in(jax.random.fold_in(k_blk, si), pi),
+                          cfg, kind, seg.periods)
+             for pi, kind in enumerate(seg.layout)]
+            for si, seg in enumerate(segs)]
+    params = {
+        "embed": jax.random.normal(
+            k_emb, (cfg.vocab_size, d), jnp.float32
+        ) * 0.02,
+        "blocks": blocks,
+        "final_norm": jnp.ones((d,), jnp.float32),
+    }
+    if not cfg.tied_embeddings:
+        params["unembed"] = _dense(k_out, (d, cfg.vocab_size), d)
+    return params
+
+
+def expert_bias_init(cfg: TransformerConfig):
+    """The routers' selection bias, [routed layers, n_experts] float32:
+    zeros, as training starts. State of the step, not a parameter."""
+    return jnp.zeros((cfg.n_routed_layers, cfg.n_experts), jnp.float32)
+
+
+_LOGICAL_AXES = {
+    "embed": ("vocab", "embed"),
+    "unembed": ("embed", "vocab"),
+    "final_norm": (None,),
+}
+
+
+def _block_axes(cfg: TransformerConfig, kind: LayerKind):
+    return {name: axes for sub in _sublayers(kind)
+            for name, axes in sub.axes(cfg).items()}
+
+
+def param_shardings(mesh, cfg: TransformerConfig):
+    """NamedSharding pytree matching transformer_init's structure, derived
+    from the logical-axis table + default_transformer_rules."""
+    rules = mesh_lib.default_transformer_rules(mesh)
+
+    def build(node):
+        if isinstance(node, dict):
+            return {k: build(v) for k, v in node.items()}
+        if isinstance(node, list):
+            return [build(v) for v in node]
+        return NamedSharding(mesh, rules.spec(node))
+
+    table = dict(_LOGICAL_AXES)
+    if cfg.tied_embeddings:
+        table.pop("unembed", None)
+    segs = segments(cfg)
+    if _one_kind(segs):
+        table["blocks"] = _block_axes(cfg, segs[0].layout[0])
+    else:
+        table["blocks"] = [[_block_axes(cfg, kind) for kind in seg.layout]
+                           for seg in segs]
+    return build(table)
+
+
+# ------------------------------------------------- the block and the stack
+
+def own_buffer_weights(blk, kind: LayerKind) -> Tuple[str, ...]:
+    """The leaves of a block of `kind` that `_own_weights` hands its
+    matmuls: the weights of its plain matmuls (`Sublayer.matmuls`) that it
+    holds. The routed experts' go to `ops/moe.py` as they are, and the
+    norms' vectors and the router are no such matmul."""
+    return tuple(name for sub in _sublayers(kind) for name in sub.matmuls
+                 if name in blk)
+
+
+def _own_weights(blk, kind: LayerKind, dt, sliced: bool):
+    """`blk` with the weights of its plain matmuls in the compute dtype,
+    each behind a barrier, so that the sites' `blk[name].astype(dt)` finds
+    them made.
+
+    Left to itself XLA fuses what takes a weight's gradient into the matmul
+    that makes it: the cast to float32 and the dynamic update of the scanned
+    stack's gradient, and in a segment of one period (whose loop it
+    unrolls) AdamW's whole update of the weight and its two moments. The
+    TPU compiler then tiles that matmul worse: 37 to 53 % of the MXU's peak
+    with AdamW inside and 69 to 80 % with the update, for 69 to 85 % into a
+    buffer (PERF.md section 6, PR 37; the head's had the same in PR 28).
+    `_own_cotangent` puts the barrier on the gradient alone: the matmul
+    writes a `[d, f]` array in `dt`, and what was fused in runs after it at
+    the memory's pace.
+
+    Where the weight arrives whole (`sliced` false: a segment of one
+    period) `_own_buffer` also makes the cast an array of its own for the
+    forward, the rematerialised and the input-gradient matmuls, which read
+    a float32 `[1, d, f]` through a fused cast at half the pace (12.0 ms for
+    17.9). A slice of a scanned stack is left to the compiler, which makes
+    the slice and the cast one operand of the matmul: there the copy costs
+    6 bytes an element a pass and the chip shows no matmul the faster for
+    it (`mistral7b.tokens4k`: -0.7 %)."""
+    own = _own_cotangent if sliced else _own_buffer
+    return {**blk, **{name: own(blk[name].astype(dt))
+                      for name in own_buffer_weights(blk, kind)}}
+
+
+def _periods(blk) -> int:
+    """The length of a block tree's leading layer axis."""
+    return jax.tree.leaves(blk)[0].shape[0]
+
+
+def _segment_trees(blocks):
+    """`params["blocks"]` as its segments, each one tree per layer of its
+    period: a model of one kind of layer is one segment of it."""
+    return [[blocks]] if isinstance(blocks, dict) else blocks
+
+
+def own_buffers(blocks, cfg: TransformerConfig) -> Tuple[int, int, int]:
+    """(the buffers `_own_weights` makes for the layers of
+    `params["blocks"]`: one for every matmul weight's gradient and one more
+    for every weight of a segment of one period; their bytes in the compute
+    dtype, whole as a matmul takes them; the widest layer's weights'
+    bytes)."""
+    item = _item(cfg)
+    count = total = widest = 0
+    kinds = [kind for seg in segments(cfg) for kind in seg.layout]
+    trees = [blk for blks in _segment_trees(blocks) for blk in blks]
+    for kind, blk in zip(kinds, trees):
+        names = own_buffer_weights(blk, kind)
+        periods = _periods(blk)
+        layer = item * sum(math.prod(blk[name].shape[1:]) for name in names)
+        each = 2 if periods == 1 else 1
+        count += each * periods * len(names)
+        total += each * periods * layer
+        widest = max(widest, layer)
+    return count, total, widest
+
+
+def _block(x, blk, positions, bias, cfg: TransformerConfig, kind: LayerKind,
+           seq_axis: Optional[str], seq_size: int, mesh=None,
+           keep_ctx: bool = False, sliced: bool = False):
+    """One block of `kind`: (x, the routed feed-forward's readings or
+    None), through its sublayers' forwards in turn. `keep_ctx`: the
+    attention kernel names its backward's residuals `attn_ctx`. `sliced`:
+    `blk` is one of several periods of a scanned stack (`_own_weights`)."""
+    blk = _own_weights(blk, kind, cfg.dtype, sliced)
+    site = _Site(positions, bias, seq_axis, seq_size, mesh, keep_ctx)
+    sublayers = _sublayers(kind)
+    if seq_axis is not None:
+        for sub in sublayers:
+            if sub.no_sequence_axis:
+                raise NotImplementedError(sub.no_sequence_axis)
+    readings = None
+    for sub in sublayers:  # the feed-forward is the last: its readings stay
+        x, readings = sub.forward(x, blk, cfg, site)
     return x, readings
 
 
@@ -1151,26 +1549,23 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
               if saved_names else None)
 
     def scan_body(sliced: bool, layout: Tuple[LayerKind, ...]):
-        def block_fn(sliding: bool):
+        def block_fn(kind: LayerKind):
             blk_fn = partial(
-                _block, cfg=cfg, seq_axis=seq_axis, seq_size=seq_size,
-                mesh=mesh, keep_ctx="attn_ctx" in saved_names, sliced=sliced,
-                sliding=sliding)
+                _block, cfg=cfg, kind=kind, seq_axis=seq_axis,
+                seq_size=seq_size, mesh=mesh,
+                keep_ctx="attn_ctx" in saved_names, sliced=sliced)
             if cfg.remat:
                 blk_fn = jax.checkpoint(blk_fn, policy=policy,
                                         static_argnums=())
             return blk_fn
 
-        # a layer under the window has the leaves of one that is not: the
-        # layout says which it is
-        under_window = [kind.op == "sliding_attention" for kind in layout]
-        blk_fns = {sliding: block_fn(sliding) for sliding in set(under_window)}
+        blk_fns = {kind: block_fn(kind) for kind in set(layout)}
 
         def body(x, period):
             blks, biases = period
             readings = []
-            for sliding, blk, bias in zip(under_window, blks, biases):
-                x, reading = blk_fns[sliding](x, blk, positions, bias)
+            for kind, blk, bias in zip(layout, blks, biases):
+                x, reading = blk_fns[kind](x, blk, positions, bias)
                 if reading is not None:
                     readings.append(reading)
             return x, readings
@@ -1183,7 +1578,7 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
         # this segment's rows of the bias, one [periods, E] per routed layer
         # of its period
         biases = [None] * len(blks)
-        routed = [i for i, blk in enumerate(blks) if "router" in blk]
+        routed = [i for i, kind in enumerate(seg.layout) if kind.routed]
         if expert_bias is not None and routed:
             rows = expert_bias[
                 routed_before:routed_before + periods * len(routed)
@@ -1302,73 +1697,24 @@ _SAVE_ORDER = (
     "mlp_gate",   # the dense feed-forward's gate product, before the silu
     "mlp_up",     # and its up product
 )
+_UNRANKED = {name for table in (_OPERATORS, _FEED_FORWARDS)
+             for sub in table.values() for name in sub.names
+             } - set(_SAVE_ORDER)
+if _UNRANKED:  # the rule would pass such a name by and never keep it
+    raise ValueError(
+        f"a sublayer makes the names {sorted(_UNRANKED)}, which "
+        "_SAVE_ORDER does not rank")
 _SAVE_RESERVE = 1 << 30  # the step stays this far under the device's limit
 _HEAD_CHUNK = 2048  # `lm_head_cross_entropy`'s chunk_tokens
-
-
-def _tile_lanes(width: int) -> int:
-    """`width` as HBM tiles a minor dimension: whole tiles of 128 lanes."""
-    return -(-width // 128) * 128
 
 
 def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
     """(values a token of one layer of `kind`: {name: width in elements of
     the compute dtype}, the layer's parameters)."""
-    d, hk, dh = cfg.d_model, cfg.kv_heads, cfg.head_dim
-    h = cfg.heads(kind.op)
-    k, f = cfg.experts_per_token, cfg.ff_dim
-    item = jnp.dtype(cfg.dtype).itemsize
-    mats = cfg.ff_matrices
-    if kind.op is None:
-        widths, params = {}, 0
-    elif kind.op == "mamba2":
-        inner = cfg.mamba_inner
-        wide = inner + cfg.mamba_conv_dim + cfg.mamba_heads
-        widths = {"mamba_in": wide, "ssd_out": inner}
-        params = d * wide + inner * d
-    elif kind.op == "conv":
-        widths = {"conv_res": d, "conv_in": 3 * d}
-        params = 4 * d * d
-    elif kind.op == "latent_attention":
-        r, dv = cfg.kv_lora_rank, cfg.v_head_dim
-        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-        up = cfg.qk_nope_head_dim + dv
-        widths = {
-            "attn_ctx": h * _tile_lanes(dv) + h * 4 // item,
-            "attn_res": d,
-            # out of `wq`, `wkv_a` and `wkv_b`
-            "attn_qkv": h * qk + r + cfg.qk_rope_head_dim + h * up,
-        }
-        params = (d * h * qk + d * (r + cfg.qk_rope_head_dim) + r * h * up
-                  + h * dv * d)
-    else:
-        widths = {
-            # o and lse as one float32 column
-            "attn_ctx": h * _tile_lanes(dh) + h * 4 // item,
-            "attn_res": d,
-            "attn_qkv": (h + 2 * hk) * dh,
-        }
-        params = d * (h + 2 * hk) * dh + h * dh * d
-        if cfg.attn_gate:
-            params += d * h
-    if not kind.ff:
-        return widths, params
-    gates = ("gate",) if cfg.gated else ()
-    if not kind.routed:
-        f = cfg.d_ff_dense if cfg.n_experts and cfg.d_ff_dense else f
-        widths.update({"mlp_" + name: f for name in (*gates, "up")})
-        params += mats * d * f
-    else:
-        params += d * cfg.n_experts + cfg.held[1] * mats * d * f
-        # a layer that holds a share of the experts is one operation whose
-        # backward makes its rows again chunk by chunk: it has no names
-        if cfg.held[1] == cfg.n_experts:
-            widths["moe_slots"] = 2 * k * 4 // item
-            widths.update({"moe_" + name: k * f for name in (*gates, "up")})
-        if cfg.n_shared_experts:  # dense work on every token, share or not
-            fs = cfg.shared_dim
-            widths.update({"shared_" + name: fs for name in (*gates, "up")})
-            params += mats * d * fs
+    widths, params = {}, 0
+    for sub in _sublayers(kind):
+        widths.update(sub.widths(cfg))
+        params += sub.params(cfg)
     return widths, params
 
 
@@ -1381,25 +1727,6 @@ def _saved_bytes(cfg: TransformerConfig, tokens: int) -> Dict[str, int]:
         for name, width in _layer_widths(cfg, kind)[0].items():
             total[name] = total.get(name, 0) + tokens * width * item
     return {name: total[name] for name in _SAVE_ORDER if name in total}
-
-
-def _scan_bytes_per_token(cfg: TransformerConfig) -> int:
-    """Bytes a token that a mixer's scan holds in HBM in its backward, by
-    the path `ssd` takes (`ops/ssd.py`). `jax.numpy`: the [H, Q, Q] arrays
-    of a chunk, Q values a token and head: the decays and their gradient in
-    float32, the masked scores and theirs in the compute dtype and in
-    float32. The kernels keep those in VMEM. What they leave is each
-    chunk's entering state, `H P N / Q` float32 values a token, and dt and
-    cum with their cotangents: as columns `[b, G, T, R]` float32, which lie
-    in HBM at a tile's 128 lanes a group (the four the kernels read and
-    write and three that the transposes from and to `[b, T, H]` make),
-    and as rows and plain `[b, T, H]` arrays, two `H` wide all told."""
-    item = jnp.dtype(cfg.dtype).itemsize
-    H, Q, G = cfg.mamba_heads, cfg.ssd_chunk, cfg.ssm_groups
-    if _kernel_impl(cfg) != "pallas" or scan_untiled(
-            Q, cfg.ssm_state, H // G, cfg.mamba_head_dim):
-        return H * Q * (4 * 4 + 2 * item)
-    return 4 * (cfg.mamba_inner * cfg.ssm_state // Q + 7 * G * 128 + 2 * H)
 
 
 @lru_cache(maxsize=None)
@@ -1417,15 +1744,9 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     larger of the head's chunk and one block in its backward.
 
     A block in its backward is taken as every value of its widest layer at
-    once: the named ones, the two normed inputs, the stream's cotangent,
-    q, k and v as the kernel takes them (q at the heads the layer's
-    operator has, k and v at the key-value heads, which the kernels' index
-    maps share among a group; latent attention's k and v at as many heads as
-    q, q and k at two tiles of lanes),
-    lse and delta at a tile's 128 lanes,
-    the feed-forward's (and the shared experts') hidden product, a
-    routed layer's dispatched rows and what a mixer's scan holds by the
-    path it takes (`_scan_bytes_per_token`); with the compute-dtype copy of
+    once: the stream's cotangent and, of each of its sublayers, the normed
+    input, the named values (`Sublayer.widths`) and what the kind says it
+    holds beside them (`Sublayer.holds`); with the compute-dtype copy of
     its weights and, where the parameters are sharded, the same weights
     gathered whole
     and their float32 gradient before it is scattered. Against the chip
@@ -1438,37 +1759,15 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     percent, a step that asks for the chip's last GiB is compiled to fit
     and runs slower than the one that keeps nothing."""
     d = cfg.d_model
-    item = jnp.dtype(cfg.dtype).itemsize
+    item = _item(cfg)
     whole = _whole_param_bytes(cfg)
     sharded = param_bytes < whole
     block = 0
     for kind in set(cfg.layers):
-        h = cfg.heads(kind.op)
+        sublayers = _sublayers(kind)
         widths, params = _layer_widths(cfg, kind)
-        # the normed inputs (an operator's, a feed-forward's) and the
-        # stream's cotangent
-        width = sum(widths.values()) + (
-            3 if kind.op is not None and kind.ff else 2) * d
-        if kind.op == "mamba2":
-            # the convolution's sum and its silu, the gated output and the
-            # normed one
-            width += 2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner
-            width += _scan_bytes_per_token(cfg) // item
-        elif kind.op == "conv":
-            width += 3 * d  # the gate's product, the taps' sum, the gated
-        elif kind.op == "latent_attention":  # q and k at their own width
-            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-            width += (h * (2 * _tile_lanes(qk) + _tile_lanes(cfg.v_head_dim))
-                      + 2 * h * 128 * 4 // item)
-        elif kind.op is not None:
-            width += ((h + 2 * cfg.kv_heads) * _tile_lanes(cfg.head_dim)
-                      + 2 * h * 128 * 4 // item)
-        if kind.routed:
-            width += (cfg.experts_per_token * (d + cfg.ff_dim)
-                      if "moe_up" in widths else 0)
-            width += widths.get("shared_up", 0)  # the shared hidden product
-        elif kind.ff:
-            width += widths["mlp_up"]
+        width = (sum(widths.values()) + (len(sublayers) + 1) * d
+                 + sum(sub.holds(cfg) for sub in sublayers))
         weights = params * item + (params * (item + 4) if sharded else 0)
         block = max(block, tokens * width * item + weights)
     unembed = cfg.vocab_size * d * item * param_bytes // whole
@@ -1611,7 +1910,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
                 limit))
         # static, so counted as the step is traced: once a step's program
         buffers, their_bytes, widest = own_buffers(
-            state["params"]["blocks"], cfg.dtype)
+            state["params"]["blocks"], cfg)
         tracing.count("train.own_buffers", buffers)
         tracing.count("train.own_buffer_bytes", their_bytes)
         logger.info(
@@ -1648,65 +1947,16 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
                               "params": p_shard, "state": state_shard}
 
 
-def keys_per_query(seq_len: int, window: Optional[int] = None) -> float:
-    """The keys a query of a causal sequence of `seq_len` sees, on average:
-    `(seq_len + 1) / 2`, and under a window that is shorter than the
-    sequence the band's pairs over its queries (the first `window` queries
-    see a triangle, every later one `window` keys)."""
-    if not window or window >= seq_len:
-        return (seq_len + 1) / 2
-    return (window * (window + 1) / 2 + (seq_len - window) * window) / seq_len
-
-
 def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
     """(matmul fwd flops/token over the layers, causal attn fwd flops/token
     over the layers, lm-head fwd flops/token)."""
-    d, f = cfg.d_model, cfg.ff_dim
-    h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    dense_f = f if cfg.d_ff_dense is None or not cfg.n_experts else cfg.d_ff_dense
-    mats = cfg.ff_matrices
-    # active parameters: the router and the held among a token's experts
-    routed = 2 * d * cfg.n_experts + (
-        cfg.experts_per_token * cfg.held[1] / max(cfg.n_experts, 1)
-        * 2 * mats * d * f)
-    # Causal attention: token t attends to t+1 keys, so the average query
-    # sees (seq_len + 1) / 2 positions; qk^T and pv each cost 2*h*dh flops
-    # per (query, key) pair. The flash kernel really skips the masked-out
-    # tiles, so crediting full seq_len here would overcount ~2x.
-    if cfg.n_shared_experts:
-        routed += 2 * mats * d * cfg.shared_dim  # every token, whole
     matmul = attn = 0.0
     for kind in cfg.layers:
-        if kind.op == "mamba2":
-            inner, H, N = cfg.mamba_inner, cfg.mamba_heads, cfg.ssm_state
-            matmul += 2 * d * (inner + cfg.mamba_conv_dim + H) + 2 * inner * d
-            # the scan as it is computed, whole chunks: the scores of a
-            # chunk, their product with the inputs, the chunk's state and
-            # the state's contribution
-            matmul += (2 * cfg.ssd_chunk * (cfg.ssm_groups * N + inner)
-                       + 2 * 2 * inner * N)
-        elif kind.op == "conv":
-            matmul += 2 * d * 3 * d + 2 * d * d
-        elif kind.op == "latent_attention":
-            r, dv = cfg.kv_lora_rank, cfg.v_head_dim
-            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-            matmul += 2 * (d * h * qk + d * (r + cfg.qk_rope_head_dim)
-                           + r * h * (cfg.qk_nope_head_dim + dv) + h * dv * d)
-            # scores over q and k's width, the values over v's
-            attn += 2 * h * (qk + dv) * ((seq_len + 1) / 2)
-        elif kind.op is not None:
-            heads = cfg.heads(kind.op)
-            matmul += 2 * d * (heads * dh + 2 * hk * dh) + 2 * heads * dh * d
-            if cfg.attn_gate:
-                matmul += 2 * d * heads
-            # a layer under the window: the band's pairs, not the triangle's
-            attn += 2 * 2 * heads * dh * keys_per_query(
-                seq_len, cfg.sliding_window
-                if kind.op == "sliding_attention" else None)
-        if kind.ff:
-            matmul += routed if kind.routed else 2 * mats * d * dense_f
-    embed = 2 * d * cfg.vocab_size
-    return matmul, attn, embed
+        for sub in _sublayers(kind):
+            of_matmuls, of_attention = sub.flops(cfg, seq_len)
+            matmul += of_matmuls
+            attn += of_attention
+    return matmul, attn, 2 * cfg.d_model * cfg.vocab_size
 
 
 def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:

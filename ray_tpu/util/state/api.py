@@ -337,9 +337,11 @@ def critical_path(trace_id: Optional[str] = None) -> Dict[str, Any]:
     than inferred from a timeline by eye.
 
     With no trace_id, the longest recorded trace (largest start->finish
-    extent) is analyzed. Returns ``{trace_id, total_s, path, segments,
-    dominant}`` — ``path`` in causal order, ``segments`` sorted by self
-    time descending, ``dominant`` the name of the top segment."""
+    extent) is analyzed, one of ``init``'s own spans alone only where there
+    is no other: with tracing on they are a trace of the driver's, and a
+    job's question is about its tasks. Returns ``{trace_id, total_s, path,
+    segments, dominant}`` — ``path`` in causal order, ``segments`` sorted by
+    self time descending, ``dominant`` the name of the top segment."""
     spans = list_spans(trace_id=trace_id, limit=100000)
     if not spans:
         return {
@@ -369,8 +371,12 @@ def critical_path(trace_id: Optional[str] = None) -> Dict[str, Any]:
             "dominant": None,
         }
     if trace_id is None:
+        def _of_init(s: dict) -> bool:
+            return (s.get("name") or "").split(".")[0] == "init"
+
+        of_work = [t for t, of_t in by_trace.items() if not all(map(_of_init, of_t))]
         trace_id = max(
-            by_trace,
+            of_work or by_trace,
             key=lambda t: max(_end(s) for s in by_trace[t])
             - min(_start(s) for s in by_trace[t]),
         )

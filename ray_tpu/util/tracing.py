@@ -391,9 +391,10 @@ def _fold(into: Dict[str, list], rows: Dict[str, list]) -> None:
 def table(mark: bool = False) -> Dict[str, list]:
     """``{name: [count, seconds, longest_seconds, time of the longest]}``
     of the train path's spans in this process, every thread's merged.
-    Count and seconds run from the process's start, so that a reader takes
-    the difference of two readings for a window. The longest cannot be had
-    that way: it is the longest since the last reading with ``mark`` (0
+    Count and seconds run from the process's start, or in a driver from its
+    last ``ray_tpu.shutdown()`` (:func:`clear_table`), so that a reader
+    takes the difference of two readings for a window. The longest cannot
+    be had that way: it is the longest since the last reading with ``mark`` (0
     where the name saw no span since then), with the wall-clock time at
     which it ended. One reader of a process marks: the train session, at
     every report."""
@@ -417,6 +418,18 @@ def table(mark: bool = False) -> Dict[str, list]:
                else [count, seconds, 0.0, 0.0])
         for name, (count, seconds, longest, end, at) in merged.items()
     }
+
+
+def clear_table() -> None:
+    """Empty the table and the counters: the cluster this process drove has
+    ended (``ray_tpu.shutdown()``), and the next one's ``init`` is not to
+    be read together with this one's. A span that is open now is counted
+    when it ends."""
+    with _tables_lock:
+        for _, rows in _tables.values():
+            rows.clear()
+        _retired.clear()
+        _counters.clear()
 
 
 def count(name: str, n: int = 1) -> None:

@@ -315,7 +315,7 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
             held = {**w, **{k: w[k][first:first + 2]
                             for k in ("w_gate", "w_up", "w_down")}}
             out, readings = model._block(
-                x, held, positions, None, share_cfg, None, 1)
+                x, held, positions, None, share_cfg, cfg.layers[0], None, 1)
             assert int(readings["dropped_slots"]) == 0
             assert readings["expert_load"].shape == (8,)
             theirs, _, _ = reference.routed_feed_forward(

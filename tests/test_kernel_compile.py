@@ -8,6 +8,7 @@ say nothing about results or times. Shapes are the ones chip_smoke.py
 trains: GPT-2-small widths, batch 32, seq 1024, bf16.
 """
 
+import functools
 import importlib
 import os
 
@@ -455,7 +456,7 @@ SCAN_CALLS = {"nemotron3nano.tokens8k": (8, 4)}
 
 def _token_cell_step(cell_name, devices, monkeypatch):
     """(lowered step of the cell at its real shapes on described devices,
-    what the rule chose while it was traced)."""
+    what the rule chose while it was traced), as `tr` stands patched."""
     from chipbench import loop, spec
     from ray_tpu.models import transformer as tr
 
@@ -485,6 +486,39 @@ def _token_cell_step(cell_name, devices, monkeypatch):
     return lowered, chosen[0]
 
 
+class _Step:
+    """A cell's step lowered once and compiled at most once, for every test
+    of this module that reads it."""
+
+    def __init__(self, lowered, chosen):
+        self.lowered, self.chosen = lowered, chosen
+
+    @functools.cached_property
+    def compiled(self):
+        return self.lowered.compile()
+
+
+@pytest.fixture(scope="module")
+def token_steps(v5e):
+    """`step_of(cell, limited)`: the cell's `_Step`, with the limit's reader
+    patched to a v5e's (a described device reports none) where `limited`;
+    one lowering and one compilation a (cell, limited) among the tests."""
+    from ray_tpu.models import transformer as tr
+
+    made = {}
+
+    def step_of(cell_name, limited):
+        if (cell_name, limited) not in made:
+            with pytest.MonkeyPatch.context() as patch:
+                if limited:
+                    patch.setattr(tr, "_memory_limit", lambda mesh: HBM_LIMIT)
+                made[cell_name, limited] = _Step(
+                    *_token_cell_step(cell_name, v5e, patch))
+        return made[cell_name, limited]
+
+    return step_of
+
+
 def _calls(text, kernel):
     import re
 
@@ -493,17 +527,14 @@ def _calls(text, kernel):
 
 @pytest.mark.parametrize("cell_name", list(TOKEN_CELLS))
 def test_token_step_with_what_it_keeps_compiles_and_fits(
-        v5e, monkeypatch, cell_name):
+        token_steps, cell_name):
     """The reader patched to a v5e's limit (a described device reports
     none): the step the chip would run compiles, stays a GB under the limit
     by the compiler's own count, and runs the flash forward once a layer
     and the whole flash backward as one kernel."""
-    from ray_tpu.models import transformer as tr
-
-    monkeypatch.setattr(tr, "_memory_limit", lambda mesh: HBM_LIMIT)
-    lowered, chosen = _token_cell_step(cell_name, v5e, monkeypatch)
+    step = token_steps(cell_name, limited=True)
+    chosen, compiled = step.chosen, step.compiled
     assert next(iter(chosen)) == "attn_ctx" and "attn_res" in chosen
-    compiled = lowered.compile()
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             - memory.alias_size_in_bytes) <= HBM_LIMIT - 10**9
@@ -526,7 +557,7 @@ def test_token_step_with_what_it_keeps_compiles_and_fits(
 
 @pytest.mark.parametrize("cell_name", list(TOKEN_CELLS))
 def test_token_step_without_a_limit_is_the_step_without_names(
-        v5e, monkeypatch, cell_name):
+        v5e, token_steps, monkeypatch, cell_name):
     """A described device reports no limit: nothing is chosen, no policy is
     passed, and the step lowers to the text of the program that has no
     names at all (the parent's, but for metadata)."""
@@ -540,11 +571,11 @@ def test_token_step_without_a_limit_is_the_step_without_names(
         text = re.sub(r"@(\w+?)_\d+\b", r"@\1", lowered.as_text())
         return re.sub(r'backend_config = "[^"]*"', "", text)
 
-    lowered, chosen = _token_cell_step(cell_name, v5e, monkeypatch)
-    assert chosen == {}
+    step = token_steps(cell_name, limited=False)
+    assert step.chosen == {}
     monkeypatch.setattr(tr, "checkpoint_name", lambda x, name: x)
     without_names, _ = _token_cell_step(cell_name, v5e, monkeypatch)
-    assert text_of(without_names) == text_of(lowered)
+    assert text_of(without_names) == text_of(step.lowered)
 
 
 # ------------------- a block's weight matmuls from and to buffers of their own
@@ -593,7 +624,7 @@ def _block_matmul_fusions(text):
 
 @pytest.mark.parametrize("cell_name", ["lfm2moe.tokens8k", "mistral7b.tokens4k"])
 def test_no_block_matmul_carries_an_update_of_the_state(
-        v5e, monkeypatch, cell_name):
+        token_steps, cell_name):
     """What PR 37 took out, held out: compiled for a v5e with what the rule
     keeps there, no fusion of a block's matmul also holds a dynamic update
     (the weight gradient written into the scanned stack) or writes more
@@ -603,15 +634,12 @@ def test_no_block_matmul_carries_an_update_of_the_state(
     `lfm2moe.tokens8k` the second in 15."""
     import re
 
-    from ray_tpu.models import transformer as tr
-
-    monkeypatch.setattr(tr, "_memory_limit", lambda mesh: HBM_LIMIT)
-    lowered, _ = _token_cell_step(cell_name, v5e, monkeypatch)
+    step = token_steps(cell_name, limited=True)
     state_shapes = {
         ",".join(map(str, aval.shape))
-        for aval in jax.tree.leaves(lowered.in_avals)
+        for aval in jax.tree.leaves(step.lowered.in_avals)
         if aval.dtype == jnp.float32 and aval.ndim >= 2}
-    fusions = list(_block_matmul_fusions(lowered.compile().as_text()))
+    fusions = list(_block_matmul_fusions(step.compiled.as_text()))
     assert len(fusions) >= 25  # a layer's products, forward and backward
     for name, result, body in fusions:
         assert not any(" dynamic-update-slice(" in line for line in body), name

@@ -619,7 +619,7 @@ def test_parameter_tree_of_the_cut():
         assert blk["w_gate"].shape == (1, 2, 64, 32)
         assert blk["router"].shape == (1, 64, 8)
         assert blk["ws_up"].shape == (1, 64, 32)
-    assert "w_gate_attn" in model.own_buffer_weights(dense)
+    assert "w_gate_attn" in model.own_buffer_weights(dense, cfg.layers[0])
     # without the new fields nothing of a plain stack's tree changes
     plain = transformer_init(key(0), TransformerConfig(
         vocab_size=64, d_model=32, n_layers=2, n_heads=2))
@@ -640,7 +640,7 @@ def test_a_layer_of_each_type_agrees_with_the_reference(kind):
     positions = jnp.broadcast_to(jnp.arange(40), (2, 40))
     with jax.default_matmul_precision("highest"):
         ours = model._attention_layer(
-            x, w, positions, cfg, None, 1, op=kind)
+            x, w, positions, cfg, None, 1, op=model._OPERATORS[kind])
         theirs = reference.attention(x, w, as_reference_config(cfg), kind)
         np.testing.assert_allclose(ours, theirs, rtol=2e-4, atol=2e-5)
         # each part of it decides something, in the layers it belongs to
@@ -663,9 +663,10 @@ def test_a_layer_of_each_type_agrees_with_the_reference(kind):
 def test_both_rotary_recipes_by_hand():
     cfg = tiny(rope_scaling=tuple(sorted(YARN.items())), d_head=128, n_heads=1,
                n_heads_sliding=1, n_kv_heads=1)
-    theta, share, scaling = cfg.rotary("full_attention")
+    theta, share, scaling = model._OPERATORS["full_attention"].rotary(cfg)
     assert (theta, share) == (500000.0, 0.5) and scaling["factor"] == 64
-    assert cfg.rotary("sliding_attention") == (10000.0, 1.0, None)
+    assert model._OPERATORS["sliding_attention"].rotary(cfg) == (
+        10000.0, 1.0, None)
     # 64 turned columns, theta 500000, factor 64 over 4096: dim(r) =
     # 64 ln(4096 / (2 pi r)) / (2 ln 500000) is 5.66 at 64 turns and 15.80 at
     # one, so the ramp runs from pair 5 to pair 16
@@ -753,7 +754,7 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
             share_cfg = dataclasses.replace(cfg, experts_held=(first, 2))
             held = {**w, **{k: w[k][first:first + 2] for k in names}}
             out, readings = model._block(
-                x, held, positions, None, share_cfg, None, 1, sliding=True)
+                x, held, positions, None, share_cfg, cfg.layers[0], None, 1)
             assert int(readings["dropped_slots"]) == 0
             assert readings["expert_load"].shape == (8,)
             theirs, _, _ = reference.routed_feed_forward(
@@ -802,12 +803,12 @@ def test_the_published_pattern_of_forty_layers_builds_and_steps():
 
 def test_a_window_under_a_sequence_axis_is_refused():
     cfg = tiny()
-    w = {k: v[0] for k, v in model._blocks_init(
-        key(0), cfg, model.LayerKind("sliding_attention", False), 1).items()}
+    kind = model.LayerKind("sliding_attention", False)
+    w = {k: v[0] for k, v in model._blocks_init(key(0), cfg, kind, 1).items()}
     x = jnp.zeros((1, 16, 64))
     with pytest.raises(NotImplementedError, match="ring attention has no band"):
-        model._block(x, w, jnp.zeros((1, 16), jnp.int32), None, cfg,
-                     "sequence", 2, sliding=True)
+        model._block(x, w, jnp.zeros((1, 16), jnp.int32), None, cfg, kind,
+                     "sequence", 2)
 
 
 # ------------------------------------------------------- shardings, remat

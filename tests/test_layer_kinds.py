@@ -1,0 +1,162 @@
+"""The two tables of `ray_tpu/models/transformer.py` (`_OPERATORS`,
+`_FEED_FORWARDS`): what a record says of its kind in one place agrees with
+what it says in the others, at tiny widths on the CPU, and a name that
+neither table has is refused."""
+
+import importlib.util
+import inspect
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import TransformerConfig
+from ray_tpu.models import transformer as model
+from ray_tpu.models.transformer import LayerKind
+
+L = 2
+BASE = dict(vocab_size=64, d_model=32, n_layers=1, n_heads=4, n_kv_heads=2,
+            d_ff=48, max_seq_len=32, attention_impl="xla")
+ROUTED = dict(n_experts=4, experts_per_token=2, n_shared_experts=1)
+MAMBA = dict(mamba_heads=4, mamba_head_dim=8, ssm_state=8, ssm_groups=2,
+             ssd_chunk=8)
+# {case: (the table, the record's name, the configuration's keys, the names
+# its forward makes: None for all the record has)}. The case of a record's
+# own name turns on every leaf it can have.
+CASES = {
+    "full_attention": ("op", "full_attention", dict(
+        attn_gate=True, qk_norm="head"), None),
+    "full_attention_plain": ("op", "full_attention", {}, None),
+    "sliding_attention": ("op", "sliding_attention", dict(
+        attn_gate=True, qk_norm=True, sliding_window=4, n_heads_sliding=8,
+        rope_theta_sliding=1e4), None),
+    "latent_attention": ("op", "latent_attention", dict(
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8), None),
+    "conv": ("op", "conv", {}, None),
+    "mamba2": ("op", "mamba2", MAMBA, None),
+    "dense_ff": ("ff", "dense_ff", {}, None),
+    "dense_ff_ungated": ("ff", "dense_ff", dict(ff_activation="relu2"),
+                         {"mlp_up"}),
+    "dense_ff_before_routed": ("ff", "dense_ff", dict(
+        ROUTED, d_ff_dense=64), None),
+    "routed_ff": ("ff", "routed_ff", ROUTED, None),
+    # a share has no names of its own; the shared expert's product has
+    "routed_ff_share_ungated": ("ff", "routed_ff", dict(
+        ROUTED, ff_activation="relu2", experts_held=(1, 2)), {"shared_up"}),
+    "routed_ff_alone": ("ff", "routed_ff", dict(
+        ROUTED, n_shared_experts=0), {"moe_slots", "moe_gate", "moe_up"}),
+}
+
+
+def of_case(case):
+    table, name, keys, made = CASES[case]
+    cfg = TransformerConfig(**{**BASE, **keys})
+    if table == "op":
+        return model._OPERATORS[name], LayerKind(name, False, False), cfg, made
+    return (model._FEED_FORWARDS[name],
+            LayerKind(None, name == "routed_ff", True), cfg, made)
+
+
+def names_made(jaxpr):
+    """The `checkpoint_name`s of a jaxpr, nested ones included."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            found.add(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found |= names_made(sub)
+    return found
+
+
+def test_the_tables_are_what_the_configuration_may_name():
+    assert set(model._OPERATORS) == {
+        "full_attention", "sliding_attention", "latent_attention", "conv",
+        "mamba2"}
+    assert set(model._FEED_FORWARDS) == {"dense_ff", "routed_ff"}
+    assert {record for _, record, _, _ in CASES.values()} == (
+        set(model._OPERATORS) | set(model._FEED_FORWARDS))
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_a_record_agrees_with_itself(case):
+    record, kind, cfg, made = of_case(case)
+    assert model._sublayers(kind) == (record,)
+    leaves = record.init(jax.random.PRNGKey(0), cfg, L)
+    axes = record.axes(cfg)
+    # the leaves `init` makes are exactly the keys of its axes
+    assert set(leaves) == set(axes)
+    for name, leaf in leaves.items():
+        assert leaf.dtype == jnp.float32 and leaf.shape[0] == L, name
+        assert axes[name][0] == "layers" and len(axes[name]) == leaf.ndim, name
+    assert leaves.keys() == model._blocks_init(
+        jax.random.PRNGKey(0), cfg, kind, L).keys()
+    assert axes == model._block_axes(cfg, kind)
+    # its matmul weights are among them, and no leaf is both kinds of weight
+    weights = (*record.matmuls, *record.moe_weights)
+    assert len(set(weights)) == len(weights)
+    if case in (*model._OPERATORS, *model._FEED_FORWARDS):
+        assert set(weights) <= set(leaves)
+    held = [name for name in weights if name in leaves]
+    assert model.own_buffer_weights(leaves, kind) == tuple(
+        name for name in record.matmuls if name in leaves)
+    # its stated parameters are its matmul leaves' sizes a layer
+    assert record.params(cfg) == sum(leaves[name].size // L for name in held)
+    assert model._layer_widths(cfg, kind) == (
+        record.widths(cfg), record.params(cfg))
+    # every name it says it keeps is ranked, and its forward makes it
+    widths = record.widths(cfg)
+    assert set(widths) <= set(record.names) <= set(model._SAVE_ORDER)
+    assert all(width > 0 for width in widths.values())
+    assert set(widths) == (set(record.names) if made is None else made)
+    blk = jax.tree.map(lambda leaf: leaf[0], leaves)
+    x = jnp.zeros((2, 16, cfg.d_model), cfg.dtype)
+    positions = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
+    traced = jax.make_jaxpr(lambda x, blk: model._block(
+        x, blk, positions, None, cfg, kind, None, 1, keep_ctx=True)[0])(x, blk)
+    assert names_made(traced.jaxpr) == set(widths)
+    assert traced.out_avals[0].shape == x.shape
+    # what its backward holds and its operations are counts, and an
+    # operator alone or a dense feed-forward does twice its parameters
+    assert record.holds(cfg) > 0
+    matmul, attention = record.flops(cfg, 16)
+    assert matmul >= 2 * sum(
+        leaves[name].size // L for name in record.matmuls if name in leaves)
+    assert (attention > 0) == ("attn_ctx" in record.names)
+
+
+@pytest.mark.parametrize("field,names,known", [
+    ("layer_types", ("sliding_atention",), "sliding_attention"),
+    ("layer_types", ("dense_ff",), "full_attention"),
+    ("sublayer_types", ("mamba",), "mamba2"),
+    ("sublayer_types", ("routed",), "routed_ff"),
+])
+def test_a_name_no_table_has_is_refused(field, names, known):
+    """At the parent a misspelt kind was full attention, built, trained and
+    counted without a word."""
+    cfg = TransformerConfig(**{**BASE, field: names})
+    with pytest.raises(ValueError, match=names[0]) as refused:
+        cfg.layers
+    assert field in str(refused.value) and known in str(refused.value)
+    with pytest.raises(ValueError, match=names[0]):
+        model.transformer_init(jax.random.PRNGKey(0), cfg)
+    with pytest.raises(ValueError, match=names[0]):
+        model.flops_per_token(cfg, 16)
+
+
+def test_a_name_without_a_rank_stops_the_import():
+    """`_SAVE_ORDER` is one decision; a name a record makes that it does not
+    rank would never be kept, in silence."""
+    source = inspect.getsource(model)
+    assert source.count('    "ssd_out",') == 1
+    spec = importlib.util.spec_from_file_location(
+        "transformer_without_a_rank", model.__file__)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = module  # `dataclass` looks its module up
+    try:
+        with pytest.raises(ValueError, match="ssd_out"):
+            exec(compile(source.replace('    "ssd_out",', ""), model.__file__,
+                         "exec"), module.__dict__)
+    finally:
+        del sys.modules[spec.name]

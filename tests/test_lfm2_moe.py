@@ -131,7 +131,8 @@ def test_one_kind_keeps_its_parameter_tree_and_its_lowered_step():
             with jax.named_scope("embed"):
                 x = params["embed"].astype(cfg.dtype)[tokens]
             blk_fn = jax.checkpoint(lambda x, blk: model._block(
-                x, blk, positions, None, cfg, None, 1, sliced=True),
+                x, blk, positions, None, cfg, cfg.layers[0], None, 1,
+                sliced=True),
                 static_argnums=())
             x, readings = jax.lax.scan(blk_fn, x, params["blocks"])
             with jax.named_scope("final_norm"):

@@ -172,13 +172,13 @@ def test_the_mixer_is_the_reference_s():
          "norm": 1.0 + 0.3 * jax.random.normal(key(6), w["norm"].shape)}
     x = jax.random.normal(key(5), (2, 40, 32))
     with jax.default_matmul_precision("highest"):
-        ours, readings = model._block(x, w, None, None, cfg, None, 1)
+        ours, readings = model._block(x, w, None, None, cfg, cfg.layers[0], None, 1)
         theirs = reference.mixer(x, w, as_reference_config(cfg))
     assert readings is None
     np.testing.assert_allclose(ours, theirs, rtol=2e-4, atol=2e-5)
     assert float(jnp.abs(ours - x).mean()) > 0.01
     with pytest.raises(NotImplementedError, match="sequence axis"):
-        model._block(x, w, None, None, cfg, "sequence", 2)
+        model._block(x, w, None, None, cfg, cfg.layers[0], "sequence", 2)
 
 
 def test_attention_without_rotation_is_a_masked_softmax():
@@ -187,10 +187,11 @@ def test_attention_without_rotation_is_a_masked_softmax():
     x = jax.random.normal(key(5), (2, 40, 32))
     positions = jnp.broadcast_to(jnp.arange(40), (2, 40))
     with jax.default_matmul_precision("highest"):
-        ours, _ = model._block(x, w, positions, None, cfg, None, 1)
+        ours, _ = model._block(x, w, positions, None, cfg, cfg.layers[0], None, 1)
         theirs = reference.attention(x, w, as_reference_config(cfg))
         rotated, _ = model._block(
-            x, w, positions, None, dataclasses.replace(cfg, rope=True), None, 1)
+            x, w, positions, None, dataclasses.replace(cfg, rope=True),
+            cfg.layers[0], None, 1)
     np.testing.assert_allclose(ours, theirs, rtol=2e-4, atol=2e-5)
     assert float(jnp.abs(rotated - ours).max()) > 1e-3
     # 4 heads of 16 on a stream of 32: the heads' width is its own key
@@ -209,7 +210,7 @@ def test_the_ungated_experts_with_the_shared_one_are_the_reference_s(
     config = as_reference_config(cfg)
 
     def ours(x, w):
-        return model._block(x, w, None, bias, cfg, None, 1)
+        return model._block(x, w, None, bias, cfg, cfg.layers[0], None, 1)
 
     def theirs(x, w):
         return reference.routed_feed_forward(x, w, config, bias)[0]
@@ -236,7 +237,8 @@ def test_the_factor_scales_the_routed_part_alone():
         alike = reference.routed_feed_forward(
             x, none, {**as_reference_config(cfg), "experts_held": (0, 0)})[0]
         out = {f: model._block(x, w, None, None, dataclasses.replace(
-            cfg, routed_scaling_factor=f), None, 1)[0] for f in (1.0, 2.5)}
+            cfg, routed_scaling_factor=f), cfg.layers[0], None, 1)[0]
+               for f in (1.0, 2.5)}
     np.testing.assert_allclose(
         out[2.5] - alike, 2.5 * (out[1.0] - alike), rtol=1e-4, atol=1e-5)
 
@@ -311,7 +313,7 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
             held = {**w, "w_up": w["w_up"][first:first + 2],
                     "w_down": w["w_down"][first:first + 2]}
             out, readings = model._block(
-                x, held, None, bias, share_cfg, None, 1)
+                x, held, None, bias, share_cfg, cfg.layers[0], None, 1)
             assert int(readings["dropped_slots"]) == 0
             assert readings["expert_load"].shape == (32,)
             parts.append(out - alike)  # this share's routed part alone
@@ -504,10 +506,13 @@ def test_flops_count_every_kind_of_sublayer():
 def test_weights_of_the_mixer_s_matmuls_get_buffers_of_their_own():
     cfg = tiny("MEM*")
     blocks = transformer_init(key(0), cfg)["blocks"][0]
-    assert model.own_buffer_weights(blocks[0]) == ("w_in", "w_out")
-    assert model.own_buffer_weights(blocks[1]) == ("ws_up", "ws_down")
-    assert model.own_buffer_weights(blocks[3]) == ("wq", "wk", "wv", "wo")
+    mixer, experts, _, attention = cfg.layers
+    assert model.own_buffer_weights(blocks[0], mixer) == ("w_in", "w_out")
+    assert model.own_buffer_weights(blocks[1], experts) == ("ws_up", "ws_down")
+    assert model.own_buffer_weights(blocks[3], attention) == (
+        "wq", "wk", "wv", "wo")
     count, total, widest = model.own_buffers(
-        transformer_init(key(0), cfg)["blocks"], jnp.bfloat16)
+        transformer_init(key(0), cfg)["blocks"],
+        dataclasses.replace(cfg, dtype=jnp.bfloat16))
     assert count == 2 * (2 + 2 + 2 + 4)  # a segment of one period: twice
     assert widest == 2 * (2 * 32 * 64 + 2 * 32 * 32)  # attention's four

@@ -59,7 +59,7 @@ CASES = {
 }
 
 
-def parent_weights(blk, dt, sliced):
+def parent_weights(blk, kind, dt, sliced):
     """The parent's block: its sites read `y @ blk[name].astype(dt)` from
     the float32 leaves, and the cast, the slice of the stack and whatever
     takes the gradient are left for the compiler to fuse into the matmuls."""
@@ -130,7 +130,9 @@ def test_every_plain_matmul_of_a_block_has_its_buffers(case):
         lambda: transformer_init(jax.random.PRNGKey(0), cfg))
     trees = [blk for blks in model._segment_trees(params["blocks"])
              for blk in blks]
-    assert [set(own_buffer_weights(blk)) for blk in trees] == [
+    kinds = [kind for seg in model.segments(cfg) for kind in seg.layout]
+    assert [set(own_buffer_weights(blk, kind))
+            for blk, kind in zip(trees, kinds)] == [
         set(names) for names in want]
     periods = [blk["mlp_norm"].shape[0] for blk in trees]
     assert sum(periods) == cfg.n_layers
@@ -146,7 +148,7 @@ def test_every_plain_matmul_of_a_block_has_its_buffers(case):
     backward = jax.make_jaxpr(jax.grad(
         lambda p, t: hidden(p, t).astype(jnp.float32).sum()))(params, tokens)
     assert barriers(backward.jaxpr) == sites + 2 * whole
-    buffers, their_bytes, widest = own_buffers(params["blocks"], cfg.dtype)
+    buffers, their_bytes, widest = own_buffers(params["blocks"], cfg)
     each = [2 if n == 1 else 1 for n in periods]
     assert buffers == sum(
         e * n * len(names) for e, n, names in zip(each, periods, want))
@@ -170,7 +172,7 @@ def test_the_step_logs_and_counts_its_own_buffers_once_a_trace(caplog):
         step(state, batch)  # the same program: traced, logged, counted once
     lines = [r.getMessage() for r in caplog.records
              if r.getMessage().startswith("train step")]
-    buffers, their_bytes, _ = own_buffers(state["params"]["blocks"], cfg.dtype)
+    buffers, their_bytes, _ = own_buffers(state["params"]["blocks"], cfg)
     assert (buffers, their_bytes) == (21, 184320)
     assert lines == [
         "train step under remat keeps nothing: 0 bytes a device beside the "

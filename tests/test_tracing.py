@@ -25,10 +25,12 @@ def _spans_by_kind(spans):
 
 
 def _wait_spans(min_count, trace_id=None, timeout=20):
+    """Every span there is, once `min_count` of them are the job's: the
+    driver's own `init*` spans are in the ring before any task runs."""
     deadline = time.time() + timeout
     while time.time() < deadline:
         spans = state_api.list_spans(trace_id)
-        if len(spans) >= min_count:
+        if sum(s["name"].split(".")[0] != "init" for s in spans) >= min_count:
             return spans
         time.sleep(0.25)
     raise AssertionError(
@@ -327,6 +329,33 @@ def test_critical_path_names_dominant(traced_cluster):
     # The chain bottoms out in the sleeping task, so it (or its executor
     # span) dominates self time.
     assert cp["segments"][0]["self_s"] >= 0.2, cp["segments"]
+
+
+@pytest.mark.parametrize("with_work", [True, False])
+def test_critical_path_passes_init_s_own_trace_by(monkeypatch, with_work):
+    """With tracing on the driver's `init*` spans are a trace of their own
+    and often the longest: with no id a job's trace is chosen over it."""
+
+    def span(trace, span_id, name, start, duration, parent=None):
+        return {"trace_id": trace, "span_id": span_id, "name": name,
+                "kind": "runtime", "start": start, "duration": duration,
+                "parent_span_id": parent}
+
+    spans = [span("t-init", "a", "init", 0.0, 2.0),
+             span("t-init", "b", "init.worker_pool", 0.5, 1.4, parent="a")]
+    if with_work:
+        spans += [span("t-job", "c", "outer", 3.0, 0.4),
+                  span("t-job", "d", "slow", 3.05, 0.3, parent="c")]
+    monkeypatch.setattr(
+        state_api, "list_spans", lambda trace_id=None, limit=0: [
+            s for s in spans if trace_id in (None, s["trace_id"])])
+    cp = state_api.critical_path()
+    if with_work:
+        assert cp["trace_id"] == "t-job" and cp["dominant"] == "slow"
+    else:  # there is no other: it is what there is to say
+        assert cp["trace_id"] == "t-init"
+        assert cp["dominant"] == "init.worker_pool"
+    assert state_api.critical_path("t-init")["trace_id"] == "t-init"
 
 
 def test_wire_schemas_declare_trace():

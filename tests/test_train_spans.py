@@ -159,6 +159,20 @@ def test_with_tracing_on_the_new_spans_reach_list_spans(
     assert by_name["jax.compile"]["cache"] in ("hit", "miss", "none")
 
 
+def test_the_table_ends_with_the_cluster(shutdown_only):
+    """A process that starts a second cluster (a test worker under `--dist
+    loadfile`, a notebook) reports that cluster's `init` alone."""
+    ray_tpu.init(num_cpus=1, num_tpus=0)
+    tracing.count("compile.programs")
+    assert tracing.table()["init"][0] >= 1
+    ray_tpu.shutdown()
+    assert not DRIVER_SPANS & set(tracing.table())
+    assert "compile.programs" not in tracing.counters()
+    ray_tpu.init(num_cpus=1, num_tpus=0)
+    count, seconds, longest, when = tracing.table()["init"]
+    assert count == 1 and seconds == longest > 0
+
+
 def test_init_leaves_jax_unimported():
     code = ("import sys, ray_tpu; ray_tpu.init(num_cpus=1, num_tpus=0);"
             "from ray_tpu.util import tracing;"

@@ -1114,8 +1114,10 @@ class _SparseAttention(_PlainAttention):
         hi, di = cfg.index_heads, cfg.index_head_dim
         widths = super().widths(cfg)
         # beside o and lse the indexer's three gradients, which the forward
-        # makes: `q_idx`'s and `k_idx`'s, and `w_idx`'s in float32
-        widths["attn_ctx"] += hi * di + di + hi * 4 // _item(cfg)
+        # makes (`q_idx`'s and `k_idx`'s, and `w_idx`'s in float32), and the
+        # selection's mask, a bit a key of the longest sequence
+        widths["attn_ctx"] += (hi * di + di + hi * 4 // _item(cfg)
+                               + cfg.max_seq_len // 8 // _item(cfg))
         return widths
 
     def params(self, cfg):
@@ -1123,9 +1125,16 @@ class _SparseAttention(_PlainAttention):
 
     def holds(self, cfg):
         """Plain attention's; the index queries, key and weights as the
-        kernels take them; the int8 mask, a byte a key of the longest
-        sequence, twice (the one the backward holds and the one
-        `index_select` makes again); `index_loss`'s own operands and
+        kernels take them; the selection's mask, a bit a key of the longest
+        sequence (the one a backward layer reads, made again or kept), and a
+        byte a key beside it, priced from the compiler's plan for a
+        described v5e: where two int8 masks were priced, the plan with
+        nothing kept fell by one, less the bits (0.235 GB of 15.05), when
+        the mask became bits. The other byte stands for what the plan holds
+        and no record prices, the held rows' buffers at 3.25 even shares
+        among it (0.24 GB): without it the rule keeps `attn_res` too and the
+        plan stands 0.08 GB under what a v5e offers a program, the chip's
+        peak over it. Then `index_loss`'s own operands and
         results (q heads first, lse a row, the three gradients); and what
         the compiler holds for a scanned stack of these layers and the rule
         has no other term for: every layer's weights in the compute dtype,
@@ -1136,7 +1145,7 @@ class _SparseAttention(_PlainAttention):
         h, hi, di = self.heads(cfg), cfg.index_heads, cfg.index_head_dim
         item = _item(cfg)
         operands = hi * _tile_lanes(di) + _tile_lanes(di) + hi * 4 // item
-        mask = 2 * cfg.max_seq_len // item
+        mask = (cfg.max_seq_len // 8 + cfg.max_seq_len) // item
         index_loss = (h * cfg.head_dim + h * 4 // item + hi * di
                       + di * 4 // item + 128 * 4 // item)
         hoisted = sum(_layer_widths(cfg, kind)[1]
@@ -1891,7 +1900,8 @@ def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
 # the norms with q, k and v, nothing with `up`).
 _SAVE_ORDER = (
     "attn_ctx",   # the kernel's o [B H, T, dv] and lse as one f32 column
-                  # (sparse attention: and the indexer's three gradients)
+                  # (sparse attention: and the indexer's three gradients and
+                  # the selection's mask as bits)
     "moe_slots",  # the sorted slots: no second sort (integers, small)
     "attn_res",   # the stream after attention: no second `wo` product
     "conv_res",   # the stream after the short convolution: no `conv_out`

@@ -57,9 +57,13 @@ diagonal. `window=None`, or a window no shorter than the sequence, is the
 causal kernel: the same names, tiles, index maps and bodies.
 
 A mask that is data (`_flash_fwd(mask=)` and the three backward calls;
-`ops/sparse_attention.py` is the caller): an int8 array `[B, key tiles, T,
-block_k]`, 1 where a (query, key) pair counts, a key tile's columns lying
-together so that a kernel's tile of it is one block whatever the walk. Its
+`ops/sparse_attention.py` is the caller): a bit a (query, key) pair, set
+where the pair counts, in an int8 array `[B, key tiles, T, block_k / 8]`.
+Bit `b` of column `c` of key tile `j` is key `j * block_k + b * (block_k / 8)
++ c`: eight bit planes of `block_k / 8` columns (`_pack_bits` and
+`_unpack_bits` are the rule, in the kernels and in `jax.numpy` alike), so a
+kernel's tile of it is one block whatever the walk, an eighth of a byte a
+pair, and at a key tile of 1,024 a plane is 128 lanes of the scores. Its
 key tile is every kernel's. The walk is the causal one, tile for tile; every
 body, bare or masked by position, also takes the tile's choice (`_pairs_mask`)
 into its two selects, and the batch row of query row `bh` is `bh // heads`.
@@ -268,8 +272,8 @@ def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
                 scratch += more * 4
     f32_tiles, dtype_tiles = _LIVE_TILES[kernel]
     live = block_q * block_k * (4 * f32_tiles + itemsize * dtype_tiles)
-    if sparse:  # the mask's int8 block, and its tile widened to 32 bits
-        blocks += block_q * block_k
+    if sparse:  # the mask's block, a bit a pair, and its tile as 32 bits
+        blocks += block_q * block_k // 8
         live += block_q * block_k * 4
     return 2 * blocks + scratch + live
 
@@ -481,15 +485,36 @@ def _tile_mask(qi, ki, *, block_q, block_k, causal, seq_q, seq_k,
     return functools.reduce(jnp.logical_and, terms)
 
 
+def _pack_bits(keep):
+    """[..., rows, tile] bool as [..., rows, tile / 8] int8, a data mask's
+    tile: bit `b` of column `c` is pair `b * (tile / 8) + c`."""
+    width = keep.shape[-1] // 8
+    plane = jax.lax.broadcasted_iota(
+        jnp.int32, keep.shape, keep.ndim - 1) // width
+    # bit 7 as -128: the sum is the int8's value, nothing to wrap
+    bits = jnp.where(keep, jnp.where(
+        plane == 7, -128, jnp.left_shift(1, plane)), 0)
+    return sum(bits[..., b * width:(b + 1) * width]
+               for b in range(8)).astype(jnp.int8)
+
+
+def _unpack_bits(bits):
+    """`_pack_bits`'s inverse: [..., rows, tile / 8] int8 as
+    [..., rows, tile] bool, a plane after another along the columns."""
+    bits = bits.astype(jnp.int32)
+    return jnp.concatenate(
+        [bits & (1 << b) for b in range(8)], axis=-1) != 0
+
+
 def _pairs_mask(masked, mask_ref, qi, ki, **shape):
     """The [bq, bk] bool mask of a tile's body, or None where it has none:
     `_tile_mask`'s terms where the positions decide something (`masked`),
-    and under a data mask (`mask_ref`: the tile of an int8 array that is 1
-    where the pair counts) that tile's choice as well, in every body."""
+    and under a data mask (`mask_ref`: the tile's bits, set where the pair
+    counts) that tile's choice as well, in every body."""
     mask = _tile_mask(qi, ki, **shape) if masked else None
     if mask_ref is None:
         return mask
-    chosen = mask_ref[0, 0].astype(jnp.int32) != 0
+    chosen = _unpack_bits(mask_ref[0, 0])
     return chosen if mask is None else jnp.logical_and(mask, chosen)
 
 
@@ -702,17 +727,18 @@ def _q_block_under_k(causal, block_q, block_k, num_q, window=None):
 
 
 def _mask_key_tile(mask, block_k):
-    """A data mask is `[B, key tiles, T, block_k]` int8, 1 where the pair
-    counts: a key tile's columns lie together, so that a kernel's tile of
-    it is one block whatever the walk. Its tile is every kernel's key
+    """A data mask is `[B, key tiles, T, block_k / 8]` int8, a bit a pair
+    (`_pack_bits`): a key tile's bits lie together, so that a kernel's tile
+    of it is one block whatever the walk. Its tile is every kernel's key
     tile."""
     if mask is None:
         return block_k
-    if block_k not in (None, mask.shape[3]):
+    tile = 8 * mask.shape[3]
+    if block_k not in (None, tile):
         raise ValueError(
-            f"a data mask in key tiles of {mask.shape[3]} cannot be walked "
-            f"in tiles of {block_k}")
-    return mask.shape[3]
+            f"a data mask in key tiles of {tile} cannot be walked in tiles "
+            f"of {block_k}")
+    return tile
 
 
 def _mask_spec_under_q(mask, BH, block_q, k_block):
@@ -1186,7 +1212,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
             return (bh // heads, ki, q_block(bh, ki, qi)[1], 0)
 
         kernel = _with_mask(kernel, 6)
-        in_specs.append(pl.BlockSpec((1, 1, block_q, block_k),
+        in_specs.append(pl.BlockSpec((1, 1, block_q, mask.shape[3]),
                                      of_grid(mask_block)))
         operands += (mask,)
     out_specs = [

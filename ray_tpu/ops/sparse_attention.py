@@ -27,9 +27,11 @@ and the kernels' reference. `impl="pallas"` is the chip's:
   against every earlier key in VMEM as sortable integers, never in HBM; the
   row's threshold, the `min(t + 1, topk)`-th largest, by bisection over the
   32 bits; the keys above it and the lowest-indexed of those equal to it,
-  by a running count; out go an int8 mask (`[B, key tiles, T, tile]`, a key
-  tile's columns together), the row's `logsumexp` of the selected scores
-  and the number selected. No `[T, T]` float array exists.
+  by a running count; out go the mask, a bit a pair (int8
+  `[B, key tiles, T, tile / 8]`, a key tile's eight bit planes together:
+  `ops/flash_attention.py` states the layout, `_mask_of` and `_keep_of`
+  pack and unpack a whole one), the row's `logsumexp` of the selected
+  scores and the number selected. No `[T, T]` float array exists.
 - the flash kernels under that mask (`ops/flash_attention.py`, `mask=`):
   the dense causal walk with the mask's tile beside the causal term,
   `flash_fwd_sparse`, `flash_bwd_dkv_dq_sparse` (or `flash_bwd_dq_sparse`
@@ -43,6 +45,12 @@ and the kernels' reference. `impl="pallas"` is the chip's:
   three gradients by hand (`softmax_S(I) - pbar` on the kept pairs, through
   the relu and the weights). They are made in the forward (they need what
   the forward has) and handed on by the backward rule.
+
+Kept (`keep_ctx`), `attn_ctx` names what the backward takes from the forward:
+o, lse as one column, the indexer's three gradients and the mask's bits
+(2 KB a token at 16,384 keys). A rematerialised block that keeps the name
+runs `flash_fwd_sparse`, `index_loss` and `index_select` once a layer: the
+selection is a function of the forward's inputs and passes no gradient.
 
 The kernels take a sequence that is a multiple of 128; any other shape
 takes the `jax.numpy` path whatever `impl` says (one line in the log).
@@ -61,7 +69,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.flash_attention import (
     _DEFAULT_VMEM, _NN, _NT, _TN, _dot, _flash_bwd_dkv, _flash_bwd_dq,
-    _flash_fwd, flash_bwd_kernels, flash_tiles, resolve_impl)
+    _flash_fwd, _pack_bits, _pairs_mask, _unpack_bits, flash_bwd_kernels,
+    flash_tiles, resolve_impl)
 
 logger = logging.getLogger(__name__)
 
@@ -211,8 +220,8 @@ def _index_select_kernel(q_ref, kt_ref, w_ref, tri_ref,  # inputs
     `q_ref` [1, Hi, bq, Di], `kt_ref` [1, chunks, Di, chunk] (the keys
     transposed, a chunk of columns together), `w_ref` [1, bq, Hi] float32,
     `tri_ref` [chunk, chunk] (1 where row <= column: a running count is a
-    product with it). `mask_ref` [1, chunks, bq, chunk] int8, `lse_ref` and
-    `kept_ref` [1, bq, 8] float32."""
+    product with it). `mask_ref` [1, chunks, bq, chunk / 8] int8 (a bit a
+    key), `lse_ref` and `kept_ref` [1, bq, 8] float32."""
     from jax.experimental import pallas as pl
 
     first = pl.program_id(1) * block_q
@@ -270,7 +279,7 @@ def _index_select_kernel(q_ref, kt_ref, w_ref, tri_ref,  # inputs
                       tri_ref[...], _NN) + seen
         keep = jnp.logical_or(keys > tau,
                               jnp.logical_and(equal, before <= room))
-        mask_ref[0, c] = jnp.where(keep, 1, 0).astype(jnp.int8)
+        mask_ref[0, c] = _pack_bits(keep)
         weight = jnp.where(keep, jnp.exp(_unsortable(keys) - top), 0.0)
         return (seen + count(equal),
                 total + jnp.sum(weight, axis=-1, keepdims=True),
@@ -279,7 +288,7 @@ def _index_select_kernel(q_ref, kt_ref, w_ref, tri_ref,  # inputs
     _, total, kept = jax.lax.fori_loop(0, walked, emit, (zeros, zeros, zeros))
 
     def clear(c, carry):
-        mask_ref[0, c] = jnp.zeros((block_q, chunk), jnp.int8)
+        mask_ref[0, c] = jnp.zeros((block_q, chunk // 8), jnp.int8)
         return carry
 
     jax.lax.fori_loop(walked, chunks, clear, 0)
@@ -295,9 +304,9 @@ def _select_block(T: int) -> int:
 
 def index_select(q_idx, k_idx, w_idx, *, topk: int, chunk: int,
                  interpret: bool = False):
-    """(mask [B, S / chunk, T, chunk] int8, lse_idx [B, T] float32, the
-    rows' `kept - min(t + 1, topk)` [B, T] float32) by the kernel: T a
-    multiple of 128 and of `chunk`."""
+    """(mask [B, S / chunk, T, chunk / 8] int8, a bit a key, lse_idx [B, T]
+    float32, the rows' `kept - min(t + 1, topk)` [B, T] float32) by the
+    kernel: T a multiple of 128 and of `chunk`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -308,7 +317,7 @@ def index_select(q_idx, k_idx, w_idx, *, topk: int, chunk: int,
     kt = k_idx.reshape(B, chunks, chunk, Di).transpose(0, 1, 3, 2)
     tri = (jnp.arange(chunk)[:, None] <= jnp.arange(chunk)[None, :]
            ).astype(jnp.bfloat16)
-    vmem = (chunks * block_q * chunk * (4 + 2)  # the keys; the mask, twice
+    vmem = (chunks * block_q * chunk * (4 + 2 / 8)  # keys; the bits, twice
             + 2 * 2 * (Hi * block_q * 128 + T * Di) * q_idx.dtype.itemsize
             + 2 * chunk * chunk * 2 + 16 * block_q * chunk * 4)
     kernel = functools.partial(
@@ -325,12 +334,12 @@ def index_select(q_idx, k_idx, w_idx, *, topk: int, chunk: int,
             pl.BlockSpec((chunk, chunk), lambda b, qi: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunks, block_q, chunk),
+            pl.BlockSpec((1, chunks, block_q, chunk // 8),
                          lambda b, qi: (b, 0, qi, 0)),
             row, row,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, chunks, T, chunk), jnp.int8),
+            jax.ShapeDtypeStruct((B, chunks, T, chunk // 8), jnp.int8),
             jax.ShapeDtypeStruct((B, T, 8), jnp.float32),
             jax.ShapeDtypeStruct((B, T, 8), jnp.float32),
         ],
@@ -354,8 +363,8 @@ def _index_loss_kernel(q_ref, k_ref, lse_ref, mask_ref, qi_ref, kt_ref, w_ref,
                        with_grads: bool):
     """One tile of `block_q` queries by `chunk` keys, the keys walked
     innermost. `q_ref` [1, H, bq, D], `k_ref` [1, Hk, chunk, D], `lse_ref`
-    [1, bq, H] (the attention's), `mask_ref` [1, 1, bq, chunk] int8 (it has
-    the causal term: a key after its query is 0), `qi_ref` [1, Hi, bq, Di],
+    [1, bq, H] (the attention's), `mask_ref` [1, 1, bq, chunk / 8] int8 (the
+    tile's bits, which have the causal term), `qi_ref` [1, Hi, bq, Di],
     `kt_ref` [1, 1, Di, chunk], `w_ref` [1, bq, Hi], `lsei_ref` [1, bq, 8]
     (the rows' logsumexp of the kept index scores). Out: `kl_ref`
     [1, bq, 8], the rows' KL; with `with_grads` the gradient of the rows'
@@ -385,7 +394,7 @@ def _index_loss_kernel(q_ref, k_ref, lse_ref, mask_ref, qi_ref, kt_ref, w_ref,
 
     @pl.when(ki * chunk < (qi + 1) * block_q)  # some key is not after
     def _body():
-        keep = mask_ref[0, 0].astype(jnp.int32) != 0
+        keep = _pairs_mask(False, mask_ref, qi, ki)
         lse = lse_ref[0]  # [bq, H]
         pbar = jnp.zeros((block_q, chunk), jnp.float32)
         for h in range(heads):
@@ -434,8 +443,8 @@ def index_loss(q, k, lse, mask, q_idx, k_idx, w_idx, lse_idx, scale,
     """(the rows' KL summed, and with `with_grads` its gradient by `q_idx`,
     `k_idx` and `w_idx`, else None) by the kernel `index_loss`: q
     [B, T, H, D], k [B, T, Hk, D], `lse` [B, H, T] (the attention's,
-    float32), `mask` [B, key tiles, T, tile] int8, `lse_idx` [B, T]. A tile
-    of 256 queries by the mask's key tile, the causal tiles alone; the
+    float32), `mask` [B, key tiles, T, tile / 8] int8, `lse_idx` [B, T]. A
+    tile of 256 queries by the mask's key tile, the causal tiles alone; the
     gradient's matmuls take their left operands rounded to the inputs'
     dtype, as the flash kernels round ds."""
     from jax.experimental import pallas as pl
@@ -444,7 +453,7 @@ def index_loss(q, k, lse, mask, q_idx, k_idx, w_idx, lse_idx, scale,
     B, T, H, D = q.shape
     Hk = k.shape[2]
     Hi, Di = q_idx.shape[2:]
-    chunks, chunk = mask.shape[1], mask.shape[3]
+    chunks, chunk = mask.shape[1], 8 * mask.shape[3]
     block_q = _select_block(T)
     num_q = T // block_q
 
@@ -463,7 +472,7 @@ def index_loss(q, k, lse, mask, q_idx, k_idx, w_idx, lse_idx, scale,
         pl.BlockSpec((1, Hk, chunk, D),
                      lambda b, qi, ki: (b, 0, jnp.minimum(ki, last(qi)), 0)),
         row(H),
-        pl.BlockSpec((1, 1, block_q, chunk),
+        pl.BlockSpec((1, 1, block_q, chunk // 8),
                      lambda b, qi, ki: (b, jnp.minimum(ki, last(qi)), qi, 0)),
         heads_rows(Hi, Di),
         pl.BlockSpec((1, 1, Di, chunk),
@@ -488,7 +497,7 @@ def index_loss(q, k, lse, mask, q_idx, k_idx, w_idx, lse_idx, scale,
                     pltpu.VMEM((block_q, 128), jnp.float32)]
     item = q.dtype.itemsize
     vmem = (2 * (H * block_q * 128 + Hk * chunk * 128 + Hi * block_q * 128
-                 + Di * chunk) * item + 2 * block_q * chunk
+                 + Di * chunk) * item + 2 * block_q * chunk // 8
             + 2 * 4 * block_q * 128 * 4 + 12 * block_q * chunk * 4)
     if with_grads:
         vmem += (2 * T * Di * 4 + 3 * Hi * block_q * 128 * 4
@@ -559,10 +568,10 @@ def _log_path(T, H, Hk, D, Dv, dtype, topk, tile, block_q):
         group=H // Hk, sparse=True))
     logger.info(
         "sparse attention at T %d, %d heads over %d, D %d, Dv %d, %s, "
-        "topk %d: selection by the kernel index_select into an int8 mask in "
-        "key tiles of %d (%d bytes a sequence); the dense causal walk under "
-        "it by %s; the index loss by the kernel index_loss", T, H, Hk, D,
-        Dv, dtype, topk, tile, T * T,
+        "topk %d: selection by the kernel index_select into a mask as bits in "
+        "key tiles of %d, %d bytes a sequence, kept with attn_ctx; the dense "
+        "causal walk under it by %s; the index loss by the kernel index_loss",
+        T, H, Hk, D, Dv, dtype, topk, tile, T * T // 8,
         ", ".join("%s_sparse %d x %d" % (
             kernel, tiles(kernel).block_q, tiles(kernel).block_k)
             for kernel in kernels))
@@ -602,10 +611,21 @@ def _forward(q, k, v, q_idx, k_idx, w_idx, topk, scale, blocks, interpret,
     return o, lse, kl / (B * T), kept, (qf, kf, vf, mask, grads)
 
 
+def _mask_of(keep, tile: int):
+    """`keep` [B, T, keys] (nonzero where a query keeps a key) as the data
+    mask the kernels take: `[B, key tiles, T, tile / 8]` int8, a bit a pair,
+    the last tile's bits past the keys 0."""
+    B, T, keys = keep.shape
+    keep = jnp.pad(keep != 0, ((0, 0), (0, 0), (0, -keys % tile)))
+    return _pack_bits(keep.reshape(B, T, -1, tile)).transpose(0, 2, 1, 3)
+
+
 def _keep_of(mask, keys: int):
-    """The mask `[B, key tiles, T, tile]` as `[B, T, keys]` int8."""
+    """`_mask_of`'s inverse: the mask as `[B, T, keys]` int8, 1 where a
+    query keeps a key."""
     B, _, T, _ = mask.shape
-    return mask.transpose(0, 2, 1, 3).reshape(B, T, -1)[..., :keys]
+    keep = _unpack_bits(mask).transpose(0, 2, 1, 3).reshape(B, T, -1)
+    return keep[..., :keys].astype(jnp.int8)
 
 
 def _heads_last(x, batch: int):
@@ -630,11 +650,12 @@ def _sparse_pallas_fwd(q, k, v, q_idx, k_idx, w_idx, topk, scale, blocks,
     if keep_ctx:
         # Named where the backward takes them, as `flash_attention` names o
         # and lse: a rematerialised block that keeps `attn_ctx` runs neither
-        # the forward kernel nor the index loss a second time. The indexer's
-        # gradients are 2.2 KB a token beside o's 8 KB. The mask is not
-        # kept (16 KB a token at 16,384): `index_select` makes it again.
+        # the forward kernel, the index loss nor the selection a second
+        # time. The indexer's gradients are 2.2 KB a token and the mask's
+        # bits 2 KB at 16,384 keys, beside o's 8 KB.
         o = checkpoint_name(o, "attn_ctx")
         lse = checkpoint_name(lse[..., 0], "attn_ctx")
+        mask = checkpoint_name(mask, "attn_ctx")
         grads = tuple(checkpoint_name(g, "attn_ctx") for g in grads)
     return (_heads_last(o, q.shape[0]), loss, kept,
             _keep_of(mask, k.shape[1])), (qf, kf, vf, o, lse, mask, grads)
@@ -655,7 +676,7 @@ def _sparse_pallas_bwd(topk, scale, blocks, interpret, keep_ctx, res,
     do = _heads_first(d_out)
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
     delta = jnp.broadcast_to(delta[..., None], (BH, T, 8))
-    block_q, tile = blocks[0], mask.shape[3]
+    block_q, tile = blocks[0], 8 * mask.shape[3]
     kernels = flash_bwd_kernels(
         T, kf.shape[1], D, qf.dtype, block_q=block_q, block_k=tile,
         v_dim=vf.shape[2], group=H // Hk, sparse=True)
@@ -691,7 +712,8 @@ def sparse_attention(q, k, v, q_idx, k_idx, w_idx, *, topk: int,
     impl: 'auto' (pallas on TPU, XLA elsewhere) | 'pallas' | 'xla'; a
     sequence that is no multiple of 128 takes 'xla' whatever it says.
     `keep_ctx` names the backward's residuals that are worth their bytes
-    `attn_ctx` (`jax.ad_checkpoint`): o, lse and the indexer's gradients.
+    `attn_ctx` (`jax.ad_checkpoint`): o, lse, the indexer's gradients and
+    the selection's mask as bits.
     `interpret` runs the kernels in interpret mode and `blocks` forces
     their (q, key) tile, for tests."""
     B, T, H, D = q.shape

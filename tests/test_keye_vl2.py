@@ -8,7 +8,9 @@ layers, and what the record means to `saved_activations`
 (`ray_tpu/ops/sparse_attention.py`, `ray_tpu/ops/flash_attention.py`
 `mask=`, `ray_tpu/models/transformer.py` `_SparseAttention`)."""
 
+import collections
 import dataclasses
+import functools
 import importlib
 import logging
 import math
@@ -41,6 +43,10 @@ def _highest_precision():
 
 def key(i):
     return jax.random.PRNGKey(i)
+
+
+# the seeded weights as one program a configuration, not a leaf at a time
+init = jax.jit(transformer_init, static_argnums=1)
 
 
 def tiny(**over):
@@ -143,6 +149,26 @@ def test_index_select_is_select_keys_bit_for_bit(T, topk):
     assert len(np.unique(np.asarray(scores[0, -1]))) < T // 2
 
 
+@pytest.mark.parametrize("tile", [32, 128, 512, 1024])
+def test_a_mask_packs_to_bits_and_back(tile):
+    """`_mask_of` and `_keep_of` are inverses at every tile the kernels take
+    (and the tests' 32), the last tile ragged; bit `b` of column `c` of key
+    tile `j` is key `j * tile + b * (tile / 8) + c`, and the bits past the
+    keys are 0."""
+    keys = 2 * tile + tile // 2 + 3
+    keep = jax.random.bernoulli(key(tile), 0.3, (2, 16, keys))
+    mask = sparse._mask_of(keep, tile)
+    assert mask.shape == (2, 3, 16, tile // 8) and mask.dtype == jnp.int8
+    np.testing.assert_array_equal(
+        np.asarray(sparse._keep_of(mask, keys)), np.asarray(keep, np.int8))
+    bits = np.asarray(mask).view(np.uint8)
+    for j, b, c in ((0, 0, 0), (1, 7, tile // 8 - 1), (2, 3, 2), (2, 4, 0)):
+        at = j * tile + b * (tile // 8) + c
+        want = np.asarray(keep[:, :, at]) if at < keys else 0
+        np.testing.assert_array_equal((bits[:, j, :, c] >> b) & 1, want)
+    assert not np.asarray(sparse._keep_of(mask, 3 * tile))[..., keys:].any()
+
+
 def test_the_sortable_integer_keeps_the_floats_order():
     x = jnp.asarray([-jnp.inf, -3.5, -1e-30, 0.0, 1e-30, 2.0, jnp.inf])
     keys = sparse._sortable(x)
@@ -208,9 +234,10 @@ def test_the_masked_flash_kernels_at_a_ragged_edge(one_kernel):
         return jnp.einsum("bhts,bshd->bthd", p, vf)
 
     do = jax.random.normal(key(8), (B, T, H, D))
-    out, pull = jax.vjp(reference, q, k, v)
-    mask = jnp.pad(keep.astype(jnp.int8), ((0, 0), (0, 0), (0, 96 - T))
-                   ).reshape(B, T, 3, tile).transpose(0, 2, 1, 3)
+    out, grads = jax.jit(lambda q, k, v, do: (lambda out, pull: (
+        out, pull(do)))(*jax.vjp(reference, q, k, v)))(q, k, v, do)
+    mask = sparse._mask_of(keep, tile)
+    assert mask.shape == (B, 3, T, tile // 8) and mask.dtype == jnp.int8
     qf, kf, vf, dof = (sparse._heads_first(x) for x in (q, k, v, do))
     how = dict(causal=True, scale=scale, block_q=tile, block_k=tile,
                interpret=True, mask=mask)
@@ -225,7 +252,7 @@ def test_the_masked_flash_kernels_at_a_ragged_edge(one_kernel):
     else:
         dq = fa._flash_bwd_dq(qf, kf, vf, dof, lse, delta, **how)
         dk, dv = fa._flash_bwd_dkv(qf, kf, vf, dof, lse, delta, **how)
-    for ours, theirs in zip((dq, dk, dv), pull(do)):
+    for ours, theirs in zip((dq, dk, dv), grads):
         np.testing.assert_allclose(
             sparse._heads_last(ours, B), theirs, rtol=2e-4, atol=2e-5)
 
@@ -246,8 +273,8 @@ def test_a_topk_no_shorter_than_the_sequence_is_causal_attention():
     q, k, v = args[:3]
     dense = mha(q, k, v, causal=True, impl="xla")
     for how in (dict(impl="xla"), dict(impl="pallas", interpret=True)):
-        out, index_loss, gap, keep = sparse.sparse_attention(
-            *args, topk=64, **how)
+        out, index_loss, gap, keep = jax.jit(functools.partial(
+            sparse.sparse_attention, topk=64, **how))(*args)
         np.testing.assert_allclose(out, dense, rtol=1e-5, atol=2e-6)
         assert float(index_loss) > 0 and float(jnp.abs(gap).max()) == 0
         np.testing.assert_array_equal(
@@ -259,10 +286,14 @@ def test_gradients_go_where_the_equations_send_them():
     reaches the indexer's three operands alone: both exactly."""
     args = operands(2, 1, 128, 4, 2, 16, 2, 8)
     for how in (dict(impl="xla"), dict(impl="pallas", interpret=True)):
-        d_out = jax.grad(lambda *a: sparse.sparse_attention(
-            *a, topk=32, **how)[0].sum(), argnums=tuple(range(6)))(*args)
-        d_loss = jax.grad(lambda *a: sparse.sparse_attention(
-            *a, topk=32, **how)[1], argnums=tuple(range(6)))(*args)
+        @jax.jit
+        def both(*a):  # one program: the forward once, pulled back twice
+            (out, loss), pull = jax.vjp(lambda *a: (lambda o, l, *_: (
+                o.sum(), l))(*sparse.sparse_attention(*a, topk=32, **how)), *a)
+            return pull((jnp.ones_like(out), jnp.zeros_like(loss))), pull(
+                (jnp.zeros_like(out), jnp.ones_like(loss)))
+
+        d_out, d_loss = both(*args)
         for g in d_out[3:] + d_loss[:3]:
             assert float(jnp.abs(g).max()) == 0.0
         for g in d_out[:3] + d_loss[3:]:
@@ -271,8 +302,8 @@ def test_gradients_go_where_the_equations_send_them():
 
 def test_the_masked_calls_have_names_of_their_own(caplog):
     """`flash_fwd_sparse`, the backward's kernel and `index_select` in the
-    jaxpr; no `[T, T]` float array anywhere (the int8 mask is the one array
-    of that size); one log line a shape."""
+    jaxpr; no `[T, T]` float array anywhere, and none of a byte a pair (the
+    mask is bits, an eighth of that); one log line a shape."""
     args = tuple(jax.ShapeDtypeStruct((1, 2048, *x.shape[2:]), jnp.bfloat16)
                  for x in operands(0, 1, 8, 4, 2, 32, 2, 16))
     sparse._log_path.cache_clear()
@@ -284,12 +315,77 @@ def test_the_masked_calls_have_names_of_their_own(caplog):
             argnums=tuple(range(6))))(*args))
     for name in ("index_select", "flash_fwd_sparse", "flash_bwd_dkv_dq_sparse"):
         assert f"name={name}" in text, name
-    assert re.search(r"i8\[1,2,2048,1024\]", text)
+    assert re.search(r"i8\[1,2,2048,128\]", text)
+    assert not re.search(r"i8\[1,2,2048,1024\]", text)
     assert not re.search(r"(f32|bf16)\[(\d+,)*2048,2048\]", text)
     lines = [r.getMessage() for r in caplog.records]
     assert len(lines) == 1 and "index_select" in lines[0]
     assert "flash_fwd_sparse 1024 x 1024" in lines[0]
-    assert "int8 mask in key tiles of 1024" in lines[0]
+    assert ("mask as bits in key tiles of 1024, 524288 bytes a sequence, "
+            "kept with attn_ctx") in lines[0]
+
+
+def kernel_calls(jaxpr, counts=None):
+    """{name: `pallas_call`s of that name} in `jaxpr` and every jaxpr
+    inside it (a program's text prints a repeated inner jaxpr once)."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] += 1
+            continue
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    kernel_calls(inner, counts)
+    return counts
+
+
+def test_a_block_that_keeps_attn_ctx_selects_once_a_layer():
+    """Two layers, each under `jax.checkpoint`: where the policy keeps
+    `attn_ctx` the gradient's program holds `index_select` once a layer (the
+    forward's: the backward reads the kept bits), `flash_fwd_sparse` and
+    `index_loss` too; where nothing is kept, each twice. The same gradients
+    to the bit."""
+    B, T, H, Hk, D, Hi, Di = 1, 128, 2, 1, 16, 2, 8
+    ks = jax.random.split(key(11), 2)
+    x = jax.random.normal(ks[0], (B, T, H * D))
+    w = 0.3 * jax.random.normal(
+        ks[1], (2, H * D, (H + 2 * Hk) * D + Hi * Di + Di + Hi))
+
+    def stack(x, w, names):
+        def layer(x, w):
+            q, k, v, q_idx, k_idx, w_idx = jnp.split(x @ w, np.cumsum(
+                [H * D, Hk * D, Hk * D, Hi * Di, Di]), axis=-1)
+            out, index_loss, _, _ = sparse.sparse_attention(
+                q.reshape(B, T, H, D), k.reshape(B, T, Hk, D),
+                v.reshape(B, T, Hk, D), q_idx.reshape(B, T, Hi, Di), k_idx,
+                w_idx, topk=24, impl="pallas", interpret=True,
+                keep_ctx="attn_ctx" in names)
+            return x + out.reshape(B, T, H * D), index_loss
+
+        layer = jax.checkpoint(
+            layer,
+            policy=jax.checkpoint_policies.save_only_these_names(*names))
+        total = 0.0
+        for i in range(2):
+            x, index_loss = layer(x, w[i])
+            total = total + index_loss
+        return jnp.sin(x).sum() + total
+
+    grads, calls = {}, {}
+    for names in ((), ("attn_ctx",)):
+        grad = jax.grad(functools.partial(stack, names=names), (0, 1))
+        found = kernel_calls(jax.make_jaxpr(grad)(x, w).jaxpr)
+        calls[names] = [found[kernel] for kernel in (
+            "index_select", "flash_fwd_sparse", "index_loss",
+            "flash_bwd_dkv_dq_sparse")]
+        grads[names] = jax.jit(grad)(x, w)
+    assert calls[()] == [4, 4, 4, 2]
+    assert calls["attn_ctx",] == [2, 2, 2, 2]
+    for ours, theirs in zip(grads["attn_ctx",], grads[()]):
+        assert float(jnp.abs(ours).max()) > 0
+        np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
 
 
 # ------------------------------------------------ the program, the reference
@@ -298,7 +394,7 @@ def test_parameter_tree_and_seeded_values():
     cfg = tiny()
     assert [(s.periods, [(k.op, k.routed) for k in s.layout])
             for s in segments(cfg)] == [(2, [("sparse_attention", True)])]
-    blocks = transformer_init(key(0), cfg)["blocks"]
+    blocks = init(key(0), cfg)["blocks"]
     assert blocks["wq_idx"].shape == (2, 64, 4 * 8)
     assert blocks["wk_idx"].shape == (2, 64, 8)
     assert blocks["w_idx"].shape == (2, 64, 4)
@@ -310,7 +406,7 @@ def test_parameter_tree_and_seeded_values():
         model.own_buffer_weights(blocks, kind))
     # plain attention's leaves are what a full-attention stack draws: the
     # indexer's are new draws beside them
-    plain = transformer_init(key(0), dataclasses.replace(
+    plain = init(key(0), dataclasses.replace(
         cfg, layer_types=("full_attention",) * 2))["blocks"]
     assert set(blocks) - set(plain) == set(INDEXER)
     for name, leaf in plain.items():
@@ -328,28 +424,30 @@ def test_the_program_agrees_with_the_plain_reference(impl, monkeypatch):
                                            "interpret": True})))
     cfg = tiny()
     config = as_reference_config(cfg)
-    params = transformer_init(key(1), cfg)
+    params = init(key(1), cfg)
     # an indexer whose key is not its initial LayerNorm's
     params["blocks"]["k_idx_bias"] = 0.3 * jax.random.normal(key(2), (2, 8))
     batch = batch_of(cfg)
-    (loss, readings), grads = jax.value_and_grad(
-        transformer_loss_and_readings, has_aux=True)(params, batch, cfg)
+    (loss, readings), grads = jax.jit(jax.value_and_grad(
+        transformer_loss_and_readings, has_aux=True), static_argnums=2)(
+            params, batch, cfg)
     assert float(readings["index_keys_min_gap"]) == 0
     assert float(readings["index_keys_max_gap"]) == 0
     keep = readings["index_keep"]  # [L, B, T, T]
     assert keep.shape == (2, 2, 48, 48)
     keys = jax.lax.top_k(keep.astype(jnp.int32), 16)[1]
     index = readings["expert_index"]
-    theirs, g_ref = jax.value_and_grad(reference.loss)(
-        params, batch, config, index, keys)
+    theirs, g_ref = jax.jit(jax.value_and_grad(
+        lambda p, b, i, k: reference.loss(p, b, config, i, k)))(
+            params, batch, index, keys)
     assert float(loss) == pytest.approx(float(theirs), rel=2e-6)
     num = sum(float(jnp.sum((a - b) ** 2)) for a, b in zip(
         jax.tree.leaves(grads), jax.tree.leaves(g_ref)))
     den = sum(float(jnp.sum(b ** 2)) for b in jax.tree.leaves(g_ref))
     assert math.sqrt(num / den) < 2e-5
     # the reference's own choices are the system's, in float32
-    _, chosen, balance, index_loss, own = reference.forward(
-        params, batch, config)
+    _, chosen, balance, index_loss, own = jax.jit(
+        lambda p, b: reference.forward(p, b, config))(params, batch)
     own_keep = jnp.stack([reference.kept_mask(k, 0, 48) for k in own])
     np.testing.assert_array_equal(np.asarray(keep != 0), np.asarray(own_keep))
     assert float(readings["index_loss"]) == pytest.approx(
@@ -364,18 +462,19 @@ def test_the_two_detachments_are_exact():
     """Cross-entropy and the balance loss leave every leaf of the indexer
     exactly 0; the index loss leaves every other leaf exactly 0."""
     cfg = tiny()
-    params = transformer_init(key(1), cfg)
+    params = init(key(1), cfg)
     batch = batch_of(cfg)
 
-    def part(which):
+    @jax.jit
+    def parts(p):  # one program: the forward once, pulled back twice
         def of(p):
             loss, readings = transformer_loss_and_readings(p, batch, cfg)
-            return (loss - readings["index_loss"], readings["index_loss"]
-                    )[which]
+            return loss - readings["index_loss"], readings["index_loss"]
 
-        return jax.grad(of)(params)
+        _, pull = jax.vjp(of, p)
+        return pull((1.0, 0.0))[0], pull((0.0, 1.0))[0]
 
-    rest, of_index = part(0), part(1)
+    rest, of_index = parts(params)
     for name, leaf in rest["blocks"].items():
         norm = float(jnp.abs(leaf).max())
         assert (norm == 0.0) == (name in INDEXER), name
@@ -389,14 +488,14 @@ def test_the_two_detachments_are_exact():
 def test_a_topk_over_the_sequence_is_full_attention_with_a_taught_indexer():
     cfg = tiny(index_topk=64)
     dense = dataclasses.replace(cfg, layer_types=("full_attention",) * 2)
-    params = transformer_init(key(1), cfg)
+    params = init(key(1), cfg)
     batch = batch_of(cfg)
-    (loss, readings), grads = jax.value_and_grad(
-        transformer_loss_and_readings, has_aux=True)(params, batch, cfg)
+    step = jax.jit(jax.value_and_grad(
+        transformer_loss_and_readings, has_aux=True), static_argnums=2)
+    (loss, readings), grads = step(params, batch, cfg)
     plain = {**params, "blocks": {k: v for k, v in params["blocks"].items()
                                   if k not in INDEXER}}
-    (loss_d, _), grads_d = jax.value_and_grad(
-        transformer_loss_and_readings, has_aux=True)(plain, batch, dense)
+    (loss_d, _), grads_d = step(plain, batch, dense)
     assert float(loss - readings["index_loss"]) == pytest.approx(
         float(loss_d), rel=1e-6)
     for name, leaf in grads_d["blocks"].items():
@@ -412,19 +511,22 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
     shares' routed parts beside them are the uncut reference's layer."""
     monkeypatch.setattr(moe, "_ROW_TILE", 8)
     cfg = tiny(n_layers=1, n_experts=8, experts_held=None)
-    w = jax.tree.map(lambda a: a[0], transformer_init(key(4), cfg)["blocks"])
+    w = jax.tree.map(lambda a: a[0], init(key(4), cfg)["blocks"])
     x = jax.random.normal(key(5), (2, 48, 64))
     positions = jnp.broadcast_to(jnp.arange(48), (2, 48))
     config = as_reference_config(cfg)
     names = ("w_gate", "w_up", "w_down")
-    after_attention, kl, _ = reference.attention(x, w, config)
-    whole, _, _ = reference.routed_feed_forward(after_attention, w, config)
+    after_attention, kl, _ = jax.jit(
+        lambda x, w: reference.attention(x, w, config))(x, w)
+    whole, _, _ = jax.jit(lambda y, w: reference.routed_feed_forward(
+        y, w, config))(after_attention, w)
     parts = []
     for first in range(0, 8, 2):
         share_cfg = dataclasses.replace(cfg, experts_held=(first, 2))
         held = {**w, **{k: w[k][first:first + 2] for k in names}}
-        out, readings = model._block(
-            x, held, positions, None, share_cfg, cfg.layers[0], None, 1)
+        out, readings = jax.jit(lambda x, held: model._block(
+            x, held, positions, None, share_cfg, cfg.layers[0], None, 1))(
+                x, held)
         assert int(readings["dropped_slots"]) == 0
         assert float(readings["index_loss"]) == pytest.approx(
             float(kl.mean()), rel=1e-5)
@@ -456,12 +558,12 @@ def test_one_sequence_s_held_rows_get_buffers_past_their_swing(monkeypatch):
 
     monkeypatch.setattr(moe, "held_chunk", recording)
     cfg = tiny(n_layers=1)
-    w = jax.tree.map(lambda a: a[0], transformer_init(key(4), cfg)["blocks"])
+    w = jax.tree.map(lambda a: a[0], init(key(4), cfg)["blocks"])
     for rows in (1, 2):
         x = jax.random.normal(key(5), (rows, 48, 64))
         positions = jnp.broadcast_to(jnp.arange(48), (rows, 48))
-        _, readings = model._block(
-            x, w, positions, None, cfg, cfg.layers[0], None, 1)
+        _, readings = jax.jit(lambda x, w: model._block(
+            x, w, positions, None, cfg, cfg.layers[0], None, 1))(x, w)
         assert int(readings["dropped_slots"]) == 0
     assert seen == [(False, 1), (False, 2)]
 
@@ -538,8 +640,9 @@ def test_layer_widths_and_operations_by_hand():
     widths, params = model._layer_widths(cfg, kind)
     assert params == 18874368 + 2260992 + d * 128 + 16 * 3 * d * 768
     assert widths == {
-        # o, lse as one f32 column a head, and the indexer's three gradients
-        "attn_ctx": 32 * 128 + 32 * 2 + 16 * 64 + 64 + 16 * 2,
+        # o, lse as one f32 column a head, the indexer's three gradients
+        # and the mask's bits: 2 KB a token at 16,384 keys
+        "attn_ctx": 32 * 128 + 32 * 2 + 16 * 64 + 64 + 16 * 2 + 16384 // 16,
         "attn_res": d, "attn_qkv": (32 + 2 * 4) * 128}
     # the program's count is the benchmark's
     assert model.flops_per_token(cfg, 16384) == pytest.approx(

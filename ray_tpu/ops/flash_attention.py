@@ -20,12 +20,23 @@ The backward is one kernel, `flash_bwd_dkv_dq`, wherever it fits: it walks
 (batch*heads, k-block, q-block) with q innermost, makes a tile's s, p, dp and
 ds once and takes dv, dk and dq from them, five matmuls a tile. dk and dv
 are summed over the q steps of a k tile in VMEM scratch; dq is summed over
-k tiles, a whole column of the grid apart, so its f32 sum and its output
-block hold all the q tiles of the (batch, head) row. `flash_bwd_kernels`
-decides from the shape alone whether that fits VMEM (every shape the repo
-runs: 8.4 MB of f32 and a 4.2 MB block at T 8192, D 192) and logs the choice
-once; where it does not, `flash_bwd_dq` (k innermost) and `flash_bwd_dkv` run
-as before, each making the tile for itself, seven matmuls between them.
+k tiles, a whole column of the grid apart, so its f32 sum holds all the q
+tiles of the (batch, head) row. The row leaves by one of two exits
+(`FlashTiles.exit`), which `flash_tiles` takes from the shape alone. "block":
+the output's block is the whole row too (8.4 MB of f32 and a 4.2 MB block,
+which the pipeline keeps twice, at T 8192, D 192), a tile of the sum is
+rounded into it when complete and the pipeline writes the row back while the
+next computes: every shape whose cheapest tile has room beside such blocks,
+1.5 to 1.8 % the faster at T 4096 and level at T 8192 (the chip, PR 49:
+ROADMAP D23 has the sweep). "tile": where it has
+not, the output lies in HBM whole (`pl.ANY`) with no block in VMEM, and a
+completed tile of the sum is rounded into a staged tile and copied to its
+rows by the kernel's own DMA, once, so that the sums alone stay:
+`keyevl2.tokens16k`'s layer, T 16,384 at 8 query heads a key-value head,
+25.2 MB of sums where the blocks would be 25.2 MB more. `flash_bwd_kernels`
+decides whether either fits VMEM beside some tile and logs the choice once;
+where neither does, `flash_bwd_dq` (k innermost) and `flash_bwd_dkv` run as
+before, each making the tile for itself, seven matmuls between them.
 
 Grouped-query attention makes no copy of k or v. They reach every kernel at
 their own heads, `[B*Hk, S, D]` and `[B*Hk, S, Dv]`, and with heads folded
@@ -40,8 +51,9 @@ once: `flash_bwd_dkv` walks the group's heads inside a key column, grid
 its key walk ends, and holds the key-value head's whole dk and dv, `S` rows
 each, as it holds the head's dq (8.4 MB of f32 and 4.2 MB of blocks twice
 more at S 8192, D 128 in bf16, whatever the group; `flash_tiles(group=)`
-prices them). With as many key heads as query heads every grid, spec,
-scratch and body is the statement it was.
+prices them), and they leave by dq's exit: as the row's blocks, or a key
+tile at a time by DMA under the group's last head. With as many key heads
+as query heads every grid, spec, scratch and body is the statement it was.
 
 The four `pallas_call`s are named `flash_fwd` (with or without the lse
 output), `flash_bwd_dkv_dq`, `flash_bwd_dq` and `flash_bwd_dkv`: the names a
@@ -178,6 +190,10 @@ class FlashTiles(NamedTuple):
     vmem_bytes: int  # estimate of what the tile needs
     vmem_limit_bytes: int  # what Mosaic is told it may use
     cost_us: float  # of a (batch, head) row's grid, by `_COST_US`
+    # how a row-long gradient leaves `flash_bwd_dkv_dq`: "block", an output
+    # block of the whole row beside its f32 sum, or "tile", the sum alone,
+    # rounded out a tile at a time by the kernel's own DMA
+    exit: str = "block"
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -240,14 +256,17 @@ def _inner_steps(kernel, T, S, block_q, block_k, window=None) -> int:
 
 
 def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
-                T=None, S=None, group: int = 1, sparse: bool = False) -> int:
+                T=None, S=None, group: int = 1, sparse: bool = False,
+                by_tile: bool = False) -> int:
     """Blocks in flight (double-buffered), scratch and the body's live
     [bq, bk] tiles, for q and k `D` wide and v `Dv` wide (`D` where None).
     A VMEM row is whole tiles of 128 lanes whatever the width is. The one
     kernel that makes all three gradients also holds a (batch, head) row's
     whole dq, `T` rows in whole q tiles: its f32 sum and its block; and
     where `group` query heads share a key-value head, that head's whole dk
-    and dv, `S` rows in whole key tiles, in place of one key tile's."""
+    and dv, `S` rows in whole key tiles, in place of one key tile's.
+    `by_tile`: the row-long gradients have no block, their sums alone stay
+    and a tile of each is staged for the DMA that takes it out."""
     qk = _cdiv(D, _LANES) * _LANES
     vo = qk if Dv is None else _cdiv(Dv, _LANES) * _LANES
     q_qk, q_vo = block_q * qk, block_q * vo  # elements: q, dq; o, do
@@ -264,12 +283,19 @@ def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
         scratch = (k_qk + k_vo) * 4
         if kernel == "flash_bwd_dkv_dq":
             dq_row = _cdiv(T, block_q) * q_qk
-            blocks += dq_row * itemsize
             scratch += dq_row * 4
             if group > 1:  # dk and dv leave and are summed by the row too
                 more = (_cdiv(S, block_k) - 1) * (k_qk + k_vo)
-                blocks += more * itemsize
                 scratch += more * 4
+            if not by_tile:
+                blocks += dq_row * itemsize
+                if group > 1:
+                    blocks += more * itemsize
+            else:  # dq's staged tile; dk's and dv's in place of their blocks
+                scratch += q_qk * itemsize
+                if group > 1:
+                    blocks -= (k_qk + k_vo) * itemsize
+                    scratch += (k_qk + k_vo) * itemsize
     f32_tiles, dtype_tiles = _LIVE_TILES[kernel]
     live = block_q * block_k * (4 * f32_tiles + itemsize * dtype_tiles)
     if sparse:  # the mask's block, a bit a pair, and its tile as 32 bits
@@ -347,7 +373,10 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     Among the tiles that fit VMEM it takes
     the one whose grid costs least by `_COST_US`: small tiles pay in grid
     steps, large ones in pairs above the causal diagonal that a diagonal
-    tile computes and masks. A forced `block_q` or `block_k` is taken as
+    tile computes and masks. What `flash_bwd_dkv_dq` must fit depends on
+    how its row-long gradients leave (`FlashTiles.exit`; the module's
+    docstring): the row's blocks if the cheapest tile has room beside
+    them, else the sums alone. A forced `block_q` or `block_k` is taken as
     given (cut to the sequence) and the other is chosen. With `window`
     (causal, `query - key < window`) the tiles with a body are the band's
     and so is the grid (`_inner_steps`): a large tile pays in pairs on both
@@ -359,23 +388,36 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     step_us, rows_us, pairs_us = _COST_US[kernel]
     pairs_us = pairs_us * _pairs_factor(kernel, D, Dv)
 
-    def plan(bq, bk):
+    def plan(bq, bk, exit):
         outer = _cdiv(T, bq) if kernel in _K_INNERMOST else _cdiv(S, bk)
         steps = outer * _inner_steps(kernel, T, S, bq, bk, window)
         active = _active_tiles(T, S, bq, bk, causal, window)
         vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv, T, S, group,
-                           sparse)
+                           sparse, by_tile=exit == "tile")
         cost = steps * step_us + active * (
             rows_us * bq / 1024 + pairs_us * bq * bk / 2 ** 20)
         return FlashTiles(bq, bk, steps, active / steps, vmem,
-                          max(_DEFAULT_VMEM, 2 * vmem), cost)
+                          max(_DEFAULT_VMEM, 2 * vmem), cost, exit)
 
     whole = window is not None
     qs = [min(block_q, T)] if block_q else _block_candidates(T, whole)
     ks = [min(block_k, S)] if block_k else _block_candidates(S, whole)
-    plans = [plan(bq, bk) for bq in qs for bk in ks]
-    fitting = [p for p in plans if p.vmem_limit_bytes <= _MAX_VMEM]
-    return min(fitting or plans[:1], key=lambda p: p.cost_us)
+
+    def fitting(exit):
+        plans = [plan(bq, bk, exit) for bq in qs for bk in ks]
+        return [p for p in plans if p.vmem_limit_bytes <= _MAX_VMEM]
+
+    # The cheapest tile that fits, and of the exits that have room for it
+    # the row's blocks: the program of every shape whose cheapest tile had
+    # room beside them, 1.5 to 1.8 % the faster at T 4096 (PERF.md section 6,
+    # PR 49). The sums alone at widths of whole lanes only (Mosaic takes
+    # no DMA to rows of 192 or 64: "Slice shape along dimension 2 must be
+    # aligned to tiling (128)").
+    fits = fitting("block")
+    if kernel == "flash_bwd_dkv_dq" and D % _LANES == Dv % _LANES == 0:
+        fits += fitting("tile")
+    return min(fits or [plan(qs[0], ks[0], "block")],
+               key=lambda p: (p.cost_us, p.exit == "tile"))
 
 
 def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
@@ -388,17 +430,19 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
     `("flash_bwd_dkv_dq",)`, all three gradients from one pass over the
     score tiles, or `("flash_bwd_dq", "flash_bwd_dkv")`, which make every
     tile twice and hold one q tile's dq at a time. Pure, as `flash_tiles`
-    is. The one kernel holds a (batch, head) row's whole dq in VMEM, its
-    f32 sum and its block, `T` rows each: it is taken where that fits
-    beside some tile, and the tile it leaves room for does not cost more in
-    grid steps than the second pass saves: in bf16, causal, T up to about
-    21k at q and k 192 wide and 44k at 128 or 64. Under a window its grid
+    is. The one kernel holds a (batch, head) row's whole dq in VMEM, `T`
+    rows of f32, and with `group` query heads to a key-value head that
+    head's whole dk and dv, `S` rows each, summed over the group (8.4 MB
+    each at 8192 rows of 128): it is taken where that fits beside some
+    tile, and the tile it leaves room for does not cost more in grid steps
+    than the second pass saves. With the row's blocks beside the sums
+    (`FlashTiles.exit` "block"; in bf16, causal, self-attention) that is T
+    up to about 21k at q and k 192 wide and 44k at 64, 14k and 8k under a
+    group; with the sums alone ("tile", at widths of whole lanes: 128) 88k,
+    and 29k under a group. Under a window its grid
     comes to a q tile's dq only through a key column whose band reaches it:
     where some q tile lies past every key it could see (more queries than
-    keys) the two kernels run, whose dq walks every q row. With `group`
-    query heads to a key-value head it also holds that head's whole dk and
-    dv, `S` rows each, summed over the group (8.4 MB of f32 and 8.4 MB of
-    blocks more at S 8192, D 128 in bf16, whatever the group)."""
+    keys) the two kernels run, whose dq walks every q row."""
     def tiles(kernel):
         return flash_tiles(kernel, T, S, D, dtype, causal=causal,
                            block_q=block_q, block_k=block_k, v_dim=v_dim,
@@ -845,6 +889,15 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     return o, (q, k, v, o, lse)
 
 
+def _exit_said(kernel: str, tiles: FlashTiles) -> str:
+    """What a log line says of how `flash_bwd_dkv_dq`'s row-long gradients
+    leave VMEM (`FlashTiles.exit`); nothing of another kernel."""
+    if kernel != "flash_bwd_dkv_dq":
+        return ""
+    return (", out a tile at a time by DMA, the row's f32 sums alone held"
+            if tiles.exit == "tile" else ", out by the row's blocks")
+
+
 @functools.lru_cache(maxsize=None)
 def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
                      window=None, group=1):
@@ -865,9 +918,9 @@ def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
         t = tiles(kernel)
         logger.info(
             "flash backward at T %d, S %d, D %d, Dv %d, %s: %s, tile %d x "
-            "%d, VMEM %d bytes of a limit of %d, %s", T, S, D, Dv, dtype,
+            "%d, VMEM %d bytes of a limit of %d, %s%s", T, S, D, Dv, dtype,
             kernel, t.block_q, t.block_k, t.vmem_bytes, t.vmem_limit_bytes,
-            heads)
+            heads, _exit_said(kernel, t))
     if window is None:
         return
     for kernel in ("flash_fwd", *kernels):
@@ -885,8 +938,8 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
     (q, k, lse) — nothing O(T·S) ever touches HBM (the previous recompute
     path materialized full f32 score matrices through XLA, which both OOMed
     large batches and made the step bandwidth-bound). One kernel makes the
-    tile once for dq, dk and dv where `flash_bwd_kernels` says a row's dq
-    fits VMEM; two kernels (dq; dk/dv) make it once each where not."""
+    tile once for dq, dk and dv where `flash_bwd_kernels` says a row's
+    sums fit VMEM; two kernels (dq; dk/dv) make it once each where not."""
     q, k, v, o, lse = res
     BH, T, _ = q.shape
     if keep_ctx:
@@ -993,7 +1046,8 @@ def _attn_bwd_dkv_kernel(
     *outputs_and_sums,
     block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
     scale: float, causal: bool, seq_q: int, seq_k: int, with_dq: bool = False,
-    window: Optional[int] = None, group: int = 1, mask_ref=None,
+    window: Optional[int] = None, group: int = 1, by_tile: bool = False,
+    mask_ref=None,
 ):
     """dk and dv of k tile `ki`, summed over the q tiles the grid walks
     innermost. `with_dq` (`flash_bwd_dkv_dq`): dq too, from the same p and
@@ -1002,7 +1056,7 @@ def _attn_bwd_dkv_kernel(
     column of the grid apart; tile `qi`'s rows are zeroed in the first
     column (under a window: the first whose band reaches them), summed over
     `ki` in ascending order as `_attn_bwd_dq_kernel` sums them, and rounded
-    once into the row's dq block in the last.
+    once in the last: into the row's dq block, or out (`by_tile`, below).
 
     With `group` query heads to a key-value head dk and dv are summed over
     the group's heads too, in f32, and rounded once. Without dq the column's
@@ -1012,12 +1066,19 @@ def _attn_bwd_dkv_kernel(
     walk ends), so the sums and the output blocks hold the key-value head's
     whole row, as dq's do: tile `ki`'s rows are zeroed at its first step
     under the group's first head and rounded out at its last under the
-    group's last."""
+    group's last.
+
+    `by_tile` (with dq): a row-long gradient has no block in VMEM. Its
+    output is the whole array where it lies (`pl.ANY`), and the flush that
+    would have rounded a tile of the sum into the row's block rounds it
+    into a staged tile and copies that to its rows (`_round_out`): dq
+    always, dk and dv where a group makes them row-long."""
     from jax.experimental import pallas as pl
 
-    if with_dq:
+    if with_dq:  # `by_tile`: and dq's staged tile, dk's and dv's, semaphores
         (dk_ref, dv_ref, dq_ref,
-         dk_acc_ref, dv_acc_ref, dq_acc_ref) = outputs_and_sums
+         dk_acc_ref, dv_acc_ref, dq_acc_ref, *staged) = outputs_and_sums
+        sems = staged.pop() if by_tile else None
     else:
         dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = outputs_and_sums
     acc, out = ..., 0  # tile `ki` in dk's and dv's sums, and in their blocks
@@ -1035,6 +1096,10 @@ def _attn_bwd_dkv_kernel(
         out = (0, acc)
     if window is not None:
         qi = _first_q_with_body(ki, block_q, block_k, num_q) + step
+    if by_tile:  # the rows of the arrays that the flushes copy to
+        bh = bkv = pl.program_id(0)
+        if group > 1:
+            bh = bkv * group + head
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
                  seq_q=seq_q, seq_k=seq_k, window=window)
 
@@ -1072,6 +1137,11 @@ def _attn_bwd_dkv_kernel(
 
     @pl.when(walk == group * steps - 1)
     def _flush():
+        if by_tile and group > 1:
+            _round_out(
+                (dk_acc_ref[acc], staged[1], dk_ref.at[bkv, acc], sems.at[1]),
+                (dv_acc_ref[acc], staged[2], dv_ref.at[bkv, acc], sems.at[2]))
+            return
         dk_ref[out] = dk_acc_ref[acc].astype(dk_ref.dtype)
         dv_ref[out] = dv_acc_ref[acc].astype(dv_ref.dtype)
 
@@ -1083,7 +1153,27 @@ def _attn_bwd_dkv_kernel(
 
         @pl.when(last_col)
         def _flush_dq():
+            if by_tile:
+                _round_out((dq_acc_ref[rows, :], staged[0],
+                            dq_ref.at[bh, rows], sems.at[0]))
+                return
             dq_ref[0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
+
+
+def _round_out(*tiles):
+    """Each (a tile of an f32 sum, its staging tile in the output's dtype,
+    the output's rows where they lie, a DMA semaphore): rounded once into
+    the staging tile and copied out by the kernel's own DMA. Every copy is
+    started, then every one waited for: the staging tiles are free again."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    copies = []
+    for total, stage_ref, rows_ref, sem in tiles:
+        stage_ref[...] = total.astype(stage_ref.dtype)
+        copies.append(pltpu.make_async_copy(stage_ref, rows_ref, sem))
+        copies[-1].start()
+    for copy in copies:
+        copy.wait()
 
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
@@ -1134,11 +1224,14 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
 
 def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
                    block_q, block_k, interpret, with_dq: bool = False,
-                   window=None, mask=None):
+                   window=None, mask=None, by_tile: bool = False):
     """(dk, dv), or with `with_dq` the kernel `flash_bwd_dkv_dq` and
     (dq, dk, dv): one more output, whose block is a (batch, head) row's
     whole dq, fetched nowhere and written back when the row is done, and
-    one more f32 sum of that size.
+    one more f32 sum of that size. Where `flash_tiles` found no room for
+    such blocks (`FlashTiles.exit` "tile"; `by_tile` forces it, for tests)
+    the row-long outputs have none: they lie in HBM, and the kernel copies
+    each completed tile out of a staged one.
 
     k and v of [BHk, S, ...] with `group = BH // BHk` query rows to each:
     dk and dv leave at [BHk, S, ...], summed over the group in VMEM. The
@@ -1158,11 +1251,12 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     tiles, num_q, num_k, steps = _grid(
         name, q, k, v, causal, block_q, block_k, window, mask)
     block_q, block_k = tiles.block_q, tiles.block_k
+    by_tile = with_dq and (by_tile or tiles.exit == "tile")
     kernel = functools.partial(
         _attn_bwd_dkv_kernel,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
         steps=steps, scale=scale, causal=causal, seq_q=T, seq_k=S,
-        with_dq=with_dq, window=window, group=group,
+        with_dq=with_dq, window=window, group=group, by_tile=by_tile,
     )
 
     q_block = _q_block_under_k(causal, block_q, block_k, num_q, window)
@@ -1234,6 +1328,14 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         scratch_shapes.append(pltpu.VMEM((rows, D), jnp.float32))
         # dq is summed over k tiles too, dk and dv over a group's heads
         inner = ("arbitrary",) * (len(grid) - 1)
+    if by_tile:  # the row-long outputs lie in HBM: a staged tile of each
+        out_specs[2] = pl.BlockSpec(memory_space=pl.ANY)
+        scratch_shapes.append(pltpu.VMEM((block_q, D), q.dtype))
+        if group > 1:
+            out_specs[:2] = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+            scratch_shapes += [pltpu.VMEM((block_k, D), k.dtype),
+                               pltpu.VMEM((block_k, Dv), v.dtype)]
+        scratch_shapes.append(pltpu.SemaphoreType.DMA((3,)))
     out = pl.pallas_call(
         kernel,
         grid=grid,

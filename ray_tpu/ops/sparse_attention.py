@@ -34,8 +34,11 @@ and the kernels' reference. `impl="pallas"` is the chip's:
   scores and the number selected. No `[T, T]` float array exists.
 - the flash kernels under that mask (`ops/flash_attention.py`, `mask=`):
   the dense causal walk with the mask's tile beside the causal term,
-  `flash_fwd_sparse`, `flash_bwd_dkv_dq_sparse` (or `flash_bwd_dq_sparse`
-  and `flash_bwd_dkv_sparse`). A seeded indexer scatters its choice over
+  `flash_fwd_sparse` and `flash_bwd_dkv_dq_sparse`, one pass over the score
+  tiles for dq, dk and dv (at T 16,384 and 8 query heads a key-value head
+  with the row-long gradients leaving VMEM a tile at a time; the planner
+  falls back to `flash_bwd_dq_sparse` and `flash_bwd_dkv_sparse` where not
+  even their f32 sums fit). A seeded indexer scatters its choice over
   the whole prefix, so no tile is empty and none is skipped; a gather of
   2,048 rows a query is not a TPU program at 8 query heads a key-value head.
 - `index_loss` (a kernel of that name): a tile of queries by keys at a
@@ -69,8 +72,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.flash_attention import (
     _DEFAULT_VMEM, _NN, _NT, _TN, _dot, _flash_bwd_dkv, _flash_bwd_dq,
-    _flash_fwd, _pack_bits, _pairs_mask, _unpack_bits, flash_bwd_kernels,
-    flash_tiles, resolve_impl)
+    _flash_fwd, _pack_bits, _pairs_mask, _unpack_bits, _exit_said,
+    flash_bwd_kernels, flash_tiles, resolve_impl)
 
 logger = logging.getLogger(__name__)
 
@@ -572,9 +575,9 @@ def _log_path(T, H, Hk, D, Dv, dtype, topk, tile, block_q):
         "key tiles of %d, %d bytes a sequence, kept with attn_ctx; the dense "
         "causal walk under it by %s; the index loss by the kernel index_loss",
         T, H, Hk, D, Dv, dtype, topk, tile, T * T // 8,
-        ", ".join("%s_sparse %d x %d" % (
-            kernel, tiles(kernel).block_q, tiles(kernel).block_k)
-            for kernel in kernels))
+        ", ".join("%s_sparse %d x %d%s" % (
+            kernel, t.block_q, t.block_k, _exit_said(kernel, t))
+            for kernel, t in zip(kernels, map(tiles, kernels))))
 
 
 def _heads_first(x):

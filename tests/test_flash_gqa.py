@@ -477,6 +477,127 @@ def test_the_backward_s_line_says_the_group(caplog):
     assert len(lines) == 2
     assert lines[0].endswith(
         "flash_bwd_dkv_dq, tile 128 x 128, VMEM %d bytes of a limit of %d, "
-        "8 query heads a key-value head by index map" % fa.flash_tiles(
+        "8 query heads a key-value head by index map, out by the row's "
+        "blocks" % fa.flash_tiles(
             "flash_bwd_dkv_dq", 128, 128, D, jnp.float32, group=8)[4:6])
-    assert lines[1].endswith(", no group")
+    assert lines[1].endswith(", no group, out by the row's blocks")
+
+
+# -------------------------------------- the row-long gradients' two exits
+
+def backward_operands(group, seq, width, v_width, window, masked):
+    """q, k, v, do, lse, delta of two key-value rows folded, `group` query
+    rows to each, with the tile's keywords; under `masked` a seeded choice
+    of pairs as the data mask, in key tiles of 128."""
+    shapes = [(KV_ROWS * group, seq, width), (KV_ROWS, seq, width),
+              (KV_ROWS, seq, v_width), (KV_ROWS * group, seq, v_width)]
+    q, k, v, do = (jax.random.normal(key(i), shape).astype(jnp.bfloat16)
+                   for i, shape in enumerate(shapes))
+    mask = None
+    if masked:  # one batch row: every head reads the same choice
+        keep = jax.random.bernoulli(key(9), 0.5, (1, seq, -(-seq // 128), 128))
+        mask = fa._pack_bits(keep).transpose(0, 2, 1, 3)
+    how = dict(causal=True, scale=width ** -0.5, block_q=128, block_k=128,
+               interpret=True, window=window, mask=mask)
+    o, lse = fa._flash_fwd(q, k, v, with_lse=True, **how)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, 8))
+    return (q, k, v, do, lse, delta), how
+
+
+@pytest.mark.parametrize("seq,width,v_width,window,masked", [
+    (256, 32, 32, None, False), (256, 32, 32, None, True),
+    (200, 32, 32, None, False), (200, 32, 32, None, True),
+    (384, 32, 32, 130, False),  # a data mask is walked with no window
+    (256, 64, 32, None, False), (256, 64, 32, None, True),
+], ids=["causal", "causal-mask", "ragged", "ragged-mask", "window",
+        "two-widths", "two-widths-mask"])
+@pytest.mark.parametrize("group", [1, WIDEST])
+def test_a_tile_at_a_time_is_the_row_s_block_to_the_bit(
+        group, seq, width, v_width, window, masked):
+    """`flash_bwd_dkv_dq` with its row-long gradients leaving by DMA a tile
+    at a time (forced here: the planner takes that exit only where the
+    row's blocks do not fit) against the same kernel with the row's blocks
+    and against `flash_bwd_dq` + `flash_bwd_dkv`: dq, dk and dv equal to the
+    bit, every tile written, and no row-long block handed to the call."""
+    operands_, how = backward_operands(
+        group, seq, width, v_width, window, masked)
+    two = (fa._flash_bwd_dq(*operands_, **how),
+           *fa._flash_bwd_dkv(*operands_, **how))
+    block = fa._flash_bwd_dkv(*operands_, with_dq=True, **how)
+    by_tile = functools.partial(fa._flash_bwd_dkv, with_dq=True,
+                                by_tile=True, **how)
+    for ours, theirs, parents_ in zip(by_tile(*operands_), block, two):
+        assert ours.shape == theirs.shape and ours.dtype == theirs.dtype
+        assert float(jnp.abs(ours.astype(jnp.float32)).max()) > 0.01
+        np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
+        np.testing.assert_array_equal(np.asarray(ours), np.asarray(parents_))
+    name = "flash_bwd_dkv_dq" + ("_sparse" if masked else
+                                 "_window" if window else "")
+    mappings = pallas_calls(by_tile, *operands_)[name][
+        "grid_mapping"].block_mappings
+    rows = -(-seq // 128) * 128
+    outputs = [tuple(getattr(b, "block_size", b) for b in m.block_shape)
+               for m in mappings[-3:]]  # dk, dv, dq
+    # what lies where the compiler put it has the array for its block
+    # (dk's and dv's tiles stay the pipeline's without a group)
+    k_block = (1, 128) if group == 1 else (KV_ROWS, rows)
+    assert outputs == [(*k_block, width), (*k_block, v_width),
+                       (KV_ROWS * group, rows, width)]
+    assert [str(m.block_aval.memory_space) for m in mappings[-3:]] == (
+        ["None", "None", "any"] if group == 1 else ["any"] * 3)
+
+
+KEYE = dict(block_k=1024, group=8, sparse=True)  # keyevl2.tokens16k's layer
+
+
+def test_keye_s_backward_is_the_one_kernel_with_its_sums_alone():
+    """T 16,384 at 8 query heads a key-value head in bf16: 25.2 MB of f32
+    sums beside a tile of 512 x 1024 where the row's blocks, twice 12.6 MB
+    more, left room for none."""
+    shape = (16384, 16384, 128, jnp.bfloat16)
+    assert fa.flash_bwd_kernels(*shape, **KEYE) == ("flash_bwd_dkv_dq",)
+    tiles = fa.flash_tiles("flash_bwd_dkv_dq", *shape, **KEYE)
+    assert tiles[:2] == (512, 1024) and tiles.exit == "tile"
+    assert tiles.vmem_bytes == 41_156_608
+    assert tiles.vmem_limit_bytes == 2 * tiles.vmem_bytes <= fa._MAX_VMEM
+    sums = 4 * 128 * (16384 + 2 * 16384)
+    assert sums == 25_165_824 < tiles.vmem_bytes
+    # the row's blocks: no tile fits beside them, as the parent found
+    for block_q in (128, 256, 512, 1024):
+        held = fa._vmem_bytes("flash_bwd_dkv_dq", block_q, 1024, 128, 2, 128,
+                              16384, 16384, group=8, sparse=True)
+        assert 2 * held > fa._MAX_VMEM
+    assert fa.flash_bwd_kernels(*shape, **dict(KEYE, group=1)) == (
+        "flash_bwd_dkv_dq",)
+    assert fa.flash_tiles("flash_bwd_dkv_dq", *shape,
+                          **dict(KEYE, group=1)).exit == "block"
+
+
+@pytest.mark.parametrize("cell,seq,width,v_width,group,window,tile,held", [
+    ("mistral7b.tokens4k", 4096, 128, 128, 4, None, (1024, 1024), 37_748_736),
+    ("mistral7b.fsdp4", 4096, 128, 128, 4, None, (1024, 1024), 37_748_736),
+    ("olmoe.tokens4k", 4096, 128, 128, 1, None, (1024, 1024), 31_457_280),
+    ("lfm2moe.tokens8k", 8192, 64, 64, 4, None, (1024, 1024), 50_331_648),
+    ("dsv2lite.tokens8k", 8192, 192, 128, 1, None, (1024, 1024), 46_137_344),
+    ("nemotron3nano.tokens8k", 8192, 128, 128, 16, None, (1024, 1024),
+     50_331_648),
+    ("lagunaxs2.tokens8k, full", 8192, 128, 128, 6, None, (1024, 1024),
+     50_331_648),
+    ("lagunaxs2.tokens8k, sliding", 8192, 128, 128, 8, 512, (512, 512),
+     32_505_856),
+])
+def test_a_cell_whose_row_s_blocks_fit_keeps_them(cell, seq, width, v_width,
+                                                  group, window, tile, held):
+    """The seven other token cells' backward as PR 48's planner chose it
+    (its answers, computed on that tree): the one kernel, the tile, the
+    estimate, and the row's blocks for its exit. Three stand at the limit to
+    the byte."""
+    shape = dict(v_dim=v_width, window=window, group=group)
+    assert fa.flash_bwd_kernels(seq, seq, width, jnp.bfloat16, **shape) == (
+        "flash_bwd_dkv_dq",)
+    tiles = fa.flash_tiles("flash_bwd_dkv_dq", seq, seq, width, jnp.bfloat16,
+                           **shape)
+    assert tiles[:2] == tile and tiles.exit == "block", cell
+    assert tiles.vmem_bytes == held
+    assert tiles.vmem_limit_bytes == 2 * held <= fa._MAX_VMEM

@@ -234,6 +234,58 @@ def test_the_one_backward_kernel_compiles_with_a_group_of_eight(v5e, window):
         f"bf16[{rows},{t},{d}]", f"bf16[{rows},{t},{d}]", f"bf16[{bh},{t},{d}]"]
 
 
+@pytest.mark.timeout(600)  # a kernel, seconds; room under six workers
+@pytest.mark.parametrize("bh,rows,t,masked,tile", [
+    (32, 4, 16384, True, (512, 1024)),  # keyevl2.tokens16k's layer
+    (2, 2, 65536, False, (512, 1024)),  # a row too long for its dq's block
+], ids=["keye", "no-group-64k"])
+def test_the_one_backward_kernel_compiles_with_its_sums_alone(
+        v5e, bh, rows, t, masked, tile):
+    """Where a row's output blocks leave no tile room the one kernel holds
+    the row-long gradients as f32 sums and copies them out a tile at a time
+    (`FlashTiles.exit == "tile"`): the outputs lie where the compiler put
+    them and have no block, the limit is the planner's, and the call is one
+    custom call under the name the metrics read, dk and dv at the
+    key-value rows. Under a group all three leave by DMA, without one dq
+    alone."""
+    import re
+
+    d = 128
+    shape = dict(group=bh // rows, sparse=masked,
+                 block_k=1024 if masked else None)
+    assert fa.flash_bwd_kernels(t, t, d, jnp.bfloat16, **shape) == (
+        "flash_bwd_dkv_dq",)
+    tiles = fa.flash_tiles("flash_bwd_dkv_dq", t, t, d, jnp.bfloat16, **shape)
+    assert tiles[:2] == tile and tiles.exit == "tile"
+    q, row = _shapes(v5e[0], bh, t, d)
+    k, _ = _shapes(v5e[0], rows, t, d)
+    operands = [q, k, k, q, row, row]
+    chosen = dict(KERNEL, scale=d ** -0.5, block_q=None, block_k=None)
+    if masked:  # the selection's bits, in key tiles of 1,024
+        operands.append(jax.ShapeDtypeStruct(
+            (1, t // 1024, t, 128), jnp.int8, sharding=q.sharding))
+        chosen["block_k"] = 1024
+
+    def fn(*a):
+        return fa._flash_bwd_dkv(*a[:6], with_dq=True, **chosen,
+                                 mask=a[6] if masked else None)
+
+    (call,) = [eqn for eqn in jax.make_jaxpr(fn)(*operands).eqns
+               if eqn.primitive.name == "pallas_call"]
+    outputs = call.params["grid_mapping"].block_mappings[-3:]
+    assert [str(m.block_aval.memory_space) for m in outputs] == (
+        ["any"] * 3 if bh > rows else ["None", "None", "any"])
+    limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert limit == tiles.vmem_limit_bytes <= fa._MAX_VMEM
+    text = jax.jit(fn).lower(*operands).compile().as_text()
+    ((name, outputs),) = re.findall(
+        r'%([\w.-]+) = \((.*?)\) custom-call\([^\n]*"tpu_custom_call"', text)
+    assert re.fullmatch(
+        "flash_bwd_dkv_dq" + ("_sparse" if masked else "") + r"(\.\d+)?", name)
+    assert re.findall(r"bf16\[[\d,]+\]", outputs) == [
+        f"bf16[{rows},{t},{d}]", f"bf16[{rows},{t},{d}]", f"bf16[{bh},{t},{d}]"]
+
+
 def test_lm_head_cross_entropy_compiles_for_v5e(v5e):
     one = SingleDeviceSharding(v5e[0])
     hidden = jax.ShapeDtypeStruct((32, 1024, 768), jnp.bfloat16, sharding=one)

@@ -354,16 +354,23 @@ def test_flash_backward_takes_the_two_kernels_where_it_is_told_to(monkeypatch):
     # longer rows leave the tile less room: measured at BH 8, 34.4 ms for
     # the two kernels' 47.3 (PERF.md section 6, PR 35)
     (16384, 192, 128, jnp.bfloat16, ONE, (1024, 512)),
-    (32768, 128, 128, jnp.bfloat16, ONE, (768, 768)),
-    # a row's dq (its f32 sum, and its block twice) is 8 T lanes(D) bytes
-    # in bf16: 67 MB at T 32768, D 192, where the limit of 96 MiB allows
-    # an estimate of 48; no tile fits beside it
+    # a row's dq with its block (its f32 sum, and the block twice) is 8 T
+    # lanes(D) bytes in bf16: 67 MB at T 32768, D 192, where the limit of
+    # 96 MiB allows an estimate of 48; no tile fits beside it, and rows of
+    # 192 cannot leave a tile at a time
     (32768, 192, 128, jnp.bfloat16, TWO, None),
-    (65536, 128, 128, jnp.bfloat16, TWO, None),
-    (32768, 128, 128, jnp.float32, TWO, None),
+    # at widths of whole lanes they can (PR 49), and the sum alone is 4 T
+    # lanes(D) bytes: the cheapest tile again where the row's block left
+    # room for 768 x 768, and the one kernel where it left room for none
+    (32768, 128, 128, jnp.bfloat16, ONE, (1024, 1024)),
+    (46080, 128, 128, jnp.bfloat16, ONE, (1024, 896)),
+    (65536, 128, 128, jnp.bfloat16, ONE, (512, 1024)),
+    (32768, 128, 128, jnp.float32, ONE, (1024, 896)),
+    (98304, 128, 128, jnp.bfloat16, TWO, None),  # 50 MB of sum
     # it fits, beside tiles so small that their grid steps cost more than
     # the second pass at 1024 x 1024 does
-    (46080, 128, 128, jnp.bfloat16, TWO, (256, 256)),
+    (22528, 192, 128, jnp.bfloat16, TWO, (256, 256)),
+    (90112, 128, 128, jnp.bfloat16, TWO, (256, 384)),
 ])
 def test_flash_backward_kernels_of_a_shape(T, D, Dv, dtype, kernels, tile):
     assert fa.flash_bwd_kernels(T, T, D, dtype, v_dim=Dv) == kernels
@@ -372,6 +379,8 @@ def test_flash_backward_kernels_of_a_shape(T, D, Dv, dtype, kernels, tile):
     assert fits == (tile is not None)
     if fits:
         assert (one.block_q, one.block_k) == tile
+        # the row's blocks wherever the cheapest tile has room beside them
+        assert (one.exit == "block") == (D == 192 or T <= 16384)
     two = sum(flash_tiles(kernel, T, T, D, dtype, v_dim=Dv).cost_us
               for kernel in TWO)
     assert (kernels == ONE) == (fits and one.cost_us <= two)

@@ -69,13 +69,22 @@ def step():
 
 
 @pytest.mark.parametrize("kernel", [
-    "index_select", "flash_fwd_sparse", "index_loss", "flash_bwd_dq_sparse",
-    "flash_bwd_dkv_sparse"])
+    "index_select", "flash_fwd_sparse", "index_loss",
+    "flash_bwd_dkv_dq_sparse"])
 def test_the_kernel_runs_once_a_layer(step, kernel):
     """`attn_ctx` kept: one instance in the scanned stack's program, the
     forward body's or the backward body's, and none made again."""
     text = step[0].as_text()
     assert len(set(re.findall(rf"%{kernel}\.\d+ = ", text))) == 1
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_bwd_dq_sparse", "flash_bwd_dkv_sparse"])
+def test_no_kernel_makes_the_score_tiles_for_itself(step, kernel):
+    """The backward is the one kernel (PR 49: its row-long dq, dk and dv
+    leave VMEM a tile at a time): neither of the two that each made every
+    tile stands in the program."""
+    assert not re.search(rf"%{kernel}\.\d+ = ", step[0].as_text())
 
 
 def test_the_mask_is_bits_and_nothing_is_a_sequence_squared(step):

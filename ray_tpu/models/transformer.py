@@ -88,6 +88,27 @@ step's loss (`index_loss` among the readings, with `index_keys_min_gap` and
 `min(t + 1, index_topk)`, both 0). Cross-entropy moves no indexer leaf and
 the index loss moves nothing else. Those are Keye-VL-2.0's (`keye_vl2`).
 
+Under a mesh with an `expert` axis (`make_mesh({"expert": n})`) a routed
+stack is expert-parallel, nothing of a layer left out: a device holds
+`n_experts / n` whole experts of every layer (the experts' leaves cut on
+their `experts` axis, never gathered), its own sequences of the batch (the
+axis is a batch axis for everything outside the routed layer) and an n-th of
+every other matrix (cut on `embed`, gathered for use and its gradient
+scattered, as `fsdp` does). The routed layer runs in a `shard_map` over the
+axis (`_routed_ffn`, `_routed_rows`): a device routes its own tokens in
+float32; an all-gather brings every device all the axis's normed rows,
+weights and choices (`moe_gather`); the device computes the rows routed to
+its experts as any share does (`experts_of_share` with `held =
+(n_held x axis_index, n_held)`, the grouped-matmul kernels in buffers of
+`held_chunk` rows); a reduce-scatter sums the partial results on each
+token's own device (`moe_scatter`). Dropless on every device; the balance
+loss, the z loss and `expert_load` are over the mesh's batch, `held_slots`,
+`dropped_slots` and `chip_load` a device each. The head is gathered whole
+for the loss and every device takes the chunks of its own sequences
+(`_head_loss`). All 64 experts of Mellum2-12B-A2.5B's layers, window-1024
+and full attention 3 : 1, train so over the four chips of one host
+(`mellum`).
+
 With `remat` each block runs under `jax.checkpoint`: its input is kept and
 its values are made again in the backward pass, but for the named ones
 (`checkpoint_name`) that `make_train_step`'s step finds room for on the
@@ -116,6 +137,10 @@ benchmark's tests patch them here.
 
 Parallelism (ray_tpu.parallel.mesh axes):
   data/fsdp — batch split; fsdp additionally shards params (ZeRO-3 style)
+  expert    — a routed layer's experts split, whole experts a device, and
+              its exchange under shard_map; for everything else one more
+              fsdp axis (batch split, params sharded). Alone in its mesh:
+              a second axis of several devices beside it is not mapped yet
   tensor    — heads + mlp hidden + vocab split (Megatron layout)
   sequence  — context parallelism; attention switches to ring_attention
 
@@ -123,12 +148,14 @@ Capability analog of what the reference reaches only through integrations
 (SURVEY §5: it ships no native SP); here it is native. Cells that train it:
 `mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k`,
 `lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`,
-`lagunaxs2.tokens8k`, `keyevl2.tokens16k` (BENCHMARK.json).
+`lagunaxs2.tokens8k`, `keyevl2.tokens16k`, and `mellum2.ep4` on the four
+chips of an `expert` axis (BENCHMARK.json).
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import logging
 import math
 from dataclasses import dataclass, field
@@ -663,63 +690,145 @@ def _routed_ffn(y, blk, cfg: TransformerConfig, mesh=None, bias=None):
     router's width) sums over the held among a token's experts and leaves
     the others' terms out; its readings gain `held_slots` (the slots whose
     expert it holds) and `dropped_slots` (those of them it did not compute:
-    0). `bias` [E] is the router's selection bias."""
-    B, T, d = y.shape
+    0). `bias` [E] is the router's selection bias.
+
+    Under a mesh with an `expert` axis of `n` devices the layer runs in a
+    `shard_map` over it (`_routed_rows`): a device routes its own sequences,
+    holds `E / n` whole experts and is such a share for the tokens of all
+    `n`, which an all-gather brings and whose partial results a
+    reduce-scatter sums on each token's own device. The batch's readings
+    are over the mesh's batch; `held_slots` and `dropped_slots` are [n], a
+    device each, and `chip_load` [n] is `expert_load` summed by device."""
     impl = _kernel_impl(cfg)
-    if impl == "pallas" and mesh is not None and mesh.size > 1:
-        raise NotImplementedError(
-            "the grouped-matmul kernels are not mapped over a mesh of "
-            f"{mesh.size} devices yet (ROADMAP R1: expert parallelism)"
-        )
+    axis = mesh_lib.expert_axis(mesh)
+    if axis is None:
+        if impl == "pallas" and mesh is not None and mesh.size > 1:
+            raise NotImplementedError(
+                "the grouped-matmul kernels are mapped over an `expert` "
+                f"axis, which the mesh {dict(mesh.shape)} does not have "
+                "(ROADMAP R1: expert parallelism)"
+            )
+        return _routed_rows(y, blk, cfg, impl, bias)
+    ways = mesh.size  # the axis is alone in its mesh
+    if cfg.n_experts % ways or blk["w_down"].shape[0] != cfg.n_experts:
+        raise ValueError(
+            f"{blk['w_down'].shape[0]} of {cfg.n_experts} experts over an "
+            f"`{axis}` axis of {ways}: the axis cuts all of a layer's "
+            "experts into whole and equal shares")
+    # the router whole (gathered where it is sharded), the experts cut
+    leaves = {name: blk[name] for name in _RoutedFF.moe_weights if name in blk}
+    if bias is not None:
+        leaves["bias"] = bias
+    own, whole = P(axis), P()
+    routed, readings = jax.shard_map(
+        lambda y, leaves: _routed_rows(
+            y, leaves, cfg, impl, leaves.get("bias"), axis),
+        mesh=mesh,
+        in_specs=(own, {name: whole if name in ("router", "bias") else own
+                        for name in leaves}),
+        out_specs=(own, {"aux_loss": whole, "z_loss": whole,
+                         "expert_load": whole, "expert_index": own,
+                         "held_slots": own, "dropped_slots": own}),
+        check_vma=False,
+    )(y, leaves)
+    readings["chip_load"] = readings["expert_load"].reshape(ways, -1).sum(-1)
+    return routed, readings
+
+
+def _router_logits(tokens, router):
+    """The router's scores before its softmax or sigmoid, [T, E]: float32
+    at full precision whatever the compute dtype: with bf16 logits the
+    k-th and the next expert swap on rounding."""
+    return jnp.dot(
+        tokens.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+def _routed_rows(y, blk, cfg: TransformerConfig, impl: str, bias=None,
+                 axis: Optional[str] = None):
+    """`_routed_ffn` on one device. With `axis` the device is one of an
+    expert axis inside a `shard_map`: `y` is its own sequences, `blk` its
+    `E / n` experts (device c's are `c E / n` onward) and the router whole.
+    It routes its own tokens; the tokens, weights and choices of all the
+    axis are gathered (`moe_gather`), the held rows computed as any share's,
+    and the partial results summed onto each token's own device
+    (`moe_scatter`). The gather and the scatter are each other's
+    transposes, so the backward is the same exchange the other way."""
+    B, T, d = y.shape
     tokens = y.reshape(B * T, d)
     with jax.named_scope("moe_router"):
-        # float32 at full precision: with bf16 logits the k-th and the next
-        # expert swap on rounding
-        logits = jnp.dot(
-            tokens.astype(jnp.float32), blk["router"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        logits = _router_logits(tokens, blk["router"])
         probs, weights, index = moe.route(
             logits, cfg.experts_per_token, cfg.norm_topk_prob,
             score=cfg.router_score, bias=bias, eps=cfg.norm_topk_eps)
         if cfg.routed_scaling_factor != 1.0:
             weights = weights * cfg.routed_scaling_factor
-        n_held = blk["w_down"].shape[0]
-        share = n_held < cfg.n_experts
+    n_held = blk["w_down"].shape[0]
+    share = n_held < cfg.n_experts
+    own_index = index
+    if axis is None:
+        first = cfg.held[0]
+    else:
+        first = jax.lax.axis_index(axis) * n_held
+        with jax.named_scope("moe_gather"):
+            tokens, weights, index = (
+                jax.lax.all_gather(x, axis, tiled=True)
+                for x in (tokens, weights, index))
+    with jax.named_scope("moe_router"):  # the slots' sort and the readings
         slots = moe.sort_slots(index, cfg.n_experts,
-                               (cfg.held[0], n_held) if share else None)
+                               (first, n_held) if share else None)
         if not share:
             slots = moe.Slots(
                 *(checkpoint_name(s, "moe_slots") for s in slots))
         if cfg.seq_aux:  # every sequence's own counts, over all E experts
-            per_sequence = moe.sequence_load(index, cfg.n_experts, B)
+            per_sequence = moe.sequence_load(own_index, cfg.n_experts, B)
             load = per_sequence.sum(axis=0) if share else slots.group_sizes
             aux_loss = moe.sequence_balancing_loss(
                 probs.reshape(B, T, -1), per_sequence)
         else:
-            load = (moe.expert_load(index, cfg.n_experts) if share
+            load = (moe.expert_load(own_index, cfg.n_experts) if share
                     else slots.group_sizes)
+        if axis is not None:
+            # over the mesh's batch: every device has as many tokens and
+            # sequences, so a mean of the devices' means is the batch's
+            load = jax.lax.psum(load, axis)
+            if cfg.seq_aux:
+                aux_loss = jax.lax.pmean(aux_loss, axis)
+            else:  # one row: the mean of the router's scores over the mesh
+                probs = jax.lax.pmean(probs.mean(axis=0), axis)[None]
+        if not cfg.seq_aux:
             aux_loss = moe.load_balancing_loss(probs, load)
+        z_loss = moe.router_z_loss(logits)
+        if axis is not None:
+            z_loss = jax.lax.pmean(z_loss, axis)
         readings = {
             "aux_loss": aux_loss,
-            "z_loss": moe.router_z_loss(logits),
+            "z_loss": z_loss,
             "expert_load": load,
-            "expert_index": index,
+            "expert_index": own_index,
         }
         if share:
             held_rows = slots.group_sizes.sum()
-            first = cfg.held[0]
             readings["held_slots"] = jnp.logical_and(
                 index >= first, index < first + n_held).sum(dtype=jnp.int32)
             readings["dropped_slots"] = readings["held_slots"] - held_rows
+            if axis is not None:  # a device each
+                readings["held_slots"] = readings["held_slots"][None]
+                readings["dropped_slots"] = readings["dropped_slots"][None]
 
     if share:  # names its own operations as below, a chunk of rows at a time
+        sequences = B if axis is None else B * jax.lax.axis_size(axis)
         out = moe.experts_of_share(
             tokens, blk.get("w_gate"), blk["w_up"], blk["w_down"], weights,
             slots,
             impl=impl, chunk=moe.held_chunk(
                 slots.order.shape[0], n_held, cfg.n_experts,
-                load_held_even=cfg.expert_bias, sequences=B))
+                load_held_even=cfg.expert_bias, sequences=sequences))
+        if axis is not None:
+            with jax.named_scope("moe_scatter"):
+                out = jax.lax.psum_scatter(
+                    out, axis, scatter_dimension=0, tiled=True)
         return out.reshape(B, T, d), readings
     with jax.named_scope("moe_dispatch"):
         xs = moe.dispatch(tokens, slots.order, slots.inverse)
@@ -1828,6 +1937,27 @@ def transformer_apply(params, tokens, cfg: TransformerConfig,
     return (x @ _unembed(params, cfg).astype(cfg.dtype)).astype(jnp.float32)
 
 
+def _head_loss(hidden, unembed, targets, mesh=None):
+    """The mean next-token cross-entropy through `lm_head_cross_entropy`.
+    Under an `expert` axis each device takes the chunks of its own
+    sequences against the head gathered whole (float32, once a step), and
+    the head's gradient is summed over the axis in float32: left to the
+    partitioner the scan over chunks runs every chunk on every device and
+    all-reduces its logits, 0.4 GB a chunk at a vocabulary of 98,304
+    (`mistral7b.fsdp4`'s S4, which stays as it is)."""
+    axis = mesh_lib.expert_axis(mesh)
+    if axis is None:
+        return lm_head_cross_entropy(hidden, unembed, targets)[0]
+
+    def own_chunks(hidden, unembed, targets):
+        loss, count = lm_head_cross_entropy(hidden, unembed, targets)
+        return jax.lax.psum(loss * count, axis) / jax.lax.psum(count, axis)
+
+    return jax.shard_map(
+        own_chunks, mesh=mesh, in_specs=(P(axis), P(), P(axis)),
+        out_specs=P(), check_vma=False)(hidden, unembed, targets)
+
+
 def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     """(loss, readings). Next-token CE; batch: {'tokens': [B, T+1] or
     ('tokens','targets')}.
@@ -1859,8 +1989,8 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
         tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     hidden, readings = _hidden_and_readings(params, tokens, cfg, **kw)
     with jax.named_scope("lm_head_ce"):
-        loss, _ = lm_head_cross_entropy(
-            hidden, _unembed(params, cfg), targets)
+        loss = _head_loss(hidden, _unembed(params, cfg), targets,
+                          kw.get("mesh"))
     if readings is None:
         return loss, {}
     if "index_loss" in readings:  # the indexers' own loss, at coefficient 1
@@ -1876,6 +2006,10 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     over_layers = jnp.sum if cfg.seq_aux else jnp.mean
     readings = dict(readings, aux_loss=over_layers(readings["aux_loss"]),
                     z_loss=readings["z_loss"].mean())
+    if "chip_load" in readings:  # [L, n]: the fullest device over the mean
+        load = readings["chip_load"].astype(jnp.float32)
+        readings["chip_load_max_over_mean"] = (
+            load.max(axis=-1) / load.mean(axis=-1))
     if cfg.router_aux_loss_coef or cfg.router_z_loss_coef:
         loss = (loss + cfg.router_aux_loss_coef * readings["aux_loss"]
                 + cfg.router_z_loss_coef * readings["z_loss"])
@@ -1938,6 +2072,38 @@ def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
     return widths, params
 
 
+def _on_an_expert_axis(cfg: TransformerConfig, ways: int) -> TransformerConfig:
+    """`cfg` as one device of an `expert` axis of `ways` devices runs it: its
+    routed layers hold `n_experts / ways` experts, so they are a share's
+    (one operation that makes no names and holds no whole layer's rows)."""
+    if ways == 1 or not cfg.n_experts:
+        return cfg
+    return dataclasses.replace(
+        cfg, experts_held=(0, cfg.n_experts // ways))
+
+
+def _exchange_bytes(cfg: TransformerConfig, tokens: int, ways: int) -> int:
+    """What a routed layer's exchange holds on a device of an `expert` axis
+    of `ways` devices, `tokens` tokens each, beside what a share's layer
+    holds: the gathered rows of all the axis, their partial results (or, in
+    the backward, their gradient) summed in float32 and the pass's own in
+    float32, and the held rows' buffers of `held_chunk` rows: two of the
+    stream's width and the feed-forward's products. Against the compiler's
+    plan for a described v5e (Mellum2's widths, 16,384 tokens a device over
+    four, PR 50): 2.24 GB between buffers of 32,768 and of 180,224 rows,
+    15.2 kB a row, for 14.6 here."""
+    if ways == 1 or not cfg.n_routed_layers:
+        return 0
+    item, d = _item(cfg), cfg.d_model
+    rows = ways * tokens
+    chunk = moe.held_chunk(
+        rows * cfg.experts_per_token, cfg.n_experts // ways, cfg.n_experts,
+        load_held_even=cfg.expert_bias,
+        sequences=ways * max(1, tokens // cfg.max_seq_len))
+    return (rows * d * (item + 2 * 4)
+            + chunk * (2 * d + cfg.ff_matrices * cfg.ff_dim) * item)
+
+
 def _saved_bytes(cfg: TransformerConfig, tokens: int) -> Dict[str, int]:
     """Bytes a device holds of each named activation over all the layers
     that make it, for `tokens` tokens on the device, in `_SAVE_ORDER`."""
@@ -1957,11 +2123,13 @@ def _whole_param_bytes(cfg: TransformerConfig) -> int:
 
 
 def _working_set_bytes(cfg: TransformerConfig, tokens: int,
-                       param_bytes: int) -> int:
+                       param_bytes: int, expert_ways: int = 1) -> int:
     """What the step that keeps nothing holds on a device beside its state
     and the gradients when it is fullest, for `tokens` tokens on the device
     and `param_bytes` of parameters there: the blocks' inputs, and the
-    larger of the head's chunk and one block in its backward.
+    larger of the head's chunk and one block in its backward. On an
+    `expert` axis of `expert_ways` devices a routed block also holds its
+    exchange (`_exchange_bytes`), and the head is gathered whole in float32.
 
     A block in its backward is taken as every value of its widest layer at
     once: the stream's cotangent and, of each of its sublayers, the normed
@@ -1986,6 +2154,8 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     item = _item(cfg)
     whole = _whole_param_bytes(cfg)
     sharded = param_bytes < whole
+    exchange = _exchange_bytes(cfg, tokens, expert_ways)
+    cfg = _on_an_expert_axis(cfg, expert_ways)
     block = 0
     for kind in set(cfg.layers):
         sublayers = _sublayers(kind)
@@ -1993,8 +2163,11 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
         width = (sum(widths.values()) + (len(sublayers) + 1) * d
                  + sum(sub.holds(cfg) for sub in sublayers))
         weights = params * item + (params * (item + 4) if sharded else 0)
-        block = max(block, tokens * width * item + weights)
+        block = max(block, tokens * width * item + weights
+                    + (exchange if kind.routed else 0))
     unembed = cfg.vocab_size * d * item * param_bytes // whole
+    if expert_ways > 1:  # whole: float32, its cast, its float32 gradient
+        unembed = cfg.vocab_size * d * (4 + item + 4)
     head = (_HEAD_CHUNK * cfg.vocab_size * (4 + 4 + item) + unembed
             + tokens * d * item)
     boundaries = (cfg.n_layers + 1) * tokens * d * item
@@ -2003,7 +2176,8 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
 
 def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
                       resident_bytes: int, param_bytes: int,
-                      limit_bytes: Optional[int]) -> Dict[str, int]:
+                      limit_bytes: Optional[int],
+                      expert_ways: int = 1) -> Dict[str, int]:
     """{name: bytes on a device} of the activations a rematerialised block
     keeps beside its input: the names of `_SAVE_ORDER`, in that order, for
     as long as they fit the device.
@@ -2014,16 +2188,20 @@ def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
     state on the device), less the gradients (`param_bytes` again: the
     parameters' bytes on the device), less `_working_set_bytes`, less
     `_SAVE_RESERVE`. A name's bytes are its width times
-    `tokens_per_device` times the layers that make it. The first name that
+    `tokens_per_device` times the layers that make it; on an `expert` axis
+    of `expert_ways` devices the routed layers are a device's share of them.
+    The first name that
     does not fit ends the choice, so a larger limit only ever adds names.
     With no limit to read (the CPU, a described topology) or without
     `remat` nothing is kept, and the step is the one without a policy."""
     if limit_bytes is None or not cfg.remat:
         return {}
     room = (limit_bytes - resident_bytes - param_bytes - _SAVE_RESERVE
-            - _working_set_bytes(cfg, tokens_per_device, param_bytes))
+            - _working_set_bytes(cfg, tokens_per_device, param_bytes,
+                                 expert_ways))
     chosen: Dict[str, int] = {}
-    for name, size in _saved_bytes(cfg, tokens_per_device).items():
+    for name, size in _saved_bytes(
+            _on_an_expert_axis(cfg, expert_ways), tokens_per_device).items():
         if sum(chosen.values()) + size > room:
             break
         chosen[name] = size
@@ -2045,8 +2223,8 @@ def _memory_limit(mesh) -> Optional[int]:
 
 # what a routed model's step reports beside loss and grad_norm
 _STEP_READINGS = ("aux_loss", "z_loss", "expert_load", "held_slots",
-                  "dropped_slots", "index_loss", "index_keys_min_gap",
-                  "index_keys_max_gap")
+                  "dropped_slots", "chip_load", "chip_load_max_over_mean",
+                  "index_loss", "index_keys_min_gap", "index_keys_max_gap")
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
@@ -2073,7 +2251,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
 
     p_shard = param_shardings(mesh, cfg)
     names = mesh.axis_names
-    batch_axes = tuple(a for a in ("data", "fsdp") if a in names) or None
+    batch_axes = tuple(a for a in mesh_lib.BATCH_AXES if a in names) or None
     seq_ax = "sequence" if "sequence" in names else None
     tok_sharding = NamedSharding(mesh, P(batch_axes, seq_ax))
     repl = NamedSharding(mesh, P())
@@ -2128,7 +2306,8 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         tokens = math.prod(tok_sharding.shard_shape(batch["tokens"].shape))
         resident = on_a_device(state, state_shard)
         params = on_a_device(state["params"], p_shard)
-        saved = saved_activations(cfg, tokens, resident, params, limit)
+        saved = saved_activations(cfg, tokens, resident, params, limit,
+                                  mesh.shape.get("expert", 1))
         kept = "keeps every activation" if not cfg.remat else (
             "under remat keeps %s: %d bytes a device beside the blocks' "
             "inputs (%d tokens a device, state %d bytes, bytes_limit %s)" % (

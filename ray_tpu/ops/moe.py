@@ -126,9 +126,10 @@ class Slots(NamedTuple):
 def sort_slots(index, n_experts: int,
                held: Optional[Tuple[int, int]] = None) -> Slots:
     """Sort the slots of `index` [T, k] by expert; with `held = (first, n)`
-    by held expert, every other expert's slots behind them."""
+    by held expert, every other expert's slots behind them. `first` may be
+    a traced value: a device's place on an expert axis times `n`."""
     flat = index.reshape(-1).astype(jnp.int32)
-    if held is not None and tuple(held) != (0, n_experts):
+    if held is not None and held[1] != n_experts:
         first, n_experts = held
         local = flat - first
         flat = jnp.where(
@@ -185,7 +186,14 @@ def held_chunk(slots: int, held: int, n_experts: int,
     shares and a quarter, past two of the three, so that a step pays for
     its buffers once a layer and beyond that for its rows alone, 3.1 ms an
     even share; buffers past all three (four even shares) would need
-    0.4 GB that the cell's chip does not have (PERF.md section 6, PR 46)."""
+    0.4 GB that the cell's chip does not have (PERF.md section 6, PR 46).
+    A device of an `expert` mesh axis is a share for the tokens of all the
+    axis: `slots` are then the axis's and `sequences` the axis's too (four
+    of 16,384 tokens in `mellum2.ep4`: 524,288 slots, 16 of 64 experts
+    held, buffers of 180,224 rows for 131,072 expected, 2.2 GB more of the
+    compiler's plan than buffers of 32,768 that a step would walk four or
+    five times; the fullest chip read 1.28 to 1.49 even shares on the
+    comparison's four sequences of 2,048: PERF.md section 6, PR 50)."""
     if load_held_even:
         slack = _HELD_SLACK
     elif sequences == 1:

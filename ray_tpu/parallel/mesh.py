@@ -9,7 +9,9 @@ over ICI). Canonical axis names follow the scaling-book convention:
     fsdp      — data parallelism with sharded params/optimizer (ZeRO-3)
     tensor    — megatron-style tensor parallelism within attention/mlp
     sequence  — context parallelism (ring attention / all-to-all)
-    expert    — MoE expert parallelism
+    expert    — MoE expert parallelism: a routed layer's experts are cut
+                over it, whole experts a device; a batch axis, and where
+                the mesh has no fsdp axis the other parameters' as well
 
 Any subset may be present; size-1 axes are free, so one codepath serves
 single-chip through multi-pod.
@@ -25,6 +27,8 @@ import numpy as np
 
 DATA, FSDP, TENSOR, SEQUENCE, EXPERT = "data", "fsdp", "tensor", "sequence", "expert"
 CANONICAL_ORDER = (DATA, FSDP, EXPERT, SEQUENCE, TENSOR)
+# the axes a batch is split over, in the mesh's order
+BATCH_AXES = (DATA, FSDP, EXPERT)
 
 
 @dataclass
@@ -95,7 +99,7 @@ def make_mesh(
 def data_parallel_spec(mesh) -> "jax.sharding.PartitionSpec":  # noqa: F821
     from jax.sharding import PartitionSpec as P
 
-    batch_axes = [a for a in (DATA, FSDP) if a in mesh.axis_names]
+    batch_axes = [a for a in BATCH_AXES if a in mesh.axis_names]
     return P(tuple(batch_axes) if batch_axes else None)
 
 
@@ -123,9 +127,16 @@ class ShardingRules:
     rules: Dict[str, Optional[object]] = field(default_factory=dict)
 
     def spec(self, logical_axes: Sequence[Optional[str]]):
+        """A mesh axis names one dimension of an array: where two logical
+        axes of one array map to the same, the first keeps it (a routed
+        layer's `experts` take `expert`, so their `embed` stays whole)."""
         from jax.sharding import PartitionSpec as P
 
-        return P(*(self.rules.get(a) if a else None for a in logical_axes))
+        dims = []
+        for logical in logical_axes:
+            axes = self.rules.get(logical) if logical else None
+            dims.append(None if axes in dims else axes)
+        return P(*dims)
 
     def sharding(self, mesh, logical_axes: Sequence[Optional[str]]):
         from jax.sharding import NamedSharding
@@ -147,8 +158,9 @@ def default_transformer_rules(mesh) -> ShardingRules:
 
     return ShardingRules(
         {
-            "batch": ax(DATA, FSDP),
-            "embed": ax(FSDP),
+            "batch": ax(*BATCH_AXES),
+            # over `expert` only where it is the mesh's one sharded axis
+            "embed": ax(FSDP) or ax(EXPERT),
             "mlp": ax(TENSOR),
             "experts": ax(EXPERT),
             "heads": ax(TENSOR),
@@ -157,6 +169,22 @@ def default_transformer_rules(mesh) -> ShardingRules:
             "seq": ax(SEQUENCE),
         }
     )
+
+
+def expert_axis(mesh) -> Optional[str]:
+    """`expert` where `mesh` cuts a routed layer's experts over several
+    devices, else None. What runs under `shard_map` over that axis (the
+    routed layer's exchange, the head's chunks) is written for a mesh whose
+    other axes are single devices, so the axis's size is then the mesh's: a
+    second axis of several devices beside it is not mapped yet."""
+    if mesh is None or mesh.shape.get(EXPERT, 1) == 1:
+        return None
+    if mesh.size != mesh.shape[EXPERT]:
+        raise NotImplementedError(
+            f"a step over the mesh {dict(mesh.shape)}: an `{EXPERT}` axis of "
+            "several devices is mapped alone, not with a second axis of "
+            "several devices beside it")
+    return EXPERT
 
 
 def shard_pytree(tree, mesh, spec_fn):

@@ -52,6 +52,9 @@ NEMOTRON_SCOPES = {"mamba", "mamba_in", "mamba_conv", "ssd", "ssd_chunk",
 # on the chip (`index_scores` is the `jax.numpy` path's alone)
 KEYE_SCOPES = {"sparse_attention", "indexer", "index_qk", "index_scores",
                "index_select", "index_loss"}
+# the routed layer's exchange over an `expert` mesh axis, inside
+# `mlp/shard_map` beside `moe_router` (PR 50)
+EXCHANGE_SCOPES = {"moe_gather", "moe_scatter"}
 SPARSE_KERNELS = {"index_select", "index_loss", "flash_fwd_sparse",
                   "flash_bwd_dkv_dq_sparse"}
 # the scan's kernels, where its shapes tile and the operators are Pallas's;
@@ -121,6 +124,23 @@ def lowered_lfm2_step():
             router_z_loss_coef=0.0)
     finally:
         moe._ROW_TILE = row_tile
+
+
+def lowered_mellum_step():
+    """Window and full attention, every layer routed, over a mesh whose
+    `expert` axis has four devices: the routed layer's exchange."""
+    cfg = TransformerConfig(
+        vocab_size=VOCAB, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=16, max_seq_len=16, remat=True, attention_impl="xla",
+        tied_embeddings=False, n_experts=8, experts_per_token=2,
+        norm_topk_prob=True, sliding_window=4, router_z_loss_coef=0.0,
+        layer_types=("sliding_attention", "full_attention"))
+    mesh = make_mesh({"expert": 4}, devices=jax.devices()[:4])
+    init_state, step, shardings = make_train_step(cfg, mesh)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((4, 16), jnp.int32,
+                                  sharding=shardings["tokens"])
+    return step.lower(state, {"tokens": tokens, "targets": tokens})
 
 
 def lowered_dsv2_step():
@@ -275,12 +295,16 @@ FAMILIES = {
                    | {"moe_shared", "expert_bias"} | NEMOTRON_SCOPES),
     "keye_vl2": (lowered_keye_step,
                  TRANSFORMER_SCOPES | MOE_SCOPES | KEYE_SCOPES),
+    "mellum": (lowered_mellum_step,
+               TRANSFORMER_SCOPES | (MOE_SCOPES - {"qk_norm"})
+               | EXCHANGE_SCOPES | {"sliding_attention"}),
     "resnet": (lowered_resnet_step, RESNET_SCOPES),
 }
 # the scopes that one family alone has, but for those that another family
 # has of them
 OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES,
-              "nemotron_h": NEMOTRON_SCOPES, "keye_vl2": KEYE_SCOPES}
+              "nemotron_h": NEMOTRON_SCOPES, "keye_vl2": KEYE_SCOPES,
+              "mellum": EXCHANGE_SCOPES}
 ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"}}
 
 
@@ -313,6 +337,20 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
                 stacks[family])
     if family == "transformer":  # the dense step names nothing of the routed
         assert not (MOE_SCOPES | MOE_KERNELS) & components(stacks[family])
+    elif family == "mellum":
+        # the exchange beside the router inside the layer's `shard_map`
+        # (whose body is lowered as a function of its own, so its stacks
+        # start at the scope): each way in the forward, and in the backward
+        # each as the other's transpose
+        found = stacks[family]
+        for stack in ("moe_gather/all_gather", "moe_scatter/reduce_scatter",
+                      "moe_scatter/all_gather", "moe_gather/reduce_scatter"):
+            assert stack in found, stack
+        assert not any("moe_router/moe_gather" in s for s in found)
+        assert any(s.startswith("while/body/moe_experts") for s in found)
+        assert any(s.startswith("mlp/shard_map") for s in found)
+        # the head takes its own chunks under its own `shard_map`
+        assert any("lm_head_ce" in s and "shard_map" in s for s in found)
     elif family == "lfm2_moe":
         # the convolution's three parts inside `short_conv`, in the scan's
         # body forward, made again, and backward (under `checkpoint`)

@@ -292,8 +292,9 @@ def test_tokens_on_a_device_follow_the_mesh(monkeypatch):
     the mesh's batch axes."""
     seen = {}
 
-    def rule(cfg, tokens, resident, params, limit):
-        seen.update(tokens=tokens, resident=resident, params=params)
+    def rule(cfg, tokens, resident, params, limit, expert_ways):
+        seen.update(tokens=tokens, resident=resident, params=params,
+                    expert_ways=expert_ways)
         return {}
 
     monkeypatch.setattr(tr, "saved_activations", rule)
@@ -304,6 +305,7 @@ def test_tokens_on_a_device_follow_the_mesh(monkeypatch):
     tokens = jax.ShapeDtypeStruct((8, 16), jnp.int32)
     step.lower(state, {"tokens": tokens, "targets": tokens})
     assert seen["tokens"] == 8 * 16 // 4
+    assert seen["expert_ways"] == 1  # the size of the mesh's `expert` axis
     whole = sum(math.prod(x.shape) * x.dtype.itemsize
                 for x in jax.tree.leaves(state["params"]))
     # the matrices are cut four ways, the norms' scales are whole

@@ -15,7 +15,9 @@ imbalance, and the group sizes always sum to `T x k`.
     load_balancing_loss, sequence_balancing_loss
                                      over the batch, or per sequence
     combine(ys, weights, inverse)    [T k, d] -> [T, d]
-    combine_held(ys, weights, order, rows)   the same from a share's rows
+    combine_held(ys, weights, slots, rows)   the same from a share's rows
+    sum_held(yss, inverse, group_sizes, rows, T)
+                                     a share's rows summed onto their tokens
     project_and_combine(hidden, w_down, weights, slots)
                                      the last two as one operation
     experts_of_share(tokens, w_gate, w_up, w_down, weights, slots)
@@ -55,10 +57,38 @@ of an expert-parallel deployment does) still routes over all `E`:
 in a tail behind them, `group_sizes` is `[n]` and sums to the held rows, and
 the kernels walk those rows only. Rows past them are never written and hold
 whatever the buffer held, so whoever brings rows back to their tokens is
-given the count of held rows (`rows`) and *selects* with it (a scatter-add
-that drops the others, a gather of scalars that selects): nothing is
-multiplied by a zero. `experts_of_share` is that layer: it walks the held rows in
-buffers of `held_chunk` rows, as many as the step's routing fills.
+given the count of held rows (`rows`) and *selects* with it (`moe_sum`
+zeroes a window's rows behind them before any product, the scatter-add
+drops them, a gather of scalars selects): nothing is multiplied by a zero.
+`experts_of_share` is that layer: it walks the held rows in buffers of
+`held_chunk` rows, as many as the step's routing fills.
+
+The sum of a share's rows onto their tokens (the combine's, each row times
+its slot's float32 weight, and the dispatch's gradient, the rows as they
+are) is a third kernel on a TPU, `moe_sum`, and `_to_tokens`' scatter-add
+anywhere else. A stable sort leaves each held expert's rows in token order,
+so a tile of 256 (or 128) tokens owns one run of rows an expert. A grid
+step is one tile: the runs' rows are copied from where they lie in HBM, in
+the rows' own dtype, by DMA windows of 32 rows, as many a run as it is long
+and all of a tile's in one buffer (a tile with more windows than the buffer
+holds takes a second round of them, and so on: nothing is dropped, and what
+a tile costs follows its rows however unevenly its experts share them), a
+matrix of the tile's tokens by the buffer's rows is built that holds a 1,
+or the slot's weight, where a row is a token's, and its product with the
+rows on the MXU is the tile's sum, accumulated in f32 and written once (or
+added into the float32 sum the layer's loop carries, in its buffer). A
+float32 weight meets bf16 rows as three bf16 parts that sum to it exactly, a
+pass each, so every product is exact and only the order of the adds differs
+from the scatter-add's. Where each run begins is computed outside from
+`inverse` and the group sizes and handed over by scalar prefetch. Its callers
+enter it through one jitted function (`sum_held`), so that a program traces
+and lowers the kernel once for each distinct use and not once for each of its
+call sites, which set-up pays for in host seconds. What the buffers'
+slack still costs a chunk after this: the gathers by `order` (the rows into
+expert order, `dy` and the weights in the backward), SwiGLU's passes and
+the casts between the grouped matmuls, which all run over the buffer's
+rows, held or not; `moe_sum` reads the runs' windows and `moe_gmm` the held
+rows' tiles alone.
 """
 
 from __future__ import annotations
@@ -163,9 +193,13 @@ def held_chunk(slots: int, held: int, n_experts: int,
     slack, in whole row tiles. A static length cannot follow the load, so
     `experts_of_share` walks the held rows a chunk of this length at a
     time, as many chunks as the step's routing fills: one, near balance.
-    Every pass over a chunk's buffers costs what the buffers hold, not the
-    rows in them, so the slack is paid in every step and a second chunk is
-    a whole pass more. Where a selection bias holds the load even
+    Every `jax.numpy` pass over a chunk's buffers (the gathers by `order`,
+    SwiGLU, the casts) costs what the buffers hold, not the rows in them;
+    the kernels (`moe_gmm`, `moe_tgmm`, and since PR 52 the sum onto the
+    tokens, `moe_sum`) walk the held rows alone. So the slack is paid in
+    every step and a second chunk is a whole pass more, by those passes
+    (the timings below are from before `moe_sum`, when the sum's five
+    passes paid for the buffer too). Where a selection bias holds the load even
     (`load_held_even`) the slack is a quarter: over 96 steps of four seeds
     `lfm2moe.tokens8k`'s held rows stayed within 5 % of even (PERF.md
     section 6, PR 32). Where only a loss term balances the router it is
@@ -232,38 +266,69 @@ def _to_tokens(ys, order, rows, tokens: int, k: int):
         ys, mode="drop")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(x, order, inverse, rows, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inverse, k):
     return x[order // k]
 
 
-def _dispatch_fwd(x, order, inverse, rows, k):
-    # a share of the experts sums the rows' gradients by `order`
-    return x[order // k], (inverse, None if rows is None else order, rows)
+def _dispatch_fwd(x, order, inverse, k):
+    return x[order // k], inverse
 
 
-def _dispatch_bwd(k, res, dxs):
-    inverse, order, rows = res
-    if rows is None:
-        per_token = _by_token(dxs, inverse, k).astype(jnp.float32)
-        dx = per_token.sum(axis=1)
-    else:
-        dx = _to_tokens(dxs.astype(jnp.float32), order, rows,
-                        inverse.shape[0] // k, k)
-    return dx.astype(dxs.dtype), None, None, None
+def _dispatch_bwd(k, inverse, dxs):
+    per_token = _by_token(dxs, inverse, k).astype(jnp.float32)
+    return per_token.sum(axis=1).astype(dxs.dtype), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def dispatch(x, order, inverse, rows=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _dispatch_held(x, slots, rows, k, copies, kernels, interpret):
+    return (x[slots.order // k],) * copies
+
+
+def _dispatch_held_fwd(x, slots, rows, k, copies, kernels, interpret):
+    return (x[slots.order // k],) * copies, (slots, rows)
+
+
+def _dispatch_held_bwd(k, copies, kernels, interpret, res, dxs):
+    """A share of the experts sums the rows' gradients onto their tokens,
+    every copy's in one float32 sum."""
+    slots, rows = res
+    tokens = slots.inverse.shape[0] // k
+    if kernels:
+        dx = sum_held(dxs, slots.inverse, slots.group_sizes, rows, tokens,
+                      interpret=interpret)
+    else:
+        dx = _to_tokens(sum(d.astype(jnp.float32) for d in dxs), slots.order,
+                        rows, tokens, k).astype(dxs[0].dtype)
+    return dx, None, None
+
+
+_dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+def dispatch(x, order, inverse, rows=None, *, group_sizes=None,
+             copies: Optional[int] = None, impl: str = "auto",
+             interpret: bool = False):
     """Rows of `x` [T, d] copied to their slots in expert order: [T k, d],
     or as many rows as `order` was cut to. The gradient gathers by the
     inverse order and sums a token's k slots, where XLA's own transpose of
     the gather would scatter-add. With `rows` (a share of the experts: the
-    buffer is a fraction of the slots) it does scatter-add, the first `rows`
-    rows alone, and a token none of whose experts is held gets zero."""
-    return _dispatch(x, order, inverse, rows, inverse.shape[0] // x.shape[0])
+    buffer is a fraction of the slots) it sums the first `rows` rows alone
+    onto their tokens, and a token none of whose experts is held gets zero:
+    `moe_sum` where `grouped_matmul` takes its kernels and the share's
+    `group_sizes` are given, else a scatter-add. With `copies` the result
+    is that many names of the one buffer, and each one's gradient enters
+    the sum as it is, none added to another first."""
+    k = inverse.shape[0] // x.shape[0]
+    if rows is None:
+        return _dispatch(x, order, inverse, k)
+    kernels = group_sizes is not None and _kernels(impl, interpret)
+    xs = _dispatch_held(x, Slots(order, inverse, group_sizes), rows, k,
+                        copies or 1, kernels, interpret)
+    return xs if copies else xs[0]
 
 
 def combine(ys, weights, inverse):
@@ -275,14 +340,21 @@ def combine(ys, weights, inverse):
     return (picked * weights[..., None]).sum(axis=1).astype(ys.dtype)
 
 
-def combine_held(ys, weights, order, rows):
+def combine_held(ys, weights, slots: Slots, rows, *, kernels: bool = False,
+                 interpret: bool = False, onto=None):
     """`combine` for a share of the experts: `ys` [n, d] holds `rows` rows
-    that are some held expert's, in the order `order` [n] gives their
-    slots. Each such row times its slot's weight, added to its token in
-    float32; a token none of whose experts is held gets zero."""
+    that are some held expert's, where `slots` (the share's: `order` [n],
+    `inverse`, the held groups' sizes) says. Each such row times its slot's
+    weight, added to its token in float32; a token none of whose experts is
+    held gets zero. `moe_sum` with `kernels`, else a scatter-add. With
+    `onto` [T, d] float32 the sum goes on there and stays float32."""
     tokens, k = weights.shape
-    weighted = ys.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
-    return _to_tokens(weighted, order, rows, tokens, k).astype(ys.dtype)
+    if kernels:
+        return sum_held((ys,), slots.inverse, slots.group_sizes, rows, tokens,
+                        weights=weights, onto=onto, interpret=interpret)
+    weighted = ys.astype(jnp.float32) * weights.reshape(-1)[slots.order][:, None]
+    total = _to_tokens(weighted, slots.order, rows, tokens, k)
+    return total.astype(ys.dtype) if onto is None else onto + total
 
 
 def load_balancing_loss(probs, group_sizes):
@@ -728,17 +800,423 @@ def grouped_matmul(x, w, group_sizes, *, impl: str = "auto",
     return _product(x, w, group_sizes, False, None, False)
 
 
+# ----------------------------------------- a share's rows onto their tokens
+
+class SumTiles(NamedTuple):
+    """`moe_sum`'s tile for one shape and use: tokens of a grid step, rows of
+    one DMA window and windows of one round, the rows of the dtype's packed
+    tile (where a window may begin), and the parts a slot's weight is placed
+    in (three bf16 values that sum to it beside bf16 rows, else the weight
+    or the 1 itself)."""
+    tt: int
+    window: int
+    windows: int
+    align: int
+    parts: int
+    vmem_limit_bytes: int
+
+
+_TOKEN_TILES = (256, 128)
+# microseconds of `moe_sum` on one v5e (PERF.md section 6, PR 51: 180,224
+# rows of 16 experts onto 65,536 tokens of 2304, and two smaller shapes): a
+# grid step's own, a window's of one buffer (its DMA, its walk, its piece of
+# the matrix), and what the MXU takes for a million multiply-adds and the
+# DMAs for a million bytes. A call read within a tenth of steps + windows +
+# max(products, bytes) at every shape and use but one (a round short of the
+# windows a tile of `lagunaxs2.tokens8k`'s 32 experts needs, since mended).
+_SUM_COST_US = (2.5, 0.1, 1e6 / 98.5e6, 1e6 / 819e3)
+
+
+def _sum_vmem(tt, rows, d, itemsize, out_itemsize, parts, sources, onto) -> int:
+    """Two slots of a round's windows (`rows` rows) for each source, the 0/1
+    (or weight) matrices, the f32 sum with a dot's result beside it, and
+    the pipeline's two blocks of the tokens (and of `onto`)."""
+    windows = 2 * sources * rows * d * itemsize
+    placing = parts * rows * tt * min(itemsize, 4)
+    sums = 2 * tt * d * 4
+    blocks = 2 * tt * d * (out_itemsize + (4 if onto else 0))
+    return windows + placing + sums + blocks
+
+
+def sum_tiles(rows: int, tokens: int, d: int, n_held: int, dtype, *,
+              out_dtype=None, weighted: bool = True, sources: int = 1,
+              onto: bool = False, tt: Optional[int] = None) -> SumTiles:
+    """The tile of `moe_sum` over `sources` buffers of `rows` rows [rows, d]
+    in `dtype`, of `n_held` experts, onto `tokens` tokens. Pure: the shape
+    and the use decide. A window is two packed tiles of rows (32 in bf16):
+    a run takes as many as it is long, so what a tile of tokens costs
+    follows its rows, however unevenly its experts share them. A round is
+    as many windows as the buffer's even part of a tile takes, each run's
+    first and last window half empty, and no fewer than one a held expert
+    and an eighth more (a run of a few rows still takes a window, now and
+    then two): a tile whose routing fills the buffer no further takes one
+    round, a fuller one a second. The 0/1 matrix is
+    [tokens of a tile, rows of a round], so the MXU's work grows with the
+    tile while the rows it places do not, and the windows' padding and the
+    grid's steps grow as the tile shrinks: of 256 and 128 tokens a step the
+    one that costs less by `_SUM_COST_US` and fits VMEM (the weighted sum
+    of bf16 rows, three passes of the MXU, takes 128 at `mellum2.ep4`'s
+    shape and the rows' gradient 256), all the tokens where there are no
+    more than 256. A forced `tt` is taken as given, for tests."""
+    itemsize = jnp.dtype(dtype).itemsize
+    out_itemsize = jnp.dtype(out_dtype or dtype).itemsize
+    align = 32 // itemsize
+    window = 2 * align
+    parts = 3 if weighted and dtype == jnp.bfloat16 else 1
+    step_us, window_us, product_us, byte_us = _SUM_COST_US
+
+    def plan(tt):
+        tiles = _cdiv(tokens, tt)
+        windows = max(
+            _cdiv(_cdiv(rows, tiles) + n_held * (align + window) // 2, window),
+            n_held + _cdiv(n_held, 8))
+        held_rows = windows * window
+        vmem = _sum_vmem(tt, held_rows, d, itemsize, out_itemsize, parts,
+                         sources, onto)
+        cost = tiles * (step_us + sources * (
+            windows * window_us + held_rows * d * max(
+                parts * tt * product_us, itemsize * byte_us) / 1e6))
+        return cost, SumTiles(tt, window, windows, align, parts,
+                              max(_DEFAULT_VMEM, 2 * vmem))
+
+    if tt is not None or tokens <= _TOKEN_TILES[0]:
+        return plan(tt or tokens)[1]
+    plans = [plan(tt) for tt in _TOKEN_TILES]
+    fitting = [p for p in plans if p[1].vmem_limit_bytes <= _MAX_VMEM]
+    return min(fitting or plans[-1:], key=lambda p: p[0])[1]
+
+
+def _sum_plan(inverse, group_sizes, rows, n: int, tokens: int,
+              tiles: SumTiles, weights=None):
+    """What `moe_sum` is handed about where the rows lie. `place` [n_held,
+    T]: the row that holds token t's slot with held expert g, -1 where it
+    has none among the first `rows` (a token names an expert once, so there
+    is one at most); with `weights` [T, k], [parts, n_held, T]: that slot's
+    float32 weight, whole or as three bf16 values that sum to it. And as
+    scalars, for every tile of tokens and held expert: the first row of the
+    run's first window (a sorted group holds its rows in token order, so a
+    tile's rows with one expert are one run) and how many windows of the
+    tile stand before the run's; for every tile its windows, the rounds it
+    takes, and the rounds before it."""
+    tt, window, align = tiles.tt, tiles.window, tiles.align
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    at = inverse.reshape(tokens, -1).T[None]                     # [1, k, T]
+    mine = jnp.logical_and(
+        jnp.logical_and(at >= starts[:, None, None], at < ends[:, None, None]),
+        at < rows)                                               # [g, k, T]
+    place = jnp.where(mine, at, -1).max(axis=1)
+    pad = (-tokens) % tt
+    place = jnp.pad(place, ((0, 0), (0, pad)), constant_values=-1)
+    by_tile = place.reshape(place.shape[0], -1, tt)
+    end = by_tile.max(axis=2) + 1                                # [g, tiles]
+    held = end > 0
+    first = jnp.where(by_tile >= 0, by_tile, n).min(axis=2)
+    # counts of rows and windows, none negative: `lax.div` is the floor, and
+    # traces and lowers as one operation where `//` is a dozen
+    first = jnp.where(
+        held, jnp.minimum(jax.lax.div(first, align) * align, n - window), 0)
+    windows = jnp.where(
+        held, jax.lax.div(end - first + (window - 1), window), 0).T
+    before = jnp.cumsum(windows, axis=1)                         # [tiles, g]
+    total = before[:, -1]
+    rounds = jnp.maximum(
+        jax.lax.div(total + (tiles.windows - 1), tiles.windows), 1)
+    scalars = (first.T.reshape(-1), (before - windows).reshape(-1), total,
+               rounds, jnp.cumsum(rounds) - rounds,
+               jnp.asarray(rows, jnp.int32).reshape(1))
+    if weights is None:
+        return scalars, place, None
+    w = jnp.where(mine, weights.astype(jnp.float32).T[None], 0.0).sum(axis=1)
+    if tiles.parts == 3:
+        # `reduce_precision` and not a cast to bf16 and back, which the
+        # TPU's compiler may take out as excess precision it is allowed
+        high = jax.lax.reduce_precision(w, 8, 7)
+        mid = jax.lax.reduce_precision(w - high, 8, 7)
+        w = jnp.stack([high, mid, w - high - mid])
+    else:
+        w = w[None]
+    return scalars, place, jnp.pad(w, ((0, 0), (0, 0), (0, pad)))
+
+
+def _sum_kernel(first, before, total, rounds, rounds_before, rows,  # scalars
+                place_ref, *refs, n, n_held, window, windows, align, sources,
+                weighted, onto):
+    """One tile of tokens: round after round, the windows of its runs copied
+    from where the rows lie into one buffer, a matrix that has a 1 (or the
+    slot's weight) where a row is a token's, and their product on the MXU
+    added up in f32. The next round's windows, of this tile or of the next,
+    are on their way while this round's are used."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    refs = list(refs)
+    parts_ref = refs.pop(0) if weighted else None
+    ys_refs = [refs.pop(0) for _ in range(sources)]
+    onto_ref = refs.pop(0) if onto else None
+    o_ref = refs.pop(0)
+    bufs = [refs.pop(0) for _ in range(sources)]
+    placing_ref, acc_ref, sems = refs
+    tile, tiles = pl.program_id(0), pl.num_programs(0)
+    tt = place_ref.shape[1]
+    # bf16 rows meet bf16 parts of a weight in one exact pass each; wider
+    # rows are multiplied as float32
+    narrow = placing_ref.dtype == jnp.bfloat16
+
+    def every_window(tile, round_, body):
+        """`body(b, g, lies, begin, there)` for the round's windows in
+        order, b the window's place in the buffer: its expert, where its
+        rows lie, where it would begin were the buffer longer, and whether
+        the tile has that many windows. The expert is found by walking on
+        from the window before's."""
+        base = tile * n_held
+
+        def step(b, g):
+            w = round_ * windows + b
+            there = w < total[tile]
+            g = jax.lax.while_loop(
+                lambda g: jnp.logical_and(
+                    g + 1 < n_held, before[base + jnp.minimum(
+                        g + 1, n_held - 1)] <= w),
+                lambda g: g + 1, g)
+            begin = jnp.where(
+                there, first[base + g] + (w - before[base + g]) * window, n)
+            lies = pl.multiple_of(jnp.minimum(begin, n - window), align)
+            body(b, g, lies, begin, there)
+            return g
+
+        jax.lax.fori_loop(0, windows, step, 0)
+
+    def piece_of(b):
+        return pl.ds(pl.multiple_of(b * window, align), window)
+
+    def copies(b, lies, slot):
+        return [pltpu.make_async_copy(
+            ys.at[pl.ds(lies, window), :], buf.at[slot, piece_of(b), :],
+            sems.at[slot, i]) for i, (ys, buf) in enumerate(zip(ys_refs, bufs))]
+
+    def start(tile, round_, slot):
+        def of(b, g, lies, begin, there):
+            @pl.when(there)
+            def _():
+                for copy in copies(b, lies, slot):
+                    copy.start()
+
+        every_window(tile, round_, of)
+
+    def sum_round(round_, slot):
+        def place(b, g, lies, begin, there):
+            piece = piece_of(b)
+
+            @pl.when(there)
+            def _():
+                for copy in copies(b, lies, slot):
+                    copy.wait()
+
+            # what a window holds that is no held row is selected away, not
+            # multiplied by a zero: a place no window takes holds what the
+            # slot held, and rows behind `rows` hold anything at all
+            @pl.when(jnp.logical_not(there))
+            def _():
+                for buf in bufs:
+                    buf[slot, piece, :] = jnp.zeros((window, buf.shape[2]),
+                                                    buf.dtype)
+
+            @pl.when(jnp.logical_and(there, lies + window > rows[0]))
+            def _():
+                live = lies + jax.lax.broadcasted_iota(
+                    jnp.int32, (window, 1), 0) < rows[0]
+                for buf in bufs:
+                    rows_ = buf[slot, piece, :]
+                    buf[slot, piece, :] = jnp.where(
+                        live, rows_, jnp.zeros_like(rows_))
+
+            token = place_ref[pl.ds(g, 1), :]                    # [1, tt]
+            hit = jnp.logical_and(
+                token - lies == jax.lax.broadcasted_iota(
+                    jnp.int32, (window, tt), 0),
+                token >= begin)
+            for part in range(placing_ref.shape[0]):
+                placing_ref[part, piece, :] = jnp.where(
+                    hit, parts_ref[part, pl.ds(g, 1), :] if weighted else 1.0,
+                    0.0).astype(placing_ref.dtype)
+
+        every_window(tile, round_, place)
+
+        total_ = None
+        for buf in bufs:
+            held_rows = buf[slot].astype(placing_ref.dtype)
+            for part in range(placing_ref.shape[0]):
+                product = jax.lax.dot_general(
+                    placing_ref[part], held_rows, _TN, precision=(
+                        jax.lax.Precision.DEFAULT if narrow
+                        else jax.lax.Precision.HIGHEST),
+                    preferred_element_type=jnp.float32)
+                total_ = product if total_ is None else total_ + product
+
+        @pl.when(round_ == 0)
+        def _():
+            acc_ref[...] = total_
+
+        @pl.when(round_ > 0)
+        def _():
+            acc_ref[...] += total_
+
+    def one_round(round_, carry):
+        slot = (rounds_before[tile] + round_) % 2
+        more = round_ + 1 < rounds[tile]
+
+        # the round after this one, the tile's or the next tile's first: the
+        # walk over a round's windows is written, traced and lowered once
+        # for every start and once for the placing
+        @pl.when(jnp.logical_or(more, tile + 1 < tiles))
+        def _():
+            start(jnp.where(more, tile, tile + 1),
+                  jnp.where(more, round_ + 1, 0), 1 - slot)
+
+        @pl.when(round_ >= 0)
+        def _():
+            sum_round(round_, slot)
+
+        return carry
+
+    # nobody before the first tile starts its first round's windows: it
+    # takes a round of its own for that, -1, which sums nothing
+    jax.lax.fori_loop(jnp.where(tile == 0, -1, 0), rounds[tile], one_round, 0)
+    summed = acc_ref[...]
+    if onto:
+        summed = summed + onto_ref[...]
+    o_ref[...] = summed.astype(o_ref.dtype)
+
+
+def sum_held(yss, inverse, group_sizes, rows, tokens: int, *, weights=None,
+             onto=None, out_dtype=None, tiles: Optional[SumTiles] = None,
+             interpret: bool = False):
+    """`moe_sum`: the first `rows` rows of each buffer of `yss` ([n, d]
+    each, in expert order: the rows of `group_sizes`' held experts, each
+    group in token order as `sort_slots` leaves them) added up by the token
+    they belong to, [tokens, d] in `out_dtype` (the rows' by default):
+    `_to_tokens` of their sum, read where they lie. `inverse` [tokens k] is
+    where each slot stands in the buffers. With `weights` [tokens, k]
+    (float32) each row times its slot's weight; with `onto` [tokens, d]
+    float32 the sum is added to it, in its buffer. Every product and sum is
+    float32.
+
+    Entered through one jitted function, `_sum_held`: a Pallas call site
+    costs a program's set-up its kernel traced to a jaxpr and lowered to
+    Mosaic (this one 0.15 s on a sandbox's CPU, 0.3 to 0.5 on the chip's
+    host), whether the executable then comes from the compile cache or not,
+    and a step holds a site a layer, forward and backward, the comparison's
+    programs as many again. Under the `jit` a process traces the kernel
+    once for each distinct use and shape and a program lowers it once for
+    each (PERF.md section 5); the inner call is inlined, so the device's
+    program is the same. The abstract mesh is handed over as it stands
+    because `jit`'s cache of traces keys on the tracing context, and JAX
+    traces a `custom_vjp`'s forward rule under an explicitly empty mesh
+    where the primal function's is unset: one context spelt two ways, and
+    without this the forward's use is traced twice a program."""
+    with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+        return _sum_held(tuple(yss), inverse, group_sizes, rows, tokens,
+                         weights=weights, onto=onto, out_dtype=out_dtype,
+                         tiles=tiles, interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("tokens", "out_dtype", "tiles", "interpret"))
+def _sum_held(yss, inverse, group_sizes, rows, tokens, *, weights, onto,
+              out_dtype, tiles, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = yss[0].shape
+    dtype = yss[0].dtype
+    n_held = group_sizes.shape[0]
+    out_dtype = jnp.dtype(jnp.float32 if onto is not None
+                          else out_dtype or dtype)
+    weighted = weights is not None
+    if tiles is None:
+        tiles = sum_tiles(n, tokens, d, n_held, dtype, out_dtype=out_dtype,
+                          weighted=weighted, sources=len(yss),
+                          onto=onto is not None)
+    tt, window, windows, align, parts, vmem_limit = tiles
+    short = max(window, _cdiv(n, align) * align) - n
+    if short:  # a buffer under a window, or of no whole packed tiles
+        yss = tuple(jnp.pad(ys, ((0, short), (0, 0))) for ys in yss)
+    padded = _cdiv(tokens, tt) * tt
+    scalars, place, w_parts = _sum_plan(
+        inverse, group_sizes, rows, n + short, tokens, tiles, weights)
+
+    def of_tile(tile, *scalars):
+        return 0, tile
+
+    def of_tile_3(tile, *scalars):
+        return 0, 0, tile
+
+    def rows_of_tile(tile, *scalars):
+        return tile, 0
+
+    operands = [place]
+    in_specs = [pl.BlockSpec((n_held, tt), of_tile)]
+    if weighted:
+        operands.append(w_parts)
+        in_specs.append(pl.BlockSpec((parts, n_held, tt), of_tile_3))
+    operands.extend(yss)
+    in_specs.extend([pl.BlockSpec(memory_space=pl.ANY)] * len(yss))
+    aliases = {}
+    if onto is not None:
+        if padded != tokens:
+            onto = jnp.pad(onto, ((0, padded - tokens), (0, 0)))
+        aliases = {len(scalars) + len(operands): 0}
+        operands.append(onto)
+        in_specs.append(pl.BlockSpec((tt, d), rows_of_tile))
+    held_rows = windows * window
+    out = pl.pallas_call(
+        functools.partial(
+            _sum_kernel, n=n + short, n_held=n_held, window=window,
+            windows=windows, align=align, sources=len(yss),
+            weighted=weighted, onto=onto is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(padded // tt,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((tt, d), rows_of_tile),
+            scratch_shapes=(
+                [pltpu.VMEM((2, held_rows, d), dtype) for _ in yss]
+                + [pltpu.VMEM((parts, held_rows, tt),
+                              dtype if dtype == jnp.bfloat16 else jnp.float32),
+                   pltpu.VMEM((tt, d), jnp.float32),
+                   pltpu.SemaphoreType.DMA((2, len(yss)))]),
+        ),
+        out_shape=jax.ShapeDtypeStruct((padded, d), out_dtype),
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+        name="moe_sum",
+    )(*scalars, *operands)
+    return out[:tokens] if padded != tokens else out
+
+
 # ------------------------------------- the down projection back to tokens
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _project_and_combine(hidden, w_down, weights, slots, rows, kernels, tm,
-                         interpret):
+def _down_and_combine(hidden, w_down, weights, slots, rows, kernels, tm,
+                      interpret, onto=None):
     with jax.named_scope("moe_experts"):
         ys = _product(hidden, w_down, slots.group_sizes, kernels, tm, interpret)
     with jax.named_scope("moe_combine"):
         if rows is None:
             return combine(ys, weights, slots.inverse)
-        return combine_held(ys, weights, slots.order, rows)
+        return combine_held(ys, weights, slots, rows, kernels=kernels,
+                            interpret=interpret, onto=onto)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _project_and_combine(hidden, w_down, weights, slots, rows, kernels, tm,
+                         interpret):
+    return _down_and_combine(hidden, w_down, weights, slots, rows, kernels, tm,
+                             interpret)
 
 
 def _project_and_combine_fwd(hidden, w_down, weights, slots, rows, kernels,
@@ -816,22 +1294,19 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def _experts_on_chunk(i, tokens, w_gate, w_up, w_down, weights, slots, order,
-                      chunk, impl):
-    """One chunk of the held rows through its experts and back to the
-    tokens, [T, d]: today's layer on buffers of `chunk` rows. Without a
-    `w_gate` the experts are ungated, `relu2` between the two products."""
-    part = _chunk_of(slots, order, i, chunk)
-    rows = part.group_sizes.sum()
+def _hidden_of_chunk(tokens, w_gate, w_up, part: Slots, rows, impl):
+    """A chunk's held rows (`part`, the first `rows` of its buffers) through
+    their experts' gate and up products: [chunk, f]. Without a `w_gate` the
+    experts are ungated, `relu2` after the one product."""
     with jax.named_scope("moe_dispatch"):
-        xs = dispatch(tokens, part.order, part.inverse, rows)
+        xs = dispatch(tokens, part.order, part.inverse, rows, impl=impl,
+                      group_sizes=part.group_sizes,
+                      copies=1 if w_gate is None else 2)
     with jax.named_scope("moe_experts"):
         gmm = functools.partial(
             grouped_matmul, group_sizes=part.group_sizes, impl=impl, tail=True)
-        hidden = (relu2(gmm(xs, w_up)) if w_gate is None
-                  else jax.nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up))
-    return project_and_combine(hidden, w_down, weights, part, impl=impl,
-                               rows=rows)
+        return (relu2(gmm(xs[0], w_up)) if w_gate is None
+                else jax.nn.silu(gmm(xs[0], w_gate)) * gmm(xs[1], w_up))
 
 
 def _chunks(slots: Slots, chunk: int):
@@ -846,9 +1321,13 @@ def _experts_of_share(tokens, w_gate, w_up, w_down, weights, slots, chunk,
     order, n_chunks = _chunks(slots, chunk)
 
     def add(i, out):
-        return out + _experts_on_chunk(
-            i, tokens, w_gate, w_up, w_down, weights, slots, order, chunk,
-            impl).astype(jnp.float32)
+        """Chunk `i` through its experts and onto the tokens' float32 sum,
+        in its buffer."""
+        part = _chunk_of(slots, order, i, chunk)
+        rows = part.group_sizes.sum()
+        hidden = _hidden_of_chunk(tokens, w_gate, w_up, part, rows, impl)
+        return _down_and_combine(hidden, w_down, weights, part, rows,
+                                 _kernels(impl, False), None, False, out)
 
     out = jax.lax.fori_loop(
         0, n_chunks, add, jnp.zeros(tokens.shape, jnp.float32))
@@ -863,26 +1342,35 @@ def _experts_of_share_fwd(tokens, w_gate, w_up, w_down, weights, slots, chunk,
 
 
 def _experts_of_share_bwd(chunk, impl, res, dout):
-    """A chunk at a time as the forward: the chunk's rows made again and
-    pulled back (the pieces' own backward rules), the gradients summed in
-    float32. A loop whose length the routing sets has no transpose of its
-    own, which is why the layer is one operation."""
-    *args, slots = res
+    """A chunk at a time as the forward: the chunk's `hidden` made again,
+    the down projection and the sum pulled back by `project_and_combine`'s
+    own rule (which needs `hidden` and never the rows: no second forward of
+    the two is traced, lowered or run), then the gate and up products and
+    the dispatch, the gradients summed in float32. A loop whose length the
+    routing sets has no transpose of its own, which is why the layer is one
+    operation."""
+    tokens, w_gate, w_up, w_down, weights, slots = res
     order, n_chunks = _chunks(slots, chunk)
 
     def add(i, grads):
-        _, pull = jax.vjp(
-            lambda *a: _experts_on_chunk(i, *a, slots, order, chunk, impl),
-            *args)
+        part = _chunk_of(slots, order, i, chunk)
+        rows = part.group_sizes.sum()
+        hidden, pull = jax.vjp(
+            lambda *a: _hidden_of_chunk(*a, part, rows, impl),
+            tokens, w_gate, w_up)
+        dhidden, dw_down, dweights, _, _ = _project_and_combine_bwd(
+            _kernels(impl, False), None, False,
+            (hidden, w_down, weights, part, rows), dout)
         return jax.tree.map(
-            lambda g, d: g + d.astype(jnp.float32), grads, pull(dout))
+            lambda g, d: g + d.astype(jnp.float32), grads,
+            (*pull(dhidden), dw_down, dweights))
 
     # an ungated layer's `w_gate` is None: a tree of no leaves, all through
+    args = (tokens, w_gate, w_up, w_down, weights)
     grads = jax.lax.fori_loop(
         0, n_chunks, add,
-        jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), tuple(args)))
-    return (*jax.tree.map(lambda g, a: g.astype(a.dtype), grads, tuple(args)),
-            None)
+        jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), args))
+    return (*jax.tree.map(lambda g, a: g.astype(a.dtype), grads, args), None)
 
 
 _experts_of_share.defvjp(_experts_of_share_fwd, _experts_of_share_bwd)

@@ -391,6 +391,54 @@ def test_grouped_matmul_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
         "bf16[131072,1024]")
 
 
+# the sum of a share's rows onto their tokens: (buffer rows, tokens, d, k,
+# held experts) of the cell, and the use (the forward's, weighted and added
+# into the loop's float32 sum; the backward's, two buffers' rows as they are)
+SUM_SHAPES = {
+    "mellum2.ep4": (180224, 65536, 2304, 8, 16),
+    "dsv2lite.tokens8k": (33792, 32768, 2048, 6, 8),
+    "lagunaxs2.tokens8k": (20480, 16384, 2048, 8, 32),
+}
+
+
+@pytest.mark.parametrize("use", ["forward", "backward"])
+@pytest.mark.parametrize("cell_name", SUM_SHAPES)
+def test_moe_sum_compiles_at_the_cell_s_shapes(v5e, cell_name, use):
+    """At the tile `sum_tiles` picks, the windows' two slots, the placing
+    matrices and the blocks fit the VMEM it asks for, the DMA windows begin
+    at whole packed tiles, and the instruction carries the kernel's name."""
+    import re
+
+    from ray_tpu.ops import moe
+
+    one = SingleDeviceSharding(v5e[0])
+    n, tokens, d, k, held = SUM_SHAPES[cell_name]
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    rows, sizes = sd((n, d), jnp.bfloat16), sd((held,), jnp.int32)
+    inverse, count = sd((tokens * k,), jnp.int32), sd((), jnp.int32)
+    if use == "forward":
+        lowered = jax.jit(
+            lambda ys, inv, s, r, w, onto: moe.sum_held(
+                (ys,), inv, s, r, tokens, weights=w, onto=onto),
+            donate_argnums=5,
+        ).lower(rows, inverse, sizes, count, sd((tokens, k), jnp.float32),
+                sd((tokens, d), jnp.float32))
+    else:
+        lowered = jax.jit(
+            lambda a, b, inv, s, r: moe.sum_held((a, b), inv, s, r, tokens)
+        ).lower(rows, rows, inverse, sizes, count)
+    text = lowered.compile().as_text()
+    call = re.search(
+        r'%([\w.-]+) = (\S+) [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert re.fullmatch(r"moe_sum(\.\d+)?", call.group(1))
+    assert call.group(2).startswith(
+        f"f32[{tokens},{d}]" if use == "forward" else f"bf16[{tokens},{d}]")
+    assert " scatter(" not in text and " sort(" not in text
+
+
 # ------------------------------------------- the Mamba-2 scan's kernels
 
 SCAN = dict(b=2, T=8192, H=64, P=64, G=8, N=128)  # nemotron3nano.tokens8k
@@ -698,3 +746,45 @@ def test_no_block_matmul_carries_an_update_of_the_state(
         written = [dims for dims in re.findall(r"f32\[([\d,]+)\]", result)
                    if dims in state_shapes]
         assert len(written) <= 1, (name, result)
+
+
+def test_a_share_s_rows_reach_their_tokens_by_moe_sum_in_mellum2_ep4(
+        token_steps):
+    """`mellum2.ep4`'s step compiled for four described v5e with the chip's
+    limit handed to the keep rule: a layer's held rows are summed onto their
+    tokens by `moe_sum`, once forward and once backward in each of four
+    layers; no scatter-add and no float32 copy of the 180,224-row buffer is
+    left under the combine or the dispatch; and the compiler plans no more
+    memory than for the parent's step (8,507,300,352 bytes a chip, the same
+    compile of PR 50's tree)."""
+    import re
+
+    compiled = token_steps("mellum2.ep4", limited=True).compiled
+    text = compiled.as_text()
+    assert _calls(text, "moe_sum") == 8
+    under = [line for line in text.splitlines() if re.search(
+        r'op_name="[^"]*(moe_combine|moe_dispatch)', line)]
+    assert len(under) > 8
+    assert not any(" scatter(" in line or " sort(" in line for line in under)
+    assert not any("f32[180224,2304]" in line for line in under)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 8_507_300_352
+
+
+def test_a_program_lowers_moe_sum_once_a_distinct_use(token_steps):
+    """What a Pallas call site costs set-up (its body traced and lowered to
+    Mosaic anew, 0.1 to 0.25 s of host time a site in every program that
+    holds it, whether the executable then comes from the cache or not:
+    PERF.md section 5) is paid once a distinct use, because `sum_held` is
+    entered through one jitted function: `lfm2moe.tokens8k`'s lowered step
+    holds two `moe_sum` payloads (the forward's weighted sum onto the
+    loop's float32 buffer, the backward's of two buffers) where its
+    compiled text calls the kernel eight times (four routed layers, once
+    forward and once backward)."""
+    import re
+
+    step = token_steps("lfm2moe.tokens8k", limited=True)
+    lowered = step.lowered.as_text()
+    assert len(re.findall(r'kernel_name = "moe_sum"', lowered)) == 2
+    assert len(re.findall(r"func\.func private @_sum_held", lowered)) == 2
+    assert len(re.findall(r"call @_sum_held", lowered)) == 8
+    assert _calls(step.compiled.as_text(), "moe_sum") == 8

@@ -467,7 +467,7 @@ def test_kernels_in_interpret_mode_give_the_share_the_xla_path_s_loss(monkeypatc
     loss, grads = jax.value_and_grad(f)(params)
     real = moe._kernels
     monkeypatch.setattr(moe, "_kernels", lambda impl, interpret: True)
-    for name in ("gmm", "tgmm"):
+    for name in ("gmm", "tgmm", "sum_held"):
         fn = getattr(moe, name)
         monkeypatch.setattr(moe, name, lambda *a, _fn=fn, **kw: _fn(
             *a, **{**kw, "interpret": True}))

@@ -269,6 +269,232 @@ def test_project_and_combine_gives_f32_master_weights_an_f32_gradient():
     np.testing.assert_allclose(dw_down, want, atol=1e-5, rtol=1e-5)
 
 
+# ------------------------------------------- a share's rows to their tokens
+
+HELD = (2, 3)  # experts 2, 3 and 4 of 8
+
+
+def _held_index(case, tokens, k):
+    """[tokens, k] experts of 8, no expert twice a token."""
+    held = jnp.arange(HELD[0], HELD[0] + HELD[1])
+    others = jnp.asarray([0, 1, 5, 6, 7])
+    if case == "no_rows":  # no token names a held expert
+        return others[(jnp.arange(tokens)[:, None] + jnp.arange(k)) % 5]
+    if case == "one_expert_every_row_of_a_tile":  # expert 3 is everyone's first
+        return jnp.stack([jnp.full((tokens,), 3), others[jnp.arange(tokens) % 5]], 1)
+    scores = jax.random.uniform(key(11), (tokens, 8))
+    index = jax.lax.top_k(scores, k)[1]
+    # token 0: all its experts are held; token 1: none is
+    return index.at[0].set(held[:k]).at[1].set(others[:k])
+
+
+# case: (tokens, k, d, rows' dtype, the buffer's rows as a share of the held
+# rows (None: 16 rows that hold nothing), chunk, tokens a tile)
+SUMS = {
+    "no_rows": (256, 2, 128, jnp.bfloat16, None, 0, 128),
+    "under_a_chunk": (256, 2, 128, jnp.bfloat16, 1.3, 0, 128),
+    "a_whole_chunk": (256, 2, 128, jnp.bfloat16, 0.6, 0, 128),
+    "the_second_chunk": (256, 2, 128, jnp.bfloat16, 0.6, 1, 128),
+    "one_expert_every_row_of_a_tile": (256, 2, 128, jnp.bfloat16, 1.1, 0, 128),
+    "three_of_three_held": (256, 3, 128, jnp.bfloat16, 1.3, 0, 128),
+    "tokens_no_whole_tile": (200, 2, 128, jnp.bfloat16, 1.3, 0, 128),
+    "the_shape_s_own_tile": (256, 2, 256, jnp.bfloat16, 1.3, 0, None),
+    "float32_rows": (256, 2, 128, jnp.float32, 1.3, 0, 128),
+}
+
+
+@pytest.mark.parametrize("use", ["combine", "dispatch_gradient"])
+@pytest.mark.parametrize("case", SUMS)
+def test_moe_sum_is_the_scatter_add_of_the_held_rows(case, use):
+    """`moe_sum` in interpret mode against `_to_tokens`: each held row times
+    its slot's float32 weight (combine) or two buffers' rows as they are
+    (the dispatch's gradient), onto their tokens in float32. Every row
+    behind `rows` is NaN, and nothing of it arrives."""
+    tokens, k, d, dtype, share, chunk_i, tt = SUMS[case]
+    index = _held_index(case, tokens, k)
+    slots = moe.sort_slots(index, 8, HELD)
+    total = int(slots.group_sizes.sum())
+    n = 16 if share is None else int(share * total) // 16 * 16
+    order, n_chunks = moe._chunks(slots, n)
+    part = moe._chunk_of(slots, order, chunk_i, n)
+    rows = part.group_sizes.sum()
+    assert int(n_chunks) == (0 if share is None else 1 if share > 1 else 2)
+    assert int(rows) == (total if int(n_chunks) < 2 else (n, total - n)[chunk_i])
+    live = (jnp.arange(n) < rows)[:, None]
+    ys, more = (jnp.where(live, jax.random.normal(key(i), (n, d), dtype),
+                          jnp.nan) for i in (1, 2))
+    # none of these is a bf16 value, nor is its first bf16 part's remainder
+    weights = 1 / 3 + jax.random.uniform(key(3), (tokens, k)) / 1024
+    assert float(jnp.abs(
+        weights - weights.astype(jnp.bfloat16).astype(jnp.float32)).min()) > 0
+    weighted = use == "combine"
+    tiles = tt and moe.sum_tiles(n, tokens, d, HELD[1], dtype, tt=tt,
+                                 out_dtype=jnp.float32, weighted=weighted,
+                                 sources=2 - weighted)
+    if case == "one_expert_every_row_of_a_tile":
+        # a run of four or five windows in rounds of three: the walk goes
+        # on into a second round, and ends inside it
+        assert int(part.group_sizes[1]) == tokens and tiles.tt == 4 * tiles.window
+        tiles = tiles._replace(windows=3)
+    got = moe.sum_held(
+        (ys,) if weighted else (ys, more), part.inverse, part.group_sizes,
+        rows, tokens, weights=weights if weighted else None,
+        out_dtype=jnp.float32, tiles=tiles or None, interpret=True)
+
+    def want(weights):
+        rows32 = ys.astype(jnp.float32)
+        rows32 = (rows32 * weights.reshape(-1)[part.order][:, None] if weighted
+                  else rows32 + more.astype(jnp.float32))
+        return moe._to_tokens(rows32, part.order, rows, tokens, k)
+
+    assert got.shape == (tokens, d) and got.dtype == jnp.float32
+    assert np.isfinite(np.asarray(got)).all()
+    # the order of a token's k adds is all that may differ
+    np.testing.assert_allclose(got, want(weights), rtol=3e-7, atol=3e-7)
+    held = np.asarray((index >= HELD[0]) & (index < HELD[0] + HELD[1]))
+    if share is None:
+        assert not held.any() and float(jnp.abs(got).max()) == 0.0
+    elif case not in ("one_expert_every_row_of_a_tile", "three_of_three_held"):
+        assert held[0].all() and not held[1].any()
+        np.testing.assert_array_equal(got[1], 0.0)
+    if weighted and int(rows):  # weights cut to bf16 are another sum
+        cut = want(weights.astype(jnp.bfloat16).astype(jnp.float32))
+        assert float(jnp.abs(cut - got).max()) > 1e-4
+
+
+def test_moe_sum_adds_onto_a_float32_sum_in_its_buffer():
+    tokens, k, d = 256, 2, 128
+    slots = moe.sort_slots(_held_index("random", tokens, k), 8, HELD)
+    n = int(slots.group_sizes.sum()) // 16 * 16 + 32
+    order, _ = moe._chunks(slots, n)
+    part = moe._chunk_of(slots, order, 0, n)
+    rows = part.group_sizes.sum()
+    ys = jax.random.normal(key(1), (n, d), jnp.bfloat16)
+    weights = jax.random.uniform(key(3), (tokens, k))
+    onto = jax.random.normal(key(4), (tokens, d))
+    got = moe.combine_held(ys, weights, part, rows, kernels=True,
+                           interpret=True, onto=onto)
+    want = moe.combine_held(ys, weights, part, rows, onto=onto)
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    alone = moe.combine_held(ys, weights, part, rows, kernels=True,
+                             interpret=True)
+    assert alone.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        alone, moe.combine_held(ys, weights, part, rows))
+
+
+@pytest.mark.parametrize("kind,shape,expected", [
+    # mellum2.ep4: 180,224 rows of 16 experts onto 65,536 tokens of 2304;
+    # the weighted sum's three passes of the MXU are cheaper at 128 tokens
+    ("forward", (180224, 65536, 2304, 16), (128, 23)),
+    ("backward", (180224, 65536, 2304, 16), (256, 34)),
+    # dsv2lite.tokens8k, lagunaxs2.tokens8k (a run is about 8 rows)
+    ("forward", (33792, 32768, 2048, 8), (256, 15)),
+    ("backward", (22528, 16384, 2048, 32), (256, 36)),
+    ("forward", (64, 40, 128, 3), (40, 5)),  # fewer tokens than a tile
+])
+def test_sum_tiles_come_from_the_shape(kind, shape, expected):
+    n, tokens, d, n_held = shape
+    forward = kind == "forward"
+    tiles = moe.sum_tiles(n, tokens, d, n_held, jnp.bfloat16,
+                          out_dtype=jnp.float32 if forward else None,
+                          weighted=forward, sources=1 if forward else 2,
+                          onto=forward)
+    assert (tiles.tt, tiles.windows) == expected
+    assert (tiles.window, tiles.align, tiles.parts) == (32, 16, 3 if forward else 1)
+    # a round holds the buffer's even part of a tile, and for every expert
+    # the half window and half packed tile a run's ends leave empty; and no
+    # fewer windows than the experts and an eighth more
+    even = -(-n // -(-tokens // tiles.tt))
+    assert tiles.windows == max(-(-(even + 24 * n_held) // 32),
+                                n_held + -(-n_held // 8))
+    assert tiles.vmem_limit_bytes <= 96 << 20
+    # where 256 tokens' windows have no room in VMEM the tile is 128
+    wide = moe.sum_tiles(n, tokens, 16 * d, n_held, jnp.bfloat16,
+                         weighted=False, sources=2)
+    if tokens > 256:
+        assert wide.tt == 128 and wide.vmem_limit_bytes > 96 << 20
+    assert moe.sum_tiles(n, tokens, d, n_held, jnp.float32).window == 16
+
+
+def _share_kernels_in_interpret_mode(monkeypatch):
+    monkeypatch.setattr(moe, "_kernels", lambda impl, interpret: impl != "xla")
+    for name in ("gmm", "tgmm", "sum_held"):
+        fn = getattr(moe, name)
+        monkeypatch.setattr(moe, name, lambda *a, _fn=fn, **kw: _fn(
+            *a, **{**kw, "interpret": True}))
+
+
+@pytest.mark.parametrize("gated", [True, False])
+def test_experts_of_share_with_the_kernels_has_the_reference_s_gradients(
+        gated, monkeypatch):
+    """Two chunks of held rows through dispatch, the experts and the combine:
+    value and every gradient with `moe_sum` (and the grouped matmul's
+    kernels) in interpret mode against the `jax.numpy` path's."""
+    tokens, k, d, f = 128, 2, 128, 128
+    index = _held_index("random", tokens, k)
+    slots = moe.sort_slots(index, 8, HELD)
+    chunk = int(slots.group_sizes.sum()) // 2 // 8 * 8 + 8
+    assert int(moe._chunks(slots, chunk)[1]) == 2
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    x = jax.random.normal(key(1), (tokens, d))
+    w_gate = jax.random.normal(key(2), (HELD[1], d, f)) / 8 if gated else None
+    w_up = jax.random.normal(key(3), (HELD[1], d, f)) / 8
+    w_down = jax.random.normal(key(4), (HELD[1], f, d)) / 8
+    weights = jax.random.uniform(key(5), (tokens, k))
+    cot = jax.random.normal(key(6), (tokens, d))
+
+    def layer(impl):
+        return lambda *a: moe.experts_of_share(
+            a[0], w_gate if w_gate is None else a[4], a[1], a[2], a[3], slots,
+            chunk=chunk, impl=impl)
+
+    args = (x, w_up, w_down, weights) + ((w_gate,) if gated else ())
+    want, pull_want = jax.vjp(layer("xla"), *args)
+    _share_kernels_in_interpret_mode(monkeypatch)
+    got, pull = jax.vjp(layer("pallas"), *args)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    for g, r in zip(pull(cot), pull_want(cot)):
+        assert g.shape == r.shape and g.dtype == r.dtype
+        np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-5)
+    none_held = ~np.asarray(
+        ((index >= HELD[0]) & (index < HELD[0] + HELD[1])).any(axis=1))
+    assert none_held.any()
+    np.testing.assert_array_equal(np.asarray(got)[none_held], 0.0)
+
+
+def test_every_call_site_of_one_use_shares_sum_held_s_trace(monkeypatch):
+    """Three layers in a Python loop, each `experts_of_share` differentiated:
+    six call sites of `sum_held` and two distinct uses (the forward's
+    weighted sum onto the loop's buffer, the backward's of two buffers), so
+    the lowered program holds two functions of that name, each traced and
+    lowered once. A kernel that every site traced anew cost set-up 0.1 to
+    0.25 s a site in every program (PERF.md section 5)."""
+    import re
+
+    tokens, k, d, f = 128, 2, 128, 128
+    slots = moe.sort_slots(_held_index("random", tokens, k), 8, HELD)
+    chunk = int(slots.group_sizes.sum()) // 8 * 8 + 8
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    _share_kernels_in_interpret_mode(monkeypatch)
+    ws = [tuple(jax.random.normal(key(3 * i + j), shape) / 8 for j, shape in
+                enumerate([(HELD[1], d, f), (HELD[1], d, f), (HELD[1], f, d)]))
+          for i in range(3)]
+    weights = jax.random.uniform(key(20), (tokens, k))
+
+    def loss(x, ws):
+        for w_gate, w_up, w_down in ws:
+            x = x + moe.experts_of_share(x, w_gate, w_up, w_down, weights,
+                                         slots, chunk=chunk, impl="pallas")
+        return x.sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        jax.random.normal(key(21), (tokens, d)), ws).as_text()
+    assert len(re.findall(r"func\.func private @_sum_held", text)) == 2
+    assert len(re.findall(r"call @_sum_held", text)) == 6
+
+
 # ------------------------------------------------------------ aux losses
 
 def test_load_balancing_loss_hand_values():

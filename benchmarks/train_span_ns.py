@@ -8,8 +8,11 @@ with no profiler session (the state of every step of every train job);
 inside a session (the host tracer at level 1, Python's tracer off, as the
 chip benchmark's traced run sets them); after the session. Then what a
 `train.report` adds: the block `ray_tpu_runtime` (the table merged and
-marked, two windows subtracted, `getrusage`, `/proc/pressure`), and a
-garbage collection's two callbacks. Prints one JSON object. The numbers
+marked, two windows subtracted, `getrusage`, `/proc/pressure`), a garbage
+collection's two callbacks, and what the worker's `jax.monitoring`
+listeners add to one event of JAX's (a trace, a lowering or a compilation:
+a scalar at its start, a time span at its end, as JAX records them), alone
+and inside an open one. Prints one JSON object. The numbers
 are this host's: `PERF.md` has the chip host's.
 """
 
@@ -46,6 +49,31 @@ def one_span():
         pass
 
 
+def listener_ns(jax):
+    """ns an event of `_watch_compiles`' two listeners, through
+    `jax.monitoring`'s own `record_*`: an event alone, and one that starts
+    and ends inside another (the self-time rule's path)."""
+    from ray_tpu.train._backend_executor import _watch_compiles
+
+    event = "/jax/core/compile/jaxpr_trace_duration"
+    record_start = jax.monitoring.record_scalar
+    record_span = jax.monitoring.record_event_time_span
+
+    def one_event():
+        record_start(event, 1.0, fun_name="f")
+        record_span(event, 1.0, 1.5, fun_name="f")
+
+    unwatched = per_call(one_event, 200_000)
+    _watch_compiles()
+    alone = per_call(one_event, 200_000)
+    record_start(event, 0.5, fun_name="outer")
+    nested = per_call(one_event, 200_000)
+    record_span(event, 0.5, 2.0, fun_name="outer")
+    return {"jax_event_ns.unwatched": unwatched,
+            "jax_event_ns.watched": alone,
+            "jax_event_ns.watched_inside_another": nested}
+
+
 def main():
     out = {}
     out["span_ns.no_jax"] = per_call(one_span, 200_000)
@@ -80,6 +108,7 @@ def main():
     gc.callbacks.remove(_runtime._on_gc)
     out["gc_callbacks_ns_a_collection"] = watched - per_call(
         lambda: gc.collect(0), 20_000)
+    out.update(listener_ns(jax))
     out["device"] = jax.devices()[0].platform
     print(json.dumps(out))
 

@@ -18,6 +18,10 @@ Public core API (analog of python/ray/_private/worker.py exports):
     ray_tpu.get(f.remote(2))  # 4
 """
 
+import time as _time
+
+_import_began = _time.perf_counter()
+
 from ray_tpu._private.common import (
     ActorDiedError,
     ActorUnavailableError,
@@ -47,6 +51,11 @@ from ray_tpu._private.worker import (
 )
 from ray_tpu.actor import ActorClass, ActorHandle
 from ray_tpu.remote_function import RemoteFunction
+from ray_tpu.util import tracing as _tracing
+
+# `import.ray_tpu`: this package's own import, the program's part of
+# `process.before_init` (docs/observability.md, "The train path")
+_tracing.observe("import.ray_tpu", _time.perf_counter() - _import_began)
 
 __version__ = "0.1.0"
 

@@ -87,6 +87,32 @@ class Worker:
 
 global_worker = Worker()
 _init_lock = threading.Lock()
+_init_entered = False
+
+
+def _observe_before_init() -> None:
+    """`process.before_init`, at the entry of the process's first `init`:
+    the seconds since the process started (its start time in
+    `/proc/self/stat` against `/proc/uptime`, both good to 10 ms):
+    the interpreter's start, the caller's imports and whatever else it did
+    first, of which `import.ray_tpu` is the program's part. No span where
+    `/proc` does not say, and none at a later `init` of this process."""
+    global _init_entered
+    if _init_entered:
+        return
+    _init_entered = True
+    import os
+
+    try:
+        with open("/proc/self/stat") as f:  # field 22, after "pid (comm) "
+            started = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        seconds = uptime - started / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return
+    if seconds >= 0.0:
+        tracing.observe("process.before_init", seconds)
 
 
 def init(
@@ -110,6 +136,7 @@ def init(
     """
     import os as _os
 
+    _observe_before_init()
     config.refresh()  # pick up env overrides set after import (fixtures)
 
     if address and (address.startswith("ray-tpu://") or address.startswith("ray://")):

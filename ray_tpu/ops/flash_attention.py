@@ -149,6 +149,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.util import tracing
+
 logger = logging.getLogger(__name__)
 
 _BIG_NEG = -1e30
@@ -167,6 +169,26 @@ def _dot(a, b, dims):
     return jax.lax.dot_general(
         a, b, dims, precision=precision, preferred_element_type=jnp.float32
     )
+
+
+def _pallas_call(kernel, *, name: str, **params):
+    """`pl.pallas_call(kernel, name=name, **params)` whose application to
+    its operands is a span `pallas.trace` with the attribute `kernel`
+    (docs/observability.md, "The train path"): every kernel of `ops/` is
+    called through here. A kernel is applied while the program around it is
+    traced, so the span runs then and never on a step's path: its row
+    counts the kernel call sites traced, and its seconds (the kernel's body
+    traced to a jaxpr) lie inside `jax.trace`'s. Applied outside any `jit`
+    the span holds the kernel's lowering, compilation and run too."""
+    from jax.experimental import pallas as pl
+
+    call = pl.pallas_call(kernel, name=name, **params)
+
+    def apply(*operands):
+        with tracing.span("pallas.trace", kernel=name):
+            return call(*operands)
+
+    return apply
 
 
 # ------------------------------------------------------------------- tiles
@@ -843,7 +865,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         kernel = _with_mask(kernel, 3)
         in_specs.append(_mask_spec_under_q(mask, BH, block_q, k_block))
         operands += (mask,)
-    return pl.pallas_call(
+    return _pallas_call(
         kernel,
         grid=(BH, num_q, steps),
         in_specs=in_specs,
@@ -1209,7 +1231,7 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
         kernel = _with_mask(kernel, 6)
         in_specs.append(_mask_spec_under_q(mask, BH, block_q, k_block))
         operands += (mask,)
-    return pl.pallas_call(
+    return _pallas_call(
         kernel,
         grid=(BH, num_q, steps),
         in_specs=in_specs,
@@ -1336,7 +1358,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
             scratch_shapes += [pltpu.VMEM((block_k, D), k.dtype),
                                pltpu.VMEM((block_k, Dv), v.dtype)]
         scratch_shapes.append(pltpu.SemaphoreType.DMA((3,)))
-    out = pl.pallas_call(
+    out = _pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,

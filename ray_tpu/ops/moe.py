@@ -100,7 +100,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import (
-    _DEFAULT_VMEM, _LANES, _MAX_VMEM, _NN, _NT, _TN, _cdiv, _dot, resolve_impl)
+    _DEFAULT_VMEM, _LANES, _MAX_VMEM, _NN, _NT, _TN, _cdiv, _dot, _pallas_call,
+    resolve_impl)
 
 # ----------------------------------------------------------------- routing
 
@@ -589,7 +590,7 @@ def gmm(x, w, group_sizes, *, transpose_w: bool = False,
     def o_block(ni, step, ki, group_ids, tile_ids, offsets, num_steps):
         return tile_ids[step], ni
 
-    out = pl.pallas_call(
+    out = _pallas_call(
         functools.partial(_gmm_kernel, tm=tm, tn=tn, tiles_k=tiles_k,
                           transpose_w=transpose_w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -687,7 +688,7 @@ def tgmm(x, dy, group_sizes, *, out_dtype=None,
     def o_block(ni, ki, step, group_ids, tile_ids, offsets, num_steps):
         return group_ids[step], ki, ni
 
-    return pl.pallas_call(
+    return _pallas_call(
         functools.partial(_tgmm_kernel, tm=tm, tk=tk, tn=tn, tail=tail),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -1171,7 +1172,7 @@ def _sum_held(yss, inverse, group_sizes, rows, tokens, *, weights, onto,
         operands.append(onto)
         in_specs.append(pl.BlockSpec((tt, d), rows_of_tile))
     held_rows = windows * window
-    out = pl.pallas_call(
+    out = _pallas_call(
         functools.partial(
             _sum_kernel, n=n + short, n_held=n_held, window=window,
             windows=windows, align=align, sources=len(yss),

@@ -71,9 +71,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.flash_attention import (
-    _DEFAULT_VMEM, _NN, _NT, _TN, _dot, _flash_bwd_dkv, _flash_bwd_dq,
-    _flash_fwd, _pack_bits, _pairs_mask, _unpack_bits, _exit_said,
-    flash_bwd_kernels, flash_tiles, resolve_impl)
+    _DEFAULT_VMEM, _NN, _NT, _TN, _dot, _pallas_call, _flash_bwd_dkv,
+    _flash_bwd_dq, _flash_fwd, _pack_bits, _pairs_mask, _unpack_bits,
+    _exit_said, flash_bwd_kernels, flash_tiles, resolve_impl)
 
 logger = logging.getLogger(__name__)
 
@@ -327,7 +327,7 @@ def index_select(q_idx, k_idx, w_idx, *, topk: int, chunk: int,
         _index_select_kernel, block_q=block_q, chunk=chunk, chunks=chunks,
         topk=topk, heads=Hi)
     row = pl.BlockSpec((1, block_q, 8), lambda b, qi: (b, qi, 0))
-    mask, lse_idx, kept = pl.pallas_call(
+    mask, lse_idx, kept = _pallas_call(
         kernel,
         grid=(B, T // block_q),
         in_specs=[
@@ -509,7 +509,7 @@ def index_loss(q, k, lse, mask, q_idx, k_idx, w_idx, lse_idx, scale,
         _index_loss_kernel, block_q=block_q, chunk=chunk, steps=chunks,
         heads=H, group=H // Hk, index_heads=Hi, scale=scale,
         with_grads=with_grads)
-    out = pl.pallas_call(
+    out = _pallas_call(
         kernel,
         grid=(B, num_q, chunks),
         in_specs=in_specs,

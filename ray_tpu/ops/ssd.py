@@ -76,7 +76,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import (
-    _DEFAULT_VMEM, _LANES, _MAX_VMEM, _NN, _NT, _TN, _dot, resolve_impl)
+    _DEFAULT_VMEM, _LANES, _MAX_VMEM, _NN, _NT, _TN, _dot, _pallas_call,
+    resolve_impl)
 
 logger = logging.getLogger(__name__)
 
@@ -469,7 +470,7 @@ def _scan_call(kernel, name, operands, kinds, out_shapes, out_kinds, P, n,
         return pl.BlockSpec((1, 1, 1, N, RP),
                             lambda i, g, c: (i, chunk(c), g, 0, 0))
 
-    return pl.pallas_call(
+    return _pallas_call(
         functools.partial(kernel, P=P),
         grid=(b, G, n),
         in_specs=[spec(a, k) for a, k in zip(operands, kinds)],

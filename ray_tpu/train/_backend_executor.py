@@ -11,6 +11,7 @@ per-worker restart.
 from __future__ import annotations
 
 import logging
+import threading
 import uuid
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
@@ -19,6 +20,7 @@ import cloudpickle
 
 import ray_tpu
 from ray_tpu.air.config import ScalingConfig
+from ray_tpu.train import _session
 from ray_tpu.train._session import TrialInfo
 from ray_tpu.train._worker_group import WorkerGroup
 from ray_tpu.util import tracing
@@ -68,39 +70,80 @@ class JaxConfig(BackendConfig):
 
 
 _compiles_watched = False
+_JAX_SPANS = {  # `jax.monitoring`'s event: the span
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_CACHE_VERDICTS = {  # the event: (the span's `cache`, the counter)
+    "/jax/compilation_cache/cache_hits": ("hit", "compile.cache_hits"),
+    "/jax/compilation_cache/cache_misses": ("miss", "compile.cache_misses"),
+}
 
 
 def _watch_compiles() -> None:
-    """Worker side: one `jax.compile` span a program compiled or loaded from
-    the persistent cache, with the counters `compile.programs`,
-    `compile.cache_hits` and `compile.cache_misses`. `jax.monitoring` tells
-    of a compilation at its end, and of the cache's verdict just before."""
+    """Worker side: what JAX does to a program before it runs, as spans.
+    `jax.trace` a function traced to a jaxpr, `jax.lower` a jaxpr lowered
+    to a module, `jax.compile` a program compiled or loaded from the
+    persistent cache, each with the attribute `function`; `jax.compile`
+    with `cache` and the counters `compile.programs`, `compile.cache_hits`
+    and `compile.cache_misses` (`jax.monitoring` tells of the cache's
+    verdict just before the compilation ends).
+
+    The table's seconds are *self* time. JAX reports an inner `jit` traced
+    while an outer one is (and a small program run eagerly in a trace:
+    traced, lowered and compiled inside it), the inner one first, so a span
+    counts less what ended inside it on its thread, and a thread's seconds
+    under the three names add up to the wall time it spent on them.
+    `jax.monitoring` tells of a start by a scalar and of an end by a time
+    span; both carry the start on `time.time()`'s clock, so a span lies in
+    the ring where it happened. The first trace to start after the train
+    function was called ends `train.before_first_program`
+    (`RuntimeAccount.program_began`)."""
     global _compiles_watched
     if _compiles_watched:
         return
     _compiles_watched = True
     import jax.monitoring
 
-    verdicts = {  # the event: (the span's `cache`, the counter)
-        "/jax/compilation_cache/cache_hits": ("hit", "compile.cache_hits"),
-        "/jax/compilation_cache/cache_misses": ("miss", "compile.cache_misses"),
-    }
     cache = [None]
+    local = threading.local()  # .open: seconds inside each span open here
 
     def on_event(event: str, **_) -> None:
-        if event in verdicts:
-            cache[0], counter = verdicts[event]
+        if event in _CACHE_VERDICTS:
+            cache[0], counter = _CACHE_VERDICTS[event]
             tracing.count(counter)
 
-    def on_duration(event: str, seconds: float, fun_name=None, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
+    def on_start(event: str, start: float, **_) -> None:
+        name = _JAX_SPANS.get(event)
+        if name is None:
+            return
+        try:
+            local.open.append(0.0)
+        except AttributeError:
+            local.open = [0.0]
+        session = _session._session if name == "jax.trace" else None
+        if session is not None:
+            session.runtime.program_began(start)
+
+    def on_span(event: str, start: float, end: float, fun_name=None,
+                **_) -> None:
+        name = _JAX_SPANS.get(event)
+        if name is None:
+            return
+        still_open = getattr(local, "open", None)
+        inside = still_open.pop() if still_open else 0.0
+        if still_open:
+            still_open[-1] += end - start
+        attrs = {"function": fun_name}
+        if name == "jax.compile":
             tracing.count("compile.programs")
-            tracing.observe("jax.compile", seconds, cache=cache[0] or "none",
-                            function=fun_name)
-            cache[0] = None
+            attrs["cache"], cache[0] = cache[0] or "none", None
+        tracing.observe(name, end - start, end=end, inside=inside, **attrs)
 
     jax.monitoring.register_event_listener(on_event)
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_scalar_listener(on_start)
+    jax.monitoring.register_event_time_span_listener(on_span)
 
 
 class _JaxBackend(Backend):

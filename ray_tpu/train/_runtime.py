@@ -137,6 +137,26 @@ class RuntimeAccount:
         self._t_report = time.perf_counter()
         self._intervals: deque = deque(maxlen=64)
         self._reports = 0
+        self._fn_called: list = []  # when, until the first program begins
+
+    def train_fn_called(self) -> None:
+        """The worker calls the user's train function now."""
+        self._fn_called = [time.time()]
+
+    def program_began(self, start: float) -> None:
+        """JAX began to trace a function at `start` (`time.time()`'s
+        clock; `_backend_executor._watch_compiles`). The first one after
+        the train function was called ends `train.before_first_program`:
+        the worker's first touch of the chip, the model's modules imported,
+        meshes and closures built, which is user code that no span can
+        wrap, between two moments the program does see."""
+        if self._fn_called:
+            try:
+                called = self._fn_called.pop()  # one thread of two gets it
+            except IndexError:
+                return
+            tracing.observe("train.before_first_program", start - called,
+                            end=start)
 
     def block(self) -> Dict[str, Any]:
         """This report's block; called once a report."""

@@ -91,6 +91,7 @@ class TrainWorker:
             # module, version skew) must still set `finished`, or the
             # driver's poll loop waits forever for a rank that never ran.
             fn = cloudpickle.loads(fn_blob)
+            s.runtime.train_fn_called()
             if s.loop_config is not None and _takes_config(fn):
                 fn(s.loop_config)
             else:

@@ -316,16 +316,27 @@ def span(name: str, kind: str = "train", **attrs: Any) -> Span:
 
 
 def observe(name: str, seconds: float, kind: str = "train",
+            end: Optional[float] = None, inside: float = 0.0,
             **attrs: Any) -> None:
     """A span of the train path that is known only once it has ended (a
-    compilation reported by ``jax.monitoring``, a garbage collection): it
-    ended now and took ``seconds``. It reaches the table and the ring, not
-    the profiler's trace, which cannot be told of a span after the fact."""
-    _add(name, seconds, time.perf_counter())
+    trace, a lowering or a compilation reported by ``jax.monitoring``, a
+    garbage collection): it took ``seconds`` and ended at ``end`` on
+    ``time.time()``'s clock, now if none is given. It reaches the table and
+    the ring, not the profiler's trace, which cannot be told of a span
+    after the fact.
+
+    ``inside`` is the seconds of the observed spans that ended inside this
+    one on its thread (a ``jit`` traced while another is). The table takes
+    the span's *self* time, ``seconds - inside``, so that what one thread
+    adds under nested names is the wall time it spent; the ring takes the
+    whole span where it happened."""
+    now = time.time()
+    ago = 0.0 if end is None else now - end
+    _add(name, seconds - inside, time.perf_counter() - ago)
     trace = _joined()
     if trace is not None:
         _emit(name, kind, trace[0], _new_id(), trace[1],
-              time.time() - seconds, seconds, attrs)
+              now - ago - seconds, seconds, attrs)
 
 
 # ---------------------------------------------------------------------------

@@ -3,6 +3,8 @@ the spans of `ray_tpu/util/tracing.py` where the train path's work happens,
 the `ray_tpu_runtime` block every `train.report` carries, and the record a
 slow report interval leaves."""
 
+import json
+import os
 import subprocess
 import sys
 import time
@@ -17,7 +19,9 @@ from ray_tpu.util import tracing
 from ray_tpu.util.state import api as state_api
 
 KEY = _runtime.KEY
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER_SPANS = {
+    "jax.trace", "jax.lower", "pallas.trace", "train.before_first_program",
     "jax.compile", "data.pipeline_start", "data.epoch_start",
     "data.batch_produce", "data.block_fetch", "data.batch_assemble",
     "data.finalize", "data.batch_wait", "train.report",
@@ -26,6 +30,10 @@ WORKER_SPANS = {
 DRIVER_SPANS = {
     "init", "init.gcs", "init.raylet", "init.worker_pool",
     "train.worker_group_start", "train.backend_start", "train.session_setup",
+}
+SETUP_SPANS = {  # every one ends before the first report
+    "jax.trace", "jax.lower", "jax.compile", "pallas.trace",
+    "train.before_first_program",
 }
 REPORTS = 4
 
@@ -42,7 +50,10 @@ def _make_loop():
         import jax.numpy as jnp
 
         from ray_tpu import train
+        from ray_tpu.ops import flash_attention
 
+        q = jnp.ones((1, 128, 1, 128), jnp.float32)
+        jax.jit(lambda q: flash_attention(q, q, q, interpret=True))(q)
         step = jax.jit(lambda x: (x * 2.0).sum())
         shard = train.get_dataset_shard("train")
         for _ in range(REPORTS):  # an epoch a report
@@ -127,9 +138,75 @@ def test_since_first_report_is_total_less_the_first_reports(untraced_run):
         assert steady[0] == count - began[0]
         assert steady[1] == pytest.approx(seconds - began[1], abs=1e-9)
         assert steady[2] <= longest
-    # the compile is set-up's: the steady state holds none
-    assert "jax.compile" not in last["since_first_report"]
+    # tracing, lowering and compiling are set-up's: the steady state holds none
+    assert not SETUP_SPANS & set(last["since_first_report"])
     assert last["interval"]["data.pipeline_start"][0] == 1
+
+
+def test_what_comes_before_a_program_runs_is_in_the_block(untraced_run):
+    total = untraced_run[0].metrics[KEY]["total"]
+    # once a session: the train function called to the first trace's start
+    count, seconds, longest, when = total["train.before_first_program"]
+    assert count == 1 and 0.0 <= seconds == longest < 60.0
+    assert total["pallas.trace"][0] == 1  # one kernel call site traced
+    assert total["jax.trace"][1] > total["pallas.trace"][1] > 0.0
+    # every program compiled was lowered, and every one lowered was traced
+    assert (total["jax.trace"][0] >= total["jax.lower"][0]
+            >= total["jax.compile"][0] >= 2)
+    for name in ("jax.trace", "jax.lower", "jax.compile"):
+        assert total[name][1] >= total[name][2] > 0.0  # self time, not below 0
+
+
+def test_an_inner_jit_s_trace_counts_once():
+    """JAX reports the inner `jit`'s trace and then the outer's, which
+    holds it: the table's seconds are each one's own."""
+    import jax
+
+    from ray_tpu.train._backend_executor import _watch_compiles
+
+    _watch_compiles()
+
+    @jax.jit
+    def inner(x):
+        time.sleep(0.2)
+        return x
+
+    @jax.jit
+    def outer(x):
+        time.sleep(0.1)
+        return inner(x)
+
+    x = jax.ShapeDtypeStruct((3,), "float32")
+    before = tracing.table().get("jax.trace", [0, 0.0])
+    t0 = time.perf_counter()
+    outer.trace(x)
+    wall = time.perf_counter() - t0
+    count, seconds, longest, when = tracing.table()["jax.trace"]
+    assert count - before[0] == 2
+    assert 0.3 <= seconds - before[1] <= wall
+    assert wall - (seconds - before[1]) < 0.1  # twice the inner's would be 0.2
+    assert abs(when - time.time()) < 60
+
+
+def test_observe_takes_the_span_s_own_end_and_its_self_time(monkeypatch):
+    monkeypatch.setattr(tracing.config, "task_trace_spans", True)
+    tracing.reset()
+    token = tracing.set_context(("t" * 16, "p" * 16))
+    try:
+        ended = time.time() - 5.0
+        tracing.table(mark=True)
+        tracing.observe("test.observed", 2.0, end=ended, inside=0.5, function="f")
+    finally:
+        tracing.reset_context(token)
+    count, seconds, longest, when = tracing.table(mark=True)["test.observed"]
+    assert (count, seconds, longest) == (1, 1.5, 1.5)
+    assert abs(when - ended) < 0.05
+    (span,) = [s for s in tracing.snapshot() if s["name"] == "test.observed"]
+    tracing.reset()
+    # the ring takes the whole span, where it happened
+    assert span["duration"] == 2.0 and span["function"] == "f"
+    assert abs(span["start"] - (ended - 2.0)) < 0.05
+    assert abs(span["time"] - ended) < 0.05
 
 
 def test_with_tracing_off_the_ring_receives_nothing(untraced_run):
@@ -144,7 +221,9 @@ def test_with_tracing_on_the_new_spans_reach_list_spans(
     _fit(tmp_path)
     want = {"train.report", "train.checkpoint_persist", "data.pipeline_start",
             "data.batch_wait", "data.batch_produce", "data.block_fetch",
-            "data.batch_assemble", "data.finalize", "jax.compile"}
+            "data.batch_assemble", "data.finalize", "jax.compile",
+            "jax.trace", "jax.lower", "pallas.trace",
+            "train.before_first_program"}
     deadline = time.time() + 25
     while time.time() < deadline:
         spans = state_api.list_spans()
@@ -157,6 +236,24 @@ def test_with_tracing_on_the_new_spans_reach_list_spans(
     assert (by_name["data.batch_produce"]["trace_id"]
             == by_name["train.report"]["trace_id"])
     assert by_name["jax.compile"]["cache"] in ("hit", "miss", "none")
+    assert by_name["pallas.trace"]["kernel"] == "flash_fwd"
+    # a span that `jax.monitoring` told of lies where it happened: the trace
+    # of the program that holds the kernel around the kernel's, its lowering
+    # after its trace, its compilation after that; and the first trace of
+    # all begins where `train.before_first_program` ends
+    kernel = by_name["pallas.trace"]
+    trace = min((s for s in spans if s["name"] == "jax.trace"
+                 and s["start"] <= kernel["start"]
+                 and kernel["time"] <= s["time"]), key=lambda s: s["start"])
+    lower, compiled = (
+        min((s for s in spans if s["name"] == name
+             and s["function"] == f"jit({trace['function']})"
+             and s["start"] >= trace["time"]), key=lambda s: s["start"])
+        for name in ("jax.lower", "jax.compile"))
+    assert trace["time"] <= lower["start"] <= lower["time"] <= compiled["start"]
+    first = min(s["start"] for s in spans if s["name"] == "jax.trace")
+    assert by_name["train.before_first_program"]["time"] == pytest.approx(
+        first, abs=1e-3)
 
 
 def test_the_table_ends_with_the_cluster(shutdown_only):
@@ -180,6 +277,45 @@ def test_init_leaves_jax_unimported():
             "ray_tpu.shutdown(); sys.exit('jax' in sys.modules)")
     done = subprocess.run([sys.executable, "-c", code], timeout=120)
     assert done.returncode == 0
+
+
+_TWO_JOBS = """
+import json, sys, time
+time.sleep(0.5)
+import ray_tpu
+from ray_tpu.air import ScalingConfig
+from ray_tpu.train.jax import JaxTrainer
+
+def loop():
+    from ray_tpu import train
+    train.report({"x": 1})
+
+def job():
+    ray_tpu.init(num_cpus=2, num_tpus=0)
+    try:
+        fit = JaxTrainer(loop, scaling_config=ScalingConfig(num_workers=1)).fit()
+        return fit.metrics["ray_tpu_runtime"]["driver"]
+    finally:
+        ray_tpu.shutdown()
+
+print(json.dumps([job(), job()]))
+"""
+
+
+def test_the_driver_s_start_is_in_the_first_job_s_table_alone(tmp_path):
+    """`process.before_init` and `import.ray_tpu` happen once a process:
+    the cluster that the process starts first reports them."""
+    done = subprocess.run(
+        [sys.executable, "-c", _TWO_JOBS], timeout=300, cwd=str(tmp_path),
+        stdout=subprocess.PIPE, env={**os.environ, "PYTHONPATH": ROOT})
+    assert done.returncode == 0
+    first, second = json.loads(done.stdout.splitlines()[-1])
+    assert first["process.before_init"][0] == first["import.ray_tpu"][0] == 1
+    # the sleep before the import, the import, and the interpreter's start
+    assert (30.0 > first["process.before_init"][1]
+            > 0.5 + first["import.ray_tpu"][1] > 0.5)
+    assert DRIVER_SPANS <= set(second)
+    assert not {"process.before_init", "import.ray_tpu"} & set(second)
 
 
 def _slow_events():

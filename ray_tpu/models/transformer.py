@@ -113,8 +113,9 @@ With `remat` each block runs under `jax.checkpoint`: its input is kept and
 its values are made again in the backward pass, but for the named ones
 (`checkpoint_name`) that `make_train_step`'s step finds room for on the
 device it is traced for (`saved_activations`: from the widths, the tokens
-and the state a device holds, and the device's memory limit; nothing where
-no limit can be read).
+and the state a device holds, the device's memory limit and which of
+`segments`' runs a scan stacks, whose moments `_moments` walks in the
+backward's order; nothing where no limit can be read).
 
 Every weight of a block's plain matmuls leaves its gradient's matmul as an
 array of its own in the compute dtype, and in a segment of one period also
@@ -1462,8 +1463,14 @@ class _Mamba2(Sublayer):
 
     def holds(self, cfg):
         """The convolution's sum and its silu, the gated output and the
-        normed one, and what the scan holds by the path it takes."""
+        normed one, what the scan holds by the path it takes, and the three
+        float32 arrays of the mixer's width that the gated norm's backward
+        holds (the compiler's plan for a described v5e,
+        `nemotron3nano.tokens8k`, PR 54: `mamba_out`'s cotangent and the
+        norm's two products, 0.27 GB each at 16,384 tokens, in a backward
+        of 2.77 GB where the other terms count 1.59 and the names 0.47)."""
         return (2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner
+                + 3 * cfg.mamba_inner * 4 // _item(cfg)
                 + _scan_bytes_per_token(cfg) // _item(cfg))
 
     def flops(self, cfg, seq_len):
@@ -2122,56 +2129,206 @@ def _whole_param_bytes(cfg: TransformerConfig) -> int:
         lambda: transformer_init(jax.random.PRNGKey(0), cfg))))
 
 
+def _block_bytes(cfg: TransformerConfig, kind: LayerKind, tokens: int,
+                 sharded: bool, exchange: int) -> int:
+    """One block of `kind` in its backward, every value at once: the
+    stream's cotangent and, of each of its sublayers, the normed input, the
+    named values (`Sublayer.widths`) and what the kind says it holds beside
+    them (`Sublayer.holds`); with the compute-dtype copy of its weights and,
+    where the parameters are sharded, the same weights gathered whole and
+    their float32 gradient before it is scattered; a routed block on an
+    `expert` axis with its `exchange`."""
+    item = _item(cfg)
+    sublayers = _sublayers(kind)
+    widths, params = _layer_widths(cfg, kind)
+    width = (sum(widths.values()) + (len(sublayers) + 1) * cfg.d_model
+             + sum(sub.holds(cfg) for sub in sublayers))
+    weights = params * item + (params * (item + 4) if sharded else 0)
+    return (tokens * width * item + weights
+            + (exchange if kind.routed else 0))
+
+
+def _head_bytes(cfg: TransformerConfig, tokens: int, param_bytes: int,
+                expert_ways: int) -> int:
+    """The head's chunk in its backward: a chunk's logits, their gradient
+    and its cast, the unembedding's cast and the normed stream."""
+    item = _item(cfg)
+    unembed = (cfg.vocab_size * cfg.d_model * item * param_bytes
+               // _whole_param_bytes(cfg))
+    if expert_ways > 1:  # whole: float32, its cast, its float32 gradient
+        unembed = cfg.vocab_size * cfg.d_model * (4 + item + 4)
+    return (_HEAD_CHUNK * cfg.vocab_size * (4 + 4 + item) + unembed
+            + tokens * cfg.d_model * item)
+
+
 def _working_set_bytes(cfg: TransformerConfig, tokens: int,
                        param_bytes: int, expert_ways: int = 1) -> int:
     """What the step that keeps nothing holds on a device beside its state
-    and the gradients when it is fullest, for `tokens` tokens on the device
-    and `param_bytes` of parameters there: the blocks' inputs, and the
-    larger of the head's chunk and one block in its backward. On an
-    `expert` axis of `expert_ways` devices a routed block also holds its
-    exchange (`_exchange_bytes`), and the head is gathered whole in float32.
+    and the gradients when it is fullest, where its stack is a scan over
+    stacked layers: the blocks' inputs, and the larger of the head's chunk
+    (`_head_bytes`) and one block in its backward, taken as every value of
+    the model's widest layer at once (`_block_bytes`). On an `expert` axis
+    of `expert_ways` devices a routed block also holds its exchange
+    (`_exchange_bytes`), and the head is gathered whole in float32. A stack
+    that is not scanned is walked a layer at a time (`_moments`).
 
-    A block in its backward is taken as every value of its widest layer at
-    once: the stream's cotangent and, of each of its sublayers, the normed
-    input, the named values (`Sublayer.widths`) and what the kind says it
-    holds beside them (`Sublayer.holds`); with the compute-dtype copy of
-    its weights and, where the parameters are sharded, the same weights
-    gathered whole
-    and their float32 gradient before it is scattered. Against the chip
-    (`bytes_in_use + bytes_reserved` less state and gradients; PERF.md
-    section 6, PR 33), GB: 4.06 for 4.03 at Mistral's widths on one chip
-    (PR 42 took 0.201 off both: k and v at their own heads, and the chip's
-    peak fell by 0.201) and 3.96 for 3.18 on four; 3.32 for 1.77 and 4.64 for 2.23 where every
-    scan is one layer long and the compiler frees an unrolled block's
-    values as it goes; 3.93 for 4.02 in `keyevl2.tokens16k` (the compiler's
-    plan for a described v5e, PR 46: 6.66 GB of scratch less 2.64 of
-    gradients; 1.16 of it the six layers' weights in bf16, hoisted out of
-    the scan, which `_SparseAttention.holds` prices because no other term
-    does). It errs to the full side: a name too few costs a
-    percent, a step that asks for the chip's last GiB is compiled to fit
-    and runs slower than the one that keeps nothing."""
-    d = cfg.d_model
-    item = _item(cfg)
+    Against the chip (`bytes_in_use + bytes_reserved`, `peak_hbm_gb.tokens`
+    in the ledger's PR 53 lines, and this PR's chip runs for the cells
+    PR 54 moved; PERF.md section 6, PR 54), GB, the rule's sum for the
+    choice it makes and the chip's peak: where a segment has more than one
+    period (this term, all the gradients and all the kept names at once)
+    15.57 for 15.510 in `mistral7b.tokens4k`, 15.52 for 15.512 in
+    `keyevl2.tokens16k` (1.16 of it the six layers' weights in bf16,
+    hoisted out of the scan, which `_SparseAttention.holds` prices because
+    no other term does), 15.81 for 15.587 in `dsv2lite.tokens8k`, whose
+    scanned five layers set the peak (the compiler's plan for a described
+    v5e: 7.855 GB of scratch in the routed scan's body, 5.45 in the dense
+    layer ahead of it, which is walked), and 15.66
+    for 14.937 on `mistral7b.fsdp4`'s four chips; where every segment is
+    one period long `_moments`' table has the pairs. It errs to the full
+    side: a name too few costs a percent, a step that asks for the chip's
+    last GiB is compiled to fit and runs slower than the one that keeps
+    nothing."""
     whole = _whole_param_bytes(cfg)
     sharded = param_bytes < whole
     exchange = _exchange_bytes(cfg, tokens, expert_ways)
+    head = _head_bytes(cfg, tokens, param_bytes, expert_ways)
     cfg = _on_an_expert_axis(cfg, expert_ways)
-    block = 0
-    for kind in set(cfg.layers):
-        sublayers = _sublayers(kind)
-        widths, params = _layer_widths(cfg, kind)
-        width = (sum(widths.values()) + (len(sublayers) + 1) * d
-                 + sum(sub.holds(cfg) for sub in sublayers))
-        weights = params * item + (params * (item + 4) if sharded else 0)
-        block = max(block, tokens * width * item + weights
-                    + (exchange if kind.routed else 0))
-    unembed = cfg.vocab_size * d * item * param_bytes // whole
-    if expert_ways > 1:  # whole: float32, its cast, its float32 gradient
-        unembed = cfg.vocab_size * d * (4 + item + 4)
-    head = (_HEAD_CHUNK * cfg.vocab_size * (4 + 4 + item) + unembed
-            + tokens * d * item)
-    boundaries = (cfg.n_layers + 1) * tokens * d * item
+    block = max(_block_bytes(cfg, kind, tokens, sharded, exchange)
+                for kind in set(cfg.layers))
+    boundaries = (cfg.n_layers + 1) * tokens * cfg.d_model * _item(cfg)
     return boundaries + max(block, head)
+
+
+class _Moment(NamedTuple):
+    """A moment of the step and what a device holds then beside the state."""
+    name: str  # "head", "optimizer", "layers 1-5" (a scan), "layer 4"
+    bytes: int
+
+
+def _moments(cfg: TransformerConfig, tokens: int, param_bytes: int,
+             expert_ways: int = 1,
+             kept: Tuple[str, ...] = ()) -> List[_Moment]:
+    """The moments at which the step that keeps the names `kept` may be
+    fullest, in the backward's order over `segments(cfg)`, each with what a
+    device holds then beside its state.
+
+    A segment of several periods is a scan over stacked layers: its
+    gradient is one buffer, whole from the scan's first step, and the
+    compiler's plan for a described v5e holds it beside every kept name
+    all through the backward (`mistral7b.tokens4k`: 2.75 GB from the first
+    layer's backward to the last's). Its moment is what the rule has always
+    counted, every term at once: the blocks' inputs, every kept name, every
+    gradient, and the model's widest block or the head's chunk
+    (`_working_set_bytes`). A model all of whose segments are scanned has
+    that one sum.
+
+    A segment of one period is a scan of length 1, which XLA inlines: each
+    layer's gradient is a buffer of its own, made when the backward reaches
+    the layer, and since the step clips nothing AdamW's update of a weight
+    follows its gradient at once (the plans of `lagunaxs2.tokens8k`,
+    `lfm2moe.tokens8k`, `nemotron3nano.tokens8k`, `mellum2.ep4` and
+    `olmoe.tokens4k`, PERF.md section 6, PR 54: no more than 0.74 GB of
+    gradients live at any position, of 1.9 to 2.8). The backward walks
+    from the last layer to the first, so layer i's moment holds the blocks'
+    inputs, the kept names of the layers before and at it, its own block
+    (`_block_bytes` at its own widths), the head's gradient (made first,
+    and in `olmoe.tokens4k`'s plan live to the end), the gradients of the
+    scanned segments behind it, and of its own gradient what a loop
+    accumulates: a share's held experts', float32, live from the first
+    chunk of held rows to the last (where the parameters are sharded the
+    block's own term has the whole gradient already).
+
+    The head's moment has every kept name and every gradient but those
+    of the layers no scan stacks; the optimizer's has every gradient and no
+    kept name.
+
+    Against the chip, GB: what the rule counted as a scan's (every term at
+    once, with the names it then kept), the chip's peak then
+    (`peak_hbm_gb.tokens`: ledger, PR 53), the sum of the fullest moment and
+    the state for the names it keeps now, and the chip's peak with them (my
+    chip runs, PR 54; the compiler's plan for a described v5e beside it):
+
+    | cell | as a scan | chip | walked | chip | plan |
+    |---|---|---|---|---|---|
+    | `olmoe.tokens4k`, one layer, every name both times | 14.21 | 11.276 | 12.12 | 11.276 | 11.15 |
+    | `lagunaxs2.tokens8k`, five layers, 2 names then 7 | 15.81 | 12.650 | 15.12 | 14.512 | 14.02 |
+    | `lfm2moe.tokens8k`, five layers, 6 then 7 | 15.68 | 11.810 | 12.63 | 12.581 | 12.36 |
+    | `nemotron3nano.tokens8k`, nine layers, 4 then 6 | 15.42 | 13.901 | 14.64 | 14.396 | 14.15 |
+    | `mellum2.ep4`, four layers on an `expert` axis, 1 then 3 | 15.83 | 13.558 | 14.91 | 14.270 | 13.73 |
+
+    The chip stands over the plan's live bytes by the program's own code
+    (0.04 GB for one layer, 0.18 to 0.27 for five to nine) and by what the
+    plan's heap loses between buffers (0.03 to 0.33)."""
+    item, d = _item(cfg), cfg.d_model
+    whole = _whole_param_bytes(cfg)
+    sharded = param_bytes < whole
+    exchange = _exchange_bytes(cfg, tokens, expert_ways)
+    head = _head_bytes(cfg, tokens, param_bytes, expert_ways)
+    at_once = _working_set_bytes(cfg, tokens, param_bytes, expert_ways)
+    cfg = _on_an_expert_axis(cfg, expert_ways)
+    on_device = _whole_param_bytes(cfg)
+    boundaries = (cfg.n_layers + 1) * tokens * d * item
+
+    def kept_bytes(kind: LayerKind) -> int:
+        widths = _layer_widths(cfg, kind)[0]
+        return tokens * item * sum(widths.get(name, 0) for name in kept)
+
+    def gradient(kind: LayerKind) -> int:
+        return 4 * _layer_widths(cfg, kind)[1] * param_bytes // on_device
+
+    kept_all = sum(kept_bytes(kind) for kind in cfg.layers)
+    # the gradients of the layers no scan stacks: none is made before its
+    # layer's backward
+    inlined = sum(gradient(kind) for seg in segments(cfg)
+                  if seg.periods == 1 for kind in seg.layout)
+    # the head's gradient once the head is done: a device's share of it
+    unembed = 4 * cfg.vocab_size * d * param_bytes // whole
+    moments = [_Moment("optimizer", param_bytes),
+               _Moment("head", boundaries + kept_all + head
+                       + param_bytes - inlined)]
+    first, behind = cfg.n_layers, 0  # walked from the last layer back
+    for seg in reversed(segments(cfg)):
+        layers = len(seg.layout) * seg.periods
+        first -= layers
+        if seg.periods > 1:
+            moments.append(_Moment(
+                "layers %d-%d" % (first, first + layers - 1),
+                at_once + kept_all + param_bytes))
+            behind += seg.periods * sum(
+                gradient(kind) for kind in seg.layout)
+            continue
+        for i in reversed(range(layers)):
+            kind = seg.layout[i]
+            before_and_at = sum(
+                kept_bytes(k) for k in cfg.layers[:first + i + 1])
+            accumulated = 0
+            if kind.routed and not sharded and cfg.held[1] < cfg.n_experts:
+                accumulated = (4 * cfg.held[1] * cfg.ff_matrices * d
+                               * cfg.ff_dim)
+            moments.append(_Moment(
+                "layer %d" % (first + i),
+                boundaries + before_and_at + unembed + behind + accumulated
+                + _block_bytes(cfg, kind, tokens, sharded, exchange)))
+    return moments
+
+
+def _fullest_moment(cfg: TransformerConfig, tokens: int, param_bytes: int,
+                    expert_ways: int = 1,
+                    kept: Tuple[str, ...] = ()) -> _Moment:
+    """The fullest of `_moments`; of equals, the first in the backward."""
+    return max(_moments(cfg, tokens, param_bytes, expert_ways, kept),
+               key=lambda moment: moment.bytes)
+
+
+def _room_bytes(cfg: TransformerConfig, tokens: int, resident_bytes: int,
+                param_bytes: int, limit_bytes: int, expert_ways: int = 1,
+                kept: Tuple[str, ...] = ()) -> int:
+    """What the step that keeps `kept` leaves of `limit_bytes` when it is
+    fullest, `_SAVE_RESERVE` set aside: with nothing kept, the room the
+    names may take."""
+    return (limit_bytes - _SAVE_RESERVE - resident_bytes - _fullest_moment(
+        cfg, tokens, param_bytes, expert_ways, kept).bytes)
 
 
 def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
@@ -2183,11 +2340,13 @@ def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
     as long as they fit the device.
 
     `cfg.remat` says that a block's input is its checkpoint; what is kept
-    beyond it follows from what the step is traced with. The room is
-    `limit_bytes` (the device's `bytes_limit`) less `resident_bytes` (the
-    state on the device), less the gradients (`param_bytes` again: the
-    parameters' bytes on the device), less `_working_set_bytes`, less
-    `_SAVE_RESERVE`. A name's bytes are its width times
+    beyond it follows from what the step is traced with. A choice fits
+    while it leaves room (`_room_bytes`): `resident_bytes` (the state on
+    the device), the fullest of the step's moments with those names kept
+    (`_moments`: over `segments(cfg)`, from `param_bytes`, the parameters'
+    bytes on the device, which its gradients take again) and
+    `_SAVE_RESERVE` stay within `limit_bytes` (the device's
+    `bytes_limit`). A name's bytes are its width times
     `tokens_per_device` times the layers that make it; on an `expert` axis
     of `expert_ways` devices the routed layers are a device's share of them.
     The first name that
@@ -2196,13 +2355,11 @@ def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
     `remat` nothing is kept, and the step is the one without a policy."""
     if limit_bytes is None or not cfg.remat:
         return {}
-    room = (limit_bytes - resident_bytes - param_bytes - _SAVE_RESERVE
-            - _working_set_bytes(cfg, tokens_per_device, param_bytes,
-                                 expert_ways))
     chosen: Dict[str, int] = {}
     for name, size in _saved_bytes(
             _on_an_expert_axis(cfg, expert_ways), tokens_per_device).items():
-        if sum(chosen.values()) + size > room:
+        if _room_bytes(cfg, tokens_per_device, resident_bytes, param_bytes,
+                       limit_bytes, expert_ways, (*chosen, name)) < 0:
             break
         chosen[name] = size
     return chosen
@@ -2306,14 +2463,27 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         tokens = math.prod(tok_sharding.shard_shape(batch["tokens"].shape))
         resident = on_a_device(state, state_shard)
         params = on_a_device(state["params"], p_shard)
-        saved = saved_activations(cfg, tokens, resident, params, limit,
-                                  mesh.shape.get("expert", 1))
-        kept = "keeps every activation" if not cfg.remat else (
-            "under remat keeps %s: %d bytes a device beside the blocks' "
-            "inputs (%d tokens a device, state %d bytes, bytes_limit %s)" % (
-                saved or "nothing", sum(saved.values()), tokens, resident,
-                limit))
+        ways = mesh.shape.get("expert", 1)
+        saved = saved_activations(cfg, tokens, resident, params, limit, ways)
+        kept = "keeps every activation"
+        if cfg.remat:
+            kept = ("under remat keeps %s: %d bytes a device beside the "
+                    "blocks' inputs (%d tokens a device, state %d bytes, "
+                    "bytes_limit %s)" % (saved or "nothing",
+                                         sum(saved.values()), tokens,
+                                         resident, limit))
+        if cfg.remat and limit is not None:
+            fullest = _fullest_moment(cfg, tokens, params, ways, tuple(saved))
+            left = limit - _SAVE_RESERVE - resident - fullest.bytes
+            kept += ("; fullest at %s, %d bytes with the state; room %d "
+                     "bytes before a name is kept, %d with these" % (
+                         fullest.name, resident + fullest.bytes,
+                         _room_bytes(cfg, tokens, resident, params, limit,
+                                     ways), left))
+            tracing.count("train.saved_room_bytes", left)
         # static, so counted as the step is traced: once a step's program
+        tracing.count("train.saved_names", len(saved))
+        tracing.count("train.saved_bytes", sum(saved.values()))
         buffers, their_bytes, widest = own_buffers(
             state["params"]["blocks"], cfg)
         tracing.count("train.own_buffers", buffers)

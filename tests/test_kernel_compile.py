@@ -508,30 +508,41 @@ def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
     """Arithmetic alone, `nemotron3nano.tokens8k` at 2 x 8192 tokens and a
     limit of 15.75 GiB. On the kernels' path the scan's part of a block's
     backward is the entering states and the columns and rows of dt and cum
-    (0.75 GB) where the `jax.numpy` path holds [H, Q, Q] arrays (2.68 GB):
-    the room is 2.14 GB and the rule keeps `mamba_in` (1.35 GB) after
-    attention's three names; `ssd_out` (0.54 GB) no longer fits. With the
-    `jax.numpy` scan the room is 0.20 GB and `attn_ctx` alone is kept."""
+    (0.75 GB) where the `jax.numpy` path holds [H, Q, Q] arrays (2.68 GB).
+    The stack is one period of nine layers, which the rule walks a layer at
+    a time (PR 54): its fullest moment is the last mixer's backward, with
+    the names of the eight layers up to it and no gradient but what a loop
+    accumulates. The room is 3.83 GB and the rule keeps every name, 2.75
+    GB, with 1.20 GB left; with the `jax.numpy` scan the room is 1.89 GB
+    and the names end with `mamba_in` (1.35 GB), 0.17 GB left."""
     from chipbench import spec
     from chipbench.loops import nemotron_h
     from ray_tpu.models import transformer as tr
 
     config = spec.load_cell(spec.ROOT, "nemotron3nano.tokens8k")["config"]
     tokens = 2 * 8192
+    params = 4 * 666962944
 
     def kept(impl):
         cfg = nemotron_h.model_config(dict(config, attention_impl=impl))
-        params = 4 * 666962944
         return cfg, tr.saved_activations(
             cfg, tokens, 3 * params, params, HBM_LIMIT)
 
     cfg, chosen = kept("pallas")
     assert tr._scan_bytes_per_token(cfg) * tokens == 746586112
     assert chosen == {"attn_ctx": 136314880, "attn_res": 88080384,
-                      "attn_qkv": 150994944, "mamba_in": 1350565888}
+                      "attn_qkv": 150994944, "mamba_in": 1350565888,
+                      "ssd_out": 536870912, "shared_up": 486539264}
+    assert tr._fullest_moment(
+        cfg, tokens, params, kept=tuple(chosen)).name == "layer 7"
+    assert tr._room_bytes(
+        cfg, tokens, 3 * params, params, HBM_LIMIT) == 3828756480
     cfg, chosen = kept("xla")
     assert tr._scan_bytes_per_token(cfg) * tokens == 64 * 128 * 20 * tokens
-    assert chosen == {"attn_ctx": 136314880}
+    assert chosen == {"attn_ctx": 136314880, "attn_res": 88080384,
+                      "attn_qkv": 150994944, "mamba_in": 1350565888}
+    assert tr._room_bytes(
+        cfg, tokens, 3 * params, params, HBM_LIMIT) == 1890988032
 
 
 # ------------------------- the token cells' steps with what remat keeps
@@ -549,8 +560,9 @@ TOKEN_CELLS = {
     "nemotron3nano.tokens8k": ((1, 2), (20, 20)),  # a share, as above
 }
 # (`ssd_fwd`, `ssd_bwd`) calls in the text: four mixers, each run forward,
-# forward again under remat (their `ssd_out` is not kept, and the backward
-# wants the entering states) and backward
+# forward again under remat (their `ssd_out` is kept since PR 54, but the
+# backward wants the entering states, which only the forward kernel makes)
+# and backward
 SCAN_CALLS = {"nemotron3nano.tokens8k": (8, 4)}
 
 
@@ -649,7 +661,7 @@ def test_token_step_with_what_it_keeps_compiles_and_fits(
     scans = SCAN_CALLS.get(cell_name, (0, 0))
     assert (_calls(text, "ssd_fwd"), _calls(text, "ssd_bwd")) == scans
     if any(scans):
-        assert "mamba_in" in chosen and "ssd_out" not in chosen
+        assert "mamba_in" in chosen and "ssd_out" in chosen
         # a sequence's [n, H, Q, Q] of decays or masked scores is nowhere
         assert "f32[2,64,64,128,128]" not in text
         assert "bf16[2,64,64,128,128]" not in text

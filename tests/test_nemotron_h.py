@@ -458,10 +458,12 @@ def test_saved_activations_name_only_what_each_kind_has():
     args = (cfg, tokens, state, state // 3)
     assert saved_activations(*args, None) == {}
     assert saved_activations(*args, 1 << 40) == sizes
+    # the stack is one period of nine layers, so it is walked a layer at a
+    # time: a limit that the step's fullest moment with four names just fits
+    four = ("attn_ctx", "attn_res", "attn_qkv", "mamba_in")
     chosen = saved_activations(
-        *args, state + state // 3 + model._SAVE_RESERVE
-        + model._working_set_bytes(cfg, tokens, state // 3)
-        + sum(list(sizes.values())[:4]))
+        *args, state + model._SAVE_RESERVE + model._fullest_moment(
+            cfg, tokens, state // 3, kept=four).bytes)
     assert list(chosen) == ["attn_ctx", "attn_res", "attn_qkv", "mamba_in"]
     # the scan's masks are in the working set: H Q values a token, in
     # float32 and the compute dtype

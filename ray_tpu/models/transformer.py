@@ -88,6 +88,23 @@ step's loss (`index_loss` among the readings, with `index_keys_min_gap` and
 `min(t + 1, index_topk)`, both 0). Cross-entropy moves no indexer leaf and
 the index loss moves nothing else. Those are Keye-VL-2.0's (`keye_vl2`).
 
+A seventh operator is Kimi Delta Attention (`"kda"` in `layer_types`;
+arXiv:2510.26692): from the normed input `kda_heads` heads of queries, keys
+and values `kda_head_dim` wide, each through a causal convolution of
+`kda_conv_taps` taps a channel (`_causal_taps`) and a silu, q and k then
+scaled to unit length a head; a log decay a *channel* of the key,
+`-exp(A_log) softplus(W_f2 W_f1 x + dt_bias)`, and `beta = 2 sigmoid(W_b
+x)` a head, in float32; the delta-rule recurrence over a matrix state in its
+chunked form (`ops/kda.py`); an RMS norm over each head's output under a
+low-rank sigmoid gate, and the output projection. `heads_held = (first, n)`
+makes attention and this operator hold `n` of their heads, `first` onward,
+as one chip of a tensor-parallel deployment does: the heads' columns of the
+input projections (for attention the key-value heads those query heads
+read), their rows of the output projection, and the mixer's output is the
+partial sum over them; nothing stands in for the other chips.
+`attn_gate="elementwise"` gates plain attention's context by a sigmoid as
+wide as the context. Those are Solar-Open2-250B's (`solar_open2`).
+
 Under a mesh with an `expert` axis (`make_mesh({"expert": n})`) a routed
 stack is expert-parallel, nothing of a layer left out: a device holds
 `n_experts / n` whole experts of every layer (the experts' leaves cut on
@@ -149,8 +166,8 @@ Capability analog of what the reference reaches only through integrations
 (SURVEY §5: it ships no native SP); here it is native. Cells that train it:
 `mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k`,
 `lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`,
-`lagunaxs2.tokens8k`, `keyevl2.tokens16k`, and `mellum2.ep4` on the four
-chips of an `expert` axis (BENCHMARK.json).
+`lagunaxs2.tokens8k`, `keyevl2.tokens16k`, `solaropen2.tokens8k`, and
+`mellum2.ep4` on the four chips of an `expert` axis (BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -170,6 +187,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
+from ray_tpu.ops.kda import SUB as _KDA_SUB, kda
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
 from ray_tpu.ops.ssd import scan_untiled, ssd
 from ray_tpu.ops.fused import (
@@ -215,7 +233,7 @@ class TransformerConfig:
     router_z_loss_coef: float = 0.001  # logsumexp(router logits)^2
     # an operator of `_OPERATORS` per layer ("full_attention" |
     # "sliding_attention" | "sparse_attention" | "conv" |
-    # "latent_attention"); () => attention everywhere
+    # "latent_attention" | "kda"); () => attention everywhere
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3  # the short convolution's reach, this token included
     n_dense_layers: int = 0  # with n_experts: leading layers with a dense FF
@@ -277,15 +295,29 @@ class TransformerConfig:
     # the share of a head's columns, the first ones, that full attention
     # turns, at `rope_theta` and, where there is one, `rope_scaling`
     partial_rotary_factor: float = 1.0
-    # one sigmoid gate a head and token on plain attention's output, from
-    # the normed input (`w_gate_attn` [d, heads])
-    attn_gate: bool = False
+    # a sigmoid gate on plain attention's output, from the normed input:
+    # one a head and token (True: `w_gate_attn` [d, heads]) or one a column
+    # of the context ("elementwise": [d, heads x head_dim])
+    attn_gate: Union[bool, str] = False
     # "sparse_attention": `index_heads` index queries of `index_head_dim`
     # over one index key a token; a query attends to the `index_topk`
     # earlier keys its index scores rank first
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # "kda" (arXiv:2510.26692), by `linear_attn_config`'s own keys:
+    # `num_heads` heads of `head_dim`, `short_conv_kernel_size` taps; the
+    # low-rank gates' rank (None => `kda_head_dim`), the tokens a chunk of
+    # `ops/kda.py`. The decay's `A_log` and `dt_bias` start as the Mamba-2
+    # mixer's do (`mamba_dt_init`)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_taps: int = 4
+    kda_gate_rank: Optional[int] = None
+    kda_chunk: int = 64
+    # (first, n): plain attention and "kda" hold heads first..first+n-1 of
+    # theirs, and attention the key-value heads those query heads read
+    heads_held: Optional[Tuple[int, int]] = None
 
     @property
     def kv_heads(self) -> int:
@@ -354,6 +386,12 @@ class TransformerConfig:
                 raise ValueError(
                     f"{field_} names {name!r}, which is none of "
                     f"{sorted(known)}")
+            if (self.heads_held and name in _OPERATORS
+                    and not _OPERATORS[name].takes_heads_held):
+                raise ValueError(
+                    f"heads_held {tuple(self.heads_held)} with the operator "
+                    f"{name!r}, which holds all its heads or none: a share "
+                    "of the heads is plain attention's and kda's")
         if self.sublayer_types:
             return tuple(
                 LayerKind(None, name == "routed_ff", True)
@@ -541,10 +579,11 @@ def _attention_layer(x, blk, positions, cfg: TransformerConfig,
     with jax.named_scope("attention"):
         o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh, keep_ctx,
                        window=op.window(cfg))
-    if "w_gate_attn" in blk:
+    if "w_gate_attn" in blk:  # a column a head, or one a column of a head
         with jax.named_scope("attn_gate"):
             gate = jax.nn.sigmoid(y @ blk["w_gate_attn"].astype(dt))
-            o = o * gate[..., None]
+            o = o * (gate.reshape(B, T, h, dh)
+                     if cfg.attn_gate == "elementwise" else gate[..., None])
     with jax.named_scope("attn_out"):
         return checkpoint_name(
             x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt), "attn_res")
@@ -555,7 +594,7 @@ def _qkv(x, blk, positions, cfg: TransformerConfig, op: "_PlainAttention"):
     of plain attention: the projections, QK-norm and RoPE by `op`'s
     recipe."""
     B, T, d = x.shape
-    h, hk, dh = op.heads(cfg), cfg.kv_heads, cfg.head_dim
+    h, hk, dh = op.heads(cfg), op.kv_heads(cfg), cfg.head_dim
     theta, share, scaling = op.rotary(cfg)
     dt = cfg.dtype
     per_head = cfg.qk_norm == "head"
@@ -915,6 +954,60 @@ def _mamba_mixer(x, blk, cfg: TransformerConfig):
         return y @ blk["w_out"].astype(dt_)
 
 
+def _unit_length(x, eps: float = 1e-6):
+    """Every head of `x` [..., dk] over its own length, in float32."""
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(
+        jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)).astype(x.dtype)
+
+
+def _kda_mixer(x, blk, cfg: TransformerConfig):
+    """(the KDA mixer on `x` [B, T, d], its readings {kda_log_decay_min,
+    kda_beta_mean}) over the `H` heads the layer holds (arXiv:2510.26692):
+    `q`, `k`, `v` = silu(conv(norm(x) W)), a causal convolution of
+    `kda_conv_taps` taps a channel without a bias, q and k then of unit
+    length a head; the log decay a key channel `g = -exp(A_log)
+    softplus(W_f2 (W_f1 u) + dt_bias)` and `beta = 2 sigmoid(W_b u)` a
+    head, in float32; the recurrence in its chunked form (`ops/kda.py`);
+    `RMSNorm(o)` over each head's width with one learned scale, times
+    `sigmoid(W_g2 (W_g1 u) + b_g)`; `W_o`. The three narrow products
+    (`W_f1`, `W_g1`, `W_b`) are one matmul."""
+    B, T, d = x.shape
+    H, dk = _OPERATORS["kda"].heads(cfg), cfg.kda_head_dim
+    rank = blk["kda_f1"].shape[-1]
+    dt = cfg.dtype
+    with jax.named_scope("kda_in"):
+        u = fused_rmsnorm(x, blk["kda_norm"], eps=cfg.norm_eps)
+        q, k, v = (checkpoint_name(u @ blk[name].astype(dt), "kda_qkv")
+                   for name in ("kda_q", "kda_k", "kda_v"))
+        narrow = u @ jnp.concatenate(
+            [blk[name].astype(dt) for name in ("kda_f1", "kda_g1", "kda_b")],
+            axis=-1)
+        f_low, g_low, b_low = jnp.split(narrow, (rank, 2 * rank), axis=-1)
+    with jax.named_scope("kda_conv"):
+        taps = blk["kda_conv"].astype(dt)  # [3, taps, H dk]: q's, k's, v's
+        q, k, v = (jax.nn.silu(_causal_taps(s, taps[i])).reshape(B, T, H, dk)
+                   for i, s in enumerate((q, k, v)))
+        q, k = _unit_length(q), _unit_length(k)
+    with jax.named_scope("kda_gates"):
+        f32 = jnp.float32
+        log_decay = -jnp.exp(blk["kda_A_log"].astype(f32))[:, None] * (
+            jax.nn.softplus(
+                (f_low @ blk["kda_f2"].astype(dt)).astype(f32)
+                + blk["kda_dt_bias"].astype(f32)).reshape(B, T, H, dk))
+        beta = 2.0 * jax.nn.sigmoid(b_low.astype(f32))
+        gate = jax.nn.sigmoid(
+            (g_low @ blk["kda_g2"].astype(dt)).astype(f32)
+            + blk["kda_g_bias"].astype(f32)).astype(dt)
+    # names its own operations `kda_chunk`, `kda_state` and `kda_out`
+    o, _, log_decay_min = kda(q, k, v, log_decay, beta, chunk=cfg.kda_chunk)
+    with jax.named_scope("kda_out"):
+        o = fused_rmsnorm(o, blk["kda_out_norm"], eps=cfg.norm_eps)
+        y = (o.reshape(B, T, H * dk) * gate) @ blk["kda_o"].astype(dt)
+    return y, {"kda_log_decay_min": log_decay_min,
+               "kda_beta_mean": beta.mean()}
+
+
 def _feed_forward(y, blk, dt, names, prefix: str = "w"):
     """A dense feed-forward on normed `y`: `(silu(y W_gate) * y W_up)
     W_down`, or without a gate's weights `relu(y W_up)^2 W_down`. `names` is
@@ -1009,6 +1102,8 @@ class Sublayer:
     names: Tuple[str, ...] = ()
     # why `forward` cannot be mapped over a sequence axis; None: it can
     no_sequence_axis: Optional[str] = None
+    # an operator that `heads_held` makes hold a share of its heads
+    takes_heads_held: bool = False
 
     def init(self, key, cfg: TransformerConfig, L: int) -> Dict[str, Any]:
         """`L` stacked layers' leaves, float32, from the layer's key. The
@@ -1024,8 +1119,8 @@ class Sublayer:
 
     def forward(self, x, blk, cfg: TransformerConfig, site: _Site):
         """(the stream after the sublayer, residual added; the readings of
-        a routed feed-forward or of sparse attention, else None), under the
-        sublayer's
+        a routed feed-forward, of sparse attention or of kda, else None),
+        under the sublayer's
         `jax.named_scope`s: they name the step's device work in a profiler
         trace (docs/observability.md, "Device scopes") and are metadata
         only. Optional leaves are found by presence: no `w_gate_attn`, no
@@ -1060,6 +1155,7 @@ class _PlainAttention(Sublayer):
 
     matmuls = ("wq", "wk", "wv", "wo", "w_gate_attn")
     names = ("attn_ctx", "attn_res", "attn_qkv")
+    takes_heads_held = True
 
     def __init__(self, sliding: bool):
         self.sliding = sliding
@@ -1068,10 +1164,32 @@ class _PlainAttention(Sublayer):
                 "attention under a window is not mapped over a sequence "
                 "axis: ring attention has no band")
 
-    def heads(self, cfg) -> int:
+    def all_heads(self, cfg) -> int:
+        """The operator's query heads, on however many chips."""
         if self.sliding:
             return cfg.n_heads_sliding or cfg.n_heads
         return cfg.n_heads
+
+    def heads(self, cfg) -> int:
+        """The query heads a layer holds: `heads_held`'s, else all."""
+        return cfg.heads_held[1] if cfg.heads_held else self.all_heads(cfg)
+
+    def kv_heads(self, cfg) -> int:
+        """The key-value heads a layer holds: those its query heads read.
+        A share is whole groups of query heads, or part of one group."""
+        if not cfg.heads_held:
+            return cfg.kv_heads
+        first, n = cfg.heads_held
+        group = self.all_heads(cfg) // cfg.kv_heads
+        whole = first % group == 0 and n % group == 0
+        inside = first // group == (first + n - 1) // group
+        if not 0 <= first < first + n <= self.all_heads(cfg) or not (
+                whole or inside):
+            raise ValueError(
+                f"heads_held {(first, n)} of {self.all_heads(cfg)} query "
+                f"heads in groups of {group} a key-value head: a share is "
+                "whole groups, or lies inside one")
+        return n // group if whole else 1
 
     def rotary(self, cfg):
         """(theta, the share of a head's columns that turns, `rope_scaling`
@@ -1085,19 +1203,31 @@ class _PlainAttention(Sublayer):
     def window(self, cfg) -> Optional[int]:
         return cfg.sliding_window if self.sliding else None
 
+    def _gate_width(self, cfg) -> int:
+        """`w_gate_attn`'s columns: none, one a head, or the context's."""
+        if cfg.attn_gate not in (False, True, "elementwise"):
+            raise ValueError(f"attn_gate {cfg.attn_gate!r}")
+        if not cfg.attn_gate:
+            return 0
+        return self.heads(cfg) * (
+            cfg.head_dim if cfg.attn_gate == "elementwise" else 1)
+
     def init(self, key, cfg, L):
-        d, hk, dh, h = cfg.d_model, cfg.kv_heads, cfg.head_dim, self.heads(cfg)
+        d, dh, h = cfg.d_model, cfg.head_dim, self.heads(cfg)
+        hk = self.kv_heads(cfg)
         ks = jax.random.split(key, 7)
         leaves = {
             "attn_norm": jnp.ones((L, d), jnp.float32),
             "wq": _dense(ks[0], (L, d, h * dh), d),
             "wk": _dense(ks[1], (L, d, hk * dh), d),
             "wv": _dense(ks[2], (L, d, hk * dh), d),
-            "wo": _dense(ks[3], (L, h * dh, d), h * dh),
+            # a share's fan-in is all the operator's heads': the partial
+            # sums of the shares add up to a product of that width
+            "wo": _dense(ks[3], (L, h * dh, d), self.all_heads(cfg) * dh),
         }
         if cfg.attn_gate:
             leaves["w_gate_attn"] = _dense(
-                jax.random.fold_in(key, 9), (L, d, h), d)
+                jax.random.fold_in(key, 9), (L, d, self._gate_width(cfg)), d)
         if cfg.qk_norm:
             per_head = cfg.qk_norm == "head"
             leaves["q_norm"] = jnp.ones(
@@ -1114,7 +1244,7 @@ class _PlainAttention(Sublayer):
             "wv": ("layers", "embed", "kv"),
             "wo": ("layers", "heads", "embed"),
         }
-        if cfg.attn_gate:  # a column a head
+        if cfg.attn_gate:  # a column a head, or the heads' columns
             table["w_gate_attn"] = ("layers", "embed", "heads")
         if cfg.qk_norm == "head":  # one scale for all heads
             table.update(q_norm=("layers", None), k_norm=("layers", None))
@@ -1138,21 +1268,24 @@ class _PlainAttention(Sublayer):
             # o and lse as one float32 column
             "attn_ctx": h * _tile_lanes(dh) + h * 4 // _item(cfg),
             "attn_res": cfg.d_model,
-            "attn_qkv": (h + 2 * cfg.kv_heads) * dh,
+            "attn_qkv": (h + 2 * self.kv_heads(cfg)) * dh,
         }
 
     def params(self, cfg):
-        d, hk, dh, h = cfg.d_model, cfg.kv_heads, cfg.head_dim, self.heads(cfg)
-        return (d * (h + 2 * hk) * dh + h * dh * d
-                + (d * h if cfg.attn_gate else 0))
+        d, dh, h = cfg.d_model, cfg.head_dim, self.heads(cfg)
+        return (d * (h + 2 * self.kv_heads(cfg)) * dh + h * dh * d
+                + d * self._gate_width(cfg))
 
     def holds(self, cfg):
         """q, k and v as the kernel takes them (q at the operator's heads, k
         and v at the key-value heads, which the kernels' index maps share
         among a group), lse and delta at a tile's 128 lanes."""
         h = self.heads(cfg)
-        return ((h + 2 * cfg.kv_heads) * _tile_lanes(cfg.head_dim)
-                + 2 * h * 128 * 4 // _item(cfg))
+        return ((h + 2 * self.kv_heads(cfg)) * _tile_lanes(cfg.head_dim)
+                + 2 * h * 128 * 4 // _item(cfg)
+                # a gate as wide as the context: its product
+                + (self._gate_width(cfg)
+                   if cfg.attn_gate == "elementwise" else 0))
 
     def flops(self, cfg, seq_len):
         # qk^T and pv each cost 2 h dh operations a (query, key) pair, over
@@ -1173,6 +1306,8 @@ class _SparseAttention(_PlainAttention):
     (`k_idx_norm`, `k_idx_bias`), `w_idx` [d, index_heads]."""
 
     matmuls = (*_PlainAttention.matmuls, "wq_idx", "wk_idx", "w_idx")
+    # the index loss reads the probabilities of every head
+    takes_heads_held = False
     no_sequence_axis = (
         "sparse attention is not mapped over a sequence axis: a query "
         "chooses among all the keys before it, and the selection and the "
@@ -1483,6 +1618,130 @@ class _Mamba2(Sublayer):
         return 2 * self.params(cfg) + scan, 0
 
 
+class _KDA(Sublayer):
+    """Kimi Delta Attention (`_kda_mixer`) over the heads `heads_held`
+    names, all `kda_heads` where it names none."""
+
+    matmuls = ("kda_q", "kda_k", "kda_v", "kda_o", "kda_f1", "kda_f2",
+               "kda_g1", "kda_g2", "kda_b")
+    names = ("kda_res", "kda_qkv")
+    no_sequence_axis = "kda is not mapped over a sequence axis"
+    takes_heads_held = True
+
+    def heads(self, cfg) -> int:
+        if not cfg.heads_held:
+            return cfg.kda_heads
+        first, n = cfg.heads_held
+        if not 0 <= first < first + n <= cfg.kda_heads:
+            raise ValueError(
+                f"heads_held {(first, n)} of {cfg.kda_heads} kda heads")
+        return n
+
+    def _rank(self, cfg) -> int:
+        return cfg.kda_gate_rank or cfg.kda_head_dim
+
+    def init(self, key, cfg, L):
+        """The decay starts as the Mamba-2 mixer's does: `exp(A_log)` a
+        head uniform in [1, 16], `softplus(dt_bias)` a channel log-uniform
+        in `mamba_dt_init`'s range and no less than its floor. The gates'
+        second factors draw at their rank's fan-in, the output gate's bias
+        at 0, and `kda_o` at the fan-in of all the operator's heads."""
+        d, H, dk, r = cfg.d_model, self.heads(cfg), cfg.kda_head_dim, (
+            self._rank(cfg))
+        if not (cfg.kda_heads and dk):
+            raise ValueError("kda needs kda_heads and kda_head_dim, not "
+                             f"{cfg.kda_heads} and {dk}")
+        wide, taps = H * dk, cfg.kda_conv_taps
+        ks = jax.random.split(jax.random.split(key, 7)[0], 12)
+        dt_min, dt_max, dt_floor = cfg.mamba_dt_init
+        dt = jnp.maximum(dt_floor, jnp.exp(jax.random.uniform(
+            ks[10], (L, wide), jnp.float32,
+            math.log(dt_min), math.log(dt_max))))
+        return {
+            "kda_norm": jnp.ones((L, d), jnp.float32),
+            "kda_q": _dense(ks[0], (L, d, wide), d),
+            "kda_k": _dense(ks[1], (L, d, wide), d),
+            "kda_v": _dense(ks[2], (L, d, wide), d),
+            "kda_conv": _dense(ks[3], (L, 3, taps, wide), taps),
+            "kda_f1": _dense(ks[4], (L, d, r), d),
+            "kda_f2": _dense(ks[5], (L, r, wide), r),
+            "kda_g1": _dense(ks[6], (L, d, r), d),
+            "kda_g2": _dense(ks[7], (L, r, wide), r),
+            "kda_g_bias": jnp.zeros((L, wide), jnp.float32),
+            "kda_b": _dense(ks[8], (L, d, H), d),
+            "kda_A_log": jnp.log(jax.random.uniform(
+                ks[9], (L, H), jnp.float32, 1.0, 16.0)),
+            "kda_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus's inverse
+            "kda_out_norm": jnp.ones((L, dk), jnp.float32),
+            "kda_o": _dense(ks[11], (L, wide, d), cfg.kda_heads * dk),
+        }
+
+    def axes(self, cfg):
+        # cut along the heads where a leaf is the heads'; the gates' first
+        # factors and the output norm's one scale are whole
+        heads = ("layers", "embed", "heads")
+        return {
+            "kda_norm": ("layers", None),
+            "kda_q": heads, "kda_k": heads, "kda_v": heads,
+            "kda_conv": ("layers", None, None, "heads"),
+            "kda_f1": ("layers", "embed", None),
+            "kda_f2": ("layers", None, "heads"),
+            "kda_g1": ("layers", "embed", None),
+            "kda_g2": ("layers", None, "heads"),
+            "kda_g_bias": ("layers", "heads"),
+            "kda_b": heads,
+            "kda_A_log": ("layers", "heads"),
+            "kda_dt_bias": ("layers", "heads"),
+            "kda_out_norm": ("layers", None),
+            "kda_o": ("layers", "heads", "embed"),
+        }
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("kda"):
+            y, readings = _kda_mixer(x, blk, cfg)
+            return checkpoint_name(x + y, "kda_res"), readings
+
+    def widths(self, cfg):
+        return {"kda_res": cfg.d_model,
+                "kda_qkv": 3 * self.heads(cfg) * cfg.kda_head_dim}
+
+    def params(self, cfg):
+        d, H, r = cfg.d_model, self.heads(cfg), self._rank(cfg)
+        wide = H * cfg.kda_head_dim
+        return 4 * d * wide + 2 * d * r + 2 * r * wide + d * H
+
+    def holds(self, cfg):
+        """In elements of the compute dtype a token, `wide` the held heads'
+        width: q, k and v out of the convolution and its silu, q and k at
+        unit length, the gate and the gated output (7 wide); in float32 the
+        log decay, its running sum, the three decayed copies of q and k
+        that `ops/kda.py` makes beside one of the keys a sub-chunk, and
+        each one's cotangent; the pair tensors of a sub-chunk, `SUB` values
+        a channel and token each (every pair's decay and its product with
+        the rows, for k with k and for q with k), with their cotangents; a
+        chunk's entering state (`dk` values a channel and chunk) and the
+        fresh values, float32. Against the chip (`solaropen2.tokens8k`, my
+        chip runs, PR 55): with two pair tensors priced the rule's sum for
+        the seven names it keeps was 14.56 GB where the compiler plans
+        15.36 for a described v5e and the chip peaks at 15.46; with four
+        it is 15.63."""
+        wide, dk = self.heads(cfg) * cfg.kda_head_dim, cfg.kda_head_dim
+        f32 = 4 // _item(cfg) or 1
+        copies = 2 + 3 + cfg.kda_chunk // _KDA_SUB
+        return (7 * wide + 2 * f32 * copies * wide
+                + 4 * f32 * _KDA_SUB * wide
+                + f32 * (wide * dk // cfg.kda_chunk + wide))
+
+    def flops(self, cfg, seq_len):
+        H, dk, C = self.heads(cfg), cfg.kda_head_dim, cfg.kda_chunk
+        # the chunked form as it is computed, whole chunks, a head: the
+        # pair products of k with k and of q with k, `W` and `U`, the
+        # scores' product with the fresh values (2 C dk each); `W S`, `q S`
+        # and the state's update (2 dk dk each)
+        chunked = H * (5 * 2 * C * dk + 3 * 2 * dk * dk)
+        return 2 * self.params(cfg) + chunked, 0
+
+
 def _ff_gates(cfg) -> Tuple[str, ...]:
     """The products of a feed-forward that have names, but for `up`."""
     return ("gate",) if cfg.gated else ()
@@ -1643,6 +1902,7 @@ _OPERATORS: Dict[str, Sublayer] = {
     "latent_attention": _LatentAttention(),
     "conv": _ShortConv(),
     "mamba2": _Mamba2(),
+    "kda": _KDA(),
 }
 _FEED_FORWARDS: Dict[str, Sublayer] = {
     "dense_ff": _DenseFF(),
@@ -1827,6 +2087,11 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig, kind: LayerKind,
         for sub in sublayers:
             if sub.no_sequence_axis:
                 raise NotImplementedError(sub.no_sequence_axis)
+    if cfg.heads_held and mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            f"heads_held {tuple(cfg.heads_held)} is one chip's share of the "
+            f"heads: no `tensor` axis sums the partial results over a mesh "
+            f"of {mesh.size} devices yet")
     readings = None
     for sub in sublayers:  # an operator's readings and the feed-forward's
         x, made = sub.forward(x, blk, cfg, site)
@@ -1835,17 +2100,22 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig, kind: LayerKind,
     return x, readings
 
 
-def _layer_axis(trees, stack: bool):
-    """Trees of arrays with a leading layer axis as one such tree: `stack`
-    interleaves them (the layers of a period, scanned over the periods),
-    else they follow one another (segments). One tree is itself."""
-    if len(trees) == 1:
-        return trees[0]
-    if stack:
-        return jax.tree.map(
-            lambda *xs: jnp.stack(xs, axis=1).reshape(-1, *xs[0].shape[1:]),
-            *trees)
-    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *trees)
+def _layer_axis(readings, stack: bool):
+    """Layers' readings ({name: array with a leading layer axis}) as one
+    such mapping, each reading over the layers that make it (a routed
+    layer's, an operator's): `stack` interleaves them (the layers of a
+    period, scanned over the periods), else they follow one another
+    (segments)."""
+    def joined(xs):
+        if len(xs) == 1:
+            return xs[0]
+        if stack:
+            return jnp.stack(xs, axis=1).reshape(-1, *xs[0].shape[1:])
+        return jnp.concatenate(xs, axis=0)
+
+    names = dict.fromkeys(name for made in readings for name in made)
+    return {name: joined([made[name] for made in readings if name in made])
+            for name in names}
 
 
 def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
@@ -1989,7 +2259,9 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     `index_keys_min_gap` and `index_keys_max_gap` (over rows and layers,
     the least and the most a row kept beyond what it should: 0) and
     `index_keep` [L, B, T, T] int8 (1 where a query keeps a key; the step
-    does not report it)."""
+    does not report it). Layers of kda read `kda_log_decay_min` (the most
+    negative running log decay at a chunk's end, over layers, heads and
+    channels) and `kda_beta_mean`."""
     if "targets" in batch:
         tokens, targets = batch["tokens"], batch["targets"]
     else:
@@ -2006,6 +2278,10 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
             index_keys_min_gap=readings["index_keys_min_gap"].min(),
             index_keys_max_gap=readings["index_keys_max_gap"].max())
         loss = loss + readings["index_loss"]
+    if "kda_beta_mean" in readings:  # over the kda layers
+        readings = dict(
+            readings, kda_beta_mean=readings["kda_beta_mean"].mean(),
+            kda_log_decay_min=readings["kda_log_decay_min"].min())
     if "aux_loss" not in readings:
         return loss, readings
     # over the layers: the mean, or with `seq_aux` the sum, as the published
@@ -2049,6 +2325,8 @@ _SAVE_ORDER = (
     "attn_qkv",   # the q, k, v products, before QK-norm, RoPE and GQA's repeat
                   # (latent attention: out of `wq`, `wkv_a` and `wkv_b`)
     "conv_in",    # the three streams out of `conv_in`
+    "kda_res",    # the stream after kda: no second `kda_o` product
+    "kda_qkv",    # kda's q, k, v products, before the convolution
     "mamba_in",   # the mixer's gate, x, B, C and dt out of `w_in`
     "ssd_out",    # the scan's output, before the gate and the norm
     "moe_gate",   # the experts' gate product [slots, f]
@@ -2381,7 +2659,8 @@ def _memory_limit(mesh) -> Optional[int]:
 # what a routed model's step reports beside loss and grad_norm
 _STEP_READINGS = ("aux_loss", "z_loss", "expert_load", "held_slots",
                   "dropped_slots", "chip_load", "chip_load_max_over_mean",
-                  "index_loss", "index_keys_min_gap", "index_keys_max_gap")
+                  "index_loss", "index_keys_min_gap", "index_keys_max_gap",
+                  "kda_log_decay_min", "kda_beta_mean")
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
@@ -2392,7 +2671,8 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
     loss and grad_norm, and with routed experts aux_loss, z_loss and
     expert_load [L, E]; of a share of the experts also held_slots and
     dropped_slots [L]; with sparse attention index_loss, index_keys_min_gap
-    and index_keys_max_gap. With `cfg.expert_bias` the state has 'expert_bias'
+    and index_keys_max_gap; with kda kda_log_decay_min and kda_beta_mean.
+    With `cfg.expert_bias` the state has 'expert_bias'
     [L, E] float32, which the optimizer does not own: after the optimizer's
     update the step moves it by `expert_bias_update_rate` toward the experts
     that this step's load left short, and reports expert_bias_abs_max.

@@ -52,6 +52,10 @@ NEMOTRON_SCOPES = {"mamba", "mamba_in", "mamba_conv", "ssd", "ssd_chunk",
 # on the chip (`index_scores` is the `jax.numpy` path's alone)
 KEYE_SCOPES = {"sparse_attention", "indexer", "index_qk", "index_scores",
                "index_select", "index_loss"}
+# KDA's parts inside `kda` (`kda_chunk`, `kda_state` and a second `kda_out`
+# are `ops/kda.py`'s own), and the elementwise gate on attention's context
+SOLAR_SCOPES = {"kda", "kda_in", "kda_conv", "kda_gates", "kda_chunk",
+                "kda_state", "kda_out"}
 # the routed layer's exchange over an `expert` mesh axis, inside
 # `mlp/shard_map` beside `moe_router` (PR 50)
 EXCHANGE_SCOPES = {"moe_gather", "moe_scatter"}
@@ -213,6 +217,24 @@ def lowered_nemotron_step():
         moe._ROW_TILE = row_tile
 
 
+def lowered_solar_step():
+    """Attention without rotation under an elementwise gate and two KDA
+    layers, a share of every mixer's heads and of the experts held, one
+    shared expert beside them."""
+    row_tile = moe._ROW_TILE
+    moe._ROW_TILE = 8
+    try:
+        return lowered_transformer_step(
+            n_layers=3, d_head=8, n_heads=8, rope=False,
+            layer_types=("full_attention", "kda", "kda"), heads_held=(4, 4),
+            attn_gate="elementwise", kda_heads=8, kda_head_dim=8,
+            kda_gate_rank=4, kda_chunk=16, n_experts=4, experts_per_token=2,
+            experts_held=(1, 1), n_shared_experts=1, d_ff_shared=48,
+            norm_topk_prob=True, router_z_loss_coef=0.0)
+    finally:
+        moe._ROW_TILE = row_tile
+
+
 def lowered_nemotron_kernel_step():
     """A mixer alone at sizes that tile (a chunk and a state of 128, a group
     of two heads of 64), the scan's kernels in interpret mode: steered
@@ -298,14 +320,18 @@ FAMILIES = {
     "mellum": (lowered_mellum_step,
                TRANSFORMER_SCOPES | (MOE_SCOPES - {"qk_norm"})
                | EXCHANGE_SCOPES | {"sliding_attention"}),
+    "solar_open2": (lowered_solar_step,
+                    TRANSFORMER_SCOPES | (MOE_SCOPES - {"qk_norm"})
+                    | {"moe_shared", "attn_gate"} | SOLAR_SCOPES),
     "resnet": (lowered_resnet_step, RESNET_SCOPES),
 }
 # the scopes that one family alone has, but for those that another family
 # has of them
 OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES,
               "nemotron_h": NEMOTRON_SCOPES, "keye_vl2": KEYE_SCOPES,
-              "mellum": EXCHANGE_SCOPES}
-ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"}}
+              "mellum": EXCHANGE_SCOPES, "solar_open2": SOLAR_SCOPES}
+ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"},
+            "solar_open2": {"moe_shared"}}
 
 
 @pytest.fixture(scope="module")
@@ -402,6 +428,22 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
         assert any(re.search(r"attn_qkv\)*/qk_norm", s) for s in stacks[family])
         assert any(s.startswith("mlp/while/body/moe_experts")
                    for s in stacks[family])
+    elif family == "solar_open2":
+        # the mixer's parts inside `kda`, forward, made again and backward
+        # (the output projection's product is not made again: the stream
+        # after the mixer is kept where there is room, and the backward
+        # needs the operands)
+        for inner in ("kda_in", "kda_conv", "kda_gates", "kda_chunk",
+                      "kda_state", "kda_out"):
+            for prefix in ("", "checkpoint/rematted_computation/", "checkpoint/"):
+                assert any(s.startswith(f"{prefix}kda/{inner}/")
+                           for s in stacks[family]), (inner, prefix)
+        # the solve is `kda_chunk`'s (the tiny step's one chunk a sequence
+        # leaves `kda_state`'s scan no loop to name)
+        assert any(re.match(r"kda/kda_chunk/triangular_solve", s)
+                   for s in stacks[family])
+        assert any(s.startswith("attn_gate/") for s in stacks[family])
+        assert any(s.startswith("mlp/moe_shared/") for s in stacks[family])
     elif family == "nemotron_h":
         # the mixer's five parts inside `mamba`, forward, made again, and
         # backward; the scan's three inside `ssd`
@@ -562,7 +604,7 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
     from chipbench import scopes
 
     program = (TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
-               | DSV2_SCOPES | NEMOTRON_SCOPES | KEYE_SCOPES)
+               | DSV2_SCOPES | NEMOTRON_SCOPES | KEYE_SCOPES | SOLAR_SCOPES)
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     # the benchmark's list is PR 25's three until a `benchmark` issue adds
     # the fourth (PERF.md section 7); its time share matches by prefix

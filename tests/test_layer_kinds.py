@@ -21,6 +21,7 @@ BASE = dict(vocab_size=64, d_model=32, n_layers=1, n_heads=4, n_kv_heads=2,
 ROUTED = dict(n_experts=4, experts_per_token=2, n_shared_experts=1)
 MAMBA = dict(mamba_heads=4, mamba_head_dim=8, ssm_state=8, ssm_groups=2,
              ssd_chunk=8)
+KDA = dict(kda_heads=4, kda_head_dim=8, kda_gate_rank=4, kda_chunk=16)
 # {case: (the table, the record's name, the configuration's keys, the names
 # its forward makes: None for all the record has)}. The case of a record's
 # own name turns on every leaf it can have.
@@ -39,6 +40,10 @@ CASES = {
         v_head_dim=8), None),
     "conv": ("op", "conv", {}, None),
     "mamba2": ("op", "mamba2", MAMBA, None),
+    "kda": ("op", "kda", dict(KDA), None),
+    "kda_share": ("op", "kda", dict(KDA, heads_held=(2, 2)), None),
+    "full_attention_share": ("op", "full_attention", dict(
+        attn_gate="elementwise", heads_held=(2, 2)), None),
     "dense_ff": ("ff", "dense_ff", {}, None),
     "dense_ff_ungated": ("ff", "dense_ff", dict(ff_activation="relu2"),
                          {"mlp_up"}),
@@ -76,7 +81,7 @@ def names_made(jaxpr):
 def test_the_tables_are_what_the_configuration_may_name():
     assert set(model._OPERATORS) == {
         "full_attention", "sliding_attention", "sparse_attention",
-        "latent_attention", "conv", "mamba2"}
+        "latent_attention", "conv", "mamba2", "kda"}
     assert set(model._FEED_FORWARDS) == {"dense_ff", "routed_ff"}
     assert {record for _, record, _, _ in CASES.values()} == (
         set(model._OPERATORS) | set(model._FEED_FORWARDS))

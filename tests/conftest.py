@@ -22,6 +22,10 @@ import threading
 
 import pytest
 
+# The longest watchdog a test outside `slow` may carry: the whole run has
+# 1,470 s, and a stalled test holds its worker and the files queued on it.
+LONGEST_TIER1_WATCHDOG_S = 600
+
 
 def pytest_addoption(parser):
     parser.addini(
@@ -36,6 +40,23 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "timeout(seconds): override the per-test watchdog timeout"
     )
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_pycollect_makeitem(collector):
+    """A longer watchdog outside `slow` fails its file's collection."""
+    made = yield
+    for item in made if isinstance(made, list) else [made]:
+        marker = isinstance(item, pytest.Item) and (
+            not item.get_closest_marker("slow")
+            and item.get_closest_marker("timeout"))
+        if marker and marker.args and (
+                float(marker.args[0]) > LONGEST_TIER1_WATCHDOG_S):
+            raise collector.CollectError(
+                f"{item.nodeid}: timeout({marker.args[0]:g}) is above "
+                f"{LONGEST_TIER1_WATCHDOG_S} s; mark the test `slow` or "
+                "shorten it")
+    return made
 
 
 @pytest.hookimpl(hookwrapper=True)

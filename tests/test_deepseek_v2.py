@@ -25,6 +25,9 @@ from ray_tpu.models.transformer import (
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import flash_attention, flash_tiles, mha
 from ray_tpu.parallel import make_mesh
+from tiny_models import (
+    as_reference_config, batch_of, distance, first_layer, init, key,
+    one_device, program, value_and_grad)
 
 # config.json's own `rope_scaling`
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 0.707,
@@ -44,22 +47,8 @@ DSV2 = dict(
 )
 
 
-def key(i):
-    return jax.random.PRNGKey(i)
-
-
 def tiny(**over):
     return TransformerConfig(**{**DSV2, **over})
-
-
-def as_reference_config(cfg):
-    return {**dataclasses.asdict(cfg), "dtype": "float32",
-            "rope_scaling": dict(cfg.rope_scaling)}
-
-
-def batch_of(cfg, rows=2, seq=32, seed=1):
-    ids = jax.random.randint(key(seed), (rows, seq + 1), 0, cfg.vocab_size)
-    return {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
 
 
 # -------------------------------------------------------------------- YaRN
@@ -215,34 +204,27 @@ def test_parameter_tree_of_the_cut():
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_the_program_agrees_with_the_plain_reference(dtype):
     cfg = tiny(dtype=jnp.dtype(dtype), remat=True)
-    params = transformer_init(key(7), cfg)
+    params = init(key(7), cfg)
     batch = batch_of(cfg, rows=3)
     config = as_reference_config(cfg)
-
-    def ours(p):
-        loss, readings = transformer_loss_and_readings(p, batch, cfg)
-        return loss, readings
-
-    (loss, readings), grads = jax.value_and_grad(ours, has_aux=True)(params)
+    (loss, readings), grads = program(cfg, params, batch)
     index = readings["expert_index"]
-    own_loss, chosen, _ = reference.forward(params, batch, config)
-    (ref_loss, (_, balance)), ref_grads = jax.value_and_grad(
+    own_loss, chosen, _ = jax.jit(
+        lambda p: reference.forward(p, batch, config))(params)
+    (ref_loss, (_, balance)), ref_grads = value_and_grad(
         lambda p: (lambda l, c, b: (l, (c, b)))(
-            *reference.forward(p, batch, config, index)), has_aux=True)(params)
+            *reference.forward(p, batch, config, index)), params, has_aux=True)
     picked = jax.nn.one_hot(index, cfg.n_experts).sum(-2) > 0
     flips = float(jnp.logical_and(picked, ~chosen).sum()) / index.size
-    num = sum(float(jnp.sum((a - b) ** 2)) for a, b in zip(
-        jax.tree.leaves(grads), jax.tree.leaves(ref_grads)))
-    den = sum(float(jnp.sum(b ** 2)) for b in jax.tree.leaves(ref_grads))
     if dtype == "float32":  # the same mathematics to rounding
         assert flips == 0.0 and float(own_loss) == pytest.approx(float(ref_loss))
         assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
-        assert math.sqrt(num / den) < 1e-5
+        assert distance(grads, ref_grads) < 1e-5
         assert float(readings["aux_loss"]) == pytest.approx(float(balance), rel=1e-6)
     else:
         assert flips < 0.05
         assert float(loss) == pytest.approx(float(ref_loss), rel=2e-3)
-        assert math.sqrt(num / den) < 0.08
+        assert distance(grads, ref_grads) < 0.08
     # the loss is the cross-entropy plus alpha times the layers' sum
     assert readings["expert_load"].shape == (2, 8)
     assert int(readings["expert_load"].sum()) == 2 * 3 * 32 * 3
@@ -258,8 +240,6 @@ def test_the_balance_loss_is_per_sequence_and_summed_over_the_layers():
         params, batch, dataclasses.replace(cfg, router_aux_loss_coef=0.0))
     assert float(loss - free) == pytest.approx(
         0.001 * float(readings["aux_loss"]), rel=1e-3)
-    over_batch, _ = transformer_loss_and_readings(
-        params, batch, dataclasses.replace(cfg, seq_aux=False))
     _, batch_readings = transformer_loss_and_readings(
         params, batch, dataclasses.replace(cfg, seq_aux=False))
     # the batch's loss is the mean over the 2 layers, and another number
@@ -299,7 +279,7 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
     monkeypatch.setattr(moe, "_ROW_TILE", 8)
     cfg = tiny(n_layers=1, layer_types=("latent_attention",), n_dense_layers=0,
                experts_held=None)
-    w = jax.tree.map(lambda a: a[0], transformer_init(key(4), cfg)["blocks"])
+    w = first_layer(cfg)
     x = jax.random.normal(key(5), (2, 48, 64))
     positions = jnp.broadcast_to(jnp.arange(48), (2, 48))
     config = as_reference_config(cfg)
@@ -380,8 +360,7 @@ def test_saved_activations_know_the_new_layer():
 
 def test_a_step_trains_and_reports_its_readings():
     cfg = tiny(remat=True)
-    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
-    init_state, step, _ = make_train_step(cfg, mesh)
+    init_state, step, _ = make_train_step(cfg, one_device())
     state = init_state(key(0))
     batch = batch_of(cfg, rows=2)
     losses = []

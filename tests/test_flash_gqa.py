@@ -20,16 +20,13 @@ import pytest
 
 import chip_smoke
 from ray_tpu.ops.flash_attention import flash_attention
+from tiny_models import key
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
 T, D = 256, 32
 TWO = ("flash_bwd_dq", "flash_bwd_dkv")
 KV_ROWS, WIDEST = 2, 8  # key-value rows folded, and the widest group tested
-
-
-def key(i):
-    return jax.random.PRNGKey(i)
 
 
 def operands(batch, kv_heads, group, dtype, seq=T):

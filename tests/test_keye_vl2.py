@@ -31,6 +31,8 @@ from ray_tpu.ops import moe
 from ray_tpu.ops import sparse_attention as sparse
 from ray_tpu.ops.flash_attention import mha
 from ray_tpu.parallel import make_mesh
+import tiny_models
+from tiny_models import first_layer, init, key, one_device
 
 INDEXER = ("wq_idx", "wk_idx", "w_idx", "k_idx_norm", "k_idx_bias")
 
@@ -41,12 +43,7 @@ def _highest_precision():
         yield
 
 
-def key(i):
-    return jax.random.PRNGKey(i)
-
-
-# the seeded weights as one program a configuration, not a leaf at a time
-init = jax.jit(transformer_init, static_argnums=1)
+batch_of = functools.partial(tiny_models.batch_of, seq=48, seed=3)
 
 
 def tiny(**over):
@@ -76,11 +73,6 @@ def as_reference_config(cfg):
         experts_per_token=cfg.experts_per_token,
         experts_held=cfg.experts_held, norm_topk_prob=cfg.norm_topk_prob,
         router_aux_loss_coef=cfg.router_aux_loss_coef)
-
-
-def batch_of(cfg, rows=2, seq=48, seed=3):
-    tokens = jax.random.randint(key(seed), (rows, seq + 1), 0, cfg.vocab_size)
-    return {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
 
 
 def operands(seed, B, T, H, Hk, D, Hi, Di, whole=False):
@@ -511,7 +503,7 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
     shares' routed parts beside them are the uncut reference's layer."""
     monkeypatch.setattr(moe, "_ROW_TILE", 8)
     cfg = tiny(n_layers=1, n_experts=8, experts_held=None)
-    w = jax.tree.map(lambda a: a[0], init(key(4), cfg)["blocks"])
+    w = first_layer(cfg)
     x = jax.random.normal(key(5), (2, 48, 64))
     positions = jnp.broadcast_to(jnp.arange(48), (2, 48))
     config = as_reference_config(cfg)
@@ -558,7 +550,7 @@ def test_one_sequence_s_held_rows_get_buffers_past_their_swing(monkeypatch):
 
     monkeypatch.setattr(moe, "held_chunk", recording)
     cfg = tiny(n_layers=1)
-    w = jax.tree.map(lambda a: a[0], init(key(4), cfg)["blocks"])
+    w = first_layer(cfg)
     for rows in (1, 2):
         x = jax.random.normal(key(5), (rows, 48, 64))
         positions = jnp.broadcast_to(jnp.arange(48), (rows, 48))
@@ -585,8 +577,7 @@ def test_the_published_stack_of_forty_eight_layers_builds_and_steps():
                experts_held=None, index_heads=sa["indexer_num_heads"],
                index_head_dim=4, index_topk=8)
     assert all(k.op == "sparse_attention" and k.routed for k in cfg.layers)
-    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
-    init, step, _ = make_train_step(cfg, mesh)
+    init, step, _ = make_train_step(cfg, one_device())
     state = init(key(0))
     assert state["params"]["blocks"]["wq_idx"].shape == (48, 32, 16 * 4)
     assert state["params"]["blocks"]["router"].shape == (48, 32, 128)

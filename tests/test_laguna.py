@@ -25,11 +25,13 @@ from chipbench.reference import laguna as reference
 from ray_tpu.models import TransformerConfig, make_train_step
 from ray_tpu.models import transformer as model
 from ray_tpu.models.transformer import (
-    param_shardings, saved_activations, segments, transformer_init,
-    transformer_loss_and_readings)
+    param_shardings, saved_activations, segments, transformer_init)
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import flash_attention, flash_tiles, mha
 from ray_tpu.parallel import make_mesh
+from tiny_models import (
+    as_reference_config, batch_of, distance, first_layer, init, key,
+    one_device, program, value_and_grad)
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
@@ -55,22 +57,8 @@ LAGUNA = dict(
 )
 
 
-def key(i):
-    return jax.random.PRNGKey(i)
-
-
 def tiny(**over):
     return TransformerConfig(**{**LAGUNA, **over})
-
-
-def as_reference_config(cfg):
-    return {**dataclasses.asdict(cfg), "dtype": "float32",
-            "rope_scaling": dict(cfg.rope_scaling)}
-
-
-def batch_of(cfg, rows=2, seq=32, seed=1):
-    ids = jax.random.randint(key(seed), (rows, seq + 1), 0, cfg.vocab_size)
-    return {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
 
 
 def masked_softmax(q, k, v, window):
@@ -701,28 +689,24 @@ def test_both_rotary_recipes_by_hand():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_the_program_agrees_with_the_plain_reference(dtype):
     cfg = tiny(dtype=dtype)
-    params = transformer_init(key(0), cfg)
+    params = init(key(0), cfg)
     batch = batch_of(cfg)
     config = as_reference_config(cfg)
     with jax.default_matmul_precision("highest"):
-        (ours, readings), grads = jax.value_and_grad(
-            lambda p: transformer_loss_and_readings(p, batch, cfg),
-            has_aux=True)(params)
-        theirs, wanted = jax.value_and_grad(
+        (ours, readings), grads = program(cfg, params, batch)
+        theirs, wanted = value_and_grad(
             lambda p: reference.loss(p, batch, config,
-                                     readings["expert_index"]))(params)
-    num = sum(float(jnp.sum((a - b) ** 2)) for a, b in zip(
-        jax.tree.leaves(grads), jax.tree.leaves(wanted)))
-    den = sum(float(jnp.sum(b ** 2)) for b in jax.tree.leaves(wanted))
+                                     readings["expert_index"]), params)
     if dtype == jnp.float32:
         assert abs(ours - theirs) / theirs < 1e-6
-        assert math.sqrt(num / den) < 1e-5
-        chosen = reference.forward(params, batch, config)[1]
+        assert distance(grads, wanted) < 1e-5
+        chosen = jax.jit(
+            lambda p: reference.forward(p, batch, config)[1])(params)
         ours_chosen = jax.nn.one_hot(readings["expert_index"], 8).sum(-2) > 0
         assert bool((chosen == ours_chosen).all())
     else:
         assert abs(ours - theirs) / theirs < 2e-3
-        assert math.sqrt(num / den) < 0.12
+        assert distance(grads, wanted) < 0.12
     assert readings["expert_load"].shape == (4, 8)
     assert int(readings["dropped_slots"].sum()) == 0
     # the mean over the layers of E sum_e f_e P_e, sigmoid scores: about E / 2
@@ -737,7 +721,7 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
     monkeypatch.setattr(moe, "_ROW_TILE", 8)
     cfg = tiny(n_layers=1, layer_types=("sliding_attention",),
                n_dense_layers=0, experts_held=None)
-    w = jax.tree.map(lambda a: a[0], transformer_init(key(4), cfg)["blocks"])
+    w = first_layer(cfg)
     x = jax.random.normal(key(5), (2, 48, 64))
     positions = jnp.broadcast_to(jnp.arange(48), (2, 48))
     config = as_reference_config(cfg)
@@ -768,7 +752,9 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
 
 def test_the_published_pattern_of_forty_layers_builds_and_steps():
     """`layer_types`, `num_attention_heads_per_layer` and `mlp_layer_types`
-    as config.json has them, at a tiny width: the cut is a cut of depth."""
+    as config.json has them, at a tiny width: the cut is a cut of depth. The
+    forty build (their state's shapes, no program); the cell's five, a dense
+    and a routed layer of each type among them, step."""
     from chipbench import spec
 
     published = spec.load_cell(
@@ -788,17 +774,18 @@ def test_the_published_pattern_of_forty_layers_builds_and_steps():
     # 48 and 64 heads by layer type, as published, at this test's 6 and 8
     assert [cfg.heads(k.op) * 8 for k in layers] == (
         published["num_attention_heads_per_layer"])
-    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
-    init, step, _ = make_train_step(cfg, mesh)
-    state = init(key(0))
+    state = jax.eval_shape(make_train_step(cfg, one_device())[0], key(0))
     trees = [blk for seg in state["params"]["blocks"] for blk in seg]
     assert len(trees) == 40
     assert [blk["wq"].shape[-1] // 8 for blk in trees] == [
         6 if kind == "full_attention" else 8 for kind in kinds]
-    batch = batch_of(cfg, rows=1, seq=24)
-    state, out = step(state, batch)
+    cut = dataclasses.replace(
+        cfg, n_layers=5, layer_types=kinds[:5], n_dense_layers=1)
+    assert set(cut.layers) == set(layers)
+    init_state, step, _ = make_train_step(cut, one_device())
+    state, out = step(init_state(key(0)), batch_of(cut, rows=1, seq=24))
     assert math.isfinite(float(out["loss"])) and float(out["grad_norm"]) > 0
-    assert out["expert_load"].shape == (39, 8)
+    assert out["expert_load"].shape == (cut.n_routed_layers, 8) == (4, 8)
 
 
 def test_a_window_under_a_sequence_axis_is_refused():

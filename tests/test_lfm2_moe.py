@@ -6,6 +6,7 @@ against something written out plainly and the whole against the benchmark's
 plain reference."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,9 @@ from ray_tpu.models.transformer import (
     transformer_loss_and_readings)
 from ray_tpu.ops import moe
 from ray_tpu.parallel import make_mesh
+from tiny_models import (
+    as_reference_config, batch_of, first_layer, init, key, one_device, program,
+    value_and_grad)
 
 LFM2 = dict(
     vocab_size=128, d_model=64, n_layers=5, n_heads=4, n_kv_heads=2,
@@ -33,21 +37,8 @@ LFM2 = dict(
 )
 
 
-def key(i):
-    return jax.random.PRNGKey(i)
-
-
 def tiny(**over):
     return TransformerConfig(**{**LFM2, **over})
-
-
-def as_reference_config(cfg):
-    return {**dataclasses.asdict(cfg), "dtype": "float32"}
-
-
-def batch_of(cfg, rows=2, seq=32, seed=1):
-    ids = jax.random.randint(key(seed), (rows, seq + 1), 0, cfg.vocab_size)
-    return {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
 
 
 def seeded_bias(cfg, seed=5, std=0.1):
@@ -234,20 +225,19 @@ def test_route_sigmoid_chooses_by_biased_score_and_weighs_by_the_score():
 
 def test_bias_takes_no_gradient_and_moves_toward_balance():
     cfg = tiny()
-    params = transformer_init(key(0), cfg)
+    params = init(key(0), cfg)
     batch = batch_of(cfg)
     bias = seeded_bias(cfg)
-    grad = jax.grad(lambda b: transformer_loss_and_readings(
-        params, batch, cfg, expert_bias=b)[0])(bias)
+    grad = jax.jit(jax.grad(lambda b: transformer_loss_and_readings(
+        params, batch, cfg, expert_bias=b)[0]))(bias)
     np.testing.assert_array_equal(grad, jnp.zeros_like(grad))
     load = jnp.array([[9, 1, 5, 5], [5, 5, 5, 5]])
     np.testing.assert_allclose(
         moe.update_expert_bias(jnp.zeros((2, 4)), load, 1e-3),
         [[-1e-3, 1e-3, 0, 0], [0, 0, 0, 0]])
     # in the step: owned by no optimizer, moved by the rate, load evening out
-    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     big = dataclasses.replace(cfg, expert_bias_update_rate=0.02)
-    init_state, step, _ = make_train_step(big, mesh, optax.sgd(0.0))
+    init_state, step, _ = make_train_step(big, one_device(), optax.sgd(0.0))
     state = init_state(key(0))
     state["expert_bias"] = 0.3 * jax.random.normal(key(9), (4, 8))
     n_opt = len(jax.tree.leaves(state["opt"]))
@@ -293,7 +283,7 @@ def layer():
     """8 small experts, 3 a token, over 96 tokens."""
     cfg = tiny(n_layers=1, layer_types=("conv",), n_dense_layers=0,
                experts_per_token=3, experts_held=None)
-    w = jax.tree.map(lambda x: x[0], transformer_init(key(4), cfg)["blocks"])
+    w = first_layer(cfg)
     y = jax.random.normal(key(5), (2, 48, 64))
     bias = 0.2 * jax.random.normal(key(6), (8,))
     return cfg, w, y, bias
@@ -338,19 +328,25 @@ def test_a_chunk_is_one_and_a_quarter_even_shares_in_whole_tiles():
     assert moe.held_chunk(131072, 64, 64) == 131072
 
 
+@functools.cache
+def the_cut():
+    """The cut with its drawn weights, a batch and a bias, and its (loss,
+    readings) and gradients at the rows and slack a chunk has: one program
+    for the three cases that set theirs against it."""
+    cfg = tiny()
+    params, batch, bias = init(key(0), cfg), batch_of(cfg), seeded_bias(cfg)
+    return cfg, params, batch, bias, program(
+        cfg, params, batch, expert_bias=bias)
+
+
 @pytest.mark.parametrize("slack", [0.01, 0.4, 100.0])
 def test_few_rows_a_chunk_or_all_in_one_give_one_loss_and_one_gradient(
         slack, monkeypatch):
-    cfg = tiny()
-    params = transformer_init(key(0), cfg)
-    batch = batch_of(cfg)
-    bias = seeded_bias(cfg)
-    f = lambda p: transformer_loss_and_readings(  # noqa: E731
-        p, batch, cfg, expert_bias=bias)
-    (loss, readings), grads = jax.value_and_grad(f, has_aux=True)(params)
+    cfg, params, batch, bias, ((loss, readings), grads) = the_cut()
     monkeypatch.setattr(moe, "_ROW_TILE", 8)
     monkeypatch.setattr(moe, "_HELD_SLACK", slack)  # 8 rows a chunk ... all
-    (loss_2, readings_2), grads_2 = jax.value_and_grad(f, has_aux=True)(params)
+    (loss_2, readings_2), grads_2 = program(
+        cfg, params, batch, expert_bias=bias)
     assert float(loss_2) == pytest.approx(float(loss), rel=1e-6)
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_2)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
@@ -432,46 +428,44 @@ def test_the_program_agrees_with_the_plain_reference(kind, monkeypatch):
     monkeypatch.setattr(moe, "_ROW_TILE", 8)
     cfg = tiny(**KINDS[kind], remat=kind == "whole")
     config = as_reference_config(cfg)
-    params = transformer_init(key(0), cfg)
+    params = init(key(0), cfg)
     batch = batch_of(cfg)
     bias = seeded_bias(cfg) if cfg.n_routed_layers else None
-
-    def system(p):
-        return transformer_loss_and_readings(p, batch, cfg, expert_bias=bias)
-
-    (loss, readings), grads = jax.value_and_grad(system, has_aux=True)(params)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: reference.loss(p, batch, config, expert_bias=bias))(params)
+    (loss, readings), grads = program(cfg, params, batch, expert_bias=bias)
+    ref_loss, ref_grads = value_and_grad(
+        lambda p: reference.loss(p, batch, config, expert_bias=bias), params)
     assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
     assert jax.tree.structure(grads) == jax.tree.structure(ref_grads)
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-6)
     if bias is not None:  # the same experts, layer by layer
-        _, chosen = reference.forward(params, batch, config, expert_bias=bias)
+        _, chosen = jax.jit(lambda p: reference.forward(
+            p, batch, config, expert_bias=bias))(params)
         ours = jax.nn.one_hot(readings["expert_index"], 8).sum(-2) > 0
         np.testing.assert_array_equal(ours, chosen)
         assert readings["expert_load"].shape == (cfg.n_routed_layers, 8)
         # without the bias the reference chooses otherwise
-        _, unbiased = reference.forward(params, batch, config)
+        _, unbiased = jax.jit(
+            lambda p: reference.forward(p, batch, config))(params)
         assert (np.asarray(unbiased) != np.asarray(chosen)).mean() > 0.02
 
 
 def test_kernels_in_interpret_mode_give_the_share_the_xla_path_s_loss(monkeypatch):
     cfg = tiny(d_model=128, n_heads=2, n_kv_heads=1, d_ff=128, d_ff_dense=128,
                n_layers=2, layer_types=("conv", "conv"))
-    params = transformer_init(key(0), cfg)
+    params = init(key(0), cfg)
     batch = batch_of(cfg, rows=1, seq=24)
     bias = seeded_bias(cfg)
     f = lambda p: transformer_loss_and_readings(  # noqa: E731
         p, batch, cfg, expert_bias=bias)[0]
-    loss, grads = jax.value_and_grad(f)(params)
+    loss, grads = value_and_grad(f, params)
     real = moe._kernels
     monkeypatch.setattr(moe, "_kernels", lambda impl, interpret: True)
     for name in ("gmm", "tgmm", "sum_held"):
         fn = getattr(moe, name)
         monkeypatch.setattr(moe, name, lambda *a, _fn=fn, **kw: _fn(
             *a, **{**kw, "interpret": True}))
-    loss_k, grads_k = jax.value_and_grad(f)(params)
+    loss_k, grads_k = value_and_grad(f, params)
     assert real("xla", False) is False
     assert float(loss_k) == pytest.approx(float(loss), rel=1e-5)
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_k)):

@@ -21,14 +21,11 @@ from ray_tpu.models.transformer import (
 from ray_tpu.ops import moe
 from ray_tpu.ops.fused import fused_rmsnorm
 from ray_tpu.parallel import make_mesh
+from tiny_models import key
 
 TINY = dict(vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
             d_ff=128, max_seq_len=64, tied_embeddings=False,
             n_experts=8, experts_per_token=2, qk_norm=True)
-
-
-def key(i):
-    return jax.random.PRNGKey(i)
 
 
 # ----------------------------------------------------------------- routing

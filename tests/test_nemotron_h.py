@@ -8,6 +8,7 @@ to `saved_activations` (`ray_tpu/ops/ssd.py`, `ray_tpu/ops/moe.py`,
 `ray_tpu/models/transformer.py`)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,14 +25,14 @@ from ray_tpu.models.transformer import (
 from ray_tpu.ops import moe
 from ray_tpu.ops.ssd import ssd
 from ray_tpu.parallel import make_mesh
+import tiny_models
+from tiny_models import (
+    as_reference_config, distance, first_layer, init, key, one_device,
+    program, ssd_by_token, value_and_grad, y_and_grads)
 
 # config.json's own `hybrid_override_pattern`
 PUBLISHED = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 KINDS = {"M": "mamba2", "E": "routed_ff", "*": "full_attention"}
-
-
-def key(i):
-    return jax.random.PRNGKey(i)
 
 
 def tiny(pattern="MEMEM*EME", **over):
@@ -48,13 +49,7 @@ def tiny(pattern="MEMEM*EME", **over):
         ssd_chunk=16, rescale_prenorm_residual=True), **over})
 
 
-def as_reference_config(cfg):
-    return {**dataclasses.asdict(cfg), "dtype": "float32"}
-
-
-def batch_of(cfg, rows=2, seq=40, seed=1):
-    ids = jax.random.randint(key(seed), (rows, seq + 1), 0, cfg.vocab_size)
-    return {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
+batch_of = functools.partial(tiny_models.batch_of, seq=40)
 
 
 def seeded_bias(cfg, scale=0.1, seed=7):
@@ -72,46 +67,26 @@ def scan_inputs(T, H=4, P=8, G=2, N=16, rows=2, seed=0):
             jax.random.normal(ks[5], (H,)))
 
 
-def ssd_by_token(x, dt, A, B, C, D):
-    """The same `y` by the recurrence itself, one `lax.scan` step a token,
-    in float32: what `ssd` is tested against."""
-    b, T, H, P = x.shape
-    G, N = B.shape[-2:]
-    rep = H // G
-    A, D = A.astype(jnp.float32), D.astype(jnp.float32)
-
-    def step(h, t):
-        x_t, dt_t, B_t, C_t = t                            # [b, H, P], [b, H], [b, G, N]
-        B_t, C_t = jnp.repeat(B_t, rep, axis=1), jnp.repeat(C_t, rep, axis=1)
-        h = (jnp.exp(dt_t * A)[..., None, None] * h
-             + (dt_t[..., None] * x_t)[..., None] * B_t[:, :, None, :])
-        return h, jnp.einsum("bHPN,bHN->bHP", h, C_t) + D[:, None] * x_t
-
-    per_token = tuple(v.astype(jnp.float32).swapaxes(0, 1) for v in (x, dt, B, C))
-    _, y = jax.lax.scan(step, jnp.zeros((b, H, P, N), jnp.float32), per_token)
-    return y.swapaxes(0, 1)
+def grad_rel_err(cfg, params, batch):
+    """The distance between the program's gradients and the reference's
+    under the program's choice of experts, over the reference's norm."""
+    (_, readings), grads = program(cfg, params, batch)
+    return distance(grads, value_and_grad(lambda p: reference.loss(
+        p, batch, as_reference_config(cfg), readings["expert_index"]),
+        params)[1])
 
 
-def grad_rel_err(cfg, params, batch, bias=None):
-    """(the program's loss and readings, the reference's loss under the
-    program's choice of experts, the distance between the two gradients
-    over the reference's norm)."""
-    config = as_reference_config(cfg)
-    (loss, readings), grads = jax.value_and_grad(
-        lambda p: transformer_loss_and_readings(
-            p, batch, cfg, expert_bias=bias), has_aux=True)(params)
-    ref_loss, ref_grads = jax.value_and_grad(lambda p: reference.loss(
-        p, batch, config, readings["expert_index"], bias))(params)
-    num = sum(float(jnp.sum((a - b) ** 2)) for a, b in zip(
-        jax.tree.leaves(grads), jax.tree.leaves(ref_grads)))
-    den = sum(float(jnp.sum(b ** 2)) for b in jax.tree.leaves(ref_grads))
-    return (loss, readings), ref_loss, (num / den) ** 0.5
-
-
-def first_layer(cfg, seed=4):
-    """The weights of a one-layer model's only layer, unstacked."""
-    blocks = transformer_init(key(seed), cfg)["blocks"]
-    return jax.tree.map(lambda a: a[0], blocks)
+@functools.cache
+def seeded(dtype="float32", kept=()):
+    """`tiny(remat=True)` with its drawn weights, a batch and a bias, and the
+    program's (loss, readings) and gradients with `kept` names saved, at rows
+    of 8: compiled once for every case that reads them."""
+    cfg = tiny(dtype=jnp.dtype(dtype), remat=True)
+    params, batch, bias = init(key(0), cfg), batch_of(cfg), seeded_bias(cfg)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "_ROW_TILE", 8)
+        out = program(cfg, params, batch, expert_bias=bias, saved_names=kept)
+    return cfg, params, batch, bias, out
 
 
 # ---------------------------------------------------------------- the scan
@@ -125,12 +100,9 @@ def first_layer(cfg, seed=4):
 def test_chunked_scan_is_the_recurrence_forward_and_backward(T, chunk, G):
     args = scan_inputs(T, G=G)
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(
-            ssd(*args, chunk=chunk), ssd_by_token(*args), rtol=2e-4, atol=2e-4)
-        ours = jax.grad(lambda *a: jnp.sum(
-            jnp.sin(ssd(*a, chunk=chunk))), argnums=range(6))(*args)
-        theirs = jax.grad(lambda *a: jnp.sum(
-            jnp.sin(ssd_by_token(*a))), argnums=range(6))(*args)
+        y, ours = y_and_grads(functools.partial(ssd, chunk=chunk), args)
+        want, theirs = y_and_grads(ssd_by_token, args)
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-4)
     for name, a, b in zip("x dt A B C D".split(), ours, theirs):
         scale = float(jnp.abs(b).max())
         np.testing.assert_allclose(
@@ -218,10 +190,10 @@ def test_the_ungated_experts_with_the_shared_one_are_the_reference_s(
     with jax.default_matmul_precision("highest"):
         out, readings = ours(x, w)
         np.testing.assert_allclose(out, theirs(x, w), rtol=2e-4, atol=2e-5)
-        g_ours = jax.grad(lambda x, w: jnp.sum(jnp.sin(ours(x, w)[0])),
-                          argnums=(0, 1))(x, w)
-        g_theirs = jax.grad(lambda x, w: jnp.sum(jnp.sin(theirs(x, w))),
-                            argnums=(0, 1))(x, w)
+        g_ours = jax.jit(jax.grad(
+            lambda x, w: jnp.sum(jnp.sin(ours(x, w)[0])), argnums=(0, 1)))(x, w)
+        g_theirs = jax.jit(jax.grad(
+            lambda x, w: jnp.sum(jnp.sin(theirs(x, w))), argnums=(0, 1)))(x, w)
     for a, b in zip(jax.tree.leaves(g_ours), jax.tree.leaves(g_theirs)):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-4)
     assert readings["expert_load"].shape == (8,)
@@ -244,19 +216,16 @@ def test_the_factor_scales_the_routed_part_alone():
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_the_program_agrees_with_the_plain_reference(dtype, monkeypatch):
-    monkeypatch.setattr(moe, "_ROW_TILE", 8)
-    cfg = tiny(dtype=jnp.dtype(dtype), remat=True)
-    params = transformer_init(key(0), cfg)
-    batch = batch_of(cfg)
-    bias = seeded_bias(cfg)
+def test_the_program_agrees_with_the_plain_reference(dtype):
+    cfg, params, batch, bias, ((loss, readings), grads) = seeded(dtype)
     config = as_reference_config(cfg)
-    (loss, readings), ref_loss, grad_err = grad_rel_err(cfg, params, batch, bias)
+    ref_loss, ref_grads = value_and_grad(lambda p: reference.loss(
+        p, batch, config, readings["expert_index"], bias), params)
     loss_tol, grad_tol = (1e-5, 1e-3) if dtype == "float32" else (3e-3, 0.1)
     assert abs(float(loss) - float(ref_loss)) / float(ref_loss) < loss_tol
-    assert grad_err < grad_tol
-    _, chosen, balance = reference.forward(
-        params, batch, config, expert_bias=bias)
+    assert distance(grads, ref_grads) < grad_tol
+    _, chosen, balance = jax.jit(lambda p: reference.forward(
+        p, batch, config, expert_bias=bias))(params)
     assert float(readings["aux_loss"]) == pytest.approx(float(balance), rel=2e-2)
     if dtype == "float32":  # the reference's own choice is the program's
         ours = jax.nn.one_hot(readings["expert_index"], 8).sum(-2) > 0
@@ -270,11 +239,11 @@ def test_a_dropped_skip_or_a_bf16_sum_of_decays_is_seen(monkeypatch):
     float32 where what is left is the fault's own: the skip `D x` left out,
     and the running sums of `dt A` rounded to bf16."""
     cfg = tiny("MEM", remat=False)
-    params = transformer_init(key(0), cfg)
+    params = init(key(0), cfg)
     batch = batch_of(cfg)
 
     def errors():
-        return grad_rel_err(cfg, params, batch)[2]
+        return grad_rel_err(cfg, params, batch)
 
     assert errors() < 1e-4
     real_ssd, real_cumsum = model.ssd, jnp.cumsum
@@ -326,15 +295,14 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
 
 def test_bias_takes_no_gradient_and_moves_toward_balance():
     cfg = tiny()
-    params = transformer_init(key(0), cfg)
+    params = init(key(0), cfg)
     batch = batch_of(cfg)
-    grad = jax.grad(lambda b: transformer_loss_and_readings(
-        params, batch, cfg, expert_bias=b)[0])(seeded_bias(cfg))
+    grad = jax.jit(jax.grad(lambda b: transformer_loss_and_readings(
+        params, batch, cfg, expert_bias=b)[0]))(seeded_bias(cfg))
     np.testing.assert_array_equal(grad, jnp.zeros_like(grad))
     # in the step: owned by no optimizer, moved by the rate, load evening out
-    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     big = dataclasses.replace(cfg, expert_bias_update_rate=0.02)
-    init_state, step, _ = make_train_step(big, mesh, optax.sgd(0.0))
+    init_state, step, _ = make_train_step(big, one_device(), optax.sgd(0.0))
     state = init_state(key(0))
     state["expert_bias"] = 0.3 * jax.random.normal(key(9), (4, 8))
     n_opt = len(jax.tree.leaves(state["opt"]))
@@ -354,7 +322,8 @@ def test_bias_takes_no_gradient_and_moves_toward_balance():
 
 def test_the_published_pattern_builds_and_steps():
     """All 52 published sublayers at a tiny width: the cut to nine is a cut
-    of depth, not a special case."""
+    of depth, not a special case. The 52 build (their state's shapes, no
+    program); the nine, which hold every kind of the 52, step."""
     cfg = tiny(PUBLISHED, experts_held=None, remat=True)
     kinds = cfg.layers
     assert len(kinds) == 52
@@ -363,18 +332,20 @@ def test_the_published_pattern_builds_and_steps():
     assert sum(k.op == "full_attention" for k in kinds) == 6
     assert all((k.op is None) == k.ff for k in kinds)  # one sublayer a layer
     assert segments(cfg) == [model.Segment(kinds, 1)]  # no shorter period
-    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
-    init_state, step, _ = make_train_step(cfg, mesh)
-    state = init_state(key(0))
+    state = jax.eval_shape(make_train_step(cfg, one_device())[0], key(0))
     assert len(state["params"]["blocks"][0]) == 52
     assert state["expert_bias"].shape == (23, 8)
-    batch = batch_of(cfg, seq=24)
+    cut = tiny(experts_held=None, remat=True)
+    assert {k.op for k in cut.layers} == {k.op for k in kinds}
+    init_state, step, _ = make_train_step(cut, one_device())
+    state = init_state(key(0))
+    batch = batch_of(cut, seq=24)
     losses = []
     for _ in range(3):
         state, out = step(state, batch)
         losses.append(float(out["loss"]))
     assert np.isfinite(losses).all() and losses[2] < losses[0]
-    assert out["expert_load"].shape == (23, 8)
+    assert out["expert_load"].shape == (cut.n_routed_layers, 8) == (4, 8)
 
 
 @pytest.mark.parametrize("pattern,layouts", [
@@ -386,15 +357,15 @@ def test_the_published_pattern_builds_and_steps():
 def test_periods_are_found_over_sublayers(pattern, layouts):
     cfg = tiny(pattern)
     assert [(len(s.layout), s.periods) for s in segments(cfg)] == layouts
-    params = transformer_init(key(0), cfg)
-    shard = param_shardings(make_mesh({"data": 1}, devices=jax.devices()[:1]), cfg)
+    params = init(key(0), cfg)
+    shard = param_shardings(one_device(), cfg)
     assert jax.tree.structure(params) == jax.tree.structure(shard)
     if pattern == "MMMM":
         assert params["blocks"]["A_log"].shape == (4, 4)
-    loss, _ = transformer_loss_and_readings(
-        params, batch_of(cfg, seq=24), cfg,
+    loss, _ = jax.jit(lambda p: transformer_loss_and_readings(
+        p, batch_of(cfg, seq=24), cfg,
         expert_bias=jnp.zeros((cfg.n_routed_layers, 8))
-        if cfg.n_routed_layers else None)
+        if cfg.n_routed_layers else None))(params)
     assert np.isfinite(loss)
 
 
@@ -475,19 +446,10 @@ def test_saved_activations_name_only_what_each_kind_has():
             == many * 4 * 8 * (4 * 4 + 2 * 2))
 
 
-def test_kept_names_leave_loss_and_gradients_as_they_are(monkeypatch):
-    monkeypatch.setattr(moe, "_ROW_TILE", 8)
-    cfg = tiny(remat=True)
-    params = transformer_init(key(0), cfg)
-    batch = batch_of(cfg)
-    bias = seeded_bias(cfg)
-
-    def run(names):
-        return jax.value_and_grad(lambda p: transformer_loss_and_readings(
-            p, batch, cfg, expert_bias=bias, saved_names=names)[0])(params)
-
-    loss, grads = run(())
-    kept_loss, kept = run(("mamba_in", "ssd_out", "shared_up", "attn_qkv"))
+def test_kept_names_leave_loss_and_gradients_as_they_are():
+    (loss, _), grads = seeded()[-1]
+    (kept_loss, _), kept = seeded(
+        kept=("mamba_in", "ssd_out", "shared_up", "attn_qkv"))[-1]
     assert float(loss) == pytest.approx(float(kept_loss), rel=1e-6)
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(kept)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)

@@ -88,9 +88,7 @@ def test_ring_attention_grad():
 
     from ray_tpu.parallel import full_attention, make_mesh, ring_attention_sharded
 
-    import jax as _jax
-
-    mesh = make_mesh({"sequence": 4}, devices=_jax.devices()[:4])
+    mesh = make_mesh({"sequence": 4}, devices=jax.devices()[:4])
     B, T, H, D = 1, 16, 2, 8
     rng = np.random.RandomState(3)
     q, k, v = (rng.randn(B, T, H, D).astype(np.float32) for _ in range(3))
@@ -101,8 +99,8 @@ def test_ring_attention_grad():
     def loss_full(q, k, v):
         return jnp.sum(full_attention(q, k, v, causal=True) ** 2)
 
-    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    g_full = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    g_full = jax.jit(jax.grad(loss_full, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_ring, g_full):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
 

@@ -1,8 +1,7 @@
 """Scalability-envelope tests (reference: release/benchmarks/README.md bars:
 10k+ queued tasks per node, 40k actors, 1k PGs cluster-wide — scaled to a
-single CI host). Excluded from the default run (`-m 'not scale'`); run with:
-
-    python -m pytest -m scale tests/test_scale.py -q
+single CI host). All run in tier-1 but the failover at a thousand raylets,
+which is `slow`: `python -m pytest -m slow tests/test_scale.py -q`.
 """
 
 import time
@@ -11,17 +10,14 @@ import pytest
 
 import ray_tpu
 
-pytestmark = pytest.mark.scale
-
 
 @pytest.fixture
 def big_cluster(shutdown_only, monkeypatch):
-    monkeypatch.setenv("RAY_TPU_ACTOR_RESOLVE_TIMEOUT_S", "800")
+    monkeypatch.setenv("RAY_TPU_ACTOR_RESOLVE_TIMEOUT_S", "540")
     ray_tpu.init(num_cpus=256, num_tpus=0)
     yield
 
 
-@pytest.mark.timeout(900)
 def test_10k_queued_tasks(big_cluster):
     """10,000 tasks queued at once all complete (reference bar: 1M queued on
     one m4.16xlarge; scaled to CI)."""
@@ -31,11 +27,10 @@ def test_10k_queued_tasks(big_cluster):
         return i
 
     refs = [tick.remote(i) for i in range(10_000)]
-    out = ray_tpu.get(refs, timeout=600)
+    out = ray_tpu.get(refs, timeout=150)
     assert out == list(range(10_000))
 
 
-@pytest.mark.timeout(900)
 def test_200_actors(big_cluster):
     """200 concurrent actors all answer (reference bar: 40k cluster-wide)."""
 
@@ -48,13 +43,12 @@ def test_200_actors(big_cluster):
             return self.i
 
     actors = [Cell.remote(i) for i in range(200)]
-    out = ray_tpu.get([a.who.remote() for a in actors], timeout=600)
+    out = ray_tpu.get([a.who.remote() for a in actors], timeout=150)
     assert out == list(range(200))
     for a in actors:
         ray_tpu.kill(a)
 
 
-@pytest.mark.timeout(900)
 def test_50_placement_groups(big_cluster):
     """50 simultaneous placement groups become ready and host work
     (reference bar: 1k+ cluster-wide)."""
@@ -67,19 +61,19 @@ def test_50_placement_groups(big_cluster):
 
     pgs = [placement_group([{"CPU": 1}]) for _ in range(50)]
     for pg in pgs:
-        assert pg.wait(timeout=120)
+        assert pg.wait(timeout=60)
     refs = [
         inside.options(
             scheduling_strategy=PlacementGroupSchedulingStrategy(placement_group=pg)
         ).remote()
         for pg in pgs
     ]
-    assert sum(ray_tpu.get(refs, timeout=600)) == 50
+    assert sum(ray_tpu.get(refs, timeout=60)) == 50
     for pg in pgs:
         remove_placement_group(pg)
 
 
-@pytest.mark.timeout(1800)
+@pytest.mark.timeout(600)  # 8 s alone, 14 s beside five workers' files
 def test_100k_queued_tasks(big_cluster):
     """100,000 tasks queued at once all complete (reference bar: 1M queued
     on one m4.16xlarge — this is the 10% point on a 1-core CI host)."""
@@ -91,7 +85,7 @@ def test_100k_queued_tasks(big_cluster):
     t0 = time.perf_counter()
     refs = [tick.remote(i) for i in range(100_000)]
     t_submit = time.perf_counter() - t0
-    out = ray_tpu.get(refs, timeout=1500)
+    out = ray_tpu.get(refs, timeout=540)
     t_total = time.perf_counter() - t0
     assert out == list(range(100_000))
     print(
@@ -100,7 +94,7 @@ def test_100k_queued_tasks(big_cluster):
     )
 
 
-@pytest.mark.timeout(1800)
+@pytest.mark.timeout(600)  # a thousand processes spawned: 8 to 17 s alone, 23 s loaded
 def test_1000_actors(big_cluster):
     """1,000 concurrent actors all answer (reference bar: 40k across a
     64-host cluster). Worker-process spawn is the expected wall on one
@@ -116,7 +110,7 @@ def test_1000_actors(big_cluster):
 
     t0 = time.perf_counter()
     actors = [Cell.remote(i) for i in range(1000)]
-    out = ray_tpu.get([a.who.remote() for a in actors], timeout=1500)
+    out = ray_tpu.get([a.who.remote() for a in actors], timeout=540)
     dt = time.perf_counter() - t0
     assert out == list(range(1000))
     print(f"\n1000 actors alive+answering in {dt:.0f}s ({1000 / dt:.1f}/s)")
@@ -124,7 +118,7 @@ def test_1000_actors(big_cluster):
         ray_tpu.kill(a)
 
 
-@pytest.mark.timeout(1800)
+@pytest.mark.timeout(600)  # 200 two-phase commits and 200 workers: 2 s alone, 4 s loaded
 def test_200_placement_groups(big_cluster):
     """200 simultaneous placement groups become ready and host work
     (reference bar: 1k+ cluster-wide)."""
@@ -143,7 +137,7 @@ def test_200_placement_groups(big_cluster):
     t0 = time.perf_counter()
     pgs = [placement_group([{"CPU": 1}]) for _ in range(200)]
     for pg in pgs:
-        assert pg.wait(timeout=600)
+        assert pg.wait(timeout=240)
     t_ready = time.perf_counter() - t0
     refs = [
         inside.options(
@@ -153,7 +147,7 @@ def test_200_placement_groups(big_cluster):
         ).remote()
         for pg in pgs
     ]
-    assert sum(ray_tpu.get(refs, timeout=900)) == 200
+    assert sum(ray_tpu.get(refs, timeout=240)) == 200
     print(f"\n200 PGs ready in {t_ready:.1f}s")
     for pg in pgs:
         remove_placement_group(pg)
@@ -184,10 +178,9 @@ def _sim_schedule(cluster, client, n_tasks, concurrency=64, latencies=None):
 
         await asyncio.gather(*(one(i) for i in range(n_tasks)))
 
-    cluster.run(schedule_all(), timeout=600)
+    cluster.run(schedule_all(), timeout=120)
 
 
-@pytest.mark.timeout(900)
 def test_sim_500_nodes_10k_tasks():
     """The headline bar: 500 in-process raylets stand up and 10,000 lease
     cycles schedule through the real spillback protocol."""
@@ -226,7 +219,6 @@ def _median_lease_latency_s(num_nodes, samples=1500):
         cluster.shutdown()
 
 
-@pytest.mark.timeout(900)
 def test_sim_lease_latency_o_k_not_o_n():
     """The per-lease scheduling decision is O(k), not O(cluster): median
     grant latency at 500 nodes stays within 2x of 50 nodes. (The old
@@ -244,7 +236,6 @@ def test_sim_lease_latency_o_k_not_o_n():
     )
 
 
-@pytest.mark.timeout(900)
 def test_sim_autoscaler_scales_to_500_nodes():
     """The autoscaler control loop drives the sim provider past 500 nodes
     on sustained synthetic demand, then runs a clean steady-state round on
@@ -300,11 +291,19 @@ def test_sim_autoscaler_scales_to_500_nodes():
         cluster.shutdown()
 
 
-@pytest.mark.timeout(1800)
-def test_sim_1000_node_failover_reconnect_storm():
-    """HA failover at the scale bar: 1000 in-process raylets lose the GCS
+# The GCS leader's lease. Beside a `-n 5` run of the compile-heavy files on 8
+# cores (load 9 to 13) a hundred raylets' new leader held 0.1, 0.2, 0.3, 0.5,
+# 1.0 and 2.0 s five times of five each and missed 0.02 s: ten times the room.
+# A thousand's wave failed or never converged in whole runs (ROADMAP D11): `slow`.
+LEASE_S = 1.0
+
+
+@pytest.mark.parametrize("n", [100, pytest.param(
+    1000, marks=[pytest.mark.slow, pytest.mark.timeout(1800)])])
+def test_sim_failover_reconnect_storm(n):
+    """HA failover at the scale bar: n in-process raylets lose the GCS
     *machine* (process + its replicated-log member), the warm standby
-    promotes from the follower log, and the full 1000-raylet reconnect
+    promotes from the follower log, and the full n-raylet reconnect
     wave re-targets the new leader through the leader file — converging to
     a complete ALIVE node view without melting the control plane."""
     import asyncio
@@ -315,14 +314,14 @@ def test_sim_1000_node_failover_reconnect_storm():
     from ray_tpu._private import gcs_ha, rpc
     from ray_tpu._private.sim_cluster import SimCluster, SimLeaseClient
 
-    n = 1000
+    probe_s = 90 if n == 100 else 600  # the hundred's fit the suite's 180 s
     tmp = tempfile.mkdtemp(prefix="ha_scale_")
     cluster = SimCluster(
         n,
         persist_path=os.path.join(tmp, "gcs.wal"),
         ha=True,
         env={
-            "RAY_TPU_GCS_LEADER_LEASE_S": "1.0",
+            "RAY_TPU_GCS_LEADER_LEASE_S": str(LEASE_S),
             "RAY_TPU_GCS_STANDBY_POLL_S": "0.05",
         },
     ).start()
@@ -331,7 +330,7 @@ def test_sim_1000_node_failover_reconnect_storm():
         client = SimLeaseClient(cluster)
         _sim_schedule(cluster, client, 500)  # warm: every node registered
         t0 = time.perf_counter()
-        assert cluster.run(cluster.kill_gcs_host_async(), timeout=120)
+        assert cluster.run(cluster.kill_gcs_host_async(), timeout=probe_s / 5)
         t_promote = time.perf_counter() - t0
 
         async def converged() -> float:
@@ -348,12 +347,14 @@ def test_sim_1000_node_failover_reconnect_storm():
 
             conn = None
             try:
-                deadline = asyncio.get_running_loop().time() + 600
+                deadline = asyncio.get_running_loop().time() + probe_s
                 while True:
                     try:
                         if conn is None:
                             conn = await dial()
-                        reply = await conn.call("GetAllNodes", timeout=60)
+                        reply = await conn.call(
+                            "GetAllNodes", timeout=probe_s / 10
+                        )
                     except (rpc.RpcError, OSError):
                         if asyncio.get_running_loop().time() > deadline:
                             raise
@@ -377,20 +378,24 @@ def test_sim_1000_node_failover_reconnect_storm():
                 if conn is not None:
                     await conn.close()
 
-        t_converge = cluster.run(converged(), timeout=700)
+        t_converge = cluster.run(converged(), timeout=probe_s * 7 / 6)
         # The promoted leader still schedules: a fresh lease burst works.
         _sim_schedule(cluster, client, 500)
         cluster.run(client.close(), timeout=30)
+        # The standby re-armed behind the new leader promotes only if that
+        # leader missed its own lease under the wave.
+        held = not cluster.gcs_standby.promoted.is_set()
         print(
             f"\n{n}-node failover: promoted in {t_promote:.2f}s, full "
-            f"reconnect storm converged in {t_converge:.1f}s"
+            f"reconnect storm converged in {t_converge:.1f}s, the new leader "
+            f"{'held' if held else 'missed'} its lease of {LEASE_S} s"
         )
     finally:
         cluster.shutdown()
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-@pytest.mark.timeout(1800)
+@pytest.mark.timeout(600)  # 2 GB through eight stores: 13 to 17 s alone or loaded
 def test_256mb_broadcast_to_8_nodes(shutdown_only):
     """One 256 MB object broadcast to tasks pinned on 8 raylets — the
     PushManager fan-out pattern (reference bar: 1 GiB to 50+ nodes)."""
@@ -424,7 +429,7 @@ def test_256mb_broadcast_to_8_nodes(shutdown_only):
         ).remote(ref)
         for n in nodes
     ]
-    out = ray_tpu.get(refs, timeout=900)
+    out = ray_tpu.get(refs, timeout=540)
     dt = time.perf_counter() - t0
     assert all(o == (0, len(payload) - 1, payload.nbytes) for o in out)
     total_gb = 256 / 1024 * len(nodes)

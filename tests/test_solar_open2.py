@@ -18,7 +18,7 @@ from chipbench.loops.nemotron_h import decayed
 from chipbench.reference import solar_open2 as reference
 from ray_tpu.models import TransformerConfig, make_train_step
 from ray_tpu.models import transformer as model
-from ray_tpu.parallel import make_mesh
+from tiny_models import distance, one_device
 
 CFG = TransformerConfig(
     vocab_size=96, d_model=32, n_layers=4, n_heads=8, n_kv_heads=2, d_head=8,
@@ -39,13 +39,6 @@ REF = dict(
 def batch_of(seed, rows=1, T=40):
     ids = jax.random.randint(jax.random.PRNGKey(seed), (rows, T + 1), 0, 96)
     return {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
-
-
-def rel(a, b):
-    num = sum(jnp.sum((x - y) ** 2) for x, y in zip(
-        jax.tree.leaves(a), jax.tree.leaves(b)))
-    den = sum(jnp.sum(y ** 2) for y in jax.tree.leaves(b))
-    return float(jnp.sqrt(num / den))
 
 
 @pytest.mark.parametrize("periods", [1, 2])
@@ -69,7 +62,7 @@ def test_loss_and_gradients_are_the_references(periods):
     l_ref, g_ref = jax.jit(jax.value_and_grad(
         lambda p, index: reference.loss(p, batch, REF, index)))(params, index)
     assert abs(float(l_sys) - float(l_ref)) < 1e-5 * abs(float(l_ref))
-    assert rel(g_sys, g_ref) < 5e-5
+    assert distance(g_sys, g_ref) < 5e-5
     # every leaf is reached: none of the kda leaves has a zero gradient
     kda_layer = g_sys["blocks"][0][1]
     for name, leaf in kda_layer.items():
@@ -119,7 +112,7 @@ def test_remat_with_names_kept_is_the_same_step():
     again = jax.jit(jax.value_and_grad(lambda p: model.transformer_loss(
         p, batch, remat, saved_names=names)))(params)
     assert abs(float(again[0]) - float(plain[0])) < 1e-6
-    assert rel(again[1], plain[1]) < 1e-5
+    assert distance(again[1], plain[1]) < 1e-5
 
 
 def test_the_rule_prices_the_new_kind():
@@ -147,12 +140,11 @@ def test_the_rule_prices_the_new_kind():
 
 
 def test_the_step_trains_and_decays_matrices_only():
-    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     no_decay = ["A_log", "dt_bias", "kda_conv", "g_bias", "norm"]
     optimizer = optax.adamw(
         3e-3, b1=0.9, b2=0.95, weight_decay=0.1,
         mask=lambda params: decayed(params, no_decay))
-    init_state, step, _ = make_train_step(CFG, mesh, optimizer)
+    init_state, step, _ = make_train_step(CFG, one_device(), optimizer)
     state = init_state(jax.random.PRNGKey(0))
     mask = decayed(state["params"], no_decay)
     kda_layer = mask["blocks"][0][1]

@@ -16,6 +16,7 @@ import pytest
 
 from ray_tpu.ops import ssd as scan
 from ray_tpu.ops.ssd import ssd
+from tiny_models import ssd_by_token, y_and_grads
 
 NAMES = "x dt A B C D".split()
 kernels = functools.partial(ssd, interpret=True)
@@ -32,37 +33,9 @@ def inputs(T, H, P, G, N=128, rows=1, seed=0, dtype=jnp.float32):
             jax.random.normal(ks[5], (H,)))
 
 
-def by_token(x, dt, A, B, C, D):
-    """`y` by the recurrence itself, one `lax.scan` step a token, float32."""
-    b, T, H, P = x.shape
-    G, N = B.shape[-2:]
-    rep = H // G
-
-    def step(h, t):
-        x_t, dt_t, B_t, C_t = t
-        B_t, C_t = jnp.repeat(B_t, rep, axis=1), jnp.repeat(C_t, rep, axis=1)
-        h = (jnp.exp(dt_t * A)[..., None, None] * h
-             + (dt_t[..., None] * x_t)[..., None] * B_t[:, :, None, :])
-        return h, jnp.einsum("bHPN,bHN->bHP", h, C_t) + D[:, None] * x_t
-
-    per_token = tuple(
-        v.astype(jnp.float32).swapaxes(0, 1) for v in (x, dt, B, C))
-    _, y = jax.lax.scan(step, jnp.zeros((b, H, P, N), jnp.float32), per_token)
-    return y.swapaxes(0, 1)
-
-
 def rel(a, b):
     a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
     return float(jnp.sqrt(jnp.sum((a - b) ** 2) / jnp.sum(b ** 2)))
-
-
-def value_and_grads(f, args):
-    """`y` and the six gradients of `sum(sin(y))`, so that every token's
-    cotangent differs."""
-    y = f(*args)
-    grads = jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a).astype(jnp.float32))),
-                     argnums=range(6))(*args)
-    return y, grads
 
 
 # (T, H, P, G): a group's channels `(H / G) P` 128 and 512, G 1 and 2; one,
@@ -88,8 +61,8 @@ def test_the_kernels_are_the_numpy_scan_forward_and_backward(T, H, P, G, dtype):
     orders of summing bf16 products are."""
     args = inputs(T, H, P, G, dtype=dtype)
     with jax.default_matmul_precision("highest"):
-        y, grads = value_and_grads(kernels, args)
-        want_y, want = value_and_grads(ssd, args)
+        y, grads = y_and_grads(kernels, args)
+        want_y, want = y_and_grads(ssd, args)
     assert y.dtype == dtype and y.shape == args[0].shape
     tol = 2e-5 if dtype == jnp.float32 else 1e-2
     assert rel(y, want_y) < tol
@@ -103,8 +76,8 @@ def test_the_kernels_are_the_numpy_scan_forward_and_backward(T, H, P, G, dtype):
 def test_the_kernels_are_the_recurrence_forward_and_backward(T, H, P, G):
     args = inputs(T, H, P, G, seed=1)
     with jax.default_matmul_precision("highest"):
-        y, grads = value_and_grads(kernels, args)
-        want_y, want = value_and_grads(by_token, args)
+        y, grads = y_and_grads(kernels, args)
+        want_y, want = y_and_grads(ssd_by_token, args)
     np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
     for name, ours, theirs in zip(NAMES, grads, want):
         scale = float(jnp.abs(theirs).max())
@@ -120,7 +93,7 @@ def test_bf16_operands_lose_nothing_of_the_decays():
     args = inputs(256, 4, 64, 2, seed=3, dtype=jnp.bfloat16)
     y = kernels(*args)
     assert y.dtype == jnp.bfloat16
-    assert rel(y, by_token(*args)) < 1e-2
+    assert rel(y, ssd_by_token(*args)) < 1e-2
 
 
 def _equations(jaxpr):
@@ -184,7 +157,7 @@ def test_a_bf16_sum_of_decays_or_a_mask_after_the_exp_is_seen(monkeypatch):
     diagonal (over 88 within a chunk at these decays), `inf * 0`, and the
     result is not a number."""
     args = inputs(256, 4, 64, 2, seed=5)
-    want = by_token(*args)
+    want = ssd_by_token(*args)
 
     def error():
         with jax.default_matmul_precision("highest"):

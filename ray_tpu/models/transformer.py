@@ -105,6 +105,22 @@ partial sum over them; nothing stands in for the other chips.
 `attn_gate="elementwise"` gates plain attention's context by a sigmoid as
 wide as the context. Those are Solar-Open2-250B's (`solar_open2`).
 
+A stack may be run several times over the same weights (`loop_steps`;
+arXiv:2510.25741's looped language model): the walk over the segments is
+one `lax.scan` over the passes, the final norm after every pass and its
+output the next pass's input, and every pass's normed stream comes back. A
+weight's gradient is the sum over its uses; a kept name is held once a
+layer a pass. `post_norm` norms plain attention's and the dense
+feed-forward's output before it joins the stream (four norms a layer).
+With `exit_gate` one `Linear(d_model, 1)` reads every pass's stream, its
+sigmoids make a distribution over the pass a token leaves after
+(`exit_distribution`), and the loss is the passes' cross-entropies under it
+less `exit_entropy_coef` times its entropy (`_exit_loss`), the passes'
+heads as one call of the weighted chunked cross-entropy (`ops/fused.py`);
+the step reads `ut_pass_loss`, `exit_p_mean` and `exit_entropy`. Those are
+Ouro-2.6B's (`ouro`). Not under a `sequence` or an `expert` axis, with
+`heads_held` or over layers that make readings yet (`_refuse_unmapped_loop`).
+
 Under a mesh with an `expert` axis (`make_mesh({"expert": n})`) a routed
 stack is expert-parallel, nothing of a layer left out: a device holds
 `n_experts / n` whole experts of every layer (the experts' leaves cut on
@@ -166,8 +182,9 @@ Capability analog of what the reference reaches only through integrations
 (SURVEY §5: it ships no native SP); here it is native. Cells that train it:
 `mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k`,
 `lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`,
-`lagunaxs2.tokens8k`, `keyevl2.tokens16k`, `solaropen2.tokens8k`, and
-`mellum2.ep4` on the four chips of an `expert` axis (BENCHMARK.json).
+`lagunaxs2.tokens8k`, `keyevl2.tokens16k`, `solaropen2.tokens8k`,
+`ouro.tokens16k`, and `mellum2.ep4` on the four chips of an `expert` axis
+(BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -191,11 +208,13 @@ from ray_tpu.ops.kda import SUB as _KDA_SUB, kda
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
 from ray_tpu.ops.ssd import scan_untiled, ssd
 from ray_tpu.ops.fused import (
+    HEAD_CHUNK,
     _own_buffer,
     _own_cotangent,
     fused_rmsnorm,
     lm_head_cross_entropy,
     softmax_cross_entropy,
+    weighted_lm_head_cross_entropy,
 )
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel.ring_attention import ring_attention
@@ -318,6 +337,19 @@ class TransformerConfig:
     # (first, n): plain attention and "kda" hold heads first..first+n-1 of
     # theirs, and attention the key-value heads those query heads read
     heads_held: Optional[Tuple[int, int]] = None
+    # the whole stack is run this many times over the same weights, the
+    # final norm after every pass, its output the next pass's input
+    # (arXiv:2510.25741's `total_ut_steps`)
+    loop_steps: int = 1
+    # an RMS norm on a sublayer's output before it joins the stream (plain
+    # attention's and the dense feed-forward's: four norms a layer)
+    post_norm: bool = False
+    # with `loop_steps` > 1: one `Linear(d_model, 1)` with a bias reads every
+    # pass's normed stream, and the loss is the passes' cross-entropies
+    # under the exit distribution its sigmoids make, less
+    # `exit_entropy_coef` times that distribution's entropy
+    exit_gate: bool = False
+    exit_entropy_coef: float = 0.0
 
     @property
     def kv_heads(self) -> int:
@@ -393,13 +425,24 @@ class TransformerConfig:
                     f"{name!r}, which holds all its heads or none: a share "
                     "of the heads is plain attention's and kda's")
         if self.sublayer_types:
-            return tuple(
+            kinds = tuple(
                 LayerKind(None, name == "routed_ff", True)
                 if name in _FEED_FORWARDS else LayerKind(name, False, False)
                 for name in names)
-        return tuple(
-            LayerKind(op, bool(self.n_experts) and i >= self.n_dense_layers)
-            for i, op in enumerate(names))
+        else:
+            kinds = tuple(
+                LayerKind(op,
+                          bool(self.n_experts) and i >= self.n_dense_layers)
+                for i, op in enumerate(names))
+        if self.post_norm:
+            for kind in set(kinds):
+                for sub in _sublayers(kind):
+                    if not sub.takes_post_norm:
+                        raise ValueError(
+                            f"post_norm with a {type(sub).__name__[1:]} "
+                            "sublayer, which has no norm on its output: "
+                            "plain attention and the dense feed-forward do")
+        return kinds
 
     @property
     def n_routed_layers(self) -> int:
@@ -585,8 +628,17 @@ def _attention_layer(x, blk, positions, cfg: TransformerConfig,
             o = o * (gate.reshape(B, T, h, dh)
                      if cfg.attn_gate == "elementwise" else gate[..., None])
     with jax.named_scope("attn_out"):
-        return checkpoint_name(
-            x + o.reshape(B, T, h * dh) @ blk["wo"].astype(dt), "attn_res")
+        out = o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
+        if "attn_post_norm" in blk:
+            out = _post_norm(out, blk["attn_post_norm"], cfg)
+        return checkpoint_name(x + out, "attn_res")
+
+
+def _post_norm(y, scale, cfg: "TransformerConfig"):
+    """A sublayer's output normed before it joins the stream
+    (`post_norm`)."""
+    with jax.named_scope("post_norm"):
+        return fused_rmsnorm(y, scale, eps=cfg.norm_eps)
 
 
 def _qkv(x, blk, positions, cfg: TransformerConfig, op: "_PlainAttention"):
@@ -1104,6 +1156,8 @@ class Sublayer:
     no_sequence_axis: Optional[str] = None
     # an operator that `heads_held` makes hold a share of its heads
     takes_heads_held: bool = False
+    # a sublayer that `post_norm` gives a norm on its output
+    takes_post_norm: bool = False
 
     def init(self, key, cfg: TransformerConfig, L: int) -> Dict[str, Any]:
         """`L` stacked layers' leaves, float32, from the layer's key. The
@@ -1156,6 +1210,7 @@ class _PlainAttention(Sublayer):
     matmuls = ("wq", "wk", "wv", "wo", "w_gate_attn")
     names = ("attn_ctx", "attn_res", "attn_qkv")
     takes_heads_held = True
+    takes_post_norm = True
 
     def __init__(self, sliding: bool):
         self.sliding = sliding
@@ -1228,6 +1283,8 @@ class _PlainAttention(Sublayer):
         if cfg.attn_gate:
             leaves["w_gate_attn"] = _dense(
                 jax.random.fold_in(key, 9), (L, d, self._gate_width(cfg)), d)
+        if cfg.post_norm:
+            leaves["attn_post_norm"] = jnp.ones((L, d), jnp.float32)
         if cfg.qk_norm:
             per_head = cfg.qk_norm == "head"
             leaves["q_norm"] = jnp.ones(
@@ -1246,6 +1303,8 @@ class _PlainAttention(Sublayer):
         }
         if cfg.attn_gate:  # a column a head, or the heads' columns
             table["w_gate_attn"] = ("layers", "embed", "heads")
+        if cfg.post_norm:
+            table["attn_post_norm"] = ("layers", None)
         if cfg.qk_norm == "head":  # one scale for all heads
             table.update(q_norm=("layers", None), k_norm=("layers", None))
         elif cfg.qk_norm:
@@ -1285,7 +1344,9 @@ class _PlainAttention(Sublayer):
                 + 2 * h * 128 * 4 // _item(cfg)
                 # a gate as wide as the context: its product
                 + (self._gate_width(cfg)
-                   if cfg.attn_gate == "elementwise" else 0))
+                   if cfg.attn_gate == "elementwise" else 0)
+                # `wo`'s product before its norm
+                + (cfg.d_model if cfg.post_norm else 0))
 
     def flops(self, cfg, seq_len):
         # qk^T and pv each cost 2 h dh operations a (query, key) pair, over
@@ -1308,6 +1369,7 @@ class _SparseAttention(_PlainAttention):
     matmuls = (*_PlainAttention.matmuls, "wq_idx", "wk_idx", "w_idx")
     # the index loss reads the probabilities of every head
     takes_heads_held = False
+    takes_post_norm = False  # `_sparse_attention_layer` has none
     no_sequence_axis = (
         "sparse attention is not mapped over a sequence axis: a query "
         "chooses among all the keys before it, and the selection and the "
@@ -1753,6 +1815,7 @@ class _DenseFF(Sublayer):
 
     matmuls = ("w_gate", "w_up", "w_down")
     names = ("mlp_gate", "mlp_up")
+    takes_post_norm = True
 
     def _width(self, cfg) -> int:
         if cfg.n_experts and cfg.d_ff_dense is not None:
@@ -1767,6 +1830,8 @@ class _DenseFF(Sublayer):
             leaves["w_gate"] = _dense(ks[4], (L, d, f), d)
         leaves["w_up"] = _dense(ks[5], (L, d, f), d)
         leaves["w_down"] = _dense(ks[6], (L, f, d), f)
+        if cfg.post_norm:
+            leaves["mlp_post_norm"] = jnp.ones((L, d), jnp.float32)
         return leaves
 
     def axes(self, cfg):
@@ -1778,13 +1843,17 @@ class _DenseFF(Sublayer):
         }
         if not cfg.gated:  # an ungated feed-forward has no gate's weights
             table.pop("w_gate")
+        if cfg.post_norm:
+            table["mlp_post_norm"] = ("layers", None)
         return table
 
     def forward(self, x, blk, cfg, site):
         with jax.named_scope("mlp"):
             y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
-            return x + _feed_forward(
-                y, blk, cfg.dtype, ("mlp_gate", "mlp_up")), None
+            out = _feed_forward(y, blk, cfg.dtype, ("mlp_gate", "mlp_up"))
+            if "mlp_post_norm" in blk:
+                out = _post_norm(out, blk["mlp_post_norm"], cfg)
+            return x + out, None
 
     def widths(self, cfg):
         return {"mlp_" + name: self._width(cfg)
@@ -1794,7 +1863,8 @@ class _DenseFF(Sublayer):
         return cfg.ff_matrices * cfg.d_model * self._width(cfg)
 
     def holds(self, cfg):
-        return self._width(cfg)  # the hidden product
+        # the hidden product; `w_down`'s before its norm
+        return self._width(cfg) + (cfg.d_model if cfg.post_norm else 0)
 
 
 class _RoutedFF(Sublayer):
@@ -1956,6 +2026,9 @@ def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
     }
     if not cfg.tied_embeddings:
         params["unembed"] = _dense(k_out, (d, cfg.vocab_size), d)
+    if cfg.exit_gate:  # one `Linear(d, 1)` for all the passes
+        params["exit_w"] = _dense(jax.random.fold_in(k_out, 1), (d,), d)
+        params["exit_b"] = jnp.zeros((), jnp.float32)
     return params
 
 
@@ -1969,6 +2042,8 @@ _LOGICAL_AXES = {
     "embed": ("vocab", "embed"),
     "unembed": ("embed", "vocab"),
     "final_norm": (None,),
+    "exit_w": (None,),
+    "exit_b": (),
 }
 
 
@@ -1992,6 +2067,8 @@ def param_shardings(mesh, cfg: TransformerConfig):
     table = dict(_LOGICAL_AXES)
     if cfg.tied_embeddings:
         table.pop("unembed", None)
+    if not cfg.exit_gate:
+        del table["exit_w"], table["exit_b"]
     segs = segments(cfg)
     if _one_kind(segs):
         table["blocks"] = _block_axes(cfg, segs[0].layout[0])
@@ -2126,7 +2203,16 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
     on a leading layer axis (None for a dense model). `expert_bias`
     [routed layers, E] is the routers' selection bias. Under `cfg.remat` a
     block's input is its checkpoint; `saved_names` are the activations kept
-    beside it (`saved_activations`), none by default."""
+    beside it (`saved_activations`), none by default.
+
+    With `loop_steps` > 1 the walk over the segments is run that many times
+    over the same `params["blocks"]`, the final norm after every pass and
+    its output the next pass's input, as one `lax.scan` over the passes
+    (the program is traced and lowered once, not `loop_steps` times), and
+    what comes back is every pass's normed stream,
+    `[loop_steps, B, T, d]`: a weight's gradient is the sum over its
+    uses, and a kept name is held once a layer a pass."""
+    _refuse_unmapped_loop(cfg, seq_axis, mesh)
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -2162,28 +2248,88 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
 
         return body
 
-    readings, routed_before = [], 0
-    for seg, blks in zip(segments(cfg), _segment_trees(params["blocks"])):
-        periods = _periods(blks[0])
-        # this segment's rows of the bias, one [periods, E] per routed layer
-        # of its period
-        biases = [None] * len(blks)
-        routed = [i for i, kind in enumerate(seg.layout) if kind.routed]
-        if expert_bias is not None and routed:
-            rows = expert_bias[
-                routed_before:routed_before + periods * len(routed)
-            ].reshape(periods, len(routed), -1)
-            routed_before += periods * len(routed)
-            for j, i in enumerate(routed):
-                biases[i] = rows[:, j]
-        x, of_period = jax.lax.scan(
-            scan_body(periods > 1, seg.layout), x, (blks, biases))
-        if of_period:
-            readings.append(_layer_axis(of_period, stack=True))
-    readings = _layer_axis(readings, stack=False) if readings else None
-    with jax.named_scope("final_norm"):
-        x = fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
-    return x, readings
+    def walk(x):
+        """The stream through every segment once, and the layers'
+        readings."""
+        readings, routed_before = [], 0
+        for seg, blks in zip(segments(cfg), _segment_trees(params["blocks"])):
+            periods = _periods(blks[0])
+            # this segment's rows of the bias, one [periods, E] per routed
+            # layer of its period
+            biases = [None] * len(blks)
+            routed = [i for i, kind in enumerate(seg.layout) if kind.routed]
+            if expert_bias is not None and routed:
+                rows = expert_bias[
+                    routed_before:routed_before + periods * len(routed)
+                ].reshape(periods, len(routed), -1)
+                routed_before += periods * len(routed)
+                for j, i in enumerate(routed):
+                    biases[i] = rows[:, j]
+            x, of_period = jax.lax.scan(
+                scan_body(periods > 1, seg.layout), x, (blks, biases))
+            if of_period:
+                readings.append(_layer_axis(of_period, stack=True))
+        return x, _layer_axis(readings, stack=False) if readings else None
+
+    def final_norm(x):
+        with jax.named_scope("final_norm"):
+            return fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
+
+    if cfg.loop_steps == 1:
+        x, readings = walk(x)
+        return final_norm(x), readings
+
+    if cfg.remat:
+        # the norm's float32 values are made again in the backward: kept,
+        # the scan over the passes stacks them, 8 bytes a value a pass
+        final_norm = jax.checkpoint(final_norm)
+
+    def one_pass(x, _):
+        with jax.named_scope("ut_pass"):
+            x, readings = walk(x)
+        if readings is not None:
+            raise NotImplementedError(
+                f"loop_steps {cfg.loop_steps} over layers that make "
+                f"readings ({sorted(readings)}): a routed feed-forward's, "
+                "sparse attention's and kda's are one a layer, not one a "
+                "layer a pass, yet")
+        normed = final_norm(x)
+        return _next_pass_input(x, normed), normed
+
+    _, streams = jax.lax.scan(one_pass, x, None, length=cfg.loop_steps)
+    return streams, None
+
+
+def _next_pass_input(left, normed):
+    """What a pass hands the next: the stream as the final norm leaves it,
+    not as the last layer left it. Under a name of its own: a test hands on
+    the other to show what the comparison reads then."""
+    return normed
+
+
+def _refuse_unmapped_loop(cfg: TransformerConfig, seq_axis, mesh) -> None:
+    """What `loop_steps` > 1 is not made and tested with yet."""
+    if cfg.loop_steps < 1:
+        raise ValueError(f"loop_steps {cfg.loop_steps}")
+    if cfg.exit_gate and cfg.loop_steps == 1:
+        raise ValueError(
+            "exit_gate with loop_steps 1: the exit distribution is over "
+            "the passes of a stack that is run several times")
+    if cfg.loop_steps == 1:
+        return
+    axes = dict(mesh.shape) if mesh is not None else {}
+    unmapped = [name for name in ("sequence", "expert")
+                if axes.get(name, 1) > 1]
+    if seq_axis is not None or unmapped:
+        raise NotImplementedError(
+            f"loop_steps {cfg.loop_steps} under a "
+            f"`{seq_axis or unmapped[0]}` axis: the passes' streams and the "
+            "one loss over them are not mapped over it yet")
+    if cfg.heads_held:
+        raise NotImplementedError(
+            f"loop_steps {cfg.loop_steps} with heads_held "
+            f"{tuple(cfg.heads_held)}: a share of the heads hands on a "
+            "partial sum, which no pass can norm and take as its input")
 
 
 def transformer_hidden(params, tokens, cfg: TransformerConfig, **kw):
@@ -2194,9 +2340,11 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, **kw):
     pass seq_axis and positions holding GLOBAL positions so RoPE and causal
     masks are correct. When called under
     a jit that shards over `mesh`, pass the mesh: the Pallas attention
-    kernel is mapped over its batch and head axes.
+    kernel is mapped over its batch and head axes. With `loop_steps` > 1:
+    the last pass's.
     """
-    return _hidden_and_readings(params, tokens, cfg, **kw)[0]
+    hidden = _hidden_and_readings(params, tokens, cfg, **kw)[0]
+    return hidden if cfg.loop_steps == 1 else hidden[-1]
 
 
 def _unembed(params, cfg: TransformerConfig):
@@ -2235,6 +2383,76 @@ def _head_loss(hidden, unembed, targets, mesh=None):
         out_specs=P(), check_vma=False)(hidden, unembed, targets)
 
 
+# the dtype of the exit gate's logits, the exit distribution and its entropy,
+# under a name of its own: a test lowers it to show what the comparison
+# reads then
+_EXIT_F32 = jnp.float32
+
+
+def exit_distribution(gate_logits):
+    """(p, log p) [T, ...] float32: the distribution over the pass a token
+    leaves after, from the T passes' gate logits `a` [T, ...]. With
+    `lambda(t) = sigmoid(a(t))`: `p(t) = lambda(t) prod_{j<t} (1 -
+    lambda(j))` for `t < T`, and the last pass takes what is left, `p(T) =
+    prod_{j<T} (1 - lambda(j))`: the last gate's reading is unused. The
+    logarithm is formed from log-sigmoids, never as the log of a product,
+    so a gate at +-30 gives a finite one."""
+    a = gate_logits.astype(_EXIT_F32)
+    stay = jax.nn.log_sigmoid(-a)  # log (1 - lambda)
+    stayed = jnp.cumsum(  # sum over j < t: the last gate is in none
+        jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]]), axis=0)
+    log_p = jnp.concatenate(
+        [jax.nn.log_sigmoid(a[:-1]) + stayed[:-1], stayed[-1:]], axis=0)
+    return jnp.exp(log_p), log_p
+
+
+def exit_probabilities(streams, exit_w, exit_b):
+    """(p [T, ...], the entropy of p a token [...]) of the T passes' normed
+    streams `[T, ..., d]` under the gate `exit_w` [d], `exit_b`: the gate's
+    logits, the exit distribution and its entropy as the step computes
+    them, float32 whatever the streams' dtype."""
+    with jax.named_scope("exit_gate"):
+        # a multiply and a sum, not a matmul: no float32 copy of the streams
+        logits = (streams.astype(_EXIT_F32)
+                  * exit_w.astype(_EXIT_F32)).sum(-1) + exit_b.astype(_EXIT_F32)
+    with jax.named_scope("exit_loss"):
+        p, log_p = exit_distribution(logits)
+        return p, -(p * log_p).sum(0)
+
+
+def _exit_loss(streams, params, targets, cfg: TransformerConfig,
+               ignore_index: int = -100):
+    """(`1/N sum_i [sum_t p(t)_i ce(t)_i - beta H(p_i)]`, the readings
+    {ut_pass_loss [T], exit_p_mean [T], exit_entropy}) of the T passes'
+    normed streams `[T, B, S, d]`: arXiv:2510.25741's first-stage
+    objective, the expected cross-entropy under the exit distribution less
+    `exit_entropy_coef` times its entropy (the KL to a uniform prior, up
+    to a constant). The gate, the distribution and the entropy are float32.
+    The four heads are ONE call of the weighted chunked cross-entropy over
+    the streams stacked as T N tokens (targets tiled, weights `p(t)_i /
+    N`): one float32 accumulator for the unembedding's gradient, and the
+    gate learns through the weights (`d loss / d weight_i` is token i's
+    cross-entropy)."""
+    passes = streams.shape[0]
+    mask = (targets != ignore_index).astype(jnp.float32)
+    count = jnp.maximum(mask.sum(), 1.0)
+    p, entropy = exit_probabilities(
+        streams, params["exit_w"], params["exit_b"])
+    with jax.named_scope("exit_loss"):
+        entropy = ((entropy * mask).sum() / count).astype(jnp.float32)
+        weights = (p * (mask / count)).astype(jnp.float32)
+    with jax.named_scope("lm_head_ce"):
+        expected, ce = weighted_lm_head_cross_entropy(
+            streams, _unembed(params, cfg),
+            jnp.broadcast_to(targets, (passes, *targets.shape)), weights)
+    loss = expected - cfg.exit_entropy_coef * entropy
+    return loss, {
+        "ut_pass_loss": ce.sum((1, 2)) / count,  # 0 at an ignored target
+        "exit_p_mean": (p * mask).sum((1, 2)) / count,
+        "exit_entropy": entropy,
+    }
+
+
 def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     """(loss, readings). Next-token CE; batch: {'tokens': [B, T+1] or
     ('tokens','targets')}.
@@ -2261,12 +2479,20 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     `index_keep` [L, B, T, T] int8 (1 where a query keeps a key; the step
     does not report it). Layers of kda read `kda_log_decay_min` (the most
     negative running log decay at a chunk's end, over layers, heads and
-    channels) and `kda_beta_mean`."""
+    channels) and `kda_beta_mean`. A stack that is run `loop_steps` times
+    under an `exit_gate` has `_exit_loss`'s loss and readings:
+    `ut_pass_loss` [passes] (the mean cross-entropy after each pass),
+    `exit_p_mean` [passes] (the mean exit probability of each pass; sums to
+    1) and `exit_entropy`; without the gate the last pass's cross-entropy."""
     if "targets" in batch:
         tokens, targets = batch["tokens"], batch["targets"]
     else:
         tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     hidden, readings = _hidden_and_readings(params, tokens, cfg, **kw)
+    if cfg.exit_gate:
+        return _exit_loss(hidden, params, targets, cfg)
+    if cfg.loop_steps > 1:  # no gate: the last pass's head alone
+        hidden = hidden[-1]
     with jax.named_scope("lm_head_ce"):
         loss = _head_loss(hidden, _unembed(params, cfg), targets,
                           kw.get("mesh"))
@@ -2344,7 +2570,6 @@ if _UNRANKED:  # the rule would pass such a name by and never keep it
         f"a sublayer makes the names {sorted(_UNRANKED)}, which "
         "_SAVE_ORDER does not rank")
 _SAVE_RESERVE = 1 << 30  # the step stays this far under the device's limit
-_HEAD_CHUNK = 2048  # `lm_head_cross_entropy`'s chunk_tokens
 
 
 def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
@@ -2391,12 +2616,14 @@ def _exchange_bytes(cfg: TransformerConfig, tokens: int, ways: int) -> int:
 
 def _saved_bytes(cfg: TransformerConfig, tokens: int) -> Dict[str, int]:
     """Bytes a device holds of each named activation over all the layers
-    that make it, for `tokens` tokens on the device, in `_SAVE_ORDER`."""
+    that make it, for `tokens` tokens on the device, in `_SAVE_ORDER`: once
+    a layer a pass of `loop_steps`."""
     item = jnp.dtype(cfg.dtype).itemsize
     total: Dict[str, int] = {}
     for kind in cfg.layers:
         for name, width in _layer_widths(cfg, kind)[0].items():
-            total[name] = total.get(name, 0) + tokens * width * item
+            total[name] = total.get(name, 0) + (
+                cfg.loop_steps * tokens * width * item)
     return {name: total[name] for name in _SAVE_ORDER if name in total}
 
 
@@ -2435,8 +2662,36 @@ def _head_bytes(cfg: TransformerConfig, tokens: int, param_bytes: int,
                // _whole_param_bytes(cfg))
     if expert_ways > 1:  # whole: float32, its cast, its float32 gradient
         unembed = cfg.vocab_size * cfg.d_model * (4 + item + 4)
-    return (_HEAD_CHUNK * cfg.vocab_size * (4 + 4 + item) + unembed
+    return (HEAD_CHUNK * cfg.vocab_size * (4 + 4 + item) + unembed
             + tokens * cfg.d_model * item)
+
+
+def _boundary_bytes(cfg: TransformerConfig, tokens: int) -> int:
+    """The blocks' inputs, which a rematerialised stack keeps whatever else
+    it keeps, and the stream that leaves the last: once a layer a pass of
+    `loop_steps`, `loop_steps n_layers + 1` in all. A looped stack (whose
+    passes are a scan) also holds, a pass each, the final norm's input,
+    the normed stream that the head and the gate read (stacked for the one
+    call over all the passes) and that stream's cotangent."""
+    streams = cfg.loop_steps * cfg.n_layers + 1
+    if cfg.loop_steps > 1:
+        streams += 3 * cfg.loop_steps
+    return streams * tokens * cfg.d_model * _item(cfg)
+
+
+def _pass_bytes(cfg: TransformerConfig, tokens: int, param_bytes: int) -> int:
+    """What the backward of one pass of a looped stack holds beside the
+    rest: the pass's own blocks' inputs, sliced out of all the passes' for
+    the scan over its layers, and the layers' gradient for this pass alone,
+    a stack of its own that is added to the sum over the passes when the
+    pass is done (the compiler's plan for a described v5e, PR 57: 0.54 and
+    1.64 GB of a scratch of 9.04 at Ouro-2.6B's widths, 8 layers, 16,384
+    tokens)."""
+    if cfg.loop_steps == 1:
+        return 0
+    layers = sum(_layer_widths(cfg, kind)[1] for kind in cfg.layers)
+    return (cfg.n_layers * tokens * cfg.d_model * _item(cfg)
+            + 4 * layers * param_bytes // _whole_param_bytes(cfg))
 
 
 def _working_set_bytes(cfg: TransformerConfig, tokens: int,
@@ -2463,7 +2718,16 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     v5e: 7.855 GB of scratch in the routed scan's body, 5.45 in the dense
     layer ahead of it, which is walked), and 15.66
     for 14.937 on `mistral7b.fsdp4`'s four chips; where every segment is
-    one period long `_moments`' table has the pairs. It errs to the full
+    one period long `_moments`' table has the pairs. A stack that is run
+    `loop_steps` times holds more (`_boundary_bytes`, `_pass_bytes`): 16.80
+    for 16.608 in `ouro.tokens16k` with nothing kept (my chip runs, PR 57;
+    the compiler's plan for a described v5e: 7.35 GB of state and 9.17 of
+    scratch, 16.53, of which 2.45 the gradients, 1.64 the layers' gradient
+    a second time for the pass in hand, 2.15 the 32 blocks' inputs and 0.54
+    the pass's own eight sliced out, 0.81 the passes' streams three times,
+    0.82 the eight layers' weights in bf16 hoisted out of both loops,
+    which the rule leaves to what it overcounts in the block, every value
+    at once). It errs to the full
     side: a name too few costs a percent, a step that asks for the chip's
     last GiB is compiled to fit and runs slower than the one that keeps
     nothing."""
@@ -2474,8 +2738,8 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     cfg = _on_an_expert_axis(cfg, expert_ways)
     block = max(_block_bytes(cfg, kind, tokens, sharded, exchange)
                 for kind in set(cfg.layers))
-    boundaries = (cfg.n_layers + 1) * tokens * cfg.d_model * _item(cfg)
-    return boundaries + max(block, head)
+    return (_boundary_bytes(cfg, tokens) + max(block, head)
+            + _pass_bytes(cfg, tokens, param_bytes))
 
 
 class _Moment(NamedTuple):
@@ -2546,11 +2810,15 @@ def _moments(cfg: TransformerConfig, tokens: int, param_bytes: int,
     at_once = _working_set_bytes(cfg, tokens, param_bytes, expert_ways)
     cfg = _on_an_expert_axis(cfg, expert_ways)
     on_device = _whole_param_bytes(cfg)
-    boundaries = (cfg.n_layers + 1) * tokens * d * item
+    boundaries = _boundary_bytes(cfg, tokens)
+    # under `loop_steps` the passes are a scan: a layer's gradient is summed
+    # over them, whole from the first, as a scanned segment's is
+    scanned = [seg.periods > 1 or cfg.loop_steps > 1 for seg in segments(cfg)]
 
     def kept_bytes(kind: LayerKind) -> int:
         widths = _layer_widths(cfg, kind)[0]
-        return tokens * item * sum(widths.get(name, 0) for name in kept)
+        return cfg.loop_steps * tokens * item * sum(
+            widths.get(name, 0) for name in kept)
 
     def gradient(kind: LayerKind) -> int:
         return 4 * _layer_widths(cfg, kind)[1] * param_bytes // on_device
@@ -2558,18 +2826,18 @@ def _moments(cfg: TransformerConfig, tokens: int, param_bytes: int,
     kept_all = sum(kept_bytes(kind) for kind in cfg.layers)
     # the gradients of the layers no scan stacks: none is made before its
     # layer's backward
-    inlined = sum(gradient(kind) for seg in segments(cfg)
-                  if seg.periods == 1 for kind in seg.layout)
+    inlined = sum(gradient(kind) for seg, scan in zip(segments(cfg), scanned)
+                  if not scan for kind in seg.layout)
     # the head's gradient once the head is done: a device's share of it
     unembed = 4 * cfg.vocab_size * d * param_bytes // whole
     moments = [_Moment("optimizer", param_bytes),
                _Moment("head", boundaries + kept_all + head
                        + param_bytes - inlined)]
     first, behind = cfg.n_layers, 0  # walked from the last layer back
-    for seg in reversed(segments(cfg)):
+    for seg, scan in reversed(list(zip(segments(cfg), scanned))):
         layers = len(seg.layout) * seg.periods
         first -= layers
-        if seg.periods > 1:
+        if scan:
             moments.append(_Moment(
                 "layers %d-%d" % (first, first + layers - 1),
                 at_once + kept_all + param_bytes))
@@ -2660,7 +2928,8 @@ def _memory_limit(mesh) -> Optional[int]:
 _STEP_READINGS = ("aux_loss", "z_loss", "expert_load", "held_slots",
                   "dropped_slots", "chip_load", "chip_load_max_over_mean",
                   "index_loss", "index_keys_min_gap", "index_keys_max_gap",
-                  "kda_log_decay_min", "kda_beta_mean")
+                  "kda_log_decay_min", "kda_beta_mean",
+                  "ut_pass_loss", "exit_p_mean", "exit_entropy")
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
@@ -2804,14 +3073,19 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
 
 def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
     """(matmul fwd flops/token over the layers, causal attn fwd flops/token
-    over the layers, lm-head fwd flops/token)."""
+    over the layers, lm-head fwd flops/token): every layer once a pass of
+    `loop_steps`; under an `exit_gate` the head and the gate's `d_model`
+    multiply-adds once a pass too."""
     matmul = attn = 0.0
     for kind in cfg.layers:
         for sub in _sublayers(kind):
             of_matmuls, of_attention = sub.flops(cfg, seq_len)
-            matmul += of_matmuls
-            attn += of_attention
-    return matmul, attn, 2 * cfg.d_model * cfg.vocab_size
+            matmul += cfg.loop_steps * of_matmuls
+            attn += cfg.loop_steps * of_attention
+    head = 2 * cfg.d_model * cfg.vocab_size
+    if cfg.exit_gate:
+        head = cfg.loop_steps * (head + 2 * cfg.d_model)
+    return matmul, attn, head
 
 
 def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:

@@ -42,13 +42,20 @@ def softmax_cross_entropy(logits, labels, *, ignore_index: int = -100):
     return (per_tok * mask).sum() / n, n
 
 
+# tokens a chunk of `lm_head_cross_entropy`: the one name the call and the
+# rule of what a rematerialised block keeps (`models/transformer.py`
+# `_head_bytes`) take it from
+HEAD_CHUNK = 2048
+
+
 def lm_head_cross_entropy(
     hidden,
     unembed,
     targets,
     *,
-    chunk_tokens: int = 2048,
+    chunk_tokens: int = HEAD_CHUNK,
     ignore_index: int = -100,
+    weights=None,
 ):
     """Fused LM-head + token CE that never materializes [B*T, V] logits.
 
@@ -62,8 +69,43 @@ def lm_head_cross_entropy(
     memory drops from B*T*V*4 bytes (gigabytes at GPT-2 vocab) to
     chunk_tokens*V*4, which is what lets large-vocab models train at large
     batch on one chip. Returns (mean_loss, valid_token_count).
+
+    With `weights` (float32, of `targets`' shape, differentiable) it returns
+    (`sum_i weights_i ce_i`, valid_token_count) instead of the mean: the
+    first output of `weighted_lm_head_cross_entropy`. Without them the
+    traced program is the one it was.
     """
-    return _lm_head_ce(hidden, unembed, targets, chunk_tokens, ignore_index)
+    if weights is None:
+        return _lm_head_ce(hidden, unembed, targets, chunk_tokens, ignore_index)
+    loss, _ = weighted_lm_head_cross_entropy(
+        hidden, unembed, targets, weights, chunk_tokens=chunk_tokens,
+        ignore_index=ignore_index)
+    return loss, _valid_count(targets, ignore_index)
+
+
+def weighted_lm_head_cross_entropy(
+    hidden,
+    unembed,
+    targets,
+    weights,
+    *,
+    chunk_tokens: int = HEAD_CHUNK,
+    ignore_index: int = -100,
+):
+    """(`sum_i weights_i ce_i`, `ce` float32 of `targets`' shape): the
+    chunked head under a weight a token. `hidden` [..., d] and `targets`,
+    `weights` [...] may have any leading shape (several streams over one
+    head stacked, their targets tiled: one float32 accumulator for the
+    unembedding's gradient however many streams). An ignored target's `ce`
+    is 0 whatever its weight.
+
+    Under differentiation the forward folds `weights_i` into `dlogits` as
+    `lm_head_cross_entropy` folds `mask / count`, three matmuls a chunk,
+    and keeps the chunks' `ce` (four bytes a token) for `d loss / d
+    weights_i = ce_i`; no `[tokens, V]` array is held. The second output is
+    a reading: no gradient passes through it."""
+    return _lm_head_ce_weighted(
+        hidden, unembed, targets, weights, chunk_tokens, ignore_index)
 
 
 def _token_chunks(hidden, targets, chunk_tokens, ignore_index):
@@ -107,15 +149,40 @@ def _own_cotangent(x):
 _own_cotangent.defvjp(lambda x: (x, None), lambda _, g: (_own_buffer(g),))
 
 
-def _chunk_loss_terms(hc, tc, w, ignore_index):
+def _chunk_token_terms(hc, tc, w, ignore_index):
     """One chunk's f32 (logits, logsumexp, one-hot label index, mask,
-    summed loss)."""
+    every token's loss: 0 at an ignored target)."""
     logits = (hc @ w).astype(jnp.float32)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     safe = jnp.where(tc == ignore_index, 0, tc)
     picked = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
     mask = (tc != ignore_index).astype(jnp.float32)
-    return logits, lse, safe, mask, ((lse - picked) * mask).sum()
+    return logits, lse, safe, mask, (lse - picked) * mask
+
+
+def _chunk_loss_terms(hc, tc, w, ignore_index):
+    """One chunk's f32 (logits, logsumexp, one-hot label index, mask,
+    summed loss)."""
+    *terms, ce = _chunk_token_terms(hc, tc, w, ignore_index)
+    return (*terms, ce.sum())
+
+
+def _chunk_gradients(hc, w, logits, lse, safe, scale):
+    """(dh, this chunk's share of dW in float32) from the logits a chunk
+    holds: dlogits = (softmax - onehot) * scale() a token, in f32, cast to
+    the compute dtype before it feeds the MXU (as autodiff casts it, the
+    transpose of the logits' cast to f32). `scale` is called where the
+    factor stood when this was written out in `_lm_head_ce_fwd`: the
+    traced program is that one, equation for equation."""
+    onehot = safe[:, None] == jnp.arange(logits.shape[-1])[None, :]
+    dlogits = (
+        (jnp.exp(logits - lse[:, None]) - onehot) * scale()[:, None]
+    ).astype(hc.dtype)
+    dh = jax.lax.dot_general(dlogits, w, (((1,), (1,)), ((), ())))
+    dw = jax.lax.dot_general(
+        hc, dlogits, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return dh, dw
 
 
 def _valid_count(targets, ignore_index):
@@ -154,15 +221,9 @@ def _lm_head_ce_fwd(hidden, unembed, targets, chunk_tokens, ignore_index):
         hc = _own_buffer(hc)  # read by the logits' matmul and by dW's
         logits, lse, safe, mask, loss = _chunk_loss_terms(
             hc, tc, w, ignore_index)
-        onehot = safe[:, None] == jnp.arange(logits.shape[-1])[None, :]
-        dlogits = (
-            (jnp.exp(logits - lse[:, None]) - onehot)
-            * (mask / count)[:, None]
-        ).astype(hc.dtype)
-        dh = jax.lax.dot_general(dlogits, w, (((1,), (1,)), ((), ())))
-        dw = dw + jax.lax.dot_general(
-            hc, dlogits, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dh, dw_chunk = _chunk_gradients(
+            hc, w, logits, lse, safe, lambda: mask / count)
+        dw = dw + dw_chunk
         return (loss_sum + loss, dw), _own_buffer(dh)
 
     (loss_sum, dw), dh = jax.lax.scan(
@@ -186,3 +247,76 @@ def _lm_head_ce_bwd(chunk_tokens, ignore_index, saved, cotangents):
 
 
 _lm_head_ce.defvjp(_lm_head_ce_fwd, _lm_head_ce_bwd)
+
+
+# ------------------------------------------------ the head under weights
+
+def _weight_chunks(weights, chunk_tokens):
+    """`weights` as `_token_chunks` lays the tokens out; the padding's
+    weights are 0 (its targets are ignored)."""
+    w = weights.reshape(-1).astype(jnp.float32)
+    pad = (-w.shape[0]) % chunk_tokens
+    if pad:
+        w = jnp.concatenate([w, jnp.zeros((pad,), w.dtype)], axis=0)
+    return w.reshape(-1, chunk_tokens)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _lm_head_ce_weighted(hidden, unembed, targets, weights, chunk_tokens,
+                         ignore_index):
+    h, t = _token_chunks(hidden, targets, chunk_tokens, ignore_index)
+    w = unembed.astype(hidden.dtype)
+
+    def body(loss_sum, xs):
+        hc, tc, wc = xs
+        *_, ce = _chunk_token_terms(_own_buffer(hc), tc, w, ignore_index)
+        return loss_sum + (ce * wc).sum(), ce
+
+    loss_sum, ce = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        (h, t, _weight_chunks(weights, chunk_tokens)))
+    return loss_sum, ce.reshape(-1)[:targets.size].reshape(targets.shape)
+
+
+def _lm_head_ce_weighted_fwd(hidden, unembed, targets, weights, chunk_tokens,
+                             ignore_index):
+    """`_lm_head_ce_fwd` with the token's weight where it has `1 / count`;
+    the chunks' `ce` is an output and the residual for the weights'
+    gradient."""
+    h, t = _token_chunks(hidden, targets, chunk_tokens, ignore_index)
+    w = unembed.astype(hidden.dtype)
+
+    def body(carry, xs):
+        loss_sum, dw = carry
+        hc, tc, wc = xs
+        hc = _own_buffer(hc)  # read by the logits' matmul and by dW's
+        logits, lse, safe, mask, ce = _chunk_token_terms(
+            hc, tc, w, ignore_index)
+        dh, dw_chunk = _chunk_gradients(
+            hc, w, logits, lse, safe, lambda: mask * wc)
+        return (loss_sum + (ce * wc).sum(), dw + dw_chunk), (
+            _own_buffer(dh), ce)
+
+    (loss_sum, dw), (dh, ce) = jax.lax.scan(
+        body,
+        (jnp.zeros((), jnp.float32), jnp.zeros(unembed.shape, jnp.float32)),
+        (h, t, _weight_chunks(weights, chunk_tokens)),
+    )
+    n = targets.size
+    dh = dh.reshape(-1, hidden.shape[-1])[:n].reshape(hidden.shape)
+    ce = ce.reshape(-1)[:n].reshape(targets.shape)
+    return (loss_sum, ce), (dh, dw.astype(unembed.dtype), ce, weights)
+
+
+def _lm_head_ce_weighted_bwd(chunk_tokens, ignore_index, saved, cotangents):
+    dh, dw, ce, weights = saved
+    g = cotangents[0]  # `ce` is a reading: its cotangent is not taken
+    return (
+        (dh.astype(jnp.float32) * g).astype(dh.dtype),
+        (dw.astype(jnp.float32) * g).astype(dw.dtype),
+        None,
+        (ce * g).astype(weights.dtype).reshape(weights.shape),
+    )
+
+
+_lm_head_ce_weighted.defvjp(_lm_head_ce_weighted_fwd, _lm_head_ce_weighted_bwd)

@@ -26,8 +26,10 @@ from ray_tpu.ops.flash_attention import (  # noqa: E402
 # the module: `ray_tpu.ops.flash_attention` is the function of that name
 fa = importlib.import_module("ray_tpu.ops.flash_attention")
 from ray_tpu.ops.fused import (  # noqa: E402
+    HEAD_CHUNK,
     lm_head_cross_entropy,
     softmax_cross_entropy,
+    weighted_lm_head_cross_entropy,
 )
 
 
@@ -626,6 +628,134 @@ def test_lm_head_ce_ignore_index():
     per = lse - logits[np.arange(8), np.where(targets[0] < 0, 0, targets[0])]
     expect = per[4:].mean()
     np.testing.assert_allclose(float(loss), expect, rtol=1e-5)
+
+
+# ------------------------------------------- the head under weights a token
+
+def _weights_like(targets, key=3):
+    return jax.random.uniform(
+        jax.random.PRNGKey(key), targets.shape, jnp.float32, 0.1, 2.0)
+
+
+def _weighted_whole_logits(h, w, wt, targets, ignore_index=-100):
+    """`sum_i w_i ce_i` by whole logits, in h's dtype as the head rounds."""
+    logits = (h @ w.astype(h.dtype)).astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    safe = jnp.where(targets == ignore_index, 0, targets)
+    picked = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
+    return ((lse - picked) * (targets != ignore_index) * wt).sum()
+
+
+def _weighted_case_plain():
+    h, w, t = _ce_inputs()
+    return h, w, _weights_like(t), t, 80
+
+
+def _weighted_case_ignored_targets():
+    h, w, t = _ce_inputs()
+    t = t.at[:, ::3].set(-100)
+    return h, w, _weights_like(t), t, 80
+
+
+def _weighted_case_stacked_streams():
+    """Four streams over one head, targets tiled: `[4, B, T, d]`."""
+    h, w, t = _ce_inputs()
+    h = jnp.stack([h, 2 * h, h + 1, -h])
+    t = jnp.broadcast_to(t, (4, *t.shape))
+    return h, w, _weights_like(t), t, 64
+
+
+def _weighted_case_one_chunk():
+    h, w, t = _ce_inputs()
+    return h, w, _weights_like(t), t, 4096
+
+
+_WEIGHTED_CASES = [_weighted_case_plain, _weighted_case_ignored_targets,
+                   _weighted_case_stacked_streams, _weighted_case_one_chunk]
+
+
+@pytest.mark.parametrize(
+    "case", _WEIGHTED_CASES, ids=lambda f: f.__name__[len("_weighted_case_"):])
+def test_weighted_lm_head_ce_against_whole_logits(case):
+    """`sum_i w_i ce_i`, and its gradients to the hidden rows, the
+    unembedding and the weights (token i's cross-entropy, 0 at an ignored
+    target), against whole logits; the second output is every token's
+    cross-entropy and the count is the valid targets'."""
+    h, w, wt, t, chunk = case()
+
+    def chunked(h, w, wt):
+        return lm_head_cross_entropy(
+            h, w, t, chunk_tokens=chunk, weights=wt)[0]
+
+    grad = lambda f: jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+    ln, gn = grad(chunked)(h, w, wt)
+    lr, gr = grad(lambda h, w, wt: _weighted_whole_logits(h, w, wt, t))(
+        h, w, wt)
+    np.testing.assert_allclose(ln, lr, rtol=1e-5)
+    for a, b in zip(gn, gr):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=1e-5)
+    loss, count = lm_head_cross_entropy(h, w, t, chunk_tokens=chunk, weights=wt)
+    assert float(count) == float((np.asarray(t) != -100).sum())
+    total, ce = weighted_lm_head_cross_entropy(h, w, t, wt, chunk_tokens=chunk)
+    assert ce.shape == t.shape and ce.dtype == jnp.float32
+    np.testing.assert_allclose(gn[2], ce, rtol=1e-6)  # d loss / d w_i = ce_i
+    np.testing.assert_allclose(float((ce * wt).sum()), float(total), rtol=1e-5)
+    assert (np.asarray(ce)[np.asarray(t) == -100] == 0).all()
+
+
+def test_weighted_lm_head_ce_with_uniform_weights_is_the_mean():
+    """Weights of `1 / count` give `lm_head_cross_entropy`'s mean, gradients
+    and all, in bf16 too (the same roundings: the weight is folded into
+    dlogits where `mask / count` is)."""
+    h, w, t = _ce_inputs(jnp.bfloat16)
+    t = t.at[0, :7].set(-100)
+    count = float((np.asarray(t) != -100).sum())
+    uniform = jnp.full(t.shape, 1.0 / count, jnp.float32)
+    mean = lambda h, w: _chunked(h, w, t)
+    weighted = lambda h, w: lm_head_cross_entropy(
+        h, w, t, chunk_tokens=80, weights=uniform)[0]
+    grad = lambda f: jax.jit(jax.value_and_grad(f, argnums=(0, 1)))
+    lm, gm = grad(mean)(h, w)
+    lw, gw = grad(weighted)(h, w)
+    np.testing.assert_allclose(lw, lm, rtol=1e-6)
+    for a, b in zip(gw, gm):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b)
+
+
+def test_weighted_lm_head_ce_holds_no_tokens_by_vocabulary_array():
+    """Differentiated, the lowered program has the carried f32 [d, V]
+    gradient once, the chunk's [chunk, V] logits, and no [tokens, V] array:
+    three matmuls a chunk."""
+    h, w, t = _ce_inputs(jnp.bfloat16)  # 192 tokens, V 257, chunks of 64
+    wt = _weights_like(t)
+    f = lambda h, w, wt: lm_head_cross_entropy(
+        h, w, t, chunk_tokens=64, weights=wt)[0]
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(h, w, wt).as_text()
+    assert "tensor<32x257xf32>" in text and "tensor<64x257xf32>" in text
+    assert "192x257" not in text and "3x64x257" not in text
+    assert text.count("stablehlo.dot_general") == 3
+    assert "stablehlo.while" in text
+
+
+def test_lm_head_ce_without_weights_traces_the_program_it_was():
+    """`weights=None` is today's program: the same jaxpr, forward and
+    differentiated, as calling the unweighted `custom_vjp` itself; the
+    default chunk is the one name the rule prices the head by."""
+    from ray_tpu.ops import fused
+
+    h, w, t = _ce_inputs(jnp.bfloat16)
+    public = lambda h, w: lm_head_cross_entropy(h, w, t, weights=None)[0]
+    direct = lambda h, w: fused._lm_head_ce(h, w, t, HEAD_CHUNK, -100)[0]
+    for wrap in (lambda f: f, lambda f: jax.grad(f, argnums=(0, 1))):
+        assert str(jax.make_jaxpr(wrap(public))(h, w)) == str(
+            jax.make_jaxpr(wrap(direct))(h, w))
+    assert HEAD_CHUNK == 2048
+    from ray_tpu.models import transformer
+
+    assert transformer.HEAD_CHUNK is HEAD_CHUNK
+    assert not hasattr(transformer, "_HEAD_CHUNK")
 
 
 def test_tensor_column_roundtrip_through_blocks():

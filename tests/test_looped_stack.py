@@ -1,0 +1,516 @@
+"""A stack that is run `loop_steps` times over the same weights
+(`ray_tpu/models/transformer.py`): the looped walk against the same blocks
+applied by hand, outputs and every gradient; `loop_steps` 1 with the new
+fields off traces the program it was; the exit distribution and its entropy;
+the sandwich norms; what the rule of a rematerialised block's kept names
+counts a pass; what is refused; the scopes. CPU, tiny sizes, seeded
+weights."""
+
+import dataclasses
+import hashlib
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import TransformerConfig
+from ray_tpu.models import transformer as tr
+from ray_tpu.ops.fused import fused_rmsnorm, weighted_lm_head_cross_entropy
+from ray_tpu.parallel import make_mesh
+
+TINY = dict(vocab_size=97, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+            max_seq_len=16, dtype=jnp.float32, tied_embeddings=False,
+            attention_impl="xla")
+
+
+def looped(**over):
+    return TransformerConfig(**{
+        **TINY, "loop_steps": 4, "post_norm": True, "exit_gate": True,
+        "exit_entropy_coef": 0.05, **over})
+
+
+def seeded(cfg, seed=0):
+    params = tr.transformer_init(jax.random.PRNGKey(seed), cfg)
+    # norms off 1, the gate's bias off 0: a gradient that a scale or a bias
+    # of exactly 1 or 0 would hide shows
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def moved(path, x):
+        name = str(path[-1].key)
+        if "norm" in name or name == "exit_b":
+            return x + 0.1 * jax.random.normal(next(keys), x.shape)
+        return x
+
+    return jax.tree_util.tree_map_with_path(moved, params)
+
+
+def batch_of(cfg, rows=2, seed=1):
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(seed), (rows, cfg.max_seq_len + 1), 0,
+        cfg.vocab_size)
+    return {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+
+
+def streams_by_hand(params, tokens, cfg, norm_between=True):
+    """The passes' normed streams with no loop of the program's: a Python
+    loop over the passes and the layers, every layer through `_block` on its
+    own slice of the stacked weights."""
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    kind = cfg.layers[0]
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    streams = []
+    for _ in range(cfg.loop_steps):
+        for layer in range(cfg.n_layers):
+            blk = jax.tree.map(lambda w: w[layer], params["blocks"])
+            x, _ = tr._block(x, blk, positions, None, cfg, kind, None, 1)
+        normed = fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
+        streams.append(normed)
+        x = normed if norm_between else x
+    return jnp.stack(streams)
+
+
+def loss_by_hand(params, batch, cfg):
+    streams = streams_by_hand(params, batch["tokens"], cfg)
+    return tr._exit_loss(streams, params, batch["targets"], cfg)[0]
+
+
+# ------------------------------------------------ the loop against by hand
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("n_layers", [1, 2])  # a scan of length 1 is inlined
+def test_the_looped_stack_is_its_blocks_applied_four_times(remat, n_layers):
+    cfg = looped(remat=remat, n_layers=n_layers)
+    params, batch = seeded(cfg), batch_of(cfg)
+    streams, readings = tr._hidden_and_readings(params, batch["tokens"], cfg)
+    assert readings is None and streams.shape == (4, 2, 16, 32)
+    by_hand = streams_by_hand(params, batch["tokens"], cfg)
+    np.testing.assert_allclose(streams, by_hand, atol=2e-5)
+    # a pass changes the stream: the four are four
+    assert float(jnp.abs(streams[1:] - streams[:-1]).max()) > 1e-2
+    np.testing.assert_allclose(
+        tr.transformer_hidden(params, batch["tokens"], cfg), by_hand[-1],
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("saved", [(), ("attn_res", "mlp_up")])
+def test_every_gradient_is_the_sum_over_a_weights_four_uses(saved):
+    cfg = looped(remat=True)
+    params, batch = seeded(cfg), batch_of(cfg)
+    loss, grads = jax.value_and_grad(
+        lambda p: tr.transformer_loss(p, batch, cfg, saved_names=saved))(params)
+    by_hand, grads_by_hand = jax.value_and_grad(loss_by_hand)(
+        params, batch, cfg)
+    assert float(loss) == pytest.approx(float(by_hand), rel=1e-6)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(flat) == 16  # 11 of the blocks, embed, unembed, norm, gate's 2
+    for (path, ours), theirs in zip(flat, jax.tree.leaves(grads_by_hand)):
+        assert float(jnp.abs(theirs).max()) > 0, path
+        np.testing.assert_allclose(ours, theirs, atol=3e-5, rtol=1e-4,
+                                   err_msg=str(path))
+
+
+def test_without_a_gate_the_loss_is_the_last_passs_head_alone():
+    """And the looped stack's gradient is not a stack's that is run once."""
+    cfg = looped(exit_gate=False, exit_entropy_coef=0.0)
+    once = dataclasses.replace(cfg, loop_steps=1)
+    params, batch = seeded(cfg), batch_of(cfg)
+    g4 = jax.grad(lambda p: tr.transformer_loss(p, batch, cfg))(params)
+    g1 = jax.grad(lambda p: tr.transformer_loss(p, batch, once))(params)
+    assert float(jnp.abs(g4["blocks"]["wq"] - g1["blocks"]["wq"]).max()) > 1e-4
+    # without the gate the loss is the last pass's cross-entropy alone
+    streams = streams_by_hand(params, batch["tokens"], cfg)
+    last = tr._head_loss(streams[-1], params["unembed"], batch["targets"])
+    assert float(tr.transformer_loss(params, batch, cfg)) == pytest.approx(
+        float(last), rel=1e-6)
+
+
+# --------------------------------- loop_steps 1: the program that there was
+
+# the equations of value_and_grad of the loss at the parent commit (d31b413),
+# their number and a sha256 over them (`equations`): a dense and a routed
+# tiny configuration, with and without remat
+PARENT_JAXPRS = {
+    "dense": (435, "36e1424ae285fbcc"),
+    "dense_remat": (569, "8c9a5e7ea992b224"),
+    "routed": (685, "d7aaea4832fc33de"),
+    "routed_remat": (900, "29ccd153f13e5b16"),
+}
+
+
+def parent_case(name):
+    over = dict(TINY, dtype=jnp.bfloat16)
+    if name.startswith("routed"):
+        over.update(n_experts=4, experts_per_token=2, d_ff=32, qk_norm=True)
+    return TransformerConfig(remat=name.endswith("remat"), **over)
+
+
+def equations(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs inside its equations,
+    depth first, as (primitive, the outputs' shapes and dtypes, the
+    parameters that are plain values). How the printer shares a
+    sub-program between its uses, which a process's cache of traces
+    decides, is not in it."""
+    plain = (bool, int, float, str, type(None))
+    for eqn in jaxpr.eqns:
+        inner, values = [], []
+        for key, value in sorted(eqn.params.items()):
+            for item in value if isinstance(value, (tuple, list)) else [value]:
+                item = getattr(item, "jaxpr", item)  # a ClosedJaxpr's own
+                if hasattr(item, "eqns"):
+                    inner.append(item)
+            if isinstance(value, plain) or (
+                    isinstance(value, tuple)
+                    and all(isinstance(v, plain) for v in value)):
+                values.append((key, value))
+        yield (eqn.primitive.name,
+               tuple(str(v.aval) for v in eqn.outvars), tuple(values))
+        for sub in inner:
+            yield from equations(sub)
+
+
+def jaxpr_digest(cfg):
+    batch = batch_of(cfg)
+    params = jax.eval_shape(
+        lambda: tr.transformer_init(jax.random.PRNGKey(0), cfg))
+    closed = jax.make_jaxpr(jax.value_and_grad(
+        lambda p: tr.transformer_loss_and_readings(
+            p, batch, cfg, saved_names=("attn_res",) if cfg.remat else ()),
+        has_aux=True))(params)
+    listed = list(equations(closed.jaxpr))
+    return len(listed), hashlib.sha256(repr(listed).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_JAXPRS))
+def test_loop_steps_1_traces_the_parents_program(name):
+    cfg = parent_case(name)
+    assert (cfg.loop_steps, cfg.post_norm, cfg.exit_gate) == (1, False, False)
+    assert jaxpr_digest(cfg) == PARENT_JAXPRS[name]
+
+
+def test_the_new_fields_off_add_no_leaf_and_no_reading():
+    cfg = TransformerConfig(**TINY)
+    params = tr.transformer_init(jax.random.PRNGKey(0), cfg)
+    assert set(params) == {"embed", "blocks", "final_norm", "unembed"}
+    assert not any("post_norm" in name for name in params["blocks"])
+    loss, readings = tr.transformer_loss_and_readings(
+        params, batch_of(cfg), cfg)
+    assert readings == {}
+    hidden = tr.transformer_hidden(params, batch_of(cfg)["tokens"], cfg)
+    assert hidden.shape == (2, 16, 32)
+
+
+# ----------------------------------------- the exit distribution, the loss
+
+def distribution_f64(a):
+    """numpy, float64: p from the sigmoids' products, as the paper writes."""
+    a = np.asarray(a, np.float64)
+    lam = 1.0 / (1.0 + np.exp(-a))
+    p, left = [], np.ones_like(a[0])
+    for t in range(a.shape[0] - 1):
+        p.append(lam[t] * left)
+        left = left * (1.0 - lam[t])
+    p.append(left)
+    return np.stack(p)
+
+
+@pytest.mark.parametrize("passes", [2, 4, 7])
+def test_the_exit_distribution_sums_to_1(passes):
+    a = 3.0 * jax.random.normal(jax.random.PRNGKey(passes), (passes, 5, 11))
+    p, log_p = tr.exit_distribution(a)
+    assert p.shape == log_p.shape == a.shape and p.dtype == jnp.float32
+    np.testing.assert_allclose(p.sum(0), 1.0, atol=1e-6)
+    np.testing.assert_allclose(p, distribution_f64(a), atol=1e-6)
+    np.testing.assert_allclose(jnp.exp(log_p), p, rtol=1e-6)
+    # the last gate's reading is unused
+    moved = a.at[-1].add(5.0)
+    np.testing.assert_array_equal(tr.exit_distribution(moved)[0], p)
+
+
+@pytest.mark.parametrize("gate", [30.0, -30.0, 88.0, -88.0])
+def test_the_distribution_is_finite_at_large_gates(gate):
+    a = jnp.full((4, 3), gate).at[1, 1].set(-gate).at[2, 2].set(0.0)
+    p, log_p = tr.exit_distribution(a)
+    assert bool(jnp.isfinite(p).all()) and bool(jnp.isfinite(log_p).all())
+    np.testing.assert_allclose(p.sum(0), 1.0, atol=1e-6)
+    entropy = lambda a: -(lambda p, lp: (p * lp).sum())(*tr.exit_distribution(a))
+    grads = jax.grad(entropy)(a)
+    assert bool(jnp.isfinite(grads).all())
+    assert float(jnp.abs(grads[-1]).max()) == 0.0  # the last gate's
+
+
+def test_the_entropys_gradient_agrees_with_a_float64_form():
+    a = jax.random.normal(jax.random.PRNGKey(5), (4, 6))
+    entropy = lambda a: -(lambda p, lp: (p * lp).sum())(*tr.exit_distribution(a))
+    ours = np.asarray(jax.grad(entropy)(a), np.float64)
+
+    def entropy_f64(a):
+        p = distribution_f64(a)
+        return -(p * np.log(p)).sum()
+
+    base, step = np.asarray(a, np.float64), 1e-6
+    theirs = np.zeros_like(base)
+    for index in np.ndindex(*base.shape):
+        up, down = base.copy(), base.copy()
+        up[index] += step
+        down[index] -= step
+        theirs[index] = (entropy_f64(up) - entropy_f64(down)) / (2 * step)
+    assert float(entropy(a)) == pytest.approx(entropy_f64(base), rel=1e-6)
+    np.testing.assert_allclose(ours, theirs, atol=2e-6)
+
+
+def test_the_loss_is_the_expected_cross_entropy_less_beta_entropy():
+    cfg = looped()
+    params, batch = seeded(cfg), batch_of(cfg)
+    targets = batch["targets"].at[0, :5].set(-100)  # ignored: count 27
+    streams = streams_by_hand(params, batch["tokens"], cfg)
+    loss, readings = tr._exit_loss(streams, params, targets, cfg)
+    logits = streams @ params["unembed"]
+    ce = jax.scipy.special.logsumexp(logits, -1) - jnp.take_along_axis(
+        logits, jnp.where(targets < 0, 0, targets)[None, ..., None].repeat(4, 0),
+        axis=-1)[..., 0]
+    gate = streams @ params["exit_w"] + params["exit_b"]
+    p = distribution_f64(gate)
+    mask = np.asarray(targets >= 0, np.float64)
+    entropy = (-(p * np.log(p)).sum(0) * mask).sum() / 27
+    expected = ((p * np.asarray(ce)).sum(0) * mask).sum() / 27
+    assert float(loss) == pytest.approx(expected - 0.05 * entropy, rel=1e-5)
+    assert float(readings["exit_entropy"]) == pytest.approx(entropy, rel=1e-5)
+    np.testing.assert_allclose(
+        readings["ut_pass_loss"], (np.asarray(ce) * mask).sum((1, 2)) / 27,
+        rtol=1e-5)
+    np.testing.assert_allclose(
+        readings["exit_p_mean"], (p * mask).sum((1, 2)) / 27, rtol=1e-5)
+    assert float(readings["exit_p_mean"].sum()) == pytest.approx(1.0, abs=1e-6)
+    # beta 0: the expected cross-entropy alone
+    plain, _ = tr._exit_loss(streams, params, targets,
+                             dataclasses.replace(cfg, exit_entropy_coef=0.0))
+    assert float(plain) == pytest.approx(expected, rel=1e-5)
+
+
+def test_the_gate_learns_through_the_weights():
+    """`d loss / d b_g` has the cross-entropies' part: with the weights held
+    constant only the entropy's is left."""
+    cfg = looped()
+    params, batch = seeded(cfg), batch_of(cfg)
+    whole = jax.grad(lambda p: tr.transformer_loss(p, batch, cfg))(params)
+
+    real = tr.weighted_lm_head_cross_entropy
+    try:
+        tr.weighted_lm_head_cross_entropy = lambda h, w, t, wt, **kw: real(
+            h, w, t, jax.lax.stop_gradient(wt), **kw)
+        held = jax.grad(lambda p: tr.transformer_loss(p, batch, cfg))(params)
+    finally:
+        tr.weighted_lm_head_cross_entropy = real
+    assert tr.weighted_lm_head_cross_entropy is weighted_lm_head_cross_entropy
+    gap = float(jnp.linalg.norm(whole["exit_w"] - held["exit_w"]))
+    assert gap > 0.1 * float(jnp.linalg.norm(whole["exit_w"]))
+
+
+# ------------------------------------------------------- the sandwich norms
+
+def test_post_norm_adds_one_scale_a_sublayer_and_norms_the_output():
+    cfg = looped()
+    params = seeded(cfg)
+    blocks = params["blocks"]
+    assert blocks["attn_post_norm"].shape == blocks["mlp_post_norm"].shape == (
+        2, 32)
+    kind = cfg.layers[0]
+    axes = tr._block_axes(cfg, kind)
+    assert set(axes) == set(blocks)
+    assert axes["attn_post_norm"] == axes["mlp_post_norm"] == ("layers", None)
+    # the output joins the stream normed: scaling a sublayer's last matrix
+    # changes nothing
+    batch = batch_of(cfg)
+    scaled = {**params, "blocks": {**blocks, "wo": 3.0 * blocks["wo"],
+                                   "w_down": 0.5 * blocks["w_down"]}}
+    np.testing.assert_allclose(
+        tr.transformer_loss(params, batch, cfg),
+        tr.transformer_loss(scaled, batch, cfg), rtol=2e-5)
+    plain = dataclasses.replace(cfg, post_norm=False)
+
+    def unnormed(p):  # a norm is found by its leaf
+        return {**p, "blocks": {k: v for k, v in p["blocks"].items()
+                                if not k.endswith("post_norm")}}
+
+    moved = tr.transformer_loss(unnormed(scaled), batch, plain) - (
+        tr.transformer_loss(unnormed(params), batch, plain))
+    assert abs(float(moved)) > 1e-3
+    # holds: the product before the norm, a `d_model` each
+    for sub in tr._sublayers(kind):
+        assert sub.holds(cfg) - sub.holds(plain) == cfg.d_model
+        assert sub.params(cfg) == sub.params(plain)
+
+
+@pytest.mark.parametrize("over", [
+    dict(n_experts=4, experts_per_token=2),
+    dict(layer_types=("conv", "full_attention")),
+    dict(sublayer_types=("mamba2", "dense_ff"), mamba_heads=2,
+         mamba_head_dim=16, ssm_state=8),
+])
+def test_post_norm_with_a_sublayer_that_has_none_is_refused(over):
+    cfg = TransformerConfig(**{**TINY, "post_norm": True, **over})
+    with pytest.raises(ValueError, match="post_norm with a"):
+        cfg.layers
+
+
+# ------------------------------------------------------------- what is refused
+
+def test_loop_steps_under_an_unmapped_axis_is_refused():
+    cfg = looped()
+    params, batch = seeded(cfg), batch_of(cfg)
+    for axis in ("sequence", "expert"):
+        mesh = make_mesh({axis: 2}, devices=jax.devices()[:2])
+        with pytest.raises(NotImplementedError, match=f"`{axis}` axis"):
+            tr.transformer_loss(params, batch, cfg, mesh=mesh)
+    with pytest.raises(NotImplementedError, match="`sequence` axis"):
+        tr.transformer_loss(params, batch, cfg, seq_axis="sequence")
+    # a data axis is a batch axis: nothing to map
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    assert math.isfinite(float(tr.transformer_loss(
+        params, batch, cfg, mesh=mesh)))
+
+
+def test_loop_steps_with_a_share_of_the_heads_is_refused():
+    cfg = looped(heads_held=(0, 2))
+    params = tr.transformer_init(jax.random.PRNGKey(0), cfg)
+    with pytest.raises(NotImplementedError, match="heads_held"):
+        tr.transformer_loss(params, batch_of(cfg), cfg)
+
+
+def test_loop_steps_over_layers_that_make_readings_is_refused():
+    cfg = looped(post_norm=False, n_experts=4, experts_per_token=2, d_ff=32)
+    params = tr.transformer_init(jax.random.PRNGKey(0), cfg)
+    with pytest.raises(NotImplementedError, match="make readings"):
+        tr.transformer_loss(params, batch_of(cfg), cfg)
+
+
+@pytest.mark.parametrize("over, error", [
+    (dict(loop_steps=1), "exit_gate with loop_steps 1"),
+    (dict(loop_steps=0, exit_gate=False), "loop_steps 0"),
+])
+def test_a_gate_without_a_loop_is_an_error(over, error):
+    cfg = looped(**over)
+    with pytest.raises(ValueError, match=error):
+        tr.transformer_hidden(
+            tr.transformer_init(jax.random.PRNGKey(0), cfg),
+            batch_of(cfg)["tokens"], cfg)
+
+
+# ---------------------------------------- what a rematerialised block keeps
+
+def rule_config(**over):
+    return looped(**{**dict(
+        remat=True, dtype=jnp.bfloat16, d_model=256, n_heads=4, d_ff=512,
+        n_layers=3, vocab_size=1024, max_seq_len=512), **over})
+
+
+def test_a_kept_name_is_held_once_a_layer_a_pass():
+    cfg = rule_config()
+    once = dataclasses.replace(cfg, loop_steps=1, exit_gate=False)
+    tokens = 2048
+    four, one = tr._saved_bytes(cfg, tokens), tr._saved_bytes(once, tokens)
+    assert list(four) == list(one) == [
+        "attn_ctx", "attn_res", "attn_qkv", "mlp_gate", "mlp_up"]
+    for name in one:
+        assert four[name] == 4 * one[name], name
+    # the blocks' inputs: 4 L + 1 and, a pass each, the final norm's input,
+    # the normed stream and its cotangent; a stack run once L + 1
+    stream = tokens * cfg.d_model * 2
+    assert tr._boundary_bytes(once, tokens) == (3 + 1) * stream
+    assert tr._boundary_bytes(cfg, tokens) == (4 * 3 + 1 + 3 * 4) * stream
+    params = tr._whole_param_bytes(cfg)
+    assert tr._pass_bytes(once, tokens, params) == 0
+    layers = 4 * 3 * (4 * 256 * 256 + 3 * 256 * 512)
+    assert tr._pass_bytes(cfg, tokens, params) == 3 * stream + layers
+    # the kept names of the moments' walk count a pass too
+    kept = ("attn_ctx", "attn_res")
+    moments = {m.name: m.bytes for m in tr._moments(cfg, tokens, params, 1, kept)}
+    bare = {m.name: m.bytes for m in tr._moments(cfg, tokens, params, 1, ())}
+    assert set(moments) == {"optimizer", "head", "layers 0-2"}
+    assert moments["head"] - bare["head"] == four["attn_ctx"] + four["attn_res"]
+    assert moments["layers 0-2"] - bare["layers 0-2"] == (
+        four["attn_ctx"] + four["attn_res"])
+
+
+def test_a_stack_of_one_period_under_the_loop_is_counted_as_a_scan():
+    """The passes are a scan: a layer's gradient is whole from the first
+    pass, so no layer is walked alone."""
+    cfg = rule_config(n_layers=1)
+    names = [m.name for m in tr._moments(
+        cfg, 2048, tr._whole_param_bytes(cfg))]
+    assert names == ["optimizer", "head", "layers 0-0"]
+    once = dataclasses.replace(cfg, loop_steps=1, exit_gate=False)
+    assert [m.name for m in tr._moments(
+        once, 2048, tr._whole_param_bytes(once))] == [
+            "optimizer", "head", "layer 0"]
+
+
+@pytest.mark.parametrize("limit_gb", [0.25, 0.3, 0.35, 0.45, 0.6, 1.0, 4.0])
+def test_saved_activations_never_chooses_more_than_fits(limit_gb):
+    cfg = rule_config()
+    tokens, limit = 2048, int(limit_gb * 2**30) + tr._SAVE_RESERVE
+    params = tr._whole_param_bytes(cfg)
+    resident = 3 * params
+    chosen = tr.saved_activations(cfg, tokens, resident, params, limit)
+    sizes = tr._saved_bytes(cfg, tokens)
+    assert list(chosen) == list(sizes)[:len(chosen)]  # a prefix, in order
+    assert all(chosen[name] == sizes[name] for name in chosen)
+    fullest = tr._fullest_moment(cfg, tokens, params, 1, tuple(chosen))
+    if chosen:
+        assert resident + fullest.bytes + tr._SAVE_RESERVE <= limit
+    if len(chosen) < len(sizes):  # the next name would not have fitted
+        more = (*chosen, list(sizes)[len(chosen)])
+        over = tr._fullest_moment(cfg, tokens, params, 1, more)
+        assert resident + over.bytes + tr._SAVE_RESERVE > limit
+    # the same limit keeps no fewer names of a stack that is run once
+    once = dataclasses.replace(cfg, loop_steps=1, exit_gate=False)
+    p1 = tr._whole_param_bytes(once)
+    assert len(tr.saved_activations(once, tokens, 3 * p1, p1, limit)) >= len(
+        chosen)
+
+
+def test_operations_count_a_layer_and_the_head_once_a_pass():
+    cfg = rule_config()
+    once = dataclasses.replace(cfg, loop_steps=1, exit_gate=False)
+    m4, a4, h4 = tr._fwd_flops_per_token(cfg, 512)
+    m1, a1, h1 = tr._fwd_flops_per_token(once, 512)
+    assert (m4, a4) == (4 * m1, 4 * a1)
+    assert h1 == 2 * 256 * 1024 and h4 == 4 * 2 * 256 * (1024 + 1)
+    # no gate: the last pass's head alone
+    ungated = dataclasses.replace(cfg, exit_gate=False)
+    assert tr._fwd_flops_per_token(ungated, 512) == (m4, a4, h1)
+    assert tr.flops_per_token(cfg, 512) == 3 * (m4 + a4 + h4)
+
+
+# ------------------------------------------------------------------ the scopes
+
+def test_the_scopes_and_the_one_loop_over_the_passes():
+    """`ut_pass`, `post_norm`, `exit_gate`, `exit_loss` stand in the lowered
+    step's name stacks with the ones there were inside them, and the passes
+    are one loop: the blocks' program is traced once, not four times."""
+    cfg = looped(remat=True)
+    params, batch = seeded(cfg), batch_of(cfg)
+    lowered = jax.jit(jax.value_and_grad(
+        lambda p: tr.transformer_loss(p, batch, cfg))).lower(params)
+    text = lowered.as_text(debug_info=True)
+    stacks = {s for s in re.findall(r'loc\("([^"]+)"', text) if "/" in s}
+    parts = {p for s in stacks for p in re.split(r"[/()]", s)}
+    assert {"ut_pass", "post_norm", "exit_gate", "exit_loss", "lm_head_ce",
+            "final_norm", "attention", "mlp", "attn_qkv", "attn_out",
+            "embed"} <= parts
+    # a norm on each sublayer's output, inside the sublayer's own scope (a
+    # loop's body is located apart from the loop: the chip's trace shows
+    # `ut_pass/mlp/post_norm`, docs/observability.md)
+    assert any(s.startswith("mlp/post_norm/") for s in stacks)
+    assert any(s.startswith("attn_out/post_norm/") for s in stacks)
+    jaxpr = str(jax.make_jaxpr(
+        lambda p: tr.transformer_loss(p, batch, cfg))(params))
+    # the scan over the passes, the scan over the layers inside it, the
+    # head's scan over chunks
+    assert jaxpr.count("length=4") == 1 and jaxpr.count("length=2") == 1
+    unrolled = jaxpr.count(" scan[")
+    assert unrolled == 3, unrolled

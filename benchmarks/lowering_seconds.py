@@ -13,10 +13,17 @@ least, the Pallas payloads in the lowered text by kernel name, and the text's
 sha256 with the payloads blanked and as they are (two trees whose first agree
 lower the same program but for the kernels' source locations; two processes
 of one tree must agree on both, or the compile cache never hits).
+
+With `--plan DIR` the step is also compiled, once, and a line says what the
+compiler plans for the chip: all its bytes and the scratch among them, the
+`.remat` fusions it made to fit, the seconds of the compile; DIR takes XLA's
+dump of the step's module, whose `*buffer-assignment.txt` and
+`*memory-usage-report.txt` name every buffer (PERF.md section 7, PR 58).
 """
 
 import argparse
 import collections
+import glob
 import hashlib
 import json
 import os
@@ -32,9 +39,15 @@ def main() -> None:
     parser.add_argument("cells", nargs="+")
     parser.add_argument("--tree", default=".", help="the checkout to lower")
     parser.add_argument("--repeat", type=int, default=3)
+    parser.add_argument("--plan", metavar="DIR", help="compile the step too "
+                        "and dump its module's buffer assignment here")
     args = parser.parse_args()
     root = os.path.abspath(args.tree)
     sys.path.insert(0, root)
+    if args.plan:  # read when the backend starts: before jax is imported
+        os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + (
+            f" --xla_dump_to={os.path.abspath(args.plan)}"
+            " --xla_dump_hlo_as_text --xla_dump_hlo_module_re=.*step.*")
 
     import jax
     from jax.experimental import topologies
@@ -73,6 +86,26 @@ def main() -> None:
             "sha256": hashlib.sha256(blank.encode()).hexdigest()[:16],
             "sha256_with_payloads": hashlib.sha256(
                 text.encode()).hexdigest()[:16],
+        }), flush=True)
+        if not (args.plan and program == "step"):
+            return
+        t0 = time.perf_counter()
+        compiled = lowered.compile()
+        seconds = round(time.perf_counter() - t0, 3)
+        # the heap XLA packed, which `memory_analysis()` does not give
+        report = sorted(glob.glob(os.path.join(
+            args.plan, "*jit_step*memory-usage-report.txt")))[-1]
+        with open(report) as f:
+            sizes = f.read()
+        print("PLANNED " + json.dumps({
+            "tree": args.tree, "cell": cell, "compile_s": seconds,
+            "total_bytes": int(re.search(
+                r"Total bytes used: (\d+)", sizes).group(1)),
+            "scratch": re.search(
+                r"size ([\d.]+\w+), preallocated-temp", sizes).group(1),
+            "remat_fusions": len(re.findall(
+                r"^\s*%?[\w.-]*\.remat[\w.]* = ", compiled.as_text(), re.M)),
+            "report": report,
         }), flush=True)
 
     for cell_name in args.cells:

@@ -109,8 +109,10 @@ A stack may be run several times over the same weights (`loop_steps`;
 arXiv:2510.25741's looped language model): the walk over the segments is
 one `lax.scan` over the passes, the final norm after every pass and its
 output the next pass's input, and every pass's normed stream comes back. A
-weight's gradient is the sum over its uses; a kept name is held once a
-layer a pass. `post_norm` norms plain attention's and the dense
+weight's gradient is the sum over its uses, made under `remat` by a
+backward of the stack's own that adds each use's into the one sum
+(`_looped_under_remat`); a kept name is held once a layer for each of the
+last passes that keep it. `post_norm` norms plain attention's and the dense
 feed-forward's output before it joins the stream (four norms a layer).
 With `exit_gate` one `Linear(d_model, 1)` reads every pass's stream, its
 sigmoids make a distribution over the pass a token leaves after
@@ -148,7 +150,9 @@ its values are made again in the backward pass, but for the named ones
 device it is traced for (`saved_activations`: from the widths, the tokens
 and the state a device holds, the device's memory limit and which of
 `segments`' runs a scan stacks, whose moments `_moments` walks in the
-backward's order; nothing where no limit can be read).
+backward's order; nothing where no limit can be read). A stack that is run
+`loop_steps` times keeps a name for the last k of its passes, the most that
+fit: the backward reaches them first.
 
 Every weight of a block's plain matmuls leaves its gradient's matmul as an
 array of its own in the compute dtype, and in a segment of one period also
@@ -2211,7 +2215,10 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
     (the program is traced and lowered once, not `loop_steps` times), and
     what comes back is every pass's normed stream,
     `[loop_steps, B, T, d]`: a weight's gradient is the sum over its
-    uses, and a kept name is held once a layer a pass."""
+    uses. Under `cfg.remat` the looped stack is differentiated by its own
+    backward (`_looped_under_remat`), and an entry of `saved_names` may be
+    a pair of a name and the number of the last passes that keep it;
+    without `remat` JAX differentiates the two scans."""
     _refuse_unmapped_loop(cfg, seq_axis, mesh)
     B, T = tokens.shape
     if positions is None:
@@ -2271,28 +2278,48 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
                 readings.append(_layer_axis(of_period, stack=True))
         return x, _layer_axis(readings, stack=False) if readings else None
 
-    def final_norm(x):
+    def final_norm(x, scale=params["final_norm"]):
         with jax.named_scope("final_norm"):
-            return fused_rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
+            return fused_rmsnorm(x, scale, eps=cfg.norm_eps)
 
     if cfg.loop_steps == 1:
         x, readings = walk(x)
         return final_norm(x), readings
 
-    if cfg.remat:
-        # the norm's float32 values are made again in the backward: kept,
-        # the scan over the passes stacks them, 8 bytes a value a pass
-        final_norm = jax.checkpoint(final_norm)
-
-    def one_pass(x, _):
-        with jax.named_scope("ut_pass"):
-            x, readings = walk(x)
+    def refuse(readings):
         if readings is not None:
             raise NotImplementedError(
                 f"loop_steps {cfg.loop_steps} over layers that make "
                 f"readings ({sorted(readings)}): a routed feed-forward's, "
                 "sparse attention's and kda's are one a layer, not one a "
                 "layer a pass, yet")
+
+    if cfg.remat:
+        def block_of(kind: LayerKind, sliced: bool, names: Tuple[str, ...]):
+            fn = partial(_block, bias=None, cfg=cfg, kind=kind,
+                         seq_axis=seq_axis, seq_size=seq_size, mesh=mesh,
+                         keep_ctx="attn_ctx" in names, sliced=sliced)
+
+            def block(x, blk, positions):
+                with jax.named_scope("ut_pass"):
+                    x, readings = fn(x, blk, positions)
+                refuse(readings)
+                return x
+
+            return block
+
+        # the norm is made again in the backward, as a block is
+        streams = _looped_under_remat(
+            x, _segment_trees(params["blocks"]), params["final_norm"],
+            positions, segments(cfg), cfg.loop_steps,
+            _passes_kept(cfg, saved_names), block_of,
+            jax.checkpoint(final_norm))
+        return streams, None
+
+    def one_pass(x, _):
+        with jax.named_scope("ut_pass"):
+            x, readings = walk(x)
+        refuse(readings)
         normed = final_norm(x)
         return _next_pass_input(x, normed), normed
 
@@ -2305,6 +2332,245 @@ def _next_pass_input(left, normed):
     not as the last layer left it. Under a name of its own: a test hands on
     the other to show what the comparison reads then."""
     return normed
+
+
+def _passes_kept(cfg: TransformerConfig, kept) -> Dict[str, int]:
+    """{name: the passes it is kept for} of `kept`, whose entries are names
+    (kept at every pass of `loop_steps`) or pairs of a name and the number of
+    the stack's last passes that keep it."""
+    passes = {}
+    for entry in kept:
+        name, k = (entry, cfg.loop_steps) if isinstance(entry, str) else entry
+        if not 0 <= k <= cfg.loop_steps:
+            raise ValueError(
+                f"{name} kept at {k} of {cfg.loop_steps} passes")
+        if k:
+            passes[name] = k
+    return passes
+
+
+class _Halves(NamedTuple):
+    """A block under `jax.checkpoint` cut where `jax.vjp` cuts it
+    (`_halves`)."""
+    forward: Any  # ClosedJaxpr: the flat arguments -> (out, *leaves)
+    pull: Any     # the pullback's treedef: its leaves are `sources`'
+    # of each leaf, the flat argument it is (its index) or the
+    # `checkpoint_name` it was kept under
+    sources: Tuple[Union[int, str], ...]
+
+    def kept(self, leaves) -> Dict[str, list]:
+        """{name: its values among `leaves`, in the program's order}."""
+        out: Dict[str, list] = {}
+        for leaf, source in zip(leaves, self.sources):
+            if isinstance(source, str):
+                out.setdefault(source, []).append(leaf)
+        return out
+
+
+def _halves(fn, names: Tuple[str, ...], *args) -> _Halves:
+    """`fn(*args) -> array` under `jax.checkpoint` keeping `names`, as the
+    two halves `jax.vjp` makes of it, so that one loop can run the first and
+    another the second: the forward as a jaxpr that also hands out what the
+    pullback holds, and the pullback as the treedef those leaves fill. What
+    such a pullback holds is its arguments and the kept names' values, in
+    the program's order; which is which is read off the jaxpr (a kept value
+    comes out of a `name` equation)."""
+    policy = (jax.checkpoint_policies.save_only_these_names(*names)
+              if names else None)
+    checkpointed = jax.checkpoint(fn, policy=policy)
+    seen = {}
+
+    def forward(*args):
+        out, pull = jax.vjp(checkpointed, *args)
+        leaves, seen["pull"] = jax.tree.flatten(pull)
+        return out, leaves
+
+    closed = jax.make_jaxpr(forward)(*args)
+    jaxpr = closed.jaxpr
+    made_by = {out: eqn for eqn in jaxpr.eqns for out in eqn.outvars}
+
+    def source(var):
+        if var in jaxpr.invars:
+            return jaxpr.invars.index(var)
+        eqn = made_by.get(var)
+        while eqn is not None and eqn.primitive.name != "name":
+            # the barrier a kept value passes on its way out
+            eqn = made_by.get(eqn.invars[0]) if len(eqn.invars) == 1 else None
+        if eqn is None or eqn.params["name"] not in names:
+            raise NotImplementedError(
+                f"a block's pullback holds {var.aval.str_short()}, which is "
+                f"neither one of its arguments nor one of {names}")
+        return eqn.params["name"]
+
+    return _Halves(closed, seen["pull"],
+                   tuple(source(var) for var in jaxpr.outvars[1:]))
+
+
+def _looped_under_remat(x, trees, norm_scale, positions, segs, passes: int,
+                        kept: Dict[str, int], block_of, final_norm):
+    """The stack of `segs` (`segments(cfg)`; `trees` their stacked weights)
+    run `passes` times from `x`, the final norm after every pass: the
+    passes' normed streams `[passes, B, T, d]`, as one unit of
+    differentiation.
+
+    JAX's own transpose of a scan over the passes round a scan over the
+    layers holds the layers' float32 gradient twice (the pass in hand beside
+    the sum over the passes) and slices the pass's blocks' inputs out of all
+    of them. Here the forward is those two scans and keeps the blocks'
+    inputs `[passes, periods, B, T, d]`, the final norm's inputs and, of
+    each name of `kept` ({name: k}), the values of its last k passes; the
+    backward is a loop over the passes from the last to the first and
+    inside it one over a segment's periods, which reads a block's input and
+    its weights by index, makes the block again under `jax.checkpoint` (the
+    names the pass keeps read by index too, the others made again), pulls
+    the stream's cotangent back through it and adds the weights' gradient
+    into its row of the one accumulator the loops carry.
+    `block_of(kind, sliced, names)` is a block `(x, blk, positions) -> x`
+    that keeps `names`; `final_norm(x, scale)`."""
+    first = {name: passes - k for name, k in kept.items()}  # pass to keep it
+    # the passes at which more is kept than in the pass before, and what a
+    # pass keeps then: one backward of the block each, chosen by the pass
+    grows = sorted(set(first.values()) - {0})
+    choices = [tuple(name for name in kept if first[name] <= at)
+               for at in (0, *grows)]
+
+    def shape_of(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    halves = [[[_halves(
+        block_of(kind, seg.periods > 1, names), names, shape_of(x),
+        shape_of(jax.tree.map(lambda w: w[0], blk)), shape_of(positions))
+        for names in choices] for kind, blk in zip(seg.layout, blks)]
+        for seg, blks in zip(segs, trees)]
+
+    def row(name, periods, p, j):
+        """Where pass p's period j lies among a kept name's values; a pass
+        that does not keep the name lands on the first row, which the first
+        pass that does writes after it."""
+        return jnp.clip((p - first[name]) * periods + j, 0,
+                        kept[name] * periods - 1)
+
+    def run(x, trees, norm_scale, positions, keep: bool):
+        """(the passes' streams; with `keep` what the backward reads: the
+        blocks' inputs, the final norm's, the kept names' values)."""
+        # the kept names' values of the passes that keep them, no row yet
+        store = [[{name: [jnp.zeros((kept[name] * seg.periods, *a.shape),
+                                    a.dtype) for a in avals]
+                   for name, avals in of_choice[-1].kept(
+                       of_choice[-1].forward.out_avals[1:]).items()}
+                  for of_choice in of_seg]
+                 for seg, of_seg in zip(segs, halves)] if keep else None
+
+        def one_pass(carry, p):
+            x, store = carry
+            inputs = []
+            for s, (seg, blks) in enumerate(zip(segs, trees)):
+                def period(carry, of_period, s=s, seg=seg):
+                    x, stored = carry
+                    blks, j = of_period
+                    ins, stored = [], list(stored) if keep else None
+                    for i, blk in enumerate(blks):
+                        ins.append(x)
+                        half = halves[s][i][-1]
+                        x, *leaves = jax.core.eval_jaxpr(
+                            half.forward.jaxpr, half.forward.consts,
+                            *jax.tree.leaves((x, blk, positions)))
+                        if not keep:
+                            continue
+                        made = half.kept(leaves)
+                        at = {name: row(name, seg.periods, p, j)
+                              for name in made}
+                        stored[i] = {
+                            name: [buf.at[at[name]].set(value) for buf, value
+                                   in zip(stored[i][name], made[name])]
+                            for name in made}
+                    return (x, stored), ins
+
+                (x, of_seg), ins = jax.lax.scan(
+                    period, (x, store[s] if keep else None),
+                    (blks, jnp.arange(seg.periods)))
+                if keep:
+                    store = [*store[:s], of_seg, *store[s + 1:]]
+                inputs.append(ins)
+            normed = final_norm(x, norm_scale)
+            return (_next_pass_input(x, normed), store), (inputs, x, normed)
+
+        (_, store), (inputs, ends, streams) = jax.lax.scan(
+            one_pass, (x, store), jnp.arange(passes))
+        return streams, (inputs, ends, store)
+
+    @jax.custom_vjp
+    def stack(x, trees, norm_scale, positions):
+        return run(x, trees, norm_scale, positions, keep=False)[0]
+
+    def forward(x, trees, norm_scale, positions):
+        streams, made = run(x, trees, norm_scale, positions, keep=True)
+        return streams, (made, trees, norm_scale, positions)
+
+    def pass_end(x, scale):
+        normed = final_norm(x, scale)
+        return _next_pass_input(x, normed), normed
+
+    def backward(held, d_streams):
+        (inputs, ends, store), trees, norm_scale, positions = held
+
+        def pulled(s, i, choice, ct, x, blk, p, j):
+            """The block's pullback at `ct` under the names of `choice`,
+            the kept values read from their rows."""
+            half = halves[s][i][choice]
+            flat = jax.tree.leaves((x, blk, positions))
+            rows = {name: iter(bufs) for name, bufs in store[s][i].items()}
+            leaves = [
+                flat[source] if isinstance(source, int) else
+                next(rows[source])[row(source, segs[s].periods, p, j)]
+                for source in half.sources]
+            d_x, d_blk, _ = jax.tree.unflatten(half.pull, leaves)(ct)
+            return d_x, d_blk
+
+        def one_pass(choice, last, n, carry):
+            ct, grads, d_scale = carry
+            p = last - n
+            ct, d = jax.vjp(pass_end, ends[p], norm_scale)[1](
+                (ct, d_streams[p]))
+            d_scale = d_scale + d
+            grads = list(grads)
+            for s in reversed(range(len(segs))):
+                seg = segs[s]
+
+                def period(m, carry, s=s, seg=seg):
+                    ct, of_seg = carry
+                    j = seg.periods - 1 - m
+                    of_seg = list(of_seg)
+                    for i in reversed(range(len(seg.layout))):
+                        ct, d_blk = pulled(
+                            s, i, choice, ct, inputs[s][i][p, j],
+                            jax.tree.map(lambda w: w[j], trees[s][i]), p, j)
+                        of_seg[i] = jax.tree.map(
+                            lambda total, d: total.at[j].add(d),
+                            of_seg[i], d_blk)
+                    return ct, of_seg
+
+                ct, grads[s] = jax.lax.fori_loop(
+                    0, seg.periods, period, (ct, grads[s]))
+            return ct, grads, d_scale
+
+        carry = (jnp.zeros_like(d_streams[0]),
+                 jax.tree.map(jnp.zeros_like, trees),
+                 jnp.zeros_like(norm_scale))
+        # a loop for each run of passes that keep the same names, the last
+        # passes' first: two branches of one loop's body would each hold
+        # their block's values through the other's turn
+        starts = (0, *grows, passes)
+        for choice in reversed(range(len(choices))):
+            start, after = starts[choice], starts[choice + 1]
+            carry = jax.lax.fori_loop(
+                0, after - start, partial(one_pass, choice, after - 1), carry)
+        ct, grads, d_scale = carry
+        return ct, grads, d_scale, None
+
+    stack.defvjp(forward, backward)
+    return stack(x, trees, norm_scale, positions)
 
 
 def _refuse_unmapped_loop(cfg: TransformerConfig, seq_axis, mesh) -> None:
@@ -2656,42 +2922,44 @@ def _block_bytes(cfg: TransformerConfig, kind: LayerKind, tokens: int,
 def _head_bytes(cfg: TransformerConfig, tokens: int, param_bytes: int,
                 expert_ways: int) -> int:
     """The head's chunk in its backward: a chunk's logits, their gradient
-    and its cast, the unembedding's cast and the normed stream."""
+    and its cast, the unembedding's cast and the normed stream (a looped
+    stack's head reads every pass's: `loop_steps` of them)."""
     item = _item(cfg)
     unembed = (cfg.vocab_size * cfg.d_model * item * param_bytes
                // _whole_param_bytes(cfg))
     if expert_ways > 1:  # whole: float32, its cast, its float32 gradient
         unembed = cfg.vocab_size * cfg.d_model * (4 + item + 4)
     return (HEAD_CHUNK * cfg.vocab_size * (4 + 4 + item) + unembed
-            + tokens * cfg.d_model * item)
+            + cfg.loop_steps * tokens * cfg.d_model * item)
 
 
 def _boundary_bytes(cfg: TransformerConfig, tokens: int) -> int:
     """The blocks' inputs, which a rematerialised stack keeps whatever else
     it keeps, and the stream that leaves the last: once a layer a pass of
-    `loop_steps`, `loop_steps n_layers + 1` in all. A looped stack (whose
-    passes are a scan) also holds, a pass each, the final norm's input,
-    the normed stream that the head and the gate read (stacked for the one
-    call over all the passes) and that stream's cotangent."""
+    `loop_steps`, `loop_steps n_layers + 1` in all. A looped stack also
+    holds through its backward, a pass each, the final norm's input and the
+    cotangent of the normed stream that the head and the gate read; the
+    normed streams themselves are the head's (`_head_bytes`) and gone when
+    the stack's backward starts."""
     streams = cfg.loop_steps * cfg.n_layers + 1
     if cfg.loop_steps > 1:
-        streams += 3 * cfg.loop_steps
+        streams += 2 * cfg.loop_steps
     return streams * tokens * cfg.d_model * _item(cfg)
 
 
-def _pass_bytes(cfg: TransformerConfig, tokens: int, param_bytes: int) -> int:
-    """What the backward of one pass of a looped stack holds beside the
-    rest: the pass's own blocks' inputs, sliced out of all the passes' for
-    the scan over its layers, and the layers' gradient for this pass alone,
-    a stack of its own that is added to the sum over the passes when the
-    pass is done (the compiler's plan for a described v5e, PR 57: 0.54 and
-    1.64 GB of a scratch of 9.04 at Ouro-2.6B's widths, 8 layers, 16,384
-    tokens)."""
+def _pass_bytes(cfg: TransformerConfig, param_bytes: int) -> int:
+    """What the loops of a looped stack hold beside the rest: every layer's
+    weights in the compute dtype. The compiler casts the stack of them once,
+    ahead of the forward's loop, and both loops read a layer's by index (its
+    plan for a described v5e, PR 58: 0.82 GB at Ouro-2.6B's widths and 8
+    layers, with or without a barrier on the slice). The backward
+    (`_looped_under_remat`) holds nothing else of its own: the layers'
+    gradient once, added to in place, and no pass's inputs apart from all
+    the passes'."""
     if cfg.loop_steps == 1:
         return 0
     layers = sum(_layer_widths(cfg, kind)[1] for kind in cfg.layers)
-    return (cfg.n_layers * tokens * cfg.d_model * _item(cfg)
-            + 4 * layers * param_bytes // _whole_param_bytes(cfg))
+    return _item(cfg) * layers * param_bytes // _whole_param_bytes(cfg)
 
 
 def _working_set_bytes(cfg: TransformerConfig, tokens: int,
@@ -2719,15 +2987,16 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     layer ahead of it, which is walked), and 15.66
     for 14.937 on `mistral7b.fsdp4`'s four chips; where every segment is
     one period long `_moments`' table has the pairs. A stack that is run
-    `loop_steps` times holds more (`_boundary_bytes`, `_pass_bytes`): 16.80
-    for 16.608 in `ouro.tokens16k` with nothing kept (my chip runs, PR 57;
-    the compiler's plan for a described v5e: 7.35 GB of state and 9.17 of
-    scratch, 16.53, of which 2.45 the gradients, 1.64 the layers' gradient
-    a second time for the pass in hand, 2.15 the 32 blocks' inputs and 0.54
-    the pass's own eight sliced out, 0.81 the passes' streams three times,
-    0.82 the eight layers' weights in bf16 hoisted out of both loops,
-    which the rule leaves to what it overcounts in the block, every value
-    at once). It errs to the full
+    `loop_steps` times holds more (`_boundary_bytes`, `_pass_bytes`): in
+    `ouro.tokens16k` 15.72 with `attn_ctx` kept for the last of the four
+    passes (15.17 with nothing kept) for the compiler's plan for a
+    described v5e of 15.54 (14.83): 7.35 GB of state and 7.35 of scratch
+    with nothing kept, of which 2.45 the gradients (the layers' 1.64 once,
+    the loops' carry), 2.15 the 32 blocks' inputs, 0.54 the passes' final
+    norm's inputs and their streams' cotangents, 0.82 the eight layers'
+    weights in bf16, cast once ahead of both loops (`_pass_bytes`), and
+    the block in hand (PERF.md section 6, PR 58, which has the chip's
+    peak). It errs to the full
     side: a name too few costs a percent, a step that asks for the chip's
     last GiB is compiled to fit and runs slower than the one that keeps
     nothing."""
@@ -2739,7 +3008,7 @@ def _working_set_bytes(cfg: TransformerConfig, tokens: int,
     block = max(_block_bytes(cfg, kind, tokens, sharded, exchange)
                 for kind in set(cfg.layers))
     return (_boundary_bytes(cfg, tokens) + max(block, head)
-            + _pass_bytes(cfg, tokens, param_bytes))
+            + _pass_bytes(cfg, param_bytes))
 
 
 class _Moment(NamedTuple):
@@ -2815,10 +3084,12 @@ def _moments(cfg: TransformerConfig, tokens: int, param_bytes: int,
     # over them, whole from the first, as a scanned segment's is
     scanned = [seg.periods > 1 or cfg.loop_steps > 1 for seg in segments(cfg)]
 
+    passes = _passes_kept(cfg, kept)
+
     def kept_bytes(kind: LayerKind) -> int:
         widths = _layer_widths(cfg, kind)[0]
-        return cfg.loop_steps * tokens * item * sum(
-            widths.get(name, 0) for name in kept)
+        return tokens * item * sum(
+            k * widths.get(name, 0) for name, k in passes.items())
 
     def gradient(kind: LayerKind) -> int:
         return 4 * _layer_widths(cfg, kind)[1] * param_bytes // on_device
@@ -2897,6 +3168,10 @@ def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
     of `expert_ways` devices the routed layers are a device's share of them.
     The first name that
     does not fit ends the choice, so a larger limit only ever adds names.
+    A stack that is run `loop_steps` times keeps a name for the last k of
+    its passes, each name the most that leave room, its bytes those of the
+    k passes, and the first name that gets fewer than all ends the choice;
+    a stack that is run once keeps a name or does not.
     With no limit to read (the CPU, a described topology) or without
     `remat` nothing is kept, and the step is the one without a policy."""
     if limit_bytes is None or not cfg.remat:
@@ -2904,11 +3179,26 @@ def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
     chosen: Dict[str, int] = {}
     for name, size in _saved_bytes(
             _on_an_expert_axis(cfg, expert_ways), tokens_per_device).items():
-        if _room_bytes(cfg, tokens_per_device, resident_bytes, param_bytes,
-                       limit_bytes, expert_ways, (*chosen, name)) < 0:
+        kept = _kept_passes(cfg, tokens_per_device, chosen, expert_ways)
+        k = next((k for k in range(cfg.loop_steps, 0, -1) if _room_bytes(
+            cfg, tokens_per_device, resident_bytes, param_bytes, limit_bytes,
+            expert_ways, (*kept, (name, k))) >= 0), 0)
+        if k:
+            chosen[name] = size * k // cfg.loop_steps
+        if k < cfg.loop_steps:
             break
-        chosen[name] = size
     return chosen
+
+
+def _kept_passes(cfg: TransformerConfig, tokens_per_device: int,
+                 saved: Dict[str, int],
+                 expert_ways: int = 1) -> Tuple[Tuple[str, int], ...]:
+    """`saved_activations`' choice as pairs of a name and the last passes
+    of `loop_steps` that keep it, from the bytes it is kept at."""
+    whole = _saved_bytes(_on_an_expert_axis(cfg, expert_ways),
+                         tokens_per_device)
+    return tuple((name, cfg.loop_steps * size // whole[name])
+                 for name, size in saved.items())
 
 
 def _memory_limit(mesh) -> Optional[int]:
@@ -3014,15 +3304,19 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         params = on_a_device(state["params"], p_shard)
         ways = mesh.shape.get("expert", 1)
         saved = saved_activations(cfg, tokens, resident, params, limit, ways)
+        chosen = _kept_passes(cfg, tokens, saved, ways)
+        said = saved or "nothing"
+        if saved and cfg.loop_steps > 1:  # a looped stack: the passes too
+            said = ", ".join("%s at %d of %d passes (%d bytes)" % (
+                name, k, cfg.loop_steps, saved[name]) for name, k in chosen)
         kept = "keeps every activation"
         if cfg.remat:
             kept = ("under remat keeps %s: %d bytes a device beside the "
                     "blocks' inputs (%d tokens a device, state %d bytes, "
-                    "bytes_limit %s)" % (saved or "nothing",
-                                         sum(saved.values()), tokens,
+                    "bytes_limit %s)" % (said, sum(saved.values()), tokens,
                                          resident, limit))
         if cfg.remat and limit is not None:
-            fullest = _fullest_moment(cfg, tokens, params, ways, tuple(saved))
+            fullest = _fullest_moment(cfg, tokens, params, ways, chosen)
             left = limit - _SAVE_RESERVE - resident - fullest.bytes
             kept += ("; fullest at %s, %d bytes with the state; room %d "
                      "bytes before a name is kept, %d with these" % (
@@ -3033,6 +3327,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         # static, so counted as the step is traced: once a step's program
         tracing.count("train.saved_names", len(saved))
         tracing.count("train.saved_bytes", sum(saved.values()))
+        tracing.count("train.saved_passes", sum(k for _, k in chosen))
         buffers, their_bytes, widest = own_buffers(
             state["params"]["blocks"], cfg)
         tracing.count("train.own_buffers", buffers)
@@ -3042,7 +3337,8 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
             "buffers of their own, %d bytes in %s over %d layers (%d the "
             "widest layer's weights)", kept, buffers, their_bytes,
             jnp.dtype(cfg.dtype).name, cfg.n_layers, widest)
-        return tuple(saved)
+        # a stack that is run once keeps a name or does not
+        return tuple(saved) if cfg.loop_steps == 1 else chosen
 
     @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))
     def step(state, batch):

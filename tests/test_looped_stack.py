@@ -7,7 +7,9 @@ counts a pass; what is refused; the scopes. CPU, tiny sizes, seeded
 weights."""
 
 import dataclasses
+import functools
 import hashlib
+import importlib
 import math
 import re
 
@@ -113,6 +115,141 @@ def test_every_gradient_is_the_sum_over_a_weights_four_uses(saved):
                                    err_msg=str(path))
 
 
+MODELS = {  # (what differs from `looped()`, the gradient's leaves)
+    "dense": (dict(n_layers=3, post_norm=False, exit_gate=False,
+                   exit_entropy_coef=0.0), 12),
+    "gated": (dict(n_layers=3), 16),  # the sandwich norms and the exit gate
+    # two kinds of layer in a period, one of which makes neither name
+    "two_kinds": (dict(n_layers=4, post_norm=False, layer_types=(
+        "conv", "full_attention", "conv", "full_attention")), 22),
+}
+
+
+@pytest.mark.parametrize("kept_passes", range(5))
+@pytest.mark.parametrize("name", ["attn_ctx", "mlp_up"])
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_the_backward_under_remat_is_plain_autodiffs(model, name, kept_passes):
+    """The loop that makes a block again and adds its gradient into the one
+    sum, `name` kept for the last `kept_passes` of the four passes (and
+    `attn_res` for all of them, so that two of the passes' backwards
+    differ): the loss, every gradient leaf and the parameters after an
+    AdamW update are those of the stack without remat, which JAX
+    differentiates by itself."""
+    import optax
+
+    over, leaves = MODELS[model]
+    cfg = looped(remat=True, **over)
+    params, batch = seeded(cfg), batch_of(cfg)
+    saved = ("attn_res", (name, kept_passes))
+    plain = dataclasses.replace(cfg, remat=False)
+    wanted, wanted_grads = jax.jit(jax.value_and_grad(
+        lambda p: tr.transformer_loss(p, batch, plain)))(params)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: tr.transformer_loss(
+        p, batch, cfg, saved_names=saved)))(params)
+    assert float(loss) == pytest.approx(float(wanted), rel=1e-6)
+    optimizer = optax.adamw(1e-2)
+    state = optimizer.init(params)
+
+    def updated(grads):
+        return optax.apply_updates(
+            params, optimizer.update(grads, state, params)[0])
+
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(flat) == leaves
+    for (path, ours), theirs, new, new_wanted in zip(
+            flat, jax.tree.leaves(wanted_grads),
+            jax.tree.leaves(updated(grads)),
+            jax.tree.leaves(updated(wanted_grads))):
+        assert float(jnp.abs(theirs).max()) > 0, path
+        np.testing.assert_allclose(ours, theirs, atol=3e-5, rtol=1e-4,
+                                   err_msg=str(path))
+        # AdamW's first step is lr sign(g) but where g is near 0
+        sure = jnp.abs(theirs) > 1e-4
+        np.testing.assert_allclose(
+            jnp.where(sure, new, 0), jnp.where(sure, new_wanted, 0),
+            atol=1e-5, err_msg=str(path))
+
+
+def test_a_pass_kept_beyond_the_stacks_is_refused():
+    cfg = looped(remat=True)
+    params, batch = seeded(cfg), batch_of(cfg)
+    with pytest.raises(ValueError, match="attn_ctx kept at 5 of 4 passes"):
+        tr.transformer_loss(params, batch, cfg, saved_names=(("attn_ctx", 5),))
+
+
+def flash_in_interpret_mode(monkeypatch):
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    return dict(attention_impl="pallas", max_seq_len=128)
+
+
+def scans(jaxpr):
+    """Every `scan` equation of `jaxpr`, the ones inside others too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (tuple, list)) else [value]:
+                item = getattr(item, "jaxpr", item)
+                if hasattr(item, "eqns"):
+                    yield from scans(item)
+
+
+@pytest.mark.parametrize("kept_passes", range(5))
+def test_a_kept_passs_flash_forward_is_not_made_again(monkeypatch,
+                                                      kept_passes):
+    """The kernel's calls in the program: one in the forward's loop; of the
+    backward's loops over the layers, one for each run of passes that keep
+    the same, the one whose passes keep `attn_ctx` has the backward kernel
+    and no forward kernel."""
+    # five layers: no run of the four passes is as long
+    cfg = looped(remat=True, n_layers=5,
+                 **flash_in_interpret_mode(monkeypatch))
+    params = jax.eval_shape(lambda: seeded(cfg))
+    batch = batch_of(cfg)
+    closed = jax.make_jaxpr(jax.grad(lambda p: tr.transformer_loss(
+        p, batch, cfg, saved_names=(("attn_ctx", kept_passes),))))(params)
+    over_layers = [str(eqn) for eqn in scans(closed.jaxpr)
+                   if eqn.params["length"] == cfg.n_layers]
+    forward = [text for text in over_layers if "flash_bwd" not in text]
+    backward = [text for text in over_layers if "flash_bwd" in text]
+    assert len(forward) == 1 and forward[0].count("name=flash_fwd") == 1
+    again = [text.count("name=flash_fwd") for text in backward]
+    assert again == {0: [1], 4: [0]}.get(kept_passes, [0, 1])
+
+
+def test_the_backward_holds_one_sum_of_the_layers_gradient():
+    """In the lowered program of a tiny looped model the layers' float32
+    gradient `[n_layers, d, d_ff]` is the loops' carry and nothing else: no
+    second stack is made a pass and added to it, and no pass's inputs
+    `[n_layers, B, T, d]` are sliced out of all the passes'. JAX's own
+    transpose of the two scans does both (the parent's text at these sizes:
+    two such adds, and a slice `tensor<1x3x2x16x32xf32>`); the stack
+    without `remat`, which JAX still differentiates, shows the adds."""
+    cfg = looped(remat=True, n_layers=3)
+    params, batch = seeded(cfg), batch_of(cfg)
+
+    def lowered(cfg, **kw):
+        return jax.jit(jax.grad(lambda p: tr.transformer_loss(
+            p, batch, cfg, **kw))).lower(params).as_text()
+
+    def adds_of_stacks(text):
+        return len(re.findall(
+            r"stablehlo\.add [^\n]*: tensor<3x32x64xf32>\n", text))
+
+    def slices_of_a_pass(text):
+        return len(re.findall(r"stablehlo\.dynamic_slice [^\n]*-> "
+                              r"tensor<(1x)?3x2x16x32xf32>\n", text))
+
+    for saved in ((), (("attn_ctx", 2),)):
+        text = lowered(cfg, saved_names=saved)
+        assert "tensor<4x3x2x16x32xf32>" in text  # all the passes' inputs
+        assert "tensor<3x32x64xf32>" in text  # the one sum
+        assert adds_of_stacks(text) == 0 and slices_of_a_pass(text) == 0
+    assert adds_of_stacks(lowered(dataclasses.replace(cfg, remat=False))) == 2
+
+
 def test_without_a_gate_the_loss_is_the_last_passs_head_alone():
     """And the looped stack's gradient is not a stack's that is run once."""
     cfg = looped(exit_gate=False, exit_entropy_coef=0.0)
@@ -176,10 +313,13 @@ def jaxpr_digest(cfg):
     batch = batch_of(cfg)
     params = jax.eval_shape(
         lambda: tr.transformer_init(jax.random.PRNGKey(0), cfg))
-    closed = jax.make_jaxpr(jax.value_and_grad(
-        lambda p: tr.transformer_loss_and_readings(
-            p, batch, cfg, saved_names=("attn_res",) if cfg.remat else ()),
-        has_aux=True))(params)
+    # under no default precision: another test module sets one for the
+    # process when it is imported, and a product's equation then names it
+    with jax.default_matmul_precision(None):
+        closed = jax.make_jaxpr(jax.value_and_grad(
+            lambda p: tr.transformer_loss_and_readings(
+                p, batch, cfg, saved_names=("attn_res",) if cfg.remat else ()),
+            has_aux=True))(params)
     listed = list(equations(closed.jaxpr))
     return len(listed), hashlib.sha256(repr(listed).encode()).hexdigest()[:16]
 
@@ -417,23 +557,31 @@ def test_a_kept_name_is_held_once_a_layer_a_pass():
         "attn_ctx", "attn_res", "attn_qkv", "mlp_gate", "mlp_up"]
     for name in one:
         assert four[name] == 4 * one[name], name
-    # the blocks' inputs: 4 L + 1 and, a pass each, the final norm's input,
-    # the normed stream and its cotangent; a stack run once L + 1
+    # the blocks' inputs: 4 L + 1 and, a pass each, the final norm's input
+    # and the normed stream's cotangent; a stack run once L + 1
     stream = tokens * cfg.d_model * 2
     assert tr._boundary_bytes(once, tokens) == (3 + 1) * stream
-    assert tr._boundary_bytes(cfg, tokens) == (4 * 3 + 1 + 3 * 4) * stream
+    assert tr._boundary_bytes(cfg, tokens) == (4 * 3 + 1 + 2 * 4) * stream
+    # the head reads the four passes' streams, stacked
     params = tr._whole_param_bytes(cfg)
-    assert tr._pass_bytes(once, tokens, params) == 0
-    layers = 4 * 3 * (4 * 256 * 256 + 3 * 256 * 512)
-    assert tr._pass_bytes(cfg, tokens, params) == 3 * stream + layers
-    # the kept names of the moments' walk count a pass too
-    kept = ("attn_ctx", "attn_res")
+    assert tr._head_bytes(cfg, tokens, params, 1) - tr._head_bytes(
+        once, tokens, tr._whole_param_bytes(once), 1) == 3 * stream
+    # beside the rest the loops hold the layers' weights in bf16, cast once
+    # for all of them; the backward has one sum of the layers' gradient and
+    # no pass's inputs apart
+    assert tr._pass_bytes(once, params) == 0
+    assert tr._pass_bytes(cfg, params) == 2 * 3 * (
+        4 * 256 * 256 + 3 * 256 * 512)
+    # the kept names of the moments' walk count the passes that keep them
+    kept = ("attn_ctx", ("attn_res", 2))
     moments = {m.name: m.bytes for m in tr._moments(cfg, tokens, params, 1, kept)}
     bare = {m.name: m.bytes for m in tr._moments(cfg, tokens, params, 1, ())}
     assert set(moments) == {"optimizer", "head", "layers 0-2"}
-    assert moments["head"] - bare["head"] == four["attn_ctx"] + four["attn_res"]
-    assert moments["layers 0-2"] - bare["layers 0-2"] == (
-        four["attn_ctx"] + four["attn_res"])
+    for moment in ("head", "layers 0-2"):
+        assert moments[moment] - bare[moment] == (
+            four["attn_ctx"] + four["attn_res"] // 2)
+    assert tr._passes_kept(cfg, kept) == {"attn_ctx": 4, "attn_res": 2}
+    assert tr._passes_kept(cfg, (("attn_ctx", 0),)) == {}
 
 
 def test_a_stack_of_one_period_under_the_loop_is_counted_as_a_scan():
@@ -449,7 +597,7 @@ def test_a_stack_of_one_period_under_the_loop_is_counted_as_a_scan():
             "optimizer", "head", "layer 0"]
 
 
-@pytest.mark.parametrize("limit_gb", [0.25, 0.3, 0.35, 0.45, 0.6, 1.0, 4.0])
+@pytest.mark.parametrize("limit_gb", [0.08, 0.1, 0.12, 0.13, 0.16, 0.2, 4.0])
 def test_saved_activations_never_chooses_more_than_fits(limit_gb):
     cfg = rule_config()
     tokens, limit = 2048, int(limit_gb * 2**30) + tr._SAVE_RESERVE
@@ -457,20 +605,30 @@ def test_saved_activations_never_chooses_more_than_fits(limit_gb):
     resident = 3 * params
     chosen = tr.saved_activations(cfg, tokens, resident, params, limit)
     sizes = tr._saved_bytes(cfg, tokens)
-    assert list(chosen) == list(sizes)[:len(chosen)]  # a prefix, in order
-    assert all(chosen[name] == sizes[name] for name in chosen)
-    fullest = tr._fullest_moment(cfg, tokens, params, 1, tuple(chosen))
+    assert list(chosen) == list(sizes)[:len(chosen)]
+    # a prefix, in order; every name at all four passes but the last, which
+    # has the most that fit
+    kept = tr._kept_passes(cfg, tokens, chosen)
+    assert all(k == 4 for _, k in kept[:-1])
+    assert all(1 <= k <= 4 and chosen[name] == sizes[name] * k // 4
+               for name, k in kept)
+    fullest = tr._fullest_moment(cfg, tokens, params, 1, kept)
     if chosen:
         assert resident + fullest.bytes + tr._SAVE_RESERVE <= limit
-    if len(chosen) < len(sizes):  # the next name would not have fitted
-        more = (*chosen, list(sizes)[len(chosen)])
+    # a pass more of the last name, or the next name's first, would not fit
+    more = None
+    if kept and kept[-1][1] < 4:
+        more = (*kept[:-1], (kept[-1][0], kept[-1][1] + 1))
+    elif len(chosen) < len(sizes):
+        more = (*kept, (list(sizes)[len(chosen)], 1))
+    if more:
         over = tr._fullest_moment(cfg, tokens, params, 1, more)
         assert resident + over.bytes + tr._SAVE_RESERVE > limit
     # the same limit keeps no fewer names of a stack that is run once
     once = dataclasses.replace(cfg, loop_steps=1, exit_gate=False)
     p1 = tr._whole_param_bytes(once)
     assert len(tr.saved_activations(once, tokens, 3 * p1, p1, limit)) >= len(
-        chosen)
+        [name for name, k in kept if k == 4])
 
 
 def test_operations_count_a_layer_and_the_head_once_a_pass():
@@ -502,11 +660,14 @@ def test_the_scopes_and_the_one_loop_over_the_passes():
     assert {"ut_pass", "post_norm", "exit_gate", "exit_loss", "lm_head_ce",
             "final_norm", "attention", "mlp", "attn_qkv", "attn_out",
             "embed"} <= parts
-    # a norm on each sublayer's output, inside the sublayer's own scope (a
-    # loop's body is located apart from the loop: the chip's trace shows
-    # `ut_pass/mlp/post_norm`, docs/observability.md)
-    assert any(s.startswith("mlp/post_norm/") for s in stacks)
-    assert any(s.startswith("attn_out/post_norm/") for s in stacks)
+    # a norm on each sublayer's output, inside the sublayer's own scope,
+    # the block inside `ut_pass`: the forward, what the backward makes
+    # again and the backward itself (docs/observability.md)
+    assert any("ut_pass" in s and "mlp/post_norm/" in s for s in stacks)
+    assert any("ut_pass" in s and "attn_out/post_norm/" in s for s in stacks)
+    assert any("/rematted_computation/ut_pass/mlp/" in s for s in stacks)
+    assert any(s.startswith("transpose(") and "/checkpoint/ut_pass/mlp/" in s
+               for s in stacks)
     jaxpr = str(jax.make_jaxpr(
         lambda p: tr.transformer_loss(p, batch, cfg))(params))
     # the scan over the passes, the scan over the layers inside it, the
@@ -514,3 +675,8 @@ def test_the_scopes_and_the_one_loop_over_the_passes():
     assert jaxpr.count("length=4") == 1 and jaxpr.count("length=2") == 1
     unrolled = jaxpr.count(" scan[")
     assert unrolled == 3, unrolled
+    # and the backward's two: over the passes, over the layers inside it
+    grad = str(jax.make_jaxpr(jax.grad(
+        lambda p: tr.transformer_loss(p, batch, cfg)))(params))
+    assert grad.count("length=4") == 2 and grad.count("length=2") == 2
+    assert grad.count(" scan[") == 5

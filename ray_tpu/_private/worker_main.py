@@ -36,6 +36,35 @@ logger = logging.getLogger(__name__)
 _GEN_BACKPRESSURE_WINDOW = 16
 
 
+class _ItemWindow:
+    """The pushes of one streaming generator's items to the task's owner, at
+    most `_GEN_BACKPRESSURE_WINDOW` of them unacknowledged: the acks double
+    as flow-control tokens, so a slow consumer throttles the producer
+    instead of the owner buffering the whole stream. `add` and `drain` run
+    on the event loop (`Executor._push_item` starts the pushes); whoever
+    drives the generator drains in a `finally`, so that the task's reply,
+    the generator's error among them, leaves only after every item pushed
+    before it is acknowledged."""
+
+    def __init__(self):
+        self._inflight: list = []
+        self.count = 0  # the items pushed: the next item's index
+
+    async def add(self, push: asyncio.Task) -> None:
+        self._inflight.append(push)
+        self.count += 1
+        if len(self._inflight) >= _GEN_BACKPRESSURE_WINDOW:
+            await self.drain()
+
+    async def drain(self) -> None:
+        """Wait for every push in flight; one that failed raises once all
+        of them are settled."""
+        inflight, self._inflight = self._inflight, []
+        for outcome in await asyncio.gather(*inflight, return_exceptions=True):
+            if isinstance(outcome, BaseException):
+                raise outcome
+
+
 def _deadline_stats_delta(worker_id: str) -> Optional[dict]:
     """Snapshot-and-reset the process deadline counters as a wire delta.
 
@@ -396,31 +425,20 @@ class Executor:
             return {"returns": []}, t_exec
         if num_returns == -1 and inspect.isgenerator(result):
             # Streaming generator on the exec thread: store + push each item
-            # as produced (same GeneratorItem protocol as the async path).
-            # Window of unacked pushes bounds the owner's buffering when the
-            # consumer is slower than the producer (reference:
-            # _generator_backpressure_num_objects).
-            idx = 0
-            inflight: list = []
-            for item in result:
-                ret = self._store_one_sync(self._dyn_oid(wire, idx), item)
-                fut = asyncio.run_coroutine_threadsafe(
-                    self._send_generator_item(
-                        conn, wire["task_id"], idx, ret[0]
-                    ),
-                    exec_t.loop,
-                )
-                inflight.append(fut)
-                if len(inflight) >= _GEN_BACKPRESSURE_WINDOW:
-                    for f in inflight:
-                        f.result()  # acks double as flow-control tokens
-                    inflight = []
-                idx += 1
-            for f in inflight:
-                f.result()
+            # as produced (same GeneratorItem protocol as the async path,
+            # `_stream_items`), the generator advanced on this thread.
+            window = _ItemWindow()
+            try:
+                for item in result:
+                    ret = self._store_one_sync(
+                        self._dyn_oid(wire, window.count), item)
+                    exec_t.run_on_loop(
+                        self._push_item(window, conn, wire["task_id"], ret[0]))
+            finally:
+                exec_t.run_on_loop(window.drain())
             # Generator execution IS the drain; restate t_exec so the
             # PROFILE store_returns phase doesn't swallow it.
-            return {"dynamic_count": idx}, time.time()
+            return {"dynamic_count": window.count}, time.time()
         if num_returns == -1:
             num_returns = 1
         values = [result] if num_returns == 1 else list(result)
@@ -606,8 +624,6 @@ class Executor:
                     # iteration overlaps this producer (reference:
                     # ReportGeneratorItemReturns). Runs INSIDE the trace
                     # scope: the generator body executes during this drain.
-                    # Acked window = flow control (see _GEN_BACKPRESSURE_WINDOW).
-                    idx = 0
                     loop = asyncio.get_running_loop()
 
                     def _advance():
@@ -619,30 +635,12 @@ class Executor:
                         finally:
                             tracing.reset_context(tok)
 
-                    inflight = []
-                    while True:
-                        ok, item = await loop.run_in_executor(self.pool, _advance)
-                        if not ok:
-                            break
-                        ret = await self.store_returns(
-                            {"num_returns": 1,
-                             "return_ids": [self._dyn_oid(wire, idx)]},
-                            item,
-                        )
-                        inflight.append(rpc.spawn(
-                            self._send_generator_item(
-                                conn, wire["task_id"], idx, ret[0]
-                            )
-                        ))
-                        if len(inflight) >= _GEN_BACKPRESSURE_WINDOW:
-                            await asyncio.gather(*inflight)
-                            inflight = []
-                        idx += 1
-                    if inflight:
-                        await asyncio.gather(*inflight)
+                    reply = await self._stream_items(
+                        wire, conn,
+                        lambda: loop.run_in_executor(self.pool, _advance))
                     if profile:
                         self._record_profile(wire, t0, t_args, t_args)
-                    return {"dynamic_count": idx}
+                    return reply
             t_exec = time.time()
             returns = await self.store_returns(wire, result)
             if profile:
@@ -943,7 +941,6 @@ class Executor:
                     # producer. Runs INSIDE the trace scope — the generator
                     # body executes during this drain, and its nested
                     # submits must inherit the trace context.
-                    idx = 0
                     if inspect.isasyncgen(result):
                         async def _advance():
                             try:
@@ -966,32 +963,7 @@ class Executor:
                                 pool, _advance_sync
                             )
                         advance = _advance
-                    inflight = []
-                    while True:
-                        ok, item = await advance()
-                        if not ok:
-                            break
-                        ret = await self.store_returns(
-                            {"num_returns": 1,
-                             "return_ids": [self._dyn_oid(wire, idx)]},
-                            item,
-                        )
-                        # Acked delivery with a bounded window: a slow
-                        # consumer throttles the producer instead of the
-                        # owner buffering the whole stream (reference:
-                        # _generator_backpressure_num_objects).
-                        inflight.append(rpc.spawn(
-                            self._send_generator_item(
-                                conn, wire["task_id"], idx, ret[0]
-                            )
-                        ))
-                        if len(inflight) >= _GEN_BACKPRESSURE_WINDOW:
-                            await asyncio.gather(*inflight)
-                            inflight = []
-                        idx += 1
-                    if inflight:
-                        await asyncio.gather(*inflight)
-                    return {"dynamic_count": idx}
+                    return await self._stream_items(wire, conn, advance)
             returns = await self.store_returns(wire, result)
             return {"returns": returns}
         except asyncio.CancelledError:
@@ -1007,6 +979,33 @@ class Executor:
                 return {"error": self._error_payload(RuntimeError("actor exited"))}
             logger.info("actor method %s raised: %r", wire.get("actor_method"), e)
             return {"error": self._error_payload(e)}
+
+    async def _stream_items(self, wire: dict, conn, advance) -> dict:
+        """Drive a streaming generator on the event loop: every item that
+        `await advance()` hands over ((True, item); (False, None) at the
+        end) is stored and pushed to the owner as produced, through one
+        `_ItemWindow`; the task's reply."""
+        window = _ItemWindow()
+        try:
+            while True:
+                ok, item = await advance()
+                if not ok:
+                    break
+                ret = await self.store_returns(
+                    {"num_returns": 1,
+                     "return_ids": [self._dyn_oid(wire, window.count)]},
+                    item,
+                )
+                await self._push_item(window, conn, wire["task_id"], ret[0])
+        finally:
+            await window.drain()
+        return {"dynamic_count": window.count}
+
+    async def _push_item(self, window: _ItemWindow, conn, task_id: str,
+                         ret: dict) -> None:
+        """Start the next item's push to the owner inside `window`."""
+        await window.add(rpc.spawn(
+            self._send_generator_item(conn, task_id, window.count, ret)))
 
     async def _send_generator_item(self, conn, task_id: str, idx: int, ret: dict):
         """One acked GeneratorItem delivery (the ack is the flow-control

@@ -1135,12 +1135,47 @@ class _Site(NamedTuple):
     keep_ctx: bool = False
 
 
+class Reading(NamedTuple):
+    """One reading a sublayer's `forward` makes a layer, and what
+    `transformer_loss_and_readings` makes of the layers' (`_settled`)."""
+    name: str
+    # how the layers' [L, ...] are joined: a key of `_OVER_LAYERS`
+    over_layers: str = "stacked"
+    of: Optional[str] = None  # not `forward`'s: read off this one, stacked
+    # what it adds to the loss: True, itself; a field of the configuration,
+    # that coefficient times it; None, nothing. A record's terms are added
+    # while any of its coefficients is not 0
+    adds: Union[None, bool, str] = None
+    step: bool = True  # the train step reports it (`_STEP_READINGS`)
+
+
+def _fullest_over_mean(load):
+    load = load.astype(jnp.float32)
+    return load.max(axis=-1) / load.mean(axis=-1)
+
+
+# a reading of the L layers that make it, [L, ...], as the model's
+_OVER_LAYERS = {
+    "stacked": lambda x, cfg: x,
+    "mean": lambda x, cfg: x.mean(),
+    "min": lambda x, cfg: x.min(),
+    "max": lambda x, cfg: x.max(),
+    # the mean, or with `seq_aux` the sum, as the published code adds each
+    # layer's own
+    "sum under seq_aux": lambda x, cfg: (
+        jnp.sum(x) if cfg.seq_aux else jnp.mean(x)),
+    # [L, n], a device each: a layer's fullest device over its mean, [L]
+    "fullest over mean": lambda x, cfg: _fullest_over_mean(x),
+}
+
+
 class Sublayer:
     """One kind of sublayer, `x -> x + f(norm(x))`: an operator (a record of
     `_OPERATORS`) or a feed-forward (of `_FEED_FORWARDS`). It states once
     what `_blocks_init`, `param_shardings`, `_own_weights`, `_block`, the
-    rule of what a rematerialised block keeps (`saved_activations`) and
-    `flops_per_token` read of it; none of them names a kind.
+    rule of what a rematerialised block keeps (`saved_activations`),
+    `transformer_loss_and_readings`, the train step and `flops_per_token`
+    read of it; none of them names a kind.
 
     Its leaves are three statements that `tests/test_layer_kinds.py` holds
     to one another: `init` makes them, `axes` has exactly their keys, and
@@ -1156,6 +1191,9 @@ class Sublayer:
     # the `checkpoint_name`s `forward` may make: every one is in
     # `_SAVE_ORDER`, or the module does not import
     names: Tuple[str, ...] = ()
+    # the readings `forward` may make, each with how the layers' are joined
+    # and what it adds to the loss
+    readings: Tuple[Reading, ...] = ()
     # why `forward` cannot be mapped over a sequence axis; None: it can
     no_sequence_axis: Optional[str] = None
     # an operator that `heads_held` makes hold a share of its heads
@@ -1176,10 +1214,9 @@ class Sublayer:
         raise NotImplementedError
 
     def forward(self, x, blk, cfg: TransformerConfig, site: _Site):
-        """(the stream after the sublayer, residual added; the readings of
-        a routed feed-forward, of sparse attention or of kda, else None),
-        under the sublayer's
-        `jax.named_scope`s: they name the step's device work in a profiler
+        """(the stream after the sublayer, residual added; {name: value} of
+        the record's `readings`, None if it states none), under the
+        sublayer's `jax.named_scope`s: they name the step's device work in a profiler
         trace (docs/observability.md, "Device scopes") and are metadata
         only. Optional leaves are found by presence: no `w_gate_attn`, no
         gate; no `w_gate` or `ws_gate`, `relu2`; no `ws_up`, no shared
@@ -1196,7 +1233,7 @@ class Sublayer:
 
     def holds(self, cfg: TransformerConfig) -> int:
         """What its backward holds at once beside the named values and its
-        normed input (`_working_set_bytes`)."""
+        normed input (`_KindTerms.block`)."""
         raise NotImplementedError
 
     def flops(self, cfg: TransformerConfig, seq_len: int):
@@ -1371,6 +1408,16 @@ class _SparseAttention(_PlainAttention):
     (`k_idx_norm`, `k_idx_bias`), `w_idx` [d, index_heads]."""
 
     matmuls = (*_PlainAttention.matmuls, "wq_idx", "wk_idx", "w_idx")
+    readings = (
+        # the indexers' own loss, over the layers and the tokens
+        Reading("index_loss", "mean", adds=True),
+        # over rows and layers, the least and the most keys a row kept
+        # beyond what it should: 0
+        Reading("index_keys_min_gap", "min"),
+        Reading("index_keys_max_gap", "max"),
+        # [L, B, T, T] int8: 1 where a query keeps a key
+        Reading("index_keep", step=False),
+    )
     # the index loss reads the probabilities of every head
     takes_heads_held = False
     takes_post_norm = False  # `_sparse_attention_layer` has none
@@ -1451,8 +1498,8 @@ class _SparseAttention(_PlainAttention):
         has no other term for: every layer's weights in the compute dtype,
         the feed-forwards' too, hoisted out of the loop, spread over a
         sequence's tokens (1.16 GB of the 6.66 GB of scratch it plans for
-        `keyevl2.tokens16k`'s step on a described v5e;
-        `_working_set_bytes`' table)."""
+        `keyevl2.tokens16k`'s step on a described v5e; PERF.md section 6,
+        "Keep rule: a scanned stack's sum")."""
         h, hi, di = self.heads(cfg), cfg.index_heads, cfg.index_head_dim
         item = _item(cfg)
         operands = hi * _tile_lanes(di) + _tile_lanes(di) + hi * 4 // item
@@ -1691,6 +1738,12 @@ class _KDA(Sublayer):
     matmuls = ("kda_q", "kda_k", "kda_v", "kda_o", "kda_f1", "kda_f2",
                "kda_g1", "kda_g2", "kda_b")
     names = ("kda_res", "kda_qkv")
+    readings = (
+        Reading("kda_beta_mean", "mean"),
+        # the most negative running log decay at a chunk's end, over layers,
+        # heads and channels
+        Reading("kda_log_decay_min", "min"),
+    )
     no_sequence_axis = "kda is not mapped over a sequence axis"
     takes_heads_held = True
 
@@ -1879,6 +1932,18 @@ class _RoutedFF(Sublayer):
     matmuls = ("ws_gate", "ws_up", "ws_down")
     moe_weights = ("router", "w_gate", "w_up", "w_down")
     names = ("moe_slots", "moe_gate", "moe_up", "shared_gate", "shared_up")
+    readings = (
+        Reading("aux_loss", "sum under seq_aux", adds="router_aux_loss_coef"),
+        Reading("z_loss", "mean", adds="router_z_loss_coef"),
+        Reading("expert_index", step=False),  # [L, B T, k]
+        # [L, E] slots, every row sums to B T k; of a share of the experts
+        # (`experts_held`, an `expert` axis), [L] or a device each [L, n],
+        # the slots whose expert it holds and those of them it did not
+        # compute (0); under an `expert` axis `expert_load` by device [L, n]
+        *map(Reading, ("expert_load", "held_slots", "dropped_slots",
+                       "chip_load")),
+        Reading("chip_load_max_over_mean", "fullest over mean", "chip_load"),
+    )
 
     def init(self, key, cfg, L):
         d, f, held = cfg.d_model, cfg.ff_dim, cfg.held[1]
@@ -1982,6 +2047,8 @@ _FEED_FORWARDS: Dict[str, Sublayer] = {
     "dense_ff": _DenseFF(),
     "routed_ff": _RoutedFF(),
 }
+# every record, the operators' first
+_RECORDS = (*_OPERATORS.values(), *_FEED_FORWARDS.values())
 
 
 def _sublayers(kind: LayerKind) -> Tuple[Sublayer, ...]:
@@ -2207,7 +2274,8 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
     on a leading layer axis (None for a dense model). `expert_bias`
     [routed layers, E] is the routers' selection bias. Under `cfg.remat` a
     block's input is its checkpoint; `saved_names` are the activations kept
-    beside it (`saved_activations`), none by default.
+    beside it (`saved_activations`; or a plain tuple of names, at every
+    pass), none by default.
 
     With `loop_steps` > 1 the walk over the segments is run that many times
     over the same `params["blocks"]`, the final norm after every pass and
@@ -2216,10 +2284,11 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
     what comes back is every pass's normed stream,
     `[loop_steps, B, T, d]`: a weight's gradient is the sum over its
     uses. Under `cfg.remat` the looped stack is differentiated by its own
-    backward (`_looped_under_remat`), and an entry of `saved_names` may be
-    a pair of a name and the number of the last passes that keep it;
-    without `remat` JAX differentiates the two scans."""
+    backward (`_looped_under_remat`), which keeps a name for the last of
+    the passes that `saved_names` gives it; without `remat` JAX
+    differentiates the two scans."""
     _refuse_unmapped_loop(cfg, seq_axis, mesh)
+    kept = _kept(cfg, saved_names)  # the one form, from here down
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -2228,15 +2297,17 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
 
     # no policy at all for an empty choice: the step is then the one that
     # keeps nothing, instruction for instruction
-    policy = (jax.checkpoint_policies.save_only_these_names(*saved_names)
-              if saved_names else None)
+    policy = (jax.checkpoint_policies.save_only_these_names(*kept)
+              if kept else None)
+
+    def block_that_keeps(names, kind: LayerKind, sliced: bool, **kw):
+        return partial(_block, cfg=cfg, kind=kind, seq_axis=seq_axis,
+                       seq_size=seq_size, mesh=mesh,
+                       keep_ctx="attn_ctx" in names, sliced=sliced, **kw)
 
     def scan_body(sliced: bool, layout: Tuple[LayerKind, ...]):
         def block_fn(kind: LayerKind):
-            blk_fn = partial(
-                _block, cfg=cfg, kind=kind, seq_axis=seq_axis,
-                seq_size=seq_size, mesh=mesh,
-                keep_ctx="attn_ctx" in saved_names, sliced=sliced)
+            blk_fn = block_that_keeps(kept, kind, sliced)
             if cfg.remat:
                 blk_fn = jax.checkpoint(blk_fn, policy=policy,
                                         static_argnums=())
@@ -2286,40 +2357,26 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
         x, readings = walk(x)
         return final_norm(x), readings
 
-    def refuse(readings):
-        if readings is not None:
-            raise NotImplementedError(
-                f"loop_steps {cfg.loop_steps} over layers that make "
-                f"readings ({sorted(readings)}): a routed feed-forward's, "
-                "sparse attention's and kda's are one a layer, not one a "
-                "layer a pass, yet")
-
     if cfg.remat:
         def block_of(kind: LayerKind, sliced: bool, names: Tuple[str, ...]):
-            fn = partial(_block, bias=None, cfg=cfg, kind=kind,
-                         seq_axis=seq_axis, seq_size=seq_size, mesh=mesh,
-                         keep_ctx="attn_ctx" in names, sliced=sliced)
+            fn = block_that_keeps(names, kind, sliced, bias=None)
 
             def block(x, blk, positions):
                 with jax.named_scope("ut_pass"):
-                    x, readings = fn(x, blk, positions)
-                refuse(readings)
-                return x
+                    return fn(x, blk, positions)[0]
 
             return block
 
         # the norm is made again in the backward, as a block is
         streams = _looped_under_remat(
             x, _segment_trees(params["blocks"]), params["final_norm"],
-            positions, segments(cfg), cfg.loop_steps,
-            _passes_kept(cfg, saved_names), block_of,
+            positions, segments(cfg), cfg.loop_steps, kept, block_of,
             jax.checkpoint(final_norm))
         return streams, None
 
     def one_pass(x, _):
         with jax.named_scope("ut_pass"):
-            x, readings = walk(x)
-        refuse(readings)
+            x, _ = walk(x)
         normed = final_norm(x)
         return _next_pass_input(x, normed), normed
 
@@ -2332,21 +2389,6 @@ def _next_pass_input(left, normed):
     not as the last layer left it. Under a name of its own: a test hands on
     the other to show what the comparison reads then."""
     return normed
-
-
-def _passes_kept(cfg: TransformerConfig, kept) -> Dict[str, int]:
-    """{name: the passes it is kept for} of `kept`, whose entries are names
-    (kept at every pass of `loop_steps`) or pairs of a name and the number of
-    the stack's last passes that keep it."""
-    passes = {}
-    for entry in kept:
-        name, k = (entry, cfg.loop_steps) if isinstance(entry, str) else entry
-        if not 0 <= k <= cfg.loop_steps:
-            raise ValueError(
-                f"{name} kept at {k} of {cfg.loop_steps} passes")
-        if k:
-            passes[name] = k
-    return passes
 
 
 class _Halves(NamedTuple):
@@ -2591,6 +2633,12 @@ def _refuse_unmapped_loop(cfg: TransformerConfig, seq_axis, mesh) -> None:
             f"loop_steps {cfg.loop_steps} under a "
             f"`{seq_axis or unmapped[0]}` axis: the passes' streams and the "
             "one loss over them are not mapped over it yet")
+    made = sorted({r.name for kind in cfg.layers for sub in _sublayers(kind)
+                   for r in sub.readings})
+    if made:
+        raise NotImplementedError(
+            f"loop_steps {cfg.loop_steps} over layers that make readings "
+            f"({made}): they are one a layer, not one a layer a pass, yet")
     if cfg.heads_held:
         raise NotImplementedError(
             f"loop_steps {cfg.loop_steps} with heads_held "
@@ -2686,6 +2734,9 @@ def exit_probabilities(streams, exit_w, exit_b):
         return p, -(p * log_p).sum(0)
 
 
+_EXIT_READINGS = ("ut_pass_loss", "exit_p_mean", "exit_entropy")
+
+
 def _exit_loss(streams, params, targets, cfg: TransformerConfig,
                ignore_index: int = -100):
     """(`1/N sum_i [sum_t p(t)_i ce(t)_i - beta H(p_i)]`, the readings
@@ -2712,11 +2763,9 @@ def _exit_loss(streams, params, targets, cfg: TransformerConfig,
             streams, _unembed(params, cfg),
             jnp.broadcast_to(targets, (passes, *targets.shape)), weights)
     loss = expected - cfg.exit_entropy_coef * entropy
-    return loss, {
-        "ut_pass_loss": ce.sum((1, 2)) / count,  # 0 at an ignored target
-        "exit_p_mean": (p * mask).sum((1, 2)) / count,
-        "exit_entropy": entropy,
-    }
+    return loss, dict(zip(_EXIT_READINGS, (
+        ce.sum((1, 2)) / count,  # 0 at an ignored target
+        (p * mask).sum((1, 2)) / count, entropy)))
 
 
 def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
@@ -2727,29 +2776,17 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     [B*T, V] f32 logits are never materialized, which at GPT-2 vocab sizes
     is the difference between HBM-bound and MXU-bound training steps.
 
-    A model with routed experts adds `router_aux_loss_coef` times the
-    load-balancing loss and `router_z_loss_coef` times the router z-loss,
-    each a mean over the layers (with `seq_aux` the balance loss is every
-    sequence's own, summed over the layers), and its readings are `aux_loss`
-    and `z_loss` (those means, or that sum), `expert_load` [L, E] (slots per
-    expert; every row sums to B T k) and `expert_index` [L, B T, k], L the
-    routed layers;
-    a coefficient of 0 adds nothing. A share of the experts
-    (`experts_held`) also reads `held_slots` and `dropped_slots` [L]. A
-    dense model's readings are empty. `expert_bias=` [L, E] is the routers'
-    selection bias, for a model that has one. Layers of sparse attention
-    add their index loss, the mean over those layers (and over the tokens),
-    at coefficient 1, and read `index_loss` (that mean),
-    `index_keys_min_gap` and `index_keys_max_gap` (over rows and layers,
-    the least and the most a row kept beyond what it should: 0) and
-    `index_keep` [L, B, T, T] int8 (1 where a query keeps a key; the step
-    does not report it). Layers of kda read `kda_log_decay_min` (the most
-    negative running log decay at a chunk's end, over layers, heads and
-    channels) and `kda_beta_mean`. A stack that is run `loop_steps` times
-    under an `exit_gate` has `_exit_loss`'s loss and readings:
-    `ut_pass_loss` [passes] (the mean cross-entropy after each pass),
-    `exit_p_mean` [passes] (the mean exit probability of each pass; sums to
-    1) and `exit_entropy`; without the gate the last pass's cross-entropy."""
+    The readings are what the records of `cfg.layers` state
+    (`Sublayer.readings`: a routed feed-forward's, sparse attention's,
+    kda's), each over the L layers that make it, joined as its record says
+    (a mean, a least, a most, or stacked [L, ...]), and the loss has what
+    the record says the reading adds: the routers' balance and z losses at
+    the configuration's coefficients (0 adds nothing), the indexers' own
+    loss at 1. A dense model's readings are empty. `expert_bias=` [L, E] is
+    the routers' selection bias, for a model that has one. A stack that is
+    run `loop_steps` times under an `exit_gate` has `_exit_loss`'s loss and
+    readings (`_EXIT_READINGS`); without the gate the last pass's
+    cross-entropy."""
     if "targets" in batch:
         tokens, targets = batch["tokens"], batch["targets"]
     else:
@@ -2762,32 +2799,25 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     with jax.named_scope("lm_head_ce"):
         loss = _head_loss(hidden, _unembed(params, cfg), targets,
                           kw.get("mesh"))
-    if readings is None:
-        return loss, {}
-    if "index_loss" in readings:  # the indexers' own loss, at coefficient 1
-        readings = dict(
-            readings, index_loss=readings["index_loss"].mean(),
-            index_keys_min_gap=readings["index_keys_min_gap"].min(),
-            index_keys_max_gap=readings["index_keys_max_gap"].max())
-        loss = loss + readings["index_loss"]
-    if "kda_beta_mean" in readings:  # over the kda layers
-        readings = dict(
-            readings, kda_beta_mean=readings["kda_beta_mean"].mean(),
-            kda_log_decay_min=readings["kda_log_decay_min"].min())
-    if "aux_loss" not in readings:
-        return loss, readings
-    # over the layers: the mean, or with `seq_aux` the sum, as the published
-    # code adds each layer's own
-    over_layers = jnp.sum if cfg.seq_aux else jnp.mean
-    readings = dict(readings, aux_loss=over_layers(readings["aux_loss"]),
-                    z_loss=readings["z_loss"].mean())
-    if "chip_load" in readings:  # [L, n]: the fullest device over the mean
-        load = readings["chip_load"].astype(jnp.float32)
-        readings["chip_load_max_over_mean"] = (
-            load.max(axis=-1) / load.mean(axis=-1))
-    if cfg.router_aux_loss_coef or cfg.router_z_loss_coef:
-        loss = (loss + cfg.router_aux_loss_coef * readings["aux_loss"]
-                + cfg.router_z_loss_coef * readings["z_loss"])
+    return _settled(loss, readings or {}, cfg)
+
+
+def _settled(loss, readings, cfg: TransformerConfig):
+    """(the loss with what the layers' readings add to it, the readings
+    joined over the layers), as the records state it (`Sublayer.readings`):
+    the operators' first, then the feed-forwards', each record's joins and
+    then its terms."""
+    readings = dict(readings)
+    for sub in _RECORDS:
+        stated = [r for r in sub.readings if (r.of or r.name) in readings]
+        for r in stated:
+            readings[r.name] = _OVER_LAYERS[r.over_layers](
+                readings[r.of or r.name], cfg)
+        terms = [(r.adds is True or getattr(cfg, r.adds), readings[r.name])
+                 for r in stated if r.adds]
+        if any(coef for coef, _ in terms):
+            for coef, value in terms:
+                loss = loss + (value if coef is True else coef * value)
     return loss, readings
 
 
@@ -2801,12 +2831,7 @@ def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
 # The activations a block under `jax.checkpoint` may keep beside its input,
 # by the names the model and the flash kernel's forward rule give them
 # (`checkpoint_name`), in the order they are kept: by the time a byte kept
-# saves. Measured on a v5e (PERF.md section 6, PR 33), ms of step per GB:
-# `attn_ctx` 29 to 64 (the kernel is not run again), the operators'
-# residuals and q, k, v about 15, the feed-forwards' and `conv_in`'s products
-# 10 to 11 (a product of a `d`-wide input costs `2 d` operations a value
-# whatever its width, so these rank by what rides with the matmul: RoPE and
-# the norms with q, k and v, nothing with `up`).
+# saves (PERF.md section 6, "Keep rule: `_SAVE_ORDER`", has the ms a GB).
 _SAVE_ORDER = (
     "attn_ctx",   # the kernel's o [B H, T, dv] and lse as one f32 column
                   # (sparse attention: and the indexer's three gradients and
@@ -2828,14 +2853,28 @@ _SAVE_ORDER = (
     "mlp_gate",   # the dense feed-forward's gate product, before the silu
     "mlp_up",     # and its up product
 )
-_UNRANKED = {name for table in (_OPERATORS, _FEED_FORWARDS)
-             for sub in table.values() for name in sub.names
-             } - set(_SAVE_ORDER)
+_UNRANKED = {name for sub in _RECORDS for name in sub.names} - set(_SAVE_ORDER)
 if _UNRANKED:  # the rule would pass such a name by and never keep it
     raise ValueError(
         f"a sublayer makes the names {sorted(_UNRANKED)}, which "
         "_SAVE_ORDER does not rank")
 _SAVE_RESERVE = 1 << 30  # the step stays this far under the device's limit
+
+
+def _kept(cfg: TransformerConfig, saved_names) -> Dict[str, int]:
+    """What a step keeps, in the one form everything below `make_train_step`
+    reads: {a name of `_SAVE_ORDER`: the number of the stack's last passes
+    that keep it}, in `_SAVE_ORDER`'s order; 1 for a stack that is run once,
+    and a name kept at no pass is not in it. `saved_names` is such a mapping
+    or a plain tuple of names, which means every pass of `loop_steps`."""
+    if not isinstance(saved_names, dict):
+        saved_names = dict.fromkeys(saved_names, cfg.loop_steps)
+    for name, k in saved_names.items():
+        if name not in _SAVE_ORDER or not 0 <= k <= cfg.loop_steps:
+            raise ValueError(f"{name} kept at {k} of {cfg.loop_steps} passes"
+                             " (or it is not a name of _SAVE_ORDER)")
+    return {name: saved_names[name] for name in _SAVE_ORDER
+            if saved_names.get(name)}
 
 
 def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
@@ -2848,26 +2887,14 @@ def _layer_widths(cfg: TransformerConfig, kind: LayerKind):
     return widths, params
 
 
-def _on_an_expert_axis(cfg: TransformerConfig, ways: int) -> TransformerConfig:
-    """`cfg` as one device of an `expert` axis of `ways` devices runs it: its
-    routed layers hold `n_experts / ways` experts, so they are a share's
-    (one operation that makes no names and holds no whole layer's rows)."""
-    if ways == 1 or not cfg.n_experts:
-        return cfg
-    return dataclasses.replace(
-        cfg, experts_held=(0, cfg.n_experts // ways))
-
-
 def _exchange_bytes(cfg: TransformerConfig, tokens: int, ways: int) -> int:
     """What a routed layer's exchange holds on a device of an `expert` axis
     of `ways` devices, `tokens` tokens each, beside what a share's layer
     holds: the gathered rows of all the axis, their partial results (or, in
     the backward, their gradient) summed in float32 and the pass's own in
     float32, and the held rows' buffers of `held_chunk` rows: two of the
-    stream's width and the feed-forward's products. Against the compiler's
-    plan for a described v5e (Mellum2's widths, 16,384 tokens a device over
-    four, PR 50): 2.24 GB between buffers of 32,768 and of 180,224 rows,
-    15.2 kB a row, for 14.6 here."""
+    stream's width and the feed-forward's products (against the compiler's
+    plan: PERF.md section 6, "Keep rule: `_exchange_bytes`")."""
     if ways == 1 or not cfg.n_routed_layers:
         return 0
     item, d = _item(cfg), cfg.d_model
@@ -2880,43 +2907,11 @@ def _exchange_bytes(cfg: TransformerConfig, tokens: int, ways: int) -> int:
             + chunk * (2 * d + cfg.ff_matrices * cfg.ff_dim) * item)
 
 
-def _saved_bytes(cfg: TransformerConfig, tokens: int) -> Dict[str, int]:
-    """Bytes a device holds of each named activation over all the layers
-    that make it, for `tokens` tokens on the device, in `_SAVE_ORDER`: once
-    a layer a pass of `loop_steps`."""
-    item = jnp.dtype(cfg.dtype).itemsize
-    total: Dict[str, int] = {}
-    for kind in cfg.layers:
-        for name, width in _layer_widths(cfg, kind)[0].items():
-            total[name] = total.get(name, 0) + (
-                cfg.loop_steps * tokens * width * item)
-    return {name: total[name] for name in _SAVE_ORDER if name in total}
-
-
 @lru_cache(maxsize=None)
 def _whole_param_bytes(cfg: TransformerConfig) -> int:
     """float32 bytes of `cfg`'s parameters, all of them on one device."""
     return 4 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
         lambda: transformer_init(jax.random.PRNGKey(0), cfg))))
-
-
-def _block_bytes(cfg: TransformerConfig, kind: LayerKind, tokens: int,
-                 sharded: bool, exchange: int) -> int:
-    """One block of `kind` in its backward, every value at once: the
-    stream's cotangent and, of each of its sublayers, the normed input, the
-    named values (`Sublayer.widths`) and what the kind says it holds beside
-    them (`Sublayer.holds`); with the compute-dtype copy of its weights and,
-    where the parameters are sharded, the same weights gathered whole and
-    their float32 gradient before it is scattered; a routed block on an
-    `expert` axis with its `exchange`."""
-    item = _item(cfg)
-    sublayers = _sublayers(kind)
-    widths, params = _layer_widths(cfg, kind)
-    width = (sum(widths.values()) + (len(sublayers) + 1) * cfg.d_model
-             + sum(sub.holds(cfg) for sub in sublayers))
-    weights = params * item + (params * (item + 4) if sharded else 0)
-    return (tokens * width * item + weights
-            + (exchange if kind.routed else 0))
 
 
 def _head_bytes(cfg: TransformerConfig, tokens: int, param_bytes: int,
@@ -2947,258 +2942,220 @@ def _boundary_bytes(cfg: TransformerConfig, tokens: int) -> int:
     return streams * tokens * cfg.d_model * _item(cfg)
 
 
-def _pass_bytes(cfg: TransformerConfig, param_bytes: int) -> int:
-    """What the loops of a looped stack hold beside the rest: every layer's
-    weights in the compute dtype. The compiler casts the stack of them once,
-    ahead of the forward's loop, and both loops read a layer's by index (its
-    plan for a described v5e, PR 58: 0.82 GB at Ouro-2.6B's widths and 8
-    layers, with or without a barrier on the slice). The backward
-    (`_looped_under_remat`) holds nothing else of its own: the layers'
-    gradient once, added to in place, and no pass's inputs apart from all
-    the passes'."""
-    if cfg.loop_steps == 1:
-        return 0
-    layers = sum(_layer_widths(cfg, kind)[1] for kind in cfg.layers)
-    return _item(cfg) * layers * param_bytes // _whole_param_bytes(cfg)
-
-
-def _working_set_bytes(cfg: TransformerConfig, tokens: int,
-                       param_bytes: int, expert_ways: int = 1) -> int:
-    """What the step that keeps nothing holds on a device beside its state
-    and the gradients when it is fullest, where its stack is a scan over
-    stacked layers: the blocks' inputs, and the larger of the head's chunk
-    (`_head_bytes`) and one block in its backward, taken as every value of
-    the model's widest layer at once (`_block_bytes`). On an `expert` axis
-    of `expert_ways` devices a routed block also holds its exchange
-    (`_exchange_bytes`), and the head is gathered whole in float32. A stack
-    that is not scanned is walked a layer at a time (`_moments`).
-
-    Against the chip (`bytes_in_use + bytes_reserved`, `peak_hbm_gb.tokens`
-    in the ledger's PR 53 lines, and this PR's chip runs for the cells
-    PR 54 moved; PERF.md section 6, PR 54), GB, the rule's sum for the
-    choice it makes and the chip's peak: where a segment has more than one
-    period (this term, all the gradients and all the kept names at once)
-    15.57 for 15.510 in `mistral7b.tokens4k`, 15.52 for 15.512 in
-    `keyevl2.tokens16k` (1.16 of it the six layers' weights in bf16,
-    hoisted out of the scan, which `_SparseAttention.holds` prices because
-    no other term does), 15.81 for 15.587 in `dsv2lite.tokens8k`, whose
-    scanned five layers set the peak (the compiler's plan for a described
-    v5e: 7.855 GB of scratch in the routed scan's body, 5.45 in the dense
-    layer ahead of it, which is walked), and 15.66
-    for 14.937 on `mistral7b.fsdp4`'s four chips; where every segment is
-    one period long `_moments`' table has the pairs. A stack that is run
-    `loop_steps` times holds more (`_boundary_bytes`, `_pass_bytes`): in
-    `ouro.tokens16k` 15.72 with `attn_ctx` kept for the last of the four
-    passes (15.17 with nothing kept) for the compiler's plan for a
-    described v5e of 15.54 (14.83): 7.35 GB of state and 7.35 of scratch
-    with nothing kept, of which 2.45 the gradients (the layers' 1.64 once,
-    the loops' carry), 2.15 the 32 blocks' inputs, 0.54 the passes' final
-    norm's inputs and their streams' cotangents, 0.82 the eight layers'
-    weights in bf16, cast once ahead of both loops (`_pass_bytes`), and
-    the block in hand (PERF.md section 6, PR 58, which has the chip's
-    peak). It errs to the full
-    side: a name too few costs a percent, a step that asks for the chip's
-    last GiB is compiled to fit and runs slower than the one that keeps
-    nothing."""
-    whole = _whole_param_bytes(cfg)
-    sharded = param_bytes < whole
-    exchange = _exchange_bytes(cfg, tokens, expert_ways)
-    head = _head_bytes(cfg, tokens, param_bytes, expert_ways)
-    cfg = _on_an_expert_axis(cfg, expert_ways)
-    block = max(_block_bytes(cfg, kind, tokens, sharded, exchange)
-                for kind in set(cfg.layers))
-    return (_boundary_bytes(cfg, tokens) + max(block, head)
-            + _pass_bytes(cfg, param_bytes))
-
-
 class _Moment(NamedTuple):
     """A moment of the step and what a device holds then beside the state."""
     name: str  # "head", "optimizer", "layers 1-5" (a scan), "layer 4"
     bytes: int
 
 
-def _moments(cfg: TransformerConfig, tokens: int, param_bytes: int,
-             expert_ways: int = 1,
-             kept: Tuple[str, ...] = ()) -> List[_Moment]:
-    """The moments at which the step that keeps the names `kept` may be
-    fullest, in the backward's order over `segments(cfg)`, each with what a
-    device holds then beside its state.
+class _Terms(NamedTuple):
+    """The rule's terms for one traced step (`_terms` adds them up once, for
+    whatever is kept), bytes on a device, and the step's moments for a choice
+    `kept` ({name: passes}, `_kept`'s form).
 
-    A segment of several periods is a scan over stacked layers: its
-    gradient is one buffer, whole from the scan's first step, and the
-    compiler's plan for a described v5e holds it beside every kept name
-    all through the backward (`mistral7b.tokens4k`: 2.75 GB from the first
-    layer's backward to the last's). Its moment is what the rule has always
-    counted, every term at once: the blocks' inputs, every kept name, every
-    gradient, and the model's widest block or the head's chunk
-    (`_working_set_bytes`). A model all of whose segments are scanned has
+    The condition the terms must keep: they err to the full side. A name
+    too few costs a percent; a step that asks for the chip's last GiB is
+    compiled to fit and runs slower than the one that keeps nothing. The
+    sums against the chips' peaks and the compiler's plans: PERF.md section
+    6, "Keep rule: a scanned stack's sum" and "Keep rule: the moments"."""
+    passes: int  # `loop_steps`
+    names: Dict[str, int]  # {name: all the layers' bytes of it a pass}
+    made: Tuple[Dict[str, int], ...]  # a layer each: {name: its bytes a pass}
+    # the step's moments in the backward's order, each (its name, what a
+    # device holds then beside its state and the kept names, how many of the
+    # first layers' kept names it holds)
+    frames: Tuple[Tuple[str, int, int], ...]
+    boundaries: int  # `_boundary_bytes`
+    head: int  # `_head_bytes`
+    exchange: int  # `_exchange_bytes`
+    loops: int  # a looped stack's weights in the compute dtype
+    at_once: int  # a scanned stack's sum: every term but the kept names
+
+    def saved_bytes(self, kept: Optional[Dict[str, int]] = None):
+        """{name: the bytes a device holds of it} for the choice `kept`;
+        None: every name the layers make, at every pass. A name's bytes are
+        its width times the tokens times the layers that make it, a pass."""
+        if kept is None:
+            kept = dict.fromkeys(self.names, self.passes)
+        return {name: k * self.names[name] for name, k in kept.items()}
+
+    def moments(self, kept: Optional[Dict[str, int]] = None) -> List[_Moment]:
+        """The moments at which the step that keeps `kept` may be fullest,
+        each with what a device holds then beside its state."""
+        of_layer = [sum(k * made.get(name, 0)
+                        for name, k in (kept or {}).items())
+                    for made in self.made]
+        return [_Moment(name, held + sum(of_layer[:layers]))
+                for name, held, layers in self.frames]
+
+    def fullest(self, kept: Optional[Dict[str, int]] = None) -> _Moment:
+        """The fullest of `moments`; of equals, the first in the backward."""
+        return max(self.moments(kept), key=lambda moment: moment.bytes)
+
+    def room(self, resident_bytes: int, limit_bytes: int,
+             kept: Optional[Dict[str, int]] = None) -> int:
+        """What the step that keeps `kept` leaves of `limit_bytes` when it
+        is fullest, the state's `resident_bytes` and `_SAVE_RESERVE` set
+        aside: with nothing kept, the room the names may take."""
+        return (limit_bytes - _SAVE_RESERVE - resident_bytes
+                - self.fullest(kept).bytes)
+
+
+@lru_cache(maxsize=None)
+def _terms(cfg: TransformerConfig, tokens: int,
+           param_bytes: Optional[int] = None, expert_ways: int = 1) -> _Terms:
+    """The rule's terms for `tokens` tokens on a device that holds
+    `param_bytes` of the parameters (None: all of them), which its gradients
+    take again, and is one of an `expert` axis of `expert_ways`: the routed
+    layers are then a device's share of them, with their exchange, and the
+    head is gathered whole. The moments, in the backward's order over
+    `segments(cfg)`:
+
+    A segment of several periods is a scan over stacked layers (and under
+    `loop_steps` the passes are a scan, so every segment is): its gradient
+    is one buffer, whole from the scan's first step, which the compiler
+    holds beside every kept name all through the backward. Its moment is
+    every term at once: the blocks' inputs, every kept name, every
+    gradient, the larger of the model's widest block and the head's chunk,
+    and a looped stack's weights in the compute dtype, cast once ahead of
+    both loops (`at_once`; the backward of `_looped_under_remat` holds
+    nothing else of its own). A model all of whose segments are scanned has
     that one sum.
 
     A segment of one period is a scan of length 1, which XLA inlines: each
     layer's gradient is a buffer of its own, made when the backward reaches
     the layer, and since the step clips nothing AdamW's update of a weight
-    follows its gradient at once (the plans of `lagunaxs2.tokens8k`,
-    `lfm2moe.tokens8k`, `nemotron3nano.tokens8k`, `mellum2.ep4` and
-    `olmoe.tokens4k`, PERF.md section 6, PR 54: no more than 0.74 GB of
-    gradients live at any position, of 1.9 to 2.8). The backward walks
-    from the last layer to the first, so layer i's moment holds the blocks'
-    inputs, the kept names of the layers before and at it, its own block
-    (`_block_bytes` at its own widths), the head's gradient (made first,
-    and in `olmoe.tokens4k`'s plan live to the end), the gradients of the
+    follows its gradient at once. The backward walks from the last layer to
+    the first, so layer i's moment holds the blocks' inputs, the kept names
+    of the layers before and at it, its own block at its own widths, the
+    head's gradient (made first, and live to the end), the gradients of the
     scanned segments behind it, and of its own gradient what a loop
     accumulates: a share's held experts', float32, live from the first
     chunk of held rows to the last (where the parameters are sharded the
-    block's own term has the whole gradient already).
+    block has the whole gradient already).
 
-    The head's moment has every kept name and every gradient but those
-    of the layers no scan stacks; the optimizer's has every gradient and no
-    kept name.
-
-    Against the chip, GB: what the rule counted as a scan's (every term at
-    once, with the names it then kept), the chip's peak then
-    (`peak_hbm_gb.tokens`: ledger, PR 53), the sum of the fullest moment and
-    the state for the names it keeps now, and the chip's peak with them (my
-    chip runs, PR 54; the compiler's plan for a described v5e beside it):
-
-    | cell | as a scan | chip | walked | chip | plan |
-    |---|---|---|---|---|---|
-    | `olmoe.tokens4k`, one layer, every name both times | 14.21 | 11.276 | 12.12 | 11.276 | 11.15 |
-    | `lagunaxs2.tokens8k`, five layers, 2 names then 7 | 15.81 | 12.650 | 15.12 | 14.512 | 14.02 |
-    | `lfm2moe.tokens8k`, five layers, 6 then 7 | 15.68 | 11.810 | 12.63 | 12.581 | 12.36 |
-    | `nemotron3nano.tokens8k`, nine layers, 4 then 6 | 15.42 | 13.901 | 14.64 | 14.396 | 14.15 |
-    | `mellum2.ep4`, four layers on an `expert` axis, 1 then 3 | 15.83 | 13.558 | 14.91 | 14.270 | 13.73 |
-
-    The chip stands over the plan's live bytes by the program's own code
-    (0.04 GB for one layer, 0.18 to 0.27 for five to nine) and by what the
-    plan's heap loses between buffers (0.03 to 0.33)."""
-    item, d = _item(cfg), cfg.d_model
+    The head's moment has every kept name and every gradient but those of
+    the layers no scan stacks; the optimizer's has every gradient and no
+    kept name. (PERF.md section 6, "Keep rule: the moments" and "Keep rule:
+    a looped stack's weights", has the plans these were read off.)"""
     whole = _whole_param_bytes(cfg)
+    if param_bytes is None:
+        param_bytes = whole
     sharded = param_bytes < whole
     exchange = _exchange_bytes(cfg, tokens, expert_ways)
     head = _head_bytes(cfg, tokens, param_bytes, expert_ways)
-    at_once = _working_set_bytes(cfg, tokens, param_bytes, expert_ways)
-    cfg = _on_an_expert_axis(cfg, expert_ways)
+    # the head's gradient once the head is done: a device's share of it
+    unembed = 4 * cfg.vocab_size * cfg.d_model * param_bytes // whole
+    if expert_ways > 1 and cfg.n_experts:
+        # as one device of the axis runs it: its routed layers are a share's
+        # (one operation that makes no names and holds no whole layer's rows)
+        cfg = dataclasses.replace(
+            cfg, experts_held=(0, cfg.n_experts // expert_ways))
     on_device = _whole_param_bytes(cfg)
-    boundaries = _boundary_bytes(cfg, tokens)
-    # under `loop_steps` the passes are a scan: a layer's gradient is summed
-    # over them, whole from the first, as a scanned segment's is
-    scanned = [seg.periods > 1 or cfg.loop_steps > 1 for seg in segments(cfg)]
-
-    passes = _passes_kept(cfg, kept)
-
-    def kept_bytes(kind: LayerKind) -> int:
-        widths = _layer_widths(cfg, kind)[0]
-        return tokens * item * sum(
-            k * widths.get(name, 0) for name, k in passes.items())
+    item, d = _item(cfg), cfg.d_model
+    names, params, block = {}, {}, {}  # of a layer, by its kind
+    for kind in dict.fromkeys(cfg.layers):
+        widths, params[kind] = _layer_widths(cfg, kind)
+        names[kind] = {name: tokens * item * w for name, w in widths.items()}
+        # the block in its backward, every value at once: the stream's
+        # cotangent and, of each of its sublayers, the normed input, the
+        # named values and what the kind says it holds beside them
+        # (`Sublayer.holds`); with the compute-dtype copy of its weights
+        # and, where the parameters are sharded, the same weights gathered
+        # whole and their float32 gradient before it is scattered; a routed
+        # block on an `expert` axis with its exchange
+        subs = _sublayers(kind)
+        width = (sum(widths.values()) + (len(subs) + 1) * d
+                 + sum(sub.holds(cfg) for sub in subs))
+        block[kind] = (tokens * width * item + params[kind] * item
+                       + (params[kind] * (item + 4) if sharded else 0)
+                       + (exchange if kind.routed else 0))
 
     def gradient(kind: LayerKind) -> int:
-        return 4 * _layer_widths(cfg, kind)[1] * param_bytes // on_device
+        return 4 * params[kind] * param_bytes // on_device
 
-    kept_all = sum(kept_bytes(kind) for kind in cfg.layers)
+    boundaries = _boundary_bytes(cfg, tokens)
+    loops = (item * param_bytes * sum(params[kind] for kind in cfg.layers)
+             // on_device if cfg.loop_steps > 1 else 0)
+    at_once = boundaries + max(*block.values(), head) + loops
+    # of a routed layer's gradient, what a loop accumulates: a share's held
+    # experts'
+    accumulated = (4 * cfg.held[1] * cfg.ff_matrices * d * cfg.ff_dim
+                   if not sharded and cfg.held[1] < cfg.n_experts else 0)
+    segs = [(seg, seg.periods > 1 or cfg.loop_steps > 1)
+            for seg in segments(cfg)]
     # the gradients of the layers no scan stacks: none is made before its
     # layer's backward
-    inlined = sum(gradient(kind) for seg, scan in zip(segments(cfg), scanned)
-                  if not scan for kind in seg.layout)
-    # the head's gradient once the head is done: a device's share of it
-    unembed = 4 * cfg.vocab_size * d * param_bytes // whole
-    moments = [_Moment("optimizer", param_bytes),
-               _Moment("head", boundaries + kept_all + head
-                       + param_bytes - inlined)]
+    inlined = sum(gradient(kind) for seg, scan in segs if not scan
+                  for kind in seg.layout)
+    frames = [("optimizer", param_bytes, 0),
+              ("head", boundaries + head + param_bytes - inlined,
+               cfg.n_layers)]
     first, behind = cfg.n_layers, 0  # walked from the last layer back
-    for seg, scan in reversed(list(zip(segments(cfg), scanned))):
+    for seg, scan in reversed(segs):
         layers = len(seg.layout) * seg.periods
         first -= layers
         if scan:
-            moments.append(_Moment(
-                "layers %d-%d" % (first, first + layers - 1),
-                at_once + kept_all + param_bytes))
-            behind += seg.periods * sum(
-                gradient(kind) for kind in seg.layout)
+            frames.append(("layers %d-%d" % (first, first + layers - 1),
+                           at_once + param_bytes, cfg.n_layers))
+            behind += seg.periods * sum(map(gradient, seg.layout))
             continue
         for i in reversed(range(layers)):
             kind = seg.layout[i]
-            before_and_at = sum(
-                kept_bytes(k) for k in cfg.layers[:first + i + 1])
-            accumulated = 0
-            if kind.routed and not sharded and cfg.held[1] < cfg.n_experts:
-                accumulated = (4 * cfg.held[1] * cfg.ff_matrices * d
-                               * cfg.ff_dim)
-            moments.append(_Moment(
-                "layer %d" % (first + i),
-                boundaries + before_and_at + unembed + behind + accumulated
-                + _block_bytes(cfg, kind, tokens, sharded, exchange)))
-    return moments
+            frames.append(("layer %d" % (first + i),
+                           boundaries + unembed + behind + block[kind]
+                           + (accumulated if kind.routed else 0),
+                           first + i + 1))
+    made = tuple(names[kind] for kind in cfg.layers)
+    return _Terms(
+        cfg.loop_steps,
+        {name: sum(of.get(name, 0) for of in made) for name in _SAVE_ORDER
+         if any(name in of for of in made)},
+        made, tuple(frames), boundaries, head, exchange, loops, at_once)
 
 
-def _fullest_moment(cfg: TransformerConfig, tokens: int, param_bytes: int,
-                    expert_ways: int = 1,
-                    kept: Tuple[str, ...] = ()) -> _Moment:
-    """The fullest of `_moments`; of equals, the first in the backward."""
-    return max(_moments(cfg, tokens, param_bytes, expert_ways, kept),
-               key=lambda moment: moment.bytes)
-
-
-def _room_bytes(cfg: TransformerConfig, tokens: int, resident_bytes: int,
-                param_bytes: int, limit_bytes: int, expert_ways: int = 1,
-                kept: Tuple[str, ...] = ()) -> int:
-    """What the step that keeps `kept` leaves of `limit_bytes` when it is
-    fullest, `_SAVE_RESERVE` set aside: with nothing kept, the room the
-    names may take."""
-    return (limit_bytes - _SAVE_RESERVE - resident_bytes - _fullest_moment(
-        cfg, tokens, param_bytes, expert_ways, kept).bytes)
+def _moments(cfg: TransformerConfig, tokens: int, param_bytes: int,
+             expert_ways: int = 1, kept=None) -> List[_Moment]:
+    """`_Terms.moments` for the choice `kept`, from the step's shapes."""
+    return _terms(cfg, tokens, param_bytes, expert_ways).moments(kept)
 
 
 def saved_activations(cfg: TransformerConfig, tokens_per_device: int,
                       resident_bytes: int, param_bytes: int,
                       limit_bytes: Optional[int],
                       expert_ways: int = 1) -> Dict[str, int]:
-    """{name: bytes on a device} of the activations a rematerialised block
-    keeps beside its input: the names of `_SAVE_ORDER`, in that order, for
-    as long as they fit the device.
+    """What a rematerialised block keeps beside its input, {name: the number
+    of the stack's last passes that keep it} (`_kept`'s form): the names of
+    `_SAVE_ORDER`, in that order, for as long as they fit the device.
 
     `cfg.remat` says that a block's input is its checkpoint; what is kept
     beyond it follows from what the step is traced with. A choice fits
-    while it leaves room (`_room_bytes`): `resident_bytes` (the state on
+    while it leaves room (`_Terms.room`): `resident_bytes` (the state on
     the device), the fullest of the step's moments with those names kept
-    (`_moments`: over `segments(cfg)`, from `param_bytes`, the parameters'
-    bytes on the device, which its gradients take again) and
+    (`_Terms.moments`: over `segments(cfg)`, from `param_bytes`, the
+    parameters' bytes on the device, which its gradients take again) and
     `_SAVE_RESERVE` stay within `limit_bytes` (the device's
-    `bytes_limit`). A name's bytes are its width times
-    `tokens_per_device` times the layers that make it; on an `expert` axis
-    of `expert_ways` devices the routed layers are a device's share of them.
-    The first name that
-    does not fit ends the choice, so a larger limit only ever adds names.
-    A stack that is run `loop_steps` times keeps a name for the last k of
-    its passes, each name the most that leave room, its bytes those of the
-    k passes, and the first name that gets fewer than all ends the choice;
-    a stack that is run once keeps a name or does not.
-    With no limit to read (the CPU, a described topology) or without
-    `remat` nothing is kept, and the step is the one without a policy."""
+    `bytes_limit`); on an `expert` axis of `expert_ways` devices the routed
+    layers are a device's share of them. The first name that does not fit
+    ends the choice, so a larger limit only ever adds names. A stack that
+    is run `loop_steps` times keeps a name for the last k of its passes,
+    each name the most that leave room, and the first name that gets fewer
+    than all ends the choice; a stack that is run once keeps a name (at 1)
+    or does not. With no limit to read (the CPU, a described topology) or
+    without `remat` nothing is kept, and the step is the one without a
+    policy. The bytes of a choice: `_Terms.saved_bytes`."""
     if limit_bytes is None or not cfg.remat:
         return {}
-    chosen: Dict[str, int] = {}
-    for name, size in _saved_bytes(
-            _on_an_expert_axis(cfg, expert_ways), tokens_per_device).items():
-        kept = _kept_passes(cfg, tokens_per_device, chosen, expert_ways)
-        k = next((k for k in range(cfg.loop_steps, 0, -1) if _room_bytes(
-            cfg, tokens_per_device, resident_bytes, param_bytes, limit_bytes,
-            expert_ways, (*kept, (name, k))) >= 0), 0)
+    terms = _terms(cfg, tokens_per_device, param_bytes, expert_ways)
+    kept: Dict[str, int] = {}
+    for name in terms.names:
+        k = next((k for k in range(cfg.loop_steps, 0, -1) if terms.room(
+            resident_bytes, limit_bytes, {**kept, name: k}) >= 0), 0)
         if k:
-            chosen[name] = size * k // cfg.loop_steps
+            kept[name] = k
         if k < cfg.loop_steps:
             break
-    return chosen
-
-
-def _kept_passes(cfg: TransformerConfig, tokens_per_device: int,
-                 saved: Dict[str, int],
-                 expert_ways: int = 1) -> Tuple[Tuple[str, int], ...]:
-    """`saved_activations`' choice as pairs of a name and the last passes
-    of `loop_steps` that keep it, from the bytes it is kept at."""
-    whole = _saved_bytes(_on_an_expert_axis(cfg, expert_ways),
-                         tokens_per_device)
-    return tuple((name, cfg.loop_steps * size // whole[name])
-                 for name, size in saved.items())
+    return kept
 
 
 def _memory_limit(mesh) -> Optional[int]:
@@ -3214,12 +3171,11 @@ def _memory_limit(mesh) -> Optional[int]:
 
 # -------------------------------------------------------------- train step
 
-# what a routed model's step reports beside loss and grad_norm
-_STEP_READINGS = ("aux_loss", "z_loss", "expert_load", "held_slots",
-                  "dropped_slots", "chip_load", "chip_load_max_over_mean",
-                  "index_loss", "index_keys_min_gap", "index_keys_max_gap",
-                  "kda_log_decay_min", "kda_beta_mean",
-                  "ut_pass_loss", "exit_p_mean", "exit_entropy")
+# what the step reports beside loss and grad_norm: what the records state
+# and the exit loss's
+_STEP_READINGS = (
+    *(r.name for sub in _RECORDS for r in sub.readings if r.step),
+    *_EXIT_READINGS)
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
@@ -3303,31 +3259,34 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         resident = on_a_device(state, state_shard)
         params = on_a_device(state["params"], p_shard)
         ways = mesh.shape.get("expert", 1)
-        saved = saved_activations(cfg, tokens, resident, params, limit, ways)
-        chosen = _kept_passes(cfg, tokens, saved, ways)
+        kept = saved_activations(cfg, tokens, resident, params, limit, ways)
+        # built once a traced step (the rule has, if it chose), and only
+        # where something is read of them
+        terms = partial(_terms, cfg, tokens, params, ways)
+        saved = terms().saved_bytes(kept) if kept else {}
         said = saved or "nothing"
         if saved and cfg.loop_steps > 1:  # a looped stack: the passes too
             said = ", ".join("%s at %d of %d passes (%d bytes)" % (
-                name, k, cfg.loop_steps, saved[name]) for name, k in chosen)
-        kept = "keeps every activation"
+                name, k, cfg.loop_steps, saved[name])
+                for name, k in kept.items())
+        keeps = "keeps every activation"
         if cfg.remat:
-            kept = ("under remat keeps %s: %d bytes a device beside the "
-                    "blocks' inputs (%d tokens a device, state %d bytes, "
-                    "bytes_limit %s)" % (said, sum(saved.values()), tokens,
-                                         resident, limit))
+            keeps = ("under remat keeps %s: %d bytes a device beside the "
+                     "blocks' inputs (%d tokens a device, state %d bytes, "
+                     "bytes_limit %s)" % (said, sum(saved.values()), tokens,
+                                          resident, limit))
         if cfg.remat and limit is not None:
-            fullest = _fullest_moment(cfg, tokens, params, ways, chosen)
+            fullest = terms().fullest(kept)
             left = limit - _SAVE_RESERVE - resident - fullest.bytes
-            kept += ("; fullest at %s, %d bytes with the state; room %d "
-                     "bytes before a name is kept, %d with these" % (
-                         fullest.name, resident + fullest.bytes,
-                         _room_bytes(cfg, tokens, resident, params, limit,
-                                     ways), left))
+            keeps += ("; fullest at %s, %d bytes with the state; room %d "
+                      "bytes before a name is kept, %d with these" % (
+                          fullest.name, resident + fullest.bytes,
+                          terms().room(resident, limit), left))
             tracing.count("train.saved_room_bytes", left)
         # static, so counted as the step is traced: once a step's program
-        tracing.count("train.saved_names", len(saved))
+        tracing.count("train.saved_names", len(kept))
         tracing.count("train.saved_bytes", sum(saved.values()))
-        tracing.count("train.saved_passes", sum(k for _, k in chosen))
+        tracing.count("train.saved_passes", sum(kept.values()))
         buffers, their_bytes, widest = own_buffers(
             state["params"]["blocks"], cfg)
         tracing.count("train.own_buffers", buffers)
@@ -3335,10 +3294,9 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         logger.info(
             "train step %s; its blocks' weight matmuls read and write %d "
             "buffers of their own, %d bytes in %s over %d layers (%d the "
-            "widest layer's weights)", kept, buffers, their_bytes,
+            "widest layer's weights)", keeps, buffers, their_bytes,
             jnp.dtype(cfg.dtype).name, cfg.n_layers, widest)
-        # a stack that is run once keeps a name or does not
-        return tuple(saved) if cfg.loop_steps == 1 else chosen
+        return kept
 
     @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))
     def step(state, batch):

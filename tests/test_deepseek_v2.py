@@ -332,7 +332,10 @@ def test_param_shardings_of_the_new_leaves():
 def test_saved_activations_know_the_new_layer():
     cfg = tiny(dtype=jnp.bfloat16, remat=True)
     tokens = 4 * 64
-    sizes = model._saved_bytes(cfg, tokens)
+    state = 12 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+        lambda: transformer_init(key(0), cfg))))
+    terms = model._terms(cfg, tokens, state // 3)
+    sizes = terms.saved_bytes()
     assert list(sizes) == ["attn_ctx", "attn_res", "attn_qkv", "shared_gate",
                            "shared_up", "mlp_gate", "mlp_up"]
     # o at a tile's 128 lanes a head and lse as one f32 column, three layers
@@ -345,15 +348,13 @@ def test_saved_activations_know_the_new_layer():
     widths, params = model._layer_widths(cfg, cfg.layers[1])
     assert params == (64 * 96 + 64 * 40 + 32 * 128 + 64 * 64
                       + 64 * 8 + 2 * 3 * 64 * 32 + 3 * 64 * 64)
-    state = 12 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
-        lambda: transformer_init(key(0), cfg))))
     args = (cfg, tokens, state, state // 3)
     assert saved_activations(*args, None) == {}  # no limit to read
     assert saved_activations(*args, 1 << 20) == {}  # no room
-    assert saved_activations(*args, 1 << 40) == sizes  # all the room
+    # all the room: every name, at the stack's one pass
+    assert saved_activations(*args, 1 << 40) == dict.fromkeys(sizes, 1)
     chosen = saved_activations(
-        *args, state + state // 3 + model._SAVE_RESERVE
-        + model._working_set_bytes(cfg, tokens, state // 3)
+        *args, state + state // 3 + model._SAVE_RESERVE + terms.at_once
         + sizes["attn_ctx"] + sizes["attn_res"])
     assert list(chosen) == ["attn_ctx", "attn_res"]
 

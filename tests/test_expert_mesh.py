@@ -237,9 +237,8 @@ def test_the_step_s_working_set_prices_the_exchange():
     all the axis's tokens: the gathered rows and the partial results are
     held, the whole layer's names are not made."""
     tokens, params = 4096, 10**6
-    alone = transformer._working_set_bytes(CFG, tokens, params)
-    over = transformer._working_set_bytes(CFG, tokens, params, WAYS)
-    assert over > alone
-    assert "moe_up" in transformer._saved_bytes(CFG, tokens)
-    assert "moe_up" not in transformer._saved_bytes(
-        transformer._on_an_expert_axis(CFG, WAYS), tokens)
+    alone = transformer._terms(CFG, tokens, params)
+    over = transformer._terms(CFG, tokens, params, WAYS)
+    assert over.at_once > alone.at_once
+    assert alone.exchange == 0 < over.exchange
+    assert "moe_up" in alone.names and "moe_up" not in over.names

@@ -529,20 +529,20 @@ def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
             cfg, tokens, 3 * params, params, HBM_LIMIT)
 
     cfg, chosen = kept("pallas")
+    terms = tr._terms(cfg, tokens, params)
     assert tr._scan_bytes_per_token(cfg) * tokens == 746586112
-    assert chosen == {"attn_ctx": 136314880, "attn_res": 88080384,
-                      "attn_qkv": 150994944, "mamba_in": 1350565888,
-                      "ssd_out": 536870912, "shared_up": 486539264}
-    assert tr._fullest_moment(
-        cfg, tokens, params, kept=tuple(chosen)).name == "layer 7"
-    assert tr._room_bytes(
-        cfg, tokens, 3 * params, params, HBM_LIMIT) == 3828756480
+    assert terms.saved_bytes(chosen) == {
+        "attn_ctx": 136314880, "attn_res": 88080384, "attn_qkv": 150994944,
+        "mamba_in": 1350565888, "ssd_out": 536870912, "shared_up": 486539264}
+    assert terms.fullest(chosen).name == "layer 7"
+    assert terms.room(3 * params, HBM_LIMIT) == 3828756480
     cfg, chosen = kept("xla")
+    terms = tr._terms(cfg, tokens, params)
     assert tr._scan_bytes_per_token(cfg) * tokens == 64 * 128 * 20 * tokens
-    assert chosen == {"attn_ctx": 136314880, "attn_res": 88080384,
-                      "attn_qkv": 150994944, "mamba_in": 1350565888}
-    assert tr._room_bytes(
-        cfg, tokens, 3 * params, params, HBM_LIMIT) == 1890988032
+    assert terms.saved_bytes(chosen) == {
+        "attn_ctx": 136314880, "attn_res": 88080384, "attn_qkv": 150994944,
+        "mamba_in": 1350565888}
+    assert terms.room(3 * params, HBM_LIMIT) == 1890988032
 
 
 # ------------------------- the token cells' steps with what remat keeps

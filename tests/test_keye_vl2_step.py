@@ -46,13 +46,18 @@ def step():
     config, traffic = cell["config"], cell["traffic"]
     # "auto" asks the platform, which is the CPU here: steered in the test
     config["attention_impl"] = "pallas"
-    chosen = []
+    chosen = []  # the bytes of what the rule kept, as the step's line has them
     rule = tr.saved_activations
+
+    def recording(cfg, tokens, resident, params, limit, ways):
+        kept = rule(cfg, tokens, resident, params, limit, ways)
+        chosen.append(tr._terms(cfg, tokens, params, ways).saved_bytes(kept))
+        return kept
+
     with pytest.MonkeyPatch.context() as patch:
         # a described device reports no limit: the chip's is handed over
         patch.setattr(tr, "_memory_limit", lambda mesh: CHIP_LIMIT)
-        patch.setattr(tr, "saved_activations", lambda *args: (
-            chosen.append(rule(*args)) or chosen[-1]))
+        patch.setattr(tr, "saved_activations", recording)
         family = spec.load_code(spec.ROOT, "loops", config["family"]).build(
             config, traffic, list(devices[:1]))
         key = jax.eval_shape(lambda: loop.seed_key(0))

@@ -840,14 +840,15 @@ def test_layer_widths_by_hand_for_both_types():
     assert params == 29458432 + 3 * d * 8192
     assert widths["mlp_gate"] == widths["mlp_up"] == 8192
     # over the five layers, two sequences of 8192: bytes in bf16
-    saved = model._saved_bytes(cfg, 16384)
+    terms = model._terms(cfg, 16384, 4 * 691623936)
+    saved = terms.saved_bytes()
     assert saved["attn_ctx"] == 16384 * 2 * (
         2 * (48 * 128 + 96) + 3 * (64 * 128 + 128))
     assert saved["attn_ctx"] / 1e9 == pytest.approx(1.22, abs=0.01)
     # the widest block in its backward is a sliding one: 64 heads' q, k, v
     # as the kernel takes them, and their lse and delta at a tile's lanes
     state = 12 * 691623936
-    working = model._working_set_bytes(cfg, 16384, 4 * 691623936)
+    working = terms.at_once
     assert 3.0e9 < working < 4.2e9
     kept = saved_activations(cfg, 16384, state, 4 * 691623936, int(15.84e9))
     assert list(kept)[:1] in ([], ["attn_ctx"])

@@ -134,6 +134,83 @@ def test_a_record_agrees_with_itself(case):
     assert (attention > 0) == ("attn_ctx" in record.names)
 
 
+# the readings a routed layer makes only as a share of the experts, and only
+# under an `expert` mesh axis (`tests/test_expert_mesh.py` has that one)
+OF_A_SHARE = {"held_slots", "dropped_slots"}
+ON_AN_AXIS = {"chip_load"}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_a_record_states_the_readings_its_forward_makes(case):
+    record, kind, cfg, _ = of_case(case)
+    leaves = record.init(jax.random.PRNGKey(0), cfg, L)
+    blk = jax.tree.map(lambda leaf: leaf[0], leaves)
+    x = jnp.zeros((2, 16, cfg.d_model), cfg.dtype)
+    positions = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
+    made = jax.eval_shape(lambda x, blk: model._block(
+        x, blk, positions, None, cfg, kind, None, 1)[1], x, blk)
+    stated = {r.name: r for r in record.readings}
+    assert len(stated) == len(record.readings)
+    if not stated:
+        assert made is None
+        return
+    own = {name for name, r in stated.items() if r.of is None}
+    share = cfg.held[1] < cfg.n_experts
+    assert set(made) == own - ON_AN_AXIS - (set() if share else OF_A_SHARE)
+    for r in record.readings:
+        assert r.over_layers in model._OVER_LAYERS
+        assert r.adds in (None, True) or isinstance(
+            getattr(cfg, r.adds), float)
+        assert r.of is None or r.of in own
+        assert (r.name in model._STEP_READINGS) == r.step
+    # over L layers, as the loss joins them: a mean, a least or a most is
+    # one number, the others keep the layer axis
+    layers = jax.tree.map(
+        lambda a: jnp.ones((L, *a.shape), a.dtype), dict(made))
+    _, settled = model._settled(jnp.float32(0.0), layers, cfg)
+    assert set(settled) >= set(made)
+    for name, value in settled.items():
+        if stated[name].over_layers in ("mean", "min", "max",
+                                        "sum under seq_aux"):
+            assert value.shape == ()
+        else:
+            assert value.shape[0] == L
+
+
+def test_the_loss_has_what_the_records_say_their_readings_add():
+    """A record's terms at the configuration's coefficients, the indexers'
+    at 1; with `seq_aux` the balance loss is summed over the layers."""
+    routed = {"aux_loss": jnp.array([2.0, 4.0]), "z_loss": jnp.array([1.0, 3.0]),
+              "chip_load": jnp.array([[1, 3], [2, 2]], jnp.int32)}
+    cfg = TransformerConfig(**{**BASE, **ROUTED, "router_aux_loss_coef": 0.5,
+                               "router_z_loss_coef": 0.25})
+    loss, settled = model._settled(jnp.float32(1.0), routed, cfg)
+    assert float(loss) == 1.0 + 0.5 * 3.0 + 0.25 * 2.0
+    assert float(settled["aux_loss"]) == 3.0 and float(settled["z_loss"]) == 2.0
+    assert settled["chip_load_max_over_mean"].tolist() == [1.5, 1.0]
+    assert settled["chip_load"].tolist() == [[1, 3], [2, 2]]
+    summed = TransformerConfig(**{**cfg.__dict__, "seq_aux": True})
+    assert float(model._settled(jnp.float32(1.0), routed, summed)[0]) == (
+        1.0 + 0.5 * 6.0 + 0.25 * 2.0)
+    off = TransformerConfig(**{**cfg.__dict__, "router_aux_loss_coef": 0.0,
+                               "router_z_loss_coef": 0.0})
+    assert float(model._settled(jnp.float32(1.0), routed, off)[0]) == 1.0
+    indexed = {"index_loss": jnp.array([0.5, 1.5]),
+               "index_keys_min_gap": jnp.array([0, -1]),
+               "index_keys_max_gap": jnp.array([0, 2])}
+    loss, settled = model._settled(jnp.float32(1.0), indexed, cfg)
+    assert float(loss) == 2.0
+    assert (int(settled["index_keys_min_gap"]),
+            int(settled["index_keys_max_gap"])) == (-1, 2)
+    # what the step reports: what the records state and the exit loss's
+    assert set(model._STEP_READINGS) == {
+        "aux_loss", "z_loss", "expert_load", "held_slots", "dropped_slots",
+        "chip_load", "chip_load_max_over_mean", "index_loss",
+        "index_keys_min_gap", "index_keys_max_gap", "kda_log_decay_min",
+        "kda_beta_mean", "ut_pass_loss", "exit_p_mean", "exit_entropy"}
+    assert len(set(model._STEP_READINGS)) == len(model._STEP_READINGS)
+
+
 @pytest.mark.parametrize("field,names,known", [
     ("layer_types", ("sliding_atention",), "sliding_attention"),
     ("layer_types", ("dense_ff",), "full_attention"),

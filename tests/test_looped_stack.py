@@ -4,7 +4,8 @@ applied by hand, outputs and every gradient; `loop_steps` 1 with the new
 fields off traces the program it was; the exit distribution and its entropy;
 the sandwich norms; what the rule of a rematerialised block's kept names
 counts a pass; what is refused; the scopes. CPU, tiny sizes, seeded
-weights."""
+weights; the frame is `tests/tiny_models.py`'s: a loss with its gradients is
+one compiled program, and a program that several cases read is made once."""
 
 import dataclasses
 import functools
@@ -18,10 +19,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import TransformerConfig
+from ray_tpu.models import TransformerConfig, make_train_step
 from ray_tpu.models import transformer as tr
 from ray_tpu.ops.fused import fused_rmsnorm, weighted_lm_head_cross_entropy
 from ray_tpu.parallel import make_mesh
+from ray_tpu.util import tracing
+
+import tiny_models as tm
+from tiny_models import value_and_grad
 
 TINY = dict(vocab_size=97, d_model=32, n_layers=2, n_heads=4, d_ff=64,
             max_seq_len=16, dtype=jnp.float32, tied_embeddings=False,
@@ -34,8 +39,9 @@ def looped(**over):
         "exit_entropy_coef": 0.05, **over})
 
 
+@functools.cache
 def seeded(cfg, seed=0):
-    params = tr.transformer_init(jax.random.PRNGKey(seed), cfg)
+    params = tm.init(tm.key(seed), cfg)
     # norms off 1, the gate's bias off 0: a gradient that a scale or a bias
     # of exactly 1 or 0 would hide shows
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
@@ -49,13 +55,11 @@ def seeded(cfg, seed=0):
     return jax.tree_util.tree_map_with_path(moved, params)
 
 
-def batch_of(cfg, rows=2, seed=1):
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(seed), (rows, cfg.max_seq_len + 1), 0,
-        cfg.vocab_size)
-    return {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+def batch_of(cfg):
+    return tm.batch_of(cfg, seq=cfg.max_seq_len)
 
 
+@functools.partial(jax.jit, static_argnums=(2, 3))
 def streams_by_hand(params, tokens, cfg, norm_between=True):
     """The passes' normed streams with no loop of the program's: a Python
     loop over the passes and the layers, every layer through `_block` on its
@@ -80,6 +84,11 @@ def loss_by_hand(params, batch, cfg):
     return tr._exit_loss(streams, params, batch["targets"], cfg)[0]
 
 
+def loss_of(cfg, batch, **kw):
+    """`params -> the model's own loss`, for `value_and_grad`."""
+    return lambda p: tr.transformer_loss(p, batch, cfg, **kw)
+
+
 # ------------------------------------------------ the loop against by hand
 
 @pytest.mark.parametrize("remat", [False, True])
@@ -87,25 +96,24 @@ def loss_by_hand(params, batch, cfg):
 def test_the_looped_stack_is_its_blocks_applied_four_times(remat, n_layers):
     cfg = looped(remat=remat, n_layers=n_layers)
     params, batch = seeded(cfg), batch_of(cfg)
-    streams, readings = tr._hidden_and_readings(params, batch["tokens"], cfg)
+    (streams, readings), hidden = jax.jit(lambda p: (
+        tr._hidden_and_readings(p, batch["tokens"], cfg),
+        tr.transformer_hidden(p, batch["tokens"], cfg)))(params)
     assert readings is None and streams.shape == (4, 2, 16, 32)
     by_hand = streams_by_hand(params, batch["tokens"], cfg)
     np.testing.assert_allclose(streams, by_hand, atol=2e-5)
     # a pass changes the stream: the four are four
     assert float(jnp.abs(streams[1:] - streams[:-1]).max()) > 1e-2
-    np.testing.assert_allclose(
-        tr.transformer_hidden(params, batch["tokens"], cfg), by_hand[-1],
-        atol=2e-5)
+    np.testing.assert_allclose(hidden, by_hand[-1], atol=2e-5)
 
 
 @pytest.mark.parametrize("saved", [(), ("attn_res", "mlp_up")])
 def test_every_gradient_is_the_sum_over_a_weights_four_uses(saved):
     cfg = looped(remat=True)
     params, batch = seeded(cfg), batch_of(cfg)
-    loss, grads = jax.value_and_grad(
-        lambda p: tr.transformer_loss(p, batch, cfg, saved_names=saved))(params)
-    by_hand, grads_by_hand = jax.value_and_grad(loss_by_hand)(
-        params, batch, cfg)
+    loss, grads = value_and_grad(loss_of(cfg, batch, saved_names=saved), params)
+    by_hand, grads_by_hand = value_and_grad(
+        lambda p: loss_by_hand(p, batch, cfg), params)
     assert float(loss) == pytest.approx(float(by_hand), rel=1e-6)
     flat = jax.tree_util.tree_leaves_with_path(grads)
     assert len(flat) == 16  # 11 of the blocks, embed, unembed, norm, gate's 2
@@ -125,6 +133,30 @@ MODELS = {  # (what differs from `looped()`, the gradient's leaves)
 }
 
 
+@jax.jit
+def adamw_update(params, grads):
+    """The parameters after AdamW's first update from `grads`: compiled once
+    a model, whatever made the gradients."""
+    import optax
+
+    optimizer = optax.adamw(1e-2)
+    updates, _ = optimizer.update(grads, optimizer.init(params), params)
+    return optax.apply_updates(params, updates)
+
+
+def loss_grads_and_update(cfg, saved=()):
+    """(loss, its gradients, the parameters after one AdamW update from
+    them) of `seeded(cfg)` with `saved` kept."""
+    params = seeded(cfg)
+    loss, grads = value_and_grad(
+        loss_of(cfg, batch_of(cfg), saved_names=saved), params)
+    return loss, grads, adamw_update(params, grads)
+
+
+# what JAX makes of the stack without remat by itself: once a model
+plain_autodiffs = functools.cache(loss_grads_and_update)
+
+
 @pytest.mark.parametrize("kept_passes", range(5))
 @pytest.mark.parametrize("name", ["attn_ctx", "mlp_up"])
 @pytest.mark.parametrize("model", sorted(MODELS))
@@ -135,31 +167,18 @@ def test_the_backward_under_remat_is_plain_autodiffs(model, name, kept_passes):
     differ): the loss, every gradient leaf and the parameters after an
     AdamW update are those of the stack without remat, which JAX
     differentiates by itself."""
-    import optax
-
     over, leaves = MODELS[model]
     cfg = looped(remat=True, **over)
-    params, batch = seeded(cfg), batch_of(cfg)
-    saved = ("attn_res", (name, kept_passes))
-    plain = dataclasses.replace(cfg, remat=False)
-    wanted, wanted_grads = jax.jit(jax.value_and_grad(
-        lambda p: tr.transformer_loss(p, batch, plain)))(params)
-    loss, grads = jax.jit(jax.value_and_grad(lambda p: tr.transformer_loss(
-        p, batch, cfg, saved_names=saved)))(params)
+    wanted, wanted_grads, wanted_params = plain_autodiffs(
+        dataclasses.replace(cfg, remat=False))
+    loss, grads, updated = loss_grads_and_update(
+        cfg, {"attn_res": 4, name: kept_passes})
     assert float(loss) == pytest.approx(float(wanted), rel=1e-6)
-    optimizer = optax.adamw(1e-2)
-    state = optimizer.init(params)
-
-    def updated(grads):
-        return optax.apply_updates(
-            params, optimizer.update(grads, state, params)[0])
-
     flat = jax.tree_util.tree_leaves_with_path(grads)
     assert len(flat) == leaves
     for (path, ours), theirs, new, new_wanted in zip(
-            flat, jax.tree.leaves(wanted_grads),
-            jax.tree.leaves(updated(grads)),
-            jax.tree.leaves(updated(wanted_grads))):
+            flat, jax.tree.leaves(wanted_grads), jax.tree.leaves(updated),
+            jax.tree.leaves(wanted_params)):
         assert float(jnp.abs(theirs).max()) > 0, path
         np.testing.assert_allclose(ours, theirs, atol=3e-5, rtol=1e-4,
                                    err_msg=str(path))
@@ -174,7 +193,9 @@ def test_a_pass_kept_beyond_the_stacks_is_refused():
     cfg = looped(remat=True)
     params, batch = seeded(cfg), batch_of(cfg)
     with pytest.raises(ValueError, match="attn_ctx kept at 5 of 4 passes"):
-        tr.transformer_loss(params, batch, cfg, saved_names=(("attn_ctx", 5),))
+        tr.transformer_loss(params, batch, cfg, saved_names={"attn_ctx": 5})
+    with pytest.raises(ValueError, match="attn_out kept .* not a name"):
+        tr.transformer_loss(params, batch, cfg, saved_names=("attn_out",))
 
 
 def flash_in_interpret_mode(monkeypatch):
@@ -209,7 +230,7 @@ def test_a_kept_passs_flash_forward_is_not_made_again(monkeypatch,
     params = jax.eval_shape(lambda: seeded(cfg))
     batch = batch_of(cfg)
     closed = jax.make_jaxpr(jax.grad(lambda p: tr.transformer_loss(
-        p, batch, cfg, saved_names=(("attn_ctx", kept_passes),))))(params)
+        p, batch, cfg, saved_names={"attn_ctx": kept_passes})))(params)
     over_layers = [str(eqn) for eqn in scans(closed.jaxpr)
                    if eqn.params["length"] == cfg.n_layers]
     forward = [text for text in over_layers if "flash_bwd" not in text]
@@ -242,7 +263,7 @@ def test_the_backward_holds_one_sum_of_the_layers_gradient():
         return len(re.findall(r"stablehlo\.dynamic_slice [^\n]*-> "
                               r"tensor<(1x)?3x2x16x32xf32>\n", text))
 
-    for saved in ((), (("attn_ctx", 2),)):
+    for saved in ((), {"attn_ctx": 2}):
         text = lowered(cfg, saved_names=saved)
         assert "tensor<4x3x2x16x32xf32>" in text  # all the passes' inputs
         assert "tensor<3x32x64xf32>" in text  # the one sum
@@ -255,14 +276,14 @@ def test_without_a_gate_the_loss_is_the_last_passs_head_alone():
     cfg = looped(exit_gate=False, exit_entropy_coef=0.0)
     once = dataclasses.replace(cfg, loop_steps=1)
     params, batch = seeded(cfg), batch_of(cfg)
-    g4 = jax.grad(lambda p: tr.transformer_loss(p, batch, cfg))(params)
-    g1 = jax.grad(lambda p: tr.transformer_loss(p, batch, once))(params)
+    loss, g4 = value_and_grad(loss_of(cfg, batch), params)
+    _, g1 = value_and_grad(loss_of(once, batch), params)
     assert float(jnp.abs(g4["blocks"]["wq"] - g1["blocks"]["wq"]).max()) > 1e-4
     # without the gate the loss is the last pass's cross-entropy alone
     streams = streams_by_hand(params, batch["tokens"], cfg)
-    last = tr._head_loss(streams[-1], params["unembed"], batch["targets"])
-    assert float(tr.transformer_loss(params, batch, cfg)) == pytest.approx(
-        float(last), rel=1e-6)
+    last = jax.jit(tr._head_loss)(
+        streams[-1], params["unembed"], batch["targets"])
+    assert float(loss) == pytest.approx(float(last), rel=1e-6)
 
 
 # --------------------------------- loop_steps 1: the program that there was
@@ -407,7 +428,8 @@ def test_the_loss_is_the_expected_cross_entropy_less_beta_entropy():
     params, batch = seeded(cfg), batch_of(cfg)
     targets = batch["targets"].at[0, :5].set(-100)  # ignored: count 27
     streams = streams_by_hand(params, batch["tokens"], cfg)
-    loss, readings = tr._exit_loss(streams, params, targets, cfg)
+    exit_loss = jax.jit(tr._exit_loss, static_argnums=3)
+    loss, readings = exit_loss(streams, params, targets, cfg)
     logits = streams @ params["unembed"]
     ce = jax.scipy.special.logsumexp(logits, -1) - jnp.take_along_axis(
         logits, jnp.where(targets < 0, 0, targets)[None, ..., None].repeat(4, 0),
@@ -426,8 +448,8 @@ def test_the_loss_is_the_expected_cross_entropy_less_beta_entropy():
         readings["exit_p_mean"], (p * mask).sum((1, 2)) / 27, rtol=1e-5)
     assert float(readings["exit_p_mean"].sum()) == pytest.approx(1.0, abs=1e-6)
     # beta 0: the expected cross-entropy alone
-    plain, _ = tr._exit_loss(streams, params, targets,
-                             dataclasses.replace(cfg, exit_entropy_coef=0.0))
+    plain, _ = exit_loss(streams, params, targets,
+                         dataclasses.replace(cfg, exit_entropy_coef=0.0))
     assert float(plain) == pytest.approx(expected, rel=1e-5)
 
 
@@ -436,13 +458,13 @@ def test_the_gate_learns_through_the_weights():
     constant only the entropy's is left."""
     cfg = looped()
     params, batch = seeded(cfg), batch_of(cfg)
-    whole = jax.grad(lambda p: tr.transformer_loss(p, batch, cfg))(params)
+    _, whole = value_and_grad(loss_of(cfg, batch), params)
 
     real = tr.weighted_lm_head_cross_entropy
     try:
         tr.weighted_lm_head_cross_entropy = lambda h, w, t, wt, **kw: real(
             h, w, t, jax.lax.stop_gradient(wt), **kw)
-        held = jax.grad(lambda p: tr.transformer_loss(p, batch, cfg))(params)
+        _, held = value_and_grad(loss_of(cfg, batch), params)
     finally:
         tr.weighted_lm_head_cross_entropy = real
     assert tr.weighted_lm_head_cross_entropy is weighted_lm_head_cross_entropy
@@ -467,17 +489,17 @@ def test_post_norm_adds_one_scale_a_sublayer_and_norms_the_output():
     batch = batch_of(cfg)
     scaled = {**params, "blocks": {**blocks, "wo": 3.0 * blocks["wo"],
                                    "w_down": 0.5 * blocks["w_down"]}}
+    loss = jax.jit(tr.transformer_loss, static_argnums=2)
     np.testing.assert_allclose(
-        tr.transformer_loss(params, batch, cfg),
-        tr.transformer_loss(scaled, batch, cfg), rtol=2e-5)
+        loss(params, batch, cfg), loss(scaled, batch, cfg), rtol=2e-5)
     plain = dataclasses.replace(cfg, post_norm=False)
 
     def unnormed(p):  # a norm is found by its leaf
         return {**p, "blocks": {k: v for k, v in p["blocks"].items()
                                 if not k.endswith("post_norm")}}
 
-    moved = tr.transformer_loss(unnormed(scaled), batch, plain) - (
-        tr.transformer_loss(unnormed(params), batch, plain))
+    moved = loss(unnormed(scaled), batch, plain) - (
+        loss(unnormed(params), batch, plain))
     assert abs(float(moved)) > 1e-3
     # holds: the product before the norm, a `d_model` each
     for sub in tr._sublayers(kind):
@@ -552,7 +574,9 @@ def test_a_kept_name_is_held_once_a_layer_a_pass():
     cfg = rule_config()
     once = dataclasses.replace(cfg, loop_steps=1, exit_gate=False)
     tokens = 2048
-    four, one = tr._saved_bytes(cfg, tokens), tr._saved_bytes(once, tokens)
+    params = tr._whole_param_bytes(cfg)
+    terms = tr._terms(cfg, tokens, params)
+    four, one = terms.saved_bytes(), tr._terms(once, tokens).saved_bytes()
     assert list(four) == list(one) == [
         "attn_ctx", "attn_res", "attn_qkv", "mlp_gate", "mlp_up"]
     for name in one:
@@ -562,26 +586,34 @@ def test_a_kept_name_is_held_once_a_layer_a_pass():
     stream = tokens * cfg.d_model * 2
     assert tr._boundary_bytes(once, tokens) == (3 + 1) * stream
     assert tr._boundary_bytes(cfg, tokens) == (4 * 3 + 1 + 2 * 4) * stream
+    assert (terms.boundaries, tr._terms(once, tokens).boundaries) == (
+        21 * stream, 4 * stream)
     # the head reads the four passes' streams, stacked
-    params = tr._whole_param_bytes(cfg)
     assert tr._head_bytes(cfg, tokens, params, 1) - tr._head_bytes(
         once, tokens, tr._whole_param_bytes(once), 1) == 3 * stream
     # beside the rest the loops hold the layers' weights in bf16, cast once
     # for all of them; the backward has one sum of the layers' gradient and
     # no pass's inputs apart
-    assert tr._pass_bytes(once, params) == 0
-    assert tr._pass_bytes(cfg, params) == 2 * 3 * (
-        4 * 256 * 256 + 3 * 256 * 512)
+    assert tr._terms(once, tokens).loops == 0
+    assert terms.loops == 2 * 3 * (4 * 256 * 256 + 3 * 256 * 512)
     # the kept names of the moments' walk count the passes that keep them
-    kept = ("attn_ctx", ("attn_res", 2))
+    kept = {"attn_ctx": 4, "attn_res": 2}
     moments = {m.name: m.bytes for m in tr._moments(cfg, tokens, params, 1, kept)}
-    bare = {m.name: m.bytes for m in tr._moments(cfg, tokens, params, 1, ())}
+    bare = {m.name: m.bytes for m in tr._moments(cfg, tokens, params, 1)}
     assert set(moments) == {"optimizer", "head", "layers 0-2"}
     for moment in ("head", "layers 0-2"):
         assert moments[moment] - bare[moment] == (
             four["attn_ctx"] + four["attn_res"] // 2)
-    assert tr._passes_kept(cfg, kept) == {"attn_ctx": 4, "attn_res": 2}
-    assert tr._passes_kept(cfg, (("attn_ctx", 0),)) == {}
+    assert terms.saved_bytes(kept) == {
+        "attn_ctx": four["attn_ctx"], "attn_res": four["attn_res"] // 2}
+    # the one form: a plain tuple of names means every pass, a name kept at
+    # no pass is not kept, and the order is the rule's
+    assert tr._kept(cfg, ("attn_res", "attn_ctx")) == {
+        "attn_ctx": 4, "attn_res": 4}
+    assert list(tr._kept(cfg, {"attn_res": 2, "attn_ctx": 4})) == [
+        "attn_ctx", "attn_res"]
+    assert tr._kept(cfg, {"attn_ctx": 0}) == tr._kept(cfg, ()) == {}
+    assert tr._kept(once, ("attn_ctx",)) == {"attn_ctx": 1}
 
 
 def test_a_stack_of_one_period_under_the_loop_is_counted_as_a_scan():
@@ -603,32 +635,85 @@ def test_saved_activations_never_chooses_more_than_fits(limit_gb):
     tokens, limit = 2048, int(limit_gb * 2**30) + tr._SAVE_RESERVE
     params = tr._whole_param_bytes(cfg)
     resident = 3 * params
-    chosen = tr.saved_activations(cfg, tokens, resident, params, limit)
-    sizes = tr._saved_bytes(cfg, tokens)
-    assert list(chosen) == list(sizes)[:len(chosen)]
+    kept = tr.saved_activations(cfg, tokens, resident, params, limit)
+    terms = tr._terms(cfg, tokens, params)
+    sizes = terms.saved_bytes()
+    assert list(kept) == list(sizes)[:len(kept)]
     # a prefix, in order; every name at all four passes but the last, which
     # has the most that fit
-    kept = tr._kept_passes(cfg, tokens, chosen)
-    assert all(k == 4 for _, k in kept[:-1])
-    assert all(1 <= k <= 4 and chosen[name] == sizes[name] * k // 4
-               for name, k in kept)
-    fullest = tr._fullest_moment(cfg, tokens, params, 1, kept)
-    if chosen:
-        assert resident + fullest.bytes + tr._SAVE_RESERVE <= limit
+    passes = list(kept.values())
+    assert all(k == 4 for k in passes[:-1])
+    assert all(1 <= k <= 4 for k in passes)
+    assert terms.saved_bytes(kept) == {
+        name: sizes[name] * k // 4 for name, k in kept.items()}
+    if kept:
+        assert terms.room(resident, limit, kept) >= 0
+        assert (resident + terms.fullest(kept).bytes + tr._SAVE_RESERVE
+                <= limit)
     # a pass more of the last name, or the next name's first, would not fit
     more = None
-    if kept and kept[-1][1] < 4:
-        more = (*kept[:-1], (kept[-1][0], kept[-1][1] + 1))
-    elif len(chosen) < len(sizes):
-        more = (*kept, (list(sizes)[len(chosen)], 1))
+    if kept and passes[-1] < 4:
+        more = {**kept, list(kept)[-1]: passes[-1] + 1}
+    elif len(kept) < len(sizes):
+        more = {**kept, list(sizes)[len(kept)]: 1}
     if more:
-        over = tr._fullest_moment(cfg, tokens, params, 1, more)
-        assert resident + over.bytes + tr._SAVE_RESERVE > limit
+        assert terms.room(resident, limit, more) < 0
     # the same limit keeps no fewer names of a stack that is run once
     once = dataclasses.replace(cfg, loop_steps=1, exit_gate=False)
     p1 = tr._whole_param_bytes(once)
     assert len(tr.saved_activations(once, tokens, 3 * p1, p1, limit)) >= len(
-        [name for name, k in kept if k == 4])
+        [name for name, k in kept.items() if k == 4])
+
+
+def test_unequal_passes_reach_the_log_line_and_the_counters(monkeypatch,
+                                                            caplog):
+    """A looped stack's choice with another number of passes a name, as the
+    traced step hands it on, says and counts it: the bytes of the step's
+    line are the choice's (`_Terms.saved_bytes`), `train.saved_passes` is
+    the sum of its passes, and the choice comes back out of both."""
+    cfg = looped(remat=True, n_layers=3)
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    init_state, step, _ = make_train_step(cfg, mesh)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    batch = {"tokens": ids, "targets": ids}
+    seen = {}
+
+    def rule(cfg, tokens, resident, params, limit, ways):
+        # the first limit at which two names are kept at unequal passes
+        terms = tr._terms(cfg, tokens, params, ways)
+        for room in range(0, 1 << 20, 1 << 10):
+            limit = resident + tr._SAVE_RESERVE + terms.fullest().bytes + room
+            kept = rule.real(cfg, tokens, resident, params, limit, ways)
+            if len(set(kept.values())) == 2:
+                seen.update(kept=kept, terms=terms, limit=limit)
+                return kept
+        raise AssertionError("no limit keeps two names at unequal passes")
+
+    rule.real = tr.saved_activations
+    monkeypatch.setattr(tr, "saved_activations", rule)
+    monkeypatch.setattr(tr, "_memory_limit", lambda mesh: 1 << 40)
+    before = tracing.counters()
+    with caplog.at_level("INFO", logger=tr.logger.name):
+        step.lower(state, batch)
+    kept, terms = seen["kept"], seen["terms"]
+    assert list(kept) == ["attn_ctx", "attn_res"] and kept["attn_ctx"] == 4
+    assert 1 <= kept["attn_res"] < 4
+    counted = {name: n - before.get(name, 0)
+               for name, n in tracing.counters().items()}
+    sizes = terms.saved_bytes(kept)
+    assert counted["train.saved_names"] == 2
+    assert counted["train.saved_passes"] == 4 + kept["attn_res"]
+    assert counted["train.saved_bytes"] == sum(sizes.values())
+    (line,) = [r.getMessage() for r in caplog.records
+               if "remat" in r.getMessage()]
+    said = re.findall(r"(\w+) at (\d) of 4 passes \((\d+) bytes\)", line)
+    assert [(name, int(k)) for name, k, _ in said] == list(kept.items())
+    assert {name: int(size) for name, _, size in said} == sizes
+    assert "%d bytes a device" % sum(sizes.values()) in line
+    # and the bytes say the passes again, a name's bytes a pass known
+    assert {name: size // terms.names[name]
+            for name, size in sizes.items()} == kept
 
 
 def test_operations_count_a_layer_and_the_head_once_a_pass():

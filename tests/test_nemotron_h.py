@@ -416,7 +416,10 @@ def test_saved_activations_name_only_what_each_kind_has():
     whole = dataclasses.replace(cfg, experts_held=None)
     assert set(model._layer_widths(whole, LayerKind(None, True))[0]) == {
         "moe_slots", "moe_up", "shared_up"}
-    sizes = model._saved_bytes(cfg, tokens)
+    state = 12 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+        lambda: transformer_init(key(0), cfg))))
+    terms = model._terms(cfg, tokens, state // 3)
+    sizes = terms.saved_bytes()
     assert list(sizes) == ["attn_ctx", "attn_res", "attn_qkv", "mamba_in",
                            "ssd_out", "shared_up"]
     assert sizes["mamba_in"] == 4 * tokens * 132 * 2  # four mixers
@@ -424,25 +427,22 @@ def test_saved_activations_name_only_what_each_kind_has():
     assert sizes["attn_res"] == tokens * 32 * 2  # one attention layer
     # a mixer's parameters but for its vectors: W_in and W_out
     assert model._layer_widths(cfg, cfg.layers[0])[1] == 32 * 132 + 32 * 32
-    state = 12 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
-        lambda: transformer_init(key(0), cfg))))
     args = (cfg, tokens, state, state // 3)
     assert saved_activations(*args, None) == {}
-    assert saved_activations(*args, 1 << 40) == sizes
+    assert saved_activations(*args, 1 << 40) == dict.fromkeys(sizes, 1)
     # the stack is one period of nine layers, so it is walked a layer at a
     # time: a limit that the step's fullest moment with four names just fits
-    four = ("attn_ctx", "attn_res", "attn_qkv", "mamba_in")
+    four = dict.fromkeys(("attn_ctx", "attn_res", "attn_qkv", "mamba_in"), 1)
     chosen = saved_activations(
-        *args, state + model._SAVE_RESERVE + model._fullest_moment(
-            cfg, tokens, state // 3, kept=four).bytes)
+        *args, state + model._SAVE_RESERVE + terms.fullest(four).bytes)
     assert list(chosen) == ["attn_ctx", "attn_res", "attn_qkv", "mamba_in"]
     # the scan's masks are in the working set: H Q values a token, in
     # float32 and the compute dtype
     mixers = tiny("MM", dtype=jnp.bfloat16)
     fewer = dataclasses.replace(mixers, ssd_chunk=8)
     many = 1 << 16  # enough tokens that a block outweighs the head's chunk
-    assert (model._working_set_bytes(mixers, many, 1 << 20)
-            - model._working_set_bytes(fewer, many, 1 << 20)
+    assert (model._terms(mixers, many, 1 << 20).at_once
+            - model._terms(fewer, many, 1 << 20).at_once
             == many * 4 * 8 * (4 * 4 + 2 * 2))
 
 

@@ -153,8 +153,8 @@ def test_remat_with_names_kept_is_the_same_step():
     plain = jax.jit(jax.value_and_grad(
         lambda p: model.transformer_loss(p, batch, CFG)))(params)
     remat = dataclasses.replace(CFG, remat=True)
-    for names in ((), ("attn_ctx", "attn_res"), (("attn_ctx", 1),),
-                  ("attn_ctx", ("attn_res", 3)),
+    for names in ((), ("attn_ctx", "attn_res"), {"attn_ctx": 1},
+                  {"attn_ctx": 4, "attn_res": 3},
                   ("attn_res", "attn_qkv", "mlp_gate", "mlp_up")):
         again = jax.jit(jax.value_and_grad(lambda p: model.transformer_loss(
             p, batch, remat, saved_names=names)))(params)
@@ -203,14 +203,15 @@ def test_the_rule_at_the_cells_own_sizes():
     tokens, limit = 16384, 16909336064
     params = model._whole_param_bytes(cfg)
     assert params == 4 * 612_438_017
-    names = model._saved_bytes(cfg, tokens)
+    terms = model._terms(cfg, tokens, params)
+    names = terms.saved_bytes()
     assert names["attn_ctx"] == 32 * 16384 * (16 * 128 + 32) * 2
     assert names["attn_res"] == 32 * 16384 * 2048 * 2
     stream = 16384 * 2048 * 2
     assert model._boundary_bytes(cfg, tokens) == (33 + 8) * stream
     # the eight layers' weights in bf16, which the compiler casts once
-    assert model._pass_bytes(cfg, params) == 2 * 8 * 51_380_224
-    fullest = model._fullest_moment(cfg, tokens, params)
+    assert terms.loops == 2 * 8 * 51_380_224
+    fullest = terms.fullest()
     assert fullest.name == "layers 0-7"
     total = 3 * params + fullest.bytes
     # on the full side of the compiler's plan for a described v5e (14.83 GB
@@ -219,14 +220,12 @@ def test_the_rule_at_the_cells_own_sizes():
     resident = 3 * params
 
     def kept(limit):
-        return dict(model._kept_passes(cfg, tokens, model.saved_activations(
-            cfg, tokens, resident, params, limit)))
+        return model.saved_activations(cfg, tokens, resident, params, limit)
 
     assert kept(limit) == {"attn_ctx": 1}
-    assert model.saved_activations(cfg, tokens, resident, params, limit) == {
+    assert terms.saved_bytes(kept(limit)) == {
         "attn_ctx": names["attn_ctx"] // 4}
-    with_it = model._fullest_moment(
-        cfg, tokens, params, 1, (("attn_ctx", 1),)).bytes
+    with_it = terms.fullest({"attn_ctx": 1}).bytes
     assert with_it - fullest.bytes == names["attn_ctx"] // 4
     assert resident + with_it + model._SAVE_RESERVE <= limit
     # a chip with half a GB less keeps nothing; with 2 GB more the kernel's

@@ -5,6 +5,7 @@ report, tiny models whose step keeps every name: the same loss, gradients
 and parameters as the step that keeps nothing, and no second product of what
 was kept under `rematted_computation`."""
 
+import collections
 import functools
 import importlib
 import math
@@ -72,21 +73,24 @@ def cell_shapes(cell_name):
 def test_the_rule_on_a_token_cell_s_shapes(cell_name):
     cfg, tokens, resident, params, ways = cell_shapes(cell_name)
     chosen = tr.saved_activations(cfg, tokens, resident, params, LIMIT, ways)
-    every = tr._saved_bytes(tr._on_an_expert_axis(cfg, ways), tokens)
-    # names in the rule's own order, each at the bytes its shape gives
+    terms = tr._terms(cfg, tokens, params, ways)
+    every = terms.saved_bytes()
+    # names in the rule's own order, kept or not (a stack that is run once),
+    # each at the bytes its shape gives
     assert list(chosen) == list(every)[:len(chosen)]
-    assert all(chosen[name] == every[name] for name in chosen)
+    assert set(chosen.values()) <= {1}
+    sizes = terms.saved_bytes(chosen)
+    assert all(sizes[name] == every[name] for name in chosen)
     if chosen:
         assert next(iter(chosen)) == "attn_ctx"
     # the step that keeps them fits: its fullest moment leaves room
-    assert tr._room_bytes(cfg, tokens, resident, params, LIMIT, ways,
-                          tuple(chosen)) >= 0
-    room = tr._room_bytes(cfg, tokens, resident, params, LIMIT, ways)
+    assert terms.room(resident, LIMIT, chosen) >= 0
+    room = terms.room(resident, LIMIT)
     if all(seg.periods > 1 for seg in tr.segments(cfg)):
         # every layer scanned: every term at once, as the rule has counted
         assert room == (LIMIT - resident - params - tr._SAVE_RESERVE
-                        - tr._working_set_bytes(cfg, tokens, params, ways))
-        assert sum(chosen.values()) <= max(room, 0)
+                        - terms.at_once)
+        assert sum(sizes.values()) <= max(room, 0)
     # no limit to read, or no rematerialisation: nothing is kept
     assert tr.saved_activations(
         cfg, tokens, resident, params, None, ways) == {}
@@ -105,13 +109,14 @@ def test_the_choice_grows_with_the_limit(cell_name):
         assert list(chosen)[:len(before)] == list(before)
         before = chosen
     # at 40 GiB: every name
-    assert before == tr._saved_bytes(tr._on_an_expert_axis(cfg, ways), tokens)
+    assert before == dict.fromkeys(
+        tr._terms(cfg, tokens, params, ways).saved_bytes(), 1)
 
 
 @pytest.mark.parametrize("cell_name", sorted(KEPT))
 def test_what_each_token_cell_keeps_at_the_chip_s_limit(cell_name):
     """The names a cell's step logs on the chip, pinned, so that a change
-    to a term of `_working_set_bytes` or of `_moments` shows which cells it
+    to a term of `_terms` or of `_Terms.moments` shows which cells it
     moves. k and v are counted at their own heads since the flash kernels
     read them there (`ops/flash_attention.py`): counted as a scanned stack
     is, every term at once, `lagunaxs2.tokens8k` (64 and 48 query heads
@@ -126,17 +131,20 @@ def test_what_each_token_cell_keeps_at_the_chip_s_limit(cell_name):
     chosen = tr.saved_activations(
         cfg, tokens, resident, params, CHIP_LIMIT, ways)
     assert tuple(chosen) == KEPT[cell_name]
+    terms = tr._terms(cfg, tokens, params, ways)
+    sizes = terms.saved_bytes(chosen)
     at_once = (CHIP_LIMIT - resident - params - tr._SAVE_RESERVE
-               - tr._working_set_bytes(cfg, tokens, params, ways))
-    room = tr._room_bytes(cfg, tokens, resident, params, CHIP_LIMIT, ways)
+               - terms.at_once)
+    room = terms.room(resident, CHIP_LIMIT)
     if cell_name == "lagunaxs2.tokens8k":
-        two = chosen["attn_ctx"] + chosen["attn_res"]
+        two = sizes["attn_ctx"] + sizes["attn_res"]
         assert 20e6 < at_once - two < 30e6
         assert room > at_once + 2e9  # no gradient but its own layer's
     if cell_name == "keyevl2.tokens16k":
         assert room == at_once
-        assert chosen == {"attn_ctx": 1038090240 + 6 * 16384 * 16384 // 8}
-        assert 0.3e9 < room - sum(chosen.values()) < 402653184
+        assert chosen == {"attn_ctx": 1}
+        assert sizes == {"attn_ctx": 1038090240 + 6 * 16384 * 16384 // 8}
+        assert 0.3e9 < room - sum(sizes.values()) < 402653184
 
 
 # `peak_hbm_gb.tokens`, GB of 1e9, with the names of `KEPT` kept: the five
@@ -165,7 +173,7 @@ def test_the_rule_s_sum_stands_at_or_above_the_chip_s_peak(cell_name):
     chosen = tr.saved_activations(
         cfg, tokens, resident, params, CHIP_LIMIT, ways)
     assert tuple(chosen) == KEPT[cell_name]
-    fullest = tr._fullest_moment(cfg, tokens, params, ways, tuple(chosen))
+    fullest = tr._terms(cfg, tokens, params, ways).fullest(chosen)
     predicted = (resident + fullest.bytes) / 1e9
     assert CHIP_PEAK_GB[cell_name] <= predicted <= CHIP_PEAK_GB[cell_name] + 1
     # and under the chip's limit, the runtime's GiB set aside
@@ -182,8 +190,8 @@ def test_scanned_stacks_have_the_room_they_had():
     for cell_name, room in rooms.items():
         cfg, tokens, resident, params, ways = cell_shapes(cell_name)
         assert all(seg.periods > 1 for seg in tr.segments(cfg))
-        assert tr._room_bytes(
-            cfg, tokens, resident, params, CHIP_LIMIT, ways) == room
+        assert tr._terms(cfg, tokens, params, ways).room(
+            resident, CHIP_LIMIT) == room
         moments = tr._moments(cfg, tokens, params, ways)
         assert [m.name for m in moments] == [
             "optimizer", "head", "layers 0-%d" % (cfg.n_layers - 1)]
@@ -192,9 +200,9 @@ def test_scanned_stacks_have_the_room_they_had():
         (1, 1), (1, 5)]
     assert tr.saved_activations(
         cfg, tokens, resident, params, CHIP_LIMIT, ways) == {}
-    assert tr._fullest_moment(cfg, tokens, params, ways).name == "layers 1-5"
-    assert 0 <= tr._room_bytes(
-        cfg, tokens, resident, params, CHIP_LIMIT, ways) < 817889280
+    terms = tr._terms(cfg, tokens, params, ways)
+    assert terms.fullest().name == "layers 1-5"
+    assert 0 <= terms.room(resident, CHIP_LIMIT) < 817889280
 
 
 def test_a_stack_that_is_not_scanned_is_walked_a_layer_at_a_time():
@@ -208,8 +216,9 @@ def test_a_stack_that_is_not_scanned_is_walked_a_layer_at_a_time():
     assert [m.name for m in nothing] == [
         "optimizer", "head", "layer 4", "layer 3", "layer 2", "layer 1",
         "layer 0"]
-    kept = tr._moments(cfg, tokens, params, ways, ("attn_ctx", "attn_qkv"))
-    sizes = tr._saved_bytes(cfg, tokens)
+    kept = tr._moments(cfg, tokens, params, ways,
+                       {"attn_ctx": 1, "attn_qkv": 1})
+    sizes = tr._terms(cfg, tokens, params, ways).saved_bytes()
     both = sizes["attn_ctx"] + sizes["attn_qkv"]
     by_name = {m.name: m.bytes for m in nothing}
     for moment in kept:
@@ -222,22 +231,22 @@ def test_a_stack_that_is_not_scanned_is_walked_a_layer_at_a_time():
             assert 0 < grown < both
     grown = [m.bytes - by_name[m.name] for m in kept if "layer" in m.name]
     assert grown == sorted(grown, reverse=True)
-    at_once = params + tr._working_set_bytes(cfg, tokens, params)
+    at_once = params + tr._terms(cfg, tokens, params).at_once
     assert max(m.bytes for m in nothing) < at_once - 2e9
 
 
 def test_a_share_of_the_experts_has_no_names():
     cfg, tokens, *_ = cell_shapes("lfm2moe.tokens8k")
     assert not any(name.startswith("moe_")
-                   for name in tr._saved_bytes(cfg, tokens))
+                   for name in tr._terms(cfg, tokens).names)
     whole = TransformerConfig(**{**cfg.__dict__, "experts_held": None})
     assert {"moe_slots", "moe_gate", "moe_up"} <= set(
-        tr._saved_bytes(whole, tokens))
+        tr._terms(whole, tokens).names)
 
 
 def test_bytes_follow_the_shapes():
     cfg, tokens, *_ = cell_shapes("mistral7b.tokens4k")
-    sizes = tr._saved_bytes(cfg, tokens)
+    sizes = tr._terms(cfg, tokens).saved_bytes()
     # two layers of 16,384 tokens: o [32 heads of 128] bf16 and one f32 lse
     assert sizes["attn_ctx"] == 2 * 16384 * 32 * (128 * 2 + 4)
     assert sizes["attn_qkv"] == 2 * 16384 * (32 + 2 * 8) * 128 * 2
@@ -303,7 +312,8 @@ def test_a_step_that_keeps_every_name_is_the_same_step(monkeypatch, model):
     cfg, (init_state, step, _) = tiny_step(MODELS[model])
     # the rule did choose, every name this model has
     kept = tr.saved_activations(cfg, 32, 0, 0, 1 << 40)
-    assert set(kept) == set(tr._saved_bytes(cfg, 32)) and len(kept) >= 5
+    assert kept == dict.fromkeys(tr._terms(cfg, 32).names, 1)
+    assert len(kept) >= 5
     state, out = step(init_state(jax.random.PRNGKey(0)), tiny_batch())
     for key in ("loss", "grad_norm"):
         np.testing.assert_allclose(out[key], plain[key], rtol=1e-6)
@@ -314,7 +324,7 @@ def test_a_step_that_keeps_every_name_is_the_same_step(monkeypatch, model):
 def test_gradients_with_kept_names_are_the_gradients(model):
     cfg, (init_state, _, _) = tiny_step(MODELS[model])
     params = init_state(jax.random.PRNGKey(0))["params"]
-    names = tuple(tr._saved_bytes(cfg, 32))
+    names = tuple(tr._terms(cfg, 32).names)
     loss, grads = jax.value_and_grad(tr.transformer_loss)(
         params, tiny_batch(), cfg)
     kept_loss, kept_grads = jax.value_and_grad(tr.transformer_loss)(
@@ -396,6 +406,36 @@ def test_the_choice_is_logged_when_the_step_is_traced(monkeypatch, caplog):
     for name in ("attn_ctx", "attn_qkv", "attn_res", "mlp_gate", "mlp_up"):
         assert name in lines[0]
     assert str(1 << 40) in lines[0]  # the limit it read
+
+
+def test_the_terms_are_computed_once_a_traced_step(monkeypatch):
+    """What does not depend on the choice (`_terms`) is computed once while
+    a step is traced, however many candidates the rule weighs and whatever
+    the step's line reads afterwards."""
+    calls = collections.Counter()
+    for name in ("_exchange_bytes", "_head_bytes", "_boundary_bytes",
+                 "_layer_widths"):
+        def counting(*args, real=getattr(tr, name), name=name):
+            calls[name] += 1
+            return real(*args)
+
+        monkeypatch.setattr(tr, name, counting)
+    weighed = []
+    moments = tr._Terms.moments
+    monkeypatch.setattr(tr._Terms, "moments", lambda self, kept=None: (
+        weighed.append(kept) or moments(self, kept)))
+    keep_everything(monkeypatch)
+    tr._terms.cache_clear()
+    cfg, (init_state, step, _) = tiny_step(MODELS["conv_attention"])
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    step.lower(state, {"tokens": tokens, "targets": tokens})
+    # a candidate a name, then the line's fullest moment and room before
+    names = max(weighed, key=lambda kept: len(kept or ()))
+    assert len(weighed) >= len(names) + 2 and len(names) >= 5
+    assert calls == {"_exchange_bytes": 1, "_head_bytes": 1,
+                     "_boundary_bytes": 1,
+                     "_layer_widths": len(set(cfg.layers))}
 
 
 def test_tokens_on_a_device_follow_the_mesh(monkeypatch):

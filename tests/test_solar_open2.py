@@ -120,12 +120,13 @@ def test_the_rule_prices_the_new_kind():
     order, more room only ever adds names, and kda's names are there."""
     cfg = dataclasses.replace(CFG, remat=True, dtype=jnp.bfloat16)
     tokens, whole = 64, model._whole_param_bytes(cfg)
-    every = model._saved_bytes(cfg, tokens)
+    terms = model._terms(cfg, tokens, whole)
+    every = terms.saved_bytes()
     assert list(every) == ["attn_ctx", "attn_res", "attn_qkv", "kda_res",
                            "kda_qkv", "shared_gate", "shared_up"]
     assert every["kda_res"] == 3 * tokens * 32 * 2
     assert every["kda_qkv"] == 3 * tokens * 3 * 4 * 8 * 2  # the held heads'
-    fullest = model._fullest_moment(cfg, tokens, whole)
+    fullest = terms.fullest()
     assert fullest.bytes > 0 and fullest.name.startswith(("layer", "head"))
     kept = []
     for limit in (1 << 30, (1 << 30) + (4 << 20), 3 << 30):

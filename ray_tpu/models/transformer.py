@@ -208,7 +208,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
-from ray_tpu.ops.kda import SUB as _KDA_SUB, kda
+from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
 from ray_tpu.ops.ssd import scan_untiled, ssd
 from ray_tpu.ops.fused import (
@@ -1056,7 +1056,8 @@ def _kda_mixer(x, blk, cfg: TransformerConfig):
             (g_low @ blk["kda_g2"].astype(dt)).astype(f32)
             + blk["kda_g_bias"].astype(f32)).astype(dt)
     # names its own operations `kda_chunk`, `kda_state` and `kda_out`
-    o, _, log_decay_min = kda(q, k, v, log_decay, beta, chunk=cfg.kda_chunk)
+    o, _, log_decay_min = kda(q, k, v, log_decay, beta, chunk=cfg.kda_chunk,
+                              impl=_kernel_impl(cfg))
     with jax.named_scope("kda_out"):
         o = fused_rmsnorm(o, blk["kda_out_norm"], eps=cfg.norm_eps)
         y = (o.reshape(B, T, H * dk) * gate) @ blk["kda_o"].astype(dt)
@@ -1832,24 +1833,28 @@ class _KDA(Sublayer):
     def holds(self, cfg):
         """In elements of the compute dtype a token, `wide` the held heads'
         width: q, k and v out of the convolution and its silu, q and k at
-        unit length, the gate and the gated output (7 wide); in float32 the
-        log decay, its running sum, the three decayed copies of q and k
-        that `ops/kda.py` makes beside one of the keys a sub-chunk, and
-        each one's cotangent; the pair tensors of a sub-chunk, `SUB` values
-        a channel and token each (every pair's decay and its product with
-        the rows, for k with k and for q with k), with their cotangents; a
-        chunk's entering state (`dk` values a channel and chunk) and the
-        fresh values, float32. Against the chip (`solaropen2.tokens8k`, my
-        chip runs, PR 55): with two pair tensors priced the rule's sum for
-        the seven names it keeps was 14.56 GB where the compiler plans
-        15.36 for a described v5e and the chip peaks at 15.46; with four
-        it is 15.63."""
+        unit length, the gate and the gated output (7 wide), and by the
+        path `kda` takes (`ops/kda.py`) the recurrence's own. The kernels:
+        their result and the cotangents of o, q, k and v (5 wide), the log
+        decay and its cotangent in float32, and a chunk's entering state
+        (`dk` float32 values a channel and chunk); every pair tensor, scaled
+        copy and the solve stay in VMEM. `jax.numpy`: in float32 the log
+        decay, its running sum, the three decayed copies of q and k beside
+        one of the keys a sub-chunk, and each one's cotangent; the pair
+        tensors of a sub-chunk, `SUB` values a channel and token each
+        (every pair's decay and its product with the rows, for k with k
+        and for q with k), with their cotangents; a chunk's entering state
+        and the fresh values. (PERF.md section 6, PR 60, has both against
+        the compiler's plans.)"""
         wide, dk = self.heads(cfg) * cfg.kda_head_dim, cfg.kda_head_dim
         f32 = 4 // _item(cfg) or 1
+        states = f32 * wide * dk // cfg.kda_chunk
+        if _kernel_impl(cfg) == "pallas" and not kda_untiled(
+                cfg.kda_chunk, dk, dk, _item(cfg)):
+            return 12 * wide + 2 * f32 * wide + states
         copies = 2 + 3 + cfg.kda_chunk // _KDA_SUB
         return (7 * wide + 2 * f32 * copies * wide
-                + 4 * f32 * _KDA_SUB * wide
-                + f32 * (wide * dk // cfg.kda_chunk + wide))
+                + 4 * f32 * _KDA_SUB * wide + states + f32 * wide)
 
     def flops(self, cfg, seq_len):
         H, dk, C = self.heads(cfg), cfg.kda_head_dim, cfg.kda_chunk

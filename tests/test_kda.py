@@ -3,7 +3,8 @@ the recurrence taken token by token, outputs, final state and every
 gradient, in float32 on the CPU: at lengths that are and are not multiples
 of the chunk, at decays where `exp(-G)` overflows float32 inside a chunk,
 with beta on both sides of 1; the state carried across chunks and across
-calls; and the mixer's taps not reading past a sequence's start."""
+calls; and the mixer's taps not reading past a sequence's start. The Pallas
+kernels have `tests/test_kda_kernel.py`."""
 
 import jax
 import jax.numpy as jnp

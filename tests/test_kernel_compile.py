@@ -504,6 +504,55 @@ def test_scan_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
         "f32[2,8,8,8192]", "bf16[2,8192,1024]", "bf16[2,8192,1024]"]
 
 
+@pytest.mark.parametrize("kernel", ["kda_fwd", "kda_bwd"])
+def test_kda_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
+    """`solaropen2.tokens8k`: one sequence of 8,192 tokens, the 8 held heads
+    of 128, chunks of 64, bf16 with float32 log decays and beta. The forward
+    alone is one `kda_fwd` that writes o lane-dense, the last state and the
+    decays' reach; differentiated, the forward rule's `kda_fwd` also writes
+    the 128 chunks' entering states in float32 and `kda_bwd` returns dq,
+    dk, dv, dg, dbeta's rows and the entering state's cotangent. No pair
+    tensor (`[.., 16, 16, 128]`), no `[.., 64, 64]` matrix a chunk and no
+    triangular solve is in either program."""
+    import re
+
+    from ray_tpu.ops.kda import kda
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    T, H, d = 8192, 8, 128
+    args = (*[sd((1, T, H, d), jnp.bfloat16)] * 3,
+            sd((1, T, H, d), jnp.float32), sd((1, T, H), jnp.float32))
+
+    def o(*args):
+        return kda(*args, chunk=64, impl="pallas")[0]
+
+    def grads(*args):
+        return jax.grad(lambda *a: o(*a).astype(jnp.float32).sum(),
+                        argnums=range(5))(*args)
+
+    text = jax.jit(o if kernel == "kda_fwd" else grads).lower(
+        *args).compile().as_text()
+    calls = {re.search(r"kda_(fwd|bwd)", name).group(0):
+             re.findall(r"(?:bf16|f32)\[[\d,]+\]", out)
+             for name, out in _custom_calls(text)}
+    assert not re.search(r"f32\[[\d,]*(16,16,128|64,64)\]", text)
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    states = ["f32[1,8,128,128]", "f32[1,4,128,1,128]"]
+    if kernel == "kda_fwd":
+        assert calls == {"kda_fwd": ["bf16[1,8192,1024]", *states]}
+        return
+    assert set(calls) == {"kda_fwd", "kda_bwd"}
+    assert calls["kda_fwd"] == [
+        "bf16[1,8192,1024]", *states, "f32[1,128,8,128,128]"]
+    assert calls["kda_bwd"] == [
+        "bf16[1,8192,1024]", "bf16[1,8192,1024]", "bf16[1,8192,1024]",
+        "f32[1,8192,1024]", "f32[1,4,128,1,128]", "f32[1,8,128,128]"]
+
+
 def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
     """Arithmetic alone, `nemotron3nano.tokens8k` at 2 x 8192 tokens and a
     limit of 15.75 GiB. On the kernels' path the scan's part of a block's

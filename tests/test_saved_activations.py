@@ -27,7 +27,8 @@ FLASH = fa.flash_attention
 LIMIT = int(15.75 * 2**30)  # what a v5e chip offers a program
 CELLS = ("mistral7b.tokens4k", "mistral7b.fsdp4", "olmoe.tokens4k",
          "lfm2moe.tokens8k", "dsv2lite.tokens8k", "nemotron3nano.tokens8k",
-         "lagunaxs2.tokens8k", "keyevl2.tokens16k", "mellum2.ep4")
+         "lagunaxs2.tokens8k", "keyevl2.tokens16k", "mellum2.ep4",
+         "solaropen2.tokens8k")
 # the cells whose routed layers run over an `expert` mesh axis, and its size
 EXPERT_WAYS = {"mellum2.ep4": 4}
 # `bytes_limit` as the chip reports it (PERF.md, "Units"), and the names
@@ -47,6 +48,8 @@ KEPT = {
                            "shared_up", "mlp_gate", "mlp_up"),
     "keyevl2.tokens16k": ("attn_ctx",),
     "mellum2.ep4": ("attn_ctx", "attn_res", "attn_qkv"),
+    "solaropen2.tokens8k": ("attn_ctx", "attn_res", "attn_qkv", "kda_res",
+                            "kda_qkv", "shared_gate", "shared_up"),
 }
 
 

@@ -1,0 +1,131 @@
+"""The step of `solaropen2.tokens8k` as the chip runs it, compiled once at its
+real sizes for a described v5e that is not attached, with the keep rule
+handed the chip's limit: KDA's recurrence is the Pallas kernels `kda_fwd`
+and `kda_bwd` behind a `custom_vjp` (`ops/kda.py`), the solve's custom call
+is gone, and the rule prices the path that runs (`models/transformer.py`
+`_KDA.holds`). Nothing runs, so nothing here is a time or a result. A file
+of its own, so that `--dist loadfile` can place its one compilation; the
+topology is described inside a fixture, never at import."""
+
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import pytest
+
+from chipbench import loop, spec
+from ray_tpu.models import transformer as tr
+
+CELL = "solaropen2.tokens8k"
+CHIP_LIMIT = 16_909_336_064  # a v5e's `bytes_limit`, as its allocator reads
+HBM_BYTES = 15.84e9  # what a v5e chip offers a program (PERF.md, "Units")
+KEPT = ("attn_ctx", "attn_res", "attn_qkv", "kda_res", "kda_qkv",
+        "shared_gate", "shared_up")
+
+
+@pytest.fixture(scope="module")
+def step():
+    """(the compiled step, the names the rule kept for it, the rule's sum
+    for them: the state with the fullest of its moments), the compile cache
+    off around it (an entry compiled for a described device cannot be read
+    back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    cell = spec.load_cell(spec.ROOT, CELL)
+    config, traffic = cell["config"], cell["traffic"]
+    # "auto" asks the platform, which is the CPU here: steered in the test
+    config["attention_impl"] = "pallas"
+    chosen = []
+    rule = tr.saved_activations
+
+    def recording(cfg, tokens, resident, params, limit, ways):
+        kept = rule(cfg, tokens, resident, params, limit, ways)
+        fullest = tr._terms(cfg, tokens, params, ways).fullest(kept)
+        chosen.append((tuple(kept), resident + fullest.bytes, fullest.name))
+        return kept
+
+    with pytest.MonkeyPatch.context() as patch:
+        # a described device reports no limit: the chip's is handed over
+        patch.setattr(tr, "_memory_limit", lambda mesh: CHIP_LIMIT)
+        patch.setattr(tr, "saved_activations", recording)
+        family = spec.load_code(spec.ROOT, "loops", config["family"]).build(
+            config, traffic, list(devices[:1]))
+        key = jax.eval_shape(lambda: loop.seed_key(0))
+        state = jax.eval_shape(
+            family.init_state, jax.eval_shape(family.init_params, key))
+        state = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            state, family.state_shardings)
+        batch = family.batch_shapes(int(traffic["batch_rows"]))
+        compiled = family.step.lower(state, batch).compile()
+    yield compiled, chosen[0]
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("kernel,calls", [("kda_fwd", 6), ("kda_bwd", 3)])
+def test_the_recurrence_is_the_kernels(step, kernel, calls):
+    """Three KDA layers, none scanned: a forward each, the forward made
+    again under remat (the rule keeps the layers' names, not the kernel's
+    residuals) and a backward each."""
+    text = step[0].as_text()
+    assert len(set(re.findall(rf"%{kernel}\.\d+ = ", text))
+               | set(re.findall(rf"%{kernel} = ", text))) == calls
+
+
+def test_the_solve_s_custom_call_is_gone(step):
+    text = step[0].as_text()
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert "triangular-solve" not in text
+
+
+def test_nothing_of_a_pair_tensor_s_shape_reaches_hbm(step):
+    """`[.., SUB, SUB, dk]` float32 a sub-chunk, 537 MB a layer, was four
+    times the parent's plan."""
+    assert not re.search(r"f32\[(\d+,)*16,16,(8,)?128\]", step[0].as_text())
+
+
+def test_the_rule_keeps_every_name(step):
+    """What the step's "train step under remat keeps {...}" line names: as
+    the parent, so the rule's choice is no second source of the gain."""
+    assert step[1][0] == KEPT
+
+
+def test_the_plan_fits_what_a_v5e_offers_a_program(step):
+    """The compiler's plan with the seven names kept: 15.00 GB at its
+    fullest, where the parent's read 15.36 (PERF.md section 6, PR 55)."""
+    memory = step[0].memory_analysis()
+    assert memory.alias_size_in_bytes > 0.9 * memory.output_size_in_bytes
+    assert 14.5e9 < memory.peak_memory_in_bytes <= HBM_BYTES - 0.05e9
+
+
+def test_the_rule_s_sum_beside_the_plan(step):
+    """The rule prices KDA's backward at what the kernels leave in HBM
+    (0.34 GB a layer for the `jax.numpy` form's 2.97), so its fullest
+    moment is no longer a KDA layer's backward but the optimizer's, 13.45
+    GB, and the plan stands 1.55 GB over it: the plan's fullest moment is a
+    routed layer's backward with all four layers' held experts' float32
+    gradient accumulators live (12 buffers of 168 MB), where `_terms`
+    counts one layer's (0.50 GB). The fitted KDA term hid that; it is
+    PERF.md section 7's row on this cell, and `_terms`' to price (S8), not
+    KDA's. Until then the rule is on the empty side here, with nothing left
+    for it to keep."""
+    memory = step[0].memory_analysis()
+    _, rules_sum, moment = step[1]
+    assert moment == "optimizer"
+    assert 13.3e9 < rules_sum < 13.6e9
+    accumulators = 3 * 3 * 4 * 8 * 4096 * 1280  # the other three layers'
+    assert 0.0 < memory.peak_memory_in_bytes - rules_sum < 1.7e9
+    assert -0.1e9 < (memory.peak_memory_in_bytes - rules_sum
+                     - accumulators) < 0.3e9

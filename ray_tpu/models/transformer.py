@@ -123,6 +123,34 @@ the step reads `ut_pass_loss`, `exit_p_mean` and `exit_entropy`. Those are
 Ouro-2.6B's (`ouro`). Not under a `sequence` or an `expert` axis, with
 `heads_held` or over layers that make readings yet (`_refuse_unmapped_loop`).
 
+A layer may read what an earlier layer made. A record states the named
+values its forward emits and reads (`Sublayer.emits`, `reads`); the walk
+carries them beside the stream, from layer to layer inside a period and as
+constants of the later segments' scans (a reader's cotangents are summed
+by the scan, in float32: `_for_readers`), and under `remat` an emitted
+value is held once, an argument of every reader's checkpoint. A reader
+before its emitter, a value emitted twice or read by nobody is a
+`ValueError` from `cfg.layers` (`_check_carried`); `segments` cuts a run
+that carries values and has no period of its own into its repeated
+stretches, so that the layers before the emitters and the readers after
+them are each one scan. Not with `loop_steps` > 1, under a `sequence` axis
+or with `heads_held` (`_refuse_unmapped_loop`, `cfg.layers`). The operators
+that use it: the Mamba-1 mixer (`"mamba1"`; arXiv:2312.00752: a decay a
+channel and state, so no matmul form; the chunked scan of
+`ops/selective_scan.py`), which as `"mamba1_emit"` also emits its scan's
+output as `scan_memory`; differential attention (arXiv:2410.05258:
+`softmax(q1 k1^T) V - lam softmax(q2 k2^T) V` over paired heads, one
+grouped-query call of the flash kernels with values twice a head's width,
+an RMS norm a pair), whole (`"diff_attention"`), under the window
+(`"sliding_diff_attention"`), emitting its keys and values as `attn_kv`
+(`"diff_attention_emit"`) or with a query projection alone over the emitted
+ones (`"cross_diff_attention"`); and the gated memory unit (`"gmu"`:
+`(silu(y W_in) * scan_memory) W_out`). `layer_norm` makes the blocks' norms
+and the final one LayerNorm with a bias, `attn_bias` gives differential
+attention's projections a bias, and `layer_depths` says where each layer
+stood in the stack it was cut from (`lam0 = 0.8 - 0.6 exp(-0.3 depth)`).
+Those are SambaY's (arXiv:2507.06607; the benchmark's family `phi4flash`).
+
 Under a mesh with an `expert` axis (`make_mesh({"expert": n})`) a routed
 stack is expert-parallel, nothing of a layer left out: a device holds
 `n_experts / n` whole experts of every layer (the experts' leaves cut on
@@ -187,8 +215,8 @@ Capability analog of what the reference reaches only through integrations
 `mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k`,
 `lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`,
 `lagunaxs2.tokens8k`, `keyevl2.tokens16k`, `solaropen2.tokens8k`,
-`ouro.tokens16k`, and `mellum2.ep4` on the four chips of an `expert` axis
-(BENCHMARK.json).
+`ouro.tokens16k`, `phi4flash.tokens16k`, and `mellum2.ep4` on the four
+chips of an `expert` axis (BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -210,6 +238,7 @@ from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
+from ray_tpu.ops.selective_scan import selective_scan
 from ray_tpu.ops.ssd import scan_untiled, ssd
 from ray_tpu.ops.fused import (
     HEAD_CHUNK,
@@ -256,7 +285,9 @@ class TransformerConfig:
     router_z_loss_coef: float = 0.001  # logsumexp(router logits)^2
     # an operator of `_OPERATORS` per layer ("full_attention" |
     # "sliding_attention" | "sparse_attention" | "conv" |
-    # "latent_attention" | "kda"); () => attention everywhere
+    # "latent_attention" | "mamba2" | "kda" | "mamba1" | "mamba1_emit" |
+    # "diff_attention" | "sliding_diff_attention" | "diff_attention_emit" |
+    # "cross_diff_attention" | "gmu"); () => attention everywhere
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3  # the short convolution's reach, this token included
     n_dense_layers: int = 0  # with n_experts: leading layers with a dense FF
@@ -354,6 +385,26 @@ class TransformerConfig:
     # `exit_entropy_coef` times that distribution's entropy
     exit_gate: bool = False
     exit_entropy_coef: float = 0.0
+    # the blocks' norms and the final one are LayerNorm with a scale and a
+    # bias (`<norm>_bias` beside every scale), for the records that take it
+    # (`takes_layer_norm`); False => RMSNorm
+    layer_norm: bool = False
+    # a bias on differential attention's q, k, v and output projections
+    attn_bias: bool = False
+    # the Mamba-1 mixer (arXiv:2312.00752; "mamba1"): `mamba1_inner`
+    # channels (the expansion of `d_model`), a state of `mamba1_state` a
+    # channel, `mamba1_dt_rank` columns for the step size (None =>
+    # ceil(d_model / 16)), `mamba1_conv_taps` taps, the tokens a chunk of
+    # the scan (`ops/selective_scan.py`); dt starts as `mamba_dt_init` says
+    mamba1_inner: int = 0
+    mamba1_state: int = 16
+    mamba1_dt_rank: Optional[int] = None
+    mamba1_conv_taps: int = 4
+    scan_chunk: int = 128
+    # the depth each layer stands at in the stack it was cut from, for the
+    # constants that depend on it (differential attention's `lam0`); () =>
+    # its index in this stack
+    layer_depths: Tuple[int, ...] = ()
 
     @property
     def kv_heads(self) -> int:
@@ -386,6 +437,20 @@ class TransformerConfig:
     @property
     def mamba_inner(self) -> int:
         return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def dt_rank(self) -> int:
+        """The columns Mamba-1's step size is projected through."""
+        return self.mamba1_dt_rank or -(-self.d_model // 16)
+
+    @property
+    def depths(self) -> Tuple[int, ...]:
+        depths = tuple(self.layer_depths) or tuple(range(self.n_layers))
+        if len(depths) != self.n_layers:
+            raise ValueError(
+                f"layer_depths names {len(depths)} layers, n_layers is "
+                f"{self.n_layers}")
+        return depths
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -446,6 +511,14 @@ class TransformerConfig:
                             f"post_norm with a {type(sub).__name__[1:]} "
                             "sublayer, which has no norm on its output: "
                             "plain attention and the dense feed-forward do")
+        if self.layer_norm:
+            for kind in set(kinds):
+                for sub in _sublayers(kind):
+                    if not sub.takes_layer_norm:
+                        raise ValueError(
+                            f"layer_norm with a {type(sub).__name__[1:]} "
+                            "sublayer, whose norm is RMSNorm alone")
+        _check_carried(kinds)
         return kinds
 
     @property
@@ -471,7 +544,11 @@ def segments(cfg: TransformerConfig) -> List[Segment]:
     """The stack as runs of whole periods: it is cut where the feed-forward
     changes kind (a layer that is an operator alone stays in the run it
     stands in), and each run is as many repetitions of its shortest period
-    as make it up (one repetition of all of it, if it has none shorter)."""
+    as make it up (one repetition of all of it, if it has none shorter). A
+    run in which a layer reads what another made cannot repeat as a whole
+    (the emitter stands in it once): where it has no period of its own it
+    is cut into its repeated stretches (`_stretches`), so that the layers
+    before the emitters and the readers after them are each scanned."""
     kinds = cfg.layers
     runs, start, routed = [], 0, None
     for i, kind in enumerate(kinds):
@@ -486,8 +563,69 @@ def segments(cfg: TransformerConfig) -> List[Segment]:
         n = len(run)
         period = next(p for p in range(1, n + 1) if n % p == 0 and all(
             run[i] == run[i % p] for i in range(n)))
-        out.append(Segment(run[:period], n // period))
+        carries = any(sub.emits or sub.reads
+                      for kind in run for sub in _sublayers(kind))
+        if period < n or not carries:
+            out.append(Segment(run[:period], n // period))
+        else:
+            out.extend(_stretches(run))
     return out
+
+
+def _stretches(run: Tuple[LayerKind, ...]) -> List[Segment]:
+    """A run with no period of its own as stretches, first to last: where a
+    stretch of layers is repeated at least twice on end, the longest such
+    (of equals the shortest period) is a segment of that many periods; the
+    layers between two such are a segment of one period."""
+    out, loose, i, n = [], [], 0, len(run)
+    while i < n:
+        best = (0, 0)  # (layers covered, -period)
+        for p in range(1, (n - i) // 2 + 1):
+            k = 1
+            while run[i + k * p:i + (k + 1) * p] == run[i:i + p]:
+                k += 1
+            if k > 1:
+                best = max(best, (k * p, -p))
+        covered, p = best[0], -best[1]
+        if not covered:
+            loose.append(run[i])
+            i += 1
+            continue
+        if loose:
+            out.append(Segment(tuple(loose), 1))
+            loose = []
+        out.append(Segment(tuple(run[i:i + p]), covered // p))
+        i += covered
+    if loose:
+        out.append(Segment(tuple(loose), 1))
+    return out
+
+
+def _check_carried(kinds: Tuple[LayerKind, ...]) -> None:
+    """What the layers emit and read (`Sublayer.emits`, `reads`) must make
+    a stack that can be walked: a value is emitted once, before every layer
+    that reads it, and some layer reads it."""
+    emitted: Dict[str, int] = {}
+    read = set()
+    for i, kind in enumerate(kinds):
+        for sub in _sublayers(kind):
+            for name in sub.reads:
+                if name not in emitted:
+                    raise ValueError(
+                        f"layer {i} ({kind.op}) reads {name!r}, which no "
+                        "layer before it emits")
+                read.add(name)
+            for name in sub.emits:
+                if name in emitted:
+                    raise ValueError(
+                        f"layer {i} ({kind.op}) emits {name!r}, which layer "
+                        f"{emitted[name]} emits already")
+                emitted[name] = i
+    unread = sorted(set(emitted) - read)
+    if unread:
+        raise ValueError(
+            f"layer {emitted[unread[0]]} emits {unread[0]!r}, which no "
+            "layer after it reads")
 
 
 # ---------------------------------------------- the sublayers' forwards
@@ -692,6 +830,27 @@ def _layer_norm(x, scale, bias, eps: float):
     normed = centred * jax.lax.rsqrt(
         jnp.mean(centred * centred, axis=-1, keepdims=True) + eps)
     return (normed * scale + bias).astype(x.dtype)
+
+
+def _block_norm(x, blk, name: str, cfg: "TransformerConfig"):
+    """A sublayer's norm of its input under the scale `blk[name]`: RMSNorm,
+    or with `layer_norm` LayerNorm with the bias `blk[name + "_bias"]`."""
+    if cfg.layer_norm:
+        return _layer_norm(x, blk[name], blk[name + "_bias"], cfg.norm_eps)
+    return fused_rmsnorm(x, blk[name], eps=cfg.norm_eps)
+
+
+def _norm_leaves(name: str, cfg: "TransformerConfig", L: int):
+    """`L` layers' leaves of the norm `name` (`_block_norm`)."""
+    leaves = {name: jnp.ones((L, cfg.d_model), jnp.float32)}
+    if cfg.layer_norm:
+        leaves[name + "_bias"] = jnp.zeros((L, cfg.d_model), jnp.float32)
+    return leaves
+
+
+def _norm_axes(name: str, cfg: "TransformerConfig"):
+    names = (name, name + "_bias") if cfg.layer_norm else (name,)
+    return dict.fromkeys(names, ("layers", None))
 
 
 def _sparse_attention_layer(x, blk, positions, cfg: TransformerConfig,
@@ -1010,6 +1169,138 @@ def _mamba_mixer(x, blk, cfg: TransformerConfig):
         return y @ blk["w_out"].astype(dt_)
 
 
+def _mamba1_mixer(x, blk, cfg: TransformerConfig):
+    """(the Mamba-1 mixer on `x` [B, T, d], the memory an emitting layer
+    hands on: the scan's output `s` [B, T, inner] in the compute dtype)
+    (arXiv:2312.00752): `[u | z] = norm(x) W_in`; a causal convolution of `mamba1_conv_taps` taps a channel over
+    `u` with a bias, then silu; `[r | B | C] = u W_x`, `r` of `dt_rank`
+    columns, `B` and `C` of `mamba1_state`; `dt = softplus(r W_dt +
+    dt_bias)` and `A = -exp(A_log)` a channel and state, in float32; the
+    scan (`ops/selective_scan.py`), whose output `s` holds the skip `D u`;
+    `(s * silu(z)) W_out`."""
+    N, R = cfg.mamba1_state, cfg.dt_rank
+    dt_, f32 = cfg.dtype, jnp.float32
+    with jax.named_scope("mamba1_in"):
+        y = _block_norm(x, blk, "mixer_norm", cfg)
+        u, z = jnp.split(
+            checkpoint_name(y @ blk["w_in"].astype(dt_), "mamba1_in"), 2,
+            axis=-1)
+    with jax.named_scope("mamba1_conv"):
+        u = jax.nn.silu(_causal_taps(u, blk["conv_w"].astype(dt_))
+                        + blk["conv_b"].astype(dt_))
+        r, b, c = jnp.split(u @ blk["w_x"].astype(dt_), (R, R + N), axis=-1)
+        step = jax.nn.softplus((r @ blk["w_dt"].astype(dt_)).astype(f32)
+                               + blk["dt_bias"].astype(f32))
+    # names its own operations `selective_scan`, backward too
+    s, _ = selective_scan(u, step, -jnp.exp(blk["A_log"].astype(f32)), b, c,
+                          blk["D"], chunk=cfg.scan_chunk)
+    s = checkpoint_name(s.astype(dt_), "scan_out")
+    with jax.named_scope("mamba1_out"):
+        gated = s * jax.nn.silu(z)
+        return gated @ blk["w_out"].astype(dt_), _scan_memory(s, gated)
+
+
+def _scan_memory(s, gated):
+    """What an emitting Mamba-1 layer hands the gated memory units: the
+    scan's output `s`, not the one the gate `z` has multiplied. Under a
+    name of its own: a test hands on the other to show what the comparison
+    reads then."""
+    return s
+
+
+def diff_lambda_init(depth):
+    """Differential attention's `lam0` at a layer's depth in its stack
+    (arXiv:2410.05258): 0.8 - 0.6 exp(-0.3 depth)."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * depth)
+
+
+def _diff_lambda(blk, depth):
+    """(lam, lam0) of a differential layer, float32 scalars: `lam =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lam0`. Under a name of its own: a
+    test takes the learned part away to show what the comparison reads
+    then."""
+    f32 = jnp.float32
+    lam0 = diff_lambda_init(depth.astype(f32))
+    dots = [jnp.sum(blk["lam_q" + i].astype(f32) * blk["lam_k" + i].astype(f32))
+            for i in "12"]
+    return jnp.exp(dots[0]) - jnp.exp(dots[1]) + lam0, lam0
+
+
+def _pair_heads(x):
+    """The heads of `x` [B, T, H, w] with the even ones first, then the odd
+    ones: a differential pair's first and second softmax read head 2 i and
+    head 2 i + 1, and the kernel takes the two as the halves of one
+    grouped-query problem."""
+    return jnp.concatenate([x[:, :, 0::2], x[:, :, 1::2]], axis=2)
+
+
+def _diff_attention_layer(x, blk, cfg: TransformerConfig, site: "_Site", *,
+                          op: "_DiffAttention"):
+    """(x + differential attention(norm(x)), {diff_lambda, and where the
+    record emits, attn_kv}) (arXiv:2410.05258, as SambaY holds it,
+    arXiv:2507.06607). `n_heads` query heads and `n_kv_heads` key and value
+    heads `head_dim` wide, each projection with a bias under `attn_bias`,
+    no rotation. Heads pair, even with odd: pair i has `q1 = q_(2i)`, `q2 =
+    q_(2i+1)` and reads the key pair j = i // (pairs a key pair), `k1 =
+    k_(2j)`, `k2 = k_(2j+1)`, `V = [v_(2j) | v_(2j+1)]`, twice `head_dim`
+    wide. `a1 = softmax(q1 k1^T / sqrt(head_dim)) V`, `a2` likewise, causal
+    and under the record's window: the two as ONE call of the attention
+    kernel, the first softmaxes' heads and then the second's over keys laid
+    out the same way and V twice. `o = (1 - lam0) RMSNorm(a1 - lam a2)` over
+    the pair's width with one learned scale a layer; `W_o`. A cross layer
+    has `W_q` and `W_o` alone and reads the keys and values another layer
+    emitted (`site.shared["attn_kv"]`: the keys paired already, V once)."""
+    B, T, d = x.shape
+    h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    dt = cfg.dtype
+
+    def projected(y, name):
+        out = checkpoint_name(y @ blk["w" + name].astype(dt), "attn_qkv")
+        if "b" + name in blk:
+            out = out + blk["b" + name].astype(dt)
+        return out
+
+    with jax.named_scope("attn_qkv"):
+        y = _block_norm(x, blk, "attn_norm", cfg)
+        q = _pair_heads(projected(y, "q").reshape(B, T, h, dh))
+        if op.cross:
+            k, v = (a.astype(dt) for a in site.shared["attn_kv"])
+        else:
+            k = _pair_heads(projected(y, "k").reshape(B, T, hk, dh))
+            v = projected(y, "v").reshape(B, T, hk // 2, 2 * dh)
+    scope = (jax.named_scope("cross_attention") if op.cross
+             else contextlib.nullcontext())
+    with scope, jax.named_scope("attention"):
+        o = _attention(q, k, jnp.concatenate([v, v], axis=2), cfg, None, 1,
+                       site.mesh, site.keep_ctx, window=op.window(cfg))
+    with jax.named_scope("diff_norm"):
+        lam, lam0 = _diff_lambda(blk, site.depth)
+        f32 = jnp.float32
+        apart = o[:, :, :h // 2].astype(f32) - lam * o[:, :, h // 2:].astype(f32)
+        o = ((1.0 - lam0) * fused_rmsnorm(
+            apart, blk["diff_norm"], eps=cfg.norm_eps)).astype(dt)
+    with jax.named_scope("attn_out"):
+        out = o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
+        if "bo" in blk:
+            out = out + blk["bo"].astype(dt)
+        x = checkpoint_name(x + out, "attn_res")
+    made = {"diff_lambda": lam}
+    if op.emits:  # the keys paired already, V once
+        made["attn_kv"] = (k, v)
+    return x, made
+
+
+def _gmu(x, blk, cfg: TransformerConfig, memory):
+    """The gated memory unit on `x` [B, T, d] (arXiv:2507.06607): `(silu(
+    norm(x) W_in) * m) W_out`, `m` [B, T, inner] the memory another layer
+    emitted."""
+    dt = cfg.dtype
+    y = _block_norm(x, blk, "gmu_norm", cfg)
+    gate = jax.nn.silu(
+        checkpoint_name(y @ blk["gmu_in"].astype(dt), "gmu_in"))
+    return (gate * memory.astype(dt)) @ blk["gmu_out"].astype(dt)
+
+
 def _unit_length(x, eps: float = 1e-6):
     """Every head of `x` [..., dk] over its own length, in float32."""
     x32 = x.astype(jnp.float32)
@@ -1091,8 +1382,10 @@ def keys_per_query(seq_len: int, window: Optional[int] = None) -> float:
 
 
 def _scan_bytes_per_token(cfg: TransformerConfig) -> int:
-    """Bytes a token that a mixer's scan holds in HBM in its backward, by
-    the path `ssd` takes (`ops/ssd.py`). `jax.numpy`: the [H, Q, Q] arrays
+    """Bytes a token that the Mamba-2 mixer's scan holds in HBM in its
+    backward, by the path `ssd` takes (`ops/ssd.py`); the Mamba-1 mixer's
+    (`ops/selective_scan.py`: a chunk's steps made again, the chunks'
+    entering states) is in `_Mamba1.holds`. `jax.numpy`: the [H, Q, Q] arrays
     of a chunk, Q values a token and head: the decays and their gradient in
     float32, the masked scores and theirs in the compute dtype and in
     float32. The kernels keep those in VMEM. What they leave is each
@@ -1115,6 +1408,15 @@ def _dense(key, shape, fan_in):
     return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
 
 
+def _dt_bias(key, shape, cfg: "TransformerConfig"):
+    """A step size's bias as the mixers draw it: `softplus(bias)` is
+    log-uniform in `mamba_dt_init`'s range and no less than its floor."""
+    dt_min, dt_max, dt_floor = cfg.mamba_dt_init
+    dt = jnp.maximum(dt_floor, jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, math.log(dt_min), math.log(dt_max))))
+    return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
+
+
 def _tile_lanes(width: int) -> int:
     """`width` as HBM tiles a minor dimension: whole tiles of 128 lanes."""
     return -(-width // 128) * 128
@@ -1134,6 +1436,13 @@ class _Site(NamedTuple):
     mesh: Any = None
     # the attention kernel names its backward's residuals `attn_ctx`
     keep_ctx: bool = False
+    # {name: value} of what the sublayers `reads`, as earlier layers emitted
+    # it (float32 where several scanned layers read one value: their
+    # cotangents are summed in it)
+    shared: Any = None
+    # the layer's depth in the stack it was cut from, a float32 scalar, for
+    # a record that `reads_depth`
+    depth: Any = None
 
 
 class Reading(NamedTuple):
@@ -1201,6 +1510,20 @@ class Sublayer:
     takes_heads_held: bool = False
     # a sublayer that `post_norm` gives a norm on its output
     takes_post_norm: bool = False
+    # a sublayer whose norm `layer_norm` makes a LayerNorm with a bias
+    takes_layer_norm: bool = False
+    # the named values `forward` makes for later layers (in what it returns
+    # beside the stream, under these names) and those it takes of earlier
+    # ones (`site.shared`); `carried` has an emitted value's width
+    emits: Tuple[str, ...] = ()
+    reads: Tuple[str, ...] = ()
+    # `forward` takes the layer's depth (`site.depth`)
+    reads_depth: bool = False
+
+    def carried(self, cfg: TransformerConfig) -> Dict[str, int]:
+        """{name: width} of what a layer `emits`, in elements of the
+        compute dtype a token."""
+        return {}
 
     def init(self, key, cfg: TransformerConfig, L: int) -> Dict[str, Any]:
         """`L` stacked layers' leaves, float32, from the layer's key. The
@@ -1660,9 +1983,6 @@ class _Mamba2(Sublayer):
         taps = cfg.mamba_conv_taps
         k_in, k_conv, k_a, k_dt, k_out = jax.random.split(
             jax.random.split(key, 7)[0], 5)
-        dt_min, dt_max, dt_floor = cfg.mamba_dt_init
-        dt = jnp.maximum(dt_floor, jnp.exp(jax.random.uniform(
-            k_dt, (L, H), jnp.float32, math.log(dt_min), math.log(dt_max))))
         w_out = _dense(k_out, (L, inner, d), inner)
         if cfg.rescale_prenorm_residual:
             w_out = w_out / math.sqrt(cfg.n_layers)
@@ -1672,7 +1992,7 @@ class _Mamba2(Sublayer):
             "w_in": _dense(k_in, (L, d, inner + conv + H), d),
             "conv_w": _dense(k_conv, (L, taps, conv), taps),
             "conv_b": jnp.zeros((L, conv), jnp.float32),
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus's inverse
+            "dt_bias": _dt_bias(k_dt, (L, H), cfg),
             "A_log": jnp.log(
                 jax.random.uniform(k_a, (L, H), jnp.float32, 1.0, 16.0)),
             "D": jnp.ones((L, H), jnp.float32),
@@ -1773,10 +2093,6 @@ class _KDA(Sublayer):
                              f"{cfg.kda_heads} and {dk}")
         wide, taps = H * dk, cfg.kda_conv_taps
         ks = jax.random.split(jax.random.split(key, 7)[0], 12)
-        dt_min, dt_max, dt_floor = cfg.mamba_dt_init
-        dt = jnp.maximum(dt_floor, jnp.exp(jax.random.uniform(
-            ks[10], (L, wide), jnp.float32,
-            math.log(dt_min), math.log(dt_max))))
         return {
             "kda_norm": jnp.ones((L, d), jnp.float32),
             "kda_q": _dense(ks[0], (L, d, wide), d),
@@ -1791,7 +2107,7 @@ class _KDA(Sublayer):
             "kda_b": _dense(ks[8], (L, d, H), d),
             "kda_A_log": jnp.log(jax.random.uniform(
                 ks[9], (L, H), jnp.float32, 1.0, 16.0)),
-            "kda_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus's inverse
+            "kda_dt_bias": _dt_bias(ks[10], (L, wide), cfg),
             "kda_out_norm": jnp.ones((L, dk), jnp.float32),
             "kda_o": _dense(ks[11], (L, wide, d), cfg.kda_heads * dk),
         }
@@ -1866,6 +2182,266 @@ class _KDA(Sublayer):
         return 2 * self.params(cfg) + chunked, 0
 
 
+class _Mamba1(Sublayer):
+    """The Mamba-1 mixer (`_mamba1_mixer`); as `mamba1_emit` it also emits
+    the scan's output, before the gate, as `scan_memory`."""
+
+    matmuls = ("w_in", "w_x", "w_dt", "w_out")
+    names = ("mamba1_in", "scan_out")
+    no_sequence_axis = "the Mamba-1 mixer is not mapped over a sequence axis"
+    takes_layer_norm = True
+
+    def __init__(self, emits: bool):
+        self.emits = ("scan_memory",) if emits else ()
+
+    def carried(self, cfg):
+        return {name: cfg.mamba1_inner for name in self.emits}
+
+    def init(self, key, cfg, L):
+        """`A = -exp(A_log)` starts at -1 .. -`mamba1_state` along a
+        channel's states, `softplus(dt_bias)` log-uniform in
+        `mamba_dt_init`'s range and no less than its floor, `w_dt` uniform
+        within `dt_rank ** -0.5`, the skip `D` at 1 (the published
+        initialiser's)."""
+        d, inner, N, R = (cfg.d_model, cfg.mamba1_inner, cfg.mamba1_state,
+                          cfg.dt_rank)
+        if not inner:
+            raise ValueError("mamba1 needs mamba1_inner, not 0")
+        taps = cfg.mamba1_conv_taps
+        k_in, k_conv, k_x, k_dtw, k_dt, k_out = jax.random.split(
+            jax.random.split(key, 7)[0], 6)
+        w_out = _dense(k_out, (L, inner, d), inner)
+        if cfg.rescale_prenorm_residual:
+            w_out = w_out / math.sqrt(cfg.n_layers)
+        return {
+            **_norm_leaves("mixer_norm", cfg, L),
+            "w_in": _dense(k_in, (L, d, 2 * inner), d),  # u, then the gate z
+            "conv_w": _dense(k_conv, (L, taps, inner), taps),
+            "conv_b": jnp.zeros((L, inner), jnp.float32),
+            "w_x": _dense(k_x, (L, inner, R + 2 * N), inner),  # r, B, C
+            "w_dt": jax.random.uniform(
+                k_dtw, (L, R, inner), jnp.float32, -R ** -0.5, R ** -0.5),
+            "dt_bias": _dt_bias(k_dt, (L, inner), cfg),
+            "A_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)),
+                (L, inner, N)),
+            "D": jnp.ones((L, inner), jnp.float32),
+            "w_out": w_out,
+        }
+
+    def axes(self, cfg):
+        # cut along the channels where a leaf is the channels'; `w_in`'s
+        # columns are two streams that are split after the product
+        return {
+            **_norm_axes("mixer_norm", cfg),
+            "w_in": ("layers", "embed", None),
+            "conv_w": ("layers", None, "heads"),
+            "conv_b": ("layers", "heads"),
+            "w_x": ("layers", "heads", None),
+            "w_dt": ("layers", None, "heads"),
+            "dt_bias": ("layers", "heads"),
+            "A_log": ("layers", "heads", None),
+            "D": ("layers", "heads"),
+            "w_out": ("layers", "heads", "embed"),
+        }
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("mamba1"):
+            y, s = _mamba1_mixer(x, blk, cfg)
+            return x + y, {"scan_memory": s} if self.emits else None
+
+    def widths(self, cfg):
+        inner = cfg.mamba1_inner
+        # the scan's output and its chunks' entering states, float32
+        states = (4 // _item(cfg) or 1) * cfg.mamba1_state * inner
+        return {"mamba1_in": 2 * inner,
+                "scan_out": inner + states // cfg.scan_chunk}
+
+    def params(self, cfg):
+        inner, R = cfg.mamba1_inner, cfg.dt_rank
+        return (cfg.d_model * 3 * inner
+                + inner * (2 * R + 2 * cfg.mamba1_state))
+
+    def holds(self, cfg):
+        """In elements of the compute dtype a token: the convolution's sum,
+        its silu and that one's cotangent, the gated output and the gate's
+        cotangent (5 inner), `w_x`'s product and its cotangent; in float32
+        the step size before and after its softplus, the scan's output and
+        the two cotangents the scan returns at that width (5 inner); a
+        chunk's entering state; and, spread over a sequence's tokens, the
+        steps of the one chunk the backward makes again (the decay, the
+        state before and after: three `[chunk, N, inner]` float32)."""
+        inner, N = cfg.mamba1_inner, cfg.mamba1_state
+        f32 = 4 // _item(cfg) or 1
+        chunk = 3 * cfg.scan_chunk * N * inner
+        return (5 * inner + 2 * (cfg.dt_rank + 2 * N) + 5 * f32 * inner
+                + f32 * (N * inner // cfg.scan_chunk
+                         + chunk // cfg.max_seq_len))
+
+
+class _DiffAttention(Sublayer):
+    """Differential attention (`_diff_attention_layer`): four records over
+    one forward. `diff_attention` over the whole causal context and
+    `sliding_diff_attention` under `sliding_window`; `diff_attention_emit`,
+    the whole one that also emits its keys and values as `attn_kv`; and
+    `cross_diff_attention`, which has `W_q` and `W_o` alone and reads
+    `attn_kv`."""
+
+    matmuls = ("wq", "wk", "wv", "wo")
+    names = ("attn_ctx", "attn_res", "attn_qkv")
+    # a layer's `lam`, [L]
+    readings = (Reading("diff_lambda"),)
+    no_sequence_axis = (
+        "differential attention is not mapped over a sequence axis")
+    takes_layer_norm = True
+    reads_depth = True
+
+    def __init__(self, sliding: bool = False, emits: bool = False,
+                 cross: bool = False):
+        self.sliding, self.cross = sliding, cross
+        self.emits = ("attn_kv",) if emits else ()
+        self.reads = ("attn_kv",) if cross else ()
+        if cross:
+            self.matmuls = ("wq", "wo")
+
+    def heads(self, cfg) -> int:
+        return cfg.n_heads
+
+    def window(self, cfg) -> Optional[int]:
+        return (cfg.sliding_window or None) if self.sliding else None
+
+    def carried(self, cfg):
+        return {name: 2 * cfg.kv_heads * cfg.head_dim for name in self.emits}
+
+    def _kv(self, cfg) -> int:
+        """The key and value heads the layer projects: none of a cross
+        layer's."""
+        return 0 if self.cross else cfg.kv_heads
+
+    def init(self, key, cfg, L):
+        d, dh, h, hk = cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.kv_heads
+        if h % 2 or hk % 2 or (h // 2) % (hk // 2):
+            raise ValueError(
+                f"differential attention pairs {h} query heads over {hk} key "
+                "heads: both even, and whole pairs of queries a pair of keys")
+        ks = jax.random.split(jax.random.split(key, 7)[0], 8)
+        leaves = {
+            **_norm_leaves("attn_norm", cfg, L),
+            "wq": _dense(ks[0], (L, d, h * dh), d),
+            "wo": _dense(ks[3], (L, h * dh, d), h * dh),
+            **{"lam_" + name: 0.1 * jax.random.normal(
+                ks[4 + i], (L, dh), jnp.float32)
+               for i, name in enumerate(("q1", "k1", "q2", "k2"))},
+            "diff_norm": jnp.ones((L, 2 * dh), jnp.float32),
+        }
+        if not self.cross:
+            leaves["wk"] = _dense(ks[1], (L, d, hk * dh), d)
+            leaves["wv"] = _dense(ks[2], (L, d, hk * dh), d)
+        if cfg.attn_bias:
+            for name in self._biases():
+                leaves[name] = jnp.zeros(
+                    (L, leaves["w" + name[1:]].shape[-1]), jnp.float32)
+        return leaves
+
+    def _biases(self) -> Tuple[str, ...]:
+        return ("bq", "bo") if self.cross else ("bq", "bk", "bv", "bo")
+
+    def axes(self, cfg):
+        table = {
+            **_norm_axes("attn_norm", cfg),
+            "wq": ("layers", "embed", "heads"),
+            "wk": ("layers", "embed", "kv"),
+            "wv": ("layers", "embed", "kv"),
+            "wo": ("layers", "heads", "embed"),
+            **dict.fromkeys(("lam_q1", "lam_k1", "lam_q2", "lam_k2",
+                             "diff_norm"), ("layers", None)),
+        }
+        if cfg.attn_bias:
+            table.update(bq=("layers", "heads"), bk=("layers", "kv"),
+                         bv=("layers", "kv"), bo=("layers", None))
+        if self.cross:
+            for name in ("wk", "wv", "bk", "bv"):
+                table.pop(name, None)
+        return table
+
+    def forward(self, x, blk, cfg, site):
+        scope = (jax.named_scope("sliding_attention") if self.sliding
+                 else contextlib.nullcontext())
+        with scope, jax.named_scope("diff_attention"):
+            return _diff_attention_layer(x, blk, cfg, site, op=self)
+
+    def widths(self, cfg):
+        h, dh = cfg.n_heads, cfg.head_dim
+        return {
+            # o at the pair's width a head, and lse as one float32 column
+            "attn_ctx": h * _tile_lanes(2 * dh) + h * 4 // _item(cfg),
+            "attn_res": cfg.d_model,
+            "attn_qkv": (h + 2 * self._kv(cfg)) * dh,
+        }
+
+    def params(self, cfg):
+        d, dh, h = cfg.d_model, cfg.head_dim, cfg.n_heads
+        return d * (h + 2 * self._kv(cfg)) * dh + h * dh * d
+
+    def holds(self, cfg):
+        """q and the paired k as the kernel takes them, V twice at the
+        pair's width; lse and delta at a tile's 128 lanes; the kernel's o,
+        the difference in float32 and the normed pairs."""
+        h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        f32 = 4 // _item(cfg) or 1
+        return ((h + hk) * _tile_lanes(dh) + hk * _tile_lanes(2 * dh)
+                + 2 * h * 128 * f32 + h * 2 * dh + (f32 + 1) * h * dh)
+
+    def flops(self, cfg, seq_len):
+        # a pair of softmaxes is two maps: every query head's q k^T over
+        # `head_dim` and p V over the pair's width, over the pairs the
+        # causal mask (and the window's band) leaves
+        h, dh = cfg.n_heads, cfg.head_dim
+        return 2 * self.params(cfg), (
+            2 * h * (dh + 2 * dh) * keys_per_query(seq_len, self.window(cfg)))
+
+
+class _GMU(Sublayer):
+    """The gated memory unit (`_gmu`): reads `scan_memory`."""
+
+    matmuls = ("gmu_in", "gmu_out")
+    names = ("gmu_in",)
+    reads = ("scan_memory",)
+    no_sequence_axis = (
+        "the gated memory unit is not mapped over a sequence axis")
+    takes_layer_norm = True
+
+    def init(self, key, cfg, L):
+        d, inner = cfg.d_model, cfg.mamba1_inner
+        k_in, k_out = jax.random.split(jax.random.split(key, 7)[0])
+        return {
+            **_norm_leaves("gmu_norm", cfg, L),
+            "gmu_in": _dense(k_in, (L, d, inner), d),
+            "gmu_out": _dense(k_out, (L, inner, d), inner),
+        }
+
+    def axes(self, cfg):
+        return {
+            **_norm_axes("gmu_norm", cfg),
+            "gmu_in": ("layers", "embed", "heads"),
+            "gmu_out": ("layers", "heads", "embed"),
+        }
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("gmu"):
+            return x + _gmu(x, blk, cfg, site.shared["scan_memory"]), None
+
+    def widths(self, cfg):
+        return {"gmu_in": cfg.mamba1_inner}
+
+    def params(self, cfg):
+        return 2 * cfg.d_model * cfg.mamba1_inner
+
+    def holds(self, cfg):
+        # the gate's silu, the gated memory and the memory's cotangent
+        return 3 * cfg.mamba1_inner
+
+
 def _ff_gates(cfg) -> Tuple[str, ...]:
     """The products of a feed-forward that have names, but for `up`."""
     return ("gate",) if cfg.gated else ()
@@ -1878,6 +2454,7 @@ class _DenseFF(Sublayer):
     matmuls = ("w_gate", "w_up", "w_down")
     names = ("mlp_gate", "mlp_up")
     takes_post_norm = True
+    takes_layer_norm = True
 
     def _width(self, cfg) -> int:
         if cfg.n_experts and cfg.d_ff_dense is not None:
@@ -1887,7 +2464,7 @@ class _DenseFF(Sublayer):
     def init(self, key, cfg, L):
         d, f = cfg.d_model, self._width(cfg)
         ks = jax.random.split(key, 7)
-        leaves = {"mlp_norm": jnp.ones((L, d), jnp.float32)}
+        leaves = _norm_leaves("mlp_norm", cfg, L)
         if cfg.gated:
             leaves["w_gate"] = _dense(ks[4], (L, d, f), d)
         leaves["w_up"] = _dense(ks[5], (L, d, f), d)
@@ -1898,7 +2475,7 @@ class _DenseFF(Sublayer):
 
     def axes(self, cfg):
         table = {
-            "mlp_norm": ("layers", None),
+            **_norm_axes("mlp_norm", cfg),
             "w_gate": ("layers", "embed", "mlp"),
             "w_up": ("layers", "embed", "mlp"),
             "w_down": ("layers", "mlp", "embed"),
@@ -1911,7 +2488,7 @@ class _DenseFF(Sublayer):
 
     def forward(self, x, blk, cfg, site):
         with jax.named_scope("mlp"):
-            y = fused_rmsnorm(x, blk["mlp_norm"], eps=cfg.norm_eps)
+            y = _block_norm(x, blk, "mlp_norm", cfg)
             out = _feed_forward(y, blk, cfg.dtype, ("mlp_gate", "mlp_up"))
             if "mlp_post_norm" in blk:
                 out = _post_norm(out, blk["mlp_post_norm"], cfg)
@@ -2047,6 +2624,13 @@ _OPERATORS: Dict[str, Sublayer] = {
     "conv": _ShortConv(),
     "mamba2": _Mamba2(),
     "kda": _KDA(),
+    "mamba1": _Mamba1(emits=False),
+    "mamba1_emit": _Mamba1(emits=True),
+    "diff_attention": _DiffAttention(),
+    "sliding_diff_attention": _DiffAttention(sliding=True),
+    "diff_attention_emit": _DiffAttention(emits=True),
+    "cross_diff_attention": _DiffAttention(cross=True),
+    "gmu": _GMU(),
 }
 _FEED_FORWARDS: Dict[str, Sublayer] = {
     "dense_ff": _DenseFF(),
@@ -2100,6 +2684,8 @@ def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
         "blocks": blocks,
         "final_norm": jnp.ones((d,), jnp.float32),
     }
+    if cfg.layer_norm:
+        params["final_norm_bias"] = jnp.zeros((d,), jnp.float32)
     if not cfg.tied_embeddings:
         params["unembed"] = _dense(k_out, (d, cfg.vocab_size), d)
     if cfg.exit_gate:  # one `Linear(d, 1)` for all the passes
@@ -2118,6 +2704,7 @@ _LOGICAL_AXES = {
     "embed": ("vocab", "embed"),
     "unembed": ("embed", "vocab"),
     "final_norm": (None,),
+    "final_norm_bias": (None,),
     "exit_w": (None,),
     "exit_b": (),
 }
@@ -2145,6 +2732,8 @@ def param_shardings(mesh, cfg: TransformerConfig):
         table.pop("unembed", None)
     if not cfg.exit_gate:
         del table["exit_w"], table["exit_b"]
+    if not cfg.layer_norm:
+        del table["final_norm_bias"]
     segs = segments(cfg)
     if _one_kind(segs):
         table["blocks"] = _block_axes(cfg, segs[0].layout[0])
@@ -2228,13 +2817,28 @@ def own_buffers(blocks, cfg: TransformerConfig) -> Tuple[int, int, int]:
 
 def _block(x, blk, positions, bias, cfg: TransformerConfig, kind: LayerKind,
            seq_axis: Optional[str], seq_size: int, mesh=None,
-           keep_ctx: bool = False, sliced: bool = False):
-    """One block of `kind`: (x, the routed feed-forward's readings or
-    None), through its sublayers' forwards in turn. `keep_ctx`: the
-    attention kernel names its backward's residuals `attn_ctx`. `sliced`:
-    `blk` is one of several periods of a scanned stack (`_own_weights`)."""
+           keep_ctx: bool = False, sliced: bool = False, shared=None,
+           depth=None):
+    """One block of `kind`: (x, the sublayers' readings or None), through
+    its sublayers' forwards in turn. `keep_ctx`: the attention kernel names
+    its backward's residuals `attn_ctx`. `sliced`: `blk` is one of several
+    periods of a scanned stack (`_own_weights`). `shared`: what the
+    sublayers read of earlier layers; `depth`: the layer's, where a
+    sublayer reads it."""
+    return _block_and_emitted(
+        x, blk, positions, bias, cfg, kind, seq_axis, seq_size, mesh,
+        keep_ctx, sliced, shared, depth)[:2]
+
+
+def _block_and_emitted(x, blk, positions, bias, cfg: TransformerConfig,
+                       kind: LayerKind, seq_axis: Optional[str],
+                       seq_size: int, mesh=None, keep_ctx: bool = False,
+                       sliced: bool = False, shared=None, depth=None):
+    """`_block`'s two and {name: value} of what the block's sublayers emit
+    for later layers (`Sublayer.emits`): what the walk runs."""
     blk = _own_weights(blk, kind, cfg.dtype, sliced)
-    site = _Site(positions, bias, seq_axis, seq_size, mesh, keep_ctx)
+    site = _Site(positions, bias, seq_axis, seq_size, mesh, keep_ctx, shared,
+                 depth)
     sublayers = _sublayers(kind)
     if seq_axis is not None:
         for sub in sublayers:
@@ -2245,12 +2849,15 @@ def _block(x, blk, positions, bias, cfg: TransformerConfig, kind: LayerKind,
             f"heads_held {tuple(cfg.heads_held)} is one chip's share of the "
             f"heads: no `tensor` axis sums the partial results over a mesh "
             f"of {mesh.size} devices yet")
-    readings = None
+    readings, emitted = None, {}
     for sub in sublayers:  # an operator's readings and the feed-forward's
         x, made = sub.forward(x, blk, cfg, site)
-        if made is not None:
+        if sub.emits:  # among what it made, under their names
+            made = dict(made)
+            emitted.update({name: made.pop(name) for name in sub.emits})
+        if made:
             readings = made if readings is None else {**readings, **made}
-    return x, readings
+    return x, readings, emitted
 
 
 def _layer_axis(readings, stack: bool):
@@ -2306,11 +2913,11 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
               if kept else None)
 
     def block_that_keeps(names, kind: LayerKind, sliced: bool, **kw):
-        return partial(_block, cfg=cfg, kind=kind, seq_axis=seq_axis,
-                       seq_size=seq_size, mesh=mesh,
+        return partial(_block_and_emitted, cfg=cfg, kind=kind,
+                       seq_axis=seq_axis, seq_size=seq_size, mesh=mesh,
                        keep_ctx="attn_ctx" in names, sliced=sliced, **kw)
 
-    def scan_body(sliced: bool, layout: Tuple[LayerKind, ...]):
+    def scan_body(sliced: bool, layout: Tuple[LayerKind, ...], shared):
         def block_fn(kind: LayerKind):
             blk_fn = block_that_keeps(kept, kind, sliced)
             if cfg.remat:
@@ -2321,20 +2928,32 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
         blk_fns = {kind: block_fn(kind) for kind in set(layout)}
 
         def body(x, period):
-            blks, biases = period
-            readings = []
-            for kind, blk, bias in zip(layout, blks, biases):
-                x, reading = blk_fns[kind](x, blk, positions, bias)
+            blks, biases, depths = period
+            readings, carried, emitted = [], dict(shared), {}
+            for kind, blk, bias, depth in zip(layout, blks, biases, depths):
+                taken = {name: carried[name] for sub in _sublayers(kind)
+                         for name in sub.reads}
+                beside = {**({"shared": taken} if taken else {}),
+                          **({} if depth is None else {"depth": depth})}
+                x, reading, made = blk_fns[kind](
+                    x, blk, positions, bias, **beside)
+                carried.update(made)
+                emitted.update(made)
                 if reading is not None:
                     readings.append(reading)
-            return x, readings
+            return x, (readings, emitted)
 
         return body
 
     def walk(x):
         """The stream through every segment once, and the layers'
-        readings."""
-        readings, routed_before = [], 0
+        readings. What a layer emits (`Sublayer.emits`) goes beside the
+        stream to the layers that read it: inside a period from layer to
+        layer, and from a segment to the later ones, whose scans take it as
+        a constant (its cotangent is the sum over the readers, in float32
+        where a scan of several periods makes it: the value is handed over
+        in float32 then)."""
+        readings, routed_before, shared, first = [], 0, {}, 0
         for seg, blks in zip(segments(cfg), _segment_trees(params["blocks"])):
             periods = _periods(blks[0])
             # this segment's rows of the bias, one [periods, E] per routed
@@ -2348,14 +2967,28 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
                 routed_before += periods * len(routed)
                 for j, i in enumerate(routed):
                     biases[i] = rows[:, j]
-            x, of_period = jax.lax.scan(
-                scan_body(periods > 1, seg.layout), x, (blks, biases))
+            # the depths of the layers that read theirs, [periods] each
+            depths = [
+                jnp.asarray(cfg.depths[first + i:first + periods * len(blks)
+                                       :len(blks)], jnp.float32)
+                if any(sub.reads_depth for sub in _sublayers(kind)) else None
+                for i, kind in enumerate(seg.layout)]
+            first += periods * len(blks)
+            x, (of_period, emitted) = jax.lax.scan(
+                scan_body(periods > 1, seg.layout,
+                          _for_readers(shared, seg)),
+                x, (blks, biases, depths))
+            # an emitter stands in a segment of one period (`_check_carried`)
+            shared.update(jax.tree.map(lambda a: a[0], emitted))
             if of_period:
                 readings.append(_layer_axis(of_period, stack=True))
         return x, _layer_axis(readings, stack=False) if readings else None
 
     def final_norm(x, scale=params["final_norm"]):
         with jax.named_scope("final_norm"):
+            if cfg.layer_norm:
+                return _layer_norm(x, scale, params["final_norm_bias"],
+                                   cfg.norm_eps)
             return fused_rmsnorm(x, scale, eps=cfg.norm_eps)
 
     if cfg.loop_steps == 1:
@@ -2387,6 +3020,19 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
 
     _, streams = jax.lax.scan(one_pass, x, None, length=cfg.loop_steps)
     return streams, None
+
+
+def _for_readers(shared, seg: Segment):
+    """Of the values emitted so far, those that the layers of `seg` read,
+    as its scan takes them: in float32 where it has several periods, so
+    that the scan's sum of the readers' cotangents is a float32 one."""
+    reads = {name for kind in seg.layout for sub in _sublayers(kind)
+             for name in sub.reads if name in shared}
+    if seg.periods == 1:
+        return {name: shared[name] for name in reads}
+    with jax.named_scope("shared_emit"):
+        return {name: jax.tree.map(lambda a: a.astype(jnp.float32),
+                                   shared[name]) for name in reads}
 
 
 def _next_pass_input(left, normed):
@@ -2621,9 +3267,20 @@ def _looped_under_remat(x, trees, norm_scale, positions, segs, passes: int,
 
 
 def _refuse_unmapped_loop(cfg: TransformerConfig, seq_axis, mesh) -> None:
-    """What `loop_steps` > 1 is not made and tested with yet."""
+    """What `loop_steps` > 1 is not made and tested with yet, and what a
+    stack whose layers read one another's values is not."""
     if cfg.loop_steps < 1:
         raise ValueError(f"loop_steps {cfg.loop_steps}")
+    carried = sorted({name for kind in cfg.layers for sub in _sublayers(kind)
+                      for name in sub.emits})
+    if carried and cfg.loop_steps > 1:
+        raise NotImplementedError(
+            f"loop_steps {cfg.loop_steps} over layers that emit {carried}: "
+            "the looped stack's own backward carries the stream alone")
+    if carried and seq_axis is not None:
+        raise NotImplementedError(
+            f"a `{seq_axis}` axis over layers that emit {carried}: a reader "
+            "takes the emitter's whole sequence, which no device holds")
     if cfg.exit_gate and cfg.loop_steps == 1:
         raise ValueError(
             "exit_gate with loop_steps 1: the exit distribution is over "
@@ -2813,8 +3470,9 @@ def _settled(loss, readings, cfg: TransformerConfig):
     the operators' first, then the feed-forwards', each record's joins and
     then its terms."""
     readings = dict(readings)
-    for sub in _RECORDS:
-        stated = [r for r in sub.readings if (r.of or r.name) in readings]
+    # records of one forward state the same readings: once
+    for of_record in dict.fromkeys(sub.readings for sub in _RECORDS):
+        stated = [r for r in of_record if (r.of or r.name) in readings]
         for r in stated:
             readings[r.name] = _OVER_LAYERS[r.over_layers](
                 readings[r.of or r.name], cfg)
@@ -2851,6 +3509,10 @@ _SAVE_ORDER = (
     "kda_qkv",    # kda's q, k, v products, before the convolution
     "mamba_in",   # the mixer's gate, x, B, C and dt out of `w_in`
     "ssd_out",    # the scan's output, before the gate and the norm
+    "scan_out",   # the Mamba-1 scan's output, before the gate, and its
+                  # chunks' entering states: no second forward scan
+    "mamba1_in",  # the Mamba-1 mixer's u and gate out of `w_in`
+    "gmu_in",     # the gated memory unit's gate product, before the silu
     "moe_gate",   # the experts' gate product [slots, f]
     "moe_up",     # and their up product
     "shared_gate",  # the shared experts' gate product, before the silu
@@ -2933,10 +3595,30 @@ def _head_bytes(cfg: TransformerConfig, tokens: int, param_bytes: int,
             + cfg.loop_steps * tokens * cfg.d_model * item)
 
 
+def _carried_bytes(cfg: TransformerConfig, tokens: int) -> Dict[str, int]:
+    """{name: bytes} of the values the layers emit for later ones
+    (`Sublayer.emits`), each held once from its emitter's forward to its
+    backward, with the sum of its readers' cotangents beside it: in the
+    compute dtype, and both in float32 where the readers are a scan of
+    several periods (`_for_readers`)."""
+    item, out = _item(cfg), {}
+    widths = {name: width for kind in cfg.layers for sub in _sublayers(kind)
+              for name, width in sub.carried(cfg).items()}
+    for seg in segments(cfg):
+        for kind in seg.layout:
+            for sub in _sublayers(kind):
+                for name in sub.reads:
+                    each = 4 if seg.periods > 1 else item
+                    out[name] = max(out.get(name, 0),
+                                    2 * each * widths[name] * tokens)
+    return out
+
+
 def _boundary_bytes(cfg: TransformerConfig, tokens: int) -> int:
     """The blocks' inputs, which a rematerialised stack keeps whatever else
     it keeps, and the stream that leaves the last: once a layer a pass of
-    `loop_steps`, `loop_steps n_layers + 1` in all. A looped stack also
+    `loop_steps`, `loop_steps n_layers + 1` in all; and what the layers
+    carry to later ones beside the stream (`_carried_bytes`). A looped stack also
     holds through its backward, a pass each, the final norm's input and the
     cotangent of the normed stream that the head and the gate read; the
     normed streams themselves are the head's (`_head_bytes`) and gone when
@@ -2944,7 +3626,8 @@ def _boundary_bytes(cfg: TransformerConfig, tokens: int) -> int:
     streams = cfg.loop_steps * cfg.n_layers + 1
     if cfg.loop_steps > 1:
         streams += 2 * cfg.loop_steps
-    return streams * tokens * cfg.d_model * _item(cfg)
+    return (streams * tokens * cfg.d_model * _item(cfg)
+            + sum(_carried_bytes(cfg, tokens).values()))
 
 
 class _Moment(NamedTuple):
@@ -3179,7 +3862,8 @@ def _memory_limit(mesh) -> Optional[int]:
 # what the step reports beside loss and grad_norm: what the records state
 # and the exit loss's
 _STEP_READINGS = (
-    *(r.name for sub in _RECORDS for r in sub.readings if r.step),
+    *dict.fromkeys(
+        r.name for sub in _RECORDS for r in sub.readings if r.step),
     *_EXIT_READINGS)
 
 
@@ -3292,6 +3976,12 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         tracing.count("train.saved_names", len(kept))
         tracing.count("train.saved_bytes", sum(saved.values()))
         tracing.count("train.saved_passes", sum(kept.values()))
+        carried = _carried_bytes(cfg, tokens)
+        tracing.count("train.carried_bytes", sum(carried.values()))
+        if carried:  # what the layers hand later ones beside the stream
+            keeps += "; carries %s beside the stream, with the sums of "\
+                "their readers' cotangents" % ", ".join(
+                    "%s (%d bytes)" % item for item in carried.items())
         buffers, their_bytes, widest = own_buffers(
             state["params"]["blocks"], cfg)
         tracing.count("train.own_buffers", buffers)

@@ -56,6 +56,14 @@ KEYE_SCOPES = {"sparse_attention", "indexer", "index_qk", "index_scores",
 # are `ops/kda.py`'s own), and the elementwise gate on attention's context
 SOLAR_SCOPES = {"kda", "kda_in", "kda_conv", "kda_gates", "kda_chunk",
                 "kda_state", "kda_out"}
+# the Mamba-1 mixer's parts inside `mamba1`, differential attention's scope
+# (round `attn_qkv`, `attention`, `attn_out`) with the pair's norm, a cross
+# layer's scope round `attention`, and the gated memory unit's (PR 61;
+# `shared_emit`, the carried values' cast for a scan of several readers, is
+# in `tests/test_phi4flash.py`: one reader each here)
+PHI4FLASH_SCOPES = {"mamba1", "mamba1_in", "mamba1_conv", "selective_scan",
+                    "mamba1_out", "diff_attention", "diff_norm",
+                    "cross_attention", "gmu"}
 # the routed layer's exchange over an `expert` mesh axis, inside
 # `mlp/shard_map` beside `moe_router` (PR 50)
 EXCHANGE_SCOPES = {"moe_gather", "moe_scatter"}
@@ -235,6 +243,19 @@ def lowered_solar_step():
         moe._ROW_TILE = row_tile
 
 
+def lowered_phi4flash_step():
+    """One of each kind of a stack whose second half reads what its first
+    half made: Mamba-1 and differential attention under a window, the two
+    emitters, a gated memory unit and a cross layer."""
+    return lowered_transformer_step(
+        n_layers=6, d_head=8, n_heads=4, rope=False, tied_embeddings=True,
+        layer_types=("mamba1", "sliding_diff_attention", "mamba1_emit",
+                     "diff_attention_emit", "gmu", "cross_diff_attention"),
+        layer_depths=(0, 1, 16, 17, 18, 19), sliding_window=4,
+        mamba1_inner=64, mamba1_state=8, mamba1_dt_rank=2, scan_chunk=8,
+        layer_norm=True, attn_bias=True)
+
+
 def lowered_nemotron_kernel_step():
     """A mixer alone at sizes that tile (a chunk and a state of 128, a group
     of two heads of 64), the scan's kernels in interpret mode: steered
@@ -323,13 +344,17 @@ FAMILIES = {
     "solar_open2": (lowered_solar_step,
                     TRANSFORMER_SCOPES | (MOE_SCOPES - {"qk_norm"})
                     | {"moe_shared", "attn_gate"} | SOLAR_SCOPES),
+    "phi4flash": (lowered_phi4flash_step,
+                  TRANSFORMER_SCOPES | {"sliding_attention"}
+                  | PHI4FLASH_SCOPES),
     "resnet": (lowered_resnet_step, RESNET_SCOPES),
 }
 # the scopes that one family alone has, but for those that another family
 # has of them
 OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES,
               "nemotron_h": NEMOTRON_SCOPES, "keye_vl2": KEYE_SCOPES,
-              "mellum": EXCHANGE_SCOPES, "solar_open2": SOLAR_SCOPES}
+              "mellum": EXCHANGE_SCOPES, "solar_open2": SOLAR_SCOPES,
+              "phi4flash": PHI4FLASH_SCOPES}
 ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"},
             "solar_open2": {"moe_shared"}}
 
@@ -488,6 +513,22 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
         assert {"moe_combine/gather", "moe_combine/reduce_sum"} <= backward
         assert any(re.fullmatch(r"moe_experts/.*ragged_dot_general", s)
                    for s in backward)
+    elif family == "phi4flash":
+        found = stacks[family]
+        # the scan is a loop, forward, made again and backward
+        for phase in ("", "rematted_computation/", "checkpoint/"):
+            assert any(s.startswith(phase + "mamba1/selective_scan/while")
+                       or f"/{phase}mamba1/selective_scan/while" in s
+                       for s in found), phase
+        # the window's scope stands outside the differential layer's, a
+        # cross layer's round its kernel call alone
+        assert any("sliding_attention/diff_attention/attention/" in s
+                   for s in found)
+        assert any("diff_attention/cross_attention/attention/" in s
+                   for s in found)
+        assert not any("cross_attention/attn_qkv" in s for s in found)
+        assert any(s.endswith("diff_attention/diff_norm/exp") for s in found)
+        assert any(re.search(r"gmu\)*/dot_general", s) for s in found)
     else:
         assert any(re.search(r"stage2\)*/bn/", s) for s in stacks[family])
         assert any(re.search(r"stem\)*/conv/conv_general_dilated", s)
@@ -604,7 +645,8 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
     from chipbench import scopes
 
     program = (TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
-               | DSV2_SCOPES | NEMOTRON_SCOPES | KEYE_SCOPES | SOLAR_SCOPES)
+               | DSV2_SCOPES | NEMOTRON_SCOPES | KEYE_SCOPES | SOLAR_SCOPES
+               | PHI4FLASH_SCOPES)
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     # the benchmark's list is PR 25's three until a `benchmark` issue adds
     # the fourth (PERF.md section 7); its time share matches by prefix

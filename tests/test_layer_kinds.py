@@ -22,6 +22,9 @@ ROUTED = dict(n_experts=4, experts_per_token=2, n_shared_experts=1)
 MAMBA = dict(mamba_heads=4, mamba_head_dim=8, ssm_state=8, ssm_groups=2,
              ssd_chunk=8)
 KDA = dict(kda_heads=4, kda_head_dim=8, kda_gate_rank=4, kda_chunk=16)
+MAMBA1 = dict(mamba1_inner=64, mamba1_state=8, mamba1_dt_rank=4, scan_chunk=8,
+              layer_norm=True)
+DIFF = dict(attn_bias=True, layer_norm=True, rope=False, sliding_window=4)
 # {case: (the table, the record's name, the configuration's keys, the names
 # its forward makes: None for all the record has)}. The case of a record's
 # own name turns on every leaf it can have.
@@ -44,7 +47,17 @@ CASES = {
     "kda_share": ("op", "kda", dict(KDA, heads_held=(2, 2)), None),
     "full_attention_share": ("op", "full_attention", dict(
         attn_gate="elementwise", heads_held=(2, 2)), None),
+    "mamba1": ("op", "mamba1", MAMBA1, None),
+    "mamba1_emit": ("op", "mamba1_emit", dict(MAMBA1, layer_norm=False),
+                    None),
+    "diff_attention": ("op", "diff_attention", DIFF, None),
+    "sliding_diff_attention": ("op", "sliding_diff_attention", DIFF, None),
+    "diff_attention_emit": ("op", "diff_attention_emit", dict(
+        DIFF, attn_bias=False), None),
+    "cross_diff_attention": ("op", "cross_diff_attention", DIFF, None),
+    "gmu": ("op", "gmu", MAMBA1, None),
     "dense_ff": ("ff", "dense_ff", {}, None),
+    "dense_ff_layer_norm": ("ff", "dense_ff", dict(layer_norm=True), None),
     "dense_ff_ungated": ("ff", "dense_ff", dict(ff_activation="relu2"),
                          {"mlp_up"}),
     "dense_ff_before_routed": ("ff", "dense_ff", dict(
@@ -67,6 +80,31 @@ def of_case(case):
             LayerKind(None, name == "routed_ff", True), cfg, made)
 
 
+def beside_the_stream(record, cfg, rows=2, tokens=16):
+    """What `_block` takes of a layer that reads: zeros of what its record
+    `reads`, at the widths the emitting records state (`attn_kv` as the
+    paired keys and the values a pair wide), and a depth."""
+    beside = {}
+    if record.reads_depth:
+        beside["depth"] = jnp.float32(3)
+    widths = {name: width for other in model._RECORDS
+              for name, width in other.carried(cfg).items()}
+    shared = {}
+    for name in record.reads:
+        assert widths[name] > 0
+        if name == "attn_kv":
+            hk, dh = cfg.kv_heads, cfg.head_dim
+            assert widths[name] == 2 * hk * dh
+            shared[name] = (jnp.zeros((rows, tokens, hk, dh), cfg.dtype),
+                            jnp.zeros((rows, tokens, hk // 2, 2 * dh),
+                                      cfg.dtype))
+        else:
+            shared[name] = jnp.zeros((rows, tokens, widths[name]), cfg.dtype)
+    if shared:
+        beside["shared"] = shared
+    return beside
+
+
 def names_made(jaxpr):
     """The `checkpoint_name`s of a jaxpr, nested ones included."""
     found = set()
@@ -81,7 +119,9 @@ def names_made(jaxpr):
 def test_the_tables_are_what_the_configuration_may_name():
     assert set(model._OPERATORS) == {
         "full_attention", "sliding_attention", "sparse_attention",
-        "latent_attention", "conv", "mamba2", "kda"}
+        "latent_attention", "conv", "mamba2", "kda", "mamba1",
+        "mamba1_emit", "diff_attention", "sliding_diff_attention",
+        "diff_attention_emit", "cross_diff_attention", "gmu"}
     assert set(model._FEED_FORWARDS) == {"dense_ff", "routed_ff"}
     assert {record for _, record, _, _ in CASES.values()} == (
         set(model._OPERATORS) | set(model._FEED_FORWARDS))
@@ -121,10 +161,19 @@ def test_a_record_agrees_with_itself(case):
     blk = jax.tree.map(lambda leaf: leaf[0], leaves)
     x = jnp.zeros((2, 16, cfg.d_model), cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
+    beside = beside_the_stream(record, cfg)
     traced = jax.make_jaxpr(lambda x, blk: model._block(
-        x, blk, positions, None, cfg, kind, None, 1, keep_ctx=True)[0])(x, blk)
+        x, blk, positions, None, cfg, kind, None, 1, keep_ctx=True,
+        **beside)[0])(x, blk)
     assert names_made(traced.jaxpr) == set(widths)
     assert traced.out_avals[0].shape == x.shape
+    # what it emits has the width it states, a token
+    emitted = jax.eval_shape(lambda x, blk: model._block_and_emitted(
+        x, blk, positions, None, cfg, kind, None, 1, **beside)[2], x, blk)
+    assert set(emitted) == set(record.emits) == set(record.carried(cfg))
+    for name, width in record.carried(cfg).items():
+        assert sum(a.size for a in jax.tree.leaves(emitted[name])) == (
+            2 * 16 * width)
     # what its backward holds and its operations are counts, and an
     # operator alone or a dense feed-forward does twice its parameters
     assert record.holds(cfg) > 0
@@ -148,7 +197,8 @@ def test_a_record_states_the_readings_its_forward_makes(case):
     x = jnp.zeros((2, 16, cfg.d_model), cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
     made = jax.eval_shape(lambda x, blk: model._block(
-        x, blk, positions, None, cfg, kind, None, 1)[1], x, blk)
+        x, blk, positions, None, cfg, kind, None, 1,
+        **beside_the_stream(record, cfg))[1], x, blk)
     stated = {r.name: r for r in record.readings}
     assert len(stated) == len(record.readings)
     if not stated:
@@ -207,7 +257,8 @@ def test_the_loss_has_what_the_records_say_their_readings_add():
         "aux_loss", "z_loss", "expert_load", "held_slots", "dropped_slots",
         "chip_load", "chip_load_max_over_mean", "index_loss",
         "index_keys_min_gap", "index_keys_max_gap", "kda_log_decay_min",
-        "kda_beta_mean", "ut_pass_loss", "exit_p_mean", "exit_entropy"}
+        "kda_beta_mean", "diff_lambda", "ut_pass_loss", "exit_p_mean",
+        "exit_entropy"}
     assert len(set(model._STEP_READINGS)) == len(model._STEP_READINGS)
 
 

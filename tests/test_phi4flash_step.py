@@ -1,0 +1,112 @@
+"""The step of `phi4flash.tokens16k` as the chip runs it, compiled once at
+its real sizes for a described v5e that is not attached, with the keep rule
+handed the chip's limit: what the rule keeps, its sum beside the compiler's
+plan, the kernels a differential layer's paired call lowers to, and the
+carried values in the program. Nothing runs, so nothing here is a time or a
+result. A file of its own, so that `--dist loadfile` can place its one
+compilation; the topology is described inside a fixture, never at import."""
+
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import pytest
+
+from chipbench import loop, spec
+from ray_tpu.models import transformer as tr
+
+CELL = "phi4flash.tokens16k"
+CHIP_LIMIT = 16_909_336_064  # a v5e's `bytes_limit`, as its allocator reads
+HBM_BYTES = 15.84e9  # what a v5e chip offers a program (PERF.md, "Units")
+STATE = 12 * 697_094_272  # float32 weights and AdamW's two moments
+
+
+@pytest.fixture(scope="module")
+def step():
+    """(the compiled step, what the rule chose for it, the rule's sum for
+    that choice), the compile cache off around it (an entry compiled for a
+    described device cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    cell = spec.load_cell(spec.ROOT, CELL)
+    config, traffic = cell["config"], cell["traffic"]
+    # "auto" asks the platform, which is the CPU here: steered in the test
+    config["attention_impl"] = "pallas"
+    chosen = []
+    rule = tr.saved_activations
+
+    def recording(cfg, tokens, resident, params, limit, ways):
+        kept = rule(cfg, tokens, resident, params, limit, ways)
+        terms = tr._terms(cfg, tokens, params, ways)
+        chosen.append((terms.saved_bytes(kept),
+                       resident + terms.fullest(kept).bytes))
+        return kept
+
+    with pytest.MonkeyPatch.context() as patch:
+        # a described device reports no limit: the chip's is handed over
+        patch.setattr(tr, "_memory_limit", lambda mesh: CHIP_LIMIT)
+        patch.setattr(tr, "saved_activations", recording)
+        family = spec.load_code(spec.ROOT, "loops", config["family"]).build(
+            config, traffic, list(devices[:1]))
+        key = jax.eval_shape(lambda: loop.seed_key(0))
+        state = jax.eval_shape(
+            family.init_state, jax.eval_shape(family.init_params, key))
+        state = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            state, family.state_shardings)
+        batch = family.batch_shapes(int(traffic["batch_rows"]))
+        compiled = family.step.lower(state, batch).compile()
+    yield (compiled, *chosen[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def test_the_plan_fits_under_the_rule_s_sum(step):
+    """The compiler's plan with the rule's choice kept stands under what a
+    v5e offers a program, and the rule's sum for that choice (the state and
+    its fullest moment) stands at or over the plan: the rule errs to the
+    full side."""
+    compiled, kept, rule_sum = step
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes > 0.9 * memory.output_size_in_bytes
+    assert memory.argument_size_in_bytes > STATE
+    plan = memory.peak_memory_in_bytes
+    assert 0.25 * 16.91e9 < plan <= HBM_BYTES - 0.05e9
+    assert plan <= rule_sum <= CHIP_LIMIT - tr._SAVE_RESERVE
+    assert next(iter(kept)) == "attn_ctx"
+
+
+def test_a_differential_layer_is_one_paired_call(step):
+    """Three layers call the kernels (one under the window, two whole): a
+    forward a layer, made again only where `attn_ctx` is not kept, at 40
+    query heads over 20 key heads 64 wide and values 128 wide."""
+    text = step[0].as_text()
+    kept = step[1]
+    calls = {name: len(set(re.findall(rf"%{name}\.\d+ = ", text)))
+             for name in ("flash_fwd", "flash_fwd_window")}
+    again = 1 if "attn_ctx" in kept else 2
+    assert calls == {"flash_fwd": 2 * again, "flash_fwd_window": again}
+    assert "bf16[40,16384,64]" in text    # q: the pairs' first, then second
+    assert "bf16[20,16384,64]" in text    # the paired keys
+    assert "bf16[20,16384,128]" in text   # V, twice
+    assert not re.search(r"(f32|bf16)\[(\d+,)*16384,16384\]", text)
+
+
+def test_no_array_of_a_sequence_s_states(step):
+    """The scan's chunks: 128 entering states a layer, and the steps of one
+    chunk; never 16,384 states."""
+    text = step[0].as_text()
+    assert re.search(r"f32\[128,1,16,5120\]", text)
+    assert not re.search(r"f32\[(\d+,)*16384,(1,)?16,5120\]", text)
+    assert not re.search(r"f32\[(\d+,)*16384,(1,)?5120,16\]", text)

@@ -28,7 +28,7 @@ LIMIT = int(15.75 * 2**30)  # what a v5e chip offers a program
 CELLS = ("mistral7b.tokens4k", "mistral7b.fsdp4", "olmoe.tokens4k",
          "lfm2moe.tokens8k", "dsv2lite.tokens8k", "nemotron3nano.tokens8k",
          "lagunaxs2.tokens8k", "keyevl2.tokens16k", "mellum2.ep4",
-         "solaropen2.tokens8k")
+         "solaropen2.tokens8k", "phi4flash.tokens16k")
 # the cells whose routed layers run over an `expert` mesh axis, and its size
 EXPERT_WAYS = {"mellum2.ep4": 4}
 # `bytes_limit` as the chip reports it (PERF.md, "Units"), and the names
@@ -50,6 +50,9 @@ KEPT = {
     "mellum2.ep4": ("attn_ctx", "attn_res", "attn_qkv"),
     "solaropen2.tokens8k": ("attn_ctx", "attn_res", "attn_qkv", "kda_res",
                             "kda_qkv", "shared_gate", "shared_up"),
+    # the rule's sum stands 2.1 GB over the chip's peak there (PERF.md
+    # section 7): `mamba1_in` would fit
+    "phi4flash.tokens16k": ("attn_ctx", "attn_res", "attn_qkv", "scan_out"),
 }
 
 

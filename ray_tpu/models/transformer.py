@@ -238,7 +238,8 @@ from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
-from ray_tpu.ops.selective_scan import selective_scan
+from ray_tpu.ops.selective_scan import (
+    channel_block, selective_scan, selective_scan_untiled)
 from ray_tpu.ops.ssd import scan_untiled, ssd
 from ray_tpu.ops.fused import (
     HEAD_CHUNK,
@@ -1176,8 +1177,10 @@ def _mamba1_mixer(x, blk, cfg: TransformerConfig):
     `u` with a bias, then silu; `[r | B | C] = u W_x`, `r` of `dt_rank`
     columns, `B` and `C` of `mamba1_state`; `dt = softplus(r W_dt +
     dt_bias)` and `A = -exp(A_log)` a channel and state, in float32; the
-    scan (`ops/selective_scan.py`), whose output `s` holds the skip `D u`;
-    `(s * silu(z)) W_out`."""
+    scan (`ops/selective_scan.py`: its kernels where the step's operators
+    resolve to Pallas and the shape tiles, `jax.numpy` on the CPU and
+    elsewhere), whose output `s` holds the skip `D u`; `(s * silu(z))
+    W_out`."""
     N, R = cfg.mamba1_state, cfg.dt_rank
     dt_, f32 = cfg.dtype, jnp.float32
     with jax.named_scope("mamba1_in"):
@@ -1193,7 +1196,8 @@ def _mamba1_mixer(x, blk, cfg: TransformerConfig):
                                + blk["dt_bias"].astype(f32))
     # names its own operations `selective_scan`, backward too
     s, _ = selective_scan(u, step, -jnp.exp(blk["A_log"].astype(f32)), b, c,
-                          blk["D"], chunk=cfg.scan_chunk)
+                          blk["D"], chunk=cfg.scan_chunk,
+                          impl=_kernel_impl(cfg))
     s = checkpoint_name(s.astype(dt_), "scan_out")
     with jax.named_scope("mamba1_out"):
         gated = s * jax.nn.silu(z)
@@ -2268,15 +2272,21 @@ class _Mamba1(Sublayer):
         cotangent (5 inner), `w_x`'s product and its cotangent; in float32
         the step size before and after its softplus, the scan's output and
         the two cotangents the scan returns at that width (5 inner); a
-        chunk's entering state; and, spread over a sequence's tokens, the
-        steps of the one chunk the backward makes again (the decay, the
-        state before and after: three `[chunk, N, inner]` float32)."""
+        chunk's entering state; and by the path `selective_scan` takes
+        (`ops/selective_scan.py`): `jax.numpy`, spread over a sequence's
+        tokens, the steps of the one chunk the backward makes again (the
+        decay, the state before and after: three `[chunk, N, inner]`
+        float32); the kernels, which keep those in VMEM, the blocks' parts
+        of `dB` and `dC`."""
         inner, N = cfg.mamba1_inner, cfg.mamba1_state
         f32 = 4 // _item(cfg) or 1
-        chunk = 3 * cfg.scan_chunk * N * inner
+        if _kernel_impl(cfg) == "pallas" and not selective_scan_untiled(
+                cfg.scan_chunk, N, inner, _item(cfg)):
+            steps = 2 * N * (inner // channel_block(inner))
+        else:
+            steps = 3 * cfg.scan_chunk * N * inner // cfg.max_seq_len
         return (5 * inner + 2 * (cfg.dt_rank + 2 * N) + 5 * f32 * inner
-                + f32 * (N * inner // cfg.scan_chunk
-                         + chunk // cfg.max_seq_len))
+                + f32 * (N * inner // cfg.scan_chunk + steps))
 
 
 class _DiffAttention(Sublayer):

@@ -553,6 +553,55 @@ def test_kda_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
         "f32[1,8192,1024]", "f32[1,4,128,1,128]", "f32[1,8,128,128]"]
 
 
+@pytest.mark.parametrize("kernel", ["selective_scan_fwd",
+                                    "selective_scan_bwd"])
+def test_selective_scan_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
+    """`phi4flash.tokens16k`: one sequence of 16,384 tokens, 5,120 channels,
+    16 states, chunks of 128, bf16 with a float32 step size. The forward
+    alone is one `selective_scan_fwd` that writes y and the last state in
+    float32; differentiated, the forward rule's `selective_scan_fwd` also
+    writes the 128 chunks' entering states and `selective_scan_bwd` returns
+    du, d dt, dA and the five blocks' parts of dB's and dC's columns. No
+    array of a chunk's steps (`[128, 1, 16, 5120]`) is in either program,
+    and none of a sequence's."""
+    import re
+
+    from ray_tpu.ops.selective_scan import selective_scan
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    T, inner, N = 16384, 5120, 16
+    args = (sd((1, T, inner), jnp.bfloat16), sd((1, T, inner), jnp.float32),
+            sd((inner, N), jnp.float32), sd((1, T, N), jnp.bfloat16),
+            sd((1, T, N), jnp.bfloat16), sd((inner,), jnp.float32))
+
+    def y(*args):
+        return selective_scan(*args, chunk=128, impl="pallas")[0]
+
+    def grads(*args):
+        return jax.grad(lambda *a: y(*a).sum(), argnums=range(6))(*args)
+
+    text = jax.jit(y if kernel == "selective_scan_fwd" else grads).lower(
+        *args).compile().as_text()
+    calls = {re.search(r"selective_scan_(fwd|bwd)", name).group(0):
+             re.findall(r"(?:bf16|f32)\[[\d,]+\]", out)
+             for name, out in _custom_calls(text)}
+    assert not re.search(r"f32\[128,1,16,5120\]", text)
+    assert not re.search(r"f32\[(\d+,)*16384,(1,)?(16,5120|5120,16)\]", text)
+    out = ["f32[1,16384,5120]", "f32[1,16,5120]"]
+    if kernel == "selective_scan_fwd":
+        assert calls == {"selective_scan_fwd": out}
+        return
+    assert set(calls) == {"selective_scan_fwd", "selective_scan_bwd"}
+    assert calls["selective_scan_fwd"] == [*out, "f32[1,128,16,5120]"]
+    assert calls["selective_scan_bwd"] == [
+        "bf16[1,16384,5120]", "f32[1,16384,5120]", "f32[1,16,5120]",
+        "f32[5,1,128,32,128]"]
+
+
 def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
     """Arithmetic alone, `nemotron3nano.tokens8k` at 2 x 8192 tokens and a
     limit of 15.75 GiB. On the kernels' path the scan's part of a block's

@@ -131,6 +131,38 @@ def test_loss_and_every_gradient_leaf_are_the_references(model, remat):
             err_msg=jax.tree_util.keystr(path))
 
 
+def test_the_gradients_through_the_scan_s_kernels_are_the_references(
+        model, monkeypatch):
+    """The Mamba-1 layers' scan by `selective_scan_fwd` and
+    `selective_scan_bwd` in interpret mode (on the chip `attention_impl`
+    resolves to them; here the mixer's call is steered): 128 channels, 16
+    states and chunks of 16 tile, so the four layers' eight kernel calls are
+    in the program, and the loss and every gradient leaf are the
+    reference's as closely as through `jax.numpy`."""
+    from ray_tpu.ops import selective_scan as scan_lib
+    from test_selective_scan_kernel import _equations
+
+    cfg, params, batch, loss, _, grads = model
+    assert scan_lib.selective_scan_untiled(
+        cfg.scan_chunk, cfg.mamba1_state, cfg.mamba1_inner, 4) is None
+    monkeypatch.setattr(tr, "selective_scan", lambda *a, **kw: (
+        scan_lib.selective_scan(*a, **{**kw, "interpret": True})))
+    fn = jax.value_and_grad(
+        lambda p: tr.transformer_loss_and_readings(p, batch, cfg)[0])
+    called = [e.params["name"]
+              for e in _equations(jax.make_jaxpr(fn)(params).jaxpr)
+              if e.primitive.name == "pallas_call"]
+    assert sorted(set(called)) == ["selective_scan_bwd", "selective_scan_fwd"]
+    ours, ours_grads = jax.jit(fn)(params)
+    assert float(ours) == pytest.approx(float(loss), rel=2e-6)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, theirs), mine in zip(flat, jax.tree.leaves(ours_grads)):
+        np.testing.assert_allclose(
+            mine, theirs, rtol=2e-4,
+            atol=2e-5 * float(jnp.abs(theirs).max()) + 1e-7,
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_kept_names_change_nothing(model):
     """Under `remat` with every name kept: the same loss and gradients, and
     the scan's forward is not made again (`scan_out` names its output and

@@ -103,10 +103,35 @@ def test_a_differential_layer_is_one_paired_call(step):
     assert not re.search(r"(f32|bf16)\[(\d+,)*16384,16384\]", text)
 
 
-def test_no_array_of_a_sequence_s_states(step):
-    """The scan's chunks: 128 entering states a layer, and the steps of one
-    chunk; never 16,384 states."""
+def test_no_array_of_a_sequence_s_states_nor_of_a_chunk_s_steps(step):
+    """The scan's chunks: 128 entering states a layer (`[b, T / chunk, N,
+    inner]`, as the kernels' forward writes them); never a chunk's 128
+    steps (`[chunk, b, N, inner]`, which the `jax.numpy` backward stacks to
+    HBM three times a chunk: 54 arrays of it in PR 61's text), never 16,384
+    states."""
     text = step[0].as_text()
-    assert re.search(r"f32\[128,1,16,5120\]", text)
+    assert re.search(r"f32\[1,128,16,5120\]", text)
+    assert not re.search(r"f32\[128,1,16,5120\]", text)
     assert not re.search(r"f32\[(\d+,)*16384,(1,)?16,5120\]", text)
     assert not re.search(r"f32\[(\d+,)*16384,(1,)?5120,16\]", text)
+
+
+# what the compiler planned for PR 61's step, whose scan was `jax.numpy`
+# (builder's, PR 62: the same compile of the parent's tree), bytes
+PLAN_BEFORE_THE_KERNELS = {"peak": 12_284_951_040, "temp": 4_744_045_056}
+
+
+def test_the_two_mamba_layers_scan_by_the_kernels(step):
+    """`selective_scan_fwd` twice and `selective_scan_bwd` twice, a layer
+    each: `scan_out` is kept, its entering states with it, so the block
+    made again runs no second forward scan; and the plan stands no higher
+    than with the `jax.numpy` scan."""
+    compiled, kept, _ = step
+    assert "scan_out" in kept
+    text = compiled.as_text()
+    calls = {name: len(set(re.findall(rf"%{name}\.\d+ = ", text)))
+             for name in ("selective_scan_fwd", "selective_scan_bwd")}
+    assert calls == {"selective_scan_fwd": 2, "selective_scan_bwd": 2}
+    memory = compiled.memory_analysis()
+    assert memory.peak_memory_in_bytes <= PLAN_BEFORE_THE_KERNELS["peak"]
+    assert memory.temp_size_in_bytes <= PLAN_BEFORE_THE_KERNELS["temp"]

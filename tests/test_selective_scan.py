@@ -108,5 +108,5 @@ def test_no_array_of_a_sequence_s_states_in_the_lowered_gradient():
 
 def test_an_impl_it_does_not_have_is_refused():
     args, _ = inputs(8)
-    with pytest.raises(ValueError, match="pallas"):
-        selective_scan(*args, impl="pallas")
+    with pytest.raises(ValueError, match="tiles"):
+        selective_scan(*args, impl="tiles")

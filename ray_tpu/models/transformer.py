@@ -237,6 +237,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
+from ray_tpu.ops.mamba_passes import (
+    causal_conv_silu, gated_group_rmsnorm, norm_untiled)
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
 from ray_tpu.ops.selective_scan import (
     channel_block, selective_scan, selective_scan_untiled)
@@ -1132,6 +1134,14 @@ def _short_conv(x, blk, cfg: TransformerConfig):
         return gated @ blk["conv_out"].astype(dt)
 
 
+def _gated_norm_kernels(cfg: TransformerConfig,
+                        T: Optional[int] = None) -> bool:
+    """Whether the Mamba-2 mixer's gated norm runs as `ops/mamba_passes.py`'s
+    kernels: the operators resolve to Pallas and the shape tiles."""
+    return _kernel_impl(cfg) == "pallas" and not norm_untiled(
+        cfg.mamba_inner, cfg.ssm_groups, T)
+
+
 def _mamba_mixer(x, blk, cfg: TransformerConfig):
     """The Mamba-2 mixer on `x` [B, T, d] (arXiv:2405.21060, as
     `nemotron_h` holds it): `[z | xBC | dt] = norm(x) W_in`; a causal
@@ -1150,9 +1160,9 @@ def _mamba_mixer(x, blk, cfg: TransformerConfig):
             checkpoint_name(u @ blk["w_in"].astype(dt_), "mamba_in"),
             (inner, inner + cfg.mamba_conv_dim), axis=-1)
     with jax.named_scope("mamba_conv"):
-        xbc = jax.nn.silu(_causal_taps(xbc, blk["conv_w"].astype(dt_))
-                          + blk["conv_b"].astype(dt_))
-        xs, b, c = jnp.split(xbc, (inner, inner + G * N), axis=-1)
+        xs, b, c = causal_conv_silu(
+            xbc, blk["conv_w"].astype(dt_), blk["conv_b"],
+            splits=(inner, G * N, G * N), impl=_kernel_impl(cfg))
     with jax.named_scope("ssd"):
         step = jax.nn.softplus(
             dt.astype(jnp.float32) + blk["dt_bias"].astype(jnp.float32))
@@ -1163,9 +1173,16 @@ def _mamba_mixer(x, blk, cfg: TransformerConfig):
             chunk=cfg.ssd_chunk, impl=_kernel_impl(cfg)),
             "ssd_out").reshape(B, T, inner)
     with jax.named_scope("mamba_norm"):
-        gated = (y * jax.nn.silu(z)).reshape(B, T, G, inner // G)
-        y = fused_rmsnorm(gated, blk["norm"].reshape(G, inner // G),
-                          eps=cfg.norm_eps).reshape(B, T, inner)
+        if _gated_norm_kernels(cfg, T):
+            y = gated_group_rmsnorm(y, z, blk["norm"], G, cfg.norm_eps,
+                                    impl=_kernel_impl(cfg))
+        else:
+            # the `jax.numpy` line stands here and not behind the call: the
+            # cell's comparison is tried on this text with the gate taken
+            # out (tests/chipbench_tests/test_chipbench_nemotron_h.py)
+            gated = (y * jax.nn.silu(z)).reshape(B, T, G, inner // G)
+            y = fused_rmsnorm(gated, blk["norm"].reshape(G, inner // G),
+                              eps=cfg.norm_eps).reshape(B, T, inner)
     with jax.named_scope("mamba_out"):
         return y @ blk["w_out"].astype(dt_)
 
@@ -2035,15 +2052,20 @@ class _Mamba2(Sublayer):
         return cfg.d_model * self._wide(cfg) + cfg.mamba_inner * cfg.d_model
 
     def holds(self, cfg):
-        """The convolution's sum and its silu, the gated output and the
-        normed one, what the scan holds by the path it takes, and the three
-        float32 arrays of the mixer's width that the gated norm's backward
-        holds (the compiler's plan for a described v5e,
+        """The convolution's results and their cotangents, the gated output
+        and the normed one, what the scan holds by the path it takes, and
+        by the path the gated norm takes (`ops/mamba_passes.py`): under
+        autodiff the three float32 arrays of the mixer's width that its
+        backward holds (the compiler's plan for a described v5e,
         `nemotron3nano.tokens8k`, PR 54: `mamba_out`'s cotangent and the
         norm's two products, 0.27 GB each at 16,384 tokens, in a backward
-        of 2.77 GB where the other terms count 1.59 and the names 0.47)."""
-        return (2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner
-                + 3 * cfg.mamba_inner * 4 // _item(cfg)
+        of 2.77 GB where the other terms count 1.59 and the names 0.47);
+        the kernels hold none (the same plan with them, PR 63: 0.95 GB
+        less scratch, where the three count 0.81)."""
+        norm = 3 * cfg.mamba_inner * 4 // _item(cfg)
+        if _gated_norm_kernels(cfg):
+            norm = 0
+        return (2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner + norm
                 + _scan_bytes_per_token(cfg) // _item(cfg))
 
     def flops(self, cfg, seq_len):

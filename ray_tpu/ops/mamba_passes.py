@@ -1,0 +1,620 @@
+"""The two bandwidth passes of a Mamba-2 mixer, each as one pass over HBM.
+
+Beside its matmuls and its scan (`ops/ssd.py`) the mixer runs two passes
+that multiply no matrix, and whose time is the bytes they move:
+
+- **`causal_conv_silu(x, w, bias)`**: `silu(sum_i w_i x_(t - taps + 1 + i) +
+  bias)` a channel of `x` [B, T, C], `w` [taps, C], `x` zero before the
+  sequence: the short convolution over x, B and C, handed on as the arrays
+  the scan takes (`splits`: the channels' widths, `[B, T, inner]` and twice
+  `[B, T, G N]`).
+- **`gated_group_rmsnorm(y, z, weight, groups, eps)`**: `GroupRMSNorm(y *
+  silu(z))`, the mean square over each of `groups` groups of channels, one
+  learned scale a channel.
+
+Two paths compute each, as `ops/ssd.py`'s: the kernels where the step's
+operators resolve to Pallas (`impl`: the TPU) and the shape tiles
+(`conv_untiled`, `norm_untiled`), `jax.numpy` under autodiff elsewhere; a
+line once a shape says which (`_log_pass`).
+
+- **`jax.numpy`**: the taps as shifted slices of a padded copy, in `x`'s
+  dtype; `ops/fused.py`'s `fused_rmsnorm` of the gated product. The CPU's
+  path and the kernels' reference. Under autodiff XLA makes an array a tap
+  and a padded sum of the four in the backward, and float32 arrays of the
+  mixer's width around the norm: 41.6 GB a step through HBM in
+  `nemotron3nano.tokens8k` for 11.5 GB of operands and results (PERF.md
+  section 6, PR 63).
+- **Kernel pairs behind a `custom_vjp`: `mamba_conv_fwd`, `mamba_conv_bwd`,
+  `mamba_norm_fwd`, `mamba_norm_bwd`.** Each reads an operand once and
+  writes a result once; every intermediate is float32 in VMEM; the
+  residuals are the inputs alone, so the backward makes the pre-activation
+  and the groups' statistics again.
+
+  The convolution's grid is (batch row, block of channels, block of
+  tokens). A block of tokens takes the 16 rows before it as a second view
+  of the same operand (zeros before the sequence): no padded copy. A step
+  widens block and rows before it into VMEM scratch and walks it `_ROWS`
+  tokens a trip; a trip's taps are sublane rotations (`pltpu.roll`) of its
+  rows and the 16 before them. The forward writes each block of channels
+  into the one of the `splits`' arrays it belongs to (a block straddles no
+  split), so no copy splits them afterwards; an array's block index stands
+  still while the grid is outside its channels, so nothing unwritten is
+  flushed. The backward's grid is (block of channels, batch row, block of
+  tokens from the last to the first): it takes the splits' cotangents as
+  they come, makes `pre` again, and `dx_t = sum_k w_(taps - 1 - k) dpre_(t +
+  k)` reads the `taps - 1` rows after a trip from the trip before it (a
+  carry, and VMEM scratch from block to block). `d w` and `d bias` are
+  summed in float32 in one output block a block of channels, eight
+  partial rows each, that stays in VMEM across the batch rows and the
+  blocks of tokens; the eight are summed outside.
+
+  The norm's grid is (batch row, block of tokens) over whole rows of
+  `inner`; a trip takes `_ROWS` tokens of one group at a time. The product
+  `y * silu(z)` is kept in float32 (the `jax.numpy` line rounds it to
+  `y`'s dtype before the norm widens it). The backward reads `y`, `z` and
+  the cotangent, writes `dy` and `dz` in their dtype and sums `d weight` in
+  float32 in one block that stays in VMEM across the grid. No float32
+  array of the mixer's width reaches HBM.
+  `benchmarks/mamba_passes_alone.py` times both paths alone.
+"""
+
+from __future__ import annotations
+
+import functools
+import logging
+import math
+from typing import Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.flash_attention import (
+    _DEFAULT_VMEM, _LANES, _MAX_VMEM, _pallas_call, resolve_impl)
+from ray_tpu.ops.fused import fused_rmsnorm
+
+logger = logging.getLogger(__name__)
+
+_F32 = jnp.float32
+_TILE = 8    # the rows of a float32 tile: a partial sum's rows, a carry's
+_HALO = 16   # the rows before a block of tokens: a tile of bf16 sublanes
+# the most tokens and channels a grid step of the convolution takes, the
+# most tokens one of the norm, and the tokens a trip of a kernel's loop
+# (the sweep: PERF.md section 6, PR 63)
+_CONV_TOKENS = 1024
+_CONV_CHANNELS = 512
+_NORM_TOKENS = 256
+_ROWS = 32
+
+
+def _largest(size: int, step: int, most: int) -> int:
+    """The largest multiple of `step` that divides `size` and is no more
+    than `most`, or 0."""
+    return max((b for b in range(step, min(size, most) + 1, step)
+                if size % b == 0), default=0)
+
+
+def _silu_and_slope(v):
+    """(`silu(v)`, `silu'(v)`) in `v`'s dtype."""
+    s = jax.nn.sigmoid(v)
+    return v * s, s * (1.0 + v * (1.0 - s))
+
+
+def _folded(v):
+    """`[8, w]`: the sum of `v`'s tiles of 8 rows, whole registers added."""
+    return functools.reduce(
+        jnp.add, [v[at:at + _TILE] for at in range(0, v.shape[0], _TILE)])
+
+
+# ------------------------------------------------------------ convolution
+
+def causal_conv_silu(x, w, bias=None, *, splits: Optional[Sequence[int]] = None,
+                     impl: str = "auto", interpret: bool = False):
+    """`silu(sum_i w_i x_(t - taps + 1 + i) + bias)` of `x` [B, T, C], `w`
+    [taps, C] and `bias` [C] or None, in `x`'s dtype: one array, or with
+    `splits` (widths that sum to C) a tuple of the arrays of those channels.
+
+    impl: 'auto' (the kernels on TPU, `jax.numpy` elsewhere) | 'pallas' |
+    'xla'; `interpret` runs the kernels in interpret mode, for tests. A
+    shape that does not tile (`conv_untiled`) takes `jax.numpy` whatever
+    `impl` says."""
+    B, T, C = x.shape
+    widths = tuple(splits) if splits is not None else (C,)
+    if sum(widths) != C:
+        raise ValueError(f"splits {widths} do not sum to {C} channels")
+    kernels = resolve_impl(impl) == "pallas" or interpret
+    untiled = conv_untiled(w.shape[0], widths, T)
+    _log_pass("causal_conv_silu", kernels, untiled, (B, T, C),
+              (w.shape[0], widths), jnp.dtype(x.dtype).name)
+    if kernels and not untiled:
+        if bias is None:
+            bias = jnp.zeros((C,), _F32)
+        out = _conv(x, w, bias, widths, conv_blocks(T, widths), interpret)
+    else:
+        out = _conv_numpy(x, w, bias, widths)
+    return out if splits is not None else out[0]
+
+
+def _conv_numpy(x, w, bias, widths):
+    """The shifted multiply-adds in `x`'s dtype, as `models/transformer.py`
+    `_causal_taps` has them, the bias, the silu and the split."""
+    taps = w.shape[0]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    pre = sum(w[i] * jax.lax.dynamic_slice_in_dim(padded, i, x.shape[1], 1)
+              for i in range(taps))
+    if bias is not None:
+        pre = pre + bias.astype(x.dtype)
+    ends = [sum(widths[:k + 1]) for k in range(len(widths) - 1)]
+    return tuple(jnp.split(jax.nn.silu(pre), ends, axis=-1))
+
+
+def conv_blocks(T: int, widths: Sequence[int]) -> Tuple[int, int, int]:
+    """(tokens a grid step, channels a grid step, tokens a trip) of the
+    convolution's kernels: a block of channels divides every split's
+    width."""
+    tokens = _largest(T, _HALO, _CONV_TOKENS)
+    return (tokens, _largest(math.gcd(*widths), _LANES, _CONV_CHANNELS),
+            _largest(tokens, _HALO, _ROWS))
+
+
+def conv_untiled(taps: int, widths: Sequence[int],
+                 T: Optional[int] = None) -> Optional[str]:
+    """Why the convolution's kernels cannot take `taps` taps and splits of
+    `widths` channels (and rows of `T` tokens, where they are known), or
+    None where they can: every split whole tiles of 128 lanes, the taps
+    within the rows a block takes before it, and the tokens whole blocks of
+    16 rows (a tile of bf16 sublanes)."""
+    for width in widths:
+        if width % _LANES:
+            return f"{width} channels are no multiple of {_LANES} lanes"
+    if not 1 < taps <= _TILE + 1:
+        return f"{taps} taps: a block takes {_TILE} rows of the one beside it"
+    if T is not None and T % _HALO:
+        return f"{T} tokens are no multiple of {_HALO} rows"
+    return None
+
+
+def pass_vmem_bytes(kernel: str, tokens: int, width: int, itemsize: int,
+                    arrays: int = 1) -> int:
+    """An estimate of what a grid step of a kernel of this module holds in
+    VMEM: its blocks `[tokens, width]` double-buffered (the convolution's
+    backward takes a cotangent an array of `arrays`, and the forward writes
+    as many) and its float32 scratch of the same shape."""
+    block = tokens * width
+    blocks, scratch = {
+        "mamba_conv_fwd": (1 + arrays, 1),
+        "mamba_conv_bwd": (2 + arrays, 2),
+        "mamba_norm_fwd": (3, 0),
+        "mamba_norm_bwd": (5, 0),
+    }[kernel]
+    return 2 * blocks * block * itemsize + scratch * (block + _HALO * width) * 4
+
+
+def _vmem_limit(kernel, tokens, width, itemsize, arrays=1) -> int:
+    need = 2 * pass_vmem_bytes(kernel, tokens, width, itemsize, arrays)
+    return min(max(_DEFAULT_VMEM, need), _MAX_VMEM)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_pass(name, kernels, untiled, shape, rest, dtype):
+    """One line for each pass and shape a process traces, as `ops/ssd.py`'s
+    `_log_scan`: which path, and the kernels' grid, blocks and VMEM."""
+    B, T, C = shape
+    said = f"{name} at B {B}, T {T}, C {C}, {dtype}"
+    item = jnp.dtype(dtype).itemsize
+    if not kernels:
+        logger.info("%s: jax.numpy", said)
+    elif untiled:
+        logger.info("%s: jax.numpy, because %s", said, untiled)
+    elif name == "causal_conv_silu":
+        taps, widths = rest
+        tokens, channels, rows = conv_blocks(T, widths)
+        logger.info(
+            "%s: mamba_conv_fwd and mamba_conv_bwd, %d taps, splits %s, grid "
+            "(%d, %d, %d), blocks [%d, %d] after [%d, %d], %d tokens a trip, "
+            "VMEM %d and %d bytes", said, taps, list(widths), B, C // channels,
+            T // tokens, tokens, channels, _HALO, channels, rows,
+            *(pass_vmem_bytes(k, tokens, channels, item, len(widths))
+              for k in ("mamba_conv_fwd", "mamba_conv_bwd")))
+    else:
+        groups, = rest
+        tokens, rows = norm_blocks(T)
+        logger.info(
+            "%s: mamba_norm_fwd and mamba_norm_bwd, %d groups of %d, grid "
+            "(%d, %d), blocks [%d, %d], %d tokens a trip, VMEM %d and %d "
+            "bytes", said, groups, C // groups, B, T // tokens, tokens, C,
+            rows, *(pass_vmem_bytes(k, tokens, C, item)
+                    for k in ("mamba_norm_fwd", "mamba_norm_bwd")))
+
+
+def _widen(x_ref, before_ref, wide, first):
+    """The block's rows after the 16 before them (zeros where the block is
+    the sequence's first), float32, into `wide` `[16 + tokens, channels]`."""
+    before = before_ref[0].astype(_F32)
+    wide[:_HALO] = jnp.where(first, jnp.zeros_like(before), before)
+    wide[_HALO:] = x_ref[0].astype(_F32)
+
+
+def _shifted(rows, taps):
+    """`rows` `[16 + n, w]` (a trip's tokens after the 16 before them) as
+    the `taps` arrays `[n, w]` a trip's taps multiply: the tokens `taps -
+    1 - i` before each, for `i` up to `taps`."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [(pltpu.roll(rows, taps - 1 - i, 0) if i < taps - 1 else rows)
+            [_HALO:] for i in range(taps)]
+
+
+def _pre_activation(shifted, w, bias):
+    return sum(x * w[i:i + 1] for i, x in enumerate(shifted)) + bias
+
+
+def _its_split(refs, bounds, block, do):
+    """`do(ref)` for the one of `refs`, an array a split, whose blocks of
+    channels (`bounds`) hold the grid's `block`."""
+    from jax.experimental import pallas as pl
+
+    if len(refs) == 1:
+        return do(refs[0])
+    for ref, (lo, hi) in zip(refs, bounds):
+        pl.when((block >= lo) & (block < hi))(functools.partial(do, ref))
+
+
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows):
+    """One block of tokens of one block of channels: `rest` is an output a
+    split, then the scratch `wide`."""
+    from jax.experimental import pallas as pl
+
+    outs, wide = rest[:-1], rest[-1]
+    tokens = x_ref.shape[1]
+    taps = w_ref.shape[0]
+    _widen(x_ref, before_ref, wide, pl.program_id(2) == 0)
+    w, bias = w_ref[...], bias_ref[...]
+    block = pl.program_id(1)
+
+    def trip(i, _):
+        at = pl.multiple_of(i * rows, rows)
+        pre = _pre_activation(
+            _shifted(wide[pl.ds(at, _HALO + rows), :], taps), w, bias)
+        out = _silu_and_slope(pre)[0]
+
+        def write(out_ref):
+            out_ref[0, pl.ds(at, rows), :] = out.astype(out_ref.dtype)
+
+        _its_split(outs, bounds, block, write)
+        return 0
+
+    jax.lax.fori_loop(0, tokens // rows, trip, 0)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows):
+    """The same block's cotangents; the grid walks the blocks of tokens
+    backwards. `rest` is a cotangent a split, then `dx`'s block and the
+    sums' (`[taps + 1, 8, channels]`: eight partial rows a tap of `d w`,
+    then `d bias`'s), then the scratch: `wide`, the block's cotangent and
+    `dpre`'s first rows of the block after this one."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    douts = rest[:len(bounds)]
+    dx_ref, sums_ref, wide, dout, after_ref = rest[len(bounds):]
+    tokens = x_ref.shape[1]
+    taps = w_ref.shape[0]
+    step = pl.program_id(2)
+
+    @pl.when((pl.program_id(1) == 0) & (step == 0))
+    def _first_of_the_channels():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    @pl.when(step == 0)
+    def _last_block():
+        after_ref[...] = jnp.zeros_like(after_ref)
+
+    _widen(x_ref, before_ref, wide, step == pl.num_programs(2) - 1)
+
+    def read(dout_ref):
+        dout[...] = dout_ref[0].astype(_F32)
+
+    _its_split(douts, bounds, pl.program_id(0), read)
+    w, bias = w_ref[...], bias_ref[...]
+    trips = tokens // rows
+
+    def trip(i, after):
+        at = pl.multiple_of((trips - 1 - i) * rows, rows)
+        shifted = _shifted(wide[pl.ds(at, _HALO + rows), :], taps)
+        pre = _pre_activation(shifted, w, bias)
+        dpre = dout[pl.ds(at, rows), :] * _silu_and_slope(pre)[1]
+        # dx_t = sum_k w_(taps - 1 - k) dpre_(t + k): the rows after these
+        both = jnp.concatenate([dpre, after], axis=0)
+        dx = dpre * w[taps - 1:taps]
+        for k in range(1, taps):
+            dx = dx + (pltpu.roll(both, rows + _TILE - k, 0)[:rows]
+                       * w[taps - 1 - k:taps - k])
+        dx_ref[0, pl.ds(at, rows), :] = dx.astype(dx_ref.dtype)
+        for tap, x in enumerate(shifted):
+            sums_ref[tap] += _folded(dpre * x)
+        sums_ref[taps] += _folded(dpre)
+        return dpre[:_TILE]
+
+    after_ref[...] = jax.lax.fori_loop(0, trips, trip, after_ref[...])
+
+
+def _split_bounds(widths, channels):
+    """(first, one past the last) block of channels of each split."""
+    ends = [sum(widths[:k + 1]) // channels for k in range(len(widths))]
+    return tuple(zip([0] + ends[:-1], ends))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 5, 6, 7))
+def _conv_call(name, x, w, bias, douts, widths, blocks, interpret):
+    """`pallas_call` of `mamba_conv_fwd` (no `douts`: an array a split) or
+    of `mamba_conv_bwd` (a cotangent a split: `dx` and the sums of `d w` and
+    `d bias`). The forward's grid is (batch row, block of channels, block
+    of tokens), the backward's (block of channels, batch row, block of
+    tokens from the last). A split's block stands at its first block until
+    the grid reaches its channels and at its last once it has left them.
+    Under `jit`: a model's layers of one shape share one trace of a
+    kernel's body (`ops/selective_scan.py` `_scan_call`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, C = x.shape
+    tokens, channels, rows = blocks
+    taps, n = w.shape[0], T // tokens
+    backward = name == "mamba_conv_bwd"
+    bounds = _split_bounds(widths, channels)
+
+    def of(step):
+        return (lambda a, b, c: step(b, a, n - 1 - c)) if backward else step
+
+    def tokens_of(step):
+        return pl.BlockSpec((1, tokens, channels), of(step))
+
+    def per_channel(*lead):
+        return pl.BlockSpec((*lead, channels),
+                            of(lambda i, j, t: (*(0,) * len(lead), j)))
+
+    def split(lo, hi):
+        # the backward walks t from n - 1 down: its first block is the last
+        early, late = (n - 1, 0) if backward else (0, n - 1)
+        return tokens_of(lambda i, j, t: (
+            i, jnp.where(j < lo, early, jnp.where(j >= hi, late, t)),
+            jnp.clip(j, lo, hi - 1) - lo))
+
+    main = tokens_of(lambda i, j, t: (i, t, j))
+    before = pl.BlockSpec(
+        (1, _HALO, channels),
+        of(lambda i, j, t: (i, jnp.maximum(t * (tokens // _HALO) - 1, 0), j)))
+    splits = [split(lo, hi) for lo, hi in bounds]
+    split_shapes = [jax.ShapeDtypeStruct((B, T, width), x.dtype)
+                    for width in widths]
+    wide = pltpu.VMEM((_HALO + tokens, channels), _F32)
+    if backward:
+        kernel, out_specs = _conv_bwd_kernel, [main, per_channel(taps + 1, _TILE)]
+        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype),
+                     jax.ShapeDtypeStruct((taps + 1, _TILE, C), _F32)]
+        scratch = [wide, pltpu.VMEM((tokens, channels), _F32),
+                   pltpu.VMEM((_TILE, channels), _F32)]
+    else:
+        kernel, out_specs, out_shape, scratch = (
+            _conv_fwd_kernel, splits, split_shapes, [wide])
+    with jax.named_scope("mamba_conv"):
+        return _pallas_call(
+            functools.partial(kernel, rows=rows, bounds=bounds),
+            grid=(C // channels, B, n) if backward else (B, C // channels, n),
+            in_specs=[main, before, per_channel(taps), per_channel(1),
+                      *splits[:len(douts)]],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(
+                    name, tokens, channels, jnp.dtype(x.dtype).itemsize,
+                    len(widths))),
+            interpret=interpret,
+            name=name,
+        )(x, x, w.astype(_F32), bias.astype(_F32).reshape(1, C), *douts)
+
+
+def _conv_fwd(x, w, bias, widths, blocks, interpret):
+    return tuple(_conv_call(
+        "mamba_conv_fwd", x, w, bias, (), widths, blocks, interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _conv(x, w, bias, widths, blocks, interpret):
+    return _conv_fwd(x, w, bias, widths, blocks, interpret)
+
+
+def _conv_vjp_fwd(x, w, bias, widths, blocks, interpret):
+    return _conv_fwd(x, w, bias, widths, blocks, interpret), (x, w, bias)
+
+
+def _conv_vjp_bwd(widths, blocks, interpret, res, douts):
+    x, w, bias = res
+    dx, sums = _conv_call("mamba_conv_bwd", x, w, bias, tuple(douts), widths,
+                          blocks, interpret)
+    sums = sums.sum(axis=1)  # the eight partial rows
+    return dx, sums[:-1].astype(w.dtype), sums[-1].astype(bias.dtype)
+
+
+_conv.defvjp(_conv_vjp_fwd, _conv_vjp_bwd)
+
+
+# ------------------------------------------------------------- gated norm
+
+def gated_group_rmsnorm(y, z, weight, groups: int, eps: float = 1e-6, *,
+                        impl: str = "auto", interpret: bool = False):
+    """`GroupRMSNorm(y * silu(z))` of `y` and `z` [B, T, inner] and `weight`
+    [inner]: the mean square over each of `groups` groups of channels in
+    float32, the result in `y`'s dtype. `impl` and `interpret` as
+    `causal_conv_silu`'s; `norm_untiled` says which shapes the kernels
+    take."""
+    B, T, inner = y.shape
+    kernels = resolve_impl(impl) == "pallas" or interpret
+    untiled = norm_untiled(inner, groups, T)
+    _log_pass("gated_group_rmsnorm", kernels, untiled, (B, T, inner),
+              (groups,), jnp.dtype(y.dtype).name)
+    if kernels and not untiled:
+        return _norm(y, z, weight, groups, eps, norm_blocks(T), interpret)
+    gated = (y * jax.nn.silu(z)).reshape(B, T, groups, inner // groups)
+    return fused_rmsnorm(gated, weight.reshape(groups, inner // groups),
+                         eps=eps).reshape(B, T, inner)
+
+
+def norm_blocks(T: int) -> Tuple[int, int]:
+    """(tokens a grid step, tokens a trip) of the norm's kernels."""
+    tokens = _largest(T, _HALO, _NORM_TOKENS)
+    return tokens, _largest(tokens, _HALO, _ROWS)
+
+
+def norm_untiled(inner: int, groups: int,
+                 T: Optional[int] = None) -> Optional[str]:
+    """Why the norm's kernels cannot take `groups` groups of `inner`
+    channels (and rows of `T` tokens, where they are known), or None where
+    they can: a group whole tiles of 128 lanes, the tokens whole blocks of
+    16 rows, and a step of the backward within VMEM."""
+    if inner % groups or inner // groups % _LANES:
+        return (f"{groups} groups of {inner} channels are no multiple of "
+                f"{_LANES} lanes each")
+    if T is not None and T % _HALO:
+        return f"{T} tokens are no multiple of {_HALO} rows"
+    tokens = _HALO if T is None else norm_blocks(T)[0]
+    need = 2 * pass_vmem_bytes("mamba_norm_bwd", tokens, inner, 4)
+    if need > _MAX_VMEM:
+        return (f"a step of mamba_norm_bwd needs {need} bytes of VMEM, over "
+                f"{_MAX_VMEM}")
+    return None
+
+
+def _group_norm(y, z, eps):
+    """(the normed product, `1 / rms`, `silu(z)`, its slope) of a trip's
+    tokens of one group, float32."""
+    act, slope = _silu_and_slope(z)
+    gated = y * act
+    scale = jax.lax.rsqrt(
+        jnp.mean(gated * gated, axis=-1, keepdims=True) + eps)
+    return gated * scale, scale, act, slope
+
+
+def _norm_fwd_kernel(y_ref, z_ref, w_ref, out_ref, *, groups, eps, rows):
+    from jax.experimental import pallas as pl
+
+    tokens, inner = y_ref.shape[1:]
+    width = inner // groups
+
+    def trip(i, _):
+        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        for g in range(groups):
+            lanes = pl.ds(g * width, width)
+            normed = _group_norm(y_ref[0, at, lanes].astype(_F32),
+                                 z_ref[0, at, lanes].astype(_F32), eps)[0]
+            out_ref[0, at, lanes] = (normed * w_ref[:, lanes]).astype(
+                out_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, tokens // rows, trip, 0)
+
+
+def _norm_bwd_kernel(y_ref, z_ref, w_ref, dout_ref, dy_ref, dz_ref, sums_ref,
+                     *, groups, eps, rows):
+    """`sums_ref` `[8, inner]`: eight partial rows of `d weight`, in VMEM
+    across the whole grid."""
+    from jax.experimental import pallas as pl
+
+    tokens, inner = y_ref.shape[1:]
+    width = inner // groups
+
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _first_step():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    def trip(i, _):
+        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        for g in range(groups):
+            lanes = pl.ds(g * width, width)
+            y = y_ref[0, at, lanes].astype(_F32)
+            normed, scale, act, slope = _group_norm(
+                y, z_ref[0, at, lanes].astype(_F32), eps)
+            dout = dout_ref[0, at, lanes].astype(_F32)
+            sums_ref[:, lanes] += _folded(dout * normed)
+            dnormed = dout * w_ref[:, lanes]
+            dgated = scale * (dnormed - normed * jnp.mean(
+                dnormed * normed, axis=-1, keepdims=True))
+            dy_ref[0, at, lanes] = (dgated * act).astype(dy_ref.dtype)
+            dz_ref[0, at, lanes] = (dgated * y * slope).astype(dz_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, tokens // rows, trip, 0)
+
+
+def _norm_call(name, kernel, operands, outs, blocks, groups, eps, interpret):
+    """`pallas_call` of `mamba_norm_fwd` or `mamba_norm_bwd` over (batch
+    row, block of tokens): blocks `[tokens, inner]` of the `[B, T, inner]`
+    arrays, `weight` whole as a row, and `d weight`'s partial rows."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, inner = operands[0].shape
+    tokens, rows = blocks
+
+    def spec(a):
+        if a.ndim == 3:
+            return pl.BlockSpec((1, tokens, inner), lambda i, t: (i, t, 0))
+        return pl.BlockSpec(a.shape, lambda i, t: (0, 0))
+
+    backward = name == "mamba_norm_bwd"
+    with jax.named_scope("mamba_norm"):
+        return _pallas_call(
+            functools.partial(kernel, groups=groups, eps=eps, rows=rows),
+            grid=(B, T // tokens),
+            in_specs=[spec(a) for a in operands],
+            out_specs=[spec(a) for a in outs],
+            out_shape=outs,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary" if backward else "parallel",
+                                     ) * 2,
+                vmem_limit_bytes=_vmem_limit(
+                    name, tokens, inner,
+                    jnp.dtype(operands[0].dtype).itemsize)),
+            interpret=interpret,
+            name=name,
+        )(*operands)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _norm_fwd(y, z, weight, groups, eps, blocks, interpret):
+    return _norm_call(
+        "mamba_norm_fwd", _norm_fwd_kernel,
+        (y, z, weight.astype(_F32).reshape(1, -1)),
+        [jax.ShapeDtypeStruct(y.shape, y.dtype)], blocks, groups, eps,
+        interpret)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _norm(y, z, weight, groups, eps, blocks, interpret):
+    return _norm_fwd(y, z, weight, groups, eps, blocks, interpret)
+
+
+def _norm_vjp_fwd(y, z, weight, groups, eps, blocks, interpret):
+    return _norm_fwd(y, z, weight, groups, eps, blocks, interpret), (
+        y, z, weight)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _norm_bwd(y, z, weight, dout, groups, eps, blocks, interpret):
+    dy, dz, sums = _norm_call(
+        "mamba_norm_bwd", _norm_bwd_kernel,
+        (y, z, weight.astype(_F32).reshape(1, -1), dout),
+        [jax.ShapeDtypeStruct(y.shape, y.dtype),
+         jax.ShapeDtypeStruct(z.shape, z.dtype),
+         jax.ShapeDtypeStruct((_TILE, y.shape[-1]), _F32)],
+        blocks, groups, eps, interpret)
+    return dy, dz, sums.sum(axis=0).astype(weight.dtype)
+
+
+def _norm_vjp_bwd(groups, eps, blocks, interpret, res, dout):
+    return _norm_bwd(*res, dout, groups, eps, blocks, interpret)
+
+
+_norm.defvjp(_norm_vjp_fwd, _norm_vjp_bwd)

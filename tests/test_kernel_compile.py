@@ -602,6 +602,60 @@ def test_selective_scan_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
         "f32[5,1,128,32,128]"]
 
 
+@pytest.mark.parametrize("use", ["forward", "backward"])
+def test_the_mixer_s_passes_compile_at_the_cell_s_shapes(v5e, use):
+    """`nemotron3nano.tokens8k`: 2 x 8,192 tokens, the convolution of 4 taps
+    over 6,144 channels split 4,096 / 1,024 / 1,024, the gated norm over 8
+    groups of 512, bf16. The forward is one `mamba_conv_fwd` that writes
+    the three arrays the scan takes and one `mamba_norm_fwd`;
+    differentiated, `mamba_conv_bwd` returns one `dxBC` and the taps' and
+    the bias's partial sums, `mamba_norm_bwd` `dy`, `dz` and `d weight`'s.
+    No float32 array of the mixer's width, no padded copy and no array a
+    tap is in either program."""
+    import re
+
+    from ray_tpu.ops.mamba_passes import causal_conv_silu, gated_group_rmsnorm
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    B, T, splits, inner = 2, 8192, (4096, 1024, 1024), 4096
+    args = (sd((B, T, sum(splits))), sd((4, sum(splits))),
+            sd((sum(splits),), jnp.float32), sd((B, T, inner)),
+            sd((B, T, inner)), sd((inner,), jnp.float32))
+
+    def out(x, w, bias, y, z, weight):
+        return (*causal_conv_silu(x, w, bias, splits=splits, impl="pallas"),
+                gated_group_rmsnorm(y, z, weight, 8, 1e-5, impl="pallas"))
+
+    def grads(*args):
+        return jax.grad(lambda *a: sum(
+            o.astype(jnp.float32).sum() for o in out(*a)),
+            argnums=range(6))(*args)
+
+    text = jax.jit(out if use == "forward" else grads).lower(
+        *args).compile().as_text()
+    calls = {re.search(r"mamba_(conv|norm)_(fwd|bwd)", name).group(0):
+             re.findall(r"(?:bf16|f32)\[[\d,]+\]", made)
+             for name, made in _custom_calls(text)}
+    assert not re.search(r"f32\[2,8192,\d+\]", text)
+    assert not re.search(r"bf16\[2,819[3-9],\d+\]", text)  # a padded copy
+    if use == "forward":
+        assert calls == {
+            "mamba_conv_fwd": ["bf16[2,8192,4096]", "bf16[2,8192,1024]",
+                               "bf16[2,8192,1024]"],
+            "mamba_norm_fwd": ["bf16[2,8192,4096]"]}
+        return
+    # the residuals are the inputs alone: no forward kernel is left where
+    # nothing reads its result
+    assert calls == {
+        "mamba_conv_bwd": ["bf16[2,8192,6144]", "f32[5,8,6144]"],
+        "mamba_norm_bwd": ["bf16[2,8192,4096]", "bf16[2,8192,4096]",
+                           "f32[8,4096]"]}
+
+
 def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
     """Arithmetic alone, `nemotron3nano.tokens8k` at 2 x 8192 tokens and a
     limit of 15.75 GiB. On the kernels' path the scan's part of a block's
@@ -612,7 +666,9 @@ def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
     the names of the eight layers up to it and no gradient but what a loop
     accumulates. The room is 3.83 GB and the rule keeps every name, 2.75
     GB, with 1.20 GB left; with the `jax.numpy` scan the room is 1.89 GB
-    and the names end with `mamba_in` (1.35 GB), 0.17 GB left."""
+    and the names end with `mamba_in` (1.35 GB), 0.17 GB left. Since PR 63
+    the gated norm's kernels hold no float32 array of the mixer's width
+    (`ops/mamba_passes.py`): 0.81 GB more room on their path."""
     from chipbench import spec
     from chipbench.loops import nemotron_h
     from ray_tpu.models import transformer as tr
@@ -633,7 +689,7 @@ def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
         "attn_ctx": 136314880, "attn_res": 88080384, "attn_qkv": 150994944,
         "mamba_in": 1350565888, "ssd_out": 536870912, "shared_up": 486539264}
     assert terms.fullest(chosen).name == "layer 7"
-    assert terms.room(3 * params, HBM_LIMIT) == 3828756480
+    assert terms.room(3 * params, HBM_LIMIT) == 3828756480 + 3 * 4096 * 4 * tokens
     cfg, chosen = kept("xla")
     terms = tr._terms(cfg, tokens, params)
     assert tr._scan_bytes_per_token(cfg) * tokens == 64 * 128 * 20 * tokens

@@ -455,6 +455,45 @@ def test_kept_names_leave_loss_and_gradients_as_they_are():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
+def test_the_mixer_s_passes_as_kernels_leave_loss_and_gradients(monkeypatch):
+    """A stack whose mixers tile (one group of 128 channels, a state of 128,
+    32 tokens) with the convolution and the gated norm through their kernels
+    (`ops/mamba_passes.py`, interpret mode) against the `jax.numpy` lines:
+    the loss and every leaf's gradient, under the step's `remat`."""
+    from ray_tpu.ops import mamba_passes as passes
+
+    cfg = tiny("MEM*M", remat=True, mamba_heads=4, mamba_head_dim=32,
+               ssm_state=128, ssm_groups=1)
+    params, bias = init(key(0), cfg), seeded_bias(cfg)
+    batch = batch_of(cfg, seq=32)
+    lines = program(cfg, params, batch, expert_bias=bias)
+    calls = []
+
+    def through(kernel):
+        def call(*args, **kw):
+            calls.append(kernel.__name__)
+            return kernel(*args, **{**kw, "interpret": True})
+        return call
+
+    monkeypatch.setattr(passes, "_ROWS", 16)
+    monkeypatch.setattr(model, "_gated_norm_kernels", lambda cfg, T=None: True)
+    monkeypatch.setattr(model, "causal_conv_silu",
+                        through(passes.causal_conv_silu))
+    monkeypatch.setattr(model, "gated_group_rmsnorm",
+                        through(passes.gated_group_rmsnorm))
+    jaxpr = str(jax.make_jaxpr(lambda p: program(
+        cfg, p, batch, expert_bias=bias))(params))
+    for name in ("mamba_conv_fwd", "mamba_conv_bwd", "mamba_norm_fwd",
+                 "mamba_norm_bwd"):
+        assert name in jaxpr
+    (loss, _), grads = program(cfg, params, batch, expert_bias=bias)
+    assert set(calls) == {"causal_conv_silu", "gated_group_rmsnorm"}
+    assert float(loss) == pytest.approx(float(lines[0][0]), rel=1e-6)
+    assert jax.tree.structure(grads) == jax.tree.structure(lines[1])
+    for ours, theirs in zip(jax.tree.leaves(grads), jax.tree.leaves(lines[1])):
+        np.testing.assert_allclose(ours, theirs, rtol=2e-4, atol=2e-6)
+
+
 def test_flops_count_every_kind_of_sublayer():
     cfg = tiny()
     matmul, attn, head = model._fwd_flops_per_token(cfg, 64)

@@ -163,7 +163,9 @@ CHIP_PEAK_GB = {
     "dsv2lite.tokens8k": 15.587,
     "keyevl2.tokens16k": 15.512,
     "lfm2moe.tokens8k": 12.581,  # 12,580,931,584 bytes
-    "nemotron3nano.tokens8k": 14.396,  # 14,395,963,392
+    # 13,427,617,280 (my chip runs, PR 63: the gated norm's kernels hold no
+    # float32 array of the mixer's width; 14.396 before)
+    "nemotron3nano.tokens8k": 13.428,
     "lagunaxs2.tokens8k": 14.512,  # 14,511,826,944
     "mellum2.ep4": 14.270,  # 14,269,986,816, the fullest of the four chips
 }

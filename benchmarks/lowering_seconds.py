@@ -128,11 +128,15 @@ def main() -> None:
             shardings = ({k: shardings[k] for k in made} if set(made) <= set(
                 shardings) else shardings["params"])
         made = described(made, shardings)
-        ids = family.batch_shapes(int(config["check"]["rows"]))["tokens"]
-        ids = jax.ShapeDtypeStruct(
-            (ids.shape[0], int(config["check"]["seq_len"])), ids.dtype,
-            sharding=ids.sharding)
-        check = {"tokens": ids, "targets": ids}
+        # the step's batch at the check's length (a head of several
+        # positions has targets [rows, length, heads])
+        check = {
+            name: jax.ShapeDtypeStruct(
+                (x.shape[0], int(config["check"]["seq_len"]), *x.shape[2:]),
+                x.dtype, sharding=x.sharding)
+            for name, x in family.batch_shapes(
+                int(config["check"]["rows"])).items()
+            if name in ("tokens", "targets")}
         report(cell_name, "check_value_and_grad", lambda: jax.jit(
             jax.value_and_grad(family.system_loss)).lower(made, check))
 

@@ -151,6 +151,28 @@ attention's projections a bias, and `layer_depths` says where each layer
 stood in the stack it was cut from (`lam0 = 0.8 - 0.6 exp(-0.3 depth)`).
 Those are SambaY's (arXiv:2507.06607; the benchmark's family `phi4flash`).
 
+An operator of pooled keys is EVA attention (`"eva_attention"` in
+`layer_types`; arXiv:2302.04542, as EvaByte holds it): plain attention's q, k
+and v at as many key heads as query heads, rotated, and two learned vectors
+a head (`eva_phi`, `eva_mu`, `[heads, head_dim]`, float32, no matmul's
+weights). A query's softmax runs over keys of two kinds at once: the tokens
+of its own window of `eva_window`, causal (block-diagonal: nothing of the
+window before), and the summaries of every chunk of `eva_chunk` tokens of
+every earlier window, each a softmax-weighted sum of the chunk's keys (by
+`phi . k`) plus `mu`, with the same weights' sum of its values
+(`ops/eva.py`: the summaries' kernel pair, the causal flash kernel on the
+windows folded into the batch, the flash kernel under a staircase over the
+summaries, and the join of the two partial softmaxes by their lse). The
+layer reads `eva_remote_mass` and `eva_chunk_entropy`. A sequence is whole
+windows or no longer than one (then plain causal attention).
+`norm_unit_offset` makes every RMSNorm's scale `1 + g` with `g` starting at
+0, `init_std` draws every matrix at one standard deviation, and
+`n_pred_heads` > 1 makes the head predict that many positions a token: the
+unembedding is `[d_model, n_pred_heads x vocab_size]`, position `t`'s
+targets are ids `t + 1 .. t + n_pred_heads`, and the loss is the mean of
+the heads' cross-entropies (`ops/fused.py` `multi_head_cross_entropy`). Those
+are EvaByte's (the benchmark's family `evabyte`).
+
 Under a mesh with an `expert` axis (`make_mesh({"expert": n})`) a routed
 stack is expert-parallel, nothing of a layer left out: a device holds
 `n_experts / n` whole experts of every layer (the experts' leaves cut on
@@ -215,8 +237,8 @@ Capability analog of what the reference reaches only through integrations
 `mistral7b.tokens4k`, `mistral7b.fsdp4`, `olmoe.tokens4k`,
 `lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`,
 `lagunaxs2.tokens8k`, `keyevl2.tokens16k`, `solaropen2.tokens8k`,
-`ouro.tokens16k`, `phi4flash.tokens16k`, and `mellum2.ep4` on the four
-chips of an `expert` axis (BENCHMARK.json).
+`ouro.tokens16k`, `phi4flash.tokens16k`, `evabyte.tokens8k`, and
+`mellum2.ep4` on the four chips of an `expert` axis (BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -235,6 +257,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import moe
+from ray_tpu.ops.eva import eva_attention
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
 from ray_tpu.ops.mamba_passes import (
@@ -249,6 +272,7 @@ from ray_tpu.ops.fused import (
     _own_cotangent,
     fused_rmsnorm,
     lm_head_cross_entropy,
+    multi_head_cross_entropy,
     softmax_cross_entropy,
     weighted_lm_head_cross_entropy,
 )
@@ -290,7 +314,8 @@ class TransformerConfig:
     # "sliding_attention" | "sparse_attention" | "conv" |
     # "latent_attention" | "mamba2" | "kda" | "mamba1" | "mamba1_emit" |
     # "diff_attention" | "sliding_diff_attention" | "diff_attention_emit" |
-    # "cross_diff_attention" | "gmu"); () => attention everywhere
+    # "cross_diff_attention" | "gmu" | "eva_attention"); () => attention
+    # everywhere
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3  # the short convolution's reach, this token included
     n_dense_layers: int = 0  # with n_experts: leading layers with a dense FF
@@ -408,6 +433,30 @@ class TransformerConfig:
     # constants that depend on it (differential attention's `lam0`); () =>
     # its index in this stack
     layer_depths: Tuple[int, ...] = ()
+    # "eva_attention" (arXiv:2302.04542), by config.json's own keys
+    # `window_size` and `chunk_size`: a causal softmax inside a window of
+    # `eva_window` tokens joined with the summaries of every earlier
+    # window's chunks of `eva_chunk` tokens
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # the head predicts this many positions a token (`num_pred_heads`): the
+    # unembedding is [d_model, n_pred_heads x vocab_size], untied
+    n_pred_heads: int = 1
+    # every RMSNorm's scale is 1 + g, g at 0 to start (`norm_add_unit_offset`),
+    # for the records that take it (`takes_unit_offset`)
+    norm_unit_offset: bool = False
+    # every matrix (the blocks' matmul weights, the embedding, the head) is
+    # drawn normal at this standard deviation; None => at 1 / sqrt(fan-in),
+    # the embedding at 0.02
+    init_std: Optional[float] = None
+    # False: a run of alike layers is ONE period, walked layer by layer, not
+    # a scan over its periods. A scan's gradient is one stacked buffer, held
+    # from the backward's first step beside the whole stack's weights in the
+    # compute dtype (cast once ahead of the loop); walked, a layer's gradient
+    # is made when the backward reaches it and AdamW's update follows at
+    # once, a layer's bytes for the stack's, at the price of a trace and a
+    # lowering a layer (`_terms` has both moments)
+    scan_layers: bool = True
 
     @property
     def kv_heads(self) -> int:
@@ -421,6 +470,11 @@ class TransformerConfig:
         """The query heads of a layer whose operator is `op`, an attention
         of `_OPERATORS`."""
         return _OPERATORS[op].heads(self)
+
+    @property
+    def head_width(self) -> int:
+        """The unembedding's columns: a vocabulary a predicted position."""
+        return self.vocab_size * self.n_pred_heads
 
     @property
     def shared_dim(self) -> int:
@@ -521,6 +575,17 @@ class TransformerConfig:
                         raise ValueError(
                             f"layer_norm with a {type(sub).__name__[1:]} "
                             "sublayer, whose norm is RMSNorm alone")
+        if self.norm_unit_offset:
+            for kind in set(kinds):
+                for sub in _sublayers(kind):
+                    if not sub.takes_unit_offset:
+                        raise ValueError(
+                            f"norm_unit_offset with a "
+                            f"{type(sub).__name__[1:]} sublayer: the scale "
+                            "1 + g is EVA attention's and the dense "
+                            "feed-forward's RMSNorm's")
+        for sub in {sub for kind in kinds for sub in _sublayers(kind)}:
+            sub.check(self)
         _check_carried(kinds)
         return kinds
 
@@ -551,7 +616,8 @@ def segments(cfg: TransformerConfig) -> List[Segment]:
     run in which a layer reads what another made cannot repeat as a whole
     (the emitter stands in it once): where it has no period of its own it
     is cut into its repeated stretches (`_stretches`), so that the layers
-    before the emitters and the readers after them are each scanned."""
+    before the emitters and the readers after them are each scanned.
+    Without `scan_layers` every run is one period of all its layers."""
     kinds = cfg.layers
     runs, start, routed = [], 0, None
     for i, kind in enumerate(kinds):
@@ -568,7 +634,9 @@ def segments(cfg: TransformerConfig) -> List[Segment]:
             run[i] == run[i % p] for i in range(n)))
         carries = any(sub.emits or sub.reads
                       for kind in run for sub in _sublayers(kind))
-        if period < n or not carries:
+        if not cfg.scan_layers:
+            out.append(Segment(tuple(run), 1))
+        elif period < n or not carries:
             out.append(Segment(run[:period], n // period))
         else:
             out.extend(_stretches(run))
@@ -840,12 +908,19 @@ def _block_norm(x, blk, name: str, cfg: "TransformerConfig"):
     or with `layer_norm` LayerNorm with the bias `blk[name + "_bias"]`."""
     if cfg.layer_norm:
         return _layer_norm(x, blk[name], blk[name + "_bias"], cfg.norm_eps)
-    return fused_rmsnorm(x, blk[name], eps=cfg.norm_eps)
+    return fused_rmsnorm(x, _norm_scale(blk[name], cfg), eps=cfg.norm_eps)
+
+
+def _norm_scale(g, cfg: "TransformerConfig"):
+    """An RMSNorm's scale from its leaf: the leaf, or with
+    `norm_unit_offset` 1 + the leaf."""
+    return 1.0 + g if cfg.norm_unit_offset else g
 
 
 def _norm_leaves(name: str, cfg: "TransformerConfig", L: int):
     """`L` layers' leaves of the norm `name` (`_block_norm`)."""
-    leaves = {name: jnp.ones((L, cfg.d_model), jnp.float32)}
+    start = jnp.zeros if cfg.norm_unit_offset else jnp.ones
+    leaves = {name: start((L, cfg.d_model), jnp.float32)}
     if cfg.layer_norm:
         leaves[name + "_bias"] = jnp.zeros((L, cfg.d_model), jnp.float32)
     return leaves
@@ -1322,6 +1397,48 @@ def _gmu(x, blk, cfg: TransformerConfig, memory):
     return (gate * memory.astype(dt)) @ blk["gmu_out"].astype(dt)
 
 
+def _eva_attention_layer(x, blk, cfg: TransformerConfig, site: "_Site"):
+    """(x + EVA attention(norm(x)), {eva_remote_mass, eva_chunk_entropy})
+    (arXiv:2302.04542, as EvaByte holds it): `n_heads` heads of q, k and v
+    `head_dim` wide, q and k rotated over all their columns at `rope_theta`;
+    `ops/eva.py` has the attention (the chunks' summaries under the layer's
+    `eva_phi` and `eva_mu`, made from the rotated keys; the window's own
+    causal softmax; the staircase over the summaries; their join), which
+    names its own operations `eva_summaries`, `eva_window`, `eva_stair` and
+    `eva_join`, backward too; `W_o`."""
+    B, T, d = x.shape
+    h, dh = cfg.n_heads, cfg.head_dim
+    dt = cfg.dtype
+    impl = _kernel_impl(cfg)
+    if impl == "pallas" and site.mesh is not None and site.mesh.size > 1:
+        raise NotImplementedError(
+            "the kernels of EVA attention are not mapped over a mesh of "
+            f"{site.mesh.size} devices yet")
+    with jax.named_scope("attn_qkv"):
+        y = _block_norm(x, blk, "attn_norm", cfg)
+        q, k, v = (checkpoint_name(y @ blk[name].astype(dt), "attn_qkv"
+                                   ).reshape(B, T, h, dh)
+                   for name in ("wq", "wk", "wv"))
+        q = _rope(q, site.positions, cfg.rope_theta)
+        k = _rope(k, site.positions, cfg.rope_theta)
+    o, readings = eva_attention(
+        q, k, v, blk["eva_phi"], blk["eva_mu"], window=cfg.eva_window,
+        chunk=cfg.eva_chunk, impl=impl, keep_ctx=site.keep_ctx)
+    with jax.named_scope("attn_out"):
+        out = o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
+        return checkpoint_name(x + out, "attn_res"), readings
+
+
+def eva_keys_per_query(seq_len: int, window: int, chunk: int) -> float:
+    """The keys a query of EVA attention sees, on average: the causal half
+    of its window, `(window + 1) / 2`, and the `window / chunk` summaries of
+    each of the `(seq_len / window - 1) / 2` windows before it; a sequence no
+    longer than a window is plain causal attention."""
+    if seq_len <= window:
+        return (seq_len + 1) / 2
+    return (window + 1) / 2 + window // chunk * (seq_len // window - 1) / 2
+
+
 def _unit_length(x, eps: float = 1e-6):
     """Every head of `x` [..., dk] over its own length, in float32."""
     x32 = x.astype(jnp.float32)
@@ -1540,6 +1657,12 @@ class Sublayer:
     reads: Tuple[str, ...] = ()
     # `forward` takes the layer's depth (`site.depth`)
     reads_depth: bool = False
+    # a sublayer whose RMSNorm `norm_unit_offset` scales by 1 + g
+    takes_unit_offset: bool = False
+
+    def check(self, cfg: TransformerConfig) -> None:
+        """Raises `ValueError` where `cfg` is none the record can run
+        (`cfg.layers` asks every record of the stack)."""
 
     def carried(self, cfg: TransformerConfig) -> Dict[str, int]:
         """{name: width} of what a layer `emits`, in elements of the
@@ -2474,6 +2597,108 @@ class _GMU(Sublayer):
         return 3 * cfg.mamba1_inner
 
 
+class _EvaAttention(Sublayer):
+    """EVA attention (`_eva_attention_layer`): plain attention's four
+    matrices at as many key heads as query heads, and the two vectors a
+    head that make the chunks' summaries, which are no matmul's weights
+    and are never decayed."""
+
+    matmuls = ("wq", "wk", "wv", "wo")
+    names = ("attn_ctx", "eva_summaries", "attn_res", "attn_qkv")
+    # a layer's share of a query's softmax that the summaries take, and its
+    # chunks' mean entropy: [L] each
+    readings = (Reading("eva_remote_mass"), Reading("eva_chunk_entropy"))
+    no_sequence_axis = (
+        "EVA attention is not mapped over a sequence axis: a window's "
+        "queries read the summaries of every earlier window, which other "
+        "devices hold")
+    takes_unit_offset = True
+
+    def heads(self, cfg) -> int:
+        return cfg.n_heads
+
+    def check(self, cfg):
+        W, C = cfg.eva_window, cfg.eva_chunk
+        if W < 1 or C < 1 or W % C:
+            raise ValueError(
+                f"eva_attention needs eva_window and eva_chunk, whole chunks "
+                f"a window, not {W} and {C}")
+        if cfg.kv_heads != cfg.n_heads:
+            raise ValueError(
+                f"eva_attention with {cfg.kv_heads} key heads for "
+                f"{cfg.n_heads} query heads: a summary is a head's own")
+        if cfg.max_seq_len > W and cfg.max_seq_len % W:
+            raise ValueError(
+                f"eva_attention with sequences of {cfg.max_seq_len} under a "
+                f"window of {W}: a sequence is whole windows, or no longer "
+                "than one")
+
+    def init(self, key, cfg, L):
+        d, dh, h = cfg.d_model, cfg.head_dim, cfg.n_heads
+        ks = jax.random.split(key, 7)
+        k_phi, k_mu = jax.random.split(jax.random.fold_in(key, 13))
+
+        def vector(key):  # normal, clipped to [-1, 1], times dh ** -0.5
+            return jnp.clip(jax.random.normal(key, (L, h, dh), jnp.float32),
+                            -1.0, 1.0) * dh ** -0.5
+
+        return {
+            **_norm_leaves("attn_norm", cfg, L),
+            "wq": _dense(ks[0], (L, d, h * dh), d),
+            "wk": _dense(ks[1], (L, d, h * dh), d),
+            "wv": _dense(ks[2], (L, d, h * dh), d),
+            "wo": _dense(ks[3], (L, h * dh, d), h * dh),
+            "eva_phi": vector(k_phi),
+            "eva_mu": vector(k_mu),
+        }
+
+    def axes(self, cfg):
+        return {
+            **_norm_axes("attn_norm", cfg),
+            "wq": ("layers", "embed", "heads"),
+            "wk": ("layers", "embed", "kv"),
+            "wv": ("layers", "embed", "kv"),
+            "wo": ("layers", "heads", "embed"),
+            "eva_phi": ("layers", "heads", None),
+            "eva_mu": ("layers", "heads", None),
+        }
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("eva"):
+            return _eva_attention_layer(x, blk, cfg, site)
+
+    def widths(self, cfg):
+        h, dh = cfg.n_heads, cfg.head_dim
+        return {
+            # the two parts' o, and each one's lse as one float32 column
+            "attn_ctx": 2 * (h * _tile_lanes(dh) + h * 4 // _item(cfg)),
+            # the chunks' keys and values: a chunk-th of k and v
+            "eva_summaries": 2 * h * dh // cfg.eva_chunk,
+            "attn_res": cfg.d_model,
+            "attn_qkv": 3 * h * dh,
+        }
+
+    def params(self, cfg):
+        return 4 * cfg.d_model * cfg.n_heads * cfg.head_dim
+
+    def holds(self, cfg):
+        """q, k and v as the kernels take them; lse and delta at a tile's
+        128 lanes, of the window's call and of the staircase's; the two
+        parts' o and their cotangents beside the joined one's; the
+        summaries and their cotangents."""
+        h, dh = cfg.n_heads, cfg.head_dim
+        return (3 * h * _tile_lanes(dh) + 4 * h * 128 * 4 // _item(cfg)
+                + 5 * h * dh + 4 * h * dh // cfg.eva_chunk)
+
+    def flops(self, cfg, seq_len):
+        # q k^T and p v over the pairs a query has: its window's causal
+        # half and the earlier windows' summaries; the summaries' own pass
+        # (6 operations a token and channel) is bandwidth and not counted
+        return 2 * self.params(cfg), (
+            2 * 2 * cfg.n_heads * cfg.head_dim
+            * eva_keys_per_query(seq_len, cfg.eva_window, cfg.eva_chunk))
+
+
 def _ff_gates(cfg) -> Tuple[str, ...]:
     """The products of a feed-forward that have names, but for `up`."""
     return ("gate",) if cfg.gated else ()
@@ -2487,6 +2712,7 @@ class _DenseFF(Sublayer):
     names = ("mlp_gate", "mlp_up")
     takes_post_norm = True
     takes_layer_norm = True
+    takes_unit_offset = True
 
     def _width(self, cfg) -> int:
         if cfg.n_experts and cfg.d_ff_dense is not None:
@@ -2663,6 +2889,7 @@ _OPERATORS: Dict[str, Sublayer] = {
     "diff_attention_emit": _DiffAttention(emits=True),
     "cross_diff_attention": _DiffAttention(cross=True),
     "gmu": _GMU(),
+    "eva_attention": _EvaAttention(),
 }
 _FEED_FORWARDS: Dict[str, Sublayer] = {
     "dense_ff": _DenseFF(),
@@ -2718,11 +2945,36 @@ def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
     }
     if cfg.layer_norm:
         params["final_norm_bias"] = jnp.zeros((d,), jnp.float32)
+    if cfg.norm_unit_offset:  # the scale is 1 + g
+        params["final_norm"] = jnp.zeros((d,), jnp.float32)
+    if cfg.n_pred_heads > 1 and (cfg.tied_embeddings or cfg.exit_gate):
+        raise ValueError(
+            f"n_pred_heads {cfg.n_pred_heads} with tied embeddings or an exit "
+            "gate: a head of several positions is a matrix of its own, and "
+            "the exit loss reads one target a position")
     if not cfg.tied_embeddings:
-        params["unembed"] = _dense(k_out, (d, cfg.vocab_size), d)
+        params["unembed"] = _dense(k_out, (d, cfg.head_width), d)
     if cfg.exit_gate:  # one `Linear(d, 1)` for all the passes
         params["exit_w"] = _dense(jax.random.fold_in(k_out, 1), (d,), d)
         params["exit_b"] = jnp.zeros((), jnp.float32)
+    return _at_init_std(params, cfg)
+
+
+def _at_init_std(params, cfg: TransformerConfig):
+    """`params` with every matrix at `init_std`: the draws are the ones they
+    were, rescaled from `1 / sqrt(fan-in)` (a matrix's rows) and, the
+    embedding's, from 0.02. None: as they are."""
+    if cfg.init_std is None:
+        return params
+    std = cfg.init_std
+    kinds = [kind for seg in segments(cfg) for kind in seg.layout]
+    trees = [blk for blks in _segment_trees(params["blocks"]) for blk in blks]
+    for kind, blk in zip(kinds, trees):
+        for name in own_buffer_weights(blk, kind):
+            blk[name] = blk[name] * (std * math.sqrt(blk[name].shape[-2]))
+    params["embed"] = params["embed"] * (std / 0.02)
+    if "unembed" in params:
+        params["unembed"] = params["unembed"] * (std * math.sqrt(cfg.d_model))
     return params
 
 
@@ -3021,7 +3273,7 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
             if cfg.layer_norm:
                 return _layer_norm(x, scale, params["final_norm_bias"],
                                    cfg.norm_eps)
-            return fused_rmsnorm(x, scale, eps=cfg.norm_eps)
+            return fused_rmsnorm(x, _norm_scale(scale, cfg), eps=cfg.norm_eps)
 
     if cfg.loop_steps == 1:
         x, readings = walk(x)
@@ -3483,6 +3735,8 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     cross-entropy."""
     if "targets" in batch:
         tokens, targets = batch["tokens"], batch["targets"]
+    elif cfg.n_pred_heads > 1:
+        tokens, targets = next_ids(batch["tokens"], cfg.n_pred_heads)
     else:
         tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     hidden, readings = _hidden_and_readings(params, tokens, cfg, **kw)
@@ -3491,9 +3745,44 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     if cfg.loop_steps > 1:  # no gate: the last pass's head alone
         hidden = hidden[-1]
     with jax.named_scope("lm_head_ce"):
-        loss = _head_loss(hidden, _unembed(params, cfg), targets,
-                          kw.get("mesh"))
+        if cfg.n_pred_heads > 1:
+            loss = _multi_head_loss(hidden, params["unembed"], targets, cfg,
+                                    kw.get("mesh"))
+        else:
+            loss = _head_loss(hidden, _unembed(params, cfg), targets,
+                              kw.get("mesh"))
     return _settled(loss, readings or {}, cfg)
+
+
+def next_ids(rows, heads: int):
+    """(tokens [B, T], targets [B, T, heads]) of rows of `T + heads` ids:
+    position `t`'s targets are ids `t + 1 .. t + heads`, what a head of
+    `heads` positions predicts."""
+    T = rows.shape[1] - heads
+    return rows[:, :T], jnp.stack(
+        [rows[:, 1 + i:1 + i + T] for i in range(heads)], axis=-1)
+
+
+# the dtype a chunk's logits leave the MXU in (`fp32_logits`), under a name
+# of its own: a test lowers it to show what the comparison reads then
+_LOGITS_F32 = jnp.float32
+
+
+def _multi_head_loss(hidden, unembed, targets, cfg: TransformerConfig,
+                     mesh=None):
+    """The mean of the `n_pred_heads` heads' cross-entropies
+    (`multi_head_cross_entropy`), `targets` [B, T, heads]."""
+    if cfg.loop_steps > 1 or mesh_lib.expert_axis(mesh) is not None:
+        raise NotImplementedError(
+            f"n_pred_heads {cfg.n_pred_heads} over a looped stack or under "
+            "an `expert` axis: the head of several positions is not made "
+            "for them yet")
+    if targets.shape[-1] != cfg.n_pred_heads:
+        raise ValueError(
+            f"targets {targets.shape} for {cfg.n_pred_heads} heads: the "
+            "last axis is a target a head")
+    return multi_head_cross_entropy(hidden, unembed, targets,
+                                    logits_dtype=_LOGITS_F32)
 
 
 def _settled(loss, readings, cfg: TransformerConfig):
@@ -3530,7 +3819,8 @@ def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
 _SAVE_ORDER = (
     "attn_ctx",   # the kernel's o [B H, T, dv] and lse as one f32 column
                   # (sparse attention: and the indexer's three gradients and
-                  # the selection's mask as bits)
+                  # the selection's mask as bits; EVA: of both its parts)
+    "eva_summaries",  # EVA's chunk keys and values: a chunk-th of k and v
     "moe_slots",  # the sorted slots: no second sort (integers, small)
     "attn_res",   # the stream after attention: no second `wo` product
     "conv_res",   # the stream after the short convolution: no `conv_out`
@@ -3619,11 +3909,11 @@ def _head_bytes(cfg: TransformerConfig, tokens: int, param_bytes: int,
     and its cast, the unembedding's cast and the normed stream (a looped
     stack's head reads every pass's: `loop_steps` of them)."""
     item = _item(cfg)
-    unembed = (cfg.vocab_size * cfg.d_model * item * param_bytes
+    unembed = (cfg.head_width * cfg.d_model * item * param_bytes
                // _whole_param_bytes(cfg))
     if expert_ways > 1:  # whole: float32, its cast, its float32 gradient
-        unembed = cfg.vocab_size * cfg.d_model * (4 + item + 4)
-    return (HEAD_CHUNK * cfg.vocab_size * (4 + 4 + item) + unembed
+        unembed = cfg.head_width * cfg.d_model * (4 + item + 4)
+    return (HEAD_CHUNK * cfg.head_width * (4 + 4 + item) + unembed
             + cfg.loop_steps * tokens * cfg.d_model * item)
 
 
@@ -3765,7 +4055,7 @@ def _terms(cfg: TransformerConfig, tokens: int,
     exchange = _exchange_bytes(cfg, tokens, expert_ways)
     head = _head_bytes(cfg, tokens, param_bytes, expert_ways)
     # the head's gradient once the head is done: a device's share of it
-    unembed = 4 * cfg.vocab_size * cfg.d_model * param_bytes // whole
+    unembed = 4 * cfg.head_width * cfg.d_model * param_bytes // whole
     if expert_ways > 1 and cfg.n_experts:
         # as one device of the axis runs it: its routed layers are a share's
         # (one operation that makes no names and holds no whole layer's rows)
@@ -4063,7 +4353,7 @@ def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
             of_matmuls, of_attention = sub.flops(cfg, seq_len)
             matmul += cfg.loop_steps * of_matmuls
             attn += cfg.loop_steps * of_attention
-    head = 2 * cfg.d_model * cfg.vocab_size
+    head = 2 * cfg.d_model * cfg.head_width
     if cfg.exit_gate:
         head = cfg.loop_steps * (head + 2 * cfg.d_model)
     return matmul, attn, head

@@ -83,6 +83,21 @@ The calls are named `flash_fwd_sparse`, `flash_bwd_dkv_dq_sparse`,
 `flash_bwd_dq_sparse` and `flash_bwd_dkv_sparse`. Without a mask every call
 is the statement it was: same names, tiles, index maps and bodies.
 
+A second entry, `flash_attention_lse`, returns `(o, lse)` with a
+`custom_vjp` that takes lse's cotangent (`delta - dlse` in place of `delta`;
+the kernels are the same), so that partial softmaxes over disjoint sets of
+keys can be joined outside: `ops/eva.py` is the caller. It also takes a
+*staircase* (`stair=(span, per)`, no `causal`): query `i` sees the first
+`per * (i // span)` keys, the `per` keys of every span of queries before its
+own. `_tile_kind` gives a tile a body where its last query sees its first
+key and a mask where its first query does not see its last; `flash_tiles`
+weighs the tiles that divide a span of queries and a span's keys, which are
+whole or empty; the index maps clamp a step with no body to the row's
+(column's) nearest tile that has one. A row that sees no key leaves o 0 and
+lse -inf. The calls are named `flash_fwd_stair`, `flash_bwd_dkv_dq_stair`,
+`flash_bwd_dq_stair` and `flash_bwd_dkv_stair`. `flash_attention`, the entry
+that returns o alone, is the program it was.
+
 The tile program, the same in all four kernels:
 
 - Tiles come from the shape. `flash_tiles(kernel, T, S, D, dtype)` (with
@@ -222,11 +237,18 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
-def _active_tiles(T, S, block_q, block_k, causal, window=None) -> int:
+def _active_tiles(T, S, block_q, block_k, causal, window=None,
+                  stair=None) -> int:
     """Tiles with a body: all of them, or under causal those that hold a
     (query, key) pair with key <= query, and with a window only those of
-    them that hold one with query - key < window: the band's."""
+    them that hold one with query - key < window: the band's. Under a
+    staircase (`stair`) those that hold a key the tile's last query sees."""
     num_q, num_k = _cdiv(T, block_q), _cdiv(S, block_k)
+    if stair is not None:
+        return sum(
+            min(num_k, _cdiv(_stair_keys((qi + 1) * block_q - 1, stair),
+                             block_k))
+            for qi in range(num_q))
     if not causal:
         return num_q * num_k
     if window is None:
@@ -259,6 +281,14 @@ def _band_cols(T, S, block_q, block_k, window):
     return [(ki * block_k // block_q,
              min(num_q - 1, ((ki + 1) * block_k + window - 2) // block_q))
             for ki in range(num_k)]
+
+
+def _stair_keys(query, stair):
+    """The keys query `query` sees under the staircase `stair` = (span,
+    per): the first `per * (query // span)`, the `per` keys of every span of
+    queries before its own. A Python number or a traced one."""
+    span, per = stair
+    return per * (query // span)
 
 
 _K_INNERMOST = ("flash_fwd", "flash_bwd_dq")  # the others walk q innermost
@@ -387,7 +417,7 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
                 block_k: Optional[int] = None,
                 v_dim: Optional[int] = None,
                 window: Optional[int] = None, group: int = 1,
-                sparse: bool = False) -> FlashTiles:
+                sparse: bool = False, stair=None) -> FlashTiles:
     """The tile of `kernel` (`flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv`,
     `flash_bwd_dkv_dq`) for q of [*, T, D] and k of [*, S, D] and v of
     [*, S, v_dim] (`D` where None) in `dtype`, `group` query heads to a
@@ -404,7 +434,11 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     and so is the grid (`_inner_steps`): a large tile pays in pairs on both
     sides of the band, a small one in steps, and one whose band crosses
     fewer tiles in some rows (columns) than in others in the trailing steps
-    of those."""
+    of those. Under a staircase (`stair` = (span, per), not causal: query
+    `i` sees the first `per * (i // span)` keys) the tiles with a body are
+    the staircase's, and of the tiles those are weighed that divide a span
+    of queries and a span's keys, where some do: such a tile is whole or
+    empty, and no body masks a pair."""
     itemsize = jnp.dtype(dtype).itemsize
     Dv = D if v_dim is None else v_dim
     step_us, rows_us, pairs_us = _COST_US[kernel]
@@ -413,7 +447,7 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     def plan(bq, bk, exit):
         outer = _cdiv(T, bq) if kernel in _K_INNERMOST else _cdiv(S, bk)
         steps = outer * _inner_steps(kernel, T, S, bq, bk, window)
-        active = _active_tiles(T, S, bq, bk, causal, window)
+        active = _active_tiles(T, S, bq, bk, causal, window, stair)
         vmem = _vmem_bytes(kernel, bq, bk, D, itemsize, Dv, T, S, group,
                            sparse, by_tile=exit == "tile")
         cost = steps * step_us + active * (
@@ -424,6 +458,9 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     whole = window is not None
     qs = [min(block_q, T)] if block_q else _block_candidates(T, whole)
     ks = [min(block_k, S)] if block_k else _block_candidates(S, whole)
+    if stair is not None:
+        qs = [b for b in qs if stair[0] % b == 0] or qs
+        ks = [b for b in ks if stair[1] % b == 0] or ks
 
     def fitting(exit):
         plans = [plan(bq, bk, exit) for bq in qs for bk in ks]
@@ -447,7 +484,8 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
                       block_k: Optional[int] = None,
                       v_dim: Optional[int] = None,
                       window: Optional[int] = None,
-                      group: int = 1, sparse: bool = False) -> Tuple[str, ...]:
+                      group: int = 1, sparse: bool = False,
+                      stair=None) -> Tuple[str, ...]:
     """The kernels of one backward, for the shapes `flash_tiles` takes:
     `("flash_bwd_dkv_dq",)`, all three gradients from one pass over the
     score tiles, or `("flash_bwd_dq", "flash_bwd_dkv")`, which make every
@@ -468,7 +506,8 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
     def tiles(kernel):
         return flash_tiles(kernel, T, S, D, dtype, causal=causal,
                            block_q=block_q, block_k=block_k, v_dim=v_dim,
-                           window=window, group=group, sparse=sparse)
+                           window=window, group=group, sparse=sparse,
+                           stair=stair)
 
     one, two = tiles("flash_bwd_dkv_dq"), ("flash_bwd_dq", "flash_bwd_dkv")
     if window is not None and any(first > last for first, last in _band_rows(
@@ -483,15 +522,20 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
 # ----------------------------------------------------------------- kernels
 
 def _tile_kind(qi, ki, *, block_q, block_k, num_q, num_k, causal,
-               seq_q, seq_k, window=None):
+               seq_q, seq_k, window=None, stair=None):
     """(has_body, needs_mask) of grid tile (qi, ki): Python bools where the
     shape settles it, traced scalars where the grid position does.
     `seq_q=None` says padded q rows need no care (the forward: a row's
     output depends on that row alone, and padded rows are never written).
     `window`: a pair counts where `query - key < window` too, and the tile
     is the band grid's logical one, which a short row's (column's) trailing
-    steps carry past the array's last: those have no body."""
+    steps carry past the array's last: those have no body. `stair`
+    (`_stair_keys`): the tile has a body where its last query sees its
+    first key, and a mask where its first query does not see its last."""
     has_body, needs_mask = True, False
+    if stair is not None:
+        has_body = ki * block_k < _stair_keys((qi + 1) * block_q - 1, stair)
+        needs_mask = (ki + 1) * block_k > _stair_keys(qi * block_q, stair)
     if causal:
         # some key of the tile is at or before some query of it
         has_body = (qi + 1) * block_q > ki * block_k
@@ -529,7 +573,7 @@ def _run_tile(body, has_body, needs_mask):
 
 
 def _tile_mask(qi, ki, *, block_q, block_k, causal, seq_q, seq_k,
-               window=None):
+               window=None, stair=None):
     """[bq, bk] bool: the pair is inside both sequences and, under causal,
     the key is not after the query, nor `window` or more before it. Only
     the terms the shape leaves open."""
@@ -548,6 +592,8 @@ def _tile_mask(qi, ki, *, block_q, block_k, causal, seq_q, seq_k,
         terms.append(q_pos >= k_pos)
     if window is not None:
         terms.append(q_pos - k_pos < window)
+    if stair is not None:
+        terms.append(k_pos < _stair_keys(q_pos, stair))
     return functools.reduce(jnp.logical_and, terms)
 
 
@@ -606,7 +652,7 @@ def _attn_fwd_kernel(
     acc_ref, m_ref, l_ref,  # VMEM scratch, persistent over the k grid dim
     *, block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
     scale: float, causal: bool, seq_k: int, window: Optional[int] = None,
-    mask_ref=None,
+    mask_ref=None, stair=None,
 ):
     from jax.experimental import pallas as pl
 
@@ -616,7 +662,7 @@ def _attn_fwd_kernel(
         ki = _first_k_with_body(qi, block_q, block_k, window) + step
     # seq_q=None: padded q rows need no care here (see _tile_kind)
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
-                 seq_q=None, seq_k=seq_k, window=window)
+                 seq_q=None, seq_k=seq_k, window=window, stair=stair)
 
     @pl.when(step == 0)
     def _init():
@@ -726,7 +772,7 @@ def _compiler_params(tiles: FlashTiles, inner=("parallel", "arbitrary")):
 
 
 def _grid(kernel, q, k, v, causal, block_q, block_k, window=None,
-          mask=None):
+          mask=None, stair=None):
     """(tiles, q tiles, k tiles, steps of the innermost grid dimension) of
     `kernel` for q of [BH, T, D], k of [BHk, S, D] and v of [BHk, S, Dv];
     `block_q`, `block_k` force a tile or are None."""
@@ -734,17 +780,20 @@ def _grid(kernel, q, k, v, causal, block_q, block_k, window=None,
     tiles = flash_tiles(kernel, T, S, q.shape[2], q.dtype, causal=causal,
                         block_q=block_q, block_k=block_k, v_dim=v.shape[2],
                         window=window, group=q.shape[0] // k.shape[0],
-                        sparse=mask is not None)
+                        sparse=mask is not None, stair=stair)
     return (tiles, _cdiv(T, tiles.block_q), _cdiv(S, tiles.block_k),
             _inner_steps(kernel, T, S, tiles.block_q, tiles.block_k, window))
 
 
-def _kernel_name(kernel: str, window, mask=None) -> str:
+def _kernel_name(kernel: str, window, mask=None, stair=None) -> str:
     """The `pallas_call`'s name: the kernel's, with a window
-    `<kernel>_window` and under a data mask `<kernel>_sparse`, which a trace
-    and the compiled HLO tell apart."""
+    `<kernel>_window`, under a data mask `<kernel>_sparse` and under a
+    staircase `<kernel>_stair`, which a trace and the compiled HLO tell
+    apart."""
     if mask is not None:
         return kernel + "_sparse"
+    if stair is not None:
+        return kernel + "_stair"
     return kernel if window is None else kernel + "_window"
 
 
@@ -753,15 +802,21 @@ def _q_block(bh, qi, ki):
 
 
 def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None,
-                     group: int = 1):
+                     group: int = 1, stair=None):
     """Index map of K and V where k is walked innermost (forward, dq): under
     causal a step past the row's last tile with a body names that tile, the
     block already in VMEM, and fetches nothing. Under a window the walk
     starts at the row's first tile with a body (the band grid), and its
     last may be the array's (`num_k`) before it is the diagonal's. With
     `group` query heads to a key-value head, heads folded batch-major, the
-    key-value row of query row `bh` is `bh // group`."""
+    key-value row of query row `bh` is `bh // group`. Under a staircase a
+    step past the row's last tile with a body names that tile (the first,
+    for a row that has none)."""
     def k_block(bh, qi, ki):
+        if stair is not None:
+            seen = _stair_keys((qi + 1) * block_q - 1, stair)
+            ki = jnp.minimum(ki, jnp.maximum(
+                jax.lax.div(seen + block_k - 1, block_k) - 1, 0))
         if window is not None:
             ki = jnp.minimum(
                 ki + _first_k_with_body(qi, block_q, block_k, window),
@@ -773,13 +828,22 @@ def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None,
     return k_block
 
 
-def _q_block_under_k(causal, block_q, block_k, num_q, window=None):
+def _q_block_under_k(causal, block_q, block_k, num_q, window=None,
+                     stair=None):
     """Index map of q, do, lse and delta where q is walked innermost (dk/dv,
     all three gradients): under causal a step before the column's first tile
     with a body names that tile, which is then there when the walk reaches
     it. Under a window the walk starts at that tile (the band grid), and a
-    step past the column's last tile with a body names that one."""
+    step past the column's last tile with a body names that one. Under a
+    staircase the column's first tile with a body holds the first query of
+    the span after its first key's (the last tile, for a column that has
+    none)."""
     def q_block(bh, ki, qi):
+        if stair is not None:
+            span, per = stair
+            first = (jax.lax.div(ki * block_k, per) + 1) * span
+            qi = jnp.maximum(qi, jnp.minimum(
+                jax.lax.div(first, block_q), num_q - 1))
         if window is not None:
             qi = jnp.minimum(
                 qi + _first_q_with_body(ki, block_q, block_k, num_q),
@@ -821,7 +885,7 @@ def _mask_spec_under_q(mask, BH, block_q, k_block):
 
 
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
-               with_lse: bool = False, window=None, mask=None):
+               with_lse: bool = False, window=None, mask=None, stair=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -829,11 +893,12 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
     S, Dv = k.shape[1], v.shape[2]
     block_k = _mask_key_tile(mask, block_k)
     tiles, num_q, num_k, steps = _grid(
-        "flash_fwd", q, k, v, causal, block_q, block_k, window, mask)
+        "flash_fwd", q, k, v, causal, block_q, block_k, window, mask, stair)
     block_q, block_k = tiles.block_q, tiles.block_k
 
     kernel = functools.partial(
         _attn_fwd_kernel_lse if with_lse else _attn_fwd_kernel,
+        stair=stair,
         block_q=block_q,
         block_k=block_k,
         num_q=num_q,
@@ -846,7 +911,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
     )
 
     k_block = _k_block_under_q(causal, block_q, block_k, window, num_k,
-                               group=BH // k.shape[0])
+                               group=BH // k.shape[0], stair=stair)
     out_shape = jax.ShapeDtypeStruct((BH, T, Dv), q.dtype)
     out_specs = pl.BlockSpec((1, block_q, Dv), _q_block)
     if with_lse:
@@ -878,7 +943,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         ],
         compiler_params=_compiler_params(tiles),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window, mask),
+        name=_kernel_name("flash_fwd", window, mask, stair),
     )(*operands)
 
 
@@ -922,7 +987,7 @@ def _exit_said(kernel: str, tiles: FlashTiles) -> str:
 
 @functools.lru_cache(maxsize=None)
 def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
-                     window=None, group=1):
+                     window=None, group=1, stair=None):
     """One line for each backward a process traces, as `saved_activations`
     has one for what it keeps: which kernels, at which tile and VMEM, and
     how many query heads read a key-value head through the index maps. Under
@@ -932,10 +997,13 @@ def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
     def tiles(kernel):
         return flash_tiles(kernel, T, S, D, dtype, causal=causal,
                            block_q=block_q, block_k=block_k, v_dim=Dv,
-                           window=window, group=group)
+                           window=window, group=group, stair=stair)
 
     heads = ("no group" if group == 1 else
              f"{group} query heads a key-value head by index map")
+    if stair is not None:
+        heads += ", under a staircase of %d keys a span of %d queries" % (
+            stair[1], stair[0])
     for kernel in kernels:
         t = tiles(kernel)
         logger.info(
@@ -966,18 +1034,34 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
     BH, T, _ = q.shape
     if keep_ctx:
         lse = jnp.broadcast_to(lse[..., None], (BH, T, 8))
+    return _flash_backward(q, k, v, o, lse, do, causal=causal, scale=scale,
+                           block_q=block_q, block_k=block_k,
+                           interpret=interpret, window=window)
+
+
+def _flash_backward(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
+                    interpret, window, dlse=None, stair=None):
+    """(dq, dk, dv) from the residuals and `do`, lse as `[BH, T, 8]`. With
+    `dlse` [BH, T] float32, lse's own cotangent (`_flash_lse`): a pair's
+    `ds = p (dp - delta)` gains `p dlse`, so `delta` becomes `delta -
+    dlse`, and the kernels are the ones they were."""
+    BH, T, _ = q.shape
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    if dlse is not None:
+        delta = delta - dlse
     # Same sublane-aligned [BH, T, 8] layout as lse.
     delta = jnp.broadcast_to(delta[..., None], (BH, T, 8))
     shape = (T, k.shape[1], q.shape[2])
     group = BH // k.shape[0]
     kernels = flash_bwd_kernels(*shape, q.dtype, causal=causal,
                                 block_q=block_q, block_k=block_k,
-                                v_dim=v.shape[2], window=window, group=group)
+                                v_dim=v.shape[2], window=window, group=group,
+                                stair=stair)
     _log_bwd_kernels(kernels, *shape, v.shape[2], jnp.dtype(q.dtype).name,
-                     causal, block_q, block_k, window=window, group=group)
+                     causal, block_q, block_k, window=window, group=group,
+                     stair=stair)
     tile = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-                interpret=interpret, window=window)
+                interpret=interpret, window=window, stair=stair)
     if kernels == ("flash_bwd_dkv_dq",):
         return _flash_bwd_dkv(q, k, v, do, lse, delta, with_dq=True, **tile)
     dq = _flash_bwd_dq(q, k, v, do, lse, delta, **tile)
@@ -988,9 +1072,51 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@functools.partial(
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
+)
+def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret, keep_ctx,
+               window, stair):
+    """(o [BH, T, Dv], lse [BH, T] float32): `_flash` that also hands out
+    every row's log of its softmax's sum, which carries a cotangent of its
+    own, so that two partial softmaxes can be joined outside
+    (`ops/eva.py`). A row that sees no key (the first span of a staircase)
+    has o 0 and lse near `_BIG_NEG`."""
+    return _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k,
+                          interpret, keep_ctx, window, stair)[0]
+
+
+def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                   keep_ctx, window, stair):
+    o, lse = _flash_fwd(
+        q, k, v, causal=causal, scale=scale, window=window, stair=stair,
+        block_q=block_q, block_k=block_k, interpret=interpret, with_lse=True,
+    )
+    lse = lse[..., 0]  # one column, as `_flash_vjp_fwd` keeps it
+    if keep_ctx:
+        o = checkpoint_name(o, "attn_ctx")
+        lse = checkpoint_name(lse, "attn_ctx")
+    return (o, lse), (q, k, v, o, lse)
+
+
+def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
+                   window, stair, res, cotangents):
+    q, k, v, o, lse = res
+    do, dlse = cotangents
+    BH, T, _ = q.shape
+    return _flash_backward(
+        q, k, v, o, jnp.broadcast_to(lse[..., None], (BH, T, 8)), do,
+        causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+        interpret=interpret, window=window, dlse=dlse.astype(jnp.float32),
+        stair=stair)
+
+
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
 def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
               masked, block_q, block_k, scale, causal, seq_q, seq_k,
-              window=None, mask_ref=None):
+              window=None, mask_ref=None, stair=None):
     """Shared per-tile computation of both backward kernels: load, sanitize
     padded rows (masked tiles only: no other tile has any), re-derive the
     softmax tile. Returns (q, k, do, p, ds), p and ds in the input's dtype.
@@ -1020,7 +1146,7 @@ def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
     ds = p * (dp - delta) * scale  # [bq, bk]
     mask = _pairs_mask(
         masked, mask_ref, qi, ki, block_q=block_q, block_k=block_k,
-        causal=causal, seq_q=seq_q, seq_k=seq_k, window=window)
+        causal=causal, seq_q=seq_q, seq_k=seq_k, window=window, stair=stair)
     if mask is not None:
         p = jnp.where(mask, p, 0.0)
         # Explicit where: p=0 times a NaN dp entry would still poison the dot.
@@ -1034,7 +1160,7 @@ def _attn_bwd_dq_kernel(
     acc_ref,
     *, block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
     scale: float, causal: bool, seq_q: int, seq_k: int,
-    window: Optional[int] = None, mask_ref=None,
+    window: Optional[int] = None, mask_ref=None, stair=None,
 ):
     from jax.experimental import pallas as pl
 
@@ -1043,7 +1169,7 @@ def _attn_bwd_dq_kernel(
     if window is not None:
         ki = _first_k_with_body(qi, block_q, block_k, window) + step
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
-                 seq_q=seq_q, seq_k=seq_k, window=window)
+                 seq_q=seq_q, seq_k=seq_k, window=window, stair=stair)
 
     @pl.when(step == 0)
     def _init():
@@ -1069,7 +1195,7 @@ def _attn_bwd_dkv_kernel(
     block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
     scale: float, causal: bool, seq_q: int, seq_k: int, with_dq: bool = False,
     window: Optional[int] = None, group: int = 1, by_tile: bool = False,
-    mask_ref=None,
+    mask_ref=None, stair=None,
 ):
     """dk and dv of k tile `ki`, summed over the q tiles the grid walks
     innermost. `with_dq` (`flash_bwd_dkv_dq`): dq too, from the same p and
@@ -1123,7 +1249,7 @@ def _attn_bwd_dkv_kernel(
         if group > 1:
             bh = bkv * group + head
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
-                 seq_q=seq_q, seq_k=seq_k, window=window)
+                 seq_q=seq_q, seq_k=seq_k, window=window, stair=stair)
 
     @pl.when(walk == 0)
     def _init():
@@ -1199,7 +1325,8 @@ def _round_out(*tiles):
 
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
-                  block_q, block_k, interpret, window=None, mask=None):
+                  block_q, block_k, interpret, window=None, mask=None,
+                  stair=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1207,17 +1334,19 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
     S, Dv = k.shape[1], v.shape[2]
     block_k = _mask_key_tile(mask, block_k)
     tiles, num_q, num_k, steps = _grid(
-        "flash_bwd_dq", q, k, v, causal, block_q, block_k, window, mask)
+        "flash_bwd_dq", q, k, v, causal, block_q, block_k, window, mask,
+        stair)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dq_kernel,
+        stair=stair,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
         steps=steps, scale=scale, causal=causal, seq_q=T, seq_k=S,
         window=window,
     )
 
     k_block = _k_block_under_q(causal, block_q, block_k, window, num_k,
-                               group=BH // k.shape[0])
+                               group=BH // k.shape[0], stair=stair)
     in_specs = [
         pl.BlockSpec((1, block_q, D), _q_block),
         pl.BlockSpec((1, block_k, D), k_block),
@@ -1240,13 +1369,14 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(tiles),
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dq", window, mask),
+        name=_kernel_name("flash_bwd_dq", window, mask, stair),
     )(*operands)
 
 
 def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
                    block_q, block_k, interpret, with_dq: bool = False,
-                   window=None, mask=None, by_tile: bool = False):
+                   window=None, mask=None, by_tile: bool = False,
+                   stair=None):
     """(dk, dv), or with `with_dq` the kernel `flash_bwd_dkv_dq` and
     (dq, dk, dv): one more output, whose block is a (batch, head) row's
     whole dq, fetched nowhere and written back when the row is done, and
@@ -1271,17 +1401,19 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     name = "flash_bwd_dkv_dq" if with_dq else "flash_bwd_dkv"
     block_k = _mask_key_tile(mask, block_k)
     tiles, num_q, num_k, steps = _grid(
-        name, q, k, v, causal, block_q, block_k, window, mask)
+        name, q, k, v, causal, block_q, block_k, window, mask, stair)
     block_q, block_k = tiles.block_q, tiles.block_k
     by_tile = with_dq and (by_tile or tiles.exit == "tile")
     kernel = functools.partial(
         _attn_bwd_dkv_kernel,
+        stair=stair,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
         steps=steps, scale=scale, causal=causal, seq_q=T, seq_k=S,
         with_dq=with_dq, window=window, group=group, by_tile=by_tile,
     )
 
-    q_block = _q_block_under_k(causal, block_q, block_k, num_q, window)
+    q_block = _q_block_under_k(causal, block_q, block_k, num_q, window,
+                               stair)
 
     def k_block(bh, ki, qi):
         return (bh, ki, 0)
@@ -1367,7 +1499,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         scratch_shapes=scratch_shapes,
         compiler_params=_compiler_params(tiles, inner),
         interpret=interpret,
-        name=_kernel_name(name, window, mask),
+        name=_kernel_name(name, window, mask, stair),
     )(*operands)
     if not with_dq:
         return out
@@ -1435,6 +1567,51 @@ def flash_attention(
     of = _flash(qf, kf, vf, causal, scale, block_q, block_k, interpret,
                 keep_ctx, window)
     return of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
+
+
+def flash_attention_lse(
+    q, k, v,
+    *,
+    causal: bool = False,
+    scale: Optional[float] = None,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    interpret: bool = False,
+    keep_ctx: bool = False,
+    window: Optional[int] = None,
+    stair: Optional[Tuple[int, int]] = None,
+):
+    """`flash_attention` that also returns the rows' lse: (o [B, T, H, Dv],
+    lse [B, T, H] float32, the log of each row's sum of `exp(scale q . k)`
+    over the keys it sees), both differentiable: the backward takes lse's
+    cotangent into `delta`. Two such partial softmaxes over disjoint keys
+    join into the whole one, `o = (e^lse1 o1 + e^lse2 o2) / (e^lse1 +
+    e^lse2)`.
+
+    `stair=(span, per)`, without `causal` or `window`: query `i` sees the
+    first `per * (i // span)` keys of `k`, the `per` keys of every span of
+    queries before its own, a staircase; the `pallas_call`s are named
+    `flash_fwd_stair`, `flash_bwd_dkv_dq_stair` (`flash_bwd_dq_stair`,
+    `flash_bwd_dkv_stair`). A row that sees no key has o 0 and lse -inf."""
+    B, T, H, D = q.shape
+    Hk, Dv = k.shape[2], v.shape[3]
+    window = _band(window, causal, k.shape[1])
+    if stair is not None and (causal or window is not None):
+        raise ValueError("a staircase is its own mask: no causal, no window")
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
+    if H % Hk:
+        raise ValueError(
+            f"{H} query heads do not divide among {Hk} key-value heads")
+    qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+    kf = k.transpose(0, 2, 1, 3).reshape(B * Hk, k.shape[1], D)
+    vf = v.transpose(0, 2, 1, 3).reshape(B * Hk, v.shape[1], Dv)
+    of, lse = _flash_lse(qf, kf, vf, causal, scale, block_q, block_k,
+                         interpret, keep_ctx, window,
+                         None if stair is None else tuple(map(int, stair)))
+    lse = jnp.where(lse > 0.5 * _BIG_NEG, lse, -jnp.inf)
+    return (of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3),
+            lse.reshape(B, H, T).transpose(0, 2, 1))
 
 
 def _band(window: Optional[int], causal: bool, seq_k: int) -> Optional[int]:

@@ -320,3 +320,53 @@ def _lm_head_ce_weighted_bwd(chunk_tokens, ignore_index, saved, cotangents):
 
 
 _lm_head_ce_weighted.defvjp(_lm_head_ce_weighted_fwd, _lm_head_ce_weighted_bwd)
+
+
+# ------------------------------------------- a head of several positions
+
+def multi_head_cross_entropy(hidden, unembed, targets, *,
+                             chunk_tokens: int = HEAD_CHUNK,
+                             logits_dtype=jnp.float32):
+    """The mean over tokens and heads of the cross-entropy of a head that
+    predicts several positions a token: `hidden` [B, T, d] (compute dtype),
+    `unembed` [d, P V] float32 (head `i`'s columns are `V i .. V (i + 1) -
+    1`), `targets` [B, T, P] integers below `V`. A chunk of tokens makes its
+    `[chunk, P V]` logits by ONE matmul accumulated in `logits_dtype`
+    (float32: `fp32_logits`) and reduces each head's `V` of them to
+    logsumexp - label logit; the chunks are a scan under `jax.checkpoint`,
+    so no `[B T, P V]` array is held and the unembedding's gradient is
+    summed over the chunks in float32. Every target counts: there is no
+    ignored index."""
+    d = hidden.shape[-1]
+    P = targets.shape[-1]
+    V = unembed.shape[-1] // P
+    h = hidden.reshape(-1, d)
+    t = targets.reshape(-1, P)
+    n = h.shape[0]
+    chunk_tokens = min(chunk_tokens, n)
+    if n % chunk_tokens:
+        raise ValueError(
+            f"{n} tokens are no whole chunks of {chunk_tokens}")
+
+    @jax.checkpoint
+    def chunk_loss(hc, tc, w):
+        # a checkpointed body is lowered as a function of its own, whose
+        # name stacks start anew: the head's scope again, so that a trace
+        # finds the chunk's matmuls under it (docs/observability.md)
+        with jax.named_scope("lm_head_ce"):
+            logits = jnp.dot(
+                _own_buffer(hc), w.astype(hc.dtype),
+                preferred_element_type=logits_dtype,
+            ).astype(jnp.float32).reshape(chunk_tokens, P, V)
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(
+                logits, tc[..., None], axis=-1)[..., 0]
+            return (lse - picked).sum()
+
+    def body(total, xs):
+        return total + chunk_loss(*xs, unembed), None
+
+    total, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        (h.reshape(-1, chunk_tokens, d), t.reshape(-1, chunk_tokens, P)))
+    return total / (n * P)

@@ -64,6 +64,13 @@ SOLAR_SCOPES = {"kda", "kda_in", "kda_conv", "kda_gates", "kda_chunk",
 PHI4FLASH_SCOPES = {"mamba1", "mamba1_in", "mamba1_conv", "selective_scan",
                     "mamba1_out", "diff_attention", "diff_norm",
                     "cross_attention", "gmu"}
+# EVA attention's scope (round `attn_qkv` and `attn_out`) and its four parts,
+# `ops/eva.py`'s own, with the summaries' kernel pair (PR 64); the window's
+# and the staircase's kernels carry the flash kernels' names, the second's
+# with `_stair`
+EVABYTE_SCOPES = {"eva", "eva_summaries", "eva_window", "eva_stair",
+                  "eva_join"}
+EVA_KERNELS = {"eva_summaries_fwd", "eva_summaries_bwd"}
 # the routed layer's exchange over an `expert` mesh axis, inside
 # `mlp/shard_map` beside `moe_router` (PR 50)
 EXCHANGE_SCOPES = {"moe_gather", "moe_scatter"}
@@ -256,6 +263,31 @@ def lowered_phi4flash_step():
         layer_norm=True, attn_bias=True)
 
 
+def lowered_evabyte_step():
+    """Two EVA attention layers over two windows of 16 (chunks of 8, heads
+    of 8), the kernels in interpret mode: steered here, since "pallas" does
+    not lower for a CPU."""
+    from ray_tpu.ops import eva
+
+    real = transformer_module.eva_attention
+    transformer_module.eva_attention = lambda *a, **kw: eva.eva_attention(
+        *a, **{**kw, "impl": "xla", "interpret": True})
+    try:
+        cfg = TransformerConfig(
+            vocab_size=VOCAB, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+            max_seq_len=32, remat=True, attention_impl="xla",
+            tied_embeddings=False, layer_types=("eva_attention",) * 2,
+            eva_window=16, eva_chunk=8, n_pred_heads=2, norm_unit_offset=True)
+        mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+        init_state, step, _ = make_train_step(cfg, mesh)
+        state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+        return step.lower(state, {
+            "tokens": jax.ShapeDtypeStruct((2, 32), jnp.int32),
+            "targets": jax.ShapeDtypeStruct((2, 32, 2), jnp.int32)})
+    finally:
+        transformer_module.eva_attention = real
+
+
 def lowered_nemotron_kernel_step():
     """A mixer alone at sizes that tile (a chunk and a state of 128, a group
     of two heads of 64), the scan's kernels in interpret mode: steered
@@ -354,7 +386,9 @@ FAMILIES = {
 OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES,
               "nemotron_h": NEMOTRON_SCOPES, "keye_vl2": KEYE_SCOPES,
               "mellum": EXCHANGE_SCOPES, "solar_open2": SOLAR_SCOPES,
-              "phi4flash": PHI4FLASH_SCOPES}
+              "phi4flash": PHI4FLASH_SCOPES,
+              # no family above: `test_eva_attention_names_its_parts` lowers it
+              "evabyte": EVABYTE_SCOPES | EVA_KERNELS}
 ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"},
             "solar_open2": {"moe_shared"}}
 
@@ -592,6 +626,36 @@ def test_the_scan_s_kernels_sit_under_the_scan_s_scope():
     assert not [s for s in stacks if "ssd_bwd" in s and "rematted" in s]
 
 
+def test_eva_attention_names_its_parts():
+    """Under `eva`: the projections, the summaries' kernel, the window's
+    causal flash kernel, the staircase's, the join and the output
+    projection, in the forward, in the forward made again and, through the
+    `custom_vjp`s, in the backward; the head of several positions under
+    `lm_head_ce`."""
+    stacks = name_stacks(lowered_evabyte_step())
+    forward = ("eva/attn_qkv/dot_general",
+               "eva/eva_summaries/eva_summaries_fwd/pallas_call",
+               "eva/eva_window/flash_fwd/pallas_call",
+               "eva/eva_stair/flash_fwd_stair/pallas_call",
+               "eva/eva_join/exp", "eva/attn_out/dot_general")
+    for want in forward:
+        for phase in ("", "checkpoint/rematted_computation/"):
+            assert any(s.endswith("/" + phase + want) or s == phase + want
+                       for s in stacks), phase + want
+    for want in ("eva/eva_summaries/eva_summaries_bwd/pallas_call",
+                 "eva/eva_window/flash_bwd_dkv_dq/pallas_call",
+                 "eva/eva_stair/flash_bwd_dkv_dq_stair/pallas_call"):
+        assert any(s.endswith("checkpoint/" + want) for s in stacks), want
+        assert not [s for s in stacks if want in s and "rematted" in s]
+    assert any(re.search(r"transpose\(jvp\(.*eva_join", s) or (
+        "checkpoint/eva/eva_join" in s) for s in stacks)
+    found = components(stacks)
+    assert EVABYTE_SCOPES | EVA_KERNELS <= found
+    assert {"flash_fwd_stair", "flash_bwd_dkv_dq_stair"} <= found
+    assert "attention" not in found  # plain attention's scope is not EVA's
+    assert any("lm_head_ce" in s and "dot_general" in s for s in stacks)
+
+
 def test_sparse_attention_s_kernels_sit_under_its_scopes():
     """On the kernels' path `index_select` is under `indexer/index_select`,
     `flash_fwd_sparse` under `attention` and `index_loss` under
@@ -646,7 +710,7 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
 
     program = (TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
                | DSV2_SCOPES | NEMOTRON_SCOPES | KEYE_SCOPES | SOLAR_SCOPES
-               | PHI4FLASH_SCOPES)
+               | PHI4FLASH_SCOPES | EVABYTE_SCOPES | EVA_KERNELS)
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     # the benchmark's list is PR 25's three until a `benchmark` issue adds
     # the fourth (PERF.md section 7); its time share matches by prefix

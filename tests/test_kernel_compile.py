@@ -656,6 +656,64 @@ def test_the_mixer_s_passes_compile_at_the_cell_s_shapes(v5e, use):
                            "f32[8,4096]"]}
 
 
+@pytest.mark.timeout(600)  # six kernels, seconds each; room under six workers
+@pytest.mark.parametrize("use", ["forward", "backward"])
+def test_eva_attention_compiles_at_the_cell_s_shapes(v5e, use):
+    """`evabyte.tokens8k`: one sequence of 8,192 at 32 heads of 128, windows
+    of 2,048 and chunks of 16, bf16. The forward is one `eva_summaries_fwd`
+    that writes a sixteenth of k and of v, the causal `flash_fwd` on the 4
+    windows folded into the batch, and `flash_fwd_stair` over the 512
+    summaries; differentiated, each has its one backward kernel. No score
+    tensor of a window against itself, of the queries against the summaries
+    or of the sequence against itself is in either program, and the lse
+    that joins the parts is a column a row."""
+    import re
+
+    from ray_tpu.ops import eva
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    T, H, D = 8192, 32, 128
+    args = (*(sd((1, T, H, D)),) * 3, *(sd((H, D), jnp.float32),) * 2)
+
+    def out(q, k, v, phi, mu):
+        return eva.eva_attention(q, k, v, phi, mu, window=2048, chunk=16,
+                                 impl="pallas")[0]
+
+    def grads(*args):
+        return jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(),
+                        argnums=range(5))(*args)
+
+    # under "highest" too: the kernels pin one pass for narrow operands
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(out if use == "forward" else grads).lower(
+            *args).compile().as_text()
+    calls = {}
+    for name, made in _custom_calls(text):
+        kernel = re.match(r"[a-z_]+?(?=\.\d+$|$)", name).group(0)
+        calls[kernel] = re.findall(r"(?:bf16|f32)\[[\d,]+\]", made)
+    for pairs in ("2048,2048", "8192,512", "8192,8192", "2048,512",
+                  "2048,384"):
+        assert not re.search(r"\[(\d+,)*%s\]" % pairs, text), pairs
+    forward = {
+        "eva_summaries_fwd": ["bf16[1,512,4096]", "bf16[1,512,4096]"],
+        "flash_fwd": ["bf16[128,2048,128]", "f32[128,2048,8]"],
+        "flash_fwd_stair": ["bf16[32,8192,128]", "f32[32,8192,8]"]}
+    if use == "forward":
+        assert calls == forward
+        return
+    assert calls == {
+        **forward,
+        "eva_summaries_bwd": ["bf16[1,8192,4096]", "bf16[1,8192,4096]",
+                              "f32[1,4,8,4096]"],
+        "flash_bwd_dkv_dq": ["bf16[128,2048,128]"] * 3,
+        "flash_bwd_dkv_dq_stair": ["bf16[32,512,128]", "bf16[32,512,128]",
+                                   "bf16[32,8192,128]"]}
+
+
 def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
     """Arithmetic alone, `nemotron3nano.tokens8k` at 2 x 8192 tokens and a
     limit of 15.75 GiB. On the kernels' path the scan's part of a block's

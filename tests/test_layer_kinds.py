@@ -25,6 +25,8 @@ KDA = dict(kda_heads=4, kda_head_dim=8, kda_gate_rank=4, kda_chunk=16)
 MAMBA1 = dict(mamba1_inner=64, mamba1_state=8, mamba1_dt_rank=4, scan_chunk=8,
               layer_norm=True)
 DIFF = dict(attn_bias=True, layer_norm=True, rope=False, sliding_window=4)
+# two windows of 8 in the 16 tokens a case is traced with
+EVA = dict(n_kv_heads=4, eva_window=8, eva_chunk=4, norm_unit_offset=True)
 # {case: (the table, the record's name, the configuration's keys, the names
 # its forward makes: None for all the record has)}. The case of a record's
 # own name turns on every leaf it can have.
@@ -56,7 +58,10 @@ CASES = {
         DIFF, attn_bias=False), None),
     "cross_diff_attention": ("op", "cross_diff_attention", DIFF, None),
     "gmu": ("op", "gmu", MAMBA1, None),
+    "eva_attention": ("op", "eva_attention", EVA, None),
     "dense_ff": ("ff", "dense_ff", {}, None),
+    "dense_ff_unit_offset": ("ff", "dense_ff", dict(norm_unit_offset=True),
+                             None),
     "dense_ff_layer_norm": ("ff", "dense_ff", dict(layer_norm=True), None),
     "dense_ff_ungated": ("ff", "dense_ff", dict(ff_activation="relu2"),
                          {"mlp_up"}),
@@ -121,7 +126,8 @@ def test_the_tables_are_what_the_configuration_may_name():
         "full_attention", "sliding_attention", "sparse_attention",
         "latent_attention", "conv", "mamba2", "kda", "mamba1",
         "mamba1_emit", "diff_attention", "sliding_diff_attention",
-        "diff_attention_emit", "cross_diff_attention", "gmu"}
+        "diff_attention_emit", "cross_diff_attention", "gmu",
+        "eva_attention"}
     assert set(model._FEED_FORWARDS) == {"dense_ff", "routed_ff"}
     assert {record for _, record, _, _ in CASES.values()} == (
         set(model._OPERATORS) | set(model._FEED_FORWARDS))
@@ -257,8 +263,8 @@ def test_the_loss_has_what_the_records_say_their_readings_add():
         "aux_loss", "z_loss", "expert_load", "held_slots", "dropped_slots",
         "chip_load", "chip_load_max_over_mean", "index_loss",
         "index_keys_min_gap", "index_keys_max_gap", "kda_log_decay_min",
-        "kda_beta_mean", "diff_lambda", "ut_pass_loss", "exit_p_mean",
-        "exit_entropy"}
+        "kda_beta_mean", "diff_lambda", "eva_remote_mass",
+        "eva_chunk_entropy", "ut_pass_loss", "exit_p_mean", "exit_entropy"}
     assert len(set(model._STEP_READINGS)) == len(model._STEP_READINGS)
 
 

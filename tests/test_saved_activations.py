@@ -28,7 +28,7 @@ LIMIT = int(15.75 * 2**30)  # what a v5e chip offers a program
 CELLS = ("mistral7b.tokens4k", "mistral7b.fsdp4", "olmoe.tokens4k",
          "lfm2moe.tokens8k", "dsv2lite.tokens8k", "nemotron3nano.tokens8k",
          "lagunaxs2.tokens8k", "keyevl2.tokens16k", "mellum2.ep4",
-         "solaropen2.tokens8k", "phi4flash.tokens16k")
+         "solaropen2.tokens8k", "phi4flash.tokens16k", "evabyte.tokens8k")
 # the cells whose routed layers run over an `expert` mesh axis, and its size
 EXPERT_WAYS = {"mellum2.ep4": 4}
 # `bytes_limit` as the chip reports it (PERF.md, "Units"), and the names
@@ -53,6 +53,9 @@ KEPT = {
     # the rule's sum stands 2.1 GB over the chip's peak there (PERF.md
     # section 7): `mamba1_in` would fit
     "phi4flash.tokens16k": ("attn_ctx", "attn_res", "attn_qkv", "scan_out"),
+    # four walked layers: the fullest moment is the last layer's backward
+    "evabyte.tokens8k": ("attn_ctx", "eva_summaries", "attn_res", "attn_qkv",
+                         "mlp_gate"),
 }
 
 

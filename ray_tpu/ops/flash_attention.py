@@ -55,6 +55,28 @@ prices them), and they leave by dq's exit: as the row's blocks, or a key
 tile at a time by DMA under the group's last head. With as many key heads
 as query heads every grid, spec, scratch and body is the statement it was.
 
+Where v lies (`v_heads`). The model holds v and dv as `[B, S, Hk, Dv]`,
+which come from and go to a matmul as `[B, S, Hk Dv]`. Every kernel takes v
+(and hands back dv) either with its heads folded into the batch by a
+transpose, `[B Hk, S, Dv]`, or where it lies, `[B, S, Hk Dv]`, a reshape
+that moves nothing, with `v_heads = Hk` beside it: the grid keeps its
+(batch, head) rows, v's block is still `(1, tile, Dv)`, and the index map of
+row `bh` names batch row `bh // v_heads`, the tile, and column block
+`bh % v_heads` (`_head_block`). The folded layout is `v_heads` 1: the maps
+and the programs the ones they were, not a second path. A block's last
+dimension must be whole tiles of 128 lanes, so only a v whole tiles wide can
+stay where it lies, and the entries leave it there where q and k are whole
+tiles wide too and v has as many heads as q (`_to_kernels` has the chip's
+readings behind both conditions). q, k and o are always folded: they come
+from or go to a pass over `[B, T, H, D]` (a rotary turn, a norm, a join by
+head), which the compiler tiles with the heads on the sublanes, so
+`[B, T, H D]` is a copy of its own on the chip, while their transposes
+mostly ride inside that pass's fusion (PERF.md section 6, PR 65, has the
+chip's readings of all four pairs in place, and of this). lse and delta are
+`[B H, T, 8]` float32. The shape decides, the backward's log line says what
+it decided, and `ops/sparse_attention.py` folds for its own kernels' sake
+and hands these theirs folded.
+
 The four `pallas_call`s are named `flash_fwd` (with or without the lse
 output), `flash_bwd_dkv_dq`, `flash_bwd_dq` and `flash_bwd_dkv`: the names a
 profiler trace and the compiled HLO show, and the ones the benchmark's
@@ -772,13 +794,15 @@ def _compiler_params(tiles: FlashTiles, inner=("parallel", "arbitrary")):
 
 
 def _grid(kernel, q, k, v, causal, block_q, block_k, window=None,
-          mask=None, stair=None):
+          mask=None, stair=None, v_heads: int = 1):
     """(tiles, q tiles, k tiles, steps of the innermost grid dimension) of
-    `kernel` for q of [BH, T, D], k of [BHk, S, D] and v of [BHk, S, Dv];
-    `block_q`, `block_k` force a tile or are None."""
+    `kernel` for q of [BH, T, D], k of [BHk, S, D] and v of [BHk, S, Dv], or
+    of [B, S, v_heads Dv] where it lies; `block_q`, `block_k` force a tile
+    or are None."""
     T, S = q.shape[1], k.shape[1]
     tiles = flash_tiles(kernel, T, S, q.shape[2], q.dtype, causal=causal,
-                        block_q=block_q, block_k=block_k, v_dim=v.shape[2],
+                        block_q=block_q, block_k=block_k,
+                        v_dim=v.shape[2] // v_heads,
                         window=window, group=q.shape[0] // k.shape[0],
                         sparse=mask is not None, stair=stair)
     return (tiles, _cdiv(T, tiles.block_q), _cdiv(S, tiles.block_k),
@@ -797,12 +821,22 @@ def _kernel_name(kernel: str, window, mask=None, stair=None) -> str:
     return kernel if window is None else kernel + "_window"
 
 
+def _head_block(row, tile, heads: int = 1):
+    """The block of (batch, head) row `row`'s tile `tile` in an array
+    [B, rows, heads * D] walked in blocks `D` wide: the batch row, the tile
+    and, as the column block, the head. `heads` 1 is the layout with the
+    heads folded into the batch, [B H, rows, D], and the map it always had."""
+    if heads == 1:
+        return (row, tile, 0)
+    return (jax.lax.div(row, heads), tile, jax.lax.rem(row, heads))
+
+
 def _q_block(bh, qi, ki):
     return (bh, qi, 0)
 
 
 def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None,
-                     group: int = 1, stair=None):
+                     group: int = 1, stair=None, heads: int = 1):
     """Index map of K and V where k is walked innermost (forward, dq): under
     causal a step past the row's last tile with a body names that tile, the
     block already in VMEM, and fetches nothing. Under a window the walk
@@ -811,7 +845,8 @@ def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None,
     `group` query heads to a key-value head, heads folded batch-major, the
     key-value row of query row `bh` is `bh // group`. Under a staircase a
     step past the row's last tile with a body names that tile (the first,
-    for a row that has none)."""
+    for a row that has none). `heads`: v's map where v lies `[B, S, heads
+    Dv]` (`_head_block`)."""
     def k_block(bh, qi, ki):
         if stair is not None:
             seen = _stair_keys((qi + 1) * block_q - 1, stair)
@@ -823,7 +858,7 @@ def _k_block_under_q(causal, block_q, block_k, window=None, num_k=None,
                 num_k - 1)
         if causal:
             ki = jnp.minimum(ki, _last_k_with_body(qi, block_q, block_k))
-        return (bh if group == 1 else bh // group, ki, 0)
+        return _head_block(bh if group == 1 else bh // group, ki, heads)
 
     return k_block
 
@@ -885,15 +920,21 @@ def _mask_spec_under_q(mask, BH, block_q, k_block):
 
 
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
-               with_lse: bool = False, window=None, mask=None, stair=None):
+               with_lse: bool = False, window=None, mask=None, stair=None,
+               v_heads: int = 1):
+    """o [BH, T, Dv], or (o, lse [BH, T, 8]), of q [BH, T, D], k
+    [BHk, S, D] and v [BHk, S, Dv], or v where it lies, [B, S, v_heads Dv]:
+    a (batch, head) row of the grid then reads its head's columns of v's
+    batch row."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
-    S, Dv = k.shape[1], v.shape[2]
+    S, Dv = k.shape[1], v.shape[2] // v_heads
     block_k = _mask_key_tile(mask, block_k)
     tiles, num_q, num_k, steps = _grid(
-        "flash_fwd", q, k, v, causal, block_q, block_k, window, mask, stair)
+        "flash_fwd", q, k, v, causal, block_q, block_k, window, mask, stair,
+        v_heads)
     block_q, block_k = tiles.block_q, tiles.block_k
 
     kernel = functools.partial(
@@ -910,8 +951,10 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         window=window,
     )
 
-    k_block = _k_block_under_q(causal, block_q, block_k, window, num_k,
-                               group=BH // k.shape[0], stair=stair)
+    k_block, v_block = (
+        _k_block_under_q(causal, block_q, block_k, window, num_k,
+                         group=BH // k.shape[0], stair=stair, heads=n)
+        for n in (1, v_heads))
     out_shape = jax.ShapeDtypeStruct((BH, T, Dv), q.dtype)
     out_specs = pl.BlockSpec((1, block_q, Dv), _q_block)
     if with_lse:
@@ -923,7 +966,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
     in_specs = [
         pl.BlockSpec((1, block_q, D), _q_block),
         pl.BlockSpec((1, block_k, D), k_block),
-        pl.BlockSpec((1, block_k, Dv), k_block),
+        pl.BlockSpec((1, block_k, Dv), v_block),
     ]
     operands = (q, k, v)
     if mask is not None:
@@ -948,20 +991,22 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
 )
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret, keep_ctx,
-           window):
+           window, v_heads):
+    """o of q, k and v as `_flash_fwd` takes them: the residuals of its
+    backward are q, k, v as they were handed over, o and lse `[BH, T]`."""
     return _flash_fwd(
-        q, k, v, causal=causal, scale=scale, window=window,
+        q, k, v, causal=causal, scale=scale, window=window, v_heads=v_heads,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                   keep_ctx, window):
+                   keep_ctx, window, v_heads):
     o, lse = _flash_fwd(
-        q, k, v, causal=causal, scale=scale, window=window,
+        q, k, v, causal=causal, scale=scale, window=window, v_heads=v_heads,
         block_q=block_q, block_k=block_k, interpret=interpret, with_lse=True,
     )
     if keep_ctx:
@@ -987,10 +1032,11 @@ def _exit_said(kernel: str, tiles: FlashTiles) -> str:
 
 @functools.lru_cache(maxsize=None)
 def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
-                     window=None, group=1, stair=None):
+                     window=None, group=1, stair=None, v_heads=1):
     """One line for each backward a process traces, as `saved_activations`
-    has one for what it keeps: which kernels, at which tile and VMEM, and
-    how many query heads read a key-value head through the index maps. Under
+    has one for what it keeps: where v lies (`v_heads`), which kernels, at
+    which tile and VMEM, and how many query heads read a key-value head
+    through the index maps. Under
     a window also the forward's tile, and of every kernel the steps of its
     grid, the band's, and the share of them that have a body: the rest are
     the trailing steps of rows (columns) whose band crosses fewer tiles."""
@@ -1004,13 +1050,16 @@ def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
     if stair is not None:
         heads += ", under a staircase of %d keys a span of %d queries" % (
             stair[1], stair[0])
+    lie = ("folded by transpose: D %d, Dv %d" % (D, Dv) if v_heads == 1 else
+           "v and dv where the model holds them, [B, S, H Dv], H %d; q, k "
+           "and o folded" % v_heads)
     for kernel in kernels:
         t = tiles(kernel)
         logger.info(
-            "flash backward at T %d, S %d, D %d, Dv %d, %s: %s, tile %d x "
-            "%d, VMEM %d bytes of a limit of %d, %s%s", T, S, D, Dv, dtype,
-            kernel, t.block_q, t.block_k, t.vmem_bytes, t.vmem_limit_bytes,
-            heads, _exit_said(kernel, t))
+            "flash backward at T %d, S %d, D %d, Dv %d, %s, %s: %s, tile %d "
+            "x %d, VMEM %d bytes of a limit of %d, %s%s", T, S, D, Dv, dtype,
+            lie, kernel, t.block_q, t.block_k, t.vmem_bytes,
+            t.vmem_limit_bytes, heads, _exit_said(kernel, t))
     if window is None:
         return
     for kernel in ("flash_fwd", *kernels):
@@ -1023,7 +1072,7 @@ def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
-                   window, res, do):
+                   window, v_heads, res, do):
     """Tiled FlashAttention-2 backward, re-deriving each softmax tile from
     (q, k, lse) — nothing O(T·S) ever touches HBM (the previous recompute
     path materialized full f32 score matrices through XLA, which both OOMed
@@ -1036,12 +1085,14 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
         lse = jnp.broadcast_to(lse[..., None], (BH, T, 8))
     return _flash_backward(q, k, v, o, lse, do, causal=causal, scale=scale,
                            block_q=block_q, block_k=block_k,
-                           interpret=interpret, window=window)
+                           interpret=interpret, window=window,
+                           v_heads=v_heads)
 
 
 def _flash_backward(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
-                    interpret, window, dlse=None, stair=None):
-    """(dq, dk, dv) from the residuals and `do`, lse as `[BH, T, 8]`. With
+                    interpret, window, dlse=None, stair=None, v_heads=1):
+    """(dq, dk, dv) from the residuals and `do`, dv as v lies (`v_heads`),
+    lse as `[BH, T, 8]`. With
     `dlse` [BH, T] float32, lse's own cotangent (`_flash_lse`): a pair's
     `ds = p (dp - delta)` gains `p dlse`, so `delta` becomes `delta -
     dlse`, and the kernels are the ones they were."""
@@ -1052,16 +1103,17 @@ def _flash_backward(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
     # Same sublane-aligned [BH, T, 8] layout as lse.
     delta = jnp.broadcast_to(delta[..., None], (BH, T, 8))
     shape = (T, k.shape[1], q.shape[2])
-    group = BH // k.shape[0]
+    Dv, group = v.shape[2] // v_heads, BH // k.shape[0]
     kernels = flash_bwd_kernels(*shape, q.dtype, causal=causal,
                                 block_q=block_q, block_k=block_k,
-                                v_dim=v.shape[2], window=window, group=group,
+                                v_dim=Dv, window=window, group=group,
                                 stair=stair)
-    _log_bwd_kernels(kernels, *shape, v.shape[2], jnp.dtype(q.dtype).name,
+    _log_bwd_kernels(kernels, *shape, Dv, jnp.dtype(q.dtype).name,
                      causal, block_q, block_k, window=window, group=group,
-                     stair=stair)
+                     stair=stair, v_heads=v_heads)
     tile = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-                interpret=interpret, window=window, stair=stair)
+                interpret=interpret, window=window, stair=stair,
+                v_heads=v_heads)
     if kernels == ("flash_bwd_dkv_dq",):
         return _flash_bwd_dkv(q, k, v, do, lse, delta, with_dq=True, **tile)
     dq = _flash_bwd_dq(q, k, v, do, lse, delta, **tile)
@@ -1073,24 +1125,25 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11)
 )
 def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret, keep_ctx,
-               window, stair):
+               window, stair, v_heads):
     """(o [BH, T, Dv], lse [BH, T] float32): `_flash` that also hands out
     every row's log of its softmax's sum, which carries a cotangent of its
     own, so that two partial softmaxes can be joined outside
     (`ops/eva.py`). A row that sees no key (the first span of a staircase)
     has o 0 and lse near `_BIG_NEG`."""
     return _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k,
-                          interpret, keep_ctx, window, stair)[0]
+                          interpret, keep_ctx, window, stair, v_heads)[0]
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                   keep_ctx, window, stair):
+                   keep_ctx, window, stair, v_heads):
     o, lse = _flash_fwd(
         q, k, v, causal=causal, scale=scale, window=window, stair=stair,
         block_q=block_q, block_k=block_k, interpret=interpret, with_lse=True,
+        v_heads=v_heads,
     )
     lse = lse[..., 0]  # one column, as `_flash_vjp_fwd` keeps it
     if keep_ctx:
@@ -1100,7 +1153,7 @@ def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
-                   window, stair, res, cotangents):
+                   window, stair, v_heads, res, cotangents):
     q, k, v, o, lse = res
     do, dlse = cotangents
     BH, T, _ = q.shape
@@ -1108,7 +1161,7 @@ def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, keep_ctx,
         q, k, v, o, jnp.broadcast_to(lse[..., None], (BH, T, 8)), do,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret, window=window, dlse=dlse.astype(jnp.float32),
-        stair=stair)
+        stair=stair, v_heads=v_heads)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -1326,16 +1379,16 @@ def _round_out(*tiles):
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
                   block_q, block_k, interpret, window=None, mask=None,
-                  stair=None):
+                  stair=None, v_heads: int = 1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
-    S, Dv = k.shape[1], v.shape[2]
+    S, Dv = k.shape[1], v.shape[2] // v_heads
     block_k = _mask_key_tile(mask, block_k)
     tiles, num_q, num_k, steps = _grid(
         "flash_bwd_dq", q, k, v, causal, block_q, block_k, window, mask,
-        stair)
+        stair, v_heads)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(
         _attn_bwd_dq_kernel,
@@ -1345,12 +1398,14 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
         window=window,
     )
 
-    k_block = _k_block_under_q(causal, block_q, block_k, window, num_k,
-                               group=BH // k.shape[0], stair=stair)
+    k_block, v_block = (
+        _k_block_under_q(causal, block_q, block_k, window, num_k,
+                         group=BH // k.shape[0], stair=stair, heads=n)
+        for n in (1, v_heads))
     in_specs = [
         pl.BlockSpec((1, block_q, D), _q_block),
         pl.BlockSpec((1, block_k, D), k_block),
-        pl.BlockSpec((1, block_k, Dv), k_block),
+        pl.BlockSpec((1, block_k, Dv), v_block),
         pl.BlockSpec((1, block_q, Dv), _q_block),
         pl.BlockSpec((1, block_q, 8), _q_block),
         pl.BlockSpec((1, block_q, 8), _q_block),
@@ -1376,7 +1431,7 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, *, causal, scale,
 def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
                    block_q, block_k, interpret, with_dq: bool = False,
                    window=None, mask=None, by_tile: bool = False,
-                   stair=None):
+                   stair=None, v_heads: int = 1):
     """(dk, dv), or with `with_dq` the kernel `flash_bwd_dkv_dq` and
     (dq, dk, dv): one more output, whose block is a (batch, head) row's
     whole dq, fetched nowhere and written back when the row is done, and
@@ -1391,17 +1446,21 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     from the grid's coordinates: `(BHk, key tiles, group * steps)` without
     dq, `(BHk, group, key tiles, steps)` with it, where dk's and dv's
     blocks and sums are the key-value row's whole `S` rows too. A group of
-    one is the grid `(BH, key tiles, steps)` and the maps as they are."""
+    one is the grid `(BH, key tiles, steps)` and the maps as they are, and
+    there v may lie `[B, S, v_heads Dv]`: dv leaves so too, its key tile at
+    its head's columns (`_head_block`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
-    S, Dv = k.shape[1], v.shape[2]
+    S, Dv = k.shape[1], v.shape[2] // v_heads
     group = BH // k.shape[0]
+    if group > 1 and v_heads > 1:
+        raise ValueError("v lies where the model holds it only with no group")
     name = "flash_bwd_dkv_dq" if with_dq else "flash_bwd_dkv"
     block_k = _mask_key_tile(mask, block_k)
     tiles, num_q, num_k, steps = _grid(
-        name, q, k, v, causal, block_q, block_k, window, mask, stair)
+        name, q, k, v, causal, block_q, block_k, window, mask, stair, v_heads)
     block_q, block_k = tiles.block_q, tiles.block_k
     by_tile = with_dq and (by_tile or tiles.exit == "tile")
     kernel = functools.partial(
@@ -1444,10 +1503,17 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
             return lambda bkv, ki, walk: index_map(
                 bkv * group + walk // steps, ki, walk % steps)
 
+    v_block, dv_block = k_block, dk_block
+    if v_heads > 1:  # no group: the grid is (bh, ki, qi), dv's block as v's
+        def v_block(bh, ki, qi):
+            return _head_block(bh, ki, v_heads)
+
+        dv_block = v_block
+
     in_specs = [
         pl.BlockSpec((1, block_q, D), of_grid(q_block)),
         pl.BlockSpec((1, block_k, D), k_block),
-        pl.BlockSpec((1, block_k, Dv), k_block),
+        pl.BlockSpec((1, block_k, Dv), v_block),
         pl.BlockSpec((1, block_q, Dv), of_grid(q_block)),
         pl.BlockSpec((1, block_q, 8), of_grid(q_block)),
         pl.BlockSpec((1, block_q, 8), of_grid(q_block)),
@@ -1465,11 +1531,11 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         operands += (mask,)
     out_specs = [
         pl.BlockSpec((1, dk_rows, D), dk_block),
-        pl.BlockSpec((1, dk_rows, Dv), dk_block),
+        pl.BlockSpec((1, dk_rows, Dv), dv_block),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((BH // group, k_rows, D), k.dtype),
-        jax.ShapeDtypeStruct((BH // group, k_rows, Dv), v.dtype),
+        jax.ShapeDtypeStruct((v.shape[0], k_rows, v.shape[2]), v.dtype),
     ]
     scratch_shapes = [
         pltpu.VMEM((dk_rows, D), jnp.float32),
@@ -1529,6 +1595,50 @@ def _xla_attention_bhtd(q, k, v, *, causal, scale, window=None):
     return jnp.einsum("bts,bsd->btd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _to_kernels(q, k, v):
+    """(q, k, v, v_heads) as the kernels take q, k of [B, T, H, D] and v of
+    [B, S, Hk, Dv].
+
+    q and k go with their heads folded into the batch, batch-major, by a
+    transpose, `[B H, T, D]` beside `[B Hk, S, D]` (query row `bh` reads
+    key-value row `bh // (H / Hk)`), and o comes back so. q and k come from
+    a rotary turn or a norm by head and o goes to a gate or a join by head:
+    passes over `[B, T, H, D]`, which the compiler lays out with the heads
+    on a tile's sublanes, and which write and read the folded layout from
+    inside their fusions at little cost, where `[B, T, H D]`, tokens on the
+    sublanes, is a copy of its own and more (PERF.md section 6, PR 65: all
+    four in place cost `evabyte.tokens8k` 5.3 % of its rate). v comes from
+    a matmul and dv goes to one, as `[B, S, Hk Dv]`, which v's index map
+    addresses as it lies: with heads whole tiles of 128 lanes wide and as
+    many of them as q has, v goes where it lies, a reshape that moves
+    nothing, `v_heads = Hk`, and dv comes back there; elsewhere it is folded
+    like the rest, `v_heads` 1. (Under a group v is a `group`-th of q's
+    bytes and so is its transpose, while each of the group's heads reads
+    v's rows at the stride: the chip read nothing for it in
+    `mistral7b.tokens4k` and -0.05 % in `lagunaxs2.tokens8k`, so those keep
+    the programs they had. q's and k's width is asked beside v's although
+    only v's block needs whole tiles: at 192 beside a v of 128,
+    `dsv2lite.tokens8k`, whose v is a slice of a projection
+    `[B, T, H, 256]`, a pass over rank 4 again, the chip read -0.87 % with
+    v in place.) The shape decides. Counted as the call is
+    traced, a count a call site: `train.flash_calls_in_place` where v stays
+    where it lies, `train.flash_calls_folded` where it does not
+    (docs/observability.md)."""
+    H, D, Hk, Dv = *q.shape[2:], k.shape[2], v.shape[3]
+    if H % Hk:
+        raise ValueError(
+            f"{H} query heads do not divide among {Hk} key-value heads")
+
+    def folded(x):
+        return x.transpose(0, 2, 1, 3).reshape(-1, *x.shape[1::2])
+
+    if D % _LANES == 0 and Dv % _LANES == 0 and H == Hk:
+        tracing.count("train.flash_calls_in_place")
+        return folded(q), folded(k), v.reshape(*v.shape[:2], Hk * Dv), Hk
+    tracing.count("train.flash_calls_folded")
+    return folded(q), folded(k), folded(v), 1
+
+
 def flash_attention(
     q, k, v,
     *,
@@ -1550,23 +1660,20 @@ def flash_attention(
     `block_q` and `block_k` force every kernel's tile; `None` lets
     `flash_tiles` choose each kernel's from the shape. `keep_ctx` names the
     backward's residuals o and lse `attn_ctx` (`jax.ad_checkpoint`), for a
-    caller under `jax.checkpoint` whose policy keeps that name."""
+    caller under `jax.checkpoint` whose policy keeps that name.
+
+    With heads whole tiles of 128 lanes wide, as many of v as of q, the
+    kernels read v where the model holds it, `[B, S, H Dv]`, and write dv
+    there (`_to_kernels`): no transpose of either, forward, backward or
+    made again."""
     B, T, H, D = q.shape
-    Hk, Dv = k.shape[2], v.shape[3]
     window = _band(window, causal, k.shape[1])
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    if H % Hk:
-        raise ValueError(
-            f"{H} query heads do not divide among {Hk} key-value heads")
-    # [B, T, H, D] -> [B*H, T, D], heads folded batch-major: k and v stay at
-    # their own heads, and query row `bh` reads key-value row `bh // (H / Hk)`
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Hk, k.shape[1], D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Hk, v.shape[1], Dv)
+    qf, kf, vf, v_heads = _to_kernels(q, k, v)
     of = _flash(qf, kf, vf, causal, scale, block_q, block_k, interpret,
-                keep_ctx, window)
-    return of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
+                keep_ctx, window, v_heads)
+    return of.reshape(B, H, T, -1).transpose(0, 2, 1, 3)
 
 
 def flash_attention_lse(
@@ -1594,23 +1701,18 @@ def flash_attention_lse(
     `flash_fwd_stair`, `flash_bwd_dkv_dq_stair` (`flash_bwd_dq_stair`,
     `flash_bwd_dkv_stair`). A row that sees no key has o 0 and lse -inf."""
     B, T, H, D = q.shape
-    Hk, Dv = k.shape[2], v.shape[3]
     window = _band(window, causal, k.shape[1])
     if stair is not None and (causal or window is not None):
         raise ValueError("a staircase is its own mask: no causal, no window")
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    if H % Hk:
-        raise ValueError(
-            f"{H} query heads do not divide among {Hk} key-value heads")
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Hk, k.shape[1], D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Hk, v.shape[1], Dv)
+    qf, kf, vf, v_heads = _to_kernels(q, k, v)
     of, lse = _flash_lse(qf, kf, vf, causal, scale, block_q, block_k,
                          interpret, keep_ctx, window,
-                         None if stair is None else tuple(map(int, stair)))
+                         None if stair is None else tuple(map(int, stair)),
+                         v_heads)
     lse = jnp.where(lse > 0.5 * _BIG_NEG, lse, -jnp.inf)
-    return (of.reshape(B, H, T, Dv).transpose(0, 2, 1, 3),
+    return (of.reshape(B, H, T, -1).transpose(0, 2, 1, 3),
             lse.reshape(B, H, T).transpose(0, 2, 1))
 
 

@@ -581,7 +581,9 @@ def _log_path(T, H, Hk, D, Dv, dtype, topk, tile, block_q):
 
 
 def _heads_first(x):
-    """[B, T, H, D] as [B H, T, D], heads folded batch-major."""
+    """[B, T, H, D] as [B H, T, D], heads folded batch-major: what
+    `index_loss` reads beside the flash kernels, which take v folded too
+    (their `v_heads` 1, the default) whatever the heads' width."""
     B, T, H, D = x.shape
     return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
 

@@ -150,13 +150,19 @@ def test_tiles_take_both_widths():
 
 
 # sha256 of `str(jax.make_jaxpr(...))` of the three kernels under
-# `value_and_grad`, taken on PR 33's commit (1919b5e) with this function: at
-# equal widths the forward and the two-kernel backward are that commit's
-# programs. Since PR 35 the backward these shapes take is the one kernel,
-# so the test steers `flash_bwd_kernels` to the two it stands for.
+# `value_and_grad`. At heads of 64 the digest was taken on PR 33's commit
+# (1919b5e) with this function: at equal widths the forward and the
+# two-kernel backward are that commit's programs, and since PR 65 those of
+# every shape that folds all its arrays' heads into the batch. Since PR 35
+# the backward these shapes take is the one kernel, so the test steers
+# `flash_bwd_kernels` to the two it stands for. At heads of 128 the kernels
+# read v and write dv where the model holds them since PR 65 (no transpose
+# of either, a head a column block of their index maps; with one head the
+# parent's maps): those two were taken on PR 65's tree, whose results
+# `tests/test_flash_layout.py` holds bit for bit to the folded call's.
 PARENT_JAXPRS = {
-    (320, 2, 128): "1f852492114a85129969ffd5b520d5848bee60a9f99937f0779dddbcf9ff20b0",
-    (4096, 1, 128): "6c7e2da1c29c98dcd379f83465f45b14b68d98d364a117e9b2ab797325d8436a",
+    (320, 2, 128): "756b0ba51dbe50cb7a691de86847cc72e8d9e4b800410e35b09f401782e01819",
+    (4096, 1, 128): "ed0e2ac1648fada51ee260bfa59ca4da53c36d16facfc478279ad8f9529bd175",
     (1024, 2, 64): "70d0fd1c68a4a3944890fcb64e7f052ff9f09629391b4c099a7e3a72b0452cca",
 }
 

@@ -143,8 +143,18 @@ def test_a_layer_is_three_kernels_forward_and_three_backward(step):
                           "flash_fwd", "flash_fwd_stair", "flash_bwd_dkv_dq",
                           "flash_bwd_dkv_dq_stair")}
     assert calls == dict.fromkeys(calls, 4)
-    assert "bf16[128,2048,128]" in text  # the windows folded into the batch
-    assert "bf16[32,512,128]" in text    # the summaries, a head a row
+    # the windows folded into the batch, q, k and o with the heads folded in
+    # too; v where the model holds it, `[B, T, H D]`, and so the summaries'
+    # values (their keys are the staircase's k): no v lies folded
+    assert "bf16[128,2048,128]" in text and "bf16[32,512,128]" in text
+    calls = re.findall(
+        r"%flash_fwd(?:_stair)?\.\d+ = \(.*?\) custom-call\(.*?\), "
+        r"custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{(.*?)\}, frontend", text)
+    assert len(calls) == 8
+    assert {tuple(re.findall(r"bf16\[[\d,]+\]", call)) for call in calls} == {
+        ("bf16[128,2048,128]", "bf16[128,2048,128]", "bf16[4,2048,4096]"),
+        ("bf16[32,8192,128]", "bf16[32,512,128]", "bf16[1,512,4096]")}
 
 
 def test_no_score_tensor_is_in_the_compiled_step(step):

@@ -85,8 +85,8 @@ def backward(q, k, v, o, lse, do, window):
     traced, under a `jit` of its own: one program a call, and no cache
     between a case that steers the plan and one that does not."""
     return jax.jit(lambda *operands: fa._flash_vjp_bwd(
-        True, D ** -0.5, 128, 128, True, False, window, operands[:5],
-        operands[5]))(q, k, v, o, lse, do)
+        True, D ** -0.5, 128, 128, True, False, window, 1,
+        operands[:5], operands[5]))(q, k, v, o, lse, do)
 
 
 @functools.lru_cache(maxsize=None)

@@ -286,6 +286,58 @@ def test_the_one_backward_kernel_compiles_with_its_sums_alone(
         f"bf16[{rows},{t},{d}]", f"bf16[{rows},{t},{d}]", f"bf16[{bh},{t},{d}]"]
 
 
+# (B, T, S, heads, the call's mask) at heads of 128, v with q's heads
+V_IN_PLACE = {
+    "evabyte-window": (4, 2048, 2048, 32, dict(causal=True)),
+    "evabyte-stair": (1, 8192, 512, 32, dict(stair=(2048, 128))),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_block_or_chosen", "bwd_tile"])
+@pytest.mark.parametrize("cell", list(V_IN_PLACE))
+def test_the_kernels_compile_with_v_where_the_model_holds_it(
+        v5e, cell, kernel):
+    """`evabyte.tokens8k`'s two calls with v and dv as `[B, S, H Dv]`, a
+    head a column block of v's index map (what the entries hand over where
+    v has q's heads): rows of 256 bytes at a stride of `H Dv`, block by
+    block, in the forward and in `flash_bwd_dkv_dq` by both of dq's exits.
+    One custom call, dv as v lies."""
+    import re
+
+    b, t, s, h, mask = V_IN_PLACE[cell]
+    d = dv = 128
+    one = SingleDeviceSharding(v5e[0])
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    q, k, v, o = (sd(b * h, t, d), sd(b * h, s, d), sd(b, s, h * dv),
+                  sd(b * h, t, dv))
+    row = sd(b * h, t, 8, dtype=jnp.float32)
+    how = dict(scale=d ** -0.5, block_q=None, block_k=None, interpret=False,
+               v_heads=h, **{"causal": False, **mask})
+    if kernel == "fwd":
+        def fn(q, k, v, do, lse, delta):
+            return fa._flash_fwd(q, k, v, with_lse=True, **how)
+        outputs = [o]
+    else:
+        assert fa.flash_bwd_kernels(
+            t, s, d, jnp.bfloat16, causal=how["causal"],
+            stair=mask.get("stair")) == ("flash_bwd_dkv_dq",)
+
+        def fn(*a):
+            return fa._flash_bwd_dkv(*a, with_dq=True,
+                                     by_tile=kernel == "bwd_tile", **how)
+        outputs = [k, v, q]
+    text = jax.jit(fn).lower(q, k, v, o, row, row).compile().as_text()
+    ((name, made),) = re.findall(
+        r'%([\w.-]+) = \((.*?)\) custom-call\([^\n]*"tpu_custom_call"', text)
+    assert name.startswith("flash_fwd" if kernel == "fwd"
+                           else "flash_bwd_dkv_dq")
+    assert re.findall(r"bf16\[[\d,]+\]", made) == [
+        "bf16[%s]" % ",".join(map(str, x.shape)) for x in outputs]
+
+
 def test_lm_head_cross_entropy_compiles_for_v5e(v5e):
     one = SingleDeviceSharding(v5e[0])
     hidden = jax.ShapeDtypeStruct((32, 1024, 768), jnp.bfloat16, sharding=one)
@@ -709,8 +761,10 @@ def test_eva_attention_compiles_at_the_cell_s_shapes(v5e, use):
         **forward,
         "eva_summaries_bwd": ["bf16[1,8192,4096]", "bf16[1,8192,4096]",
                               "f32[1,4,8,4096]"],
-        "flash_bwd_dkv_dq": ["bf16[128,2048,128]"] * 3,
-        "flash_bwd_dkv_dq_stair": ["bf16[32,512,128]", "bf16[32,512,128]",
+        # dk, dv, dq: dv where v lies, `[B, S, H D]` (PR 65)
+        "flash_bwd_dkv_dq": ["bf16[128,2048,128]", "bf16[4,2048,4096]",
+                             "bf16[128,2048,128]"],
+        "flash_bwd_dkv_dq_stair": ["bf16[32,512,128]", "bf16[1,512,4096]",
                                    "bf16[32,8192,128]"]}
 
 

@@ -2,11 +2,14 @@
 """KDA's recurrence alone on the chip: `ops/kda.py`'s kernels against its
 `jax.numpy` form.
 
-    python3 benchmarks/kda_alone.py [--shapes cell,probe] [--paths xla,pallas] [--seed 0]
+    python3 benchmarks/kda_alone.py [--shapes cell,probe,kimi] [--paths xla,pallas] [--seed 0]
 
 At each shape (`cell`: the 8,192 tokens and 8 held heads of 128 a layer of
 `solaropen2.tokens8k` hands the recurrence; `probe`: the 2,048 tokens of the
-benchmark's `kda_rel_err`), with inputs as a KDA layer hands them at the
+benchmark's `kda_rel_err`; `kimi`: the 16,384 tokens and all 32 heads of a
+layer of `kimilinear.tokens16k`, a grid of (1, 16, 256), for which take
+`--paths pallas`: the `jax.numpy` form's pair tensors are 4 GB there), with
+inputs as a KDA layer hands them at the
 start of training (q and k of unit length, log decays of a thousandth to 1.6
 nats a token, beta on both sides of 1): the forward alone and the forward
 with the backward of all five inputs, each path under `jit`, the host's
@@ -33,7 +36,8 @@ import jax.numpy as jnp  # noqa: E402
 from ray_tpu.ops.kda import kda, kda_recurrent  # noqa: E402
 
 SHAPES = {"cell": dict(T=8192, H=8, dk=128, dv=128, chunk=64),
-          "probe": dict(T=2048, H=8, dk=128, dv=128, chunk=64)}
+          "probe": dict(T=2048, H=8, dk=128, dv=128, chunk=64),
+          "kimi": dict(T=16384, H=32, dk=128, dv=128, chunk=64)}
 HBM_BYTES_PER_S = 819e9  # a v5e's, as `chipbench/peaks.py` has it
 
 

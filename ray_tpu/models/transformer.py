@@ -347,7 +347,11 @@ class TransformerConfig:
     # layer is two sublayers, an operator and then a feed-forward
     sublayer_types: Tuple[str, ...] = ()
     d_head: Optional[int] = None  # a head's width; None => d_model / n_heads
-    rope: bool = True  # plain attention rotates q and k by position
+    # plain attention rotates q and k by position, latent attention a
+    # head's `qk_rope_head_dim` columns and the shared key; False
+    # (`use_rope` false, `mla_use_nope`): the columns enter the scores as
+    # they leave their projections, and `rope_scaling` scales nothing
+    rope: bool = True
     # "swiglu": silu(gate) * up; "relu2": relu(up)^2, ungated (no `w_gate`):
     # the dense, the shared and the routed experts' alike
     ff_activation: str = "swiglu"
@@ -397,6 +401,10 @@ class TransformerConfig:
     kda_conv_taps: int = 4
     kda_gate_rank: Optional[int] = None
     kda_chunk: int = 64
+    # beta = 2 sigmoid(W_b u) in (0, 2) (`kda_allow_neg_eigval`: the factor
+    # I - beta k k^T may turn a state's component along k); False: sigmoid,
+    # in (0, 1), the paper's
+    kda_allow_neg_eigval: bool = True
     # (first, n): plain attention and "kda" hold heads first..first+n-1 of
     # theirs, and attention the key-value heads those query heads read
     heads_held: Optional[Tuple[int, int]] = None
@@ -980,23 +988,30 @@ def _latent_attention_layer(x, blk, positions, cfg: TransformerConfig,
     multi-head attention, with q and k `qk_nope_head_dim + qk_rope_head_dim`
     wide and v `v_head_dim` wide. Rotary positions turn the last
     `qk_rope_head_dim` columns of a head of q, and the one key of that width
-    that `wkv_a` makes beside the latent and all heads share."""
+    that `wkv_a` makes beside the latent and all heads share; without
+    `cfg.rope` (`mla_use_nope`) nothing is turned or scaled: those columns
+    and that key stand in the scores as they leave their projections, and
+    the layers around this one carry position."""
     B, T, d = x.shape
     h, r = cfg.n_heads, cfg.kv_lora_rank
     nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     dt = cfg.dtype
-    scaling = dict(cfg.rope_scaling) if cfg.rope_scaling else None
+    scaling = dict(cfg.rope_scaling) if cfg.rope and cfg.rope_scaling else None
     with jax.named_scope("attn_qkv"):
         y = fused_rmsnorm(x, blk["attn_norm"], eps=cfg.norm_eps)
         q = checkpoint_name(y @ blk["wq"].astype(dt), "attn_qkv").reshape(
             B, T, h, nope + rope)
-        q = jnp.concatenate(
-            [q[..., :nope],
-             _rope(q[..., nope:], positions, cfg.rope_theta, scaling)], axis=-1)
+        if cfg.rope:
+            q = jnp.concatenate(
+                [q[..., :nope],
+                 _rope(q[..., nope:], positions, cfg.rope_theta, scaling)],
+                axis=-1)
     with jax.named_scope("kv_down"):
         down = checkpoint_name(y @ blk["wkv_a"].astype(dt), "attn_qkv")
         latent = fused_rmsnorm(down[..., :r], blk["kv_norm"], eps=cfg.norm_eps)
-        k_pe = _rope(down[..., None, r:], positions, cfg.rope_theta, scaling)
+        k_pe = down[..., None, r:]
+        if cfg.rope:
+            k_pe = _rope(k_pe, positions, cfg.rope_theta, scaling)
     with jax.named_scope("kv_up"):
         kv = checkpoint_name(latent @ blk["wkv_b"].astype(dt), "attn_qkv"
                              ).reshape(B, T, h, nope + dv)
@@ -1452,8 +1467,8 @@ def _kda_mixer(x, blk, cfg: TransformerConfig):
     `q`, `k`, `v` = silu(conv(norm(x) W)), a causal convolution of
     `kda_conv_taps` taps a channel without a bias, q and k then of unit
     length a head; the log decay a key channel `g = -exp(A_log)
-    softplus(W_f2 (W_f1 u) + dt_bias)` and `beta = 2 sigmoid(W_b u)` a
-    head, in float32; the recurrence in its chunked form (`ops/kda.py`);
+    softplus(W_f2 (W_f1 u) + dt_bias)` and `beta = 2 sigmoid(W_b u)` (without
+    `kda_allow_neg_eigval`, `sigmoid(W_b u)`) a head, in float32; the recurrence in its chunked form (`ops/kda.py`);
     `RMSNorm(o)` over each head's width with one learned scale, times
     `sigmoid(W_g2 (W_g1 u) + b_g)`; `W_o`. The three narrow products
     (`W_f1`, `W_g1`, `W_b`) are one matmul."""
@@ -1480,7 +1495,9 @@ def _kda_mixer(x, blk, cfg: TransformerConfig):
             jax.nn.softplus(
                 (f_low @ blk["kda_f2"].astype(dt)).astype(f32)
                 + blk["kda_dt_bias"].astype(f32)).reshape(B, T, H, dk))
-        beta = 2.0 * jax.nn.sigmoid(b_low.astype(f32))
+        beta = jax.nn.sigmoid(b_low.astype(f32))
+        if cfg.kda_allow_neg_eigval:
+            beta = 2.0 * beta
         gate = jax.nn.sigmoid(
             (g_low @ blk["kda_g2"].astype(dt)).astype(f32)
             + blk["kda_g_bias"].astype(f32)).astype(dt)

@@ -71,6 +71,10 @@ PHI4FLASH_SCOPES = {"mamba1", "mamba1_in", "mamba1_conv", "selective_scan",
 EVABYTE_SCOPES = {"eva", "eva_summaries", "eva_window", "eva_stair",
                   "eva_join"}
 EVA_KERNELS = {"eva_summaries_fwd", "eva_summaries_bwd"}
+# `ops/kda.py`'s two kernels: on the CPU the recurrence is the `jax.numpy`
+# form's, so no step lowered here names them (`tests/test_kernel_compile.py`
+# compiles them, and finds them by these names)
+KDA_KERNELS = {"kda_fwd", "kda_bwd"}
 # the routed layer's exchange over an `expert` mesh axis, inside
 # `mlp/shard_map` beside `moe_router` (PR 50)
 EXCHANGE_SCOPES = {"moe_gather", "moe_scatter"}
@@ -250,6 +254,28 @@ def lowered_solar_step():
         moe._ROW_TILE = row_tile
 
 
+def lowered_kimi_step():
+    """A dense KDA layer with beta in (0, 1), then routed KDA, latent
+    attention without positions and KDA: a share of the experts held under
+    a sigmoid router with its selection bias, one shared expert beside
+    them."""
+    row_tile = moe._ROW_TILE
+    moe._ROW_TILE = 8
+    try:
+        return lowered_transformer_step(
+            n_layers=4, n_kv_heads=None, rope=False,
+            layer_types=("kda", "kda", "latent_attention", "kda"),
+            kda_heads=4, kda_head_dim=8, kda_gate_rank=4, kda_chunk=16,
+            kda_allow_neg_eigval=False, kv_lora_rank=16, qk_nope_head_dim=8,
+            qk_rope_head_dim=4, v_head_dim=8, n_dense_layers=1, d_ff_dense=48,
+            n_experts=4, experts_per_token=2, experts_held=(1, 1),
+            n_shared_experts=1, router_score="sigmoid", expert_bias=True,
+            norm_topk_prob=True, routed_scaling_factor=2.446,
+            router_aux_loss_coef=0.0, router_z_loss_coef=0.0)
+    finally:
+        moe._ROW_TILE = row_tile
+
+
 def lowered_phi4flash_step():
     """One of each kind of a stack whose second half reads what its first
     half made: Mamba-1 and differential attention under a window, the two
@@ -379,6 +405,10 @@ FAMILIES = {
     "phi4flash": (lowered_phi4flash_step,
                   TRANSFORMER_SCOPES | {"sliding_attention"}
                   | PHI4FLASH_SCOPES),
+    # nothing of its own: KDA's scopes beside latent attention's in one step
+    "kimi_linear": (lowered_kimi_step,
+                    TRANSFORMER_SCOPES | (MOE_SCOPES - {"qk_norm"})
+                    | {"expert_bias"} | SOLAR_SCOPES | DSV2_SCOPES),
     "resnet": (lowered_resnet_step, RESNET_SCOPES),
 }
 # the scopes that one family alone has, but for those that another family
@@ -388,9 +418,11 @@ OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES,
               "mellum": EXCHANGE_SCOPES, "solar_open2": SOLAR_SCOPES,
               "phi4flash": PHI4FLASH_SCOPES,
               # no family above: `test_eva_attention_names_its_parts` lowers it
-              "evabyte": EVABYTE_SCOPES | EVA_KERNELS}
+              "evabyte": EVABYTE_SCOPES | EVA_KERNELS,
+              "kda's kernels": KDA_KERNELS}
 ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"},
-            "solar_open2": {"moe_shared"}}
+            "solar_open2": {"moe_shared"},
+            "kimi_linear": SOLAR_SCOPES | DSV2_SCOPES | {"expert_bias"}}
 
 
 @pytest.fixture(scope="module")
@@ -503,6 +535,32 @@ def test_the_lowered_step_holds_every_scope(stacks, family):
                    for s in stacks[family])
         assert any(s.startswith("attn_gate/") for s in stacks[family])
         assert any(s.startswith("mlp/moe_shared/") for s in stacks[family])
+    elif family == "kimi_linear":
+        found = stacks[family]
+        # each mixer's parts under its own scope, forward, made again and
+        # backward, in the dense layer's period and in the routed one's
+        for outer, parts in (
+                ("kda", ("kda_in", "kda_conv", "kda_gates", "kda_chunk",
+                         "kda_state", "kda_out")),
+                ("latent_attention", ("attn_qkv", "kv_down", "kv_up",
+                                      "attention", "attn_out"))):
+            for inner in parts:
+                for prefix in ("", "checkpoint/rematted_computation/",
+                               "checkpoint/"):
+                    assert any(s.startswith(f"{prefix}{outer}/{inner}/")
+                               for s in found), (outer, inner, prefix)
+        # nothing is rotated: no angle is made anywhere in the step
+        assert not any(re.search(r"/(cos|sin)$", s) for s in found)
+        assert any(re.search(r"/(cos|sin)$", s)
+                   for s in stacks["deepseek_v2"])
+        # no plain attention layer: `attention` stands under
+        # `latent_attention` alone
+        assert not any(s.startswith("attention/") for s in found)
+        # the leading dense feed-forward and the routed ones under `mlp`
+        assert any(s.startswith("mlp/moe_router/") for s in found)
+        assert any(s.startswith("mlp/while/body/moe_experts") for s in found)
+        assert any(s.startswith("mlp/moe_shared/") for s in found)
+        assert any(s.endswith("expert_bias/sign") for s in found)
     elif family == "nemotron_h":
         # the mixer's five parts inside `mamba`, forward, made again, and
         # backward; the scan's three inside `ssd`
@@ -710,7 +768,8 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
 
     program = (TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
                | DSV2_SCOPES | NEMOTRON_SCOPES | KEYE_SCOPES | SOLAR_SCOPES
-               | PHI4FLASH_SCOPES | EVABYTE_SCOPES | EVA_KERNELS)
+               | PHI4FLASH_SCOPES | EVABYTE_SCOPES | EVA_KERNELS
+               | KDA_KERNELS)
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     # the benchmark's list is PR 25's three until a `benchmark` issue adds
     # the fourth (PERF.md section 7); its time share matches by prefix

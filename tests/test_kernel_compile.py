@@ -556,26 +556,35 @@ def test_scan_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
         "f32[2,8,8,8192]", "bf16[2,8192,1024]", "bf16[2,8192,1024]"]
 
 
+# (tokens, heads) of a KDA layer's one sequence, heads of 128
+KDA_SHAPES = {"solaropen2.tokens8k": (8192, 8),
+              "kimilinear.tokens16k": (16384, 32)}
+
+
 @pytest.mark.parametrize("kernel", ["kda_fwd", "kda_bwd"])
-def test_kda_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
+@pytest.mark.parametrize("cell", list(KDA_SHAPES))
+def test_kda_kernel_compiles_at_the_cell_s_shapes(v5e, cell, kernel):
     """`solaropen2.tokens8k`: one sequence of 8,192 tokens, the 8 held heads
-    of 128, chunks of 64, bf16 with float32 log decays and beta. The forward
+    of 128; `kimilinear.tokens16k`: 16,384 tokens, all 32 heads (a grid of
+    (1, 16, 256), 4,096 steps a pass). Chunks of 64, bf16 with float32 log
+    decays and beta. The forward
     alone is one `kda_fwd` that writes o lane-dense, the last state and the
     decays' reach; differentiated, the forward rule's `kda_fwd` also writes
-    the 128 chunks' entering states in float32 and `kda_bwd` returns dq,
+    the chunks' entering states in float32 and `kda_bwd` returns dq,
     dk, dv, dg, dbeta's rows and the entering state's cotangent. No pair
     tensor (`[.., 16, 16, 128]`), no `[.., 64, 64]` matrix a chunk and no
     triangular solve is in either program."""
     import re
 
-    from ray_tpu.ops.kda import kda
+    from ray_tpu.ops.kda import kda, kda_untiled
 
     one = SingleDeviceSharding(v5e[0])
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    T, H, d = 8192, 8, 128
+    (T, H), d = KDA_SHAPES[cell], 128
+    assert kda_untiled(64, d, d, 2) is None  # the kernels take the shape
     args = (*[sd((1, T, H, d), jnp.bfloat16)] * 3,
             sd((1, T, H, d), jnp.float32), sd((1, T, H), jnp.float32))
 
@@ -593,16 +602,47 @@ def test_kda_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
              for name, out in _custom_calls(text)}
     assert not re.search(r"f32\[[\d,]*(16,16,128|64,64)\]", text)
     assert "InvertDiagBlocksLowerTriangular" not in text
-    states = ["f32[1,8,128,128]", "f32[1,4,128,1,128]"]
+    n, wide = T // 64, H * d
+    states = [f"f32[1,{H},128,128]", f"f32[1,{H // 2},{n},1,128]"]
+    tokens = f"[1,{T},{wide}]"
     if kernel == "kda_fwd":
-        assert calls == {"kda_fwd": ["bf16[1,8192,1024]", *states]}
+        assert calls == {"kda_fwd": ["bf16" + tokens, *states]}
         return
     assert set(calls) == {"kda_fwd", "kda_bwd"}
     assert calls["kda_fwd"] == [
-        "bf16[1,8192,1024]", *states, "f32[1,128,8,128,128]"]
+        "bf16" + tokens, *states, f"f32[1,{n},{H},128,128]"]
     assert calls["kda_bwd"] == [
-        "bf16[1,8192,1024]", "bf16[1,8192,1024]", "bf16[1,8192,1024]",
-        "f32[1,8192,1024]", "f32[1,4,128,1,128]", "f32[1,8,128,128]"]
+        "bf16" + tokens, "bf16" + tokens, "bf16" + tokens,
+        "f32" + tokens, f"f32[1,{H // 2},{n},1,128]", f"f32[1,{H},128,128]"]
+
+
+def test_the_flash_pair_compiles_at_kimi_s_latent_attention(v5e):
+    """`kimilinear.tokens16k`'s one latent-attention layer as the model
+    calls it: one sequence of 16,384 tokens, 32 heads, q and k 192 wide
+    (nothing rotated: the kernels see the same arrays) and v 128, through
+    `mha`: `flash_fwd` once and the whole backward as `flash_bwd_dkv_dq`,
+    and no `[T, T]` array anywhere."""
+    import re
+
+    one = SingleDeviceSharding(v5e[0])
+    T, H = 16384, 32
+    qk = jax.ShapeDtypeStruct((1, T, H, 192), jnp.bfloat16, sharding=one)
+    vo = jax.ShapeDtypeStruct((1, T, H, 128), jnp.bfloat16, sharding=one)
+    assert fa.flash_bwd_kernels(T, T, 192, jnp.bfloat16, v_dim=128) == (
+        "flash_bwd_dkv_dq",)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: fa.mha(
+            *a, causal=True, scale=192 ** -0.5, impl="pallas").astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(qk, qk, vo).compile().as_text()
+    # under `jit` alone the calls carry their transforms' names before
+    # the kernel's (`jvp_flash_fwd_`)
+    names = sorted(name for name, _ in _custom_calls(text))
+    assert len(names) == 2
+    assert "flash_fwd" in names[0] and "flash_bwd_dkv_dq" in names[1]
+    assert not re.search(r"(f32|bf16)\[(\d+,)*16384,16384\]", text)
 
 
 @pytest.mark.parametrize("kernel", ["selective_scan_fwd",
@@ -954,6 +994,51 @@ def test_token_step_without_a_limit_is_the_step_without_names(
     monkeypatch.setattr(tr, "checkpoint_name", lambda x, name: x)
     without_names, _ = _token_cell_step(cell_name, v5e, monkeypatch)
     assert text_of(without_names) == text_of(step.lowered)
+
+
+def test_kimi_step_compiles_fits_and_is_priced(token_steps):
+    """`kimilinear.tokens16k` at its real shapes with the chip's limit
+    handed to the keep rule: every name is kept, the compiler's plan fits
+    what a v5e offers a program with no `.remat` fusion made to fit, the
+    rule's sum stands at or over the plan and under the chip, and the step
+    runs KDA's kernels (four layers: forward, forward again, backward),
+    the flash pair once and the grouped-matmul kernels."""
+    import re
+
+    from chipbench import kimi_linear_flops, spec
+    from ray_tpu.models import transformer as tr
+
+    step = token_steps("kimilinear.tokens16k", limited=True)
+    assert tuple(step.chosen) == (
+        "attn_ctx", "attn_res", "attn_qkv", "kda_res", "kda_qkv",
+        "shared_gate", "shared_up", "mlp_gate", "mlp_up")
+    memory = step.compiled.memory_analysis()
+    config = spec.load_cell(spec.ROOT, "kimilinear.tokens16k")["config"]
+    n_params = kimi_linear_flops.state_params(config)
+    # 12 bytes a parameter of state: weights and AdamW's two moments; the
+    # gradients are in the program's scratch
+    assert memory.argument_size_in_bytes == pytest.approx(
+        12 * n_params, rel=0.01)
+    plan = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert 14.0e9 < plan < 15.84e9
+    text = step.compiled.as_text()
+    assert ".remat" not in text
+    cfg = spec.load_code(spec.ROOT, "loops", "kimi_linear").model_config(
+        {**config, "attention_impl": "pallas"})
+    terms = tr._terms(cfg, 16384, 4 * n_params)
+    predicted = 12 * n_params + terms.fullest(step.chosen).bytes
+    assert plan - 0.2e9 <= predicted <= HBM_LIMIT - tr._SAVE_RESERVE
+    # every layer traced apart (one period of four and the dense layer):
+    # a call site a layer
+    assert _calls(text, "kda_fwd") == 8 and _calls(text, "kda_bwd") == 4
+    assert _calls(text, "flash_fwd") == 1  # `attn_ctx` kept
+    assert _calls(text, "flash_bwd_dkv_dq") == 1
+    assert _calls(text, "moe_gmm") > 0 and _calls(text, "moe_tgmm") > 0
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert not re.search(r"(f32|bf16)\[(\d+,)*16384,16384\]", text)
+    # the chunks' entering states of a layer: 32 heads x 256 chunks, float32
+    assert "f32[1,256,32,128,128]" in text
 
 
 # ------------------- a block's weight matmuls from and to buffers of their own

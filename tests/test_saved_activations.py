@@ -28,7 +28,8 @@ LIMIT = int(15.75 * 2**30)  # what a v5e chip offers a program
 CELLS = ("mistral7b.tokens4k", "mistral7b.fsdp4", "olmoe.tokens4k",
          "lfm2moe.tokens8k", "dsv2lite.tokens8k", "nemotron3nano.tokens8k",
          "lagunaxs2.tokens8k", "keyevl2.tokens16k", "mellum2.ep4",
-         "solaropen2.tokens8k", "phi4flash.tokens16k", "evabyte.tokens8k")
+         "solaropen2.tokens8k", "phi4flash.tokens16k", "evabyte.tokens8k",
+         "kimilinear.tokens16k")
 # the cells whose routed layers run over an `expert` mesh axis, and its size
 EXPERT_WAYS = {"mellum2.ep4": 4}
 # `bytes_limit` as the chip reports it (PERF.md, "Units"), and the names
@@ -56,6 +57,11 @@ KEPT = {
     # four walked layers: the fullest moment is the last layer's backward
     "evabyte.tokens8k": ("attn_ctx", "eva_summaries", "attn_res", "attn_qkv",
                          "mlp_gate"),
+    # a dense KDA layer and one period of four walked layers: every name,
+    # the fullest moment the last layer's backward (KDA_PLAN)
+    "kimilinear.tokens16k": ("attn_ctx", "attn_res", "attn_qkv", "kda_res",
+                             "kda_qkv", "shared_gate", "shared_up",
+                             "mlp_gate", "mlp_up"),
 }
 
 

@@ -1,13 +1,19 @@
 #!/usr/bin/env python3
-"""The Mamba-2 mixer's two bandwidth passes alone on the chip:
-`ops/mamba_passes.py`'s kernels against its `jax.numpy` paths.
+"""The Mamba-2 mixer's two bandwidth passes, and the KDA mixer's short
+convolutions, alone on the chip: `ops/mamba_passes.py`'s kernels against
+its `jax.numpy` paths.
 
-    python3 benchmarks/mamba_passes_alone.py [--shapes cell,probe] [--passes conv,norm] [--paths xla,pallas] [--conv-blocks 512x512x32,...] [--norm-blocks 256x32,...] [--seed 0]
+    python3 benchmarks/mamba_passes_alone.py [--shapes cell,probe,kimi,solar] [--passes conv,norm,kda_conv] [--paths xla,pallas] [--conv-blocks 512x512x32,...] [--norm-blocks 256x32,...] [--seed 0]
 
 At each shape (`cell`: the 2 x 8,192 tokens a mixer of
 `nemotron3nano.tokens8k` hands them, the convolution over 6,144 channels of 4
 taps split 4,096 / 1,024 / 1,024, the norm over 8 groups of 512, bf16;
-`probe`: 2,048 tokens a row) and for each pass: the forward alone and the
+`probe`: 2,048 tokens a row; `kimi` and `solar`, pass `kda_conv` alone (PR
+67): a KDA layer's three streams q, k and v as `kimilinear.tokens16k` and
+`solaropen2.tokens8k` hand them, `[1, 16384, 4096]` and `[1, 8192, 1024]`,
+4 taps each, q's and k's heads of 128 then at unit length: three calls, as
+`models/transformer.py` `_kda_mixer` makes them) and for each pass: the
+forward alone and the
 forward with the backward of every input (`jax.vjp` under one `jit`), the
 host's clock over 10 calls after one that compiles; the bytes the
 mathematics has to move (each operand read once and each result written
@@ -36,6 +42,9 @@ from ray_tpu.ops import mamba_passes as lib  # noqa: E402
 SHAPES = {
     "cell": dict(B=2, T=8192, splits=(4096, 1024, 1024), taps=4, groups=8),
     "probe": dict(B=2, T=2048, splits=(4096, 1024, 1024), taps=4, groups=8),
+    # a KDA layer's streams: `splits` the three calls' widths
+    "kimi": dict(B=1, T=16384, splits=(4096,) * 3, taps=4, unit=128),
+    "solar": dict(B=1, T=8192, splits=(1024,) * 3, taps=4, unit=128),
 }
 EPS = 1e-5
 HBM_BYTES_PER_S = 819e9  # a v5e's, as `chipbench/peaks.json` has it
@@ -54,6 +63,20 @@ def conv_inputs(shape, seed, dtype=jnp.bfloat16):
                   for k, width in zip(ks[3:], splits)))
 
 
+def kda_conv_inputs(shape, seed, dtype=jnp.bfloat16):
+    """((q, k, v, the three streams' taps), a cotangent a stream)."""
+    (x, w, _), cts = conv_inputs(shape, seed, dtype)
+    return ((*jnp.split(x, 3, axis=-1),
+             jnp.stack(jnp.split(w, 3, axis=-1))), cts)
+
+
+def kda_conv(q, k, v, taps, *, unit, path):
+    return tuple(
+        lib.causal_conv_silu(s, taps[i], unit=unit * (i < 2),
+                             name="kda_conv", impl=path)
+        for i, s in enumerate((q, k, v)))
+
+
 def norm_inputs(shape, seed, dtype=jnp.bfloat16):
     B, T, inner = shape["B"], shape["T"], shape["splits"][0]
     ks = jax.random.split(jax.random.PRNGKey(seed + 1), 4)
@@ -69,8 +92,8 @@ def needed_bytes(name, shape, backward: bool, item: int = 2) -> int:
     the result written; with the backward y, z and the cotangent read, dy
     and dz written. The parameters' few KB are left out."""
     B, T, splits = shape["B"], shape["T"], shape["splits"]
-    width = sum(splits) if name == "conv" else splits[0]
-    arrays = ((2, 3) if name == "conv" else (3, 5))
+    width = splits[0] if name == "norm" else sum(splits)
+    arrays = ((3, 5) if name == "norm" else (2, 3))
     return (arrays[0] + backward * arrays[1]) * B * T * width * item
 
 
@@ -109,9 +132,13 @@ def main():
     # `--conv-blocks` or `--norm-blocks` entry names them
     knobs = {"conv": ("_CONV_TOKENS", "_CONV_CHANNELS", "_ROWS"),
              "norm": ("_NORM_TOKENS", "_ROWS")}
+    knobs["kda_conv"] = knobs["conv"]
+    inputs = {"conv": conv_inputs, "norm": norm_inputs,
+              "kda_conv": kda_conv_inputs}
     own = {name: tuple(getattr(lib, k) for k in names)
            for name, names in knobs.items()}
     swept = {"conv": blocks(args.conv_blocks), "norm": blocks(args.norm_blocks)}
+    swept["kda_conv"] = swept["conv"]
 
     def take(name, block):
         for knob, value in zip(knobs[name], block):
@@ -120,8 +147,9 @@ def main():
     for shape_name in args.shapes.split(","):
         shape = SHAPES[shape_name]
         for name in args.passes.split(","):
-            operands, cts = (conv_inputs if name == "conv" else norm_inputs)(
-                shape, args.seed)
+            if (name == "kda_conv") != ("unit" in shape):
+                continue  # a KDA layer's shapes take its pass alone
+            operands, cts = inputs[name](shape, args.seed)
             runs = [(path, block) for path in args.paths.split(",")
                     for block in ([own[name]] if path != "pallas" else
                                   [own[name]] + [b for b in swept[name]
@@ -133,6 +161,9 @@ def main():
                     def forward(*a, path=path):
                         return lib.causal_conv_silu(
                             *a, splits=shape["splits"], impl=path)
+                elif name == "kda_conv":
+                    def forward(*a, path=path):
+                        return kda_conv(*a, unit=shape["unit"], path=path)
                 else:
                     def forward(*a, path=path):
                         return lib.gated_group_rmsnorm(

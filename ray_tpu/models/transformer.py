@@ -261,7 +261,8 @@ from ray_tpu.ops.eva import eva_attention
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
 from ray_tpu.ops.mamba_passes import (
-    causal_conv_silu, gated_group_rmsnorm, norm_untiled)
+    _log_pass, causal_conv_silu, conv_untiled, gated_group_rmsnorm,
+    norm_untiled)
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
 from ray_tpu.ops.selective_scan import (
     channel_block, selective_scan, selective_scan_untiled)
@@ -1461,6 +1462,36 @@ def _unit_length(x, eps: float = 1e-6):
         jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)).astype(x.dtype)
 
 
+def _kda_conv_kernels(cfg: TransformerConfig,
+                      T: Optional[int] = None) -> bool:
+    """Whether the KDA mixer's short convolutions run as
+    `ops/mamba_passes.py`'s kernels (`kda_conv_fwd`, `kda_conv_bwd`): the
+    operators resolve to Pallas and the shape tiles."""
+    return _kernel_impl(cfg) == "pallas" and not _kda_conv_untiled(cfg, T)
+
+
+def _kda_conv_untiled(cfg: TransformerConfig, T: Optional[int] = None):
+    return conv_untiled(
+        cfg.kda_conv_taps, (_OPERATORS["kda"].heads(cfg) * cfg.kda_head_dim,),
+        T, cfg.kda_head_dim)
+
+
+def _kda_conv_calls_said(before) -> str:
+    """What a trace added to the counts of the KDA mixers' short
+    convolutions since the counters read `before`, by the path each call
+    took, for the step's log line; nothing where no such layer was traced."""
+    now = tracing.counters()
+    kernels, numpy = (
+        now.get(name, 0) - before.get(name, 0)
+        for name in ("train.kda_conv_calls_kernels",
+                     "train.kda_conv_calls_numpy"))
+    if not kernels + numpy:
+        return ""
+    return ("; KDA's short convolutions: %d calls by the kernels "
+            "kda_conv_fwd and kda_conv_bwd, %d by jax.numpy" % (
+                kernels, numpy))
+
+
 def _kda_mixer(x, blk, cfg: TransformerConfig):
     """(the KDA mixer on `x` [B, T, d], its readings {kda_log_decay_min,
     kda_beta_mean}) over the `H` heads the layer holds (arXiv:2510.26692):
@@ -1486,9 +1517,27 @@ def _kda_mixer(x, blk, cfg: TransformerConfig):
         f_low, g_low, b_low = jnp.split(narrow, (rank, 2 * rank), axis=-1)
     with jax.named_scope("kda_conv"):
         taps = blk["kda_conv"].astype(dt)  # [3, taps, H dk]: q's, k's, v's
-        q, k, v = (jax.nn.silu(_causal_taps(s, taps[i])).reshape(B, T, H, dk)
-                   for i, s in enumerate((q, k, v)))
-        q, k = _unit_length(q), _unit_length(k)
+        if _kda_conv_kernels(cfg, T):
+            # a stream's taps, silu and (q, k) unit length a head: one read
+            # and one write of it, a call a stream (`ops/mamba_passes.py`)
+            q, k, v = (causal_conv_silu(
+                s, taps[i], unit=dk * (i < 2), name="kda_conv",
+                impl=_kernel_impl(cfg)).reshape(B, T, H, dk)
+                for i, s in enumerate((q, k, v)))
+            tracing.count("train.kda_conv_calls_kernels", 3)
+        else:
+            # the `jax.numpy` lines stand here and not behind the call: the
+            # cells' comparisons are tried on this text with the taps or the
+            # unit length taken out (tests/chipbench_tests/
+            # test_chipbench_solar_open2.py, test_chipbench_kimi_linear.py)
+            q, k, v = (jax.nn.silu(_causal_taps(s, taps[i])
+                                   ).reshape(B, T, H, dk)
+                       for i, s in enumerate((q, k, v)))
+            q, k = _unit_length(q), _unit_length(k)
+            _log_pass("kda_conv", _kernel_impl(cfg) == "pallas",
+                      _kda_conv_untiled(cfg, T), (B, T, H * dk),
+                      (cfg.kda_conv_taps, (H * dk,), dk), jnp.dtype(dt).name)
+            tracing.count("train.kda_conv_calls_numpy", 3)
     with jax.named_scope("kda_gates"):
         f32 = jnp.float32
         log_decay = -jnp.exp(blk["kda_A_log"].astype(f32))[:, None] * (
@@ -2315,7 +2364,9 @@ class _KDA(Sublayer):
     def holds(self, cfg):
         """In elements of the compute dtype a token, `wide` the held heads'
         width: q, k and v out of the convolution and its silu, q and k at
-        unit length, the gate and the gated output (7 wide), and by the
+        unit length, the gate and the gated output (7 wide; 5 where the
+        convolutions run as `kda_conv_fwd`, which writes q and k at unit
+        length and leaves the silu's in VMEM), and by the
         path `kda` takes (`ops/kda.py`) the recurrence's own. The kernels:
         their result and the cotangents of o, q, k and v (5 wide), the log
         decay and its cotangent in float32, and a chunk's entering state
@@ -2331,11 +2382,12 @@ class _KDA(Sublayer):
         wide, dk = self.heads(cfg) * cfg.kda_head_dim, cfg.kda_head_dim
         f32 = 4 // _item(cfg) or 1
         states = f32 * wide * dk // cfg.kda_chunk
+        streams = (5 if _kda_conv_kernels(cfg) else 7) * wide
         if _kernel_impl(cfg) == "pallas" and not kda_untiled(
                 cfg.kda_chunk, dk, dk, _item(cfg)):
-            return 12 * wide + 2 * f32 * wide + states
+            return streams + 5 * wide + 2 * f32 * wide + states
         copies = 2 + 3 + cfg.kda_chunk // _KDA_SUB
-        return (7 * wide + 2 * f32 * copies * wide
+        return (streams + 2 * f32 * copies * wide
                 + 4 * f32 * _KDA_SUB * wide + states + f32 * wide)
 
     def flops(self, cfg, seq_len):
@@ -4325,20 +4377,22 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
             state["params"]["blocks"], cfg)
         tracing.count("train.own_buffers", buffers)
         tracing.count("train.own_buffer_bytes", their_bytes)
-        logger.info(
+        return kept, (
             "train step %s; its blocks' weight matmuls read and write %d "
             "buffers of their own, %d bytes in %s over %d layers (%d the "
-            "widest layer's weights)", keeps, buffers, their_bytes,
-            jnp.dtype(cfg.dtype).name, cfg.n_layers, widest)
-        return kept
+            "widest layer's weights)" % (
+                keeps, buffers, their_bytes, jnp.dtype(cfg.dtype).name,
+                cfg.n_layers, widest))
 
     @partial(jax.jit, donate_argnums=(0,), out_shardings=(state_shard, repl))
     def step(state, batch):
         bias = ({"expert_bias": state["expert_bias"]} if cfg.expert_bias
                 else {})
+        kept, said = saved_names(state, batch)
+        calls = tracing.counters()
         (loss, readings), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state["params"], batch, saved_names=saved_names(state, batch),
-            **bias)
+            state["params"], batch, saved_names=kept, **bias)
+        logger.info(said + _kda_conv_calls_said(calls))
         with jax.named_scope("optimizer"):
             updates, opt = optimizer.update(
                 grads, state["opt"], state["params"]

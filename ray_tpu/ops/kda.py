@@ -107,6 +107,17 @@ blocks and VMEM, or why the shape went to `jax.numpy`):
   the entering states reaches HBM. Heads are padded to whole groups with
   heads of `g = 0` and `beta = 0`. `benchmarks/kda_alone.py` times both
   paths alone.
+
+What hands `kda` its q, k and v is the mixer's, not this module's: the
+short convolution, its silu and q's and k's unit length a head
+(`models/transformer.py` `_kda_mixer`, scope `kda_conv`). By the same two
+conditions (the operators resolve to Pallas, the streams tile:
+`ops/mamba_passes.py` `conv_untiled`) that pass runs as the kernel pair
+`kda_conv_fwd` and `kda_conv_bwd`, `ops/mamba_passes.py`
+`causal_conv_silu(..., unit=dk, name="kda_conv")`, a call a stream, each
+stream read once and written once; elsewhere as the mixer's `jax.numpy`
+lines. `train.kda_conv_calls_kernels` and `train.kda_conv_calls_numpy`
+count the calls of each (docs/observability.md; PERF.md section 6, PR 67).
 """
 
 from __future__ import annotations

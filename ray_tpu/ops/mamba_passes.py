@@ -1,4 +1,5 @@
-"""The two bandwidth passes of a Mamba-2 mixer, each as one pass over HBM.
+"""The two bandwidth passes of a Mamba-2 mixer, each as one pass over HBM,
+and with the first the KDA mixer's short convolutions.
 
 Beside its matmuls and its scan (`ops/ssd.py`) the mixer runs two passes
 that multiply no matrix, and whose time is the bytes they move:
@@ -7,7 +8,11 @@ that multiply no matrix, and whose time is the bytes they move:
   bias)` a channel of `x` [B, T, C], `w` [taps, C], `x` zero before the
   sequence: the short convolution over x, B and C, handed on as the arrays
   the scan takes (`splits`: the channels' widths, `[B, T, inner]` and twice
-  `[B, T, G N]`).
+  `[B, T, G N]`). With `unit`, each head of `unit` channels of the silu's
+  result then over its own length, `out rsqrt(sum out^2 + 1e-6)` in float32:
+  the KDA mixer's q and k (`models/transformer.py` `_kda_mixer`, scope
+  `kda_conv`: three calls a layer, q's and k's with `unit = kda_head_dim`,
+  v's without, no bias, under `name="kda_conv"`; PR 67).
 - **`gated_group_rmsnorm(y, z, weight, groups, eps)`**: `GroupRMSNorm(y *
   silu(z))`, the mean square over each of `groups` groups of channels, one
   learned scale a channel.
@@ -25,7 +30,9 @@ line once a shape says which (`_log_pass`).
   `nemotron3nano.tokens8k` for 11.5 GB of operands and results (PERF.md
   section 6, PR 63).
 - **Kernel pairs behind a `custom_vjp`: `mamba_conv_fwd`, `mamba_conv_bwd`,
-  `mamba_norm_fwd`, `mamba_norm_bwd`.** Each reads an operand once and
+  `mamba_norm_fwd`, `mamba_norm_bwd`; the convolution's pair under the
+  caller's `name`, `kda_conv_fwd` and `kda_conv_bwd` for the KDA mixer.**
+  Each reads an operand once and
   writes a result once; every intermediate is float32 in VMEM; the
   residuals are the inputs alone, so the backward makes the pre-activation
   and the groups' statistics again.
@@ -47,6 +54,19 @@ line once a shape says which (`_log_pass`).
   summed in float32 in one output block a block of channels, eight
   partial rows each, that stays in VMEM across the batch rows and the
   blocks of tokens; the eight are summed outside.
+
+  The unit length (`unit`, a static argument: without it the body is the
+  one above to the equation) is one more step behind the silu, on a trip's
+  float32 rows in VMEM: a block of channels holds whole heads, each a
+  lane tile or several, and a head's sum of squares is a sum over its
+  lanes. The backward makes the silu and the length again and takes the
+  cotangent through them, `d act = (dout - out sum(dout out)) / length` a
+  head, before the silu's slope. A trip takes `_UNIT_ROWS` tokens there
+  and not `_ROWS`: the sums over lanes want more rows in flight (PERF.md
+  section 6, PR 67). Under autodiff the `jax.numpy` lines kept float32
+  arrays of the streams' width round the norm beside the taps' arrays:
+  56.1 GB a step through HBM in `kimilinear.tokens16k` for 11.3 GB of
+  operands and results.
 
   The norm's grid is (batch row, block of tokens) over whole rows of
   `inner`; a trip takes `_ROWS` tokens of one group at a time. The product
@@ -84,6 +104,10 @@ _CONV_TOKENS = 1024
 _CONV_CHANNELS = 512
 _NORM_TOKENS = 256
 _ROWS = 32
+# a trip's tokens where heads go to unit length: a head's sums over its lanes
+# want more rows in flight (the sweep: PERF.md section 6, PR 67)
+_UNIT_ROWS = 128
+_UNIT_EPS = 1e-6  # under a head's unit length, as the model's `_unit_length`
 
 
 def _largest(size: int, step: int, most: int) -> int:
@@ -99,6 +123,34 @@ def _silu_and_slope(v):
     return v * s, s * (1.0 + v * (1.0 - s))
 
 
+def _by_head(unit, fn, *arrays):
+    """`fn` of each head's `unit` lanes of `arrays` `[n, w]`, the heads' results
+    side by side again: whole lane tiles cut and joined."""
+    return jnp.concatenate(
+        [fn(*(a[:, at:at + unit] for a in arrays))
+         for at in range(0, arrays[0].shape[1], unit)], axis=1)
+
+
+def _inverse_length(act):
+    """`1 / length` of a head `[n, unit]`, `[n, 1]`: `models/transformer.py`
+    `_unit_length`'s arithmetic, float32."""
+    return jax.lax.rsqrt(
+        jnp.sum(act * act, axis=-1, keepdims=True) + _UNIT_EPS)
+
+
+def _unit_length(act):
+    """A head `[n, unit]` over its own length."""
+    return act * _inverse_length(act)
+
+
+def _unit_length_slope(act, dout):
+    """`d act` of a head's `_unit_length(act)` under the cotangent `dout`:
+    `(dout - out sum(dout out)) / length`."""
+    scale = _inverse_length(act)
+    out = act * scale
+    return scale * (dout - out * jnp.sum(dout * out, axis=-1, keepdims=True))
+
+
 def _folded(v):
     """`[8, w]`: the sum of `v`'s tiles of 8 rows, whole registers added."""
     return functools.reduce(
@@ -108,10 +160,15 @@ def _folded(v):
 # ------------------------------------------------------------ convolution
 
 def causal_conv_silu(x, w, bias=None, *, splits: Optional[Sequence[int]] = None,
+                     unit: int = 0, name: str = "mamba_conv",
                      impl: str = "auto", interpret: bool = False):
     """`silu(sum_i w_i x_(t - taps + 1 + i) + bias)` of `x` [B, T, C], `w`
     [taps, C] and `bias` [C] or None, in `x`'s dtype: one array, or with
     `splits` (widths that sum to C) a tuple of the arrays of those channels.
+    With `unit`, each head of `unit` channels of the silu's result over its
+    own length, `out rsqrt(sum out^2 + 1e-6)` in float32 (KDA's q and k,
+    `models/transformer.py` `_kda_mixer`). `name` is the caller's: the
+    kernels are `<name>_fwd` and `<name>_bwd`, under the scope `name`.
 
     impl: 'auto' (the kernels on TPU, `jax.numpy` elsewhere) | 'pallas' |
     'xla'; `interpret` runs the kernels in interpret mode, for tests. A
@@ -122,50 +179,63 @@ def causal_conv_silu(x, w, bias=None, *, splits: Optional[Sequence[int]] = None,
     if sum(widths) != C:
         raise ValueError(f"splits {widths} do not sum to {C} channels")
     kernels = resolve_impl(impl) == "pallas" or interpret
-    untiled = conv_untiled(w.shape[0], widths, T)
-    _log_pass("causal_conv_silu", kernels, untiled, (B, T, C),
-              (w.shape[0], widths), jnp.dtype(x.dtype).name)
+    untiled = conv_untiled(w.shape[0], widths, T, unit)
+    _log_pass(name, kernels, untiled, (B, T, C),
+              (w.shape[0], widths, unit), jnp.dtype(x.dtype).name)
     if kernels and not untiled:
         if bias is None:
             bias = jnp.zeros((C,), _F32)
-        out = _conv(x, w, bias, widths, conv_blocks(T, widths), interpret)
+        out = _conv(x, w, bias, name, widths, conv_blocks(T, widths, unit),
+                    unit, interpret)
     else:
-        out = _conv_numpy(x, w, bias, widths)
+        out = _conv_numpy(x, w, bias, widths, unit)
     return out if splits is not None else out[0]
 
 
-def _conv_numpy(x, w, bias, widths):
+def _conv_numpy(x, w, bias, widths, unit=0):
     """The shifted multiply-adds in `x`'s dtype, as `models/transformer.py`
-    `_causal_taps` has them, the bias, the silu and the split."""
+    `_causal_taps` has them, the bias, the silu, the heads' unit length as
+    its `_unit_length` has it, and the split."""
     taps = w.shape[0]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     pre = sum(w[i] * jax.lax.dynamic_slice_in_dim(padded, i, x.shape[1], 1)
               for i in range(taps))
     if bias is not None:
         pre = pre + bias.astype(x.dtype)
+    out = jax.nn.silu(pre)
+    if unit:
+        heads = out.astype(_F32).reshape(*out.shape[:2], -1, unit)
+        out = _unit_length(heads).astype(out.dtype).reshape(out.shape)
     ends = [sum(widths[:k + 1]) for k in range(len(widths) - 1)]
-    return tuple(jnp.split(jax.nn.silu(pre), ends, axis=-1))
+    return tuple(jnp.split(out, ends, axis=-1))
 
 
-def conv_blocks(T: int, widths: Sequence[int]) -> Tuple[int, int, int]:
+def conv_blocks(T: int, widths: Sequence[int],
+                unit: int = 0) -> Tuple[int, int, int]:
     """(tokens a grid step, channels a grid step, tokens a trip) of the
     convolution's kernels: a block of channels divides every split's
-    width."""
+    width, and holds whole heads of `unit`."""
     tokens = _largest(T, _HALO, _CONV_TOKENS)
-    return (tokens, _largest(math.gcd(*widths), _LANES, _CONV_CHANNELS),
-            _largest(tokens, _HALO, _ROWS))
+    return (tokens,
+            _largest(math.gcd(*widths), unit or _LANES, _CONV_CHANNELS),
+            _largest(tokens, _HALO, _UNIT_ROWS if unit else _ROWS))
 
 
-def conv_untiled(taps: int, widths: Sequence[int],
-                 T: Optional[int] = None) -> Optional[str]:
+def conv_untiled(taps: int, widths: Sequence[int], T: Optional[int] = None,
+                 unit: int = 0) -> Optional[str]:
     """Why the convolution's kernels cannot take `taps` taps and splits of
-    `widths` channels (and rows of `T` tokens, where they are known), or
-    None where they can: every split whole tiles of 128 lanes, the taps
-    within the rows a block takes before it, and the tokens whole blocks of
-    16 rows (a tile of bf16 sublanes)."""
+    `widths` channels (and rows of `T` tokens, where they are known; heads
+    of `unit` channels), or None where they can: every split whole tiles of
+    128 lanes, a head whole tiles too and within a block of channels, the
+    taps within the rows a block takes before it, and the tokens whole
+    blocks of 16 rows (a tile of bf16 sublanes)."""
     for width in widths:
         if width % _LANES:
             return f"{width} channels are no multiple of {_LANES} lanes"
+    if unit % _LANES or unit > _CONV_CHANNELS or math.gcd(*widths) % (
+            unit or _LANES):
+        return (f"heads of {unit} channels are no whole tiles of {_LANES} "
+                f"lanes within a block of {_CONV_CHANNELS}")
     if not 1 < taps <= _TILE + 1:
         return f"{taps} taps: a block takes {_TILE} rows of the one beside it"
     if T is not None and T % _HALO:
@@ -180,12 +250,12 @@ def pass_vmem_bytes(kernel: str, tokens: int, width: int, itemsize: int,
     backward takes a cotangent an array of `arrays`, and the forward writes
     as many) and its float32 scratch of the same shape."""
     block = tokens * width
-    blocks, scratch = {
-        "mamba_conv_fwd": (1 + arrays, 1),
-        "mamba_conv_bwd": (2 + arrays, 2),
-        "mamba_norm_fwd": (3, 0),
-        "mamba_norm_bwd": (5, 0),
-    }[kernel]
+    blocks, scratch = {  # by the name's end: the pass, whoever named it
+        "conv_fwd": (1 + arrays, 1),
+        "conv_bwd": (2 + arrays, 2),
+        "norm_fwd": (3, 0),
+        "norm_bwd": (5, 0),
+    }["_".join(kernel.rsplit("_", 2)[-2:])]
     return 2 * blocks * block * itemsize + scratch * (block + _HALO * width) * 4
 
 
@@ -197,24 +267,28 @@ def _vmem_limit(kernel, tokens, width, itemsize, arrays=1) -> int:
 @functools.lru_cache(maxsize=None)
 def _log_pass(name, kernels, untiled, shape, rest, dtype):
     """One line for each pass and shape a process traces, as `ops/ssd.py`'s
-    `_log_scan`: which path, and the kernels' grid, blocks and VMEM."""
+    `_log_scan`: which path, and the kernels' grid, blocks and VMEM. `name`
+    is "gated_group_rmsnorm" or the name the convolution's caller gave."""
     B, T, C = shape
-    said = f"{name} at B {B}, T {T}, C {C}, {dtype}"
+    norm = name == "gated_group_rmsnorm"  # else the convolution's caller
+    said = (f"{'causal_conv_silu' if name == 'mamba_conv' else name} at B {B}, "
+            f"T {T}, C {C}, {dtype}")
     item = jnp.dtype(dtype).itemsize
     if not kernels:
         logger.info("%s: jax.numpy", said)
     elif untiled:
         logger.info("%s: jax.numpy, because %s", said, untiled)
-    elif name == "causal_conv_silu":
-        taps, widths = rest
-        tokens, channels, rows = conv_blocks(T, widths)
+    elif not norm:
+        taps, widths, unit = rest
+        tokens, channels, rows = conv_blocks(T, widths, unit)
         logger.info(
-            "%s: mamba_conv_fwd and mamba_conv_bwd, %d taps, splits %s, grid "
+            "%s: %s_fwd and %s_bwd, %d taps, splits %s%s, grid "
             "(%d, %d, %d), blocks [%d, %d] after [%d, %d], %d tokens a trip, "
-            "VMEM %d and %d bytes", said, taps, list(widths), B, C // channels,
-            T // tokens, tokens, channels, _HALO, channels, rows,
-            *(pass_vmem_bytes(k, tokens, channels, item, len(widths))
-              for k in ("mamba_conv_fwd", "mamba_conv_bwd")))
+            "VMEM %d and %d bytes", said, name, name, taps, list(widths),
+            f", unit length a head of {unit}" if unit else "",
+            B, C // channels, T // tokens, tokens, channels, _HALO, channels,
+            rows, *(pass_vmem_bytes(k, tokens, channels, item, len(widths))
+                    for k in ("conv_fwd", "conv_bwd")))
     else:
         groups, = rest
         tokens, rows = norm_blocks(T)
@@ -259,7 +333,8 @@ def _its_split(refs, bounds, block, do):
         pl.when((block >= lo) & (block < hi))(functools.partial(do, ref))
 
 
-def _conv_fwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows):
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows,
+                     unit):
     """One block of tokens of one block of channels: `rest` is an output a
     split, then the scratch `wide`."""
     from jax.experimental import pallas as pl
@@ -276,6 +351,8 @@ def _conv_fwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows):
         pre = _pre_activation(
             _shifted(wide[pl.ds(at, _HALO + rows), :], taps), w, bias)
         out = _silu_and_slope(pre)[0]
+        if unit:
+            out = _by_head(unit, _unit_length, out)
 
         def write(out_ref):
             out_ref[0, pl.ds(at, rows), :] = out.astype(out_ref.dtype)
@@ -286,7 +363,8 @@ def _conv_fwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows):
     jax.lax.fori_loop(0, tokens // rows, trip, 0)
 
 
-def _conv_bwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows):
+def _conv_bwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows,
+                     unit):
     """The same block's cotangents; the grid walks the blocks of tokens
     backwards. `rest` is a cotangent a split, then `dx`'s block and the
     sums' (`[taps + 1, 8, channels]`: eight partial rows a tap of `d w`,
@@ -322,7 +400,11 @@ def _conv_bwd_kernel(x_ref, before_ref, w_ref, bias_ref, *rest, bounds, rows):
         at = pl.multiple_of((trips - 1 - i) * rows, rows)
         shifted = _shifted(wide[pl.ds(at, _HALO + rows), :], taps)
         pre = _pre_activation(shifted, w, bias)
-        dpre = dout[pl.ds(at, rows), :] * _silu_and_slope(pre)[1]
+        dact = dout[pl.ds(at, rows), :]
+        act, slope = _silu_and_slope(pre)
+        if unit:
+            dact = _by_head(unit, _unit_length_slope, act, dact)
+        dpre = dact * slope
         # dx_t = sum_k w_(taps - 1 - k) dpre_(t + k): the rows after these
         both = jnp.concatenate([dpre, after], axis=0)
         dx = dpre * w[taps - 1:taps]
@@ -344,11 +426,11 @@ def _split_bounds(widths, channels):
     return tuple(zip([0] + ends[:-1], ends))
 
 
-@functools.partial(jax.jit, static_argnums=(0, 5, 6, 7))
-def _conv_call(name, x, w, bias, douts, widths, blocks, interpret):
-    """`pallas_call` of `mamba_conv_fwd` (no `douts`: an array a split) or
-    of `mamba_conv_bwd` (a cotangent a split: `dx` and the sums of `d w` and
-    `d bias`). The forward's grid is (batch row, block of channels, block
+@functools.partial(jax.jit, static_argnums=(0, 5, 6, 7, 8))
+def _conv_call(name, x, w, bias, douts, widths, blocks, unit, interpret):
+    """`pallas_call` of `<caller's name>_fwd` (no `douts`: an array a split)
+    or of `<caller's name>_bwd` (a cotangent a split: `dx` and the sums of
+    `d w` and `d bias`), under the caller's name as a scope. The forward's grid is (batch row, block of channels, block
     of tokens), the backward's (block of channels, batch row, block of
     tokens from the last). A split's block stands at its first block until
     the grid reaches its channels and at its last once it has left them.
@@ -360,7 +442,7 @@ def _conv_call(name, x, w, bias, douts, widths, blocks, interpret):
     B, T, C = x.shape
     tokens, channels, rows = blocks
     taps, n = w.shape[0], T // tokens
-    backward = name == "mamba_conv_bwd"
+    backward = name.endswith("_bwd")
     bounds = _split_bounds(widths, channels)
 
     def of(step):
@@ -397,9 +479,9 @@ def _conv_call(name, x, w, bias, douts, widths, blocks, interpret):
     else:
         kernel, out_specs, out_shape, scratch = (
             _conv_fwd_kernel, splits, split_shapes, [wide])
-    with jax.named_scope("mamba_conv"):
+    with jax.named_scope(name[:-len("_bwd")]):
         return _pallas_call(
-            functools.partial(kernel, rows=rows, bounds=bounds),
+            functools.partial(kernel, rows=rows, bounds=bounds, unit=unit),
             grid=(C // channels, B, n) if backward else (B, C // channels, n),
             in_specs=[main, before, per_channel(taps), per_channel(1),
                       *splits[:len(douts)]],
@@ -409,31 +491,31 @@ def _conv_call(name, x, w, bias, douts, widths, blocks, interpret):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
                 vmem_limit_bytes=_vmem_limit(
-                    name, tokens, channels, jnp.dtype(x.dtype).itemsize,
-                    len(widths))),
+                    "conv" + name[-len("_bwd"):], tokens, channels,
+                    jnp.dtype(x.dtype).itemsize, len(widths))),
             interpret=interpret,
             name=name,
         )(x, x, w.astype(_F32), bias.astype(_F32).reshape(1, C), *douts)
 
 
-def _conv_fwd(x, w, bias, widths, blocks, interpret):
+def _conv_fwd(x, w, bias, name, widths, blocks, unit, interpret):
     return tuple(_conv_call(
-        "mamba_conv_fwd", x, w, bias, (), widths, blocks, interpret))
+        name + "_fwd", x, w, bias, (), widths, blocks, unit, interpret))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _conv(x, w, bias, widths, blocks, interpret):
-    return _conv_fwd(x, w, bias, widths, blocks, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _conv(x, w, bias, name, widths, blocks, unit, interpret):
+    return _conv_fwd(x, w, bias, name, widths, blocks, unit, interpret)
 
 
-def _conv_vjp_fwd(x, w, bias, widths, blocks, interpret):
-    return _conv_fwd(x, w, bias, widths, blocks, interpret), (x, w, bias)
+def _conv_vjp_fwd(x, w, bias, *static):
+    return _conv_fwd(x, w, bias, *static), (x, w, bias)
 
 
-def _conv_vjp_bwd(widths, blocks, interpret, res, douts):
+def _conv_vjp_bwd(name, widths, blocks, unit, interpret, res, douts):
     x, w, bias = res
-    dx, sums = _conv_call("mamba_conv_bwd", x, w, bias, tuple(douts), widths,
-                          blocks, interpret)
+    dx, sums = _conv_call(name + "_bwd", x, w, bias, tuple(douts), widths,
+                          blocks, unit, interpret)
     sums = sums.sum(axis=1)  # the eight partial rows
     return dx, sums[:-1].astype(w.dtype), sums[-1].astype(bias.dtype)
 

@@ -75,6 +75,10 @@ EVA_KERNELS = {"eva_summaries_fwd", "eva_summaries_bwd"}
 # form's, so no step lowered here names them (`tests/test_kernel_compile.py`
 # compiles them, and finds them by these names)
 KDA_KERNELS = {"kda_fwd", "kda_bwd"}
+# the KDA mixer's short convolutions on the kernels' path (PR 67;
+# `ops/mamba_passes.py` under the mixer's name): the `jax.numpy` lines'
+# operations stand under `kda_conv` on the CPU
+KDA_CONV_KERNELS = {"kda_conv_fwd", "kda_conv_bwd"}
 # the routed layer's exchange over an `expert` mesh axis, inside
 # `mlp/shard_map` beside `moe_router` (PR 50)
 EXCHANGE_SCOPES = {"moe_gather", "moe_scatter"}
@@ -329,6 +333,36 @@ def lowered_nemotron_kernel_step():
         transformer_module.ssd = scan
 
 
+# the two families with KDA layers, each with its other mixer, at heads of
+# one lane tile
+KDA_FAMILIES = {
+    "solar_open2": dict(
+        layer_types=("full_attention", "kda"), heads_held=(2, 2),
+        attn_gate="elementwise", kda_heads=4),
+    "kimi_linear": dict(
+        layer_types=("kda", "latent_attention"), n_kv_heads=None,
+        kda_heads=2, kda_allow_neg_eigval=False, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8),
+}
+
+
+def lowered_kda_conv_kernel_step(family):
+    """A family's KDA layer at streams that tile, its short convolutions'
+    kernels in interpret mode: steered here, as the scan's above."""
+    conv = transformer_module.causal_conv_silu
+    takes = transformer_module._kda_conv_kernels
+    transformer_module.causal_conv_silu = functools.partial(
+        conv, interpret=True)
+    transformer_module._kda_conv_kernels = lambda cfg, T=None: True
+    try:
+        return lowered_transformer_step(
+            n_layers=2, rope=False, kda_head_dim=128, kda_gate_rank=4,
+            kda_chunk=16, **KDA_FAMILIES[family])
+    finally:
+        transformer_module.causal_conv_silu = conv
+        transformer_module._kda_conv_kernels = takes
+
+
 def lowered_moe_kernels():
     x = jax.ShapeDtypeStruct((32, 16), jnp.float32)
     w = jax.ShapeDtypeStruct((4, 16, 8), jnp.float32)
@@ -419,7 +453,7 @@ OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES,
               "phi4flash": PHI4FLASH_SCOPES,
               # no family above: `test_eva_attention_names_its_parts` lowers it
               "evabyte": EVABYTE_SCOPES | EVA_KERNELS,
-              "kda's kernels": KDA_KERNELS}
+              "kda's kernels": KDA_KERNELS | KDA_CONV_KERNELS}
 ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"},
             "solar_open2": {"moe_shared"},
             "kimi_linear": SOLAR_SCOPES | DSV2_SCOPES | {"expert_bias"}}
@@ -684,6 +718,32 @@ def test_the_scan_s_kernels_sit_under_the_scan_s_scope():
     assert not [s for s in stacks if "ssd_bwd" in s and "rematted" in s]
 
 
+@pytest.mark.parametrize("family", sorted(KDA_FAMILIES))
+def test_kda_s_short_convolutions_sit_under_their_scope(family):
+    """On the kernels' path `kda_conv_fwd` is under `kda/kda_conv` in the
+    forward and in the forward made again, `kda_conv_bwd` in the backward,
+    where a split by scope reads `kda_conv` and `pallas_time_share.tokens`
+    the kernels; `kda_kernel_time_share.tokens` (`^kda_fwd`, `^kda_bwd`)
+    and `mamba_pass_time_share.tokens` (`^mamba_conv_`, `^mamba_norm_`)
+    match neither name. Nothing else is left under the scope but the taps'
+    cast and slices (and their gradient's pad), the reshapes and the
+    partial sums' sum."""
+    stacks = name_stacks(lowered_kda_conv_kernel_step(family))
+    # the calls, under the mixer's scope: `_conv_call` is a `jit` of its
+    # own, lowered as a function whose stacks start at its own scope
+    for prefix in ("", "checkpoint/rematted_computation/", "checkpoint/"):
+        assert f"{prefix}kda/kda_conv/jit(_conv_call)" in stacks, prefix
+    for kernel in KDA_CONV_KERNELS:
+        assert any(s.startswith(f"kda_conv/{kernel}/") for s in stacks)
+        assert not re.match(r"^kda_fwd|^kda_bwd|^mamba_conv_|^mamba_norm_",
+                            kernel)
+    assert KDA_CONV_KERNELS <= components(stacks)
+    under = {s.rsplit("/", 1)[-1] for s in stacks
+             if re.search(r"(^|/)kda/kda_conv/[^/]+$", s)}
+    assert under and not under & {
+        "logistic", "rsqrt", "dynamic_slice", "mul", "integer_pow"}, under
+
+
 def test_eva_attention_names_its_parts():
     """Under `eva`: the projections, the summaries' kernel, the window's
     causal flash kernel, the staircase's, the join and the output
@@ -769,7 +829,7 @@ def test_every_name_a_metric_matches_is_a_name_of_the_program(stacks, family):
     program = (TRANSFORMER_SCOPES | RESNET_SCOPES | KERNELS | LFM2_SCOPES
                | DSV2_SCOPES | NEMOTRON_SCOPES | KEYE_SCOPES | SOLAR_SCOPES
                | PHI4FLASH_SCOPES | EVABYTE_SCOPES | EVA_KERNELS
-               | KDA_KERNELS)
+               | KDA_KERNELS | KDA_CONV_KERNELS)
     assert set(scopes.SCOPES) == TRANSFORMER_SCOPES | RESNET_SCOPES
     # the benchmark's list is PR 25's three until a `benchmark` issue adds
     # the fourth (PERF.md section 7); its time share matches by prefix

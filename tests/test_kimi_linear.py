@@ -9,8 +9,10 @@ configuration; the kernels' path against the `jax.numpy` one; remat with
 names kept; the rule's arithmetic over the mixed stack; the optimizer's
 mask."""
 
+import collections
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,8 @@ from chipbench.reference import kimi_linear as reference
 from ray_tpu.models import TransformerConfig, make_train_step
 from ray_tpu.models import transformer as model
 from ray_tpu.ops import kda as kda_lib
+from ray_tpu.ops import mamba_passes
+from ray_tpu.util import tracing
 from tiny_models import distance, one_device
 
 KINDS = ("kda", "kda", "kda", "latent_attention", "kda")
@@ -232,7 +236,10 @@ def test_beta_s_range_is_the_configuration_s(neg_eigval):
 
 def test_the_kernels_path_is_the_numpy_path():
     """Heads of 128 tile: KDA's kernels (interpret mode here) under the
-    mixed stack give the `jax.numpy` path's loss and gradients."""
+    mixed stack give the `jax.numpy` path's loss and gradients, the
+    recurrence's and the short convolutions' (`kda_conv_fwd`,
+    `kda_conv_bwd`; PR 67) alike; each path counts its convolution calls,
+    three a layer."""
     cfg = dataclasses.replace(
         CFG, n_layers=3, layer_types=("kda", "latent_attention", "kda"),
         kda_heads=2, kda_head_dim=128, kda_chunk=64)
@@ -242,13 +249,50 @@ def test_the_kernels_path_is_the_numpy_path():
     def ours(p):
         return model.transformer_loss(p, batch, cfg, expert_bias=bias)
 
+    def counted(before=None):
+        now = tracing.counters()
+        return tuple(now.get(name, 0) - (before or {}).get(name, 0)
+                     for name in ("train.kda_conv_calls_kernels",
+                                  "train.kda_conv_calls_numpy"))
+
+    before = tracing.counters()
     numpy_path = jax.jit(jax.value_and_grad(ours))(params)
+    assert counted(before) == (0, 6)
+    before = tracing.counters()
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(model, "kda", functools.partial(
             kda_lib.kda, interpret=True))  # the kernels, whatever `impl`
+        patch.setattr(model, "_kda_conv_kernels", lambda cfg, T=None: True)
+        patch.setattr(model, "causal_conv_silu", functools.partial(
+            mamba_passes.causal_conv_silu, interpret=True))
+        traced = jax.make_jaxpr(jax.value_and_grad(ours))(params)
         kernels = jax.jit(jax.value_and_grad(ours))(params)
+    assert counted(before) == (12, 0)  # traced twice
+    calls = collections.Counter(re.findall(r"name=(kda_conv_\w+)", str(traced)))
+    assert set(calls) == {"kda_conv_fwd", "kda_conv_bwd"}
     assert float(kernels[0]) == pytest.approx(float(numpy_path[0]), rel=1e-5)
     assert distance(kernels[1], numpy_path[1]) < 1e-4
+
+
+def test_the_path_is_the_code_s_choice_from_the_operators_and_the_shape():
+    """The kernels where the step's operators resolve to Pallas and the
+    streams tile; `jax.numpy` on the CPU's path, at heads that are no whole
+    lane tiles, at tokens that are no whole blocks of 16 rows and at more
+    taps than a block reads of the one before it. `_KDA.holds` follows."""
+    on_chip = dataclasses.replace(
+        CFG, kda_heads=2, kda_head_dim=128, attention_impl="pallas")
+    assert model._kda_conv_kernels(on_chip)
+    assert model._kda_conv_kernels(on_chip, 128)
+    assert not model._kda_conv_kernels(on_chip, 40)
+    assert not model._kda_conv_kernels(
+        dataclasses.replace(on_chip, attention_impl="xla"), 128)
+    assert not model._kda_conv_kernels(
+        dataclasses.replace(on_chip, kda_head_dim=8), 128)
+    assert not model._kda_conv_kernels(
+        dataclasses.replace(on_chip, kda_conv_taps=12), 128)
+    record, wide = model._OPERATORS["kda"], 2 * 128
+    assert record.holds(dataclasses.replace(on_chip, kda_conv_taps=12)) == (
+        record.holds(on_chip) + 2 * wide)
 
 
 def test_remat_with_names_kept_is_the_same_step():
@@ -306,7 +350,10 @@ def test_kda_s_entering_states_at_the_cell_s_width():
     solar = dataclasses.replace(kimi, kda_heads=64, heads_held=(0, 8))
     wide = 32 * 128
     states = 2 * wide * 128 // 64  # float32, in elements of bf16 a token
-    assert record.holds(kimi) == 12 * wide + 2 * 2 * wide + states
+    # ten widths: the silu's q and k stay in `kda_conv_fwd`'s VMEM (PR 67)
+    assert record.holds(kimi) == 10 * wide + 2 * 2 * wide + states
+    assert record.holds(dataclasses.replace(kimi, kda_conv_taps=12)) == (
+        12 * wide + 2 * 2 * wide + states)  # taps the kernels do not take
     assert 16384 * states * 2 == 536_870_912
     assert record.holds(kimi) == 4 * record.holds(solar)
     assert 8192 * (2 * 8 * 128 * 128 // 64) * 2 == 67_108_864

@@ -6,7 +6,9 @@ the silu; `fused_rmsnorm` of the gated product): results and every gradient
 over several blocks of tokens and of channels, the splits' edges and a
 group's, bf16 and float32; which shapes tile, and the line that says which
 path a shape took; the dtypes the kernels read, compute and write in, from
-their own jaxprs."""
+their own jaxprs. And the same pair under the KDA mixer's name with each
+head of the silu's result at unit length (`kda_conv_fwd`, `kda_conv_bwd`; PR
+67), against `_kda_mixer`'s `jax.numpy` lines."""
 
 import functools
 import logging
@@ -15,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models.transformer import _causal_taps
+from ray_tpu.models.transformer import _causal_taps, _unit_length
 from ray_tpu.ops import mamba_passes as passes
 from ray_tpu.ops.fused import fused_rmsnorm
 
@@ -28,7 +30,9 @@ DTYPES = pytest.mark.parametrize(
 def blocks(monkeypatch):
     """Sets the most a grid step and a trip take, so that a small shape has
     several of each."""
-    def set_(conv_tokens=32, conv_channels=128, norm_tokens=32, rows=16):
+    def set_(conv_tokens=32, conv_channels=128, norm_tokens=32, rows=16,
+             unit_rows=16):
+        monkeypatch.setattr(passes, "_UNIT_ROWS", unit_rows)
         monkeypatch.setattr(passes, "_CONV_TOKENS", conv_tokens)
         monkeypatch.setattr(passes, "_CONV_CHANNELS", conv_channels)
         monkeypatch.setattr(passes, "_NORM_TOKENS", norm_tokens)
@@ -300,6 +304,134 @@ def test_a_shape_that_tiles_says_which_kernels_and_at_what_size(
     assert numpy_line.endswith("bfloat16: jax.numpy")
     assert "mamba_norm_fwd and mamba_norm_bwd, 2 groups of 512, grid (2, 1), " \
         "blocks [64, 1024], 16 tokens a trip" in norm_line
+
+
+# ------------------------------------ the KDA mixer's short convolutions
+
+def kda_lines(x, w, unit):
+    """`_kda_mixer`'s `jax.numpy` lines for one stream: the taps, the silu
+    and, for q and k, each head of `unit` channels at unit length."""
+    out = jax.nn.silu(_causal_taps(x, w))
+    if unit:
+        B, T, C = out.shape
+        out = _unit_length(out.reshape(B, T, C // unit, unit)).reshape(B, T, C)
+    return out
+
+
+def kda_conv(x, w, unit, **how):
+    return passes.causal_conv_silu(x, w, unit=unit, name="kda_conv", **how)
+
+
+# (T, H dk, tokens and channels a grid step, tokens a trip)
+KDA_SHAPES = [
+    # `solaropen2.tokens8k`'s width: three blocks of tokens (zeros before
+    # the first, the 16 rows of the one before for the others), two of
+    # channels, two trips a block
+    (96, 1024, 32, 512, 16),
+    # `kimilinear.tokens16k`'s: eight blocks of channels of four heads
+    (32, 4096, 32, 512, 32),
+]
+
+
+@DTYPES
+@pytest.mark.parametrize("unit", [0, 128], ids=["v", "q_and_k"])
+@pytest.mark.parametrize("T,wide,tokens,channels,rows", KDA_SHAPES)
+def test_kda_s_convolution_kernels_are_the_mixer_s_lines(
+        T, wide, tokens, channels, rows, unit, dtype, blocks):
+    """Forward, `dx` and the taps' gradient. float32: the order of the sums
+    apart. bf16: the lines round every product, the taps' sum and the
+    silu's result to bf16, the kernels keep them in float32 into the norm
+    and round what they write once."""
+    blocks(conv_tokens=tokens, conv_channels=channels, rows=rows,
+           unit_rows=rows)
+    (x, w), (ct,) = conv_inputs(2, T, (wide,), 4, dtype, bias=False)
+    x = x.at[0, :, 128:256].set(0)  # a head of all zeros: the `eps` alone
+    assert passes.conv_blocks(T, (wide,), unit) == (tokens, channels, rows)
+    out, grads = out_and_grads(
+        lambda x, w: kda_conv(x, w, unit, interpret=True), (x, w), ct)
+    want_out, want = out_and_grads(
+        lambda x, w: kda_lines(x, w, unit), (x, w), ct)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    assert out.dtype == dtype and out.shape == x.shape
+    assert rel(out, want_out) < tol
+    assert not out[0, :, 128:256].any()
+    for name, ours, theirs, arg in zip(("x", "w"), grads, want, (x, w)):
+        assert ours.dtype == arg.dtype and ours.shape == arg.shape, name
+        assert bool(jnp.isfinite(ours.astype(jnp.float32)).all()), name
+        assert rel(ours, theirs) < tol, name
+    # the zero head's `dx`: `dout / sqrt(eps)` through the taps, as the lines'
+    assert rel(grads[0][0, :, 128:256], want[0][0, :, 128:256]) < tol
+
+
+def test_kda_s_numpy_path_is_the_mixer_s_lines_to_the_bit():
+    (x, w), _ = conv_inputs(2, 40, (256,), 4, jnp.bfloat16, bias=False)
+    for unit in (0, 128):
+        assert bool((kda_conv(x, w, unit, impl="xla")
+                     == kda_lines(x, w, unit)).all())
+
+
+def test_a_stream_without_the_unit_length_runs_the_mamba_mixer_s_body(blocks):
+    """v's kernels under KDA's name are the Mamba-2 mixer's to the equation
+    (its bias at zero); q's and k's add a head's sums and its rsqrt."""
+    blocks(conv_channels=256)
+    (x, w), (ct,) = conv_inputs(2, 64, (256,), 4, jnp.bfloat16, bias=False)
+
+    def bodies(**how):
+        def both(x, w):
+            out, pull = jax.vjp(functools.partial(
+                passes.causal_conv_silu, interpret=True, **how), x, w)
+            return out, pull(ct)
+        return {e.params["name"]: e.params["jaxpr"] for e in _equations(
+            jax.make_jaxpr(both)(x, w).jaxpr)
+            if e.primitive.name == "pallas_call"}
+
+    mamba, v, q = bodies(), bodies(name="kda_conv"), bodies(
+        name="kda_conv", unit=128)
+    assert set(v) == set(q) == {"kda_conv_fwd", "kda_conv_bwd"}
+    for kind in ("fwd", "bwd"):
+        assert str(v["kda_conv_" + kind]) == str(mamba["mamba_conv_" + kind])
+        norms = [e.primitive.name for e in _equations(q["kda_conv_" + kind])
+                 if e.primitive.name in ("rsqrt", "reduce_sum")]
+        # two heads a block of 256 channels; the backward sums `dout out` too
+        assert norms.count("rsqrt") == 2
+        assert norms.count("reduce_sum") == (2 if kind == "fwd" else 4)
+        assert not [e for e in _equations(v["kda_conv_" + kind])
+                    if e.primitive.name in ("rsqrt", "reduce_sum")]
+
+
+def test_kda_s_shapes_tile_and_heads_that_do_not_take_the_numpy_lines(caplog):
+    """Both cells' streams tile and fit VMEM; a head that is no whole lane
+    tile, or that straddles the splits, takes `jax.numpy` and says why."""
+    for T, wide in ((16384, 4096), (8192, 1024)):
+        assert passes.conv_untiled(4, (wide,), T, 128) is None
+        tokens, channels, rows = passes.conv_blocks(T, (wide,), 128)
+        assert (tokens, channels, rows) == (1024, 512, 128)
+        assert passes.conv_blocks(T, (wide,))[2] == 32  # v: the Mamba mixer's
+        for kernel in ("kda_conv_fwd", "kda_conv_bwd"):
+            need = passes.pass_vmem_bytes(kernel, tokens, channels, 2)
+            assert need == passes.pass_vmem_bytes(
+                kernel.replace("kda", "mamba"), tokens, channels, 2)
+            assert need < passes._vmem_limit(
+                kernel, tokens, channels, 2) <= 96 << 20
+    assert "64 channels are no whole tiles" in passes.conv_untiled(
+        4, (1024,), 8192, 64)
+    assert "heads of 256" in passes.conv_untiled(4, (384,), 8192, 256)
+    assert passes.conv_untiled(4, (1024,), 8192, 256) is None
+    (x, w), _ = conv_inputs(2, 32, (128,), 4, jnp.float32, bias=False)
+    passes._log_pass.cache_clear()
+    with caplog.at_level(logging.INFO, logger="ray_tpu.ops.mamba_passes"):
+        out = kda_conv(x, w, 64, impl="pallas")
+        assert "pallas_call" in str(jax.make_jaxpr(functools.partial(
+            kda_conv, unit=128, interpret=True))(x, w))
+    assert bool((out == kda_lines(x, w, 64)).all())
+    numpy_line, kernels_line = [r.getMessage() for r in caplog.records]
+    assert numpy_line == (
+        "kda_conv at B 2, T 32, C 128, float32: jax.numpy, because heads of "
+        "64 channels are no whole tiles of 128 lanes within a block of 512")
+    assert kernels_line.startswith(
+        "kda_conv at B 2, T 32, C 128, float32: kda_conv_fwd and "
+        "kda_conv_bwd, 4 taps, splits [128], unit length a head of 128, "
+        "grid (2, 1, 1), blocks [32, 128] after [16, 128], 32 tokens a trip")
 
 
 def _equations(jaxpr):

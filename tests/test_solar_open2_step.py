@@ -2,11 +2,13 @@
 real sizes for a described v5e that is not attached, with the keep rule
 handed the chip's limit: KDA's recurrence is the Pallas kernels `kda_fwd`
 and `kda_bwd` behind a `custom_vjp` (`ops/kda.py`), the solve's custom call
-is gone, and the rule prices the path that runs (`models/transformer.py`
-`_KDA.holds`). Nothing runs, so nothing here is a time or a result. A file
+is gone, the mixers' short convolutions are the kernels `kda_conv_fwd` and
+`kda_conv_bwd` (`ops/mamba_passes.py`; PR 67), and the rule prices the path
+that runs (`models/transformer.py` `_KDA.holds`). Nothing runs, so nothing here is a time or a result. A file
 of its own, so that `--dist loadfile` can place its one compilation; the
 topology is described inside a fixture, never at import."""
 
+import logging
 import os
 import re
 
@@ -17,6 +19,7 @@ import pytest
 
 from chipbench import loop, spec
 from ray_tpu.models import transformer as tr
+from ray_tpu.util import tracing
 
 CELL = "solaropen2.tokens8k"
 CHIP_LIMIT = 16_909_336_064  # a v5e's `bytes_limit`, as its allocator reads
@@ -28,7 +31,8 @@ KEPT = ("attn_ctx", "attn_res", "attn_qkv", "kda_res", "kda_qkv",
 @pytest.fixture(scope="module")
 def step():
     """(the compiled step, the names the rule kept for it, the rule's sum
-    for them: the state with the fullest of its moments), the compile cache
+    for them: the state with the fullest of its moments; the step's log
+    line and what its trace added to the counters), the compile cache
     off around it (an entry compiled for a described device cannot be read
     back)."""
     from jax.experimental import topologies
@@ -68,8 +72,22 @@ def step():
             lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
             state, family.state_shardings)
         batch = family.batch_shapes(int(traffic["batch_rows"]))
-        compiled = family.step.lower(state, batch).compile()
-    yield compiled, chosen[0]
+        lines, before = [], tracing.counters()
+        handler = logging.Handler()
+        handler.emit = lambda record: lines.append(record.getMessage())
+        logger = logging.getLogger("ray_tpu.models.transformer")
+        level = logger.level
+        logger.addHandler(handler)
+        logger.setLevel(logging.INFO)
+        try:
+            compiled = family.step.lower(state, batch).compile()
+        finally:
+            logger.removeHandler(handler)
+            logger.setLevel(level)
+        counted = {name: n - before.get(name, 0)
+                   for name, n in tracing.counters().items()}
+    yield compiled, chosen[0], [
+        line for line in lines if line.startswith("train step")], counted
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
 
@@ -82,6 +100,28 @@ def test_the_recurrence_is_the_kernels(step, kernel, calls):
     text = step[0].as_text()
     assert len(set(re.findall(rf"%{kernel}\.\d+ = ", text))
                | set(re.findall(rf"%{kernel} = ", text))) == calls
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    ("kda_conv_fwd", 18), ("kda_conv_bwd", 9)])
+def test_the_short_convolutions_are_the_kernels(step, kernel, calls):
+    """q's, k's and v's a layer: a forward each, made again, and a backward
+    each; no float32 array of the streams' width under the scope and no
+    padded copy is left of the `jax.numpy` lines. The step's line and its
+    counters say so: a count a call a trace, and the three layers, one
+    period each of one kind, share one trace of their block."""
+    text = step[0].as_text()
+    assert len(set(re.findall(rf"%{kernel}\.\d+ = ", text))
+               | set(re.findall(rf"%{kernel} = ", text))) == calls
+    assert not [line for line in text.splitlines()
+                if "/kda_conv/" in line and "= f32[1,8192,1024]" in line]
+    assert not re.search(r"bf16\[1,8195,1024\]", text)
+    line, = step[2]
+    assert line.endswith(
+        "; KDA's short convolutions: 3 calls by the kernels kda_conv_fwd "
+        "and kda_conv_bwd, 0 by jax.numpy")
+    assert step[3]["train.kda_conv_calls_kernels"] == 3
+    assert not step[3].get("train.kda_conv_calls_numpy")
 
 
 def test_the_solve_s_custom_call_is_gone(step):

@@ -12,8 +12,12 @@ marked, two windows subtracted, `getrusage`, `/proc/pressure`), a garbage
 collection's two callbacks, and what the worker's `jax.monitoring`
 listeners add to one event of JAX's (a trace, a lowering or a compilation:
 a scalar at its start, a time span at its end, as JAX records them), alone
-and inside an open one. Prints one JSON object. The numbers
-are this host's: `PERF.md` has the chip host's.
+and inside an open one. Last, what the step's own account costs
+(`tracing.Step`, `_runtime._fold_steps`): a jitted call bare and under
+`Step`, and a report that folds a chunk of five steps' readings of a share's
+shape (26 routed layers of 64 experts) against one that folds none. Prints
+one JSON object. The numbers are this host's: `PERF.md` has the chip
+host's.
 """
 
 import gc
@@ -74,6 +78,44 @@ def listener_ns(jax):
             "jax_event_ns.watched_inside_another": nested}
 
 
+def step_account(jax):
+    """ns a call of a jitted step bare and under `tracing.Step`; us a
+    report with five steps' readings to fold, their arrays ready, less the
+    five calls and the wait."""
+    jnp = jax.numpy
+
+    def fn(state, batch):
+        load = jnp.full((26, 64), 1024, jnp.int32) + batch.astype(jnp.int32)[0]
+        held = load[:, :8].sum(-1)
+        return state + 1, {
+            "loss": batch.sum(), "grad_norm": batch.max(),
+            "aux_loss": batch.min(), "z_loss": batch.mean(),
+            "expert_load": load, "held_slots": held,
+            "dropped_slots": jnp.zeros_like(held)}
+
+    jitted = jax.jit(fn)
+    step = tracing.Step(jitted, {"held_chunk": 11264})
+    state, batch = jnp.zeros(()), jnp.ones(8)
+    jax.block_until_ready(step(state, batch))
+    out = {"step_call_ns.bare_jit": per_call(
+               lambda: jitted(state, batch), 20_000),
+           "step_call_ns.under_step": per_call(
+               lambda: step(state, batch), 20_000)}
+    account = _runtime.RuntimeAccount()
+
+    def chunk(report):
+        for _ in range(5):
+            made = step(state, batch)
+        jax.block_until_ready(made)
+        if report:
+            account.block()
+
+    folded = per_call(lambda: chunk(True), 1_000)
+    out["report_five_steps_us"] = (
+        folded - per_call(lambda: chunk(False), 1_000)) / 1e3
+    return out
+
+
 def main():
     out = {}
     out["span_ns.no_jax"] = per_call(one_span, 200_000)
@@ -109,6 +151,7 @@ def main():
     out["gc_callbacks_ns_a_collection"] = watched - per_call(
         lambda: gc.collect(0), 20_000)
     out.update(listener_ns(jax))
+    out.update(step_account(jax))
     out["device"] = jax.devices()[0].platform
     print(json.dumps(out))
 

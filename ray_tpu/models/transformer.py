@@ -4262,7 +4262,12 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
     """Build (init_state, step) jitted over the mesh.
 
     state = {'params': f32 sharded, 'opt': optax state, 'step': scalar}
-    step(state, batch) -> (state, metrics); params/opt donated. metrics are
+    step(state, batch) -> (state, metrics); params/opt donated. `step` is
+    the jitted function under `tracing.Step`: a call is the span
+    `train.step` and leaves `metrics` for the train session's account;
+    `lower`, `trace` and every other attribute are the jitted function's;
+    `step.static["held_chunk"]` is, once a share's step is traced, the rows
+    of the buffers its routed layers walk their held rows in. metrics are
     loss and grad_norm, and with routed experts aux_loss, z_loss and
     expert_load [L, E]; of a share of the experts also held_slots and
     dropped_slots [L]; with sparse attention index_loss, index_keys_min_gap
@@ -4319,9 +4324,20 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
             state["expert_bias"] = jax.device_put(expert_bias_init(cfg), repl)
         return state
 
+    static = {}  # what the step's trace says of its program, for `Step`
+
     def loss_fn(params, batch, **kw):
         loss, readings = transformer_loss_and_readings(
             params, batch, cfg, mesh=mesh, **kw)
+        if "held_slots" in readings:
+            # the rows of a share's buffers, as `_routed_rows` asks for them:
+            # of the slots and the sequences of the mesh's whole batch
+            ways = mesh.size if mesh_lib.expert_axis(mesh) else 1
+            static["held_chunk"] = moe.held_chunk(
+                math.prod(readings["expert_index"].shape[-2:]),
+                cfg.held[1] // ways, cfg.n_experts,
+                load_held_even=cfg.expert_bias,
+                sequences=batch["tokens"].shape[0])
         return loss, {k: readings[k] for k in _STEP_READINGS if k in readings}
 
     def on_a_device(tree, shardings):
@@ -4409,8 +4425,9 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
                     jnp.abs(state["expert_bias"]))
         return state, {"loss": loss, "grad_norm": gnorm, **readings}
 
-    return init_state, step, {"tokens": tok_sharding, "replicated": repl,
-                              "params": p_shard, "state": state_shard}
+    return init_state, tracing.Step(step, static), {
+        "tokens": tok_sharding, "replicated": repl, "params": p_shard,
+        "state": state_shard}
 
 
 def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):

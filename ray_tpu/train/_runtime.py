@@ -7,6 +7,10 @@ enqueues (docs/observability.md, "The train path"):
      "since_first_report": the same less its state at the first report,
      "interval":           the same less its state at the previous report,
      "counters":           {"compile.programs": ..., ...} since the session began,
+     "counters_since_first_report": the same less their state at the first report,
+     "readings":           the last read step's readings that no counter sums,
+     "steps":              [step, chunks a layer, held rows a layer] of the
+                           last 64 read steps of a share of the experts,
      "rusage":             this interval's deltas of getrusage and /proc/pressure}
 
 A table is `{span: [count, seconds, longest_seconds, time of the longest]}`
@@ -14,21 +18,32 @@ A table is `{span: [count, seconds, longest_seconds, time of the longest]}`
 of any training job: the first interval holds the compile. A name that saw
 no span in a window is left out of that window's table.
 
+The steps account for themselves (`tracing.Step`, which `make_train_step`
+hands out): a step leaves its readings, device arrays it does not wait for,
+whose copies to the host it starts, and a report takes those that are
+ready, reads them in one `jax.device_get` and folds them into the counters
+`moe.*` and `train.steps_read` (`_fold_steps`). One that is not ready stays
+for the next report: the account never waits for the device.
+
 When an interval lasts more than `SLOW_FACTOR` times the median of those
 before it (and `SLOW_MIN_S`), the account records one flight-recorder event (`train`,
-`slow_interval`) and logs one line with what this process did in the gap.
+`slow_interval`) and logs one line with what this process did in the gap,
+and under `moe` what the interval's steps routed.
 """
 
 from __future__ import annotations
 
 import gc
+import itertools
 import logging
 import os
 import resource
 import statistics
 import time
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
+
+import numpy as np
 
 from ray_tpu._private import telemetry
 from ray_tpu.util import tracing
@@ -40,6 +55,10 @@ SLOW_FACTOR = 3.0
 SLOW_MIN_SEEN = 5  # intervals seen before one can be called slow
 SLOW_MIN_S = 0.1  # a loop that reports every millisecond jitters by 3x
 GC_MIN_S = 1e-3  # a collection shorter than this is no span
+
+# the readings that `_fold_steps` sums over layer-steps
+_SUMMED = ("expert_load", "held_slots", "dropped_slots",
+           "chip_load_max_over_mean")
 
 _RUSAGE = ("ru_nivcsw", "ru_nvcsw", "ru_majflt", "ru_utime", "ru_stime")
 _PRESSURE = ("cpu", "memory", "io")
@@ -89,6 +108,11 @@ def _less(now: Table, then: Table, longest: Table) -> Table:
     return out
 
 
+def _risen(now: Dict[str, float], then: Dict[str, float]) -> Dict[str, float]:
+    """The counters `now` less what they read `then`."""
+    return {k: v - then.get(k, 0) for k, v in now.items()}
+
+
 def _keep_longest(into: Table, interval: Table) -> None:
     for name, row in interval.items():
         if row[2] > into.get(name, (0.0, 0.0))[0]:
@@ -125,14 +149,88 @@ def watch_gc() -> None:
         gc.callbacks.append(_on_gc)
 
 
+def _ready(readings: Dict[str, Any]) -> Optional[bool]:
+    """Whether the device has made a step's readings: one program makes
+    them all, so the first says it for all. None where it was deleted since
+    (donated on): such a one cannot be asked."""
+    first = next(iter(readings.values()))
+    return None if first.is_deleted() else first.is_ready()
+
+
+def _fold_steps(steps: List[tuple]) -> tuple:
+    """`steps` (`tracing.take_steps`'s, their arrays ready) into the counters,
+    each summed over layer-steps (a routed layer in a step):
+    `moe.layer_steps`; `moe.fullest_expert_slots` and `moe.even_expert_slots`
+    (`expert_load`'s maximum and mean over a layer's experts); of a share of
+    the experts `moe.held_slots`, `moe.dropped_slots`, `moe.held_rows` (their
+    difference: the rows the kernels walk), `moe.buffer_rows` (chunks walked
+    times the step's `static["held_chunk"]`; a chunk at least) and
+    `moe.extra_chunk_layer_steps` (more than one chunk; over an `expert`
+    axis a device each, and the layer-step's chunks are its fullest
+    device's); over such an axis `moe.chip_load_max_over_mean_sum` (the mean
+    over a step's layers of each one's fullest chip over the mean, summed
+    over steps); `train.steps_read`. Returns (the last step's readings that
+    are no sum, as numbers and lists; a row `[step, chunks a layer, held
+    rows a layer]` a step of a share). The report waits on the loop's
+    thread with the device idle, so a callable's steps are folded together:
+    a few `numpy` calls a report, not a step."""
+    sums: Dict[str, Any] = {"train.steps_read": len(steps)}
+    rows: List[list] = []
+
+    def add(name: str, n) -> None:
+        sums[name] = sums.get(name, 0) + n.item()
+
+    # from the host's memory: `tracing.Step` started the copies
+    def stacked(readings, name, *shape):
+        made = np.stack([np.asarray(r[name]) for r in readings])
+        return made.reshape(len(made), *(shape or (-1, made.shape[-1])))
+
+    for _, same in itertools.groupby(steps, key=lambda step: id(step[1])):
+        numbers, (static, *_), readings = zip(*same)  # one callable's
+        if "expert_load" not in readings[0]:
+            continue
+        load = stacked(readings, "expert_load")  # [S, layers, E]
+        layers = load.shape[1]
+        sums["moe.layer_steps"] = (
+            sums.get("moe.layer_steps", 0) + load.shape[0] * layers)
+        add("moe.fullest_expert_slots", load.max(-1).sum())
+        add("moe.even_expert_slots", load.mean(-1).sum())
+        if "chip_load_max_over_mean" in readings[0]:
+            add("moe.chip_load_max_over_mean_sum", stacked(
+                readings, "chip_load_max_over_mean", -1).mean(-1).sum())
+        if "held_slots" not in readings[0]:
+            continue
+        held = stacked(readings, "held_slots", layers, -1)  # [.., devices]
+        walked = held - stacked(readings, "dropped_slots", layers, -1)
+        chunk = static["held_chunk"]
+        chunks = np.maximum(1, -(-walked // chunk))
+        fullest = chunks.max(-1)  # [S, layers]
+        add("moe.held_slots", held.sum())
+        add("moe.held_rows", walked.sum())
+        add("moe.dropped_slots", held.sum() - walked.sum())
+        add("moe.buffer_rows", chunks.sum() * chunk)
+        add("moe.extra_chunk_layer_steps", (fullest > 1).sum())
+        rows.extend(map(list, zip(
+            numbers, fullest.tolist(), walked.max(-1).tolist())))
+    for name, n in sums.items():
+        tracing.count(name, n)
+    return {k: np.asarray(v).tolist() for k, v in steps[-1][2].items()
+            if k not in _SUMMED}, rows
+
+
 class RuntimeAccount:
     def __init__(self) -> None:
         watch_gc()
+        tracing.take_steps()  # an earlier session's, or nobody's
         self._began = self._previous = tracing.table(mark=True)
         self._first: Optional[Table] = None
         self._longest: Table = {}  # since the session began
         self._longest_steady: Table = {}  # since the first report
-        self._counters_began = tracing.counters()
+        self._counters_began = self._counters_previous = tracing.counters()
+        self._counters_first: Optional[Dict[str, float]] = None
+        self._unread: deque = deque(maxlen=tracing.STEPS_KEPT)  # not ready
+        self._readings: Dict[str, Any] = {}
+        self._steps: deque = deque(maxlen=tracing.STEPS_KEPT)
         self._usage = _usage()
         self._t_report = time.perf_counter()
         self._intervals: deque = deque(maxlen=64)
@@ -158,8 +256,28 @@ class RuntimeAccount:
             tracing.observe("train.before_first_program", start - called,
                             end=start)
 
+    def _read_steps(self) -> None:
+        """Fold the steps whose readings the device has made; the first
+        that it has not, and those after it, wait for the next report."""
+        self._unread.extend(tracing.take_steps())
+        ready = []
+        while self._unread:
+            state = _ready(self._unread[0][2])
+            if state is False:
+                break
+            step = self._unread.popleft()
+            if state:
+                ready.append(step)
+        if ready:
+            try:
+                self._readings, rows = _fold_steps(ready)
+            except RuntimeError:  # an array deleted since: the report stands
+                return
+            self._steps.extend(rows)
+
     def block(self) -> Dict[str, Any]:
         """This report's block; called once a report."""
+        self._read_steps()
         now_s = time.perf_counter()
         seconds, self._t_report = now_s - self._t_report, now_s
         now = tracing.table(mark=True)
@@ -175,20 +293,26 @@ class RuntimeAccount:
                 now, now if self._first is None else self._first,
                 self._longest_steady),
             "interval": interval,
-            "counters": {k: v - self._counters_began.get(k, 0)
-                         for k, v in counters.items()},
+            "counters": _risen(counters, self._counters_began),
+            "counters_since_first_report": _risen(
+                counters, counters if self._counters_first is None
+                else self._counters_first),
+            "readings": self._readings,
+            "steps": list(self._steps),
             "rusage": {k: v - self._usage.get(k, 0) for k, v in usage.items()},
         }
         if self._reports:  # the first interval is set-up, and no yardstick
-            self._check_slow(seconds, block)
+            self._check_slow(seconds, block, counters)
             self._intervals.append(seconds)
         else:
-            self._first = now
+            self._first, self._counters_first = now, counters
         self._reports += 1
         self._previous, self._usage = now, usage
+        self._counters_previous = counters
         return block
 
-    def _check_slow(self, seconds: float, block: Dict[str, Any]) -> None:
+    def _check_slow(self, seconds: float, block: Dict[str, Any],
+                    counters: Dict[str, float]) -> None:
         if len(self._intervals) < SLOW_MIN_SEEN:
             return
         median = statistics.median(self._intervals)
@@ -201,5 +325,10 @@ class RuntimeAccount:
             "rusage": block["rusage"],
             "compiles": block["interval"].get("jax.compile", (0,))[0],
         }
+        moe = {k: v for k, v in _risen(
+            counters, self._counters_previous).items()
+            if v and k.startswith("moe.")}
+        if moe:  # what the interval's steps routed, where they routed
+            record["moe"] = moe
         telemetry.record_event("train", "slow_interval", **record)
         logger.warning("train slow_interval %s", _rounded(record))

@@ -432,26 +432,93 @@ def table(mark: bool = False) -> Dict[str, list]:
 
 
 def clear_table() -> None:
-    """Empty the table and the counters: the cluster this process drove has
-    ended (``ray_tpu.shutdown()``), and the next one's ``init`` is not to
-    be read together with this one's. A span that is open now is counted
-    when it ends."""
+    """Empty the table, the counters and the steps not yet taken: the
+    cluster this process drove has ended (``ray_tpu.shutdown()``), and the
+    next one's ``init`` is not to be read together with this one's. A span
+    that is open now is counted when it ends."""
     with _tables_lock:
         for _, rows in _tables.values():
             rows.clear()
         _retired.clear()
         _counters.clear()
+    _steps.clear()
 
 
-def count(name: str, n: int = 1) -> None:
-    """Add to a counter of the train path (``compile.programs``, ...)."""
+def count(name: str, n: float = 1) -> None:
+    """Add to a counter of the train path (``compile.programs``, ...): a
+    whole number, or where ratios are summed a float."""
     with _tables_lock:  # a compilation's pace, not a span's: a lock is cheap
         _counters[name] = _counters.get(name, 0) + n
 
 
-def counters() -> Dict[str, int]:
+def counters() -> Dict[str, float]:
     with _tables_lock:
         return dict(_counters)
+
+
+# ---------------------------------------------------------------------------
+# The step: the one boundary a train job crosses most often. The program sees
+# its own steps here, whoever's loop calls them.
+# ---------------------------------------------------------------------------
+
+STEPS_KEPT = 64
+READING_BYTES = 1 << 16  # a reading above this is no number to report
+# (the step's number, its callable's `static`, its readings), oldest first,
+# until the train session's account takes them (`take_steps`). Bounded:
+# outside a session nothing takes them, and the oldest goes.
+_steps: "deque[tuple]" = deque(maxlen=STEPS_KEPT)
+
+
+class Step:
+    """A jitted train step `(state, batch) -> (state, readings)` as the
+    program sees it run: a call is the span ``train.step`` (the host's
+    seconds to hand the step to the runtime; its count is the program's own
+    number of steps) and leaves the step's readings (those of
+    ``READING_BYTES`` at most), device arrays that the call does not wait
+    for, where the train session's account finds them
+    (``ray_tpu/train/_runtime.py``). Their copies to the host are started
+    here (``copy_to_host_async``: queued behind the step, waited for by
+    nobody), so that a report reads them from the host's memory: fetched
+    at the report they cost 0.15 ms an array on a v5e's host, 3.4 ms a
+    chunk of five steps, with the device idle (PERF.md section 6, PR 68).
+    ``static`` holds what the step's trace said of its program and no run
+    changes. Every other attribute (``lower``, ``trace``, ``eval_shape``,
+    ``_cache_size``, ...) is the jitted function's."""
+
+    def __init__(self, jitted, static: Dict[str, Any]):
+        self._jitted = jitted
+        self.static = static
+        self.calls = 0
+
+    def __call__(self, *args, **kwargs):
+        with span("train.step"):
+            out = self._jitted(*args, **kwargs)
+        readings = out[1]
+        # traced through (`jax.eval_shape(step, ...)`), it ran nothing
+        if hasattr(next(iter(readings.values()), None), "copy_to_host_async"):
+            kept = {name: x for name, x in readings.items()
+                    if x.nbytes <= READING_BYTES}
+            for x in kept.values():
+                x.copy_to_host_async()
+            self.calls += 1
+            _steps.append((self.calls, self.static, kept))
+        return out
+
+    def __getattr__(self, name: str):
+        if name == "_jitted":  # a copy not yet filled: no attribute, no loop
+            raise AttributeError(name)
+        return getattr(self._jitted, name)
+
+
+def take_steps() -> List[tuple]:
+    """The steps that ran since the last call, oldest first, the last
+    ``STEPS_KEPT`` of them at most: theirs who calls."""
+    taken = []
+    while True:
+        try:
+            taken.append(_steps.popleft())
+        except IndexError:
+            return taken
 
 
 def span_flush_delta() -> List[dict]:
@@ -527,10 +594,6 @@ def stop_flusher() -> None:
     both the restart and the GCS's query-time local drain."""
     global _flusher_started
     _flusher_started = False
-
-
-def reset_flusher_for_test() -> None:
-    stop_flusher()
 
 
 def snapshot() -> List[dict]:

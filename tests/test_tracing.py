@@ -260,7 +260,7 @@ def test_worker_exit_flushes_spans(monkeypatch, shutdown_only):
     from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import tracing
 
-    tracing.reset_flusher_for_test()
+    tracing.stop_flusher()
     tracing.reset()
     ray_tpu.init(num_cpus=2, num_tpus=0)
 

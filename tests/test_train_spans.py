@@ -438,3 +438,289 @@ def test_no_span_is_lost_while_the_table_is_read():
     assert tracing.table()["test.stress"][0] - before == threads * spans_each
     assert tracing.counters()["test.stress"] >= threads
     assert seen == sorted(seen)  # a reading never goes back
+
+
+# --- the step accounts for itself: `tracing.Step`, `_runtime._fold_steps` ---
+
+ROUTED = dict(
+    vocab_size=128, d_model=32, n_layers=4, n_heads=4, n_kv_heads=2, d_ff=16,
+    max_seq_len=32, n_experts=8, experts_per_token=2, norm_topk_prob=True,
+    tied_embeddings=False, attention_impl="xla", router_aux_loss_coef=0.01,
+    remat=True)
+# (what a routed layer holds, the mesh's axis and its devices)
+ROUTED_CELLS = {
+    "share": ((2, 2), "data", 1),       # two of eight experts: `held_slots`
+    "all_held": (None, "data", 1),      # olmoe's shape: no `moe.held_*`
+    "expert_axis": (None, "expert", 4),  # a chip a share: `chip_load`
+}
+
+
+class _Job:
+    """A tiny routed train step on the CPU, its state and one batch."""
+
+    def __init__(self, held, axis, ways):
+        import jax
+        import jax.numpy as jnp
+        import optax
+
+        from ray_tpu.models import TransformerConfig, make_train_step
+        from ray_tpu.ops import moe
+        from ray_tpu.parallel import make_mesh
+
+        if len(jax.devices()) < ways:
+            pytest.skip(f"{ways} devices for the `{axis}` axis")
+        self.asked = []  # what the routed layers' trace asked of `held_chunk`
+        held_chunk = moe.held_chunk
+
+        def recorded(*args, **kwargs):
+            self.asked.append(held_chunk(*args, **kwargs))
+            return self.asked[-1]
+
+        self.cfg = TransformerConfig(
+            **ROUTED, dtype=jnp.float32, experts_held=held)
+        mesh = make_mesh({axis: ways}, jax.devices()[:ways])
+        init, self.step, shardings = make_train_step(
+            self.cfg, mesh, optax.adamw(1e-3))
+        self.state = init(jax.random.PRNGKey(0))
+        self.batch = {"tokens": jax.device_put(
+            jax.random.randint(jax.random.PRNGKey(2), (4, 33), 0, 128),
+            shardings["tokens"])}
+        with pytest.MonkeyPatch.context() as patch:
+            # buffers of a few rows, so that a layer walks a second chunk
+            patch.setattr(moe, "_ROW_TILE", 8)
+            patch.setattr(moe, "held_chunk", recorded)
+            self.lowered = self.step.lower(self.state, self.batch).as_text()
+            self.run(1)
+
+    def run(self, steps):
+        """`steps` steps, waited for on every device (`device_get` alone
+        waits for the one it reads from); their readings on the host."""
+        import jax
+
+        outs = []
+        for _ in range(steps):
+            self.state, out = self.step(self.state, self.batch)
+            outs.append(out)
+        return jax.device_get(jax.block_until_ready(outs))
+
+
+@pytest.fixture(scope="module")
+def jobs():
+    made = {}
+
+    def job(name):
+        if name not in made:
+            made[name] = _Job(*ROUTED_CELLS[name])
+        return made[name]
+
+    return job
+
+
+def _moe(counters):
+    return {k: v for k, v in counters.items()
+            if k.startswith("moe.") or k == "train.steps_read"}
+
+
+def _by_hand(outs, chunk):
+    """The counters `_fold_steps` keeps, summed from steps' outputs."""
+    import numpy as np
+
+    want = dict.fromkeys((
+        "moe.layer_steps", "moe.fullest_expert_slots",
+        "moe.even_expert_slots", "moe.held_slots", "moe.dropped_slots",
+        "moe.held_rows", "moe.buffer_rows", "moe.extra_chunk_layer_steps",
+        "moe.chip_load_max_over_mean_sum"), 0)
+    want["train.steps_read"] = len(outs)
+    for out in outs:
+        for layer, load in enumerate(out["expert_load"]):
+            want["moe.layer_steps"] += 1
+            want["moe.fullest_expert_slots"] += int(max(load))
+            want["moe.even_expert_slots"] += sum(map(int, load)) / len(load)
+            if "held_slots" not in out:
+                continue
+            held = np.atleast_1d(out["held_slots"][layer])  # a device each
+            dropped = np.atleast_1d(out["dropped_slots"][layer])
+            chunks = [max(1, -(-int(h - d) // chunk))
+                      for h, d in zip(held, dropped)]
+            want["moe.held_slots"] += int(held.sum())
+            want["moe.dropped_slots"] += int(dropped.sum())
+            want["moe.held_rows"] += int((held - dropped).sum())
+            want["moe.buffer_rows"] += sum(chunks) * chunk
+            want["moe.extra_chunk_layer_steps"] += max(chunks) > 1
+        if "chip_load_max_over_mean" in out:
+            load = out["chip_load"].astype(float)  # [L, chips]
+            want["moe.chip_load_max_over_mean_sum"] += float(
+                (load.max(-1) / load.mean(-1)).mean())
+    return want
+
+
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+def test_the_step_is_the_jitted_function_under_a_span(jobs, cell):
+    import jax
+
+    job = jobs(cell)
+    step = job.step
+    assert isinstance(step, tracing.Step)
+    bare = step._jitted
+    assert step.lower.__self__ is bare and step.trace.__self__ is bare
+    assert job.lowered == bare.lower(job.state, job.batch).as_text()
+    calls = tracing.table().get("train.step", [0])[0]
+    cached, kept = step._cache_size(), list(tracing._steps)
+    # traced through, a step ran nothing and leaves the account nothing
+    shapes = jax.eval_shape(step, job.state, job.batch)[1]
+    assert shapes["loss"].shape == () and list(tracing._steps) == kept
+    number = step.calls
+    job.run(3)
+    assert step.calls == number + 3 and step._cache_size() == cached
+    assert tracing.table()["train.step"][0] == calls + 1 + 3
+    assert [n for n, _, _ in tracing._steps][-3:] == [
+        number + 1, number + 2, number + 3]
+    # the rows of a share's buffers are what its layers' trace asked for
+    share = cell != "all_held"
+    assert set(job.asked) == ({step.static["held_chunk"]} if share else set())
+    assert set(step.static) == ({"held_chunk"} if share else set())
+
+
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+def test_a_report_folds_the_steps_readings_into_the_counters(jobs, cell):
+    job = jobs(cell)
+    account = _runtime.RuntimeAccount()
+    outs = job.run(5)
+    block = account.block()
+    want = _by_hand(outs, job.step.static.get("held_chunk"))
+    got = _moe(block["counters"])
+    assert set(got) <= set(want)
+    assert {k: got.get(k, 0) for k in want} == pytest.approx(want)
+    assert got["train.steps_read"] == block["total"]["train.step"][0] == 5
+    share = cell != "all_held"
+    assert (got.get("moe.held_rows", 0) > 0) == share
+    assert ("moe.chip_load_max_over_mean_sum" in got) == (
+        cell == "expert_axis")
+    if share:  # tiles of 8 rows: a layer-step in two walks a second chunk
+        assert 0 < got["moe.extra_chunk_layer_steps"] < got["moe.layer_steps"]
+        assert got["moe.held_rows"] < got["moe.buffer_rows"]
+        assert got["moe.dropped_slots"] == 0
+    # a row a step of a share: [step, chunks a layer, held rows a layer]
+    assert len(block["steps"]) == (5 if share else 0)
+    for (number, chunks, rows), out in zip(block["steps"], outs):
+        assert len(chunks) == len(rows) == job.cfg.n_layers
+        held = (out["held_slots"] - out["dropped_slots"]).reshape(
+            job.cfg.n_layers, -1).max(-1)
+        assert rows == held.tolist()
+    assert [row[0] for row in block["steps"]] == list(
+        range(job.step.calls - 4, job.step.calls + 1))[:len(block["steps"])]
+    # what no counter sums, as the last step left it
+    assert block["readings"]["grad_norm"] == pytest.approx(
+        float(outs[-1]["grad_norm"]))
+    assert not set(block["readings"]) & set(_runtime._SUMMED)
+    json.dumps(block)  # numbers and lists: a report's metrics
+
+
+class _Late:
+    """A reading the device has not made yet, until `ready` is set."""
+
+    def __init__(self, array):
+        self.array, self.ready, self.size = array, False, array.size
+
+    def is_ready(self):
+        return self.ready
+
+    def is_deleted(self):
+        return False
+
+    def __array__(self, *args, **kwargs):
+        assert self.ready, "the account waited for the device"
+        return self.array.__array__(*args, **kwargs)
+
+
+def test_a_step_that_is_not_ready_waits_for_the_next_report(jobs):
+    job = jobs("share")
+    account = _runtime.RuntimeAccount()
+    before = _moe(tracing.counters())
+    first, late, third = job.run(3)
+    first, late, third = list(tracing._steps)[-3:]
+    tracing._steps.clear()
+    held = {k: _Late(v) for k, v in late[2].items()}
+    tracing._steps.extend([first, (late[0], late[1], held), third])
+    block = account.block()
+    read = {k: v - before[k] for k, v in _moe(tracing.counters()).items()}
+    assert read["train.steps_read"] == 1  # the third stands behind the late
+    assert [row[0] for row in block["steps"]] == [first[0]]
+    assert len(account._unread) == 2 and not tracing._steps
+    assert account.block()["steps"] == block["steps"]  # still not ready
+    for reading in held.values():
+        reading.ready = True
+    block = account.block()
+    assert [row[0] for row in block["steps"]] == [first[0], late[0], third[0]]
+    assert block["counters"]["train.steps_read"] == 3
+    assert not account._unread
+
+
+def test_with_no_session_the_steps_kept_are_bounded():
+    import jax
+
+    step = tracing.Step(
+        jax.jit(lambda state, batch: (state + 1, {"loss": batch.sum()})), {})
+    tracing.take_steps()
+    state = 0
+    for _ in range(tracing.STEPS_KEPT + 6):
+        state, out = step(state, jax.numpy.ones(3))
+    assert int(state) == step.calls == tracing.STEPS_KEPT + 6
+    assert [n for n, _, _ in tracing._steps] == list(
+        range(7, tracing.STEPS_KEPT + 7))
+    taken = tracing.take_steps()
+    assert len(taken) == tracing.STEPS_KEPT and not tracing._steps
+    # a session's account begins with none of them
+    step(state, jax.numpy.ones(3))
+    account = _runtime.RuntimeAccount()
+    assert not tracing._steps and not account._unread
+    # a reading deleted since is dropped, and the report stands: the first
+    # of a step's, which is the one asked whether it is ready, or another
+    both = tracing.Step(jax.jit(lambda state, batch: (
+        state + 1, {"loss": batch.sum(), "grad_norm": batch.max()})), {})
+    for made, name in ((step, "loss"), (both, "grad_norm")):
+        _, out = made(state, jax.numpy.ones(3))
+        out[name].delete()
+        block = account.block()
+        assert block["counters"].get("train.steps_read", 0) == 0
+        assert not account._unread and block["readings"] == {}
+
+
+def test_counters_since_first_report_leave_the_first_reports_out(jobs):
+    job = jobs("share")
+    session = _TrainSession()
+    job.run(2)
+    session.report({"i": 0})
+    job.run(3)
+    session.report({"i": 1})
+    first, last = (session.result_queue.get_nowait().metrics[KEY]
+                   for _ in range(2))
+    assert first["counters"]["train.steps_read"] == 2
+    assert not any(first["counters_since_first_report"].values())
+    assert last["counters"]["train.steps_read"] == 5
+    steady = last["counters_since_first_report"]
+    assert steady["train.steps_read"] == 3
+    assert steady["train.steps_read"] == last["since_first_report"][
+        "train.step"][0]
+    for name, n in _moe(last["counters"]).items():
+        assert steady[name] == pytest.approx(n - first["counters"][name])
+    assert steady["moe.layer_steps"] == 3 * job.cfg.n_layers
+    assert [row[0] for row in last["steps"]][-3:] == list(
+        range(job.step.calls - 2, job.step.calls + 1))
+
+
+def test_a_slow_interval_says_what_its_steps_routed(jobs):
+    job = jobs("share")
+    telemetry.flight().clear()
+    session = _TrainSession()
+    for i in range(7):
+        time.sleep(0.02)
+        session.report({"i": i})
+    outs = job.run(2)
+    time.sleep(0.4)
+    session.report({"i": 7})
+    (record,) = _slow_events()
+    want = _by_hand(outs, job.step.static["held_chunk"])
+    assert record["moe"] == pytest.approx(
+        {k: v for k, v in want.items() if v and k.startswith("moe.")})
+    assert record["spans"]["train.step"][0] == 2

@@ -1,19 +1,21 @@
 #!/usr/bin/env python3
 """The Mamba-2 mixer's two bandwidth passes, and the KDA mixer's short
-convolutions, alone on the chip: `ops/mamba_passes.py`'s kernels against
-its `jax.numpy` paths.
+convolutions and its output norm and gate, alone on the chip:
+`ops/mamba_passes.py`'s kernels against its `jax.numpy` paths.
 
-    python3 benchmarks/mamba_passes_alone.py [--shapes cell,probe,kimi,solar] [--passes conv,norm,kda_conv] [--paths xla,pallas] [--conv-blocks 512x512x32,...] [--norm-blocks 256x32,...] [--seed 0]
+    python3 benchmarks/mamba_passes_alone.py [--shapes cell,probe,kimi,solar] [--passes conv,norm,kda_conv,kda_out_norm] [--paths xla,pallas] [--conv-blocks 512x512x32,...] [--norm-blocks 256x32,...] [--out-norm-blocks 512x1024x32,...] [--seed 0]
 
 At each shape (`cell`: the 2 x 8,192 tokens a mixer of
 `nemotron3nano.tokens8k` hands them, the convolution over 6,144 channels of 4
 taps split 4,096 / 1,024 / 1,024, the norm over 8 groups of 512, bf16;
-`probe`: 2,048 tokens a row; `kimi` and `solar`, pass `kda_conv` alone (PR
-67): a KDA layer's three streams q, k and v as `kimilinear.tokens16k` and
-`solaropen2.tokens8k` hand them, `[1, 16384, 4096]` and `[1, 8192, 1024]`,
-4 taps each, q's and k's heads of 128 then at unit length: three calls, as
-`models/transformer.py` `_kda_mixer` makes them) and for each pass: the
-forward alone and the
+`probe`: 2,048 tokens a row; `kimi` and `solar`, the KDA mixer's passes
+alone: `kda_conv` (PR 67), a KDA layer's three streams q, k and v as
+`kimilinear.tokens16k` and `solaropen2.tokens8k` hand them, `[1, 16384,
+4096]` and `[1, 8192, 1024]`, 4 taps each, q's and k's heads of 128 then at
+unit length: three calls, as `models/transformer.py` `_kda_mixer` makes
+them; `kda_out_norm` (PR 69), the recurrence's o and the gate's
+pre-activation at those shapes through `group_rmsnorm_gated`, 32 and 8
+heads of 128: one call) and for each pass: the forward alone and the
 forward with the backward of every input (`jax.vjp` under one `jit`), the
 host's clock over 10 calls after one that compiles; the bytes the
 mathematics has to move (each operand read once and each result written
@@ -21,8 +23,9 @@ once; with the backward the inputs read again, the cotangents read and the
 inputs' written) and the share of HBM's rate that is; and the distance of
 the kernels' results and gradients from the `jax.numpy` path's. The kernels
 are timed at each `tokens x channels x tokens-a-trip` of `--conv-blocks`
-and `tokens x tokens-a-trip` of `--norm-blocks` (the module's own choice
-first, and marked). Prints one JSON line a measurement and fails without a
+and `tokens x tokens-a-trip` of `--norm-blocks`, and `tokens x channels x
+tokens-a-trip` of `--out-norm-blocks` (the module's own choice first, and
+marked). Prints one JSON line a measurement and fails without a
 TPU: a CPU's time is not a chip's.
 """
 
@@ -77,6 +80,15 @@ def kda_conv(q, k, v, taps, *, unit, path):
         for i, s in enumerate((q, k, v)))
 
 
+def kda_out_norm_inputs(shape, seed, dtype=jnp.bfloat16):
+    """((o, the gate's pre-activation, its bias, the heads' one scale), a
+    cotangent)."""
+    (o, z, weight), ct = norm_inputs(shape, seed, dtype)
+    bias = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(seed + 2), (o.shape[-1],))
+    return (o, z, bias, weight[:shape["unit"]]), ct
+
+
 def norm_inputs(shape, seed, dtype=jnp.bfloat16):
     B, T, inner = shape["B"], shape["T"], shape["splits"][0]
     ks = jax.random.split(jax.random.PRNGKey(seed + 1), 4)
@@ -92,8 +104,9 @@ def needed_bytes(name, shape, backward: bool, item: int = 2) -> int:
     the result written; with the backward y, z and the cotangent read, dy
     and dz written. The parameters' few KB are left out."""
     B, T, splits = shape["B"], shape["T"], shape["splits"]
-    width = splits[0] if name == "norm" else sum(splits)
-    arrays = ((3, 5) if name == "norm" else (2, 3))
+    norm = name in ("norm", "kda_out_norm")  # else a convolution
+    width = splits[0] if norm else sum(splits)
+    arrays = (3, 5) if norm else (2, 3)
     return (arrays[0] + backward * arrays[1]) * B * T * width * item
 
 
@@ -123,6 +136,7 @@ def main():
     parser.add_argument("--paths", default="xla,pallas")
     parser.add_argument("--conv-blocks", default="")
     parser.add_argument("--norm-blocks", default="")
+    parser.add_argument("--out-norm-blocks", default="")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args()
     device = jax.devices()[0]
@@ -131,13 +145,16 @@ def main():
     # the module's constants a pass's blocks are read from, in the order a
     # `--conv-blocks` or `--norm-blocks` entry names them
     knobs = {"conv": ("_CONV_TOKENS", "_CONV_CHANNELS", "_ROWS"),
-             "norm": ("_NORM_TOKENS", "_ROWS")}
+             "norm": ("_NORM_TOKENS", "_ROWS"),
+             "kda_out_norm": ("_GATED_TOKENS", "_GATED_LANES", "_GATED_ROWS")}
     knobs["kda_conv"] = knobs["conv"]
     inputs = {"conv": conv_inputs, "norm": norm_inputs,
-              "kda_conv": kda_conv_inputs}
+              "kda_conv": kda_conv_inputs,
+              "kda_out_norm": kda_out_norm_inputs}
     own = {name: tuple(getattr(lib, k) for k in names)
            for name, names in knobs.items()}
-    swept = {"conv": blocks(args.conv_blocks), "norm": blocks(args.norm_blocks)}
+    swept = {"conv": blocks(args.conv_blocks), "norm": blocks(args.norm_blocks),
+             "kda_out_norm": blocks(args.out_norm_blocks)}
     swept["kda_conv"] = swept["conv"]
 
     def take(name, block):
@@ -147,7 +164,7 @@ def main():
     for shape_name in args.shapes.split(","):
         shape = SHAPES[shape_name]
         for name in args.passes.split(","):
-            if (name == "kda_conv") != ("unit" in shape):
+            if name.startswith("kda_") != ("unit" in shape):
                 continue  # a KDA layer's shapes take its pass alone
             operands, cts = inputs[name](shape, args.seed)
             runs = [(path, block) for path in args.paths.split(",")
@@ -164,6 +181,11 @@ def main():
                 elif name == "kda_conv":
                     def forward(*a, path=path):
                         return kda_conv(*a, unit=shape["unit"], path=path)
+                elif name == "kda_out_norm":
+                    def forward(*a, path=path):
+                        return lib.group_rmsnorm_gated(
+                            *a, shape["splits"][0] // shape["unit"], EPS,
+                            impl=path)
                 else:
                     def forward(*a, path=path):
                         return lib.gated_group_rmsnorm(

@@ -262,7 +262,7 @@ from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
 from ray_tpu.ops.mamba_passes import (
     _log_pass, causal_conv_silu, conv_untiled, gated_group_rmsnorm,
-    norm_untiled)
+    group_rmsnorm_gated, norm_untiled)
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
 from ray_tpu.ops.selective_scan import (
     channel_block, selective_scan, selective_scan_untiled)
@@ -1476,20 +1476,39 @@ def _kda_conv_untiled(cfg: TransformerConfig, T: Optional[int] = None):
         T, cfg.kda_head_dim)
 
 
-def _kda_conv_calls_said(before) -> str:
+def _kda_out_norm_kernels(cfg: TransformerConfig,
+                          T: Optional[int] = None) -> bool:
+    """Whether the KDA mixer's output norm and gate run as
+    `ops/mamba_passes.py`'s kernels (`kda_out_norm_fwd`,
+    `kda_out_norm_bwd`): the operators resolve to Pallas and the shape
+    tiles."""
+    return _kernel_impl(cfg) == "pallas" and not _kda_out_norm_untiled(
+        cfg, T)
+
+
+def _kda_out_norm_untiled(cfg: TransformerConfig, T: Optional[int] = None):
+    H = _OPERATORS["kda"].heads(cfg)
+    return norm_untiled(H * cfg.kda_head_dim, H, T, "kda_out_norm")
+
+
+def _kda_calls_said(before) -> str:
     """What a trace added to the counts of the KDA mixers' short
-    convolutions since the counters read `before`, by the path each call
-    took, for the step's log line; nothing where no such layer was traced."""
+    convolutions (three calls a mixer) and of their output norms and gates
+    (one) since the counters read `before`, by the path each call took, for
+    the step's log line; nothing where no such layer was traced."""
     now = tracing.counters()
-    kernels, numpy = (
-        now.get(name, 0) - before.get(name, 0)
-        for name in ("train.kda_conv_calls_kernels",
-                     "train.kda_conv_calls_numpy"))
-    if not kernels + numpy:
-        return ""
-    return ("; KDA's short convolutions: %d calls by the kernels "
-            "kda_conv_fwd and kda_conv_bwd, %d by jax.numpy" % (
-                kernels, numpy))
+    said = ""
+    for what, name in (("short convolutions", "kda_conv"),
+                       ("output norms and gates", "kda_out_norm")):
+        kernels, numpy = (
+            now.get(counter, 0) - before.get(counter, 0)
+            for counter in (f"train.{name}_calls_kernels",
+                            f"train.{name}_calls_numpy"))
+        if kernels + numpy:
+            said += ("; KDA's %s: %d calls by the kernels %s_fwd and "
+                     "%s_bwd, %d by jax.numpy" % (
+                         what, kernels, name, name, numpy))
+    return said
 
 
 def _kda_mixer(x, blk, cfg: TransformerConfig):
@@ -1502,7 +1521,11 @@ def _kda_mixer(x, blk, cfg: TransformerConfig):
     `kda_allow_neg_eigval`, `sigmoid(W_b u)`) a head, in float32; the recurrence in its chunked form (`ops/kda.py`);
     `RMSNorm(o)` over each head's width with one learned scale, times
     `sigmoid(W_g2 (W_g1 u) + b_g)`; `W_o`. The three narrow products
-    (`W_f1`, `W_g1`, `W_b`) are one matmul."""
+    (`W_f1`, `W_g1`, `W_b`) are one matmul. Where the operators are Pallas's
+    and the shapes tile, the short convolutions and the output norm with its
+    gate are `ops/mamba_passes.py`'s one-pass kernels on `[B, T, H dk]`
+    (`_kda_conv_kernels`, `_kda_out_norm_kernels`); elsewhere the
+    `jax.numpy` lines below."""
     B, T, d = x.shape
     H, dk = _OPERATORS["kda"].heads(cfg), cfg.kda_head_dim
     rank = blk["kda_f1"].shape[-1]
@@ -1547,15 +1570,32 @@ def _kda_mixer(x, blk, cfg: TransformerConfig):
         beta = jax.nn.sigmoid(b_low.astype(f32))
         if cfg.kda_allow_neg_eigval:
             beta = 2.0 * beta
-        gate = jax.nn.sigmoid(
-            (g_low @ blk["kda_g2"].astype(dt)).astype(f32)
-            + blk["kda_g_bias"].astype(f32)).astype(dt)
+        gate = g_low @ blk["kda_g2"].astype(dt)
+        out_kernels = _kda_out_norm_kernels(cfg, T)
+        if not out_kernels:  # else `kda_out`'s kernels make the sigmoid
+            gate = jax.nn.sigmoid(
+                gate.astype(f32) + blk["kda_g_bias"].astype(f32)).astype(dt)
     # names its own operations `kda_chunk`, `kda_state` and `kda_out`
     o, _, log_decay_min = kda(q, k, v, log_decay, beta, chunk=cfg.kda_chunk,
                               impl=_kernel_impl(cfg))
     with jax.named_scope("kda_out"):
-        o = fused_rmsnorm(o, blk["kda_out_norm"], eps=cfg.norm_eps)
-        y = (o.reshape(B, T, H * dk) * gate) @ blk["kda_o"].astype(dt)
+        if out_kernels:
+            # the norm a head, its scale and the gate: one read of o and of
+            # the gate's pre-activation and one write, on `[B, T, H dk]` as
+            # `kda` and the matmul leave them (`ops/mamba_passes.py`)
+            o = group_rmsnorm_gated(
+                o.reshape(B, T, H * dk), gate, blk["kda_g_bias"],
+                blk["kda_out_norm"], H, cfg.norm_eps, impl=_kernel_impl(cfg))
+            tracing.count("train.kda_out_norm_calls_kernels")
+        else:
+            # the `jax.numpy` lines stand here, as `kda_conv`'s do
+            o = fused_rmsnorm(o, blk["kda_out_norm"], eps=cfg.norm_eps)
+            o = o.reshape(B, T, H * dk) * gate
+            _log_pass("kda_out_norm", _kernel_impl(cfg) == "pallas",
+                      _kda_out_norm_untiled(cfg, T), (B, T, H * dk), (H,),
+                      jnp.dtype(dt).name)
+            tracing.count("train.kda_out_norm_calls_numpy")
+        y = o @ blk["kda_o"].astype(dt)
     return y, {"kda_log_decay_min": log_decay_min,
                "kda_beta_mean": beta.mean()}
 
@@ -4408,7 +4448,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         calls = tracing.counters()
         (loss, readings), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state["params"], batch, saved_names=kept, **bias)
-        logger.info(said + _kda_conv_calls_said(calls))
+        logger.info(said + _kda_calls_said(calls))
         with jax.named_scope("optimizer"):
             updates, opt = optimizer.update(
                 grads, state["opt"], state["params"]

@@ -118,6 +118,17 @@ conditions (the operators resolve to Pallas, the streams tile:
 stream read once and written once; elsewhere as the mixer's `jax.numpy`
 lines. `train.kda_conv_calls_kernels` and `train.kda_conv_calls_numpy`
 count the calls of each (docs/observability.md; PERF.md section 6, PR 67).
+
+What takes o is the mixer's too: the norm a head under one learned scale and
+the sigmoid gate (scope `kda_out` of the mixer, not this module's `kda_out`
+of the `jax.numpy` path). The kernels write o as `[b, T, H dv]`, and where
+the heads fill whole groups `kda` returns a free reshape of it, so by the
+same two conditions (`ops/mamba_passes.py` `norm_untiled`) that pass reads
+it where it lies, as the kernel pair `kda_out_norm_fwd` and
+`kda_out_norm_bwd` (`group_rmsnorm_gated`); no transpose and no float32
+copy stands between the two kernels. `train.kda_out_norm_calls_kernels` and
+`train.kda_out_norm_calls_numpy` count the mixers of each (PERF.md section
+6, PR 69).
 """
 
 from __future__ import annotations

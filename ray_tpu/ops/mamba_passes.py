@@ -1,5 +1,6 @@
 """The two bandwidth passes of a Mamba-2 mixer, each as one pass over HBM,
-and with the first the KDA mixer's short convolutions.
+with the first the KDA mixer's short convolutions, and beside the second
+the KDA mixer's output norm and gate.
 
 Beside its matmuls and its scan (`ops/ssd.py`) the mixer runs two passes
 that multiply no matrix, and whose time is the bytes they move:
@@ -16,6 +17,16 @@ that multiply no matrix, and whose time is the bytes they move:
 - **`gated_group_rmsnorm(y, z, weight, groups, eps)`**: `GroupRMSNorm(y *
   silu(z))`, the mean square over each of `groups` groups of channels, one
   learned scale a channel.
+- **`group_rmsnorm_gated(o, z, bias, weight, groups, eps)`** (PR 69): the
+  KDA mixer's own pass behind its recurrence (`ops/kda.py`),
+  `RMSNorm(o) * weight * sigmoid(z + bias)`: the norm first, over each of
+  `groups` heads, one learned scale `[dk]` shared by the heads, and the gate
+  outside the norm, a sigmoid with a bias (`_kda_mixer`, scope `kda_out`).
+  Another formula than Mamba-2's and another backward rule, so kernel bodies
+  of its own (`_gated_fwd_kernel`, `_gated_bwd_kernel`), chosen by which of
+  the two functions a layer calls; the grid, the specs, the blocks, the
+  conditions and the log line are the gated norm's (`_norm_call`,
+  `norm_blocks`, `norm_untiled`, `_log_pass`, `pass_vmem_bytes`).
 
 Two paths compute each, as `ops/ssd.py`'s: the kernels where the step's
 operators resolve to Pallas (`impl`: the TPU) and the shape tiles
@@ -31,7 +42,8 @@ line once a shape says which (`_log_pass`).
   section 6, PR 63).
 - **Kernel pairs behind a `custom_vjp`: `mamba_conv_fwd`, `mamba_conv_bwd`,
   `mamba_norm_fwd`, `mamba_norm_bwd`; the convolution's pair under the
-  caller's `name`, `kda_conv_fwd` and `kda_conv_bwd` for the KDA mixer.**
+  caller's `name`, `kda_conv_fwd` and `kda_conv_bwd` for the KDA mixer;
+  `kda_out_norm_fwd` and `kda_out_norm_bwd`.**
   Each reads an operand once and
   writes a result once; every intermediate is float32 in VMEM; the
   residuals are the inputs alone, so the backward makes the pre-activation
@@ -75,7 +87,22 @@ line once a shape says which (`_log_pass`).
   the cotangent, writes `dy` and `dz` in their dtype and sums `d weight` in
   float32 in one block that stays in VMEM across the grid. No float32
   array of the mixer's width reaches HBM.
-  `benchmarks/mamba_passes_alone.py` times both paths alone.
+
+  KDA's output norm and gate walk (block of heads, batch row, block of
+  tokens): a row holds 32 heads in `kimilinear.tokens16k`, and a trip
+  unrolls the heads of its block alone (`_GATED_LANES` channels), a head a
+  lane tile or several. The lines it replaces norm o on `[B, T, H, dk]` in
+  float32, which this chip tiles with the heads on the sublanes: the
+  compiler copied o to and from the layout of `[B, T, H dk]`, twelve copies
+  of 268 MB a step there, and rounded the normed o and the gate to bf16
+  before their product (PERF.md section 6, PR 69). The kernels read o and
+  the gate's pre-activation as `kda` and the matmul leave them, `[B, T, H
+  dk]`, hold the statistics, the sigmoid and the product in float32 and
+  round once; the backward makes them again, writes `do` and `dz` and sums
+  `d weight` (over tokens, and over the heads outside) and `d bias` in
+  sixteen float32 partial rows a block of heads, which stay in VMEM while
+  the grid walks that block's tokens.
+  `benchmarks/mamba_passes_alone.py` times every pass's two paths alone.
 """
 
 from __future__ import annotations
@@ -108,6 +135,11 @@ _ROWS = 32
 # want more rows in flight (the sweep: PERF.md section 6, PR 67)
 _UNIT_ROWS = 128
 _UNIT_EPS = 1e-6  # under a head's unit length, as the model's `_unit_length`
+# the most tokens and channels a grid step of KDA's output norm takes, and the
+# tokens a trip (the sweep: PERF.md section 6, PR 69)
+_GATED_TOKENS = 2048
+_GATED_LANES = 512
+_GATED_ROWS = 64
 
 
 def _largest(size: int, step: int, most: int) -> int:
@@ -268,17 +300,18 @@ def _vmem_limit(kernel, tokens, width, itemsize, arrays=1) -> int:
 def _log_pass(name, kernels, untiled, shape, rest, dtype):
     """One line for each pass and shape a process traces, as `ops/ssd.py`'s
     `_log_scan`: which path, and the kernels' grid, blocks and VMEM. `name`
-    is "gated_group_rmsnorm" or the name the convolution's caller gave."""
+    is the kernels' (`<name>_fwd`, `<name>_bwd`); `rest` is a convolution's
+    (taps, splits, unit) or a norm's (groups,)."""
     B, T, C = shape
-    norm = name == "gated_group_rmsnorm"  # else the convolution's caller
-    said = (f"{'causal_conv_silu' if name == 'mamba_conv' else name} at B {B}, "
-            f"T {T}, C {C}, {dtype}")
+    said = {"mamba_conv": "causal_conv_silu",
+            "mamba_norm": "gated_group_rmsnorm"}.get(name, name)
+    said = f"{said} at B {B}, T {T}, C {C}, {dtype}"
     item = jnp.dtype(dtype).itemsize
     if not kernels:
         logger.info("%s: jax.numpy", said)
     elif untiled:
         logger.info("%s: jax.numpy, because %s", said, untiled)
-    elif not norm:
+    elif len(rest) == 3:
         taps, widths, unit = rest
         tokens, channels, rows = conv_blocks(T, widths, unit)
         logger.info(
@@ -291,13 +324,16 @@ def _log_pass(name, kernels, untiled, shape, rest, dtype):
                     for k in ("conv_fwd", "conv_bwd")))
     else:
         groups, = rest
-        tokens, rows = norm_blocks(T)
+        tokens, rows, lanes = _norm_blocks_of(name, T, C, groups)
+        grid = (B, T // tokens)
+        if name != "mamba_norm":
+            grid = (C // lanes, *grid)
         logger.info(
-            "%s: mamba_norm_fwd and mamba_norm_bwd, %d groups of %d, grid "
-            "(%d, %d), blocks [%d, %d], %d tokens a trip, VMEM %d and %d "
-            "bytes", said, groups, C // groups, B, T // tokens, tokens, C,
-            rows, *(pass_vmem_bytes(k, tokens, C, item)
-                    for k in ("mamba_norm_fwd", "mamba_norm_bwd")))
+            "%s: %s_fwd and %s_bwd, %d groups of %d, grid %s, blocks "
+            "[%d, %d], %d tokens a trip, VMEM %d and %d bytes", said, name,
+            name, groups, C // groups, grid, tokens, lanes, rows,
+            *(pass_vmem_bytes(k, tokens, lanes, item)
+              for k in ("norm_fwd", "norm_bwd")))
 
 
 def _widen(x_ref, before_ref, wide, first):
@@ -535,8 +571,8 @@ def gated_group_rmsnorm(y, z, weight, groups: int, eps: float = 1e-6, *,
     B, T, inner = y.shape
     kernels = resolve_impl(impl) == "pallas" or interpret
     untiled = norm_untiled(inner, groups, T)
-    _log_pass("gated_group_rmsnorm", kernels, untiled, (B, T, inner),
-              (groups,), jnp.dtype(y.dtype).name)
+    _log_pass("mamba_norm", kernels, untiled, (B, T, inner), (groups,),
+              jnp.dtype(y.dtype).name)
     if kernels and not untiled:
         return _norm(y, z, weight, groups, eps, norm_blocks(T), interpret)
     gated = (y * jax.nn.silu(z)).reshape(B, T, groups, inner // groups)
@@ -544,27 +580,38 @@ def gated_group_rmsnorm(y, z, weight, groups: int, eps: float = 1e-6, *,
                          eps=eps).reshape(B, T, inner)
 
 
-def norm_blocks(T: int) -> Tuple[int, int]:
-    """(tokens a grid step, tokens a trip) of the norm's kernels."""
-    tokens = _largest(T, _HALO, _NORM_TOKENS)
-    return tokens, _largest(tokens, _HALO, _ROWS)
+def norm_blocks(T: int, most: int = 0, trip: int = 0) -> Tuple[int, int]:
+    """(tokens a grid step, tokens a trip) of a norm's kernels: the gated
+    norm's, or under `most` and `trip` tokens."""
+    tokens = _largest(T, _HALO, most or _NORM_TOKENS)
+    return tokens, _largest(tokens, _HALO, trip or _ROWS)
 
 
-def norm_untiled(inner: int, groups: int,
-                 T: Optional[int] = None) -> Optional[str]:
-    """Why the norm's kernels cannot take `groups` groups of `inner`
-    channels (and rows of `T` tokens, where they are known), or None where
-    they can: a group whole tiles of 128 lanes, the tokens whole blocks of
-    16 rows, and a step of the backward within VMEM."""
+def _norm_blocks_of(name, T, inner, groups) -> Tuple[int, int, int]:
+    """(tokens a grid step, tokens a trip, channels a grid step) of the
+    kernels `<name>_fwd` and `<name>_bwd`: `mamba_norm_*` take whole rows."""
+    if name == "mamba_norm":
+        return (*norm_blocks(T), inner)
+    return gated_blocks(T, inner, groups)
+
+
+def norm_untiled(inner: int, groups: int, T: Optional[int] = None,
+                 name: str = "mamba_norm") -> Optional[str]:
+    """Why the norm's kernels `<name>_fwd` and `<name>_bwd` cannot take
+    `groups` groups of `inner` channels (and rows of `T` tokens, where they
+    are known), or None where they can: a group whole tiles of 128 lanes,
+    the tokens whole blocks of 16 rows, and a step of the backward within
+    VMEM."""
     if inner % groups or inner // groups % _LANES:
         return (f"{groups} groups of {inner} channels are no multiple of "
                 f"{_LANES} lanes each")
     if T is not None and T % _HALO:
         return f"{T} tokens are no multiple of {_HALO} rows"
-    tokens = _HALO if T is None else norm_blocks(T)[0]
-    need = 2 * pass_vmem_bytes("mamba_norm_bwd", tokens, inner, 4)
+    tokens, _, lanes = _norm_blocks_of(
+        name, _HALO if T is None else T, inner, groups)
+    need = 2 * pass_vmem_bytes("norm_bwd", tokens, lanes, 4)
     if need > _MAX_VMEM:
-        return (f"a step of mamba_norm_bwd needs {need} bytes of VMEM, over "
+        return (f"a step of {name}_bwd needs {need} bytes of VMEM, over "
                 f"{_MAX_VMEM}")
     return None
 
@@ -630,34 +677,55 @@ def _norm_bwd_kernel(y_ref, z_ref, w_ref, dout_ref, dy_ref, dz_ref, sums_ref,
     jax.lax.fori_loop(0, tokens // rows, trip, 0)
 
 
-def _norm_call(name, kernel, operands, outs, blocks, groups, eps, interpret):
-    """`pallas_call` of `mamba_norm_fwd` or `mamba_norm_bwd` over (batch
-    row, block of tokens): blocks `[tokens, inner]` of the `[B, T, inner]`
-    arrays, `weight` whole as a row, and `d weight`'s partial rows."""
+def _norm_call(name, kernel, operands, outs, blocks, interpret):
+    """`pallas_call` of a norm's kernel `<scope>_fwd` or `<scope>_bwd`
+    (`mamba_norm_*`, `kda_out_norm_*`) under its scope, over (batch row,
+    block of tokens): blocks `[tokens, inner]` of the `[B, T, inner]`
+    arrays, a parameter whole as a row, and the parameters' partial rows.
+    With a third of `blocks`, `lanes`, over (block of `lanes` channels,
+    batch row, block of tokens): blocks `[tokens, lanes]`, a row of `inner`
+    channels by its `lanes`, so that the partial rows of a block of
+    channels stay in VMEM while the grid walks its tokens, and a narrower
+    row whole."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, inner = operands[0].shape
-    tokens, rows = blocks
+    tokens, rows, lanes = (*blocks, 0)[:3]
+    grid = (B, T // tokens)
+    if lanes:
+        grid = (inner // lanes, *grid)
+
+    def at(step):
+        """`step(batch row, block of tokens, block of lanes)` of the grid's
+        indices."""
+        if lanes:
+            return lambda j, i, t: step(i, t, j)
+        return lambda i, t: step(i, t, 0)
 
     def spec(a):
         if a.ndim == 3:
-            return pl.BlockSpec((1, tokens, inner), lambda i, t: (i, t, 0))
-        return pl.BlockSpec(a.shape, lambda i, t: (0, 0))
+            return pl.BlockSpec((1, tokens, lanes or inner),
+                                at(lambda i, t, j: (i, t, j)))
+        if lanes and a.shape[1] == inner:
+            return pl.BlockSpec((a.shape[0], lanes),
+                                at(lambda i, t, j: (0, j)))
+        return pl.BlockSpec(a.shape, at(lambda i, t, j: (0, 0)))
 
-    backward = name == "mamba_norm_bwd"
-    with jax.named_scope("mamba_norm"):
+    backward = name.endswith("_bwd")
+    with jax.named_scope(name[:-len("_bwd")]):
         return _pallas_call(
-            functools.partial(kernel, groups=groups, eps=eps, rows=rows),
-            grid=(B, T // tokens),
+            functools.partial(kernel, rows=rows),
+            grid=grid,
             in_specs=[spec(a) for a in operands],
             out_specs=[spec(a) for a in outs],
             out_shape=outs,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary" if backward else "parallel",
-                                     ) * 2,
+                dimension_semantics=(
+                    *(("parallel",) if lanes else ()),
+                    *("arbitrary" if backward else "parallel",) * 2),
                 vmem_limit_bytes=_vmem_limit(
-                    name, tokens, inner,
+                    name, tokens, lanes or inner,
                     jnp.dtype(operands[0].dtype).itemsize)),
             interpret=interpret,
             name=name,
@@ -667,10 +735,10 @@ def _norm_call(name, kernel, operands, outs, blocks, groups, eps, interpret):
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _norm_fwd(y, z, weight, groups, eps, blocks, interpret):
     return _norm_call(
-        "mamba_norm_fwd", _norm_fwd_kernel,
+        "mamba_norm_fwd",
+        functools.partial(_norm_fwd_kernel, groups=groups, eps=eps),
         (y, z, weight.astype(_F32).reshape(1, -1)),
-        [jax.ShapeDtypeStruct(y.shape, y.dtype)], blocks, groups, eps,
-        interpret)[0]
+        [jax.ShapeDtypeStruct(y.shape, y.dtype)], blocks, interpret)[0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -686,12 +754,13 @@ def _norm_vjp_fwd(y, z, weight, groups, eps, blocks, interpret):
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _norm_bwd(y, z, weight, dout, groups, eps, blocks, interpret):
     dy, dz, sums = _norm_call(
-        "mamba_norm_bwd", _norm_bwd_kernel,
+        "mamba_norm_bwd",
+        functools.partial(_norm_bwd_kernel, groups=groups, eps=eps),
         (y, z, weight.astype(_F32).reshape(1, -1), dout),
         [jax.ShapeDtypeStruct(y.shape, y.dtype),
          jax.ShapeDtypeStruct(z.shape, z.dtype),
          jax.ShapeDtypeStruct((_TILE, y.shape[-1]), _F32)],
-        blocks, groups, eps, interpret)
+        blocks, interpret)
     return dy, dz, sums.sum(axis=0).astype(weight.dtype)
 
 
@@ -700,3 +769,148 @@ def _norm_vjp_bwd(groups, eps, blocks, interpret, res, dout):
 
 
 _norm.defvjp(_norm_vjp_fwd, _norm_vjp_bwd)
+
+
+# --------------------------------------------- KDA's output norm and gate
+
+def group_rmsnorm_gated(o, z, bias, weight, groups: int, eps: float = 1e-6, *,
+                        name: str = "kda_out_norm", impl: str = "auto",
+                        interpret: bool = False):
+    """`RMSNorm(o) * weight * sigmoid(z + bias)` of `o` and `z` [B, T, inner],
+    `bias` [inner] and `weight` [inner // groups]: the mean square over each
+    of `groups` heads of channels in float32, one learned scale a channel of
+    a head, the same for every head, the result in `o`'s dtype (the KDA
+    mixer's output norm and gate, `models/transformer.py` `_kda_mixer`: `z`
+    the gate's second product as the matmul leaves it). The kernels are
+    `<name>_fwd` and `<name>_bwd`, under the scope `name`. `impl` and
+    `interpret` as `causal_conv_silu`'s; `norm_untiled` says which shapes
+    the kernels take, and the rest take the mixer's `jax.numpy` lines."""
+    B, T, inner = o.shape
+    kernels = resolve_impl(impl) == "pallas" or interpret
+    untiled = norm_untiled(inner, groups, T, name)
+    _log_pass(name, kernels, untiled, (B, T, inner), (groups,),
+              jnp.dtype(o.dtype).name)
+    if kernels and not untiled:
+        return _gated(o, z, bias, weight, name, eps,
+                      gated_blocks(T, inner, groups), interpret)
+    normed = fused_rmsnorm(o.reshape(B, T, groups, inner // groups), weight,
+                           eps=eps).reshape(B, T, inner)
+    return normed * jax.nn.sigmoid(
+        z.astype(_F32) + bias.astype(_F32)).astype(o.dtype)
+
+
+def gated_blocks(T: int, inner: int, groups: int) -> Tuple[int, int, int]:
+    """(tokens a grid step, tokens a trip, channels a grid step) of
+    `group_rmsnorm_gated`'s kernels: a block of channels holds whole heads,
+    one at the least."""
+    head = inner // groups
+    return (*norm_blocks(T, _GATED_TOKENS, _GATED_ROWS),
+            _largest(inner, head, max(head, _GATED_LANES)))
+
+
+def _head_norm(o, z, bias, eps):
+    """(the normed head, `1 / rms`, the gate `sigmoid(z + bias)`) of a
+    trip's tokens of one head, float32."""
+    scale = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * scale, scale, jax.nn.sigmoid(z + bias)
+
+
+def _gated_fwd_kernel(o_ref, z_ref, bias_ref, w_ref, out_ref, *, eps, rows):
+    """One block of tokens of one block of heads: `w_ref` `[1, head]`, the
+    one scale of every head."""
+    from jax.experimental import pallas as pl
+
+    tokens, lanes = o_ref.shape[1:]
+    width = w_ref.shape[1]
+
+    def trip(i, _):
+        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        for head in range(lanes // width):
+            lane = pl.ds(head * width, width)
+            normed, _, gate = _head_norm(
+                o_ref[0, at, lane].astype(_F32),
+                z_ref[0, at, lane].astype(_F32), bias_ref[:, lane], eps)
+            out_ref[0, at, lane] = (normed * w_ref[...] * gate).astype(
+                out_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, tokens // rows, trip, 0)
+
+
+def _gated_bwd_kernel(o_ref, z_ref, bias_ref, w_ref, dout_ref, do_ref, dz_ref,
+                      sums_ref, *, eps, rows):
+    """`sums_ref` `[16, lanes]`: eight partial rows of `d weight` a channel
+    of each head of the block, then eight of `d bias`, in VMEM across the
+    batch rows and the blocks of tokens."""
+    from jax.experimental import pallas as pl
+
+    tokens, lanes = o_ref.shape[1:]
+    width = w_ref.shape[1]
+
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _first_of_the_heads():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    def trip(i, _):
+        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        for head in range(lanes // width):
+            lane = pl.ds(head * width, width)
+            normed, scale, gate = _head_norm(
+                o_ref[0, at, lane].astype(_F32),
+                z_ref[0, at, lane].astype(_F32), bias_ref[:, lane], eps)
+            # the cotangents of `normed * weight` and of `normed`
+            dscaled = dout_ref[0, at, lane].astype(_F32) * gate
+            dnormed = dscaled * w_ref[...]
+            along = dnormed * normed
+            dz = along * (1.0 - gate)  # `dout normed weight gate (1 - gate)`
+            sums_ref[:_TILE, lane] += _folded(dscaled * normed)
+            sums_ref[_TILE:, lane] += _folded(dz)
+            do_ref[0, at, lane] = (scale * (dnormed - normed * jnp.mean(
+                along, axis=-1, keepdims=True))).astype(do_ref.dtype)
+            dz_ref[0, at, lane] = dz.astype(dz_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, tokens // rows, trip, 0)
+
+
+def _rows_of(bias, weight):
+    return bias.astype(_F32).reshape(1, -1), weight.astype(_F32).reshape(1, -1)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _gated_fwd(o, z, bias, weight, name, eps, blocks, interpret):
+    return _norm_call(
+        name + "_fwd", functools.partial(_gated_fwd_kernel, eps=eps),
+        (o, z, *_rows_of(bias, weight)),
+        [jax.ShapeDtypeStruct(o.shape, o.dtype)], blocks, interpret)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _gated(o, z, bias, weight, name, eps, blocks, interpret):
+    return _gated_fwd(o, z, bias, weight, name, eps, blocks, interpret)
+
+
+def _gated_vjp_fwd(o, z, bias, weight, *static):
+    return _gated_fwd(o, z, bias, weight, *static), (o, z, bias, weight)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _gated_bwd(o, z, bias, weight, dout, name, eps, blocks, interpret):
+    do, dz, sums = _norm_call(
+        name + "_bwd", functools.partial(_gated_bwd_kernel, eps=eps),
+        (o, z, *_rows_of(bias, weight), dout),
+        [jax.ShapeDtypeStruct(o.shape, o.dtype),
+         jax.ShapeDtypeStruct(z.shape, z.dtype),
+         jax.ShapeDtypeStruct((2 * _TILE, o.shape[-1]), _F32)],
+        blocks, interpret)
+    dweight, dbias = sums[:_TILE], sums[_TILE:]
+    return (do, dz, dbias.sum(axis=0).astype(bias.dtype),
+            dweight.reshape(-1, weight.shape[0]).sum(axis=0).astype(
+                weight.dtype))
+
+
+def _gated_vjp_bwd(name, eps, blocks, interpret, res, dout):
+    return _gated_bwd(*res, dout, name, eps, blocks, interpret)
+
+
+_gated.defvjp(_gated_vjp_fwd, _gated_vjp_bwd)

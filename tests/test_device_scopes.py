@@ -348,19 +348,20 @@ KDA_FAMILIES = {
 
 def lowered_kda_conv_kernel_step(family):
     """A family's KDA layer at streams that tile, its short convolutions'
-    kernels in interpret mode: steered here, as the scan's above."""
-    conv = transformer_module.causal_conv_silu
-    takes = transformer_module._kda_conv_kernels
-    transformer_module.causal_conv_silu = functools.partial(
-        conv, interpret=True)
-    transformer_module._kda_conv_kernels = lambda cfg, T=None: True
-    try:
+    kernels and its output norm's (PR 69) in interpret mode: steered here,
+    as the scan's above."""
+    steered = {"causal_conv_silu": functools.partial(
+                   transformer_module.causal_conv_silu, interpret=True),
+               "group_rmsnorm_gated": functools.partial(
+                   transformer_module.group_rmsnorm_gated, interpret=True),
+               "_kda_conv_kernels": lambda cfg, T=None: True,
+               "_kda_out_norm_kernels": lambda cfg, T=None: True}
+    with pytest.MonkeyPatch.context() as patch:
+        for name, value in steered.items():
+            patch.setattr(transformer_module, name, value)
         return lowered_transformer_step(
             n_layers=2, rope=False, kda_head_dim=128, kda_gate_rank=4,
             kda_chunk=16, **KDA_FAMILIES[family])
-    finally:
-        transformer_module.causal_conv_silu = conv
-        transformer_module._kda_conv_kernels = takes
 
 
 def lowered_moe_kernels():
@@ -742,6 +743,28 @@ def test_kda_s_short_convolutions_sit_under_their_scope(family):
              if re.search(r"(^|/)kda/kda_conv/[^/]+$", s)}
     assert under and not under & {
         "logistic", "rsqrt", "dynamic_slice", "mul", "integer_pow"}, under
+
+
+@pytest.mark.parametrize("family", sorted(KDA_FAMILIES))
+def test_kda_s_output_norm_sits_under_kda_out(family):
+    """On the kernels' path (PR 69) `kda_out_norm_fwd` is under
+    `kda/kda_out` in the forward and in the forward made again,
+    `kda_out_norm_bwd` in the backward, so a split by scope reads them as
+    `kda_out` beside the `kda_o` product; neither name matches
+    `kda_kernel_time_share.tokens` or `mamba_pass_time_share.tokens`.
+    Nothing of the norm's or the gate's arithmetic is left beside them."""
+    stacks = name_stacks(lowered_kda_conv_kernel_step(family))
+    for prefix, call in (("", "_gated_fwd"), ("checkpoint/", "_gated_bwd"),
+                         ("checkpoint/rematted_computation/", "_gated_fwd")):
+        assert f"{prefix}kda/kda_out/jit({call})" in stacks, prefix
+    for kernel in ("kda_out_norm_fwd", "kda_out_norm_bwd"):
+        assert any(s.startswith(f"kda_out_norm/{kernel}/") for s in stacks)
+        assert not re.match(r"^kda_fwd|^kda_bwd|^mamba_conv_|^mamba_norm_",
+                            kernel)
+    under = {s.rsplit("/", 1)[-1] for s in stacks
+             if re.search(r"(^|/)kda/kda_out/[^/]+$", s)}
+    assert "dot_general" in under and not under & {
+        "logistic", "rsqrt", "mul", "integer_pow", "reduce_sum"}, under
 
 
 def test_eva_attention_names_its_parts():

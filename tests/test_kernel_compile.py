@@ -795,6 +795,52 @@ def test_kda_s_short_convolutions_compile_at_the_cell_s_shapes(
             ("kda_conv_bwd", [stream, f"f32[5,8,{wide}]"])] * 3
 
 
+@pytest.mark.parametrize("use", ["forward", "backward"])
+@pytest.mark.parametrize("cell,T,heads", [
+    ("kimilinear.tokens16k", 16384, 32), ("solaropen2.tokens8k", 8192, 8)])
+def test_kda_s_output_norm_compiles_at_the_cell_s_shapes(
+        v5e, cell, T, heads, use):
+    """A KDA mixer's o and its gate's pre-activation `[1, T, H dk]` (32
+    heads of 128 at 16,384 tokens, 8 at 8,192), bf16, the bias and the one
+    scale float32: one `kda_out_norm_fwd`; differentiated, one
+    `kda_out_norm_bwd` that writes `do`, `dz` and sixteen partial rows. No
+    float32 array of the mixer's width and no array by head `[.., H, dk]`
+    is in either program (PR 69)."""
+    import re
+
+    from ray_tpu.ops.mamba_passes import group_rmsnorm_gated
+
+    one = SingleDeviceSharding(v5e[0])
+    wide = heads * 128
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (sd((1, T, wide)), sd((1, T, wide)), sd((wide,), jnp.float32),
+            sd((128,), jnp.float32))
+
+    def out(*args):
+        return group_rmsnorm_gated(*args, heads, 1e-5, impl="pallas")
+
+    def grads(*args):
+        return jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(),
+                        argnums=range(4))(*args)
+
+    text = jax.jit(out if use == "forward" else grads).lower(
+        *args).compile().as_text()
+    calls = [(re.search(r"kda_out_norm_(fwd|bwd)", name).group(0),
+              re.findall(r"(?:bf16|f32)\[[\d,]+\]", made))
+             for name, made in _custom_calls(text)]
+    assert not re.search(rf"f32\[1,{T},[\d,]+\]", text)
+    assert not re.search(rf"\[(1,{T}|\d+,8),{heads},128\]", text)
+    stream = f"bf16[1,{T},{wide}]"
+    if use == "forward":
+        assert calls == [("kda_out_norm_fwd", [stream])]
+    else:
+        assert calls == [
+            ("kda_out_norm_bwd", [stream, stream, f"f32[16,{wide}]"])]
+
+
 @pytest.mark.timeout(600)  # six kernels, seconds each; room under six workers
 @pytest.mark.parametrize("use", ["forward", "backward"])
 def test_eva_attention_compiles_at_the_cell_s_shapes(v5e, use):
@@ -1068,8 +1114,9 @@ def test_kimi_step_compiles_fits_and_is_priced(token_steps):
         12 * n_params, rel=0.01)
     plan = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
-    # 13.89 GB since the short convolutions are kernels (PR 67; 14.85 before)
-    assert 13.5e9 < plan < 14.5e9
+    # 13.89 GB since the short convolutions are kernels (PR 67; 14.85
+    # before), 13.73 since the output norm and gate are (PR 69)
+    assert 13.4e9 < plan < 14.0e9
     text = step.compiled.as_text()
     assert ".remat" not in text
     cfg = spec.load_code(spec.ROOT, "loops", "kimi_linear").model_config(
@@ -1085,6 +1132,13 @@ def test_kimi_step_compiles_fits_and_is_priced(token_steps):
     assert _calls(text, "kda_conv_bwd") == 12
     assert not [line for line in text.splitlines()
                 if "/kda_conv/" in line and "= f32[1,16384,4096]" in line]
+    # the output norm and gate a layer, as the kernels (PR 69), and none of
+    # the twelve copies of o to and from the layout of `[B, T, H, dk]`
+    assert _calls(text, "kda_out_norm_fwd") == 8
+    assert _calls(text, "kda_out_norm_bwd") == 4
+    assert not re.search(r"= f32\[2048,8,32,128\]\S* copy\(", text)
+    assert not [line for line in text.splitlines() if "/kda_out/" in line
+                and re.search(r"= f32\[1,16384,(4096|32,128)\]", line)]
     assert _calls(text, "flash_fwd") == 1  # `attn_ctx` kept
     assert _calls(text, "flash_bwd_dkv_dq") == 1
     assert _calls(text, "moe_gmm") > 0 and _calls(text, "moe_tgmm") > 0

@@ -237,13 +237,22 @@ def test_beta_s_range_is_the_configuration_s(neg_eigval):
 def test_the_kernels_path_is_the_numpy_path():
     """Heads of 128 tile: KDA's kernels (interpret mode here) under the
     mixed stack give the `jax.numpy` path's loss and gradients, the
-    recurrence's and the short convolutions' (`kda_conv_fwd`,
-    `kda_conv_bwd`; PR 67) alike; each path counts its convolution calls,
-    three a layer."""
+    recurrence's, the short convolutions' (`kda_conv_fwd`, `kda_conv_bwd`;
+    PR 67) and the output norm and gate's (`kda_out_norm_fwd`,
+    `kda_out_norm_bwd`; PR 69) alike; each path counts its calls, three
+    convolutions and one output norm a layer."""
     cfg = dataclasses.replace(
         CFG, n_layers=3, layer_types=("kda", "latent_attention", "kda"),
         kda_heads=2, kda_head_dim=128, kda_chunk=64)
     params = model.transformer_init(jax.random.PRNGKey(1), cfg)
+
+    def off_its_first_value(path, leaf):  # the gate's bias 0, the scale 1
+        if path[-1].key in ("kda_g_bias", "kda_out_norm"):
+            return leaf + 0.1 * jax.random.normal(
+                jax.random.PRNGKey(len(path)), leaf.shape)
+        return leaf
+
+    params = jax.tree_util.tree_map_with_path(off_its_first_value, params)
     batch, bias = batch_of(4, T=128), bias_of(3, cfg)
 
     def ours(p):
@@ -253,11 +262,18 @@ def test_the_kernels_path_is_the_numpy_path():
         now = tracing.counters()
         return tuple(now.get(name, 0) - (before or {}).get(name, 0)
                      for name in ("train.kda_conv_calls_kernels",
-                                  "train.kda_conv_calls_numpy"))
+                                  "train.kda_conv_calls_numpy",
+                                  "train.kda_out_norm_calls_kernels",
+                                  "train.kda_out_norm_calls_numpy"))
 
     before = tracing.counters()
     numpy_path = jax.jit(jax.value_and_grad(ours))(params)
-    assert counted(before) == (0, 6)
+    assert counted(before) == (0, 6, 0, 2)
+    said = model._kda_calls_said(before)
+    assert said == (
+        "; KDA's short convolutions: 0 calls by the kernels kda_conv_fwd and "
+        "kda_conv_bwd, 6 by jax.numpy; KDA's output norms and gates: 0 calls "
+        "by the kernels kda_out_norm_fwd and kda_out_norm_bwd, 2 by jax.numpy")
     before = tracing.counters()
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(model, "kda", functools.partial(
@@ -265,13 +281,26 @@ def test_the_kernels_path_is_the_numpy_path():
         patch.setattr(model, "_kda_conv_kernels", lambda cfg, T=None: True)
         patch.setattr(model, "causal_conv_silu", functools.partial(
             mamba_passes.causal_conv_silu, interpret=True))
+        patch.setattr(model, "_kda_out_norm_kernels",
+                      lambda cfg, T=None: True)
+        patch.setattr(model, "group_rmsnorm_gated", functools.partial(
+            mamba_passes.group_rmsnorm_gated, interpret=True))
         traced = jax.make_jaxpr(jax.value_and_grad(ours))(params)
         kernels = jax.jit(jax.value_and_grad(ours))(params)
-    assert counted(before) == (12, 0)  # traced twice
-    calls = collections.Counter(re.findall(r"name=(kda_conv_\w+)", str(traced)))
-    assert set(calls) == {"kda_conv_fwd", "kda_conv_bwd"}
+    assert counted(before) == (12, 0, 4, 0)  # traced twice
+    assert not model._kda_calls_said(tracing.counters())
+    calls = collections.Counter(
+        re.findall(r"name=(kda_(?:conv|out_norm)_\w+)", str(traced)))
+    assert set(calls) == {"kda_conv_fwd", "kda_conv_bwd",
+                          "kda_out_norm_fwd", "kda_out_norm_bwd"}
     assert float(kernels[0]) == pytest.approx(float(numpy_path[0]), rel=1e-5)
     assert distance(kernels[1], numpy_path[1]) < 1e-4
+    for name in ("kda_g_bias", "kda_out_norm", "kda_g2"):
+        ours_, theirs = (
+            [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(grads)
+             if path[-1].key == name] for grads in (kernels[1], numpy_path[1]))
+        assert theirs and all(bool(leaf.any()) for leaf in theirs), name
+        assert distance(ours_, theirs) < 1e-4, name
 
 
 def test_the_path_is_the_code_s_choice_from_the_operators_and_the_shape():
@@ -290,6 +319,16 @@ def test_the_path_is_the_code_s_choice_from_the_operators_and_the_shape():
         dataclasses.replace(on_chip, kda_head_dim=8), 128)
     assert not model._kda_conv_kernels(
         dataclasses.replace(on_chip, kda_conv_taps=12), 128)
+    # the output norm and gate's kernels by the same two observables
+    assert model._kda_out_norm_kernels(on_chip)
+    assert model._kda_out_norm_kernels(on_chip, 128)
+    assert model._kda_out_norm_kernels(
+        dataclasses.replace(on_chip, kda_conv_taps=12), 128)
+    assert not model._kda_out_norm_kernels(on_chip, 40)
+    assert not model._kda_out_norm_kernels(
+        dataclasses.replace(on_chip, attention_impl="xla"), 128)
+    assert not model._kda_out_norm_kernels(
+        dataclasses.replace(on_chip, kda_head_dim=8), 128)
     record, wide = model._OPERATORS["kda"], 2 * 128
     assert record.holds(dataclasses.replace(on_chip, kda_conv_taps=12)) == (
         record.holds(on_chip) + 2 * wide)

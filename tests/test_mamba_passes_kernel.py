@@ -8,9 +8,12 @@ group's, bf16 and float32; which shapes tile, and the line that says which
 path a shape took; the dtypes the kernels read, compute and write in, from
 their own jaxprs. And the same pair under the KDA mixer's name with each
 head of the silu's result at unit length (`kda_conv_fwd`, `kda_conv_bwd`; PR
-67), against `_kda_mixer`'s `jax.numpy` lines."""
+67), against `_kda_mixer`'s `jax.numpy` lines; and the KDA mixer's output
+norm and gate (`group_rmsnorm_gated`: `kda_out_norm_fwd`,
+`kda_out_norm_bwd`; PR 69) against the mixer's lines for them."""
 
 import functools
+import hashlib
 import logging
 
 import jax
@@ -31,7 +34,10 @@ def blocks(monkeypatch):
     """Sets the most a grid step and a trip take, so that a small shape has
     several of each."""
     def set_(conv_tokens=32, conv_channels=128, norm_tokens=32, rows=16,
-             unit_rows=16):
+             unit_rows=16, gated=(32, 256, 16)):
+        for knob, value in zip(("_GATED_TOKENS", "_GATED_LANES",
+                                "_GATED_ROWS"), gated):
+            monkeypatch.setattr(passes, knob, value)
         monkeypatch.setattr(passes, "_UNIT_ROWS", unit_rows)
         monkeypatch.setattr(passes, "_CONV_TOKENS", conv_tokens)
         monkeypatch.setattr(passes, "_CONV_CHANNELS", conv_channels)
@@ -514,3 +520,228 @@ def test_the_cell_s_shapes_tile_and_fit_vmem():
     for kernel in ("mamba_norm_fwd", "mamba_norm_bwd"):
         need = passes.pass_vmem_bytes(kernel, tokens, 4096, 2)
         assert need < passes._vmem_limit(kernel, tokens, 4096, 2) <= 96 << 20
+
+
+# ----------------------------------- the KDA mixer's output norm and gate
+
+def gated_lines(o, z, bias, weight, heads):
+    """`_kda_mixer`'s `jax.numpy` lines: the gate rounded to `o`'s dtype,
+    the norm a head on `[B, T, H, dk]`, rounded, and their product."""
+    B, T, inner = o.shape
+    gate = jax.nn.sigmoid(z.astype(jnp.float32) + bias).astype(o.dtype)
+    normed = fused_rmsnorm(o.reshape(B, T, heads, inner // heads), weight,
+                           eps=EPS)
+    return normed.reshape(B, T, inner) * gate
+
+
+def gated_inputs(B, T, heads, dtype, dk=128, seed=0):
+    """((o, the gate's pre-activation, its bias, the heads' one scale), a
+    cotangent)."""
+    (o, z, weight), ct = norm_inputs(B, T, heads * dk, dtype, seed)
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(seed + 7), (heads * dk,))
+    return (o, z, bias, weight[:dk]), ct
+
+
+def gated(*args, heads, **how):
+    return passes.group_rmsnorm_gated(*args, heads, EPS, **how)
+
+
+# (B, T, heads, tokens, channels a grid step)
+GATED_SHAPES = [
+    (2, 96, 4, 32, 256),    # three blocks of tokens, two of two heads each
+    (1, 32, 32, 16, 1024),  # `kimilinear.tokens16k`'s row: four blocks of 8
+    (1, 32, 8, 32, 1024),   # `solaropen2.tokens8k`'s: one block, two trips
+    (2, 48, 3, 16, 128),    # a head a block: three partial rows of `d weight`
+]
+
+
+@DTYPES
+@pytest.mark.parametrize("B,T,heads,tokens,lanes", GATED_SHAPES)
+def test_kda_s_output_norm_kernels_are_the_mixer_s_lines(
+        B, T, heads, tokens, lanes, dtype, blocks):
+    """The result, `do`, `dz`, `d bias` and `d weight`. float32: the order
+    of the sums apart. bf16: the lines round the normed o and the gate to
+    bf16 and multiply in bf16, the kernels round their product once."""
+    blocks(gated=(tokens, lanes, 16))
+    args, ct = gated_inputs(B, T, heads, dtype)
+    assert passes.gated_blocks(T, heads * 128, heads) == (tokens, 16, lanes)
+    out, grads = out_and_grads(
+        functools.partial(gated, heads=heads, interpret=True), args, ct)
+    want_out, want = out_and_grads(
+        lambda *a: gated_lines(*a, heads), args, ct)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
+    assert out.dtype == dtype and out.shape == (B, T, heads * 128)
+    assert rel(out, want_out) < tol
+    for name, ours, theirs, arg in zip(
+            ("o", "z", "bias", "weight"), grads, want, args):
+        assert ours.dtype == arg.dtype and ours.shape == arg.shape, name
+        assert rel(ours, theirs) < tol, name
+
+
+def test_bf16_output_norm_kernels_are_nearer_float32_than_the_lines(blocks):
+    blocks()
+    args, ct = gated_inputs(2, 64, 4, jnp.bfloat16)
+    wide = tuple(a.astype(jnp.float32) for a in args)
+    true = out_and_grads(lambda *a: gated_lines(*a, 4), wide,
+                         ct.astype(jnp.float32))
+    ours = out_and_grads(
+        functools.partial(gated, heads=4, interpret=True), args, ct)
+    theirs = out_and_grads(lambda *a: gated_lines(*a, 4), args, ct)
+    for got, lines, exact in zip(*(jax.tree.leaves(t)
+                                   for t in (ours, theirs, true))):
+        assert rel(got, exact) <= rel(lines, exact) < 1e-2
+
+
+def test_a_head_s_statistics_are_its_own_under_the_one_scale(blocks):
+    """Scaling one head's o leaves its normed values as they were (but for
+    `eps`) and every other head to the bit; the scale is one `[dk]` row, the
+    same for every head, and its gradient the sum of the heads'."""
+    blocks()
+    (o, z, bias, weight), ct = gated_inputs(1, 32, 4, jnp.float32)
+    run = functools.partial(passes.group_rmsnorm_gated, groups=4, eps=1e-12,
+                            interpret=True)
+    out = run(o, z, bias, weight)
+    scaled = run(o.at[..., 128:256].multiply(64.0), z, bias, weight)
+    assert rel(scaled[..., 128:256], out[..., 128:256]) < 1e-5
+    for head in (0, 2, 3):
+        lanes = slice(128 * head, 128 * head + 128)
+        assert bool((scaled[..., lanes] == out[..., lanes]).all())
+    # the heads as four rows of a model of one head: the same values, and
+    # `d weight` the one gradient of all four
+    rows = [a.reshape(1, 32, 4, 128).transpose(2, 0, 1, 3).reshape(4, 32, 128)
+            for a in (o, z, ct)]
+    by_head, pull = jax.vjp(
+        lambda w: jnp.stack([passes.group_rmsnorm_gated(
+            rows[0][h:h + 1], rows[1][h:h + 1], bias[128 * h:128 * h + 128],
+            w, 1, 1e-12, interpret=True)[0] for h in range(4)]), weight)
+    assert rel(out.reshape(32, 4, 128).transpose(1, 0, 2), by_head) < 1e-6
+    dweight = jax.grad(lambda w: (run(o, z, bias, w) * ct).sum())(weight)
+    assert dweight.shape == (128,)
+    assert rel(dweight, pull(rows[2])[0]) < 1e-5
+
+
+@pytest.mark.parametrize("T,inner,heads,why", [
+    (16384, 4096, 32, None),                   # `kimilinear.tokens16k`'s
+    (8192, 1024, 8, None),                     # `solaropen2.tokens8k`'s
+    (64, 32, 2, "2 groups of 32 channels"),    # the tests' toy
+    (64, 512, 8, "8 groups of 512 channels"),  # heads of 64 lanes
+    (40, 512, 4, "40 tokens are no multiple of 16"),
+    (64, 1 << 16, 2, "a step of kda_out_norm_bwd needs"),
+])
+def test_which_shapes_the_output_norm_s_kernels_take(T, inner, heads, why):
+    said = passes.norm_untiled(inner, heads, T, "kda_out_norm")
+    assert (said is None) if why is None else (why in said)
+    if why is None:  # both cells' blocks, four heads a trip, fit VMEM
+        tokens, rows, lanes = passes.gated_blocks(T, inner, heads)
+        assert (tokens, rows, lanes) == (2048, 64, 512)
+        for kernel in ("kda_out_norm_fwd", "kda_out_norm_bwd"):
+            need = passes.pass_vmem_bytes(kernel, tokens, lanes, 2)
+            assert need == passes.pass_vmem_bytes(
+                kernel.replace("kda_out", "mamba"), tokens, lanes, 2)
+            assert need < passes._vmem_limit(
+                kernel, tokens, lanes, 2) <= 96 << 20
+
+
+def test_the_output_norm_says_which_path_a_shape_took(caplog, blocks):
+    blocks(gated=(32, 256, 16))
+    (o, z, bias, weight), _ = gated_inputs(2, 64, 4, jnp.bfloat16)
+    passes._log_pass.cache_clear()
+    with caplog.at_level(logging.INFO, logger="ray_tpu.ops.mamba_passes"):
+        assert "pallas_call" in str(jax.make_jaxpr(functools.partial(
+            gated, heads=4, interpret=True))(o, z, bias, weight))
+        auto = functools.partial(gated, heads=4)
+        assert "pallas_call" not in str(jax.make_jaxpr(auto)(
+            o, z, bias, weight))
+        assert bool((auto(o, z, bias, weight)
+                     == gated_lines(o, z, bias, weight, 4)).all())
+        narrow = functools.partial(gated, heads=8, impl="pallas")
+        assert "pallas_call" not in str(jax.make_jaxpr(narrow)(
+            o, z, bias, weight[:64]))
+        assert bool((narrow(o, z, bias, weight[:64])  # runs: no kernel
+                     == gated_lines(o, z, bias, weight[:64], 8)).all())
+    kernels_line, numpy_line, narrow_line = [
+        r.getMessage() for r in caplog.records]
+    fwd, bwd = (passes.pass_vmem_bytes(k, 32, 256, 2)
+                for k in ("kda_out_norm_fwd", "kda_out_norm_bwd"))
+    assert kernels_line == (
+        "kda_out_norm at B 2, T 64, C 512, bfloat16: kda_out_norm_fwd and "
+        "kda_out_norm_bwd, 4 groups of 128, grid (2, 2, 2), blocks [32, 256], "
+        f"16 tokens a trip, VMEM {fwd} and {bwd} bytes")
+    assert numpy_line == (
+        "kda_out_norm at B 2, T 64, C 512, bfloat16: jax.numpy")
+    assert narrow_line == (
+        "kda_out_norm at B 2, T 64, C 512, bfloat16: jax.numpy, because 8 "
+        "groups of 512 channels are no multiple of 128 lanes each")
+
+
+def _calls(f, *args):
+    """{a kernel's name: its `pallas_call` equation} of `f`'s trace."""
+    return {e.params["name"]: e
+            for e in _equations(jax.make_jaxpr(f)(*args).jaxpr)
+            if e.primitive.name == "pallas_call"}
+
+
+def test_the_output_norm_s_kernels_hold_float32_and_write_none_of_it(blocks):
+    """Under bf16 inputs, from the kernels' own jaxprs: what they read and
+    write at the mixer's width is bf16, the float32 operands are the bias's
+    and the scale's rows and the float32 result the sixteen partial rows of
+    `d weight` and `d bias`; inside, every logistic, rsqrt, product and sum
+    is float32; the residuals are the inputs alone."""
+    blocks()
+    args, ct = gated_inputs(2, 64, 4, jnp.bfloat16)
+
+    def both(*args):
+        out, pull = jax.vjp(
+            functools.partial(gated, heads=4, interpret=True), *args)
+        return out, pull(ct)
+
+    calls = _calls(both, *args)
+    assert set(calls) == {"kda_out_norm_fwd", "kda_out_norm_bwd"}
+    bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+    wide, row, head = (bf16, (2, 64, 512)), (f32, (1, 512)), (f32, (1, 128))
+
+    def of(variables):
+        return [(v.aval.dtype, v.aval.shape) for v in variables]
+
+    assert of(calls["kda_out_norm_fwd"].invars) == [wide, wide, row, head]
+    assert of(calls["kda_out_norm_fwd"].outvars) == [wide]
+    assert of(calls["kda_out_norm_bwd"].invars) == [
+        wide, wide, row, head, wide]
+    assert of(calls["kda_out_norm_bwd"].outvars) == [
+        wide, wide, (f32, (16, 512))]
+    made = {id(v) for v in calls["kda_out_norm_fwd"].outvars}
+    assert not any(id(v) in made for v in calls["kda_out_norm_bwd"].invars)
+    for name, call in calls.items():
+        inner = list(_equations(call.params["jaxpr"]))
+        for kind in ("logistic", "rsqrt", "mul", "add", "reduce_sum"):
+            found = [e for e in inner if e.primitive.name == kind
+                     and e.outvars[0].aval.shape]
+            assert found, (name, kind)
+            assert all(e.outvars[0].aval.dtype == f32 for e in found), (
+                name, kind)
+
+
+# `str` of the kernels' jaxprs and grid mappings at the parent of PR 69
+# (commit f570df7), sha256: equations and digest
+MAMBA_NORM_BODIES = {
+    "mamba_norm_fwd": (48, "ad398d3553a97650"),
+    "mamba_norm_bwd": (107, "3da6c4e6c4f33002"),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(MAMBA_NORM_BODIES))
+def test_the_mamba_mixer_s_norm_traces_to_the_equations_it_had(kernel):
+    """`gated_group_rmsnorm`'s kernels share `_norm_call` with KDA's and keep
+    their bodies, their grid of two axes and their blocks of whole rows."""
+    (y, z, weight), ct = norm_inputs(2, 64, 1024, jnp.bfloat16)
+
+    def both(y, z, weight, ct):
+        out, pull = jax.vjp(lambda *a: passes.gated_group_rmsnorm(
+            *a, 2, 1e-5, interpret=True), y, z, weight)
+        return out, pull(ct)
+
+    call = _calls(both, y, z, weight, ct)[kernel]
+    text = str(call.params["jaxpr"]) + str(call.params["grid_mapping"])
+    equations, digest = MAMBA_NORM_BODIES[kernel]
+    assert len(list(_equations(call.params["jaxpr"]))) == equations
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

@@ -129,14 +129,16 @@ def main() -> None:
                 shardings) else shardings["params"])
         made = described(made, shardings)
         # the step's batch at the check's length (a head of several
-        # positions has targets [rows, length, heads])
+        # positions [rows, length, heads]; a block-diffusion batch has a
+        # noise draw a token and a level a block, cut as the tokens are)
+        of_check = family.batch_shapes(int(config["check"]["rows"]))
+        length, whole = int(config["check"]["seq_len"]), batch["tokens"].shape[1]
         check = {
             name: jax.ShapeDtypeStruct(
-                (x.shape[0], int(config["check"]["seq_len"]), *x.shape[2:]),
+                (x.shape[0], x.shape[1] * length // whole, *x.shape[2:]),
                 x.dtype, sharding=x.sharding)
-            for name, x in family.batch_shapes(
-                int(config["check"]["rows"])).items()
-            if name in ("tokens", "targets")}
+            for name, x in of_check.items()
+            if name in ("tokens", "targets", "noise", "level")}
         report(cell_name, "check_value_and_grad", lambda: jax.jit(
             jax.value_and_grad(family.system_loss)).lower(made, check))
 

@@ -173,6 +173,31 @@ targets are ids `t + 1 .. t + n_pred_heads`, and the loss is the mean of
 the heads' cross-entropies (`ops/fused.py` `multi_head_cross_entropy`). Those
 are EvaByte's (the benchmark's family `evabyte`).
 
+A stack may train by block diffusion and not by next-token prediction
+(`objective="block_diffusion"`; BD3-LMs, arXiv:2503.09573, as SDAR,
+arXiv:2510.06303, adopts it). A batch is `{"tokens" [B, L], "noise" [B, L],
+"level" [B, L / diffusion_block]}`, integers: token `i` of block `b = i //
+diffusion_block` is masked where `noise_i < level_b`, at the block's noise
+level `t_b = level_b / 2^24` (`diffusion_inputs`). The stack runs on `2 L`
+rows for `L` tokens, the noisy copy (`mask_token_id` at the masked
+positions) and then the clean one, both at positions `0 .. L - 1`; every
+layer's operator is `"block_diffusion_attention"`: plain attention's leaves
+and projections under the mask of `ops/block_diffusion.py` (a row sees the
+clean rows of the blocks before its own and its own half's rows of its own
+block, both directions: the flash kernels' staircase at steps of one block
+over the clean keys, the two halves folded into the query heads; the own
+block in `jax.numpy`; the two joined by their lse). The head reads the noisy
+half alone, with no shift, and the loss is `1 / (B L) sum_i m_i / t_b(i)
+ce_i` through the weighted chunked cross-entropy (`ops/fused.py`), plus what
+the layers' readings add over all `2 L` rows; the step reads
+`diffusion_tokens`, `diffusion_masked_tokens`, `diffusion_weight_sum` and
+`diffusion_rows`. Not with another operator in the stack, `loop_steps` > 1,
+`n_pred_heads` > 1 or over a mesh of several devices yet. `embed_init_std`
+draws the embedding at a scale of its own (1 in the benchmark's cell: a
+row's own token then sets its routing, where at the default 0.02 a seeded
+stack's rows all follow their context's average to the same few experts).
+Those are SDAR-30B-A3B's (the benchmark's family `sdar`).
+
 Under a mesh with an `expert` axis (`make_mesh({"expert": n})`) a routed
 stack is expert-parallel, nothing of a layer left out: a device holds
 `n_experts / n` whole experts of every layer (the experts' leaves cut on
@@ -238,7 +263,8 @@ Capability analog of what the reference reaches only through integrations
 `lfm2moe.tokens8k`, `dsv2lite.tokens8k`, `nemotron3nano.tokens8k`,
 `lagunaxs2.tokens8k`, `keyevl2.tokens16k`, `solaropen2.tokens8k`,
 `ouro.tokens16k`, `phi4flash.tokens16k`, `evabyte.tokens8k`, and
-`mellum2.ep4` on the four chips of an `expert` axis (BENCHMARK.json).
+`sdar.tokens16k`, and `mellum2.ep4` on the four chips of an `expert` axis
+(BENCHMARK.json).
 """
 
 from __future__ import annotations
@@ -257,6 +283,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import moe
+from ray_tpu.ops.block_diffusion import (
+    block_diffusion_attention, pairs_per_token)
 from ray_tpu.ops.eva import eva_attention
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
@@ -315,8 +343,8 @@ class TransformerConfig:
     # "sliding_attention" | "sparse_attention" | "conv" |
     # "latent_attention" | "mamba2" | "kda" | "mamba1" | "mamba1_emit" |
     # "diff_attention" | "sliding_diff_attention" | "diff_attention_emit" |
-    # "cross_diff_attention" | "gmu" | "eva_attention"); () => attention
-    # everywhere
+    # "cross_diff_attention" | "gmu" | "eva_attention" |
+    # "block_diffusion_attention"); () => attention everywhere
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3  # the short convolution's reach, this token included
     n_dense_layers: int = 0  # with n_experts: leading layers with a dense FF
@@ -466,6 +494,30 @@ class TransformerConfig:
     # once, a layer's bytes for the stack's, at the price of a trace and a
     # lowering a layer (`_terms` has both moments)
     scan_layers: bool = True
+    # "next_token": position t predicts token t + 1 under a causal operator.
+    # "block_diffusion" (arXiv:2503.09573): a batch carries its noise
+    # (`diffusion_inputs`), the stack runs on a noisy and a clean copy of
+    # every sequence, 2 L rows for L tokens, under
+    # "block_diffusion_attention" in every layer, and the masked positions of
+    # the noisy copy predict their own token, weighted by 1 / t; blocks of
+    # `diffusion_block` tokens share a noise level, and `mask_token_id`
+    # stands at a masked position
+    objective: str = "next_token"
+    diffusion_block: int = 0
+    mask_token_id: Optional[int] = None
+    # the embedding is drawn normal at this standard deviation; None => 0.02.
+    # At 0.02 a seeded stack's stream is its context's average (attention's
+    # output is two orders over a token's own row), every row of a sequence
+    # routes to the same few experts, and a share's held rows swing between
+    # none and three even shares from step to step; at 1 a row's own token
+    # sets its routing, as in any trained checkpoint, and the load is even
+    embed_init_std: Optional[float] = None
+
+    @property
+    def rows_per_token(self) -> int:
+        """The rows the stack runs for a token of a sequence: the noisy and
+        the clean copy under block diffusion."""
+        return 2 if self.objective == "block_diffusion" else 1
 
     @property
     def kv_heads(self) -> int:
@@ -593,6 +645,17 @@ class TransformerConfig:
                             f"{type(sub).__name__[1:]} sublayer: the scale "
                             "1 + g is EVA attention's and the dense "
                             "feed-forward's RMSNorm's")
+        if self.objective not in ("next_token", "block_diffusion"):
+            raise ValueError(f"objective {self.objective!r}")
+        if self.objective == "block_diffusion":
+            others = sorted({kind.op for kind in kinds if kind.op not in (
+                None, "block_diffusion_attention")})
+            if others:
+                raise ValueError(
+                    f"objective 'block_diffusion' over the operators "
+                    f"{others}: a causal operator (or a recurrence) would "
+                    "let a noisy row see what the mask hides; every layer's "
+                    "operator is 'block_diffusion_attention'")
         for sub in {sub for kind in kinds for sub in _sublayers(kind)}:
             sub.check(self)
         _check_carried(kinds)
@@ -1453,6 +1516,33 @@ def eva_keys_per_query(seq_len: int, window: int, chunk: int) -> float:
     if seq_len <= window:
         return (seq_len + 1) / 2
     return (window + 1) / 2 + window // chunk * (seq_len // window - 1) / 2
+
+
+def _block_diffusion_attention_layer(x, blk, cfg: TransformerConfig,
+                                     site: "_Site", *,
+                                     op: "_BlockDiffusionAttention"):
+    """x + block-diffusion attention(norm(x)) on the doubled stream `x`
+    [B, 2 L, d], the noisy half first: plain attention's projections,
+    QK-norm and RoPE (`_qkv`; `site.positions` holds `0 .. L - 1` twice), and
+    `ops/block_diffusion.py`'s attention, which names its own operations
+    `bd_stair`, `bd_own_block` and `bd_join`, backward too; `W_o`."""
+    B, R, d = x.shape
+    h, dh = op.heads(cfg), cfg.head_dim
+    dt = cfg.dtype
+    impl = _kernel_impl(cfg)
+    if site.mesh is not None and site.mesh.size > 1:
+        raise NotImplementedError(
+            "block-diffusion attention is not mapped over a mesh of "
+            f"{site.mesh.size} devices yet: the two halves of a sequence "
+            "and its clean keys lie on one device")
+    _, q, k, v = _qkv(x, blk, site.positions, cfg, op)
+    with jax.named_scope("bd_attention"):
+        o = block_diffusion_attention(
+            q, k, v, block=cfg.diffusion_block, impl=impl,
+            keep_ctx=site.keep_ctx)
+    with jax.named_scope("attn_out"):
+        out = o.reshape(B, R, h * dh) @ blk["wo"].astype(dt)
+        return checkpoint_name(x + out, "attn_res")
 
 
 def _unit_length(x, eps: float = 1e-6):
@@ -2808,6 +2898,91 @@ class _EvaAttention(Sublayer):
             * eva_keys_per_query(seq_len, cfg.eva_window, cfg.eva_chunk))
 
 
+class _BlockDiffusionAttention(_PlainAttention):
+    """Block-diffusion attention (`_block_diffusion_attention_layer`): a
+    fourth record over `_PlainAttention`'s leaves, at full attention's heads
+    and rotary recipe, on a stream of two rows a token under the mask of
+    `ops/block_diffusion.py`. Widths are a row's, as every record's are a
+    row of the stream's: the rule counts the stream's rows
+    (`TransformerConfig.rows_per_token`)."""
+
+    matmuls = ("wq", "wk", "wv", "wo")  # no gate on its context
+    takes_heads_held = False  # the halves are folded over all the heads
+    takes_post_norm = False  # `_block_diffusion_attention_layer` has none
+    no_sequence_axis = (
+        "block-diffusion attention is not mapped over a sequence axis: a "
+        "row reads the clean rows of every earlier block and its own "
+        "block's, which other devices hold")
+
+    def __init__(self):
+        super().__init__(sliding=False)
+
+    def check(self, cfg):
+        if cfg.objective != "block_diffusion":
+            raise ValueError(
+                "block_diffusion_attention under the objective "
+                f"{cfg.objective!r}: its rows are a noisy and a clean copy "
+                "of a sequence, which the objective 'block_diffusion' makes")
+        block, mask_id = cfg.diffusion_block, cfg.mask_token_id
+        if block < 1 or cfg.max_seq_len % block:
+            raise ValueError(
+                f"diffusion_block {block} under sequences of "
+                f"{cfg.max_seq_len}: a sequence is whole blocks")
+        if mask_id is None or not 0 <= mask_id < cfg.vocab_size:
+            raise ValueError(
+                f"mask_token_id {mask_id} is no id of a vocabulary of "
+                f"{cfg.vocab_size}")
+        if cfg.loop_steps > 1 or cfg.n_pred_heads > 1 or cfg.attn_gate:
+            raise ValueError(
+                "block_diffusion_attention with loop_steps > 1, "
+                "n_pred_heads > 1 or attn_gate: none is made for two rows "
+                "a token yet")
+
+    def forward(self, x, blk, cfg, site):
+        with jax.named_scope("block_diffusion_attention"):
+            return _block_diffusion_attention_layer(
+                x, blk, cfg, site, op=self), None
+
+    def holds(self, cfg):
+        """Plain attention's (q, k and v as the kernels take them, q with
+        its halves folded into the heads; lse and delta at a tile's 128
+        lanes); the own block's scores and weights, `diffusion_block` float32
+        values a head each; the two parts' o with their cotangents beside
+        the joined one's, the own block's in float32; and, over a share of
+        the experts, the held rows' buffers of a sequence's `held_chunk`
+        rows (two of the stream's width and the feed-forward's products),
+        spread over the sequence's rows: no record prices them
+        (`_RoutedFF.holds` of a share is 0, and `_SparseAttention.holds`
+        stands a byte a key for them), and two rows a token double them,
+        1.36 GB at 32,768 rows over 16 of 128 experts. Without them the rule
+        kept `attn_qkv` too and the compiler's plan for a described v5e
+        stood at 15.99 GB of the 16.91 it offers a program, fitted by
+        fusions it made again (PERF.md section 6, PR 70)."""
+        h, dh, item = self.heads(cfg), cfg.head_dim, _item(cfg)
+        buffers = 0
+        if cfg.n_experts and cfg.held[1] < cfg.n_experts:
+            rows = cfg.rows_per_token * cfg.max_seq_len
+            chunk = moe.held_chunk(
+                rows * cfg.experts_per_token, cfg.held[1], cfg.n_experts,
+                load_held_even=cfg.expert_bias, sequences=1)
+            buffers = chunk * (
+                2 * cfg.d_model + cfg.ff_matrices * cfg.ff_dim) // rows
+        return (super().holds(cfg)
+                + 2 * cfg.diffusion_block * h * 4 // item
+                + h * dh * (3 + 2 * 4 // item) + buffers)
+
+    def flops(self, cfg, seq_len):
+        # a token's operations: both of its rows through the projections,
+        # and q k^T and p v over the pairs the mask leaves, `seq_len +
+        # diffusion_block` a head for the two rows (`pairs_per_token`): the
+        # staircase skips the tiles above its diagonal as the causal walk
+        # does. The stack's two rows a token: `_fwd_flops_per_token`
+        return 2 * self.params(cfg), (
+            2 * 2 * self.heads(cfg) * cfg.head_dim
+            * pairs_per_token(seq_len, cfg.diffusion_block)
+            / cfg.rows_per_token)
+
+
 def _ff_gates(cfg) -> Tuple[str, ...]:
     """The products of a feed-forward that have names, but for `up`."""
     return ("gate",) if cfg.gated else ()
@@ -2999,6 +3174,7 @@ _OPERATORS: Dict[str, Sublayer] = {
     "cross_diff_attention": _DiffAttention(cross=True),
     "gmu": _GMU(),
     "eva_attention": _EvaAttention(),
+    "block_diffusion_attention": _BlockDiffusionAttention(),
 }
 _FEED_FORWARDS: Dict[str, Sublayer] = {
     "dense_ff": _DenseFF(),
@@ -3048,7 +3224,7 @@ def transformer_init(rng, cfg: TransformerConfig) -> Dict[str, Any]:
     params = {
         "embed": jax.random.normal(
             k_emb, (cfg.vocab_size, d), jnp.float32
-        ) * 0.02,
+        ) * (0.02 if cfg.embed_init_std is None else cfg.embed_init_std),
         "blocks": blocks,
         "final_norm": jnp.ones((d,), jnp.float32),
     }
@@ -3075,6 +3251,10 @@ def _at_init_std(params, cfg: TransformerConfig):
     embedding's, from 0.02. None: as they are."""
     if cfg.init_std is None:
         return params
+    if cfg.embed_init_std is not None:
+        raise ValueError(
+            f"init_std {cfg.init_std} and embed_init_std "
+            f"{cfg.embed_init_std}: init_std draws the embedding too")
     std = cfg.init_std
     kinds = [kind for seg in segments(cfg) for kind in seg.layout]
     trees = [blk for blks in _segment_trees(params["blocks"]) for blk in blks]
@@ -3842,6 +4022,8 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
     run `loop_steps` times under an `exit_gate` has `_exit_loss`'s loss and
     readings (`_EXIT_READINGS`); without the gate the last pass's
     cross-entropy."""
+    if cfg.objective == "block_diffusion":
+        return _block_diffusion_loss(params, batch, cfg, **kw)
     if "targets" in batch:
         tokens, targets = batch["tokens"], batch["targets"]
     elif cfg.n_pred_heads > 1:
@@ -3861,6 +4043,82 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
             loss = _head_loss(hidden, _unembed(params, cfg), targets,
                               kw.get("mesh"))
     return _settled(loss, readings or {}, cfg)
+
+
+NOISE_LEVELS = 1 << 24  # a block's level is a whole number of these parts
+
+
+def diffusion_inputs(batch, cfg: TransformerConfig):
+    """(rows [B, 2 L] ids, positions [B, 2 L], weights [B, L] float32,
+    masked [B, L] bool) of a block-diffusion batch `{"tokens" [B, L],
+    "noise" [B, L] in [0, 2^24), "level" [B, L / diffusion_block] in
+    [1, 2^24]}`, integers. Token `i` of block `b = i // diffusion_block` is
+    masked where `noise_i < level_b`: with probability `t_b = level_b /
+    2^24`, the block's noise level, when `noise` is uniform. `rows` is the
+    noisy copy (`mask_token_id` where masked) and then the clean one, both
+    at positions `0 .. L - 1`; `weights_i = m_i / t_b(i) / (B L)`, BD3-LMs'
+    bound under a linear schedule (arXiv:2503.09573), so that the loss is
+    their sum with the masked positions' cross-entropies. Integers in, so
+    that another implementation reads the same mask to the bit: 2^24 and
+    every level are exact in float32."""
+    tokens, noise, level = batch["tokens"], batch["noise"], batch["level"]
+    B, L = tokens.shape
+    block = cfg.diffusion_block
+    if noise.shape != (B, L) or level.shape != (B, L // block) or L % block:
+        raise ValueError(
+            f"a block-diffusion batch of tokens {tokens.shape} in blocks of "
+            f"{block} takes noise of that shape and a level a block, not "
+            f"{noise.shape} and {level.shape}")
+    with jax.named_scope("bd_noise"):
+        level = jnp.repeat(level, block, axis=1)
+        masked = noise < level
+        noisy = jnp.where(masked, jnp.asarray(cfg.mask_token_id, tokens.dtype),
+                          tokens)
+        weights = jnp.where(
+            masked, NOISE_LEVELS / level.astype(jnp.float32), 0.0) / (B * L)
+        positions = jnp.broadcast_to(
+            jnp.tile(jnp.arange(L, dtype=jnp.int32), 2), (B, 2 * L))
+        return (jnp.concatenate([noisy, tokens], axis=1), positions, weights,
+                masked)
+
+
+_DIFFUSION_READINGS = ("diffusion_tokens", "diffusion_masked_tokens",
+                       "diffusion_weight_sum", "diffusion_rows")
+
+
+def _block_diffusion_loss(params, batch, cfg: TransformerConfig, **kw):
+    """(`1 / (B L) sum_i m_i / t_b(i) ce_i` plus what the layers' readings
+    add, the readings) of a block-diffusion batch (`diffusion_inputs`): the
+    stack on the doubled stream, the head over the noisy half's L rows
+    alone, position `i` predicting token `i` (no shift), each masked
+    position weighted by its block's `1 / t`. The clean half's last hidden
+    rows reach no head: they are computed, their keys and values are what
+    the noisy rows read. The layers' readings (the routers' balance loss) are
+    over all 2 L rows. The readings gain `_DIFFUSION_READINGS`: the step's
+    tokens, how many of them were masked, the sum of `m_i / t` (its mean a
+    token is 1 in expectation) and the rows the stack ran."""
+    mesh = kw.get("mesh")
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "the objective 'block_diffusion' is not mapped over a mesh of "
+            f"{mesh.size} devices yet: the head reads one half of every "
+            "sequence's rows")
+    rows, positions, weights, masked = diffusion_inputs(batch, cfg)
+    tokens = batch["tokens"]
+    L = tokens.shape[1]
+    hidden, readings = _hidden_and_readings(
+        params, rows, cfg, positions=positions, **kw)
+    with jax.named_scope("bd_loss"), jax.named_scope("lm_head_ce"):
+        loss, _ = weighted_lm_head_cross_entropy(
+            hidden[:, :L], _unembed(params, cfg), tokens, weights)
+    loss, readings = _settled(loss, readings or {}, cfg)
+    with jax.named_scope("bd_noise"):
+        readings.update(zip(_DIFFUSION_READINGS, (
+            jnp.asarray(tokens.size, jnp.int32),
+            masked.sum(dtype=jnp.int32),
+            weights.sum() * tokens.size,
+            jnp.asarray(rows.size, jnp.int32))))
+    return loss, readings
 
 
 def next_ids(rows, heads: int):
@@ -3928,7 +4186,8 @@ def transformer_loss(params, batch, cfg: TransformerConfig, **kw):
 _SAVE_ORDER = (
     "attn_ctx",   # the kernel's o [B H, T, dv] and lse as one f32 column
                   # (sparse attention: and the indexer's three gradients and
-                  # the selection's mask as bits; EVA: of both its parts)
+                  # the selection's mask as bits; EVA: of both its parts;
+                  # block diffusion: of the staircase's part, a row each)
     "eva_summaries",  # EVA's chunk keys and values: a chunk-th of k and v
     "moe_slots",  # the sorted slots: no second sort (integers, small)
     "attn_res",   # the stream after attention: no second `wo` product
@@ -4000,7 +4259,8 @@ def _exchange_bytes(cfg: TransformerConfig, tokens: int, ways: int) -> int:
     chunk = moe.held_chunk(
         rows * cfg.experts_per_token, cfg.n_experts // ways, cfg.n_experts,
         load_held_even=cfg.expert_bias,
-        sequences=ways * max(1, tokens // cfg.max_seq_len))
+        sequences=ways * max(
+            1, tokens // (cfg.rows_per_token * cfg.max_seq_len)))
     return (rows * d * (item + 2 * 4)
             + chunk * (2 * d + cfg.ff_matrices * cfg.ff_dim) * item)
 
@@ -4295,7 +4555,7 @@ def _memory_limit(mesh) -> Optional[int]:
 _STEP_READINGS = (
     *dict.fromkeys(
         r.name for sub in _RECORDS for r in sub.readings if r.step),
-    *_EXIT_READINGS)
+    *_EXIT_READINGS, *_DIFFUSION_READINGS)
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
@@ -4391,7 +4651,9 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         traced, from what it is traced with: the state's and the batch's
         shapes as the mesh lays them out, and the device's memory limit."""
         limit = _memory_limit(mesh)
-        tokens = math.prod(tok_sharding.shard_shape(batch["tokens"].shape))
+        # the stream's rows: two a token under block diffusion
+        tokens = cfg.rows_per_token * math.prod(
+            tok_sharding.shard_shape(batch["tokens"].shape))
         resident = on_a_device(state, state_shard)
         params = on_a_device(state["params"], p_shard)
         ways = mesh.shape.get("expert", 1)
@@ -4474,13 +4736,16 @@ def _fwd_flops_per_token(cfg: TransformerConfig, seq_len: int):
     """(matmul fwd flops/token over the layers, causal attn fwd flops/token
     over the layers, lm-head fwd flops/token): every layer once a pass of
     `loop_steps`; under an `exit_gate` the head and the gate's `d_model`
-    multiply-adds once a pass too."""
+    multiply-adds once a pass too. A record's operations are a row's: under
+    block diffusion a token is two rows through every layer and one through
+    the head."""
     matmul = attn = 0.0
+    passes = cfg.loop_steps * cfg.rows_per_token
     for kind in cfg.layers:
         for sub in _sublayers(kind):
             of_matmuls, of_attention = sub.flops(cfg, seq_len)
-            matmul += cfg.loop_steps * of_matmuls
-            attn += cfg.loop_steps * of_attention
+            matmul += passes * of_matmuls
+            attn += passes * of_attention
     head = 2 * cfg.d_model * cfg.head_width
     if cfg.exit_gate:
         head = cfg.loop_steps * (head + 2 * cfg.d_model)

@@ -116,7 +116,12 @@ key and a mask where its first query does not see its last; `flash_tiles`
 weighs the tiles that divide a span of queries and a span's keys, which are
 whole or empty; the index maps clamp a step with no body to the row's
 (column's) nearest tile that has one. A row that sees no key leaves o 0 and
-lse -inf. The calls are named `flash_fwd_stair`, `flash_bwd_dkv_dq_stair`,
+lse -inf. A span finer than any tile (`ops/block_diffusion.py`: steps of a
+block of 4 queries and 4 keys, `stair=(4, 4)`, at a group of 16 query heads
+a key-value head) divides none: every tile the stairs cross, the diagonal's,
+takes the in-tile mask (`k_pos < per * (q_pos // span)`), the tiles under it
+the bare body and the ones above it none, the walk, the tile and the VMEM
+the causal kernels' at that shape. The calls are named `flash_fwd_stair`, `flash_bwd_dkv_dq_stair`,
 `flash_bwd_dq_stair` and `flash_bwd_dkv_stair`. `flash_attention`, the entry
 that returns o alone, is the program it was.
 
@@ -460,7 +465,12 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     `i` sees the first `per * (i // span)` keys) the tiles with a body are
     the staircase's, and of the tiles those are weighed that divide a span
     of queries and a span's keys, where some do: such a tile is whole or
-    empty, and no body masks a pair."""
+    empty, and no body masks a pair. Where none does (a span finer than
+    128: block diffusion's steps of 4) every candidate is weighed, the
+    diagonal's tiles are masked ones, and the choice comes out the causal
+    walk's: at T = S = 16,384, D 128 and a group of 16, 1024 x 1024 forward
+    and 1024 x 768 for the one backward kernel with the sums alone held
+    (46.0 MB of VMEM, `keyevl2.tokens16k`'s at a group of 8)."""
     itemsize = jnp.dtype(dtype).itemsize
     Dv = D if v_dim is None else v_dim
     step_us, rows_us, pairs_us = _COST_US[kernel]

@@ -59,6 +59,9 @@ GC_MIN_S = 1e-3  # a collection shorter than this is no span
 # the readings that `_fold_steps` sums over layer-steps
 _SUMMED = ("expert_load", "held_slots", "dropped_slots",
            "chip_load_max_over_mean")
+# a block-diffusion step's readings, each summed over steps into the counter
+# `diffusion.<what>` (`models/transformer.py` `_DIFFUSION_READINGS`)
+_DIFFUSION = ("tokens", "masked_tokens", "weight_sum", "rows")
 
 _RUSAGE = ("ru_nivcsw", "ru_nvcsw", "ru_majflt", "ru_utime", "ru_stime")
 _PRESSURE = ("cpu", "memory", "io")
@@ -169,7 +172,10 @@ def _fold_steps(steps: List[tuple]) -> tuple:
     axis a device each, and the layer-step's chunks are its fullest
     device's); over such an axis `moe.chip_load_max_over_mean_sum` (the mean
     over a step's layers of each one's fullest chip over the mean, summed
-    over steps); `train.steps_read`. Returns (the last step's readings that
+    over steps); of a block-diffusion step `diffusion.tokens`,
+    `diffusion.masked_tokens`, `diffusion.weight_sum` (the sum of `m / t`
+    over the step's tokens: its mean a token is 1 in expectation) and
+    `diffusion.rows` (the rows the stack ran); `train.steps_read`. Returns (the last step's readings that
     are no sum, as numbers and lists; a row `[step, chunks a layer, held
     rows a layer]` a step of a share). The report waits on the loop's
     thread with the device idle, so a callable's steps are folded together:
@@ -187,6 +193,10 @@ def _fold_steps(steps: List[tuple]) -> tuple:
 
     for _, same in itertools.groupby(steps, key=lambda step: id(step[1])):
         numbers, (static, *_), readings = zip(*same)  # one callable's
+        if "diffusion_tokens" in readings[0]:
+            for what in _DIFFUSION:
+                add("diffusion." + what,
+                    stacked(readings, "diffusion_" + what, -1).sum())
         if "expert_load" not in readings[0]:
             continue
         load = stacked(readings, "expert_load")  # [S, layers, E]

@@ -71,6 +71,12 @@ PHI4FLASH_SCOPES = {"mamba1", "mamba1_in", "mamba1_conv", "selective_scan",
 EVABYTE_SCOPES = {"eva", "eva_summaries", "eva_window", "eva_stair",
                   "eva_join"}
 EVA_KERNELS = {"eva_summaries_fwd", "eva_summaries_bwd"}
+# block diffusion's (PR 70): the noise and the loss round the stack, the
+# layer's scope (round `attn_qkv` and `attn_out`) with `bd_attention` and its
+# three parts, `ops/block_diffusion.py`'s own; the staircase's kernels carry
+# the flash kernels' names with `_stair`, as EVA's do
+SDAR_SCOPES = {"block_diffusion_attention", "bd_noise", "bd_attention",
+               "bd_stair", "bd_own_block", "bd_join", "bd_loss"}
 # `ops/kda.py`'s two kernels: on the CPU the recurrence is the `jax.numpy`
 # form's, so no step lowered here names them (`tests/test_kernel_compile.py`
 # compiles them, and finds them by these names)
@@ -318,6 +324,36 @@ def lowered_evabyte_step():
         transformer_module.eva_attention = real
 
 
+def lowered_sdar_step():
+    """Two block-diffusion layers over a share of the experts on sequences
+    of 32 in blocks of 4, the staircase's kernels in interpret mode: steered
+    here, since "pallas" does not lower for a CPU."""
+    from ray_tpu.ops import block_diffusion
+
+    real = transformer_module.block_diffusion_attention
+    transformer_module.block_diffusion_attention = (
+        lambda *a, **kw: block_diffusion.block_diffusion_attention(
+            *a, **{**kw, "impl": "xla", "interpret": True}))
+    try:
+        cfg = TransformerConfig(
+            vocab_size=VOCAB, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+            d_ff=16, max_seq_len=32, remat=True, attention_impl="xla",
+            tied_embeddings=False, qk_norm="head", n_experts=8,
+            experts_per_token=2, experts_held=(0, 4),
+            layer_types=("block_diffusion_attention",) * 2,
+            objective="block_diffusion", diffusion_block=4,
+            mask_token_id=VOCAB - 1)
+        mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+        init_state, step, _ = make_train_step(cfg, mesh)
+        state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+        ids = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+        return step.lower(state, {
+            "tokens": ids, "noise": ids,
+            "level": jax.ShapeDtypeStruct((2, 8), jnp.int32)})
+    finally:
+        transformer_module.block_diffusion_attention = real
+
+
 def lowered_nemotron_kernel_step():
     """A mixer alone at sizes that tile (a chunk and a state of 128, a group
     of two heads of 64), the scan's kernels in interpret mode: steered
@@ -454,6 +490,8 @@ OWN_SCOPES = {"lfm2_moe": LFM2_SCOPES, "deepseek_v2": DSV2_SCOPES,
               "phi4flash": PHI4FLASH_SCOPES,
               # no family above: `test_eva_attention_names_its_parts` lowers it
               "evabyte": EVABYTE_SCOPES | EVA_KERNELS,
+              # nor here: `test_block_diffusion_names_its_parts` lowers it
+              "sdar": SDAR_SCOPES,
               "kda's kernels": KDA_KERNELS | KDA_CONV_KERNELS}
 ALSO_HAS = {"nemotron_h": {"moe_shared", "expert_bias"},
             "solar_open2": {"moe_shared"},
@@ -795,6 +833,42 @@ def test_eva_attention_names_its_parts():
     assert {"flash_fwd_stair", "flash_bwd_dkv_dq_stair"} <= found
     assert "attention" not in found  # plain attention's scope is not EVA's
     assert any("lm_head_ce" in s and "dot_general" in s for s in stacks)
+
+
+def test_block_diffusion_names_its_parts():
+    """`bd_noise` before the stack and `bd_loss` round the head; under
+    `block_diffusion_attention` the projections, `bd_attention` with the
+    staircase's flash kernel under `bd_stair`, the own block and the join
+    (inside the loop over chunks of rows), and the output projection, in
+    the forward, in the forward made again and in the backward."""
+    stacks = name_stacks(lowered_sdar_step())
+    layer = "block_diffusion_attention/"
+    forward = (layer + "attn_qkv/dot_general",
+               layer + "bd_attention/bd_stair/flash_fwd_stair/pallas_call",
+               layer + "attn_out/dot_general")
+    for want in forward:
+        for phase in ("", "checkpoint/rematted_computation/"):
+            assert any(s.endswith("/" + phase + want) or s == phase + want
+                       for s in stacks), phase + want
+    want = layer + "bd_attention/bd_stair/flash_bwd_dkv_dq_stair/pallas_call"
+    assert any(s.endswith("checkpoint/" + want) for s in stacks)
+    assert not [s for s in stacks if want in s and "rematted" in s]
+    # the own block and the join run inside the loop over chunks of rows,
+    # whose body is lowered as a function of its own: its stacks start at
+    # the scope, forward, made again and backward
+    for part, op in (("bd_own_block", "reduce_sum"), ("bd_join", "exp")):
+        for phase in ("", "checkpoint/rematted_computation/", "checkpoint/"):
+            assert phase + part + "/" + op in stacks, phase + part
+    found = components(stacks)
+    assert SDAR_SCOPES <= found
+    assert {"flash_fwd_stair", "flash_bwd_dkv_dq_stair"} <= found
+    assert "attention" not in found and "eva_join" not in found
+    # the chunked head's loop under both scopes (a chunk's matmuls are a
+    # function of their own, whose stacks start bare)
+    assert any(re.search(r"bd_loss\)?/lm_head_ce/while/body/", s)
+               for s in stacks)
+    # the routed feed-forward of a share runs on the stream's 2 L rows
+    assert any("mlp/moe_router/" in s for s in stacks)
 
 
 def test_sparse_attention_s_kernels_sit_under_its_scopes():

@@ -901,6 +901,61 @@ def test_eva_attention_compiles_at_the_cell_s_shapes(v5e, use):
                                    "bf16[32,8192,128]"]}
 
 
+@pytest.mark.timeout(600)  # two kernels at 64 x 16,384; room under six workers
+@pytest.mark.parametrize("use", ["forward", "backward"])
+def test_block_diffusion_attention_compiles_at_the_cell_s_shapes(v5e, use):
+    """`sdar.tokens16k`: one sequence of 16,384 as 32,768 rows at 32 query
+    heads over 4 key-value heads of 128, blocks of 4, bf16. The forward is
+    one `flash_fwd_stair` on both halves' 64 query heads against the clean
+    half's keys and values (a group of 16 a key-value head, no copy of k or
+    v); differentiated, one `flash_bwd_dkv_dq_stair` whose dk and dv leave at
+    the 4 key-value heads, summed over the group in the kernel. The own
+    block and the join are fusions over a chunk of 2,048 rows: no score
+    tensor of the stream against itself, of a half against the clean half,
+    or of a head's whole stream in float32 is in either program."""
+    import re
+
+    from ray_tpu.ops import block_diffusion
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    R, H, Hk, D = 32768, 32, 4, 128
+    args = (sd((1, R, H, D)), sd((1, R, Hk, D)), sd((1, R, Hk, D)))
+
+    def out(q, k, v):
+        return block_diffusion.block_diffusion_attention(
+            q, k, v, block=4, impl="pallas")
+
+    def grads(*args):
+        return jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(),
+                        argnums=range(3))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(out if use == "forward" else grads).lower(
+            *args).compile().as_text()
+    calls = {}
+    for name, made in _custom_calls(text):
+        kernel = re.match(r"[a-z_]+?(?=\.\d+$|$)", name).group(0)
+        calls[kernel] = re.findall(r"(?:bf16|f32)\[[\d,]+\]", made)
+    for pairs in ("32768,32768", "16384,16384", "32768,16384"):
+        assert not re.search(r"\[(\d+,)*%s\]" % pairs, text), pairs
+    forward = {"flash_fwd_stair": ["bf16[64,16384,128]", "f32[64,16384,8]"]}
+    if use == "forward":
+        assert not re.search(r"f32\[(1,)?32768,32,128\]", text)
+        assert calls == forward
+        return
+    assert calls == {
+        # dk and dv at the key-value heads, in whole key tiles of 768
+        # (22 x 768 = 16,896 rows, cut to 16,384 outside), dq at the query
+        # heads
+        **forward,
+        "flash_bwd_dkv_dq_stair": ["bf16[4,16896,128]", "bf16[4,16896,128]",
+                                   "bf16[64,16384,128]"]}
+
+
 def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
     """Arithmetic alone, `nemotron3nano.tokens8k` at 2 x 8192 tokens and a
     limit of 15.75 GiB. On the kernels' path the scan's part of a block's
@@ -1146,6 +1201,46 @@ def test_kimi_step_compiles_fits_and_is_priced(token_steps):
     assert not re.search(r"(f32|bf16)\[(\d+,)*16384,16384\]", text)
     # the chunks' entering states of a layer: 32 heads x 256 chunks, float32
     assert "f32[1,256,32,128,128]" in text
+
+
+def test_sdar_step_compiles_fits_and_is_priced(token_steps):
+    """`sdar.tokens16k` at its real shapes, 32,768 rows a step, with the
+    chip's limit handed to the keep rule: `attn_ctx` and `attn_res` are
+    kept (with `attn_qkv` too the plan stood at 15.99 GB, fitted by fusions
+    the compiler made again: PR 70), the compiler's plan fits what a v5e
+    offers a program with no `.remat` fusion made to fit, the rule's sum
+    stands at or over the plan and under the chip, and the step runs the
+    staircase's forward once a layer (`attn_ctx` kept), its whole backward
+    as one kernel, and the grouped-matmul kernels on the stream's rows."""
+    import re
+
+    from chipbench import sdar_flops, spec
+    from ray_tpu.models import transformer as tr
+
+    step = token_steps("sdar.tokens16k", limited=True)
+    assert tuple(step.chosen) == ("attn_ctx", "attn_res")
+    memory = step.compiled.memory_analysis()
+    config = spec.load_cell(spec.ROOT, "sdar.tokens16k")["config"]
+    n_params = sdar_flops.state_params(config)
+    assert memory.argument_size_in_bytes == pytest.approx(
+        12 * n_params, rel=0.01)
+    text = step.compiled.as_text()
+    assert ".remat" not in text
+    cfg = spec.load_code(spec.ROOT, "loops", "sdar").model_config(
+        {**config, "attention_impl": "pallas"})
+    terms = tr._terms(cfg, 2 * 16384, 4 * n_params)
+    predicted = 12 * n_params + terms.fullest(step.chosen).bytes
+    # the heap the compiler packs is 14.58 GB (`lowering_seconds.py --plan`:
+    # `memory_analysis()` sums this program's temporaries unpacked, 13.5 GB
+    # of them) and the chip holds 14.54 GB in the window (PR 70): the rule's
+    # sum stands over both and under what it may ask for
+    assert 14.6e9 <= predicted <= HBM_LIMIT - tr._SAVE_RESERVE
+    assert _calls(text, "flash_fwd_stair") == 1
+    assert _calls(text, "flash_bwd_dkv_dq_stair") == 1
+    assert _calls(text, "flash_fwd") == _calls(text, "flash_bwd_dkv_dq") == 0
+    assert _calls(text, "moe_gmm") > 0 and _calls(text, "moe_tgmm") > 0
+    assert not re.search(r"(f32|bf16|pred)\[(\d+,)*32768,32768\]", text)
+    assert not re.search(r"(f32|bf16|pred)\[(\d+,)*16384,16384\]", text)
 
 
 # ------------------- a block's weight matmuls from and to buffers of their own

@@ -59,6 +59,10 @@ CASES = {
     "cross_diff_attention": ("op", "cross_diff_attention", DIFF, None),
     "gmu": ("op", "gmu", MAMBA1, None),
     "eva_attention": ("op", "eva_attention", EVA, None),
+    # the 16 rows a case is traced with are two halves of two blocks of 4
+    "block_diffusion_attention": ("op", "block_diffusion_attention", dict(
+        qk_norm="head", objective="block_diffusion", diffusion_block=4,
+        mask_token_id=63), None),
     "dense_ff": ("ff", "dense_ff", {}, None),
     "dense_ff_unit_offset": ("ff", "dense_ff", dict(norm_unit_offset=True),
                              None),
@@ -127,7 +131,7 @@ def test_the_tables_are_what_the_configuration_may_name():
         "latent_attention", "conv", "mamba2", "kda", "mamba1",
         "mamba1_emit", "diff_attention", "sliding_diff_attention",
         "diff_attention_emit", "cross_diff_attention", "gmu",
-        "eva_attention"}
+        "eva_attention", "block_diffusion_attention"}
     assert set(model._FEED_FORWARDS) == {"dense_ff", "routed_ff"}
     assert {record for _, record, _, _ in CASES.values()} == (
         set(model._OPERATORS) | set(model._FEED_FORWARDS))
@@ -264,7 +268,9 @@ def test_the_loss_has_what_the_records_say_their_readings_add():
         "chip_load", "chip_load_max_over_mean", "index_loss",
         "index_keys_min_gap", "index_keys_max_gap", "kda_log_decay_min",
         "kda_beta_mean", "diff_lambda", "eva_remote_mass",
-        "eva_chunk_entropy", "ut_pass_loss", "exit_p_mean", "exit_entropy"}
+        "eva_chunk_entropy", "ut_pass_loss", "exit_p_mean", "exit_entropy",
+        "diffusion_tokens", "diffusion_masked_tokens", "diffusion_weight_sum",
+        "diffusion_rows"}
     assert len(set(model._STEP_READINGS)) == len(model._STEP_READINGS)
 
 

@@ -284,7 +284,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import moe
 from ray_tpu.ops.block_diffusion import (
-    block_diffusion_attention, pairs_per_token)
+    block_diffusion_attention, own_join_untiled, pairs_per_token)
 from ray_tpu.ops.eva import eva_attention
 from ray_tpu.ops.flash_attention import mha, resolve_impl
 from ray_tpu.ops.kda import SUB as _KDA_SUB, kda, kda_untiled
@@ -1540,9 +1540,23 @@ def _block_diffusion_attention_layer(x, blk, cfg: TransformerConfig,
         o = block_diffusion_attention(
             q, k, v, block=cfg.diffusion_block, impl=impl,
             keep_ctx=site.keep_ctx)
+        tracing.count("train.bd_own_join_calls_kernels"
+                      if _bd_own_join_kernels(cfg, R // 2)
+                      else "train.bd_own_join_calls_numpy")
     with jax.named_scope("attn_out"):
         out = o.reshape(B, R, h * dh) @ blk["wo"].astype(dt)
         return checkpoint_name(x + out, "attn_res")
+
+
+def _bd_own_join_kernels(cfg: TransformerConfig, L: int) -> bool:
+    """Whether a block-diffusion layer's own block and join run as
+    `ops/block_diffusion.py`'s kernels (`bd_own_join_fwd`,
+    `bd_own_join_bwd`) on halves of `L` rows: the operators resolve to
+    Pallas and the shape tiles."""
+    op = _OPERATORS["block_diffusion_attention"]
+    return _kernel_impl(cfg) == "pallas" and not own_join_untiled(
+        L, op.heads(cfg), op.kv_heads(cfg), cfg.head_dim,
+        cfg.diffusion_block, _item(cfg))
 
 
 def _unit_length(x, eps: float = 1e-6):
@@ -1581,21 +1595,24 @@ def _kda_out_norm_untiled(cfg: TransformerConfig, T: Optional[int] = None):
     return norm_untiled(H * cfg.kda_head_dim, H, T, "kda_out_norm")
 
 
-def _kda_calls_said(before) -> str:
+def _calls_said(before) -> str:
     """What a trace added to the counts of the KDA mixers' short
-    convolutions (three calls a mixer) and of their output norms and gates
-    (one) since the counters read `before`, by the path each call took, for
-    the step's log line; nothing where no such layer was traced."""
+    convolutions (three calls a mixer), of their output norms and gates
+    (one) and of the block-diffusion layers' own blocks and joins (one a
+    layer) since the counters read `before`, by the path each call took,
+    for the step's log line; nothing where no such layer was traced."""
     now = tracing.counters()
     said = ""
-    for what, name in (("short convolutions", "kda_conv"),
-                       ("output norms and gates", "kda_out_norm")):
+    for what, name in (("KDA's short convolutions", "kda_conv"),
+                       ("KDA's output norms and gates", "kda_out_norm"),
+                       ("block diffusion's own blocks and joins",
+                        "bd_own_join")):
         kernels, numpy = (
             now.get(counter, 0) - before.get(counter, 0)
             for counter in (f"train.{name}_calls_kernels",
                             f"train.{name}_calls_numpy"))
         if kernels + numpy:
-            said += ("; KDA's %s: %d calls by the kernels %s_fwd and "
+            said += ("; %s: %d calls by the kernels %s_fwd and "
                      "%s_bwd, %d by jax.numpy" % (
                          what, kernels, name, name, numpy))
     return said
@@ -2946,9 +2963,17 @@ class _BlockDiffusionAttention(_PlainAttention):
     def holds(self, cfg):
         """Plain attention's (q, k and v as the kernels take them, q with
         its halves folded into the heads; lse and delta at a tile's 128
-        lanes); the own block's scores and weights, `diffusion_block` float32
-        values a head each; the two parts' o with their cotangents beside
-        the joined one's, the own block's in float32; and, over a share of
+        lanes); round the own block and the join, by the path they take
+        (`_bd_own_join_kernels`): by the kernels `bd_own_join_fwd` and
+        `bd_own_join_bwd` the staircase's o's cotangent, the joined o and
+        its, and the pair's dq beside the staircase's, four of q's width in
+        its dtype, every float32 value a head and row VMEM's (the compiler's
+        plan for a described v5e, `benchmarks/lowering_seconds.py --plan`:
+        13.67 GB where the lines' step planned 14.58, PERF.md section 6, PR
+        71); by the `jax.numpy` lines the own block's scores and weights,
+        `diffusion_block` float32 values a head each, and the two parts' o
+        with their cotangents beside the joined one's, the own block's in
+        float32; and, over a share of
         the experts, the held rows' buffers of a sequence's `held_chunk`
         rows (two of the stream's width and the feed-forward's products),
         spread over the sequence's rows: no record prices them
@@ -2967,9 +2992,12 @@ class _BlockDiffusionAttention(_PlainAttention):
                 load_held_even=cfg.expert_bias, sequences=1)
             buffers = chunk * (
                 2 * cfg.d_model + cfg.ff_matrices * cfg.ff_dim) // rows
-        return (super().holds(cfg)
-                + 2 * cfg.diffusion_block * h * 4 // item
-                + h * dh * (3 + 2 * 4 // item) + buffers)
+        if _bd_own_join_kernels(cfg, cfg.max_seq_len):
+            round_the_join = h * dh * 4
+        else:
+            round_the_join = (2 * cfg.diffusion_block * h * 4 // item
+                              + h * dh * (3 + 2 * 4 // item))
+        return super().holds(cfg) + round_the_join + buffers
 
     def flops(self, cfg, seq_len):
         # a token's operations: both of its rows through the projections,
@@ -4710,7 +4738,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
         calls = tracing.counters()
         (loss, readings), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state["params"], batch, saved_names=kept, **bias)
-        logger.info(said + _kda_calls_said(calls))
+        logger.info(said + _calls_said(calls))
         with jax.named_scope("optimizer"):
             updates, opt = optimizer.update(
                 grads, state["opt"], state["params"]

@@ -910,9 +910,12 @@ def test_block_diffusion_attention_compiles_at_the_cell_s_shapes(v5e, use):
     half's keys and values (a group of 16 a key-value head, no copy of k or
     v); differentiated, one `flash_bwd_dkv_dq_stair` whose dk and dv leave at
     the 4 key-value heads, summed over the group in the kernel. The own
-    block and the join are fusions over a chunk of 2,048 rows: no score
+    block and the join are the pair `bd_own_join_fwd` and `bd_own_join_bwd`
+    (PR 71), which read q, oS and lseS as the staircase's kernels take and
+    leave them and write o as `wo` reads it, `[1, 32768, 4096]`: no score
     tensor of the stream against itself, of a half against the clean half,
-    or of a head's whole stream in float32 is in either program."""
+    or of a head's whole stream in float32 is in either program, and no
+    loop over rows."""
     import re
 
     from ray_tpu.ops import block_diffusion
@@ -942,18 +945,27 @@ def test_block_diffusion_attention_compiles_at_the_cell_s_shapes(v5e, use):
         calls[kernel] = re.findall(r"(?:bf16|f32)\[[\d,]+\]", made)
     for pairs in ("32768,32768", "16384,16384", "32768,16384"):
         assert not re.search(r"\[(\d+,)*%s\]" % pairs, text), pairs
-    forward = {"flash_fwd_stair": ["bf16[64,16384,128]", "f32[64,16384,8]"]}
+    assert not re.search(r"f32\[(1,)?32768,32,128\]", text)
+    # no loop over chunks of rows; the backward's one `while` is the single
+    # trip round `bd_own_join_bwd` (`_own_join_vjp_bwd` says why)
+    assert len(re.findall(r"\bwhile\(", text)) == (use == "backward")
+    forward = {"flash_fwd_stair": ["bf16[64,16384,128]", "f32[64,16384,8]"],
+               "bd_own_join_fwd": ["bf16[1,32768,4096]"]}
     if use == "forward":
-        assert not re.search(r"f32\[(1,)?32768,32,128\]", text)
         assert calls == forward
         return
     assert calls == {
         # dk and dv at the key-value heads, in whole key tiles of 768
         # (22 x 768 = 16,896 rows, cut to 16,384 outside), dq at the query
         # heads
-        **forward,
+        "flash_fwd_stair": forward["flash_fwd_stair"],
         "flash_bwd_dkv_dq_stair": ["bf16[4,16896,128]", "bf16[4,16896,128]",
-                                   "bf16[64,16384,128]"]}
+                                   "bf16[64,16384,128]"],
+        # dq, dk, dv, doS and dlseS, each as its operand lies (the forward
+        # is not made again: the residuals are the operands)
+        "bd_own_join_bwd": ["bf16[64,16384,128]", "bf16[4,32768,128]",
+                            "bf16[4,32768,128]", "bf16[64,16384,128]",
+                            "f32[8,8,16384]"]}
 
 
 def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
@@ -1230,17 +1242,66 @@ def test_sdar_step_compiles_fits_and_is_priced(token_steps):
         {**config, "attention_impl": "pallas"})
     terms = tr._terms(cfg, 2 * 16384, 4 * n_params)
     predicted = 12 * n_params + terms.fullest(step.chosen).bytes
-    # the heap the compiler packs is 14.58 GB (`lowering_seconds.py --plan`:
-    # `memory_analysis()` sums this program's temporaries unpacked, 13.5 GB
-    # of them) and the chip holds 14.54 GB in the window (PR 70): the rule's
-    # sum stands over both and under what it may ask for
+    # the heap the compiler packs is 13.67 GB since the own block and the
+    # join are kernels (`lowering_seconds.py --plan`, PR 71; 14.58 GB with
+    # the `jax.numpy` lines, where the chip held 14.54 GB in the window, PR
+    # 70): the rule's sum (14.78 GB) stands over it and under what it may
+    # ask for
     assert 14.6e9 <= predicted <= HBM_LIMIT - tr._SAVE_RESERVE
     assert _calls(text, "flash_fwd_stair") == 1
     assert _calls(text, "flash_bwd_dkv_dq_stair") == 1
+    # the own block and the join: forward and made again (o is not kept),
+    # and the backward
+    assert _calls(text, "bd_own_join_fwd") == 2
+    assert _calls(text, "bd_own_join_bwd") == 1
     assert _calls(text, "flash_fwd") == _calls(text, "flash_bwd_dkv_dq") == 0
     assert _calls(text, "moe_gmm") > 0 and _calls(text, "moe_tgmm") > 0
     assert not re.search(r"(f32|bf16|pred)\[(\d+,)*32768,32768\]", text)
     assert not re.search(r"(f32|bf16|pred)\[(\d+,)*16384,16384\]", text)
+
+
+@pytest.mark.timeout(600)
+def test_sdar_s_comparison_compiles_for_v5e(v5e):
+    """The comparison's system side of `sdar.tokens16k` as
+    `chipbench/loops/sdar.py` `errors_of` jits it (loss, readings and
+    gradients of one sequence of 4,096 tokens, the layers scanned and
+    rematerialised): with `bd_own_join_bwd` called bare in the layers'
+    backward the TPU compiler's memory-space assignment dies here (SIGSEGV in
+    `BestFitRepacker::Finish`: no exception, the process), which the step at
+    16,384 tokens never showed; `ops/block_diffusion.py` `_own_join_vjp_bwd`
+    calls it inside a `while` of one trip (PR 71)."""
+    from chipbench import loop, spec
+    from ray_tpu.models import transformer as tr
+
+    cell = spec.load_cell(spec.ROOT, "sdar.tokens16k")
+    config, traffic = cell["config"], cell["traffic"]
+    config["attention_impl"] = "pallas"  # "auto" asks the CPU here
+    family = spec.load_code(spec.ROOT, "loops", config["family"]).build(
+        config, traffic, list(v5e[:1]))
+    made = jax.eval_shape(
+        family.init_params, jax.eval_shape(lambda: loop.seed_key(0)))
+    made = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        made, family.state_shardings["params"])
+    rows, length = (int(config["check"][k]) for k in ("rows", "seq_len"))
+    whole = family.batch_shapes(rows)
+    batch = {
+        name: jax.ShapeDtypeStruct(
+            (x.shape[0], x.shape[1] * length // whole["tokens"].shape[1]),
+            x.dtype, sharding=x.sharding)
+        for name, x in whole.items()}
+    cfg = family.model_config
+
+    @jax.jit
+    def system_side(params, batch):
+        (loss, readings), grads = jax.value_and_grad(
+            family.system_loss_and_readings, has_aux=True)(params, batch)
+        return loss, dict(
+            readings, masked=tr.diffusion_inputs(batch, cfg)[3]), grads
+
+    text = system_side.lower(made, batch).compile().as_text()
+    assert _calls(text, "bd_own_join_fwd") == 2
+    assert _calls(text, "bd_own_join_bwd") == 1
 
 
 # ------------------- a block's weight matmuls from and to buffers of their own

@@ -269,7 +269,7 @@ def test_the_kernels_path_is_the_numpy_path():
     before = tracing.counters()
     numpy_path = jax.jit(jax.value_and_grad(ours))(params)
     assert counted(before) == (0, 6, 0, 2)
-    said = model._kda_calls_said(before)
+    said = model._calls_said(before)
     assert said == (
         "; KDA's short convolutions: 0 calls by the kernels kda_conv_fwd and "
         "kda_conv_bwd, 6 by jax.numpy; KDA's output norms and gates: 0 calls "
@@ -288,7 +288,7 @@ def test_the_kernels_path_is_the_numpy_path():
         traced = jax.make_jaxpr(jax.value_and_grad(ours))(params)
         kernels = jax.jit(jax.value_and_grad(ours))(params)
     assert counted(before) == (12, 0, 4, 0)  # traced twice
-    assert not model._kda_calls_said(tracing.counters())
+    assert not model._calls_said(tracing.counters())
     calls = collections.Counter(
         re.findall(r"name=(kda_(?:conv|out_norm)_\w+)", str(traced)))
     assert set(calls) == {"kda_conv_fwd", "kda_conv_bwd",

@@ -85,9 +85,12 @@ def test_folding_the_halves_into_the_heads_and_back():
     assert bool(jnp.all(bd.unfold_halves(folded[..., 0], 2) == x[..., 0]))
 
 
-# (L, heads, key-value heads, width): a group of 16 (2 x 8 over 1), and a
-# ragged end (200 rows under tiles of 128)
-SHAPES = {"group16": (256, 8, 1, 32), "ragged": (200, 4, 2, 32)}
+# (L, heads, key-value heads, width): a group of 16 (2 x 8 over 1), a
+# ragged end (200 rows under tiles of 128), and a shape whose own block and
+# join tile (`own_join_untiled`: the path "kernels" runs `bd_own_join_fwd`
+# and `bd_own_join_bwd` there, and the `jax.numpy` lines at the other two)
+SHAPES = {"group16": (256, 8, 1, 32), "ragged": (200, 4, 2, 32),
+          "tiled": (256, 4, 2, 128)}
 _made = {}
 
 
@@ -124,6 +127,18 @@ def test_the_parts_add_up_to_the_dense_mask(shape, path, what):
     theirs = attention_and_gradients(shape, "dense")[what]
     assert float(jnp.abs(ours - theirs).max()) < 2e-5 * float(
         jnp.abs(theirs).max())
+
+
+def test_the_own_block_and_join_run_as_kernels_where_they_tile():
+    """Which of `SHAPES` took which path above, from the traces."""
+    for shape, (L, H, Hk, D) in SHAPES.items():
+        args = (jnp.zeros((1, 2 * L, H, D)), *[jnp.zeros((1, 2 * L, Hk, D))] * 2)
+        text = str(jax.make_jaxpr(lambda *a: bd.block_diffusion_attention(
+            *a, block=BLOCK, interpret=True))(*args))
+        assert ("bd_own_join_fwd" in text) == (shape == "tiled")
+        assert "flash_fwd_stair" in text
+        assert bd.own_join_untiled(L, H, Hk, D, BLOCK, 4) is None or (
+            shape != "tiled")
 
 
 def test_the_staircase_at_a_span_under_a_tile():
@@ -215,24 +230,37 @@ def test_the_loss_is_the_masked_positions_weighted():
     assert readings["expert_index"].shape == (2, 128, 2)  # both halves routed
 
 
-def test_a_noisy_row_is_blind_to_what_the_mask_hides():
+@pytest.mark.parametrize("path", ["xla", "kernels"])
+def test_a_noisy_row_is_blind_to_what_the_mask_hides(path, monkeypatch):
     """The noisy half's hidden rows of block b do not move when a clean
     token of block b or later changes, and the clean half's do not move
-    when the noise does."""
+    when the noise does. By the XLA path at the tiny widths, and by the
+    kernels in interpret mode (the staircase's and the own block and
+    join's) at halves of 128 rows and heads of 128, which they tile."""
+    L = 32
     cfg = tiny(n_experts=0, experts_held=None)
+    if path == "kernels":
+        L = 128
+        cfg = tiny(n_experts=0, experts_held=None, max_seq_len=L, d_head=128,
+                   n_layers=1, layer_types=("block_diffusion_attention",))
+        assert bd.own_join_untiled(L, 4, 2, 128, BLOCK, 4) is None
+        monkeypatch.setattr(
+            model, "block_diffusion_attention",
+            lambda *a, **kw: bd.block_diffusion_attention(
+                *a, **{**kw, "interpret": True}))
     params = model.transformer_init(jax.random.PRNGKey(0), cfg)
-    batch = batch_of(rows=1)
+    batch = batch_of(rows=1, length=L)
     rows, positions, _, _ = model.diffusion_inputs(batch, cfg)
     hidden = model.transformer_hidden(params, rows, cfg, positions=positions)
-    changed = rows.at[0, 32 + 9].set((rows[0, 32 + 9] + 1) % 127)  # clean, block 2
+    changed = rows.at[0, L + 9].set((rows[0, L + 9] + 1) % 127)  # clean, block 2
     moved = model.transformer_hidden(params, changed, cfg, positions=positions)
     same = jnp.abs(hidden - moved).max(-1)[0] == 0
-    assert bool(same[:12].all()) and bool(same[32:40].all())  # blocks 0 to 2
+    assert bool(same[:12].all()) and bool(same[L:L + 8].all())  # blocks 0 to 2
     assert not bool(same[12:16].any())  # the noisy block 3 reads clean block 2
     noisier = rows.at[0, 5].set(127)  # a noisy row of block 1
     moved = model.transformer_hidden(params, noisier, cfg, positions=positions)
     same = jnp.abs(hidden - moved).max(-1)[0] == 0
-    assert bool(same[32:].all()) and bool(same[:4].all()) and bool(same[8:32].all())
+    assert bool(same[L:].all()) and bool(same[:4].all()) and bool(same[8:L].all())
     assert not bool(same[4:8].any())
 
 

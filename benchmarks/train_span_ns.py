@@ -13,9 +13,10 @@ collection's two callbacks, and what the worker's `jax.monitoring`
 listeners add to one event of JAX's (a trace, a lowering or a compilation:
 a scalar at its start, a time span at its end, as JAX records them), alone
 and inside an open one. Last, what the step's own account costs
-(`tracing.Step`, `_runtime._fold_steps`): a jitted call bare and under
-`Step`, and a report that folds a chunk of five steps' readings of a share's
-shape (26 routed layers of 64 experts) against one that folds none. Prints
+(`tracing.Step` with the model's `tracing.Account`, which
+`_runtime._fold_steps` folds by): a jitted call bare and under `Step`, and
+a report that folds a chunk of five steps' readings of a share's shape (26
+routed layers of 64 experts) against one that folds none. Prints
 one JSON object. The numbers are this host's: `PERF.md` has the chip
 host's.
 """
@@ -94,7 +95,10 @@ def step_account(jax):
             "dropped_slots": jnp.zeros_like(held)}
 
     jitted = jax.jit(fn)
-    step = tracing.Step(jitted, {"held_chunk": 11264})
+    from ray_tpu.models import transformer
+
+    step = tracing.Step(
+        jitted, {"held_chunk": 11264}, transformer._STEP_ACCOUNT)
     state, batch = jnp.zeros(()), jnp.ones(8)
     jax.block_until_ready(step(state, batch))
     out = {"step_call_ns.bare_jit": per_call(

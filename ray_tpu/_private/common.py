@@ -208,7 +208,7 @@ _CONFIG_DEFAULTS: Dict[str, Any] = {
     # dedicated TPU hosts for cold-start-sensitive pipelines.
     "prefault_object_store": False,
     # GCS fault tolerance: persist control-plane state to a session-scoped
-    # sqlite file so a restarted GCS resumes with its actor/PG/KV/job tables
+    # log file so a restarted GCS resumes with its actor/PG/KV/job tables
     # intact (reference: RedisStoreClient, redis_store_client.h:33). Cheap
     # (WAL write-through of few-hundred-byte records); disable for pure
     # in-memory control planes.
@@ -216,15 +216,14 @@ _CONFIG_DEFAULTS: Dict[str, Any] = {
     # Which durable store backs the GCS when persistence is on
     # (gcs_store.py): "wal" — append-only CRC-framed log with group commit
     # (one fsync per loop tick of mutations) and snapshot compaction;
-    # "sqlite" — write-through WAL-mode sqlite rows; "memory" — no
-    # durability even with a persist path (testing).
+    # "replicated" — that log mirrored to the HA standby (below);
+    # "memory" — no durability even with a persist path (testing).
     "gcs_persist_backend": "wal",
     # Durability/sync policy for the durable backends
     # (docs/fault_tolerance.md): "batch" — group-commit fsync per loop tick
-    # (wal) / sqlite synchronous=NORMAL (an OS crash can lose the last
-    # tick / the commits since the last WAL checkpoint; a process crash
-    # loses nothing); "always" — fsync per record (wal) / synchronous=FULL;
-    # "off" — never fsync (page cache only).
+    # (an OS crash can lose the last tick; a process crash loses
+    # nothing); "always" — fsync per record; "off" — never fsync (page
+    # cache only).
     "gcs_store_sync": "batch",
     # WAL log-size threshold that triggers snapshot compaction (the full
     # table state is rewritten as one frame and the log truncated).

@@ -4,7 +4,7 @@ TPU-native analog of the reference's gcs_server (src/ray/gcs/gcs_server/gcs_serv
 one asyncio process holding cluster state — node membership, actor FSM with
 restarts, placement-group 2PC, internal KV (which doubles as the function
 table), pubsub, and the job table. Persistence is pluggable (reference:
-store_client.h:33): in-memory by default, sqlite write-through for GCS fault
+store_client.h:33): in-memory by default, a group-commit log for GCS fault
 tolerance — kill and restart the GCS and raylets/workers reconnect,
 re-register, and detached actors survive (analog of the Redis-backed FT mode
 + NotifyGCSRestart reconnect protocol, node_manager.proto:373).
@@ -593,8 +593,8 @@ class GcsServer:
         for t in self._bg_tasks:
             t.cancel()
         await self.server.stop()
-        # Graceful shutdown owns the store handle: close() checkpoints the
-        # sqlite WAL / flushes+fsyncs the group-commit tail.
+        # Graceful shutdown owns the store handle: close() flushes+fsyncs
+        # the group-commit tail.
         self.store.close()
 
     async def crash(self) -> None:

@@ -1,13 +1,12 @@
 """GCS persistence: pluggable store clients.
 
 TPU-native analog of the reference's StoreClient abstraction
-(src/ray/gcs/store_client/store_client.h:33). Three backends, selected by
-the ``gcs_persist_backend`` knob when a persist path is configured:
+(src/ray/gcs/store_client/store_client.h:33). The backends, selected by
+the ``gcs_persist_backend`` knob when a persist path is configured
+(``replicated``, the HA control plane's, is `ReplicatedStoreClient` below):
 
 - ``memory`` (in_memory_store_client.h:31): no durability, state dies with
   the GCS process. Also the backend when no persist path is given.
-- ``sqlite``: write-through rows in a WAL-mode sqlite file. Simple and
-  battle-tested, but pays a full journal commit per record.
 - ``wal`` (default): an append-only CRC-framed log with *group commit* —
   mutations from one event-loop tick coalesce into a single OS write (and,
   per the ``gcs_store_sync`` policy, a single fsync), so hot-path
@@ -22,9 +21,8 @@ loses nothing that ``put`` returned for — buffered records are flushed to
 the OS before the process dies, and page-cache writes survive process
 death. An *OS/power* crash can lose the records since the last fsync:
 under the default ``gcs_store_sync="batch"`` that is at most one loop tick
-of mutations for the wal backend, and for sqlite (``synchronous=NORMAL``
-under WAL) the commits since the last WAL checkpoint. ``"always"`` closes
-that window at per-commit fsync cost; ``"off"`` never fsyncs.
+of mutations. ``"always"`` closes that window at per-commit fsync cost;
+``"off"`` never fsyncs.
 
 All values are opaque bytes (the GCS msgpacks its own records). Table
 layout follows the reference's gcs_table_storage.cc (one logical table per
@@ -36,7 +34,6 @@ from __future__ import annotations
 import asyncio
 import concurrent.futures
 import os
-import sqlite3
 import struct
 import threading
 import time
@@ -51,7 +48,7 @@ from ray_tpu._private.common import config
 _TEL_WRITE_S = telemetry.histogram(
     "gcs",
     "store_write_s",
-    "store commit latency (one group-commit flush or sqlite commit)",
+    "store commit latency (one group-commit flush)",
     buckets=telemetry.LATENCY_BUCKETS_S,
 )
 _TEL_WAL_BYTES = telemetry.counter(
@@ -104,100 +101,6 @@ class InMemoryStoreClient(StoreClient):
 
     def get_all(self, table: str) -> Dict[str, bytes]:
         return dict(self._tables.get(table, {}))
-
-
-class SqliteStoreClient(StoreClient):
-    """Durable file-backed store for GCS fault tolerance.
-
-    WAL mode + one flat table; writes are a few hundred bytes each and run
-    inline on the GCS loop (sub-ms on local disk, same order as the
-    reference's Redis round trip from the GCS process).
-
-    Sync policy (``gcs_store_sync``): "always" -> synchronous=FULL (fsync
-    per commit), "batch" -> NORMAL (WAL writes fsynced at checkpoint; an
-    OS crash can lose the last commits), "off" -> OFF. close() checkpoints
-    the WAL (wal_checkpoint TRUNCATE) so a graceful shutdown leaves the
-    main db file complete and the -wal file empty.
-    """
-
-    _SYNC_PRAGMA = {"always": "FULL", "batch": "NORMAL", "off": "OFF"}
-
-    def __init__(self, path: str, sync: Optional[str] = None):
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        self._path = path
-        self._lock = threading.Lock()
-        self._closed = False
-        self._db = sqlite3.connect(path, check_same_thread=False)
-        self._db.execute("PRAGMA journal_mode=WAL")
-        level = self._SYNC_PRAGMA.get(sync or config.gcs_store_sync, "NORMAL")
-        self._db.execute(f"PRAGMA synchronous={level}")
-        self._db.execute(
-            "CREATE TABLE IF NOT EXISTS gcs (tbl TEXT, key TEXT, value BLOB,"
-            " PRIMARY KEY (tbl, key))"
-        )
-        self._db.commit()
-
-    def put(self, table: str, key: str, value: bytes) -> None:
-        with self._lock:
-            if self._closed:
-                return  # shutdown race: a trailing handler after stop()
-            t0 = time.perf_counter()
-            self._db.execute(
-                "INSERT OR REPLACE INTO gcs (tbl, key, value) VALUES (?, ?, ?)",
-                (table, key, value),
-            )
-            self._db.commit()
-            _TEL_WRITE_S.default.observe(time.perf_counter() - t0)
-
-    def get(self, table: str, key: str) -> Optional[bytes]:
-        with self._lock:
-            if self._closed:
-                return None
-            row = self._db.execute(
-                "SELECT value FROM gcs WHERE tbl = ? AND key = ?", (table, key)
-            ).fetchone()
-        return None if row is None else bytes(row[0])
-
-    def delete(self, table: str, key: str) -> None:
-        with self._lock:
-            if self._closed:
-                return
-            self._db.execute(
-                "DELETE FROM gcs WHERE tbl = ? AND key = ?", (table, key)
-            )
-            self._db.commit()
-
-    def get_all(self, table: str) -> Dict[str, bytes]:
-        with self._lock:
-            if self._closed:
-                return {}
-            rows = self._db.execute(
-                "SELECT key, value FROM gcs WHERE tbl = ?", (table,)
-            ).fetchall()
-        return {k: bytes(v) for k, v in rows}
-
-    def close(self) -> None:
-        with self._lock:
-            if self._closed:
-                return
-            self._closed = True
-            try:
-                # Fold the -wal file back into the main db so a graceful
-                # shutdown leaves one complete file (and no stale -wal to
-                # replay — or to lose — on the next open).
-                self._db.execute("PRAGMA wal_checkpoint(TRUNCATE)")
-            except sqlite3.Error:
-                pass
-            self._db.close()
-
-    def crash(self) -> None:
-        with self._lock:
-            if self._closed:
-                return
-            self._closed = True
-            # No checkpoint: the -wal file stays behind exactly as a killed
-            # process would leave it; sqlite replays it on the next open.
-            self._db.close()
 
 
 # -- WAL backend -------------------------------------------------------------
@@ -262,12 +165,12 @@ class WalStoreClient(StoreClient):
         with open(self._path, "rb") as f:
             data = f.read()
         if data.startswith(b"SQLite format 3"):
-            # Backend switched under an existing file: refuse rather than
-            # "recover" a sqlite db into an empty log (torn-tail truncation
-            # at offset 0 would destroy it).
+            # Another program's file under the persist path: refuse rather
+            # than "recover" a sqlite db into an empty log (torn-tail
+            # truncation at offset 0 would destroy it).
             raise ValueError(
-                f"{self._path} is a sqlite store; set gcs_persist_backend="
-                "sqlite or remove the file"
+                f"{self._path} is a sqlite database, not a GCS log; move "
+                "or remove the file"
             )
         off = 0
         good = 0
@@ -1334,14 +1237,12 @@ def make_store(
 ) -> StoreClient:
     """Build the configured store. No path -> in-memory regardless of
     backend; with a path, ``backend`` (default: the ``gcs_persist_backend``
-    knob) picks wal / sqlite / memory / replicated. ``term``/``on_fenced``
+    knob) picks wal / memory / replicated. ``term``/``on_fenced``
     apply to the replicated backend only (leadership stamp + fencing
     notification for the HA control plane)."""
     if not persist_path:
         return InMemoryStoreClient()
     backend = backend or config.gcs_persist_backend
-    if backend == "sqlite":
-        return SqliteStoreClient(persist_path)
     if backend == "memory":
         return InMemoryStoreClient()
     if backend == "replicated":

@@ -61,8 +61,8 @@ class Node:
         self.gcs_standby = None  # GcsStandby when HA mode is on (head only)
 
     def gcs_persist_path(self) -> str:
-        """Session-scoped store file backing GCS fault tolerance (WAL or
-        sqlite per the ``gcs_persist_backend`` knob; gcs_store.make_store)."""
+        """Session-scoped store file backing GCS fault tolerance (the
+        ``gcs_persist_backend`` knob's; gcs_store.make_store)."""
         import tempfile
 
         return os.path.join(

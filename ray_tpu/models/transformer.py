@@ -1157,6 +1157,15 @@ def _router_logits(tokens, router):
     )
 
 
+def _buffer_rows(cfg: TransformerConfig, rows: int, sequences: int,
+                 ways: int = 1) -> int:
+    """`ops/moe.py` `buffer_rows` of `cfg`'s routed layers."""
+    return moe.buffer_rows(
+        rows, sequences, ways, experts_per_token=cfg.experts_per_token,
+        held=cfg.held[1], n_experts=cfg.n_experts,
+        load_held_even=cfg.expert_bias)
+
+
 def _routed_rows(y, blk, cfg: TransformerConfig, impl: str, bias=None,
                  axis: Optional[str] = None):
     """`_routed_ffn` on one device. With `axis` the device is one of an
@@ -1230,13 +1239,11 @@ def _routed_rows(y, blk, cfg: TransformerConfig, impl: str, bias=None,
                 readings["dropped_slots"] = readings["dropped_slots"][None]
 
     if share:  # names its own operations as below, a chunk of rows at a time
-        sequences = B if axis is None else B * jax.lax.axis_size(axis)
+        ways = 1 if axis is None else jax.lax.axis_size(axis)
         out = moe.experts_of_share(
             tokens, blk.get("w_gate"), blk["w_up"], blk["w_down"], weights,
-            slots,
-            impl=impl, chunk=moe.held_chunk(
-                slots.order.shape[0], n_held, cfg.n_experts,
-                load_held_even=cfg.expert_bias, sequences=sequences))
+            slots, impl=impl,
+            chunk=_buffer_rows(cfg, tokens.shape[0], B * ways, ways))
         if axis is not None:
             with jax.named_scope("moe_scatter"):
                 out = jax.lax.psum_scatter(
@@ -1808,6 +1815,11 @@ class Reading(NamedTuple):
     # while any of its coefficients is not 0
     adds: Union[None, bool, str] = None
     step: bool = True  # the train step reports it (`_STEP_READINGS`)
+    # what a report makes of the steps' (`_STEP_ACCOUNT`): None, it shows the
+    # last step's; a counter's name, it sums the steps' into it too; True, it
+    # shows none and hands them, stacked [S, L, ...], to its record's
+    # `account(static, stacked)`, a `tracing.Account`'s `fold`
+    over_steps: Union[None, bool, str] = None
 
 
 def _fullest_over_mean(load):
@@ -2987,10 +2999,7 @@ class _BlockDiffusionAttention(_PlainAttention):
         buffers = 0
         if cfg.n_experts and cfg.held[1] < cfg.n_experts:
             rows = cfg.rows_per_token * cfg.max_seq_len
-            chunk = moe.held_chunk(
-                rows * cfg.experts_per_token, cfg.held[1], cfg.n_experts,
-                load_held_even=cfg.expert_bias, sequences=1)
-            buffers = chunk * (
+            buffers = _buffer_rows(cfg, rows, 1) * (
                 2 * cfg.d_model + cfg.ff_matrices * cfg.ff_dim) // rows
         if _bd_own_join_kernels(cfg, cfg.max_seq_len):
             round_the_join = h * dh * 4
@@ -3092,10 +3101,23 @@ class _RoutedFF(Sublayer):
         # (`experts_held`, an `expert` axis), [L] or a device each [L, n],
         # the slots whose expert it holds and those of them it did not
         # compute (0); under an `expert` axis `expert_load` by device [L, n]
-        *map(Reading, ("expert_load", "held_slots", "dropped_slots",
-                       "chip_load")),
-        Reading("chip_load_max_over_mean", "fullest over mean", "chip_load"),
+        *(Reading(name, over_steps=True)
+          for name in ("expert_load", "held_slots", "dropped_slots")),
+        Reading("chip_load"),
+        Reading("chip_load_max_over_mean", "fullest over mean", "chip_load",
+                over_steps=True),
     )
+
+    def account(self, static, stacked):
+        """`ops/moe.py` `layer_steps`, a share's over the buffers' rows that
+        its trace asked for."""
+        load = stacked["expert_load"]
+        of_share = {name: stacked[name].reshape(*load.shape[:2], -1)
+                    for name in ("held_slots", "dropped_slots")
+                    if name in stacked}
+        return moe.layer_steps(
+            load, **of_share, chunk=static.get("held_chunk"),
+            chip_load_max_over_mean=stacked.get("chip_load_max_over_mean"))
 
     def init(self, key, cfg, L):
         d, f, held = cfg.d_model, cfg.ff_dim, cfg.held[1]
@@ -4110,8 +4132,11 @@ def diffusion_inputs(batch, cfg: TransformerConfig):
                 masked)
 
 
-_DIFFUSION_READINGS = ("diffusion_tokens", "diffusion_masked_tokens",
-                       "diffusion_weight_sum", "diffusion_rows")
+# the loss's own readings of a block-diffusion step, each summed over steps
+# into the counter `diffusion.<what>` and the last step's shown
+_DIFFUSION_READINGS = tuple(
+    Reading("diffusion_" + what, over_steps="diffusion." + what)
+    for what in ("tokens", "masked_tokens", "weight_sum", "rows"))
 
 
 def _block_diffusion_loss(params, batch, cfg: TransformerConfig, **kw):
@@ -4141,7 +4166,7 @@ def _block_diffusion_loss(params, batch, cfg: TransformerConfig, **kw):
             hidden[:, :L], _unembed(params, cfg), tokens, weights)
     loss, readings = _settled(loss, readings or {}, cfg)
     with jax.named_scope("bd_noise"):
-        readings.update(zip(_DIFFUSION_READINGS, (
+        readings.update(zip((r.name for r in _DIFFUSION_READINGS), (
             jnp.asarray(tokens.size, jnp.int32),
             masked.sum(dtype=jnp.int32),
             weights.sum() * tokens.size,
@@ -4284,11 +4309,8 @@ def _exchange_bytes(cfg: TransformerConfig, tokens: int, ways: int) -> int:
         return 0
     item, d = _item(cfg), cfg.d_model
     rows = ways * tokens
-    chunk = moe.held_chunk(
-        rows * cfg.experts_per_token, cfg.n_experts // ways, cfg.n_experts,
-        load_held_even=cfg.expert_bias,
-        sequences=ways * max(
-            1, tokens // (cfg.rows_per_token * cfg.max_seq_len)))
+    chunk = _buffer_rows(cfg, rows, ways * max(
+        1, tokens // (cfg.rows_per_token * cfg.max_seq_len)), ways)
     return (rows * d * (item + 2 * 4)
             + chunk * (2 * d + cfg.ff_matrices * cfg.ff_dim) * item)
 
@@ -4583,7 +4605,36 @@ def _memory_limit(mesh) -> Optional[int]:
 _STEP_READINGS = (
     *dict.fromkeys(
         r.name for sub in _RECORDS for r in sub.readings if r.step),
-    *_EXIT_READINGS, *_DIFFUSION_READINGS)
+    *_EXIT_READINGS, *(r.name for r in _DIFFUSION_READINGS))
+
+
+# the readings of which a report makes more than showing the last step's,
+# and the records that account for some of their own
+_OVER_STEPS = [r for r in (*(r for sub in _RECORDS for r in sub.readings),
+                           *_DIFFUSION_READINGS) if r.over_steps]
+_OWN_ACCOUNTS = [(sub, own) for sub in _RECORDS if (own := {
+    r.name for r in sub.readings if r.over_steps is True})]
+
+
+def _account_of_steps(static, stacked):
+    """`_STEP_ACCOUNT`'s `fold`: of the steps' readings `stacked`, those a
+    record accounts for itself and those summed into a counter, as
+    `Reading.over_steps` states it where the reading is."""
+    sums, rows = {}, []
+    for sub, own in _OWN_ACCOUNTS:
+        if not own.isdisjoint(stacked):
+            sums, rows = sub.account(static, stacked)
+    sums.update((r.over_steps, stacked[r.name].sum().item())
+                for r in _OVER_STEPS
+                if r.over_steps is not True and r.name in stacked)
+    return sums, rows
+
+
+# what a report makes of the steps of `make_train_step`: the train session's
+# account (`ray_tpu/train/_runtime.py`) folds by it and names no reading
+_STEP_ACCOUNT = tracing.Account(
+    {r.name: r.over_steps is not True for r in _OVER_STEPS},
+    _account_of_steps)
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
@@ -4592,14 +4643,13 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
     state = {'params': f32 sharded, 'opt': optax state, 'step': scalar}
     step(state, batch) -> (state, metrics); params/opt donated. `step` is
     the jitted function under `tracing.Step`: a call is the span
-    `train.step` and leaves `metrics` for the train session's account;
-    `lower`, `trace` and every other attribute are the jitted function's;
+    `train.step` and leaves `metrics` for the train session's account, which
+    folds them by `step.account` (`_STEP_ACCOUNT`); `lower`, `trace` and
+    every other attribute are the jitted function's;
     `step.static["held_chunk"]` is, once a share's step is traced, the rows
     of the buffers its routed layers walk their held rows in. metrics are
-    loss and grad_norm, and with routed experts aux_loss, z_loss and
-    expert_load [L, E]; of a share of the experts also held_slots and
-    dropped_slots [L]; with sparse attention index_loss, index_keys_min_gap
-    and index_keys_max_gap; with kda kda_log_decay_min and kda_beta_mean.
+    loss and grad_norm, and of `_STEP_READINGS` (what the stack's records
+    state, the exit loss's and block diffusion's) those the step makes.
     With `cfg.expert_bias` the state has 'expert_bias'
     [L, E] float32, which the optimizer does not own: after the optimizer's
     update the step moves it by `expert_bias_update_rate` toward the experts
@@ -4659,13 +4709,11 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
             params, batch, cfg, mesh=mesh, **kw)
         if "held_slots" in readings:
             # the rows of a share's buffers, as `_routed_rows` asks for them:
-            # of the slots and the sequences of the mesh's whole batch
-            ways = mesh.size if mesh_lib.expert_axis(mesh) else 1
-            static["held_chunk"] = moe.held_chunk(
-                math.prod(readings["expert_index"].shape[-2:]),
-                cfg.held[1] // ways, cfg.n_experts,
-                load_held_even=cfg.expert_bias,
-                sequences=batch["tokens"].shape[0])
+            # of the rows and the sequences of the mesh's whole batch
+            static["held_chunk"] = _buffer_rows(
+                cfg, readings["expert_index"].shape[-2],
+                batch["tokens"].shape[0],
+                mesh.size if mesh_lib.expert_axis(mesh) else 1)
         return loss, {k: readings[k] for k in _STEP_READINGS if k in readings}
 
     def on_a_device(tree, shardings):
@@ -4755,7 +4803,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None):
                     jnp.abs(state["expert_bias"]))
         return state, {"loss": loss, "grad_norm": gnorm, **readings}
 
-    return init_state, tracing.Step(step, static), {
+    return init_state, tracing.Step(step, static, _STEP_ACCOUNT), {
         "tokens": tok_sharding, "replicated": repl, "params": p_shard,
         "state": state_shard}
 

@@ -94,10 +94,12 @@ rows' tiles alone.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops.flash_attention import (
     _DEFAULT_VMEM, _LANES, _MAX_VMEM, _NN, _NT, _TN, _cdiv, _dot, _pallas_call,
@@ -221,14 +223,7 @@ def held_chunk(slots: int, held: int, n_experts: int,
     shares and a quarter, past two of the three, so that a step pays for
     its buffers once a layer and beyond that for its rows alone, 3.1 ms an
     even share; buffers past all three (four even shares) would need
-    0.4 GB that the cell's chip does not have (PERF.md section 6, PR 46).
-    A device of an `expert` mesh axis is a share for the tokens of all the
-    axis: `slots` are then the axis's and `sequences` the axis's too (four
-    of 16,384 tokens in `mellum2.ep4`: 524,288 slots, 16 of 64 experts
-    held, buffers of 180,224 rows for 131,072 expected, 2.2 GB more of the
-    compiler's plan than buffers of 32,768 that a step would walk four or
-    five times; the fullest chip read 1.28 to 1.49 even shares on the
-    comparison's four sequences of 2,048: PERF.md section 6, PR 50)."""
+    0.4 GB that the cell's chip does not have (PERF.md section 6, PR 46)."""
     if load_held_even:
         slack = _HELD_SLACK
     elif sequences == 1:
@@ -238,6 +233,23 @@ def held_chunk(slots: int, held: int, n_experts: int,
     even = _cdiv(slots * held, n_experts)
     tiles = max(1, _cdiv(int(slack * even), _ROW_TILE))
     return min(slots, tiles * _ROW_TILE)
+
+
+def buffer_rows(rows: int, sequences: int, ways: int = 1, *,
+                experts_per_token: int, held: int, n_experts: int,
+                load_held_even: bool) -> int:
+    """`held_chunk` of `rows` stream rows in `sequences` sequences routed
+    together over `held` of `n_experts` experts: what the layer that walks
+    the buffers, the step that accounts for them and the rule that prices
+    them ask. A device of an `expert` axis of `ways` is a share for the
+    tokens of all the axis, so the rows and the sequences are the axis's
+    (four of 16,384 tokens in `mellum2.ep4`: 524,288 slots, 16 of 64
+    experts held, buffers of 180,224 rows for 131,072 expected, 2.2 GB more
+    of the compiler's plan than buffers of 32,768 that a step would walk
+    four or five times; the fullest chip read 1.28 to 1.49 even shares on
+    the comparison's four sequences of 2,048: PERF.md section 6, PR 50)."""
+    return held_chunk(rows * experts_per_token, held // ways, n_experts,
+                      load_held_even=load_held_even, sequences=sequences)
 
 
 def _by_token(ys, inverse, k, rows=None):
@@ -1310,10 +1322,49 @@ def _hidden_of_chunk(tokens, w_gate, w_up, part: Slots, rows, impl):
                 else jax.nn.silu(gmm(xs[0], w_gate)) * gmm(xs[1], w_up))
 
 
+def _whole_chunks(rows, chunk: int):
+    """Chunks of `chunk` rows that hold `rows` rows: none of no rows."""
+    return (rows + chunk - 1) // chunk
+
+
 def _chunks(slots: Slots, chunk: int):
     """`order` padded to whole chunks, and how many of them hold a row."""
     order = jnp.pad(slots.order, (0, (-slots.order.shape[0]) % chunk))
-    return order, (slots.group_sizes.sum() + chunk - 1) // chunk
+    return order, _whole_chunks(slots.group_sizes.sum(), chunk)
+
+
+def layer_steps(expert_load, held_slots=None, dropped_slots=None,
+                chunk: Optional[int] = None, chip_load_max_over_mean=None):
+    """What `S` steps' routed layers did, on the host and in `numpy`, from
+    the readings the layers make: `expert_load` [S, layers, E]; of a share
+    `held_slots` and `dropped_slots` [S, layers, devices] (one, or each of
+    an `expert` axis) and `chunk`, its buffers' rows; over an axis
+    `chip_load_max_over_mean` [S, layers]. Returns ({counter `moe.*`: its
+    sum over the layer-steps}, of a share a row a step `[chunks a layer,
+    held rows a layer]`, the fullest device's); docs/observability.md, "The
+    train path", has each counter. The chunks are `_chunks`' own rounding,
+    and one at least: `moe.buffer_rows` counts a layer's buffers once even
+    where it held no row."""
+    sums = {"moe.fullest_expert_slots": expert_load.max(-1).sum(),
+            "moe.even_expert_slots": expert_load.mean(-1).sum()}
+    rows = []
+    if chip_load_max_over_mean is not None:
+        sums["moe.chip_load_max_over_mean_sum"] = (
+            chip_load_max_over_mean.mean(-1).sum())
+    if held_slots is not None:
+        walked = held_slots - dropped_slots
+        chunks = np.maximum(1, _whole_chunks(walked, chunk))
+        fullest = chunks.max(-1)  # [S, layers]
+        sums.update({
+            "moe.held_slots": held_slots.sum(),
+            "moe.held_rows": walked.sum(),
+            "moe.dropped_slots": held_slots.sum() - walked.sum(),
+            "moe.buffer_rows": chunks.sum() * chunk,
+            "moe.extra_chunk_layer_steps": (fullest > 1).sum()})
+        rows = list(map(list, zip(
+            fullest.tolist(), walked.max(-1).tolist())))
+    return {"moe.layer_steps": math.prod(expert_load.shape[:2]),
+            **{name: n.item() for name, n in sums.items()}}, rows
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))

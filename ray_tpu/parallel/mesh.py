@@ -216,9 +216,3 @@ def fsdp_sharding_for_leaf(mesh, leaf):
     spec = [None] * len(shape)
     spec[axis] = FSDP
     return NamedSharding(mesh, P(*spec))
-
-
-def host_local_device_count() -> int:
-    import jax
-
-    return jax.local_device_count()

@@ -8,9 +8,10 @@ enqueues (docs/observability.md, "The train path"):
      "interval":           the same less its state at the previous report,
      "counters":           {"compile.programs": ..., ...} since the session began,
      "counters_since_first_report": the same less their state at the first report,
-     "readings":           the last read step's readings that no counter sums,
-     "steps":              [step, chunks a layer, held rows a layer] of the
-                           last 64 read steps of a share of the experts,
+     "readings":           the last read step's readings, but those its
+                           account takes whole,
+     "steps":              [step, *what its account says of it] of the last
+                           64 read steps that have an account,
      "rusage":             this interval's deltas of getrusage and /proc/pressure}
 
 A table is `{span: [count, seconds, longest_seconds, time of the longest]}`
@@ -21,14 +22,15 @@ no span in a window is left out of that window's table.
 The steps account for themselves (`tracing.Step`, which `make_train_step`
 hands out): a step leaves its readings, device arrays it does not wait for,
 whose copies to the host it starts, and a report takes those that are
-ready, reads them in one `jax.device_get` and folds them into the counters
-`moe.*` and `train.steps_read` (`_fold_steps`). One that is not ready stays
-for the next report: the account never waits for the device.
+ready, reads them from the host's memory and folds them into the counter
+`train.steps_read` and those the step's own account names (`_fold_steps`;
+`tracing.Account`: this module knows no reading of any model). One that is
+not ready stays for the next report: the account never waits for the device.
 
 When an interval lasts more than `SLOW_FACTOR` times the median of those
 before it (and `SLOW_MIN_S`), the account records one flight-recorder event (`train`,
 `slow_interval`) and logs one line with what this process did in the gap,
-and under `moe` what the interval's steps routed.
+and under `steps` the rise of the counters that the steps' accounts named.
 """
 
 from __future__ import annotations
@@ -55,13 +57,6 @@ SLOW_FACTOR = 3.0
 SLOW_MIN_SEEN = 5  # intervals seen before one can be called slow
 SLOW_MIN_S = 0.1  # a loop that reports every millisecond jitters by 3x
 GC_MIN_S = 1e-3  # a collection shorter than this is no span
-
-# the readings that `_fold_steps` sums over layer-steps
-_SUMMED = ("expert_load", "held_slots", "dropped_slots",
-           "chip_load_max_over_mean")
-# a block-diffusion step's readings, each summed over steps into the counter
-# `diffusion.<what>` (`models/transformer.py` `_DIFFUSION_READINGS`)
-_DIFFUSION = ("tokens", "masked_tokens", "weight_sum", "rows")
 
 _RUSAGE = ("ru_nivcsw", "ru_nvcsw", "ru_majflt", "ru_utime", "ru_stime")
 _PRESSURE = ("cpu", "memory", "io")
@@ -161,71 +156,34 @@ def _ready(readings: Dict[str, Any]) -> Optional[bool]:
 
 
 def _fold_steps(steps: List[tuple]) -> tuple:
-    """`steps` (`tracing.take_steps`'s, their arrays ready) into the counters,
-    each summed over layer-steps (a routed layer in a step):
-    `moe.layer_steps`; `moe.fullest_expert_slots` and `moe.even_expert_slots`
-    (`expert_load`'s maximum and mean over a layer's experts); of a share of
-    the experts `moe.held_slots`, `moe.dropped_slots`, `moe.held_rows` (their
-    difference: the rows the kernels walk), `moe.buffer_rows` (chunks walked
-    times the step's `static["held_chunk"]`; a chunk at least) and
-    `moe.extra_chunk_layer_steps` (more than one chunk; over an `expert`
-    axis a device each, and the layer-step's chunks are its fullest
-    device's); over such an axis `moe.chip_load_max_over_mean_sum` (the mean
-    over a step's layers of each one's fullest chip over the mean, summed
-    over steps); of a block-diffusion step `diffusion.tokens`,
-    `diffusion.masked_tokens`, `diffusion.weight_sum` (the sum of `m / t`
-    over the step's tokens: its mean a token is 1 in expectation) and
-    `diffusion.rows` (the rows the stack ran); `train.steps_read`. Returns (the last step's readings that
-    are no sum, as numbers and lists; a row `[step, chunks a layer, held
-    rows a layer]` a step of a share). The report waits on the loop's
-    thread with the device idle, so a callable's steps are folded together:
-    a few `numpy` calls a report, not a step."""
-    sums: Dict[str, Any] = {"train.steps_read": len(steps)}
+    """`steps` (`tracing.take_steps`'s, their arrays ready) into the
+    counters: `train.steps_read`, and whatever the account of a callable
+    that states one sums of its steps (`tracing.Account`;
+    docs/observability.md, "The train path"). Returns (the last step's
+    readings, but those its account takes whole, as numbers and lists; the
+    accounts' rows, each behind its step's number; the counters they
+    named). The report waits on the loop's thread with the device idle, so a
+    callable's steps are folded together: a few `numpy` calls a report."""
+    sums: Dict[str, Any] = {}
     rows: List[list] = []
-
-    def add(name: str, n) -> None:
-        sums[name] = sums.get(name, 0) + n.item()
-
-    # from the host's memory: `tracing.Step` started the copies
-    def stacked(readings, name, *shape):
-        made = np.stack([np.asarray(r[name]) for r in readings])
-        return made.reshape(len(made), *(shape or (-1, made.shape[-1])))
-
     for _, same in itertools.groupby(steps, key=lambda step: id(step[1])):
-        numbers, (static, *_), readings = zip(*same)  # one callable's
-        if "diffusion_tokens" in readings[0]:
-            for what in _DIFFUSION:
-                add("diffusion." + what,
-                    stacked(readings, "diffusion_" + what, -1).sum())
-        if "expert_load" not in readings[0]:
+        numbers, (made, *_), readings = zip(*same)  # one callable's
+        if made.account is None:
             continue
-        load = stacked(readings, "expert_load")  # [S, layers, E]
-        layers = load.shape[1]
-        sums["moe.layer_steps"] = (
-            sums.get("moe.layer_steps", 0) + load.shape[0] * layers)
-        add("moe.fullest_expert_slots", load.max(-1).sum())
-        add("moe.even_expert_slots", load.mean(-1).sum())
-        if "chip_load_max_over_mean" in readings[0]:
-            add("moe.chip_load_max_over_mean_sum", stacked(
-                readings, "chip_load_max_over_mean", -1).mean(-1).sum())
-        if "held_slots" not in readings[0]:
-            continue
-        held = stacked(readings, "held_slots", layers, -1)  # [.., devices]
-        walked = held - stacked(readings, "dropped_slots", layers, -1)
-        chunk = static["held_chunk"]
-        chunks = np.maximum(1, -(-walked // chunk))
-        fullest = chunks.max(-1)  # [S, layers]
-        add("moe.held_slots", held.sum())
-        add("moe.held_rows", walked.sum())
-        add("moe.dropped_slots", held.sum() - walked.sum())
-        add("moe.buffer_rows", chunks.sum() * chunk)
-        add("moe.extra_chunk_layer_steps", (fullest > 1).sum())
-        rows.extend(map(list, zip(
-            numbers, fullest.tolist(), walked.max(-1).tolist())))
+        # from the host's memory: `tracing.Step` started the copies
+        stacked = {name: np.stack([np.asarray(r[name]) for r in readings])
+                   for name in made.account.reads if name in readings[0]}
+        added, of_steps = made.account.fold(made.static, stacked)
+        for name, n in added.items():
+            sums[name] = sums.get(name, 0) + n
+        rows.extend([number, *row] for number, row in zip(numbers, of_steps))
+    tracing.count("train.steps_read", len(steps))
     for name, n in sums.items():
         tracing.count(name, n)
-    return {k: np.asarray(v).tolist() for k, v in steps[-1][2].items()
-            if k not in _SUMMED}, rows
+    _, last, readings = steps[-1]
+    shown = last.account.reads if last.account else {}
+    return ({k: np.asarray(v).tolist() for k, v in readings.items()
+             if shown.get(k, True)}, rows, set(sums))
 
 
 class RuntimeAccount:
@@ -241,6 +199,7 @@ class RuntimeAccount:
         self._unread: deque = deque(maxlen=tracing.STEPS_KEPT)  # not ready
         self._readings: Dict[str, Any] = {}
         self._steps: deque = deque(maxlen=tracing.STEPS_KEPT)
+        self._accounted: set = set()  # the counters the steps' accounts named
         self._usage = _usage()
         self._t_report = time.perf_counter()
         self._intervals: deque = deque(maxlen=64)
@@ -280,10 +239,11 @@ class RuntimeAccount:
                 ready.append(step)
         if ready:
             try:
-                self._readings, rows = _fold_steps(ready)
+                self._readings, rows, named = _fold_steps(ready)
             except RuntimeError:  # an array deleted since: the report stands
                 return
             self._steps.extend(rows)
+            self._accounted |= named
 
     def block(self) -> Dict[str, Any]:
         """This report's block; called once a report."""
@@ -335,10 +295,9 @@ class RuntimeAccount:
             "rusage": block["rusage"],
             "compiles": block["interval"].get("jax.compile", (0,))[0],
         }
-        moe = {k: v for k, v in _risen(
-            counters, self._counters_previous).items()
-            if v and k.startswith("moe.")}
-        if moe:  # what the interval's steps routed, where they routed
-            record["moe"] = moe
+        risen = _risen(counters, self._counters_previous)
+        steps = {k: risen[k] for k in sorted(self._accounted) if risen.get(k)}
+        if steps:  # what the interval's steps did, by their own account
+            record["steps"] = steps
         telemetry.record_event("train", "slow_interval", **record)
         logger.warning("train slow_interval %s", _rounded(record))

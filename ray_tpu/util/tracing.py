@@ -55,7 +55,8 @@ import threading
 import time
 import zlib
 from collections import deque
-from typing import Any, Dict, Iterable, Iterator, List, Optional
+from typing import (
+    Any, Callable, Dict, Iterable, Iterator, List, NamedTuple, Optional, Tuple)
 
 from ray_tpu._private.common import config
 from ray_tpu._private import rpc as _rpc
@@ -463,10 +464,22 @@ def counters() -> Dict[str, float]:
 
 STEPS_KEPT = 64
 READING_BYTES = 1 << 16  # a reading above this is no number to report
-# (the step's number, its callable's `static`, its readings), oldest first,
-# until the train session's account takes them (`take_steps`). Bounded:
-# outside a session nothing takes them, and the oldest goes.
+# (the step's number, its `Step`, its readings), oldest first, until the
+# train session's account takes them (`take_steps`). Bounded: outside a
+# session nothing takes them, and the oldest goes.
 _steps: "deque[tuple]" = deque(maxlen=STEPS_KEPT)
+
+
+class Account(NamedTuple):
+    """What a report makes of a `Step`'s steps beside showing the last one's
+    readings, stated by whoever built the step: the train session's account
+    folds by it and knows no reading's name."""
+    # {a reading `fold` is handed: whether a report still shows the last's}
+    reads: Dict[str, bool]
+    # (the step's `static`, {reading: the steps' stacked [S, ...]} of those
+    # `reads` the steps made) -> ({counter: the number the steps add to it},
+    # a list a step for the block's `steps`, or none)
+    fold: Callable[[Dict[str, Any], Dict[str, Any]], Tuple[dict, List[list]]]
 
 
 class Step:
@@ -482,12 +495,15 @@ class Step:
     at the report they cost 0.15 ms an array on a v5e's host, 3.4 ms a
     chunk of five steps, with the device idle (PERF.md section 6, PR 68).
     ``static`` holds what the step's trace said of its program and no run
-    changes. Every other attribute (``lower``, ``trace``, ``eval_shape``,
-    ``_cache_size``, ...) is the jitted function's."""
+    changes, ``account`` what a report sums of the steps. Every other
+    attribute (``lower``, ``trace``, ``_cache_size``, ...) is the jitted
+    function's."""
 
-    def __init__(self, jitted, static: Dict[str, Any]):
+    def __init__(self, jitted, static: Dict[str, Any],
+                 account: Optional[Account] = None):
         self._jitted = jitted
         self.static = static
+        self.account = account
         self.calls = 0
 
     def __call__(self, *args, **kwargs):
@@ -501,7 +517,7 @@ class Step:
             for x in kept.values():
                 x.copy_to_host_async()
             self.calls += 1
-            _steps.append((self.calls, self.static, kept))
+            _steps.append((self.calls, self, kept))
         return out
 
     def __getattr__(self, name: str):

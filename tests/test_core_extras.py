@@ -1,6 +1,6 @@
-"""Core API extras: cancel, dynamic generators, ActorPool, Queue,
-TorchTrainer (analog of python/ray/tests/test_cancel.py, test_generators.py,
-test_actor_pool.py, test_queue.py; train/tests/test_torch_trainer.py)."""
+"""Core API extras: cancel, dynamic generators, ActorPool, Queue (analog of
+python/ray/tests/test_cancel.py, test_generators.py, test_actor_pool.py,
+test_queue.py)."""
 
 import time
 
@@ -122,47 +122,6 @@ def test_queue(ray_start_regular):
     assert [q.get(timeout=10) for _ in range(3)] == [0, 1, 2]
     assert ray_tpu.get(ref)
     q.shutdown()
-
-
-def test_torch_trainer_ddp(ray_start_regular):
-    from ray_tpu.air import ScalingConfig
-    from ray_tpu.train.torch import TorchTrainer
-
-    def train_fn(config):
-        import torch
-        import torch.distributed as dist
-        from torch import nn
-
-        import ray_tpu.train as train
-        from ray_tpu.train.torch import prepare_model
-
-        assert dist.is_initialized() and dist.get_world_size() == 2
-        rank = dist.get_rank()
-
-        model = prepare_model(nn.Linear(4, 1))
-        opt = torch.optim.SGD(model.parameters(), lr=0.1)
-        torch.manual_seed(0)
-        X = torch.randn(64, 4)
-        y = X.sum(dim=1, keepdim=True)
-        for _ in range(config["epochs"]):
-            opt.zero_grad()
-            loss = ((model(X) - y) ** 2).mean()
-            loss.backward()  # DDP allreduces grads here
-            opt.step()
-        # Gradient sync means identical weights on every rank.
-        w = model.module.weight.detach().clone()
-        gathered = [torch.zeros_like(w) for _ in range(2)]
-        dist.all_gather(gathered, w)
-        assert torch.allclose(gathered[0], gathered[1])
-        train.report({"loss": float(loss), "rank": rank})
-
-    trainer = TorchTrainer(
-        train_fn,
-        train_loop_config={"epochs": 20},
-        scaling_config=ScalingConfig(num_workers=2),
-    )
-    result = trainer.fit()
-    assert result.metrics["loss"] < 1.0
 
 
 def test_streaming_generator_overlaps_producer(ray_start_regular):

@@ -12,7 +12,6 @@ import pytest
 from ray_tpu._private import gcs_store
 from ray_tpu._private.gcs_store import (
     InMemoryStoreClient,
-    SqliteStoreClient,
     WalStoreClient,
     inject_torn_tail,
     make_store,
@@ -126,42 +125,20 @@ def test_wal_sync_always_flushes_inline(wal_path):
 
 
 def test_wal_refuses_sqlite_file(tmp_path):
+    import sqlite3
+
     p = str(tmp_path / "gcs.db")
-    sq = SqliteStoreClient(p)
-    sq.put("kv", "k", b"v")
-    sq.close()
+    with sqlite3.connect(p) as db:
+        db.execute("CREATE TABLE kv (k TEXT PRIMARY KEY, v BLOB)")
+        db.execute("INSERT INTO kv VALUES ('k', x'76')")
+    db.close()
     with pytest.raises(ValueError):
         WalStoreClient(p)
     assert not inject_torn_tail(p)
     # The refused open must not have damaged the sqlite file.
-    sq2 = SqliteStoreClient(p)
-    assert sq2.get("kv", "k") == b"v"
-    sq2.close()
-
-
-def test_sqlite_close_checkpoints_wal(tmp_path):
-    p = str(tmp_path / "gcs.db")
-    s = SqliteStoreClient(p)
-    s.put("kv", "k", b"v")
-    assert os.path.getsize(p + "-wal") > 0
-    s.close()
-    # Graceful close folds the -wal file into the main db.
-    assert (
-        not os.path.exists(p + "-wal") or os.path.getsize(p + "-wal") == 0
-    )
-    s2 = SqliteStoreClient(p)
-    assert s2.get("kv", "k") == b"v"
-    s2.close()
-
-
-def test_sqlite_crash_leaves_wal_replayable(tmp_path):
-    p = str(tmp_path / "gcs.db")
-    s = SqliteStoreClient(p)
-    s.put("kv", "k", b"v")
-    s.crash()  # no checkpoint: -wal left behind
-    s2 = SqliteStoreClient(p)
-    assert s2.get("kv", "k") == b"v"  # sqlite replays its WAL on open
-    s2.close()
+    db = sqlite3.connect(p)
+    assert db.execute("SELECT v FROM kv WHERE k = 'k'").fetchone() == (b"v",)
+    db.close()
 
 
 _OPS = [
@@ -186,11 +163,10 @@ def _apply(store):
 
 
 def test_backend_parity(tmp_path):
-    """Same op sequence -> same get_all across all three backends, both
-    live and (for the durable two) after a reopen."""
+    """Same op sequence -> same get_all across the backends, both live and
+    (for the durable one) after a reopen."""
     stores = {
         "memory": InMemoryStoreClient(),
-        "sqlite": SqliteStoreClient(str(tmp_path / "p.db")),
         "wal": WalStoreClient(str(tmp_path / "p.wal")),
     }
     tables = ("kv", "actors", "named", "jobs", "pgs")
@@ -200,12 +176,9 @@ def test_backend_parity(tmp_path):
     for name, s in stores.items():
         assert {t: s.get_all(t) for t in tables} == expect, name
         s.close()
-    for name, reopened in (
-        ("sqlite", SqliteStoreClient(str(tmp_path / "p.db"))),
-        ("wal", WalStoreClient(str(tmp_path / "p.wal"))),
-    ):
-        assert {t: reopened.get_all(t) for t in tables} == expect, name
-        reopened.close()
+    reopened = WalStoreClient(str(tmp_path / "p.wal"))
+    assert {t: reopened.get_all(t) for t in tables} == expect
+    reopened.close()
 
 
 def test_make_store_backend_selection(tmp_path, monkeypatch):
@@ -216,19 +189,17 @@ def test_make_store_backend_selection(tmp_path, monkeypatch):
         make_store(str(tmp_path / "a.wal")), WalStoreClient
     )  # default knob = wal
     assert isinstance(
-        make_store(str(tmp_path / "b.db"), backend="sqlite"), SqliteStoreClient
-    )
-    assert isinstance(
         make_store(str(tmp_path / "c"), backend="memory"), InMemoryStoreClient
     )
-    monkeypatch.setenv("RAY_TPU_GCS_PERSIST_BACKEND", "sqlite")
+    monkeypatch.setenv("RAY_TPU_GCS_PERSIST_BACKEND", "memory")
     config.refresh()
     try:
         assert isinstance(
-            make_store(str(tmp_path / "d.db")), SqliteStoreClient
+            make_store(str(tmp_path / "d.db")), InMemoryStoreClient
         )
-        with pytest.raises(ValueError):
-            make_store(str(tmp_path / "e"), backend="bogus")
+        for unknown in ("bogus", "sqlite"):  # the second went in PR 72
+            with pytest.raises(ValueError):
+                make_store(str(tmp_path / "e"), backend=unknown)
     finally:
         monkeypatch.delenv("RAY_TPU_GCS_PERSIST_BACKEND")
         config.refresh()

@@ -8,11 +8,7 @@ say nothing about results or times. Shapes are the ones chip_smoke.py
 trains: GPT-2-small widths, batch 32, seq 1024, bf16.
 """
 
-import functools
 import importlib
-import os
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
 import jax
 import jax.numpy as jnp
@@ -22,34 +18,13 @@ from jax.sharding import SingleDeviceSharding
 from ray_tpu.models import TransformerConfig, make_train_step
 from ray_tpu.ops.fused import lm_head_cross_entropy
 from ray_tpu.parallel import make_mesh
+from v5e_described import v5e  # noqa: F401 - the module's fixture
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
 BH, T, D = 32 * 12, 1024, 64
 KERNEL = dict(causal=True, scale=D ** -0.5, block_q=128, block_k=128,
               interpret=False)
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    """Four described v5e devices, with the compile cache off around the
-    tests: an entry compiled for a described device is written but cannot be
-    read back without a chip, and the next compile would warn."""
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
-    was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield topo.devices
-    jax.config.update("jax_enable_compilation_cache", was_on)
-    compilation_cache.reset_cache()
 
 
 def _shapes(device, bh=BH, t=T, d=D):
@@ -966,451 +941,3 @@ def test_block_diffusion_attention_compiles_at_the_cell_s_shapes(v5e, use):
         "bd_own_join_bwd": ["bf16[64,16384,128]", "bf16[4,32768,128]",
                             "bf16[4,32768,128]", "bf16[64,16384,128]",
                             "f32[8,8,16384]"]}
-
-
-def test_what_the_rule_keeps_of_the_mixers_at_a_v5e_s_limit():
-    """Arithmetic alone, `nemotron3nano.tokens8k` at 2 x 8192 tokens and a
-    limit of 15.75 GiB. On the kernels' path the scan's part of a block's
-    backward is the entering states and the columns and rows of dt and cum
-    (0.75 GB) where the `jax.numpy` path holds [H, Q, Q] arrays (2.68 GB).
-    The stack is one period of nine layers, which the rule walks a layer at
-    a time (PR 54): its fullest moment is the last mixer's backward, with
-    the names of the eight layers up to it and no gradient but what a loop
-    accumulates. The room is 3.83 GB and the rule keeps every name, 2.75
-    GB, with 1.20 GB left; with the `jax.numpy` scan the room is 1.89 GB
-    and the names end with `mamba_in` (1.35 GB), 0.17 GB left. Since PR 63
-    the gated norm's kernels hold no float32 array of the mixer's width
-    (`ops/mamba_passes.py`): 0.81 GB more room on their path."""
-    from chipbench import spec
-    from chipbench.loops import nemotron_h
-    from ray_tpu.models import transformer as tr
-
-    config = spec.load_cell(spec.ROOT, "nemotron3nano.tokens8k")["config"]
-    tokens = 2 * 8192
-    params = 4 * 666962944
-
-    def kept(impl):
-        cfg = nemotron_h.model_config(dict(config, attention_impl=impl))
-        return cfg, tr.saved_activations(
-            cfg, tokens, 3 * params, params, HBM_LIMIT)
-
-    cfg, chosen = kept("pallas")
-    terms = tr._terms(cfg, tokens, params)
-    assert tr._scan_bytes_per_token(cfg) * tokens == 746586112
-    assert terms.saved_bytes(chosen) == {
-        "attn_ctx": 136314880, "attn_res": 88080384, "attn_qkv": 150994944,
-        "mamba_in": 1350565888, "ssd_out": 536870912, "shared_up": 486539264}
-    assert terms.fullest(chosen).name == "layer 7"
-    assert terms.room(3 * params, HBM_LIMIT) == 3828756480 + 3 * 4096 * 4 * tokens
-    cfg, chosen = kept("xla")
-    terms = tr._terms(cfg, tokens, params)
-    assert tr._scan_bytes_per_token(cfg) * tokens == 64 * 128 * 20 * tokens
-    assert terms.saved_bytes(chosen) == {
-        "attn_ctx": 136314880, "attn_res": 88080384, "attn_qkv": 150994944,
-        "mamba_in": 1350565888}
-    assert terms.room(3 * params, HBM_LIMIT) == 1890988032
-
-
-# ------------------------- the token cells' steps with what remat keeps
-
-HBM_LIMIT = int(15.75 * 2**30)  # a v5e's `bytes_limit`, to the GiB's hundredth
-# after what the rule keeps at that limit: (flash_fwd calls in the text, the
-# parent's; moe_gmm calls, the parent's). One scanned layer kind with
-# attention in every cell; without `attn_ctx` the kernel runs in the second
-# forward too, and `olmoe.tokens4k`'s gate and up products with it.
-TOKEN_CELLS = {
-    "mistral7b.tokens4k": ((1, 2), (0, 0)),
-    "mistral7b.fsdp4": ((1, 2), (0, 0)),
-    "olmoe.tokens4k": ((1, 2), (6, 8)),
-    "lfm2moe.tokens8k": ((1, 2), (32, 32)),  # a share's layer has no names
-    "nemotron3nano.tokens8k": ((1, 2), (20, 20)),  # a share, as above
-}
-# (`ssd_fwd`, `ssd_bwd`) calls in the text: four mixers, each run forward,
-# forward again under remat (their `ssd_out` is kept since PR 54, but the
-# backward wants the entering states, which only the forward kernel makes)
-# and backward
-SCAN_CALLS = {"nemotron3nano.tokens8k": (8, 4)}
-
-
-def _token_cell_step(cell_name, devices, monkeypatch):
-    """(lowered step of the cell at its real shapes on described devices,
-    what the rule chose while it was traced), as `tr` stands patched."""
-    from chipbench import loop, spec
-    from ray_tpu.models import transformer as tr
-
-    cell = spec.load_cell(spec.ROOT, cell_name)
-    config, traffic = cell["config"], cell["traffic"]
-    # "auto" asks the platform, which is the CPU here: steered in the test
-    config["attention_impl"] = "pallas"
-    chosen = []
-    rule = tr.saved_activations
-
-    def recording(*args):
-        chosen.append(rule(*args))
-        return chosen[-1]
-
-    monkeypatch.setattr(tr, "saved_activations", recording)
-    family = spec.load_code(spec.ROOT, "loops", config["family"]).build(
-        config, traffic, list(devices[:cell["workload"]["chips"]]))
-    key = jax.eval_shape(lambda: loop.seed_key(0))
-    state = jax.eval_shape(
-        family.init_state, jax.eval_shape(family.init_params, key))
-    state = jax.tree.map(
-        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        state, family.state_shardings)
-    batch = family.batch_shapes(int(traffic["batch_rows"]))
-    lowered = family.step.lower(state, batch)
-    monkeypatch.setattr(tr, "saved_activations", rule)
-    return lowered, chosen[0]
-
-
-class _Step:
-    """A cell's step lowered once and compiled at most once, for every test
-    of this module that reads it."""
-
-    def __init__(self, lowered, chosen):
-        self.lowered, self.chosen = lowered, chosen
-
-    @functools.cached_property
-    def compiled(self):
-        return self.lowered.compile()
-
-
-@pytest.fixture(scope="module")
-def token_steps(v5e):
-    """`step_of(cell, limited)`: the cell's `_Step`, with the limit's reader
-    patched to a v5e's (a described device reports none) where `limited`;
-    one lowering and one compilation a (cell, limited) among the tests."""
-    from ray_tpu.models import transformer as tr
-
-    made = {}
-
-    def step_of(cell_name, limited):
-        if (cell_name, limited) not in made:
-            with pytest.MonkeyPatch.context() as patch:
-                if limited:
-                    patch.setattr(tr, "_memory_limit", lambda mesh: HBM_LIMIT)
-                made[cell_name, limited] = _Step(
-                    *_token_cell_step(cell_name, v5e, patch))
-        return made[cell_name, limited]
-
-    return step_of
-
-
-def _calls(text, kernel):
-    import re
-
-    return len(re.findall(rf"%{kernel}(\.\d+)? = ", text))
-
-
-@pytest.mark.parametrize("cell_name", list(TOKEN_CELLS))
-def test_token_step_with_what_it_keeps_compiles_and_fits(
-        token_steps, cell_name):
-    """The reader patched to a v5e's limit (a described device reports
-    none): the step the chip would run compiles, stays a GB under the limit
-    by the compiler's own count, and runs the flash forward once a layer
-    and the whole flash backward as one kernel."""
-    step = token_steps(cell_name, limited=True)
-    chosen, compiled = step.chosen, step.compiled
-    assert next(iter(chosen)) == "attn_ctx" and "attn_res" in chosen
-    memory = compiled.memory_analysis()
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            - memory.alias_size_in_bytes) <= HBM_LIMIT - 10**9
-    text = compiled.as_text()
-    (flash, _), (gmm, _) = TOKEN_CELLS[cell_name]
-    assert _calls(text, "flash_fwd") == flash
-    assert _calls(text, "flash_bwd_dkv_dq") == 1
-    assert _calls(text, "flash_bwd_dq") == _calls(text, "flash_bwd_dkv") == 0
-    assert _calls(text, "moe_gmm") == gmm
-    if cell_name == "olmoe.tokens4k":
-        assert {"moe_slots", "moe_gate", "moe_up"} <= set(chosen)
-    scans = SCAN_CALLS.get(cell_name, (0, 0))
-    assert (_calls(text, "ssd_fwd"), _calls(text, "ssd_bwd")) == scans
-    if any(scans):
-        assert "mamba_in" in chosen and "ssd_out" in chosen
-        # a sequence's [n, H, Q, Q] of decays or masked scores is nowhere
-        assert "f32[2,64,64,128,128]" not in text
-        assert "bf16[2,64,64,128,128]" not in text
-
-
-@pytest.mark.parametrize("cell_name", list(TOKEN_CELLS))
-def test_token_step_without_a_limit_is_the_step_without_names(
-        v5e, token_steps, monkeypatch, cell_name):
-    """A described device reports no limit: nothing is chosen, no policy is
-    passed, and the step lowers to the text of the program that has no
-    names at all (the parent's, but for metadata)."""
-    import re
-
-    from ray_tpu.models import transformer as tr
-
-    def text_of(lowered):
-        # a function's name ends in a counter of the functions traced, and
-        # a kernel's serialized body holds the locations it was traced at
-        text = re.sub(r"@(\w+?)_\d+\b", r"@\1", lowered.as_text())
-        return re.sub(r'backend_config = "[^"]*"', "", text)
-
-    step = token_steps(cell_name, limited=False)
-    assert step.chosen == {}
-    monkeypatch.setattr(tr, "checkpoint_name", lambda x, name: x)
-    without_names, _ = _token_cell_step(cell_name, v5e, monkeypatch)
-    assert text_of(without_names) == text_of(step.lowered)
-
-
-def test_kimi_step_compiles_fits_and_is_priced(token_steps):
-    """`kimilinear.tokens16k` at its real shapes with the chip's limit
-    handed to the keep rule: every name is kept, the compiler's plan fits
-    what a v5e offers a program with no `.remat` fusion made to fit, the
-    rule's sum stands at or over the plan and under the chip, and the step
-    runs KDA's kernels (four layers: forward, forward again, backward),
-    the flash pair once and the grouped-matmul kernels."""
-    import re
-
-    from chipbench import kimi_linear_flops, spec
-    from ray_tpu.models import transformer as tr
-
-    step = token_steps("kimilinear.tokens16k", limited=True)
-    assert tuple(step.chosen) == (
-        "attn_ctx", "attn_res", "attn_qkv", "kda_res", "kda_qkv",
-        "shared_gate", "shared_up", "mlp_gate", "mlp_up")
-    memory = step.compiled.memory_analysis()
-    config = spec.load_cell(spec.ROOT, "kimilinear.tokens16k")["config"]
-    n_params = kimi_linear_flops.state_params(config)
-    # 12 bytes a parameter of state: weights and AdamW's two moments; the
-    # gradients are in the program's scratch
-    assert memory.argument_size_in_bytes == pytest.approx(
-        12 * n_params, rel=0.01)
-    plan = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
-    # 13.89 GB since the short convolutions are kernels (PR 67; 14.85
-    # before), 13.73 since the output norm and gate are (PR 69)
-    assert 13.4e9 < plan < 14.0e9
-    text = step.compiled.as_text()
-    assert ".remat" not in text
-    cfg = spec.load_code(spec.ROOT, "loops", "kimi_linear").model_config(
-        {**config, "attention_impl": "pallas"})
-    terms = tr._terms(cfg, 16384, 4 * n_params)
-    predicted = 12 * n_params + terms.fullest(step.chosen).bytes
-    assert plan - 0.2e9 <= predicted <= HBM_LIMIT - tr._SAVE_RESERVE
-    # every layer traced apart (one period of four and the dense layer):
-    # a call site a layer
-    assert _calls(text, "kda_fwd") == 8 and _calls(text, "kda_bwd") == 4
-    # q's, k's and v's short convolutions a layer, as the kernels (PR 67)
-    assert _calls(text, "kda_conv_fwd") == 24
-    assert _calls(text, "kda_conv_bwd") == 12
-    assert not [line for line in text.splitlines()
-                if "/kda_conv/" in line and "= f32[1,16384,4096]" in line]
-    # the output norm and gate a layer, as the kernels (PR 69), and none of
-    # the twelve copies of o to and from the layout of `[B, T, H, dk]`
-    assert _calls(text, "kda_out_norm_fwd") == 8
-    assert _calls(text, "kda_out_norm_bwd") == 4
-    assert not re.search(r"= f32\[2048,8,32,128\]\S* copy\(", text)
-    assert not [line for line in text.splitlines() if "/kda_out/" in line
-                and re.search(r"= f32\[1,16384,(4096|32,128)\]", line)]
-    assert _calls(text, "flash_fwd") == 1  # `attn_ctx` kept
-    assert _calls(text, "flash_bwd_dkv_dq") == 1
-    assert _calls(text, "moe_gmm") > 0 and _calls(text, "moe_tgmm") > 0
-    assert "InvertDiagBlocksLowerTriangular" not in text
-    assert not re.search(r"(f32|bf16)\[(\d+,)*16384,16384\]", text)
-    # the chunks' entering states of a layer: 32 heads x 256 chunks, float32
-    assert "f32[1,256,32,128,128]" in text
-
-
-def test_sdar_step_compiles_fits_and_is_priced(token_steps):
-    """`sdar.tokens16k` at its real shapes, 32,768 rows a step, with the
-    chip's limit handed to the keep rule: `attn_ctx` and `attn_res` are
-    kept (with `attn_qkv` too the plan stood at 15.99 GB, fitted by fusions
-    the compiler made again: PR 70), the compiler's plan fits what a v5e
-    offers a program with no `.remat` fusion made to fit, the rule's sum
-    stands at or over the plan and under the chip, and the step runs the
-    staircase's forward once a layer (`attn_ctx` kept), its whole backward
-    as one kernel, and the grouped-matmul kernels on the stream's rows."""
-    import re
-
-    from chipbench import sdar_flops, spec
-    from ray_tpu.models import transformer as tr
-
-    step = token_steps("sdar.tokens16k", limited=True)
-    assert tuple(step.chosen) == ("attn_ctx", "attn_res")
-    memory = step.compiled.memory_analysis()
-    config = spec.load_cell(spec.ROOT, "sdar.tokens16k")["config"]
-    n_params = sdar_flops.state_params(config)
-    assert memory.argument_size_in_bytes == pytest.approx(
-        12 * n_params, rel=0.01)
-    text = step.compiled.as_text()
-    assert ".remat" not in text
-    cfg = spec.load_code(spec.ROOT, "loops", "sdar").model_config(
-        {**config, "attention_impl": "pallas"})
-    terms = tr._terms(cfg, 2 * 16384, 4 * n_params)
-    predicted = 12 * n_params + terms.fullest(step.chosen).bytes
-    # the heap the compiler packs is 13.67 GB since the own block and the
-    # join are kernels (`lowering_seconds.py --plan`, PR 71; 14.58 GB with
-    # the `jax.numpy` lines, where the chip held 14.54 GB in the window, PR
-    # 70): the rule's sum (14.78 GB) stands over it and under what it may
-    # ask for
-    assert 14.6e9 <= predicted <= HBM_LIMIT - tr._SAVE_RESERVE
-    assert _calls(text, "flash_fwd_stair") == 1
-    assert _calls(text, "flash_bwd_dkv_dq_stair") == 1
-    # the own block and the join: forward and made again (o is not kept),
-    # and the backward
-    assert _calls(text, "bd_own_join_fwd") == 2
-    assert _calls(text, "bd_own_join_bwd") == 1
-    assert _calls(text, "flash_fwd") == _calls(text, "flash_bwd_dkv_dq") == 0
-    assert _calls(text, "moe_gmm") > 0 and _calls(text, "moe_tgmm") > 0
-    assert not re.search(r"(f32|bf16|pred)\[(\d+,)*32768,32768\]", text)
-    assert not re.search(r"(f32|bf16|pred)\[(\d+,)*16384,16384\]", text)
-
-
-@pytest.mark.timeout(600)
-def test_sdar_s_comparison_compiles_for_v5e(v5e):
-    """The comparison's system side of `sdar.tokens16k` as
-    `chipbench/loops/sdar.py` `errors_of` jits it (loss, readings and
-    gradients of one sequence of 4,096 tokens, the layers scanned and
-    rematerialised): with `bd_own_join_bwd` called bare in the layers'
-    backward the TPU compiler's memory-space assignment dies here (SIGSEGV in
-    `BestFitRepacker::Finish`: no exception, the process), which the step at
-    16,384 tokens never showed; `ops/block_diffusion.py` `_own_join_vjp_bwd`
-    calls it inside a `while` of one trip (PR 71)."""
-    from chipbench import loop, spec
-    from ray_tpu.models import transformer as tr
-
-    cell = spec.load_cell(spec.ROOT, "sdar.tokens16k")
-    config, traffic = cell["config"], cell["traffic"]
-    config["attention_impl"] = "pallas"  # "auto" asks the CPU here
-    family = spec.load_code(spec.ROOT, "loops", config["family"]).build(
-        config, traffic, list(v5e[:1]))
-    made = jax.eval_shape(
-        family.init_params, jax.eval_shape(lambda: loop.seed_key(0)))
-    made = jax.tree.map(
-        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        made, family.state_shardings["params"])
-    rows, length = (int(config["check"][k]) for k in ("rows", "seq_len"))
-    whole = family.batch_shapes(rows)
-    batch = {
-        name: jax.ShapeDtypeStruct(
-            (x.shape[0], x.shape[1] * length // whole["tokens"].shape[1]),
-            x.dtype, sharding=x.sharding)
-        for name, x in whole.items()}
-    cfg = family.model_config
-
-    @jax.jit
-    def system_side(params, batch):
-        (loss, readings), grads = jax.value_and_grad(
-            family.system_loss_and_readings, has_aux=True)(params, batch)
-        return loss, dict(
-            readings, masked=tr.diffusion_inputs(batch, cfg)[3]), grads
-
-    text = system_side.lower(made, batch).compile().as_text()
-    assert _calls(text, "bd_own_join_fwd") == 2
-    assert _calls(text, "bd_own_join_bwd") == 1
-
-
-# ------------------- a block's weight matmuls from and to buffers of their own
-
-# the scopes of a block's plain matmuls (docs/observability.md, "Device
-# scopes"); the router's is float32 on purpose and is not `_own_weights`'
-BLOCK_SCOPES = {"mlp", "attn_qkv", "attn_out", "short_conv", "latent_attention"}
-
-
-def _computations(text):
-    """{name: its lines} of a compiled module's text."""
-    import re
-
-    found, name = {}, None
-    for line in text.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(.*\) -> .* \{$", line)
-        if head:
-            name = head.group(1)
-            found[name] = []
-        elif name is not None:
-            found[name].append(line)
-    return found
-
-
-def _block_matmul_fusions(text):
-    """(fusion's name, its result type, its fused computation's lines) of
-    every fusion that holds a convolution under a block's scope."""
-    import re
-
-    computations = _computations(text)
-    for lines in computations.values():
-        for line in lines:
-            fusion = re.match(
-                r"\s*(?:ROOT )?%([\w.-]+) = (.*?) fusion\(.* calls=%([\w.-]+)",
-                line)
-            if not fusion:
-                continue
-            body = computations[fusion.group(3)]
-            scopes = {
-                part for inner in body if " convolution(" in inner
-                for part in re.split(
-                    r"[/()]", re.search(r'op_name="([^"]*)"', inner).group(1))}
-            if scopes & BLOCK_SCOPES and "moe_router" not in scopes:
-                yield fusion.group(1), fusion.group(2), body
-
-
-@pytest.mark.parametrize("cell_name", ["lfm2moe.tokens8k", "mistral7b.tokens4k"])
-def test_no_block_matmul_carries_an_update_of_the_state(
-        token_steps, cell_name):
-    """What PR 37 took out, held out: compiled for a v5e with what the rule
-    keeps there, no fusion of a block's matmul also holds a dynamic update
-    (the weight gradient written into the scanned stack) or writes more
-    than one float32 array of a parameter's shape (AdamW's update of a
-    one-layer segment's weight and moments, fused into its gradient's
-    matmul). `mistral7b.tokens4k` had the first in 7 fusions of a layer,
-    `lfm2moe.tokens8k` the second in 15."""
-    import re
-
-    step = token_steps(cell_name, limited=True)
-    state_shapes = {
-        ",".join(map(str, aval.shape))
-        for aval in jax.tree.leaves(step.lowered.in_avals)
-        if aval.dtype == jnp.float32 and aval.ndim >= 2}
-    fusions = list(_block_matmul_fusions(step.compiled.as_text()))
-    assert len(fusions) >= 25  # a layer's products, forward and backward
-    for name, result, body in fusions:
-        assert not any(" dynamic-update-slice(" in line for line in body), name
-        written = [dims for dims in re.findall(r"f32\[([\d,]+)\]", result)
-                   if dims in state_shapes]
-        assert len(written) <= 1, (name, result)
-
-
-def test_a_share_s_rows_reach_their_tokens_by_moe_sum_in_mellum2_ep4(
-        token_steps):
-    """`mellum2.ep4`'s step compiled for four described v5e with the chip's
-    limit handed to the keep rule: a layer's held rows are summed onto their
-    tokens by `moe_sum`, once forward and once backward in each of four
-    layers; no scatter-add and no float32 copy of the 180,224-row buffer is
-    left under the combine or the dispatch; and the compiler plans no more
-    memory than for the parent's step (8,507,300,352 bytes a chip, the same
-    compile of PR 50's tree)."""
-    import re
-
-    compiled = token_steps("mellum2.ep4", limited=True).compiled
-    text = compiled.as_text()
-    assert _calls(text, "moe_sum") == 8
-    under = [line for line in text.splitlines() if re.search(
-        r'op_name="[^"]*(moe_combine|moe_dispatch)', line)]
-    assert len(under) > 8
-    assert not any(" scatter(" in line or " sort(" in line for line in under)
-    assert not any("f32[180224,2304]" in line for line in under)
-    assert compiled.memory_analysis().temp_size_in_bytes <= 8_507_300_352
-
-
-def test_a_program_lowers_moe_sum_once_a_distinct_use(token_steps):
-    """What a Pallas call site costs set-up (its body traced and lowered to
-    Mosaic anew, 0.1 to 0.25 s of host time a site in every program that
-    holds it, whether the executable then comes from the cache or not:
-    PERF.md section 5) is paid once a distinct use, because `sum_held` is
-    entered through one jitted function: `lfm2moe.tokens8k`'s lowered step
-    holds two `moe_sum` payloads (the forward's weighted sum onto the
-    loop's float32 buffer, the backward's of two buffers) where its
-    compiled text calls the kernel eight times (four routed layers, once
-    forward and once backward)."""
-    import re
-
-    step = token_steps("lfm2moe.tokens8k", limited=True)
-    lowered = step.lowered.as_text()
-    assert len(re.findall(r'kernel_name = "moe_sum"', lowered)) == 2
-    assert len(re.findall(r"func\.func private @_sum_held", lowered)) == 2
-    assert len(re.findall(r"call @_sum_held", lowered)) == 8
-    assert _calls(step.compiled.as_text(), "moe_sum") == 8

@@ -642,6 +642,66 @@ def test_the_lowered_step_makes_no_expert_rows_again():
         rf"stablehlo\.gather[^\n]*-> tensor<{slot_rows}x128x", text)) == 5
 
 
+# ------------------------------------ the step's account of its routed layers
+
+@pytest.mark.parametrize("rows", [0, 15, 16, 17, 32, 33])
+def test_the_host_s_account_walks_the_chunks_the_layer_walks(rows):
+    """`layer_steps` (on the host, `numpy`) and `_chunks` (in the step) round
+    a layer's held rows to chunks by one expression; the account counts a
+    chunk at least, a layer's buffers once even where it held no row."""
+    chunk = 16
+    slots = moe.Slots(jnp.arange(40), jnp.arange(40),
+                      jnp.array([rows // 3, rows - rows // 3]))
+    order, in_the_step = moe._chunks(slots, chunk)
+    assert order.shape == (48,) and int(in_the_step) == -(-rows // chunk)
+    load = np.array([[[rows, 40 - rows, 0, 0]]])  # [S, layers, E]
+    sums, of_steps = moe.layer_steps(
+        load, np.array([[[rows + 2]]]), np.array([[[2]]]), chunk)
+    chunks = max(1, int(in_the_step))
+    assert of_steps == [[[chunks], [rows]]]
+    assert sums == {
+        "moe.layer_steps": 1, "moe.fullest_expert_slots": max(rows, 40 - rows),
+        "moe.even_expert_slots": 10.0, "moe.held_slots": rows + 2,
+        "moe.dropped_slots": 2, "moe.held_rows": rows,
+        "moe.buffer_rows": chunks * chunk,
+        "moe.extra_chunk_layer_steps": int(chunks > 1)}
+    assert all(type(n) in (int, float) for n in sums.values())
+
+
+def test_over_an_expert_axis_the_account_takes_the_fullest_device():
+    """Two steps of two layers on three devices: every device's buffers are
+    paid for, a layer-step's chunks are its fullest device's, and without a
+    share there is nothing of one."""
+    held = np.array([[[16, 17, 0], [5, 6, 7]], [[33, 1, 1], [16, 16, 16]]])
+    load = np.ones((2, 2, 6), np.int32)
+    ratio = np.array([[1.5, 1.0], [2.0, 1.5]], np.float32)
+    sums, of_steps = moe.layer_steps(
+        load, held, np.zeros_like(held), 16, chip_load_max_over_mean=ratio)
+    assert of_steps == [[[2, 1], [17, 7]], [[3, 1], [33, 16]]]
+    assert sums["moe.extra_chunk_layer_steps"] == 2
+    assert sums["moe.buffer_rows"] == 16 * ((1 + 2 + 1) + 3 + (3 + 1 + 1) + 3)
+    assert sums["moe.held_rows"] == sums["moe.held_slots"] == held.sum()
+    assert sums["moe.chip_load_max_over_mean_sum"] == 1.25 + 1.75
+    whole, none = moe.layer_steps(load)
+    assert none == [] and whole == {
+        "moe.layer_steps": 4, "moe.fullest_expert_slots": 4,
+        "moe.even_expert_slots": 4.0}
+
+
+def test_buffer_rows_is_held_chunk_of_the_rows_routed_together():
+    """One recipe for the layer, the step and the rule: a share's, and a
+    device's of an `expert` axis for the rows and sequences of all of it
+    (`mellum2.ep4`: four chips of 16,384 tokens each, 8 of 64 experts a
+    token, PERF.md section 6, PR 50)."""
+    numbers = dict(experts_per_token=8, n_experts=64, load_held_even=False)
+    assert moe.buffer_rows(4 * 16384, 4, 4, held=64, **numbers) == 180224
+    assert moe.buffer_rows(16384, 1, held=8, **numbers) == moe.held_chunk(
+        131072, 8, 64, load_held_even=False, sequences=1)
+    assert moe.buffer_rows(
+        16384, 16, held=8, experts_per_token=8, n_experts=64,
+        load_held_even=True) == moe.held_chunk(131072, 8, 64) == 20480
+
+
 def test_flops_count_active_parameters():
     cfg = TransformerConfig(
         vocab_size=50304, d_model=2048, n_layers=1, n_heads=16, n_kv_heads=16,

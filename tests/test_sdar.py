@@ -364,9 +364,13 @@ def test_the_step_trains_and_the_account_counts():
     assert losses[2] < losses[0] and all(map(math.isfinite, losses))
     assert int(out["diffusion_tokens"]) == 64 and int(out["diffusion_rows"]) == 128
     assert step.static["held_chunk"] > 0
+    assert {r.name: r.over_steps for r in model._DIFFUSION_READINGS} == {
+        "diffusion_" + what: "diffusion." + what
+        for what in ("tokens", "masked_tokens", "weight_sum", "rows")}
     before = tracing.counters()
     jax.block_until_ready(out)
-    _runtime._fold_steps(tracing.take_steps())
+    _, _, named = _runtime._fold_steps(tracing.take_steps())
+    assert {"diffusion.tokens", "moe.held_rows"} <= named
     after = tracing.counters()
     count = lambda name: after[name] - before.get(name, 0)  # noqa: E731
     assert count("diffusion.tokens") == 3 * 64

@@ -440,7 +440,7 @@ def test_no_span_is_lost_while_the_table_is_read():
     assert seen == sorted(seen)  # a reading never goes back
 
 
-# --- the step accounts for itself: `tracing.Step`, `_runtime._fold_steps` ---
+# --- the step accounts for itself: `tracing.Step`, its `tracing.Account` ---
 
 ROUTED = dict(
     vocab_size=128, d_model=32, n_layers=4, n_heads=4, n_kv_heads=2, d_ff=16,
@@ -522,7 +522,8 @@ def _moe(counters):
 
 
 def _by_hand(outs, chunk):
-    """The counters `_fold_steps` keeps, summed from steps' outputs."""
+    """The counters the step's account names (`ops/moe.py` `layer_steps`),
+    summed from steps' outputs."""
     import numpy as np
 
     want = dict.fromkeys((
@@ -579,6 +580,8 @@ def test_the_step_is_the_jitted_function_under_a_span(jobs, cell):
     share = cell != "all_held"
     assert set(job.asked) == ({step.static["held_chunk"]} if share else set())
     assert set(step.static) == ({"held_chunk"} if share else set())
+    from ray_tpu.models import transformer
+    assert step.account is transformer._STEP_ACCOUNT
 
 
 @pytest.mark.parametrize("cell", ROUTED_CELLS)
@@ -612,7 +615,12 @@ def test_a_report_folds_the_steps_readings_into_the_counters(jobs, cell):
     # what no counter sums, as the last step left it
     assert block["readings"]["grad_norm"] == pytest.approx(
         float(outs[-1]["grad_norm"]))
-    assert not set(block["readings"]) & set(_runtime._SUMMED)
+    # ... and what the step's account takes whole is not shown
+    taken = {name for name, shown in job.step.account.reads.items()
+             if not shown}
+    assert taken == {"expert_load", "held_slots", "dropped_slots",
+                     "chip_load_max_over_mean"}
+    assert not set(block["readings"]) & taken
     json.dumps(block)  # numbers and lists: a report's metrics
 
 
@@ -721,6 +729,179 @@ def test_a_slow_interval_says_what_its_steps_routed(jobs):
     session.report({"i": 7})
     (record,) = _slow_events()
     want = _by_hand(outs, job.step.static["held_chunk"])
-    assert record["moe"] == pytest.approx(
+    assert record["steps"] == pytest.approx(
         {k: v for k, v in want.items() if v and k.startswith("moe.")})
     assert record["spans"]["train.step"][0] == 2
+
+
+# --- the runtime folds by the step's own account, and knows no model ---
+
+def _step_of(readings_of, account=None):
+    """A `tracing.Step` whose `i`-th call reads `readings_of(i)`."""
+    import jax
+
+    jitted = jax.jit(lambda i, _: (i + 1, readings_of(i)))
+    return tracing.Step(jitted, {"scale": 10}, account)
+
+
+def test_a_step_is_folded_by_its_own_account_and_one_without_by_none():
+    import jax.numpy as jnp
+
+    def fold(static, stacked):
+        assert set(stacked) == {"x", "y"}  # those of `reads` the steps made
+        assert stacked["x"].shape == (3, 2)
+        return ({"x.sum": stacked["x"].sum().item() * static["scale"]},
+                [[y] for y in stacked["y"].tolist()])
+
+    mine = tracing.Account({"x": True, "y": False, "absent": False}, fold)
+    step = _step_of(lambda i: {
+        "loss": 1.0 / (i + 1), "x": jnp.stack([i, 2 * i]), "y": i + 0.5}, mine)
+    bare = _step_of(lambda i: {"loss": 3.0 - i, "x": i})
+    account = _runtime.RuntimeAccount()
+    before = tracing.counters()
+    state = 1
+    for _ in range(3):
+        state, _ = step(state, 0)
+    block = account.block()
+    assert block["counters"]["x.sum"] == (1 + 2 + 3) * 3 * 10
+    assert block["counters"]["train.steps_read"] == 3
+    # `y` is the account's whole; `x` is summed and the last one's shown
+    assert block["readings"] == {"loss": pytest.approx(1 / 4), "x": [3, 6]}
+    assert block["steps"] == [[1, 1.5], [2, 2.5], [3, 3.5]]
+    assert account._accounted == {"x.sum"}
+    for _ in range(2):
+        bare(5, 0)
+    block = account.block()
+    assert block["counters"]["train.steps_read"] == 5
+    assert block["counters"]["x.sum"] == 180  # a reading's name is nothing
+    assert block["readings"] == {"loss": -2.0, "x": 5}
+    assert len(block["steps"]) == 3
+    assert set(tracing.counters()) - set(before) <= {
+        "x.sum", "train.steps_read"}
+
+
+def test_the_runtime_names_no_reading_and_no_counter_of_a_model():
+    """`ray_tpu/train/` folds by what a step hands it: no name of a model's
+    readings outside prose, no import of the model or the kernels."""
+    import ast
+    import glob
+    import io
+    import tokenize
+
+    path = os.path.join(ROOT, "ray_tpu", "train", "_runtime.py")
+    with open(path) as f:
+        source = f.read()
+    docstrings = {
+        (node.body[0].lineno, node.body[0].col_offset)
+        for node in ast.walk(ast.parse(source))
+        if isinstance(node, (ast.Module, ast.FunctionDef, ast.ClassDef))
+        and ast.get_docstring(node, clean=False) is not None}
+    code = [tok.string for tok in tokenize.generate_tokens(
+        io.StringIO(source).readline)
+        if tok.type != tokenize.COMMENT and tok.start not in docstrings]
+    for word in ("expert", "moe", "diffusion", "held"):
+        assert not [t for t in code if word in t.lower()], word
+    for module in glob.glob(
+            os.path.join(ROOT, "ray_tpu", "train", "**", "*.py"),
+            recursive=True):
+        with open(module) as f:
+            for node in ast.walk(ast.parse(f.read())):
+                names = ([a.name for a in node.names]
+                         if isinstance(node, ast.Import) else
+                         [node.module or ""]
+                         if isinstance(node, ast.ImportFrom) else [])
+                for name in names:
+                    assert not name.startswith(
+                        ("ray_tpu.models", "ray_tpu.ops")), (module, name)
+
+
+# What PR 71's `_fold_steps` (the parent's, which named the readings itself)
+# made of these very readings: three steps, numbered 7 to 9, of three routed
+# layers over four experts with buffers of 16 rows, held rows of none, one
+# under, at and one over a whole chunk, and three slots dropped.
+_HELD = [[[0, 15], [16, 17], [33, 5]], [[15, 15], [16, 0], [32, 31]],
+         [[17, 16], [1, 0], [48, 49]]]
+_LOAD = [[[5, 3, 9, 7], [6, 6, 6, 6], [0, 12, 1, 11]],
+         [[4, 4, 8, 8], [2, 10, 6, 6], [7, 5, 9, 3]],
+         [[6, 7, 5, 6], [1, 1, 11, 11], [12, 0, 0, 12]]]
+_ROUTED = {"train.steps_read": 3, "moe.layer_steps": 9,
+           "moe.fullest_expert_slots": 84, "moe.even_expert_slots": 54.0}
+_LAST = {"loss": 2.0, "grad_norm": 1.25, "aux_loss": 0.25}
+PARENT_BLOCKS = {
+    "expert_axis": {
+        "counters": {
+            **_ROUTED, "moe.chip_load_max_over_mean_sum": 3.527777910232544,
+            "moe.held_slots": 326, "moe.held_rows": 323,
+            "moe.dropped_slots": 3, "moe.buffer_rows": 464,
+            "moe.extra_chunk_layer_steps": 5},
+        "readings": {**_LAST, "chip_load": [[13, 11], [2, 22], [12, 12]]},
+        "steps": [[7, [1, 2, 3], [15, 17, 33]], [8, [1, 1, 2], [15, 16, 32]],
+                  [9, [2, 1, 4], [17, 1, 49]]]},
+    "share_under_block_diffusion": {
+        "counters": {
+            **_ROUTED, "moe.held_slots": 178, "moe.held_rows": 175,
+            "moe.dropped_slots": 3, "moe.buffer_rows": 240,
+            "moe.extra_chunk_layer_steps": 4, "diffusion.tokens": 192,
+            "diffusion.masked_tokens": 93, "diffusion.weight_sum": 183.0,
+            "diffusion.rows": 384},
+        "readings": {
+            **_LAST, "diffusion_tokens": 64, "diffusion_masked_tokens": 32,
+            "diffusion_weight_sum": 61.5, "diffusion_rows": 128},
+        "steps": [[7, [1, 1, 3], [0, 16, 33]], [8, [1, 1, 2], [15, 16, 29]],
+                  [9, [2, 1, 3], [17, 1, 48]]]},
+}
+
+
+def _parent_s_readings(cell, s):
+    """Step `s`'s readings, as PR 72's session gave them to the parent's
+    `_fold_steps` to take `PARENT_BLOCKS`."""
+    import numpy as np
+
+    held, load = np.array(_HELD, np.int32), np.array(_LOAD, np.int32)
+    dropped = np.zeros_like(held)
+    dropped[1, 2, 1] = 3
+    readings = {
+        "loss": np.float32(2.5 - s / 4), "grad_norm": np.float32(1 + s / 8),
+        "aux_loss": np.float32(0.125 * s), "expert_load": load[s]}
+    if cell == "expert_axis":
+        chip = load.reshape(3, 3, 2, 2).sum(-1)[s]
+        readings.update(
+            held_slots=held[s], dropped_slots=dropped[s], chip_load=chip,
+            chip_load_max_over_mean=(
+                chip.max(-1) / chip.mean(-1)).astype(np.float32))
+    else:
+        readings.update(
+            held_slots=held[s, :, 0], dropped_slots=dropped[s, :, 1],
+            diffusion_tokens=np.int32(64),
+            diffusion_masked_tokens=np.int32(30 + s),
+            diffusion_weight_sum=np.float32(60.5 + s / 2),
+            diffusion_rows=np.int32(128))
+    return readings
+
+
+@pytest.mark.parametrize("cell", PARENT_BLOCKS)
+def test_the_model_s_account_makes_the_block_the_runtime_s_own_made(cell):
+    import jax
+
+    from ray_tpu.models import transformer
+
+    step = tracing.Step(
+        jax.jit(lambda s, readings: (s, readings)), {"held_chunk": 16},
+        transformer._STEP_ACCOUNT)
+    step.calls = 6
+    account = _runtime.RuntimeAccount()
+    before = tracing.counters()
+    for s in range(3):
+        step(s, _parent_s_readings(cell, s))
+    block = account.block()
+    want = PARENT_BLOCKS[cell]
+    risen = {k: v - before.get(k, 0) for k, v in tracing.counters().items()
+             if k.startswith(("moe.", "diffusion.", "train.steps_read"))}
+    risen = {k: v for k, v in risen.items() if v}
+    assert risen == want["counters"]
+    assert {k: type(v) for k, v in risen.items()} == {
+        k: type(v) for k, v in want["counters"].items()}
+    assert block["readings"] == want["readings"]
+    assert block["steps"] == want["steps"]
+    assert account._accounted == set(want["counters"]) - {"train.steps_read"}
+    json.dumps(block)

@@ -271,6 +271,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import logging
 import math
 from dataclasses import dataclass, field
@@ -2640,11 +2641,14 @@ class _Mamba1(Sublayer):
                 + inner * (2 * R + 2 * cfg.mamba1_state))
 
     def holds(self, cfg):
-        """In elements of the compute dtype a token: the convolution's sum,
-        its silu and that one's cotangent, the gated output and the gate's
-        cotangent (5 inner), `w_x`'s product and its cotangent; in float32
-        the step size before and after its softplus, the scan's output and
-        the two cotangents the scan returns at that width (5 inner); a
+        """In elements of the compute dtype a token, as the compiler's plan
+        for `phi4flash.tokens16k` holds them at the backward scan (a
+        described v5e, PR 73: 2.01 GB at 16,384 tokens where ten arrays of
+        `inner`, five of them float32, priced 2.58): u and the gate apart
+        from `w_in`'s product, the convolution's silu, the scan's du and
+        two cotangents of `w_out`'s (6 inner), `w_x`'s product and its
+        cotangent; in float32 the convolution's sum, the scan's d delta and
+        the gated output's cotangent (3 inner); a
         chunk's entering state; and by the path `selective_scan` takes
         (`ops/selective_scan.py`): `jax.numpy`, spread over a sequence's
         tokens, the steps of the one chunk the backward makes again (the
@@ -2658,7 +2662,7 @@ class _Mamba1(Sublayer):
             steps = 2 * N * (inner // channel_block(inner))
         else:
             steps = 3 * cfg.scan_chunk * N * inner // cfg.max_seq_len
-        return (5 * inner + 2 * (cfg.dt_rank + 2 * N) + 5 * f32 * inner
+        return (6 * inner + 2 * (cfg.dt_rank + 2 * N) + 3 * f32 * inner
                 + f32 * (N * inner // cfg.scan_chunk + steps))
 
 
@@ -2985,28 +2989,41 @@ class _BlockDiffusionAttention(_PlainAttention):
         71); by the `jax.numpy` lines the own block's scores and weights,
         `diffusion_block` float32 values a head each, and the two parts' o
         with their cotangents beside the joined one's, the own block's in
-        float32; and, over a share of
-        the experts, the held rows' buffers of a sequence's `held_chunk`
-        rows (two of the stream's width and the feed-forward's products),
-        spread over the sequence's rows: no record prices them
-        (`_RoutedFF.holds` of a share is 0, and `_SparseAttention.holds`
-        stands a byte a key for them), and two rows a token double them,
-        1.36 GB at 32,768 rows over 16 of 128 experts. Without them the rule
-        kept `attn_qkv` too and the compiler's plan for a described v5e
-        stood at 15.99 GB of the 16.91 it offers a program, fitted by
-        fusions it made again (PERF.md section 6, PR 70)."""
+        float32; or, if they are larger, over a share of the experts the
+        held rows' buffers of a sequence's `held_chunk` rows (two of the
+        stream's width and the feed-forward's products) and the rows'
+        float32 sum, spread over the sequence's rows: no record prices them
+        in a scanned stack (`_RoutedFF.holds` of a share is 0, and
+        `_SparseAttention.holds` stands a byte a key for them), two rows a
+        token double them, 1.36 and 0.27 GB at 32,768 rows over 16 of 128
+        experts, and they are the feed-forward's backward's, where the
+        join's four arrays are gone: the plan's fullest position is there
+        (PR 73). And what the compiler holds for a scanned stack of these
+        layers beside the one the block has: the other layers' weights in
+        the compute dtype, hoisted out of the loop (0.74 GB for four layers
+        in `sdar.tokens16k`'s plan). The sum stands 0.91 GB over the chip
+        that way (1.15 before) and refuses `attn_qkv` by 0.05 GB, which is
+        what the chip wants: at PR 70 the plan with the name stood at 15.99
+        GB of the 16.91 a v5e offers a program, fitted by fusions the
+        compiler made again; since PR 71's kernels it is 15.24 GB with
+        none, the chip holds 15.18, and the step is 2.0 % slower than the
+        one that makes q, k and v again (PERF.md section 6, PRs 70 and
+        73)."""
         h, dh, item = self.heads(cfg), cfg.head_dim, _item(cfg)
+        rows = cfg.rows_per_token * cfg.max_seq_len
         buffers = 0
         if cfg.n_experts and cfg.held[1] < cfg.n_experts:
-            rows = cfg.rows_per_token * cfg.max_seq_len
-            buffers = _buffer_rows(cfg, rows, 1) * (
-                2 * cfg.d_model + cfg.ff_matrices * cfg.ff_dim) // rows
+            buffers = (_held_buffer_bytes(cfg, rows, 1) // (rows * item)
+                       + cfg.d_model * 4 // item)
         if _bd_own_join_kernels(cfg, cfg.max_seq_len):
             round_the_join = h * dh * 4
         else:
             round_the_join = (2 * cfg.diffusion_block * h * 4 // item
                               + h * dh * (3 + 2 * 4 // item))
-        return super().holds(cfg) + round_the_join + buffers
+        ff = _FEED_FORWARDS["routed_ff" if cfg.n_experts else "dense_ff"]
+        hoisted = ((cfg.n_layers - 1) * (self.params(cfg) + ff.params(cfg))
+                   // rows)
+        return super().holds(cfg) + max(round_the_join, buffers) + hoisted
 
     def flops(self, cfg, seq_len):
         # a token's operations: both of its rows through the projections,
@@ -3188,9 +3205,14 @@ class _RoutedFF(Sublayer):
                 + (mats * d * cfg.shared_dim if cfg.n_shared_experts else 0))
 
     def holds(self, cfg):
-        """The dispatched rows and the experts' hidden product, where the
-        layer holds every expert; the shared experts' hidden product."""
-        rows = (cfg.experts_per_token * (cfg.d_model + cfg.ff_dim)
+        """The dispatched rows, their cotangent and the experts' hidden
+        product, where the layer holds every expert (`olmoe.tokens4k`'s plan
+        has four arrays of the dispatched rows' size in the experts'
+        backward and none of attention's cotangents beside them); the shared
+        experts' hidden product. A share's held rows' buffers are the
+        layer's frame's (`_terms`, `_held_buffer_bytes`), not a width a
+        token."""
+        rows = (cfg.experts_per_token * (2 * cfg.d_model + cfg.ff_dim)
                 if cfg.held[1] == cfg.n_experts else 0)
         return rows + (cfg.shared_dim if cfg.n_shared_experts else 0)
 
@@ -4269,6 +4291,10 @@ if _UNRANKED:  # the rule would pass such a name by and never keep it
         f"a sublayer makes the names {sorted(_UNRANKED)}, which "
         "_SAVE_ORDER does not rank")
 _SAVE_RESERVE = 1 << 30  # the step stays this far under the device's limit
+# what a program holds beside its heap: the compiler's scratch of its own
+# (128 MiB in every plan for a v5e) and its code, a layer that is inlined
+_PROGRAM_BYTES = 128 << 20
+_LAYER_CODE_BYTES = 32 << 20
 
 
 def _kept(cfg: TransformerConfig, saved_names) -> Dict[str, int]:
@@ -4307,12 +4333,19 @@ def _exchange_bytes(cfg: TransformerConfig, tokens: int, ways: int) -> int:
     plan: PERF.md section 6, "Keep rule: `_exchange_bytes`")."""
     if ways == 1 or not cfg.n_routed_layers:
         return 0
-    item, d = _item(cfg), cfg.d_model
     rows = ways * tokens
-    chunk = _buffer_rows(cfg, rows, ways * max(
+    return (rows * cfg.d_model * (_item(cfg) + 2 * 4)
+            + _held_buffer_bytes(cfg, tokens, ways))
+
+
+def _held_buffer_bytes(cfg: TransformerConfig, tokens: int, ways: int) -> int:
+    """A share's held rows' buffers of `held_chunk` rows, of the rows of
+    `ways` devices of `tokens` tokens each: two of the stream's width and
+    the feed-forward's products."""
+    chunk = _buffer_rows(cfg, ways * tokens, ways * max(
         1, tokens // (cfg.rows_per_token * cfg.max_seq_len)), ways)
-    return (rows * d * (item + 2 * 4)
-            + chunk * (2 * d + cfg.ff_matrices * cfg.ff_dim) * item)
+    return (chunk * (2 * cfg.d_model + cfg.ff_matrices * cfg.ff_dim)
+            * _item(cfg))
 
 
 @lru_cache(maxsize=None)
@@ -4384,15 +4417,21 @@ class _Terms(NamedTuple):
 
     The condition the terms must keep: they err to the full side. A name
     too few costs a percent; a step that asks for the chip's last GiB is
-    compiled to fit and runs slower than the one that keeps nothing. The
-    sums against the chips' peaks and the compiler's plans: PERF.md section
-    6, "Keep rule: a scanned stack's sum" and "Keep rule: the moments"."""
+    compiled to fit and runs slower than the one that keeps nothing. How
+    far to the full side: for the choice the rule makes the sum of the state
+    and the fullest moment stands at or over the chip's peak and no more
+    than 1 GB over it in every token cell, and no less than the compiler's
+    plan less 0.2 GB (`tests/test_saved_activations.py` `CHIP_PEAK_GB`,
+    `tests/test_step_compile.py`). The sums against the chips' peaks and
+    the compiler's plans: PERF.md section 6, "Keep rule: a scanned stack's
+    sum" and "Keep rule: the moments" (PR 73 read the walked layers' terms
+    off the plans of eleven cells at once)."""
     passes: int  # `loop_steps`
     names: Dict[str, int]  # {name: all the layers' bytes of it a pass}
     made: Tuple[Dict[str, int], ...]  # a layer each: {name: its bytes a pass}
     # the step's moments in the backward's order, each (its name, what a
-    # device holds then beside its state and the kept names, how many of the
-    # first layers' kept names it holds)
+    # device holds then beside its state and the kept names of other layers,
+    # how many of the first layers' kept names it holds beside that)
     frames: Tuple[Tuple[str, int, int], ...]
     boundaries: int  # `_boundary_bytes`
     head: int  # `_head_bytes`
@@ -4455,18 +4494,40 @@ def _terms(cfg: TransformerConfig, tokens: int,
     layer's gradient is a buffer of its own, made when the backward reaches
     the layer, and since the step clips nothing AdamW's update of a weight
     follows its gradient at once. The backward walks from the last layer to
-    the first, so layer i's moment holds the blocks' inputs, the kept names
-    of the layers before and at it, its own block at its own widths, the
-    head's gradient (made first, and live to the end), the gradients of the
-    scanned segments behind it, and of its own gradient what a loop
-    accumulates: a share's held experts', float32, live from the first
-    chunk of held rows to the last (where the parameters are sharded the
-    block has the whole gradient already).
+    the first, so layer i's moment holds the blocks' inputs; the kept names
+    of the layers before it; its own block (below), which has the layer's
+    own names at their widths whether they were kept or are made again: a
+    kept name is a residual the backward reads where it would have made it,
+    live once; the head's gradient (made first, and live to the end); the
+    gradients of the scanned segments behind it; of the gradients of the
+    layers no scan stacks what a loop accumulates: a share's held experts',
+    float32, live from the first chunk of held rows, its own and those of
+    the routed layers behind it, which may wait for the optimizer to the
+    step's end (they do in `solaropen2.tokens8k`'s plan, for two layers in
+    `lagunaxs2.tokens8k`'s, not in `lfm2moe.tokens8k`'s; where the
+    parameters are sharded the block has the whole gradient already); and
+    the program's own beside its heap (`_PROGRAM_BYTES`, and a layer's
+    code, twice where it routes: 27 to 59 MB a layer in the plans).
+
+    A walked layer's block is the larger of its sublayers' backward
+    moments, the feed-forward's and then the operator's: a sublayer's
+    moment has the names made up to it and what it alone holds
+    (`Sublayer.holds`), a share's feed-forward's on one device also its
+    held rows' buffers and their float32 sum (an `expert` axis' exchange
+    has both). The operator's cotangents are not live in the
+    feed-forward's backward, nor the feed-forward's products in the
+    operator's (the plans of `phi4flash.tokens16k` and `evabyte.tokens8k`;
+    of what EVA's backward holds 0.67 of 1.09 GB is made again ahead of
+    the feed-forward's backward, and the sum still stands over that plan).
+    A scanned segment goes on counting both sublayers at once: its sums
+    stand within 0.1 GB of the chips' peaks that way.
 
     The head's moment has every kept name and every gradient but those of
     the layers no scan stacks; the optimizer's has every gradient and no
     kept name. (PERF.md section 6, "Keep rule: the moments" and "Keep rule:
-    a looped stack's weights", has the plans these were read off.)"""
+    a looped stack's weights", has the plans these were read off: PR 54's
+    of five cells, PR 58's, and PR 73's of every cell with a walked
+    segment, with today's names and with the names the rule now adds.)"""
     whole = _whole_param_bytes(cfg)
     if param_bytes is None:
         param_bytes = whole
@@ -4482,7 +4543,7 @@ def _terms(cfg: TransformerConfig, tokens: int,
             cfg, experts_held=(0, cfg.n_experts // expert_ways))
     on_device = _whole_param_bytes(cfg)
     item, d = _item(cfg), cfg.d_model
-    names, params, block = {}, {}, {}  # of a layer, by its kind
+    names, params, block, walked = {}, {}, {}, {}  # of a layer, by its kind
     for kind in dict.fromkeys(cfg.layers):
         widths, params[kind] = _layer_widths(cfg, kind)
         names[kind] = {name: tokens * item * w for name, w in widths.items()}
@@ -4494,11 +4555,21 @@ def _terms(cfg: TransformerConfig, tokens: int,
         # whole and their float32 gradient before it is scattered; a routed
         # block on an `expert` axis with its exchange
         subs = _sublayers(kind)
-        width = (sum(widths.values()) + (len(subs) + 1) * d
-                 + sum(sub.holds(cfg) for sub in subs))
-        block[kind] = (tokens * width * item + params[kind] * item
-                       + (params[kind] * (item + 4) if sharded else 0)
-                       + (exchange if kind.routed else 0))
+        held = [sub.holds(cfg) for sub in subs]
+        rest = (tokens * (len(subs) + 1) * d * item + params[kind] * item
+                + (params[kind] * (item + 4) if sharded else 0)
+                + (exchange if kind.routed else 0))
+        block[kind] = tokens * (sum(widths.values()) + sum(held)) * item + rest
+        # where no scan stacks the layer: the larger of its sublayers'
+        # backward moments, each the names made up to it and what it alone
+        # holds; a share's feed-forward's on one device with its held rows'
+        # buffers and their float32 sum (an axis' exchange has both)
+        made = itertools.accumulate(
+            sum(sub.widths(cfg).values()) for sub in subs)
+        moments = [tokens * (m + h) * item for m, h in zip(made, held)]
+        if kind.routed and expert_ways == 1 and cfg.held[1] < cfg.n_experts:
+            moments[-1] += _held_buffer_bytes(cfg, tokens, 1) + tokens * d * 4
+        walked[kind] = max(moments) + rest
 
     def gradient(kind: LayerKind) -> int:
         return 4 * params[kind] * param_bytes // on_device
@@ -4520,7 +4591,11 @@ def _terms(cfg: TransformerConfig, tokens: int,
     frames = [("optimizer", param_bytes, 0),
               ("head", boundaries + head + param_bytes - inlined,
                cfg.n_layers)]
-    first, behind = cfg.n_layers, 0  # walked from the last layer back
+    # the program's own beside its heap, where its layers are inlined
+    program = _PROGRAM_BYTES + _LAYER_CODE_BYTES * sum(
+        1 + kind.routed for seg, scan in segs if not scan
+        for kind in seg.layout)
+    first, behind, waiting = cfg.n_layers, 0, 0  # from the last layer back
     for seg, scan in reversed(segs):
         layers = len(seg.layout) * seg.periods
         first -= layers
@@ -4531,10 +4606,10 @@ def _terms(cfg: TransformerConfig, tokens: int,
             continue
         for i in reversed(range(layers)):
             kind = seg.layout[i]
+            waiting += accumulated if kind.routed else 0
             frames.append(("layer %d" % (first + i),
-                           boundaries + unembed + behind + block[kind]
-                           + (accumulated if kind.routed else 0),
-                           first + i + 1))
+                           boundaries + unembed + behind + walked[kind]
+                           + waiting + program, first + i))
     made = tuple(names[kind] for kind in cfg.layers)
     return _Terms(
         cfg.loop_steps,

@@ -335,7 +335,11 @@ def test_param_shardings_of_the_new_leaves():
         sharding.shard_shape(leaf.shape)  # every cut divides its dimension
 
 
-def test_saved_activations_know_the_new_layer():
+def test_saved_activations_know_the_new_layer(monkeypatch):
+    # a program's own 160 MiB would be all there is to a model of this size
+    monkeypatch.setattr(model, "_PROGRAM_BYTES", 0)
+    monkeypatch.setattr(model, "_LAYER_CODE_BYTES", 0)
+    model._terms.cache_clear()
     cfg = tiny(dtype=jnp.bfloat16, remat=True)
     tokens = 4 * 64
     state = 12 * sum(x.size for x in jax.tree.leaves(jax.eval_shape(
@@ -359,10 +363,11 @@ def test_saved_activations_know_the_new_layer():
     assert saved_activations(*args, 1 << 20) == {}  # no room
     # all the room: every name, at the stack's one pass
     assert saved_activations(*args, 1 << 40) == dict.fromkeys(sizes, 1)
+    two = {"attn_ctx": 1, "attn_res": 1}
     chosen = saved_activations(
-        *args, state + state // 3 + model._SAVE_RESERVE + terms.at_once
-        + sizes["attn_ctx"] + sizes["attn_res"])
-    assert list(chosen) == ["attn_ctx", "attn_res"]
+        *args, state + model._SAVE_RESERVE + terms.fullest(two).bytes)
+    assert chosen == two
+    model._terms.cache_clear()
 
 
 def test_a_step_trains_and_reports_its_readings():

@@ -130,7 +130,7 @@ def test_the_plan_fits_under_the_rule_s_sum(step):
     assert 0.75 * 16.91e9 < plan <= HBM_BYTES - 0.05e9
     assert plan <= rule_sum <= CHIP_LIMIT - tr._SAVE_RESERVE
     assert list(kept) == ["attn_ctx", "eva_summaries", "attn_res",
-                          "attn_qkv", "mlp_gate"]
+                          "attn_qkv", "mlp_gate", "mlp_up"]  # every name
     assert kept["eva_summaries"] == 4 * 2 * 512 * 4096 * 2
 
 

@@ -125,7 +125,9 @@ def test_the_two_mamba_layers_scan_by_the_kernels(step):
     """`selective_scan_fwd` twice and `selective_scan_bwd` twice, a layer
     each: `scan_out` is kept, its entering states with it, so the block
     made again runs no second forward scan; and the plan stands no higher
-    than with the `jax.numpy` scan."""
+    than with the `jax.numpy` scan, the names set aside that the rule keeps
+    since its sum was set right (PR 73: `mamba1_in` and `gmu_in`, 0.84
+    GB)."""
     compiled, kept, _ = step
     assert "scan_out" in kept
     text = compiled.as_text()
@@ -133,5 +135,11 @@ def test_the_two_mamba_layers_scan_by_the_kernels(step):
              for name in ("selective_scan_fwd", "selective_scan_bwd")}
     assert calls == {"selective_scan_fwd": 2, "selective_scan_bwd": 2}
     memory = compiled.memory_analysis()
-    assert memory.peak_memory_in_bytes <= PLAN_BEFORE_THE_KERNELS["peak"]
-    assert memory.temp_size_in_bytes <= PLAN_BEFORE_THE_KERNELS["temp"]
+    since = kept["mamba1_in"] + kept["gmu_in"]
+    assert since == 5 * 16384 * 5120 * 2
+    assert memory.peak_memory_in_bytes - since <= 1.01 * (
+        PLAN_BEFORE_THE_KERNELS["peak"])
+    # (its scratch grows by 1.15 GB for them: with more held through the
+    # backward the compiler schedules the feed-forwards' products apart)
+    assert memory.temp_size_in_bytes - since <= (
+        PLAN_BEFORE_THE_KERNELS["temp"] + 0.35e9)

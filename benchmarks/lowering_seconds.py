@@ -19,6 +19,9 @@ compiler plans for the chip: all its bytes and the scratch among them, the
 `.remat` fusions it made to fit, the seconds of the compile; DIR takes XLA's
 dump of the step's module, whose `*buffer-assignment.txt` and
 `*memory-usage-report.txt` name every buffer (PERF.md section 7, PR 58).
+`--keep none` (or `--keep attn_ctx,attn_res`) takes the keep rule's place
+with that choice of names: the plan of the step that keeps nothing is what
+a new cell is sized by before anything else is built on it (PR 74).
 """
 
 import argparse
@@ -41,6 +44,9 @@ def main() -> None:
     parser.add_argument("--repeat", type=int, default=3)
     parser.add_argument("--plan", metavar="DIR", help="compile the step too "
                         "and dump its module's buffer assignment here")
+    parser.add_argument("--keep", default="rule", help="the names a "
+                        "rematerialised block keeps: the rule's own, 'none' "
+                        "or names with commas between")
     args = parser.parse_args()
     root = os.path.abspath(args.tree)
     sys.path.insert(0, root)
@@ -60,6 +66,10 @@ def main() -> None:
         platform="tpu", topology_name="v5e:2x2").devices
     # a described device reports no limit; the chip's goes to the keep rule
     transformer._memory_limit = lambda mesh: 16_910_000_000
+    if args.keep != "rule":  # in the rule's place, for what a plan would be
+        kept = {} if args.keep == "none" else dict.fromkeys(
+            args.keep.split(","), 1)
+        transformer.saved_activations = lambda *a, **kw: dict(kept)
 
     def described(shapes, shardings):
         if isinstance(shardings, jax.sharding.Sharding):  # one for the lot
@@ -81,6 +91,7 @@ def main() -> None:
         blank = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
         print("LOWERED " + json.dumps({
             "tree": args.tree, "cell": cell, "program": program,
+            "keep": args.keep,
             "least_s": min(seconds), "seconds": seconds,
             "payloads": dict(sorted(kernels.items())),
             "sha256": hashlib.sha256(blank.encode()).hexdigest()[:16],

@@ -3,7 +3,7 @@
 convolutions and its output norm and gate, alone on the chip:
 `ops/mamba_passes.py`'s kernels against its `jax.numpy` paths.
 
-    python3 benchmarks/mamba_passes_alone.py [--shapes cell,probe,kimi,solar] [--passes conv,norm,kda_conv,kda_out_norm] [--paths xla,pallas] [--conv-blocks 512x512x32,...] [--norm-blocks 256x32,...] [--out-norm-blocks 512x1024x32,...] [--seed 0]
+    python3 benchmarks/mamba_passes_alone.py [--shapes cell,probe,granite,kimi,solar] [--passes conv,norm,kda_conv,kda_out_norm] [--paths xla,pallas] [--conv-blocks 512x512x32,...] [--norm-blocks 256x32,...] [--out-norm-blocks 512x1024x32,...] [--seed 0]
 
 At each shape (`cell`: the 2 x 8,192 tokens a mixer of
 `nemotron3nano.tokens8k` hands them, the convolution over 6,144 channels of 4
@@ -45,6 +45,8 @@ from ray_tpu.ops import mamba_passes as lib  # noqa: E402
 SHAPES = {
     "cell": dict(B=2, T=8192, splits=(4096, 1024, 1024), taps=4, groups=8),
     "probe": dict(B=2, T=2048, splits=(4096, 1024, 1024), taps=4, groups=8),
+    # `granite4hmicro.longctx`: one group of B and C, the norm over 4,096
+    "granite": dict(B=1, T=32768, splits=(4096, 128, 128), taps=4, groups=1),
     # a KDA layer's streams: `splits` the three calls' widths
     "kimi": dict(B=1, T=16384, splits=(4096,) * 3, taps=4, unit=128),
     "solar": dict(B=1, T=8192, splits=(1024,) * 3, taps=4, unit=128),

@@ -295,7 +295,7 @@ from ray_tpu.ops.mamba_passes import (
 from ray_tpu.ops.sparse_attention import keys_kept, sparse_attention
 from ray_tpu.ops.selective_scan import (
     channel_block, selective_scan, selective_scan_untiled)
-from ray_tpu.ops.ssd import scan_untiled, ssd
+from ray_tpu.ops.ssd import head_tile, scan_untiled, ssd
 from ray_tpu.ops.fused import (
     HEAD_CHUNK,
     _own_buffer,
@@ -513,6 +513,17 @@ class TransformerConfig:
     # none and three even shares from step to step; at 1 a row's own token
     # sets its routing, as in any trained checkpoint, and the load is even
     embed_init_std: Optional[float] = None
+    # the four multipliers of a stack parametrised for width (muP, as
+    # config.json's keys of the same names have them): the embedding's rows
+    # times `embedding_multiplier`; what a sublayer adds to the stream times
+    # `residual_multiplier`, for the records that take it
+    # (`takes_multipliers`); plain attention's scores times
+    # `attention_multiplier` in place of `1 / sqrt(head_dim)` (None); the
+    # logits over `logits_scaling`. At 1 and None no instruction is added
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     @property
     def rows_per_token(self) -> int:
@@ -646,6 +657,23 @@ class TransformerConfig:
                             f"{type(sub).__name__[1:]} sublayer: the scale "
                             "1 + g is EVA attention's and the dense "
                             "feed-forward's RMSNorm's")
+        if (self.attention_multiplier is not None
+                and self.attention_impl == "ring"):
+            raise ValueError(
+                f"attention_multiplier {self.attention_multiplier} with "
+                "attention_impl 'ring', whose scores' scale is its own")
+        if (self.residual_multiplier != 1
+                or self.attention_multiplier is not None):
+            for kind in set(kinds):
+                for sub in _sublayers(kind):
+                    if not sub.takes_multipliers:
+                        raise ValueError(
+                            f"residual_multiplier {self.residual_multiplier} "
+                            f"or attention_multiplier "
+                            f"{self.attention_multiplier} with a "
+                            f"{type(sub).__name__[1:]} sublayer: the "
+                            "multipliers are written for plain attention, "
+                            "the Mamba-2 mixer and the dense feed-forward")
         if self.objective not in ("next_token", "block_diffusion"):
             raise ValueError(f"objective {self.objective!r}")
         if self.objective == "block_diffusion":
@@ -907,7 +935,7 @@ def _attention_layer(x, blk, positions, cfg: TransformerConfig,
     y, q, k, v = _qkv(x, blk, positions, cfg, op)
     with jax.named_scope("attention"):
         o = _attention(q, k, v, cfg, seq_axis, seq_size, mesh, keep_ctx,
-                       window=op.window(cfg))
+                       scale=cfg.attention_multiplier, window=op.window(cfg))
     if "w_gate_attn" in blk:  # a column a head, or one a column of a head
         with jax.named_scope("attn_gate"):
             gate = jax.nn.sigmoid(y @ blk["w_gate_attn"].astype(dt))
@@ -917,7 +945,14 @@ def _attention_layer(x, blk, positions, cfg: TransformerConfig,
         out = o.reshape(B, T, h * dh) @ blk["wo"].astype(dt)
         if "attn_post_norm" in blk:
             out = _post_norm(out, blk["attn_post_norm"], cfg)
-        return checkpoint_name(x + out, "attn_res")
+        return checkpoint_name(x + _to_the_stream(out, cfg), "attn_res")
+
+
+def _to_the_stream(out, cfg: "TransformerConfig"):
+    """What a sublayer adds to the stream: its output times
+    `residual_multiplier`; at 1 the output itself, and no instruction."""
+    return out if cfg.residual_multiplier == 1 else (
+        out * cfg.residual_multiplier)
 
 
 def _post_norm(y, scale, cfg: "TransformerConfig"):
@@ -1606,15 +1641,18 @@ def _kda_out_norm_untiled(cfg: TransformerConfig, T: Optional[int] = None):
 def _calls_said(before) -> str:
     """What a trace added to the counts of the KDA mixers' short
     convolutions (three calls a mixer), of their output norms and gates
-    (one) and of the block-diffusion layers' own blocks and joins (one a
-    layer) since the counters read `before`, by the path each call took,
+    (one), of the block-diffusion layers' own blocks and joins (one a
+    layer) and of the Mamba-2 mixers' scans (`ops/ssd.py` counts them: one a
+    mixer and trace of it) since the counters read `before`, by the path
+    each call took,
     for the step's log line; nothing where no such layer was traced."""
     now = tracing.counters()
     said = ""
     for what, name in (("KDA's short convolutions", "kda_conv"),
                        ("KDA's output norms and gates", "kda_out_norm"),
                        ("block diffusion's own blocks and joins",
-                        "bd_own_join")):
+                        "bd_own_join"),
+                       ("Mamba-2's scans", "ssd")):
         kernels, numpy = (
             now.get(counter, 0) - before.get(counter, 0)
             for counter in (f"train.{name}_calls_kernels",
@@ -1752,13 +1790,17 @@ def _scan_bytes_per_token(cfg: TransformerConfig) -> int:
     cum with their cotangents: as columns `[b, G, T, R]` float32, which lie
     in HBM at a tile's 128 lanes a group (the four the kernels read and
     write and three that the transposes from and to `[b, T, H]` make),
-    and as rows and plain `[b, T, H]` arrays, two `H` wide all told."""
+    and as rows and plain `[b, T, H]` arrays, two `H` wide all told. Where
+    a group's heads are taken in tiles (`head_tile`) every tile is such a
+    group, and each writes a `dB` and a `dC` of its own."""
     item = _item(cfg)
     H, Q, G = cfg.mamba_heads, cfg.ssd_chunk, cfg.ssm_groups
-    if _kernel_impl(cfg) != "pallas" or scan_untiled(
-            Q, cfg.ssm_state, H // G, cfg.mamba_head_dim):
+    N, P = cfg.ssm_state, cfg.mamba_head_dim
+    if _kernel_impl(cfg) != "pallas" or scan_untiled(Q, N, H // G, P):
         return H * Q * (4 * 4 + 2 * item)
-    return 4 * (cfg.mamba_inner * cfg.ssm_state // Q + 7 * G * 128 + 2 * H)
+    tiles = H // head_tile(Q, N, H // G, P, item)  # of all the groups
+    own = 2 * tiles * N * 4 if tiles > G else 0  # float32, to be summed
+    return 4 * (cfg.mamba_inner * N // Q + 7 * tiles * 128 + 2 * H) + own
 
 
 # ------------------------------------------------- the kinds of sublayer
@@ -1885,6 +1927,9 @@ class Sublayer:
     reads_depth: bool = False
     # a sublayer whose RMSNorm `norm_unit_offset` scales by 1 + g
     takes_unit_offset: bool = False
+    # a sublayer that adds `residual_multiplier` times its output and, where
+    # it has scores, scales them by `attention_multiplier`
+    takes_multipliers: bool = False
 
     def check(self, cfg: TransformerConfig) -> None:
         """Raises `ValueError` where `cfg` is none the record can run
@@ -1930,6 +1975,13 @@ class Sublayer:
         normed input (`_KindTerms.block`)."""
         raise NotImplementedError
 
+    def residuals(self, cfg: TransformerConfig) -> int:
+        """What its forward leaves for its own backward beside its names
+        and its normed input, which a walked layer still holds while the
+        sublayers AFTER it run their backward (`_terms`): none, for the
+        records whose plans showed none."""
+        return 0
+
     def flops(self, cfg: TransformerConfig, seq_len: int):
         """(matmul operations, causal attention's): of plain matmuls alone,
         two a parameter."""
@@ -1946,6 +1998,7 @@ class _PlainAttention(Sublayer):
     names = ("attn_ctx", "attn_res", "attn_qkv")
     takes_heads_held = True
     takes_post_norm = True
+    takes_multipliers = True
 
     def __init__(self, sliding: bool):
         self.sliding = sliding
@@ -2115,6 +2168,7 @@ class _SparseAttention(_PlainAttention):
     # the index loss reads the probabilities of every head
     takes_heads_held = False
     takes_post_norm = False  # `_sparse_attention_layer` has none
+    takes_multipliers = False
     no_sequence_axis = (
         "sparse attention is not mapped over a sequence axis: a query "
         "chooses among all the keys before it, and the selection and the "
@@ -2343,6 +2397,7 @@ class _Mamba2(Sublayer):
     matmuls = ("w_in", "w_out")
     names = ("mamba_in", "ssd_out")
     no_sequence_axis = "the Mamba-2 mixer is not mapped over a sequence axis"
+    takes_multipliers = True
 
     def init(self, key, cfg, L):
         """`A = -exp(A_log)` starts uniform in [-16, -1], `softplus(dt_bias)`
@@ -2388,7 +2443,7 @@ class _Mamba2(Sublayer):
 
     def forward(self, x, blk, cfg, site):
         with jax.named_scope("mamba"):
-            return x + _mamba_mixer(x, blk, cfg), None
+            return x + _to_the_stream(_mamba_mixer(x, blk, cfg), cfg), None
 
     def _wide(self, cfg) -> int:
         """`w_in`'s columns: the gate, x, B and C, and dt a head."""
@@ -2416,6 +2471,20 @@ class _Mamba2(Sublayer):
             norm = 0
         return (2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner + norm
                 + _scan_bytes_per_token(cfg) // _item(cfg))
+
+    def residuals(self, cfg):
+        """Through a feed-forward's backward behind it in the layer: the
+        convolution's results, the scan's entering states (float32, a
+        chunk), the normed output that `w_out`'s gradient takes, and the
+        gate `z` and `xBC` as arrays of their own, the kernels' operands,
+        beside the product `mamba_in` they are cut from (the compiler's plan
+        for a described v5e, `granite4hmicro.longctx`, PR 74: 0.29, 0.27,
+        0.27 and 0.55 GB at 32,768 tokens, all live with the feed-forward's
+        three products at the step's fullest; a stack of `sublayer_types`
+        has no sublayer behind a mixer)."""
+        states = 4 * cfg.mamba_inner * cfg.ssm_state // cfg.ssd_chunk
+        return (2 * cfg.mamba_conv_dim + 2 * cfg.mamba_inner
+                + states // _item(cfg))
 
     def flops(self, cfg, seq_len):
         inner, N = cfg.mamba_inner, cfg.ssm_state
@@ -2942,6 +3011,7 @@ class _BlockDiffusionAttention(_PlainAttention):
     matmuls = ("wq", "wk", "wv", "wo")  # no gate on its context
     takes_heads_held = False  # the halves are folded over all the heads
     takes_post_norm = False  # `_block_diffusion_attention_layer` has none
+    takes_multipliers = False
     no_sequence_axis = (
         "block-diffusion attention is not mapped over a sequence axis: a "
         "row reads the clean rows of every earlier block and its own "
@@ -3051,6 +3121,7 @@ class _DenseFF(Sublayer):
     takes_post_norm = True
     takes_layer_norm = True
     takes_unit_offset = True
+    takes_multipliers = True
 
     def _width(self, cfg) -> int:
         if cfg.n_experts and cfg.d_ff_dense is not None:
@@ -3088,7 +3159,7 @@ class _DenseFF(Sublayer):
             out = _feed_forward(y, blk, cfg.dtype, ("mlp_gate", "mlp_up"))
             if "mlp_post_norm" in blk:
                 out = _post_norm(out, blk["mlp_post_norm"], cfg)
-            return x + out, None
+            return x + _to_the_stream(out, cfg), None
 
     def widths(self, cfg):
         return {"mlp_" + name: self._width(cfg)
@@ -3551,6 +3622,8 @@ def _hidden_and_readings(params, tokens, cfg: TransformerConfig,
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.embedding_multiplier != 1:
+            x = x * cfg.embedding_multiplier
 
     # no policy at all for an empty choice: the step is then the one that
     # keeps nothing, instruction for instruction
@@ -3969,7 +4042,13 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, **kw):
 
 
 def _unembed(params, cfg: TransformerConfig):
-    return params["embed"].T if cfg.tied_embeddings else params["unembed"]
+    """The head `[d_model, head_width]`, float32, over `logits_scaling`:
+    the division is the weights' (once a step over the head, exact at a
+    power of two), so the chunked cross-entropy, its backward and every
+    other reader of the head multiply the scaled logits and no
+    `[tokens, vocab]` array is made for it."""
+    w = params["embed"].T if cfg.tied_embeddings else params["unembed"]
+    return w if cfg.logits_scaling == 1 else w / cfg.logits_scaling
 
 
 def transformer_apply(params, tokens, cfg: TransformerConfig,
@@ -4109,8 +4188,8 @@ def transformer_loss_and_readings(params, batch, cfg: TransformerConfig, **kw):
         hidden = hidden[-1]
     with jax.named_scope("lm_head_ce"):
         if cfg.n_pred_heads > 1:
-            loss = _multi_head_loss(hidden, params["unembed"], targets, cfg,
-                                    kw.get("mesh"))
+            loss = _multi_head_loss(hidden, _unembed(params, cfg), targets,
+                                    cfg, kw.get("mesh"))
         else:
             loss = _head_loss(hidden, _unembed(params, cfg), targets,
                               kw.get("mesh"))
@@ -4511,8 +4590,10 @@ def _terms(cfg: TransformerConfig, tokens: int,
 
     A walked layer's block is the larger of its sublayers' backward
     moments, the feed-forward's and then the operator's: a sublayer's
-    moment has the names made up to it and what it alone holds
-    (`Sublayer.holds`), a share's feed-forward's on one device also its
+    moment has the names made up to it, what it alone holds
+    (`Sublayer.holds`) and what the sublayers before it left for their own
+    backward (`Sublayer.residuals`: the Mamba-2 mixer's), a share's
+    feed-forward's on one device also its
     held rows' buffers and their float32 sum (an `expert` axis' exchange
     has both). The operator's cotangents are not live in the
     feed-forward's backward, nor the feed-forward's products in the
@@ -4566,7 +4647,11 @@ def _terms(cfg: TransformerConfig, tokens: int,
         # buffers and their float32 sum (an axis' exchange has both)
         made = itertools.accumulate(
             sum(sub.widths(cfg).values()) for sub in subs)
-        moments = [tokens * (m + h) * item for m, h in zip(made, held)]
+        # and what the sublayers before it left for their own backward
+        left = itertools.accumulate(
+            (sub.residuals(cfg) for sub in subs), initial=0)
+        moments = [tokens * (m + h + l) * item
+                   for m, h, l in zip(made, held, left)]
         if kind.routed and expert_ways == 1 and cfg.held[1] < cfg.n_experts:
             moments[-1] += _held_buffer_bytes(cfg, tokens, 1) + tokens * d * 4
         walked[kind] = max(moments) + rest

@@ -52,9 +52,18 @@ says once a shape which it took (`_log_scan`):
   `mixed_h @ (dt x)_h` is `P` wide; where `P` is under a tile's 128 lanes
   the heads of a tile are taken one at a time against the tile with the
   other heads' lanes zeroed (the MXU's pass is 128 wide either way), so
-  no slice, store or concatenation is narrower than 128 lanes. From the
-  forward rule the kernel also writes each chunk's entering state
-  (`[b, n, G, N, R P]` float32), the backward's one residual beside the
+  no slice, store or concatenation is narrower than 128 lanes. A group
+  whose heads do not fit a step's VMEM (`head_tile`: one group of 64 heads
+  at chunks of 256 would hold 73 MB in `ssd_bwd`) is taken a TILE of its
+  heads a step: the grid's second axis is then (group, tile), every tile
+  a group of its own but for `B` and `C`, whose blocks the index maps
+  share among a group's tiles (each tile makes the group's `scores`
+  again: one `[Q, N] x [N, Q]` product beside a product as large for
+  every head of the tile), and `ssd_bwd` writes a tile's `dB` and `dC` in
+  float32, summed over the tiles outside. A group that fits is one tile,
+  and the call is the one it was. From the forward rule the kernel also
+  writes each chunk's entering state (`[b, n, G, N, R P]` float32; tile
+  by tile where a group is tiled), the backward's one residual beside the
   inputs. `ssd_bwd` walks the chunks from the last to the first with the
   state's cotangent in the same scratch, makes `scores` and the decay
   tiles again from `B`, `C` and `cum`, and returns `dx`, `dB` and `dC`
@@ -78,6 +87,7 @@ import jax.numpy as jnp
 from ray_tpu.ops.flash_attention import (
     _DEFAULT_VMEM, _LANES, _MAX_VMEM, _NN, _NT, _TN, _dot, _pallas_call,
     resolve_impl)
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -120,7 +130,9 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 128, impl: str = "auto",
               jnp.dtype(x.dtype).name)
     A, D = A.astype(_F32), D.astype(_F32)
     if kernels and not untiled:
+        tracing.count("train.ssd_calls_kernels")
         return _scan_kernels(x, dt, A, B, C, D, Q, interpret)[:, :T]
+    tracing.count("train.ssd_calls_numpy")
     return _scan_numpy(x, dt, A, B, C, D, Q)[:, :T]
 
 
@@ -215,6 +227,27 @@ def _vmem_limit(kernel, Q, N, RP, itemsize) -> int:
     return min(max(_DEFAULT_VMEM, need), _MAX_VMEM)
 
 
+# the most a grid step of `ssd_bwd` may hold by `scan_vmem_bytes` before a
+# group's heads are taken in tiles: what `_vmem_limit` can still ask twice
+# of. One group of 64 heads of 64 at chunks of 256 (73.4 MB whole) then goes
+# 32 heads a tile, 38.8 MB: of 4, 8, 16, 32 and 64 a tile the fastest alone
+# on the chip (18.0, 15.9, 15.2, 14.8, 15.6 ms forward and backward at
+# 32,768 tokens) and but for 64 the least in HBM, a tile's columns of dt
+# and cum lying there at 128 lanes (PERF.md section 6, PR 74)
+_STEP_VMEM = _MAX_VMEM // 2
+
+
+def head_tile(Q: int, N: int, R: int, P: int, itemsize: int) -> int:
+    """The heads of a group that a grid step takes: all `R` where
+    `ssd_bwd`'s estimate (`scan_vmem_bytes`) stands under `_STEP_VMEM`, else
+    the most heads that divide `R`, fill whole tiles of 128 lanes and stand
+    under it (the fewest such, if none does)."""
+    whole = [r for r in range(R, 0, -1)
+             if R % r == 0 and not r * P % _LANES]
+    return next((r for r in whole if scan_vmem_bytes(
+        "ssd_bwd", Q, N, r * P, itemsize) <= _STEP_VMEM), whole[-1])
+
+
 @functools.lru_cache(maxsize=None)
 def _log_scan(kernels, untiled, b, T, H, P, G, N, Q, dtype):
     """One line for each scan a process traces, as `_log_bwd_kernels` and
@@ -228,11 +261,14 @@ def _log_scan(kernels, untiled, b, T, H, P, G, N, Q, dtype):
         logger.info("%s: jax.numpy (ssd_chunk, ssd_state, ssd_out), because "
                     "%s", shape, untiled)
     else:
-        RP, item = H // G * P, jnp.dtype(dtype).itemsize
+        item = jnp.dtype(dtype).itemsize
+        heads = head_tile(Q, N, H // G, P, item)
+        RP = heads * P
         logger.info(
-            "%s: ssd_fwd and ssd_bwd, grid (%d, %d, %d), blocks [%d, %d] of x "
-            "and [%d, %d] of B and C, a state of [%d, %d] float32, VMEM %d "
-            "and %d bytes of limits of %d and %d", shape, b, G, T // Q, Q, RP,
+            "%s: ssd_fwd and ssd_bwd, %d heads a tile, grid (%d, %d, %d), "
+            "blocks [%d, %d] of x and [%d, %d] of B and C, a state of "
+            "[%d, %d] float32, VMEM %d and %d bytes of limits of %d and %d",
+            shape, heads, b, H // heads, T // Q, Q, RP,
             Q, N, N, RP, *(f(k, Q, N, RP, item) for f in (
                 scan_vmem_bytes, _vmem_limit) for k in ("ssd_fwd", "ssd_bwd")))
 
@@ -410,8 +446,8 @@ def _ssd_bwd_kernel(x_ref, dtc_ref, cumc_ref, cumr_ref, b_ref, c_ref, d_ref,
         dxdt.append(acc)
     dxdt = _beside(dxdt) + ddecayed * to_end_x
     dscores = dscores.astype(dtype)
-    dc_ref[0] = (dC + _dot(dscores, Bm, _NN)).astype(dtype)
-    db_ref[0] = (dB + _dot(dscores, Cm, _TN)).astype(dtype)
+    dc_ref[0] = (dC + _dot(dscores, Bm, _NN)).astype(dc_ref.dtype)
+    db_ref[0] = (dB + _dot(dscores, Cm, _TN)).astype(db_ref.dtype)
     dx_ref[0] = (dxdt * dt_x + g * d_ref[0]).astype(dtype)
 
     # a token and head: d dt through `dt x`; d cum through exp(cum) and
@@ -434,28 +470,35 @@ def _ssd_bwd_kernel(x_ref, dtc_ref, cumc_ref, cumr_ref, b_ref, c_ref, d_ref,
 
 
 # how each operand and result of the kernels lies, by its place in the call
-_TOKENS, _COLUMNS, _ROWS, _SKIP, _STATES = range(5)
-_INPUTS = (_TOKENS, _COLUMNS, _COLUMNS, _ROWS, _TOKENS, _TOKENS, _SKIP)
-_GRADS = _INPUTS[:-1]  # the skip's is `jax.numpy`'s
+_TOKENS, _COLUMNS, _ROWS, _SKIP, _STATES, _SHARED = range(6)
+_INPUTS = (_TOKENS, _COLUMNS, _COLUMNS, _ROWS, _SHARED, _SHARED, _SKIP)
+# the skip's is `jax.numpy`'s; dB and dC are a tile's own
+_GRADS = (_TOKENS, _COLUMNS, _COLUMNS, _ROWS, _TOKENS, _TOKENS)
 
 
 def _scan_call(kernel, name, operands, kinds, out_shapes, out_kinds, P, n,
-               interpret):
+               tiles, interpret):
     """`pallas_call` of `ssd_fwd` or `ssd_bwd` (which walks the chunks
-    backwards) over (batch row, group, chunk). Blocks: `[Q, w]` of tokens
-    `[b, T, G w]`, `[Q, R]` of columns `[b, G, T, R]`, `[R, Q]` of rows
-    `[b, G, R, T]`, a group's skip `[1, R P]` of `[G, 1, R P]`, a chunk's
-    entering state `[N, R P]` of `[b, n, G, N, R P]`."""
+    backwards) over (batch row, group, chunk); `G` and `R` below are the
+    tiles of heads of all the groups and a tile's heads, `tiles` a group.
+    Blocks: `[Q, w]` of tokens `[b, T, G w]`, `[Q, N]` of a group's `B` and
+    `C`, shared by its `tiles`, `[Q, R]` of columns `[b, G, T, R]`, `[R, Q]`
+    of rows `[b, G, R, T]`, a tile's skip `[1, R P]` of `[G, 1, R P]`, a
+    chunk's entering state `[N, R P]` of `[b, n, G, N, R P]`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     x, _, cumc, _, Bm = operands[:5]
     b, T = x.shape[:2]
     G, R = cumc.shape[1], cumc.shape[3]
-    N, RP, Q = Bm.shape[2] // G, R * P, T // n
+    N, RP, Q = Bm.shape[2] * tiles // G, R * P, T // n
     chunk = (lambda c: n - 1 - c) if name == "ssd_bwd" else (lambda c: c)
+    group = (lambda g: g // tiles) if tiles > 1 else (lambda g: g)
 
     def spec(a, kind):
+        if kind == _SHARED:
+            return pl.BlockSpec((1, Q, N),
+                                lambda i, g, c: (i, chunk(c), group(g)))
         if kind == _TOKENS:
             return pl.BlockSpec((1, Q, a.shape[2] // G),
                                 lambda i, g, c: (i, chunk(c), g))
@@ -486,38 +529,47 @@ def _scan_call(kernel, name, operands, kinds, out_shapes, out_kinds, P, n,
     )(*operands)
 
 
-def _ssd_fwd(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, interpret,
+def _ssd_fwd(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, tiles, interpret,
              with_states: bool = False):
     b = x.shape[0]
     G, R = cumc.shape[1], cumc.shape[3]
     out = [jax.ShapeDtypeStruct(x.shape, x.dtype)]
     if with_states:
         out.append(jax.ShapeDtypeStruct(
-            (b, n, G, Bm.shape[2] // G, R * P), _F32))
+            (b, n, G, Bm.shape[2] * tiles // G, R * P), _F32))
     got = _scan_call(
         _ssd_fwd_kernel, "ssd_fwd", (x, dtc, cumc, cumr, Bm, Cm, skip),
-        _INPUTS, out, (_TOKENS, _STATES), P, n, interpret)
+        _INPUTS, out, (_TOKENS, _STATES), P, n, tiles, interpret)
     return got if with_states else got[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _scan(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, interpret):
-    return _ssd_fwd(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _scan(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, tiles, interpret):
+    return _ssd_fwd(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, tiles, interpret)
 
 
-def _scan_vjp_fwd(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, interpret):
-    y, entering = _ssd_fwd(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, interpret,
-                           with_states=True)
+def _scan_vjp_fwd(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, tiles, interpret):
+    y, entering = _ssd_fwd(x, dtc, cumc, cumr, Bm, Cm, skip, P, n, tiles,
+                           interpret, with_states=True)
     return y, (x, dtc, cumc, cumr, Bm, Cm, skip, entering)
 
 
-def _scan_vjp_bwd(P, n, interpret, res, dy):
+def _scan_vjp_bwd(P, n, tiles, interpret, res, dy):
     x, dtc, cumc, cumr, Bm, Cm, skip, _ = res
+    b, T, GN = Bm.shape
+    # a tile's own dB and dC, `[b, T, (G, tiles) N]`, float32 where they
+    # are summed: rounded a tile, the sum reads 4e-3 from `jax.numpy`'s
     shapes = [jax.ShapeDtypeStruct(v.shape, v.dtype)
-              for v in (x, dtc, cumc, cumr, Bm, Cm)]
+              for v in (x, dtc, cumc, cumr)] + 2 * [jax.ShapeDtypeStruct(
+                  (b, T, tiles * GN), _F32 if tiles > 1 else Bm.dtype)]
     grads = _scan_call(
         _ssd_bwd_kernel, "ssd_bwd", (*res, dy), (*_INPUTS, _STATES, _TOKENS),
-        shapes, _GRADS, P, n, interpret)
+        shapes, _GRADS, P, n, tiles, interpret)
+    if tiles > 1:  # summed over a group's tiles
+        N = GN * tiles // cumc.shape[1]
+        grads = (*grads[:4], *(
+            g.reshape(b, T, -1, tiles, N).sum(axis=3).astype(
+                Bm.dtype).reshape(b, T, GN) for g in grads[4:]))
     # left to XLA, which makes this sum in the fusion that makes dy from the
     # gate's backward (PERF.md section 6, PR 39)
     dskip = (dy.astype(_F32) * x.astype(_F32)).sum(axis=(0, 1)).reshape(
@@ -528,20 +580,26 @@ def _scan_vjp_bwd(P, n, interpret, res, dy):
 _scan.defvjp(_scan_vjp_fwd, _scan_vjp_bwd)
 
 
-def _scan_kernels(x, dt, A, B, C, D, Q, interpret):
+def _scan_kernels(x, dt, A, B, C, D, Q, interpret,
+                  heads: Optional[int] = None):
     """The scan of whole chunks of `Q` tokens by `ssd_fwd` and `ssd_bwd`:
     the layouts they take, made here; `a = dt A` and its running sums are
-    `jax.numpy`, and autodiff's."""
+    `jax.numpy`, and autodiff's. `heads` a tile (`head_tile`'s where None:
+    the tests name others) make the kernels' groups, `tiles` of them
+    sharing a group's `B` and `C`."""
     b, T, H, P = x.shape
     G, N = B.shape[-2:]
-    R, n = H // G, T // Q
+    n = T // Q
+    R = heads or head_tile(Q, N, H // G, P, jnp.dtype(x.dtype).itemsize)
+    tiles = H // G // R
     cum = jnp.cumsum((dt * A).reshape(b, n, Q, H), axis=2)
 
-    def columns(v):                                        # [b, G, T, R]
-        return v.reshape(b, T, G, R).transpose(0, 2, 1, 3)
+    def columns(v):                                        # [b, G tiles, T, R]
+        return v.reshape(b, T, H // R, R).transpose(0, 2, 1, 3)
 
     cumc = columns(cum)
     y = _scan(x.reshape(b, T, H * P), columns(dt), cumc, cumc.swapaxes(2, 3),
               B.reshape(b, T, G * N), C.reshape(b, T, G * N),
-              jnp.repeat(D, P).reshape(G, 1, R * P), P, n, interpret)
+              jnp.repeat(D, P).reshape(H // R, 1, R * P), P, n, tiles,
+              interpret)
     return y.reshape(b, T, H, P)

@@ -59,6 +59,20 @@ def pytest_pycollect_makeitem(collector):
     return made
 
 
+# The files that compile the cells' whole steps for a described v5e: 400
+# test-seconds each in a whole six-worker run, and by their names collected
+# when 1,130 s of it are gone, so that two workers walked them to 1,483 s
+# while four stood idle from 1,261 (PR 74's run; ROADMAP D11). `--dist
+# loadfile` hands files out in the order they are collected: these go first.
+FIRST_FILES = ("test_step_compile.py", "test_step_compile_walked.py")
+
+
+def pytest_collection_modifyitems(items):
+    """`FIRST_FILES`' cases ahead of the rest, every file's own order and
+    the others' kept (a stable sort; every worker collects the same)."""
+    items.sort(key=lambda item: item.path.name not in FIRST_FILES)
+
+
 @pytest.hookimpl(hookwrapper=True)
 def pytest_runtest_call(item):
     """Watchdog so one wedged test cannot hang the whole suite."""

@@ -531,6 +531,69 @@ def test_scan_kernel_compiles_at_the_cell_s_shapes(v5e, kernel):
         "f32[2,8,8,8192]", "bf16[2,8192,1024]", "bf16[2,8192,1024]"]
 
 
+@pytest.mark.parametrize("use", ["forward", "backward"])
+def test_the_mixer_s_kernels_compile_at_granite_s_shapes(v5e, use):
+    """`granite4hmicro.longctx`: one sequence of 32,768 tokens, 64 heads of
+    64 in ONE group of B and C, a state of 128, chunks of 256, bf16: the
+    scan 32 heads a tile (`head_tile`; whole, `ssd_bwd` would hold 73 MB),
+    the entering states tile by tile, a tile's `dB` and `dC` float32 and
+    summed outside; the convolution over 4,352 channels split 4,096 / 128 /
+    128 (blocks of 128 channels) and the gated norm over one group of 4,096.
+    No `[.., 256, 256]` array of a chunk's decays or scores, no float32
+    array of the mixer's width and no padded copy is in either program."""
+    import re
+
+    from ray_tpu.ops.mamba_passes import causal_conv_silu, gated_group_rmsnorm
+    from ray_tpu.ops.ssd import ssd
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    T, H, P, N, splits = 32768, 64, 64, 128, (4096, 128, 128)
+    args = (sd((1, T, sum(splits))), sd((4, sum(splits))),
+            sd((sum(splits),), jnp.float32), sd((1, T, H), jnp.float32),
+            sd((H,), jnp.float32), sd((H,), jnp.float32),
+            sd((1, T, H * P)), sd((H * P,), jnp.float32))
+
+    def out(xbc, w, bias, dt, A, D, z, weight):
+        x, b, c = causal_conv_silu(xbc, w, bias, splits=splits, impl="pallas")
+        y = ssd(x.reshape(1, T, H, P), dt, A, b.reshape(1, T, 1, N),
+                c.reshape(1, T, 1, N), D, chunk=256, impl="pallas")
+        return gated_group_rmsnorm(
+            y.reshape(1, T, H * P), z, weight, 1, 1e-5, impl="pallas")
+
+    def grads(*args):
+        return jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(),
+                        argnums=range(8))(*args)
+
+    text = jax.jit(out if use == "forward" else grads).lower(
+        *args).compile().as_text()
+    calls = {re.search(r"(ssd|mamba_conv|mamba_norm)_(fwd|bwd)", name).group(0):
+             re.findall(r"(?:bf16|f32)\[[\d,]+\]", made)
+             for name, made in _custom_calls(text)}
+    assert not re.search(r"(f32|bf16)\[(\d+,)*256,256\]", text)
+    # (inside the fusion that sums `dD = dy x` the two are widened a tile at
+    # a time: no instruction writes such an array)
+    assert not re.search(
+        r"= f32\[1,32768,4\d\d\d\]\S* (fusion|custom-call|copy)\(", text)
+    assert not re.search(r"bf16\[1,3277[0-9],\d+\]", text)  # a padded copy
+    wide, narrow = "bf16[1,32768,4096]", "bf16[1,32768,128]"
+    if use == "forward":
+        assert calls == {"mamba_conv_fwd": [wide, narrow, narrow],
+                         "ssd_fwd": [wide], "mamba_norm_fwd": [wide]}
+        return
+    assert calls == {
+        "mamba_conv_fwd": [wide, narrow, narrow],
+        "ssd_fwd": [wide, "f32[1,128,2,128,2048]"],
+        "mamba_norm_bwd": [wide, wide, "f32[8,4096]"],
+        "ssd_bwd": [wide, "f32[1,2,32768,32]", "f32[1,2,32768,32]",
+                    "f32[1,2,32,32768]", "f32[1,32768,256]",
+                    "f32[1,32768,256]"],
+        "mamba_conv_bwd": ["bf16[1,32768,4352]", "f32[5,8,4352]"]}
+
+
 # (tokens, heads) of a KDA layer's one sequence, heads of 128
 KDA_SHAPES = {"solaropen2.tokens8k": (8192, 8),
               "kimilinear.tokens16k": (16384, 32)}

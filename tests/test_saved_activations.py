@@ -217,6 +217,31 @@ def test_the_rule_s_sum_stands_at_or_above_the_chip_s_peak(cell_name):
     assert predicted * 1e9 <= CHIP_LIMIT - tr._SAVE_RESERVE
 
 
+def test_the_longest_walked_stack_keeps_nothing_where_the_sum_has_no_room():
+    """`granite4hmicro.longctx`: ten walked layers at 32,768 rows. A layer's
+    fullest moment is its feed-forward's backward, with what the mixer
+    before it left for its own (`Sublayer.residuals`, 1.38 GB): state and
+    moment are 16.09 GB, 0.26 GB over what the rule may ask for, so it
+    keeps nothing. The compiler's plan with nothing kept is 15.84 GB with
+    no fusion made again (`tests/test_step_compile_walked.py`) and the
+    chip's peak 15,728,650,240 bytes (my chip runs, PR 74): the sum stands
+    over the chip's peak by 0.36 GB, inside the GB it may. The attention
+    layer's moment is 1.6 GB under a mixer layer's."""
+    cfg, tokens, resident, params, ways = cell_shapes("granite4hmicro.longctx")
+    assert (tokens, resident) == (32768, 12 * 797850560)
+    assert tr.saved_activations(
+        cfg, tokens, resident, params, CHIP_LIMIT, ways) == {}
+    terms = tr._terms(cfg, tokens, params, ways)
+    assert terms.fullest().name == "layer 9"
+    predicted = (resident + terms.fullest().bytes) / 1e9
+    assert 15.729 <= predicted <= 15.729 + 1
+    assert -0.3e9 < terms.room(resident, CHIP_LIMIT) < -0.2e9
+    moments = {m.name: m.bytes for m in terms.moments()}
+    assert moments["layer 9"] == moments["layer 0"]
+    assert 1.5e9 < moments["layer 9"] - moments["layer 5"] < 1.7e9
+    assert tr._scan_bytes_per_token(cfg) == 17920  # two tiles of 32 heads
+
+
 def test_scanned_stacks_have_the_room_they_had():
     """Where every segment has several periods the rule counts every term
     at once, as it did: the room to the byte. `dsv2lite.tokens8k`'s five

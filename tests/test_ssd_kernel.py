@@ -16,7 +16,7 @@ import pytest
 
 from ray_tpu.ops import ssd as scan
 from ray_tpu.ops.ssd import ssd
-from tiny_models import ssd_by_token, y_and_grads
+from tiny_models import equations as _equations, ssd_by_token, y_and_grads
 
 NAMES = "x dt A B C D".split()
 kernels = functools.partial(ssd, interpret=True)
@@ -94,16 +94,6 @@ def test_bf16_operands_lose_nothing_of_the_decays():
     y = kernels(*args)
     assert y.dtype == jnp.bfloat16
     assert rel(y, ssd_by_token(*args)) < 1e-2
-
-
-def _equations(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) else [value]:
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _equations(inner)
 
 
 def test_the_kernels_compute_in_the_stated_dtypes():
@@ -214,7 +204,7 @@ def test_a_shape_that_tiles_says_which_kernels_and_at_what_size(caplog):
         assert "pallas_call" in str(jax.make_jaxpr(kernels)(*args))
         assert "pallas_call" not in str(jax.make_jaxpr(ssd)(*args))  # "auto"
     kernel_line, numpy_line = [r.getMessage() for r in caplog.records]
-    assert "ssd_fwd and ssd_bwd, grid (1, 2, 2)" in kernel_line
+    assert "ssd_fwd and ssd_bwd, 8 heads a tile, grid (1, 2, 2)" in kernel_line
     assert "blocks [128, 512] of x and [128, 128] of B and C" in kernel_line
     assert "a state of [128, 512] float32" in kernel_line
     fwd, bwd = (scan.scan_vmem_bytes(k, 128, 128, 512, 4)
@@ -234,3 +224,100 @@ def test_the_cell_s_shape_tiles_and_fits_vmem():
         assert need < limit <= 96 << 20
     with pytest.raises(ValueError, match="groups"):
         kernels(*inputs(128, 4, 64, 3))
+
+
+# ------------------------------------------------ a tile of a group's heads
+
+# (T, H, P, G, chunk, heads a tile): 8 and 2 tiles a group (one tile is
+# `SHAPES`' above), one group and eight, chunks of 128 and 256, heads that
+# share a lane tile and heads of one
+TILED = [
+    (256, 16, 64, 1, 128, 2),    # one group in 8 tiles of 2 heads
+    (512, 16, 64, 1, 256, 8),    # chunks of 256, 2 tiles of 8 heads
+    (256, 32, 64, 8, 128, 2),    # eight groups, 2 tiles each
+    (256, 8, 128, 1, 128, 1),    # heads of a whole lane tile, a head a tile
+]
+
+
+@pytest.mark.parametrize("T,H,P,G,chunk,heads", TILED)
+def test_a_tile_of_a_group_s_heads_is_the_numpy_scan(T, H, P, G, chunk, heads):
+    """The kernels with a group's heads taken `heads` at a time against
+    the `jax.numpy` scan: `y` and every cotangent, `dB` and `dC` summed over
+    a group's tiles in float32."""
+    args = inputs(T, H, P, G)
+
+    def tiled(x, dt, A, B, C, D):
+        return scan._scan_kernels(
+            x, dt.astype(jnp.float32), A, B, C, D, chunk, True, heads=heads)
+
+    def plain(x, dt, A, B, C, D):
+        return scan._scan_numpy(x, dt.astype(jnp.float32), A, B, C, D, chunk)
+
+    with jax.default_matmul_precision("highest"):
+        y, grads = y_and_grads(tiled, args)
+        want_y, want = y_and_grads(plain, args)
+    assert rel(y, want_y) < 2e-5
+    for name, ours, theirs, arg in zip(NAMES, grads, want, args):
+        assert ours.dtype == arg.dtype and ours.shape == arg.shape, name
+        assert rel(ours, theirs) < (2e-3 if name == "A" else 2e-5), name
+
+
+def test_the_tiles_share_a_group_s_b_and_c_and_sum_their_cotangents():
+    """Read from the jaxpr at bf16: with two tiles a group the kernels'
+    groups are the tiles, `B` and `C` stay `[b, T, G N]`, `ssd_bwd` writes a
+    tile's `dB` and `dC` in float32 and the sum over the tiles is taken
+    outside; with one tile the call has no such sum and writes them in the
+    operands' dtype."""
+    args = inputs(256, 16, 64, 2, dtype=jnp.bfloat16)
+
+    def calls(heads):
+        jaxpr = jax.make_jaxpr(lambda *a: jax.grad(
+            lambda *a: scan._scan_kernels(
+                *a, 128, True, heads=heads).astype(jnp.float32).sum(),
+            argnums=(3, 4))(*a))(*args)
+        return {e.params["name"]: e for e in _equations(jaxpr.jaxpr)
+                if e.primitive.name == "pallas_call"}
+
+    tiled, whole = calls(4), calls(8)
+    for made, tiles, dtype in ((tiled, 2, jnp.float32), (whole, 1, jnp.bfloat16)):
+        x, dtc, _, _, Bm = (v.aval for v in made["ssd_bwd"].invars[:5])
+        assert x.shape == (1, 256, 1024) and Bm.shape == (1, 256, 256)
+        assert dtc.shape == (1, 2 * tiles, 256, 8 // tiles)
+        dB, dC = (v.aval for v in made["ssd_bwd"].outvars[4:])
+        assert dB.shape == dC.shape == (1, 256, 2 * tiles * 128)
+        assert dB.dtype == dC.dtype == dtype
+        states = made["ssd_fwd"].outvars[1].aval
+        assert states.shape == (1, 2, 2 * tiles, 128, 1024 // (2 * tiles))
+
+
+def test_the_tile_is_chosen_by_what_the_backward_would_hold():
+    """`head_tile`: all of a group's heads where `ssd_bwd`'s estimate
+    stands under half of what a kernel may ask Mosaic for, else the most
+    that do. Nemotron's group of 8 heads at chunks of 128 is one tile
+    (6.3 MB); one group of 64 heads at chunks of 256 would hold 73.4 MB and
+    goes 32 heads a tile, 38.8 MB, inside a limit of 77.6 MB of a v5e's
+    128; the cell's shape tiles."""
+    assert scan.head_tile(128, 128, 8, 64, 2) == 8
+    assert scan.scan_vmem_bytes("ssd_bwd", 128, 128, 512, 2) == 6291456
+    assert scan.scan_untiled(256, 128, 64, 64) is None
+    assert scan.scan_vmem_bytes("ssd_bwd", 256, 128, 4096, 2) == 73400320
+    assert scan.head_tile(256, 128, 64, 64, 2) == 32
+    for kernel, need in (("ssd_fwd", 22020096), ("ssd_bwd", 38797312)):
+        assert scan.scan_vmem_bytes(kernel, 256, 128, 2048, 2) == need
+        assert need <= scan._STEP_VMEM == 48 << 20
+        assert scan._vmem_limit(kernel, 256, 128, 2048, 2) == 2 * need
+    # chunks of 512, heads of a whole tile, a lone head
+    assert scan.head_tile(512, 128, 64, 64, 2) == 16
+    assert scan.head_tile(128, 128, 4, 128, 2) == 4
+    assert scan.head_tile(128, 128, 1, 128, 2) == 1
+
+
+def test_the_log_line_says_the_head_tile(caplog):
+    scan._log_scan.cache_clear()
+    with caplog.at_level(logging.INFO, logger="ray_tpu.ops.ssd"):
+        scan._log_scan(True, None, 1, 32768, 64, 64, 1, 128, 256, "bfloat16")
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert "ssd_fwd and ssd_bwd, 32 heads a tile, grid (1, 2, 128)" in line
+    assert "blocks [256, 2048] of x and [256, 128] of B and C" in line
+    assert "a state of [128, 2048] float32" in line
+

@@ -94,3 +94,14 @@ def ssd_by_token(x, dt, A, B, C, D):
     per_token = tuple(v.astype(jnp.float32).swapaxes(0, 1) for v in (x, dt, B, C))
     _, y = jax.lax.scan(step, jnp.zeros((b, H, P, N), jnp.float32), per_token)
     return y.swapaxes(0, 1)
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from equations(inner)

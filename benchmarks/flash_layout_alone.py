@@ -6,7 +6,7 @@ the model holds them, `[B, S, Hk Dv]` (`v_heads = Hk`: what the entries hand
 over where v has q's heads, whole tiles wide), and the transposes a folded
 call needs timed beside them.
 
-    python3 benchmarks/flash_layout_alone.py [--shapes evabyte-window,...] [--calls 10] [--seed 0]
+    python3 benchmarks/flash_layout_alone.py [--shapes evabyte-window,...] [--calls 10] [--seed 0] [--tile 1024x512]
 
 At each shape (`evabyte-window`: the four windows of 2,048 of
 `evabyte.tokens8k` folded into the batch, 32 heads of 128; `evabyte-stair`:
@@ -16,9 +16,19 @@ its 8,192 queries against 512 summaries under the staircase; `ouro`:
 where the entries fold v, timed folded only: `laguna-window` and
 `laguna-full`, `lagunaxs2.tokens8k`'s 64 / 8 heads under a window of 512
 and 48 / 8 whole, 2 x 8,192, and `mistral`, `mistral7b.tokens4k`'s 32 / 8
-at 4 x 4,096; bf16) and for the forward (`_flash_fwd` with lse) and the
-backward (`_flash_backward`: delta and the kernels the plan takes): ms a
-call by the
+at 4 x 4,096; and the heads narrower than a tile of lanes, or wider by half
+of one, that long rows send out of `flash_bwd_dkv_dq` a tile at a time
+padded to whole lanes (PR 75): `phi4flash`, `phi4flash.tokens16k`'s 40 / 20
+paired heads, q and k 64 wide and v 128, at T 16,384, `phi4flash-window`,
+the same under its window of 512, `granite`, `granite4hmicro.longctx`'s
+32 / 8 heads of 64 at T 32,768, and `kimilinear-mla`,
+`kimilinear.tokens16k`'s 32 heads, 192 and 128 wide, at T 16,384; bf16)
+and for the forward (`_flash_fwd` with lse) and the backward
+(`_flash_backward`: delta and the kernels the plan takes, which the line
+names with their tiles and the one kernel's exit; `backward_pair`: the same
+with the plan answering `flash_bwd_dq` and `flash_bwd_dkv`, and how far the
+plan's gradients lie from theirs; with `--tile`, `backward_forced`: the
+plan at that tile of q rows x keys): ms a call by the
 host's clock over `--calls` calls after one that compiles, for each way of
 lying; the bytes the call's arrays hold (each read or written once) and
 the GB/s that is; whether the results are equal bit for bit to the folded
@@ -32,6 +42,7 @@ measurement and fails without a TPU: a CPU's time is not a chip's.
 """
 
 import argparse
+import functools
 import importlib
 import json
 import os
@@ -56,7 +67,28 @@ SHAPES = {
                           window=512),
     "laguna-full": dict(B=2, T=8192, S=8192, H=48, Hk=8, causal=True),
     "mistral": dict(B=4, T=4096, S=4096, H=32, Hk=8, causal=True),
+    "phi4flash": dict(B=1, T=16384, S=16384, H=40, Hk=20, D=64, causal=True),
+    "phi4flash-window": dict(B=1, T=16384, S=16384, H=40, Hk=20, D=64,
+                             causal=True, window=512),
+    "granite": dict(B=1, T=32768, S=32768, H=32, Hk=8, D=64, Dv=64,
+                    causal=True),
+    "kimilinear-mla": dict(B=1, T=16384, S=16384, H=32, Hk=32, D=192,
+                           causal=True),
 }
+PAIR = ("flash_bwd_dq", "flash_bwd_dkv")
+
+
+def the_pair(fn):
+    """`fn` traced with the plan answering the two kernels."""
+    def call(*operands):
+        plan = fa.flash_bwd_kernels
+        fa.flash_bwd_kernels = lambda *a, **kw: PAIR
+        try:
+            return fn(*operands)
+        finally:
+            fa.flash_bwd_kernels = plan
+
+    return call
 
 
 def fold(x, heads):
@@ -92,7 +124,11 @@ def main():
     parser.add_argument("--shapes", default=",".join(SHAPES))
     parser.add_argument("--calls", type=int, default=10)
     parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--tile", help="q rows x keys, as 1024x512: the "
+                        "backward also at this tile, forced")
     args = parser.parse_args()
+    forced = args.tile and dict(zip(("block_q", "block_k"),
+                                    map(int, args.tile.split("x"))))
     device = jax.devices()[0]
     if device.platform != "tpu":
         raise SystemExit(f"needs a TPU, found {device.platform}")
@@ -103,7 +139,7 @@ def main():
     for name in args.shapes.split(","):
         shape = dict(SHAPES[name])
         B, T, S, H, Hk = (shape.pop(k) for k in ("B", "T", "S", "H", "Hk"))
-        D, Dv = shape.pop("D", 128), 128
+        D, Dv = shape.pop("D", 128), shape.pop("Dv", 128)
         how = dict(causal=False, window=None, stair=None, scale=D ** -0.5,
                    block_q=None, block_k=None, interpret=False)
         how.update(shape)
@@ -121,22 +157,49 @@ def main():
                                     ("v_in_place", v, Hk))[:1 + (H == Hk)]:
             forward = jax.jit(lambda q, k, v, n=v_heads: fa._flash_fwd(
                 q, k, v, with_lse=True, v_heads=n, **how))
-            backward = jax.jit(
-                lambda q, k, v, o, lse, do, n=v_heads: fa._flash_backward(
-                    q, k, v, o, lse, do, v_heads=n, **how))
+
+            def backward_at(q, k, v, o, lse, do, n=v_heads, **tile):
+                return fa._flash_backward(q, k, v, o, lse, do, v_heads=n,
+                                          **{**how, **tile})
+
+            backward = jax.jit(backward_at)
             o, lse = forward(q_, k_, v_)
             dq, dk, dv = backward(q_, k_, v_, o, lse, do_)
             kept[layout] = (lse, o, dq, dk,
                             dv if v_heads == 1 else fold(dv, Hk))
-            for use, fn, operands, moved in (
-                    ("forward", forward, (q_, k_, v_),
-                     nbytes(q_, k_, v_, o, lse)),
-                    ("backward", backward, (q_, k_, v_, o, lse, do_),
-                     nbytes(q_, k_, v_, o, do_, lse, lse, dq, dk, dv))):
+            uses = [("forward", forward, (q_, k_, v_),
+                     nbytes(q_, k_, v_, o, lse), {})]
+            grads = (q_, k_, v_, o, lse, do_)
+            moved = nbytes(q_, k_, v_, o, do_, lse, lse, dq, dk, dv)
+            planned = dict(T=T, S=S, D=D, dtype=q.dtype, v_dim=Dv,
+                           group=H // Hk, causal=how["causal"],
+                           window=how["window"], stair=how["stair"])
+            # (use, the jitted backward, the tile forced; None: the pair)
+            ways = [("backward", backward, {}),
+                    ("backward_pair", jax.jit(the_pair(backward_at)), None)]
+            if forced:
+                ways.append(("backward_forced", jax.jit(functools.partial(
+                    backward_at, **forced)), forced))
+            for use, fn, tile in ways:
+                kernels = PAIR if tile is None else fa.flash_bwd_kernels(
+                    **planned, **tile)
+                said = {"kernels": {
+                    kernel: "%d x %d" % t[:2] + (
+                        ", " + t.exit if kernel == "flash_bwd_dkv_dq" else "")
+                    for kernel in kernels
+                    for t in [fa.flash_tiles(kernel, **planned, **(tile or {}))]}}
+                if fn is not backward:  # how far the plan's lie from these
+                    said["plan_s_largest_difference"] = {
+                        what: float(jnp.abs(a.astype(jnp.float32)
+                                            - b.astype(jnp.float32)).max())
+                        for what, a, b in zip(("dq", "dk", "dv"),
+                                              (dq, dk, dv), fn(*grads))}
+                uses.append((use, fn, grads, moved, said))
+            for use, fn, operands, moved, said in uses:
                 ms = timed(fn, *operands, calls=args.calls)
                 report(shape=name, use=use, layout=layout,
                        ms_a_call=round(ms, 4), bytes=moved,
-                       gb_per_s=round(moved / ms / 1e6, 1))
+                       gb_per_s=round(moved / ms / 1e6, 1), **said)
             if layout != "folded":
                 report(shape=name, use="equals_folded", layout=layout, equal={
                     what: bool(jnp.array_equal(a, b, equal_nan=True))

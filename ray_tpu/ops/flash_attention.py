@@ -33,10 +33,25 @@ not, the output lies in HBM whole (`pl.ANY`) with no block in VMEM, and a
 completed tile of the sum is rounded into a staged tile and copied to its
 rows by the kernel's own DMA, once, so that the sums alone stay:
 `keyevl2.tokens16k`'s layer, T 16,384 at 8 query heads a key-value head,
-25.2 MB of sums where the blocks would be 25.2 MB more. `flash_bwd_kernels`
+25.2 MB of sums where the blocks would be 25.2 MB more. The tile exit is
+offered at every width (PR 75). Mosaic takes no DMA to rows that are no
+whole lanes, so a gradient 64 or 192 wide leaves 128 or 256 wide: the
+staged tile and the output are `_whole_lanes` of the width, the flush
+rounds the sum into the first columns (the rest zero, written at the row's
+first step), and the caller slices the width back; the plan pays for the
+padded columns by their bytes (`_padded_exit_bytes`), and at whole lanes
+every array, tile and program is the one it was. Under a group, dk and dv
+that together fill no more than a tile of lanes (64 and 64) are the columns
+of one sum, one staged tile and one output `[B Hk, S, 128]`
+(`_share_lanes`: half the VMEM of two sums half empty; the MXU places dv's
+product in the upper columns through operands `_at_lanes` pads), which the
+caller splits: `phi4flash.tokens16k`'s paired heads (64 / 128 under a group
+of 2 at T 16,384) and `granite4hmicro.longctx`'s (64 / 64 under a group of
+4 at T 32,768) fit so. `flash_bwd_kernels`
 decides whether either fits VMEM beside some tile and logs the choice once;
 where neither does, `flash_bwd_dq` (k innermost) and `flash_bwd_dkv` run as
-before, each making the tile for itself, seven matmuls between them.
+before, each making the tile for itself, seven matmuls between them (no
+cell's shape since PR 75).
 
 Grouped-query attention makes no copy of k or v. They reach every kernel at
 their own heads, `[B*Hk, S, D]` and `[B*Hk, S, Dv]`, and with heads folded
@@ -176,8 +191,10 @@ The tile program, the same in all four kernels:
   accumulator `Dv` wide (latent attention: 128 + 64 rotary against 128).
   A block's last dimension is the array's whole width, so 192 goes to the
   MXU as it is, with no zero column in HBM; in VMEM it fills two tiles of
-  128 lanes, which is what `_vmem_bytes` counts. With `Dv == D` every
-  shape, tile and body is the one-width kernel's.
+  128 lanes, which is what `_vmem_bytes` counts. (The one exception is a
+  row-long gradient of `flash_bwd_dkv_dq` that leaves a tile at a time: a
+  DMA's rows are whole lanes, above.) With `Dv == D` every shape, tile and
+  body is the one-width kernel's.
 """
 
 from __future__ import annotations
@@ -264,6 +281,20 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def _whole_lanes(width: int) -> int:
+    """`width` rounded up to whole tiles of 128 lanes: what a row of it
+    fills in VMEM, and the least a DMA to its rows may be."""
+    return _cdiv(width, _LANES) * _LANES
+
+
+def _share_lanes(D: int, Dv: int, group: int) -> bool:
+    """Whether `flash_bwd_dkv_dq` by tile holds dk's and dv's row-long sums
+    as the columns of one: under a group (where they are row-long), at
+    widths that together fill no more than a tile of lanes (64 and 64),
+    where each alone would fill one half empty."""
+    return group > 1 and D + Dv <= _LANES
+
+
 def _active_tiles(T, S, block_q, block_k, causal, window=None,
                   stair=None) -> int:
     """Tiles with a body: all of them, or under causal those that hold a
@@ -345,9 +376,10 @@ def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
     where `group` query heads share a key-value head, that head's whole dk
     and dv, `S` rows in whole key tiles, in place of one key tile's.
     `by_tile`: the row-long gradients have no block, their sums alone stay
-    and a tile of each is staged for the DMA that takes it out."""
-    qk = _cdiv(D, _LANES) * _LANES
-    vo = qk if Dv is None else _cdiv(Dv, _LANES) * _LANES
+    and a tile of each is staged for the DMA that takes it out; dk and dv
+    that share a tile of lanes (`_share_lanes`) have one sum between them."""
+    qk = _whole_lanes(D)
+    vo = qk if Dv is None else _whole_lanes(Dv)
     q_qk, q_vo = block_q * qk, block_q * vo  # elements: q, dq; o, do
     k_qk, k_vo = block_k * qk, block_k * vo  # k, dk; v, dv
     row = block_q * _LANES * 4  # an lse/delta block, or m or l
@@ -359,12 +391,15 @@ def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
         scratch = q_qk * 4
     else:
         blocks = (q_qk + q_vo + 2 * k_qk + 2 * k_vo) * itemsize + 2 * row
-        scratch = (k_qk + k_vo) * 4
+        k_sums = k_qk + k_vo  # a key tile of dk's and dv's sums: or of one
+        if by_tile and _share_lanes(D, D if Dv is None else Dv, group):
+            k_sums = block_k * _LANES
+        scratch = k_sums * 4
         if kernel == "flash_bwd_dkv_dq":
             dq_row = _cdiv(T, block_q) * q_qk
             scratch += dq_row * 4
             if group > 1:  # dk and dv leave and are summed by the row too
-                more = (_cdiv(S, block_k) - 1) * (k_qk + k_vo)
+                more = (_cdiv(S, block_k) - 1) * k_sums
                 scratch += more * 4
             if not by_tile:
                 blocks += dq_row * itemsize
@@ -374,7 +409,7 @@ def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None,
                 scratch += q_qk * itemsize
                 if group > 1:
                     blocks -= (k_qk + k_vo) * itemsize
-                    scratch += (k_qk + k_vo) * itemsize
+                    scratch += k_sums * itemsize
     f32_tiles, dtype_tiles = _LIVE_TILES[kernel]
     live = block_q * block_k * (4 * f32_tiles + itemsize * dtype_tiles)
     if sparse:  # the mask's block, a bit a pair, and its tile as 32 bits
@@ -422,6 +457,33 @@ _COST_US = {
 # tile: s, dq, dk | dp, dv.
 _PAIR_MATMULS = {"flash_fwd": (1, 1), "flash_bwd_dq": (2, 1),
                  "flash_bwd_dkv": (2, 2), "flash_bwd_dkv_dq": (3, 2)}
+
+
+# A byte of HBM traffic, in microseconds: a v5e's 819 GB/s.
+_HBM_US_A_BYTE = 1 / 819e3
+
+
+def _padded_exit_bytes(rows_q: int, rows_k: int, D: int, Dv: int,
+                       itemsize: int, group: int = 1) -> int:
+    """What the tile-at-a-time exit of a (batch, head) row moves in HBM
+    that the row's blocks would not. Mosaic takes no DMA to rows that are
+    no whole lanes ("Slice shape along dimension 2 must be aligned to
+    tiling (128)"), so a row-long gradient narrower than its lanes leaves
+    at `_whole_lanes` of its width, the columns past it zero, and a slice
+    after the call reads that and writes the width: twice the padded
+    array. dq of `rows_q` rows always; dk and dv of `rows_k` where a group
+    makes them row-long, a `group`-th of each to a query head's row.
+    Nothing at widths of whole lanes, whose arrays are the ones they were."""
+    def padded(rows, width):
+        lanes = _whole_lanes(width)
+        return 2 * rows * lanes * itemsize if lanes != width else 0
+
+    moved = padded(rows_q, D)
+    if _share_lanes(D, Dv, group):  # one array of dk and dv, split after
+        moved += 2 * rows_k * _LANES * itemsize // group
+    elif group > 1:
+        moved += (padded(rows_k, D) + padded(rows_k, Dv)) // group
+    return moved
 
 
 def _pairs_factor(kernel: str, D: int, Dv: int) -> float:
@@ -484,6 +546,9 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
                            sparse, by_tile=exit == "tile")
         cost = steps * step_us + active * (
             rows_us * bq / 1024 + pairs_us * bq * bk / 2 ** 20)
+        if exit == "tile":
+            cost += _HBM_US_A_BYTE * _padded_exit_bytes(
+                _cdiv(T, bq) * bq, _cdiv(S, bk) * bk, D, Dv, itemsize, group)
         return FlashTiles(bq, bk, steps, active / steps, vmem,
                           max(_DEFAULT_VMEM, 2 * vmem), cost, exit)
 
@@ -501,11 +566,10 @@ def flash_tiles(kernel: str, T: int, S: int, D: int, dtype, *,
     # The cheapest tile that fits, and of the exits that have room for it
     # the row's blocks: the program of every shape whose cheapest tile had
     # room beside them, 1.5 to 1.8 % the faster at T 4096 (PERF.md section 6,
-    # PR 49). The sums alone at widths of whole lanes only (Mosaic takes
-    # no DMA to rows of 192 or 64: "Slice shape along dimension 2 must be
-    # aligned to tiling (128)").
+    # PR 49). The sums alone at every width: one of no whole lanes leaves
+    # padded to them (`_padded_exit_bytes`), which its plan pays for.
     fits = fitting("block")
-    if kernel == "flash_bwd_dkv_dq" and D % _LANES == Dv % _LANES == 0:
+    if kernel == "flash_bwd_dkv_dq":
         fits += fitting("tile")
     return min(fits or [plan(qs[0], ks[0], "block")],
                key=lambda p: (p.cost_us, p.exit == "tile"))
@@ -530,8 +594,9 @@ def flash_bwd_kernels(T: int, S: int, D: int, dtype, *, causal: bool = True,
     than the second pass saves. With the row's blocks beside the sums
     (`FlashTiles.exit` "block"; in bf16, causal, self-attention) that is T
     up to about 21k at q and k 192 wide and 44k at 64, 14k and 8k under a
-    group; with the sums alone ("tile", at widths of whole lanes: 128) 88k,
-    and 29k under a group. Under a window its grid
+    group; with the sums alone ("tile") 88k at 128 and at 64, 44k at 192,
+    and under a group 29k, or 45k where dk and dv share a tile of lanes.
+    Under a window its grid
     comes to a q tile's dq only through a key column whose band reaches it:
     where some q tile lies past every key it could see (more queries than
     keys) the two kernels run, whose dq walks every q row."""
@@ -1031,13 +1096,28 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     return o, (q, k, v, o, lse)
 
 
-def _exit_said(kernel: str, tiles: FlashTiles) -> str:
+def _exit_said(kernel: str, tiles: FlashTiles, D: int, Dv: int,
+               group: int = 1) -> str:
     """What a log line says of how `flash_bwd_dkv_dq`'s row-long gradients
-    leave VMEM (`FlashTiles.exit`); nothing of another kernel."""
+    leave VMEM (`FlashTiles.exit`), and by tile which of them (dq; under a
+    group dk and dv) leave padded to whole lanes, at which width, or as
+    the columns of one array (`_share_lanes`); nothing of another kernel."""
     if kernel != "flash_bwd_dkv_dq":
         return ""
-    return (", out a tile at a time by DMA, the row's f32 sums alone held"
-            if tiles.exit == "tile" else ", out by the row's blocks")
+    if tiles.exit != "tile":
+        return ", out by the row's blocks"
+    said = ", out a tile at a time by DMA, the row's f32 sums alone held"
+    shared = _share_lanes(D, Dv, group)
+    alone = [("dq", D)] + [("dk", D), ("dv", Dv)] * (group > 1 and not shared)
+    padded = ", ".join(
+        "%s %d as %d" % (name, width, _whole_lanes(width))
+        for name, width in alone if width != _whole_lanes(width))
+    if padded:
+        said += ", padded to whole lanes: " + padded
+    if shared:
+        said += ", dk %d and dv %d wide as the columns of one array of %d" % (
+            D, Dv, _LANES)
+    return said
 
 
 @functools.lru_cache(maxsize=None)
@@ -1069,7 +1149,7 @@ def _log_bwd_kernels(kernels, T, S, D, Dv, dtype, causal, block_q, block_k,
             "flash backward at T %d, S %d, D %d, Dv %d, %s, %s: %s, tile %d "
             "x %d, VMEM %d bytes of a limit of %d, %s%s", T, S, D, Dv, dtype,
             lie, kernel, t.block_q, t.block_k, t.vmem_bytes,
-            t.vmem_limit_bytes, heads, _exit_said(kernel, t))
+            t.vmem_limit_bytes, heads, _exit_said(kernel, t, D, Dv, group))
     if window is None:
         return
     for kernel in ("flash_fwd", *kernels):
@@ -1258,7 +1338,7 @@ def _attn_bwd_dkv_kernel(
     block_q: int, block_k: int, num_q: int, num_k: int, steps: int,
     scale: float, causal: bool, seq_q: int, seq_k: int, with_dq: bool = False,
     window: Optional[int] = None, group: int = 1, by_tile: bool = False,
-    mask_ref=None, stair=None,
+    shared: bool = False, mask_ref=None, stair=None,
 ):
     """dk and dv of k tile `ki`, summed over the q tiles the grid walks
     innermost. `with_dq` (`flash_bwd_dkv_dq`): dq too, from the same p and
@@ -1283,15 +1363,27 @@ def _attn_bwd_dkv_kernel(
     output is the whole array where it lies (`pl.ANY`), and the flush that
     would have rounded a tile of the sum into the row's block rounds it
     into a staged tile and copies that to its rows (`_round_out`): dq
-    always, dk and dv where a group makes them row-long."""
+    always, dk and dv where a group makes them row-long.
+
+    `shared` (by tile, under a group, `_share_lanes`): dk and dv are together
+    no wider than a tile of lanes, where each alone would fill one. Their
+    sums are the columns of one, `[S, 128]`: dk the first `D`, dv the `Dv`
+    after them, a key tile's products made there by the MXU, which takes q
+    and do as `[bq, 128]` operands zero outside those columns (`_at_lanes`);
+    one staged tile, one output `[B Hk, S, 128]`, which the caller splits."""
     from jax.experimental import pallas as pl
 
-    if with_dq:  # `by_tile`: and dq's staged tile, dk's and dv's, semaphores
+    if shared:  # dq's staged tile, dk's and dv's one, semaphores
+        dkv_ref, dq_ref, dkv_acc_ref, dq_acc_ref, *staged = outputs_and_sums
+        kv_sums = (dkv_acc_ref,)
+    elif with_dq:  # `by_tile`: and dq's staged tile, dk's and dv's, semaphores
         (dk_ref, dv_ref, dq_ref,
          dk_acc_ref, dv_acc_ref, dq_acc_ref, *staged) = outputs_and_sums
-        sems = staged.pop() if by_tile else None
+        kv_sums = (dk_acc_ref, dv_acc_ref)
     else:
-        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = outputs_and_sums
+        dk_ref, dv_ref, *kv_sums = outputs_and_sums
+        dk_acc_ref, dv_acc_ref = kv_sums
+    sems = staged.pop() if by_tile else None
     acc, out = ..., 0  # tile `ki` in dk's and dv's sums, and in their blocks
     if group == 1 or not with_dq:
         ki = pl.program_id(1)
@@ -1311,12 +1403,26 @@ def _attn_bwd_dkv_kernel(
         bh = bkv = pl.program_id(0)
         if group > 1:
             bh = bkv * group + head
+        # A staged tile is whole lanes wide, its sum the gradient's width:
+        # the columns past it leave as zeros, written at the row's first
+        # step and never again (`_round_out` writes the sum's columns).
+        sums = (dq_acc_ref, *kv_sums)
+        padded = [stage_ref for stage_ref, acc_ref in zip(staged, sums)
+                  if stage_ref.shape[1] != acc_ref.shape[1]]
+        if padded:
+            at_row_start = functools.reduce(jnp.logical_and, [
+                pl.program_id(i) == 0 for i in range(1, 3 + (group > 1))])
+
+            @pl.when(at_row_start)
+            def _init_padding():
+                for stage_ref in padded:
+                    stage_ref[...] = jnp.zeros_like(stage_ref)
     shape = dict(block_q=block_q, block_k=block_k, causal=causal,
                  seq_q=seq_q, seq_k=seq_k, window=window, stair=stair)
 
     @pl.when(walk == 0)
     def _init():
-        for acc_ref in (dk_acc_ref, dv_acc_ref):
+        for acc_ref in kv_sums:
             acc_ref[acc] = jnp.zeros(
                 (block_k, acc_ref.shape[1]), acc_ref.dtype)
 
@@ -1337,8 +1443,13 @@ def _attn_bwd_dkv_kernel(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
             masked=masked, scale=scale, mask_ref=mask_ref, **shape,
         )
-        dv_acc_ref[acc] += _dot(p, do, _TN)  # [bk, Dv]
-        dk_acc_ref[acc] += _dot(ds, q, _TN)  # [bk, D]
+        if shared:  # [bk, lanes]: dk in the first D columns, dv after them
+            dkv_acc_ref[acc] += (
+                _dot(ds, _at_lanes(q, 0), _TN)
+                + _dot(p, _at_lanes(do, q.shape[1]), _TN))
+        else:
+            dv_acc_ref[acc] += _dot(p, do, _TN)  # [bk, Dv]
+            dk_acc_ref[acc] += _dot(ds, q, _TN)  # [bk, D]
         if with_dq:
             dq_acc_ref[rows, :] += _dot(ds, k, _NN)  # [bq, D]
 
@@ -1348,6 +1459,10 @@ def _attn_bwd_dkv_kernel(
 
     @pl.when(walk == group * steps - 1)
     def _flush():
+        if shared:
+            _round_out((dkv_acc_ref[acc], staged[1], dkv_ref.at[bkv, acc],
+                        sems.at[1]))
+            return
         if by_tile and group > 1:
             _round_out(
                 (dk_acc_ref[acc], staged[1], dk_ref.at[bkv, acc], sems.at[1]),
@@ -1371,16 +1486,28 @@ def _attn_bwd_dkv_kernel(
             dq_ref[0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
 
 
+def _at_lanes(x, first: int):
+    """`x` [rows, width] as [rows, 128]: its columns at lanes `first`
+    onward, zero elsewhere. As an operand of a matmul it puts the product's
+    columns there at no more passes of the MXU, whose array is 128 wide."""
+    return jnp.pad(x, ((0, 0), (first, _LANES - first - x.shape[1])))
+
+
 def _round_out(*tiles):
     """Each (a tile of an f32 sum, its staging tile in the output's dtype,
     the output's rows where they lie, a DMA semaphore): rounded once into
     the staging tile and copied out by the kernel's own DMA. Every copy is
-    started, then every one waited for: the staging tiles are free again."""
+    started, then every one waited for: the staging tiles are free again.
+    The staging tile and the rows are whole lanes wide; a sum narrower than
+    that is rounded into their first columns, the rest as they were."""
     from jax.experimental.pallas import tpu as pltpu
 
     copies = []
     for total, stage_ref, rows_ref, sem in tiles:
-        stage_ref[...] = total.astype(stage_ref.dtype)
+        if total.shape[1] == stage_ref.shape[1]:
+            stage_ref[...] = total.astype(stage_ref.dtype)
+        else:
+            stage_ref[:, :total.shape[1]] = total.astype(stage_ref.dtype)
         copies.append(pltpu.make_async_copy(stage_ref, rows_ref, sem))
         copies[-1].start()
     for copy in copies:
@@ -1473,12 +1600,14 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         name, q, k, v, causal, block_q, block_k, window, mask, stair, v_heads)
     block_q, block_k = tiles.block_q, tiles.block_k
     by_tile = with_dq and (by_tile or tiles.exit == "tile")
+    shared = by_tile and _share_lanes(D, Dv, group)
     kernel = functools.partial(
         _attn_bwd_dkv_kernel,
         stair=stair,
         block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
         steps=steps, scale=scale, causal=causal, seq_q=T, seq_k=S,
         with_dq=with_dq, window=window, group=group, by_tile=by_tile,
+        shared=shared,
     )
 
     q_block = _q_block_under_k(causal, block_q, block_k, num_q, window,
@@ -1551,6 +1680,11 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         pltpu.VMEM((dk_rows, D), jnp.float32),
         pltpu.VMEM((dk_rows, Dv), jnp.float32),
     ]
+    if shared:  # dk and dv the columns of one output and one sum
+        out_specs, scratch_shapes = out_specs[:1], [
+            pltpu.VMEM((dk_rows, _LANES), jnp.float32)]
+        out_shape = [jax.ShapeDtypeStruct(
+            (BH // group, k_rows, _LANES), k.dtype)]
     if with_dq:
         rows = num_q * block_q  # T in whole q tiles: every step's are there
         out_specs.append(pl.BlockSpec((1, rows, D), of_grid(row_block)))
@@ -1558,13 +1692,22 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
         scratch_shapes.append(pltpu.VMEM((rows, D), jnp.float32))
         # dq is summed over k tiles too, dk and dv over a group's heads
         inner = ("arbitrary",) * (len(grid) - 1)
-    if by_tile:  # the row-long outputs lie in HBM: a staged tile of each
-        out_specs[2] = pl.BlockSpec(memory_space=pl.ANY)
-        scratch_shapes.append(pltpu.VMEM((block_q, D), q.dtype))
+    if by_tile:
+        # The row-long outputs lie in HBM, whole lanes wide (a DMA takes no
+        # less), with a staged tile of each: at a width of whole lanes the
+        # array it was, at another its columns first and zeros after.
+        def out_by_tile(i, tile_rows):
+            lanes = _whole_lanes(out_shape[i].shape[2])
+            out_specs[i] = pl.BlockSpec(memory_space=pl.ANY)
+            out_shape[i] = jax.ShapeDtypeStruct(
+                (*out_shape[i].shape[:2], lanes), out_shape[i].dtype)
+            scratch_shapes.append(
+                pltpu.VMEM((tile_rows, lanes), out_shape[i].dtype))
+
+        out_by_tile(-1, block_q)  # dq, then under a group dk and dv
         if group > 1:
-            out_specs[:2] = [pl.BlockSpec(memory_space=pl.ANY)] * 2
-            scratch_shapes += [pltpu.VMEM((block_k, D), k.dtype),
-                               pltpu.VMEM((block_k, Dv), v.dtype)]
+            for i in range(len(out_specs) - 1):
+                out_by_tile(i, block_k)
         scratch_shapes.append(pltpu.SemaphoreType.DMA((3,)))
     out = _pallas_call(
         kernel,
@@ -1579,10 +1722,17 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, scale,
     )(*operands)
     if not with_dq:
         return out
-    dk, dv, dq = out
-    if k_rows != S:
-        dk, dv = dk[:, :S], dv[:, :S]
-    return (dq if rows == T else dq[:, :T]), dk, dv
+    if shared:
+        dkv, dq = out
+        dk, dv = dkv[..., :D], dkv[..., D:]
+    else:
+        dk, dv, dq = out
+    # each as its operand is: the rows past the sequence's and, by tile,
+    # the columns past the width go
+    return tuple(
+        grad if grad.shape == like.shape
+        else grad[:, :like.shape[1], :like.shape[2]]
+        for grad, like in zip((dq, dk, dv), (q, k, v)))
 
 
 def _xla_attention_bhtd(q, k, v, *, causal, scale, window=None):

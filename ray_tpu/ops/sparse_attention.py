@@ -576,7 +576,8 @@ def _log_path(T, H, Hk, D, Dv, dtype, topk, tile, block_q):
         "causal walk under it by %s; the index loss by the kernel index_loss",
         T, H, Hk, D, Dv, dtype, topk, tile, T * T // 8,
         ", ".join("%s_sparse %d x %d%s" % (
-            kernel, t.block_q, t.block_k, _exit_said(kernel, t))
+            kernel, t.block_q, t.block_k,
+            _exit_said(kernel, t, D, Dv, H // Hk))
             for kernel, t in zip(kernels, map(tiles, kernels))))
 
 

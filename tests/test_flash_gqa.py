@@ -480,6 +480,35 @@ def test_the_backward_s_line_says_the_group(caplog):
     assert lines[1].endswith(", no group, out by the row's blocks")
 
 
+def test_the_backward_s_line_says_what_leaves_padded(caplog):
+    """At the three shapes PR 75 moved to the tile exit
+    (`phi4flash.tokens16k` whole, `granite4hmicro.longctx`,
+    `kimilinear.tokens16k`'s latent layer) the line names the arrays that
+    leave wider than they are; at whole lanes (`mellum2.ep4`) it ends as it
+    did."""
+    fa._log_bwd_kernels.cache_clear()
+    one = ("flash_bwd_dkv_dq",)
+    with caplog.at_level(logging.INFO, logger=fa.logger.name):
+        for T, width, v_width, group in [(16384, 64, 128, 2), (32768, 64, 64, 4),
+                                         (16384, 192, 128, 1),
+                                         (16384, 128, 128, 8)]:
+            fa._log_bwd_kernels(one, T, T, width, v_width, "bfloat16", True,
+                                None, None, group=group)
+    held = ", out a tile at a time by DMA, the row's f32 sums alone held"
+    lines = [r.getMessage() for r in caplog.records]
+    assert "flash_bwd_dkv_dq, tile 1024 x 768, VMEM 46006272 bytes" in lines[0]
+    assert lines[0].endswith(
+        "2 query heads a key-value head by index map" + held
+        + ", padded to whole lanes: dq 64 as 128, dk 64 as 128")
+    assert "flash_bwd_dkv_dq, tile 768 x 768, VMEM 49152000 bytes" in lines[1]
+    assert lines[1].endswith(
+        held + ", padded to whole lanes: dq 64 as 128, dk 64 and dv 64 wide "
+        "as the columns of one array of 128")
+    assert lines[2].endswith(
+        "no group" + held + ", padded to whole lanes: dq 192 as 256")
+    assert lines[3].endswith("by index map" + held)
+
+
 # -------------------------------------- the row-long gradients' two exits
 
 def backward_operands(group, seq, width, v_width, window, masked):
@@ -507,8 +536,10 @@ def backward_operands(group, seq, width, v_width, window, masked):
     (200, 32, 32, None, False), (200, 32, 32, None, True),
     (384, 32, 32, 130, False),  # a data mask is walked with no window
     (256, 64, 32, None, False), (256, 64, 32, None, True),
+    # dk and dv too wide for one tile of lanes between them
+    (256, 64, 128, None, False), (200, 96, 64, None, True),
 ], ids=["causal", "causal-mask", "ragged", "ragged-mask", "window",
-        "two-widths", "two-widths-mask"])
+        "two-widths", "two-widths-mask", "64-128", "96-64-ragged-mask"])
 @pytest.mark.parametrize("group", [1, WIDEST])
 def test_a_tile_at_a_time_is_the_row_s_block_to_the_bit(
         group, seq, width, v_width, window, masked):
@@ -534,15 +565,170 @@ def test_a_tile_at_a_time_is_the_row_s_block_to_the_bit(
     mappings = pallas_calls(by_tile, *operands_)[name][
         "grid_mapping"].block_mappings
     rows = -(-seq // 128) * 128
-    outputs = [tuple(getattr(b, "block_size", b) for b in m.block_shape)
-               for m in mappings[-3:]]  # dk, dv, dq
-    # what lies where the compiler put it has the array for its block
-    # (dk's and dv's tiles stay the pipeline's without a group)
-    k_block = (1, 128) if group == 1 else (KV_ROWS, rows)
-    assert outputs == [(*k_block, width), (*k_block, v_width),
-                       (KV_ROWS * group, rows, width)]
-    assert [str(m.block_aval.memory_space) for m in mappings[-3:]] == (
-        ["None", "None", "any"] if group == 1 else ["any"] * 3)
+    # what lies where the compiler put it has the array for its block,
+    # whole lanes wide: a DMA to its rows takes no less (dk's and dv's
+    # tiles stay the pipeline's without a group, at their own widths; under
+    # one, widths that fill no more than a tile of lanes between them leave
+    # as the columns of one array)
+    lanes = fa._whole_lanes
+    if group == 1:
+        expected = [(1, 128, width), (1, 128, v_width)]  # dk, dv
+    elif width + v_width <= 128:
+        expected = [(KV_ROWS, rows, 128)]  # dk | dv
+    else:
+        expected = [(KV_ROWS, rows, lanes(width)),
+                    (KV_ROWS, rows, lanes(v_width))]
+    expected.append((KV_ROWS * group, rows, lanes(width)))  # dq
+    mappings = mappings[-len(expected):]
+    assert [tuple(getattr(b, "block_size", b) for b in m.block_shape)
+            for m in mappings] == expected
+    assert [str(m.block_aval.memory_space) for m in mappings] == (
+        ["None", "None", "any"] if group == 1 else ["any"] * len(expected))
+
+
+# (query heads a key-value head, q and k's width, v's): `phi4flash.tokens16k`'s
+# paired heads, dk padded to whole lanes and dv whole; and
+# `granite4hmicro.longctx`'s, dk and dv the halves of one tile of lanes
+NARROW = {"64-128-group2": (2, 64, 128), "64-64-group4": (4, 64, 64)}
+
+
+@pytest.mark.parametrize("seq,window", [(256, None), (200, None), (384, 130)],
+                         ids=["causal", "ragged", "window"])
+@pytest.mark.parametrize("heads", list(NARROW))
+def test_heads_narrower_than_a_lane_tile_leave_by_tile(heads, seq, window):
+    """The one kernel by tile at heads of 64, as `flash_tiles` now offers
+    it: dq (and under the group dk, or dk beside dv) leaves padded to whole
+    lanes and the caller takes the columns. dq, dk and dv finite, equal to
+    `flash_bwd_dq` + `flash_bwd_dkv`'s to the bit, and to the masked
+    softmax's within the chip smoke's tolerance."""
+    group, width, v_width = NARROW[heads]
+    (q, k, v, do, lse, delta), how = backward_operands(
+        group, seq, width, v_width, window, False)
+    ours = fa._flash_bwd_dkv(q, k, v, do, lse, delta, with_dq=True,
+                             by_tile=True, **how)
+    pair = (fa._flash_bwd_dq(q, k, v, do, lse, delta, **how),
+            *fa._flash_bwd_dkv(q, k, v, do, lse, delta, **how))
+
+    def unfolded(x):  # [rows, seq, d] as the softmax's [1, seq, rows, d]
+        return x.astype(jnp.float32).transpose(1, 0, 2)[None]
+
+    with jax.default_matmul_precision("highest"):
+        softmax = jax.vjp(
+            functools.partial(masked_softmax, window=window),
+            *map(unfolded, (q, k, v)))[1](unfolded(do))
+    for grad, theirs, wanted, like in zip(ours, pair, softmax, (q, k, v)):
+        assert grad.shape == like.shape and grad.dtype == like.dtype
+        assert np.isfinite(np.asarray(grad, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(grad), np.asarray(theirs))
+        assert rel_err(unfolded(grad), wanted) <= chip_smoke.KERNEL_TOLERANCE
+
+
+# Every token cell's attention shapes as its step hands them to the plan
+# (recorded while each cell's step was lowered, PR 75): (T, S, q and k's
+# width, v's, the rest of the shape) -> (tile, exit) of the one kernel.
+# Up to `lagunaxs2` the row's blocks leave the cheapest tile its room; from
+# `keyevl2` on the sums alone do, at whole lanes since PR 49; the last four
+# are PR 75's: rows of 192 and 64 leave a tile at a time padded to whole
+# lanes (`kimilinear` left by its blocks at 1024 x 512; `phi4flash` and
+# `granite` ran `flash_bwd_dq` and `flash_bwd_dkv`).
+CELLS = {
+    "mistral7b": (4096, 4096, 128, 128, dict(group=4), (1024, 1024), "block"),
+    "olmoe": (4096, 4096, 128, 128, {}, (1024, 1024), "block"),
+    "lfm2moe": (8192, 8192, 64, 64, dict(group=4), (1024, 1024), "block"),
+    "dsv2lite": (8192, 8192, 192, 128, {}, (1024, 1024), "block"),
+    "nemotron3nano": (8192, 8192, 128, 128, dict(group=16), (1024, 1024),
+                      "block"),
+    "solaropen2": (8192, 8192, 128, 128, dict(group=8), (1024, 1024), "block"),
+    "ouro": (16384, 16384, 128, 128, {}, (1024, 1024), "block"),
+    "evabyte-windows": (2048, 2048, 128, 128, {}, (512, 512), "block"),
+    "evabyte-stair": (8192, 512, 128, 128, dict(
+        causal=False, stair=(2048, 128)), (1024, 128), "block"),
+    "lagunaxs2-full": (8192, 8192, 128, 128, dict(group=6), (1024, 1024),
+                       "block"),
+    "lagunaxs2-sliding": (8192, 8192, 128, 128, dict(group=8, window=512),
+                          (512, 512), "block"),
+    "keyevl2": (16384, 16384, 128, 128, dict(
+        group=8, sparse=True, block_k=1024), (512, 1024), "tile"),
+    "mellum2-full": (16384, 16384, 128, 128, dict(group=8), (1024, 768),
+                     "tile"),
+    "mellum2-sliding": (16384, 16384, 128, 128, dict(group=8, window=1024),
+                        (512, 512), "tile"),
+    "sdar": (16384, 16384, 128, 128, dict(
+        causal=False, group=16, stair=(4, 4)), (1024, 768), "tile"),
+    "kimilinear-latent": (16384, 16384, 192, 128, {}, (1024, 1024), "tile"),
+    "phi4flash-whole": (16384, 16384, 64, 128, dict(group=2), (1024, 768),
+                        "tile"),
+    "phi4flash-sliding": (16384, 16384, 64, 128, dict(group=2, window=512),
+                          (512, 512), "tile"),
+    "granite4hmicro": (32768, 32768, 64, 64, dict(group=4), (768, 768),
+                       "tile"),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_every_cell_s_backward_is_the_one_kernel_at_its_tile_and_exit(cell):
+    T, S, width, v_width, rest, tile, exit = CELLS[cell]
+    shape = dict(v_dim=v_width, **rest)
+    assert fa.flash_bwd_kernels(T, S, width, jnp.bfloat16, **shape) == (
+        "flash_bwd_dkv_dq",)
+    tiles = fa.flash_tiles("flash_bwd_dkv_dq", T, S, width, jnp.bfloat16,
+                           **shape)
+    assert (tiles[:2], tiles.exit) == (tile, exit)
+    assert tiles.vmem_bytes < tiles.vmem_limit_bytes <= fa._MAX_VMEM
+    if exit == "tile":  # no tile has room beside the row's blocks, or only
+        # a costlier one: the sums alone were not taken for their own sake
+        blocks = [fa._vmem_bytes(
+            "flash_bwd_dkv_dq", *tile, width, 2, v_width, T, S,
+            group=rest.get("group", 1), sparse=rest.get("sparse", False))]
+        assert 2 * blocks[0] > fa._MAX_VMEM
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_every_cell_s_log_line_names_its_exit(cell):
+    """What the backward's line ends with a cell (`_exit_said`): the exit,
+    and by tile what leaves wider than it is; nothing of another kernel."""
+    T, S, width, v_width, rest, _, exit = CELLS[cell]
+    group = rest.get("group", 1)
+    tiles = fa.flash_tiles("flash_bwd_dkv_dq", T, S, width, jnp.bfloat16,
+                           v_dim=v_width, **rest)
+    said = fa._exit_said("flash_bwd_dkv_dq", tiles, width, v_width, group)
+    held = ", out a tile at a time by DMA, the row's f32 sums alone held"
+    narrow = ", padded to whole lanes: dq 64 as 128, "
+    assert said == {
+        "block": ", out by the row's blocks", "tile": held}[exit] + {
+        "kimilinear-latent": ", padded to whole lanes: dq 192 as 256",
+        "phi4flash-whole": narrow + "dk 64 as 128",
+        "phi4flash-sliding": narrow + "dk 64 as 128",
+        "granite4hmicro": narrow + "dk 64 and dv 64 wide as the columns of "
+                          "one array of 128",
+    }.get(cell, "")
+    assert fa._exit_said("flash_bwd_dkv", tiles, width, v_width, group) == ""
+
+
+def test_the_padded_columns_are_in_the_plan_s_cost():
+    """A row-long gradient of no whole lanes leaves at whole lanes and a
+    slice follows: twice the padded array in HBM, at a v5e's 819 GB/s, which
+    the tile exit's plan pays and the row's blocks do not. At whole lanes
+    nothing; dk and dv that share a tile of lanes are split after."""
+    moved = fa._padded_exit_bytes
+    assert moved(16384, 16384, 128, 128, 2, group=8) == 0
+    assert moved(16384, 16384, 192, 128, 2) == 2 * 16384 * 256 * 2
+    assert moved(16384, 16896, 64, 128, 2, group=2) == (
+        2 * 16384 * 128 * 2 + 2 * 16896 * 128 * 2 // 2)
+    assert moved(33024, 33024, 64, 64, 2, group=4) == (
+        2 * 33024 * 128 * 2 + 2 * 33024 * 128 * 2 // 4)
+    shape = (16384, 16384, 192, jnp.bfloat16)
+    by_tile = fa.flash_tiles("flash_bwd_dkv_dq", *shape, v_dim=128)
+    whole = fa.flash_tiles("flash_bwd_dkv_dq", *shape[:2], 256, jnp.bfloat16,
+                           v_dim=128)
+    # q and k of 256, whole lanes: the same tile and exit, nothing padded
+    assert (*by_tile[:2], by_tile.exit) == (*whole[:2], whole.exit)
+    assert by_tile.cost_us - whole.cost_us == pytest.approx(
+        2 * 16384 * 256 * 2 / 819e3)
+    # `kimilinear.tokens16k`'s latent layer: cheaper by tile with the term in
+    forced = fa.flash_tiles("flash_bwd_dkv_dq", *shape, v_dim=128,
+                            block_q=1024, block_k=512)
+    assert forced.exit == "block" and by_tile.cost_us < forced.cost_us
 
 
 KEYE = dict(block_k=1024, group=8, sparse=True)  # keyevl2.tokens16k's layer

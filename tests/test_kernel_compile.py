@@ -209,56 +209,93 @@ def test_the_one_backward_kernel_compiles_with_a_group_of_eight(v5e, window):
         f"bf16[{rows},{t},{d}]", f"bf16[{rows},{t},{d}]", f"bf16[{bh},{t},{d}]"]
 
 
+# (query rows, key-value rows, T, q and k's width, v's, window, data mask,
+# the planner's tile)
+SUMS_ALONE = {
+    "keye": (32, 4, 16384, 128, 128, None, True, (512, 1024)),
+    # a row too long for its dq's block
+    "no-group-64k": (2, 2, 65536, 128, 128, None, False, (512, 1024)),
+    # heads narrower than a tile of lanes, or wider by half of one (PR 75):
+    # `phi4flash.tokens16k`'s paired heads whole and under its window,
+    # `granite4hmicro.longctx`'s, whose dk and dv share a tile of lanes,
+    # and `kimilinear.tokens16k`'s latent layer
+    "phi4flash": (40, 20, 16384, 64, 128, None, False, (1024, 768)),
+    "phi4flash-window": (40, 20, 16384, 64, 128, 512, False, (512, 512)),
+    "granite": (32, 8, 32768, 64, 64, None, False, (768, 768)),
+    "kimilinear": (32, 32, 16384, 192, 128, None, False, (1024, 1024)),
+}
+
+
 @pytest.mark.timeout(600)  # a kernel, seconds; room under six workers
-@pytest.mark.parametrize("bh,rows,t,masked,tile", [
-    (32, 4, 16384, True, (512, 1024)),  # keyevl2.tokens16k's layer
-    (2, 2, 65536, False, (512, 1024)),  # a row too long for its dq's block
-], ids=["keye", "no-group-64k"])
-def test_the_one_backward_kernel_compiles_with_its_sums_alone(
-        v5e, bh, rows, t, masked, tile):
+@pytest.mark.parametrize("cell", list(SUMS_ALONE))
+def test_the_one_backward_kernel_compiles_with_its_sums_alone(v5e, cell):
     """Where a row's output blocks leave no tile room the one kernel holds
     the row-long gradients as f32 sums and copies them out a tile at a time
     (`FlashTiles.exit == "tile"`): the outputs lie where the compiler put
     them and have no block, the limit is the planner's, and the call is one
     custom call under the name the metrics read, dk and dv at the
     key-value rows. Under a group all three leave by DMA, without one dq
-    alone."""
+    alone. At a width of no whole lanes the array that leaves so is padded
+    to them (Mosaic refused the copy to rows of 64 and 192: "Slice shape
+    along dimension 2 must be aligned to tiling (128)"), and dk and dv that
+    fill one tile of lanes between them leave as the columns of one."""
     import re
 
-    d = 128
-    shape = dict(group=bh // rows, sparse=masked,
+    bh, rows, t, d, dv, window, masked, tile = SUMS_ALONE[cell]
+    shape = dict(group=bh // rows, sparse=masked, v_dim=dv, window=window,
                  block_k=1024 if masked else None)
     assert fa.flash_bwd_kernels(t, t, d, jnp.bfloat16, **shape) == (
         "flash_bwd_dkv_dq",)
     tiles = fa.flash_tiles("flash_bwd_dkv_dq", t, t, d, jnp.bfloat16, **shape)
     assert tiles[:2] == tile and tiles.exit == "tile"
-    q, row = _shapes(v5e[0], bh, t, d)
-    k, _ = _shapes(v5e[0], rows, t, d)
-    operands = [q, k, k, q, row, row]
-    chosen = dict(KERNEL, scale=d ** -0.5, block_q=None, block_k=None)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    row = sd(bh, t, 8, dtype=jnp.float32)
+    operands = [sd(bh, t, d), sd(rows, t, d), sd(rows, t, dv), sd(bh, t, dv),
+                row, row]
+    chosen = dict(KERNEL, scale=d ** -0.5, block_q=None, block_k=None,
+                  window=window)
     if masked:  # the selection's bits, in key tiles of 1,024
-        operands.append(jax.ShapeDtypeStruct(
-            (1, t // 1024, t, 128), jnp.int8, sharding=q.sharding))
+        operands.append(sd(1, t // 1024, t, 128, dtype=jnp.int8))
         chosen["block_k"] = 1024
 
     def fn(*a):
         return fa._flash_bwd_dkv(*a[:6], with_dq=True, **chosen,
                                  mask=a[6] if masked else None)
 
+    # what the kernel writes: whole tiles of rows, whole lanes of columns
+    lanes = fa._whole_lanes
+    rows_q, rows_k = (-(-t // b) * b for b in tile)
+    if bh == rows:  # dk's and dv's tiles are the pipeline's
+        written = [(rows, t, d), (rows, t, dv)]
+    elif d + dv <= 128:
+        written = [(rows, rows_k, 128)]
+    else:
+        written = [(rows, rows_k, lanes(d)), (rows, rows_k, lanes(dv))]
+    written.append((bh, rows_q, lanes(d)))
     (call,) = [eqn for eqn in jax.make_jaxpr(fn)(*operands).eqns
                if eqn.primitive.name == "pallas_call"]
-    outputs = call.params["grid_mapping"].block_mappings[-3:]
+    outputs = call.params["grid_mapping"].block_mappings[-len(written):]
     assert [str(m.block_aval.memory_space) for m in outputs] == (
-        ["any"] * 3 if bh > rows else ["None", "None", "any"])
+        ["any"] * len(written) if bh > rows else ["None", "None", "any"])
     limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
     assert limit == tiles.vmem_limit_bytes <= fa._MAX_VMEM
-    text = jax.jit(fn).lower(*operands).compile().as_text()
+    lowered = jax.jit(fn).lower(*operands)
+    # and what the caller hands on: dq, dk, dv as q, k and v are
+    assert [x.shape for x in jax.tree.leaves(lowered.out_info)] == [
+        x.shape for x in operands[:3]]
+    text = lowered.compile().as_text()
     ((name, outputs),) = re.findall(
         r'%([\w.-]+) = \((.*?)\) custom-call\([^\n]*"tpu_custom_call"', text)
     assert re.fullmatch(
-        "flash_bwd_dkv_dq" + ("_sparse" if masked else "") + r"(\.\d+)?", name)
+        "flash_bwd_dkv_dq" + ("_sparse" if masked else
+                              "_window" if window else "") + r"(\.\d+)?",
+        name)
     assert re.findall(r"bf16\[[\d,]+\]", outputs) == [
-        f"bf16[{rows},{t},{d}]", f"bf16[{rows},{t},{d}]", f"bf16[{bh},{t},{d}]"]
+        "bf16[%d,%d,%d]" % x for x in written]
 
 
 # (B, T, S, heads, the call's mask) at heads of 128, v with q's heads

@@ -353,25 +353,32 @@ def test_flash_backward_takes_the_two_kernels_where_it_is_told_to(monkeypatch):
     (8192, 64, 64, jnp.bfloat16, ONE, (1024, 1024)),    # lfm2moe.tokens8k
     (4096, 128, 128, jnp.bfloat16, ONE, (1024, 1024)),  # mistral7b.*, olmoe.*
     (1024, 64, 64, jnp.bfloat16, ONE, (512, 512)),      # chip_smoke.py
-    # longer rows leave the tile less room: measured at BH 8, 34.4 ms for
-    # the two kernels' 47.3 (PERF.md section 6, PR 35)
-    (16384, 192, 128, jnp.bfloat16, ONE, (1024, 512)),
+    # longer rows leave the tile less room beside the row's blocks (1024 x
+    # 512: at BH 8, 34.4 ms for the two kernels' 47.3, PERF.md section 6,
+    # PR 35); since PR 75 rows of 192 leave a tile at a time too, padded to
+    # 256 columns, and the sums alone leave the cheapest tile its room
+    (16384, 192, 128, jnp.bfloat16, ONE, (1024, 1024)),
     # a row's dq with its block (its f32 sum, and the block twice) is 8 T
     # lanes(D) bytes in bf16: 67 MB at T 32768, D 192, where the limit of
-    # 96 MiB allows an estimate of 48; no tile fits beside it, and rows of
-    # 192 cannot leave a tile at a time
-    (32768, 192, 128, jnp.bfloat16, TWO, None),
-    # at widths of whole lanes they can (PR 49), and the sum alone is 4 T
-    # lanes(D) bytes: the cheapest tile again where the row's block left
-    # room for 768 x 768, and the one kernel where it left room for none
+    # 96 MiB allows an estimate of 48; the sum alone is 33.6 MB
+    (32768, 192, 128, jnp.bfloat16, ONE, (512, 896)),
+    # at widths of whole lanes (PR 49) the sum alone is 4 T lanes(D) bytes:
+    # the cheapest tile again where the row's block left room for 768 x
+    # 768, and the one kernel where it left room for none
     (32768, 128, 128, jnp.bfloat16, ONE, (1024, 1024)),
     (46080, 128, 128, jnp.bfloat16, ONE, (1024, 896)),
     (65536, 128, 128, jnp.bfloat16, ONE, (512, 1024)),
     (32768, 128, 128, jnp.float32, ONE, (1024, 896)),
     (98304, 128, 128, jnp.bfloat16, TWO, None),  # 50 MB of sum
-    # it fits, beside tiles so small that their grid steps cost more than
-    # the second pass at 1024 x 1024 does
-    (22528, 192, 128, jnp.bfloat16, TWO, (256, 256)),
+    # heads of 64 fill a tile of lanes in VMEM as heads of 128 do, and
+    # leave padded to it: the same tiles
+    (65536, 64, 64, jnp.bfloat16, ONE, (512, 1024)),
+    (98304, 64, 64, jnp.bfloat16, TWO, None),
+    # where the row's blocks left room for tiles so small that their grid
+    # steps cost more than the second pass at 1024 x 1024 does, the sums
+    # alone leave room for 1024 x 768
+    (22528, 192, 128, jnp.bfloat16, ONE, (1024, 768)),
+    # and here they do not
     (90112, 128, 128, jnp.bfloat16, TWO, (256, 384)),
 ])
 def test_flash_backward_kernels_of_a_shape(T, D, Dv, dtype, kernels, tile):
@@ -382,7 +389,7 @@ def test_flash_backward_kernels_of_a_shape(T, D, Dv, dtype, kernels, tile):
     if fits:
         assert (one.block_q, one.block_k) == tile
         # the row's blocks wherever the cheapest tile has room beside them
-        assert (one.exit == "block") == (D == 192 or T <= 16384)
+        assert (one.exit == "block") == (T < 16384)
     two = sum(flash_tiles(kernel, T, T, D, dtype, v_dim=Dv).cost_us
               for kernel in TWO)
     assert (kernels == ONE) == (fits and one.cost_us <= two)

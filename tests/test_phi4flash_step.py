@@ -103,6 +103,29 @@ def test_a_differential_layer_is_one_paired_call(step):
     assert not re.search(r"(f32|bf16)\[(\d+,)*16384,16384\]", text)
 
 
+def test_every_attention_layer_s_backward_is_the_one_kernel(step):
+    """Heads of 64 under a group at T 16,384: the row's blocks leave the
+    one kernel no tile, and its sums alone do since the row-long gradients
+    leave a tile at a time padded to whole lanes (PR 75). `flash_bwd_dkv_dq`
+    once a whole layer, `flash_bwd_dkv_dq_window` once, and neither of the
+    pair that made every score tile twice; dq and dk leave 128 columns wide
+    in whole tiles of rows and the program takes the 64."""
+    text = step[0].as_text()
+    calls = {name: len(set(re.findall(rf"%{name}\.\d+ = ", text)))
+             for name in ("flash_bwd_dkv_dq", "flash_bwd_dkv_dq_window",
+                          "flash_bwd_dq", "flash_bwd_dq_window",
+                          "flash_bwd_dkv", "flash_bwd_dkv_window")}
+    assert calls == {"flash_bwd_dkv_dq": 2, "flash_bwd_dkv_dq_window": 1,
+                     "flash_bwd_dq": 0, "flash_bwd_dq_window": 0,
+                     "flash_bwd_dkv": 0, "flash_bwd_dkv_window": 0}
+    for made in re.findall(
+            r"%flash_bwd_dkv_dq(?:_window)?\.\d+ = \((.*?)\) custom-call",
+            text):
+        assert re.findall(r"bf16\[[\d,]+\]", made) in (
+            ["bf16[20,16896,128]"] * 2 + ["bf16[40,16384,128]"],   # 1024 x 768
+            ["bf16[20,16384,128]"] * 2 + ["bf16[40,16384,128]"])   # 512 x 512
+
+
 def test_no_array_of_a_sequence_s_states_nor_of_a_chunk_s_steps(step):
     """The scan's chunks: 128 entering states a layer (`[b, T / chunk, N,
     inner]`, as the kernels' forward writes them); never a chunk's 128

@@ -150,6 +150,12 @@ def test_a_walked_step_compiles_fits_and_is_priced(token_steps, cell_name):
         assert "f32[1,128,2,128,2048]" in text  # the entering states, by tile
         assert not re.search(r"(f32|bf16)\[(\d+,)*256,256\]", text)
         assert _calls(text, "flash_fwd") == 2  # `attn_ctx` is not kept
+        # the attention layer's backward is the one kernel since PR 75 (dk
+        # and dv, 64 wide each, the halves of one tile of lanes), and the
+        # plan above stands where the pair's stood
+        assert _calls(text, "flash_bwd_dkv_dq") == 1
+        assert _calls(text, "flash_bwd_dq") == _calls(
+            text, "flash_bwd_dkv") == 0
 
 
 def test_sdar_step_compiles_fits_and_is_priced(token_steps):
@@ -203,6 +209,56 @@ def test_sdar_step_compiles_fits_and_is_priced(token_steps):
     assert _calls(text, "moe_gmm") > 0 and _calls(text, "moe_tgmm") > 0
     assert not re.search(r"(f32|bf16|pred)\[(\d+,)*32768,32768\]", text)
     assert not re.search(r"(f32|bf16|pred)\[(\d+,)*16384,16384\]", text)
+
+
+# every step this module compiles, and the flash backward in it
+STEPS = ["kimilinear.tokens16k", *WALKED_CELLS, "sdar.tokens16k"]
+FORMS = ("", "_window", "_stair", "_sparse")
+
+
+@pytest.mark.parametrize("cell_name", STEPS)
+def test_no_step_runs_the_two_kernel_backward(token_steps, cell_name):
+    """Since PR 75 the tile-at-a-time exit is offered at every width, so
+    the long rows of narrow heads (`phi4flash.tokens16k`,
+    `granite4hmicro.longctx`) take the one kernel too: no step holds a
+    `flash_bwd_dq` or a `flash_bwd_dkv` in any form, every one a
+    `flash_bwd_dkv_dq` (ROADMAP D17)."""
+    text = token_steps(cell_name, limited=True).compiled.as_text()
+    for kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
+        assert not any(_calls(text, kernel + form) for form in FORMS), kernel
+    assert any(_calls(text, "flash_bwd_dkv_dq" + form) for form in FORMS)
+
+
+# what the one kernel writes where a row-long gradient leaves a tile at a
+# time at a width of no whole lanes (dk, dv, dq as the call lists them):
+# whole tiles of rows, whole lanes of columns, and in
+# `granite4hmicro.longctx` dk and dv as the halves of one array
+PADDED = {
+    "kimilinear.tokens16k": [
+        ["bf16[32,16384,192]", "bf16[32,16384,128]", "bf16[32,16384,256]"]],
+    "phi4flash.tokens16k": [
+        ["bf16[20,16896,128]"] * 2 + ["bf16[40,16384,128]"],
+        ["bf16[20,16896,128]"] * 2 + ["bf16[40,16384,128]"],
+        ["bf16[20,16384,128]"] * 2 + ["bf16[40,16384,128]"]],
+    "granite4hmicro.longctx": [["bf16[8,33024,128]", "bf16[32,33024,128]"]],
+}
+
+
+@pytest.mark.parametrize("cell_name", list(PADDED))
+def test_a_narrow_row_leaves_the_one_kernel_padded_to_whole_lanes(
+        token_steps, cell_name):
+    import re
+
+    text = token_steps(cell_name, limited=True).compiled.as_text()
+    written = [re.findall(r"bf16\[[\d,]+\]", outputs) for outputs in re.findall(
+        r"%flash_bwd_dkv_dq(?:_window)?(?:\.\d+)? = \((.*?)\) custom-call",
+        text)]
+    assert sorted(written) == sorted(PADDED[cell_name])
+    # and the program takes the columns: dq as q is
+    q = {"kimilinear.tokens16k": "bf16[32,16384,192]",
+         "phi4flash.tokens16k": "bf16[40,16384,64]",
+         "granite4hmicro.longctx": "bf16[32,32768,64]"}[cell_name]
+    assert q in text
 
 
 @pytest.mark.timeout(600)
